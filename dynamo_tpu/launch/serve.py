@@ -23,6 +23,13 @@ Graph file (YAML or JSON):
       - name: prefill
         replicas: 1
         args: [out=tpu, --model-config, tiny, --role, prefill]
+
+One process per chip (launch/chips.py): on a TPU host each ``out=tpu``
+replica is started with its own chip visible, every other child is pinned
+to the CPU, and a graph that wants more chips than the host has is
+refused before anything starts. The supervisor itself never touches JAX.
+Children inherit this process's stdout/stderr, so a worker that dies at
+start-up says why where the operator is looking.
 """
 from __future__ import annotations
 
@@ -36,6 +43,8 @@ import sys
 import time
 from dataclasses import dataclass, field
 from typing import Any, Optional
+
+from dynamo_tpu.launch.chips import ChipPlacement
 
 log = logging.getLogger(__name__)
 
@@ -59,6 +68,9 @@ def load_graph(path: str) -> dict[str, Any]:
 class _Child:
     name: str
     cmd: list[str]
+    # chip placement (launch/chips.py): kept across restarts, so a
+    # restarted replica comes back on the chip it had
+    env: dict[str, str] = field(default_factory=dict)
     proc: Optional[subprocess.Popen] = None
     restarts: list[float] = field(default_factory=list)
     give_up: bool = False
@@ -89,21 +101,31 @@ class Supervisor:
 
     def _build_children(self) -> None:
         base = [self.python, "-m", "dynamo_tpu.cli"]
+        chips = ChipPlacement()
+        off_chip, _ = chips.env_for([])  # pinned to the CPU on a TPU host
         if self.external_cp is None:
             self.children.append(_Child(
                 name="control-plane",
                 cmd=base + ["cp", "--port", str(self.cp_port)],
+                env=off_chip,
             ))
         for spec in self.graph.get("workers", []) or []:
             name = spec.get("name", "worker")
             replicas = int(spec.get("replicas", 1))
             args = [str(a) for a in (spec.get("args") or [])]
             for i in range(replicas):
+                try:
+                    env, taken = chips.env_for(args)
+                except ValueError as e:
+                    raise SystemExit(f"serve: {name}-{i}: {e}") from e
+                if taken:
+                    log.info("serve: %s-%d gets chip(s) %s", name, i, taken)
                 self.children.append(_Child(
                     name=f"{name}-{i}",
                     cmd=base + ["run", "in=endpoint",
                                 "--control-plane", self.cp_addr,
                                 "--namespace", self.namespace] + args,
+                    env=env,
                 ))
         if "frontend" in self.graph:
             # a bare `frontend:` key (YAML null) means defaults, not absent
@@ -116,16 +138,16 @@ class Supervisor:
                             "--namespace", self.namespace,
                             "--http-port",
                             str(fe.get("http_port", 8080))] + args,
+                env=off_chip,
             ))
 
     def _spawn(self, child: _Child) -> None:
         log.info("serve: starting %s: %s", child.name, " ".join(child.cmd))
+        # stdout/stderr inherited: a child that cannot start is visible
         child.proc = subprocess.Popen(
             child.cmd,
-            stdout=subprocess.DEVNULL,
-            stderr=subprocess.DEVNULL,
             start_new_session=True,
-            env=dict(os.environ),
+            env={**os.environ, **child.env},
         )
 
     async def start(self) -> "Supervisor":

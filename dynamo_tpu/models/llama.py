@@ -47,6 +47,7 @@ from dynamo_tpu.kv_quant import (
 )
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.ops.attention import (
+    DecodeAttention,
     ctx_decode_attention,
     ctx_prefill_attention,
     flash_prefill_attention,
@@ -1120,6 +1121,10 @@ def decode_step_impl(
                                # resident LoRA bank rows (0 = identity);
                                # mixed ids batch into ONE program via a
                                # row gather + rank-r einsum per site
+    *,
+    attn: DecodeAttention,     # which attention implementation to trace
+                               # (ops/attention.py) — always the caller's
+                               # explicit choice, no default
 ) -> tuple[Cache, jnp.ndarray]:
     """One decode step for all slots. Returns (ring, logits [B, vocab]).
 
@@ -1159,7 +1164,7 @@ def decode_step_impl(
 
         def attend(q, new_ring, l=l):
             return ctx_decode_attention(
-                q, ctx_kv["k"], ctx_kv["v"],
+                attn, q, ctx_kv["k"], ctx_kv["v"],
                 new_ring["k"], new_ring["v"], jnp.int32(l),
                 ctx_lens, ring_base,
                 ctx_k_scale=ctx_kv["k_scale"] if quant else None,
@@ -1174,7 +1179,10 @@ def decode_step_impl(
     return ring, logits
 
 
-decode_step = jax.jit(decode_step_impl, static_argnums=(0,), donate_argnums=(3,))
+decode_step = jax.jit(
+    decode_step_impl, static_argnums=(0,), static_argnames=("attn",),
+    donate_argnums=(3,),
+)
 
 
 def flush_ctx_impl(

@@ -6,9 +6,12 @@ paged pool exists only as prefix-cache storage, copied in/out at
 admission/seal. Attention in the hot path therefore reads dense slabs —
 no gathers, no page tables:
 
-  - decode: the Pallas flash kernel on TPU backends
-    (ops/pallas flash_decode.py), the pure-jnp reference elsewhere
-    (CPU test meshes, interpret checks);
+  - decode: the implementation the CALLER names with a ``DecodeAttention``
+    — the compiled Pallas flash kernel (ops/flash_decode.py), mapped per
+    shard over the mesh's ``tp`` axis, on TPU devices; the pure-jnp
+    reference on the CPU test meshes. Nothing here looks at the process's
+    default backend, and there is no fall-through from one to the other:
+    a kernel that fails to compile fails the program;
   - prefill: one dense causal attention over the slot's region — prefill
     is a large matmul XLA already schedules well; no kernel needed.
 
@@ -19,30 +22,66 @@ CUDA kernel).
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh, PartitionSpec as P
 
 from dynamo_tpu.ops.flash_decode import (
     flash_decode_attention,
     flash_decode_attention_reference,
 )
+from dynamo_tpu.parallel.mesh import AXIS_TENSOR
 
 NEG_INF = -1e30
 
-# None = auto (pallas iff backend is tpu); True/False force. Tests flip this
-# to validate kernel-vs-reference parity.
-USE_PALLAS: Optional[bool] = None
+PALLAS = "pallas"                      # the compiled Mosaic kernel
+PALLAS_INTERPRET = "pallas_interpret"  # same kernel, interpreted: tests only
+REFERENCE_IMPL = "reference"           # pure jnp
 
 
-def _pallas_enabled() -> bool:
-    if USE_PALLAS is not None:
-        return USE_PALLAS
-    return jax.default_backend() == "tpu"
+@dataclasses.dataclass(frozen=True)
+class DecodeAttention:
+    """The decode-attention implementation a program is traced with.
+    Hashable: it is a static argument of the jitted model functions."""
+
+    impl: str
+    # kernel impls: the mesh whose ``tp`` axis the kernel is mapped over
+    # (Mosaic calls cannot be partitioned by GSPMD). None = unsharded
+    # operands (single-device kernel tests).
+    mesh: Optional[Mesh] = None
+    # kernel tiling; 0 = the kernel's defaults (tools sweep these)
+    chunk: int = 0
+    slot_block: int = 0
+
+    def __post_init__(self):
+        if self.impl not in (PALLAS, PALLAS_INTERPRET, REFERENCE_IMPL):
+            raise ValueError(f"unknown decode attention impl {self.impl!r}")
+
+
+REFERENCE = DecodeAttention(REFERENCE_IMPL)
+
+
+def decode_attention_for(mesh: Mesh) -> DecodeAttention:
+    """The implementation an engine placed on ``mesh`` runs: the compiled
+    kernel on TPU devices — the only path there — and the jnp reference
+    on CPU devices (the test meshes; interpret-mode Pallas is far too
+    slow to serve from). Decided from the devices the engine's arrays
+    live on, once, and reported by the engine at start."""
+    platform = mesh.devices.flat[0].platform
+    if platform == "tpu":
+        return DecodeAttention(PALLAS, mesh)
+    if platform == "cpu":
+        return REFERENCE
+    raise ValueError(
+        f"no decode attention implementation for platform {platform!r}"
+    )
 
 
 def ctx_decode_attention(
+    attn: DecodeAttention,
     q: jnp.ndarray,          # [B, n_heads, hd] — one new token per slot
     ctx_k: jnp.ndarray,      # [L, kvh, B(+1), S, hd]
     ctx_v: jnp.ndarray,
@@ -59,14 +98,47 @@ def ctx_decode_attention(
     ring. Returns [B, n_heads, hd]. When the ctx region is int8
     (scales given), each KV chunk dequantizes in VMEM right after the
     DMA — the HBM stream is the int8 bytes."""
-    if _pallas_enabled():
+    scales = () if ctx_k_scale is None else (ctx_k_scale, ctx_v_scale)
+    if attn.impl == REFERENCE_IMPL:
+        return flash_decode_attention_reference(
+            q, ctx_k, ctx_v, ring_k, ring_v, layer, ctx_lens, ring_base,
+            *scales,
+        )
+
+    def kernel(q, ctx_k, ctx_v, ring_k, ring_v, layer, ctx_lens, ring_base,
+               *scales):
+        k_scale, v_scale = scales or (None, None)
         return flash_decode_attention(
             q, ctx_k, ctx_v, ring_k, ring_v, layer, ctx_lens, ring_base,
-            ctx_k_scale=ctx_k_scale, ctx_v_scale=ctx_v_scale,
+            chunk=attn.chunk, slot_block=attn.slot_block,
+            interpret=attn.impl == PALLAS_INTERPRET,
+            ctx_k_scale=k_scale, ctx_v_scale=v_scale,
         )
-    return flash_decode_attention_reference(
-        q, ctx_k, ctx_v, ring_k, ring_v, layer, ctx_lens, ring_base,
-        ctx_k_scale=ctx_k_scale, ctx_v_scale=ctx_v_scale,
+
+    if attn.mesh is not None:
+        # heads are independent: q/out shard on heads, ctx/ring on kv
+        # heads (the layouts llama.param/ctx/ring_shardings already give
+        # them), each shard runs the kernel on its own heads — no
+        # collective. Scales carry no head axis: replicated.
+        tp = attn.mesh.shape[AXIS_TENSOR]
+        if ctx_k.shape[1] % tp:
+            raise ValueError(
+                f"{ctx_k.shape[1]} kv heads do not divide over tp={tp}"
+            )
+        heads = P(None, AXIS_TENSOR, None)
+        kv = P(None, AXIS_TENSOR, None, None, None)
+        kernel = jax.shard_map(
+            kernel, mesh=attn.mesh,
+            in_specs=(heads, kv, kv, kv, kv, P(), P(), P())
+            + (P(),) * len(scales),
+            out_specs=heads,
+            # pallas_call has no replication rule; the other mesh axes
+            # see replicated operands and produce replicated outputs
+            check_vma=False,
+        )
+    return kernel(
+        q, ctx_k, ctx_v, ring_k, ring_v, jnp.asarray(layer, jnp.int32),
+        ctx_lens, ring_base, *scales,
     )
 
 

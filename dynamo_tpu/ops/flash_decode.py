@@ -2,7 +2,8 @@
 plus a small per-round write ring.
 
 Round-4 redesign of the decode hot path. Two lessons drive the design
-(measured on v5e, tools history in git):
+(from the round-3/4 development runs; not re-measured on the v5e host
+since — PERF.md):
 
   1. The round-3 kernel walked the paged pool with grid (slot, kv-head,
      page): 36k kernel invocations per step at ~0.4 µs each — 15.9 ms/step
@@ -22,8 +23,22 @@ Round-5 knob: ``slot_block`` processes SB slots per grid invocation
 fixed overhead (grid sequencing + DMA setup + Mosaic's serialization of
 small batched dots), so fewer, fatter invocations close the gap to the
 bandwidth roofline. The DMA-skip index then clamps to the LONGEST live
-context in the slot group (short slots ride along). Env overrides for
-experiments: ``DYNAMO_FLASH_SB`` / ``DYNAMO_FLASH_CHUNK``.
+context in the slot group (short slots ride along). ``chunk`` and
+``slot_block`` are static arguments (``ops.attention.DecodeAttention``
+carries them for sweeps); nothing is read from the environment.
+
+Partitioning: GSPMD cannot split a Mosaic call, so under a mesh the
+caller maps this function per shard over ``tp`` (``ops/attention.py``):
+heads are independent, each shard sees its own kv heads and needs no
+collective.
+
+Int8 ctx (scales given): the int8 payload widens exactly to the compute
+dtype in VMEM and the per-group f32 scales multiply the score /
+probability COLUMNS (q.(s k) == s (q.k)), never the [chunk, hd] tiles —
+Mosaic refuses the sublane-splitting reshape a tile-wise dequant needs
+("infer-vector-layout: unsupported shape cast"). Scale blocks are
+[.., 1, chunk/group]: a block's last two dims must be (8, 128)-divisible
+or equal to the array's.
 
 Position semantics: ctx_kv[l, :, b, p] holds position p of slot b, valid
 while p < ring_base[b]; ring[l, :, b, r] holds position ring_base[b]+r,
@@ -41,7 +56,6 @@ from __future__ import annotations
 import functools
 import logging
 import math
-import os
 
 import jax
 import jax.numpy as jnp
@@ -50,14 +64,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 logger = logging.getLogger(__name__)
 
-# renamed TPUCompilerParams -> CompilerParams across jax releases
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", None
-) or getattr(pltpu, "TPUCompilerParams")
-
 NEG_INF = -1e30
 
 DEFAULT_CHUNK = 512
+DEFAULT_SLOT_BLOCK = 1
 
 # chunk floor for the divisor fallback: below this the grid degenerates
 # into the per-invocation-overhead regime the kernel exists to avoid
@@ -105,7 +115,7 @@ def _kernel(
     q_ref,       # [SB, nkv, G, HD]
     k_ref,       # [1, nkv, SB, CHUNK, HD] — int8 when quantized
     v_ref,
-    # quantized only: ksc_ref/vsc_ref [1, SB, CHUNK//group] f32
+    # quantized only: ksc_ref/vsc_ref [1, SB, 1, 1, CHUNK//group] f32
     # then:
     # rk_ref,    # [1, nkv, SB, R, HD]   ring lanes (compute dtype)
     # rv_ref,
@@ -137,8 +147,14 @@ def _kernel(
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    def accumulate(j, k, v, start, limit, length):
-        # k/v [nkv, length, HD]; positions start + iota valid below limit
+    def accumulate(j, k, v, start, limit, length, k_sc=None, v_sc=None):
+        # k/v [nkv, length, HD]; positions start + iota valid below limit.
+        # k_sc/v_sc [1, length] f32: per-position dequant scales of an
+        # int8 chunk, applied to the score / probability COLUMNS —
+        # q.(s_c k_c) == s_c (q.k_c) and sum_c p_c (s_c v_c) ==
+        # sum_c (p_c s_c) v_c — so the [nkv, length, HD] tiles are never
+        # rescaled element-wise (and never reshaped: Mosaic's layout
+        # inference refuses the sublane-splitting cast that needs)
         pos = start + jax.lax.broadcasted_iota(
             jnp.int32, (1, 1, length), 2)
         valid = pos < limit
@@ -147,6 +163,8 @@ def _kernel(
             q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         ) * scale                                          # [nkv, G, length]
+        if k_sc is not None:
+            s = s * k_sc[None]
         s = jnp.where(valid, s, NEG_INF)
         m_prev = m_ref[j, :, :, :1]
         row_max = jnp.max(s, axis=2, keepdims=True)
@@ -155,12 +173,25 @@ def _kernel(
         alpha = jnp.exp(m_prev - m_new)
         l_new = l_ref[j, :, :, :1] * alpha + jnp.sum(
             p, axis=2, keepdims=True)
+        if v_sc is not None:
+            p = p * v_sc[None]
         acc_ref[j] = acc_ref[j] * alpha + jax.lax.dot_general(
             p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )
         m_ref[j] = jnp.broadcast_to(m_new, m_ref.shape[1:])
         l_ref[j] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+
+    def per_position(sc):
+        # [1, chunk//grp] group scales -> [1, chunk] along the lane axis:
+        # position c takes sc[c // grp] (ascending overwrite, one compare
+        # per group — no integer divide, no lane gather)
+        grp = chunk // sc.shape[1]
+        pos = jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
+        out = jnp.broadcast_to(sc[:, :1], (1, chunk))
+        for g in range(1, sc.shape[1]):
+            out = jnp.where(pos >= g * grp, sc[:, g:g + 1], out)
+        return out
 
     for j in range(sb):
         b = s_idx * sb + j
@@ -173,23 +204,19 @@ def _kernel(
         def _(j=j, ctx=ctx, base=base):
             k = k_ref[0, :, j]                  # [nkv, chunk, HD]
             v = v_ref[0, :, j]
+            k_sc = v_sc = None
             if quantized:
                 # dequantize in VMEM, right after the DMA: the HBM
-                # stream was the int8 bytes; QK/PV dots stay in the
-                # compute precision
-                nkv, _, hd = k.shape
-                nGc = ksc_ref.shape[2]
-                grp = chunk // nGc
-                ks = ksc_ref[0, j]              # [chunk//grp] f32
-                vs = vsc_ref[0, j]
-                k = (k.astype(jnp.float32).reshape(nkv, nGc, grp, hd)
-                     * ks[None, :, None, None]
-                     ).reshape(nkv, chunk, hd).astype(q_ref.dtype)
-                v = (v.astype(jnp.float32).reshape(nkv, nGc, grp, hd)
-                     * vs[None, :, None, None]
-                     ).reshape(nkv, chunk, hd).astype(q_ref.dtype)
+                # stream was the int8 bytes. The payload widens to the
+                # compute dtype exactly (|int8| <= 127 fits bf16's
+                # mantissa); the f32 scales ride the score columns
+                k = k.astype(jnp.float32).astype(q_ref.dtype)
+                v = v.astype(jnp.float32).astype(q_ref.dtype)
+                k_sc = per_position(ksc_ref[0, j, 0])
+                v_sc = per_position(vsc_ref[0, j, 0])
             accumulate(
                 j, k, v, i * chunk, jnp.minimum(base, ctx), chunk,
+                k_sc, v_sc,
             )
 
         # ring chunk: slot r holds position base + r, valid below ctx
@@ -225,18 +252,18 @@ def flash_decode_attention(
     """Flash decode attention over contiguous KV + ring. Returns
     [B, n_heads, HD]. The current token's KV must already be in the ring
     (position ctx-1 == ring_base + r for the step's ring slot r).
-    chunk/slot_block of 0 pick the defaults (env-overridable). With
-    ctx scales given, ctx_k/ctx_v are int8 and each chunk dequantizes in
-    VMEM after its DMA (half the live-context HBM bytes)."""
+    chunk/slot_block of 0 pick the defaults. With ctx scales given,
+    ctx_k/ctx_v are int8 and each chunk dequantizes in VMEM after its
+    DMA (half the live-context HBM bytes)."""
     B, n_heads, hd = q.shape
     L, nkv, _, S, _ = ctx_k.shape
     R = ring_k.shape[3]
     g = n_heads // nkv
     quantized = ctx_k_scale is not None
     if chunk <= 0:
-        chunk = int(os.environ.get("DYNAMO_FLASH_CHUNK", DEFAULT_CHUNK))
+        chunk = DEFAULT_CHUNK
     if slot_block <= 0:
-        slot_block = int(os.environ.get("DYNAMO_FLASH_SB", 1))
+        slot_block = DEFAULT_SLOT_BLOCK
     # chunk must tile S exactly (and whole scale groups when quantized)
     group = S // ctx_k_scale.shape[2] if quantized else 1
     chunk = _pick_chunk(S, chunk, group)
@@ -263,7 +290,7 @@ def flash_decode_attention(
         return (layer[0], 0, s, jnp.minimum(i, _grp_live(s, base)), 0)
 
     def sc_map(s, i, layer, ctx, base):
-        return (layer[0], s, jnp.minimum(i, _grp_live(s, base)))
+        return (layer[0], s, jnp.minimum(i, _grp_live(s, base)), 0, 0)
 
     def ring_map(s, i, layer, ctx, base):
         return (layer[0], 0, s, 0, 0)
@@ -275,11 +302,17 @@ def flash_decode_attention(
     ]
     inputs = [qg, ctx_k, ctx_v]
     if quantized:
+        # Mosaic wants a block's last two dims (8, 128)-divisible or equal
+        # to the array's: view the scale row as [n_chunks, 1, chunk/group]
+        # so one chunk's scales are a whole (1, chunk/group) minor tile
+        ngc = chunk // group
+        sc_shape = (L, ctx_k_scale.shape[1], n_chunks, 1, ngc)
         in_specs += [
-            pl.BlockSpec((1, sb, chunk // group), sc_map),
-            pl.BlockSpec((1, sb, chunk // group), sc_map),
+            pl.BlockSpec((1, sb, 1, 1, ngc), sc_map),
+            pl.BlockSpec((1, sb, 1, 1, ngc), sc_map),
         ]
-        inputs += [ctx_k_scale, ctx_v_scale]
+        inputs += [ctx_k_scale.reshape(sc_shape),
+                   ctx_v_scale.reshape(sc_shape)]
     in_specs += [
         pl.BlockSpec((1, nkv, sb, R, hd), ring_map),
         pl.BlockSpec((1, nkv, sb, R, hd), ring_map),
@@ -303,7 +336,7 @@ def flash_decode_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((B, nkv, g, hd), q.dtype),
         # generous scoped-vmem budget for the chunked block pipeline
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024,
         ),
         interpret=interpret,

@@ -63,10 +63,7 @@ def _ring_body(sp_size: int, axis: str, q, k, v, my_idx, Tl, scale):
     # accumulators are per-device (sp-varying) state: mark them so the
     # fori_loop carry type matches the sharded outputs
     def _vary(x):
-        pcast = getattr(jax.lax, "pcast", None)
-        if pcast is not None:
-            return pcast(x, axis, to="varying")
-        return jax.lax.pvary(x, (axis,))
+        return jax.lax.pcast(x, axis, to="varying")
 
     m0 = _vary(jnp.full((H, Tq), -1e29, jnp.float32))
     l0 = _vary(jnp.zeros((H, Tq), jnp.float32))

@@ -19,6 +19,7 @@ from __future__ import annotations
 import asyncio
 import json
 import logging
+import os
 import subprocess
 import sys
 import time
@@ -85,7 +86,13 @@ class LocalConnector:
     SIGTERM asks the worker to stop admitting, finish its in-flight
     requests and exit (launch/run.py installs the drain handler) — the
     warm KV and live streams survive scale-down. SIGKILL only lands
-    after ``drain_grace_s`` as the unresponsive-worker backstop."""
+    after ``drain_grace_s`` as the unresponsive-worker backstop.
+
+    One process per chip (launch/chips.py): on a TPU host each
+    ``out=tpu`` worker is spawned with its own chip visible, and a
+    scale-up past the host's chips is refused with an error instead of
+    spawning a worker that would die at TPU init. Workers inherit this
+    process's stdout/stderr."""
 
     def __init__(self, worker_cmd: list[str], drain_grace_s: float = 30.0,
                  clock: Optional[Any] = None):
@@ -93,7 +100,11 @@ class LocalConnector:
 
         # e.g. [sys.executable, "-m", "dynamo_tpu.cli", "run",
         #       "in=endpoint", "out=mocker", "--control-plane", addr, ...]
+        from dynamo_tpu.launch.chips import ChipPlacement
+
         self.worker_cmd = list(worker_cmd)
+        self._chips = ChipPlacement()
+        self._proc_chips: dict[int, list[int]] = {}  # pid -> chips held
         self.drain_grace_s = drain_grace_s
         # drain-grace deadlines are sim-visible: under a compressed clock
         # the grace window must compress too (real clock default)
@@ -110,7 +121,15 @@ class LocalConnector:
 
     def current_replicas(self) -> int:
         self.procs = [p for p in self.procs if p.poll() is None]
+        self._reap_chips()
         return len(self.procs)
+
+    def _reap_chips(self) -> None:
+        """Return the chips of every exited worker (retirees included)."""
+        live = {p.pid for p in self.procs + self._retiring_procs
+                if p.poll() is None}
+        for pid in [pid for pid in self._proc_chips if pid not in live]:
+            self._chips.release(self._proc_chips.pop(pid))
 
     async def _retire(self, proc: subprocess.Popen) -> None:
         """SIGTERM -> wait out the drain grace -> SIGKILL backstop."""
@@ -133,13 +152,19 @@ class LocalConnector:
         self.current_replicas()  # reap exited
         self._retiring = [t for t in self._retiring if not t.done()]
         while len(self.procs) < n:
+            try:
+                env, taken = self._chips.env_for(self.worker_cmd)
+            except ValueError as e:
+                log.error("planner: cannot scale to %d workers: %s", n, e)
+                break
             proc = subprocess.Popen(
-                self.worker_cmd,
-                stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL,
-                start_new_session=True,
+                self.worker_cmd, start_new_session=True,
+                env={**os.environ, **env},
             )
             self.procs.append(proc)
-            log.info("planner: spawned worker pid %d", proc.pid)
+            self._proc_chips[proc.pid] = taken
+            log.info("planner: spawned worker pid %d (chips %s)",
+                     proc.pid, taken or "none")
         while len(self.procs) > n:
             proc = self.procs.pop()
             log.info("planner: draining worker pid %d (grace %.0fs)",
@@ -170,11 +195,27 @@ class MultihostLocalConnector:
     together. Command args are templated with ``{rank}``, ``{coord}``
     (a fresh coordinator address per group) and ``{replica}`` (unique
     component suffix, so concurrent groups' bring-up barriers and command
-    queues never collide)."""
+    queues never collide).
+
+    Every rank of every group runs on THIS host, which is how the CPU
+    harness exercises the cross-host protocol. On a TPU host the ranks
+    cannot share its chips (one process per chip, launch/chips.py: the
+    first rank would take them all and the rest die at TPU init), so the
+    connector refuses unless ``env`` pins the ranks to the CPU."""
 
     def __init__(self, cmd_template: list[str], num_nodes: int = 2,
                  host: str = "127.0.0.1",
                  env: Optional[dict[str, str]] = None):
+        from dynamo_tpu.launch.chips import host_chip_count
+
+        platforms = (env if env is not None else os.environ).get(
+            "JAX_PLATFORMS", "")
+        if host_chip_count() > 0 and platforms != "cpu":
+            raise ValueError(
+                "MultihostLocalConnector runs all ranks on one host; on a "
+                "TPU host they cannot share its chips (one process per "
+                "chip). Run one rank per host, or pin JAX_PLATFORMS=cpu."
+            )
         self.cmd_template = list(cmd_template)
         self.num_nodes = num_nodes
         self.host = host
@@ -211,9 +252,7 @@ class MultihostLocalConnector:
                     for a in self.cmd_template
                 ]
                 group.append(subprocess.Popen(
-                    cmd, stdout=subprocess.DEVNULL,
-                    stderr=subprocess.DEVNULL, start_new_session=True,
-                    env=self.env,
+                    cmd, start_new_session=True, env=self.env,
                 ))
             self.groups.append(group)
             log.info("planner: spawned multihost group %d (%d procs)",

@@ -22,9 +22,9 @@ counters — they are exact for the streams the fused round provably
 moves (every weight byte, every live KV chunk the DMA-skip index map
 admits) and they deliberately exclude second-order traffic (activation
 spills, sampler temporaries). On CPU harnesses the byte fields stay
-real (geometry is geometry) while utilization fractions should be
-nulled by the caller per the PR 7 honesty rule — a CPU has no TPU peak
-bandwidth to attribute against.
+real (geometry is geometry) while there are no peaks to attribute
+against: ``chip_info`` returns None for them, and an accelerator that is
+not in the table is an error, never an assumed v5e.
 """
 from __future__ import annotations
 
@@ -32,27 +32,34 @@ from typing import Optional, Sequence
 
 import numpy as np
 
-# chip peak table (bf16 FLOP/s, HBM B/s); device_kind -> (flops, bw)
+# chip peak table (bf16 FLOP/s, HBM B/s); device_kind -> (flops, bw).
+# Published per-chip peaks (Google Cloud TPU documentation).
 CHIP_PEAKS = {
     "TPU v5e": (197e12, 819e9),
     "TPU v5 lite": (197e12, 819e9),
     "TPU v4": (275e12, 1228e9),
     "TPU v6e": (918e12, 1640e9),
 }
-DEFAULT_PEAK = (197e12, 819e9)  # assume v5e if unknown
 
 
 def chip_info():
-    """(device_kind, (peak_flops, peak_bw), on_accelerator)."""
+    """(device_kind, (peak_flops, peak_bw) | None, on_accelerator).
+    The CPU has no peaks (None); an accelerator whose device_kind is not
+    in CHIP_PEAKS raises — a utilization against a guessed peak is a
+    made-up number."""
     import jax
 
     dev = jax.devices()[0]
     kind = dev.device_kind
-    on_accel = dev.platform != "cpu"
+    if dev.platform == "cpu":
+        return kind, None, False
     for name, peak in CHIP_PEAKS.items():
         if name.lower() in kind.lower():
-            return kind, peak, on_accel
-    return kind, DEFAULT_PEAK, on_accel
+            return kind, peak, True
+    raise ValueError(
+        f"no peak FLOP/s / bandwidth on record for device_kind {kind!r}; "
+        "add it to dynamo_tpu.roofline.CHIP_PEAKS with its source"
+    )
 
 
 def decode_byte_accounting(

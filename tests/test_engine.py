@@ -18,6 +18,7 @@ from dynamo_tpu.engine.engine import TpuEngine
 from dynamo_tpu.kv_router.protocols import KvEventKind
 from dynamo_tpu.models import llama
 from dynamo_tpu.models.config import ModelConfig
+from dynamo_tpu.ops.attention import REFERENCE
 from dynamo_tpu.protocols.common import (
     PreprocessedRequest,
     SamplingOptions,
@@ -140,7 +141,7 @@ def manual_greedy(cfg, params, ecfg, prompt, n_new):
             cfg, params, ctx, ring,
             jnp.asarray([out[-1]], jnp.int32),
             jnp.asarray([seq_len], jnp.int32),
-            ring_base, jnp.int32(0),
+            ring_base, jnp.int32(0), attn=REFERENCE,
         )
         ctx = llama.flush_ctx(
             ctx, ring, jnp.asarray([0], jnp.int32), ring_base,

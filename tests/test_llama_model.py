@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from dynamo_tpu.models import llama
 from dynamo_tpu.models.config import ModelConfig
+from dynamo_tpu.ops.attention import REFERENCE
 from dynamo_tpu.parallel.mesh import MeshConfig, make_mesh
 
 PAGE = 8
@@ -102,7 +103,7 @@ def test_decode_matches_hf(pair):
             ctx_lens = jnp.asarray([len(seq), 1], jnp.int32)
             ring, logits = llama.decode_step(
                 cfg, params, ctx, ring, tokens, ctx_lens,
-                ring_base, jnp.int32(s),
+                ring_base, jnp.int32(s), attn=REFERENCE,
             )
             ref = hf_logits(model, seq)[-1]
             got = np.asarray(logits)[0]

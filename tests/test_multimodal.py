@@ -13,6 +13,7 @@ from dynamo_tpu.engine.config import EngineConfig
 from dynamo_tpu.engine.engine import TpuEngine
 from dynamo_tpu.models import llama
 from dynamo_tpu.models.config import ModelConfig
+from dynamo_tpu.ops.attention import REFERENCE
 from dynamo_tpu.models.vision import (
     VisionConfig,
     encode_image,
@@ -123,6 +124,7 @@ async def test_multimodal_e2e_inprocess(setup):
         ring, lg = llama.decode_step(
             cfg, params, ctx, ring, jnp.asarray([ref[-1]], jnp.int32),
             jnp.asarray([seq_len], jnp.int32), rb, jnp.int32(0),
+            attn=REFERENCE,
         )
         ctx = llama.flush_ctx(ctx, ring, jnp.asarray([0], jnp.int32), rb,
                               jnp.asarray([1], jnp.int32))

@@ -1,0 +1,193 @@
+"""The decode kernel as the ENGINE calls it lowers for a TPU — checked on
+the CPU by cross-lowering (``lowering_platforms=("tpu",)`` runs Pallas's
+TPU lowering rules: BlockSpec tiling, scalar prefetch, the GSPMD
+partitioning refusal) — and the per-shard mapping over ``tp`` computes
+what the reference computes (interpret mode on the virtual device mesh).
+
+Both failures this file pins were live before PR 21: any tp>1 engine
+died with "Mosaic kernels cannot be automatically partitioned", and the
+int8-ctx scale BlockSpecs broke the (8, 128) tiling rule. Mosaic's own
+compile (layout inference, VMEM fit) needs libtpu's compiler: that is
+tools/tpu_compile_check.py (slow-marked below), then chip_smoke.py.
+"""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from dynamo_tpu.models import llama
+from dynamo_tpu.models.config import ModelConfig
+from dynamo_tpu.ops.attention import (
+    PALLAS,
+    PALLAS_INTERPRET,
+    REFERENCE,
+    DecodeAttention,
+    ctx_decode_attention,
+    decode_attention_for,
+)
+from dynamo_tpu.ops.flash_decode import flash_decode_attention
+from dynamo_tpu.parallel.mesh import MeshConfig, make_mesh
+
+# the CLI's default engine sizes (EngineConfig): 8 slots, 4096 context,
+# 4-step rounds, 64-token pages (= the int8 scale group)
+B, S, R, GROUP = 8, 4096, 4, 64
+
+
+def _kernel_args(c, quant, layers=2):
+    sds = jax.ShapeDtypeStruct
+
+    ctx_dtype = jnp.int8 if quant else jnp.bfloat16
+    kv = (layers, c.num_kv_heads, B + 1, S, c.head_dim)
+    ring = (layers, c.num_kv_heads, B, R, c.head_dim)
+    args = [
+        sds((B, c.num_heads, c.head_dim), jnp.bfloat16),
+        sds(kv, ctx_dtype), sds(kv, ctx_dtype),
+        sds(ring, jnp.bfloat16), sds(ring, jnp.bfloat16),
+        sds((), jnp.int32), sds((B,), jnp.int32), sds((B,), jnp.int32),
+    ]
+    scales = {}
+    if quant:
+        sc = sds((layers, B + 1, S // GROUP), jnp.float32)
+        scales = {"ctx_k_scale": sc, "ctx_v_scale": sc}
+    return args, scales
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("model", ["llama3_1b", "llama3_8b"])
+def test_flash_decode_lowers_for_tpu(model, quant):
+    """hd 64 / g 4 and hd 128 serving shapes, dense and int8 ctx."""
+    c = getattr(ModelConfig, model)()
+    args, scales = _kernel_args(c, quant)
+    lowered = flash_decode_attention.trace(*args, **scales).lower(
+        lowering_platforms=("tpu",)
+    )
+    assert "tpu_custom_call" in lowered.as_text()
+
+
+def _abstract(make, shardings):
+    return jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        jax.eval_shape(make), shardings,
+    )
+
+
+@pytest.mark.parametrize("kv_quant", ["none", "int8"])
+@pytest.mark.parametrize("tp", [1, 4])
+def test_decode_step_lowers_for_tpu_on_tp_mesh(tp, kv_quant):
+    """decode_step_impl with the kernel forced, GSPMD-sharded params /
+    ctx / ring on a tp mesh of the virtual devices: the kernel must be
+    shard-mapped (GSPMD cannot partition a Mosaic call)."""
+    c = ModelConfig.llama3_1b(num_layers=1)
+    mesh = make_mesh(MeshConfig(tp=tp), jax.devices()[:tp])
+    params = _abstract(lambda: llama.init_params(c, 0),
+                       llama.param_shardings(c, mesh))
+    ctx = _abstract(
+        lambda: llama.init_ctx(c, B, S, jnp.bfloat16, kv_quant=kv_quant,
+                               group=GROUP),
+        llama.ctx_shardings(c, mesh, kv_quant=kv_quant),
+    )
+    ring = _abstract(lambda: llama.init_ring(c, B, R, jnp.bfloat16),
+                     llama.ring_shardings(c, mesh))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    lowered = llama.decode_step.trace(
+        c, params, ctx, ring, i32(B), i32(B), i32(B), i32(),
+        attn=DecodeAttention(PALLAS, mesh),
+    ).lower(lowering_platforms=("tpu",))
+    assert "tpu_custom_call" in lowered.as_text()
+
+
+# small shapes for the interpreter: 4 kv heads shard over tp in {2, 4}
+L_, NKV, NH, HD = 2, 4, 8, 16
+B_, S_, R_, G_ = 2, 32, 2, 16
+
+
+@pytest.fixture(scope="module")
+def small():
+    rng = np.random.RandomState(0)
+
+    def f32(*shape):
+        return jnp.asarray(rng.randn(*shape) * 0.3, jnp.float32)
+
+    return dict(
+        q=f32(B_, NH, HD),
+        ck=f32(L_, NKV, B_ + 1, S_, HD), cv=f32(L_, NKV, B_ + 1, S_, HD),
+        rk=f32(L_, NKV, B_, R_, HD), rv=f32(L_, NKV, B_, R_, HD),
+        base=jnp.asarray([17, 30], jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["dense", "int8"])
+@pytest.mark.parametrize("tp", [2, 4])
+def test_shard_mapped_kernel_matches_reference(small, tp, quant):
+    """The kernel mapped per shard over tp (interpret mode, virtual
+    devices) vs the unsharded jnp reference: heads are independent, so
+    sharding q/out on heads and ctx/ring on kv heads changes nothing."""
+    d = small
+    ck, cv, scales = d["ck"], d["cv"], ()
+    if quant:
+        def q8(x):
+            g = np.asarray(x).reshape(L_, NKV, B_ + 1, S_ // G_, G_, HD)
+            s = np.maximum(np.abs(g).max(axis=(1, 4, 5)) / 127.0, 1e-8)
+            q = np.clip(np.rint(g / s[:, None, :, :, None, None]),
+                        -127, 127).astype(np.int8).reshape(x.shape)
+            return jnp.asarray(q), jnp.asarray(s, jnp.float32)
+
+        (ck, ks), (cv, vs) = q8(ck), q8(cv)
+        scales = (ks, vs)
+    ctx_lens = d["base"] + 2
+    mesh = make_mesh(MeshConfig(tp=tp), jax.devices()[:tp])
+    attn = DecodeAttention(PALLAS_INTERPRET, mesh, chunk=16)
+    args = (d["q"], ck, cv, d["rk"], d["rv"], jnp.int32(1), ctx_lens,
+            d["base"]) + scales
+    got = jax.jit(ctx_decode_attention, static_argnums=0)(attn, *args)
+    want = ctx_decode_attention(REFERENCE, *args)
+    # interpret mode emulates the MXU's bf16 passes (test_flash_decode)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), rtol=5e-3, atol=5e-3)
+    # and the output really is head-sharded over the mesh
+    assert len({s.index for s in got.addressable_shards}) == tp
+
+
+def test_kv_heads_must_divide_tp(small):
+    d = small
+    mesh = make_mesh(MeshConfig(tp=8), jax.devices()[:8])
+    with pytest.raises(ValueError, match="kv heads do not divide"):
+        ctx_decode_attention(
+            DecodeAttention(PALLAS_INTERPRET, mesh), d["q"], d["ck"],
+            d["cv"], d["rk"], d["rv"], jnp.int32(0), d["base"] + 1,
+            d["base"],
+        )
+
+
+def test_engine_selection_is_by_device_and_named():
+    """CPU test meshes run the reference, by name; an implementation
+    nobody wrote is an error, never a silent substitute."""
+    mesh = make_mesh(MeshConfig(tp=1), jax.devices()[:1])
+    assert decode_attention_for(mesh) is REFERENCE
+    with pytest.raises(ValueError, match="unknown decode attention"):
+        DecodeAttention("auto")
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("tp,kv_quant", [(1, "none"), (4, "int8")])
+def test_v5e_topology_compile(tp, kv_quant):
+    """Full XLA:TPU + Mosaic compile of the decode step, the flush and a
+    prefill bucket for compile-only v5e devices (cut to 2 layers)."""
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
+    import tpu_compile_check
+
+    # conftest pins matmul precision to "highest" for the CPU goldens;
+    # Mosaic refuses a bf16 dot at that precision ("Bad lhs type"), and
+    # no serving process sets it — compile what serving compiles
+    with jax.default_matmul_precision("default"):
+        records = tpu_compile_check.compile_programs(
+            "llama3_1b", tp, kv_quant, layers=2)
+    assert all(r["ok"] for r in records), records
+    assert records[0]["mosaic_calls"] == 2
