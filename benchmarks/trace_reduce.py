@@ -1,0 +1,314 @@
+#!/usr/bin/env python3
+"""Device trace -> numbers. Two pieces, kept apart:
+
+  read_xplane(path)  the ``.xplane.pb`` a ``jax.profiler`` trace leaves ->
+                     a flat list of ``(plane, line, name, start_ns,
+                     dur_ns)``; needs JAX (``ProfileData``), nothing else.
+  reduce_events(ev)  pure arithmetic on that list: union of intervals per
+                     chip, sums by name, collectives and their exposed
+                     part, idle gaps labelled with the XLA modules on
+                     either side. No JAX.
+
+Run by the harness as a child of its own with ``JAX_PLATFORMS=cpu`` after
+the server has exited (the parent stays off JAX, nothing contends for the
+chip):  ``python3 benchmarks/trace_reduce.py --reduce <dir> --out <json>``
+and checked on a hand-written event list by
+``python3 -m benchmarks.trace_reduce --selftest`` (exit 0/1).
+
+What a v5e trace looks like (one looked at by hand, PR 24; ``--dump``
+lists a trace's planes, lines and heaviest names): one plane per chip,
+``/device:TPU:<n>``, with the lines ``XLA Modules`` (one event per executed
+program, named ``jit_<function>(<fingerprint>)``), ``XLA Ops`` (one event
+per HLO op executed, named by its whole HLO text, ``%copy.12 = bf16[..]{..}
+copy(..)``; a ``while`` and the ops of its body are both there, nested; a
+Pallas call appears under its kernel function's name), ``Async XLA Ops``
+and ``TC Overlay`` (not read); host threads are planes ``/host:CPU``.
+"""
+from __future__ import annotations
+
+import argparse
+import glob
+import gzip
+import json
+import os
+import re
+import sys
+
+DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
+OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
+COLLECTIVE = re.compile(
+    r"^(all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all"
+    r"|collective-broadcast)")
+# ops that only contain other ops: their time is their children's
+CONTAINER = re.compile(r"^(while|conditional|call)$")
+
+Event = tuple  # (plane, line, name, start_ns, dur_ns)
+
+
+def read_xplane(path: str) -> list[Event]:
+    from jax.profiler import ProfileData
+
+    if path.endswith(".gz"):
+        with gzip.open(path, "rb") as f:
+            data = ProfileData.from_serialized_xspace(f.read())
+    else:
+        data = ProfileData.from_file(path)
+    out: list[Event] = []
+    for plane in data.planes:
+        if not DEVICE_PLANE.match(plane.name):
+            continue
+        for line in plane.lines:
+            if line.name not in (OPS_LINE, MODULES_LINE):
+                continue
+            for ev in line.events:
+                out.append((plane.name, line.name, ev.name,
+                            int(ev.start_ns), int(ev.duration_ns)))
+    return out
+
+
+def find_xplane(trace_dir: str) -> str:
+    found = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb*")))
+    if not found:
+        raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
+    return found[-1]
+
+
+# --------------------------------------------------------------------------
+# interval arithmetic
+
+
+def union(intervals: list[tuple[int, int]]) -> list[tuple[int, int]]:
+    """Sorted, disjoint cover of [start, end) intervals."""
+    out: list[list[int]] = []
+    for s, e in sorted(i for i in intervals if i[1] > i[0]):
+        if out and s <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], e)
+        else:
+            out.append([s, e])
+    return [(s, e) for s, e in out]
+
+
+def total(cover: list[tuple[int, int]]) -> int:
+    return sum(e - s for s, e in cover)
+
+
+def overlap(a: list[tuple[int, int]], b: list[tuple[int, int]]) -> int:
+    """Length of the intersection of two disjoint sorted covers."""
+    i = j = n = 0
+    while i < len(a) and j < len(b):
+        lo, hi = max(a[i][0], b[j][0]), min(a[i][1], b[j][1])
+        if hi > lo:
+            n += hi - lo
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return n
+
+
+def op_kind(name: str) -> str:
+    """An op event is named by its HLO text, ``%copy.12 = bf16[..]{..}
+    copy(..)`` (or just ``copy.12``): -> ``copy``."""
+    head = name.split(" = ", 1)[0].lstrip("%")
+    return re.sub(r"[.\d]+$", "", head)
+
+
+def op_label(name: str) -> str:
+    """Kind and output type without layouts: the 32 per-layer copies of
+    one unrolled op get one label, ``fusion (f32[32,1024], f32[..])``."""
+    rhs = name.split(" = ", 1)[1] if " = " in name else ""
+    rhs = re.sub(r"\{[^}]*\}", "", rhs)
+    m = re.search(r" [a-z][a-z\-]*\(", rhs)
+    shape = (rhs[:m.start()] if m else rhs).strip()
+    return (op_kind(name) + " " + shape).strip()[:120]
+
+
+def module_base(name: str) -> str:
+    """``jit_engine_round_seal(123456)`` -> ``jit_engine_round_seal``."""
+    return name.split("(", 1)[0]
+
+
+def reduce_events(events: list[Event]) -> dict:
+    """Everything the per-layer readers and the breakdown need."""
+    chips: dict[str, dict] = {}
+    for plane, line, name, start, dur in events:
+        if not DEVICE_PLANE.match(plane):
+            continue
+        chip = chips.setdefault(plane, {"ops": [], "modules": []})
+        (chip["ops"] if line == OPS_LINE else chip["modules"]).append(
+            (name, start, start + dur))
+    all_ops = [o for c in chips.values() for o in c["ops"]]
+    if not all_ops:
+        return {"chips": 0, "window_s": 0.0, "busy_s": 0.0}
+    w0 = min(s for _, s, _ in all_ops)
+    w1 = max(e for _, _, e in all_ops)
+    window = w1 - w0
+    per_chip = {}
+    op_time: dict[str, int] = {}
+    gaps: dict[str, int] = {}
+    modules: dict[str, dict] = {}
+    for plane, chip in sorted(chips.items()):
+        busy = union([(s, e) for _, s, e in chip["ops"]])
+        kinds = [op_kind(n) for n, _, _ in chip["ops"]]
+        coll = union([(s, e) for (_, s, e), k in zip(chip["ops"], kinds)
+                      if COLLECTIVE.match(k)])
+        other = union([(s, e) for (_, s, e), k in zip(chip["ops"], kinds)
+                       if not COLLECTIVE.match(k) and not CONTAINER.match(k)])
+        per_chip[plane] = {
+            "busy_s": total(busy) / 1e9,
+            "collective_s": total(coll) / 1e9,
+            "collective_exposed_s": (total(coll) - overlap(coll, other)) / 1e9,
+        }
+        for (n, s, e), k in zip(chip["ops"], kinds):
+            if not CONTAINER.match(k):
+                label = op_label(n)
+                op_time[label] = op_time.get(label, 0) + (e - s)
+        mods = sorted(chip["modules"], key=lambda m: m[1])
+        for n, s, e in mods:
+            m = modules.setdefault(module_base(n), {"count": 0, "ns": 0})
+            m["count"] += 1
+            m["ns"] += e - s
+        # idle gaps, labelled by the modules on either side
+        edges = [w0] + [x for s, e in busy for x in (s, e)] + [w1]
+        for g0, g1 in zip(edges[0::2], edges[1::2]):
+            if g1 <= g0:
+                continue
+            before = [n for n, s, e in mods if e <= g0 + 1]
+            after = [n for n, s, e in mods if s >= g1 - 1]
+            inside = [n for n, s, e in mods if s <= g0 and e >= g1]
+            if inside:
+                label = f"inside {module_base(inside[0])}"
+            else:
+                label = (f"{module_base(before[-1]) if before else 'start'}"
+                         f" -> {module_base(after[0]) if after else 'end'}")
+            gaps[label] = gaps.get(label, 0) + (g1 - g0)
+    n = len(chips)
+    busiest = max(c["busy_s"] for c in per_chip.values())
+    idlest = min(c["busy_s"] for c in per_chip.values())
+    return {
+        "chips": n,
+        "window_s": window / 1e9,
+        "busy_s": sum(c["busy_s"] for c in per_chip.values()) / n,
+        "busy_s_max": busiest,
+        "busy_s_min": idlest,
+        "per_chip": per_chip,
+        # modules: per-chip means (every chip runs every module of a
+        # sharded program, so counts and times are summed over chips)
+        "modules": {k: {"count": v["count"] / n, "seconds": v["ns"] / 1e9 / n}
+                    for k, v in modules.items()},
+        "device_ops": [[k, v / 1e9 / n] for k, v in sorted(
+            op_time.items(), key=lambda kv: -kv[1])[:10]],
+        "idle_gaps": [[k, v / 1e9 / n] for k, v in sorted(
+            gaps.items(), key=lambda kv: -kv[1])[:10]],
+    }
+
+
+# --------------------------------------------------------------------------
+
+
+def selftest() -> int:
+    bad: list[str] = []
+
+    def expect(name, cond):
+        if not cond:
+            bad.append(name)
+
+    def near(a, b):
+        return abs(a - b) < 1e-12
+
+    expect("union merges overlap and touch",
+           union([(5, 7), (0, 2), (1, 3), (3, 4)]) == [(0, 4), (5, 7)])
+    expect("overlap", overlap([(0, 4), (6, 9)], [(3, 7)]) == 2)
+    T0, T1 = "/device:TPU:0", "/device:TPU:1"
+    ms = 1_000_000
+    ev = [
+        # chip 0: two overlapping ops (0-4 ms), a gap, a collective alone
+        # (6-8 ms), a collective under compute (8-10 ms), a while that
+        # only contains the first two ops
+        (T0, MODULES_LINE, "jit_round(11)", 0, 4 * ms),
+        (T0, MODULES_LINE, "jit_prefill(22)", 6 * ms, 4 * ms),
+        (T0, OPS_LINE, "%while.1 = (s32[]{:T(128)}) while(s32[] %p)", 0, 4 * ms),
+        (T0, OPS_LINE, "%fusion.1 = f32[8,4]{1,0:T(8,128)} fusion(f32[8] %a)",
+         0, 3 * ms),
+        (T0, OPS_LINE, "fusion.2", 2 * ms, 2 * ms),
+        (T0, OPS_LINE, "%all-reduce.1 = bf16[16,5120]{1,0} all-reduce(bf16[16,"
+         "5120] %x)", 6 * ms, 2 * ms),
+        (T0, OPS_LINE, "all-reduce.2", 8 * ms, 2 * ms),
+        (T0, OPS_LINE, "fusion.3", 8 * ms, 2 * ms),
+        # chip 1: busy only 0-2 ms
+        (T1, MODULES_LINE, "jit_round(11)", 0, 2 * ms),
+        (T1, OPS_LINE, "fusion.1", 0, 2 * ms),
+        # a host plane is ignored
+        ("/host:CPU", "python", "sleep", 0, 100 * ms),
+    ]
+    r = reduce_events(ev)
+    expect("chips", r["chips"] == 2)
+    expect("window", near(r["window_s"], 0.010))
+    c0, c1 = r["per_chip"][T0], r["per_chip"][T1]
+    expect("busy chip 0 (union, not sum)", near(c0["busy_s"], 0.008))
+    expect("busy chip 1", near(c1["busy_s"], 0.002))
+    expect("busy mean", near(r["busy_s"], 0.005))
+    expect("worst chip", near(r["busy_s_min"], 0.002))
+    expect("collective", near(c0["collective_s"], 0.004))
+    expect("exposed collective: only the one with no compute beside it",
+           near(c0["collective_exposed_s"], 0.002))
+    expect("container op not in the op sums",
+           all(not k.startswith("while") for k, _ in r["device_ops"]))
+    expect("ops of one kind and shape share a label",
+           ["fusion f32[8,4]", 0.003 / 2] in
+           [[k, round(v, 12)] for k, v in r["device_ops"]])
+    expect("label of a bare name", op_label("fusion.3") == "fusion")
+    expect("module mean over chips",
+           near(r["modules"]["jit_round"]["seconds"], 0.003)
+           and near(r["modules"]["jit_round"]["count"], 1.0))
+    expect("gap label", ["jit_round -> jit_prefill", 0.002 / 2]
+           in [[k, round(v, 12)] for k, v in r["idle_gaps"]])
+    expect("chip 1's tail is a gap", any(
+        k == "jit_round -> end" and near(v, 0.008 / 2)
+        for k, v in r["idle_gaps"]))
+    empty = reduce_events([("/host:CPU", "python", "sleep", 0, ms)])
+    expect("empty device", empty["chips"] == 0 and empty["busy_s"] == 0.0)
+    for name in bad:
+        print(f"trace_reduce selftest FAILED: {name}")
+    print("trace_reduce selftest:", "FAILED" if bad else "ok")
+    return 1 if bad else 0
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--selftest", action="store_true")
+    ap.add_argument("--reduce", metavar="TRACE_DIR")
+    ap.add_argument("--out", metavar="JSON")
+    ap.add_argument("--dump", action="store_true",
+                    help="also list planes, lines and the commonest names")
+    args = ap.parse_args()
+    if args.selftest:
+        return selftest()
+    if not args.reduce:
+        ap.error("--selftest or --reduce")
+    events = read_xplane(find_xplane(args.reduce))
+    result = reduce_events(events)
+    if args.dump:
+        from jax.profiler import ProfileData
+
+        data = ProfileData.from_file(find_xplane(args.reduce))
+        result["planes"] = [
+            [pl.name, ln.name, sum(1 for _ in ln.events)]
+            for pl in data.planes for ln in pl.lines]
+        seen: dict = {}
+        for plane, line, name, _, dur in events:
+            k = (plane, line, name)
+            c = seen.setdefault(k, [0, 0])
+            c[0] += 1
+            c[1] += dur
+        result["dump"] = [[*k, c, d / 1e9] for k, (c, d) in sorted(
+            seen.items(), key=lambda kv: -kv[1][1])[:80]]
+    with open(args.out, "w") as f:
+        json.dump(result, f)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
