@@ -184,6 +184,10 @@ class _Request:
     itl_gaps: list[tuple] = field(default_factory=list)
     t_prefill_start: Optional[float] = None
     t_last_emit: Optional[float] = None
+    # first_token span attrs: prefill dispatches this request took part
+    # in, and decode rounds in flight when its last one was dispatched
+    prefill_chunks: int = 0
+    rounds_at_dispatch: int = 0
     decode_rounds: int = 0
     # speculative decoding (spec/): a speculating slot's device lane
     # stays PARKED (dest=scratch) — its real state lives here on the
@@ -508,6 +512,14 @@ class TpuEngine:
         self._h_e2e = self.telemetry.get(tmetrics.E2E[0])
         self._h_queue = self.telemetry.get(tmetrics.QUEUE[0])
         self._h_round = self.telemetry.get(tmetrics.ROUND[0])
+        self._h_first_token = self.telemetry.get(tmetrics.FIRST_TOKEN[0])
+        self._h_frontend = self.telemetry.get(tmetrics.FRONTEND[0])
+        self._h_pf_tokens = self.telemetry.get(tmetrics.PREFILL_TOKENS[0])
+        self._h_pf_padded = self.telemetry.get(tmetrics.PREFILL_PADDED[0])
+        self._h_pf_matched = self.telemetry.get(tmetrics.PREFILL_MATCHED[0])
+        self._h_live_steps = self.telemetry.get(
+            tmetrics.ROUND_LIVE_LANE_STEPS[0])
+        self._h_round_tokens = self.telemetry.get(tmetrics.ROUND_TOKENS[0])
         # histogram snapshots are built per metrics() call, which the
         # engine loop makes EVERY round via on_metrics while the
         # publisher throttles to ~4 Hz — cache at the publish cadence so
@@ -1072,6 +1084,14 @@ class TpuEngine:
             tokens=list(request.token_ids),
             trace_detail="trace_detail" in (request.annotations or []),
         )
+        if request.received_unix is not None:
+            # everything before the engine, on the unix clock the stamp
+            # crossed processes on (the deadline's convention)
+            pre_engine = max(0.0, time.time() - request.received_unix)
+            self._h_frontend.observe(
+                pre_engine, exemplar_id=request.request_id or None)
+            r.trace_spans.append(Span(
+                "frontend", request.received_unix, pre_engine).to_dict())
         if self.remote_kv is not None and self.offload is not None:
             await self._remote_prefetch(r)
         r.counted = True
@@ -1853,6 +1873,8 @@ class TpuEngine:
                 and not self._prefilling and self._intake.empty()
                 and all(s is None for s in self._slots)):
             self._drained_evt.set()
+        if not did_work:
+            prof.mark_fed()  # nothing live: idle, not starved
         prof.end_round(record=did_work)
         return did_work
 
@@ -2280,13 +2302,14 @@ class TpuEngine:
                     TENANT.inc("dynamo_tenant_adapter_rounds_total",
                                r.tenant)
         self.step_count += n
+        self._h_live_steps.observe(len(active) * n)
         stacked.copy_to_host_async()
         self.dispatch_counts["fetch"] += 1
         if lp_stacked is not None:
             # packed: ONE extra fetch pipeline, not three
             lp_stacked.copy_to_host_async()
             self.dispatch_counts["fetch"] += 1
-        self._entries.append(
+        self._track(
             _Entry(
                 kind="round",
                 t_dispatch=t_disp,
@@ -2463,7 +2486,7 @@ class TpuEngine:
         for slot, r, _, _ in rows:
             r.spec_ready = False
             r.spec_inflight = True
-        self._entries.append(_Entry(
+        self._track(_Entry(
             kind="spec", handle=out_toks, rows=rows,
             aux=(n_out, new_keys), n_steps=K, t_dispatch=t_disp,
             spec_host=(t_draft_end - t_disp, t_verify_end - t_draft_end),
@@ -2604,7 +2627,7 @@ class TpuEngine:
         for slot, r, *_ in rows:
             r.spec_ready = False
             r.spec_inflight = True
-        self._entries.append(_Entry(
+        self._track(_Entry(
             kind="spec_tree", handle=packed, rows=rows,
             aux=(M, parents, nodes_used), n_steps=K, t_dispatch=t_disp,
             spec_host=(t_draft_end - t_disp, t_verify_end - t_draft_end),
@@ -3070,7 +3093,7 @@ class TpuEngine:
             "g2_offload", pages=len(batch),
             dispatch_ms=round((time.monotonic() - t_disp) * 1e3, 3),
         )
-        self._entries.append(_Entry(
+        self._track(_Entry(
             kind="offload", handle=out, n_steps=len(batch),
             hashes=[h for _, h, _ in batch],
             parents=[par for _, _, par in batch],
@@ -3473,6 +3496,8 @@ class TpuEngine:
             })
         t_disp = time.monotonic()
         self.dispatch_counts["prefill_batch"] += 1
+        self._h_pf_tokens.observe(sum(chunk_lens))
+        self._h_pf_padded.observe(K * width)
         self.ctx, logits = llama.batch_prefill(
             self.config, self.params, self.ctx, jnp.asarray(toks),
             jnp.asarray(slots), jnp.asarray(q_starts),
@@ -3484,6 +3509,7 @@ class TpuEngine:
         )
         done: list[_Request] = []
         for i, r in enumerate(group):
+            r.prefill_chunks += 1
             r.prefill_pos = int(q_starts[i]) + chunk_lens[i]
             if r.prefill_pos < len(r.tokens):
                 self._seal_prefilled(r)  # mid-prompt blocks seal per chunk
@@ -3567,6 +3593,7 @@ class TpuEngine:
                 len(matched_pages), max_blocks,
             )
         r.matched_blocks = len(usable_pages)
+        self._h_pf_matched.observe(len(usable_pages) * ps)
         if usable_pages:
             w = pow2_cover(len(usable_pages))
             padded = np.zeros(w, np.int32)  # padding -> scratch page 0
@@ -3660,6 +3687,9 @@ class TpuEngine:
             })
         t_disp = time.monotonic()
         self.dispatch_counts["prefill"] += 1
+        self._h_pf_tokens.observe(len(chunk))
+        self._h_pf_padded.observe(pad_t)
+        r.prefill_chunks += 1
         self.ctx, logits = llama.prefill(
             self.config, self.params, self.ctx,
             jnp.asarray(toks), jnp.int32(r.slot),
@@ -3707,6 +3737,9 @@ class TpuEngine:
             })
         t_disp = time.monotonic()
         self.dispatch_counts["sp_prefill"] += 1
+        self._h_pf_tokens.observe(len(prompt))
+        self._h_pf_padded.observe(len(toks))
+        r.prefill_chunks += 1
         kv, logits = llama.sp_prefill(
             self.config, self.params,
             sp_shard(jnp.asarray(toks), self.mesh),
@@ -3726,8 +3759,15 @@ class TpuEngine:
         """Shared prefill tail: commit prompt blocks, sample the first
         token on device, activate the slot. `index` is the request's row
         when `logits` was sliced from a batched prefill — broadcast so
-        followers slice their own replayed [K, V] logits identically."""
+        followers slice their own replayed [K, V] logits identically.
+
+        The ``prefill`` span recorded here ends when the last prefill
+        program was DISPATCHED: it is host dispatch time, not device
+        work. ``first_token`` (_note_first_token), whose child it
+        becomes, ends where the work does."""
         prompt = r.tokens
+        r.rounds_at_dispatch = sum(
+            1 for en in self._entries if en.kind == "round")
         if r.t_prefill_start is not None:
             r.trace_spans.append(_span_dict(
                 "prefill", r.t_prefill_start,
@@ -3821,12 +3861,17 @@ class TpuEngine:
         if first_lp is not None:
             first_lp.copy_to_host_async()  # packed: one fetch
             self.dispatch_counts["fetch"] += 1
-        self._entries.append(_Entry(
+        self._track(_Entry(
             kind="first", handle=first_tok, request=r, lp_handle=first_lp
         ))
         return "done"
 
     # ---- processing side (lagged results) ----
+
+    def _track(self, entry: _Entry) -> None:
+        """Every in-flight fetch is tracked here: the device has work."""
+        self._entries.append(entry)
+        self.prof.mark_fed()
 
     def _process_entries(self, block: bool = False) -> None:
         # first-token / offload entries are independent of round ordering
@@ -3835,6 +3880,8 @@ class TpuEngine:
         # process them as soon as their fetch lands instead of behind up
         # to max_inflight_rounds stacked round fetches. This is the TTFT
         # lever: the first token no longer waits out the decode pipeline.
+        if not self._entries:
+            return
         remaining = []
         for entry in self._entries:
             if entry.kind != "round" and entry.handle.is_ready():
@@ -3849,6 +3896,11 @@ class TpuEngine:
             self._entries.pop(0)
             self._consume_entry(entry)
             block = False  # only force at most one blocking wait
+        # the last tracked fetch is consumed: if requests are still live
+        # the device has nothing queued until the next dispatch
+        if (self._waiting or self._prefilling
+                or self._slot_active.any() or self._slot_spec.any()):
+            self.prof.mark_starved()
 
     def _unpack_lp(self, packed: np.ndarray):
         """Split one packed logprob row/stack [..., 1+2K] back into
@@ -3905,6 +3957,8 @@ class TpuEngine:
             ttft = r.first_token_time - r.enqueue_time
             self._h_ttft.observe(ttft,
                                  exemplar_id=r.req.request_id or None)
+            if r.t_prefill_start is not None:
+                self._note_first_token(r)
             TENANT.observe("dynamo_tenant_request_ttft_seconds",
                            r.tenant, ttft,
                            exemplar_id=r.req.request_id or None)
@@ -3924,6 +3978,25 @@ class TpuEngine:
             r.spec_tokens = list(r.tokens) + [tok]
             r.spec_ready = True
 
+    def _note_first_token(self, r: _Request) -> None:
+        """The ``first_token`` phase: ``queue`` ended when the request got
+        its lane (t_prefill_start), this one ends HERE, with the fetched
+        token on the host -- so engine TTFT = queue + first_token exactly.
+        The dispatch-only ``prefill`` span becomes its child."""
+        rid = r.req.request_id or None
+        dur = r.first_token_time - r.t_prefill_start
+        self._h_first_token.observe(dur, exemplar_id=rid)
+        sp = Span("first_token", time.time() - dur, dur, attrs=dict(
+            request_id=rid, prompt_tokens=len(r.tokens),
+            chunks=r.prefill_chunks,
+            rounds_in_flight_at_dispatch=r.rounds_at_dispatch,
+        )).to_dict()
+        for i in range(len(r.trace_spans) - 1, -1, -1):
+            if r.trace_spans[i]["name"] == "prefill":
+                sp["children"] = [r.trace_spans.pop(i)]
+                break
+        r.trace_spans.append(sp)
+
     def _process_round(self, entry: _Entry, toks: np.ndarray) -> None:
         """Consume one round's stacked tokens. Emission is BATCHED per
         request per round (tokens of a round arrive together in one fetch;
@@ -3933,6 +4006,7 @@ class TpuEngine:
         lp_arrs = None
         if entry.lp_handle is not None:
             lp_arrs = self._unpack_lp(np.asarray(entry.lp_handle))
+        delivered = 0
         for slot, r in enumerate(entry.slots):
             # identity check doubles as the epoch: a recycled slot holds
             # a different _Request object than the snapshot
@@ -3963,6 +4037,7 @@ class TpuEngine:
                 if finish is not None:
                     break
             if batch:
+                delivered += len(batch)
                 self._note_emit(r, len(batch), entry, "decode_round")
             if batch or finish is not None:
                 extra = {}
@@ -3978,6 +4053,7 @@ class TpuEngine:
                 continue
             if r.spec_gated or r.spec_rearm_wait > 0:
                 self._spec_gated_advance(slot, r, batch)
+        self._h_round_tokens.observe(delivered)
         self.tokens_generated += int(
             sum(1 for s in entry.slots if s is not None) * entry.n_steps
         )
@@ -4142,5 +4218,6 @@ class TpuEngine:
         self._waiting = []
         self._prefilling = {}
         self._entries = []
+        self.prof.mark_fed()  # nothing live: idle, not starved
         self._seal_queue = []
 

@@ -594,6 +594,7 @@ class HttpService:
                 pre = chain.preprocess(chat_req)
             except ValueError as e:
                 raise _ApiError(400, str(e))
+            pre.received_unix = env["t0_unix"]
 
             rid = make_id("resp")
             self.metrics.inflight.labels(rreq.model).inc()
@@ -647,7 +648,7 @@ class HttpService:
         accounting (requests_total/duration), 499 on cancellation.
         `fn(body, env)` does the endpoint-specific work and sets
         env["model"] as soon as it is known."""
-        env = {"model": "", "t0": time.monotonic()}
+        env = {"model": "", "t0": time.monotonic(), "t0_unix": time.time()}
         status = "500"
         t0 = env["t0"]
         try:
@@ -787,6 +788,7 @@ class HttpService:
                 pre = chain.preprocess(req)
             except ValueError as e:
                 raise _ApiError(400, str(e))
+            pre.received_unix = env["t0_unix"]
             # trace context: minted here, keyed by the engine-facing
             # request id (it travels through the runtime protocol to the
             # router and worker; their spans come back via output

@@ -85,6 +85,12 @@ class PreprocessedRequest:
     # skips workers whose queue can't meet it.
     priority: int = 0
     deadline: Optional[float] = None
+    # Unix time the HTTP handler was entered (same convention as the
+    # deadline: absolute, so it survives the hop to a worker process).
+    # The engine observes now - received_unix as the request's
+    # pre-engine time; None (direct engine use, old callers) observes
+    # nothing.
+    received_unix: Optional[float] = None
     # Router annotation: expected prefix-cache hit depth for this worker
     # (reference kv_router.rs estimated_prefix_hit_num_blocks).
     estimated_prefix_hit_num_blocks: Optional[int] = None

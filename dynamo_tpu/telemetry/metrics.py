@@ -356,16 +356,48 @@ QUEUE = ("dynamo_request_queue_seconds",
          "admission queue wait (enqueue to prefill start)")
 ROUND = ("dynamo_engine_round_seconds",
          "engine round wall time (dispatch to result processed)")
+# request phases closed where the work ENDS, so that engine TTFT =
+# queue + first_token by construction (engine._process_first)
+FIRST_TOKEN = ("dynamo_request_first_token_seconds",
+               "first lane given (queue end) to first token fetched on "
+               "the host: prefill device time plus the wait behind "
+               "programs already in flight")
+FRONTEND = ("dynamo_request_frontend_seconds",
+            "HTTP handler entry to engine intake: parse, templating, "
+            "tokenizing, routing and transport (requests stamped "
+            "with received_unix only)")
+# work and waste, one observation per dispatch / consumed round: the
+# sum is the quantity, the count the dispatches (or requests, rounds)
+PREFILL_TOKENS = ("dynamo_engine_prefill_tokens",
+                  "real prompt tokens computed per prefill dispatch")
+PREFILL_PADDED = ("dynamo_engine_prefill_padded_tokens",
+                  "token positions a prefill dispatch ran: lanes of the "
+                  "compiled group (dummies included) x bucket width")
+PREFILL_MATCHED = ("dynamo_engine_prefill_matched_tokens",
+                   "prompt tokens served from a prefix match (HBM or "
+                   "host tier) per request starting its prefill")
+ROUND_LIVE_LANE_STEPS = ("dynamo_engine_round_live_lane_steps",
+                         "lanes live at dispatch x steps per fused "
+                         "decode round")
+ROUND_TOKENS = ("dynamo_engine_round_tokens",
+                "tokens a consumed decode round delivered to streams")
+
+# token-count series: powers of two up to a full 32k-position dispatch
+TOKEN_BUCKETS = tuple(float(2 ** i) for i in range(16))
 
 
 def request_histograms(
     reg: TelemetryRegistry, *, engine: bool = False
 ) -> TelemetryRegistry:
     """Install the canonical request series on ``reg``. ``engine=True``
-    adds the engine-only series (queue wait, round time)."""
+    adds the engine-only series (queue wait, round time, the request
+    phases and the work-and-waste token counts)."""
     for name, help_ in (TTFT, ITL, E2E):
         reg.histogram(name, help_)
     if engine:
-        for name, help_ in (QUEUE, ROUND):
+        for name, help_ in (QUEUE, ROUND, FIRST_TOKEN, FRONTEND):
             reg.histogram(name, help_)
+        for name, help_ in (PREFILL_TOKENS, PREFILL_PADDED, PREFILL_MATCHED,
+                            ROUND_LIVE_LANE_STEPS, ROUND_TOKENS):
+            reg.histogram(name, help_, TOKEN_BUCKETS)
     return reg

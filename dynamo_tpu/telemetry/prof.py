@@ -17,6 +17,16 @@ registry at the engine's metrics-publish cadence (~10 Hz), which renders
 gauge, and the SLO burn-rate gauges on all three scrape surfaces (same
 pattern as the RESILIENCE / KV_TRANSFER plane registries). ``/debug/prof``
 serves the live top-segment summary.
+
+Two things ride the same switches. STARVED time: between
+``mark_starved()`` (the engine consumed its last tracked fetch while
+requests were live, so the device has nothing queued) and ``mark_fed()``
+(a fetch is tracked again), every slice is also charged to
+``starved[segment]`` -- which host segment ran while the device had
+nothing to do. And, while a ``jax.profiler`` session is on, each segment
+is a ``TraceAnnotation("host/<segment>")`` on the profiler's own clock,
+so a device trace's idle gaps can be labelled by the host segment that
+covers them (tools/trace_gaps.py).
 """
 from __future__ import annotations
 
@@ -52,6 +62,8 @@ SEGMENTS = (
 _SEG_INDEX = {s: i for i, s in enumerate(SEGMENTS)}
 _N_SEG = len(SEGMENTS)
 _OTHER = _SEG_INDEX["other"]
+ANNOTATION_PREFIX = "host/"
+_ANN_NAMES = tuple(ANNOTATION_PREFIX + s for s in SEGMENTS)
 
 # host segments run at µs scale — DEFAULT_TIME_BUCKETS' 0.5 ms floor
 # would flatten the whole distribution into one bucket. Same ~1.6x step
@@ -87,6 +99,16 @@ class RoundProf:
     across a read is acceptable for a profiler. ``enabled=False`` turns
     every method into an early-out so `prof_attribution=false` engines
     pay one attribute load + branch per call site.
+
+    Starved time is an estimate from the host's side, biased both ways
+    by a few hundred microseconds per event: patches and standalone seals
+    are dispatched without a tracked entry (device work the flag cannot
+    see: counts high), and a prefill is dispatched a little before its
+    ``first`` entry is tracked (the flag clears late: counts high), while
+    a tracked fetch whose program already finished still reads as fed
+    (counts low). Rounds that are not recorded (the idle spin) drop
+    their starved slice: an engine with no requests is idle, not starved.
+    Calibrated against the device trace's idle share in PERF.md.
     """
 
     RING = 256  # recent per-round records kept for /debug/prof + timeline
@@ -94,6 +116,21 @@ class RoundProf:
     def __init__(self, enabled: bool = True):
         self.enabled = enabled
         self._acc = [0.0] * _N_SEG     # current round, per segment
+        # the part of _acc that ran while the device was starved
+        self._starved_acc = [0.0] * _N_SEG
+        self._starved_dirty = False    # _starved_acc holds something
+        self._starved = False
+        self.starved_total = np.zeros(_N_SEG)
+        # profiler annotations: a TraceMe takes its start time when it is
+        # CONSTRUCTED, so one is made per switch, and only while a
+        # profiler session is on (asked once per round)
+        self._annotation = None
+        self._tracing = False
+        self._ann_open = None
+        if enabled:
+            from jax.profiler import TraceAnnotation
+
+            self._annotation = TraceAnnotation
         self._seg = _OTHER
         self._t = 0.0
         self._t_begin = 0.0
@@ -122,20 +159,54 @@ class RoundProf:
             return
         t = time.monotonic()
         self._acc = [0.0] * _N_SEG
+        if self._starved_dirty:
+            self._starved_acc = [0.0] * _N_SEG
+            self._starved_dirty = False
         self._seg = _OTHER
         self._t = t
         self._t_begin = t
         self._in_round = True
+        self._tracing = self._annotation.is_enabled()
+
+    def _charge(self) -> None:
+        """Charge time since the last switch to the current segment."""
+        t = time.monotonic()
+        dt = t - self._t
+        self._acc[self._seg] += dt
+        if self._starved:
+            self._starved_acc[self._seg] += dt
+            self._starved_dirty = True
+        self._t = t
 
     def enter(self, seg: int) -> None:
         """Charge time since the last switch to the PREVIOUS segment and
         make ``seg`` (an index into SEGMENTS) current."""
         if not self.enabled or not self._in_round:
             return
-        t = time.monotonic()
-        self._acc[self._seg] += t - self._t
-        self._t = t
+        self._charge()
         self._seg = seg
+        if self._ann_open is not None:
+            self._ann_open.__exit__(None, None, None)
+            self._ann_open = None
+        if self._tracing:
+            self._ann_open = self._annotation(_ANN_NAMES[seg])
+
+    def mark_starved(self) -> None:
+        """The device has nothing queued although requests are live:
+        from here on slices are also charged to ``starved[segment]``."""
+        if not self.enabled or self._starved:
+            return
+        if self._in_round:
+            self._charge()
+        self._starved = True
+
+    def mark_fed(self) -> None:
+        """A fetch is tracked again: the device has work."""
+        if not self._starved:
+            return
+        if self._in_round:
+            self._charge()
+        self._starved = False
 
     def push(self, seg: int) -> int:
         """Nested attribution (e.g. annotation build inside the fetch
@@ -147,10 +218,15 @@ class RoundProf:
     def end_round(self, record: bool = True) -> None:
         if not self.enabled or not self._in_round:
             return
-        self.enter(_OTHER)  # close the open segment
+        self._charge()  # close the open segment
+        if self._ann_open is not None:
+            self._ann_open.__exit__(None, None, None)
+            self._ann_open = None
         self._in_round = False
         if not record:
             return  # idle spin — keep µs no-op rounds out of the stats
+        if self._starved_dirty:
+            self.starved_total += self._starved_acc
         wall = self._t - self._t_begin
         row = self._rec_n % self.RING
         self._ring_acc[row] = self._acc
@@ -205,6 +281,13 @@ class RoundProf:
             "segments": {
                 s: float(self.total[i]) for i, s in enumerate(SEGMENTS)
             },
+            "starved": {
+                "total_s": float(self.starved_total.sum()),
+                "segments": {
+                    s: float(self.starved_total[i])
+                    for i, s in enumerate(SEGMENTS)
+                },
+            },
         }
 
     def coverage(self) -> float:
@@ -243,6 +326,18 @@ class RoundProf:
                 r_wall / n_recent * 1e3, 4) if n_recent else 0.0,
             "coverage_ratio": round(self.coverage(), 4),
             "segments": rows,
+            # device-starved host time (live requests, nothing tracked
+            # in flight) by the segment that was running, hottest first
+            "starved": {
+                "total_s": round(totals["starved"]["total_s"], 6),
+                "share": round(totals["starved"]["total_s"] / wall, 4)
+                if wall > 0 else 0.0,
+                "segments": {
+                    s: round(v, 6) for s, v in sorted(
+                        totals["starved"]["segments"].items(),
+                        key=lambda kv: -kv[1]) if v > 0.0
+                },
+            },
         }
 
 
@@ -395,6 +490,7 @@ PROF = ProfRegistry()
 
 __all__ = [
     "SEGMENTS",
+    "ANNOTATION_PREFIX",
     "HOST_BUCKETS",
     "HOST_ROUND",
     "COVERAGE",

@@ -187,8 +187,11 @@ async def test_disagg_remote_prefill_spans_ride_finishing_output(setup):
         rp = next(s for s in spans if s["name"] == "remote_prefill")
         assert rp["attrs"]["tokens"] == len(prompt)
         assert rp["attrs"]["blocks"] >= 3
-        # the engine's own queue/prefill spans are still there
-        assert "prefill" in names
+        # the engine's own queue/prefill spans are still there (prefill
+        # as the child of the first_token span that ends at the fetch)
+        ft = next(s for s in spans if s["name"] == "first_token")
+        assert "queue" in names
+        assert [c["name"] for c in ft["children"]] == ["prefill"]
     finally:
         await pworker.stop()
         await srv.stop()

@@ -2,8 +2,8 @@
 
 Pins the tentpole invariants: the flat switch model attributes host
 round time with self-coverage ~1.0 by construction; the always-on
-instrumentation costs within 5% of the disabled engine's steady-decode
-wall; the SLO burn-rate math interpolates histogram CDFs correctly; the
+instrumentation costs <= 20 µs a host-loop round and <= 5 µs a dispatch
+(a per-call microbench, not a wall-clock A/B of two engines); the SLO burn-rate math interpolates histogram CDFs correctly; the
 ``--dispatch-budget`` tool emits a ``host_breakdown`` keyed by the full
 segment enum; and the timeline exporter turns a real disagg request
 (span tree + host rounds + kv_transfer stream events) into parseable
@@ -280,22 +280,70 @@ async def _steady_round_wall_ms(eng, repeats=2) -> float:
     return best
 
 
+def _best_us(fn, calls: int = 200, repeats: int = 30) -> float:
+    """Best mean cost (µs) of ``fn`` over ``repeats`` batches: the least
+    disturbed batch is the measurement, whatever else the box runs."""
+    best = float("inf")
+    for _ in range(repeats):
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            fn()
+        best = min(best, (time.perf_counter() - t0) / calls)
+    return best * 1e6
+
+
 async def test_attribution_overhead_within_5pct():
-    """The always-on claim: attribution ON vs OFF steady-decode
-    per-round wall within 5% (plus a small absolute allowance for
-    shared-CI scheduling noise — the instrumentation itself is ~15
-    monotonic() calls, single-digit µs, per round)."""
-    walls = {}
-    for mode in (True, False):
-        eng = _engine(prof_attribution=mode)
-        eng.start()
-        walls[mode] = await _steady_round_wall_ms(eng)
-        await eng.stop()
-    assert walls[True] is not None and walls[False] is not None
-    assert walls[True] <= walls[False] * 1.05 + 0.3, walls
+    """The always-on claim, per call: one host-loop round of the
+    attribution plane (begin, 15 segment switches, end; the annotation
+    branch and the starved accounting included) costs <= 20 µs, and what
+    a dispatch adds (mark_fed + the two prefill token observes, the most
+    any dispatch site makes) <= 5 µs. A wall-clock A/B of two engines
+    under parallel test workers is not a measurement; this is, and the
+    absolute steady-decode pin on a real engine stays."""
+    from dynamo_tpu.telemetry import TelemetryRegistry, request_histograms
+
+    p = RoundProf()
+    n_seg = len(SEGMENTS) - 1
+
+    def one_round():
+        p.begin_round()
+        for i in range(15):
+            p.enter(i % n_seg)
+        p.end_round()
+
+    fed = _best_us(one_round)
+    p.mark_starved()                      # the dearer branch of _charge
+    starved = _best_us(one_round)
+    assert max(fed, starved) <= 20.0, (fed, starved)
+
+    reg = request_histograms(TelemetryRegistry(), engine=True)
+    real = reg.get("dynamo_engine_prefill_tokens")
+    padded = reg.get("dynamo_engine_prefill_padded_tokens")
+
+    def one_dispatch():
+        real.observe(319)
+        padded.observe(512)
+        p.mark_fed()
+
+    assert _best_us(one_dispatch) <= 5.0
+
+    off = RoundProf(enabled=False)
+
+    def one_round_off():
+        off.begin_round()
+        for i in range(15):
+            off.enter(i % n_seg)
+        off.mark_starved()
+        off.end_round()
+
+    assert _best_us(one_round_off) <= min(fed, 5.0)   # early-outs only
     # steady-decode host budget pin: the generous tiny-harness ceiling
     # (typical ~1-5 ms/round on CPU; regressions land well above)
-    assert walls[True] <= 50.0, walls
+    eng = _engine(prof_attribution=True)
+    eng.start()
+    wall = await _steady_round_wall_ms(eng)
+    await eng.stop()
+    assert wall is not None and wall <= 50.0, wall
 
 
 def test_disabled_engine_records_nothing():

@@ -99,6 +99,13 @@ def test_registry_render_and_snapshot():
         "dynamo_request_ttft_seconds", "dynamo_request_itl_seconds",
         "dynamo_request_e2e_seconds", "dynamo_request_queue_seconds",
         "dynamo_engine_round_seconds",
+        "dynamo_request_first_token_seconds",
+        "dynamo_request_frontend_seconds",
+        "dynamo_engine_prefill_tokens",
+        "dynamo_engine_prefill_padded_tokens",
+        "dynamo_engine_prefill_matched_tokens",
+        "dynamo_engine_round_live_lane_steps",
+        "dynamo_engine_round_tokens",
     }
     reg.get("dynamo_request_ttft_seconds").observe(0.2)
     text = reg.render()
@@ -372,9 +379,12 @@ async def test_frontend_span_tree_and_histograms(tiny_routed_manager):
         tree = await tr.json()
         assert tree["trace_id"] == rid and tree["finished"]
         names = [s["name"] for s in tree["spans"]]
-        for expected in ("tokenize", "route", "queue", "prefill",
+        for expected in ("tokenize", "route", "queue", "first_token",
                          "decode_round"):
             assert expected in names, (expected, names)
+        # the dispatch-only prefill span hangs under first_token
+        ft = next(s for s in tree["spans"] if s["name"] == "first_token")
+        assert [c["name"] for c in ft["children"]] == ["prefill"]
         route = next(s for s in tree["spans"] if s["name"] == "route")
         assert "overlap_blocks" in route["attrs"]
     idx = await client.get("/debug/trace")
@@ -424,7 +434,7 @@ async def test_frontend_unary_trace_and_ttft(tiny_routed_manager):
     tr = await client.get(f"/debug/trace/{rid}")
     assert tr.status == 200
     names = [s["name"] for s in (await tr.json())["spans"]]
-    assert "tokenize" in names and "prefill" in names
+    assert "tokenize" in names and "first_token" in names
     mtext = await (await client.get("/metrics")).text()
     assert "dynamo_request_ttft_seconds_count 1" in mtext
     await client.close()
