@@ -323,6 +323,27 @@ def command_loop(engine, watch, stop_evt: threading.Event, loop) -> None:
     loop.call_soon_threadsafe(stop_evt.set)
 
 
+class EveryTokenSpeaks:
+    """The offline test tokenizer with its three special ids decoded as
+    text like every other id; ``decode`` is all the backend reads.
+    ``backend.py`` yields nothing for an engine output that decodes to no
+    text and the frontend's usage then never counts its tokens; random
+    weights decoding greedily can settle on a special id, whole rounds of
+    it. Seen on the chip (PR 27, seed 2700270011, twice):
+    ``usage.completion_tokens`` 54 of 58, 14 chunks of 16, ``correct``
+    false. With this every emission is a chunk with text, so the client's
+    clock sees each one and usage counts each token. The program's fault
+    is not hidden by it: ``loadgen.send`` fails a request whose usage is
+    short by even one token, so a launcher without this wrapper reads
+    ``correct`` false on such a seed again."""
+
+    def __init__(self, tok):
+        self._tok = tok
+
+    def decode(self, ids, skip_special_tokens: bool = True) -> str:
+        return self._tok.decode(ids, skip_special_tokens=False)
+
+
 async def serve(args) -> int:
     from dynamo_tpu.backend import Backend
     from dynamo_tpu.frontend import HttpService, ModelManager
@@ -341,7 +362,7 @@ async def serve(args) -> int:
 
     tok = make_test_tokenizer()
     chain = ModelChain(
-        name="bench", engine=engine, backend=Backend(tok),
+        name="bench", engine=engine, backend=Backend(EveryTokenSpeaks(tok)),
         preprocessor=OpenAIPreprocessor(
             tokenizer=tok, formatter=PromptFormatter(), model_name="bench"))
     manager = ModelManager()

@@ -6,8 +6,8 @@ A request's record (times in seconds relative to the window's start):
   chunks  arrival time of every streamed chunk that carried text
   tokens  usage.completion_tokens (None if the stream never said)
   asked   max_tokens asked for (ignore_eos: the stream must deliver it)
-  ok      HTTP 200, finish_reason "length", usage tokens == asked (or one
-          short: loadgen.USAGE_SHORTFALL_OK), no in-band error
+  ok      HTTP 200, finish_reason "length", usage tokens == asked, no
+          in-band error
 Requests with 0 <= due < seconds are "of the window": they make
 ``attempted``/``failed`` and the latency metrics. Pre-roll requests
 (due < 0) only add the tokens that reach the client inside the window.
