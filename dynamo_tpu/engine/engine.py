@@ -72,7 +72,10 @@ from dynamo_tpu.kv_router.protocols import (
 )
 from dynamo_tpu.models import llama
 from dynamo_tpu.models.config import ModelConfig
-from dynamo_tpu.ops.attention import decode_attention_for
+from dynamo_tpu.ops.attention import (
+    decode_attention_for,
+    prefill_attention_pairs,
+)
 from dynamo_tpu.overload import (
     OVERLOAD,
     PRIORITY_HIGH,
@@ -517,6 +520,9 @@ class TpuEngine:
         self._h_pf_tokens = self.telemetry.get(tmetrics.PREFILL_TOKENS[0])
         self._h_pf_padded = self.telemetry.get(tmetrics.PREFILL_PADDED[0])
         self._h_pf_matched = self.telemetry.get(tmetrics.PREFILL_MATCHED[0])
+        self._h_pf_live = self.telemetry.get(tmetrics.PREFILL_ATTN_LIVE[0])
+        self._h_pf_scored = self.telemetry.get(
+            tmetrics.PREFILL_ATTN_SCORED[0])
         self._h_live_steps = self.telemetry.get(
             tmetrics.ROUND_LIVE_LANE_STEPS[0])
         self._h_round_tokens = self.telemetry.get(tmetrics.ROUND_TOKENS[0])
@@ -3481,10 +3487,10 @@ class TpuEngine:
             seq_lens[i] = start + len(chunk)
             chunk_lens.append(len(chunk))
             adapter_ids[i] = r.adapter_id
-        # ctx_span is binary — 0 (fresh) or the FULL region: each distinct
-        # value is its own XLA compile of the whole prefill program, and
-        # the masked flash scan over dead context is a rounding error next
-        # to the parameter matmuls
+        # ctx_span is binary — 0 (fresh: no region read compiled) or the
+        # FULL region: each distinct value is its own XLA compile of the
+        # whole prefill program. The span only BOUNDS the read: the
+        # attention scans each lane's region blocks below its q_start
         ctx_span = e.max_context if int(q_starts.max()) > 0 else 0
         self.batch_prefills += 1
         if self.on_dispatch is not None:
@@ -3498,6 +3504,7 @@ class TpuEngine:
         self.dispatch_counts["prefill_batch"] += 1
         self._h_pf_tokens.observe(sum(chunk_lens))
         self._h_pf_padded.observe(K * width)
+        self._observe_attn_pairs(width, q_starts, seq_lens, ctx_span)
         self.ctx, logits = llama.batch_prefill(
             self.config, self.params, self.ctx, jnp.asarray(toks),
             jnp.asarray(slots), jnp.asarray(q_starts),
@@ -3517,6 +3524,16 @@ class TpuEngine:
             if self._finish_prefill(r, logits[i], index=i) == "done":
                 done.append(r)
         return done
+
+    def _observe_attn_pairs(self, width, q_starts, seq_lens,
+                            ctx_span) -> None:
+        """One observation per prefill dispatch of the (query, key) pairs
+        its attention had to score and the pairs it did score (whole
+        blocks) — the host's mirror of prefill_attention's loop bounds."""
+        live, scored = prefill_attention_pairs(
+            width, q_starts, seq_lens, ctx_span)
+        self._h_pf_live.observe(live)
+        self._h_pf_scored.observe(scored)
 
     def _free_slot(self) -> Optional[int]:
         for i, s in enumerate(self._slots):
@@ -3689,12 +3706,17 @@ class TpuEngine:
         self.dispatch_counts["prefill"] += 1
         self._h_pf_tokens.observe(len(chunk))
         self._h_pf_padded.observe(pad_t)
+        self._observe_attn_pairs(
+            pad_t, [start], [start + len(chunk)],
+            e.max_context if start else 0)
         r.prefill_chunks += 1
+        # a fresh prompt runs the program with no read of the region
         self.ctx, logits = llama.prefill(
             self.config, self.params, self.ctx,
             jnp.asarray(toks), jnp.int32(r.slot),
             jnp.int32(start), jnp.int32(start + len(chunk)),
             embeds, embeds_mask, jnp.int32(r.adapter_id),
+            fresh=start == 0,
         )
         self.flight.record(
             "prefill", slots=[r.slot], tokens=len(chunk), start=start,
@@ -3739,6 +3761,10 @@ class TpuEngine:
         self.dispatch_counts["sp_prefill"] += 1
         self._h_pf_tokens.observe(len(prompt))
         self._h_pf_padded.observe(len(toks))
+        # the ring path scores every pair of its padded prompt
+        n = len(prompt)
+        self._h_pf_live.observe(n * (n + 1) // 2)
+        self._h_pf_scored.observe(len(toks) ** 2)
         r.prefill_chunks += 1
         kv, logits = llama.sp_prefill(
             self.config, self.params,

@@ -284,6 +284,7 @@ class Follower:
                 jnp.int32(cmd["slot"]),
                 jnp.int32(cmd["start"]), jnp.int32(cmd["end"]),
                 None, None, jnp.int32(cmd.get("adapter", 0)),
+                fresh=int(cmd["start"]) == 0,
             )
         elif op == "prefill_batch":
             from dynamo_tpu.models import llama

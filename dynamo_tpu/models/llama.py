@@ -48,9 +48,9 @@ from dynamo_tpu.kv_quant import (
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.ops.attention import (
     DecodeAttention,
+    PriorContext,
     ctx_decode_attention,
-    ctx_prefill_attention,
-    flash_prefill_attention,
+    prefill_attention,
 )
 from dynamo_tpu.ops.rope import apply_rope, rope_cos_sin, rope_inv_freq
 
@@ -279,26 +279,16 @@ def _ctx_compute_dtype(config: ModelConfig, ctx_kv: Cache):
     return ctx_kv["k"].dtype
 
 
-def _ctx_slot_slab(ctx_kv: Cache, name: str, l: int, slot: jnp.ndarray,
-                   dtype, span: int = 0) -> jnp.ndarray:
-    """One slot's [kvh, S, hd] ctx slab in the compute dtype —
-    dequantizing on read when the region is int8 (prefill/score reads;
-    the decode hot path dequantizes inside the kernel instead)."""
-    slab = jax.lax.dynamic_index_in_dim(
-        ctx_kv[name][l], slot, axis=1, keepdims=False
-    )  # [kvh, S, hd]
-    if span > 0:
-        slab = slab[:, :span]
-    if not ctx_is_quantized(ctx_kv):
-        return slab
-    g = ctx_group_size(ctx_kv)
-    sc = jax.lax.dynamic_index_in_dim(
-        ctx_kv[name + "_scale"][l], slot, axis=0, keepdims=False
-    )  # [nG]
-    sc = jnp.repeat(sc, g)  # [S] per-position
-    if span > 0:
-        sc = sc[:span]
-    return (slab.astype(jnp.float32) * sc[None, :, None]).astype(dtype)
+def _prior_context(ctx_kv: Cache, l: int,
+                   slots: jnp.ndarray) -> PriorContext:
+    """Layer ``l`` of the region as the prior context of the chunks in
+    lanes ``slots`` — handed to ``prefill_attention`` whole, which reads
+    it block by block below each q_start (dequantizing an int8 region
+    per block); no per-lane slab is sliced out first."""
+    return PriorContext(
+        ctx_kv["k"], ctx_kv["v"], jnp.int32(l), slots,
+        ctx_kv.get("k_scale"), ctx_kv.get("v_scale"),
+    )
 
 
 def _quant_store_span(
@@ -584,6 +574,26 @@ def _ffn(c: ModelConfig, lp, x: jnp.ndarray, valid=None,
     return _mlp(x, lp["wg"], lp["wu"], lp["wd"], ad)
 
 
+def _layer_qkv(c: ModelConfig, lp, h, cos, sin, ad=None):
+    """First half of a decoder layer: norm, QKV projections, RoPE.
+    ``h`` is [N, H]; returns q [N, heads, hd], k and v [N, kvh, hd]."""
+    N = h.shape[0]
+    ad = ad or {}
+    x = rms_norm(h, lp["ln1"], c.rms_norm_eps)
+    q = _mm_ad(x, lp["wq"], ad.get("wq")).reshape(N, c.num_heads, c.head_dim)
+    k = _mm_ad(x, lp["wk"], ad.get("wk")).reshape(N, c.num_kv_heads, c.head_dim)
+    v = _mm_ad(x, lp["wv"], ad.get("wv")).reshape(N, c.num_kv_heads, c.head_dim)
+    return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
+
+
+def _layer_out(c: ModelConfig, lp, h, attn, ffn_valid=None, ad=None):
+    """Second half: output projection, residual, norm, FFN."""
+    ad = ad or {}
+    h = h + _mm_ad(attn.reshape(h.shape[0], c.q_dim), lp["wo"], ad.get("wo"))
+    x2 = rms_norm(h, lp["ln2"], c.rms_norm_eps)
+    return h + _ffn(c, lp, x2, ffn_valid, ad)
+
+
 def _layer_body(c: ModelConfig, lp, h, cos, sin, write_kv, attend,
                 ffn_valid=None, ad=None):
     """Shared decoder-layer body for prefill and decode.
@@ -594,20 +604,10 @@ def _layer_body(c: ModelConfig, lp, h, cos, sin, write_kv, attend,
     layer's adapter-factor slices (``_adapter_layer``) or None — the
     rank-r LoRA deltas fuse into the existing site matmuls.
     """
-    N = h.shape[0]
-    ad = ad or {}
-    x = rms_norm(h, lp["ln1"], c.rms_norm_eps)
-    q = _mm_ad(x, lp["wq"], ad.get("wq")).reshape(N, c.num_heads, c.head_dim)
-    k = _mm_ad(x, lp["wk"], ad.get("wk")).reshape(N, c.num_kv_heads, c.head_dim)
-    v = _mm_ad(x, lp["wv"], ad.get("wv")).reshape(N, c.num_kv_heads, c.head_dim)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    q, k, v = _layer_qkv(c, lp, h, cos, sin, ad)
     new_cache = write_kv(k, v)
     attn = attend(q, new_cache)
-    h = h + _mm_ad(attn.reshape(N, c.q_dim), lp["wo"], ad.get("wo"))
-    x2 = rms_norm(h, lp["ln2"], c.rms_norm_eps)
-    h = h + _ffn(c, lp, x2, ffn_valid, ad)
-    return h, new_cache
+    return _layer_out(c, lp, h, attn, ffn_valid, ad), new_cache
 
 
 def _logits(config: ModelConfig, params: Params, h: jnp.ndarray) -> jnp.ndarray:
@@ -643,6 +643,8 @@ def prefill_impl(
     adapter_id: Optional[jnp.ndarray] = None,   # scalar i32 — resident
                               # LoRA bank row (0 = identity base model);
                               # ignored when params carry no bank
+    fresh: bool = False,      # STATIC: the caller knows q_start == 0 —
+                              # no read of the region is compiled at all
 ) -> tuple[Cache, jnp.ndarray]:
     """Run T new tokens through the model, writing their KV into the
     slot's contiguous context region at [q_start, q_start+T).
@@ -672,10 +674,11 @@ def prefill_impl(
 
     # Layers are UNROLLED (python loop, static layer index). The region is
     # READ-ONLY during the layer stack: each layer's chunk KV is carried in
-    # values and attention takes it directly (ctx_prefill_attention); ALL
-    # writes land in one tail pass after the last read, so the donated
-    # update chain aliases in place (interleaved write/read of the GB-
-    # scale buffer would force XLA to materialize copies of it).
+    # values and attention takes it directly (prefill_attention, which
+    # reads the region only in blocks below q_start, and not at all when
+    # `fresh`); ALL writes land in one tail pass after the last read, so
+    # the donated update chain aliases in place (interleaved write/read of
+    # the GB-scale buffer would force XLA to materialize copies of it).
     ag = _gather_adapters(params.get("adapters"), adapter_id)
     new_ks: list[jnp.ndarray] = []
     new_vs: list[jnp.ndarray] = []
@@ -689,11 +692,12 @@ def prefill_impl(
 
         def attend(q, kv, l=l):
             k_new, v_new = kv
-            k_ctx = _ctx_slot_slab(ctx_kv, "k", l, slot, cdt)
-            v_ctx = _ctx_slot_slab(ctx_kv, "v", l, slot, cdt)
-            return ctx_prefill_attention(
-                q, k_ctx, v_ctx, k_new, v_new, q_start, seq_len
-            )
+            one = lambda x: jnp.asarray(x)[None]  # noqa: E731 — K = 1
+            return prefill_attention(
+                q[None], k_new[None], v_new[None], one(q_start),
+                one(seq_len),
+                None if fresh else _prior_context(ctx_kv, l, one(slot)),
+            )[0]
 
         # padding tokens must not claim MoE expert capacity
         h, _ = _layer_body(c, lp, h, cos, sin, write_kv, attend,
@@ -727,7 +731,10 @@ def prefill_impl(
     return out_ctx, logits
 
 
-prefill = jax.jit(prefill_impl, static_argnums=(0,), donate_argnums=(2,))
+prefill = jax.jit(
+    prefill_impl, static_argnums=(0,), static_argnames=("fresh",),
+    donate_argnums=(2,),
+)
 
 
 def _batch_forward(
@@ -745,12 +752,17 @@ def _batch_forward(
     chunk_masks: Optional[jnp.ndarray] = None,  # [K, T, T] bool tree-
                             # causal in-chunk visibility (spec tree)
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Read-only vmapped layer stack shared by batch_prefill and
-    batch_score: K chunks through the model in one program. Returns
-    (ks, vs, h) — stacked per-layer KV [K, L, T, kvh, hd] and final
-    hidden states [K, T, H]; region writes happen OUTSIDE the vmap (a
-    shared-buffer update inside vmap would be a scatter with
-    lane-conflict semantics).
+    """Read-only layer stack shared by batch_prefill and batch_score: K
+    chunks through the model in one program. Returns (ks, vs, h) —
+    stacked per-layer KV [K, L, T, kvh, hd] and final hidden states
+    [K, T, H]; region writes happen after the stack, in the caller.
+
+    Each layer is lane-batched end to end: its two halves (_layer_qkv,
+    _layer_out) are vmapped over the K lanes — one [K, T, H] pipeline,
+    so a tp-sharded layer keeps two all-reduces over [K, T, hidden] —
+    and between them ONE prefill_attention call takes all lanes with
+    their q_starts and seq_lens, so a dummy lane or a short prompt costs
+    no attention. ``ctx_span`` 0 compiles no read of the region.
 
     Tree mode (``depths``/``chunk_masks`` given, always together): the
     chunk is a packed token TREE, not a linear run — node t's RoPE
@@ -765,66 +777,48 @@ def _batch_forward(
     )
 
     cdt = _ctx_compute_dtype(c, ctx_kv)
-    # gather bank rows OUTSIDE the vmap ([K, L, d, r] per site), then vmap
-    # over the gathered rows so each lane sees its own [L, d, r] factors
+    # gather bank rows once ([K, L, d, r] per site); each vmapped half
+    # then sees its lane's own [L, d, r] factors
     ag = _gather_adapters(params.get("adapters"), adapter_ids)
-    if depths is not None:
+    if depths is None:
+        positions = q_starts[:, None] + jnp.arange(T, dtype=jnp.int32)
+        node_valid = positions < seq_lens[:, None]
+    else:
         assert ag is None, "tree chunks are base-model only"
+        # padding nodes (depth -1) pin to position q_start and are
+        # masked out of attention (chunk_masks) and MoE routing below
+        positions = q_starts[:, None] + jnp.maximum(depths, 0)
+        node_valid = (positions < seq_lens[:, None]) & (depths >= 0)
+    cos, sin = jax.vmap(lambda p: rope_cos_sin(p, inv_freq))(positions)
+    h = jax.vmap(lambda t: _embed_rows(params, t, cdt))(tokens)
+    new_ks: list[jnp.ndarray] = []
+    new_vs: list[jnp.ndarray] = []
+    for l in range(c.num_layers):
+        lp = jax.tree.map(lambda x: x[l], params["layers"])
 
-    def compute(toks, slot, q_start, seq_len, ag_row, depth_row=None,
-                cm_row=None):
-        if depth_row is None:
-            positions = q_start + jnp.arange(T, dtype=jnp.int32)
-            node_valid = positions < seq_len
-        else:
-            # padding nodes (depth -1) pin to position q_start and are
-            # masked out of attention (cm_row) and MoE routing below
-            positions = q_start + jnp.maximum(depth_row, 0)
-            node_valid = (positions < seq_len) & (depth_row >= 0)
-        cos, sin = rope_cos_sin(positions, inv_freq)
-        h = _embed_rows(params, toks, cdt)
-        new_ks: list[jnp.ndarray] = []
-        new_vs: list[jnp.ndarray] = []
-        for l in range(c.num_layers):
-            lp = jax.tree.map(lambda x: x[l], params["layers"])
+        def ad(ag_row, l=l):
+            return _adapter_layer(ag_row, l, per_row=False)
 
-            def write_kv(k, v):
-                new_ks.append(k)
-                new_vs.append(v)
-                return (k, v)
-
-            def attend(q, kv, l=l):
-                k_new, v_new = kv
-                if ctx_span > 0:
-                    k_ctx = _ctx_slot_slab(
-                        ctx_kv, "k", l, slot, cdt, span=ctx_span)
-                    v_ctx = _ctx_slot_slab(
-                        ctx_kv, "v", l, slot, cdt, span=ctx_span)
-                else:
-                    k_ctx = v_ctx = None
-                return flash_prefill_attention(
-                    q, k_ctx, v_ctx, k_new, v_new, q_start, seq_len,
-                    chunk_mask=cm_row,
-                )
-
-            h, _ = _layer_body(c, lp, h, cos, sin, write_kv, attend,
-                               ffn_valid=node_valid,
-                               ad=_adapter_layer(ag_row, l, per_row=False))
-        return (
-            jnp.stack(new_ks).astype(cdt),
-            jnp.stack(new_vs).astype(cdt),
-            h,
+        q, k, v = jax.vmap(
+            lambda h, cos, sin, ag_row: _layer_qkv(
+                c, lp, h, cos, sin, ad(ag_row))
+        )(h, cos, sin, ag)
+        new_ks.append(k)
+        new_vs.append(v)
+        attn = prefill_attention(
+            q, k, v, q_starts, seq_lens,
+            _prior_context(ctx_kv, l, slots) if ctx_span > 0 else None,
+            chunk_masks, ctx_span=ctx_span,
         )
-
-    if depths is not None:
-        return jax.vmap(
-            lambda t, s, q, sl, d, cm: compute(t, s, q, sl, None, d, cm)
-        )(tokens, slots, q_starts, seq_lens, depths, chunk_masks)
-    if ag is None:
-        return jax.vmap(
-            lambda t, s, q, sl: compute(t, s, q, sl, None)
-        )(tokens, slots, q_starts, seq_lens)
-    return jax.vmap(compute)(tokens, slots, q_starts, seq_lens, ag)
+        h = jax.vmap(
+            lambda h, attn, valid, ag_row: _layer_out(
+                c, lp, h, attn, valid, ad(ag_row))
+        )(h, attn, node_valid, ag)
+    return (
+        jnp.stack(new_ks, axis=1).astype(cdt),
+        jnp.stack(new_vs, axis=1).astype(cdt),
+        h,
+    )
 
 
 def _write_chunks(
@@ -836,32 +830,37 @@ def _write_chunks(
     seq_lens: Optional[jnp.ndarray] = None,  # [K] i32 — bounds the rows
                             # feeding int8 scales (padding excluded)
 ) -> Cache:
-    """Tail pass: K span writes per buffer, K static (unrolled), after
-    every read — the donated update chain aliases in place. Quantized
-    regions route each span through the group-requantize window
-    (_quant_store_span) instead of a raw DUS."""
+    """Tail pass: K span writes per buffer, after every read — one
+    rolled loop over the lanes whose carried buffers update in place, so
+    the donated chain still aliases and the program's size does not grow
+    with K. Quantized regions route each span through the
+    group-requantize window (_quant_store_span) instead of a raw DUS."""
     K = ks.shape[0]
-    if ctx_is_quantized(ctx_kv):
-        g = ctx_group_size(ctx_kv)
-        ck, ksc = ctx_kv["k"], ctx_kv["k_scale"]
-        cv, vsc = ctx_kv["v"], ctx_kv["v_scale"]
-        for i in range(K):
-            vt = None if seq_lens is None else seq_lens[i] - q_starts[i]
-            ck, ksc = _quant_store_span(
-                ck, ksc, slots[i], q_starts[i],
-                ks[i].transpose(0, 2, 1, 3), g, valid_t=vt)
-            cv, vsc = _quant_store_span(
-                cv, vsc, slots[i], q_starts[i],
-                vs[i].transpose(0, 2, 1, 3), g, valid_t=vt)
-        return {"k": ck, "v": cv, "k_scale": ksc, "v_scale": vsc}
-    ck, cv = ctx_kv["k"], ctx_kv["v"]
-    for i in range(K):
-        upd_k = ks[i].transpose(0, 2, 1, 3)[:, :, None]  # [L,kvh,1,T,hd]
-        upd_v = vs[i].transpose(0, 2, 1, 3)[:, :, None]
-        at = (0, 0, slots[i], q_starts[i], 0)
-        ck = jax.lax.dynamic_update_slice(ck, upd_k, at)
-        cv = jax.lax.dynamic_update_slice(cv, upd_v, at)
-    return {"k": ck, "v": cv}
+    quant = ctx_is_quantized(ctx_kv)
+    g = ctx_group_size(ctx_kv) if quant else 0
+
+    def write_lane(i, ctx_kv):
+        # [L, T, kvh, hd] -> [L, kvh, T, hd]
+        upd = {
+            "k": jax.lax.dynamic_index_in_dim(
+                ks, i, keepdims=False).transpose(0, 2, 1, 3),
+            "v": jax.lax.dynamic_index_in_dim(
+                vs, i, keepdims=False).transpose(0, 2, 1, 3),
+        }
+        out = dict(ctx_kv)
+        vt = None if seq_lens is None else seq_lens[i] - q_starts[i]
+        for name, span in upd.items():
+            if quant:
+                out[name], out[name + "_scale"] = _quant_store_span(
+                    ctx_kv[name], ctx_kv[name + "_scale"], slots[i],
+                    q_starts[i], span, g, valid_t=vt)
+            else:
+                out[name] = jax.lax.dynamic_update_slice(
+                    ctx_kv[name], span[:, :, None],
+                    (0, 0, slots[i], q_starts[i], 0))
+        return out
+
+    return jax.lax.fori_loop(0, K, write_lane, dict(ctx_kv))
 
 
 def batch_prefill_impl(
@@ -884,12 +883,12 @@ def batch_prefill_impl(
     `prefill` above keeps the multimodal-embeds and odd-shape paths).
 
     Matmuls see [K*T, H] rows (the MXU-utilization win over K separate
-    [T, H] dispatches); attention is the blocked flash scan
-    (ops/attention.py flash_prefill_attention), so no [T, S+T] score
-    tensor materializes. Per-request KV lands in each slot's contiguous
-    region at [q_start_k, q_start_k+T); all writes happen in one tail
-    pass after the last read (the round-4 no-interleave discipline —
-    models/llama.py module doc). Returns (ctx_kv, logits[K, vocab]) with
+    [T, H] dispatches); attention is the one blocked scan over live rows
+    (ops/attention.py prefill_attention), so no [T, S+T] score tensor
+    materializes and dummy lanes cost no attention. Per-request KV lands
+    in each slot's contiguous region at [q_start_k, q_start_k+T); all
+    writes happen in one tail pass after the last read (the round-4
+    no-interleave discipline — models/llama.py module doc). Returns (ctx_kv, logits[K, vocab]) with
     each row the last valid token's logits.
 
     Padding lanes (group smaller than the compiled K): point slot at the

@@ -12,8 +12,12 @@ no gathers, no page tables:
     reference on the CPU test meshes. Nothing here looks at the process's
     default backend, and there is no fall-through from one to the other:
     a kernel that fails to compile fails the program;
-  - prefill: one dense causal attention over the slot's region — prefill
-    is a large matmul XLA already schedules well; no kernel needed.
+  - prefill: ONE blocked running-softmax attention in pure XLA
+    (``prefill_attention``) for every prefill-family program, solo and
+    batched: two rolled loops whose trip counts follow the live rows, so
+    padding rows, dummy lanes, blocks above the causal diagonal and — for
+    a fresh prompt — the whole region are never scored, and the lowered
+    program has the same size at every bucket width and lane count.
 
 This replaces the round-3 paged-attention kernel whose (slot, head, page)
 grid cost 15.9 ms/step in pure invocation overhead (SURVEY.md §7 "Paged
@@ -23,7 +27,8 @@ CUDA kernel).
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+import functools
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -142,177 +147,199 @@ def ctx_decode_attention(
     )
 
 
-def ctx_prefill_attention(
-    q: jnp.ndarray,        # [T, n_heads, hd] — new tokens (padded)
-    k_ctx: jnp.ndarray,    # [kvh, S, hd] — slot's PRIOR context (< q_start)
-    v_ctx: jnp.ndarray,
-    k_new: jnp.ndarray,    # [T, kvh, hd] — this chunk's keys
+PREFILL_BLOCK = 256  # query/key rows per block of prefill_attention
+
+
+class PriorContext(NamedTuple):
+    """Where a prefill chunk's prior context lives: the engine's ctx
+    region, read block by block inside the attention loop (never sliced
+    into a per-lane slab first)."""
+
+    k: jnp.ndarray           # [L, kvh, lanes, S, hd] — the whole region
+    v: jnp.ndarray
+    layer: jnp.ndarray       # scalar i32 — a VALUE, so that every layer
+                             # of a program shares one traced attention
+    slots: jnp.ndarray       # [K] i32 — each chunk's lane of the region
+    k_scale: Optional[jnp.ndarray] = None  # f32 [L, lanes, S//g] when the
+    v_scale: Optional[jnp.ndarray] = None  # region is int8
+
+
+@functools.partial(jax.jit, static_argnames=("block", "ctx_span"))
+def prefill_attention(
+    q: jnp.ndarray,          # [K, T, n_heads, hd] — K chunks of T new tokens
+    k_new: jnp.ndarray,      # [K, T, kvh, hd] — the chunks' own keys
     v_new: jnp.ndarray,
-    q_start: jnp.ndarray,  # scalar i32 — #tokens already in the region
-    seq_len: jnp.ndarray,  # scalar i32 — total valid context length
+    q_starts: jnp.ndarray,   # [K] i32 — tokens already in each region
+    seq_lens: jnp.ndarray,   # [K] i32 — total valid context (0 = dummy lane)
+    ctx: Optional[PriorContext] = None,  # None = fresh chunks (every
+                             # q_start 0): no region read is compiled
+    chunk_masks: Optional[jnp.ndarray] = None,  # [K, T, T] bool in-chunk
+                             # visibility (tree-causal); None = causal
+    block: int = PREFILL_BLOCK,
+    ctx_span: int = 0,       # STATIC bound on the region rows read (0 =
+                             # the whole region)
 ) -> jnp.ndarray:
-    """Causal attention of T new tokens (positions q_start..q_start+T)
-    against prior context [0, q_start) plus the chunk itself (causal).
-    Returns [T, n_heads, hd]. The chunk's KV is passed directly rather
-    than read back from the region — the region write happens ONCE at the
-    end of the prefill program, so XLA never interleaves writes with the
-    custom-call/einsum reads (the copy pathology this layout exists to
-    avoid). Dense T×S einsums — prefill is MXU-friendly as-is."""
-    T, n_heads, hd = q.shape
-    kv_heads, S, _ = k_ctx.shape
-    n_rep = n_heads // kv_heads
+    """The one prefill attention: blocked, running-softmax, causal, in
+    pure XLA, scoring only (query block, key block) pairs that can hold
+    a live pair. Chunk k's T tokens sit at positions q_start..q_start+T
+    and attend the prior context [0, q_start) plus the chunk itself.
+    Returns [K, T, n_heads, hd]; rows of a query block with no live row
+    (padding past seq_len, dummy lanes) and rows that see no key are 0.
 
-    k = jnp.concatenate(
-        [k_ctx, k_new.transpose(1, 0, 2).astype(k_ctx.dtype)], axis=1
-    )  # [kvh, S+T, hd]
-    v = jnp.concatenate(
-        [v_ctx, v_new.transpose(1, 0, 2).astype(v_ctx.dtype)], axis=1
-    )
-    k = jnp.repeat(k, n_rep, axis=0)  # [nh, S+T, hd]
-    v = jnp.repeat(v, n_rep, axis=0)
-    scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
-    qt = q.transpose(1, 0, 2)  # [nh, T, hd]
-    scores = jnp.einsum(
-        "nth,nsh->nts", qt, k, preferred_element_type=jnp.float32
-    ) * scale
-    q_pos = q_start + jnp.arange(T)[:, None]            # [T, 1]
-    ctx_pos = jnp.arange(S)[None, :]                    # [1, S]
-    ctx_ok = jnp.broadcast_to(
-        (ctx_pos < q_start) & (ctx_pos < seq_len), (T, S)
-    )
-    new_pos = q_start + jnp.arange(T)[None, :]          # [1, T]
-    new_ok = (new_pos <= q_pos) & (new_pos < seq_len)   # causal in-chunk
-    mask = jnp.concatenate([ctx_ok, new_ok], axis=1)    # [T, S+T]
-    scores = jnp.where(mask[None], scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum(
-        "nts,nsh->tnh", probs.astype(v.dtype), v,
-        preferred_element_type=jnp.float32,
-    )
-    return out.astype(q.dtype)
+    Two rolled loops whose trip counts are traced values:
 
-def flash_prefill_attention(
-    q: jnp.ndarray,        # [T, n_heads, hd] — new tokens (padded)
-    k_ctx: Optional[jnp.ndarray],  # [kvh, Sc, hd] prior context, or None
-    v_ctx: Optional[jnp.ndarray],
-    k_new: jnp.ndarray,    # [T, kvh, hd] — this chunk's keys
-    v_new: jnp.ndarray,
-    q_start: jnp.ndarray,  # scalar i32 — #tokens already in the region
-    seq_len: jnp.ndarray,  # scalar i32 — total valid context length
-    block: int = 256,
-    chunk_mask: Optional[jnp.ndarray] = None,  # [T, T] bool in-chunk
-                            # visibility (tree-causal); None = causal
-) -> jnp.ndarray:
-    """Blocked running-softmax ("flash") prefill attention in pure XLA.
+      - outer, over the WORK LIST: the (lane, query block) pairs with a
+        row below the lane's live length seq_len - q_start. A dummy lane
+        contributes none, a 300-token prompt in a 1024 bucket two of four;
+      - inner, over key blocks: first the prior context's blocks below
+        q_start (read from the region in place, dequantized per block
+        when it is int8), then the chunk's blocks up to the causal
+        diagonal and the live length. With a ``chunk_mask`` (a packed
+        token TREE: node i sees its ancestor chain, not its index
+        predecessors; spec/verifier.py) only the live length bounds them.
 
-    Same semantics as ctx_prefill_attention — T new tokens at positions
-    q_start..q_start+T attend prior context [0, q_start) plus the chunk
-    causally — but scores never materialize beyond [nh, T, block], so
-    large chunks (T in the thousands) don't allocate the [T, S+T] f32
-    score tensor the dense path does (32 heads x 3072^2 x 4B = 1.2 GB per
-    layer). lax.scan over key blocks with the standard (m, l, acc)
-    running-max rescale; attention FLOPs are a rounding error next to the
-    parameter matmuls at serving sizes, so the causal 2x block waste is
-    taken in exchange for compiler-friendly static control flow.
-
-    Pass k_ctx=None for fresh prefill (q_start==0 everywhere): the
-    context scan is omitted entirely from the compiled program instead of
-    masked out. The reference's analogue of this split is vLLM's
-    prefill-vs-extend kernel dispatch.
-
-    ``chunk_mask`` replaces the causal in-chunk mask with an explicit
-    [T, T] visibility matrix (chunk_mask[i, j] = query row i may attend
-    chunk key j) — the tree-speculation hook: verify chunks hold a packed
-    token TREE whose nodes attend their ancestor chain, not their index
-    predecessors (spec/verifier.py builds it from parent pointers). The
-    prior-context scan is unaffected: every tree node attends the full
-    committed prefix. Rows with no visible key anywhere (padding nodes)
-    fall out of the m > NEG_INF/2 gate below and emit zeros.
+    So the number of operations in the lowered program depends neither
+    on T nor on K, and the work done follows the live rows. It is a
+    jitted function called from inside the model's jits: the layers of a
+    program, unrolled there, share ONE traced and lowered attention (the
+    layer index is a value), which is what keeps the tracing and
+    lowering that set-up pays per program from growing with depth. Scores, max,
+    sum and accumulator are float32; probabilities are cast to the value
+    dtype before the PV product. A width that is no multiple of the
+    block slides its last block back (start = width - block) and masks
+    the rows it has already seen, instead of padding the source.
     """
-    T, n_heads, hd = q.shape
-    kvh = k_new.shape[1]
-    n_rep = n_heads // kvh
+    K, T, n_heads, hd = q.shape
+    kvh = k_new.shape[2]
+    rep = n_heads // kvh
+    blk = min(block, T)
+    nq = -(-T // blk)
+    i32 = jnp.int32
     scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
-    qt = q.transpose(1, 0, 2)            # [nh, T, hd]
-    q_pos = q_start + jnp.arange(T)      # [T]
+    q_starts = q_starts.astype(i32)
+    seq_lens = seq_lens.astype(i32)
+    n_live = jnp.clip(seq_lens - q_starts, 0, T)     # [K] live chunk rows
+    nblk_live = (n_live + blk - 1) // blk            # [K] live blocks
+    ends = jnp.cumsum(nblk_live)                     # work list offsets
 
-    m0 = jnp.full((n_heads, T), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((n_heads, T), jnp.float32)
-    acc0 = jnp.zeros((n_heads, T, hd), jnp.float32)
+    # kv-head-major operands: a block is then one [kvh, rep*blk, hd] x
+    # [kvh, blk, hd] product, K/V never repeated across the GQA group
+    qt = q.reshape(K, T, kvh, rep, hd).transpose(0, 2, 3, 1, 4)
+    kt = k_new.transpose(0, 2, 1, 3).astype(q.dtype)  # [K, kvh, T, hd]
+    vt = v_new.transpose(0, 2, 1, 3).astype(q.dtype)
 
-    def blocked(k_src, v_src, mask_fn, carry):
-        """Scan key blocks of k_src [kvh, S, hd]; mask_fn(key_pos[blk],
-        q_pos[T]) -> [T, blk] validity."""
-        S = k_src.shape[1]
-        blk = min(block, S)
-        nblk = -(-S // blk)
-        if nblk * blk != S:  # pad the tail block; masks exclude it
-            pad = ((0, 0), (0, nblk * blk - S), (0, 0))
-            k_src = jnp.pad(k_src, pad)
-            v_src = jnp.pad(v_src, pad)
-        # scan over block starts and slice per step — the old
-        # reshape+transpose built a [nblk, kvh, blk, hd] copy of the
-        # whole source up front, so even exact-fit calls paid a full
-        # extra materialization of the context
-        starts = jnp.arange(nblk, dtype=jnp.int32) * blk
+    def score(carry, q_blk, k_blk, v_blk, ok):
+        """One running-softmax step: q_blk [kvh, rep, blk, hd] against
+        k_blk/v_blk [kvh, n, hd] under ok [blk, n]."""
+        m, l, acc = carry
+        s = jnp.einsum("grqh,gkh->grqk", q_blk, k_blk,
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where(ok, s, NEG_INF)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new[..., None])
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "grqk,gkh->grqh", p.astype(v_blk.dtype), v_blk,
+            preferred_element_type=jnp.float32)
+        return m_new, l * alpha + p.sum(axis=-1), acc
 
-        def step(c, start):
-            m, l, acc = c
-            k_blk = jax.lax.dynamic_slice_in_dim(k_src, start, blk, 1)
-            v_blk = jax.lax.dynamic_slice_in_dim(v_src, start, blk, 1)
-            k_rep = jnp.repeat(k_blk, n_rep, axis=0)
-            v_rep = jnp.repeat(v_blk, n_rep, axis=0)
-            s = jnp.einsum(
-                "nth,nbh->ntb", qt, k_rep,
-                preferred_element_type=jnp.float32,
-            ) * scale                          # [nh, T, blk]
-            key_pos = start + jnp.arange(blk)
-            s = jnp.where(mask_fn(key_pos)[None], s, NEG_INF)
-            m_new = jnp.maximum(m, s.max(axis=-1))
-            alpha = jnp.exp(m - m_new)
-            p = jnp.exp(s - m_new[..., None])
-            l_new = l * alpha + p.sum(axis=-1)
-            acc_new = acc * alpha[..., None] + jnp.einsum(
-                "ntb,nbh->nth", p.astype(v_rep.dtype), v_rep,
-                preferred_element_type=jnp.float32,
-            )
-            return (m_new, l_new, acc_new), None
+    def query_block(w, out):
+        lane = jnp.sum(w >= ends).astype(i32)
+        qb = w - (ends[lane] - nblk_live[lane])
+        q0 = jnp.minimum(qb * blk, T - blk)
+        rows = q0 + jnp.arange(blk, dtype=i32)       # chunk-relative
+        q_start, live = q_starts[lane], n_live[lane]
+        q_blk = jax.lax.dynamic_slice(
+            qt, (lane, 0, 0, q0, 0), (1, kvh, rep, blk, hd))[0]
+        carry = (jnp.full((kvh, rep, blk), NEG_INF, jnp.float32),
+                 jnp.zeros((kvh, rep, blk), jnp.float32),
+                 jnp.zeros((kvh, rep, blk, hd), jnp.float32))
 
-        carry, _ = jax.lax.scan(step, carry, starts)
-        return carry
+        if ctx is not None:
+            span = ctx_span or ctx.k.shape[3]
+            cb = min(block, span)
+            layer = jnp.asarray(ctx.layer, i32)
+            below = jnp.minimum(jnp.minimum(q_start, seq_lens[lane]), span)
+            slot = ctx.slots[lane].astype(i32)
 
-    carry = (m0, l0, acc0)
-    if k_ctx is not None:
-        # prior context: valid below q_start (q_start <= seq_len always)
-        carry = blocked(
-            k_ctx, v_ctx,
-            lambda kp: jnp.broadcast_to(
-                (kp < q_start) & (kp < seq_len), (T, kp.shape[0])
-            ),
-            carry,
-        )
-    # the chunk itself: causal, bounded by seq_len — or the caller's
-    # explicit (tree-causal) visibility matrix, sliced per key block
-    if chunk_mask is None:
-        in_chunk = lambda kp: (  # noqa: E731 — tiny closure pair
-            ((q_start + kp)[None, :] <= q_pos[:, None])
-            & ((q_start + kp) < seq_len)[None, :]
-        )
-    else:
-        in_chunk = lambda kp: jnp.take(  # noqa: E731
-            chunk_mask, kp, axis=1
-        )
-    carry = blocked(
-        k_new.transpose(1, 0, 2).astype(qt.dtype),
-        v_new.transpose(1, 0, 2).astype(qt.dtype),
-        in_chunk,
-        carry,
-    )
-    m, l, acc = carry
-    # fully-masked rows (padding queries): their blocks contribute
-    # p = exp(NEG_INF - NEG_INF) = 1 per key (NEG_INF is finite), so l
-    # ends at the key count, not 0 — gate on the running max never having
-    # seen a real (unmasked) score and emit zeros explicitly
-    out = acc / jnp.maximum(l, 1e-30)[..., None]     # [nh, T, hd]
-    out = jnp.where((m > NEG_INF / 2)[..., None], out, 0.0)
-    return out.transpose(1, 0, 2).astype(q.dtype)
+            def ctx_block(j, carry):
+                k0 = jnp.minimum(j * cb, span - cb)
+                kp = k0 + jnp.arange(cb, dtype=i32)  # absolute position
+                at = (layer, 0, slot, k0, 0)
+                size = (1, kvh, 1, cb, hd)
+                k_blk = jax.lax.dynamic_slice(ctx.k, at, size)[0, :, 0]
+                v_blk = jax.lax.dynamic_slice(ctx.v, at, size)[0, :, 0]
+                if ctx.k_scale is not None:
+                    g = ctx.k.shape[3] // ctx.k_scale.shape[2]
+
+                    def dequant(x, scale_grid):
+                        sc = scale_grid[layer, slot][kp // g]
+                        return x.astype(jnp.float32) * sc[None, :, None]
+
+                    k_blk = dequant(k_blk, ctx.k_scale)
+                    v_blk = dequant(v_blk, ctx.v_scale)
+                ok = (kp >= j * cb) & (kp < below)
+                return score(carry, q_blk, k_blk.astype(q.dtype),
+                             v_blk.astype(q.dtype), ok[None, :])
+
+            carry = jax.lax.fori_loop(
+                0, (below + cb - 1) // cb, ctx_block, carry)
+
+        def chunk_block(j, carry):
+            k0 = jnp.minimum(j * blk, T - blk)
+            kp = k0 + jnp.arange(blk, dtype=i32)     # chunk-relative
+            at, size = (lane, 0, k0, 0), (1, kvh, blk, hd)
+            ok = ((kp >= j * blk) & (kp < live))[None, :]
+            if chunk_masks is None:
+                ok = ok & (kp[None, :] <= rows[:, None])
+            else:
+                ok = ok & jax.lax.dynamic_slice(
+                    chunk_masks, (lane, q0, k0), (1, blk, blk))[0]
+            return score(carry, q_blk,
+                         jax.lax.dynamic_slice(kt, at, size)[0],
+                         jax.lax.dynamic_slice(vt, at, size)[0], ok)
+
+        n_keys = nblk_live[lane]
+        if chunk_masks is None:
+            n_keys = jnp.minimum(qb + 1, n_keys)     # the causal diagonal
+        m, l, acc = jax.lax.fori_loop(0, n_keys, chunk_block, carry)
+        # a row that met no unmasked score holds p = exp(0) per masked
+        # key (NEG_INF is finite): gate on the running max, emit zeros
+        o = acc / jnp.maximum(l, 1e-30)[..., None]
+        o = jnp.where((m > NEG_INF / 2)[..., None], o, 0.0)
+        return jax.lax.dynamic_update_slice(
+            out, o.astype(q.dtype)[None], (lane, 0, 0, q0, 0))
+
+    out = jax.lax.fori_loop(0, ends[-1], query_block, jnp.zeros_like(qt))
+    return out.transpose(0, 3, 1, 2, 4).reshape(K, T, n_heads, hd)
+
+
+def prefill_attention_pairs(
+    width: int,               # T, the bucket
+    q_starts,                 # per lane (dummies included)
+    seq_lens,
+    ctx_span: int = 0,        # region rows the program may read (the
+                              # whole region for the solo program); 0 =
+                              # the fresh program, which reads none
+    block: int = PREFILL_BLOCK,
+    causal: bool = True,
+) -> tuple[int, int]:
+    """Host-side count for one prefill dispatch, mirroring the loop
+    bounds of ``prefill_attention``: (live, scored) (query, key) pairs —
+    the pairs the mask admits for real prompt rows, and the pairs the
+    program computes a score for (whole blocks)."""
+    blk = min(block, width)
+    live = scored = 0
+    for q_start, seq_len in zip(q_starts, seq_lens):
+        q_start, seq_len = int(q_start), int(seq_len)
+        n = min(max(seq_len - q_start, 0), width)
+        live += n * q_start + n * (n + 1) // 2
+        nb = -(-n // blk)
+        ctx_keys = 0
+        if ctx_span:
+            cb = min(block, ctx_span)
+            ctx_keys = -(-min(q_start, seq_len, ctx_span) // cb) * cb
+        for qb in range(nb):
+            keys = min(qb + 1, nb) if causal else nb
+            scored += blk * (ctx_keys + keys * blk)
+    return live, scored

@@ -376,6 +376,12 @@ PREFILL_PADDED = ("dynamo_engine_prefill_padded_tokens",
 PREFILL_MATCHED = ("dynamo_engine_prefill_matched_tokens",
                    "prompt tokens served from a prefix match (HBM or "
                    "host tier) per request starting its prefill")
+PREFILL_ATTN_LIVE = ("dynamo_engine_prefill_attn_live_pairs",
+                     "(query, key) pairs the attention mask admits for "
+                     "the real prompt rows of a prefill dispatch")
+PREFILL_ATTN_SCORED = ("dynamo_engine_prefill_attn_scored_pairs",
+                       "(query, key) pairs a prefill dispatch computes a "
+                       "score for: whole blocks of its live rows")
 ROUND_LIVE_LANE_STEPS = ("dynamo_engine_round_live_lane_steps",
                          "lanes live at dispatch x steps per fused "
                          "decode round")
@@ -384,6 +390,8 @@ ROUND_TOKENS = ("dynamo_engine_round_tokens",
 
 # token-count series: powers of two up to a full 32k-position dispatch
 TOKEN_BUCKETS = tuple(float(2 ** i) for i in range(16))
+# pair-count series: up to eight lanes of 4096 x 4096 pairs
+PAIR_BUCKETS = tuple(float(4 ** i) for i in range(4, 15))
 
 
 def request_histograms(
@@ -400,4 +408,6 @@ def request_histograms(
         for name, help_ in (PREFILL_TOKENS, PREFILL_PADDED, PREFILL_MATCHED,
                             ROUND_LIVE_LANE_STEPS, ROUND_TOKENS):
             reg.histogram(name, help_, TOKEN_BUCKETS)
+        for name, help_ in (PREFILL_ATTN_LIVE, PREFILL_ATTN_SCORED):
+            reg.histogram(name, help_, PAIR_BUCKETS)
     return reg

@@ -36,6 +36,8 @@ PAD = "dynamo_engine_prefill_padded_tokens"
 MATCH = "dynamo_engine_prefill_matched_tokens"
 LIVE = "dynamo_engine_round_live_lane_steps"
 RTOK = "dynamo_engine_round_tokens"
+ALIVE = "dynamo_engine_prefill_attn_live_pairs"
+ASCORED = "dynamo_engine_prefill_attn_scored_pairs"
 
 
 def _engine(**kw) -> TpuEngine:
@@ -149,6 +151,46 @@ def test_counters_add_up(served, case):
         live, toks = _delta(h0, h2, LIVE), _delta(h0, h2, RTOK)
         assert live >= toks > 0
         assert live % served["flush_every"] == 0
+
+
+async def test_prefill_dispatches_observe_attention_pairs():
+    """A solo fresh dispatch, a batched fresh group with dummy lanes and
+    a solo continuation after a prefix hit each observe the (query, key)
+    pairs their attention had to score and did score, by hand: buckets
+    (32, 64) are below the attention's 256-row block, so a live lane
+    scores its one bucket x bucket block, a dummy lane nothing, and a
+    continuation adds the region block(s) below its q_start."""
+    eng = _engine()
+    eng.start()
+    try:
+        solo = [7 + j for j in range(20)]
+        h0 = _hists(eng)
+        await _one(eng, solo)
+        h1 = await _settled(eng)
+        assert _delta(h0, h1, ALIVE, "count") == 1
+        assert _delta(h0, h1, ALIVE) == 20 * 21 // 2
+        assert _delta(h0, h1, ASCORED) == 32 * 32
+
+        group = [[100 * (i + 1) + j for j in range(n)]
+                 for i, n in enumerate((40, 40, 50))]
+        before = eng.batch_prefills
+        await asyncio.gather(*[_one(eng, p) for p in group])
+        h2 = await _settled(eng)
+        assert eng.batch_prefills > before       # 8 lanes, 5+ of them dummies
+        assert _delta(h1, h2, ALIVE) == 2 * (40 * 41 // 2) + 50 * 51 // 2
+        assert _delta(h1, h2, ASCORED) == 3 * 64 * 64
+        assert _delta(h1, h2, ALIVE, "count") == _delta(h1, h2, PF, "count")
+
+        # the 20-token prompt again: one sealed page (16 rows) matches, the
+        # last 4 tokens run at q_start 16 against one region block (the
+        # whole 256-row region is narrower than two blocks)
+        await _one(eng, solo)
+        h3 = await _settled(eng)
+        assert _delta(h2, h3, MATCH) == PS
+        assert _delta(h2, h3, ALIVE) == 4 * 16 + 4 * 5 // 2
+        assert _delta(h2, h3, ASCORED) == 32 * (256 + 32)
+    finally:
+        await eng.stop()
 
 
 # ---- request phases ---------------------------------------------------
@@ -374,11 +416,11 @@ def _sources():
     before = snap(100.0, {
         FRONT: (1.0, 10), FIRST: (5.0, 10), PF: (1000.0, 4),
         PAD: (2000.0, 4), MATCH: (0.0, 4), LIVE: (400.0, 20),
-        RTOK: (300.0, 20)}, 1.0)
+        RTOK: (300.0, 20), ALIVE: (1e6, 4), ASCORED: (4e6, 4)}, 1.0)
     after = snap(150.0, {
         FRONT: (1.5, 60), FIRST: (30.0, 60), PF: (17000.0, 54),
         PAD: (26000.0, 54), MATCH: (4000.0, 54), LIVE: (3600.0, 120),
-        RTOK: (2700.0, 120)}, 3.5)
+        RTOK: (2700.0, 120), ALIVE: (7e6, 54), ASCORED: (19e6, 54)}, 3.5)
     return {"before": before, "after": after,
             "engine_up": {"flush_every": 4},
             "config": {"engine": {"max_decode_slots": 8}}}
@@ -392,6 +434,7 @@ READERS = {
     "step.decode_lane_util": (2400 / (100 * 4 * 8) * 100, [RTOK]),
     "step.decode_garbage_share": ((1 - 2400 / 3200) * 100, [LIVE]),
     "kv.prefix_hit_share": (4000 / (4000 + 16000) * 100, [MATCH]),
+    "step.prefill_attn_live_share": (6e6 / 15e6 * 100, [ASCORED]),
 }
 
 

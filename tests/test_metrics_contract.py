@@ -215,6 +215,9 @@ def test_readme_documents_canonical_series():
         "dynamo_tenant_adapter_rounds_total",
         "dynamo_tenant_request_ttft_seconds",
         "dynamo_tenant_request_queue_seconds",
+        # prefill attention work and waste (engine dispatch sites, PR 29)
+        "dynamo_engine_prefill_attn_live_pairs",
+        "dynamo_engine_prefill_attn_scored_pairs",
     ):
         assert name in readme, f"{name} missing from README"
     for endpoint in ("/debug/trace", "/debug/flight", "/debug/prof",

@@ -104,6 +104,8 @@ def test_registry_render_and_snapshot():
         "dynamo_engine_prefill_tokens",
         "dynamo_engine_prefill_padded_tokens",
         "dynamo_engine_prefill_matched_tokens",
+        "dynamo_engine_prefill_attn_live_pairs",
+        "dynamo_engine_prefill_attn_scored_pairs",
         "dynamo_engine_round_live_lane_steps",
         "dynamo_engine_round_tokens",
     }
