@@ -10,7 +10,12 @@ Differences from the original accounting: it takes plain sizes (no
 program types), counts the bytes ONE CHIP moves (weights and KV heads are
 split over tp), and leaves out the write ring and the logits row (both
 under 1 % here) — so it counts low, never high, and a share of the
-roofline computed from it cannot pass 100 % by over-counting.
+roofline computed from it cannot pass 100 % by over-counting. That holds
+for a dense block with full attention in every layer. A block whose step
+does not read all of ``param_bytes`` (experts no token chose) or all of a
+lane's context (window layers) carries a count of its own,
+``benchmarks/bytes/<name>.py``, named by its configuration's ``"bytes"``
+(``layer_metrics/step.decode_roofline.py`` looks it up).
 """
 from __future__ import annotations
 

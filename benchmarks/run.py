@@ -27,7 +27,6 @@ T_START = __import__("time").monotonic()
 
 import argparse
 import glob
-import importlib.util
 import json
 import os
 import re
@@ -40,6 +39,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
 
+import byname  # noqa: E402
 import loadgen  # noqa: E402
 import peaks  # noqa: E402
 import procs  # noqa: E402
@@ -65,11 +65,8 @@ def load_json(*parts: str) -> dict:
 
 
 def load_reader(name: str):
-    path = os.path.join(HERE, "layer_metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location("reader_" + name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return byname.module_with(
+        os.path.join(HERE, "layer_metrics"), name, "read").read
 
 
 def in_cell(metric: dict, workload: str) -> bool:
@@ -225,6 +222,18 @@ def run(args) -> dict:
         "errors": sorted({r["error"] for r in log if r["error"]})[:5],
     }
     print("notes: " + json.dumps(notes), flush=True)
+    # every number `correct` compared, beside its limit, as the last lines
+    # of stderr: what the driver's record keeps of a run that is not correct
+    for name, got, rel, limit in (
+            ("check.max_abs_logprob_diff", check["max_abs_logprob_diff"],
+             "<=", check["tol_max"]),
+            ("check.mean_abs_logprob_diff", check["mean_abs_logprob_diff"],
+             "<=", check["tol_mean"]),
+            ("attempted", gen["attempted"], ">", 0),
+            ("failed", gen["failed"], "==", 0),
+            ("programs_lowered_in_window", lowered, "==", 0),
+            ("compiled_in_window", compiled, "==", 0)):
+        print(f"compared: {name} {got} {rel} {limit}", file=sys.stderr)
 
     device = {"platform": up["platform"], "kind": up["device_kind"],
               "count": cell["chips"],
@@ -261,7 +270,8 @@ def run(args) -> dict:
         "before": snap0, "after": after, "engine_up": up, "trace": trace,
         "trace_span": span, "gen": gen, "log": log, "config": cfg,
         "cell": cell, "knobs": knobs, "mix": mix, "seconds": args.seconds,
-        "peaks": peaks, "delta_hist_mean_ms": delta_hist_mean_ms,
+        "peaks": peaks, "byname": byname,
+        "delta_hist_mean_ms": delta_hist_mean_ms,
     }
     for m in bench["per_layer"]:
         if not in_cell(m, cell["name"]):

@@ -13,10 +13,36 @@ What is the benchmark's own here, and why:
   * while the engine is constructed, its ctx region and prefix pool are
     born sharded too (``born_sharded``): built eagerly on device 0, as
     the program does, the tp=4 configuration's ctx does not fit a chip;
-  * a correctness check against ``reference.py`` before serving;
+  * a correctness check against the configuration's plain reference
+    before serving (below);
   * a stdin command loop for the parent: ``snapshot <file>`` (the
     engine's counters), ``trace <dir> <delay_s> <seconds>`` (a
     ``jax.profiler`` trace of the device), ``stop``.
+
+What this launcher knows about a block it finds through the configuration
+file; a configuration without these keys gets the defaults in brackets:
+
+  ``"reference": "<name>"``  the plain reference of its block,
+      ``benchmarks/references/<name>.py`` (no key: ``benchmarks/
+      reference.py``), loaded by path. The contract: ``logprobs(hf,
+      params, tokens, positions) -> float array [len(positions),
+      vocab_size]``, the log-softmax of the next token after each of
+      ``positions`` of ``tokens``; float32 under
+      ``jax.default_matmul_precision("highest")``; it imports nothing of
+      the program and takes the engine's own weight pytree (``params``),
+      so both sides compute the same model. The module may also state
+      ``CHECK_PROMPTS`` (pairs of prompt tokens and decode steps >= 8),
+      ``CHECK_TOL_MAX`` and ``CHECK_TOL_MEAN``, each with its reason
+      written beside it; what it does not state is this file's. A named
+      file that is missing, or has no ``logprobs``, is an error, never a
+      fall back to another block's reference (``byname.py``).
+  ``"bytes": "<name>"``  read by ``layer_metrics/step.decode_roofline.py``,
+      not here.
+
+The program module that builds the block is still named here
+(``dynamo_tpu.models.llama``): ``TpuEngine`` calls it at some 25 sites, so
+a key that named another would change what this file patches and not what
+the engine runs. That seam is the program's to open (ROADMAP D2).
 """
 from __future__ import annotations
 
@@ -33,6 +59,8 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
 sys.path.insert(0, REPO)
 sys.path.insert(0, HERE)
+
+import byname  # noqa: E402
 
 # How far the served model (bf16 activations, f32 accumulation, KV cache in
 # bf16, int8 weights dequantized inside the matmul) may stand from the
@@ -68,6 +96,26 @@ def load_config(path: str, dry_run: bool) -> dict:
     if dry_run:
         cfg.update(cfg["dry_run"])   # tiny widths, same file, same code
     return cfg
+
+
+def reference_for(cfg: dict) -> dict:
+    """The configuration's plain reference, loaded by path, and what the
+    check holds the engine to: ``logprobs``, ``file`` (from the checkout's
+    root), ``prompts``, ``tol_max``, ``tol_mean``; the last three are the
+    module's own where it states them, else this file's."""
+    if "reference" in cfg:
+        directory, name = os.path.join(HERE, "references"), cfg["reference"]
+    else:
+        directory, name = HERE, "reference"
+    mod = byname.module_with(directory, name, "logprobs")
+    prompts = [[int(p), int(n)] for p, n in
+               getattr(mod, "CHECK_PROMPTS", CHECK_PROMPTS)]
+    if not prompts:
+        raise ValueError(f"{mod.__file__}: CHECK_PROMPTS is empty")
+    return {"logprobs": mod.logprobs,
+            "file": os.path.relpath(mod.__file__, REPO), "prompts": prompts,
+            "tol_max": float(getattr(mod, "CHECK_TOL_MAX", CHECK_TOL_MAX)),
+            "tol_mean": float(getattr(mod, "CHECK_TOL_MEAN", CHECK_TOL_MEAN))}
 
 
 class CompileWatch:
@@ -156,7 +204,8 @@ def build_engine(cfg: dict, seed: int, dry_run: bool):
     with born_sharded(llama, mesh):
         engine = TpuEngine(mcfg, ecfg, params=params, mesh=mesh)
     dev0 = devices[0]
-    ctx_k = engine.ctx["k"]
+    # the largest leaf of the ctx region, whatever kinds of state it holds
+    ctx_k = max(jax.tree.leaves(engine.ctx), key=lambda a: a.nbytes)
     param_bytes = sum(x.nbytes for x in jax.tree.leaves(params))
     say("engine up", {
         "platform": dev0.platform, "device_kind": dev0.device_kind,
@@ -203,23 +252,25 @@ def warm_seal_widths(engine, rows: int) -> dict:
     return {"widths": widths}
 
 
-async def check_against_reference(engine, cfg: dict, seed: int) -> dict:
+async def check_against_reference(engine, cfg: dict, seed: int,
+                                  reference: dict) -> dict:
     """Prefill + decode through the engine's normal path with logprobs,
-    then the same tokens teacher-forced through the plain reference."""
+    then the same tokens teacher-forced through the configuration's plain
+    reference, at its prompts and under its tolerances."""
     import random
 
     import numpy as np
 
-    import reference
     from dynamo_tpu.protocols.common import (
         OutputOptions, PreprocessedRequest, SamplingOptions, StopConditions)
 
+    tol_max, tol_mean = reference["tol_max"], reference["tol_mean"]
     rng = random.Random(seed)
     vocab = cfg["vocab_size"]
     worst, total, n = 0.0, 0.0, 0
     mismatched_argmax = 0
     phases: list[float] = []    # per prompt: engine seconds, reference seconds
-    for prompt_len, n_out in CHECK_PROMPTS:
+    for prompt_len, n_out in reference["prompts"]:
         prompt = [rng.randrange(10, vocab) for _ in range(prompt_len)]
         req = PreprocessedRequest(
             token_ids=prompt, model="bench",
@@ -239,7 +290,7 @@ async def check_against_reference(engine, cfg: dict, seed: int) -> dict:
         phases.append(round(t_ref - t_gen, 2))
         seq = prompt + toks
         positions = [prompt_len - 1 + i for i in range(n_out)]
-        ref = reference.logprobs(cfg, engine.params, seq, positions)
+        ref = reference["logprobs"](cfg, engine.params, seq, positions)
         phases.append(round(time.monotonic() - t_ref, 2))
         for i, row in enumerate(tops):
             ids = np.asarray([p[0] for p in row], np.int64)
@@ -252,9 +303,10 @@ async def check_against_reference(engine, cfg: dict, seed: int) -> dict:
             # sit within the rounding, so argmax is not held to agree
             mismatched_argmax += int(int(np.argmax(ref[i])) != toks[i])
     mean = total / n
-    return {"ok": bool(worst <= CHECK_TOL_MAX and mean <= CHECK_TOL_MEAN),
+    return {"ok": bool(worst <= tol_max and mean <= tol_mean),
             "max_abs_logprob_diff": worst, "mean_abs_logprob_diff": mean,
-            "tol_max": CHECK_TOL_MAX, "tol_mean": CHECK_TOL_MEAN,
+            "tol_max": tol_max, "tol_mean": tol_mean,
+            "reference": reference["file"], "prompts": reference["prompts"],
             "compared": n, "argmax_differs": mismatched_argmax,
             "phases_s": phases}
 
@@ -352,11 +404,13 @@ async def serve(args) -> int:
     from dynamo_tpu.tokenizer import make_test_tokenizer
 
     cfg = load_config(args.config, args.dry_run)
+    # before anything is built: a wrong name costs no compile
+    reference = reference_for(cfg)
     watch = CompileWatch()
     engine = build_engine(cfg, args.seed, args.dry_run)
     t0 = time.monotonic()
     say("seal warm-up", warm_seal_widths(engine, args.seal_rows))
-    verdict = await check_against_reference(engine, cfg, args.seed)
+    verdict = await check_against_reference(engine, cfg, args.seed, reference)
     verdict["seconds"] = round(time.monotonic() - t0, 3)
     say("check", verdict)
 

@@ -5,9 +5,9 @@
                      a flat list of ``(plane, line, name, start_ns,
                      dur_ns)``; needs JAX (``ProfileData``), nothing else.
   reduce_events(ev)  pure arithmetic on that list: union of intervals per
-                     chip, sums by name, collectives and their exposed
-                     part, idle gaps labelled with the XLA modules on
-                     either side. No JAX.
+                     chip, sums by name, every named kernel (custom
+                     call), collectives and their exposed part, idle gaps
+                     labelled with the XLA modules on either side. No JAX.
 
 Run by the harness as a child of its own with ``JAX_PLATFORMS=cpu`` after
 the server has exited (the parent stays off JAX, nothing contends for the
@@ -42,6 +42,8 @@ COLLECTIVE = re.compile(
     r"|collective-broadcast)")
 # ops that only contain other ops: their time is their children's
 CONTAINER = re.compile(r"^(while|conditional|call)$")
+# a Pallas/Mosaic kernel is a custom call named after its kernel function
+KERNEL_OPCODE = "custom-call"
 
 Event = tuple  # (plane, line, name, start_ns, dur_ns)
 
@@ -115,14 +117,30 @@ def op_kind(name: str) -> str:
     return re.sub(r"[.\d]+$", "", head)
 
 
+_OPCODE = re.compile(r" ([a-z][a-z\-]*)\(")
+
+
+def _typed_rhs(name: str) -> str:
+    """``%x.1 = bf16[8]{0} copy(..)`` -> ``bf16[8] copy(..)``: what
+    follows `` = ``, without layouts; a bare name -> ''."""
+    rhs = name.split(" = ", 1)[1] if " = " in name else ""
+    return re.sub(r"\{[^}]*\}", "", rhs)
+
+
 def op_label(name: str) -> str:
     """Kind and output type without layouts: the 32 per-layer copies of
     one unrolled op get one label, ``fusion (f32[32,1024], f32[..])``."""
-    rhs = name.split(" = ", 1)[1] if " = " in name else ""
-    rhs = re.sub(r"\{[^}]*\}", "", rhs)
-    m = re.search(r" [a-z][a-z\-]*\(", rhs)
+    rhs = _typed_rhs(name)
+    m = _OPCODE.search(rhs)
     shape = (rhs[:m.start()] if m else rhs).strip()
     return (op_kind(name) + " " + shape).strip()[:120]
+
+
+def op_code(name: str) -> str:
+    """The HLO opcode of an op event named by its HLO text: ``%x.1 =
+    bf16[8]{0} custom-call(..)`` -> ``custom-call``; a bare name -> ''."""
+    m = _OPCODE.search(_typed_rhs(name))
+    return m.group(1) if m else ""
 
 
 def module_base(name: str) -> str:
@@ -147,6 +165,7 @@ def reduce_events(events: list[Event]) -> dict:
     window = w1 - w0
     per_chip = {}
     op_time: dict[str, int] = {}
+    kernel_time: dict[str, int] = {}
     gaps: dict[str, int] = {}
     modules: dict[str, dict] = {}
     for plane, chip in sorted(chips.items()):
@@ -165,6 +184,8 @@ def reduce_events(events: list[Event]) -> dict:
             if not CONTAINER.match(k):
                 label = op_label(n)
                 op_time[label] = op_time.get(label, 0) + (e - s)
+                if op_code(n) == KERNEL_OPCODE:
+                    kernel_time[label] = kernel_time.get(label, 0) + (e - s)
         mods = sorted(chip["modules"], key=lambda m: m[1])
         for n, s, e in mods:
             m = modules.setdefault(module_base(n), {"count": 0, "ns": 0})
@@ -202,6 +223,9 @@ def reduce_events(events: list[Event]) -> dict:
             op_time.items(), key=lambda kv: -kv[1])[:10]],
         "idle_gaps": [[k, v / 1e9 / n] for k, v in sorted(
             gaps.items(), key=lambda kv: -kv[1])[:10]],
+        # every named kernel, however light: a reader finds its own by the
+        # label's first word (the ten device_ops keep only the heaviest)
+        "kernels": {k: v / 1e9 / n for k, v in sorted(kernel_time.items())},
     }
 
 
@@ -237,9 +261,14 @@ def selftest() -> int:
          "5120] %x)", 6 * ms, 2 * ms),
         (T0, OPS_LINE, "all-reduce.2", 8 * ms, 2 * ms),
         (T0, OPS_LINE, "fusion.3", 8 * ms, 2 * ms),
-        # chip 1: busy only 0-2 ms
+        # chip 1: busy only 0-2 ms, a kernel inside it twice
         (T1, MODULES_LINE, "jit_round(11)", 0, 2 * ms),
         (T1, OPS_LINE, "fusion.1", 0, 2 * ms),
+        (T1, OPS_LINE, "%my_kernel.3 = bf16[8,4]{1,0:T(8,128)(2,1)} custom-call("
+         "bf16[8,4]{1,0} %q), custom_call_target=\"tpu_custom_call\"",
+         0, ms // 2),
+        (T1, OPS_LINE, "%my_kernel.7 = bf16[8,4]{1,0} custom-call(bf16[8,4] %q)",
+         ms, ms // 4),
         # a host plane is ignored
         ("/host:CPU", "python", "sleep", 0, 100 * ms),
     ]
@@ -260,6 +289,12 @@ def selftest() -> int:
            ["fusion f32[8,4]", 0.003 / 2] in
            [[k, round(v, 12)] for k, v in r["device_ops"]])
     expect("label of a bare name", op_label("fusion.3") == "fusion")
+    expect("opcode", op_code(ev[3][2]) == "fusion"
+           and op_code("%t = (f32[8]{0}, bf16[8,4]{1,0}) fusion(f32[8] %a)")
+           == "fusion" and op_code("fusion.3") == "")
+    expect("kernels: every custom call by label, mean over chips, and "
+           "nothing else", list(r["kernels"]) == ["my_kernel bf16[8,4]"]
+           and near(r["kernels"]["my_kernel bf16[8,4]"], 0.00075 / 2))
     expect("module mean over chips",
            near(r["modules"]["jit_round"]["seconds"], 0.003)
            and near(r["modules"]["jit_round"]["count"], 1.0))
