@@ -70,7 +70,7 @@ from dynamo_tpu.kv_router.protocols import (
     KvStats,
     WorkerStats,
 )
-from dynamo_tpu.models import llama
+from dynamo_tpu.models import llama, mla_moe
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.ops.attention import (
     decode_attention_for,
@@ -345,12 +345,17 @@ class TpuEngine:
         # compiled Pallas kernel on TPU devices, the jnp reference on the
         # CPU test meshes — decided here, once, from the mesh's devices
         self.decode_attn = decode_attention_for(self.mesh)
+        if model_config.mla is not None:
+            # (its decode attention is ops/latent_decode.py, in XLA on
+            # every platform; decode_attn goes unused)
+            self._refuse_latent_planes(self.ecfg, on_dispatch, draft_config)
         dev0 = self.mesh.devices.flat[0]
         log.info(
             "engine devices: platform=%s device_kind=%s mesh=%s "
             "decode_attention=%s",
             dev0.platform, dev0.device_kind, dict(self.mesh.shape),
-            self.decode_attn.impl,
+            ("latent rows, XLA" if model_config.mla is not None
+             else self.decode_attn.impl),
         )
         self.on_metrics = on_metrics
         # multihost leader hook: every device dispatch is broadcast to the
@@ -526,6 +531,15 @@ class TpuEngine:
         self._h_live_steps = self.telemetry.get(
             tmetrics.ROUND_LIVE_LANE_STEPS[0])
         self._h_round_tokens = self.telemetry.get(tmetrics.ROUND_TOKENS[0])
+        # routed-expert counters, one observation per consumed decode
+        # round; they ride the round's stacked-token fetch (an extra row)
+        self._h_moe_touched = self.telemetry.get(tmetrics.MOE_TOUCHED[0])
+        self._h_moe_routed = self.telemetry.get(tmetrics.MOE_ROUTED[0])
+        self._h_moe_load_max = self.telemetry.get(tmetrics.MOE_LOAD_MAX[0])
+        # bytes a token holds in the ctx region: observed once, here
+        self.telemetry.get(tmetrics.KV_ROW_BYTES[0]).observe(sum(
+            x.nbytes for x in jax.tree.leaves(self.ctx)
+        ) / ((e.max_decode_slots + 1) * e.max_context))
         # histogram snapshots are built per metrics() call, which the
         # engine loop makes EVERY round via on_metrics while the
         # publisher throttles to ~4 Hz — cache at the publish cadence so
@@ -752,7 +766,11 @@ class TpuEngine:
             bare argmax instead of top-k over the vocab."""
             B = dev["tokens"].shape[0]
             ring_base = jnp.maximum(dev["ctx"] - 1, 0)
-            toks_out = jnp.zeros((n_steps, B), jnp.int32)
+            # routed-expert models: one more row carries the round's
+            # routing counters home in the same fetch
+            routed = c.routed is not None
+            toks_out = jnp.zeros((n_steps + int(routed), B), jnp.int32)
+            moe_stats = jnp.zeros(3, jnp.int32)
             lp_out = (
                 jnp.zeros((n_steps, B, 1 + 2 * max_logprobs), jnp.float32)
                 if want_lp else None
@@ -765,15 +783,23 @@ class TpuEngine:
 
             # MoE models: freed/garbage lanes must not claim expert
             # capacity (and masking keeps outputs batch-independent)
-            live = (dev["dest"] != B) if c.moe is not None else None
+            live = ((dev["dest"] != B)
+                    if c.moe is not None or routed else None)
 
             def body(s, carry):
-                ring, dev, toks_out, lp_out = carry
-                ring, logits = llama.decode_step_impl(
-                    c, params, ctx_kv, ring, dev["tokens"], dev["ctx"],
-                    ring_base, s, live, dev["adapter"],
-                    attn=self.decode_attn,
-                )
+                ring, dev, toks_out, lp_out, moe_stats = carry
+                if routed:
+                    ring, logits, st = mla_moe.decode_step_impl(
+                        c, params, ctx_kv, ring, dev["tokens"], dev["ctx"],
+                        ring_base, s, live,
+                    )
+                    moe_stats = mla_moe.merge_stats(moe_stats, st)
+                else:
+                    ring, logits = llama.decode_step_impl(
+                        c, params, ctx_kv, ring, dev["tokens"], dev["ctx"],
+                        ring_base, s, live, dev["adapter"],
+                        attn=self.decode_attn,
+                    )
                 if want_sample:
                     toks, st = sampling.sample_step_impl(
                         logits,
@@ -801,11 +827,13 @@ class TpuEngine:
                     keys=keys,
                     counts=counts,
                 )
-                return ring, dev, toks_out, lp_out
+                return ring, dev, toks_out, lp_out, moe_stats
 
-            ring, dev, toks_out, lp_out = jax.lax.fori_loop(
-                0, n_steps, body, (ring, dev, toks_out, lp_out)
+            ring, dev, toks_out, lp_out, moe_stats = jax.lax.fori_loop(
+                0, n_steps, body, (ring, dev, toks_out, lp_out, moe_stats)
             )
+            if routed:
+                toks_out = toks_out.at[n_steps, :3].set(moe_stats)
             # round boundary: scatter the ring into the ctx region
             # (single write, after every read — aliases in place)
             valid = jnp.minimum(jnp.int32(n_steps), max_context - ring_base)
@@ -1127,6 +1155,42 @@ class TpuEngine:
     # are pow2-bucketed for compile-cache reuse; padding targets scratch
     # page 0 (garbage by contract)
 
+    def _refuse_latent_planes(self, e: EngineConfig, on_dispatch,
+                              draft_config) -> None:
+        """Planes that know one row geometry (a K and a V of [kv_heads,
+        head_dim]) refuse a latent-row model at start-up, by name: none
+        reinterprets the row."""
+        planes = {
+            "kv_quant=int8 (the int8 KV plane)": e.kv_quant != "none",
+            "host/disk offload tiers and their kv_integrity frames "
+            "(engine/offload.py)": (e.host_offload_pages > 0
+                                    or e.disk_offload_pages > 0),
+            "speculative decoding (spec/)": (e.speculative != "off"
+                                             or draft_config is not None),
+            "resident LoRA adapters (tenancy/)": e.lora_adapters > 0,
+            "sequence-parallel prefill (sp_prefill_threshold)":
+                e.sp_prefill_threshold is not None,
+            "the multihost leader/follower replay": on_dispatch is not None,
+        }
+        for plane, on in planes.items():
+            if on:
+                raise ValueError(
+                    f"{plane} cannot carry a latent (MLA) cache row yet; "
+                    "turn it off for this model")
+        if e.max_decode_slots < 3:
+            # the round's three routing counters ride home in one more
+            # row of the stacked-token fetch, max_decode_slots wide
+            raise ValueError(
+                f"max_decode_slots={e.max_decode_slots}: a routed-expert "
+                "model needs at least 3 (its routing counters ride the "
+                "round's token fetch in a row that wide)")
+
+    def _refuse_latent_transfer(self) -> None:
+        if self.config.mla is not None:
+            raise ValueError(
+                "kv_transfer / disaggregation cannot carry a latent (MLA) "
+                "cache row yet: pages move as a K and a V")
+
     def _gather_padded(self, pages: list[int]):
         """Device gather of whole pages; returns DEVICE arrays
         ``(data [2, L, kvh, pow2(n), ps, hd], scales|None)`` — callers
@@ -1248,6 +1312,7 @@ class TpuEngine:
     def _start_stream(
         self, kind: str, ids: list[int], chunk_pages: int, inflight: int,
     ) -> queue_mod.Queue:
+        self._refuse_latent_transfer()
         if self.on_dispatch is not None:
             raise RuntimeError(
                 "multihost engine: the page transfer plane is single-host"
@@ -1383,6 +1448,7 @@ class TpuEngine:
         return progressed
 
     def _xfer_op(self, kind: str, page_ids: list[int], data) -> Any:
+        self._refuse_latent_transfer()
         if self.on_dispatch is not None and kind in (
             "export", "import", "export_hash",
         ):
@@ -4032,6 +4098,11 @@ class TpuEngine:
         lp_arrs = None
         if entry.lp_handle is not None:
             lp_arrs = self._unpack_lp(np.asarray(entry.lp_handle))
+        if self.config.routed is not None:
+            touched, routed, load_max = toks[entry.n_steps, :3]
+            self._h_moe_touched.observe(int(touched))
+            self._h_moe_routed.observe(int(routed))
+            self._h_moe_load_max.observe(int(load_max))
         delivered = 0
         for slot, r in enumerate(entry.slots):
             # identity check doubles as the epoch: a recycled slot holds

@@ -13,6 +13,37 @@ from dataclasses import dataclass, field
 from typing import Any, Optional
 
 
+# model_type values served by the dense decoder (models/llama.py) and by
+# the latent-attention + routed-expert block (models/mla_moe.py)
+_DENSE_TYPES = frozenset({"llama", "mistral", "qwen2"})
+_MLA_MOE_TYPES = frozenset({"deepseek_v3", "joyai_llm_flash"})
+# keys that mean "not a dense Llama": a config carrying one is refused
+# rather than read with its extra structure dropped
+_FOREIGN_KEYS = ("kv_lora_rank", "q_lora_rank", "n_routed_experts",
+                 "num_experts", "num_local_experts", "layer_types",
+                 "sliding_window", "first_k_dense_replace")
+_MLA_KEYS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+             "qk_rope_head_dim", "v_head_dim")
+_ROUTED_KEYS = ("n_routed_experts", "num_experts_per_tok",
+                "moe_intermediate_size", "n_shared_experts",
+                "first_k_dense_replace", "routed_scaling_factor",
+                "norm_topk_prob")
+_TINY_MLA_MOE = {
+    "model_type": "deepseek_v3", "vocab_size": 256, "hidden_size": 64,
+    "intermediate_size": 128, "num_hidden_layers": 3,
+    "num_attention_heads": 4, "q_lora_rank": 32, "kv_lora_rank": 24,
+    "qk_nope_head_dim": 16, "qk_rope_head_dim": 8, "v_head_dim": 16,
+    "n_routed_experts": 8, "num_experts_per_tok": 2,
+    "moe_intermediate_size": 32, "n_shared_experts": 1,
+    "first_k_dense_replace": 1, "routed_scaling_factor": 2.5,
+    "norm_topk_prob": True, "scoring_func": "sigmoid",
+    "topk_method": "noaux_tc", "n_group": 1, "topk_group": 1,
+    "rope_theta": 10000.0, "rope_interleave": True, "rope_scaling": None,
+    "rms_norm_eps": 1e-6, "max_position_embeddings": 512,
+    "hidden_act": "silu", "tie_word_embeddings": False,
+}
+
+
 @dataclass(frozen=True)
 class ModelConfig:
     """Llama-family architecture hyperparameters."""
@@ -45,6 +76,22 @@ class ModelConfig:
     # decode weight-pass floor and is what fits an 8B on a 16 GB v5e
     # (the reference's FP8 recipes, examples/llm/benchmarks/README.md:28).
     quant: Optional[str] = None
+    # Latent (MLA) attention + the DeepSeek-V3 expert block (hashable like
+    # rope_scaling; keys in _MLA_KEYS / _ROUTED_KEYS below). With `mla`
+    # set the model is served by models/mla_moe.py: ONE cached row of
+    # kv_lora_rank + qk_rope_head_dim values a token a layer, a leading
+    # run of dense layers, then expert layers. `num_kv_heads`/`head_dim`
+    # then describe that cached row (1 head of the row's width).
+    mla: Optional[tuple[tuple[str, Any], ...]] = None
+    routed: Optional[tuple[tuple[str, Any], ...]] = None
+
+    @property
+    def mla_dict(self) -> Optional[dict[str, Any]]:
+        return dict(self.mla) if self.mla else None
+
+    @property
+    def routed_dict(self) -> Optional[dict[str, Any]]:
+        return dict(self.routed) if self.routed else None
 
     @property
     def rope_scaling_dict(self) -> Optional[dict[str, Any]]:
@@ -64,6 +111,20 @@ class ModelConfig:
 
     @classmethod
     def from_hf_dict(cls, d: dict[str, Any]) -> "ModelConfig":
+        model_type = d.get("model_type", "llama")
+        if model_type in _MLA_MOE_TYPES or "kv_lora_rank" in d:
+            return cls._from_hf_mla_moe(d)
+        if model_type not in _DENSE_TYPES:
+            raise ValueError(
+                f"model_type {model_type!r} is no block this program "
+                f"builds (dense: {sorted(_DENSE_TYPES)}; latent attention "
+                f"+ routed experts: {sorted(_MLA_MOE_TYPES)})")
+        unknown = sorted(k for k in _FOREIGN_KEYS if d.get(k))
+        if unknown:
+            raise ValueError(
+                f"config keys {unknown} belong to a block the dense "
+                f"{model_type!r} decoder does not have; refusing to read "
+                "it as a Llama")
         num_heads = d["num_attention_heads"]
         head_dim = d.get("head_dim") or d["hidden_size"] // num_heads
         return cls(
@@ -85,6 +146,61 @@ class ModelConfig:
             tie_word_embeddings=d.get("tie_word_embeddings", False),
             model_type=d.get("model_type", "llama"),
         )
+
+    @classmethod
+    def _from_hf_mla_moe(cls, d: dict[str, Any]) -> "ModelConfig":
+        """The DeepSeek-V3 block as a config.json parameterises it. Every
+        key the equations need must be there with a value this program
+        implements; anything else is an error, never a default."""
+        missing = sorted(k for k in _MLA_KEYS + _ROUTED_KEYS if k not in d)
+        if missing:
+            raise ValueError(f"latent-attention block: keys {missing} "
+                             "are missing from the config")
+        refused = {
+            "rope_scaling": d.get("rope_scaling") is not None,
+            "scoring_func": d["scoring_func"] != "sigmoid",
+            "topk_method": d.get("topk_method", "noaux_tc") != "noaux_tc",
+            "n_group/topk_group": (d.get("n_group", 1), d.get(
+                "topk_group", 1)) != (1, 1),
+            "hidden_act": d.get("hidden_act", "silu") != "silu",
+            "attention_bias": bool(d.get("attention_bias")),
+            "moe_layer_freq": d.get("moe_layer_freq", 1) != 1,
+            "rope_interleave": not d.get("rope_interleave", True),
+            "num_nextn_predict_layers (the draft head is not built)":
+                d.get("num_nextn_predict_layers", 0) != 0,
+            "tie_word_embeddings": bool(d.get("tie_word_embeddings")),
+        }
+        bad = sorted(k for k, v in refused.items() if v)
+        if bad:
+            raise ValueError(
+                f"latent-attention block: {bad} have values this program "
+                "does not implement")
+        mla = {k: d[k] for k in _MLA_KEYS}
+        routed = {k: d[k] for k in _ROUTED_KEYS}
+        row = d["kv_lora_rank"] + d["qk_rope_head_dim"]
+        return cls(
+            vocab_size=d["vocab_size"],
+            hidden_size=d["hidden_size"],
+            intermediate_size=d["intermediate_size"],
+            num_layers=d["num_hidden_layers"],
+            num_heads=d["num_attention_heads"],
+            num_kv_heads=1,
+            head_dim=row,
+            rope_theta=float(d.get("rope_theta", 10000.0)),
+            rms_norm_eps=d.get("rms_norm_eps", 1e-6),
+            max_position_embeddings=d.get("max_position_embeddings", 8192),
+            model_type=d.get("model_type", "deepseek_v3"),
+            mla=tuple(sorted(mla.items())),
+            routed=tuple(sorted(routed.items())),
+        )
+
+    @classmethod
+    def tiny_mla_moe(cls, **kw) -> "ModelConfig":
+        """Toy latent-attention + routed-expert model for CPU tests:
+        1 dense + 2 expert layers, 8 experts top 2, one shared."""
+        d = dict(_TINY_MLA_MOE)
+        d.update(kw)
+        return cls.from_hf_dict(d)
 
     @classmethod
     def from_pretrained(cls, model_dir: str) -> "ModelConfig":

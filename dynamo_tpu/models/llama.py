@@ -27,6 +27,20 @@ Design notes (TPU-first, round-4 layout):
     decode is a fixed-slot batch, one token per slot. Both are jittable with
     static shapes; the engine buckets prompt lengths to bound recompiles.
 
+THE SEAM (ROADMAP D2). These names are what the engine and the
+benchmark's launcher call. A ``ModelConfig`` with ``mla`` set is the
+latent-attention + routed-expert block of models/mla_moe.py: the
+parameter, state and prefill functions below hand over to that module.
+Of the movers, flush_ctx and seal_blocks hand a latent region to that
+module's in-place span forms and keep their K/V bodies as they were;
+load_ctx_pages carries whatever ROW KINDS a region holds (``row_kinds``:
+``k`` and ``v`` of [kvh, hd] here, one ``kv`` row there). The decode step
+has ONE entry per block: ``decode_step_impl`` here, and
+``mla_moe.decode_step_impl`` (which also returns the routing counters)
+for the latent block; the engine's round picks. Functions of planes that
+cannot carry a latent row (speculation, sequence-parallel prefill,
+embeddings, page transfer) refuse it by name (``_dense_only``).
+
 Parity: this is the TPU engine the reference delegates to vLLM for
 (launch/dynamo-run subprocess engines; SURVEY.md §2.1 L3).
 """
@@ -45,6 +59,7 @@ from dynamo_tpu.kv_quant import (
     dequantize_groups,
     requantize_groups,
 )
+from dynamo_tpu.models import mla_moe
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.ops.attention import (
     DecodeAttention,
@@ -56,6 +71,28 @@ from dynamo_tpu.ops.rope import apply_rope, rope_cos_sin, rope_inv_freq
 
 Params = dict[str, Any]
 Cache = dict[str, jnp.ndarray]
+
+
+def row_kinds(state: Cache) -> tuple[str, ...]:
+    """The kinds of row a region, pool or ring holds (its leaves that
+    are not scale grids), in a fixed order."""
+    return tuple(sorted(n for n in state if not n.endswith("_scale")))
+
+
+def _any_row(state: Cache) -> jnp.ndarray:
+    return state[row_kinds(state)[0]]
+
+
+def _dense_only(config_or_state, plane: str) -> None:
+    """Refuse a latent-row model (or its state) in a plane that knows one
+    row geometry only, naming the plane."""
+    latent = (config_or_state.mla is not None
+              if isinstance(config_or_state, ModelConfig)
+              else "k" not in config_or_state)
+    if latent:
+        raise ValueError(
+            f"{plane}: this plane cannot carry a latent (MLA) cache row; "
+            "it moves a K and a V of [kv_heads, head_dim]")
 
 
 # ---------------------------------------------------------------------------
@@ -70,6 +107,8 @@ def init_params(config: ModelConfig, rng: jax.Array | int = 0) -> Params:
     constant per-channel scale matched to the dense init's std) — an 8B's
     dense weights can never be materialized on a 16 GB chip, so there is
     no dense-then-quantize step here."""
+    if config.mla is not None:
+        return mla_moe.init_params(config, rng)
     if isinstance(rng, int):
         rng = jax.random.PRNGKey(rng)
     c = config
@@ -125,6 +164,8 @@ def param_shardings(config: ModelConfig, mesh: Mesh) -> Params:
     qkv/gate/up shard the output (head/hidden) dim; o/down shard the input
     dim; embedding + lm_head shard the vocab dim. Quantized leaves get the
     weight's spec on "q" and the spec minus the reduced axis on "s"."""
+    if config.mla is not None:
+        return mla_moe.param_shardings(config, mesh)
     quant8 = config.quant == "int8"
 
     def ns(*spec):
@@ -186,6 +227,8 @@ def init_cache(
     untouched: quantize fuses into seal_blocks (ctx->pool), dequantize
     into load_ctx_pages (pool->ctx)."""
     c = config
+    if c.mla is not None:
+        return mla_moe.init_cache(c, num_pages, page_size, dtype, kv_quant)
     shape = (c.num_layers, c.num_kv_heads, num_pages, page_size, c.head_dim)
     if kv_quant == "int8":
         return {
@@ -201,6 +244,8 @@ def init_cache(
 def cache_shardings(
     config: ModelConfig, mesh: Mesh, kv_quant: str = "none"
 ) -> Cache:
+    if config.mla is not None:
+        return mla_moe.row_shardings(config, mesh, kv_quant)
     s = NamedSharding(mesh, P(None, "tp", None, None, None))
     out = {"k": s, "v": s}
     if kv_quant == "int8":
@@ -232,6 +277,8 @@ def init_ctx(
     S is padded up to a multiple of it (the engine's max_context is
     already page-aligned, so no padding in practice)."""
     c = config
+    if c.mla is not None:
+        return mla_moe.init_ctx(c, batch, ctx_len, dtype, kv_quant, group)
     shape = (c.num_layers, c.num_kv_heads, batch + 1, ctx_len, c.head_dim)
     if kv_quant == "int8":
         S = -(-ctx_len // group) * group
@@ -250,6 +297,8 @@ def init_ctx(
 
 def ctx_shardings(config: ModelConfig, mesh: Mesh,
                   kv_quant: str = "none") -> Cache:
+    if config.mla is not None:
+        return mla_moe.row_shardings(config, mesh, kv_quant)
     s = NamedSharding(mesh, P(None, "tp", None, None, None))
     out = {"k": s, "v": s}
     if kv_quant == "int8":
@@ -361,12 +410,16 @@ def init_ring(
     position ``ring_base[b] + r``.
     """
     c = config
+    if c.mla is not None:
+        return mla_moe.init_ring(c, batch, ring_len, dtype)
     dtype = dtype or jnp.dtype(c.dtype)
     shape = (c.num_layers, c.num_kv_heads, batch, ring_len, c.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
 def ring_shardings(config: ModelConfig, mesh: Mesh) -> Cache:
+    if config.mla is not None:
+        return mla_moe.row_shardings(config, mesh)
     s = NamedSharding(mesh, P(None, "tp", None, None, None))
     return {"k": s, "v": s}
 
@@ -520,7 +573,7 @@ def _moe_ffn(c: ModelConfig, lp, x: jnp.ndarray,
     not steal expert capacity from live tokens (and masking makes output
     independent of the co-batched garbage, keeping decode bit-exact
     regardless of slot occupancy)."""
-    from dynamo_tpu.models.moe import MoEConfig
+    from dynamo_tpu.models.moe import MoEConfig, grouped_experts
 
     md = c.moe_dict
     mcfg = MoEConfig(
@@ -530,6 +583,16 @@ def _moe_ffn(c: ModelConfig, lp, x: jnp.ndarray,
         top_k=md.get("top_k", 2),
         capacity_factor=md.get("capacity_factor", 1.25),
     )
+    if mcfg.capacity_factor <= 0:
+        # dropless (the serving default): the one grouped expert layer
+        # (models/moe.py); the one-hot dispatch below is the
+        # capacity-bounded approximation only
+        logits = jnp.matmul(x, lp["wr"], preferred_element_type=jnp.float32)
+        gate_w, sel = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
+                                    mcfg.top_k)
+        gate_w = gate_w / jnp.maximum(gate_w.sum(-1, keepdims=True), 1e-9)
+        return grouped_experts(x, sel, gate_w, lp["we_g"], lp["we_u"],
+                               lp["we_d"], valid)[0]
     T = x.shape[0]
     E, K = mcfg.num_experts, mcfg.top_k
     C = mcfg.capacity(T)
@@ -660,6 +723,10 @@ def prefill_impl(
     region.
     """
     c = config
+    if c.mla is not None:
+        return mla_moe.prefill_impl(
+            c, params, ctx_kv, tokens, slot, q_start, seq_len, embeds,
+            embeds_mask, adapter_id, fresh)
     T = tokens.shape[0]
     inv_freq = jnp.asarray(
         rope_inv_freq(c.head_dim, c.rope_theta, c.rope_scaling_dict)
@@ -771,6 +838,7 @@ def _batch_forward(
     instead of index order. Tree chunks never carry adapters (spec is
     confined to the base model)."""
     c = config
+    _dense_only(c, "speculation (spec/: scoring, drafting)")
     K, T = tokens.shape
     inv_freq = jnp.asarray(
         rope_inv_freq(c.head_dim, c.rope_theta, c.rope_scaling_dict)
@@ -895,6 +963,10 @@ def batch_prefill_impl(
     scratch lane (batch index B) with seq_len=0 — ffn_valid masks their
     tokens out of MoE routing and their region writes hit scratch.
     """
+    if config.mla is not None:
+        return mla_moe.batch_prefill_impl(
+            config, params, ctx_kv, tokens, slots, q_starts, seq_lens,
+            ctx_span, adapter_ids)
     ks, vs, h = _batch_forward(
         config, params, ctx_kv, tokens, slots, q_starts, seq_lens, ctx_span,
         adapter_ids,
@@ -1135,6 +1207,8 @@ def decode_step_impl(
     forces XLA copies (see init_ring).
     """
     c = config
+    _dense_only(c, "llama.decode_step (the latent block's decode entry "
+                "is mla_moe.decode_step_impl)")
     inv_freq = jnp.asarray(
         rope_inv_freq(c.head_dim, c.rope_theta, c.rope_scaling_dict)
     )
@@ -1204,6 +1278,9 @@ def flush_ctx_impl(
     lane's own prefix + the new entries — never stale suffix bytes), and
     scatter int8 + scales back. Still one fused pass inside the round
     program — zero extra dispatches."""
+    if mla_moe.ROW in ring:
+        return mla_moe.flush_ctx_impl(ctx_kv, ring, dest, ring_base,
+                                      valid_len)
     L, kvh, B, R, hd = ring["k"].shape
     S = ctx_kv["k"].shape[3]
     scratch = ctx_kv["k"].shape[2] - 1
@@ -1313,8 +1390,8 @@ def load_ctx_pages_impl(
     is always safe because real matched runs fit the region by admission
     contract — only padding can overflow."""
     n = page_ids.shape[0]
-    ps = cache["k"].shape[3]
-    S = ctx_kv["k"].shape[3]
+    ps = _any_row(cache).shape[3]
+    S = _any_row(ctx_kv).shape[3]
     usable = min(n, S // ps)
     if usable <= 0:
         return dict(ctx_kv)
@@ -1356,7 +1433,7 @@ def load_ctx_pages_impl(
             )
         return out
     out = {}
-    for name in ("k", "v"):
+    for name in row_kinds(ctx_kv):
         pages = cache[name][:, :, page_ids]      # [L, kvh, usable, ps, hd]
         if pool_q:
             # fused dequant: int8 pages * per-(layer, page) scale, in the
@@ -1386,6 +1463,7 @@ def write_ctx_span_impl(
     (GSPMD gathers the sp-sharded span into the replicated region).
     Int8 ctx quantizes on store (fresh absmax scales for the covered
     groups — same grid as the in-round writes)."""
+    _dense_only(ctx_kv, "sequence-parallel prefill (write_ctx_span)")
     if ctx_is_quantized(ctx_kv):
         out = dict(ctx_kv)
         g = ctx_group_size(ctx_kv)
@@ -1430,6 +1508,9 @@ def seal_blocks_impl(
     seal degenerates to a RAW int8 copy: blocks and their scales move
     verbatim, no requantize pass at the boundary at all."""
     ps = page_size
+    if mla_moe.ROW in ctx_kv:
+        return mla_moe.seal_blocks_impl(cache, ctx_kv, slots, starts, pages,
+                                        ps)
     pool_q = cache_is_quantized(cache)
     ctx_q = ctx_is_quantized(ctx_kv)
     if ctx_q:
@@ -1512,6 +1593,7 @@ def sp_prefill(
     from dynamo_tpu.ops.ring_attention import ring_attention
 
     c = config
+    _dense_only(c, "sequence-parallel prefill (sp_prefill)")
     T = int(tokens.shape[0])
     inv_freq = jnp.asarray(
         rope_inv_freq(c.head_dim, c.rope_theta, c.rope_scaling_dict)
@@ -1562,6 +1644,7 @@ def encode_impl(
     """Mean-pooled, L2-normalized final hidden state [H] over the valid
     tokens. Cache-free causal attention (prompt-sized, one shot)."""
     c = config
+    _dense_only(c, "embeddings (encode)")
     T = tokens.shape[0]
     inv_freq = jnp.asarray(
         rope_inv_freq(c.head_dim, c.rope_theta, c.rope_scaling_dict)
@@ -1610,6 +1693,7 @@ def gather_pages_impl(cache: Cache, page_ids: jnp.ndarray) -> jnp.ndarray:
     """Pull whole pages out of the pool: [2, L, kvh, n, ps, hd] (k then v).
     Callers bucket n to a pow2 (padding with scratch page 0) to bound
     recompiles; the host slices the padding off after fetch."""
+    _dense_only(cache, "KV page transfer / offload tiers (gather_pages)")
     return jnp.stack(
         [cache["k"][:, :, page_ids], cache["v"][:, :, page_ids]]
     )
@@ -1620,6 +1704,7 @@ def scatter_pages_impl(
 ) -> Cache:
     """Write whole pages into the pool (inverse of gather_pages). Padding
     entries must point at scratch page 0 — it is garbage by contract."""
+    _dense_only(cache, "KV page transfer / offload tiers (scatter_pages)")
     return {
         "k": cache["k"].at[:, :, page_ids].set(data[0].astype(cache["k"].dtype)),
         "v": cache["v"].at[:, :, page_ids].set(data[1].astype(cache["v"].dtype)),

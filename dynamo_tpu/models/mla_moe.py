@@ -1,0 +1,496 @@
+"""The DeepSeek-V3 decoder block on the served path: latent (MLA)
+attention, a leading run of dense SwiGLU layers, then layers of
+sigmoid-routed experts with a shared one.
+
+What differs from models/llama.py, and how the engine meets it:
+
+  - CACHE SPEC. A token holds ONE row a layer: ``[c_kv | k_rope]`` after
+    norm and RoPE (kv_lora_rank + qk_rope_head_dim values), read by every
+    query head. The ctx region, the pool and the ring are therefore
+    ``{"kv": [L, 1, lanes, S, row]}`` — one row kind, one "head". Ring ->
+    region and region -> pool move it as in-place spans (flush_ctx_impl,
+    seal_blocks_impl below, reached through the ``llama`` names); pool ->
+    region goes through ``llama.load_ctx_pages``, which carries whatever
+    row kinds a region holds (``llama.row_kinds``).
+  - TWO ATTENTION FORMS, one result. A fresh prefill expands K and V from
+    the latent (``c_kv W_kvb``) and attends at width 192/128 through the
+    shared blocked prefill attention. Decode, and a prefill chunk that
+    continues a context already in the region, ABSORB ``W_kvb``: the query
+    is taken into the latent space (``q_nope W_kvb^K``), scores and the
+    weighted sum run over the cached rows themselves, and ``W_kvb^V`` is
+    applied to the result. That is algebra, not an approximation.
+  - TWO LAYER KINDS. Attention weights are stacked over all layers; the
+    dense MLPs (``params["dense"]``) are a stack of their own and the
+    expert layers (``params["experts"]``) a list, one entry a layer.
+  - EXPERTS. Scores are sigmoid(x W_r) in float32; the top k of
+    ``scores + bias`` are selected, weighted by their scores WITHOUT the
+    bias, normalised, scaled; the grouped dropless product
+    (models/moe.py: grouped_experts) reads only the experts some token
+    picked; the shared expert is added ungated.
+
+Every function here is reached through the ``llama`` names the engine and
+the benchmark's launcher call (``llama.init_params``, ``init_ctx``,
+``prefill``, ``batch_prefill`` ...), which dispatch on ``config.mla``.
+"""
+from __future__ import annotations
+
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from dynamo_tpu.models.config import ModelConfig
+from dynamo_tpu.models.moe import grouped_experts
+from dynamo_tpu.ops.attention import PriorContext, prefill_attention
+from dynamo_tpu.ops.latent_decode import latent_decode_attention
+from dynamo_tpu.ops.rope import rope_inv_freq
+
+Params = dict[str, Any]
+Cache = dict[str, jnp.ndarray]
+
+ROW = "kv"   # the one row kind of this block's cache
+
+
+def dims(c: ModelConfig) -> dict[str, int]:
+    m, r = c.mla_dict, c.routed_dict
+    return {
+        "nh": c.num_heads, "q_rank": m["q_lora_rank"],
+        "kv_rank": m["kv_lora_rank"], "nope": m["qk_nope_head_dim"],
+        "rope": m["qk_rope_head_dim"], "v": m["v_head_dim"],
+        "row": m["kv_lora_rank"] + m["qk_rope_head_dim"],
+        # the row as STORED: zero-padded to whole 128-value lanes. The
+        # device tiles the minor dimension in 128s anyway (the pad costs
+        # no memory), and a minor dimension that is no multiple of 128
+        # makes XLA:TPU store the region position-minor, which turns
+        # every row-wise read and write into a relayout of the region
+        "stored": -(-(m["kv_lora_rank"] + m["qk_rope_head_dim"]) // 128)
+        * 128,
+        "E": r["n_routed_experts"], "K": r["num_experts_per_tok"],
+        "I_e": r["moe_intermediate_size"],
+        "I_s": r["moe_intermediate_size"] * r["n_shared_experts"],
+        "n_dense": min(r["first_k_dense_replace"], c.num_layers),
+    }
+
+
+def kv_row_bytes(c: ModelConfig, itemsize: int) -> int:
+    """Bytes one token holds in the ctx region, all layers."""
+    return c.num_layers * dims(c)["stored"] * itemsize
+
+
+# ---------------------------------------------------------------------------
+# Parameters
+
+def init_params(config: ModelConfig, rng: jax.Array | int = 0) -> Params:
+    """Random parameters, 1/sqrt(fan_in); the selection bias is drawn
+    small and non-zero so that selecting and weighting differ."""
+    if isinstance(rng, int):
+        rng = jax.random.PRNGKey(rng)
+    c, d = config, dims(config)
+    if c.quant is not None:
+        raise ValueError("the latent-attention block has no int8 weights")
+    dtype = jnp.dtype(c.dtype)
+    keys = iter(jax.random.split(rng, 16 + 8 * c.num_layers))
+
+    def rnd(*shape, scale=None):
+        scale = scale or 1.0 / np.sqrt(shape[-2])
+        return (jax.random.normal(next(keys), shape, jnp.float32)
+                * scale).astype(dtype)
+
+    L, H, V = c.num_layers, c.hidden_size, c.vocab_size
+    Ld, Le = d["n_dense"], c.num_layers - d["n_dense"]
+    nh, I = d["nh"], c.intermediate_size
+    return {
+        "embed": rnd(V, H, scale=0.02),
+        "norm_f": jnp.ones((H,), dtype),
+        "lm_head": rnd(H, V, scale=0.02),
+        "layers": {
+            "ln1": jnp.ones((L, H), dtype),
+            "ln2": jnp.ones((L, H), dtype),
+            "wqa": rnd(L, H, d["q_rank"]),
+            "q_norm": jnp.ones((L, d["q_rank"]), dtype),
+            "wqb": rnd(L, d["q_rank"], nh * (d["nope"] + d["rope"])),
+            "wkva": rnd(L, H, d["row"]),
+            "kv_norm": jnp.ones((L, d["kv_rank"]), dtype),
+            "wkvb": rnd(L, d["kv_rank"], nh * (d["nope"] + d["v"])),
+            "wo": rnd(L, nh * d["v"], H),
+        },
+        "dense": {
+            "wg": rnd(Ld, H, I), "wu": rnd(Ld, H, I), "wd": rnd(Ld, I, H),
+        },
+        # one entry per expert layer, NOT stacked: the grouped product is
+        # a kernel call, and a kernel's operand sliced out of a stack is
+        # a copy of the layer's experts (768 MB each, every step)
+        "experts": [{
+            "wr": rnd(H, d["E"]),
+            # in SCORE units, where the 8th and 9th of 256 lie ~0.007
+            # apart and a sigmoid near its top moves 0.1 a unit of
+            # logit: 0.1 here would hand some experts to half the tokens
+            # (a step then read 36 % of the experts independent lanes
+            # touch 69 % of; PERF.md section 6, PR 31), where a trained
+            # bias exists to BALANCE the load
+            "bias": 0.01 * jax.random.normal(
+                next(keys), (d["E"],), jnp.float32),
+            "we_g": rnd(d["E"], H, d["I_e"]),
+            "we_u": rnd(d["E"], H, d["I_e"]),
+            "we_d": rnd(d["E"], d["I_e"], H),
+            "ws_g": rnd(H, d["I_s"]),
+            "ws_u": rnd(H, d["I_s"]),
+            "ws_d": rnd(d["I_s"], H),
+        } for _ in range(Le)],
+    }
+
+
+def param_shardings(config: ModelConfig, mesh: Mesh) -> Params:
+    """One chip holds a whole layer: everything replicated. A mesh with
+    tp or ep > 1 is refused — the expert-parallel share and its exchange
+    are not built (ROADMAP M1)."""
+    for axis in ("tp", "ep"):
+        if mesh.shape.get(axis, 1) > 1:
+            raise ValueError(
+                f"the latent-attention block is not sharded over {axis!r} "
+                f"(mesh {dict(mesh.shape)}): one chip holds a whole layer")
+    shapes = jax.eval_shape(lambda: init_params(config, 0))
+    return jax.tree.map(
+        lambda x: NamedSharding(mesh, P(*([None] * x.ndim))), shapes)
+
+
+# ---------------------------------------------------------------------------
+# Cache spec: the region, the pool and the ring
+
+def _rows(config: ModelConfig, lanes: int, length: int, dtype) -> Cache:
+    dtype = dtype or jnp.dtype(config.dtype)
+    return {ROW: jnp.zeros(
+        (config.num_layers, 1, lanes, length, dims(config)["stored"]),
+        dtype)}
+
+
+def _refuse_quant(kv_quant: str) -> None:
+    if kv_quant != "none":
+        raise ValueError(
+            f"kv_quant={kv_quant!r}: the int8 KV plane cannot carry a "
+            "latent row (its scale grid is per K and V page)")
+
+
+def init_cache(config, num_pages, page_size, dtype=None, kv_quant="none"):
+    _refuse_quant(kv_quant)
+    return _rows(config, num_pages, page_size, dtype)
+
+
+def init_ctx(config, batch, ctx_len, dtype=None, kv_quant="none",
+             group=128):
+    _refuse_quant(kv_quant)
+    return _rows(config, batch + 1, ctx_len, dtype)
+
+
+def init_ring(config, batch, ring_len, dtype=None):
+    return _rows(config, batch, ring_len, dtype)
+
+
+def row_shardings(config: ModelConfig, mesh: Mesh,
+                  kv_quant: str = "none") -> Cache:
+    _refuse_quant(kv_quant)
+    return {ROW: NamedSharding(mesh, P(None, None, None, None, None))}
+
+
+# ---------------------------------------------------------------------------
+# Forward pieces
+
+def _rms(x, w, eps):
+    xf = x.astype(jnp.float32)
+    var = jnp.mean(xf * xf, axis=-1, keepdims=True)
+    return (xf * jax.lax.rsqrt(var + eps)).astype(x.dtype) * w
+
+
+def _rope_pairs(x, positions, inv_freq):
+    """Interleaved rotary: values (2i, 2i+1) of the last axis are one
+    pair, turned by positions * inv_freq[i]. ``x`` [N, ..., r],
+    ``positions`` [N]."""
+    ang = positions.astype(jnp.float32)[:, None] * inv_freq   # [N, r/2]
+    shape = (x.shape[0],) + (1,) * (x.ndim - 2) + (ang.shape[-1],)
+    cos, sin = jnp.cos(ang).reshape(shape), jnp.sin(ang).reshape(shape)
+    xf = x.astype(jnp.float32).reshape(*x.shape[:-1], -1, 2)
+    a, b = xf[..., 0], xf[..., 1]
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def _attn_in(c: ModelConfig, lp, h, positions):
+    """Norm, the two low-rank projections, RoPE. ``h`` [N, H] ->
+    q_nope [N, nh, nope], q_rope [N, nh, rope], and the row to cache
+    [N, stored] = [c_kv | k_rope | 0...]."""
+    d = dims(c)
+    N = h.shape[0]
+    inv_freq = jnp.asarray(rope_inv_freq(d["rope"], c.rope_theta, None))
+    x = _rms(h, lp["ln1"], c.rms_norm_eps)
+    cq = _rms(x @ lp["wqa"], lp["q_norm"], c.rms_norm_eps)
+    q = (cq @ lp["wqb"]).reshape(N, d["nh"], d["nope"] + d["rope"])
+    q_nope, q_rope = q[..., :d["nope"]], q[..., d["nope"]:]
+    kv = x @ lp["wkva"]
+    c_kv = _rms(kv[:, :d["kv_rank"]], lp["kv_norm"], c.rms_norm_eps)
+    k_rope = _rope_pairs(kv[:, d["kv_rank"]:], positions, inv_freq)
+    q_rope = _rope_pairs(q_rope, positions, inv_freq)
+    pad = jnp.zeros((N, d["stored"] - d["row"]), c_kv.dtype)
+    return q_nope, q_rope, jnp.concatenate([c_kv, k_rope, pad], axis=-1)
+
+
+def _wkvb(c: ModelConfig, lp):
+    """W_kvb split by head: keys' part [kv_rank, nh, nope] and values'
+    part [kv_rank, nh, v]."""
+    d = dims(c)
+    w = lp["wkvb"].reshape(d["kv_rank"], d["nh"], d["nope"] + d["v"])
+    return w[..., :d["nope"]], w[..., d["nope"]:]
+
+
+def _absorb_q(c: ModelConfig, lp, q_nope, q_rope, times: float = 1.0):
+    """The query in the latent space, scaled: its scores against cached
+    rows are ``times`` x the expanded scores / sqrt(qk width)."""
+    d = dims(c)
+    wk, _ = _wkvb(c, lp)
+    q_lat = jnp.einsum("nhd,chd->nhc", q_nope, wk)
+    pad = jnp.zeros(q_rope.shape[:2] + (d["stored"] - d["row"],),
+                    q_rope.dtype)
+    q = jnp.concatenate([q_lat, q_rope, pad], axis=-1)
+    return q * jnp.asarray(times / np.sqrt(d["nope"] + d["rope"]), q.dtype)
+
+
+def _unabsorb_o(c: ModelConfig, lp, o_lat):
+    """[N, nh, kv_rank] weighted sums of latents -> [N, nh * v]."""
+    _, wv = _wkvb(c, lp)
+    o = jnp.einsum("nhc,chd->nhd", o_lat, wv)
+    return o.reshape(o.shape[0], -1)
+
+
+def _mlp(x, wg, wu, wd):
+    return (jax.nn.silu(x @ wg) * (x @ wu)) @ wd
+
+
+def route(c: ModelConfig, ep, x):
+    """The published router: sigmoid scores in float32, top k of scores +
+    bias, weights from the scores alone, normalised and scaled."""
+    r = c.routed_dict
+    with jax.named_scope("moe_route"):
+        logits = jnp.matmul(x, ep["wr"], preferred_element_type=jnp.float32)
+        s = jax.nn.sigmoid(logits)
+        _, sel = jax.lax.top_k(s + ep["bias"], r["num_experts_per_tok"])
+        w = jnp.take_along_axis(s, sel, axis=-1)
+        if r["norm_topk_prob"]:
+            w = w / (w.sum(-1, keepdims=True) + 1e-20)
+        return sel, w * r["routed_scaling_factor"]
+
+
+def expert_ffn(c: ModelConfig, ep, x, valid=None):
+    """One expert layer [N, H] -> [N, H], and the tokens each expert
+    received. ``valid`` False rows are routed nowhere."""
+    sel, w = route(c, ep, x)
+    y, load = grouped_experts(x, sel, w, ep["we_g"], ep["we_u"],
+                              ep["we_d"], valid)
+    with jax.named_scope("moe_shared"):
+        y = y + _mlp(x, ep["ws_g"], ep["ws_u"], ep["ws_d"])
+    return y, load
+
+
+def merge_stats(a, b):
+    """Routing counters [experts touched, tokens routed, most tokens on
+    one expert] of two steps or layers as one: sums and a maximum."""
+    return jnp.stack([a[0] + b[0], a[1] + b[1], jnp.maximum(a[2], b[2])])
+
+
+def _ffn(c: ModelConfig, params, l: int, x, valid, stats):
+    """Layer l's MLP (l is static): dense below first_k_dense_replace,
+    experts from there on. ``stats`` accumulates [experts touched, tokens
+    routed, most tokens on one expert]."""
+    n_dense = dims(c)["n_dense"]
+    if l < n_dense:
+        dp = jax.tree.map(lambda a: a[l], params["dense"])
+        return _mlp(x, dp["wg"], dp["wu"], dp["wd"]), stats
+    ep = params["experts"][l - n_dense]
+    y, load = expert_ffn(c, ep, x, valid)
+    return y, merge_stats(
+        stats, jnp.stack([jnp.sum(load > 0), load.sum(), load.max()]))
+
+
+def _layer_out(c: ModelConfig, params, lp, l, h, attn, valid, stats):
+    h = h + attn @ lp["wo"]
+    x2 = _rms(h, lp["ln2"], c.rms_norm_eps)
+    y, stats = _ffn(c, params, l, x2, valid, stats)
+    return h + y, stats
+
+
+def _logits(c: ModelConfig, params, h):
+    h = _rms(h, params["norm_f"], c.rms_norm_eps)
+    return jnp.matmul(h, params["lm_head"],
+                      preferred_element_type=jnp.float32)
+
+
+def _refuse_adapters(params):
+    if params.get("adapters") is not None:
+        raise ValueError("the latent-attention block carries no LoRA bank")
+
+
+# ---------------------------------------------------------------------------
+# Prefill
+
+def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
+                       seq_lens, ctx_span=0, adapter_ids=None):
+    """K chunks [K, T] through the model in one program; their rows land
+    in each lane's region at [q_start, q_start + T) in one tail pass after
+    every read. ``ctx_span`` 0: every chunk is fresh, K and V are expanded
+    from the latent and no region read is compiled. Else the chunks
+    continue contexts already in the region and attention is absorbed,
+    over the region's rows and the chunk's own."""
+    c, d = config, dims(config)
+    _refuse_adapters(params)
+    K, T = tokens.shape
+    cdt = ctx_kv[ROW].dtype
+    positions = q_starts[:, None] + jnp.arange(T, dtype=jnp.int32)
+    valid = (positions < seq_lens[:, None]).reshape(K * T)
+    pos = positions.reshape(K * T)
+    h = params["embed"][tokens.reshape(K * T)].astype(cdt)
+    stats = jnp.zeros(3, jnp.int32)
+    rows_out = []
+    for l in range(c.num_layers):
+        lp = jax.tree.map(lambda a: a[l], params["layers"])
+        with jax.named_scope("mla_attn"):
+            q_nope, q_rope, row = _attn_in(c, lp, h, pos)
+            rows_out.append(row)
+            lanes = lambda a: a.reshape(K, T, *a.shape[1:])  # noqa: E731
+            if ctx_span == 0:
+                wk, wv = _wkvb(c, lp)
+                c_kv = row[:, :d["kv_rank"]]
+                k_rope = row[:, d["kv_rank"]:d["row"]]
+                k = jnp.concatenate([
+                    jnp.einsum("nc,chd->nhd", c_kv, wk),
+                    jnp.broadcast_to(k_rope[:, None],
+                                     (K * T, d["nh"], d["rope"]))], -1)
+                v = jnp.einsum("nc,chd->nhd", c_kv, wv)
+                # the shared attention takes one width: V rides zero-
+                # padded to the key's and the pad is cut from the result
+                v = jnp.pad(v, ((0, 0), (0, 0),
+                                (0, k.shape[-1] - d["v"])))
+                q = jnp.concatenate([q_nope, q_rope], -1)
+                o = prefill_attention(lanes(q), lanes(k), lanes(v),
+                                      q_starts, seq_lens)
+                attn = o[..., :d["v"]].reshape(K * T, -1)
+            else:
+                # prefill_attention divides by sqrt(its width), the
+                # row's: undone here, _absorb_q divides by sqrt(qk width)
+                q = _absorb_q(c, lp, q_nope, q_rope,
+                              np.sqrt(d["stored"]))
+                r = lanes(row)[:, :, None].astype(cdt)
+                o = prefill_attention(
+                    lanes(q), r, r, q_starts, seq_lens,
+                    PriorContext(ctx_kv[ROW], ctx_kv[ROW], jnp.int32(l),
+                                 slots),
+                    ctx_span=ctx_span)
+                attn = _unabsorb_o(
+                    c, lp, o[..., :d["kv_rank"]].reshape(
+                        K * T, d["nh"], d["kv_rank"]))
+        h, stats = _layer_out(c, params, lp, l, h, attn, valid, stats)
+
+    rows = jnp.stack(rows_out).reshape(
+        c.num_layers, K, T, d["stored"]).astype(cdt)
+
+    def write_lane(i, buf):
+        span = jax.lax.dynamic_index_in_dim(rows, i, axis=1, keepdims=False)
+        return jax.lax.dynamic_update_slice(
+            buf, span[:, None, None], (0, 0, slots[i], q_starts[i], 0))
+
+    out_ctx = {ROW: jax.lax.fori_loop(0, K, write_lane, ctx_kv[ROW])}
+    last = jnp.maximum(seq_lens - q_starts - 1, 0)
+    h_last = jnp.take_along_axis(
+        h.reshape(K, T, -1), last[:, None, None], axis=1)[:, 0]
+    return out_ctx, _logits(c, params, h_last)
+
+
+def prefill_impl(config, params, ctx_kv, tokens, slot, q_start, seq_len,
+                 embeds=None, embeds_mask=None, adapter_id=None,
+                 fresh=False):
+    """One chunk: the K = 1 case of the batched program."""
+    if embeds is not None:
+        raise ValueError("the latent-attention block takes no embedding "
+                         "overrides (multimodal)")
+    one = lambda x: jnp.asarray(x, jnp.int32)[None]  # noqa: E731
+    ctx_kv, logits = batch_prefill_impl(
+        config, params, ctx_kv, tokens[None], one(slot), one(q_start),
+        one(seq_len), 0 if fresh else ctx_kv[ROW].shape[3])
+    return ctx_kv, logits[0]
+
+
+# ---------------------------------------------------------------------------
+# Decode
+
+def decode_step_impl(config, params, ctx_kv, ring, tokens, ctx_lens,
+                     ring_base, ring_pos, live=None, adapter_ids=None):
+    """One decode step for all slots: (ring, logits [B, vocab], stats
+    [3] i32). The new token's row lands in ring slot ``ring_pos``;
+    attention is absorbed, over the region's rows below ring_base and the
+    ring's above. The region is read-only here (llama.init_ring)."""
+    c, d = config, dims(config)
+    _refuse_adapters(params)
+    positions = jnp.maximum(ctx_lens - 1, 0)
+    h = params["embed"][tokens].astype(ctx_kv[ROW].dtype)
+    buf = ring[ROW]
+    stats = jnp.zeros(3, jnp.int32)
+    for l in range(c.num_layers):
+        lp = jax.tree.map(lambda a: a[l], params["layers"])
+        with jax.named_scope("mla_attn"):
+            q_nope, q_rope, row = _attn_in(c, lp, h, positions)
+            buf = jax.lax.dynamic_update_slice(
+                buf, row.astype(buf.dtype)[None, None, :, None, :],
+                (l, 0, 0, ring_pos, 0))
+            o_lat = latent_decode_attention(
+                _absorb_q(c, lp, q_nope, q_rope), ctx_kv[ROW], buf,
+                jnp.int32(l), ctx_lens, ring_base, d["kv_rank"])
+            attn = _unabsorb_o(c, lp, o_lat)
+        h, stats = _layer_out(c, params, lp, l, h, attn, live, stats)
+    return {ROW: buf}, _logits(c, params, h), stats
+
+
+# ---------------------------------------------------------------------------
+# Movers of the latent row. One "head" makes a lane's R ring entries, and
+# a block's page_size rows, CONTIGUOUS in the region: both move as
+# in-place span writes in a rolled loop, where the K/V movers' scatter and
+# gather forms make XLA copy the whole region (1.7 GB here) per call.
+
+def flush_ctx_impl(ctx_kv, ring, dest, ring_base, valid_len):
+    """Ring -> region, once per round after every read (llama.flush_ctx).
+    Lane b's entry r holds position ring_base[b] + r and goes to lane
+    dest[b]; entries at or past valid_len[b] leave the region's rows as
+    they were."""
+    buf, src = ctx_kv[ROW], ring[ROW]
+    L, _, B, R, row = src.shape
+    S = buf.shape[3]
+    i = jnp.arange(R, dtype=jnp.int32)
+
+    def lane(b, buf):
+        # the span never runs off the region's end: it starts at most at
+        # S - R and the entries are shifted inside it
+        start = jnp.clip(ring_base[b], 0, S - R)
+        at = (0, 0, dest[b], start, 0)
+        old = jax.lax.dynamic_slice(buf, at, (L, 1, 1, R, row))
+        new = jax.lax.dynamic_slice(src, (0, 0, b, 0, 0), (L, 1, 1, R, row))
+        entry = i - (ring_base[b] - start)
+        ok = (entry >= 0) & (entry < valid_len[b])
+        new = jnp.take(new, jnp.clip(entry, 0, R - 1), axis=3)
+        span = jnp.where(ok[None, None, None, :, None], new, old)
+        return jax.lax.dynamic_update_slice(buf, span, at)
+
+    return {ROW: jax.lax.fori_loop(0, B, lane, buf)}
+
+
+def seal_blocks_impl(cache, ctx_kv, slots, starts, pages, page_size):
+    """Region -> pool (llama.seal_blocks): entry i copies lane slots[i]'s
+    rows [starts[i], starts[i] + page_size) into pool page pages[i];
+    padding entries target scratch page 0."""
+    src = ctx_kv[ROW]
+    L, row = src.shape[0], src.shape[4]
+
+    def one(i, pool):
+        block = jax.lax.dynamic_slice(
+            src, (0, 0, slots[i], starts[i], 0), (L, 1, 1, page_size, row))
+        return jax.lax.dynamic_update_slice(
+            pool, block.astype(pool.dtype), (0, 0, pages[i], 0, 0))
+
+    return {ROW: jax.lax.fori_loop(0, slots.shape[0], one, cache[ROW])}

@@ -178,6 +178,83 @@ def _build_moe(mesh: Mesh, axis: str, cfg: MoEConfig, n: int, Tl: int):
     return run
 
 
+# ---------------------------------------------------------------------------
+# The SERVED expert layer: dropless, grouped. (token, pick) rows are
+# sorted by expert and each projection is ONE grouped product (the
+# megablox Pallas kernel on the TPU, jax.lax.ragged_dot elsewhere) that
+# visits only the experts some row was routed to, so a decode step reads
+# the weights of the experts touched and not all E of them, no [T*K, E, C]
+# slot tensor exists, and no token is ever dropped. Routing (which score
+# function, bias, scale) is the caller's: this takes the picks and their
+# combine weights.
+
+GMM_ROWS = 128   # rows per tile of the TPU kernel (tools/moe_gmm_bench.py)
+
+
+def _gmm_tpu(x, w, group_sizes):
+    """The grouped product on the TPU: the megablox Pallas kernel, each
+    tile one expert's WHOLE [in, out] matrix (full-K, full-N tiles): a
+    visit streams the matrix once at ~700 GB/s on a v5e, where
+    ``ragged_dot``'s own lowering reads it at ~210 GB/s (PERF.md section
+    6, PR 31). Empty experts are never visited."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    m, k = x.shape
+    tm = next((t for t in (GMM_ROWS, 64, 32, 16, 8) if m % t == 0), None)
+    if tm is None:
+        return jax.lax.ragged_dot(x, w, group_sizes)
+    return gmm(x, w, group_sizes, preferred_element_type=x.dtype,
+               tiling=(tm, k, w.shape[2]))
+
+
+def _grouped(x, w, group_sizes, expert_of_row):
+    """Rows of x (sorted by expert) times their expert's matrix; dense
+    or int8 ``{"q", "s"}`` experts (scale per [E, out])."""
+    if isinstance(w, dict):
+        y = jax.lax.ragged_dot(x, w["q"].astype(x.dtype), group_sizes)
+        return y * w["s"].astype(x.dtype)[expert_of_row]
+    return jax.lax.platform_dependent(
+        x, w, group_sizes, tpu=_gmm_tpu, default=jax.lax.ragged_dot)
+
+
+def grouped_experts(
+    x: jnp.ndarray,        # [T, H]
+    sel: jnp.ndarray,      # [T, K] i32 — the experts each token picked
+    weights: jnp.ndarray,  # [T, K] f32 — their combine weights
+    wg, wu, wd,            # [E, H, I], [E, H, I], [E, I, H]
+    valid=None,            # [T] bool — False: routed NOWHERE (padding,
+                           # garbage decode lanes); its output row is 0
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """sum_k weights[t, k] * SwiGLU_{sel[t, k]}(x[t]) for every token, and
+    the tokens each expert received ([E] i32: what the counters read)."""
+    T, K = sel.shape
+    E = (wg["q"] if isinstance(wg, dict) else wg).shape[0]
+    flat = sel.reshape(T * K).astype(jnp.int32)
+    if valid is not None:
+        # expert id E sorts last and belongs to no group: rows past the
+        # groups' total are never computed
+        flat = jnp.where(jnp.repeat(valid, K), flat, E)
+    order = jnp.argsort(flat, stable=True)
+    expert_of_row = jnp.minimum(flat[order], E - 1)
+    group_sizes = jnp.zeros(E + 1, jnp.int32).at[flat].add(1)[:E]
+    xs = x[order // K]                                   # [T*K, H]
+    with jax.named_scope("moe_experts"):
+        g = _grouped(xs, wg, group_sizes, expert_of_row)
+        u = _grouped(xs, wu, group_sizes, expert_of_row)
+        y = _grouped(jax.nn.silu(g) * u, wd, group_sizes, expert_of_row)
+    # the inverse permutation: the sorted row that holds pick (t, k)
+    back = jnp.zeros_like(order).at[order].set(
+        jnp.arange(T * K, dtype=order.dtype))
+    y = y[back].reshape(T, K, -1)
+    w = weights.astype(jnp.float32)
+    if valid is not None:
+        # unrouted rows hold whatever the product left there
+        y = jnp.where(valid[:, None, None], y, 0)
+        w = jnp.where(valid[:, None], w, 0.0)
+    out = jnp.einsum("tk,tkh->th", w, y.astype(jnp.float32))
+    return out.astype(x.dtype), group_sizes
+
+
 def moe_reference(h, params, cfg: MoEConfig) -> jnp.ndarray:
     """Single-device dense reference (no capacity drops) for testing."""
     logits = (h @ params["wr"]).astype(jnp.float32)

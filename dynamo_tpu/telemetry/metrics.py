@@ -387,6 +387,18 @@ ROUND_LIVE_LANE_STEPS = ("dynamo_engine_round_live_lane_steps",
                          "decode round")
 ROUND_TOKENS = ("dynamo_engine_round_tokens",
                 "tokens a consumed decode round delivered to streams")
+MOE_TOUCHED = ("dynamo_moe_experts_touched",
+               "distinct routed experts whose weights a consumed decode "
+               "round read, summed over its steps and expert layers")
+MOE_ROUTED = ("dynamo_moe_tokens_routed",
+              "(token, expert) picks of live lanes in a consumed decode "
+              "round, summed over its steps and expert layers")
+MOE_LOAD_MAX = ("dynamo_moe_expert_load_max",
+                "most tokens one expert received in one step of one layer "
+                "of a consumed decode round")
+KV_ROW_BYTES = ("dynamo_kv_row_bytes",
+                "bytes one token holds in the ctx region, all layers "
+                "(observed once, at engine start)")
 
 # token-count series: powers of two up to a full 32k-position dispatch
 TOKEN_BUCKETS = tuple(float(2 ** i) for i in range(16))
@@ -406,8 +418,10 @@ def request_histograms(
         for name, help_ in (QUEUE, ROUND, FIRST_TOKEN, FRONTEND):
             reg.histogram(name, help_)
         for name, help_ in (PREFILL_TOKENS, PREFILL_PADDED, PREFILL_MATCHED,
-                            ROUND_LIVE_LANE_STEPS, ROUND_TOKENS):
+                            ROUND_LIVE_LANE_STEPS, ROUND_TOKENS,
+                            MOE_TOUCHED, MOE_ROUTED, MOE_LOAD_MAX):
             reg.histogram(name, help_, TOKEN_BUCKETS)
+        reg.histogram(*KV_ROW_BYTES, tuple(float(4 ** i) for i in range(3, 12)))
         for name, help_ in (PREFILL_ATTN_LIVE, PREFILL_ATTN_SCORED):
             reg.histogram(name, help_, PAIR_BUCKETS)
     return reg

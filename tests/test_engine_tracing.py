@@ -38,6 +38,9 @@ LIVE = "dynamo_engine_round_live_lane_steps"
 RTOK = "dynamo_engine_round_tokens"
 ALIVE = "dynamo_engine_prefill_attn_live_pairs"
 ASCORED = "dynamo_engine_prefill_attn_scored_pairs"
+TOUCHED = "dynamo_moe_experts_touched"
+ROUTED = "dynamo_moe_tokens_routed"
+LOADMAX = "dynamo_moe_expert_load_max"
 
 
 def _engine(**kw) -> TpuEngine:
@@ -416,14 +419,20 @@ def _sources():
     before = snap(100.0, {
         FRONT: (1.0, 10), FIRST: (5.0, 10), PF: (1000.0, 4),
         PAD: (2000.0, 4), MATCH: (0.0, 4), LIVE: (400.0, 20),
-        RTOK: (300.0, 20), ALIVE: (1e6, 4), ASCORED: (4e6, 4)}, 1.0)
+        RTOK: (300.0, 20), ALIVE: (1e6, 4), ASCORED: (4e6, 4),
+        TOUCHED: (1000.0, 20), ROUTED: (3000.0, 20), LOADMAX: (100.0, 20)},
+        1.0)
     after = snap(150.0, {
         FRONT: (1.5, 60), FIRST: (30.0, 60), PF: (17000.0, 54),
         PAD: (26000.0, 54), MATCH: (4000.0, 54), LIVE: (3600.0, 120),
-        RTOK: (2700.0, 120), ALIVE: (7e6, 54), ASCORED: (19e6, 54)}, 3.5)
+        RTOK: (2700.0, 120), ALIVE: (7e6, 54), ASCORED: (19e6, 54),
+        TOUCHED: (205800.0, 120), ROUTED: (617400.0, 120),
+        LOADMAX: (700.0, 120)}, 3.5)
     return {"before": before, "after": after,
             "engine_up": {"flush_every": 4},
-            "config": {"engine": {"max_decode_slots": 8}}}
+            "config": {"engine": {"max_decode_slots": 8},
+                       "num_hidden_layers": 5, "first_k_dense_replace": 1,
+                       "n_routed_experts": 256}}
 
 
 READERS = {
@@ -435,6 +444,10 @@ READERS = {
     "step.decode_garbage_share": ((1 - 2400 / 3200) * 100, [LIVE]),
     "kv.prefix_hit_share": (4000 / (4000 + 16000) * 100, [MATCH]),
     "step.prefill_attn_live_share": (6e6 / 15e6 * 100, [ASCORED]),
+    # 100 rounds x 4 steps x 4 expert layers x 256 experts = 409600
+    "moe.experts_touched_share": (204800 / 409600 * 100, [TOUCHED]),
+    # mean of the rounds' maxima 6 over 614400 / 204800 = 3 a touched expert
+    "moe.load_max_over_mean": (6.0 / 3.0, [LOADMAX]),
 }
 
 
@@ -457,18 +470,52 @@ def test_reader_gives_none_on_a_program_without_the_counter(name):
     assert _reader(name)(src) is None
 
 
+def test_generator_readers_of_the_chat_decode_mix_by_hand():
+    """The load generator's own two numbers in the open-loop chat-decode
+    mix: how late it sent, and the backlog carried in less carried out
+    (window 10 s: the requests due in it ask for 150 + 50 tokens)."""
+    log = [{"ok": True, "asked": 100, "due": -1.0},
+           {"ok": True, "asked": 150, "due": 0.0},
+           {"ok": True, "asked": 50, "due": 9.9},
+           {"ok": True, "asked": 70, "due": 10.0}]
+    src = {"gen": {"tok_s": 23.5, "late_ms_p90": 1.4}, "log": log,
+           "seconds": 10.0}
+    late = _reader("gen.late_ms_p90.chat-decode-open")
+    carried = _reader("gen.carried_tok_s.chat-decode-open")
+    assert late(src) == 1.4
+    assert carried(src) == pytest.approx(23.5 - 200 / 10.0)
+    # a failed request: tok_s leaves its tokens out, asked keeps them in
+    log[1]["ok"] = False
+    assert carried(src) is None
+    assert late(dict(src, gen={"tok_s": 23.5})) is None
+
+
 def test_benchmark_json_names_every_new_reader():
     import json
 
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        per_layer = {m["name"]: m for m in json.load(f)["per_layer"]}
-    for name in READERS:
+        bench = json.load(f)
+    per_layer = {m["name"]: m for m in bench["per_layer"]}
+    end_to_end = {m["name"]: m for m in bench["end_to_end"]}
+    cells = [w["name"] for w in bench["workloads"]]
+    # every reader this file checks by hand, and every entry of the
+    # newest cell (its TTFT readers included)
+    new_cell = "mla-moe-joyai-d5.chat-decode"
+    assert new_cell in cells
+    for name in sorted(set(READERS) | {
+            n for n, m in per_layer.items()
+            if new_cell in m.get("workloads", ())}):
         entry = per_layer[name]
         assert os.path.exists(os.path.join(
             REPO, "benchmarks", "layer_metrics", name + ".py"))
-        # TTFT is judged in cell 1 only: its movers list that cell
-        assert (entry.get("workloads") == ["mistral7b-w8.chat"]) == (
-            entry["moves"] == "ttft_ms_p90")
+        # PERF.md section 2: TTFT is judged in no cell since PR 30, and
+        # an entry names under `moves` a metric that is judged in EVERY
+        # cell the entry is read in (the driver records it only there)
+        moved = end_to_end[entry["moves"]]
+        assert entry["moves"] != "ttft_ms_p90"
+        for cell in entry.get("workloads", cells):
+            assert cell in cells
+            assert cell in moved.get("workloads", cells), (name, cell)
 
 
 # ---- tools/trace_gaps.py ---------------------------------------------

@@ -1,0 +1,499 @@
+"""The latent-attention + routed-expert block (models/mla_moe.py) against
+its plain reference (benchmarks/references/mla_moe.py), on seeded random
+weights at tiny widths on the CPU. Logits, not tokens: with random
+weights the largest logit changes on rounding.
+
+Every comparison here is float32 against float32 under the suite's
+``jax_default_matmul_precision=highest``: the two sides differ only in
+the ORDER of float32 sums (absorbed against expanded attention, blocked
+running softmax against one softmax, grouped against dense experts), so
+logits of magnitude ~1 agree to ~1e-5 and the tolerances are 2e-4. A
+dropped term, a wrong rope pairing, a bias leaking into the weights or a
+mis-scaled expert moves logits by 1e-2 and more.
+"""
+import dataclasses
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dynamo_tpu.engine.config import EngineConfig
+from dynamo_tpu.engine.engine import TpuEngine
+from dynamo_tpu.models import llama, mla_moe
+from dynamo_tpu.models.config import _TINY_MLA_MOE, ModelConfig
+from dynamo_tpu.models.moe import grouped_experts
+from dynamo_tpu.ops.attention import REFERENCE
+from dynamo_tpu.ops.latent_decode import latent_decode_attention
+from dynamo_tpu.parallel.mesh import MeshConfig
+from dynamo_tpu.protocols.common import (
+    PreprocessedRequest,
+    StopConditions,
+)
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+TOL = dict(rtol=2e-4, atol=2e-4)
+PS = 16
+
+
+def load_reference():
+    path = os.path.join(REPO, "benchmarks", "references", "mla_moe.py")
+    spec = importlib.util.spec_from_file_location("ref_mla_moe", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture(scope="module")
+def setup():
+    cfg = ModelConfig.tiny_mla_moe(dtype="float32")
+    params = llama.init_params(cfg, 3)
+    return cfg, params, load_reference()
+
+
+def padded(prompt, width):
+    toks = np.zeros(width, np.int32)
+    toks[: len(prompt)] = prompt
+    return jnp.asarray(toks)
+
+
+def ref_logits(ref, params, seq, positions):
+    """The reference's log-probs; the program's logits are compared after
+    the same log-softmax."""
+    return ref.logprobs(dict(_TINY_MLA_MOE), params, list(seq), positions)
+
+
+def log_softmax(x):
+    return np.asarray(jax.nn.log_softmax(jnp.asarray(x), -1))
+
+
+# the block's one decode entry (the engine's round calls it too)
+DECODE_STEP = jax.jit(mla_moe.decode_step_impl, static_argnums=(0,))
+
+
+def decode_steps(cfg, params, ctx, first_logits, seq_len, n):
+    """n greedy decode steps through ring + flush; returns tokens and
+    the logits that chose them."""
+    toks = [int(np.argmax(first_logits))]
+    rows = [np.asarray(first_logits)]
+    ring = llama.init_ring(cfg, 1, 1, dtype=jnp.float32)
+    for _ in range(n):
+        seq_len += 1
+        base = jnp.asarray([seq_len - 1], jnp.int32)
+        ring, lg, _ = DECODE_STEP(
+            cfg, params, ctx, ring, jnp.asarray([toks[-1]], jnp.int32),
+            jnp.asarray([seq_len], jnp.int32), base, jnp.int32(0))
+        ctx = llama.flush_ctx(ctx, ring, jnp.asarray([0], jnp.int32), base,
+                              jnp.asarray([1], jnp.int32))
+        rows.append(np.asarray(lg)[0])
+        toks.append(int(np.argmax(rows[-1])))
+    return toks, np.stack(rows), ctx
+
+
+def test_prefill_then_decode_through_the_latent_cache(setup):
+    """(a) fresh (expanded) prefill, then 9 decode steps (absorbed, over
+    region + ring) against the reference's full forward."""
+    cfg, params, ref = setup
+    prompt = np.random.RandomState(0).randint(1, 256, 21).tolist()
+    ctx = llama.init_ctx(cfg, 1, 128, jnp.float32)
+    ctx, logits = llama.prefill(
+        cfg, params, ctx, padded(prompt, 32), jnp.int32(0), jnp.int32(0),
+        jnp.int32(len(prompt)), fresh=True)
+    toks, rows, _ = decode_steps(cfg, params, ctx, logits, len(prompt), 9)
+    seq = prompt + toks[:-1]
+    want = ref_logits(ref, params, seq,
+                      [len(prompt) - 1 + i for i in range(10)])
+    np.testing.assert_allclose(log_softmax(rows), want, **TOL)
+
+
+def test_absorbed_decode_attention_equals_expanded():
+    """(b) the absorbed form over cached rows against K and V expanded
+    per head: algebra, so float32 agrees to summation order."""
+    rng = np.random.RandomState(1)
+    B, nh, rank, rope, nope, vd, S, R = 3, 4, 24, 8, 16, 16, 40, 2
+    row = rank + rope
+    stored = 128
+    lens = np.array([37, 5, 18], np.int32)       # incl. the current token
+    base = np.maximum(lens - R, 0).astype(np.int32)
+    rows = rng.randn(B, S + R, row).astype(np.float32)
+    wk = rng.randn(rank, nh, nope).astype(np.float32) / 5
+    wv = rng.randn(rank, nh, vd).astype(np.float32) / 5
+    q_nope = rng.randn(B, nh, nope).astype(np.float32)
+    q_rope = rng.randn(B, nh, rope).astype(np.float32)
+    # region holds positions < base, the ring the rest; stored rows are
+    # zero-padded and the region's tail is garbage
+    ctx = rng.randn(1, 1, B + 1, S, stored).astype(np.float32)
+    ring = np.zeros((1, 1, B, R, stored), np.float32)
+    for b in range(B):
+        ctx[0, 0, b, : base[b], :row] = rows[b, : base[b]]
+        ctx[0, 0, b, : base[b], row:] = 0
+        n = lens[b] - base[b]
+        ring[0, 0, b, :n, :row] = rows[b, base[b]: lens[b]]
+    scale = 1.0 / np.sqrt(nope + rope)
+    q = np.concatenate([np.einsum("bhd,chd->bhc", q_nope, wk), q_rope,
+                        np.zeros((B, nh, stored - row), np.float32)], -1)
+    got = latent_decode_attention(
+        jnp.asarray(q * scale), jnp.asarray(ctx), jnp.asarray(ring),
+        jnp.int32(0), jnp.asarray(lens), jnp.asarray(base), rank, chunk=16)
+    got = np.einsum("bhc,chd->bhd", np.asarray(got), wv)
+    for b in range(B):
+        live = rows[b, : lens[b]]
+        k = np.concatenate([
+            np.einsum("sc,chd->shd", live[:, :rank], wk),
+            np.broadcast_to(live[:, None, rank:], (lens[b], nh, rope))], -1)
+        v = np.einsum("sc,chd->shd", live[:, :rank], wv)
+        s = np.einsum("hd,shd->hs",
+                      np.concatenate([q_nope[b], q_rope[b]], -1), k) * scale
+        p = np.exp(s - s.max(-1, keepdims=True))
+        p /= p.sum(-1, keepdims=True)
+        np.testing.assert_allclose(got[b], np.einsum("hs,shd->hd", p, v),
+                                   **TOL)
+
+
+def test_expert_layer_equals_a_dense_loop_over_experts(setup):
+    """(c) uneven routing, a bias that changes the selection but not the
+    weights, no token dropped, padded lanes routed nowhere."""
+    cfg, params, _ = setup
+    ep = dict(params["experts"][0])
+    # a bias that pulls most tokens to experts 0 and 1: uneven load
+    ep["bias"] = ep["bias"].at[:2].add(0.6)
+    rng = np.random.RandomState(2)
+    T = 14
+    x = jnp.asarray(rng.randn(T, cfg.hidden_size), jnp.float32)
+    valid = jnp.asarray(np.arange(T) < 11)
+    got, load = mla_moe.expert_ffn(cfg, ep, x, valid)
+    r = cfg.routed_dict
+    s = np.asarray(jax.nn.sigmoid(x @ ep["wr"]), np.float64)
+    biased = s + np.asarray(ep["bias"], np.float64)
+    want = np.zeros((T, cfg.hidden_size))
+    counts = np.zeros(r["n_routed_experts"], np.int64)
+
+    def swiglu(v, g, u, d):
+        return np.asarray((jax.nn.silu(v @ g) * (v @ u)) @ d, np.float64)
+
+    selected_by_bias = 0
+    for t in range(11):
+        sel = np.argsort(-biased[t])[: r["num_experts_per_tok"]]
+        selected_by_bias += set(sel) != set(
+            np.argsort(-s[t])[: r["num_experts_per_tok"]])
+        w = s[t, sel] / (s[t, sel].sum() + 1e-20) * r["routed_scaling_factor"]
+        for e, we in zip(sel, w):
+            counts[e] += 1
+            want[t] += we * swiglu(x[t], ep["we_g"][e], ep["we_u"][e],
+                                   ep["we_d"][e])
+        want[t] += swiglu(x[t], ep["ws_g"], ep["ws_u"], ep["ws_d"])
+    # padded lanes: the shared expert still runs on them (their rows are
+    # discarded by the caller); the routed part is exactly zero
+    routed_only, _ = grouped_experts(
+        x, *mla_moe.route(cfg, ep, x), ep["we_g"], ep["we_u"], ep["we_d"],
+        valid)
+    assert np.all(np.asarray(routed_only)[11:] == 0)
+    np.testing.assert_allclose(np.asarray(got)[:11], want[:11], **TOL)
+    # dropless: every pick of every live token was computed
+    np.testing.assert_array_equal(np.asarray(load), counts)
+    assert counts.sum() == 11 * r["num_experts_per_tok"]
+    assert counts.max() > 2 * counts.mean()        # the routing was uneven
+    assert selected_by_bias > 0                    # and the bias mattered
+
+
+def test_chunked_prefill_and_reloaded_prefix_give_one_prefill(setup):
+    """(d) two chunks (the second absorbed, over the region), and a
+    prefix sealed to the pool then loaded into another lane, give the
+    logits of one prefill: the pool and its movers carry the latent row.
+    The sealed rows themselves move bit-exactly."""
+    cfg, params, ref = setup
+    prompt = np.random.RandomState(4).randint(1, 256, 44).tolist()
+    S = 128
+    ctx = llama.init_ctx(cfg, 2, S, jnp.float32)
+    ctx, one = llama.prefill(
+        cfg, params, ctx, padded(prompt, 64), jnp.int32(0), jnp.int32(0),
+        jnp.int32(len(prompt)), fresh=True)
+    np.testing.assert_allclose(
+        log_softmax(one), ref_logits(ref, params, prompt, [43])[0], **TOL)
+
+    # two chunks through the batched program: 32 fresh, then 12 over them
+    ctx2 = llama.init_ctx(cfg, 2, S, jnp.float32)
+    i32 = lambda *v: jnp.asarray(v, jnp.int32)  # noqa: E731
+    ctx2, _ = llama.batch_prefill(
+        cfg, params, ctx2, padded(prompt[:32], 32)[None], i32(1), i32(0),
+        i32(32), 0)
+    ctx2, two = llama.batch_prefill(
+        cfg, params, ctx2, padded(prompt[32:], 32)[None], i32(1), i32(32),
+        i32(44), S)
+    np.testing.assert_allclose(np.asarray(two[0]), np.asarray(one), **TOL)
+
+    # seal lane 0's first two blocks, load them into lane 1 of a fresh
+    # region, prefill the rest there
+    cache = llama.init_cache(cfg, 8, PS, jnp.float32)
+    cache = llama.seal_blocks(cache, ctx, i32(0, 0), i32(0, PS), i32(3, 5),
+                              page_size=PS)
+    row = mla_moe.ROW
+    np.testing.assert_array_equal(
+        np.asarray(cache[row][:, 0, 5]), np.asarray(ctx[row][:, 0, 0, PS:2 * PS]))
+    ctx3 = llama.init_ctx(cfg, 2, S, jnp.float32)
+    ctx3 = llama.load_ctx_pages(ctx3, cache, jnp.int32(1), i32(3, 5))
+    np.testing.assert_array_equal(
+        np.asarray(ctx3[row][:, 0, 1, : 2 * PS]),
+        np.asarray(ctx[row][:, 0, 0, : 2 * PS]))
+    ctx3, three = llama.prefill(
+        cfg, params, ctx3, padded(prompt[32:], 32), jnp.int32(1),
+        jnp.int32(32), jnp.int32(44))
+    np.testing.assert_allclose(np.asarray(three), np.asarray(one), **TOL)
+
+
+def test_ring_flush_near_the_regions_end_keeps_every_row(setup):
+    """The span flush shifts entries inside a span that never runs off
+    the region; rows past valid_len stay as they were."""
+    cfg, _, _ = setup
+    S, R = 32, 4
+    ctx = jax.tree.map(lambda a: a + 7.0, llama.init_ctx(cfg, 2, S,
+                                                         jnp.float32))
+    ring = llama.init_ring(cfg, 2, R, dtype=jnp.float32)
+    row = mla_moe.ROW
+    ring = {row: ring[row] + jnp.arange(1, R + 1, dtype=jnp.float32)[
+        None, None, None, :, None]}
+    out = llama.flush_ctx(ctx, ring, jnp.asarray([0, 1], jnp.int32),
+                          jnp.asarray([30, 10], jnp.int32),
+                          jnp.asarray([2, 3], jnp.int32))[row]
+    lane0 = np.asarray(out[0, 0, 0, :, 0])
+    np.testing.assert_array_equal(lane0[28:], [7, 7, 1, 2])
+    lane1 = np.asarray(out[0, 0, 1, :, 0])
+    np.testing.assert_array_equal(lane1[9:14], [7, 1, 2, 3, 7])
+
+
+def test_from_hf_dict_reads_the_published_keys_and_refuses_the_unknown():
+    """(e)"""
+    import json
+
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "mla-moe-joyai-d5.json")) as f:
+        published = json.load(f)
+    c = ModelConfig.from_hf_dict(published)
+    d = mla_moe.dims(c)
+    assert (d["nh"], d["q_rank"], d["kv_rank"], d["nope"], d["rope"],
+            d["v"], d["row"], d["stored"]) == (32, 1536, 512, 128, 64, 128,
+                                               576, 640)
+    assert (d["E"], d["K"], d["I_e"], d["I_s"], d["n_dense"]) == (
+        256, 8, 768, 768, 1)
+    assert (c.vocab_size, c.hidden_size, c.intermediate_size,
+            c.num_layers) == (129280, 2048, 7168, 5)
+    assert c.rope_theta == 32e6 and c.rms_norm_eps == 1e-6
+    assert c.routed_dict["routed_scaling_factor"] == 2.5
+    # the dense decoder still reads its own keys
+    assert ModelConfig.from_hf_dict({
+        "model_type": "mistral", "vocab_size": 8, "hidden_size": 8,
+        "intermediate_size": 8, "num_hidden_layers": 1,
+        "num_attention_heads": 2, "sliding_window": None}).mla is None
+    with pytest.raises(ValueError, match="no block this program builds"):
+        ModelConfig.from_hf_dict({"model_type": "laguna", "vocab_size": 8})
+    with pytest.raises(ValueError, match="refusing to read it as a Llama"):
+        ModelConfig.from_hf_dict({
+            "model_type": "llama", "vocab_size": 8, "hidden_size": 8,
+            "intermediate_size": 8, "num_hidden_layers": 1,
+            "num_attention_heads": 2, "num_local_experts": 8})
+    for key, value in (("scoring_func", "softmax"), ("n_group", 8),
+                       ("num_nextn_predict_layers", 1),
+                       ("rope_scaling", {"type": "yarn", "factor": 40})):
+        with pytest.raises(ValueError, match="does not implement"):
+            ModelConfig.from_hf_dict(dict(published, **{key: value}))
+    with pytest.raises(ValueError, match="missing"):
+        ModelConfig.from_hf_dict(
+            {k: v for k, v in published.items() if k != "q_lora_rank"})
+
+
+@pytest.mark.parametrize("plane,kw", [
+    ("int8 KV", {"kv_quant": "int8"}),
+    ("offload", {"host_offload_pages": 8}),
+    ("spec/", {"speculative": "ngram"}),
+    ("LoRA", {"lora_adapters": 2}),
+    ("sequence-parallel", {"sp_prefill_threshold": 64}),
+    # no plane: the routing counters' row is max_decode_slots wide
+    ("fewer than 3 slots", {"max_decode_slots": 2}),
+])
+def test_a_plane_that_cannot_carry_a_latent_row_refuses_at_start(
+        setup, plane, kw):
+    """(f) named refusals at engine start-up; nothing reinterprets the
+    row."""
+    cfg, params, _ = setup
+    ecfg = EngineConfig(**{**dict(
+        num_pages=16, page_size=PS, max_pages_per_seq=4, max_decode_slots=4,
+        prefill_buckets=(32,), cache_dtype="float32"), **kw})
+    with pytest.raises(ValueError, match="latent|at least 3"):
+        TpuEngine(cfg, ecfg, params=params, mesh_config=MeshConfig(tp=1))
+
+
+def test_model_functions_of_other_planes_refuse_a_latent_row(setup):
+    cfg, params, _ = setup
+    cache = llama.init_cache(cfg, 4, PS, jnp.float32)
+    z = jnp.zeros(1, jnp.int32)
+    ctx1 = llama.init_ctx(cfg, 1, 32, jnp.float32)
+    for call in (
+        lambda: llama.decode_step_impl(
+            cfg, params, ctx1, llama.init_ring(cfg, 1, 1, jnp.float32),
+            z, z, z, jnp.int32(0), attn=REFERENCE),
+        lambda: llama.gather_pages(cache, z),
+        lambda: llama.encode_impl(cfg, params, z, jnp.int32(1)),
+        lambda: llama.batch_score_impl(
+            cfg, params, llama.init_ctx(cfg, 1, 32, jnp.float32),
+            z[None], z, z, z, 32),
+        lambda: llama.init_ctx(cfg, 1, 32, kv_quant="int8"),
+    ):
+        with pytest.raises(ValueError, match="latent"):
+            call()
+    with pytest.raises(ValueError, match="not sharded over"):
+        from dynamo_tpu.parallel.mesh import make_mesh
+        if len(jax.devices()) < 2:
+            pytest.skip("needs 2 virtual devices")
+        llama.param_shardings(
+            cfg, make_mesh(MeshConfig(tp=2), jax.devices()[:2]))
+
+
+def test_the_lower_precision_controls_fail_the_references_tolerances(setup):
+    """The reference's own controls (experts rounded to 8 bits; router
+    scores in bfloat16) move the log-probs of a float32 program by more
+    than a float32 program moves from the reference: what the chip check
+    rests on, at tiny widths."""
+    cfg, params, ref = setup
+    seq = np.random.RandomState(5).randint(1, 256, 40).tolist()
+    pos = list(range(20, 40))
+    sound = ref_logits(ref, params, seq, pos)
+    # 8 experts and 40 tokens hold few near-ties for a bf16 router to
+    # flip, so that control only has to stand clear of the tolerance
+    # here; at 256 experts it flips several a layer (PERF.md section 6)
+    for control, times in (("experts_int8", 20), ("router_bf16", 3)):
+        off = ref.logprobs(dict(_TINY_MLA_MOE), params, seq, pos,
+                           control=control)
+        assert np.abs(off - sound).max() > times * TOL["atol"], control
+
+
+def test_the_reference_refuses_weights_that_are_not_the_stated_ones(setup):
+    """What the engine holds is what its steps stream: a routed-expert
+    matrix held in 8 bits, or a pytree of another block, stops the check
+    instead of being dequantised or read as something else."""
+    cfg, params, ref = setup
+    hf, seq = dict(_TINY_MLA_MOE), list(range(1, 9))
+    first = dict(params["experts"][0])
+    first["we_g"] = jnp.round(first["we_g"] * 100).astype(jnp.int8)
+    held8 = dict(params, experts=[first] + list(params["experts"][1:]))
+    with pytest.raises(ValueError, match=r"we_g.*held as int8"):
+        ref.logprobs(hf, held8, seq, [7])
+    dense = llama.init_params(ModelConfig.tiny(dtype="float32"), 0)
+    with pytest.raises(ValueError, match="not this block's"):
+        ref.logprobs(hf, dense, seq, [7])
+
+
+def test_the_local_fault_control_moves_one_position_only(setup):
+    """``lane_swap`` (one compared position answers with its neighbour's
+    state) is what the check's MAX limit is set against: the other
+    positions do not move at all."""
+    cfg, params, ref = setup
+    seq = np.random.RandomState(6).randint(1, 256, 40).tolist()
+    pos = list(range(28, 36))
+    sound = ref_logits(ref, params, seq, pos)
+    off = ref.logprobs(dict(_TINY_MLA_MOE), params, seq, pos,
+                       control="lane_swap")
+    moved = np.abs(off - sound).max(-1)
+    assert moved[4] > 100 * TOL["atol"]
+    np.testing.assert_array_equal(np.delete(off, 4, 0),
+                                  np.delete(sound, 4, 0))
+    np.testing.assert_array_equal(off[4], sound[3])
+
+
+async def test_engine_serves_the_block_and_counts_its_routing(setup):
+    """Through TpuEngine (prefill bucket, fused rounds, ring flush, fused
+    seal, prefix reuse): greedy tokens equal the hand-driven loop, and
+    the routing counters ride the round's fetch."""
+    cfg, params, _ = setup
+    ecfg = EngineConfig(num_pages=32, page_size=PS, max_pages_per_seq=8,
+                        max_decode_slots=4, prefill_buckets=(32, 64),
+                        cache_dtype="float32")
+    eng = TpuEngine(cfg, ecfg, params=params, mesh_config=MeshConfig(tp=1))
+    prompt = list(range(1, 41))
+
+    async def collect():
+        req = PreprocessedRequest(
+            token_ids=list(prompt),
+            stop_conditions=StopConditions(max_tokens=10, ignore_eos=True))
+        return [t async for out in eng.generate(req) for t in out.token_ids]
+
+    toks = await collect()
+    ctx = llama.init_ctx(cfg, 1, ecfg.max_context, jnp.float32)
+    ctx, logits = llama.prefill(
+        cfg, params, ctx, padded(prompt, 64), jnp.int32(0), jnp.int32(0),
+        jnp.int32(len(prompt)), fresh=True)
+    want, _, _ = decode_steps(cfg, params, ctx, logits, len(prompt), 9)
+    assert toks == want
+    assert await collect() == want          # served from the sealed prefix
+    assert eng.allocator.hit_blocks >= 1
+    snap = eng.telemetry.snapshot()
+    rounds = snap["dynamo_moe_tokens_routed"]["count"]
+    r = cfg.routed_dict
+    per_round = (ecfg.flush_every * r["num_experts_per_tok"]
+                 * (cfg.num_layers - r["first_k_dense_replace"]))
+    assert rounds > 0
+    assert snap["dynamo_moe_tokens_routed"]["sum"] == rounds * per_round
+    assert 0 < snap["dynamo_moe_experts_touched"]["sum"] <= rounds * per_round
+    assert snap["dynamo_kv_row_bytes"]["sum"] == mla_moe.kv_row_bytes(cfg, 4)
+    await eng.stop()
+
+
+def test_the_byte_count_and_the_kernels_roofline_reader_by_hand():
+    """benchmarks/bytes/mla_moe.py and layer_metrics/kernel.gmm_roofline:
+    plain arithmetic on counters and a reduced trace, no JAX; a parent
+    program without the counters or the kernel reads nothing."""
+    import json
+
+    def load(*parts):
+        path = os.path.join(REPO, "benchmarks", *parts)
+        spec = importlib.util.spec_from_file_location(
+            "bench_" + parts[-1][:-3].replace(".", "_"), path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod
+
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "mla-moe-joyai-d5.json")) as f:
+        cfg = json.load(f)
+    count = load("bytes", "mla_moe.py")
+    reader = load("layer_metrics", "kernel.gmm_roofline.py").read
+    s = count.shapes(cfg)
+    assert s["expert"] == 3 * 2048 * 768 and s["n_expert_layers"] == 4
+    assert s["attn"] == 26_345_472 and s["row"] == 576      # ISSUE's 26.35 M
+
+    def hist(total, n):
+        return {"sum": total, "count": n}
+
+    # 100 rounds x 4 steps; 800 experts touched and 1600 picks a step
+    src = {
+        "config": cfg, "engine_up": {"flush_every": 4,
+                                     "device_kind": "TPU v5 lite"},
+        "before": {"histograms": {
+            "dynamo_moe_experts_touched": hist(0.0, 0),
+            "dynamo_moe_tokens_routed": hist(0.0, 0)}},
+        "after": {"histograms": {
+            "dynamo_moe_experts_touched": hist(320_000.0, 100),
+            "dynamo_moe_tokens_routed": hist(640_000.0, 100)}},
+        "peaks": load("peaks.py"), "byname": load("byname.py"),
+        # 10 rounds in the span: 40 steps, 0.4 s in decode-shaped calls
+        "trace": {"modules": {"jit_engine_round_seal": {"count": 10,
+                                                        "seconds": 0.6}},
+                  "kernels": {"gmm bf16[512,768]": 0.25,
+                              "gmm bf16[512,2048]": 0.15,
+                              "gmm bf16[8192,768]": 9.9}},
+    }
+    weights = (5 * s["attn"] + s["dense_mlp"] + 4 * (s["shared"] + s["router"])
+               + s["head"] + 800 * s["expert"]) * 2
+    assert count.decode_bytes_per_step(src, [100.0, 5000.0]) == (
+        weights + (100 + 4096) * 576 * 5 * 2)
+    gmm_bytes = 800 * s["expert"] * 2
+    assert reader(src) == pytest.approx(
+        gmm_bytes / 819e9 / (0.4 / 40) * 100.0)              # ~92 %
+    # the parent: no counters, or no such kernel in its trace
+    bare = dict(src, before={"histograms": {}}, after={"histograms": {}})
+    assert reader(bare) is None
+    assert count.decode_bytes_per_step(bare, [100.0]) == (
+        weights - 800 * s["expert"] * 2 + 100 * 576 * 5 * 2)
+    assert reader(dict(src, trace=dict(src["trace"], kernels={}))) is None
+    assert reader(dict(src, config={"engine": {}})) is None

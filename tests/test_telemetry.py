@@ -108,6 +108,8 @@ def test_registry_render_and_snapshot():
         "dynamo_engine_prefill_attn_scored_pairs",
         "dynamo_engine_round_live_lane_steps",
         "dynamo_engine_round_tokens",
+        "dynamo_moe_experts_touched", "dynamo_moe_tokens_routed",
+        "dynamo_moe_expert_load_max", "dynamo_kv_row_bytes",
     }
     reg.get("dynamo_request_ttft_seconds").observe(0.2)
     text = reg.render()

@@ -1,0 +1,93 @@
+#!/usr/bin/env python
+"""Sweep an open-loop cell's offered rate on the chip and say where its
+backlog stops being bounded (PERF.md section 4: the knee; the cell then
+offers 0.8 of it).
+
+For each rate: writes ``benchmarks/cells/<cell>.json`` IN THE CHIP
+MACHINE'S COPY (never committed from there), runs ``benchmarks/run.py``
+once, and reads the request log it leaves. One JSON line per rate with
+TTFT percentiles of the window's first and second half (a backlog that
+grows through the window shows as a second half far above the first),
+the tokens completed per second against the tokens the schedule asked
+for, and the end-to-end metrics. No JAX here.
+
+  chiprun --timeout 3000 -- python3 tools/knee_sweep.py \
+      --workload mla-moe-joyai-d5.chat-decode --rates 4 6 8 10
+"""
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import subprocess
+import sys
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(REPO, "benchmarks"))
+
+import stats  # noqa: E402
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--rates", type=float, nargs="+", required=True)
+    ap.add_argument("--seconds", type=float, default=50.0)
+    ap.add_argument("--seed", type=int, default=3100000001)
+    args = ap.parse_args()
+    cell_file = os.path.join(REPO, "benchmarks", "cells",
+                             args.workload + ".json")
+    with open(cell_file) as f:
+        kept = f.read()
+    try:
+        for i, rate in enumerate(args.rates):
+            with open(cell_file, "w") as f:
+                json.dump({"rate_rps": rate}, f)
+            r = subprocess.run(
+                [sys.executable, os.path.join(REPO, "benchmarks", "run.py"),
+                 "--workload", args.workload, "--seed", str(args.seed + i),
+                 "--seconds", str(args.seconds), "--trace", "0"],
+                cwd=REPO, capture_output=True, text=True)
+            rec = {"rate_rps": rate, "rc": r.returncode}
+            if r.returncode != 0:
+                rec["stderr"] = r.stderr[-1500:]
+                print(json.dumps(rec), flush=True)
+                continue
+            line = json.loads(r.stdout.strip().splitlines()[-1])
+            with open(os.path.join(REPO, "chiprun_out", "bench",
+                                   args.workload, "requests.json")) as f:
+                log = json.load(f)
+            mine = [q for q in log if stats.of_window(q, args.seconds)
+                    and q["ok"]]
+            half = args.seconds / 2
+            for tag, part in (("first_half", [q for q in mine
+                                              if q["due"] < half]),
+                              ("second_half", [q for q in mine
+                                               if q["due"] >= half])):
+                t = [stats.ttft_s(q) * 1e3 for q in part]
+                if t:
+                    rec[f"ttft_ms_p50_{tag}"] = stats.percentile(t, 0.5)
+                    rec[f"ttft_ms_p90_{tag}"] = stats.percentile(t, 0.9)
+            gen = stats.reduce_log(log, args.seconds)
+            asked = sum(q["asked"] for q in log
+                        if stats.of_window(q, args.seconds))
+            rec.update(
+                correct=line["correct"], attempted=line["attempted"],
+                failed=line["failed"], offered_tok_s=asked / args.seconds,
+                tok_s=gen.get("tok_s"), tpot_ms_p50=gen.get("tpot_ms_p50"),
+                tpot_ms_p90=gen.get("tpot_ms_p90"),
+                ttft_ms_p90=gen.get("ttft_ms_p90"),
+                late_ms_p90=gen.get("late_ms_p90"),
+                last_finish_s=max((q["chunks"][-1] for q in log
+                                   if q["chunks"]), default=None),
+                setup_s=line["metrics"]["setup_s"]["value"],
+                memory_peak_bytes=line["device"]["memory_peak_bytes"])
+            print(json.dumps(rec), flush=True)
+    finally:
+        with open(cell_file, "w") as f:
+            f.write(kept)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
