@@ -46,7 +46,18 @@ import procs  # noqa: E402
 import stats  # noqa: E402
 from procs import BenchFailure  # noqa: E402
 
+# Every fixed wait of a run (README.md has the table). A limit is there to
+# end a run whose child hangs; `procs.wait_for` fails at once when the child
+# dies. None is sized to today's program: what grows with the model's SIZE
+# (start-up) or with the program's SPEED (a faster program puts more events
+# into the traced span, so collecting and reducing them takes longer) gets
+# several times the longest wait seen on the chip (PERF.md section 3).
 START_TIMEOUT_S = 1000.0      # a cold start compiles a dozen whole models
+HTTP_UP_TIMEOUT_S = 120.0
+SNAPSHOT_TIMEOUT_S = 60.0
+TRACE_COLLECT_TIMEOUT_S = 1000.0   # after the window, for `trace done`
+SERVER_EXIT_TIMEOUT_S = 60.0
+TRACE_REDUCE_TIMEOUT_S = 1000.0
 TRACE_DELAY_S = 4.0           # into the window
 TRACE_SECONDS = 3.0
 _LINE = {k: re.compile(rf"^{k}: (\{{.*\}})$", re.M)
@@ -83,9 +94,18 @@ def snapshot(child: procs.Child, out_dir: str, tag: str) -> dict:
         os.remove(path)
     child.tell(f"snapshot {path}")
     procs.wait_for(f"the {tag} snapshot", child,
-                   lambda: os.path.exists(path), 60.0)
+                   lambda: os.path.exists(path), SNAPSHOT_TIMEOUT_S)
     with open(path) as f:
         return json.load(f)
+
+
+def collected_trace(server: procs.Child) -> dict:
+    """The server's `trace done` line: the profiler has handed over the
+    span and the trace is on disk. A server that is alive is collecting and
+    is waited for; one that died fails the run at once."""
+    return procs.wait_for("the profiler to collect the trace", server,
+                          lambda: said(server, "trace done"),
+                          TRACE_COLLECT_TIMEOUT_S)
 
 
 def reduce_trace(trace_dir: str, out_dir: str) -> dict:
@@ -94,8 +114,17 @@ def reduce_trace(trace_dir: str, out_dir: str) -> dict:
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
     cmd = [sys.executable, os.path.join(HERE, "trace_reduce.py"),
            "--reduce", trace_dir, "--out", out]
-    r = subprocess.run(cmd, env=env, cwd=REPO, capture_output=True,
-                       text=True, timeout=600)
+    try:
+        r = subprocess.run(cmd, env=env, cwd=REPO, capture_output=True,
+                           text=True, timeout=TRACE_REDUCE_TIMEOUT_S)
+    except subprocess.TimeoutExpired as e:      # run() has killed the child
+        tail = e.stderr or b""          # bytes here, whatever `text` says
+        if isinstance(tail, bytes):
+            tail = tail.decode(errors="replace")
+        raise BenchFailure(
+            f"the trace reduction (trace_reduce.py) did not finish within "
+            f"{TRACE_REDUCE_TIMEOUT_S:g}s; the end of what it said:\n"
+            f"{tail[-2000:]}") from None
     if r.returncode != 0:
         raise BenchFailure(f"trace reduction failed:\n{r.stderr[-2000:]}")
     with open(out) as f:
@@ -156,7 +185,7 @@ def run(args) -> dict:
         procs.wait_for("the HTTP service", server,
                        lambda: said(server, "serving") and procs.http_json(
                            port, "GET", "/health", timeout=5)[0] == 200,
-                       120.0)
+                       HTTP_UP_TIMEOUT_S)
         warm = loadgen.warm_up(port, mix, vocab, args.seed)
         t_warm = time.monotonic()
         warm_failed = [r for r in warm if not r["ok"]]
@@ -189,10 +218,11 @@ def run(args) -> dict:
         entries1 = cache_entries(up["compile_cache"])
         traced = None
         if args.trace:
-            traced = procs.wait_for("the trace", server,
-                                    lambda: said(server, "trace done"), 120.0)
+            t_close = time.monotonic()
+            traced = collected_trace(server)
+            trace_wait_s = time.monotonic() - t_close
         server.tell("stop")
-        rc = server.wait(60.0)
+        rc = server.wait(SERVER_EXIT_TIMEOUT_S)
         if rc != 0 or "engine round failed" in server.log_text():
             raise BenchFailure(f"server exited {rc} or logged a failed "
                                f"round; tail:\n{server.log_text()[-2000:]}")
@@ -221,7 +251,25 @@ def run(args) -> dict:
         "tpot_ms_p50": gen.get("tpot_ms_p50"),
         "errors": sorted({r["error"] for r in log if r["error"]})[:5],
     }
+    trace = reduce_failure = None
+    if args.trace:
+        t_reduce = time.monotonic()
+        try:
+            trace = reduce_trace(trace_dir, out_dir)
+        except BenchFailure as e:
+            reduce_failure = e      # after the notes, which say how long
+        shutil.rmtree(trace_dir, ignore_errors=True)   # large; never kept
+        # how near each wait of a traced run came to its limit
+        notes.update(
+            trace_collect_s=traced["collect_s"],
+            trace_wait_s=round(trace_wait_s, 3),
+            trace_wait_limit_s=TRACE_COLLECT_TIMEOUT_S,
+            trace_reduce_s=round(time.monotonic() - t_reduce, 3),
+            trace_reduce_limit_s=TRACE_REDUCE_TIMEOUT_S)
+    notes["elapsed_s"] = round(time.monotonic() - T_START, 3)
     print("notes: " + json.dumps(notes), flush=True)
+    if reduce_failure is not None:
+        raise reduce_failure
     # every number `correct` compared, beside its limit, as the last lines
     # of stderr: what the driver's record keeps of a run that is not correct
     for name, got, rel, limit in (
@@ -251,8 +299,6 @@ def run(args) -> dict:
                                       "unit": m["unit"]}
         return result
 
-    trace = reduce_trace(trace_dir, out_dir)
-    shutil.rmtree(trace_dir, ignore_errors=True)   # large; never kept
     if not trace.get("busy_s"):
         raise BenchFailure("the trace shows no op on the device")
     # the traced span on the generator's clock (both processes read the

@@ -54,6 +54,7 @@ import os
 import sys
 import threading
 import time
+import traceback
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
@@ -336,25 +337,55 @@ def snapshot(engine, watch: CompileWatch) -> dict:
     }
 
 
+def take_trace(path: str, delay_s: float, seconds: float) -> dict:
+    """A profiler trace of ``seconds``, ``delay_s`` from now, left as
+    ``<path>/plugins/profile/span/server.xplane.pb`` (where
+    ``trace_reduce.find_xplane`` looks). The device planes are what is
+    read: no Python tracer (it slows the engine's host loop and bloats the
+    file), least host tracing.
+
+    ``jax.profiler.start_trace`` / ``stop_trace`` are this session and
+    ``stop_and_export``, which besides the ``.xplane.pb`` converts every
+    event into a ``trace.json.gz`` that nothing here reads: on the chip a
+    fifth of the collection of cell 1's span and two thirds of the four-chip
+    cell's (95 of 146 s; PERF.md section 3), growing with the events, i.e.
+    with the program's SPEED. So the session is held here and its bytes are
+    written as they come. What is left, ``stop()``, is one CPU-bound thread
+    inside the profiler (22-24 s on one chip, 52-61 s on four), and no
+    ``ProfileOptions`` setting of JAX 0.9.0 shortens it: ``host_tracer_level``
+    0, ``enable_hlo_proto`` off and ``tpu_trace_mode`` were tried."""
+    import jax
+    from jax._src.lib import _profiler   # what jax.profiler itself drives
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    time.sleep(delay_s)
+    session = _profiler.ProfilerSession(opts)
+    t0 = time.time()
+    time.sleep(seconds)
+    t1 = time.time()
+    xspace = session.stop()             # collecting takes minutes
+    out_dir = os.path.join(path, "plugins", "profile", "span")
+    os.makedirs(out_dir, exist_ok=True)
+    with open(os.path.join(out_dir, "server.xplane.pb"), "wb") as f:
+        f.write(xspace)
+    return {"dir": path, "t_start_wall": t0, "t_stop_wall": t1,
+            "collect_s": round(time.time() - t1, 2),
+            "xspace_bytes": len(xspace)}
+
+
 def command_loop(engine, watch, stop_evt: threading.Event, loop) -> None:
     """Reads the parent's commands from stdin (a thread of its own)."""
-    import jax
 
     def trace(path: str, delay_s: float, seconds: float) -> None:
-        # the device planes are what is read: no Python tracer (it slows
-        # the engine's host loop and bloats the file), least host tracing
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        opts.host_tracer_level = 1
-        time.sleep(delay_s)
-        jax.profiler.start_trace(path, profiler_options=opts)
-        t0 = time.time()
-        time.sleep(seconds)
-        t1 = time.time()
-        jax.profiler.stop_trace()   # collecting takes many seconds
-        say("trace done", {"dir": path, "t_start_wall": t0,
-                           "t_stop_wall": t1,
-                           "collect_s": round(time.time() - t1, 2)})
+        try:
+            say("trace done", take_trace(path, delay_s, seconds))
+        except Exception:
+            # no trace, no result line: die where the parent sees it at
+            # once, not after its whole wait for `trace done`
+            traceback.print_exc()
+            os._exit(1)
 
     for line in sys.stdin:
         words = line.split()

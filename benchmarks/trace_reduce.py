@@ -27,8 +27,11 @@ and ``TC Overlay`` (not read); host threads are planes ``/host:CPU``.
 from __future__ import annotations
 
 import argparse
+import bisect
+import functools
 import glob
 import gzip
+import itertools
 import json
 import os
 import re
@@ -110,6 +113,10 @@ def overlap(a: list[tuple[int, int]], b: list[tuple[int, int]]) -> int:
     return n
 
 
+# A span holds a million events under some ten thousand names, and reducing
+# it is part of every traced run: each function of a name is worked out once
+# (`functools.cache` on op_kind, op_label, op_code).
+@functools.cache
 def op_kind(name: str) -> str:
     """An op event is named by its HLO text, ``%copy.12 = bf16[..]{..}
     copy(..)`` (or just ``copy.12``): -> ``copy``."""
@@ -127,6 +134,7 @@ def _typed_rhs(name: str) -> str:
     return re.sub(r"\{[^}]*\}", "", rhs)
 
 
+@functools.cache
 def op_label(name: str) -> str:
     """Kind and output type without layouts: the 32 per-layer copies of
     one unrolled op get one label, ``fusion (f32[32,1024], f32[..])``."""
@@ -136,6 +144,7 @@ def op_label(name: str) -> str:
     return (op_kind(name) + " " + shape).strip()[:120]
 
 
+@functools.cache
 def op_code(name: str) -> str:
     """The HLO opcode of an op event named by its HLO text: ``%x.1 =
     bf16[8]{0} custom-call(..)`` -> ``custom-call``; a bare name -> ''."""
@@ -146,6 +155,44 @@ def op_code(name: str) -> str:
 def module_base(name: str) -> str:
     """``jit_engine_round_seal(123456)`` -> ``jit_engine_round_seal``."""
     return name.split("(", 1)[0]
+
+
+def label_gaps(mods: list[tuple], busy: list[tuple[int, int]], w0: int,
+               w1: int) -> dict[str, int]:
+    """One chip's idle nanoseconds inside [w0, w1), by the modules around
+    each gap of its ``busy`` cover: ``inside <m>`` for the first module (by
+    start; ``mods`` is sorted by it) that spans the gap, else ``<the last
+    module that ended before it> -> <the first that starts after it>``
+    (``start`` / ``end`` where there is none).
+
+    A chip that is never idle for long still has a gap of nanoseconds after
+    most of its ops, a million to a span, so each side is found by
+    bisection and not by a pass over the modules: ``spans_to[i]`` is the
+    latest end among modules 0..i (it first reaches a time at the first
+    module that does), ``ended[j]`` the last module among the j that end
+    earliest. ``test_trace_reduce.py`` holds it to the plain passes."""
+    starts = [s for _, s, _ in mods]
+    spans_to = list(itertools.accumulate((e for _, _, e in mods), max))
+    by_end = sorted((e, i) for i, (_, _, e) in enumerate(mods))
+    ends = [e for e, _ in by_end]
+    ended = list(itertools.accumulate((i for _, i in by_end), max))
+    gaps: dict[str, int] = {}
+    edges = [w0] + [x for s, e in busy for x in (s, e)] + [w1]
+    for g0, g1 in zip(edges[0::2], edges[1::2]):
+        if g1 <= g0:
+            continue
+        began = bisect.bisect_right(starts, g0)
+        inside = bisect.bisect_left(spans_to, g1, 0, began)
+        if inside < began:
+            label = f"inside {module_base(mods[inside][0])}"
+        else:
+            before = bisect.bisect_right(ends, g0 + 1)
+            after = bisect.bisect_left(starts, g1 - 1)
+            left = mods[ended[before - 1]][0] if before else "start"
+            right = mods[after][0] if after < len(mods) else "end"
+            label = f"{module_base(left)} -> {module_base(right)}"
+        gaps[label] = gaps.get(label, 0) + (g1 - g0)
+    return gaps
 
 
 def reduce_events(events: list[Event]) -> dict:
@@ -191,20 +238,8 @@ def reduce_events(events: list[Event]) -> dict:
             m = modules.setdefault(module_base(n), {"count": 0, "ns": 0})
             m["count"] += 1
             m["ns"] += e - s
-        # idle gaps, labelled by the modules on either side
-        edges = [w0] + [x for s, e in busy for x in (s, e)] + [w1]
-        for g0, g1 in zip(edges[0::2], edges[1::2]):
-            if g1 <= g0:
-                continue
-            before = [n for n, s, e in mods if e <= g0 + 1]
-            after = [n for n, s, e in mods if s >= g1 - 1]
-            inside = [n for n, s, e in mods if s <= g0 and e >= g1]
-            if inside:
-                label = f"inside {module_base(inside[0])}"
-            else:
-                label = (f"{module_base(before[-1]) if before else 'start'}"
-                         f" -> {module_base(after[0]) if after else 'end'}")
-            gaps[label] = gaps.get(label, 0) + (g1 - g0)
+        for label, ns in label_gaps(mods, busy, w0, w1).items():
+            gaps[label] = gaps.get(label, 0) + ns
     n = len(chips)
     busiest = max(c["busy_s"] for c in per_chip.values())
     idlest = min(c["busy_s"] for c in per_chip.values())
