@@ -438,7 +438,7 @@ class TpuEngine:
             llama.ctx_shardings(c, self.mesh, kv_quant=e.kv_quant),
         )
         # decode write ring: the round's steps write here; flush_ctx
-        # scatters it into the ctx region once per round (keeping the
+        # writes it into the ctx region once per round (keeping the
         # GB-scale region read-only inside the round — see llama.init_ring)
         self.ring = jax.tree.map(
             lambda x, s: jax.device_put(x, s),
@@ -834,8 +834,8 @@ class TpuEngine:
             )
             if routed:
                 toks_out = toks_out.at[n_steps, :3].set(moe_stats)
-            # round boundary: scatter the ring into the ctx region
-            # (single write, after every read — aliases in place)
+            # round boundary: the ring goes into the ctx region, one
+            # in-place span a lane, after every read (llama.flush_ctx_impl)
             valid = jnp.minimum(jnp.int32(n_steps), max_context - ring_base)
             ctx_kv = llama.flush_ctx_impl(
                 ctx_kv, ring, dev["dest"], ring_base, valid

@@ -7,8 +7,14 @@ Design notes (TPU-first, round-4 layout):
     lax.scan carry defeats).
   - SERVING CONTEXT is contiguous per slot: ``ctx_kv [L, kv_heads, B+1,
     S_max, head_dim]`` — slot b's tokens live at [.., b, 0:ctx). Decode
-    scatters one row per slot per step and attention streams dense slabs
-    (ops/flash_decode.py); prefill writes a contiguous span. Lane B is a
+    writes one row per slot per step into a small ring, flushed into the
+    region once a round as one span a lane (flush_ctx), and attention
+    streams dense slabs (ops/flash_decode.py); prefill writes a
+    contiguous span. Writers of the region slice and update SPANS
+    (dynamic_slice / dynamic_update_slice in a rolled loop): a scatter or
+    a gather over (lane, position) indices makes XLA:TPU relayout the
+    whole region and copy it back, whatever the donation (the dense
+    cells' traces, PERF.md §6, PR 34). Lane B is a
     SCRATCH lane: freed slots' in-flight garbage steps are redirected
     there (``dest`` argument), so a slot being prefilled for a new request
     is never corrupted by a stale pipelined step.
@@ -31,10 +37,9 @@ THE SEAM (ROADMAP D2). These names are what the engine and the
 benchmark's launcher call. A ``ModelConfig`` with ``mla`` set is the
 latent-attention + routed-expert block of models/mla_moe.py: the
 parameter, state and prefill functions below hand over to that module.
-Of the movers, flush_ctx and seal_blocks hand a latent region to that
-module's in-place span forms and keep their K/V bodies as they were;
-load_ctx_pages carries whatever ROW KINDS a region holds (``row_kinds``:
-``k`` and ``v`` of [kvh, hd] here, one ``kv`` row there). The decode step
+The movers (flush_ctx, seal_blocks, load_ctx_pages) carry whatever ROW
+KINDS a region holds (``row_kinds``: ``k`` and ``v`` of [kvh, hd] here,
+one ``kv`` row there). The decode step
 has ONE entry per block: ``decode_step_impl`` here, and
 ``mla_moe.decode_step_impl`` (which also returns the routing counters)
 for the latent block; the engine's round picks. Functions of planes that
@@ -402,12 +407,12 @@ def init_ring(
     """Per-slot decode write ring ``[L, kv_heads, B, R, head_dim]``.
 
     Decode steps write their token's KV here (a cheap small-buffer
-    update); ``flush_ctx`` scatters a full ring into the ctx region once
-    per round. This keeps the GB-scale ctx region READ-ONLY inside the
-    round program — per-layer writes interleaved with the attention
-    custom calls force XLA to materialize full copies of it (measured:
-    ~7 GB temps, 120 ms/step). Ring slot r of lane b holds the token at
-    position ``ring_base[b] + r``.
+    update); ``flush_ctx`` writes a full ring into the ctx region once
+    per round, one span a lane. This keeps the GB-scale ctx region
+    READ-ONLY inside the round program — per-layer writes interleaved
+    with the attention custom calls force XLA to materialize full copies
+    of it (measured: ~7 GB temps, 120 ms/step). Ring slot r of lane b
+    holds the token at position ``ring_base[b] + r``.
     """
     c = config
     if c.mla is not None:
@@ -1265,11 +1270,19 @@ def flush_ctx_impl(
     ring_base: jnp.ndarray,  # [B] int32
     valid_len: jnp.ndarray,  # [B] int32 — #real tokens in the ring per slot
 ) -> Cache:
-    """Scatter a full ring into the ctx region (once per round, AFTER all
-    of the round's reads — the single write aliases in place under
-    donation). Ring entry (b, r) holds position ring_base[b]+r and goes to
-    lane dest[b]; entries beyond valid_len[b], beyond the region length,
-    or belonging to freed slots are redirected to the scratch lane.
+    """Write a full ring into the ctx region, once per round AFTER all of
+    the round's reads. Ring entry (b, r) holds position ring_base[b]+r and
+    goes to lane dest[b]; entries at or past valid_len[b], or past the
+    region's end, leave the region's rows as they were, and freed slots
+    (dest == B) write the scratch lane.
+
+    Every row kind the ring holds (``k`` and ``v`` of [kvh, hd], or the
+    latent block's one ``kv`` row) moves as an in-place SPAN a lane: slice
+    the R rows the lane's entries cover out of the region, overlay the
+    valid entries, write the span back, in a loop rolled over the lanes.
+    A lane's R entries are contiguous in the region whatever the number of
+    heads, so nothing else is touched (a scatter over (lane, position)
+    would relayout the whole region: module header).
 
     Quantized regions (ctx_is_quantized) instead requantize the minimal
     group-aligned WINDOW around each lane's ring span: gather old int8
@@ -1278,32 +1291,32 @@ def flush_ctx_impl(
     lane's own prefix + the new entries — never stale suffix bytes), and
     scatter int8 + scales back. Still one fused pass inside the round
     program — zero extra dispatches."""
-    if mla_moe.ROW in ring:
-        return mla_moe.flush_ctx_impl(ctx_kv, ring, dest, ring_base,
-                                      valid_len)
-    L, kvh, B, R, hd = ring["k"].shape
-    S = ctx_kv["k"].shape[3]
-    scratch = ctx_kv["k"].shape[2] - 1
-    r_idx = jnp.arange(R, dtype=jnp.int32)[None, :]   # [1, R]
-    pos = ring_base[:, None] + r_idx                  # [B, R]
-    valid = (r_idx < valid_len[:, None]) & (pos < S)
     if ctx_is_quantized(ctx_kv):
-        return _flush_ctx_quant(ctx_kv, ring, dest, ring_base, valid_len,
-                                valid)
-    lane = jnp.where(valid, dest[:, None], scratch)   # [B, R]
-    pos = jnp.where(valid, pos, 0)
-    lflat = lane.reshape(-1)                          # [B*R]
-    pflat = pos.reshape(-1)
+        return _flush_ctx_quant(ctx_kv, ring, dest, ring_base, valid_len)
+    B, R = _any_row(ring).shape[2:4]
+    S = _any_row(ctx_kv).shape[3]
+    i = jnp.arange(R, dtype=jnp.int32)
 
-    out = {}
-    for name in ("k", "v"):
-        buf = ctx_kv[name]
-        upd = ring[name].transpose(0, 2, 3, 1, 4).reshape(L, B * R, kvh, hd)
-        for l in range(L):
-            # advanced dims ([B*R]) lead: target [B*R, kvh, hd]
-            buf = buf.at[l, :, lflat, pflat].set(upd[l])
-        out[name] = buf
-    return out
+    def span(buf, src, b):
+        # the span never runs off the region's end: it starts at most at
+        # S - R and the entries are shifted inside it
+        size = src.shape[:2] + (1, R) + src.shape[4:]
+        start = jnp.clip(ring_base[b], 0, S - R)
+        at = (0, 0, dest[b], start, 0)
+        old = jax.lax.dynamic_slice(buf, at, size)
+        new = jax.lax.dynamic_slice(src, (0, 0, b, 0, 0), size)
+        entry = i - (ring_base[b] - start)
+        ok = (entry >= 0) & (entry < valid_len[b])
+        new = jnp.take(new, jnp.clip(entry, 0, R - 1), axis=3)
+        new = jnp.where(ok[None, None, None, :, None],
+                        new.astype(buf.dtype), old)
+        return jax.lax.dynamic_update_slice(buf, new, at)
+
+    def lane(b, bufs):
+        return {n: span(buf, ring[n], b) for n, buf in bufs.items()}
+
+    return jax.lax.fori_loop(
+        0, B, lane, {n: ctx_kv[n] for n in row_kinds(ring)})
 
 
 def _flush_ctx_quant(
@@ -1312,11 +1325,13 @@ def _flush_ctx_quant(
     dest: jnp.ndarray,       # [B] i32 (freed slots -> scratch lane)
     ring_base: jnp.ndarray,  # [B] i32
     valid_len: jnp.ndarray,  # [B] i32
-    valid: jnp.ndarray,      # [B, R] bool — precomputed entry validity
 ) -> Cache:
     """Ring flush into an int8 ctx region (see flush_ctx_impl doc)."""
     L, kvh, B, R, hd = ring["k"].shape
     lanes, S = ctx_kv["k"].shape[2], ctx_kv["k"].shape[3]
+    r_idx = jnp.arange(R, dtype=jnp.int32)[None, :]            # [1, R]
+    valid = ((r_idx < valid_len[:, None])
+             & (ring_base[:, None] + r_idx < S))               # [B, R]
     g = ctx_group_size(ctx_kv)
     nG = S // g
     # window: enough group slots to hold a ring span at any alignment
@@ -1501,18 +1516,34 @@ def seal_blocks_impl(
     entry copies ctx_kv[:, :, slots[i], starts[i]:+ps] into pool page
     pages[i]. Padding rows target scratch page 0 (garbage by contract).
 
-    Quantized pools (cache_is_quantized) quantize in the SAME fused
-    gather: per-(layer, page) absmax scales over the block's
-    [kvh, ps, hd] elements, int8 payload + scale scattered together.
-    When the ctx region is int8 too (same group == page_size grid) the
-    seal degenerates to a RAW int8 copy: blocks and their scales move
+    An unquantised region seals into an unquantised pool as in-place span
+    writes, one ``dynamic_slice`` of the region and one
+    ``dynamic_update_slice`` of the pool an entry and row kind, in a loop
+    rolled over the entries: a block's rows are contiguous in both (a
+    gather over a flat view would copy the whole region: module header).
+
+    Quantized pools (cache_is_quantized) quantize in ONE fused gather:
+    per-(layer, page) absmax scales over the block's [kvh, ps, hd]
+    elements, int8 payload + scale scattered together. When the ctx
+    region is int8 too (same group == page_size grid) the seal
+    degenerates to a RAW int8 copy: blocks and their scales move
     verbatim, no requantize pass at the boundary at all."""
     ps = page_size
-    if mla_moe.ROW in ctx_kv:
-        return mla_moe.seal_blocks_impl(cache, ctx_kv, slots, starts, pages,
-                                        ps)
     pool_q = cache_is_quantized(cache)
     ctx_q = ctx_is_quantized(ctx_kv)
+    if not (pool_q or ctx_q):
+        def block(pool, src, i):
+            rows = jax.lax.dynamic_slice(
+                src, (0, 0, slots[i], starts[i], 0),
+                src.shape[:2] + (1, ps) + src.shape[4:])
+            return jax.lax.dynamic_update_slice(
+                pool, rows.astype(pool.dtype), (0, 0, pages[i], 0, 0))
+
+        def one(i, pools):
+            return {n: block(pool, ctx_kv[n], i) for n, pool in pools.items()}
+
+        return jax.lax.fori_loop(
+            0, slots.shape[0], one, {n: cache[n] for n in row_kinds(ctx_kv)})
     if ctx_q:
         g = ctx_group_size(ctx_kv)
         assert g == ps, (
@@ -1520,11 +1551,9 @@ def seal_blocks_impl(
         )
     out = {}
     for name in ("k", "v"):
-        # ONE gather over the (lane, position)-flattened axis. The
-        # previous vmap(dynamic_index + dynamic_slice) materialized the
-        # full [L, kvh, S, hd] LANE per entry before slicing — at long
-        # context (S 3328, n 512) that is ~28 GB of temps and the seal
-        # program OOMs at compile
+        # ONE gather over the (lane, position)-flattened axis (a
+        # vmap(dynamic_index + dynamic_slice) materialized the full
+        # [L, kvh, S, hd] LANE per entry before slicing)
         src = ctx_kv[name]
         L, kvh, lanes, S, hd = src.shape
         flat = src.reshape(L, kvh, lanes * S, hd)
@@ -1550,19 +1579,17 @@ def seal_blocks_impl(
                 out[name] = cache[name].at[:, :, pages].set(
                     dense.astype(cache[name].dtype))
             continue
-        if pool_q:
-            bf = blocks.astype(jnp.float32)
-            s = jnp.max(jnp.abs(bf), axis=(1, 3, 4)) / 127.0   # [L, n]
-            s = jnp.maximum(s, 1e-8)
-            q = jnp.clip(
-                jnp.round(bf / s[:, None, :, None, None]), -127, 127
-            ).astype(jnp.int8)
-            out[name] = cache[name].at[:, :, pages].set(q)
-            out[name + "_scale"] = (
-                cache[name + "_scale"].at[:, pages].set(s)
-            )
-        else:
-            out[name] = cache[name].at[:, :, pages].set(blocks)
+        # dense region, int8 pool: quantize a page on the way in
+        bf = blocks.astype(jnp.float32)
+        s = jnp.max(jnp.abs(bf), axis=(1, 3, 4)) / 127.0   # [L, n]
+        s = jnp.maximum(s, 1e-8)
+        q = jnp.clip(
+            jnp.round(bf / s[:, None, :, None, None]), -127, 127
+        ).astype(jnp.int8)
+        out[name] = cache[name].at[:, :, pages].set(q)
+        out[name + "_scale"] = (
+            cache[name + "_scale"].at[:, pages].set(s)
+        )
     return out
 
 

@@ -7,11 +7,10 @@ What differs from models/llama.py, and how the engine meets it:
   - CACHE SPEC. A token holds ONE row a layer: ``[c_kv | k_rope]`` after
     norm and RoPE (kv_lora_rank + qk_rope_head_dim values), read by every
     query head. The ctx region, the pool and the ring are therefore
-    ``{"kv": [L, 1, lanes, S, row]}`` — one row kind, one "head". Ring ->
-    region and region -> pool move it as in-place spans (flush_ctx_impl,
-    seal_blocks_impl below, reached through the ``llama`` names); pool ->
-    region goes through ``llama.load_ctx_pages``, which carries whatever
-    row kinds a region holds (``llama.row_kinds``).
+    ``{"kv": [L, 1, lanes, S, row]}`` — one row kind, one "head". The
+    movers (``llama.flush_ctx``, ``seal_blocks``, ``load_ctx_pages``)
+    carry whatever row kinds a region holds (``llama.row_kinds``); the
+    number of heads is in the array's shape.
   - TWO ATTENTION FORMS, one result. A fresh prefill expands K and V from
     the latent (``c_kv W_kvb``) and attends at width 192/128 through the
     shared blocked prefill attention. Decode, and a prefill chunk that
@@ -446,51 +445,3 @@ def decode_step_impl(config, params, ctx_kv, ring, tokens, ctx_lens,
             attn = _unabsorb_o(c, lp, o_lat)
         h, stats = _layer_out(c, params, lp, l, h, attn, live, stats)
     return {ROW: buf}, _logits(c, params, h), stats
-
-
-# ---------------------------------------------------------------------------
-# Movers of the latent row. One "head" makes a lane's R ring entries, and
-# a block's page_size rows, CONTIGUOUS in the region: both move as
-# in-place span writes in a rolled loop, where the K/V movers' scatter and
-# gather forms make XLA copy the whole region (1.7 GB here) per call.
-
-def flush_ctx_impl(ctx_kv, ring, dest, ring_base, valid_len):
-    """Ring -> region, once per round after every read (llama.flush_ctx).
-    Lane b's entry r holds position ring_base[b] + r and goes to lane
-    dest[b]; entries at or past valid_len[b] leave the region's rows as
-    they were."""
-    buf, src = ctx_kv[ROW], ring[ROW]
-    L, _, B, R, row = src.shape
-    S = buf.shape[3]
-    i = jnp.arange(R, dtype=jnp.int32)
-
-    def lane(b, buf):
-        # the span never runs off the region's end: it starts at most at
-        # S - R and the entries are shifted inside it
-        start = jnp.clip(ring_base[b], 0, S - R)
-        at = (0, 0, dest[b], start, 0)
-        old = jax.lax.dynamic_slice(buf, at, (L, 1, 1, R, row))
-        new = jax.lax.dynamic_slice(src, (0, 0, b, 0, 0), (L, 1, 1, R, row))
-        entry = i - (ring_base[b] - start)
-        ok = (entry >= 0) & (entry < valid_len[b])
-        new = jnp.take(new, jnp.clip(entry, 0, R - 1), axis=3)
-        span = jnp.where(ok[None, None, None, :, None], new, old)
-        return jax.lax.dynamic_update_slice(buf, span, at)
-
-    return {ROW: jax.lax.fori_loop(0, B, lane, buf)}
-
-
-def seal_blocks_impl(cache, ctx_kv, slots, starts, pages, page_size):
-    """Region -> pool (llama.seal_blocks): entry i copies lane slots[i]'s
-    rows [starts[i], starts[i] + page_size) into pool page pages[i];
-    padding entries target scratch page 0."""
-    src = ctx_kv[ROW]
-    L, row = src.shape[0], src.shape[4]
-
-    def one(i, pool):
-        block = jax.lax.dynamic_slice(
-            src, (0, 0, slots[i], starts[i], 0), (L, 1, 1, page_size, row))
-        return jax.lax.dynamic_update_slice(
-            pool, block.astype(pool.dtype), (0, 0, pages[i], 0, 0))
-
-    return {ROW: jax.lax.fori_loop(0, slots.shape[0], one, cache[ROW])}

@@ -9,7 +9,14 @@ died with "Mosaic kernels cannot be automatically partitioned", and the
 int8-ctx scale BlockSpecs broke the (8, 128) tiling rule. Mosaic's own
 compile (layout inference, VMEM fit) needs libtpu's compiler: that is
 tools/tpu_compile_check.py (slow-marked below), then chip_smoke.py.
+
+The K/V movers alone compile for compile-only v5e devices in under a
+second, so THAT guard is tier-1: no region-shaped copy in ring -> region
+or region -> pool (a third of the chip in both dense cells until PR 34).
 """
+import os
+import sys
+
 import numpy as np
 import pytest
 
@@ -172,16 +179,62 @@ def test_engine_selection_is_by_device_and_named():
         DecodeAttention("auto")
 
 
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
+import tpu_compile_check  # noqa: E402
+
+MOVERS = ("flush_ctx", "seal_blocks", "flush_seal")
+
+
+@pytest.fixture(scope="module", params=["mistral7b-w8", "nemo12b-tp4"],
+                ids=["tp1_8slots", "tp4_16slots"])
+def mover_records(request):
+    """The movers at the dense cells' K/V shapes (kvh 8, hd 128, S 4096,
+    64-token pages; 8 slots on one chip, 16 over tp=4), 2 layers,
+    compiled by XLA:TPU for compile-only v5e devices."""
+    from jax.experimental import topologies
+    try:
+        topologies.get_topology_desc(
+            platform="tpu", topology_name=tpu_compile_check.TOPOLOGY)
+    except Exception as exc:  # noqa: BLE001 — no libtpu, or it is held
+        pytest.skip(f"no compile-only v5e topology here: {exc!r:.200}")
+    records = tpu_compile_check.compile_programs(
+        config=request.param, layers=2, programs=MOVERS)
+    return dict(zip(MOVERS, records))   # the tool keeps its table's order
+
+
+@pytest.mark.parametrize("program", MOVERS)
+def test_kv_movers_copy_no_region_on_v5e(mover_records, program):
+    """ring -> region, region -> pool, and both in one jit: the compiled
+    text has no ``copy`` the size of a region buffer (5-d or a flat view)
+    and the program's temporaries stay under 5 % of one."""
+    rec = mover_records[program]
+    assert rec["ok"], rec
+    assert rec["region_shard"][1:] in ([8, 9, 4096, 128], [2, 17, 4096, 128])
+    assert rec["region_copies"] == {"count": 0, "shapes": []}, rec
+    assert rec["temp_bytes"] < 0.05 * rec["region_bytes"], rec
+
+
+def test_region_copies_reads_copy_and_copy_start():
+    shard = (2, 2, 17, 4096, 128)
+    text = """
+  %copy.83 = bf16[2,2,17,4096,128]{4,1,3,2,0:T(2,128)(2,1)S(1)} copy(%gte.1)
+  %copy-start.1 = (bf16[2,2,17,4096,128]{4,1,3,2,0:T(2,128)(2,1)}, bf16[2,2,17,4096,128]{4,1,3,2,0}, u32[]{:S(2)}) copy-start(%fusion.6)
+  %copy-done.1 = bf16[2,2,17,4096,128]{4,1,3,2,0:T(2,128)(2,1)} copy-done(%copy-start.1)
+  ROOT %copy.9 = bf16[2,2,69632,128]{3,2,1,0:T(8,128)(2,1)} copy(%bitcast.3)
+  %copy.2 = s32[16]{0:T(128)} copy(%p.3)
+  %fusion.5 = bf16[2,2,17,4096,128]{4,3,2,1,0} fusion(%copy.83), kind=kLoop
+"""
+    assert tpu_compile_check.region_copies(text, shard) == [
+        "bf16[2,2,17,4096,128]", "bf16[2,2,17,4096,128]",
+        "bf16[2,2,69632,128]"]
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("tp,kv_quant", [(1, "none"), (4, "int8")])
 def test_v5e_topology_compile(tp, kv_quant):
-    """Full XLA:TPU + Mosaic compile of the decode step, the flush and a
-    prefill bucket for compile-only v5e devices (cut to 2 layers)."""
-    import os
-    import sys
-
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
-    import tpu_compile_check
+    """Full XLA:TPU + Mosaic compile of the decode step, the flush, the
+    seal, the fused round and a prefill bucket for compile-only v5e
+    devices (cut to 2 layers)."""
 
     # conftest pins matmul precision to "highest" for the CPU goldens;
     # Mosaic refuses a bf16 dot at that precision ("Bad lhs type"), and

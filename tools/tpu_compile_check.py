@@ -8,26 +8,42 @@ v5e devices: shardings over them on ``ShapeDtypeStruct`` arguments take
 CPU-only box. This is the check to run before spending a chip call on a
 kernel or sharding change — a Mosaic refusal ("unsupported shape cast",
 a BlockSpec the tiling rejects, "cannot be automatically partitioned")
-shows up here in seconds. It proves the programs COMPILE; whether they
-compute the right thing, fit HBM at run time, or run fast is the chip's
-to say (chip_smoke.py).
+shows up here in seconds, and so does a mover that makes XLA copy the
+whole K/V region (``region_copies``: a third of the chip in both dense
+cells until PR 34). It proves the programs COMPILE; whether they compute
+the right thing, fit HBM at run time, or run fast is the chip's to say
+(chip_smoke.py).
 
   python tools/tpu_compile_check.py                        # llama3_1b, tp=1
   python tools/tpu_compile_check.py --tp 4 --kv-quant int8
   python tools/tpu_compile_check.py --model-config llama3_8b_int8 --layers 4
+  python tools/tpu_compile_check.py --config mistral7b-w8 --layers 2
+  python tools/tpu_compile_check.py --config nemo12b-tp4 --layers 2 \\
+      --programs flush_ctx,seal_blocks,round_seal --seal-width 512
 
-Compiles, at the CLI's default engine sizes (B 8, S 4096, R 4): one decode
-step (``llama.decode_step`` — every layer's Mosaic call), the ring->ctx
-flush, and the smallest batched prefill bucket (K 8 x T 128, fresh). Prints
-one JSON line per program with XLA's memory analysis; exits 1 if any
-program fails to compile. libtpu warns about ``TPU_ACCELERATOR_TYPE`` /
-worker hostnames on a box with no TPU — harmless here.
+``--config <name>`` takes the model, ``tp``, weight quantisation and
+engine sizes from ``benchmarks/configs/<name>.json`` (what a cell of the
+benchmark runs); without it the CLI's default engine sizes (B 8, S 4096,
+R 4). Compiles one decode step (``llama.decode_step`` — every layer's
+Mosaic call; not for the latent block, whose step is in the round), the
+ring->ctx flush, the standalone ctx->pool seal at ``--seal-width``
+entries, both in one jit, the fused round as the engine builds it
+(``flush_every`` decode steps, flush, seal), the pool -> region load and
+the pool's page gather / scatter at a long prompt's pages, and the
+smallest batched prefill bucket (fresh).
+Prints one JSON line per program with XLA's memory analysis and the
+region-shaped copies in the compiled text; exits 1 if any program fails
+to compile. libtpu warns about ``TPU_ACCELERATOR_TYPE`` / worker hostnames
+on a box with no TPU — harmless here.
 """
 from __future__ import annotations
 
 import argparse
+import hashlib
 import json
+import math
 import os
+import re
 import sys
 import time
 
@@ -35,25 +51,67 @@ import time
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 # repo-root invocation (python tools/tpu_compile_check.py) without install
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
 
 TOPOLOGY = "v5e:2x2"  # the four-chip host; tp=1 uses its first device
 
+# `%copy.3 = bf16[2,8,9,4096,128]{4,1,3,2,0:T(8,128)(2,1)} copy(%p)`, and
+# `copy-start`, whose result is a tuple that leads with the copy's shape
+_COPY = re.compile(
+    r"^\s*(?:ROOT\s+)?%?[\w.\-]+ = \(?(\w+)\[([\d,]*)\]"
+    r"(?:[^\n]*?[)}])? copy(?:-start)?\(", re.M)
+_MOSAIC_BODY = re.compile(r'\\22body\\22: \\22[^\\]*\\22')
 
-def compile_programs(model_config: str, tp: int, kv_quant: str,
-                     layers: int = 0) -> list[dict]:
-    """Compile decode step, flush and one prefill bucket for a tp-wide
-    mesh of compile-only v5e devices. Returns one record per program:
-    {"program", "ok", "seconds", "error" | memory fields}."""
+
+def region_copies(hlo_text: str, *shards: tuple[int, ...]) -> list[str]:
+    """The ``copy`` instructions of a compiled module whose result has as
+    many elements as one chip's shard of a K/V buffer (the ctx region, the
+    pool): the buffer itself (5-d) or any flat view of it.
+    ``["bf16[2,8,9,4096,128]", ...]``"""
+    sizes = {math.prod(s) for s in shards}
+    found = []
+    for m in _COPY.finditer(hlo_text):
+        if m.group(2) and math.prod(map(int, m.group(2).split(","))) in sizes:
+            found.append(f"{m.group(1)}[{m.group(2)}]")
+    return found
+
+
+def compile_programs(model_config: str = "llama3_1b", tp: int = 1,
+                     kv_quant: str = "none", layers: int = 0, *,
+                     config: str = "", programs: tuple[str, ...] = (),
+                     seal_width: int = 0) -> list[dict]:
+    """Compile the serving programs for a tp-wide mesh of compile-only
+    v5e devices. Returns one record per program: {"program", "ok",
+    "seconds", "error" | memory fields, "region_copies"}. ``config`` names
+    a file of benchmarks/configs and overrides ``model_config`` and
+    ``tp``; ``programs`` keeps the named ones only."""
+    import dataclasses
+
     import jax
     import jax.numpy as jnp
     from jax.experimental import topologies
 
     from dynamo_tpu.engine.config import EngineConfig
+    from dynamo_tpu.engine.engine import TpuEngine
     from dynamo_tpu.models import llama
     from dynamo_tpu.models.config import ModelConfig
     from dynamo_tpu.ops.attention import decode_attention_for
     from dynamo_tpu.parallel.mesh import MeshConfig, make_mesh
+
+    engine_kw = {}
+    if config:
+        path = os.path.join(REPO, "benchmarks", "configs", config + ".json")
+        with open(path) as f:
+            cfg = json.load(f)
+        c = ModelConfig.from_hf_dict(cfg)
+        if cfg.get("quant"):
+            c = dataclasses.replace(c, quant=cfg["quant"])
+        tp, engine_kw, model_config = int(cfg["tp"]), cfg["engine"], config
+    else:
+        c = getattr(ModelConfig, model_config)()
+    if layers > 0:
+        c = dataclasses.replace(c, num_layers=layers)
 
     topo = topologies.get_topology_desc(
         platform="tpu", topology_name=TOPOLOGY
@@ -61,9 +119,7 @@ def compile_programs(model_config: str, tp: int, kv_quant: str,
     mesh = make_mesh(MeshConfig(tp=tp), list(topo.devices))
     attn = decode_attention_for(mesh)  # TPU devices: the compiled kernel
 
-    kw = {"num_layers": layers} if layers > 0 else {}
-    c = getattr(ModelConfig, model_config)(**kw)
-    e = EngineConfig(kv_quant=kv_quant)
+    e = EngineConfig(kv_quant=kv_quant, **engine_kw)
     B, S, R = e.max_decode_slots, e.max_context, e.flush_every
     dtype = jnp.dtype(e.cache_dtype)
 
@@ -85,40 +141,130 @@ def compile_programs(model_config: str, tp: int, kv_quant: str,
                                group=e.page_size),
         llama.ctx_shardings(c, mesh, kv_quant=kv_quant),
     )
+    pool = abstract(
+        lambda: llama.init_cache(c, e.num_pages, e.page_size, dtype,
+                                 kv_quant=kv_quant),
+        llama.cache_shardings(c, mesh, kv_quant=kv_quant),
+    )
     ring = abstract(lambda: llama.init_ring(c, B, R, dtype),
                     llama.ring_shardings(c, mesh))
     K, T = e.prefill_batch_max, e.prefill_buckets[0]
 
-    programs = {
+    def largest_shard(state):
+        a = max(jax.tree.leaves(state), key=lambda a: math.prod(a.shape))
+        return a.sharding.shard_shape(a.shape), a.dtype.itemsize
+
+    region_shard, itemsize = largest_shard(ctx)
+    pool_shard, _ = largest_shard(pool)
+
+    # the round AS THE ENGINE BUILDS IT: its jits close over these three
+    # attributes and nothing else of an engine
+    eng = TpuEngine.__new__(TpuEngine)
+    eng.config, eng.ecfg, eng.decode_attn = c, e, attn
+    eng._build_jits()
+    W = eng._seal_fuse_w
+    dev = {
+        "counts": i32(B, c.vocab_size),
+        "keys": jax.ShapeDtypeStruct((B, 2), jnp.uint32, sharding=rep),
+        **{k: i32(B) for k in ("tokens", "ctx", "dest", "top_k", "adapter")},
+        **{k: jax.ShapeDtypeStruct((B,), jnp.float32, sharding=rep)
+           for k in ("temp", "top_p", "freq", "pres", "rep")},
+    }
+    sw = seal_width or W
+    # pool <-> region and pool <-> host movers, at a long prompt's pages
+    n_pages = min(e.max_pages_per_seq, 64)
+    quant = kv_quant == "int8"
+    page_data = jax.ShapeDtypeStruct(
+        (2, c.num_layers, c.num_kv_heads, n_pages, e.page_size, c.head_dim),
+        jnp.int8 if quant else dtype, sharding=jax.sharding.NamedSharding(
+            mesh, jax.sharding.PartitionSpec(None, None, "tp")))
+    page_scales = jax.ShapeDtypeStruct(
+        (2, c.num_layers, n_pages), jnp.float32, sharding=rep)
+
+    table = {
         "decode_step": lambda: llama.decode_step.trace(
             c, params, ctx, ring, i32(B), i32(B), i32(B), i32(), attn=attn,
         ),
         "flush_ctx": lambda: llama.flush_ctx.trace(
             ctx, ring, i32(B), i32(B), i32(B),
         ),
-        f"batch_prefill_K{K}_T{T}": lambda: llama.batch_prefill.trace(
+        "seal_blocks": lambda: llama.seal_blocks.trace(
+            pool, ctx, i32(sw), i32(sw), i32(sw), page_size=e.page_size,
+        ),
+        "flush_seal": lambda: jax.jit(
+            lambda pool, ctx, ring, dest, base, valid, slots, starts, pages:
+            (ctx := llama.flush_ctx_impl(ctx, ring, dest, base, valid),
+             llama.seal_blocks_impl(pool, ctx, slots, starts, pages,
+                                    e.page_size)),
+            donate_argnums=(0, 1),
+        ).trace(pool, ctx, ring, i32(B), i32(B), i32(B),
+                i32(sw), i32(sw), i32(sw)),
+        "round_seal": lambda: eng._engine_round_seal.trace(
+            params, ctx, ring, dev, pool, i32(W), i32(W), i32(W),
+            R, False, False,
+        ),
+        "load_ctx_pages": lambda: llama.load_ctx_pages.trace(
+            ctx, pool, i32(), i32(n_pages),
+        ),
+        "gather_pages": lambda: (
+            llama.gather_pages_q if quant else llama.gather_pages
+        ).trace(pool, i32(n_pages)),
+        "scatter_pages": lambda: (
+            llama.scatter_pages_q.trace(pool, i32(n_pages), page_data,
+                                        page_scales)
+            if quant else
+            llama.scatter_pages.trace(pool, i32(n_pages), page_data)),
+        "batch_prefill": lambda: llama.batch_prefill.trace(
             c, params, ctx, i32(K, T), i32(K), i32(K), i32(K), 0, i32(K),
         ),
     }
+    if c.mla is not None:  # its step is in the round; no page transfer
+        for name in ("decode_step", "gather_pages", "scatter_pages"):
+            del table[name]
+    unknown = sorted(set(programs) - set(table))
+    if unknown:
+        raise ValueError(f"unknown programs {unknown}; have {sorted(table)}")
+    labels = {"seal_blocks": f"seal_blocks_w{sw}",
+              "flush_seal": f"flush_seal_w{sw}",
+              **{n: f"{n}_n{n_pages}" for n in
+                 ("load_ctx_pages", "gather_pages", "scatter_pages")},
+              "round_seal": f"round_seal_n{R}_w{W}",
+              "batch_prefill": f"batch_prefill_K{K}_T{T}"}
     out = []
-    for name, trace in programs.items():
-        rec = {"program": name, "model_config": model_config, "tp": tp,
+    for name, trace in table.items():
+        if programs and name not in programs:
+            continue
+        rec = {"program": labels.get(name, name),
+               "model_config": model_config, "tp": tp,
                "kv_quant": kv_quant, "layers": c.num_layers,
-               "decode_attention": attn.impl}
+               "decode_attention": attn.impl,
+               "region_shard": list(region_shard)}
         t0 = time.monotonic()
         try:
-            compiled = trace().lower().compile()
+            lowered = trace().lower()
+            # equal across two trees = the same program before the
+            # compiler (a Mosaic kernel's serialised body names the
+            # tree's file paths: masked)
+            rec["lowered_sha256"] = hashlib.sha256(_MOSAIC_BODY.sub(
+                "", lowered.as_text()).encode()).hexdigest()[:16]
+            compiled = lowered.compile()
         except Exception as exc:  # noqa: BLE001 — the refusal IS the result
             rec.update(ok=False, error=f"{type(exc).__name__}: {exc}"[:2000])
         else:
             mem = compiled.memory_analysis()
+            text = compiled.as_text()
+            copies = region_copies(text, region_shard, pool_shard)
             rec.update(
                 ok=True,
                 argument_gb=round(mem.argument_size_in_bytes / 1e9, 3),
                 temp_gb=round(mem.temp_size_in_bytes / 1e9, 3),
                 output_gb=round(mem.output_size_in_bytes / 1e9, 3),
                 alias_gb=round(mem.alias_size_in_bytes / 1e9, 3),
-                mosaic_calls=compiled.as_text().count("tpu_custom_call"),
+                temp_bytes=mem.temp_size_in_bytes,
+                region_bytes=math.prod(region_shard) * itemsize,
+                region_copies={"count": len(copies),
+                               "shapes": sorted(set(copies))},
+                mosaic_calls=text.count("tpu_custom_call"),
             )
         rec["seconds"] = round(time.monotonic() - t0, 2)
         out.append(rec)
@@ -130,13 +276,26 @@ def main(argv: list[str] | None = None) -> int:
         prog="tpu_compile_check", description=__doc__,
         formatter_class=argparse.RawDescriptionHelpFormatter)
     ap.add_argument("--model-config", default="llama3_1b")
+    ap.add_argument("--config", default="",
+                    help="a name in benchmarks/configs (overrides "
+                         "--model-config and --tp)")
     ap.add_argument("--tp", type=int, default=1, choices=(1, 2, 4))
     ap.add_argument("--kv-quant", default="none", choices=("none", "int8"))
     ap.add_argument("--layers", type=int, default=0,
                     help="cut depth (0 = the config's own)")
+    ap.add_argument("--programs", default="",
+                    help="comma-separated subset: decode_step, flush_ctx, "
+                         "seal_blocks, flush_seal, round_seal, "
+                         "load_ctx_pages, gather_pages, scatter_pages, "
+                         "batch_prefill")
+    ap.add_argument("--seal-width", type=int, default=0,
+                    help="entries of the standalone seal (0 = the fused "
+                         "round's width)")
     args = ap.parse_args(argv)
     records = compile_programs(
-        args.model_config, args.tp, args.kv_quant, args.layers
+        args.model_config, args.tp, args.kv_quant, args.layers,
+        config=args.config, seal_width=args.seal_width,
+        programs=tuple(p for p in args.programs.split(",") if p),
     )
     for rec in records:
         print(json.dumps(rec))
