@@ -8,8 +8,8 @@ on ONE directory that does not move: a path from ``tempfile``, a pid or
 the clock never hits.
 
 Contract (one call, before the first jit, in every process that compiles
-for the chip: ``launch/run.py`` out=tpu, ``bench.py``,
-``tools/profile_round.py``, ``chip_smoke.py``'s children):
+for the chip: ``launch/run.py`` out=tpu, ``benchmarks/server.py``,
+``chip_smoke.py``'s children):
 
   - ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself; this module
     sets no path in code, so the directory can be placed from outside.

@@ -92,14 +92,8 @@ class RuntimeConfig:
     max_waiting_requests: int = 0
     max_waiting_prefill_tokens: int = 0
     preempt_running: bool = False
-    # double-buffered round pipelining (engine/engine.py _round): hide
-    # host bookkeeping under device execution; off = legacy serialized
-    # round order (the differential-test baseline)
-    round_pipeline: bool = True
-    # performance-attribution plane (telemetry/prof.py): per-round
-    # host-segment timers + the SLO burn-rate gauges
-    # dynamo_slo_{ttft,itl}_burn_rate over these targets
-    prof_attribution: bool = True
+    # performance-attribution plane (telemetry/prof.py): the SLO
+    # burn-rate gauges dynamo_slo_{ttft,itl}_burn_rate over these targets
     slo_ttft_target_s: float = 0.5
     slo_itl_target_s: float = 0.05
     slo_objective: float = 0.99

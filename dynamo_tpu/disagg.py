@@ -195,7 +195,7 @@ class PrefillWorker:
         self.poll_wakeups_saved = 0
         self._commit_evt: Optional[asyncio.Event] = None
         self._commit_cb: Optional[Any] = None
-        # chunk-pipeline stats (bench disagg phase + tests read these):
+        # chunk-pipeline stats (tests read these):
         # transfer seconds spent while the prefill forward was STILL
         # computing count as hidden — overlap_ratio = hidden / total
         self.chunks_streamed = 0
@@ -263,8 +263,8 @@ class PrefillWorker:
         # engine batches several blocks into one seal the event can
         # legitimately lag a full fused round, and at the old
         # max(25x, 50 ms) every missed edge stalled the export stream
-        # long enough to erase the chunked-streaming TTFT win entirely
-        # (BENCH_r07's 0.9x regression). 5x the poll cadence floors at
+        # long enough to lose what chunked streaming hides. 5x the poll
+        # cadence floors at
         # 10 ms: late commits still coalesce, a lost edge costs at most
         # one round-ish of extra latency.
         done, _ = await asyncio.wait(

@@ -57,9 +57,10 @@ class EngineConfig:
     # pipeline flushes (falls back to the strict process-then-dispatch
     # order) whenever slot state is about to change under it:
     # admissions/prefills, pending release patches, seal-queue overflow
-    # past the fused width, speculating slots, and drain. `off` restores
-    # the pre-pipelining round order exactly (the differential tests
-    # compare the two).
+    # past the fused width, speculating slots, and drain. False is the
+    # differential tests' reference order and nothing else: it runs
+    # every round in that fallback order, and no launcher, flag or
+    # runtime setting reaches it.
     round_pipeline: bool = True
     # prefill chunks dispatched per scheduling round: bounds how long a
     # round can stall decode behind prompt processing (the ITL-interference
@@ -90,29 +91,13 @@ class EngineConfig:
     speculative: str = "off"
     num_speculative_tokens: int = 4   # K proposals per verify step (the CAP
                                       # when spec_adaptive is on)
-    spec_ngram_max: int = 3           # longest tail n-gram to match
-    spec_ngram_min: int = 1
-    # acceptance-adaptive K (spec/decoder.py AdaptiveKController): each
-    # slot's effective K walks within [spec_min_k, num_speculative_tokens]
-    # on an EWMA of its per-step acceptance fraction — grow above
-    # grow_threshold, shrink below shrink_threshold; a slot whose rate
-    # stays at/below despec_threshold after spec_min_observations verify
-    # steps de-speculates back to the fused decode round (speculation is
-    # actively costing it a full forward per ~1 emitted token there).
-    # The round's draft/verify width is the bucketed max of the
-    # participants' effective K, so an all-low-acceptance batch really
-    # does less device work per round.
+    # acceptance-adaptive K (spec/decoder.py AdaptiveKController, where
+    # its thresholds live): each slot's effective K walks within
+    # [spec_min_k, num_speculative_tokens] on its acceptance rate, and a
+    # slot whose acceptance collapses de-speculates back to the fused
+    # decode round
     spec_adaptive: bool = True
     spec_min_k: int = 1
-    spec_grow_threshold: float = 0.8
-    spec_shrink_threshold: float = 0.4
-    spec_despec_threshold: float = 0.125
-    spec_rate_ewma: float = 0.75      # weight of history in the rolling rate
-    spec_min_observations: int = 8    # verify steps before despec may fire
-    # fuse draft proposing across slots into ONE llama.batch_draft program
-    # per round (False = legacy per-slot dispatch loop, kept for A/B
-    # dispatch-overhead measurement in bench/profile_round)
-    spec_batch_draft: bool = True
     # tree speculation (spec/verifier.py spec_verify_tree): proposals
     # form a packed token tree — up to spec_branches candidates per
     # divergence point — verified in ONE forward under a tree-causal
@@ -207,17 +192,8 @@ class EngineConfig:
     # for 15 s is abandoned.
     kv_transfer_stream_idle_timeout_s: float = 15.0
 
-    # flight recorder (telemetry/flight.py): ring capacity of recent
-    # engine-round events served at /debug/flight and dumped to the log
-    # when an engine round fails
-    flight_recorder_events: int = 256
-
-    # performance-attribution plane (telemetry/prof.py): per-round
-    # host-segment timers feeding dynamo_host_round_seconds{segment} and
-    # /debug/prof. Always-on by design (near-zero overhead, pinned by
-    # tests/test_prof.py); the switch exists for A/B measurement.
-    prof_attribution: bool = True
-    # SLO targets backing the dynamo_slo_{ttft,itl}_burn_rate gauges:
+    # SLO targets backing the dynamo_slo_{ttft,itl}_burn_rate gauges
+    # (performance-attribution plane, telemetry/prof.py):
     # burn rate = frac-of-observations-over-target / (1 - objective),
     # recomputed from the live histograms at the metrics-publish cadence
     slo_ttft_target_s: float = 0.5

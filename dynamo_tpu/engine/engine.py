@@ -545,12 +545,16 @@ class TpuEngine:
         # publisher throttles to ~4 Hz — cache at the publish cadence so
         # the per-round cost is a timestamp compare, not 5 locked walks
         self._hist_snap: tuple[float, dict] = (0.0, {})
-        self.flight = FlightRecorder(e.flight_recorder_events)
+        # flight recorder (telemetry/flight.py): a ring of the most
+        # recent engine-round events (FlightRecorder's own capacity),
+        # served at /debug/flight and dumped to the log when an engine
+        # round fails
+        self.flight = FlightRecorder()
         # performance-attribution plane (telemetry/prof.py): per-round
         # host-segment switch timers, folded into the process-global
         # PROF registry at the metrics-publish cadence and served at
-        # /debug/prof
-        self.prof = RoundProf(enabled=e.prof_attribution)
+        # /debug/prof; the benchmark reads prof.totals() in every cell
+        self.prof = RoundProf()
         PROF.configure(e.slo_ttft_target_s, e.slo_itl_target_s,
                        e.slo_objective)
         # tail-latency forensics (telemetry/forensics.py): worker-side
@@ -683,18 +687,17 @@ class TpuEngine:
         self.tokens_generated = 0
         self.sp_prefills = 0
         self.batch_prefills = 0     # batched-prefill dispatches (K >= 2)
-        # dispatch-budget accounting (tools/profile_round.py
-        # --dispatch-budget, the bench dispatches_per_round field, and
-        # the tier-1 regression pin): every host->device program launch
-        # or async D2H fetch initiation increments its bucket
+        # dispatch-budget accounting (pinned per round by
+        # tests/test_dispatch_budget.py; copied into every snapshot of
+        # benchmarks/server.py): every host->device program launch or
+        # async D2H fetch initiation increments its bucket
         self.dispatch_counts: dict[str, int] = {
             "round": 0, "round_seal": 0, "seal": 0, "patch": 0,
             "prefill": 0, "prefill_batch": 0, "sp_prefill": 0,
             "load_ctx": 0, "sample_first": 0, "fetch": 0, "encode": 0,
             "offload_gather": 0, "xfer_gather": 0, "xfer_scatter": 0,
             # speculative path: the fused batch-draft and verify
-            # programs (the legacy PER-SLOT draft loop's dispatches are
-            # accounted by spec.stats()['spec_draft_dispatch_total'])
+            # programs
             "spec_draft": 0, "spec_verify": 0,
         }
         # prefix-commit event plane: subscribers (the disagg streaming
@@ -2017,11 +2020,12 @@ class TpuEngine:
         self._active_cache = None
 
     def pipeline_stats(self) -> dict:
-        """Round-pipeline effectiveness counters (profile_round
-        --dispatch-budget / bench): mean in-flight depth right after an
-        early dispatch, the fraction of pipelined-round host time spent
-        in the completion half (running under device execution), and the
-        per-reason flush counts."""
+        """Round-pipeline effectiveness counters (read by
+        tests/test_round_pipeline.py and tests/test_host_budget.py):
+        mean in-flight depth right after an early dispatch, the fraction
+        of pipelined-round host time spent in the completion half
+        (running under device execution), and the per-reason flush
+        counts."""
         n = self._pipe_dispatches
         return {
             "round_pipeline": bool(self.ecfg.round_pipeline),
@@ -2523,7 +2527,7 @@ class TpuEngine:
                 penalties[3][j] = so.repetition_penalty or 1.0
         t_disp = time.monotonic()
         drafted = None
-        if self.spec.draft is not None and e.spec_batch_draft:
+        if self.spec.draft is not None:
             # ONE multi-slot multi-token draft program; the [B, K] device
             # result splices into the verify tokens INSIDE the verify jit
             self.dispatch_counts["spec_draft"] += 1
@@ -2531,14 +2535,9 @@ class TpuEngine:
                 [(slot, r.spec_tokens) for slot, r, _, _ in rows], B, K,
             )
         else:
-            for j, (slot, r, _n, _k) in enumerate(rows):
-                proposal = self.spec.propose(slot, r.spec_tokens, K)
-                if isinstance(proposal, list):    # n-gram: host tokens
-                    toks[j, 1:] = proposal
-                else:          # legacy per-slot draft: device [K], no sync
-                    if drafted is None:
-                        drafted = jnp.zeros((B, K), jnp.int32)
-                    drafted = drafted.at[j].set(proposal)
+            # n-gram: host tokens, per slot by nature
+            for j, (_slot, r, _n, _k) in enumerate(rows):
+                toks[j, 1:] = self.spec.propose(r.spec_tokens, K)
         t_draft_end = time.monotonic()
         self.dispatch_counts["spec_verify"] += 1
         self.ctx, out_toks, n_out, new_keys = self.spec.verify(
@@ -3663,8 +3662,9 @@ class TpuEngine:
             ))
         # a matched/onboarded run longer than the ctx region cannot be
         # loaded (and the pow2 PADDING below can overflow the region even
-        # when the real run fits — load_ctx_pages clamps that statically;
-        # BENCH_r05: 46 matched pages padded to 64 vs a 52-page region).
+        # when the real run fits — load_ctx_pages clamps that statically:
+        # 46 matched pages padded to 64 against a 52-page region once
+        # crashed a run).
         # Drop overflow pages rather than failing the engine round; their
         # refs are released with the rest after the load dispatch.
         max_blocks = self.ecfg.max_context // ps

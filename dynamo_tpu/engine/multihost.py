@@ -1,4 +1,4 @@
-"""Cross-host single-engine controller (BASELINE config 4; reference
+"""Cross-host single-engine controller (a model sharded past one host; reference
 MultiNodeConfig launch/dynamo-run/src/flags.rs:86-101 +
 leader_worker_barrier.rs:137,230 — vLLM uses ray, TRT-LLM uses MPI; the
 TPU-native answer is jax.distributed + SPMD lockstep).

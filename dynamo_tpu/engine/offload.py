@@ -5,9 +5,9 @@ Reference shape (offload.rs:46-80, pool.rs:156, block_manager.rs:69-82):
 blocks leaving the device pool's reuse set are offloaded down the tier
 hierarchy (G1 HBM -> G2 DRAM -> G3 disk) through a priority queue with
 batched transfers; prefix hits consult lower tiers and onboard blocks back
-up. This buys the BASELINE's "40% TTFT from KV offload to CPU RAM" on
-multi-turn traffic whose working set exceeds HBM, and G3 extends the
-reusable corpus past DRAM.
+up. It is for multi-turn traffic whose working set exceeds HBM (the
+reference's KV offload to CPU RAM), and G3 extends the reusable corpus
+past DRAM.
 
 TPU redesign: offload piggybacks on the engine's pipelined round loop —
 candidates are pages PARKED in the allocator's LRU (committed, refcount 0);

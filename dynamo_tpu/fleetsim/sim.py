@@ -11,7 +11,7 @@ zero coverage, since the router's dispatch seam is exercised through
 engine keyed by the same lease id discovery found in the store.
 
 ``SimFleet`` owns the workers (list guarded by ``_mu`` — the planner's
-connector and the bench's scale calls race) and scales by spawning /
+connector and a driver's own scale calls race) and scales by spawning /
 draining them newest-first. ``SimConnector`` adapts the fleet to the
 planner's ``Connector`` protocol, closing the loop: planner decisions
 cause real registrations and real lease revocations, which the watcher

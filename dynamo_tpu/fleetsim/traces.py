@@ -16,7 +16,7 @@ tests/test_fleetsim.py pins. Prompts come from a ``PromptPopulation``
 with Zipf-hot shared prefixes so the KV router's prefix matching has
 realistic overlap structure to exploit.
 
-Traces serialize to JSONL (``save_jsonl``/``load_jsonl``) so a bench run
+Traces serialize to JSONL (``save_jsonl``/``load_jsonl``) so a run
 can be recorded once and replayed across branches.
 """
 from __future__ import annotations

@@ -57,8 +57,7 @@ from dynamo_tpu.telemetry import (
     request_histograms,
 )
 from dynamo_tpu.telemetry import metrics as tmetrics
-from dynamo_tpu.telemetry.fleet_feed import FLEET_FEED
-from dynamo_tpu.telemetry.forensics import FORENSICS, OUTLIERS, ForensicsCapture
+from dynamo_tpu.telemetry.forensics import OUTLIERS, ForensicsCapture
 from dynamo_tpu.telemetry.timeline import to_chrome_trace
 from dynamo_tpu.telemetry.trace import span_now
 
@@ -333,14 +332,6 @@ class HttpService:
         return web.json_response(model_list_response(self.manager.list_models()))
 
     async def handle_metrics(self, request: web.Request) -> web.Response:
-        from dynamo_tpu.kv_fleet_metrics import KV_FLEET
-        from dynamo_tpu.kv_integrity import KV_INTEGRITY
-        from dynamo_tpu.kv_quant import KV_QUANT
-        from dynamo_tpu.kv_transfer_metrics import KV_TRANSFER
-        from dynamo_tpu.planner_metrics import PLANNER
-        from dynamo_tpu.resilience.metrics import RESILIENCE
-        from dynamo_tpu.runtime.store_metrics import STORE
-        from dynamo_tpu.spec.metrics import SPEC
         from dynamo_tpu.telemetry.prof import PROF
 
         # SLO burn-rate gauges refresh at scrape time from the frontend's
@@ -354,19 +345,7 @@ class HttpService:
         om = wants_openmetrics(request)
         body = (self.metrics.render()
                 + self.telemetry.render(openmetrics=om).encode()
-                + RESILIENCE.render().encode()
-                + KV_TRANSFER.render().encode()
-                + KV_QUANT.render().encode()
-                + KV_INTEGRITY.render().encode()
-                + OVERLOAD.render().encode()
-                + PROF.render().encode()
-                + STORE.render().encode()
-                + PLANNER.render().encode()
-                + KV_FLEET.render().encode()
-                + SPEC.render().encode()
-                + FLEET_FEED.render(openmetrics=om).encode()
-                + TENANT.render(openmetrics=om).encode()
-                + FORENSICS.render().encode())
+                + tmetrics.render_planes(om).encode())
         if om:
             return web.Response(
                 body=body + b"# EOF\n",

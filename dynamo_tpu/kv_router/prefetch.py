@@ -18,7 +18,7 @@ knows every block's holders and heat:
 
 Delivery is duck-typed: a worker object (or its ``.engine``/``.inner``)
 exposing ``apply_fleet_hints(digest)`` / ``prefetch_hashes(hashes)``
-is called directly — that covers in-process fleets (bench, tests,
+is called directly — that covers in-process fleets (tests,
 fleetsim). Workers reached only over the wire get the same payloads
 published on the store's pub/sub plane (``kv_fleet.{worker_id}``; the
 worker side subscribes in frontend/watcher.py register_llm) when a

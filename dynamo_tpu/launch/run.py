@@ -130,22 +130,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "stream (preemption-as-migration via the "
                         "resilience plane; exactly-once, greedy "
                         "token-identical)")
-    p.add_argument("--round-pipeline",
-                   default="on" if cfg.round_pipeline else "off",
-                   choices=["on", "off"],
-                   help="double-buffered round pipelining: dispatch "
-                        "round N+1 before blocking on round N's token "
-                        "fetch, hiding host bookkeeping under device "
-                        "execution; off restores the serialized round "
-                        "order (A/B + differential baseline)")
     # performance-attribution plane (telemetry/prof.py)
-    p.add_argument("--prof-attribution",
-                   default="on" if cfg.prof_attribution else "off",
-                   choices=["on", "off"],
-                   help="per-round host-segment attribution "
-                        "(dynamo_host_round_seconds{segment} + "
-                        "/debug/prof); near-zero overhead, off only "
-                        "for A/B measurement")
     p.add_argument("--slo-ttft-target", type=float,
                    default=cfg.slo_ttft_target_s,
                    help="TTFT SLO target in seconds backing the "
@@ -306,6 +291,7 @@ def build_parser() -> argparse.ArgumentParser:
                    help="KV indexer access-heat decay half-life (0 = raw "
                         "undecayed counters, the legacy behavior)")
     p.add_argument("--no-kv-dedup-admission", action="store_true",
+                   default=not cfg.kv_dedup_admission,
                    help="disable dedup-by-hash admission hints: G4 "
                         "probes ignore the fleet holder digest")
     p.add_argument("--prefill-timeout", type=float, default=60.0,
@@ -547,7 +533,7 @@ def build_chain(args) -> "Any":
             # replica (SURVEY §2.5 DP row). tp BEYOND the local chips: ONE
             # logical engine spans every host — rank 0 runs the scheduler
             # and broadcasts each dispatch, other ranks replay in lockstep
-            # (engine/multihost.py; BASELINE config 4).
+            # (engine/multihost.py).
             cross_host = args.tensor_parallel_size > len(local_devices)
             if cross_host:
                 if getattr(args, "role", None) in ("decode", "prefill"):
@@ -610,8 +596,6 @@ def build_chain(args) -> "Any":
             max_waiting_requests=args.max_waiting_requests,
             max_waiting_prefill_tokens=args.max_waiting_prefill_tokens,
             preempt_running=args.preempt_running == "on",
-            round_pipeline=args.round_pipeline == "on",
-            prof_attribution=args.prof_attribution == "on",
             slo_ttft_target_s=args.slo_ttft_target,
             slo_itl_target_s=args.slo_itl_target,
             slo_objective=args.slo_objective,

@@ -5,8 +5,8 @@ per round, and ``tests/test_dispatch_budget.py`` pins the *count* — but
 only on the paths the test drives. The invariant it depends on is that
 ``TpuEngine.dispatch_counts`` sees every host->device program launch
 and every async D2H fetch initiation; an unaccounted dispatch added on
-a cold path silently corrupts the budget report and the bench's
-``dispatches_per_round``. This rule is the static companion: every
+a cold path silently corrupts the counts that test compares (and that
+every snapshot of the benchmark's server copies). This rule is the static companion: every
 compiled-call site in ``engine/`` (a call to a ``jax.jit``-produced
 callable, ``jax.device_put``, or ``.copy_to_host_async()``) must sit in
 a function that increments ``dispatch_counts`` — or in a function all

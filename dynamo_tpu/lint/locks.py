@@ -40,7 +40,7 @@ GUARDED_BY: dict[str, dict[str, str]] = {
         #                      round-pipeline counters: written ONLY by
         #                      the engine thread inside _round;
         #                      pipeline_stats() performs advisory
-        #                      GIL-atomic reads for tools/bench.
+        #                      GIL-atomic reads for tests.
     },
     "disagg.py": {
         # pending remote-prefill jobs: serving tasks add/discard, the
@@ -68,7 +68,7 @@ GUARDED_BY: dict[str, dict[str, str]] = {
     },
     "fleetsim/sim.py": {
         # simulated fleet roster: resized by the planner's connector AND
-        # the bench driver — concurrent asyncio tasks, and scale_to
+        # a test's own scale calls — concurrent asyncio tasks, and scale_to
         # awaits mid-resize (spawn/drain), so an unguarded access reads
         # a half-resized fleet
         "_workers": "_mu",

@@ -8,13 +8,15 @@ every canonical 2-tuple metric constant) must carry a valid type and a
 non-empty help string; every ``dynamo_*`` metric-name literal anywhere
 in the tree must have a README row; and every module-level registry
 (``OVERLOAD``, ``KV_TRANSFER``, ... — anything assigned from
-``CounterRegistry(...)`` or ``ProfRegistry(...)``) must be rendered on
-all three scrape surfaces (frontend ``/metrics``, per-worker system
-server, aggregating exporter), so a new subsystem plane cannot ship
+``CounterRegistry(...)`` or ``ProfRegistry(...)``) must be rendered by
+``telemetry/metrics.py:render_planes``, the one list that all three
+scrape surfaces (frontend ``/metrics``, per-worker system server,
+aggregating exporter) call, so a new subsystem plane cannot ship
 half-scraped.
 
-The surface check only runs when all three surface modules are in the
-scanned set (i.e. whole-tree runs, not single-file fixture runs).
+The surface check only runs when that module and all three surface
+modules are in the scanned set (i.e. whole-tree runs, not single-file
+fixture runs).
 """
 from __future__ import annotations
 
@@ -27,6 +29,7 @@ _METRIC_NAME = re.compile(r"dynamo_[a-z0-9_]+")
 _VALID_TYPES = {"counter", "gauge", "histogram", "summary"}
 _REGISTRY_CTORS = {"CounterRegistry", "ProfRegistry", "FleetLatencyFeed",
                    "TenantRegistry"}
+_PLANES = "telemetry/metrics.py"     # home of render_planes
 _SURFACES = (
     "frontend/service.py",
     "runtime/system_server.py",
@@ -49,8 +52,8 @@ def _const_str(node: ast.AST) -> str | None:
 class MetricsContractRule:
     ID = "DTL005"
     WHAT = ("every dynamo_* family needs HELP text + a valid TYPE, a "
-            "README row, and its registry rendered on all three scrape "
-            "surfaces")
+            "README row, and its registry in render_planes, which all "
+            "three scrape surfaces call")
 
     def check(self, index: ProjectIndex) -> list[Finding]:
         findings: list[Finding] = []
@@ -116,9 +119,23 @@ class MetricsContractRule:
     # -- three-surface rendering ------------------------------------------
 
     def _check_surfaces(self, index: ProjectIndex, findings) -> None:
+        planes = index.get(_PLANES)
         surfaces = [index.get(s) for s in _SURFACES]
-        if any(s is None for s in surfaces):
+        if planes is None or any(s is None for s in surfaces):
             return
+        listed = next(
+            (ast.get_source_segment(planes.source, n) or ""
+             for n in planes.tree.body
+             if isinstance(n, ast.FunctionDef) and n.name == "render_planes"),
+            "")
+        for sname, smod in zip(_SURFACES, surfaces):
+            if "render_planes(" not in smod.source:
+                findings.append(Finding(
+                    self.ID, smod.path, 1, 0,
+                    f"scrape surface {sname} does not call render_planes"
+                    " — every metric plane must appear on all three "
+                    "surfaces",
+                ))
         for mod in index.modules.values():
             for node in ast.walk(mod.tree):
                 if not isinstance(node, ast.Assign):
@@ -131,16 +148,15 @@ class MetricsContractRule:
                     if not (isinstance(tgt, ast.Name)
                             and tgt.id.isupper()):
                         continue  # instance/local registries opt out
-                    for sname, smod in zip(_SURFACES, surfaces):
-                        # open paren, not `render()`: surfaces may pass
-                        # render(openmetrics=...) for exemplar-capable
-                        # registries
-                        if f"{tgt.id}.render(" not in smod.source:
-                            findings.append(Finding(
-                                self.ID, mod.path, node.lineno,
-                                node.col_offset,
-                                f"registry {tgt.id} is not rendered on "
-                                f"scrape surface {sname} — every metric "
-                                "plane must appear on all three "
-                                "surfaces",
-                            ))
+                    # open paren, not `render()`: the list may pass
+                    # render(openmetrics=...) for exemplar-capable
+                    # registries
+                    if f"{tgt.id}.render(" not in listed:
+                        findings.append(Finding(
+                            self.ID, mod.path, node.lineno,
+                            node.col_offset,
+                            f"registry {tgt.id} is not rendered by "
+                            "render_planes (telemetry/metrics.py) — "
+                            "every metric plane must appear on all "
+                            "three scrape surfaces",
+                        ))

@@ -34,9 +34,7 @@ from dynamo_tpu.kv_router.protocols import ForwardPassMetrics
 from dynamo_tpu.runtime.client import KvClient
 from dynamo_tpu.runtime.publisher import METRICS_TOPIC
 from dynamo_tpu.telemetry.fleet_feed import FLEET_FEED
-from dynamo_tpu.telemetry.forensics import FORENSICS
-from dynamo_tpu.telemetry.metrics import render_histogram
-from dynamo_tpu.tenancy import TENANT
+from dynamo_tpu.telemetry.metrics import render_histogram, render_planes
 
 log = logging.getLogger(__name__)
 
@@ -189,27 +187,8 @@ class MetricsExporter:
                 )[2:])
         gauge("dynamo_metrics_workers",
               "workers in the last load-plane snapshot", len(snap.metrics))
-        # resilience + KV-transfer + overload planes: process-local
-        # counters, same families on every scrape surface
-        from dynamo_tpu.kv_fleet_metrics import KV_FLEET
-        from dynamo_tpu.kv_integrity import KV_INTEGRITY
-        from dynamo_tpu.kv_quant import KV_QUANT
-        from dynamo_tpu.kv_transfer_metrics import KV_TRANSFER
-        from dynamo_tpu.overload import OVERLOAD
-        from dynamo_tpu.planner_metrics import PLANNER
-        from dynamo_tpu.resilience.metrics import RESILIENCE
-        from dynamo_tpu.runtime.store_metrics import STORE
-        from dynamo_tpu.spec.metrics import SPEC
-        from dynamo_tpu.telemetry.prof import PROF
-
-        return ("\n".join(lines) + "\n" + RESILIENCE.render()
-                + KV_TRANSFER.render() + KV_QUANT.render()
-                + KV_INTEGRITY.render() + OVERLOAD.render()
-                + PROF.render() + STORE.render() + PLANNER.render()
-                + KV_FLEET.render() + SPEC.render()
-                + FLEET_FEED.render(openmetrics=openmetrics)
-                + TENANT.render(openmetrics=openmetrics)
-                + FORENSICS.render())
+        # the process-local planes: same families on every scrape surface
+        return "\n".join(lines) + "\n" + render_planes(openmetrics)
 
     async def handle_metrics(self, request: web.Request) -> web.Response:
         if "application/openmetrics-text" in request.headers.get(

@@ -143,7 +143,7 @@ class MockerEngine:
         self._queue_waits: deque = deque(maxlen=32)
         # latency histograms on the SAME canonical ladders as the real
         # engine (fleet merge sums only identical ladders), shipped in
-        # ForwardPassMetrics.histograms so fleet-feed / planner / bench
+        # ForwardPassMetrics.histograms so fleet-feed / planner
         # paths exercise on CPU; exemplars carry request ids
         self.telemetry = request_histograms(TelemetryRegistry(),
                                             engine=True)

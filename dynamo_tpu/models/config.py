@@ -267,8 +267,8 @@ class ModelConfig:
 
     @classmethod
     def llama3_8b(cls, **kw) -> "ModelConfig":
-        """Llama-3.1-8B / DeepSeek-R1-Distill-Llama-8B shapes (the reference
-        benchmark model, BASELINE.json)."""
+        """Llama-3.1-8B / DeepSeek-R1-Distill-Llama-8B shapes (the
+        reference's benchmark model)."""
         base = dict(
             vocab_size=128256,
             hidden_size=4096,
@@ -285,8 +285,8 @@ class ModelConfig:
 
     @classmethod
     def llama3_8b_int8(cls) -> "ModelConfig":
-        """BASELINE config 1's model on one 16 GB v5e: w8a16 int8 weights
-        (~8 GB) — bf16 cannot fit."""
+        """The 8B model on one 16 GB v5e: w8a16 int8 weights (~8 GB) —
+        bf16 cannot fit."""
         return cls.llama3_8b(quant="int8")
 
     @classmethod

@@ -436,7 +436,7 @@ def ring_shardings(config: ModelConfig, mesh: Mesh) -> Cache:
 # "s": f32 [..., out]}; every matmul site routes through _mm/_embed_rows
 # so dense and quantized params are interchangeable. The int8 tensor is
 # what streams from HBM (half the weight-pass bytes of bf16 — the decode
-# roofline — and what fits an 8B on a 16 GB v5e, BASELINE config 1); the
+# roofline — and what fits an 8B on a 16 GB v5e); the
 # dequantize (convert + per-channel scale) fuses into the matmul epilogue.
 # Reference analogue: the FP8 serving recipes
 # (examples/llm/benchmarks/README.md:28).
@@ -1088,11 +1088,11 @@ def batch_draft_impl(
     in ONE program: the catch-up chunk (the tokens accepted since the
     slot's last draft) runs as a batch_prefill-shaped forward, then a
     ``lax.fori_loop`` runs k-1 single-token batched steps with argmax
-    feedback entirely on device — the cross-slot fusion of what
-    DraftModelProposer.propose dispatched as 1 + (k-1) programs PER SLOT.
+    feedback entirely on device — one dispatch a round whatever the
+    number of slots and k (DraftModelProposer.propose_batch).
     Returns (ctx_kv, drafted [B, k] i32); nothing touches the host.
 
-    KV bookkeeping matches the per-slot path: the catch-up chunk lands at
+    KV bookkeeping: the catch-up chunk lands at
     [q_start, seq_len), draft step s writes at seq_len + s, and the last
     drafted token's KV is never computed (it is never fed back). Rollback
     stays pointer truncation. Dummy rows (seq_len 0) write the scratch

@@ -189,7 +189,7 @@ class LocalConnector:
 
 
 class MultihostLocalConnector:
-    """DP replicas OF a cross-host engine (BASELINE config 4 x planner):
+    """DP replicas OF a cross-host engine (engine/multihost.py x planner):
     each replica is a GROUP of ``num_nodes`` processes — rank 0 the
     in=endpoint leader, the rest replay followers — spawned and retired
     together. Command args are templated with ``{rank}``, ``{coord}``

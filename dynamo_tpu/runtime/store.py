@@ -704,8 +704,7 @@ def crash_store(server: asyncio.AbstractServer) -> None:
     live connection (clients see ConnectionResetError, not a clean FIN),
     kill the sweeper. The KvStore object — and its journal — survive only
     on disk; restart with ``serve_store(store=KvStore(journal_path=...))``.
-    Used by the kill_store chaos point, the store_outage bench phase, and
-    the restart tests."""
+    Used by the kill_store chaos point and the restart tests."""
     task = getattr(server, "_dcp_sweeper", None)
     if task is not None and not task.done():
         task.cancel()

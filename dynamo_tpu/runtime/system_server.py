@@ -29,11 +29,10 @@ from typing import Any, Optional
 from aiohttp import web
 
 from dynamo_tpu.resilience.chaos import CHAOS
-from dynamo_tpu.resilience.metrics import RESILIENCE
 from dynamo_tpu.telemetry import TRACES
 from dynamo_tpu.telemetry.fleet_feed import FLEET_FEED
-from dynamo_tpu.telemetry.forensics import FORENSICS, OUTLIERS
-from dynamo_tpu.telemetry.metrics import render_histogram
+from dynamo_tpu.telemetry.forensics import OUTLIERS
+from dynamo_tpu.telemetry.metrics import render_histogram, render_planes
 from dynamo_tpu.telemetry.timeline import to_chrome_trace
 from dynamo_tpu.tenancy import TENANT
 
@@ -172,26 +171,8 @@ class SystemServer:
                         label=f'worker="{w}"',
                         openmetrics=openmetrics,
                     ))
-        # resilience + KV-transfer + overload planes: counters of THIS
-        # process
-        from dynamo_tpu.kv_fleet_metrics import KV_FLEET
-        from dynamo_tpu.kv_integrity import KV_INTEGRITY
-        from dynamo_tpu.kv_quant import KV_QUANT
-        from dynamo_tpu.kv_transfer_metrics import KV_TRANSFER
-        from dynamo_tpu.overload import OVERLOAD
-        from dynamo_tpu.planner_metrics import PLANNER
-        from dynamo_tpu.runtime.store_metrics import STORE
-        from dynamo_tpu.spec.metrics import SPEC
-        from dynamo_tpu.telemetry.prof import PROF
-
-        return ("\n".join(lines) + "\n" + RESILIENCE.render()
-                + KV_TRANSFER.render() + KV_QUANT.render()
-                + KV_INTEGRITY.render() + OVERLOAD.render()
-                + PROF.render() + STORE.render() + PLANNER.render()
-                + KV_FLEET.render() + SPEC.render()
-                + FLEET_FEED.render(openmetrics=openmetrics)
-                + TENANT.render(openmetrics=openmetrics)
-                + FORENSICS.render())
+        # the planes' counters of THIS process
+        return "\n".join(lines) + "\n" + render_planes(openmetrics)
 
     async def handle_metrics(self, request: web.Request) -> web.Response:
         if "application/openmetrics-text" in request.headers.get(

@@ -8,10 +8,11 @@ engine scheduler calls:
                           logprobs need the per-token sampler path)
   k_for(slot)/round_k()   the slot's effective K and the bucketed round
                           width covering a batch of slots
-  propose(slot, hist, k)  K candidate tokens — host list (n-gram) or
-                          device array (draft model, no host sync)
+  propose(hist, k)        K candidate tokens from the n-gram proposer
+                          (a host lookup, per slot by nature)
   propose_batch(...)      ONE batched draft dispatch for every
-                          speculating slot (llama.batch_draft)
+                          speculating slot (llama.batch_draft; a device
+                          array, no host sync)
   verify(...)             dispatch the fused score+accept program for a
                           batch of speculating slots
   on_result(...)          commit counters, update the adaptive-K rate,
@@ -19,17 +20,17 @@ engine scheduler calls:
   should_despec(slot)     has this slot's acceptance collapsed?
   release(slot)           slot freed/de-speculated — drop draft state
 
-Counters feed three surfaces: engine.metrics() (WorkerStats spec
+Counters feed two surfaces: engine.metrics() (WorkerStats spec
 fields -> metrics_exporter/system_server gauges, incl. the mean
-effective K as dynamo_spec_effective_k), per-request annotations on the
-finishing LLMEngineOutput (sdk.request_stats), and the bench speculative
-phase. Dispatch counters (spec_draft_dispatch_total /
-spec_verify_dispatch_total) make the O(dispatches)-per-token cost
-directly observable — tools/profile_round.py --spec reads them.
+effective K as dynamo_spec_effective_k) and per-request annotations on
+the finishing LLMEngineOutput (sdk.request_stats). Dispatch counters
+(spec_draft_dispatch_total / spec_verify_dispatch_total) make the
+O(dispatches)-per-token cost directly observable; tests/
+test_spec_adaptive.py holds drafting to one dispatch a verify round.
 """
 from __future__ import annotations
 
-from typing import Any, Optional, Union
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -39,6 +40,21 @@ from dynamo_tpu.engine.config import EngineConfig, pow2_cover
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.spec.proposer import DraftModelProposer, NGramProposer
 from dynamo_tpu.spec.verifier import spec_verify, spec_verify_tree
+
+# acceptance-adaptive K (AdaptiveKController): a slot's effective K walks
+# within [spec_min_k, num_speculative_tokens] on an EWMA of its per-step
+# acceptance fraction — grow at/above GROW_THRESHOLD, shrink at/below
+# SHRINK_THRESHOLD; a slot whose rate stays at/below DESPEC_THRESHOLD
+# after MIN_OBSERVATIONS verify steps de-speculates back to the fused
+# decode round (speculation is costing it a full forward per ~1 emitted
+# token there). The round's draft/verify width is the bucketed max of
+# the participants' effective K, so an all-low-acceptance batch really
+# does less device work per round.
+GROW_THRESHOLD = 0.8
+SHRINK_THRESHOLD = 0.4
+DESPEC_THRESHOLD = 0.125
+RATE_EWMA = 0.75       # weight of history in the rolling rate (and the gate's)
+MIN_OBSERVATIONS = 8   # verify steps before despec may fire
 
 
 class AdaptiveKController:
@@ -220,19 +236,18 @@ class SpecDecoder:
         if ecfg.spec_adaptive:
             self.adaptive = AdaptiveKController(
                 self.k, min(ecfg.spec_min_k, self.k),
-                grow_at=ecfg.spec_grow_threshold,
-                shrink_at=ecfg.spec_shrink_threshold,
-                despec_at=ecfg.spec_despec_threshold,
-                ewma=ecfg.spec_rate_ewma,
-                min_obs=ecfg.spec_min_observations,
+                grow_at=GROW_THRESHOLD,
+                shrink_at=SHRINK_THRESHOLD,
+                despec_at=DESPEC_THRESHOLD,
+                ewma=RATE_EWMA,
+                min_obs=MIN_OBSERVATIONS,
                 m_max=self.branches if self.tree else 1,
             )
         self.ngram: Optional[NGramProposer] = None
         self.draft: Optional[DraftModelProposer] = None
         if mode == "ngram":
-            self.ngram = NGramProposer(
-                self.k, ecfg.spec_ngram_max, ecfg.spec_ngram_min
-            )
+            # tail n-grams of 3 down to 1 tokens: NGramProposer's own bounds
+            self.ngram = NGramProposer(self.k)
         else:
             if draft_config is None:
                 raise ValueError("speculative=draft needs a draft_config")
@@ -338,7 +353,7 @@ class SpecDecoder:
             return
         step = accepted / max(k_used, 1)
         prev = self._gate_rate.get(slot)
-        ew = self.ecfg.spec_rate_ewma
+        ew = RATE_EWMA
         rate = step if prev is None else ew * prev + (1.0 - ew) * step
         self._gate_rate[slot] = rate
         if rate < self.gate_at:
@@ -363,16 +378,9 @@ class SpecDecoder:
     # ------------------------------------------------------------------
     # proposing
 
-    def propose(
-        self, slot: int, history: list[int], k: int
-    ) -> Union[list[int], jnp.ndarray]:
-        """Per-slot proposal (n-gram host lookup, or the LEGACY per-slot
-        draft path kept for spec_batch_draft=False A/B runs)."""
-        if self.ngram is not None:
-            return self.ngram.propose(history, k)
-        # 1 catch-up prefill + (k-1) single-token programs
-        self.draft_dispatch_total += k
-        return self.draft.propose(slot, history, k)
+    def propose(self, history: list[int], k: int) -> list[int]:
+        """N-gram proposal: a host lookup in the request's own history."""
+        return self.ngram.propose(history, k)
 
     def propose_batch(
         self, rows: list[tuple[int, list[int]]], width: int, k: int

@@ -1,8 +1,8 @@
 """Speculative-token proposers.
 
-Two strategies behind one contract — ``propose(slot, history) -> K
-tokens`` where ``history`` is the request's full committed sequence
-(prompt + emitted output, the pending token last):
+Two strategies; both propose K tokens from ``history``, the request's
+full committed sequence (prompt + emitted output, the pending token
+last):
 
   - NGramProposer: model-free prompt-lookup decoding. Matches the tail
     n-gram of the history against an earlier occurrence and proposes the
@@ -10,10 +10,11 @@ tokens`` where ``history`` is the request's full committed sequence
     cost — wins on repetitive/structured text (code, extraction, long
     copies) where the continuation literally appears earlier.
   - DraftModelProposer: a small model sharing the target's tokenizer,
-    run through the EXISTING engine forward (llama.prefill): one
-    catch-up chunk to sync its private ctx region with the slot history,
-    then K greedy single-token steps. The argmax chain stays on device —
-    the proposed [K] array feeds the verifier without a host round trip.
+    run as ONE program for every speculating slot (llama.batch_draft):
+    a catch-up chunk syncs its private ctx region with each slot's
+    history, then K greedy single-token steps. The argmax chain stays on
+    device — the proposed [B, K] array feeds the verifier without a host
+    round trip.
 
 Correctness note: acceptance treats every proposal as a deterministic
 (point-mass) draft, so HOW tokens are proposed never biases the output
@@ -140,9 +141,9 @@ class DraftModelProposer:
     """Draft-model proposer with a private contiguous ctx region.
 
     The draft shares the target's tokenizer (vocab ids must line up) and
-    runs through ``llama.prefill``: a bucketed catch-up chunk writes the
-    history delta into the slot's draft lane, then K-1 single-token
-    prefills extend it greedily. Rollback after a rejected verify is
+    runs through ``llama.batch_draft``: a bucketed catch-up chunk writes
+    each slot's history delta into its draft lane, then K-1 single-token
+    steps extend it greedily. Rollback after a rejected verify is
     ``truncate(slot, n)`` — the draft region beyond ``n`` is dead weight
     that the next catch-up chunk overwrites (attention masks by seq_len,
     so it is never read meanwhile).
@@ -180,41 +181,6 @@ class DraftModelProposer:
         # holds at [0, pos) — the rollback pointer
         self.pos = np.zeros(ecfg.max_decode_slots, np.int64)
 
-    def propose(self, slot: int, history: list[int], k: int) -> jnp.ndarray:
-        """Draft k continuation tokens for ``history`` (pending token
-        last). Returns a DEVICE [k] i32 array — no host sync; the caller
-        splices it straight into the verify batch."""
-        start = int(self.pos[slot])
-        chunk = history[start:]
-        assert chunk, "history must extend past the draft position"
-        # clamp the pow2 padding to the region end: a padded width that
-        # overflows would make prefill's dynamic_update_slice CLAMP the
-        # write start, silently shifting real KV onto earlier rows (the
-        # chunk itself always fits — the engine despeculates before the
-        # history can outgrow the region)
-        w = min(pow2_cover(len(chunk), 8), self.ecfg.max_context - start)
-        toks = np.zeros(w, np.int32)
-        toks[: len(chunk)] = chunk
-        self.ctx, logits = llama.prefill(
-            self.config, self.params, self.ctx,
-            jnp.asarray(toks), jnp.int32(slot),
-            jnp.int32(start), jnp.int32(len(history)),
-        )
-        drafted = [jnp.argmax(logits).astype(jnp.int32)]
-        pos = len(history)
-        for _ in range(k - 1):
-            self.ctx, logits = llama.prefill(
-                self.config, self.params, self.ctx,
-                drafted[-1][None], jnp.int32(slot),
-                jnp.int32(pos), jnp.int32(pos + 1),
-            )
-            drafted.append(jnp.argmax(logits).astype(jnp.int32))
-            pos += 1
-        # KV written: history plus drafted[:-1] (the last draft is never
-        # fed back, so its KV was never computed)
-        self.pos[slot] = len(history) + k - 1
-        return jnp.stack(drafted)
-
     def propose_batch(
         self, rows: list[tuple[int, list[int]]], width: int, k: int,
         branches: int = 1,
@@ -223,7 +189,7 @@ class DraftModelProposer:
         dispatch (llama.batch_draft): the per-slot catch-up chunks run as
         one [width, T] batched forward, then k-1 batched single-token
         steps advance greedily inside a fori_loop — O(1) dispatches per
-        round where the per-slot path issued O(len(rows) * k).
+        round in the number of slots and in k.
 
         ``rows`` is [(slot, history)] for the live rows; the remaining
         lanes up to ``width`` are dummies (scratch lane, seq_len 0),
@@ -245,9 +211,11 @@ class DraftModelProposer:
                 "history must extend past the draft position"
             chunks.append((slot, hist, start))
             max_len = max(max_len, len(hist) - start)
-        # one shared pow2 chunk width, clamped to the region (see
-        # propose: an overflowing padded write start would be CLAMPED by
-        # dynamic_update_slice, silently shifting real KV). Rows whose
+        # one shared pow2 chunk width, clamped to the region (an
+        # overflowing padded write start would be CLAMPED by
+        # dynamic_update_slice, silently shifting real KV onto earlier
+        # rows; the chunk itself always fits — the engine despeculates
+        # before the history can outgrow the region). Rows whose
         # start + T would overflow re-feed a little extra history
         # instead (start_eff < start recomputes identical KV — harmless).
         T = min(pow2_cover(max_len, 8), S)

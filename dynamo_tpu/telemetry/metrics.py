@@ -21,8 +21,8 @@ import numpy as np
 
 # decode steps run ~1-100 ms, TTFT ~10 ms-10 s, E2E up to minutes: a
 # 1-2-3.5-5-7.5 per-decade ladder covers every request-latency series.
-# Resolution matters beyond dashboards — bench.py reports percentiles
-# interpolated from these buckets, so each step is kept under ~1.6x
+# Resolution matters beyond dashboards — a percentile read off a scrape
+# is interpolated within a bucket, so each step is kept under ~1.6x
 # (a within-bucket shift quantizes to at most that).
 DEFAULT_TIME_BUCKETS = (
     0.0005, 0.001, 0.002, 0.0035, 0.005, 0.0075,
@@ -219,6 +219,37 @@ class CounterRegistry:
         for h in self._hists.values():
             lines.extend(h.render(openmetrics=openmetrics))
         return "\n".join(lines) + "\n"
+
+
+def render_planes(openmetrics: bool = False) -> str:
+    """Prometheus text of every process-local metric plane: THE list of
+    module-level registries, which all three scrape surfaces (frontend
+    ``/metrics``, per-worker system server, aggregating exporter) render
+    through this one call, so a plane cannot ship half-scraped (lint
+    rule DTL005 holds every module-level registry to being named here).
+    ``openmetrics`` reaches the registries whose histograms carry
+    exemplars. Imports are local: the planes import this module."""
+    from dynamo_tpu.kv_fleet_metrics import KV_FLEET
+    from dynamo_tpu.kv_integrity import KV_INTEGRITY
+    from dynamo_tpu.kv_quant import KV_QUANT
+    from dynamo_tpu.kv_transfer_metrics import KV_TRANSFER
+    from dynamo_tpu.overload import OVERLOAD
+    from dynamo_tpu.planner_metrics import PLANNER
+    from dynamo_tpu.resilience.metrics import RESILIENCE
+    from dynamo_tpu.runtime.store_metrics import STORE
+    from dynamo_tpu.spec.metrics import SPEC
+    from dynamo_tpu.telemetry.fleet_feed import FLEET_FEED
+    from dynamo_tpu.telemetry.forensics import FORENSICS
+    from dynamo_tpu.telemetry.prof import PROF
+    from dynamo_tpu.tenancy import TENANT
+
+    return (RESILIENCE.render() + KV_TRANSFER.render() + KV_QUANT.render()
+            + KV_INTEGRITY.render() + OVERLOAD.render() + PROF.render()
+            + STORE.render() + PLANNER.render() + KV_FLEET.render()
+            + SPEC.render()
+            + FLEET_FEED.render(openmetrics=openmetrics)
+            + TENANT.render(openmetrics=openmetrics)
+            + FORENSICS.render())
 
 
 def percentile_from_snapshot(

@@ -1,9 +1,7 @@
 """Performance-attribution plane: where the engine's host milliseconds go.
 
-BENCH_r07 left the host loop as the bottleneck (host_ms_per_step 1.57 vs
-device 1.03) with no way to say WHERE inside `TpuEngine._round` the time
-is spent. This module attributes every host-side slice of the serving
-round to a named segment with a flat current-segment switch model:
+This module says WHERE inside `TpuEngine._round` the host's time is
+spent: it attributes every host-side slice of the serving round to a named segment with a flat current-segment switch model:
 ``enter(seg)`` charges the elapsed time since the previous switch to the
 previous segment, so the per-round segment sums equal the measured round
 wall EXACTLY (self-coverage ~1.0 by construction) and the cost per
@@ -39,9 +37,9 @@ import numpy as np
 from .metrics import Histogram, render_histogram
 
 # the host-round segment enum — the contract shared by the engine's
-# enter() calls, `dynamo_host_round_seconds{segment=...}`, the
-# `host_breakdown` JSON field (tools/profile_round.py --dispatch-budget,
-# bench.py), and /debug/prof. Order is the approximate order the
+# enter() calls, `dynamo_host_round_seconds{segment=...}`, /debug/prof
+# and `totals()` (which the benchmark's `sched.host_ms_per_round` and
+# `sched.starved_share` read). Order is the approximate order the
 # segments run inside _round.
 SEGMENTS = (
     "intake",         # _drain_intake: waiting-queue pulls
@@ -96,9 +94,9 @@ class RoundProf:
 
     Single-writer (the engine thread); readers take snapshots of the
     totals under the GIL via plain dict/list copies — per-field tearing
-    across a read is acceptable for a profiler. ``enabled=False`` turns
-    every method into an early-out so `prof_attribution=false` engines
-    pay one attribute load + branch per call site.
+    across a read is acceptable for a profiler. Always on: the
+    benchmark reads ``totals()`` in every cell, so there is no off mode
+    to measure in.
 
     Starved time is an estimate from the host's side, biased both ways
     by a few hundred microseconds per event: patches and standalone seals
@@ -113,8 +111,7 @@ class RoundProf:
 
     RING = 256  # recent per-round records kept for /debug/prof + timeline
 
-    def __init__(self, enabled: bool = True):
-        self.enabled = enabled
+    def __init__(self):
         self._acc = [0.0] * _N_SEG     # current round, per segment
         # the part of _acc that ran while the device was starved
         self._starved_acc = [0.0] * _N_SEG
@@ -124,19 +121,17 @@ class RoundProf:
         # profiler annotations: a TraceMe takes its start time when it is
         # CONSTRUCTED, so one is made per switch, and only while a
         # profiler session is on (asked once per round)
-        self._annotation = None
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
         self._tracing = False
         self._ann_open = None
-        if enabled:
-            from jax.profiler import TraceAnnotation
-
-            self._annotation = TraceAnnotation
         self._seg = _OTHER
         self._t = 0.0
         self._t_begin = 0.0
         self._in_round = False
         # cumulative since engine start (fold-independent, what
-        # host_breakdown deltas read)
+        # deltas of totals() read)
         self.total = np.zeros(_N_SEG)
         self.rounds = 0
         self.wall_total = 0.0
@@ -155,8 +150,6 @@ class RoundProf:
     # -- engine-thread hot path ----------------------------------------
 
     def begin_round(self) -> None:
-        if not self.enabled:
-            return
         t = time.monotonic()
         self._acc = [0.0] * _N_SEG
         if self._starved_dirty:
@@ -181,7 +174,7 @@ class RoundProf:
     def enter(self, seg: int) -> None:
         """Charge time since the last switch to the PREVIOUS segment and
         make ``seg`` (an index into SEGMENTS) current."""
-        if not self.enabled or not self._in_round:
+        if not self._in_round:
             return
         self._charge()
         self._seg = seg
@@ -194,7 +187,7 @@ class RoundProf:
     def mark_starved(self) -> None:
         """The device has nothing queued although requests are live:
         from here on slices are also charged to ``starved[segment]``."""
-        if not self.enabled or self._starved:
+        if self._starved:
             return
         if self._in_round:
             self._charge()
@@ -216,7 +209,7 @@ class RoundProf:
         return prev
 
     def end_round(self, record: bool = True) -> None:
-        if not self.enabled or not self._in_round:
+        if not self._in_round:
             return
         self._charge()  # close the open segment
         if self._ann_open is not None:
@@ -318,7 +311,6 @@ class RoundProf:
         if top:
             rows = rows[:top]
         return {
-            "enabled": self.enabled,
             "rounds": totals["rounds"],
             "wall_s": round(wall, 6),
             "recent_rounds": n_recent,

@@ -1,4 +1,4 @@
-"""Perfetto/Chrome-trace timeline assembly for one request or bench phase.
+"""Perfetto/Chrome-trace timeline assembly for one request.
 
 Merges four event sources into a single ``trace.json`` loadable at
 ui.perfetto.dev (or chrome://tracing):

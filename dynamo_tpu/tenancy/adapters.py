@@ -110,7 +110,7 @@ def set_adapter(bank, adapter_id: int, weights: dict):
 
 def random_adapter(config: Any, rank: int, seed: int = 0,
                    scale: float = 0.05) -> dict:
-    """Small random factors for every site — test/bench fixture for a
+    """Small random factors for every site — test fixture for a
     visibly non-identity adapter."""
     rng = np.random.default_rng(seed)
     c = config

@@ -1,14 +1,13 @@
 """Dispatch-budget regression pins: the decode-round dispatch diet.
 
-BENCH_r06 showed 6.53 ms wall/step vs 1.04 ms device/step — the gap is
-host tax, and a big slice of it is per-round host→device dispatches.
-After the diet (seals fused into the round program, packed patch
+A big slice of the host's cost per step is per-round host→device
+dispatches. After the diet (seals fused into the round program, packed patch
 uploads, packed logprob fetches, metrics publish throttled), a steady
 decode round costs exactly ONE program dispatch + ONE stacked-token
 fetch. These tests pin that budget via the engine's own
 ``dispatch_counts`` accounting so future PRs can't silently regrow it
-(the tool view of the same numbers: ``tools/profile_round.py
---dispatch-budget``).
+(on the chip the cost shows as ``sched.host_ms_per_round`` and
+``device.idle_share``, PERF.md).
 """
 import asyncio
 

@@ -250,25 +250,36 @@ def test_received_unix_survives_the_wire():
 
 async def test_sleep_between_fetch_and_dispatch_is_starved_admit():
     """One round in flight at most and no early dispatch: every round's
-    fetch leaves nothing tracked while the slot is live, so the sleep
-    injected ahead of admission runs with the device starved."""
+    fetch leaves nothing tracked while the slot is live, so admission
+    runs with the device starved and its time is booked to ``admit``.
+    Order and counts, not durations: what a hook ahead of admission sees
+    of the profile's state, round by round."""
     eng = _engine(max_inflight_rounds=0, round_pipeline=False)
     admit = eng._admit
+    ADMIT = SEGMENTS.index("admit")
+    seen = []            # (device starved?, open segment, decode slot live?)
 
-    def slow_admit():
-        time.sleep(0.005)
+    def watched_admit():
+        seen.append((eng.prof._starved, eng.prof._seg,
+                     bool(eng._slot_active.any())))
         admit()
 
-    eng._admit = slow_admit
+    eng._admit = watched_admit
     eng.start()
     toks, _ = await _one(eng, list(range(1, 30)), osl=17)
     await eng.stop()
     t = eng.prof.totals()
     rounds = -(-(17 - 1) // eng.ecfg.flush_every)
     assert len(toks) == 17
+    # admission always runs inside its own segment
+    assert all(seg == ADMIT for _, seg, _ in seen)
+    # with a decode slot live, the blocking fetch ahead of admission has
+    # consumed the only round in flight: starved every time, and at
+    # least once for each round but the last (whose fetch ends the slot)
+    live = [starved for starved, _, slot_live in seen if slot_live]
+    assert all(live) and len(live) >= rounds - 1
     starved = t["starved"]["segments"]
-    assert starved["admit"] >= 0.005 * (rounds - 1)
-    assert starved["admit"] == max(starved.values())
+    assert starved["admit"] > 0.0
     assert t["starved"]["total_s"] == pytest.approx(sum(starved.values()))
     # a subset of the segment's own time, never more
     assert all(starved[s] <= t["segments"][s] + 1e-9 for s in SEGMENTS)
@@ -353,18 +364,6 @@ def test_annotations_balanced_across_enter_push_end(session):
     # strictly alternating: never two segments open at once, none left
     assert [k for k, _ in log] == ["open", "close"] * len(opens)
     assert all(log[i][1] == log[i + 1][1] for i in range(0, len(log), 2))
-
-
-def test_disabled_prof_is_early_outs():
-    p = RoundProf(enabled=False)
-    assert p._annotation is None          # jax.profiler never imported for it
-    p.begin_round()
-    p.mark_starved()
-    p.enter(SEGMENTS.index("admit"))
-    p.mark_fed()
-    p.end_round()
-    t = p.totals()
-    assert t["rounds"] == 0 and t["starved"]["total_s"] == 0.0
 
 
 def test_segments_land_on_the_profilers_host_plane(tmp_path):

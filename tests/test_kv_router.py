@@ -3,7 +3,7 @@ sequence.rs tests).
 
 The keystone behavior test runs the router over N mocker workers and checks
 that prefix-heavy traffic concentrates on the warm worker — the reference's
-headline 3x-TTFT feature (BASELINE.md), exercised on CPU.
+headline KV-aware routing feature, exercised on CPU.
 """
 import asyncio
 import random

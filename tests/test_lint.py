@@ -121,6 +121,31 @@ def test_dtl005_fires_on_invalid_family_type():
     assert "DTL005" in rules_fired(bad, "dynamo_tpu/bogus/metrics.py")
 
 
+def test_dtl005_fires_on_a_registry_render_planes_does_not_name():
+    """The one list of metric planes (telemetry/metrics.py:
+    render_planes) and the three scrape surfaces that call it, plus one
+    module whose registry the list leaves out."""
+    from dynamo_tpu.lint.core import ProjectIndex, _run
+    from dynamo_tpu.lint.metrics_contract import (
+        _PLANES,
+        _SURFACES,
+        MetricsContractRule,
+    )
+
+    index = ProjectIndex(str(REPO_ROOT))
+    for rel in (_PLANES, *_SURFACES):
+        index.add_file("dynamo_tpu/" + rel)
+    rule = [MetricsContractRule()]
+    assert [f for f in _run(index, rule) if "render_planes" in f.message] == []
+    index.add_source(
+        "dynamo_tpu/bogus/metrics.py",
+        "from dynamo_tpu.telemetry.metrics import CounterRegistry\n"
+        "BOGUS = CounterRegistry((), label='bogus')\n")
+    missed = [f for f in _run(index, rule) if "render_planes" in f.message]
+    assert [f.path for f in missed] == ["dynamo_tpu/bogus/metrics.py"]
+    assert "BOGUS" in missed[0].message
+
+
 def test_dtl006_fires_on_unregistered_wire_exception():
     bad = (
         "class FlakyLinkError(ConnectionError):\n"

@@ -203,7 +203,7 @@ def test_sharded_prefill_matches_unsharded(pair):
 
 
 def test_load_ctx_pages_pow2_clamp_at_bench_r05_shape():
-    """Regression pin for the BENCH_r05 tail crash: a 46-page matched run
+    """Regression pin for a crash in a run's tail: a 46-page matched run
     pow2-padded to 64 pages (update span 64*64 = 4096 tokens) loaded into
     a ctx region of S = 3328 (52 pages) must clamp statically to the
     region — the unclamped dynamic_update_slice was a trace-time

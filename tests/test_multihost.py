@@ -1,4 +1,4 @@
-"""Cross-host single-engine test (BASELINE config 4; reference
+"""Cross-host single-engine test (a model sharded past one host; reference
 flags.rs:86-101 MultiNodeConfig + leader_worker_barrier.rs): TWO OS
 processes form one jax.distributed mesh (2 hosts x 2 virtual CPU devices,
 tp=4); the leader runs the full engine scheduler and broadcasts every

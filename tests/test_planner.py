@@ -222,7 +222,7 @@ async def test_system_server_per_worker():
 
 @pytest.mark.asyncio_timeout(600)
 async def test_planner_scales_multihost_engine_groups():
-    """BASELINE config 4 x planner: DP replicas OF a cross-host engine.
+    """engine/multihost.py x planner: DP replicas OF a cross-host engine.
     Each replica the planner adds is a 2-process lockstep group (leader
     in=endpoint + replay follower over one jax.distributed mesh); scale
     1 -> 2 under held load, then back to 1, with registrations following

@@ -809,23 +809,38 @@ def test_resilience_metrics_render_families():
     assert "dynamo_resilience_draining 1" in text
 
 
-def test_resilience_metrics_on_all_three_surfaces():
+def _frontend_surface():
     from dynamo_tpu.frontend.service import HttpService
-    from dynamo_tpu.metrics_exporter import MetricsExporter
+
+    return HttpService()
+
+
+def _system_surface():
     from dynamo_tpu.runtime.system_server import SystemServer
 
+    return SystemServer(None, worker_id="w0")
+
+
+def _exporter_surface():
+    from dynamo_tpu.metrics_exporter import MetricsExporter
+
+    return MetricsExporter(kv=None)
+
+
+@pytest.mark.parametrize("openmetrics", [False, True])
+@pytest.mark.parametrize(
+    "surface", [_frontend_surface, _system_surface, _exporter_surface])
+async def test_resilience_metrics_on_all_three_surfaces(surface, openmetrics):
+    """Each scrape surface answers a real GET /metrics with the
+    resilience plane in it (through telemetry.metrics.render_planes)."""
+    from aiohttp.test_utils import make_mocked_request
+
+    RESILIENCE.reset()
     RESILIENCE.inc("dynamo_migration_total", 2)
-    sys_text = SystemServer(None, worker_id="w0").render()
-    exp_text = MetricsExporter(kv=None).render()
-    svc = HttpService()
-    import asyncio as _a
-
-    async def front():
-        req = None  # handle_metrics ignores the request object
-        resp = await svc.handle_metrics(req)
-        return resp.body.decode()
-
-    front_text = _a.get_event_loop_policy().new_event_loop().run_until_complete(front())
-    for text in (sys_text, exp_text, front_text):
-        assert "dynamo_migration_total 2" in text
-        assert "# TYPE dynamo_resilience_breaker_trips_total counter" in text
+    accept = "application/openmetrics-text" if openmetrics else "*/*"
+    resp = await surface().handle_metrics(
+        make_mocked_request("GET", "/metrics", headers={"Accept": accept}))
+    text = resp.text
+    assert "dynamo_migration_total 2" in text
+    assert "# TYPE dynamo_resilience_breaker_trips_total counter" in text
+    assert text.endswith("# EOF\n") == openmetrics

@@ -146,7 +146,8 @@ async def test_system_server_chaos_control():
         assert names == {"kill_worker", "stall_stream", "drop_response",
                          "delay", "storm", "flip_kv_bits",
                          "corrupt_frame", "truncate_g3",
-                         "kill_store", "partition_store"}
+                         "corrupt_prefetch", "kill_store",
+                         "partition_store"}
         resp = await c.post("/chaos", json={
             "point": "kill_worker", "probability": 0.5,
             "after_outputs": 3, "once": True,

@@ -10,7 +10,7 @@ flight (migration replay).
 
 The off mode (``round_pipeline=False``) is the legacy serialized round
 order — the differential baseline, kept reachable exactly for these
-tests and for ``--round-pipeline off`` triage in production.
+tests (no flag or runtime setting selects it).
 """
 import asyncio
 

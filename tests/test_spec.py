@@ -462,7 +462,7 @@ async def test_spec_metrics_and_sdk_request_stats():
 
 
 async def test_spec_repetitive_prompts_exceed_one_token_per_step():
-    """The bench claim at test scale: on repetitive prompts, n-gram
+    """At test scale: on repetitive prompts, n-gram
     speculation emits strictly more than one token per verify step."""
     cfg = ModelConfig.tiny(dtype="float32")
     setup = (cfg, llama.init_params(cfg, 0))

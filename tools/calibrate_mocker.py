@@ -4,8 +4,8 @@
 The fleet simulator's workers are MockerEngines; for its autoscaling and
 routing conclusions to transfer, the mocker's two timing knobs must
 match the engine the fleet would actually run. This tool reads the JSON
-emitted by ``dynamo_tpu.profiler.profile_engine`` (or tools/bench.py's
-profile phase) and inverts the concurrency-1 point:
+emitted by ``dynamo_tpu.profiler.profile_engine`` and inverts the
+concurrency-1 point:
 
 - ``prefill_time_per_token_s`` = TTFT p50 at concurrency 1 / ISL
   (an unloaded TTFT is ~pure prefill; queueing is simulated separately)

@@ -8,8 +8,9 @@ chrome://tracing:
   - a /debug/trace/{request_id} span tree (frontend + worker spans,
     disagg kv chunks, spec draft/verify children)
   - a /debug/flight dump (recent engine dispatches, as instants)
-  - kv_transfer stream events captured in a bench/debug JSON payload
-  - host-round segment records (same payload shape bench.py emits)
+  - kv_transfer stream events captured in a debug JSON payload
+  - host-round segment records (``RoundProf.recent()`` rows, as the
+    /debug/outliers dossier carries them)
 
 Usage:
     python tools/trace_export.py http://HOST:PORT/debug/trace/REQ_ID \
