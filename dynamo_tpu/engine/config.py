@@ -68,11 +68,16 @@ class EngineConfig:
     prefill_chunks_per_round: int = 2
     # batched multi-request prefill (models/llama.py batch_prefill — the
     # vLLM max_num_batched_tokens analogue): concurrent same-bucket chunks
-    # run as ONE [K, T] program. K is compiled at
-    # min(prefill_batch_max, prefill_token_budget // T) and short groups
-    # are padded with scratch-lane dummies — one compilation per (T, ctx)
-    # shape instead of one per group size (each is a whole-model
-    # compile). 1 disables batching.
+    # run as ONE [K, T] program. K follows the group it carries
+    # (prefill_lanes below: the power of two that covers the group, at
+    # most min(prefill_batch_max, prefill_token_budget // T)), so a
+    # dummy lane pays the block's matmuls only where a group of 3 or
+    # 5-7 leaves one. A group holds at most prefill_chunks_per_round
+    # requests: at its default of 2 the only batched K is 2, one
+    # whole-model compile per (T, ctx_span); raised, K is 2, 4 or 8 and
+    # up to three widths per (T, ctx_span) compile lazily, each when a
+    # group of its size first forms. prefill_batch_max 1 disables
+    # batching.
     prefill_batch_max: int = 8
     prefill_token_budget: int = 8192
 
@@ -263,3 +268,13 @@ class EngineConfig:
             if n_tokens <= b:
                 return b
         return None
+
+    def prefill_lanes(self, width: int, group: Optional[int] = None) -> int:
+        """Lanes of a batched prefill at chunk width `width`: without
+        `group` the most a group may hold (admission's cap), with it the
+        compiled K of the dispatch that carries `group` requests. Both
+        from here, so a group never outgrows its program and a program
+        is never wider than a group could fill."""
+        most = max(1, min(self.prefill_batch_max,
+                          self.prefill_token_budget // width))
+        return most if group is None else min(pow2_cover(group), most)

@@ -3500,7 +3500,7 @@ class TpuEngine:
         e = self.ecfg
         group: list[_Request] = []
         width = 0
-        cap = min(budget, max(1, e.prefill_batch_max))
+        cap = budget
         for r in self._waiting:
             if len(group) >= cap:
                 break
@@ -3513,7 +3513,7 @@ class TpuEngine:
             t = self._chunk_width(len(r.tokens) - r.prefill_pos)
             if not group:
                 width = t
-                cap = min(cap, max(1, e.prefill_token_budget // t))
+                cap = min(budget, e.prefill_lanes(t))
             elif t != width:
                 break
             group.append(r)
@@ -3530,13 +3530,11 @@ class TpuEngine:
     ) -> list[_Request]:
         """Dispatch one batched prefill for the group's next chunks and
         finish the requests whose prompts complete. The compiled batch
-        width is the CAP for this bucket (not the group size): short
-        groups pad with scratch-lane dummies so each (T, ctx_span) shape
-        compiles once."""
+        width follows the group (EngineConfig.prefill_lanes, which also
+        capped it): a group of two runs two lanes, and only a group of 3
+        or 5-7 pads with scratch-lane dummies."""
         e = self.ecfg
-        K = max(len(group),
-                min(e.prefill_batch_max,
-                    max(1, e.prefill_token_budget // width)))
+        K = e.prefill_lanes(width, len(group))
         toks = np.zeros((K, width), np.int32)
         slots = np.full(K, self._B, np.int32)   # dummies -> scratch lane
         q_starts = np.zeros(K, np.int32)

@@ -6,6 +6,7 @@ its tokens equal a hand-driven prefill/decode loop on the raw model — any
 scheduler off-by-one (ctx lengths, page growth, commit timing) breaks it.
 """
 import asyncio
+import threading
 
 import numpy as np
 import pytest
@@ -364,6 +365,119 @@ async def test_engine_batched_prefill_groups(engine_setup):
     solo = [await collect(eng, req(i)) for i in range(4)]
     assert [t for t, _ in solo] == [t for t, _ in wave1]
     await eng.stop()
+
+
+class _HeldGroups:
+    """An ``on_dispatch`` sink that records every batched prefill and
+    holds the engine thread inside the FIRST solo prefill until released:
+    what is submitted meanwhile sits in the intake and reaches admission
+    together, so the group's size is the test's and not the race's."""
+
+    def __init__(self):
+        self.entered = threading.Event()
+        self.release = threading.Event()
+        self.batched = []
+
+    def __call__(self, kind, payload):
+        if kind == "prefill" and not self.entered.is_set():
+            self.entered.set()
+            assert self.release.wait(60.0)
+        elif kind == "prefill_batch":
+            self.batched.append(payload)
+
+
+async def _dispatch_one_group(cfg, ecfg, params, n, prompt_len):
+    """Serve `n` same-bucket prompts that arrive while a first one holds
+    the engine; returns (engine's scratch lane, the batched dispatches,
+    the padded-token delta of the held prompt plus the group)."""
+    from dynamo_tpu.parallel.mesh import MeshConfig
+
+    sink = _HeldGroups()
+    eng = TpuEngine(cfg, ecfg, params=params, mesh_config=MeshConfig(tp=1),
+                    on_dispatch=sink)
+    pad = "dynamo_engine_prefill_padded_tokens"
+
+    def req(base):
+        return PreprocessedRequest(
+            token_ids=[base + j for j in range(prompt_len)],
+            stop_conditions=StopConditions(max_tokens=3, ignore_eos=True),
+        )
+
+    try:
+        before = eng.telemetry.snapshot()[pad]["sum"]
+        held = asyncio.create_task(collect(eng, req(1)))
+        while not sink.entered.is_set():
+            await asyncio.sleep(0.005)
+        group = [asyncio.create_task(collect(eng, req(1000 * (i + 1))))
+                 for i in range(n)]
+        while eng._intake.qsize() < n:
+            await asyncio.sleep(0.005)
+        sink.release.set()
+        outs = await asyncio.gather(held, *group)
+        assert all(len(t) == 3 for t, _ in outs)
+        padded = eng.telemetry.snapshot()[pad]["sum"] - before
+    finally:
+        sink.release.set()
+        await eng.stop()
+    return eng._B, sink.batched, padded
+
+
+@pytest.mark.parametrize("n,lanes", [(2, 2), (3, 4), (4, 4), (5, 8)])
+async def test_batched_prefill_lanes_follow_the_group(engine_setup, n, lanes):
+    """A batched prefill is compiled for the lanes it carries: with room
+    for groups of up to 8, a group of n dispatches [pow2_cover(n), T] and
+    only the lanes past n are scratch-lane dummies (seq_len 0); the
+    padded-token counter is charged K * T for it."""
+    from dataclasses import replace
+
+    cfg, ecfg, params = engine_setup
+    ecfg = replace(ecfg, prefill_chunks_per_round=8, max_decode_slots=8)
+    scratch, batched, padded = await _dispatch_one_group(
+        cfg, ecfg, params, n, prompt_len=40)
+    assert len(batched) == 1
+    d = batched[0]
+    assert np.asarray(d["tokens"]).shape == (lanes, 64)
+    assert len(set(d["slots"][:n]) - {scratch}) == n   # n lanes of their own
+    assert d["slots"][n:] == [scratch] * (lanes - n)
+    assert d["seq_lens"] == [40] * n + [0] * (lanes - n)
+    # the held prompt ran solo in its own bucket; the group K * T
+    assert padded == 64 + lanes * 64
+
+
+async def test_default_config_batches_two_lanes_without_dummies():
+    """Under the DEFAULT EngineConfig (prefill_chunks_per_round 2) a
+    batched dispatch is a group of two in a [2, T] program: no lane of
+    it is the scratch lane."""
+    cfg = ModelConfig.tiny(dtype="float32")
+    ecfg = EngineConfig()
+    scratch, batched, padded = await _dispatch_one_group(
+        cfg, ecfg, llama.init_params(cfg, 0), 2, prompt_len=40)
+    assert len(batched) == 1
+    d = batched[0]
+    assert np.asarray(d["tokens"]).shape == (2, 128)
+    assert scratch not in d["slots"] and d["seq_lens"] == [40, 40]
+    assert padded == 128 + 2 * 128
+
+
+@pytest.mark.parametrize("width", [128, 256, 512, 1024, 2048, 4096, 8192])
+@pytest.mark.parametrize("kw", [
+    {}, {"prefill_batch_max": 6}, {"prefill_batch_max": 1},
+    {"prefill_token_budget": 2048},
+])
+def test_prefill_lanes_cap_and_width_agree(width, kw):
+    """EngineConfig.prefill_lanes is the one rule admission's cap and
+    the compiled K both read: every group the cap admits fits its K, K is
+    never wider than the cap, and it is the covering power of two
+    whenever that fits."""
+    e = EngineConfig(**kw)
+    cap = e.prefill_lanes(width)
+    assert cap == max(1, min(e.prefill_batch_max,
+                             e.prefill_token_budget // width))
+    for n in range(1, cap + 1):
+        k = e.prefill_lanes(width, n)
+        assert n <= k <= cap
+        assert k == min(1 << (n - 1).bit_length(), cap)
+    assert e.prefill_lanes(width, cap) == cap
 
 
 async def test_engine_int8_quantized_serving(engine_setup):

@@ -157,12 +157,13 @@ def test_counters_add_up(served, case):
 
 
 async def test_prefill_dispatches_observe_attention_pairs():
-    """A solo fresh dispatch, a batched fresh group with dummy lanes and
-    a solo continuation after a prefix hit each observe the (query, key)
+    """A solo fresh dispatch, a batched fresh group of two (two lanes,
+    no dummy: the third prompt runs solo, before or after it) and a solo
+    continuation after a prefix hit each observe the (query, key)
     pairs their attention had to score and did score, by hand: buckets
     (32, 64) are below the attention's 256-row block, so a live lane
-    scores its one bucket x bucket block, a dummy lane nothing, and a
-    continuation adds the region block(s) below its q_start."""
+    scores its one bucket x bucket block, and a continuation adds the
+    region block(s) below its q_start."""
     eng = _engine()
     eng.start()
     try:
@@ -179,7 +180,8 @@ async def test_prefill_dispatches_observe_attention_pairs():
         before = eng.batch_prefills
         await asyncio.gather(*[_one(eng, p) for p in group])
         h2 = await _settled(eng)
-        assert eng.batch_prefills > before       # 8 lanes, 5+ of them dummies
+        assert eng.batch_prefills > before       # 2 lanes, both live
+        assert _delta(h1, h2, PAD) == 3 * 64     # no lane but the prompts'
         assert _delta(h1, h2, ALIVE) == 2 * (40 * 41 // 2) + 50 * 51 // 2
         assert _delta(h1, h2, ASCORED) == 3 * 64 * 64
         assert _delta(h1, h2, ALIVE, "count") == _delta(h1, h2, PF, "count")

@@ -30,7 +30,8 @@ ring->ctx flush, the standalone ctx->pool seal at ``--seal-width``
 entries, both in one jit, the fused round as the engine builds it
 (``flush_every`` decode steps, flush, seal), the pool -> region load and
 the pool's page gather / scatter at a long prompt's pages, and the
-smallest batched prefill bucket (fresh).
+smallest batched prefill bucket (fresh) at the lanes the engine gives a
+group of two.
 Prints one JSON line per program with XLA's memory analysis and the
 region-shaped copies in the compiled text; exits 1 if any program fails
 to compile. libtpu warns about ``TPU_ACCELERATOR_TYPE`` / worker hostnames
@@ -148,7 +149,10 @@ def compile_programs(model_config: str = "llama3_1b", tp: int = 1,
     )
     ring = abstract(lambda: llama.init_ring(c, B, R, dtype),
                     llama.ring_shardings(c, mesh))
-    K, T = e.prefill_batch_max, e.prefill_buckets[0]
+    # the batched prefill the cells run: a group of two (the most that
+    # prefill_chunks_per_round's default lets form) at the first bucket
+    T = e.prefill_buckets[0]
+    K = e.prefill_lanes(T, 2)
 
     def largest_shard(state):
         a = max(jax.tree.leaves(state), key=lambda a: math.prod(a.shape))
