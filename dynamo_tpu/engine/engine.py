@@ -72,6 +72,7 @@ from dynamo_tpu.kv_router.protocols import (
 )
 from dynamo_tpu.models import llama, mla_moe
 from dynamo_tpu.models.config import ModelConfig
+from dynamo_tpu.ops import latent_decode
 from dynamo_tpu.ops.attention import (
     decode_attention_for,
     prefill_attention_pairs,
@@ -536,6 +537,14 @@ class TpuEngine:
         self._h_moe_touched = self.telemetry.get(tmetrics.MOE_TOUCHED[0])
         self._h_moe_routed = self.telemetry.get(tmetrics.MOE_ROUTED[0])
         self._h_moe_load_max = self.telemetry.get(tmetrics.MOE_LOAD_MAX[0])
+        self._h_hc_residual = self.telemetry.get(
+            tmetrics.HC_SINKHORN_RESIDUAL[0])
+        self._h_pf_continued = self.telemetry.get(
+            tmetrics.PREFILL_CONTINUED[0])
+        self._h_attn_rows_read = self.telemetry.get(
+            tmetrics.DECODE_ATTN_ROWS_READ[0])
+        self._h_attn_rows_live = self.telemetry.get(
+            tmetrics.DECODE_ATTN_ROWS_LIVE[0])
         # bytes a token holds in the ctx region: observed once, here
         self.telemetry.get(tmetrics.KV_ROW_BYTES[0]).observe(sum(
             x.nbytes for x in jax.tree.leaves(self.ctx)
@@ -773,7 +782,7 @@ class TpuEngine:
             # routing counters home in the same fetch
             routed = c.routed is not None
             toks_out = jnp.zeros((n_steps + int(routed), B), jnp.int32)
-            moe_stats = jnp.zeros(3, jnp.int32)
+            moe_stats = mla_moe.stats_zero(c)
             lp_out = (
                 jnp.zeros((n_steps, B, 1 + 2 * max_logprobs), jnp.float32)
                 if want_lp else None
@@ -836,7 +845,8 @@ class TpuEngine:
                 0, n_steps, body, (ring, dev, toks_out, lp_out, moe_stats)
             )
             if routed:
-                toks_out = toks_out.at[n_steps, :3].set(moe_stats)
+                toks_out = toks_out.at[
+                    n_steps, :moe_stats.shape[0]].set(moe_stats)
             # round boundary: the ring goes into the ctx region, one
             # in-place span a lane, after every read (llama.flush_ctx_impl)
             valid = jnp.minimum(jnp.int32(n_steps), max_context - ring_base)
@@ -1180,12 +1190,13 @@ class TpuEngine:
                 raise ValueError(
                     f"{plane} cannot carry a latent (MLA) cache row yet; "
                     "turn it off for this model")
-        if e.max_decode_slots < 3:
-            # the round's three routing counters ride home in one more
-            # row of the stacked-token fetch, max_decode_slots wide
+        need = 3 if self.config.hc is None else 4
+        if e.max_decode_slots < need:
+            # the round's counters (mla_moe.stats_zero) ride home in one
+            # more row of the stacked-token fetch, max_decode_slots wide
             raise ValueError(
-                f"max_decode_slots={e.max_decode_slots}: a routed-expert "
-                "model needs at least 3 (its routing counters ride the "
+                f"max_decode_slots={e.max_decode_slots}: this routed-expert "
+                f"model needs at least {need} (its counters ride the "
                 "round's token fetch in a row that wide)")
 
     def _refuse_latent_transfer(self) -> None:
@@ -2361,6 +2372,8 @@ class TpuEngine:
             spec_slots=np.flatnonzero(self._slot_spec).tolist(),
             dispatch_ms=round((time.monotonic() - t_disp) * 1e3, 3),
         )
+        if self.config.mla is not None:
+            self._observe_decode_attn_rows(active, n)
         # only dispatched lanes advance (spec slots track their own
         # lengths through verify processing)
         self._ctx_disp[active] = np.minimum(
@@ -3597,6 +3610,22 @@ class TpuEngine:
             width, q_starts, seq_lens, ctx_span)
         self._h_pf_live.observe(live)
         self._h_pf_scored.observe(scored)
+        # and the prompt positions it computed in CONTINUING chunks
+        self._h_pf_continued.observe(sum(
+            min(max(int(n) - int(q), 0), width)
+            for q, n in zip(q_starts, seq_lens) if int(q) > 0))
+
+    def _observe_decode_attn_rows(self, active, n_steps: int) -> None:
+        """One observation per dispatched round of the region rows its
+        latent decode attention read and the rows that were some lane's
+        own — the host's mirror of latent_decode_attention's trip count:
+        every lane reads up to the LONGEST lane's rows, in whole chunks
+        (the region's rows lie below the round's ring base, ctx - 1)."""
+        base = np.maximum(self._ctx_disp - 1, 0)
+        chunk = min(latent_decode.CHUNK, self.ecfg.max_context)
+        longest = -(-int(base.max()) // chunk) * chunk
+        self._h_attn_rows_read.observe(n_steps * self._B * longest)
+        self._h_attn_rows_live.observe(n_steps * int(base[active].sum()))
 
     def _free_slot(self) -> Optional[int]:
         for i, s in enumerate(self._slots):
@@ -4101,6 +4130,11 @@ class TpuEngine:
             self._h_moe_touched.observe(int(touched))
             self._h_moe_routed.observe(int(routed))
             self._h_moe_load_max.observe(int(load_max))
+            if self.config.hc is not None:
+                # the fourth counter is a float32's bits
+                self._h_hc_residual.observe(float(
+                    toks[entry.n_steps, 3:4].astype(np.int32).view(
+                        np.float32)[0]))
         delivered = 0
         for slot, r in enumerate(entry.slots):
             # identity check doubles as the epoch: a recycled slot holds

@@ -16,7 +16,7 @@ from typing import Any, Optional
 # model_type values served by the dense decoder (models/llama.py) and by
 # the latent-attention + routed-expert block (models/mla_moe.py)
 _DENSE_TYPES = frozenset({"llama", "mistral", "qwen2"})
-_MLA_MOE_TYPES = frozenset({"deepseek_v3", "joyai_llm_flash"})
+_MLA_MOE_TYPES = frozenset({"deepseek_v3", "joyai_llm_flash", "xing4_0"})
 # keys that mean "not a dense Llama": a config carrying one is refused
 # rather than read with its extra structure dropped
 _FOREIGN_KEYS = ("kv_lora_rank", "q_lora_rank", "n_routed_experts",
@@ -28,6 +28,16 @@ _ROUTED_KEYS = ("n_routed_experts", "num_experts_per_tok",
                 "moe_intermediate_size", "n_shared_experts",
                 "first_k_dense_replace", "routed_scaling_factor",
                 "norm_topk_prob")
+# manifold-constrained hyper-connections (arXiv:2512.24880): all five or
+# none. With them the residual state is hc_mult streams (ops/
+# hyper_connection.py)
+_HC_KEYS = ("hc_mult", "hc_sinkhorn_iters", "hc_eps",
+            "mhc_h_res_clamp_min", "mhc_h_res_clamp_max")
+# the one rope_scaling this block implements: YaRN as DeepSeek-V3's
+# config.json parameterises it (ops/rope.py: yarn_inv_freq, yarn_mscale)
+_YARN_KEYS = frozenset({"type", "factor", "original_max_position_embeddings",
+                        "beta_fast", "beta_slow", "mscale",
+                        "mscale_all_dim"})
 _TINY_MLA_MOE = {
     "model_type": "deepseek_v3", "vocab_size": 256, "hidden_size": 64,
     "intermediate_size": 128, "num_hidden_layers": 3,
@@ -41,6 +51,13 @@ _TINY_MLA_MOE = {
     "rope_theta": 10000.0, "rope_interleave": True, "rope_scaling": None,
     "rms_norm_eps": 1e-6, "max_position_embeddings": 512,
     "hidden_act": "silu", "tie_word_embeddings": False,
+}
+_TINY_MHC = {
+    "hc_mult": 4, "hc_sinkhorn_iters": 20, "hc_eps": 1e-6,
+    "mhc_h_res_clamp_min": -30, "mhc_h_res_clamp_max": 30,
+    "rope_scaling": {
+        "type": "yarn", "factor": 4, "original_max_position_embeddings": 64,
+        "beta_fast": 32, "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1},
 }
 
 
@@ -84,6 +101,13 @@ class ModelConfig:
     # then describe that cached row (1 head of the row's width).
     mla: Optional[tuple[tuple[str, Any], ...]] = None
     routed: Optional[tuple[tuple[str, Any], ...]] = None
+    # the four-stream residual of that block (keys in _HC_KEYS); None =
+    # one stream, the plain residual
+    hc: Optional[tuple[tuple[str, Any], ...]] = None
+
+    @property
+    def hc_dict(self) -> Optional[dict[str, Any]]:
+        return dict(self.hc) if self.hc else None
 
     @property
     def mla_dict(self) -> Optional[dict[str, Any]]:
@@ -156,8 +180,22 @@ class ModelConfig:
         if missing:
             raise ValueError(f"latent-attention block: keys {missing} "
                              "are missing from the config")
+        scaling = d.get("rope_scaling")
+        if scaling is not None and (
+                not isinstance(scaling, dict)
+                or scaling.get("type") != "yarn"
+                or set(scaling) != _YARN_KEYS):
+            raise ValueError(
+                f"latent-attention block: rope_scaling {scaling!r} is one "
+                "this program does not implement: only type 'yarn' with "
+                f"exactly the keys {sorted(_YARN_KEYS)}")
+        hc_given = sorted(k for k in _HC_KEYS if k in d)
+        if hc_given and len(hc_given) != len(_HC_KEYS):
+            raise ValueError(
+                "latent-attention block: hyper-connection keys "
+                f"{sorted(set(_HC_KEYS) - set(hc_given))} are missing "
+                f"from the config (it has {hc_given})")
         refused = {
-            "rope_scaling": d.get("rope_scaling") is not None,
             "scoring_func": d["scoring_func"] != "sigmoid",
             "topk_method": d.get("topk_method", "noaux_tc") != "noaux_tc",
             "n_group/topk_group": (d.get("n_group", 1), d.get(
@@ -169,6 +207,9 @@ class ModelConfig:
             "num_nextn_predict_layers (the draft head is not built)":
                 d.get("num_nextn_predict_layers", 0) != 0,
             "tie_word_embeddings": bool(d.get("tie_word_embeddings")),
+            "hc_mult < 2": bool(hc_given) and int(d["hc_mult"]) < 2,
+            "hc_sinkhorn_iters < 1":
+                bool(hc_given) and int(d["hc_sinkhorn_iters"]) < 1,
         }
         bad = sorted(k for k, v in refused.items() if v)
         if bad:
@@ -190,8 +231,11 @@ class ModelConfig:
             rms_norm_eps=d.get("rms_norm_eps", 1e-6),
             max_position_embeddings=d.get("max_position_embeddings", 8192),
             model_type=d.get("model_type", "deepseek_v3"),
+            rope_scaling=(tuple(sorted(scaling.items()))
+                          if scaling else None),
             mla=tuple(sorted(mla.items())),
             routed=tuple(sorted(routed.items())),
+            hc=(tuple((k, d[k]) for k in _HC_KEYS) if hc_given else None),
         )
 
     @classmethod
@@ -201,6 +245,14 @@ class ModelConfig:
         d = dict(_TINY_MLA_MOE)
         d.update(kw)
         return cls.from_hf_dict(d)
+
+    @classmethod
+    def tiny_mla_moe_mhc(cls, **kw) -> "ModelConfig":
+        """The toy block with the four-stream residual and YaRN (factor 4
+        over 64 positions, so that a short test prompt crosses it)."""
+        d = dict(_TINY_MHC)
+        d.update(kw)
+        return cls.tiny_mla_moe(**d)
 
     @classmethod
     def from_pretrained(cls, model_dir: str) -> "ModelConfig":

@@ -26,6 +26,15 @@ What differs from models/llama.py, and how the engine meets it:
     bias, normalised, scaled; the grouped dropless product
     (models/moe.py: grouped_experts) reads only the experts some token
     picked; the shared expert is added ungated.
+  - Two things a config may add to the block (``ModelConfig.hc``,
+    ``.rope_scaling``). A RESIDUAL OF n STREAMS (manifold-constrained
+    hyper-connections, ops/hyper_connection.py): ``h`` is then [N, n, H],
+    the embedding opens n copies, each sublayer reads a mix of the
+    streams and its output is spread over a remix of them (``_open`` /
+    ``_close``), and the head closes them with their sum. YaRN rotary
+    (``_rotary``): its own inverse frequencies, a factor on cos and sin,
+    and ``mscale_all_dim``'s factor, squared, on the softmax scale of
+    BOTH attention forms.
 
 Every function here is reached through the ``llama`` names the engine and
 the benchmark's launcher call (``llama.init_params``, ``init_ctx``,
@@ -42,9 +51,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models.moe import grouped_experts
+from dynamo_tpu.ops import hyper_connection as hc
 from dynamo_tpu.ops.attention import PriorContext, prefill_attention
 from dynamo_tpu.ops.latent_decode import latent_decode_attention
-from dynamo_tpu.ops.rope import rope_inv_freq
+from dynamo_tpu.ops.rope import rope_inv_freq, yarn_inv_freq, yarn_mscale
 
 Params = dict[str, Any]
 Cache = dict[str, jnp.ndarray]
@@ -70,6 +80,8 @@ def dims(c: ModelConfig) -> dict[str, int]:
         "I_e": r["moe_intermediate_size"],
         "I_s": r["moe_intermediate_size"] * r["n_shared_experts"],
         "n_dense": min(r["first_k_dense_replace"], c.num_layers),
+        # residual streams (1: the plain residual)
+        "n": c.hc_dict["hc_mult"] if c.hc else 1,
     }
 
 
@@ -100,7 +112,7 @@ def init_params(config: ModelConfig, rng: jax.Array | int = 0) -> Params:
     L, H, V = c.num_layers, c.hidden_size, c.vocab_size
     Ld, Le = d["n_dense"], c.num_layers - d["n_dense"]
     nh, I = d["nh"], c.intermediate_size
-    return {
+    params = {
         "embed": rnd(V, H, scale=0.02),
         "norm_f": jnp.ones((H,), dtype),
         "lm_head": rnd(H, V, scale=0.02),
@@ -139,6 +151,23 @@ def init_params(config: ModelConfig, rng: jax.Array | int = 0) -> Params:
             "ws_d": rnd(d["I_s"], H),
         } for _ in range(Le)],
     }
+    if c.hc is not None:
+        # drawn after everything else, so that a seed gives the block
+        # without streams the weights it always gave. Two sublayers a
+        # layer, each phi [n H, n + n + n n] and its 3 gains and n + n +
+        # n n offsets. The gains are 1 and b_res is of order 1 so that
+        # the token-dependent term and the Sinkhorn iterations both move
+        # the logits (a trained model's small gates would hide both from
+        # any check); b_pre and b_post are 0
+        n = d["n"]
+        m = 2 * n + n * n
+        b_res = jax.random.normal(next(keys), (L, 2, n * n), jnp.float32)
+        params["layers"].update(
+            hc_phi=rnd(L, 2, n * H, m),
+            hc_a=jnp.ones((L, 2, 3), jnp.float32),
+            hc_b=jnp.concatenate(
+                [jnp.zeros((L, 2, 2 * n), jnp.float32), b_res], axis=-1))
+    return params
 
 
 def param_shardings(config: ModelConfig, mesh: Mesh) -> Params:
@@ -202,13 +231,27 @@ def _rms(x, w, eps):
     return (xf * jax.lax.rsqrt(var + eps)).astype(x.dtype) * w
 
 
-def _rope_pairs(x, positions, inv_freq):
+def _rotary(c: ModelConfig):
+    """(inverse frequencies [rope/2], the factor on cos and sin, the
+    factor on the softmax scale): 1 and 1 without ``rope_scaling``, YaRN's
+    with it (ops/rope.py)."""
+    rope, s = dims(c)["rope"], c.rope_scaling_dict
+    if s is None:
+        return rope_inv_freq(rope, c.rope_theta, None), 1.0, 1.0
+    m_all = yarn_mscale(s["factor"], s["mscale_all_dim"])
+    return (yarn_inv_freq(rope, c.rope_theta, s),
+            yarn_mscale(s["factor"], s["mscale"]) / m_all, m_all * m_all)
+
+
+def _rope_pairs(x, positions, inv_freq, times: float = 1.0):
     """Interleaved rotary: values (2i, 2i+1) of the last axis are one
-    pair, turned by positions * inv_freq[i]. ``x`` [N, ..., r],
-    ``positions`` [N]."""
+    pair, turned by positions * inv_freq[i]; cos and sin times ``times``.
+    ``x`` [N, ..., r], ``positions`` [N]."""
     ang = positions.astype(jnp.float32)[:, None] * inv_freq   # [N, r/2]
     shape = (x.shape[0],) + (1,) * (x.ndim - 2) + (ang.shape[-1],)
     cos, sin = jnp.cos(ang).reshape(shape), jnp.sin(ang).reshape(shape)
+    if times != 1.0:
+        cos, sin = cos * times, sin * times
     xf = x.astype(jnp.float32).reshape(*x.shape[:-1], -1, 2)
     a, b = xf[..., 0], xf[..., 1]
     out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
@@ -221,15 +264,16 @@ def _attn_in(c: ModelConfig, lp, h, positions):
     [N, stored] = [c_kv | k_rope | 0...]."""
     d = dims(c)
     N = h.shape[0]
-    inv_freq = jnp.asarray(rope_inv_freq(d["rope"], c.rope_theta, None))
+    inv_freq, times, _ = _rotary(c)
+    inv_freq = jnp.asarray(inv_freq)
     x = _rms(h, lp["ln1"], c.rms_norm_eps)
     cq = _rms(x @ lp["wqa"], lp["q_norm"], c.rms_norm_eps)
     q = (cq @ lp["wqb"]).reshape(N, d["nh"], d["nope"] + d["rope"])
     q_nope, q_rope = q[..., :d["nope"]], q[..., d["nope"]:]
     kv = x @ lp["wkva"]
     c_kv = _rms(kv[:, :d["kv_rank"]], lp["kv_norm"], c.rms_norm_eps)
-    k_rope = _rope_pairs(kv[:, d["kv_rank"]:], positions, inv_freq)
-    q_rope = _rope_pairs(q_rope, positions, inv_freq)
+    k_rope = _rope_pairs(kv[:, d["kv_rank"]:], positions, inv_freq, times)
+    q_rope = _rope_pairs(q_rope, positions, inv_freq, times)
     pad = jnp.zeros((N, d["stored"] - d["row"]), c_kv.dtype)
     return q_nope, q_rope, jnp.concatenate([c_kv, k_rope, pad], axis=-1)
 
@@ -244,14 +288,26 @@ def _wkvb(c: ModelConfig, lp):
 
 def _absorb_q(c: ModelConfig, lp, q_nope, q_rope, times: float = 1.0):
     """The query in the latent space, scaled: its scores against cached
-    rows are ``times`` x the expanded scores / sqrt(qk width)."""
+    rows are ``times`` x the expanded scores / sqrt(qk width), times the
+    rotary rule's factor on the softmax scale."""
     d = dims(c)
+    times = times * _rotary(c)[2]
     wk, _ = _wkvb(c, lp)
     q_lat = jnp.einsum("nhd,chd->nhc", q_nope, wk)
     pad = jnp.zeros(q_rope.shape[:2] + (d["stored"] - d["row"],),
                     q_rope.dtype)
     q = jnp.concatenate([q_lat, q_rope, pad], axis=-1)
-    return q * jnp.asarray(times / np.sqrt(d["nope"] + d["rope"]), q.dtype)
+    return _scaled(c, q, times / np.sqrt(d["nope"] + d["rope"]))
+
+
+def _scaled(c: ModelConfig, q, k: float):
+    """``q`` x ``k``. Under ``rope_scaling``, whose factor on the softmax
+    scale is part of ``k``, the product is taken in float32 and rounded
+    once (bfloat16 holds YaRN's 2.0047 as 2.0: scores 0.23 % low);
+    without it, the product the one-stream block has always lowered."""
+    if c.rope_scaling_dict is None:
+        return q * jnp.asarray(k, q.dtype)
+    return (q.astype(jnp.float32) * k).astype(q.dtype)
 
 
 def _unabsorb_o(c: ModelConfig, lp, o_lat):
@@ -290,35 +346,85 @@ def expert_ffn(c: ModelConfig, ep, x, valid=None):
     return y, load
 
 
+def stats_zero(c: ModelConfig):
+    """A step's counters before any layer: [experts touched, tokens
+    routed, most tokens on one expert], and with hyper-connections a
+    fourth, the last layer's Sinkhorn residual (hc.row_sum_residual) as
+    the bits of a float32 (not negative, so the bits order as it does)."""
+    return jnp.zeros(3 if c.hc is None else 4, jnp.int32)
+
+
 def merge_stats(a, b):
-    """Routing counters [experts touched, tokens routed, most tokens on
-    one expert] of two steps or layers as one: sums and a maximum."""
-    return jnp.stack([a[0] + b[0], a[1] + b[1], jnp.maximum(a[2], b[2])])
+    """The counters of two steps or layers as one: sums, and maxima from
+    the third on."""
+    return jnp.stack([a[0] + b[0], a[1] + b[1]] + [
+        jnp.maximum(a[i], b[i]) for i in range(2, a.shape[0])])
 
 
 def _ffn(c: ModelConfig, params, l: int, x, valid, stats):
     """Layer l's MLP (l is static): dense below first_k_dense_replace,
-    experts from there on. ``stats`` accumulates [experts touched, tokens
-    routed, most tokens on one expert]."""
+    experts from there on. ``stats`` accumulates the routing counters
+    (``stats_zero``)."""
     n_dense = dims(c)["n_dense"]
     if l < n_dense:
         dp = jax.tree.map(lambda a: a[l], params["dense"])
         return _mlp(x, dp["wg"], dp["wu"], dp["wd"]), stats
     ep = params["experts"][l - n_dense]
     y, load = expert_ffn(c, ep, x, valid)
-    return y, merge_stats(
-        stats, jnp.stack([jnp.sum(load > 0), load.sum(), load.max()]))
+    seen = [jnp.sum(load > 0), load.sum(), load.max()]
+    return y, merge_stats(stats, jnp.stack(
+        seen + [jnp.int32(0)] * (stats.shape[0] - len(seen))))
 
 
-def _layer_out(c: ModelConfig, params, lp, l, h, attn, valid, stats):
-    h = h + attn @ lp["wo"]
-    x2 = _rms(h, lp["ln2"], c.rms_norm_eps)
+def _embed(c: ModelConfig, params, tokens, dtype):
+    """[N] -> the residual state: [N, H], or n copies of it [N, n, H]."""
+    h = params["embed"][tokens].astype(dtype)
+    if c.hc is None:
+        return h
+    return jnp.broadcast_to(h[:, None], (h.shape[0], dims(c)["n"],
+                                         h.shape[1]))
+
+
+def _open(c: ModelConfig, lp, sub: int, h):
+    """Sublayer ``sub``'s (0 attention, 1 MLP) input from the residual
+    state, and the coefficients that will close it (None: one stream)."""
+    if c.hc is None:
+        return h, None
+    k = c.hc_dict
+    with jax.named_scope("hc_pre"):
+        mix = hc.mix_coefficients(
+            h, lp["hc_phi"][sub], lp["hc_a"][sub], lp["hc_b"][sub],
+            n=k["hc_mult"], iters=k["hc_sinkhorn_iters"], eps=k["hc_eps"],
+            clamp=(k["mhc_h_res_clamp_min"], k["mhc_h_res_clamp_max"]),
+            norm_eps=c.rms_norm_eps)
+        return hc.hc_pre(h, mix), mix
+
+
+def _close(h, f, mix):
+    """The residual state after a sublayer whose output is ``f``."""
+    if mix is None:
+        return h + f
+    with jax.named_scope("hc_post"):
+        return hc.hc_post(h, f, mix)
+
+
+def _layer_out(c: ModelConfig, params, lp, l, h, attn, mix, valid, stats):
+    h = _close(h, attn @ lp["wo"], mix)
+    u, mix = _open(c, lp, 1, h)
+    x2 = _rms(u, lp["ln2"], c.rms_norm_eps)
     y, stats = _ffn(c, params, l, x2, valid, stats)
-    return h + y, stats
+    if mix is not None and l == c.num_layers - 1:
+        stats = stats.at[3].set(jax.lax.bitcast_convert_type(
+            hc.row_sum_residual(mix), jnp.int32))
+    return _close(h, y, mix), stats
 
 
 def _logits(c: ModelConfig, params, h):
-    h = _rms(h, params["norm_f"], c.rms_norm_eps)
+    if c.hc is not None:   # the head closes the streams: their plain sum
+        h = _rms(h.astype(jnp.float32).sum(axis=1), params["norm_f"],
+                 c.rms_norm_eps).astype(h.dtype)
+    else:
+        h = _rms(h, params["norm_f"], c.rms_norm_eps)
     return jnp.matmul(h, params["lm_head"],
                       preferred_element_type=jnp.float32)
 
@@ -344,15 +450,17 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
     K, T = tokens.shape
     cdt = ctx_kv[ROW].dtype
     positions = q_starts[:, None] + jnp.arange(T, dtype=jnp.int32)
+    scale_times = _rotary(c)[2]
     valid = (positions < seq_lens[:, None]).reshape(K * T)
     pos = positions.reshape(K * T)
-    h = params["embed"][tokens.reshape(K * T)].astype(cdt)
-    stats = jnp.zeros(3, jnp.int32)
+    h = _embed(c, params, tokens.reshape(K * T), cdt)
+    stats = stats_zero(c)
     rows_out = []
     for l in range(c.num_layers):
         lp = jax.tree.map(lambda a: a[l], params["layers"])
+        u, mix = _open(c, lp, 0, h)
         with jax.named_scope("mla_attn"):
-            q_nope, q_rope, row = _attn_in(c, lp, h, pos)
+            q_nope, q_rope, row = _attn_in(c, lp, u, pos)
             rows_out.append(row)
             lanes = lambda a: a.reshape(K, T, *a.shape[1:])  # noqa: E731
             if ctx_span == 0:
@@ -369,6 +477,8 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
                 v = jnp.pad(v, ((0, 0), (0, 0),
                                 (0, k.shape[-1] - d["v"])))
                 q = jnp.concatenate([q_nope, q_rope], -1)
+                if scale_times != 1.0:
+                    q = _scaled(c, q, scale_times)
                 o = prefill_attention(lanes(q), lanes(k), lanes(v),
                                       q_starts, seq_lens)
                 attn = o[..., :d["v"]].reshape(K * T, -1)
@@ -386,7 +496,7 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
                 attn = _unabsorb_o(
                     c, lp, o[..., :d["kv_rank"]].reshape(
                         K * T, d["nh"], d["kv_rank"]))
-        h, stats = _layer_out(c, params, lp, l, h, attn, valid, stats)
+        h, stats = _layer_out(c, params, lp, l, h, attn, mix, valid, stats)
 
     rows = jnp.stack(rows_out).reshape(
         c.num_layers, K, T, d["stored"]).astype(cdt)
@@ -400,6 +510,8 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
     last = jnp.maximum(seq_lens - q_starts - 1, 0)
     h_last = jnp.take_along_axis(
         h.reshape(K, T, -1), last[:, None, None], axis=1)[:, 0]
+    if c.hc is not None:
+        h_last = h_last.reshape(K, *h.shape[1:])
     return out_ctx, _logits(c, params, h_last)
 
 
@@ -423,19 +535,21 @@ def prefill_impl(config, params, ctx_kv, tokens, slot, q_start, seq_len,
 def decode_step_impl(config, params, ctx_kv, ring, tokens, ctx_lens,
                      ring_base, ring_pos, live=None, adapter_ids=None):
     """One decode step for all slots: (ring, logits [B, vocab], stats
-    [3] i32). The new token's row lands in ring slot ``ring_pos``;
-    attention is absorbed, over the region's rows below ring_base and the
-    ring's above. The region is read-only here (llama.init_ring)."""
+    i32, ``stats_zero``'s layout). The new token's row lands in ring slot
+    ``ring_pos``; attention is absorbed, over the region's rows below
+    ring_base and the ring's above. The region is read-only here
+    (llama.init_ring)."""
     c, d = config, dims(config)
     _refuse_adapters(params)
     positions = jnp.maximum(ctx_lens - 1, 0)
-    h = params["embed"][tokens].astype(ctx_kv[ROW].dtype)
+    h = _embed(c, params, tokens, ctx_kv[ROW].dtype)
     buf = ring[ROW]
-    stats = jnp.zeros(3, jnp.int32)
+    stats = stats_zero(c)
     for l in range(c.num_layers):
         lp = jax.tree.map(lambda a: a[l], params["layers"])
+        u, mix = _open(c, lp, 0, h)
         with jax.named_scope("mla_attn"):
-            q_nope, q_rope, row = _attn_in(c, lp, h, positions)
+            q_nope, q_rope, row = _attn_in(c, lp, u, positions)
             buf = jax.lax.dynamic_update_slice(
                 buf, row.astype(buf.dtype)[None, None, :, None, :],
                 (l, 0, 0, ring_pos, 0))
@@ -443,5 +557,5 @@ def decode_step_impl(config, params, ctx_kv, ring, tokens, ctx_lens,
                 _absorb_q(c, lp, q_nope, q_rope), ctx_kv[ROW], buf,
                 jnp.int32(l), ctx_lens, ring_base, d["kv_rank"])
             attn = _unabsorb_o(c, lp, o_lat)
-        h, stats = _layer_out(c, params, lp, l, h, attn, live, stats)
+        h, stats = _layer_out(c, params, lp, l, h, attn, mix, live, stats)
     return {ROW: buf}, _logits(c, params, h), stats

@@ -189,12 +189,29 @@ def _build_moe(mesh: Mesh, axis: str, cfg: MoEConfig, n: int, Tl: int):
 # combine weights.
 
 GMM_ROWS = 128   # rows per tile of the TPU kernel (tools/moe_gmm_bench.py)
+# the most one weight tile may hold: the kernel double-buffers it inside
+# the 16 MiB of VMEM a Mosaic call gets by default, beside the row and
+# output tiles. 2048 x 768 (3 MiB) fits whole; 3584 x 1024 (7 MiB) does
+# not ("Ran out of memory in memory space vmem", compile-only, PR 37)
+GMM_TILE_BYTES = 4 * 2 ** 20
+
+
+def gmm_tile_n(k: int, n: int, itemsize: int) -> int:
+    """Output columns of a weight tile [k, tn]: the whole matrix where it
+    fits GMM_TILE_BYTES, else n halved (whole 128-column lanes) until it
+    does. The contraction is never split: a tile then needs no second
+    visit to finish its sums."""
+    tn = n
+    while k * tn * itemsize > GMM_TILE_BYTES and tn % 256 == 0:
+        tn //= 2
+    return tn
 
 
 def _gmm_tpu(x, w, group_sizes):
     """The grouped product on the TPU: the megablox Pallas kernel, each
-    tile one expert's WHOLE [in, out] matrix (full-K, full-N tiles): a
-    visit streams the matrix once at ~700 GB/s on a v5e, where
+    tile one expert's WHOLE [in, out] matrix where VMEM holds it (full-K,
+    full-N tiles; else full-K and half or a quarter of N, ``gmm_tile_n``):
+    a visit streams the matrix once at ~700 GB/s on a v5e, where
     ``ragged_dot``'s own lowering reads it at ~210 GB/s (PERF.md section
     6, PR 31). Empty experts are never visited."""
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
@@ -204,7 +221,7 @@ def _gmm_tpu(x, w, group_sizes):
     if tm is None:
         return jax.lax.ragged_dot(x, w, group_sizes)
     return gmm(x, w, group_sizes, preferred_element_type=x.dtype,
-               tiling=(tm, k, w.shape[2]))
+               tiling=(tm, k, gmm_tile_n(k, w.shape[2], w.dtype.itemsize)))
 
 
 def _grouped(x, w, group_sizes, expert_of_row):

@@ -1,7 +1,9 @@
 """Rotary position embeddings (HF llama "rotate-half" convention, incl.
-llama3 frequency scaling)."""
+llama3 frequency scaling), and YaRN as DeepSeek-V3's config.json
+parameterises it (the latent-attention block, models/mla_moe.py)."""
 from __future__ import annotations
 
+import math
 from typing import Any, Optional
 
 import jax.numpy as jnp
@@ -31,6 +33,35 @@ def rope_inv_freq(
         is_mid = (wavelen <= low_wavelen) & (wavelen >= high_wavelen)
         inv_freq = np.where(is_mid, mid, scaled)
     return inv_freq.astype(np.float32)
+
+
+def yarn_inv_freq(head_dim: int, theta: float,
+                  scaling: dict[str, Any]) -> np.ndarray:
+    """YaRN's inverse frequencies [head_dim/2]: dimensions that turn more
+    than ``beta_fast`` times over the original context keep their
+    frequency, those that turn fewer than ``beta_slow`` times are slowed
+    by ``factor``, and a linear ramp over the dimension index joins them
+    (DeepSeek-V3's ``DeepseekV3YarnRotaryEmbedding``)."""
+    factor = float(scaling["factor"])
+    orig = scaling["original_max_position_embeddings"]
+
+    def turns_at(n_rot: float) -> float:
+        """The (fractional) dimension index that turns n_rot times."""
+        return (head_dim * math.log(orig / (n_rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(turns_at(scaling["beta_fast"])), 0)
+    high = min(math.ceil(turns_at(scaling["beta_slow"])), head_dim - 1)
+    i = np.arange(head_dim // 2, dtype=np.float64)
+    extra = 1.0 / theta ** (2 * i / head_dim)
+    # equal bounds: the published code widens the ramp by 0.001
+    ramp = np.clip((i - low) / ((high - low) or 0.001), 0.0, 1.0)
+    return (extra / factor * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term ``0.1 mscale ln(factor) + 1``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
 def rope_cos_sin(positions: jnp.ndarray, inv_freq: jnp.ndarray):

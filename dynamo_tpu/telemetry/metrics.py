@@ -427,6 +427,24 @@ MOE_ROUTED = ("dynamo_moe_tokens_routed",
 MOE_LOAD_MAX = ("dynamo_moe_expert_load_max",
                 "most tokens one expert received in one step of one layer "
                 "of a consumed decode round")
+HC_SINKHORN_RESIDUAL = (
+    "dynamo_hc_sinkhorn_residual",
+    "hyper-connection models: max over a consumed decode round's tokens "
+    "of |rowsum(H_res) - 1| in the last layer, what the Sinkhorn "
+    "iterations left unconverged")
+PREFILL_CONTINUED = (
+    "dynamo_prefill_continued_tokens",
+    "prompt tokens a prefill dispatch computed in chunks that continue a "
+    "context already in the region (q_start > 0)")
+DECODE_ATTN_ROWS_READ = (
+    "dynamo_decode_attn_rows_read",
+    "latent-attention models: region rows a dispatched decode round's "
+    "attention read a layer: steps x lanes x the longest live context in "
+    "whole chunks")
+DECODE_ATTN_ROWS_LIVE = (
+    "dynamo_decode_attn_rows_live",
+    "latent-attention models: region rows of that round that were some "
+    "live lane's own context: steps x the sum of the lanes' lengths")
 KV_ROW_BYTES = ("dynamo_kv_row_bytes",
                 "bytes one token holds in the ctx region, all layers "
                 "(observed once, at engine start)")
@@ -450,8 +468,14 @@ def request_histograms(
             reg.histogram(name, help_)
         for name, help_ in (PREFILL_TOKENS, PREFILL_PADDED, PREFILL_MATCHED,
                             ROUND_LIVE_LANE_STEPS, ROUND_TOKENS,
-                            MOE_TOUCHED, MOE_ROUTED, MOE_LOAD_MAX):
+                            MOE_TOUCHED, MOE_ROUTED, MOE_LOAD_MAX,
+                            PREFILL_CONTINUED):
             reg.histogram(name, help_, TOKEN_BUCKETS)
+        reg.histogram(*HC_SINKHORN_RESIDUAL,
+                      tuple(10.0 ** i for i in range(-9, 1)))
+        for name, help_ in (DECODE_ATTN_ROWS_READ, DECODE_ATTN_ROWS_LIVE):
+            reg.histogram(name, help_,
+                          tuple(float(4 ** i) for i in range(3, 13)))
         reg.histogram(*KV_ROW_BYTES, tuple(float(4 ** i) for i in range(3, 12)))
         for name, help_ in (PREFILL_ATTN_LIVE, PREFILL_ATTN_SCORED):
             reg.histogram(name, help_, PAIR_BUCKETS)

@@ -16,11 +16,32 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NEW_CELL = "mla-moe-joyai-d5.chat-decode"
 
 
-def run(cmd, limit_s):
-    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
+def run(cmd, limit_s, cwd=REPO):
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=cwd)
     env.pop("XLA_FLAGS", None)   # the rehearsal sets its own device count
-    return subprocess.run(cmd, cwd=REPO, env=env, capture_output=True,
+    return subprocess.run(cmd, cwd=cwd, env=env, capture_output=True,
                           text=True, timeout=limit_s)
+
+
+def checkout_at_rate(tmp_path, cell, rate_rps):
+    """A copy of what the benchmark reads (``BENCHMARK.json``,
+    ``benchmarks/``; the program linked, not copied) whose cell offers
+    ``rate_rps``: what ``tools/knee_sweep.py`` does to the chip machine's
+    copy. The CPU computes a 4096-token continuing chunk of the TINY model
+    in two seconds, so at a rate sized for the chip every stream of the
+    rehearsal would outlast the drain grace."""
+    import shutil
+
+    root = str(tmp_path / "checkout")
+    shutil.copytree(os.path.join(REPO, "benchmarks"),
+                    os.path.join(root, "benchmarks"))
+    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), root)
+    os.symlink(os.path.join(REPO, "dynamo_tpu"),
+               os.path.join(root, "dynamo_tpu"))
+    with open(os.path.join(root, "benchmarks", "cells", cell + ".json"),
+              "w") as f:
+        json.dump({"rate_rps": rate_rps}, f)
+    return root
 
 
 @pytest.mark.parametrize("cmd,limit_s", [
@@ -34,11 +55,26 @@ def test_the_benchmarks_own_checks_pass(cmd, limit_s):
     assert r.returncode == 0, (r.stdout + r.stderr)[-3000:]
 
 
-def test_the_new_cell_rehearses_on_the_cpu():
-    """The cell's files end to end at the dry-run widths: configuration,
-    reference by name, traffic mix, warm-up, window, result line."""
-    r = run([sys.executable, "benchmarks/run.py", "--workload", NEW_CELL,
-             "--seed", "3100310031", "--seconds", "6", "--cpu-dry-run"], 420)
+@pytest.mark.parametrize("cell,seed,reference,rate_rps", [
+    (NEW_CELL, "3100310031", "benchmarks/references/mla_moe.py", None),
+    ("xing4-mhc-d7.longdoc", "3700370037",
+     "benchmarks/references/mla_moe_mhc.py", 0.34),
+    ("mistral7b-w8.longprompt", "3700370038", "benchmarks/reference.py",
+     None),
+], ids=["chat-decode", "longdoc", "longprompt"])
+def test_the_new_cell_rehearses_on_the_cpu(tmp_path, cell, seed, reference,
+                                           rate_rps):
+    """A cell's files end to end at the dry-run widths: configuration,
+    reference by name, traffic mix, warm-up, window, result line; at the
+    cell's own rate, or where the CPU cannot hold that (above) at one it
+    can. The long-document cell's check and traffic run a fresh
+    4096-token chunk, continuing chunks and decode over a 16384-token
+    region here too, and its warm-up set has to leave the window nothing
+    to compile."""
+    root = REPO if rate_rps is None else checkout_at_rate(
+        tmp_path, cell, rate_rps)
+    r = run([sys.executable, "benchmarks/run.py", "--workload", cell,
+             "--seed", seed, "--seconds", "6", "--cpu-dry-run"], 900, root)
     assert r.returncode == 0, (r.stdout + r.stderr)[-3000:]
     line = json.loads(r.stdout.strip().splitlines()[-1])
     assert line["dry_run"] is True and line["device"]["platform"] == "cpu"
@@ -46,4 +82,91 @@ def test_the_new_cell_rehearses_on_the_cpu():
     assert {"tpot_ms_p90", "setup_s"} <= set(line["metrics"])
     notes = next(json.loads(l[len("notes: "):])
                  for l in r.stdout.splitlines() if l.startswith("notes: "))
-    assert notes["check"]["reference"] == "benchmarks/references/mla_moe.py"
+    assert notes["check"]["reference"] == reference
+
+
+def _sweep_line(tok_s, offered, p50, p90, failed=0):
+    return {"failed": failed, "tok_s": tok_s, "offered_tok_s": offered,
+            "ttft_ms_p50_first_half": p50[0], "ttft_ms_p50_second_half": p50[1],
+            "ttft_ms_p90_first_half": p90[0], "ttft_ms_p90_second_half": p90[1]}
+
+
+@pytest.mark.parametrize("line,bounded", [
+    # lines of sweeps on the chip (PERF.md section 4, PR 37), rounded
+    (_sweep_line(151.7, 152.0, (690, 516), (1246, 1202)), True),
+    # level TTFT, completion 0.90: the window's last requests end after it
+    (_sweep_line(153.4, 170.2, (647, 676), (1351, 1527)), True),
+    (_sweep_line(168.0, 189.9, (592, 1118), (1026, 3382)), False),
+    # a first half slower than the second is one trajectory, not a queue
+    (_sweep_line(107.6, 110.9, (1724, 826), (2513, 1448)), True),
+    # the median grows 1.67x while p90 grows 1.31x: a queue
+    (_sweep_line(120.4, 138.7, (3937, 6568), (5662, 7393)), False),
+    (_sweep_line(151.7, 152.0, (690, 516), (1246, 1202), failed=1), False),
+    ({"failed": 0, "tok_s": 1.0, "offered_tok_s": 1.0}, False),
+], ids=["keeps-up", "level-at-0.90", "tail-grows", "first-half-slower",
+        "median-grows", "a-failure", "an-empty-half"])
+def test_the_knee_rule_reads_the_recorded_sweeps(line, bounded):
+    """ONE rule says whether a rate's backlog stayed bounded, for every
+    open-loop cell (``tools/knee_sweep.py: bounded``)."""
+    sys.path.insert(0, os.path.join(REPO, "tools"))
+    try:
+        import knee_sweep
+    finally:
+        sys.path.pop(0)
+    assert knee_sweep.bounded(line) is bounded
+
+
+def test_the_cause_tool_holds_picks_and_coefficients_at_tiny_widths():
+    """``tools/mla_moe_mhc_cause.py`` (the emulation behind the check's
+    limits: a rounded pass of the reference against its float32 pass)
+    prints its four passes; with the picks held no pick differs and the
+    distance is no larger, with the coefficients held none is apart."""
+    r = run([sys.executable, "tools/mla_moe_mhc_cause.py", "--seed", "1",
+             "--dry-run"], 600)
+    assert r.returncode == 0, r.stderr[-2000:]
+    rows = [json.loads(line) for line in r.stdout.splitlines()
+            if line.startswith("{")]
+    assert [tuple(row["held_to_float32"]) for row in rows] == [
+        (), ("picks",), ("mix",), ("picks", "mix")]
+    free, picks, mix, both = rows
+    assert free["mean_abs_logprob_diff"] > 0
+    assert picks["mean_abs_logprob_diff"] <= free["mean_abs_logprob_diff"]
+    for row in (picks, both):
+        assert not any(row["picks_differ_share_by_expert_layer"])
+    for row in (mix, both):
+        assert not any(row["h_res_apart_max_by_sublayer"])
+
+
+def _stated_limits(path):
+    """``CHECK_TOL_MAX`` / ``CHECK_TOL_MEAN`` as a reference's file states
+    them, read from its text (importing it would take JAX)."""
+    import ast
+
+    with open(os.path.join(REPO, path)) as f:
+        stated = {t.id: ast.literal_eval(node.value)
+                  for node in ast.parse(f.read()).body
+                  if isinstance(node, ast.Assign)
+                  for t in node.targets if isinstance(t, ast.Name)
+                  and t.id.startswith("CHECK_TOL_")}
+    return stated["CHECK_TOL_MAX"], stated["CHECK_TOL_MEAN"]
+
+
+@pytest.mark.parametrize("reading,worst,mean,passes", [
+    # readings of the check on the chip (PERF.md section 6, PR 37): the
+    # sound program's extremes over its seeds, then each control's
+    # reading nearest the limits
+    ("sound-largest-max", 5.2026, 0.3124, True),    # seed 1235265473
+    ("sound-largest-mean", 3.9465, 0.3907, True),
+    ("mix_bf16-largest", 3.9671, 0.3995, True),     # reported as passing
+    ("hc_iters_1-smallest", 4.5400, 0.5736, False),
+    ("hc_static-smallest", 5.2576, 1.7143, False),
+    ("yarn_off-smallest", 7.6777, 3.6346, False),
+    ("fp8-smallest", 4.9620, 1.5830, False),
+], ids=lambda v: v if isinstance(v, str) else None)
+def test_the_four_stream_checks_limits_stand_between_its_readings(
+        reading, worst, mean, passes):
+    """Whoever moves a limit of ``references/mla_moe_mhc.py`` is held to
+    the record: every sound reading passes, every control that leaves out
+    part of the mathematics, and the lower precision, fails."""
+    tol_max, tol_mean = _stated_limits("benchmarks/references/mla_moe_mhc.py")
+    assert (worst <= tol_max and mean <= tol_mean) is passes, reading

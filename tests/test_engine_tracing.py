@@ -41,6 +41,10 @@ ASCORED = "dynamo_engine_prefill_attn_scored_pairs"
 TOUCHED = "dynamo_moe_experts_touched"
 ROUTED = "dynamo_moe_tokens_routed"
 LOADMAX = "dynamo_moe_expert_load_max"
+HCRES = "dynamo_hc_sinkhorn_residual"
+CONT = "dynamo_prefill_continued_tokens"
+ROWS_READ = "dynamo_decode_attn_rows_read"
+ROWS_LIVE = "dynamo_decode_attn_rows_live"
 
 
 def _engine(**kw) -> TpuEngine:
@@ -421,14 +425,17 @@ def _sources():
         FRONT: (1.0, 10), FIRST: (5.0, 10), PF: (1000.0, 4),
         PAD: (2000.0, 4), MATCH: (0.0, 4), LIVE: (400.0, 20),
         RTOK: (300.0, 20), ALIVE: (1e6, 4), ASCORED: (4e6, 4),
-        TOUCHED: (1000.0, 20), ROUTED: (3000.0, 20), LOADMAX: (100.0, 20)},
+        TOUCHED: (1000.0, 20), ROUTED: (3000.0, 20), LOADMAX: (100.0, 20),
+        HCRES: (2e-6, 20), CONT: (500.0, 4), ROWS_READ: (1e6, 20),
+        ROWS_LIVE: (4e5, 20)},
         1.0)
     after = snap(150.0, {
         FRONT: (1.5, 60), FIRST: (30.0, 60), PF: (17000.0, 54),
         PAD: (26000.0, 54), MATCH: (4000.0, 54), LIVE: (3600.0, 120),
         RTOK: (2700.0, 120), ALIVE: (7e6, 54), ASCORED: (19e6, 54),
         TOUCHED: (205800.0, 120), ROUTED: (617400.0, 120),
-        LOADMAX: (700.0, 120)}, 3.5)
+        LOADMAX: (700.0, 120), HCRES: (3.2e-5, 120), CONT: (6900.0, 54),
+        ROWS_READ: (9e6, 120), ROWS_LIVE: (2.4e6, 120)}, 3.5)
     return {"before": before, "after": after,
             "engine_up": {"flush_every": 4},
             "config": {"engine": {"max_decode_slots": 8},
@@ -449,6 +456,11 @@ READERS = {
     "moe.experts_touched_share": (204800 / 409600 * 100, [TOUCHED]),
     # mean of the rounds' maxima 6 over 614400 / 204800 = 3 a touched expert
     "moe.load_max_over_mean": (6.0 / 3.0, [LOADMAX]),
+    # PR 37: the mean of 100 rounds' maxima; 6400 of the window's 16000
+    # prompt positions in continuing chunks; 2e6 of 8e6 rows read
+    "hc.sinkhorn_residual_max": (3e-5 / 100, [HCRES]),
+    "step.prefill_continued_share": (6400 / 16000 * 100, [CONT]),
+    "step.decode_attn_live_share": (2e6 / 8e6 * 100, [ROWS_READ]),
 }
 
 
@@ -469,6 +481,86 @@ def test_reader_gives_none_on_a_program_without_the_counter(name):
             snap["histograms"].pop(key, None)
             snap["prof"].pop(key, None)
     assert _reader(name)(src) is None
+
+
+@pytest.fixture(scope="module")
+def served_streams():
+    """The four-stream latent block (tiny, float32) serving two prompts:
+    70 tokens in buckets of 32 (one fresh chunk, two continuing), and 20."""
+    from dynamo_tpu.models import llama
+
+    cfg = ModelConfig.tiny_mla_moe_mhc()
+    params = llama.init_params(cfg, 11)
+
+    async def scenario():
+        eng = TpuEngine(cfg, EngineConfig(
+            num_pages=64, page_size=PS, max_pages_per_seq=8,
+            max_decode_slots=4, prefill_buckets=(32,),
+            cache_dtype="float32"), params=params,
+            mesh_config=MeshConfig(tp=1))
+        eng.start()
+        h0 = _hists(eng)
+        await asyncio.gather(
+            _one(eng, [7 + i for i in range(70)], osl=9),
+            _one(eng, [300 + i for i in range(20)], osl=9))
+        h1 = await _settled(eng)
+        await eng.stop()
+        return h0, h1, eng.ecfg
+    return asyncio.run(scenario())
+
+
+@pytest.mark.parametrize("case", [
+    "continued_tokens_are_the_later_chunks",
+    "one_residual_a_consumed_round_and_converged",
+    "rows_read_cover_rows_live_in_whole_chunks",
+])
+def test_counters_of_the_four_stream_block(served_streams, case):
+    h0, h1, e = served_streams
+    if case == "continued_tokens_are_the_later_chunks":
+        # 70 = 32 fresh + 32 + 6 continuing; the 20-token prompt is fresh
+        assert _delta(h0, h1, CONT) == 38
+        assert _delta(h0, h1, CONT, "count") == _delta(h0, h1, PF, "count")
+        assert _delta(h0, h1, PF) == 90
+    elif case == "one_residual_a_consumed_round_and_converged":
+        rounds = _delta(h0, h1, RTOK, "count")
+        assert rounds > 0 and _delta(h0, h1, HCRES, "count") == rounds
+        # b_res of order 1: 20 iterations leave the slowest token ~1e-3
+        assert 0.0 <= _delta(h0, h1, HCRES) / rounds < 2e-2
+    else:
+        read, live = _delta(h0, h1, ROWS_READ), _delta(h0, h1, ROWS_LIVE)
+        rounds = _delta(h0, h1, ROWS_READ, "count")
+        assert rounds == _delta(h0, h1, LIVE, "count")
+        assert 0 < live <= read
+        # steps x lanes x whole chunks (the region is shorter than one)
+        chunk = min(256, e.max_context)
+        assert read % (e.flush_every * e.max_decode_slots * chunk) == 0
+
+
+def test_hc_scopes_are_in_the_lowered_step():
+    """``hc_pre`` / ``hc_post`` name the two sides of each sublayer in
+    the program's debug locations, beside ``mla_attn`` and ``moe_*``:
+    what a device trace is reduced by."""
+    import jax
+    import jax.numpy as jnp
+    from dynamo_tpu.models import llama, mla_moe
+
+    cfg = ModelConfig.tiny_mla_moe_mhc()
+    params = jax.eval_shape(lambda: llama.init_params(cfg, 0))
+    ctx = jax.eval_shape(lambda: llama.init_ctx(cfg, 4, 64, jnp.float32))
+    ring = jax.eval_shape(lambda: llama.init_ring(cfg, 4, 4, jnp.float32))
+    i32 = jax.ShapeDtypeStruct((4,), jnp.int32)
+    text = jax.jit(mla_moe.decode_step_impl, static_argnums=(0,)).lower(
+        cfg, params, ctx, ring, i32, i32, i32,
+        jax.ShapeDtypeStruct((), jnp.int32)).as_text(debug_info=True)
+    for scope in ("hc_pre", "hc_post", "mla_attn", "moe_route",
+                  "moe_experts", "moe_shared"):
+        assert f"/{scope}/" in text or f"{scope}/" in text, scope
+    plain = jax.jit(mla_moe.decode_step_impl, static_argnums=(0,)).lower(
+        ModelConfig.tiny_mla_moe(), jax.eval_shape(
+            lambda: llama.init_params(ModelConfig.tiny_mla_moe(), 0)),
+        ctx, ring, i32, i32, i32,
+        jax.ShapeDtypeStruct((), jnp.int32)).as_text(debug_info=True)
+    assert "hc_pre" not in plain and "hc_post" not in plain
 
 
 def test_generator_readers_of_the_chat_decode_mix_by_hand():
@@ -501,11 +593,12 @@ def test_benchmark_json_names_every_new_reader():
     cells = [w["name"] for w in bench["workloads"]]
     # every reader this file checks by hand, and every entry of the
     # newest cell (its TTFT readers included)
-    new_cell = "mla-moe-joyai-d5.chat-decode"
-    assert new_cell in cells
+    new_cells = {"mla-moe-joyai-d5.chat-decode", "xing4-mhc-d7.longdoc",
+                 "mistral7b-w8.longprompt"}
+    assert new_cells <= set(cells)
     for name in sorted(set(READERS) | {
             n for n, m in per_layer.items()
-            if new_cell in m.get("workloads", ())}):
+            if new_cells & set(m.get("workloads", ()))}):
         entry = per_layer[name]
         assert os.path.exists(os.path.join(
             REPO, "benchmarks", "layer_metrics", name + ".py"))

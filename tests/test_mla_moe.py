@@ -23,7 +23,7 @@ import pytest
 from dynamo_tpu.engine.config import EngineConfig
 from dynamo_tpu.engine.engine import TpuEngine
 from dynamo_tpu.models import llama, mla_moe
-from dynamo_tpu.models.config import _TINY_MLA_MOE, ModelConfig
+from dynamo_tpu.models.config import _TINY_MHC, _TINY_MLA_MOE, ModelConfig
 from dynamo_tpu.models.moe import grouped_experts
 from dynamo_tpu.ops.attention import REFERENCE
 from dynamo_tpu.ops.latent_decode import latent_decode_attention
@@ -38,9 +38,9 @@ TOL = dict(rtol=2e-4, atol=2e-4)
 PS = 16
 
 
-def load_reference():
-    path = os.path.join(REPO, "benchmarks", "references", "mla_moe.py")
-    spec = importlib.util.spec_from_file_location("ref_mla_moe", path)
+def load_reference(name="mla_moe"):
+    path = os.path.join(REPO, "benchmarks", "references", name + ".py")
+    spec = importlib.util.spec_from_file_location("ref_" + name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
@@ -53,16 +53,32 @@ def setup():
     return cfg, params, load_reference()
 
 
+@pytest.fixture(scope="module", params=["one-stream", "mhc"])
+def block(request, setup):
+    """The two blocks models/mla_moe.py serves, each with its reference
+    and the config.json keys the reference reads: the plain residual, and
+    four mixed streams with YaRN (factor 4 over 64 positions: ``long``
+    is a prompt length that crosses it)."""
+    if request.param == "one-stream":
+        cfg, params, ref = setup
+        return cfg, params, ref, dict(_TINY_MLA_MOE), 21
+    cfg = ModelConfig.tiny_mla_moe_mhc()
+    hf = dict(_TINY_MLA_MOE, **_TINY_MHC)
+    return cfg, llama.init_params(cfg, 3), load_reference("mla_moe_mhc"), \
+        hf, 75
+
+
 def padded(prompt, width):
     toks = np.zeros(width, np.int32)
     toks[: len(prompt)] = prompt
     return jnp.asarray(toks)
 
 
-def ref_logits(ref, params, seq, positions):
+def ref_logits(ref, params, seq, positions, hf=None):
     """The reference's log-probs; the program's logits are compared after
     the same log-softmax."""
-    return ref.logprobs(dict(_TINY_MLA_MOE), params, list(seq), positions)
+    return ref.logprobs(hf or dict(_TINY_MLA_MOE), params, list(seq),
+                        positions)
 
 
 def log_softmax(x):
@@ -92,19 +108,19 @@ def decode_steps(cfg, params, ctx, first_logits, seq_len, n):
     return toks, np.stack(rows), ctx
 
 
-def test_prefill_then_decode_through_the_latent_cache(setup):
+def test_prefill_then_decode_through_the_latent_cache(block):
     """(a) fresh (expanded) prefill, then 9 decode steps (absorbed, over
     region + ring) against the reference's full forward."""
-    cfg, params, ref = setup
-    prompt = np.random.RandomState(0).randint(1, 256, 21).tolist()
+    cfg, params, ref, hf, long = block
+    prompt = np.random.RandomState(0).randint(1, 256, long).tolist()
     ctx = llama.init_ctx(cfg, 1, 128, jnp.float32)
     ctx, logits = llama.prefill(
-        cfg, params, ctx, padded(prompt, 32), jnp.int32(0), jnp.int32(0),
-        jnp.int32(len(prompt)), fresh=True)
+        cfg, params, ctx, padded(prompt, -(-long // 32) * 32), jnp.int32(0),
+        jnp.int32(0), jnp.int32(len(prompt)), fresh=True)
     toks, rows, _ = decode_steps(cfg, params, ctx, logits, len(prompt), 9)
     seq = prompt + toks[:-1]
     want = ref_logits(ref, params, seq,
-                      [len(prompt) - 1 + i for i in range(10)])
+                      [len(prompt) - 1 + i for i in range(10)], hf)
     np.testing.assert_allclose(log_softmax(rows), want, **TOL)
 
 
@@ -198,30 +214,51 @@ def test_expert_layer_equals_a_dense_loop_over_experts(setup):
     assert selected_by_bias > 0                    # and the bias mattered
 
 
-def test_chunked_prefill_and_reloaded_prefix_give_one_prefill(setup):
-    """(d) two chunks (the second absorbed, over the region), and a
-    prefix sealed to the pool then loaded into another lane, give the
-    logits of one prefill: the pool and its movers carry the latent row.
-    The sealed rows themselves move bit-exactly."""
-    cfg, params, ref = setup
-    prompt = np.random.RandomState(4).randint(1, 256, 44).tolist()
+@pytest.mark.parametrize("k,n,want", [
+    (2048, 768, 768),     # cell 3's gate/up: whole, as before PR 37
+    (768, 2048, 2048),    # and its down product
+    (3584, 1024, 512),    # 7 MiB whole: half the output columns
+    (1024, 3584, 1792),   # the down product: 14 lanes of 128
+    (8192, 384, 384),     # 6 MiB but 384 has no half of whole lanes
+], ids=["joyai-up", "joyai-down", "xing-up", "xing-down", "odd"])
+def test_grouped_product_tile_follows_the_matrix(k, n, want):
+    """The megablox kernel double-buffers one weight tile in 16 MiB of
+    VMEM: whole matrices up to 4 MiB, else the output columns halved in
+    whole 128-value lanes, the contraction never split."""
+    from dynamo_tpu.models.moe import GMM_TILE_BYTES, gmm_tile_n
+
+    tn = gmm_tile_n(k, n, 2)
+    assert tn == want and n % tn == 0 and (tn == n or tn % 128 == 0)
+    assert k * tn * 2 <= GMM_TILE_BYTES or tn % 256
+
+
+def test_chunked_prefill_and_reloaded_prefix_give_one_prefill(block):
+    """(d) one FRESH chunk and one CONTINUING chunk (absorbed, over the
+    region) against the reference's full forward, and a prefix sealed to
+    the pool then loaded into another lane, give the logits of one
+    prefill: the pool and its movers carry the latent row. The sealed
+    rows themselves move bit-exactly."""
+    cfg, params, ref, hf, long = block
+    n, first = long + 23, 32 if long < 32 else 64   # 44 or 98 tokens
+    prompt = np.random.RandomState(4).randint(1, 256, n).tolist()
     S = 128
     ctx = llama.init_ctx(cfg, 2, S, jnp.float32)
     ctx, one = llama.prefill(
-        cfg, params, ctx, padded(prompt, 64), jnp.int32(0), jnp.int32(0),
+        cfg, params, ctx, padded(prompt, S), jnp.int32(0), jnp.int32(0),
         jnp.int32(len(prompt)), fresh=True)
-    np.testing.assert_allclose(
-        log_softmax(one), ref_logits(ref, params, prompt, [43])[0], **TOL)
+    want = ref_logits(ref, params, prompt, [n - 1], hf)[0]
+    np.testing.assert_allclose(log_softmax(one), want, **TOL)
 
-    # two chunks through the batched program: 32 fresh, then 12 over them
+    # two chunks through the batched program: fresh, then the rest over it
     ctx2 = llama.init_ctx(cfg, 2, S, jnp.float32)
     i32 = lambda *v: jnp.asarray(v, jnp.int32)  # noqa: E731
     ctx2, _ = llama.batch_prefill(
-        cfg, params, ctx2, padded(prompt[:32], 32)[None], i32(1), i32(0),
-        i32(32), 0)
+        cfg, params, ctx2, padded(prompt[:first], first)[None], i32(1),
+        i32(0), i32(first), 0)
     ctx2, two = llama.batch_prefill(
-        cfg, params, ctx2, padded(prompt[32:], 32)[None], i32(1), i32(32),
-        i32(44), S)
+        cfg, params, ctx2, padded(prompt[first:], first)[None], i32(1),
+        i32(first), i32(n), S)
+    np.testing.assert_allclose(log_softmax(two[0]), want, **TOL)
     np.testing.assert_allclose(np.asarray(two[0]), np.asarray(one), **TOL)
 
     # seal lane 0's first two blocks, load them into lane 1 of a fresh
@@ -238,8 +275,8 @@ def test_chunked_prefill_and_reloaded_prefix_give_one_prefill(setup):
         np.asarray(ctx3[row][:, 0, 1, : 2 * PS]),
         np.asarray(ctx[row][:, 0, 0, : 2 * PS]))
     ctx3, three = llama.prefill(
-        cfg, params, ctx3, padded(prompt[32:], 32), jnp.int32(1),
-        jnp.int32(32), jnp.int32(44))
+        cfg, params, ctx3, padded(prompt[32:], S - 32), jnp.int32(1),
+        jnp.int32(32), jnp.int32(n))
     np.testing.assert_allclose(np.asarray(three), np.asarray(one), **TOL)
 
 

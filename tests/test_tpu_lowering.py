@@ -214,6 +214,55 @@ def test_kv_movers_copy_no_region_on_v5e(mover_records, program):
     assert rec["temp_bytes"] < 0.05 * rec["region_bytes"], rec
 
 
+@pytest.fixture(scope="module")
+def latent_records():
+    """The movers and the absorbed decode attention at the long-context
+    latent cell's region, ``[7, 1, 17, 16384, 640]`` (7 layers, one row
+    kind, 16 + 1 lanes of 16384 tokens, rows stored at 640), compiled by
+    XLA:TPU for a compile-only v5e device."""
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name=tpu_compile_check.TOPOLOGY)
+    except Exception as exc:  # noqa: BLE001 — no libtpu, or it is held
+        pytest.skip(f"no compile-only v5e topology here: {exc!r:.200}")
+    records = dict(zip(MOVERS, tpu_compile_check.compile_programs(
+        config="xing4-mhc-d7", programs=MOVERS)))
+    from dynamo_tpu.ops.latent_decode import latent_decode_attention
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    region = (7, 1, 17, 16384, 640)
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(latent_decode_attention, static_argnums=(6,)).lower(
+            arg((16, 32, 640)), arg(region), arg((7, 1, 16, 4, 640)),
+            arg((), jnp.int32), arg((16,), jnp.int32), arg((16,), jnp.int32),
+            512).compile()
+    found = tpu_compile_check.region_copies(compiled.as_text(), region)
+    records["latent_decode"] = {
+        "ok": True, "region_shard": list(region),
+        "region_copies": {"count": len(found), "shapes": sorted(set(found))},
+        "temp_bytes": compiled.memory_analysis().temp_size_in_bytes,
+        "region_bytes": 2 * 7 * 17 * 16384 * 640}
+    return records
+
+
+@pytest.mark.parametrize("program", MOVERS + ("latent_decode",))
+def test_latent_movers_and_decode_copy_no_region_on_v5e(latent_records,
+                                                        program):
+    """A new region shape is a new chance for XLA:TPU to relayout it (a
+    576-wide row did, PR 31): ring -> region, region -> pool, both in one
+    jit, and the decode attention's chunk reads leave the 2.5 GB region
+    where it is."""
+    rec = latent_records[program]
+    assert rec["ok"], rec
+    assert rec["region_shard"] == [7, 1, 17, 16384, 640]
+    assert rec["region_copies"] == {"count": 0, "shapes": []}, rec
+    assert rec["temp_bytes"] < 0.05 * rec["region_bytes"], rec
+
+
 def test_region_copies_reads_copy_and_copy_start():
     shard = (2, 2, 17, 4096, 128)
     text = """

@@ -9,7 +9,8 @@ once, and reads the request log it leaves. One JSON line per rate with
 TTFT percentiles of the window's first and second half (a backlog that
 grows through the window shows as a second half far above the first),
 the tokens completed per second against the tokens the schedule asked
-for, and the end-to-end metrics. No JAX here.
+for, the end-to-end metrics, and ``bounded``: the one rule by which a
+cell's knee is read (``bounded`` below; PERF.md section 4). No JAX here.
 
   chiprun --timeout 3000 -- python3 tools/knee_sweep.py \
       --workload mla-moe-joyai-d5.chat-decode --rates 4 6 8 10
@@ -26,6 +27,30 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "benchmarks"))
 
 import stats  # noqa: E402
+
+
+LEVEL = 1.5      # second-half TTFT over first-half TTFT, p50 and p90
+KEEPS_UP = 0.88  # tokens completed in the window over tokens it asked for
+
+
+def bounded(rec: dict) -> bool:
+    """Whether the backlog stayed bounded through the window: no request
+    failed, the TTFT of the requests due in the second half stands within
+    ``LEVEL`` x the first half's at the median AND at p90 (a rate 5 % over
+    capacity adds 1.25 s of queue by the second half, 2x and more of any
+    cell's TTFT; halves of one trajectory differ by up to 0.6-1.4x), and
+    the window completed ``KEEPS_UP`` of the tokens its requests asked
+    for (0.90-0.97 with level TTFT, because the requests due in its last
+    seconds finish after it: completion alone reads a knee one step low).
+    A cell's knee is the highest swept rate that reads bounded, twice."""
+    try:
+        level = all(rec[f"ttft_ms_{p}_second_half"]
+                    <= LEVEL * rec[f"ttft_ms_{p}_first_half"]
+                    for p in ("p50", "p90"))
+        return (rec["failed"] == 0 and level
+                and rec["tok_s"] >= KEEPS_UP * rec["offered_tok_s"])
+    except KeyError:   # a half with no finished request
+        return False
 
 
 def main() -> int:
@@ -82,6 +107,7 @@ def main() -> int:
                                    if q["chunks"]), default=None),
                 setup_s=line["metrics"]["setup_s"]["value"],
                 memory_peak_bytes=line["device"]["memory_peak_bytes"])
+            rec["bounded"] = bounded(rec)
             print(json.dumps(rec), flush=True)
     finally:
         with open(cell_file, "w") as f:

@@ -29,9 +29,10 @@ Mosaic call; not for the latent block, whose step is in the round), the
 ring->ctx flush, the standalone ctx->pool seal at ``--seal-width``
 entries, both in one jit, the fused round as the engine builds it
 (``flush_every`` decode steps, flush, seal), the pool -> region load and
-the pool's page gather / scatter at a long prompt's pages, and the
-smallest batched prefill bucket (fresh) at the lanes the engine gives a
-group of two.
+the pool's page gather / scatter at a long prompt's pages, and a batched
+prefill at the lanes the engine gives a group of two: fresh
+(``batch_prefill``) and continuing contexts in the region
+(``batch_prefill_cont``), at the smallest bucket.
 Prints one JSON line per program with XLA's memory analysis and the
 region-shaped copies in the compiled text; exits 1 if any program fails
 to compile. libtpu warns about ``TPU_ACCELERATOR_TYPE`` / worker hostnames
@@ -221,6 +222,9 @@ def compile_programs(model_config: str = "llama3_1b", tp: int = 1,
         "batch_prefill": lambda: llama.batch_prefill.trace(
             c, params, ctx, i32(K, T), i32(K), i32(K), i32(K), 0, i32(K),
         ),
+        "batch_prefill_cont": lambda: llama.batch_prefill.trace(
+            c, params, ctx, i32(K, T), i32(K), i32(K), i32(K), S, i32(K),
+        ),
     }
     if c.mla is not None:  # its step is in the round; no page transfer
         for name in ("decode_step", "gather_pages", "scatter_pages"):
@@ -233,7 +237,8 @@ def compile_programs(model_config: str = "llama3_1b", tp: int = 1,
               **{n: f"{n}_n{n_pages}" for n in
                  ("load_ctx_pages", "gather_pages", "scatter_pages")},
               "round_seal": f"round_seal_n{R}_w{W}",
-              "batch_prefill": f"batch_prefill_K{K}_T{T}"}
+              "batch_prefill": f"batch_prefill_K{K}_T{T}",
+              "batch_prefill_cont": f"batch_prefill_cont_K{K}_T{T}_S{S}"}
     out = []
     for name, trace in table.items():
         if programs and name not in programs:
@@ -291,7 +296,7 @@ def main(argv: list[str] | None = None) -> int:
                     help="comma-separated subset: decode_step, flush_ctx, "
                          "seal_blocks, flush_seal, round_seal, "
                          "load_ctx_pages, gather_pages, scatter_pages, "
-                         "batch_prefill")
+                         "batch_prefill, batch_prefill_cont")
     ap.add_argument("--seal-width", type=int, default=0,
                     help="entries of the standalone seal (0 = the fused "
                          "round's width)")
