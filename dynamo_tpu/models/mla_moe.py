@@ -11,13 +11,23 @@ What differs from models/llama.py, and how the engine meets it:
     movers (``llama.flush_ctx``, ``seal_blocks``, ``load_ctx_pages``)
     carry whatever row kinds a region holds (``llama.row_kinds``); the
     number of heads is in the array's shape.
-  - TWO ATTENTION FORMS, one result. A fresh prefill expands K and V from
-    the latent (``c_kv W_kvb``) and attends at width 192/128 through the
-    shared blocked prefill attention. Decode, and a prefill chunk that
-    continues a context already in the region, ABSORB ``W_kvb``: the query
-    is taken into the latent space (``q_nope W_kvb^K``), scores and the
-    weighted sum run over the cached rows themselves, and ``W_kvb^V`` is
-    applied to the result. That is algebra, not an approximation.
+  - TWO ATTENTION FORMS, one result. PREFILL, fresh or continuing a
+    context already in the region, expands K and V from the latent
+    (``c_kv W_kvb``) and attends at the heads' own width (nope + rope =
+    192 for scores, v = 128 for values) through the shared blocked prefill
+    attention: the chunk's own rows as they are computed, the prior rows
+    of the region ONCE a (layer, lane, row) a dispatch into a workspace
+    the attention's region loop reads (``_expand_prior``). DECODE absorbs
+    ``W_kvb``: the query is taken into the latent space (``q_nope
+    W_kvb^K``), scores and the weighted sum run over the cached rows
+    themselves at their stored width, and ``W_kvb^V`` is applied to the
+    result. That is algebra, not an approximation; which side pays is
+    arithmetic. Expanding one cached row costs kv_rank x nh x (nope + v)
+    x 2 = 8.4 MFLOP a layer (512, 32, 128 + 128); scoring it absorbed
+    costs nh x (stored - 192) x 2 x 2 = 57 kFLOP more a QUERY row (640
+    against 192 columns, scores and weighted sum): expansion pays from
+    ~146 query rows a prior row up. A decode step has one query row a
+    lane, a prefill chunk a bucket's worth (128 at the least).
   - TWO LAYER KINDS. Attention weights are stacked over all layers; the
     dense MLPs (``params["dense"]``) are a stack of their own and the
     expert layers (``params["experts"]``) a list, one entry a layer.
@@ -42,6 +52,7 @@ the benchmark's launcher call (``llama.init_params``, ``init_ctx``,
 """
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
@@ -52,7 +63,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models.moe import grouped_experts
 from dynamo_tpu.ops import hyper_connection as hc
-from dynamo_tpu.ops.attention import PriorContext, prefill_attention
+from dynamo_tpu.ops.attention import (
+    PREFILL_BLOCK,
+    PriorContext,
+    prefill_attention,
+)
 from dynamo_tpu.ops.latent_decode import latent_decode_attention
 from dynamo_tpu.ops.rope import rope_inv_freq, yarn_inv_freq, yarn_mscale
 
@@ -286,12 +301,12 @@ def _wkvb(c: ModelConfig, lp):
     return w[..., :d["nope"]], w[..., d["nope"]:]
 
 
-def _absorb_q(c: ModelConfig, lp, q_nope, q_rope, times: float = 1.0):
-    """The query in the latent space, scaled: its scores against cached
-    rows are ``times`` x the expanded scores / sqrt(qk width), times the
+def _absorb_q(c: ModelConfig, lp, q_nope, q_rope):
+    """The decode query in the latent space, scaled: its scores against
+    cached rows are the expanded scores / sqrt(qk width), times the
     rotary rule's factor on the softmax scale."""
     d = dims(c)
-    times = times * _rotary(c)[2]
+    times = _rotary(c)[2]
     wk, _ = _wkvb(c, lp)
     q_lat = jnp.einsum("nhd,chd->nhc", q_nope, wk)
     pad = jnp.zeros(q_rope.shape[:2] + (d["stored"] - d["row"],),
@@ -437,14 +452,81 @@ def _refuse_adapters(params):
 # ---------------------------------------------------------------------------
 # Prefill
 
+def _expand_kv(c: ModelConfig, lp, row):
+    """K and V per head from cached rows [N, stored]: K [N, nh, nope +
+    rope] = [c_kv W_kvb^K | k_rope, the same for every head] and V [N,
+    nh, v] = c_kv W_kvb^V, in the rows' dtype."""
+    d = dims(c)
+    wk, wv = _wkvb(c, lp)
+    c_kv = row[:, :d["kv_rank"]]
+    k_rope = row[:, d["kv_rank"]:d["row"]]
+    k = jnp.concatenate([
+        jnp.einsum("nc,chd->nhd", c_kv, wk),
+        jnp.broadcast_to(k_rope[:, None],
+                         (row.shape[0], d["nh"], d["rope"]))], -1)
+    return k, jnp.einsum("nc,chd->nhd", c_kv, wv)
+
+
+@functools.partial(jax.jit, static_argnames=("c",))
+def _expand_prior(c: ModelConfig, work, region, wkvb, layer, slots, below):
+    """The prior rows of K continuing chunks, expanded per head ONCE a
+    dispatch: rows [0, below[i]) of lane ``slots[i]`` of the region's
+    layer ``layer`` -> ``work`` = (K [1, nh, K, span, nope + rope], V [1,
+    nh, K, span, v]), which ``prefill_attention`` then reads as it reads
+    a region (layer 0, lane i). One rolled loop over the lanes' LIVE
+    blocks, the blocks ``prefill_attention``'s region loop will visit: a
+    fresh or dummy lane (below 0) costs nothing, a 4096-row context in a
+    16384-row span a quarter of it. Rows at or past ``below`` keep what
+    ``work`` held, finite values the attention masks. Jitted with the
+    layer a VALUE, so that a program's layers share one traced
+    expansion."""
+    span = work[0].shape[3]
+    cb = min(PREFILL_BLOCK, span)
+    i32 = jnp.int32
+    below = below.astype(i32)
+    nblk = (below + cb - 1) // cb
+    ends = jnp.cumsum(nblk)
+
+    def expand(w, work):
+        lane = jnp.sum(w >= ends).astype(i32)
+        # a span of whole blocks (every engine's) writes at j x cb, an
+        # offset XLA:TPU can see is aligned to the workspace's tiles: the
+        # write takes 23 us a block where the slid form below, whose
+        # offset it cannot see through the minimum, took 83 (chip runs,
+        # PR 39). Only a span that is no multiple of the block
+        # slides its last block back, as the attention's loop does
+        k0 = (w - (ends[lane] - nblk[lane])) * cb
+        if span % cb:
+            k0 = jnp.minimum(k0, span - cb)
+        rows = jax.lax.dynamic_slice(
+            region, (layer, 0, slots[lane].astype(i32), k0, 0),
+            (1, 1, 1, cb, region.shape[4]))[0, 0, 0]
+        at = (0, 0, lane, k0, 0)
+        return tuple(
+            jax.lax.dynamic_update_slice(
+                buf, x.transpose(1, 0, 2)[None, :, None], at)
+            for buf, x in zip(work, _expand_kv(c, {"wkvb": wkvb}, rows)))
+
+    return jax.lax.fori_loop(0, ends[-1], expand, tuple(work))
+
+
 def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
                        seq_lens, ctx_span=0, adapter_ids=None):
     """K chunks [K, T] through the model in one program; their rows land
     in each lane's region at [q_start, q_start + T) in one tail pass after
-    every read. ``ctx_span`` 0: every chunk is fresh, K and V are expanded
-    from the latent and no region read is compiled. Else the chunks
-    continue contexts already in the region and attention is absorbed,
-    over the region's rows and the chunk's own."""
+    every read. Attention is EXPANDED in both programs (K and V per head
+    from the latent, scored at nope + rope through the shared blocked
+    prefill attention): a chunk is T query rows, and expanding a cached
+    row once pays from ~146 query rows up (the module's header has the
+    arithmetic). ``ctx_span`` 0: every chunk is fresh and no region read
+    is compiled. Else the chunks continue contexts already in the region:
+    each layer first expands the lanes' live prior rows into a workspace
+    of ``ctx_span`` rows a lane (``_expand_prior``; nh x (nope + rope +
+    v) values a row: 336 MB for one lane of 16384 rows of 32 heads in
+    bfloat16, allocated once a program and rewritten by every layer), and
+    the attention reads that workspace where a dense model's reads its
+    region. A fresh lane (q_start 0) in a continuing program expands and
+    reads nothing. Only decode absorbs (``decode_step_impl``)."""
     c, d = config, dims(config)
     _refuse_adapters(params)
     K, T = tokens.shape
@@ -456,6 +538,11 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
     h = _embed(c, params, tokens.reshape(K * T), cdt)
     stats = stats_zero(c)
     rows_out = []
+    span, prior = min(ctx_span, ctx_kv[ROW].shape[3]), None
+    if span:
+        below = jnp.minimum(jnp.minimum(q_starts, seq_lens), span)
+        work = tuple(jnp.zeros((1, d["nh"], K, span, w), cdt)
+                     for w in (d["nope"] + d["rope"], d["v"]))
     for l in range(c.num_layers):
         lp = jax.tree.map(lambda a: a[l], params["layers"])
         u, mix = _open(c, lp, 0, h)
@@ -463,39 +550,26 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
             q_nope, q_rope, row = _attn_in(c, lp, u, pos)
             rows_out.append(row)
             lanes = lambda a: a.reshape(K, T, *a.shape[1:])  # noqa: E731
-            if ctx_span == 0:
-                wk, wv = _wkvb(c, lp)
-                c_kv = row[:, :d["kv_rank"]]
-                k_rope = row[:, d["kv_rank"]:d["row"]]
-                k = jnp.concatenate([
-                    jnp.einsum("nc,chd->nhd", c_kv, wk),
-                    jnp.broadcast_to(k_rope[:, None],
-                                     (K * T, d["nh"], d["rope"]))], -1)
-                v = jnp.einsum("nc,chd->nhd", c_kv, wv)
-                # the shared attention takes one width: V rides zero-
-                # padded to the key's and the pad is cut from the result
+            k, v = _expand_kv(c, lp, row)
+            if not span:
+                # in the fresh programs V rides zero-padded to the key's
+                # width and the pad is cut from the result: the lowering
+                # the routed-expert chat cell's programs are pinned to
+                # (tests/test_tpu_lowering.py); the attention would take
+                # V at its own width, as the continuing programs pass it
                 v = jnp.pad(v, ((0, 0), (0, 0),
                                 (0, k.shape[-1] - d["v"])))
-                q = jnp.concatenate([q_nope, q_rope], -1)
-                if scale_times != 1.0:
-                    q = _scaled(c, q, scale_times)
-                o = prefill_attention(lanes(q), lanes(k), lanes(v),
-                                      q_starts, seq_lens)
-                attn = o[..., :d["v"]].reshape(K * T, -1)
             else:
-                # prefill_attention divides by sqrt(its width), the
-                # row's: undone here, _absorb_q divides by sqrt(qk width)
-                q = _absorb_q(c, lp, q_nope, q_rope,
-                              np.sqrt(d["stored"]))
-                r = lanes(row)[:, :, None].astype(cdt)
-                o = prefill_attention(
-                    lanes(q), r, r, q_starts, seq_lens,
-                    PriorContext(ctx_kv[ROW], ctx_kv[ROW], jnp.int32(l),
-                                 slots),
-                    ctx_span=ctx_span)
-                attn = _unabsorb_o(
-                    c, lp, o[..., :d["kv_rank"]].reshape(
-                        K * T, d["nh"], d["kv_rank"]))
+                work = _expand_prior(c, work, ctx_kv[ROW], lp["wkvb"],
+                                     jnp.int32(l), slots, below)
+                prior = PriorContext(*work, jnp.int32(0),
+                                     jnp.arange(K, dtype=jnp.int32))
+            q = jnp.concatenate([q_nope, q_rope], -1)
+            if scale_times != 1.0:
+                q = _scaled(c, q, scale_times)
+            o = prefill_attention(lanes(q), lanes(k), lanes(v), q_starts,
+                                  seq_lens, prior, ctx_span=span)
+            attn = o[..., :d["v"]].reshape(K * T, -1)
         h, stats = _layer_out(c, params, lp, l, h, attn, mix, valid, stats)
 
     rows = jnp.stack(rows_out).reshape(

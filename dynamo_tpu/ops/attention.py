@@ -151,12 +151,16 @@ PREFILL_BLOCK = 256  # query/key rows per block of prefill_attention
 
 
 class PriorContext(NamedTuple):
-    """Where a prefill chunk's prior context lives: the engine's ctx
-    region, read block by block inside the attention loop (never sliced
-    into a per-lane slab first)."""
+    """Where a prefill chunk's prior context lives, read block by block
+    inside the attention loop (never sliced into a per-lane slab first).
+    A dense model hands over the engine's ctx region itself. A latent-row
+    model hands over a WORKSPACE of the same geometry holding its chunks'
+    prior rows expanded per head, written once a dispatch before the call
+    (one layer, lane i = chunk i; models/mla_moe.py:_expand_prior, whose
+    header has the arithmetic)."""
 
     k: jnp.ndarray           # [L, kvh, lanes, S, hd] — the whole region
-    v: jnp.ndarray
+    v: jnp.ndarray           # [L, kvh, lanes, S, hd_v]
     layer: jnp.ndarray       # scalar i32 — a VALUE, so that every layer
                              # of a program shares one traced attention
     slots: jnp.ndarray       # [K] i32 — each chunk's lane of the region
@@ -168,7 +172,7 @@ class PriorContext(NamedTuple):
 def prefill_attention(
     q: jnp.ndarray,          # [K, T, n_heads, hd] — K chunks of T new tokens
     k_new: jnp.ndarray,      # [K, T, kvh, hd] — the chunks' own keys
-    v_new: jnp.ndarray,
+    v_new: jnp.ndarray,      # [K, T, kvh, hd_v] — values, at their width
     q_starts: jnp.ndarray,   # [K] i32 — tokens already in each region
     seq_lens: jnp.ndarray,   # [K] i32 — total valid context (0 = dummy lane)
     ctx: Optional[PriorContext] = None,  # None = fresh chunks (every
@@ -183,7 +187,7 @@ def prefill_attention(
     pure XLA, scoring only (query block, key block) pairs that can hold
     a live pair. Chunk k's T tokens sit at positions q_start..q_start+T
     and attend the prior context [0, q_start) plus the chunk itself.
-    Returns [K, T, n_heads, hd]; rows of a query block with no live row
+    Returns [K, T, n_heads, hd_v]; rows of a query block with no live row
     (padding past seq_len, dummy lanes) and rows that see no key are 0.
 
     Two rolled loops whose trip counts are traced values:
@@ -210,7 +214,7 @@ def prefill_attention(
     the rows it has already seen, instead of padding the source.
     """
     K, T, n_heads, hd = q.shape
-    kvh = k_new.shape[2]
+    kvh, hd_v = k_new.shape[2], v_new.shape[3]
     rep = n_heads // kvh
     blk = min(block, T)
     nq = -(-T // blk)
@@ -253,7 +257,7 @@ def prefill_attention(
             qt, (lane, 0, 0, q0, 0), (1, kvh, rep, blk, hd))[0]
         carry = (jnp.full((kvh, rep, blk), NEG_INF, jnp.float32),
                  jnp.zeros((kvh, rep, blk), jnp.float32),
-                 jnp.zeros((kvh, rep, blk, hd), jnp.float32))
+                 jnp.zeros((kvh, rep, blk, hd_v), jnp.float32))
 
         if ctx is not None:
             span = ctx_span or ctx.k.shape[3]
@@ -266,9 +270,10 @@ def prefill_attention(
                 k0 = jnp.minimum(j * cb, span - cb)
                 kp = k0 + jnp.arange(cb, dtype=i32)  # absolute position
                 at = (layer, 0, slot, k0, 0)
-                size = (1, kvh, 1, cb, hd)
-                k_blk = jax.lax.dynamic_slice(ctx.k, at, size)[0, :, 0]
-                v_blk = jax.lax.dynamic_slice(ctx.v, at, size)[0, :, 0]
+                k_blk = jax.lax.dynamic_slice(
+                    ctx.k, at, (1, kvh, 1, cb, hd))[0, :, 0]
+                v_blk = jax.lax.dynamic_slice(
+                    ctx.v, at, (1, kvh, 1, cb, hd_v))[0, :, 0]
                 if ctx.k_scale is not None:
                     g = ctx.k.shape[3] // ctx.k_scale.shape[2]
 
@@ -288,16 +293,17 @@ def prefill_attention(
         def chunk_block(j, carry):
             k0 = jnp.minimum(j * blk, T - blk)
             kp = k0 + jnp.arange(blk, dtype=i32)     # chunk-relative
-            at, size = (lane, 0, k0, 0), (1, kvh, blk, hd)
+            at = (lane, 0, k0, 0)
             ok = ((kp >= j * blk) & (kp < live))[None, :]
             if chunk_masks is None:
                 ok = ok & (kp[None, :] <= rows[:, None])
             else:
                 ok = ok & jax.lax.dynamic_slice(
                     chunk_masks, (lane, q0, k0), (1, blk, blk))[0]
-            return score(carry, q_blk,
-                         jax.lax.dynamic_slice(kt, at, size)[0],
-                         jax.lax.dynamic_slice(vt, at, size)[0], ok)
+            return score(
+                carry, q_blk,
+                jax.lax.dynamic_slice(kt, at, (1, kvh, blk, hd))[0],
+                jax.lax.dynamic_slice(vt, at, (1, kvh, blk, hd_v))[0], ok)
 
         n_keys = nblk_live[lane]
         if chunk_masks is None:
@@ -310,8 +316,10 @@ def prefill_attention(
         return jax.lax.dynamic_update_slice(
             out, o.astype(q.dtype)[None], (lane, 0, 0, q0, 0))
 
-    out = jax.lax.fori_loop(0, ends[-1], query_block, jnp.zeros_like(qt))
-    return out.transpose(0, 3, 1, 2, 4).reshape(K, T, n_heads, hd)
+    out = jax.lax.fori_loop(
+        0, ends[-1], query_block,
+        jnp.zeros(qt.shape[:-1] + (hd_v,), qt.dtype))
+    return out.transpose(0, 3, 1, 2, 4).reshape(K, T, n_heads, hd_v)
 
 
 def prefill_attention_pairs(

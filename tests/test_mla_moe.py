@@ -233,11 +233,11 @@ def test_grouped_product_tile_follows_the_matrix(k, n, want):
 
 
 def test_chunked_prefill_and_reloaded_prefix_give_one_prefill(block):
-    """(d) one FRESH chunk and one CONTINUING chunk (absorbed, over the
-    region) against the reference's full forward, and a prefix sealed to
-    the pool then loaded into another lane, give the logits of one
-    prefill: the pool and its movers carry the latent row. The sealed
-    rows themselves move bit-exactly."""
+    """(d) one FRESH chunk and one CONTINUING chunk (its prior rows
+    expanded from the region) against the reference's full forward, and a
+    prefix sealed to the pool then loaded into another lane, give the
+    logits of one prefill: the pool and its movers carry the latent row.
+    The sealed rows themselves move bit-exactly."""
     cfg, params, ref, hf, long = block
     n, first = long + 23, 32 if long < 32 else 64   # 44 or 98 tokens
     prompt = np.random.RandomState(4).randint(1, 256, n).tolist()
@@ -278,6 +278,125 @@ def test_chunked_prefill_and_reloaded_prefix_give_one_prefill(block):
         cfg, params, ctx3, padded(prompt[32:], S - 32), jnp.int32(1),
         jnp.int32(32), jnp.int32(n))
     np.testing.assert_allclose(np.asarray(three), np.asarray(one), **TOL)
+
+
+# (region rows S, chunk width T, [(q_start, chunk tokens)] a lane of the
+# continuing program; a lane of (0, 0) is a dummy). The attention's blocks
+# are 256 rows: in a 320-row region the second prior block slides back to
+# 64, and the chunk ends where the region does.
+CONTINUING = {
+    "q_start_on_a_block": (512, 64, [(256, 50)]),
+    "q_start_off_a_block": (512, 64, [(300, 50)]),
+    "last_block_slides_back": (320, 32, [(288, 30)]),
+    "fresh_lane_beside_continuing": (512, 64, [(0, 40), (300, 50)]),
+    "dummy_lane_beside_continuing": (512, 64, [(300, 50), (0, 0)]),
+    "two_lanes_at_their_own_q_start": (512, 64, [(256, 60), (300, 50)]),
+    "chunk_fills_its_bucket": (512, 64, [(130, 64)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CONTINUING))
+def test_continuing_chunk_equals_one_fresh_prefill(block, case):
+    """A chunk that continues a context in the region (its prior rows
+    expanded once into the workspace, scored at the heads' width) gives
+    what ONE fresh prefill of the whole prompt gives: the last token's
+    logits and every row the chunk writes, in every layer (a row of layer
+    l > 0 carries the attention of the layers under it at ITS position),
+    to float32 summation order."""
+    cfg, params, _, _, _ = block
+    S, T, lanes = CONTINUING[case]   # fresh programs run S wide
+    tight = dict(rtol=1e-5, atol=1e-5)
+    i32 = lambda *v: jnp.asarray(v, jnp.int32)  # noqa: E731
+    row, B = mla_moe.ROW, 2
+    rng = np.random.RandomState(7)
+    prompts = [rng.randint(1, 256, q + n).tolist() for q, n in lanes]
+
+    def fresh(ctx, prompt, slot, upto):
+        return llama.batch_prefill(
+            cfg, params, ctx, padded(prompt[:upto], S)[None], i32(slot),
+            i32(0), i32(upto), 0)
+
+    whole = llama.init_ctx(cfg, B, S, jnp.float32)
+    ctx = llama.init_ctx(cfg, B, S, jnp.float32)
+    want = []
+    for slot, ((q, n), prompt) in enumerate(zip(lanes, prompts)):
+        if n == 0:
+            want.append(None)
+            continue
+        whole, logits = fresh(whole, prompt, slot, q + n)
+        want.append(logits[0])
+        if q:
+            ctx, _ = fresh(ctx, prompt, slot, q)
+    K = len(lanes)
+    slots = [B if n == 0 else i for i, (_, n) in enumerate(lanes)]
+    toks = jnp.stack([padded(p[q:], T) for (q, _), p in zip(lanes, prompts)])
+    ctx, got = llama.batch_prefill(
+        cfg, params, ctx, toks, i32(*slots), i32(*[q for q, _ in lanes]),
+        i32(*[q + n for q, n in lanes]), S)
+    assert got.shape[0] == K
+    for slot, (q, n) in enumerate(lanes):
+        if n == 0:
+            continue
+        np.testing.assert_allclose(
+            np.asarray(got[slot]), np.asarray(want[slot]), **tight)
+        np.testing.assert_allclose(
+            np.asarray(ctx[row][:, 0, slot, q:q + n]),
+            np.asarray(whole[row][:, 0, slot, q:q + n]), **tight)
+
+
+def test_continuing_chunk_equals_absorbed_decode_and_only_adds_rows(block):
+    """The two attention forms over the SAME cached rows: a continuing
+    chunk (prior rows expanded, scored at the heads' width) against the
+    absorbed form, which is decode fed the chunk's tokens one at a time
+    over region + ring; the last token's logits agree to float32
+    summation order. The chunk only ADDS rows: what the region held below
+    q_start, and every other lane, is unchanged bit for bit, so decode
+    after it reads the rows a fresh prefill of the whole prompt wrote."""
+    cfg, params, _, _, _ = block
+    S, T, q, n = 512, 32, 300, 21     # q_start off a block, chunk < bucket
+    tight = dict(rtol=1e-5, atol=1e-5)
+    i32 = lambda *v: jnp.asarray(v, jnp.int32)  # noqa: E731
+    row = mla_moe.ROW
+    prompt = np.random.RandomState(11).randint(1, 256, q + n).tolist()
+
+    def fresh(upto):
+        return llama.batch_prefill(
+            cfg, params, llama.init_ctx(cfg, 1, S, jnp.float32),
+            padded(prompt[:upto], S)[None], i32(0), i32(0), i32(upto), 0)
+
+    prior, _ = fresh(q)
+    whole, _ = fresh(q + n)
+    before = np.asarray(prior[row])
+    ctx, got = llama.batch_prefill(
+        cfg, params, prior, padded(prompt[q:], T)[None], i32(0), i32(q),
+        i32(q + n), S)
+    after = np.asarray(ctx[row])
+    np.testing.assert_array_equal(after[:, :, 0, :q], before[:, :, 0, :q])
+    # (the bucket's padding rows, [q + n, q + T), are garbage by contract)
+    np.testing.assert_array_equal(after[:, :, 0, q + T:],
+                                  before[:, :, 0, q + T:])
+    np.testing.assert_array_equal(after[:, :, 1:], before[:, :, 1:])
+
+    # absorbed: the chunk's tokens through decode, teacher-forced
+    absorbed = llama.init_ctx(cfg, 1, S, jnp.float32)
+    absorbed = {row: absorbed[row].at[:].set(jnp.asarray(before))}
+    ring = llama.init_ring(cfg, 1, 1, dtype=jnp.float32)
+    for i, tok in enumerate(prompt[q:]):
+        base = i32(q + i)
+        ring, logits, _ = DECODE_STEP(
+            cfg, params, absorbed, ring, i32(tok), i32(q + i + 1), base,
+            jnp.int32(0))
+        absorbed = llama.flush_ctx(absorbed, ring, i32(0), base, i32(1))
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(logits[0]),
+                               **tight)
+    np.testing.assert_allclose(
+        np.asarray(absorbed[row][:, 0, 0, q:q + n]), after[:, 0, 0, q:q + n],
+        **tight)
+
+    # decode after the chunk against decode after one fresh prefill
+    _, rows_chunked, _ = decode_steps(cfg, params, ctx, got[0], q + n, 3)
+    _, rows_whole, _ = decode_steps(cfg, params, whole, got[0], q + n, 3)
+    np.testing.assert_allclose(rows_chunked, rows_whole, **tight)
 
 
 def test_ring_flush_near_the_regions_end_keeps_every_row(setup):

@@ -15,6 +15,7 @@ second, so THAT guard is tier-1: no region-shaped copy in ring -> region
 or region -> pool (a third of the chip in both dense cells until PR 34).
 """
 import os
+import re
 import sys
 
 import numpy as np
@@ -185,18 +186,24 @@ import tpu_compile_check  # noqa: E402
 MOVERS = ("flush_ctx", "seal_blocks", "flush_seal")
 
 
+def _v5e_or_skip():
+    """The compile-only v5e topology, described inside a fixture (never at
+    import: one process at a time may load libtpu), or a skip."""
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name=tpu_compile_check.TOPOLOGY)
+    except Exception as exc:  # noqa: BLE001 — no libtpu, or it is held
+        pytest.skip(f"no compile-only v5e topology here: {exc!r:.200}")
+
+
 @pytest.fixture(scope="module", params=["mistral7b-w8", "nemo12b-tp4"],
                 ids=["tp1_8slots", "tp4_16slots"])
 def mover_records(request):
     """The movers at the dense cells' K/V shapes (kvh 8, hd 128, S 4096,
     64-token pages; 8 slots on one chip, 16 over tp=4), 2 layers,
     compiled by XLA:TPU for compile-only v5e devices."""
-    from jax.experimental import topologies
-    try:
-        topologies.get_topology_desc(
-            platform="tpu", topology_name=tpu_compile_check.TOPOLOGY)
-    except Exception as exc:  # noqa: BLE001 — no libtpu, or it is held
-        pytest.skip(f"no compile-only v5e topology here: {exc!r:.200}")
+    _v5e_or_skip()
     records = tpu_compile_check.compile_programs(
         config=request.param, layers=2, programs=MOVERS)
     return dict(zip(MOVERS, records))   # the tool keeps its table's order
@@ -220,12 +227,7 @@ def latent_records():
     latent cell's region, ``[7, 1, 17, 16384, 640]`` (7 layers, one row
     kind, 16 + 1 lanes of 16384 tokens, rows stored at 640), compiled by
     XLA:TPU for a compile-only v5e device."""
-    from jax.experimental import topologies
-    try:
-        topo = topologies.get_topology_desc(
-            platform="tpu", topology_name=tpu_compile_check.TOPOLOGY)
-    except Exception as exc:  # noqa: BLE001 — no libtpu, or it is held
-        pytest.skip(f"no compile-only v5e topology here: {exc!r:.200}")
+    topo = _v5e_or_skip()
     records = dict(zip(MOVERS, tpu_compile_check.compile_programs(
         config="xing4-mhc-d7", programs=MOVERS)))
     from dynamo_tpu.ops.latent_decode import latent_decode_attention
@@ -261,6 +263,129 @@ def test_latent_movers_and_decode_copy_no_region_on_v5e(latent_records,
     assert rec["region_shard"] == [7, 1, 17, 16384, 640]
     assert rec["region_copies"] == {"count": 0, "shapes": []}, rec
     assert rec["temp_bytes"] < 0.05 * rec["region_bytes"], rec
+
+
+# the two continuing programs of the long-context latent cell, by bucket:
+# a ceiling on XLA's temporaries. The parent's programs held 0.451 / 0.715
+# GB; the prior rows' workspace (32 heads x (192 + 128) values x 16384 rows
+# in bfloat16 = 0.336 GB) lives through the whole program: 0.822 / 1.068 GB
+# compiled (PR 39, PR 40), of the parent's + ~0.5 GB the chip has room for
+CONTINUING_TEMP_CEILING = {2048: 0.86e9, 4096: 1.1e9}
+
+
+@pytest.fixture(scope="module", params=sorted(CONTINUING_TEMP_CEILING),
+                ids=lambda T: f"T{T}")
+def continuing_prefill_record(request):
+    """A continuing ``[1, T]`` prefill of the long-context latent cell (all
+    7 layers, region ``[7, 1, 17, 16384, 640]``), compiled by XLA:TPU and
+    Mosaic for a compile-only v5e device (~45 s each), with its text."""
+    _v5e_or_skip()
+    # conftest's "highest" makes Mosaic refuse the grouped product's bf16
+    # dots, and no serving process sets it
+    with jax.default_matmul_precision("default"):
+        (rec,) = tpu_compile_check.compile_programs(
+            config="xing4-mhc-d7", programs=("batch_prefill_cont",),
+            prefill_width=request.param, keep_text=True)
+    return request.param, rec
+
+
+# f32[1,32,256,640], bf16[1,1,32,4096,640]: 32 heads by the stored row
+_HEADS_BY_STORED_ROW = re.compile(r"\w+\[(?:\d+,)*32,(?:\d+,)*640\]")
+
+
+@pytest.mark.parametrize("what", ["no_region_copy", "no_op_at_the_rows_width",
+                                  "temporaries"])
+def test_latent_continuing_prefill_scores_at_the_heads_width(
+        continuing_prefill_record, what):
+    """A continuing chunk over latent rows expands its prior rows into a
+    workspace and scores at 192 / 128: the compiled program copies
+    nothing the size of the region, has no op whose shape carries 32
+    heads x the stored row's 640 columns (the absorbed form's scores and
+    accumulator did), and its temporaries stay under the parent's plus
+    the workspace."""
+    T, rec = continuing_prefill_record
+    assert rec["ok"], rec.get("error")
+    assert rec["program"] == f"batch_prefill_cont_K1_T{T}_S16384"
+    assert rec["region_shard"] == [7, 1, 17, 16384, 640]
+    if what == "no_region_copy":
+        assert rec["region_copies"] == {"count": 0, "shapes": []}
+    elif what == "no_op_at_the_rows_width":
+        assert not sorted(set(_HEADS_BY_STORED_ROW.findall(rec["text"])))
+        assert "bf16[1,32,1,16384,192]" in rec["text"]   # the workspace
+    else:
+        assert rec["temp_bytes"] < CONTINUING_TEMP_CEILING[T], rec["temp_gb"]
+
+
+def test_long_context_latent_cell_keeps_four_prefill_programs():
+    """The cell's whole-model programs are a budget (the chip machine's
+    compile cache holds ~190 MiB: six of them): a prefill program a
+    (bucket, lanes, fresh | continuing), here 2 x 1 x 2, beside the
+    round's two (with and without log-probs). The expansion of a
+    continuing chunk's prior rows is a jit INSIDE the prefill program,
+    which ``test_latent_continuing_prefill_...`` compiles as one module."""
+    import json
+
+    from dynamo_tpu.engine.config import EngineConfig
+
+    with open(os.path.join(os.path.dirname(tpu_compile_check.__file__), "..",
+                           "benchmarks", "configs",
+                           "xing4-mhc-d7.json")) as f:
+        e = EngineConfig(**json.load(f)["engine"])
+    programs = {(T, e.prefill_lanes(T, group), continuing)
+                for T in e.prefill_buckets
+                for group in range(1, e.prefill_chunks_per_round + 1)
+                for continuing in (False, True)}
+    assert sorted(programs) == [(2048, 1, False), (2048, 1, True),
+                                (4096, 1, False), (4096, 1, True)]
+
+
+# ``lowered_sha256`` (tools/tpu_compile_check.py: the StableHLO text before
+# the compiler, Mosaic bodies masked) of the programs that share code with
+# the continuing latent chunk and must NOT move with it: every program
+# the routed-expert chat cell runs (its prompts fit one bucket, so every
+# chunk is fresh) and the dense prefill, fresh and continuing, which shares
+# ``prefill_attention``. Recorded on the parent of PR 40 (edc371c) by this
+# test itself; a PR that MEANS to change one of these programs records the
+# new digest here and says so (equal digests are what let a chip check of
+# the cell be predicted ``unchanged``, PERF.md section 6, PR 35).
+UNMOVED = {
+    ("mla-moe-joyai-d5", 0): {
+        "flush_ctx": "aa9a25ef5ee32101",
+        "seal_blocks_w64": "60d93eac534dbceb",
+        "flush_seal_w64": "0cf53d0f869aa38b",
+        "round_seal_n4_w64": "9c08905c1b04a832",
+        "load_ctx_pages_n64": "217cccff59be759b",
+        "batch_prefill_K2_T128": "459e2c202d3ac337",
+    },
+    ("mistral7b-w8", 2): {
+        "batch_prefill_K2_T128": "587de9cf00cdecb3",
+        "batch_prefill_cont_K2_T128_S4096": "2c9a09ec6d798ada",
+    },
+}
+_UNMOVED_NAMES = {"mla-moe-joyai-d5": MOVERS + ("round_seal", "load_ctx_pages",
+                                                "batch_prefill"),
+                  "mistral7b-w8": ("batch_prefill", "batch_prefill_cont")}
+
+
+@pytest.fixture(scope="module")
+def unmoved_digests():
+    _v5e_or_skip()
+    with jax.default_matmul_precision("default"):
+        return {
+            (config, layers): {
+                r["program"]: r.get("lowered_sha256", r.get("error"))
+                for r in tpu_compile_check.compile_programs(
+                    config=config, layers=layers,
+                    programs=_UNMOVED_NAMES[config])}
+            for config, layers in UNMOVED}
+
+
+@pytest.mark.parametrize("key,program", [
+    (key, program) for key in sorted(UNMOVED) for program in UNMOVED[key]],
+    ids=lambda v: v if isinstance(v, str) else v[0])
+def test_programs_beside_the_continuing_latent_chunk_keep_their_lowering(
+        unmoved_digests, key, program):
+    assert unmoved_digests[key][program] == UNMOVED[key][program]
 
 
 def test_region_copies_reads_copy_and_copy_start():

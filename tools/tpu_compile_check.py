@@ -32,7 +32,9 @@ entries, both in one jit, the fused round as the engine builds it
 the pool's page gather / scatter at a long prompt's pages, and a batched
 prefill at the lanes the engine gives a group of two: fresh
 (``batch_prefill``) and continuing contexts in the region
-(``batch_prefill_cont``), at the smallest bucket.
+(``batch_prefill_cont``), at the smallest bucket (``--prefill-width``
+names another: the long-context cell's continuing ``[1, 4096]`` program
+is held by tests/test_tpu_lowering.py).
 Prints one JSON line per program with XLA's memory analysis and the
 region-shaped copies in the compiled text; exits 1 if any program fails
 to compile. libtpu warns about ``TPU_ACCELERATOR_TYPE`` / worker hostnames
@@ -82,12 +84,15 @@ def region_copies(hlo_text: str, *shards: tuple[int, ...]) -> list[str]:
 def compile_programs(model_config: str = "llama3_1b", tp: int = 1,
                      kv_quant: str = "none", layers: int = 0, *,
                      config: str = "", programs: tuple[str, ...] = (),
-                     seal_width: int = 0) -> list[dict]:
+                     seal_width: int = 0, prefill_width: int = 0,
+                     keep_text: bool = False) -> list[dict]:
     """Compile the serving programs for a tp-wide mesh of compile-only
     v5e devices. Returns one record per program: {"program", "ok",
     "seconds", "error" | memory fields, "region_copies"}. ``config`` names
     a file of benchmarks/configs and overrides ``model_config`` and
-    ``tp``; ``programs`` keeps the named ones only."""
+    ``tp``; ``programs`` keeps the named ones only; ``prefill_width``
+    picks the prefill programs' bucket (0: the first); ``keep_text`` adds
+    the compiled module's text to the record (``"text"``)."""
     import dataclasses
 
     import jax
@@ -152,7 +157,9 @@ def compile_programs(model_config: str = "llama3_1b", tp: int = 1,
                     llama.ring_shardings(c, mesh))
     # the batched prefill the cells run: a group of two (the most that
     # prefill_chunks_per_round's default lets form) at the first bucket
-    T = e.prefill_buckets[0]
+    T = prefill_width or e.prefill_buckets[0]
+    if T not in e.prefill_buckets:
+        raise ValueError(f"{T} is no prefill bucket of {e.prefill_buckets}")
     K = e.prefill_lanes(T, 2)
 
     def largest_shard(state):
@@ -275,6 +282,8 @@ def compile_programs(model_config: str = "llama3_1b", tp: int = 1,
                                "shapes": sorted(set(copies))},
                 mosaic_calls=text.count("tpu_custom_call"),
             )
+            if keep_text:
+                rec["text"] = text
         rec["seconds"] = round(time.monotonic() - t0, 2)
         out.append(rec)
     return out
@@ -300,10 +309,13 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--seal-width", type=int, default=0,
                     help="entries of the standalone seal (0 = the fused "
                          "round's width)")
+    ap.add_argument("--prefill-width", type=int, default=0,
+                    help="the prefill programs' bucket (0 = the first)")
     args = ap.parse_args(argv)
     records = compile_programs(
         args.model_config, args.tp, args.kv_quant, args.layers,
         config=args.config, seal_width=args.seal_width,
+        prefill_width=args.prefill_width,
         programs=tuple(p for p in args.programs.split(",") if p),
     )
     for rec in records:
