@@ -70,7 +70,7 @@ from dynamo_tpu.kv_router.protocols import (
     KvStats,
     WorkerStats,
 )
-from dynamo_tpu.models import llama, mla_moe
+from dynamo_tpu.models import llama, mla_moe, ssm_moe
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.ops import latent_decode
 from dynamo_tpu.ops.attention import (
@@ -346,10 +346,14 @@ class TpuEngine:
         # compiled Pallas kernel on TPU devices, the jnp reference on the
         # CPU test meshes — decided here, once, from the mesh's devices
         self.decode_attn = decode_attention_for(self.mesh)
-        if model_config.mla is not None:
-            # (its decode attention is ops/latent_decode.py, in XLA on
-            # every platform; decode_attn goes unused)
-            self._refuse_latent_planes(self.ecfg, on_dispatch, draft_config)
+        # the module of a block that is not the dense decoder: its own
+        # decode step, which also returns the routing counters
+        self._block = llama.block_of(model_config)
+        if self._block is not None:
+            # (the latent block's decode attention is ops/latent_decode.py,
+            # in XLA on every platform; decode_attn goes unused there)
+            self._refuse_row_only_planes(self.ecfg, on_dispatch,
+                                         draft_config)
         dev0 = self.mesh.devices.flat[0]
         log.info(
             "engine devices: platform=%s device_kind=%s mesh=%s "
@@ -446,11 +450,19 @@ class TpuEngine:
             llama.init_ring(c, e.max_decode_slots, e.flush_every, cache_dtype),
             llama.ring_shardings(c, self.mesh),
         )
+        # a page of K/V rows without the recurrent state at its boundary
+        # cannot resume a prompt: a model with recurrent layers takes no
+        # prefix match and seals nothing (a preempted or migrated stream
+        # recomputes from position 0)
+        prefix_caching = e.enable_prefix_caching and c.hybrid is None
+        if e.enable_prefix_caching and not prefix_caching:
+            log.info("prefix cache bypassed: this model's layers carry a "
+                     "recurrent state that pages of K/V rows do not hold")
         self.allocator = PageAllocator(
             e.num_pages, e.page_size,
             worker_id=e.worker_id,
             on_event=on_kv_event,
-            enable_prefix_caching=e.enable_prefix_caching,
+            enable_prefix_caching=prefix_caching,
         )
         # host-DRAM offload tier (KVBM G2): parked pages are batch-gathered
         # once per round and fetched to host behind compute. A deque:
@@ -545,10 +557,20 @@ class TpuEngine:
             tmetrics.DECODE_ATTN_ROWS_READ[0])
         self._h_attn_rows_live = self.telemetry.get(
             tmetrics.DECODE_ATTN_ROWS_LIVE[0])
-        # bytes a token holds in the ctx region: observed once, here
+        self._h_moe_picks_routed = self.telemetry.get(
+            tmetrics.MOE_PICKS_ROUTED[0])
+        # bytes a token holds in the ctx region, and bytes a lane holds
+        # in recurrent state whatever its context: observed once, here
+        recurrent = llama.state_kinds(self.ctx)
         self.telemetry.get(tmetrics.KV_ROW_BYTES[0]).observe(sum(
-            x.nbytes for x in jax.tree.leaves(self.ctx)
+            x.nbytes for n, leaf in self.ctx.items() if n not in recurrent
+            for x in jax.tree.leaves(leaf)
         ) / ((e.max_decode_slots + 1) * e.max_context))
+        if recurrent:
+            self.telemetry.get(tmetrics.SSM_STATE_BYTES[0]).observe(sum(
+                x.nbytes for n in recurrent
+                for x in jax.tree.leaves(self.ctx[n])
+            ) / (e.max_decode_slots + 1))
         # histogram snapshots are built per metrics() call, which the
         # engine loop makes EVERY round via on_metrics while the
         # publisher throttles to ~4 Hz — cache at the publish cadence so
@@ -780,9 +802,14 @@ class TpuEngine:
             ring_base = jnp.maximum(dev["ctx"] - 1, 0)
             # routed-expert models: one more row carries the round's
             # routing counters home in the same fetch
-            routed = c.routed is not None
+            block = llama.block_of(c)
+            routed = block is not None
             toks_out = jnp.zeros((n_steps + int(routed), B), jnp.int32)
-            moe_stats = mla_moe.stats_zero(c)
+            moe_stats = (block or mla_moe).stats_zero(c)
+            # recurrent leaves (models/ssm_moe.py) are updated by every
+            # step, where the region's rows are read-only until the
+            # flush: they ride the loop's carry ({} for the other blocks)
+            recurrent = {n: ctx_kv[n] for n in llama.state_kinds(ctx_kv)}
             lp_out = (
                 jnp.zeros((n_steps, B, 1 + 2 * max_logprobs), jnp.float32)
                 if want_lp else None
@@ -799,8 +826,15 @@ class TpuEngine:
                     if c.moe is not None or routed else None)
 
             def body(s, carry):
-                ring, dev, toks_out, lp_out, moe_stats = carry
-                if routed:
+                ring, dev, toks_out, lp_out, moe_stats, recurrent = carry
+                if block is ssm_moe:
+                    ring, recurrent, logits, st = block.decode_step_impl(
+                        c, params, ctx_kv, ring, recurrent, dev["tokens"],
+                        dev["ctx"], ring_base, s, live,
+                        attn=self.decode_attn,
+                    )
+                    moe_stats = block.merge_stats(moe_stats, st)
+                elif routed:
                     ring, logits, st = mla_moe.decode_step_impl(
                         c, params, ctx_kv, ring, dev["tokens"], dev["ctx"],
                         ring_base, s, live,
@@ -839,10 +873,12 @@ class TpuEngine:
                     keys=keys,
                     counts=counts,
                 )
-                return ring, dev, toks_out, lp_out, moe_stats
+                return ring, dev, toks_out, lp_out, moe_stats, recurrent
 
-            ring, dev, toks_out, lp_out, moe_stats = jax.lax.fori_loop(
-                0, n_steps, body, (ring, dev, toks_out, lp_out, moe_stats)
+            (ring, dev, toks_out, lp_out, moe_stats,
+             recurrent) = jax.lax.fori_loop(
+                0, n_steps, body,
+                (ring, dev, toks_out, lp_out, moe_stats, recurrent)
             )
             if routed:
                 toks_out = toks_out.at[
@@ -853,6 +889,8 @@ class TpuEngine:
             ctx_kv = llama.flush_ctx_impl(
                 ctx_kv, ring, dev["dest"], ring_base, valid
             )
+            if recurrent:
+                ctx_kv = dict(ctx_kv, **recurrent)
             return ctx_kv, ring, dev, toks_out, lp_out
 
         engine_round = functools.partial(
@@ -1168,11 +1206,14 @@ class TpuEngine:
     # are pow2-bucketed for compile-cache reuse; padding targets scratch
     # page 0 (garbage by contract)
 
-    def _refuse_latent_planes(self, e: EngineConfig, on_dispatch,
-                              draft_config) -> None:
+    def _refuse_row_only_planes(self, e: EngineConfig, on_dispatch,
+                                draft_config) -> None:
         """Planes that know one row geometry (a K and a V of [kv_heads,
-        head_dim]) refuse a latent-row model at start-up, by name: none
-        reinterprets the row."""
+        head_dim], addressable by position) refuse a latent-row model and
+        a model with recurrent layers at start-up, by name: none
+        reinterprets the row, and none snapshots a recurrent state."""
+        what = ("a latent (MLA) cache row" if self.config.mla is not None
+                else "a recurrent (state-space) state")
         planes = {
             "kv_quant=int8 (the int8 KV plane)": e.kv_quant != "none",
             "host/disk offload tiers and their kv_integrity frames "
@@ -1188,12 +1229,13 @@ class TpuEngine:
         for plane, on in planes.items():
             if on:
                 raise ValueError(
-                    f"{plane} cannot carry a latent (MLA) cache row yet; "
+                    f"{plane} cannot carry {what} yet; "
                     "turn it off for this model")
-        need = 3 if self.config.hc is None else 4
+        need = self._block.stats_zero(self.config).shape[0]
         if e.max_decode_slots < need:
-            # the round's counters (mla_moe.stats_zero) ride home in one
-            # more row of the stacked-token fetch, max_decode_slots wide
+            # the round's counters (the block's stats_zero) ride home in
+            # one more row of the stacked-token fetch, max_decode_slots
+            # wide
             raise ValueError(
                 f"max_decode_slots={e.max_decode_slots}: this routed-expert "
                 f"model needs at least {need} (its counters ride the "
@@ -1204,6 +1246,12 @@ class TpuEngine:
             raise ValueError(
                 "kv_transfer / disaggregation cannot carry a latent (MLA) "
                 "cache row yet: pages move as a K and a V")
+        if self.config.hybrid is not None:
+            raise ValueError(
+                "kv_transfer / disaggregation cannot carry a recurrent "
+                "(state-space) state yet: pages move K and V rows, and a "
+                "prompt cannot resume from rows without the state at "
+                "their boundary")
 
     def _gather_padded(self, pages: list[int]):
         """Device gather of whole pages; returns DEVICE arrays
@@ -4125,11 +4173,16 @@ class TpuEngine:
         lp_arrs = None
         if entry.lp_handle is not None:
             lp_arrs = self._unpack_lp(np.asarray(entry.lp_handle))
-        if self.config.routed is not None:
+        if self._block is not None:
             touched, routed, load_max = toks[entry.n_steps, :3]
             self._h_moe_touched.observe(int(touched))
             self._h_moe_routed.observe(int(routed))
             self._h_moe_load_max.observe(int(load_max))
+            if self.config.hybrid is not None:
+                # a share of the experts is held: ``routed`` counted the
+                # picks that landed on it, this all the picks the router made
+                self._h_moe_picks_routed.observe(
+                    int(toks[entry.n_steps, 3]))
             if self.config.hc is not None:
                 # the fourth counter is a float32's bits
                 self._h_hc_residual.observe(float(

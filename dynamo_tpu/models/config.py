@@ -14,9 +14,11 @@ from typing import Any, Optional
 
 
 # model_type values served by the dense decoder (models/llama.py) and by
-# the latent-attention + routed-expert block (models/mla_moe.py)
+# the latent-attention + routed-expert block (models/mla_moe.py); the
+# state-space + attention hybrid with routed experts is models/ssm_moe.py
 _DENSE_TYPES = frozenset({"llama", "mistral", "qwen2"})
 _MLA_MOE_TYPES = frozenset({"deepseek_v3", "joyai_llm_flash", "xing4_0"})
+_SSM_MOE_TYPES = frozenset({"granitemoehybrid"})
 # keys that mean "not a dense Llama": a config carrying one is refused
 # rather than read with its extra structure dropped
 _FOREIGN_KEYS = ("kv_lora_rank", "q_lora_rank", "n_routed_experts",
@@ -38,6 +40,35 @@ _HC_KEYS = ("hc_mult", "hc_sinkhorn_iters", "hc_eps",
 _YARN_KEYS = frozenset({"type", "factor", "original_max_position_embeddings",
                         "beta_fast", "beta_slow", "mscale",
                         "mscale_all_dim"})
+# the hybrid block as its config.json parameterises it: every key must
+# be there (models/ssm_moe.py reads them from ``ModelConfig.hybrid``)
+_SSM_KEYS = ("mamba_n_heads", "mamba_d_head", "mamba_d_state",
+             "mamba_d_conv", "mamba_expand", "mamba_n_groups",
+             "mamba_chunk_size", "mamba_conv_bias", "mamba_proj_bias")
+_HYBRID_KEYS = _SSM_KEYS + (
+    "layer_types", "attention_multiplier", "embedding_multiplier",
+    "residual_multiplier", "logits_scaling", "shared_intermediate_size",
+    "num_local_experts", "num_experts_per_tok", "intermediate_size")
+_LAYER_KINDS = frozenset({"mamba", "attention"})
+_TINY_SSM_MOE = {
+    "model_type": "granitemoehybrid", "vocab_size": 256, "hidden_size": 64,
+    "intermediate_size": 32, "shared_intermediate_size": 48,
+    "num_hidden_layers": 6,
+    "layer_types": ["mamba", "mamba", "attention"] * 2,
+    "num_attention_heads": 4, "num_key_value_heads": 2,
+    "mamba_n_heads": 8, "mamba_d_head": 16, "mamba_d_state": 16,
+    "mamba_d_conv": 4, "mamba_expand": 2, "mamba_n_groups": 1,
+    "mamba_chunk_size": 8, "mamba_conv_bias": True,
+    "mamba_proj_bias": False,
+    "num_local_experts": 4, "num_experts_per_tok": 2,
+    "expert_share": {"published_experts": 8, "of": 2, "index": 0},
+    "attention_multiplier": 0.0625, "embedding_multiplier": 12,
+    "residual_multiplier": 0.22, "logits_scaling": 16,
+    "position_embedding_type": "nope", "rope_scaling": None,
+    "attention_bias": False, "hidden_act": "silu",
+    "normalization_function": "rmsnorm", "rms_norm_eps": 1e-5,
+    "max_position_embeddings": 512, "tie_word_embeddings": True,
+}
 _TINY_MLA_MOE = {
     "model_type": "deepseek_v3", "vocab_size": 256, "hidden_size": 64,
     "intermediate_size": 128, "num_hidden_layers": 3,
@@ -104,6 +135,18 @@ class ModelConfig:
     # the four-stream residual of that block (keys in _HC_KEYS); None =
     # one stream, the plain residual
     hc: Optional[tuple[tuple[str, Any], ...]] = None
+    # Layers of two kinds, Mamba-2 state-space mixers and NoPE attention,
+    # each followed by softmax-routed experts of which this chip may hold
+    # a share (hashable; keys in _HYBRID_KEYS plus the share). With
+    # `hybrid` set the model is served by models/ssm_moe.py: K/V rows for
+    # the attention layers and, beside them, a recurrent state a lane for
+    # each state-space layer. `num_layers` counts both kinds;
+    # `num_heads`/`num_kv_heads`/`head_dim` are the attention layers'.
+    hybrid: Optional[tuple[tuple[str, Any], ...]] = None
+
+    @property
+    def hybrid_dict(self) -> Optional[dict[str, Any]]:
+        return dict(self.hybrid) if self.hybrid else None
 
     @property
     def hc_dict(self) -> Optional[dict[str, Any]]:
@@ -138,11 +181,15 @@ class ModelConfig:
         model_type = d.get("model_type", "llama")
         if model_type in _MLA_MOE_TYPES or "kv_lora_rank" in d:
             return cls._from_hf_mla_moe(d)
+        if model_type in _SSM_MOE_TYPES:
+            return cls._from_hf_ssm_moe(d)
         if model_type not in _DENSE_TYPES:
             raise ValueError(
                 f"model_type {model_type!r} is no block this program "
                 f"builds (dense: {sorted(_DENSE_TYPES)}; latent attention "
-                f"+ routed experts: {sorted(_MLA_MOE_TYPES)})")
+                f"+ routed experts: {sorted(_MLA_MOE_TYPES)}; state-space "
+                f"+ attention layers with routed experts: "
+                f"{sorted(_SSM_MOE_TYPES)})")
         unknown = sorted(k for k in _FOREIGN_KEYS if d.get(k))
         if unknown:
             raise ValueError(
@@ -237,6 +284,100 @@ class ModelConfig:
             routed=tuple(sorted(routed.items())),
             hc=(tuple((k, d[k]) for k in _HC_KEYS) if hc_given else None),
         )
+
+    @classmethod
+    def _from_hf_ssm_moe(cls, d: dict[str, Any]) -> "ModelConfig":
+        """The state-space + attention hybrid with routed experts, as a
+        ``granitemoehybrid`` config.json parameterises it. Every key the
+        equations need must be there with a value this program builds;
+        anything else is refused by name, never defaulted.
+
+        The experts held HERE are ``num_local_experts`` (the published
+        key; a cut configuration lists it under ``reduced``). A file that
+        holds a share states the deployment under a key of its own,
+        ``expert_share``: ``published_experts`` (the router's width),
+        ``of`` (the chips that share a layer's experts) and ``index``
+        (which of them this is: it holds experts ``index * held`` up to
+        ``(index + 1) * held``). Without the key every expert is held."""
+        missing = sorted(k for k in _HYBRID_KEYS if k not in d)
+        if missing:
+            raise ValueError(f"state-space hybrid block: keys {missing} "
+                             "are missing from the config")
+        kinds = tuple(d["layer_types"])
+        held = int(d["num_local_experts"])
+        share = d.get("expert_share") or {
+            "published_experts": held, "of": 1, "index": 0}
+        if set(share) != {"published_experts", "of", "index"}:
+            raise ValueError(
+                "state-space hybrid block: expert_share needs exactly "
+                f"published_experts, of and index (it has {sorted(share)})")
+        E, of, index = (int(share[k]) for k in
+                        ("published_experts", "of", "index"))
+        nh, hd = int(d["mamba_n_heads"]), int(d["mamba_d_head"])
+        refused = {
+            f"position_embedding_type {d.get('position_embedding_type')!r}"
+            " (only 'nope': no rotary is built for this block)":
+                d.get("position_embedding_type") != "nope",
+            "rope_scaling (there is no rotary to scale)":
+                d.get("rope_scaling") is not None,
+            f"mamba_n_groups {d['mamba_n_groups']} (the scan shares one B "
+            "and C over all heads)": d["mamba_n_groups"] != 1,
+            "attention_bias": bool(d.get("attention_bias")),
+            "mamba_proj_bias": bool(d["mamba_proj_bias"]),
+            "mamba_conv_bias false": not d["mamba_conv_bias"],
+            f"hidden_act {d.get('hidden_act')!r}":
+                d.get("hidden_act", "silu") != "silu",
+            f"normalization_function {d.get('normalization_function')!r}":
+                d.get("normalization_function", "rmsnorm") != "rmsnorm",
+            "tie_word_embeddings false (the head is the embedding)":
+                not d.get("tie_word_embeddings"),
+            f"layer_types other than {sorted(_LAYER_KINDS)}":
+                not set(kinds) <= _LAYER_KINDS,
+            "layer_types whose length is not num_hidden_layers":
+                len(kinds) != d["num_hidden_layers"],
+            "mamba_n_heads x mamba_d_head != mamba_expand x hidden_size":
+                nh * hd != d["mamba_expand"] * d["hidden_size"],
+            "mamba_d_conv < 2": d["mamba_d_conv"] < 2,
+            f"expert_share: {held} held x {of} chips is not the published "
+            f"{E} experts (a share is a whole-number split)":
+                of < 1 or held * of != E,
+            f"expert_share index {index} outside 0..{of - 1}":
+                not 0 <= index < max(of, 1),
+            "num_experts_per_tok above the published experts":
+                d["num_experts_per_tok"] > E,
+        }
+        bad = sorted(k for k, v in refused.items() if v)
+        if bad:
+            raise ValueError(
+                f"state-space hybrid block: {bad} are values this program "
+                "does not build")
+        hybrid = {k: d[k] for k in _HYBRID_KEYS if k != "layer_types"}
+        hybrid.update(layer_types=kinds, published_experts=E,
+                      share_of=of, share_index=index)
+        num_heads = d["num_attention_heads"]
+        return cls(
+            vocab_size=d["vocab_size"],
+            hidden_size=d["hidden_size"],
+            intermediate_size=d["intermediate_size"],
+            num_layers=d["num_hidden_layers"],
+            num_heads=num_heads,
+            num_kv_heads=d.get("num_key_value_heads", num_heads),
+            head_dim=d.get("head_dim") or d["hidden_size"] // num_heads,
+            rms_norm_eps=d.get("rms_norm_eps", 1e-5),
+            max_position_embeddings=d.get("max_position_embeddings", 8192),
+            tie_word_embeddings=True,
+            model_type=d["model_type"],
+            hybrid=tuple(sorted(hybrid.items())),
+        )
+
+    @classmethod
+    def tiny_ssm_moe(cls, **kw) -> "ModelConfig":
+        """Toy state-space hybrid for CPU tests: two periods of (mamba,
+        mamba, attention), 8 experts top 2 of which share 0 of 2 holds 4,
+        a scan chunk of 8."""
+        d = dict(_TINY_SSM_MOE)
+        d.update(kw)
+        return cls.from_hf_dict(d)
 
     @classmethod
     def tiny_mla_moe(cls, **kw) -> "ModelConfig":

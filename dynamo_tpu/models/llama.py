@@ -34,17 +34,22 @@ Design notes (TPU-first, round-4 layout):
     static shapes; the engine buckets prompt lengths to bound recompiles.
 
 THE SEAM (ROADMAP D2). These names are what the engine and the
-benchmark's launcher call. A ``ModelConfig`` with ``mla`` set is the
-latent-attention + routed-expert block of models/mla_moe.py: the
-parameter, state and prefill functions below hand over to that module.
+benchmark's launcher call. Three blocks hang on them (``block_of``): a
+``ModelConfig`` with ``mla`` set is the latent-attention + routed-expert
+block of models/mla_moe.py, one with ``hybrid`` set the state-space +
+attention hybrid of models/ssm_moe.py, and the parameter, state and
+prefill functions below hand over to that module.
 The movers (flush_ctx, seal_blocks, load_ctx_pages) carry whatever ROW
-KINDS a region holds (``row_kinds``: ``k`` and ``v`` of [kvh, hd] here,
-one ``kv`` row there). The decode step
-has ONE entry per block: ``decode_step_impl`` here, and
-``mla_moe.decode_step_impl`` (which also returns the routing counters)
-for the latent block; the engine's round picks. Functions of planes that
-cannot carry a latent row (speculation, sequence-parallel prefill,
-embeddings, page transfer) refuse it by name (``_dense_only``).
+KINDS a region holds (``row_kinds``: ``k`` and ``v`` of [kvh, hd] here
+and in the hybrid, one ``kv`` row in the latent block) and pass over a
+region's RECURRENT leaves (``state_kinds``: the hybrid's per-lane SSM
+state and convolution window, which are no rows). The decode step
+has ONE entry per block: ``decode_step_impl`` here, and each module's
+own ``decode_step_impl`` (which also returns the routing counters; the
+hybrid's takes and returns the recurrent state); the engine's round
+picks. Functions of planes that cannot carry a latent row or a recurrent
+state (speculation, sequence-parallel prefill, embeddings, page
+transfer) refuse it by name (``_dense_only``).
 
 Parity: this is the TPU engine the reference delegates to vLLM for
 (launch/dynamo-run subprocess engines; SURVEY.md §2.1 L3).
@@ -64,7 +69,7 @@ from dynamo_tpu.kv_quant import (
     dequantize_groups,
     requantize_groups,
 )
-from dynamo_tpu.models import mla_moe
+from dynamo_tpu.models import mla_moe, ssm_moe
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.ops.attention import (
     DecodeAttention,
@@ -78,10 +83,28 @@ Params = dict[str, Any]
 Cache = dict[str, jnp.ndarray]
 
 
+def block_of(config: ModelConfig):
+    """The module that builds ``config``'s block where it is not the
+    dense decoder of this file (None): every name below hands over to
+    it."""
+    if config.mla is not None:
+        return mla_moe
+    if config.hybrid is not None:
+        return ssm_moe
+    return None
+
+
 def row_kinds(state: Cache) -> tuple[str, ...]:
     """The kinds of row a region, pool or ring holds (its leaves that
-    are not scale grids), in a fixed order."""
-    return tuple(sorted(n for n in state if not n.endswith("_scale")))
+    are neither scale grids nor recurrent state), in a fixed order."""
+    return tuple(sorted(n for n in state
+                        if not n.endswith(("_scale", "_state"))))
+
+
+def state_kinds(state: Cache) -> tuple[str, ...]:
+    """A region's recurrent leaves (``*_state``: per lane, not
+    addressable by position). No mover touches them."""
+    return tuple(sorted(n for n in state if n.endswith("_state")))
 
 
 def _any_row(state: Cache) -> jnp.ndarray:
@@ -89,15 +112,22 @@ def _any_row(state: Cache) -> jnp.ndarray:
 
 
 def _dense_only(config_or_state, plane: str) -> None:
-    """Refuse a latent-row model (or its state) in a plane that knows one
-    row geometry only, naming the plane."""
-    latent = (config_or_state.mla is not None
-              if isinstance(config_or_state, ModelConfig)
-              else "k" not in config_or_state)
+    """Refuse a latent-row or recurrent-state model (or its state) in a
+    plane that knows one row geometry only, naming the plane."""
+    if isinstance(config_or_state, ModelConfig):
+        latent = config_or_state.mla is not None
+        recurrent = config_or_state.hybrid is not None
+    else:
+        latent = "k" not in config_or_state
+        recurrent = bool(state_kinds(config_or_state))
     if latent:
         raise ValueError(
             f"{plane}: this plane cannot carry a latent (MLA) cache row; "
             "it moves a K and a V of [kv_heads, head_dim]")
+    if recurrent:
+        raise ValueError(
+            f"{plane}: this plane cannot carry a recurrent state; it "
+            "moves K and V rows that are addressable by position")
 
 
 # ---------------------------------------------------------------------------
@@ -112,8 +142,8 @@ def init_params(config: ModelConfig, rng: jax.Array | int = 0) -> Params:
     constant per-channel scale matched to the dense init's std) — an 8B's
     dense weights can never be materialized on a 16 GB chip, so there is
     no dense-then-quantize step here."""
-    if config.mla is not None:
-        return mla_moe.init_params(config, rng)
+    if block_of(config) is not None:
+        return block_of(config).init_params(config, rng)
     if isinstance(rng, int):
         rng = jax.random.PRNGKey(rng)
     c = config
@@ -169,8 +199,8 @@ def param_shardings(config: ModelConfig, mesh: Mesh) -> Params:
     qkv/gate/up shard the output (head/hidden) dim; o/down shard the input
     dim; embedding + lm_head shard the vocab dim. Quantized leaves get the
     weight's spec on "q" and the spec minus the reduced axis on "s"."""
-    if config.mla is not None:
-        return mla_moe.param_shardings(config, mesh)
+    if block_of(config) is not None:
+        return block_of(config).param_shardings(config, mesh)
     quant8 = config.quant == "int8"
 
     def ns(*spec):
@@ -232,8 +262,9 @@ def init_cache(
     untouched: quantize fuses into seal_blocks (ctx->pool), dequantize
     into load_ctx_pages (pool->ctx)."""
     c = config
-    if c.mla is not None:
-        return mla_moe.init_cache(c, num_pages, page_size, dtype, kv_quant)
+    if block_of(c) is not None:
+        return block_of(c).init_cache(c, num_pages, page_size, dtype,
+                                      kv_quant)
     shape = (c.num_layers, c.num_kv_heads, num_pages, page_size, c.head_dim)
     if kv_quant == "int8":
         return {
@@ -249,8 +280,8 @@ def init_cache(
 def cache_shardings(
     config: ModelConfig, mesh: Mesh, kv_quant: str = "none"
 ) -> Cache:
-    if config.mla is not None:
-        return mla_moe.row_shardings(config, mesh, kv_quant)
+    if block_of(config) is not None:
+        return block_of(config).row_shardings(config, mesh, kv_quant)
     s = NamedSharding(mesh, P(None, "tp", None, None, None))
     out = {"k": s, "v": s}
     if kv_quant == "int8":
@@ -282,8 +313,9 @@ def init_ctx(
     S is padded up to a multiple of it (the engine's max_context is
     already page-aligned, so no padding in practice)."""
     c = config
-    if c.mla is not None:
-        return mla_moe.init_ctx(c, batch, ctx_len, dtype, kv_quant, group)
+    if block_of(c) is not None:
+        return block_of(c).init_ctx(c, batch, ctx_len, dtype, kv_quant,
+                                    group)
     shape = (c.num_layers, c.num_kv_heads, batch + 1, ctx_len, c.head_dim)
     if kv_quant == "int8":
         S = -(-ctx_len // group) * group
@@ -302,8 +334,8 @@ def init_ctx(
 
 def ctx_shardings(config: ModelConfig, mesh: Mesh,
                   kv_quant: str = "none") -> Cache:
-    if config.mla is not None:
-        return mla_moe.row_shardings(config, mesh, kv_quant)
+    if block_of(config) is not None:
+        return block_of(config).ctx_shardings(config, mesh, kv_quant)
     s = NamedSharding(mesh, P(None, "tp", None, None, None))
     out = {"k": s, "v": s}
     if kv_quant == "int8":
@@ -415,16 +447,16 @@ def init_ring(
     holds the token at position ``ring_base[b] + r``.
     """
     c = config
-    if c.mla is not None:
-        return mla_moe.init_ring(c, batch, ring_len, dtype)
+    if block_of(c) is not None:
+        return block_of(c).init_ring(c, batch, ring_len, dtype)
     dtype = dtype or jnp.dtype(c.dtype)
     shape = (c.num_layers, c.num_kv_heads, batch, ring_len, c.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
 def ring_shardings(config: ModelConfig, mesh: Mesh) -> Cache:
-    if config.mla is not None:
-        return mla_moe.row_shardings(config, mesh)
+    if block_of(config) is not None:
+        return block_of(config).row_shardings(config, mesh)
     s = NamedSharding(mesh, P(None, "tp", None, None, None))
     return {"k": s, "v": s}
 
@@ -728,8 +760,8 @@ def prefill_impl(
     region.
     """
     c = config
-    if c.mla is not None:
-        return mla_moe.prefill_impl(
+    if block_of(c) is not None:
+        return block_of(c).prefill_impl(
             c, params, ctx_kv, tokens, slot, q_start, seq_len, embeds,
             embeds_mask, adapter_id, fresh)
     T = tokens.shape[0]
@@ -968,8 +1000,8 @@ def batch_prefill_impl(
     scratch lane (batch index B) with seq_len=0 — ffn_valid masks their
     tokens out of MoE routing and their region writes hit scratch.
     """
-    if config.mla is not None:
-        return mla_moe.batch_prefill_impl(
+    if block_of(config) is not None:
+        return block_of(config).batch_prefill_impl(
             config, params, ctx_kv, tokens, slots, q_starts, seq_lens,
             ctx_span, adapter_ids)
     ks, vs, h = _batch_forward(
@@ -1447,7 +1479,8 @@ def load_ctx_pages_impl(
                 ctx_kv[name + "_scale"], s[:, None], (0, slot, 0)
             )
         return out
-    out = {}
+    # (a region's recurrent leaves are no rows: they pass through)
+    out = {n: ctx_kv[n] for n in state_kinds(ctx_kv)}
     for name in row_kinds(ctx_kv):
         pages = cache[name][:, :, page_ids]      # [L, kvh, usable, ps, hd]
         if pool_q:

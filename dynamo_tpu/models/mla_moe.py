@@ -237,6 +237,9 @@ def row_shardings(config: ModelConfig, mesh: Mesh,
     return {ROW: NamedSharding(mesh, P(None, None, None, None, None))}
 
 
+ctx_shardings = row_shardings   # the region holds rows only
+
+
 # ---------------------------------------------------------------------------
 # Forward pieces
 

@@ -241,12 +241,26 @@ def grouped_experts(
     wg, wu, wd,            # [E, H, I], [E, H, I], [E, I, H]
     valid=None,            # [T] bool — False: routed NOWHERE (padding,
                            # garbage decode lanes); its output row is 0
+    first: int | None = None,  # a SHARE of the experts is held: wg/wu/wd
+                           # are experts first .. first + E of the ones
+                           # ``sel`` names; None: all of them
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """sum_k weights[t, k] * SwiGLU_{sel[t, k]}(x[t]) for every token, and
-    the tokens each expert received ([E] i32: what the counters read)."""
+    the tokens each expert received ([E] i32: what the counters read).
+
+    With ``first`` the layer holds one chip's share of an expert-parallel
+    deployment: ``sel`` and ``weights`` are the router's over ALL its
+    experts, a pick that lands on an expert held elsewhere is routed
+    nowhere and adds nothing, and the result is this share's part of the
+    layer's sum (what the other chips would add arrives by an exchange
+    that one chip does not run). The counts are of the held experts."""
     T, K = sel.shape
     E = (wg["q"] if isinstance(wg, dict) else wg).shape[0]
     flat = sel.reshape(T * K).astype(jnp.int32)
+    if first is not None:
+        # the same idiom as ``valid`` below: id E belongs to no group
+        flat = flat - first
+        flat = jnp.where((flat >= 0) & (flat < E), flat, E)
     if valid is not None:
         # expert id E sorts last and belongs to no group: rows past the
         # groups' total are never computed
@@ -264,7 +278,13 @@ def grouped_experts(
         jnp.arange(T * K, dtype=order.dtype))
     y = y[back].reshape(T, K, -1)
     w = weights.astype(jnp.float32)
-    if valid is not None:
+    if first is not None:
+        # picks held elsewhere, and unrouted rows, hold whatever the
+        # product left there
+        here = (flat < E).reshape(T, K)
+        y = jnp.where(here[..., None], y, 0)
+        w = jnp.where(here, w, 0.0)
+    elif valid is not None:
         # unrouted rows hold whatever the product left there
         y = jnp.where(valid[:, None, None], y, 0)
         w = jnp.where(valid[:, None], w, 0.0)

@@ -1,0 +1,522 @@
+"""The state-space + attention hybrid with routed experts on the served
+path (the ``granitemoehybrid`` block): layers of TWO KINDS in one stack,
+Mamba-2 mixers (ops/mamba2.py) and, one in a period, a NoPE GQA
+attention layer; every layer then runs softmax-routed experts, of which
+this chip may hold a SHARE, plus a shared MLP.
+
+What differs from models/llama.py and models/mla_moe.py, and how the
+engine meets it:
+
+  - A LANE'S STATE IS NOT ROWS ONLY. The attention layers keep K/V rows,
+    addressable by position, in a region of the dense geometry
+    (``{"k", "v"}: [L_attn, kvh, lanes, S, hd]``: the dense movers, the
+    ring and both attention ops are used as they are, at the attention
+    layers' ordinal). Each Mamba layer keeps, per lane, a float32 SSM
+    state [heads, d_head, d_state] and the last ``d_conv - 1`` inputs of
+    its convolution: NOT addressable by position, the same size whatever
+    the context. They live beside the rows in what ``init_ctx`` returns,
+    under names that end in ``_state`` (``llama.row_kinds`` passes over
+    them, so no mover sees them as a row): one array a Mamba layer,
+    ``lanes + 1`` wide like the region.
+  - DECODE updates them every step where the region is read-only until
+    the round's flush: the engine's round carries them through its
+    ``fori_loop``. A lane that is not live (freed, or being prefilled)
+    steps with ``dt`` = 0: its state decays by exp(0) and is fed 0, i.e.
+    it is written back bit for bit, with no select over the state.
+  - PREFILL reads a continuing chunk's state from its lane (zeros when
+    the chunk is fresh) and writes the state after the chunk's last REAL
+    position: padding runs with ``dt`` 0 and the convolution's window is
+    gathered at the real length.
+  - NO PREFIX REUSE: a page of K/V rows without the recurrent state at
+    its boundary cannot resume a prompt, so the engine turns its prefix
+    cache off for this block, and the planes that move rows only refuse
+    it by name (engine.py: ``_refuse_row_only_planes``).
+  - EXPERTS. ``logits = x W_r`` over the PUBLISHED number of experts in
+    float32; the top k logits are picked and the combine weights are the
+    softmax over those k. The grouped product (models/moe.py) is told
+    which experts it holds; a pick held elsewhere adds nothing here.
+  - The four multipliers: the embedding x ``embedding_multiplier``, each
+    sublayer's output x ``residual_multiplier``, the attention's softmax
+    scale = ``attention_multiplier``, the logits / ``logits_scaling``;
+    the head is the embedding (tied).
+
+Every function here is reached through the ``llama`` names
+(``llama.block_of``), as models/mla_moe.py is.
+"""
+from __future__ import annotations
+
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from dynamo_tpu.models.config import ModelConfig
+from dynamo_tpu.models.mla_moe import _mlp, _rms   # the same norm and SwiGLU
+from dynamo_tpu.models.moe import grouped_experts
+from dynamo_tpu.ops import mamba2
+from dynamo_tpu.ops.attention import (
+    DecodeAttention,
+    PriorContext,
+    ctx_decode_attention,
+    prefill_attention,
+)
+
+Params = dict[str, Any]
+Cache = dict[str, Any]
+
+SSM, CONV = "ssm_state", "conv_state"   # the recurrent leaves of a ctx
+
+
+def dims(c: ModelConfig) -> dict[str, Any]:
+    k = c.hybrid_dict
+    inner = k["mamba_n_heads"] * k["mamba_d_head"]
+    kinds = k["layer_types"]
+    return {
+        "kinds": kinds,
+        "n_ssm": sum(t == "mamba" for t in kinds),
+        "n_attn": sum(t == "attention" for t in kinds),
+        "nh": k["mamba_n_heads"], "P": k["mamba_d_head"],
+        "N": k["mamba_d_state"], "W": k["mamba_d_conv"],
+        "inner": inner, "conv": inner + 2 * k["mamba_d_state"],
+        "chunk": k["mamba_chunk_size"],
+        "E": k["published_experts"], "held": k["num_local_experts"],
+        "K": k["num_experts_per_tok"], "I_e": k["intermediate_size"],
+        "I_s": k["shared_intermediate_size"],
+        # the first expert held here; None: all of them, and the grouped
+        # product traces as it does without a share
+        "first": (k["share_index"] * k["num_local_experts"]
+                  if k["share_of"] > 1 else None),
+    }
+
+
+def kv_row_bytes(c: ModelConfig, itemsize: int) -> int:
+    """Bytes one token holds in the ctx region (attention layers only)."""
+    return dims(c)["n_attn"] * 2 * c.kv_dim * itemsize
+
+
+def state_bytes(c: ModelConfig, itemsize: int) -> int:
+    """Bytes one lane holds in recurrent state, whatever its context."""
+    d = dims(c)
+    return d["n_ssm"] * (d["nh"] * d["P"] * d["N"] * 4
+                         + (d["W"] - 1) * d["conv"] * itemsize)
+
+
+# ---------------------------------------------------------------------------
+# Parameters
+
+def init_params(config: ModelConfig, rng: jax.Array | int = 0) -> Params:
+    """Random parameters, normal x 1/sqrt(fan_in), and Mamba-2's own
+    initialisation of the recurrence: ``A`` uniform in 1..16, ``dt``
+    log-uniform in 0.001..0.1 (``dt_bias`` its inverse softplus), ``D``
+    1. Heads then remember over tens to thousands of positions, so a
+    state dropped at a chunk boundary changes the logits."""
+    if isinstance(rng, int):
+        rng = jax.random.PRNGKey(rng)
+    c, d = config, dims(config)
+    if c.quant is not None:
+        raise ValueError("the state-space hybrid block has no int8 weights")
+    dtype = jnp.dtype(c.dtype)
+    keys = iter(jax.random.split(rng, 4 + 16 * c.num_layers))
+
+    def rnd(*shape, scale=None):
+        scale = scale or 1.0 / np.sqrt(shape[-2])
+        return (jax.random.normal(next(keys), shape, jnp.float32)
+                * scale).astype(dtype)
+
+    H = c.hidden_size
+
+    def layer(kind):
+        lp = {
+            "ln1": jnp.ones((H,), dtype), "ln2": jnp.ones((H,), dtype),
+            "wr": rnd(H, d["E"]),
+            # the published fused input matrix [H, 2 I] as its two halves
+            "we_g": rnd(d["held"], H, d["I_e"]),
+            "we_u": rnd(d["held"], H, d["I_e"]),
+            "we_d": rnd(d["held"], d["I_e"], H),
+            "ws_g": rnd(H, d["I_s"]), "ws_u": rnd(H, d["I_s"]),
+            "ws_d": rnd(d["I_s"], H),
+        }
+        if kind == "attention":
+            lp.update(wq=rnd(H, c.q_dim), wk=rnd(H, c.kv_dim),
+                      wv=rnd(H, c.kv_dim), wo=rnd(c.q_dim, H))
+            return lp
+        u = lambda lo, hi: jax.random.uniform(  # noqa: E731
+            next(keys), (d["nh"],), jnp.float32, lo, hi)
+        dt = jnp.exp(u(np.log(1e-3), np.log(1e-1)))
+        lp.update(
+            w_in=rnd(H, 2 * d["inner"] + 2 * d["N"] + d["nh"]),
+            conv_w=rnd(d["W"], d["conv"], scale=1.0 / np.sqrt(d["W"])),
+            conv_b=rnd(d["conv"], scale=0.1),
+            A_log=jnp.log(u(1.0, 16.0)),
+            dt_bias=dt + jnp.log(-jnp.expm1(-dt)),
+            D=jnp.ones((d["nh"],), jnp.float32),
+            norm=jnp.ones((d["inner"],), dtype),
+            w_out=rnd(d["inner"], H),
+        )
+        return lp
+
+    return {
+        "embed": rnd(c.vocab_size, H, scale=1.0 / np.sqrt(H)),
+        "norm_f": jnp.ones((H,), dtype),
+        # one entry a layer, NOT stacked: the kinds differ, and a
+        # kernel's operand sliced out of a stack is a copy of it
+        "layers": [layer(kind) for kind in d["kinds"]],
+    }
+
+
+def param_shardings(config: ModelConfig, mesh: Mesh) -> Params:
+    """One chip runs a layer without its exchange: everything
+    replicated, a mesh with tp or ep > 1 refused."""
+    for axis in ("tp", "ep"):
+        if mesh.shape.get(axis, 1) > 1:
+            raise ValueError(
+                f"the state-space hybrid block is not sharded over "
+                f"{axis!r} (mesh {dict(mesh.shape)}): the chip's share of "
+                "the experts is the configuration's (expert_share), and "
+                "the exchange around it is not built")
+    shapes = jax.eval_shape(lambda: init_params(config, 0))
+    return jax.tree.map(
+        lambda x: NamedSharding(mesh, P(*([None] * x.ndim))), shapes)
+
+
+# ---------------------------------------------------------------------------
+# Cache spec: rows for the attention layers, a state for the others
+
+def _rows(c: ModelConfig, lanes: int, length: int, dtype) -> Cache:
+    shape = (dims(c)["n_attn"], c.num_kv_heads, lanes, length, c.head_dim)
+    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+
+def _refuse_quant(kv_quant: str) -> None:
+    if kv_quant != "none":
+        raise ValueError(
+            f"kv_quant={kv_quant!r}: the int8 KV plane cannot carry a "
+            "recurrent state (its scale grid is per page of rows)")
+
+
+def init_cache(config, num_pages, page_size, dtype=None, kv_quant="none"):
+    _refuse_quant(kv_quant)
+    return _rows(config, num_pages, page_size,
+                 dtype or jnp.dtype(config.dtype))
+
+
+def init_ctx(config, batch, ctx_len, dtype=None, kv_quant="none",
+             group=128):
+    _refuse_quant(kv_quant)
+    d = dims(config)
+    dtype = dtype or jnp.dtype(config.dtype)
+    ctx = _rows(config, batch + 1, ctx_len, dtype)
+    ctx[SSM] = [jnp.zeros((batch + 1, d["nh"], d["P"], d["N"]), jnp.float32)
+                for _ in range(d["n_ssm"])]
+    ctx[CONV] = [jnp.zeros((batch + 1, d["W"] - 1, d["conv"]), dtype)
+                 for _ in range(d["n_ssm"])]
+    return ctx
+
+
+def init_ring(config, batch, ring_len, dtype=None):
+    return _rows(config, batch, ring_len, dtype or jnp.dtype(config.dtype))
+
+
+def row_shardings(config: ModelConfig, mesh: Mesh,
+                  kv_quant: str = "none") -> Cache:
+    _refuse_quant(kv_quant)
+    s = NamedSharding(mesh, P(None, None, None, None, None))
+    return {"k": s, "v": s}
+
+
+def ctx_shardings(config: ModelConfig, mesh: Mesh,
+                  kv_quant: str = "none") -> Cache:
+    out = row_shardings(config, mesh, kv_quant)
+    n = dims(config)["n_ssm"]
+    out[SSM] = [NamedSharding(mesh, P(None, None, None, None))] * n
+    out[CONV] = [NamedSharding(mesh, P(None, None, None))] * n
+    return out
+
+
+# ---------------------------------------------------------------------------
+# Forward pieces
+
+def route(c: ModelConfig, lp, x):
+    """The published router: float32 logits over ALL the deployment's
+    experts, the top k logits picked, softmax over the picked."""
+    with jax.named_scope("moe_route"):
+        logits = jnp.matmul(x, lp["wr"], preferred_element_type=jnp.float32)
+        top, sel = jax.lax.top_k(logits, dims(c)["K"])
+        return sel, jax.nn.softmax(top, axis=-1)
+
+
+def stats_zero(c: ModelConfig):
+    """A step's counters before any layer: [held experts touched, picks
+    that landed on a held expert, most tokens on one held expert, all
+    picks of routed tokens]."""
+    return jnp.zeros(4, jnp.int32)
+
+
+def merge_stats(a, b):
+    return jnp.stack([a[0] + b[0], a[1] + b[1], jnp.maximum(a[2], b[2]),
+                      a[3] + b[3]])
+
+
+def _ffn(c: ModelConfig, lp, x, valid, stats):
+    """Routed experts (this chip's share) + the shared MLP, ungated."""
+    d = dims(c)
+    sel, w = route(c, lp, x)
+    y, load = grouped_experts(x, sel, w, lp["we_g"], lp["we_u"], lp["we_d"],
+                              valid, first=d["first"])
+    with jax.named_scope("moe_shared"):
+        y = y + _mlp(x, lp["ws_g"], lp["ws_u"], lp["ws_d"])
+    picks = (x.shape[0] if valid is None else valid.sum()) * d["K"]
+    return y, merge_stats(stats, jnp.stack([
+        jnp.sum(load > 0), load.sum(), load.max(),
+        jnp.asarray(picks, jnp.int32)]).astype(jnp.int32))
+
+
+def _layer_out(c: ModelConfig, lp, h, mix, valid, stats):
+    r = jnp.asarray(c.hybrid_dict["residual_multiplier"], h.dtype)
+    h = h + r * mix
+    y, stats = _ffn(c, lp, _rms(h, lp["ln2"], c.rms_norm_eps), valid, stats)
+    return h + r * y, stats
+
+
+def _embed(c: ModelConfig, params, tokens, dtype):
+    m = jnp.asarray(c.hybrid_dict["embedding_multiplier"], dtype)
+    return params["embed"][tokens].astype(dtype) * m
+
+
+def _logits(c: ModelConfig, params, h):
+    h = _rms(h, params["norm_f"], c.rms_norm_eps)
+    y = jnp.matmul(h, params["embed"].T, preferred_element_type=jnp.float32)
+    return y / c.hybrid_dict["logits_scaling"]
+
+
+def _qkv(c: ModelConfig, lp, x):
+    """No rotary. The softmax scale, ``attention_multiplier``, rides on q
+    (x sqrt(head_dim), which the attention ops divide out again): the
+    product is taken in float32 and rounded once."""
+    N = x.shape[0]
+    k = c.hybrid_dict["attention_multiplier"] * np.sqrt(c.head_dim)
+    q = ((x @ lp["wq"]).astype(jnp.float32) * k).astype(x.dtype)
+    return (q.reshape(N, c.num_heads, c.head_dim),
+            (x @ lp["wk"]).reshape(N, c.num_kv_heads, c.head_dim),
+            (x @ lp["wv"]).reshape(N, c.num_kv_heads, c.head_dim))
+
+
+def _ssm_in(c: ModelConfig, lp, x):
+    """[N, H] -> the gate z [N, inner], the convolution's input [N,
+    conv], dt [N, heads] float32 (after its bias and softplus)."""
+    d = dims(c)
+    with jax.named_scope("ssm_in_proj"):
+        zxd = x @ lp["w_in"]
+    z, xbc, dt = jnp.split(zxd, [d["inner"], d["inner"] + d["conv"]], -1)
+    return z, xbc, jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"])
+
+
+def _ssm_out(c: ModelConfig, lp, y, xs, z):
+    """``y`` [N, heads, P] float32 from the scan -> the mixer's output:
+    + D x, the gate FIRST, then the norm over all heads, then W_out."""
+    with jax.named_scope("ssm_out"):
+        y = y + lp["D"][:, None] * xs.astype(jnp.float32)
+        y = y.reshape(y.shape[0], -1) * jax.nn.silu(z.astype(jnp.float32))
+        var = jnp.mean(y * y, axis=-1, keepdims=True)
+        y = (y * jax.lax.rsqrt(var + c.rms_norm_eps)).astype(z.dtype)
+        return (y * lp["norm"]) @ lp["w_out"]
+
+
+def _split_xbc(c: ModelConfig, xbc):
+    d = dims(c)
+    xs, B, C = jnp.split(xbc, [d["inner"], d["inner"] + d["N"]], -1)
+    return xs.reshape(*xs.shape[:-1], d["nh"], d["P"]), B, C
+
+
+def _prior_rows(ctx_kv, layer: int, slots, span: int):
+    """The prior context of K continuing chunks: their lanes' rows of
+    attention layer ``layer``, SLICED out of the region into a workspace
+    ([1, kvh, K, span, hd] a kind: 16.8 MB a lane at 8192 rows of 8 heads
+    of 128) that the attention's loop then reads. Handing the region
+    itself to that loop, as the dense decoder does, makes XLA:TPU copy it
+    whole before the tail's in-place writes (the loop carries it as an
+    invariant: 553 MB a kind here, compile-only v5e, PR 41). None when
+    ``span`` is 0 (every chunk fresh)."""
+    if not span:
+        return None
+    K = slots.shape[0]
+
+    def lanes(buf):
+        size = (1, buf.shape[1], 1, span, buf.shape[4])
+        return jnp.concatenate([
+            jax.lax.dynamic_slice(buf, (layer, 0, slots[i], 0, 0), size)
+            for i in range(K)], axis=2)
+
+    return PriorContext(lanes(ctx_kv["k"]), lanes(ctx_kv["v"]),
+                        jnp.int32(0), jnp.arange(K, dtype=jnp.int32))
+
+
+def _refuse_adapters(params):
+    if params.get("adapters") is not None:
+        raise ValueError("the state-space hybrid block carries no LoRA bank")
+
+
+# ---------------------------------------------------------------------------
+# Prefill
+
+def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
+                       seq_lens, ctx_span=0, adapter_ids=None):
+    """K chunks [K, T] through the model in one program. The attention
+    layers' rows land in each lane's region at [q_start, q_start + T) and
+    the Mamba layers' states in the lane, in one tail pass after every
+    read. ``ctx_span`` 0: every chunk is fresh, and neither the region
+    nor a lane's state is read. Else a chunk with q_start > 0 starts
+    from its lane's state as it attends its lane's rows; one with
+    q_start 0 starts from zeros."""
+    c, d = config, dims(config)
+    _refuse_adapters(params)
+    K, T = tokens.shape
+    cdt = ctx_kv["k"].dtype
+    positions = q_starts[:, None] + jnp.arange(T, dtype=jnp.int32)
+    real = positions < seq_lens[:, None]                      # [K, T]
+    valid = real.reshape(K * T)
+    n_real = jnp.clip(seq_lens - q_starts, 0, T)
+    span = min(ctx_span, ctx_kv["k"].shape[3])
+    continuing = q_starts > 0
+    h = _embed(c, params, tokens.reshape(K * T), cdt)
+    stats = stats_zero(c)
+    ks, vs, ssm_out, conv_out = [], [], [], []
+    A = lambda lp: -jnp.exp(lp["A_log"])  # noqa: E731
+    for kind, lp in zip(d["kinds"], params["layers"]):
+        x = _rms(h, lp["ln1"], c.rms_norm_eps)
+        if kind == "attention":
+            with jax.named_scope("nope_attn"):
+                q, k, v = (a.reshape(K, T, *a.shape[1:])
+                           for a in _qkv(c, lp, x))
+                prior = _prior_rows(ctx_kv, len(ks), slots, span)
+                ks.append(k)
+                vs.append(v)
+                o = prefill_attention(q, k, v, q_starts, seq_lens, prior,
+                                      ctx_span=span)
+                mix = o.reshape(K * T, c.q_dim) @ lp["wo"]
+        else:
+            j = len(ssm_out)
+            z, xbc, dt = _ssm_in(c, lp, x)
+            # padding neither decays nor feeds the state
+            dt = jnp.where(real[..., None], dt.reshape(K, T, -1), 0.0)
+            if span:
+                keep = continuing[:, None, None]
+                win0 = jnp.where(keep, ctx_kv[CONV][j][slots], 0)
+                S0 = jnp.where(keep[..., None], ctx_kv[SSM][j][slots], 0.0)
+            else:
+                win0 = jnp.zeros((K, d["W"] - 1, d["conv"]), cdt)
+                S0 = jnp.zeros((K, d["nh"], d["P"], d["N"]), jnp.float32)
+            with jax.named_scope("ssm_conv"):
+                xbc, win = jax.vmap(
+                    lambda a, w0, n: mamba2.causal_conv(
+                        a, w0, lp["conv_w"], lp["conv_b"], n)
+                )(xbc.reshape(K, T, -1), win0, n_real)
+            xs, Bm, Cm = _split_xbc(c, xbc)
+            with jax.named_scope("ssm_scan"):
+                y, S = jax.vmap(
+                    lambda xs, dt, Bm, Cm, S0: mamba2.chunk_scan(
+                        xs, dt, A(lp), Bm, Cm, S0, d["chunk"])
+                )(xs, dt, Bm, Cm, S0)
+            ssm_out.append(S)
+            conv_out.append(win)
+            mix = _ssm_out(c, lp, y.reshape(K * T, d["nh"], d["P"]),
+                           xs.reshape(K * T, d["nh"], d["P"]), z)
+        h, stats = _layer_out(c, lp, h, mix, valid, stats)
+
+    # tail: every read is done. Rows as spans, states as whole lanes
+    rows = {"k": jnp.stack(ks, 1).astype(cdt),      # [K, L_attn, T, kvh, hd]
+            "v": jnp.stack(vs, 1).astype(cdt)}
+
+    def write_lane(i, out):
+        out = dict(out)
+        for name, r in rows.items():
+            r = jax.lax.dynamic_index_in_dim(r, i, keepdims=False)
+            out[name] = jax.lax.dynamic_update_slice(
+                out[name], r.transpose(0, 2, 1, 3)[:, :, None],
+                (0, 0, slots[i], q_starts[i], 0))
+        for name, new in ((SSM, ssm_out), (CONV, conv_out)):
+            out[name] = [
+                jax.lax.dynamic_update_slice(
+                    buf, jax.lax.dynamic_index_in_dim(s, i, keepdims=True),
+                    (slots[i],) + (0,) * (buf.ndim - 1))
+                for buf, s in zip(out[name], new)]
+        return out
+
+    out_ctx = jax.lax.fori_loop(0, K, write_lane, dict(ctx_kv))
+    last = jnp.maximum(seq_lens - q_starts - 1, 0)
+    h_last = jnp.take_along_axis(
+        h.reshape(K, T, -1), last[:, None, None], axis=1)[:, 0]
+    return out_ctx, _logits(c, params, h_last)
+
+
+def prefill_impl(config, params, ctx_kv, tokens, slot, q_start, seq_len,
+                 embeds=None, embeds_mask=None, adapter_id=None,
+                 fresh=False):
+    """One chunk: the K = 1 case of the batched program."""
+    if embeds is not None:
+        raise ValueError("the state-space hybrid block takes no embedding "
+                         "overrides (multimodal)")
+    one = lambda x: jnp.asarray(x, jnp.int32)[None]  # noqa: E731
+    ctx_kv, logits = batch_prefill_impl(
+        config, params, ctx_kv, tokens[None], one(slot), one(q_start),
+        one(seq_len), 0 if fresh else ctx_kv["k"].shape[3])
+    return ctx_kv, logits[0]
+
+
+# ---------------------------------------------------------------------------
+# Decode
+
+def decode_step_impl(config, params, ctx_kv, ring, state, tokens, ctx_lens,
+                     ring_base, ring_pos, live, *, attn: DecodeAttention):
+    """One decode step for all slots: (ring, state, logits [B, vocab],
+    stats). The attention layers' new rows land in ring slot
+    ``ring_pos`` and the region is read-only, as in the dense decoder;
+    ``state`` (``{SSM: [...], CONV: [...]}``, lanes + 1 wide) comes back
+    moved on by one position for the lanes that are ``live`` and bit for
+    bit as it was for the others."""
+    c, d = config, dims(config)
+    _refuse_adapters(params)
+    B = tokens.shape[0]
+    h = _embed(c, params, tokens, ctx_kv["k"].dtype)
+    stats = stats_zero(c)
+    ring = dict(ring)
+    ssm, conv = list(state[SSM]), list(state[CONV])
+    # the scratch lane rides along as one more row that never moves
+    pad = lambda a: jnp.pad(a, ((0, 1),) + ((0, 0),) * (a.ndim - 1))  # noqa: E731
+    a = j = 0
+    for kind, lp in zip(d["kinds"], params["layers"]):
+        x = _rms(h, lp["ln1"], c.rms_norm_eps)
+        if kind == "attention":
+            with jax.named_scope("nope_attn"):
+                q, k, v = _qkv(c, lp, x)
+                for name, new in (("k", k), ("v", v)):
+                    ring[name] = jax.lax.dynamic_update_slice(
+                        ring[name],
+                        new.transpose(1, 0, 2)[None, :, :, None, :].astype(
+                            ring[name].dtype), (a, 0, 0, ring_pos, 0))
+                o = ctx_decode_attention(
+                    attn, q, ctx_kv["k"], ctx_kv["v"], ring["k"], ring["v"],
+                    jnp.int32(a), ctx_lens, ring_base)
+                mix = o.reshape(B, c.q_dim) @ lp["wo"]
+            a += 1
+        else:
+            z, xbc, dt = _ssm_in(c, lp, x)
+            with jax.named_scope("ssm_conv"):
+                # over all lanes + 1 rows, so that the window is
+                # rewritten whole, in place
+                xbc, win = mamba2.conv_step(
+                    pad(xbc), conv[j], lp["conv_w"], lp["conv_b"])
+                xbc = xbc[:B]
+                conv[j] = jnp.where(pad(live)[:, None, None], win, conv[j])
+            xs, Bm, Cm = _split_xbc(c, xbc)
+            with jax.named_scope("ssm_scan"):
+                # dt 0: exp(0) S + 0, the state as it was
+                y, ssm[j] = mamba2.scan_step(
+                    pad(xs), pad(jnp.where(live[:, None], dt, 0.0)),
+                    -jnp.exp(lp["A_log"]), pad(Bm), pad(Cm), ssm[j])
+            mix = _ssm_out(c, lp, y[:B], xs, z)
+            j += 1
+        h, stats = _layer_out(c, lp, h, mix, live, stats)
+    return ring, {SSM: ssm, CONV: conv}, _logits(c, params, h), stats

@@ -427,6 +427,14 @@ MOE_ROUTED = ("dynamo_moe_tokens_routed",
 MOE_LOAD_MAX = ("dynamo_moe_expert_load_max",
                 "most tokens one expert received in one step of one layer "
                 "of a consumed decode round")
+MOE_PICKS_ROUTED = ("dynamo_moe_picks_routed",
+                    "models that hold a share of the experts: all (token, "
+                    "pick) pairs the router made for live lanes in a "
+                    "consumed decode round, held here or elsewhere")
+SSM_STATE_BYTES = ("dynamo_ssm_state_bytes",
+                   "bytes one lane holds in recurrent (state-space) state, "
+                   "all layers, whatever its context (observed once, at "
+                   "engine start)")
 HC_SINKHORN_RESIDUAL = (
     "dynamo_hc_sinkhorn_residual",
     "hyper-connection models: max over a consumed decode round's tokens "
@@ -469,6 +477,7 @@ def request_histograms(
         for name, help_ in (PREFILL_TOKENS, PREFILL_PADDED, PREFILL_MATCHED,
                             ROUND_LIVE_LANE_STEPS, ROUND_TOKENS,
                             MOE_TOUCHED, MOE_ROUTED, MOE_LOAD_MAX,
+                            MOE_PICKS_ROUTED,
                             PREFILL_CONTINUED):
             reg.histogram(name, help_, TOKEN_BUCKETS)
         reg.histogram(*HC_SINKHORN_RESIDUAL,
@@ -477,6 +486,8 @@ def request_histograms(
             reg.histogram(name, help_,
                           tuple(float(4 ** i) for i in range(3, 13)))
         reg.histogram(*KV_ROW_BYTES, tuple(float(4 ** i) for i in range(3, 12)))
+        reg.histogram(*SSM_STATE_BYTES,
+                      tuple(float(4 ** i) for i in range(6, 15)))
         for name, help_ in (PREFILL_ATTN_LIVE, PREFILL_ATTN_SCORED):
             reg.histogram(name, help_, PAIR_BUCKETS)
     return reg

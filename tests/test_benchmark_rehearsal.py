@@ -44,24 +44,133 @@ def checkout_at_rate(tmp_path, cell, rate_rps):
     return root
 
 
+def _benchmark_tests():
+    """(file, function) of every test under ``benchmarks/``, read from the
+    text (importing them here would import the harness)."""
+    import ast
+
+    found = []
+    for name in sorted(os.listdir(os.path.join(REPO, "benchmarks"))):
+        if name.startswith("test_") and name.endswith(".py"):
+            with open(os.path.join(REPO, "benchmarks", name)) as f:
+                found += [(name[:-3], node.name)
+                          for node in ast.parse(f.read()).body
+                          if isinstance(node, ast.FunctionDef)
+                          and node.name.startswith("test_")]
+    return found
+
+
+@pytest.fixture(scope="module")
+def benchmarks_own_run(tmp_path_factory):
+    """``pytest benchmarks`` as a builder types it, ONCE, nothing left
+    out: the outcome of every case by (file, function)."""
+    import xml.etree.ElementTree as ET
+
+    xml = str(tmp_path_factory.mktemp("bench") / "run.xml")
+    r = run([sys.executable, "-m", "pytest", "benchmarks", "-q",
+             "-p", "no:cacheprovider", "-p", "no:xdist",
+             "--junitxml", xml], 180)
+    assert os.path.exists(xml), (r.stdout + r.stderr)[-3000:]
+    ran: dict = {}
+    for case in ET.parse(xml).getroot().iter("testcase"):
+        key = (case.get("classname").rsplit(".", 1)[-1],
+               case.get("name").split("[", 1)[0])
+        bad = [c.get("message", "")[:600] for c in case
+               if c.tag in ("failure", "error")]
+        ran.setdefault(key, []).append((case.get("name"), bad))
+    return ran, (r.stdout + r.stderr)[-3000:]
+
+
+@pytest.mark.parametrize("file,function", _benchmark_tests(),
+                         ids=lambda v: v)
+def test_the_benchmarks_own_tests_pass(benchmarks_own_run, file, function):
+    """One tier-1 case a test function of ``benchmarks/``, so that one that
+    fails stands red under its own name and hides no other. RED SINCE PR 41,
+    for the next ``benchmark`` issue (PERF.md section 7 (a)):
+    ``test_contract``'s two cases that draw their example from
+    ``OPEN_UNDER_KNEE``, the alphabetically first open-loop cell, which is
+    ``granite4h-ep2-d10.ragdoc`` now and not the cell whose two servers the
+    example is (``mistral7b-w8.chat``); a ``model_config`` PR may edit no
+    file of the benchmark. What they show is held below, by the cell's name."""
+    ran, tail = benchmarks_own_run
+    cases = ran.get((file, function))
+    assert cases, f"{file}::{function} did not run\n{tail}"
+    failed = {name: bad for name, bad in cases if bad}
+    assert not failed, failed
+
+
 @pytest.mark.parametrize("cmd,limit_s", [
-    ([sys.executable, "-m", "pytest", "benchmarks", "-q",
-      "-p", "no:cacheprovider", "-p", "no:xdist"], 120),
     ([sys.executable, "-m", "benchmarks.trace_reduce", "--selftest"], 120),
     ([sys.executable, "benchmarks/stats.py", "--selftest"], 60),
-], ids=["pytest-benchmarks", "trace_reduce-selftest", "stats-selftest"])
+], ids=["trace_reduce-selftest", "stats-selftest"])
 def test_the_benchmarks_own_checks_pass(cmd, limit_s):
     r = run(cmd, limit_s)
     assert r.returncode == 0, (r.stdout + r.stderr)[-3000:]
 
 
+@pytest.mark.parametrize("cell,entry,shown,registered_agrees", [
+    ("mistral7b-w8.chat", "gen.carried_tok_s", "lower", True),
+    ("mistral7b-w8.longprompt", "gen.carried_tok_s.longprompt-open",
+     "lower", True),
+    # accepted entries that say ``lower`` where the two servers show
+    # ``higher``: theirs to repair is a ``benchmark`` issue's (PERF.md 7 (a))
+    ("mla-moe-joyai-d5.chat-decode", "gen.carried_tok_s.chat-decode-open",
+     "higher", False),
+    ("xing4-mhc-d7.longdoc", "gen.carried_tok_s.longdoc-open", "higher",
+     False),
+    ("granite4h-ep2-d10.ragdoc", "gen.carried_tok_s.ragdoc-open", "higher",
+     True),
+], ids=["chat", "longprompt", "chat-decode", "longdoc", "ragdoc"])
+def test_carried_tok_s_reads_the_way_its_entry_says(monkeypatch, cell, entry,
+                                                     shown, registered_agrees):
+    """``benchmarks/test_contract.py``'s two servers (cell 1's pace before
+    and after PR 26) on each open-loop cell's OWN schedule, the cell given
+    by name. Where the answers are short beside the pre-roll, the backlog
+    carried INTO the window decides: both read above zero and the faster
+    server the LOWER (the contract's example, asserted on cell 1 as that
+    file writes it). Where the longest answers outlast the pre-roll
+    (``ragdoc-open``: 384 tokens are 17 s at the slow pace, the pre-roll 6),
+    what is carried OUT past the window's end decides: both read below
+    zero and the faster server the HIGHER."""
+    import importlib
+
+    contract = importlib.import_module("benchmarks.test_contract")
+    monkeypatch.setattr(contract, "OPEN_UNDER_KNEE", cell)
+    read = contract._load("reader_" + entry, "layer_metrics",
+                          entry + ".py").read
+    byname = contract._load("bench_byname", "byname.py")
+    carried = {}
+    for name, server in (("slow", contract.SLOW), ("fast", contract.FAST)):
+        log = contract.open_log(server)
+        gen = contract.stats.reduce_log(log, contract.SECONDS)
+        carried[name] = read({"gen": gen, "log": log, "byname": byname,
+                              "seconds": contract.SECONDS})
+        assert carried[name] == pytest.approx(
+            gen["tok_s"] - contract.offered_tok_s(log))
+    if shown == "lower":
+        assert 0 < carried["fast"] < carried["slow"]
+    else:
+        assert carried["slow"] < carried["fast"] < 0
+    assert (contract.PER_LAYER[entry]["better"] == shown) is registered_agrees
+    if cell == "mistral7b-w8.chat":
+        contract.test_open_loop_under_the_knee_the_faster_server_reads_the_lower_tok_s()
+        contract.test_carried_tok_s_reader_is_tok_s_less_the_windows_own_tokens()
+
+
 @pytest.mark.parametrize("cell,seed,reference,rate_rps", [
-    (NEW_CELL, "3100310031", "benchmarks/references/mla_moe.py", None),
+    # 14.4 req/s is sized for the chip: ~170 streams of up to 512 tokens
+    # in 12 s. Alone the CPU holds that; under the six workers of the
+    # driver's run it did not (PR 40's run of the standing tree: streams
+    # outlasted the drain grace, ``failed`` > 0). A third of it, as the
+    # other rehearsals run at a rate the CPU holds
+    (NEW_CELL, "3100310031", "benchmarks/references/mla_moe.py", 4.0),
     ("xing4-mhc-d7.longdoc", "3700370037",
      "benchmarks/references/mla_moe_mhc.py", 0.34),
     ("mistral7b-w8.longprompt", "3700370038", "benchmarks/reference.py",
      None),
-], ids=["chat-decode", "longdoc", "longprompt"])
+    ("granite4h-ep2-d10.ragdoc", "4100410041",
+     "benchmarks/references/ssm_moe.py", 1.0),
+], ids=["chat-decode", "longdoc", "longprompt", "ragdoc"])
 def test_the_new_cell_rehearses_on_the_cpu(tmp_path, cell, seed, reference,
                                            rate_rps):
     """A cell's files end to end at the dry-run widths: configuration,

@@ -112,6 +112,8 @@ def test_registry_render_and_snapshot():
         "dynamo_moe_expert_load_max", "dynamo_kv_row_bytes",
         "dynamo_hc_sinkhorn_residual", "dynamo_prefill_continued_tokens",
         "dynamo_decode_attn_rows_read", "dynamo_decode_attn_rows_live",
+        "dynamo_moe_picks_routed",
+        "dynamo_ssm_state_bytes",
     }
     reg.get("dynamo_request_ttft_seconds").observe(0.2)
     text = reg.render()

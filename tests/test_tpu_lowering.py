@@ -339,6 +339,62 @@ def test_long_context_latent_cell_keeps_four_prefill_programs():
                                 (4096, 1, False), (4096, 1, True)]
 
 
+# the state-space hybrid cell's programs: what each may hold in XLA's
+# temporaries (compiled, PR 41: the round 0.033 GB, a 4096-token chunk
+# 1.014 GB, fresh or continuing) with a little room. 12.3 GB of weights,
+# rows and recurrent state leave the chip ~3 GB
+HYBRID_TEMP_CEILING = {"round_seal": 0.1e9, "batch_prefill_cont": 1.1e9}
+
+
+@pytest.fixture(scope="module", params=sorted(HYBRID_TEMP_CEILING))
+def hybrid_record(request):
+    """The fused round and a continuing ``[1, 4096]`` prefill of the
+    state-space hybrid cell at its published widths (10 layers, region
+    ``[1, 8, 33, 8192, 128]``, nine ``[33, 128, 64, 128]`` float32 states),
+    compiled by XLA:TPU and Mosaic for a compile-only v5e (~15-35 s)."""
+    _v5e_or_skip()
+    with jax.default_matmul_precision("default"):
+        (rec,) = tpu_compile_check.compile_programs(
+            config="granite4h-ep2-d10", programs=(request.param,),
+            prefill_width=4096)
+    return request.param, rec
+
+
+def test_hybrid_programs_copy_neither_the_state_nor_the_region(hybrid_record):
+    """The recurrent state is rewritten in place by every decode step and
+    written a lane at a prefill chunk's end; the region is read-only in
+    the round and read through a sliced workspace by a continuing chunk:
+    no ``copy`` the size of the region (553 MB a kind) or of one layer's
+    float32 state (138 MB, 1.26 GB over nine), and temporaries that leave
+    the chip its room."""
+    name, rec = hybrid_record
+    assert rec["ok"], rec.get("error")
+    assert rec["region_shard"] == [1, 8, 33, 8192, 128]
+    assert rec["region_copies"] == {"count": 0, "shapes": []}, rec
+    assert rec["temp_bytes"] < HYBRID_TEMP_CEILING[name], rec["temp_gb"]
+    assert rec["argument_gb"] < 12.5
+
+
+def test_hybrid_cell_keeps_four_prefill_programs():
+    """As the long-context latent cell: 2 buckets x 1 lane x {fresh,
+    continuing} whole-model prefill programs beside the round's two."""
+    import json
+
+    from dynamo_tpu.engine.config import EngineConfig
+
+    with open(os.path.join(os.path.dirname(tpu_compile_check.__file__), "..",
+                           "benchmarks", "configs",
+                           "granite4h-ep2-d10.json")) as f:
+        e = EngineConfig(**json.load(f)["engine"])
+    assert (e.max_decode_slots, e.max_context) == (32, 8192)
+    programs = {(T, e.prefill_lanes(T, group), continuing)
+                for T in e.prefill_buckets
+                for group in range(1, e.prefill_chunks_per_round + 1)
+                for continuing in (False, True)}
+    assert sorted(programs) == [(2048, 1, False), (2048, 1, True),
+                                (4096, 1, False), (4096, 1, True)]
+
+
 # ``lowered_sha256`` (tools/tpu_compile_check.py: the StableHLO text before
 # the compiler, Mosaic bodies masked) of the programs that share code with
 # the continuing latent chunk and must NOT move with it: every program

@@ -1,14 +1,19 @@
 #!/usr/bin/env python
-"""The reference check of the latent-attention + routed-expert cell, and
-its CONTROLS, in one process on the chip.
+"""The reference check of a routed-expert cell, and its CONTROLS, in one
+process on the chip: ``--config`` names the configuration (the latent
+block's by default; ``granite4h-ep2-d10`` for the state-space hybrid).
 
 Builds the engine exactly as the benchmark's launcher does
 (``benchmarks/server.py``: same configuration file, weights from the
-seed) and runs the launcher's own ``check_against_reference`` against
-``benchmarks/references/mla_moe.py``: once as it stands (sound: has to
-pass), then with the reference computing what a faulty program would
-(a control has to FAIL the check, by a limit or by the reference's
-refusal, or the check does not catch a program that computes that way):
+seed) and runs the launcher's own ``check_against_reference`` against the
+configuration's reference: once as it stands (sound: has to pass), then
+with the reference computing what a faulty program would (``control=``;
+a control has to FAIL the check, by a limit or by the reference's refusal,
+or the check does not catch a program that computes that way). Which
+controls: the reference's own ``CONTROLS_REQUIRED`` (each has to fail) and
+``CONTROLS_NAMED`` (reported whichever way they read) where it states
+them, as ``references/ssm_moe.py`` does beside what each computes; for
+``references/mla_moe.py``, which states none, this file's:
 
   fp8           every matmul operand in float8_e4m3fn (MEAN)
   lane_swap     one compared position answers with its neighbour's
@@ -19,22 +24,28 @@ refusal, or the check does not catch a program that computes that way):
   experts_int8  routed experts ROUNDED to 8   } both read inside the
                 bits, held in bfloat16        } band sound seeds span
 
-The engine generates ONCE; every check after the first replays its
-outputs, so all controls are held against the same tokens and log-probs.
-One JSON line per check. Exit code 1 if the sound check fails or one of
-the first three controls passes; else 4 while one of the two named
-controls still passes (the hole PERF.md section 7 item 2 describes: it
-takes a comparison that is told the program's picks); 0 only when every
-control fails.
+The engine is built once and generates ONCE a seed; every check after the
+first replays its outputs, so all controls are held against the same
+tokens and log-probs. With several ``--seeds`` the weights are drawn anew
+for each (the old ones dropped first: two copies do not fit the chip) and
+the first ``--controls-on`` of them also run the controls. One JSON line a
+(seed, control), also appended to ``chiprun_out/<reference>_control.jsonl``.
+Exit code 1 if a sound check fails or a required control passes; else 4
+while a named control still passes (a hole the records describe: PERF.md
+section 7 item 2 for the latent block's two, the hybrid configuration's
+``assumed`` for ``state_bf16``); 0 only when every control fails.
 
-  chiprun -- python3 tools/mla_moe_control.py --seed 3100310031
-  python3 tools/mla_moe_control.py --seed 1 --dry-run     # tiny, CPU
+  chiprun -- python3 tools/mla_moe_control.py --seeds 3100310031
+  chiprun --timeout 3000 -- python3 tools/mla_moe_control.py \
+      --config granite4h-ep2-d10 --seeds 4100410001 ... --controls-on 4
+  python3 tools/mla_moe_control.py --seeds 1 --dry-run     # tiny, CPU
 """
 from __future__ import annotations
 
 import argparse
 import asyncio
 import functools
+import gc
 import json
 import os
 import sys
@@ -84,40 +95,65 @@ def experts_held_in_8_bits(params: dict) -> dict:
 
 
 async def main(args) -> int:
+    import jax
+
     import server  # benchmarks/server.py
+    from dynamo_tpu.models import llama
 
     cfg = server.load_config(
         os.path.join(REPO, "benchmarks", "configs", args.config + ".json"),
         args.dry_run)
     reference = server.reference_for(cfg)
-    engine = server.build_engine(cfg, args.seed, args.dry_run)
-    replay = Replay(engine)
-    ok, unseen = True, False
-    for control in (None,) + REQUIRED + NAMED:
-        stored = control == "experts_int8_stored"
-        replay.rewind(experts_held_in_8_bits(engine.params) if stored
-                      else None)
-        ref = reference if control is None or stored else dict(
-            reference, logprobs=functools.partial(
-                reference["logprobs"], control=control))
-        rec = {"control": control, "seed": args.seed}
-        try:
-            verdict = await server.check_against_reference(
-                replay, cfg, args.seed, ref)
-        except ValueError as e:
-            verdict = {"ok": False, "refused": str(e)}
-        else:
-            rec["failed_by"] = [n for n, got, tol in (
-                ("max", verdict["max_abs_logprob_diff"], verdict["tol_max"]),
-                ("mean", verdict["mean_abs_logprob_diff"],
-                 verdict["tol_mean"])) if got > tol]
-        print(json.dumps({**rec, **verdict}), flush=True)
-        if control is None:
-            ok &= verdict["ok"]
-        elif control in REQUIRED:
-            ok &= not verdict["ok"]
-        else:
-            unseen |= verdict["ok"]
+    stated = reference["logprobs"].__globals__
+    required = tuple(stated.get("CONTROLS_REQUIRED", REQUIRED))
+    named = tuple(stated.get("CONTROLS_NAMED", NAMED))
+    engine = server.build_engine(cfg, args.seeds[0], args.dry_run)
+    draw = jax.jit(
+        lambda key: llama.init_params(engine.config, key),
+        out_shardings=llama.param_shardings(engine.config, engine.mesh))
+    out_path = os.path.join(
+        REPO, "chiprun_out", os.path.basename(reference["file"])[:-3]
+        + "_control.jsonl")
+    os.makedirs(os.path.dirname(out_path), exist_ok=True)
+    ok, unseen, replay = True, False, None
+    for n, seed in enumerate(args.seeds):
+        if n:
+            # nothing may still hold the old weights when the new are drawn
+            engine.params = replay = None
+            gc.collect()
+            engine.params = draw(jax.random.PRNGKey(seed % (2 ** 31)))
+        replay = Replay(engine)
+        controls = (None,) + (required + named if n < args.controls_on
+                              else ())
+        for control in controls:
+            stored = control == "experts_int8_stored"
+            replay.rewind(experts_held_in_8_bits(engine.params) if stored
+                          else None)
+            ref = reference if control is None or stored else dict(
+                reference, logprobs=functools.partial(
+                    reference["logprobs"], control=control))
+            rec = {"control": control, "seed": seed}
+            try:
+                verdict = await server.check_against_reference(
+                    replay, cfg, seed, ref)
+            except ValueError as e:
+                verdict = {"ok": False, "refused": str(e)}
+            else:
+                rec["failed_by"] = [name for name, got, tol in (
+                    ("max", verdict["max_abs_logprob_diff"],
+                     verdict["tol_max"]),
+                    ("mean", verdict["mean_abs_logprob_diff"],
+                     verdict["tol_mean"])) if got > tol]
+            line = json.dumps({**rec, **verdict})
+            print(line, flush=True)
+            with open(out_path, "a") as f:
+                f.write(line + "\n")
+            if control is None:
+                ok &= verdict["ok"]
+            elif control in required:
+                ok &= not verdict["ok"]
+            else:
+                unseen |= verdict["ok"]
     await engine.stop()
     if args.dry_run:
         return 0
@@ -127,6 +163,8 @@ async def main(args) -> int:
 if __name__ == "__main__":
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--config", default="mla-moe-joyai-d5")
-    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seeds", "--seed", type=int, nargs="+", required=True)
+    ap.add_argument("--controls-on", type=int, default=1,
+                    help="how many of the first seeds also run the controls")
     ap.add_argument("--dry-run", action="store_true")
     sys.exit(asyncio.run(main(ap.parse_args())))

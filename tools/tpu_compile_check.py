@@ -168,6 +168,11 @@ def compile_programs(model_config: str = "llama3_1b", tp: int = 1,
 
     region_shard, itemsize = largest_shard(ctx)
     pool_shard, _ = largest_shard(pool)
+    # a model with recurrent layers: one layer's float32 state, all
+    # lanes, is the other buffer no program may copy whole (the few-MB
+    # convolution windows beside it are left out)
+    state_shards = [shard for shard, width in (
+        largest_shard(ctx[n]) for n in llama.state_kinds(ctx)) if width == 4]
 
     # the round AS THE ENGINE BUILDS IT: its jits close over these three
     # attributes and nothing else of an engine
@@ -233,7 +238,8 @@ def compile_programs(model_config: str = "llama3_1b", tp: int = 1,
             c, params, ctx, i32(K, T), i32(K), i32(K), i32(K), S, i32(K),
         ),
     }
-    if c.mla is not None:  # its step is in the round; no page transfer
+    if llama.block_of(c) is not None:
+        # its step is in the round; no page transfer
         for name in ("decode_step", "gather_pages", "scatter_pages"):
             del table[name]
     unknown = sorted(set(programs) - set(table))
@@ -269,7 +275,9 @@ def compile_programs(model_config: str = "llama3_1b", tp: int = 1,
         else:
             mem = compiled.memory_analysis()
             text = compiled.as_text()
-            copies = region_copies(text, region_shard, pool_shard)
+            copies = region_copies(text, region_shard, pool_shard) + [
+                found for found in region_copies(text, *state_shards)
+                if found.startswith("f32[")]
             rec.update(
                 ok=True,
                 argument_gb=round(mem.argument_size_in_bytes / 1e9, 3),
