@@ -350,8 +350,6 @@ class TpuEngine:
         # decode step, which also returns the routing counters
         self._block = llama.block_of(model_config)
         if self._block is not None:
-            # (the latent block's decode attention is ops/latent_decode.py,
-            # in XLA on every platform; decode_attn goes unused there)
             self._refuse_row_only_planes(self.ecfg, on_dispatch,
                                          draft_config)
         dev0 = self.mesh.devices.flat[0]
@@ -359,8 +357,7 @@ class TpuEngine:
             "engine devices: platform=%s device_kind=%s mesh=%s "
             "decode_attention=%s",
             dev0.platform, dev0.device_kind, dict(self.mesh.shape),
-            ("latent rows, XLA" if model_config.mla is not None
-             else self.decode_attn.impl),
+            self.decode_attn.impl,
         )
         self.on_metrics = on_metrics
         # multihost leader hook: every device dispatch is broadcast to the
@@ -837,7 +834,7 @@ class TpuEngine:
                 elif routed:
                     ring, logits, st = mla_moe.decode_step_impl(
                         c, params, ctx_kv, ring, dev["tokens"], dev["ctx"],
-                        ring_base, s, live,
+                        ring_base, s, live, attn=self.decode_attn,
                     )
                     moe_stats = mla_moe.merge_stats(moe_stats, st)
                 else:
@@ -3666,13 +3663,19 @@ class TpuEngine:
     def _observe_decode_attn_rows(self, active, n_steps: int) -> None:
         """One observation per dispatched round of the region rows its
         latent decode attention read and the rows that were some lane's
-        own — the host's mirror of latent_decode_attention's trip count:
-        every lane reads up to the LONGEST lane's rows, in whole chunks
-        (the region's rows lie below the round's ring base, ctx - 1)."""
+        own — the host's mirror of latent_decode_attention's trip counts
+        (``latent_decode.region_trips``, which its wrapper calls too):
+        each dispatched lane's rows in whole chunks under the kernel,
+        every lane to the longest of them under the XLA loop (the
+        region's rows lie below the round's ring base, ctx - 1)."""
         base = np.maximum(self._ctx_disp - 1, 0)
-        chunk = min(latent_decode.CHUNK, self.ecfg.max_context)
-        longest = -(-int(base.max()) // chunk) * chunk
-        self._h_attn_rows_read.observe(n_steps * self._B * longest)
+        live = np.zeros(self._B, bool)
+        live[active] = True
+        attn = self.decode_attn
+        cb = latent_decode.chunk_rows(self.ecfg.max_context, attn.chunk)
+        trips = latent_decode.region_trips(base, live, cb)
+        self._h_attn_rows_read.observe(
+            n_steps * latent_decode.region_rows_read(attn.impl, trips, cb))
         self._h_attn_rows_live.observe(n_steps * int(base[active].sum()))
 
     def _free_slot(self) -> Optional[int]:

@@ -65,6 +65,7 @@ from dynamo_tpu.models.moe import grouped_experts
 from dynamo_tpu.ops import hyper_connection as hc
 from dynamo_tpu.ops.attention import (
     PREFILL_BLOCK,
+    DecodeAttention,
     PriorContext,
     prefill_attention,
 )
@@ -610,12 +611,14 @@ def prefill_impl(config, params, ctx_kv, tokens, slot, q_start, seq_len,
 # Decode
 
 def decode_step_impl(config, params, ctx_kv, ring, tokens, ctx_lens,
-                     ring_base, ring_pos, live=None, adapter_ids=None):
+                     ring_base, ring_pos, live=None, adapter_ids=None, *,
+                     attn: DecodeAttention):
     """One decode step for all slots: (ring, logits [B, vocab], stats
     i32, ``stats_zero``'s layout). The new token's row lands in ring slot
     ``ring_pos``; attention is absorbed, over the region's rows below
-    ring_base and the ring's above. The region is read-only here
-    (llama.init_ring)."""
+    ring_base and the ring's above, by the implementation ``attn`` names
+    (ops/latent_decode.py); a lane that is not ``live`` reads no region
+    row. The region is read-only here (llama.init_ring)."""
     c, d = config, dims(config)
     _refuse_adapters(params)
     positions = jnp.maximum(ctx_lens - 1, 0)
@@ -631,8 +634,8 @@ def decode_step_impl(config, params, ctx_kv, ring, tokens, ctx_lens,
                 buf, row.astype(buf.dtype)[None, None, :, None, :],
                 (l, 0, 0, ring_pos, 0))
             o_lat = latent_decode_attention(
-                _absorb_q(c, lp, q_nope, q_rope), ctx_kv[ROW], buf,
-                jnp.int32(l), ctx_lens, ring_base, d["kv_rank"])
-            attn = _unabsorb_o(c, lp, o_lat)
-        h, stats = _layer_out(c, params, lp, l, h, attn, mix, live, stats)
+                attn, _absorb_q(c, lp, q_nope, q_rope), ctx_kv[ROW], buf,
+                jnp.int32(l), ctx_lens, ring_base, d["kv_rank"], live)
+            o = _unabsorb_o(c, lp, o_lat)
+        h, stats = _layer_out(c, params, lp, l, h, o, mix, live, stats)
     return {ROW: buf}, _logits(c, params, h), stats

@@ -7,11 +7,15 @@ admission/seal. Attention in the hot path therefore reads dense slabs —
 no gathers, no page tables:
 
   - decode: the implementation the CALLER names with a ``DecodeAttention``
-    — the compiled Pallas flash kernel (ops/flash_decode.py), mapped per
-    shard over the mesh's ``tp`` axis, on TPU devices; the pure-jnp
-    reference on the CPU test meshes. Nothing here looks at the process's
-    default backend, and there is no fall-through from one to the other:
-    a kernel that fails to compile fails the program;
+    — a compiled Pallas kernel on TPU devices, the pure-jnp reference on
+    the CPU test meshes. Two kernels stand behind the one name, by what
+    the region holds: K and V per kv head (``ctx_decode_attention`` here:
+    the flash kernel of ops/flash_decode.py, mapped per shard over the
+    mesh's ``tp`` axis) or one latent row a position
+    (ops/latent_decode.py: ``latent_decode_attention``, a work list of
+    each lane's own chunks). Nothing here looks at the process's default
+    backend, and there is no fall-through from one to the other: a kernel
+    that fails to compile fails the program;
   - prefill: ONE blocked running-softmax attention in pure XLA
     (``prefill_attention``) for every prefill-family program, solo and
     batched: two rolled loops whose trip counts follow the live rows, so
@@ -53,11 +57,15 @@ class DecodeAttention:
     Hashable: it is a static argument of the jitted model functions."""
 
     impl: str
-    # kernel impls: the mesh whose ``tp`` axis the kernel is mapped over
-    # (Mosaic calls cannot be partitioned by GSPMD). None = unsharded
-    # operands (single-device kernel tests).
+    # kernel impls: the mesh whose ``tp`` axis the flash kernel is mapped
+    # over (Mosaic calls cannot be partitioned by GSPMD). None = unsharded
+    # operands (single-device kernel tests). The latent block holds whole
+    # layers on every device and refuses tp / ep > 1: its kernel is called
+    # bare, as its grouped expert product is.
     mesh: Optional[Mesh] = None
-    # kernel tiling; 0 = the kernel's defaults (tools sweep these)
+    # kernel tiling; 0 = the kernel's defaults (tools sweep these).
+    # ``chunk`` is region rows a step of either decode attention, XLA
+    # loop included; ``slot_block`` is the flash kernel's alone
     chunk: int = 0
     slot_block: int = 0
 

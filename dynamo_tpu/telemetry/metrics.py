@@ -447,8 +447,9 @@ PREFILL_CONTINUED = (
 DECODE_ATTN_ROWS_READ = (
     "dynamo_decode_attn_rows_read",
     "latent-attention models: region rows a dispatched decode round's "
-    "attention read a layer: steps x lanes x the longest live context in "
-    "whole chunks")
+    "attention read a layer: steps x the dispatched lanes' own rows in "
+    "whole chunks under the TPU kernel; steps x lanes x the longest "
+    "dispatched lane under the XLA loop of the CPU meshes")
 DECODE_ATTN_ROWS_LIVE = (
     "dynamo_decode_attn_rows_live",
     "latent-attention models: region rows of that round that were some "

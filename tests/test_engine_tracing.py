@@ -12,11 +12,13 @@ import importlib.util
 import os
 import time
 
+import numpy as np
 import pytest
 
 from dynamo_tpu.engine.config import EngineConfig
 from dynamo_tpu.engine.engine import TpuEngine
 from dynamo_tpu.models.config import ModelConfig
+from dynamo_tpu.ops import latent_decode
 from dynamo_tpu.parallel.mesh import MeshConfig
 from dynamo_tpu.protocols.common import PreprocessedRequest, StopConditions
 from dynamo_tpu.telemetry import prof as tprof
@@ -505,7 +507,8 @@ def served_streams():
             _one(eng, [300 + i for i in range(20)], osl=9))
         h1 = await _settled(eng)
         await eng.stop()
-        return h0, h1, eng.ecfg
+        lens = (np.asarray(eng._dev["ctx"]), eng._ctx_disp.copy())
+        return h0, h1, eng.ecfg, lens
     return asyncio.run(scenario())
 
 
@@ -513,9 +516,10 @@ def served_streams():
     "continued_tokens_are_the_later_chunks",
     "one_residual_a_consumed_round_and_converged",
     "rows_read_cover_rows_live_in_whole_chunks",
+    "a_lane_without_a_request_counts_on_on_the_device",
 ])
 def test_counters_of_the_four_stream_block(served_streams, case):
-    h0, h1, e = served_streams
+    h0, h1, e, (dev_lens, host_lens) = served_streams
     if case == "continued_tokens_are_the_later_chunks":
         # 70 = 32 fresh + 32 + 6 continuing; the 20-token prompt is fresh
         assert _delta(h0, h1, CONT) == 38
@@ -526,14 +530,49 @@ def test_counters_of_the_four_stream_block(served_streams, case):
         assert rounds > 0 and _delta(h0, h1, HCRES, "count") == rounds
         # b_res of order 1: 20 iterations leave the slowest token ~1e-3
         assert 0.0 <= _delta(h0, h1, HCRES) / rounds < 2e-2
-    else:
+    elif case == "rows_read_cover_rows_live_in_whole_chunks":
         read, live = _delta(h0, h1, ROWS_READ), _delta(h0, h1, ROWS_LIVE)
         rounds = _delta(h0, h1, ROWS_READ, "count")
         assert rounds == _delta(h0, h1, LIVE, "count")
         assert 0 < live <= read
-        # steps x lanes x whole chunks (the region is shorter than one)
-        chunk = min(256, e.max_context)
+        # the XLA loop of the CPU meshes: steps x lanes x whole chunks
+        # (the region is shorter than one)
+        chunk = min(latent_decode.CHUNK, e.max_context)
         assert read % (e.flush_every * e.max_decode_slots * chunk) == 0
+    else:
+        # two requests, four lanes: every step adds 1 to EVERY lane's
+        # device length (the round body), and a lane that never held a
+        # request, or was freed, keeps counting from 1 while the host's
+        # mirror holds it at 1. So the round hands the attention `live`,
+        # and a lane that is not live reads no region row
+        # (ops/latent_decode.py; tests/test_mla_moe.py::
+        # test_latent_decode_kernel_equals_the_xla_loop_lane_by_lane)
+        assert host_lens.tolist() == [1, 1, 1, 1]
+        rounds = _delta(h0, h1, ROWS_READ, "count")
+        assert (dev_lens[2:] == 1 + rounds * e.flush_every).all()
+
+
+@pytest.mark.parametrize("impl,read", [("pallas", 4 * (3 + 1) * 512),
+                                       ("reference", 4 * 4 * 3 * 512)])
+def test_decode_attn_rows_mirror_by_hand(impl, read):
+    """The host's mirror of the latent decode attention's trip counts:
+    four lanes of a 2048-row region, two dispatched at 1300 and 512
+    region rows (3 chunks and 1 of 512), four steps. The kernel reads the
+    dispatched lanes' own chunks; the XLA loop every lane to the longest."""
+    from dynamo_tpu.ops.attention import DecodeAttention
+
+    class Seen(list):
+        observe = list.append
+
+    eng = TpuEngine.__new__(TpuEngine)
+    eng._B, eng.decode_attn = 4, DecodeAttention(impl)
+    eng.ecfg = EngineConfig(page_size=64, max_pages_per_seq=32)
+    assert latent_decode.CHUNK == 512 and eng.ecfg.max_context == 2048
+    eng._ctx_disp = np.array([1301, 1, 513, 2000], np.int32)
+    eng._h_attn_rows_read, eng._h_attn_rows_live = Seen(), Seen()
+    eng._observe_decode_attn_rows(np.array([0, 2]), 4)
+    assert eng._h_attn_rows_read == [read]
+    assert eng._h_attn_rows_live == [4 * (1300 + 512)]
 
 
 def test_hc_scopes_are_in_the_lowered_step():
@@ -543,23 +582,28 @@ def test_hc_scopes_are_in_the_lowered_step():
     import jax
     import jax.numpy as jnp
     from dynamo_tpu.models import llama, mla_moe
+    from dynamo_tpu.ops.attention import REFERENCE
 
     cfg = ModelConfig.tiny_mla_moe_mhc()
     params = jax.eval_shape(lambda: llama.init_params(cfg, 0))
     ctx = jax.eval_shape(lambda: llama.init_ctx(cfg, 4, 64, jnp.float32))
     ring = jax.eval_shape(lambda: llama.init_ring(cfg, 4, 4, jnp.float32))
     i32 = jax.ShapeDtypeStruct((4,), jnp.int32)
-    text = jax.jit(mla_moe.decode_step_impl, static_argnums=(0,)).lower(
+    step = jax.jit(mla_moe.decode_step_impl, static_argnums=(0,),
+                   static_argnames=("attn",))
+    text = step.lower(
         cfg, params, ctx, ring, i32, i32, i32,
-        jax.ShapeDtypeStruct((), jnp.int32)).as_text(debug_info=True)
+        jax.ShapeDtypeStruct((), jnp.int32),
+        attn=REFERENCE).as_text(debug_info=True)
     for scope in ("hc_pre", "hc_post", "mla_attn", "moe_route",
                   "moe_experts", "moe_shared"):
         assert f"/{scope}/" in text or f"{scope}/" in text, scope
-    plain = jax.jit(mla_moe.decode_step_impl, static_argnums=(0,)).lower(
+    plain = step.lower(
         ModelConfig.tiny_mla_moe(), jax.eval_shape(
             lambda: llama.init_params(ModelConfig.tiny_mla_moe(), 0)),
         ctx, ring, i32, i32, i32,
-        jax.ShapeDtypeStruct((), jnp.int32)).as_text(debug_info=True)
+        jax.ShapeDtypeStruct((), jnp.int32),
+        attn=REFERENCE).as_text(debug_info=True)
     assert "hc_pre" not in plain and "hc_post" not in plain
 
 

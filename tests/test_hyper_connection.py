@@ -4,6 +4,7 @@ widths: the tokens-minor slab form against the textbook [N, n, n] form,
 what the Sinkhorn iterations reach, the tie to the one-stream block, and
 the rotary numbers against values worked out by hand.
 """
+import functools
 import json
 import os
 
@@ -15,6 +16,7 @@ import pytest
 from dynamo_tpu.models import llama, mla_moe
 from dynamo_tpu.models.config import _TINY_MHC, _TINY_MLA_MOE, ModelConfig
 from dynamo_tpu.ops import hyper_connection as hc
+from dynamo_tpu.ops.attention import REFERENCE
 from dynamo_tpu.ops.rope import yarn_inv_freq, yarn_mscale
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -128,7 +130,8 @@ def test_fixed_coefficients_reproduce_the_one_stream_block():
     toks = jnp.asarray(
         np.random.RandomState(7).randint(1, 256, (1, 96)), jnp.int32)
     i32 = lambda *v: jnp.asarray(v, jnp.int32)  # noqa: E731
-    step = jax.jit(mla_moe.decode_step_impl, static_argnums=(0,))
+    step = jax.jit(functools.partial(mla_moe.decode_step_impl,
+                                     attn=REFERENCE), static_argnums=(0,))
     got = {}
     for name, cfg, params in (("one", one, p1), ("four", four, p4)):
         ctx = llama.init_ctx(cfg, 1, 128, jnp.float32)

@@ -12,6 +12,7 @@ dropped term, a wrong rope pairing, a bias leaking into the weights or a
 mis-scaled expert moves logits by 1e-2 and more.
 """
 import dataclasses
+import functools
 import importlib.util
 import os
 
@@ -25,7 +26,8 @@ from dynamo_tpu.engine.engine import TpuEngine
 from dynamo_tpu.models import llama, mla_moe
 from dynamo_tpu.models.config import _TINY_MHC, _TINY_MLA_MOE, ModelConfig
 from dynamo_tpu.models.moe import grouped_experts
-from dynamo_tpu.ops.attention import REFERENCE
+from dynamo_tpu.ops import latent_decode
+from dynamo_tpu.ops.attention import REFERENCE, DecodeAttention
 from dynamo_tpu.ops.latent_decode import latent_decode_attention
 from dynamo_tpu.parallel.mesh import MeshConfig
 from dynamo_tpu.protocols.common import (
@@ -86,7 +88,8 @@ def log_softmax(x):
 
 
 # the block's one decode entry (the engine's round calls it too)
-DECODE_STEP = jax.jit(mla_moe.decode_step_impl, static_argnums=(0,))
+DECODE_STEP = jax.jit(functools.partial(mla_moe.decode_step_impl,
+                                        attn=REFERENCE), static_argnums=(0,))
 
 
 def decode_steps(cfg, params, ctx, first_logits, seq_len, n):
@@ -124,9 +127,11 @@ def test_prefill_then_decode_through_the_latent_cache(block):
     np.testing.assert_allclose(log_softmax(rows), want, **TOL)
 
 
-def test_absorbed_decode_attention_equals_expanded():
+@pytest.mark.parametrize("impl", ["reference", "pallas_interpret"])
+def test_absorbed_decode_attention_equals_expanded(impl):
     """(b) the absorbed form over cached rows against K and V expanded
-    per head: algebra, so float32 agrees to summation order."""
+    per head: algebra, so float32 agrees to summation order. Both region
+    implementations: the XLA loop and the kernel, interpreted."""
     rng = np.random.RandomState(1)
     B, nh, rank, rope, nope, vd, S, R = 3, 4, 24, 8, 16, 16, 40, 2
     row = rank + rope
@@ -151,8 +156,9 @@ def test_absorbed_decode_attention_equals_expanded():
     q = np.concatenate([np.einsum("bhd,chd->bhc", q_nope, wk), q_rope,
                         np.zeros((B, nh, stored - row), np.float32)], -1)
     got = latent_decode_attention(
-        jnp.asarray(q * scale), jnp.asarray(ctx), jnp.asarray(ring),
-        jnp.int32(0), jnp.asarray(lens), jnp.asarray(base), rank, chunk=16)
+        DecodeAttention(impl, chunk=16), jnp.asarray(q * scale),
+        jnp.asarray(ctx), jnp.asarray(ring), jnp.int32(0),
+        jnp.asarray(lens), jnp.asarray(base), rank)
     got = np.einsum("bhc,chd->bhd", np.asarray(got), wv)
     for b in range(B):
         live = rows[b, : lens[b]]
@@ -166,6 +172,122 @@ def test_absorbed_decode_attention_equals_expanded():
         p /= p.sum(-1, keepdims=True)
         np.testing.assert_allclose(got[b], np.einsum("hs,shd->hd", p, v),
                                    **TOL)
+
+
+# lanes of a ragged batch, region of S = 64 rows read 16 at a time:
+# (rows below the ring base, holds a request)
+RAGGED = {
+    "no_region_rows": (0, True),
+    "on_a_chunk_boundary": (32, True),
+    "one_past_a_boundary": (33, True),
+    "at_the_regions_end": (64, True),
+    "mid_chunk": (7, True),
+    "idle_with_a_stale_length": (61, False),
+}
+
+
+@pytest.fixture(scope="module")
+def ragged():
+    """One batch with every lane of ``RAGGED``, garbage in each region
+    tail and in the scratch lane, through both implementations."""
+    rng = np.random.RandomState(3)
+    B, nh, row, vw, S, R, L = len(RAGGED), 4, 128, 64, 64, 2, 2
+    below = np.array([n for n, _ in RAGGED.values()], np.int32)
+    live = np.array([ok for _, ok in RAGGED.values()])
+    args = dict(
+        q=jnp.asarray(rng.randn(B, nh, row) * 0.3, jnp.float32),
+        ctx=rng.randn(L, 1, B + 1, S, row).astype(np.float32),
+        ring=jnp.asarray(rng.randn(L, 1, B, R, row), jnp.float32),
+        lens=jnp.asarray(below + 1), base=jnp.asarray(below),
+        live=jnp.asarray(live))
+
+    def run(impl, ctx, chunk=16):
+        return np.asarray(latent_decode_attention(
+            DecodeAttention(impl, chunk=chunk), args["q"], jnp.asarray(ctx),
+            args["ring"], jnp.int32(1), args["lens"], args["base"], vw,
+            args["live"]))
+
+    return dict(args, below=below, run=run, S=S, vw=vw)
+
+
+@pytest.mark.parametrize("lane", list(RAGGED))
+def test_latent_decode_kernel_equals_the_xla_loop_lane_by_lane(ragged, lane):
+    """The kernel reads each lane's own chunks and the XLA loop every lane
+    to the longest: the same softmax over the same rows, lane by lane,
+    whatever lies in the region past a lane's length."""
+    b = list(RAGGED).index(lane)
+    want = ragged["run"]("reference", ragged["ctx"])
+    got = ragged["run"]("pallas_interpret", ragged["ctx"])
+    np.testing.assert_allclose(got[b], want[b], **TOL)
+    # the lane by hand: its region rows below its length, then the ring
+    n, holds = RAGGED[lane]
+    rows = np.concatenate([ragged["ctx"][1, 0, b, : n if holds else 0],
+                           np.asarray(ragged["ring"])[1, 0, b, :1]])
+    s = np.asarray(ragged["q"])[b] @ rows.T
+    p = np.exp(s - s.max(-1, keepdims=True))
+    np.testing.assert_allclose(
+        got[b], (p / p.sum(-1, keepdims=True)) @ rows[:, : ragged["vw"]],
+        **TOL)
+
+
+@pytest.mark.parametrize("impl", ["reference", "pallas_interpret"])
+@pytest.mark.parametrize("chunk", [16, 24], ids=["tiles", "slides_back"])
+def test_latent_decode_reads_nothing_past_a_lanes_rows(ragged, impl, chunk):
+    """Poison in every region row a lane's attention may not use changes
+    nothing: the other layer, the scratch lane, all of an idle lane's rows
+    and a live lane's tail. The kernel never FETCHES past a lane's last
+    whole chunk, so there the poison is NaN; inside that chunk, and for
+    the XLA loop (which reads every lane to the longest), it is masked
+    and has to be finite (0 x NaN in the value product). A chunk that
+    does not tile the region slides its last block back and masks the
+    rows it has seen."""
+    kernel = impl != "reference"
+    want = ragged["run"](impl, ragged["ctx"], chunk)
+    ctx = ragged["ctx"].copy()
+    poison = np.nan if kernel else 7.0
+    ctx[0] = ctx[1, 0, len(RAGGED)] = poison
+    for b, (n, holds) in enumerate(RAGGED.values()):
+        n = n if holds else 0
+        ctx[1, 0, b, n:] = 7.0
+        ctx[1, 0, b, min(-(-n // chunk) * chunk, ragged["S"]):] = poison
+    np.testing.assert_allclose(ragged["run"](impl, ctx, chunk), want, **TOL)
+
+
+def test_region_trips_by_hand_and_the_kernels_own_walk(ragged):
+    """The one function the kernel's wrapper and the engine's mirror both
+    call: chunks a lane, by hand; rows read by each implementation; and
+    the same counts from what the kernel itself walks: a NaN in the last
+    row of chunk k of every lane spoils a lane's output (NaN through the
+    value product where the row is masked, the "no visible row" guard's
+    zeros where it is scored) exactly when the kernel fetched that
+    chunk."""
+    below, live = ragged["below"], np.asarray(ragged["live"])
+    trips = latent_decode.region_trips(below, live, 16)
+    assert trips.tolist() == [0, 2, 3, 4, 1, 0]
+    want = ragged["run"]("pallas_interpret", ragged["ctx"])
+    walked = np.zeros(len(RAGGED), int)
+    for k in range(ragged["S"] // 16):
+        ctx = ragged["ctx"].copy()
+        ctx[1, 0, :, k * 16 + 15] = np.nan
+        out = ragged["run"]("pallas_interpret", ctx)
+        walked += ~np.isclose(out, want).all(axis=(1, 2))
+    assert walked.tolist() == trips.tolist()
+    assert latent_decode.region_rows_read("pallas", trips, 16) == 160
+    assert latent_decode.region_rows_read("reference", trips, 16) == 384
+    # the module's own chunk, a region shorter than it, an idle batch
+    c = latent_decode.CHUNK
+    assert latent_decode.chunk_rows(8 * c) == c
+    assert latent_decode.chunk_rows(40) == 40
+    assert latent_decode.chunk_rows(8 * c, 16) == 16
+    assert latent_decode.region_trips(
+        np.array([0, 1, c, c + 1, 5 * c]), True, c).tolist() == [
+            0, 1, 1, 2, 5]
+    idle = latent_decode.region_trips(below, np.zeros(6, bool), 16)
+    assert latent_decode.region_rows_read("reference", idle, 16) == 0
+    # traced in, traced out: what the wrapper hands the kernel
+    np.testing.assert_array_equal(
+        latent_decode.region_trips(jnp.asarray(below), jnp.asarray(live),
+                                   16), trips)
 
 
 def test_expert_layer_equals_a_dense_loop_over_experts(setup):

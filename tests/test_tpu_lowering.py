@@ -14,6 +14,7 @@ The K/V movers alone compile for compile-only v5e devices in under a
 second, so THAT guard is tier-1: no region-shaped copy in ring -> region
 or region -> pool (a third of the chip in both dense cells until PR 34).
 """
+import math
 import os
 import re
 import sys
@@ -221,46 +222,67 @@ def test_kv_movers_copy_no_region_on_v5e(mover_records, program):
     assert rec["temp_bytes"] < 0.05 * rec["region_bytes"], rec
 
 
+# the two latent cells' regions: layers, lanes, context (rows stored at 640)
+LATENT_REGIONS = {"xing4-mhc-d7": (7, 16, 16384),
+                  "mla-moe-joyai-d5": (5, 64, 4096)}
+
+
 @pytest.fixture(scope="module")
 def latent_records():
-    """The movers and the absorbed decode attention at the long-context
-    latent cell's region, ``[7, 1, 17, 16384, 640]`` (7 layers, one row
-    kind, 16 + 1 lanes of 16384 tokens, rows stored at 640), compiled by
-    XLA:TPU for a compile-only v5e device."""
+    """The movers at the long-context latent cell's region,
+    ``[7, 1, 17, 16384, 640]`` (7 layers, one row kind, 16 + 1 lanes of
+    16384 tokens, rows stored at 640), and the absorbed decode attention
+    THROUGH ITS KERNEL (32 heads, value 512, the module's own chunk) at
+    that region and at the chat cell's ``[5, 1, 65, 4096, 640]``,
+    compiled by XLA:TPU and Mosaic for a compile-only v5e device."""
     topo = _v5e_or_skip()
     records = dict(zip(MOVERS, tpu_compile_check.compile_programs(
         config="xing4-mhc-d7", programs=MOVERS)))
     from dynamo_tpu.ops.latent_decode import latent_decode_attention
     one = jax.sharding.SingleDeviceSharding(topo.devices[0])
-    region = (7, 1, 17, 16384, 640)
 
     def arg(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
-    with jax.default_matmul_precision("default"):
-        compiled = jax.jit(latent_decode_attention, static_argnums=(6,)).lower(
-            arg((16, 32, 640)), arg(region), arg((7, 1, 16, 4, 640)),
-            arg((), jnp.int32), arg((16,), jnp.int32), arg((16,), jnp.int32),
-            512).compile()
-    found = tpu_compile_check.region_copies(compiled.as_text(), region)
-    records["latent_decode"] = {
-        "ok": True, "region_shard": list(region),
-        "region_copies": {"count": len(found), "shapes": sorted(set(found))},
-        "temp_bytes": compiled.memory_analysis().temp_size_in_bytes,
-        "region_bytes": 2 * 7 * 17 * 16384 * 640}
+    for config, (L, B, S) in LATENT_REGIONS.items():
+        region = (L, 1, B + 1, S, 640)
+        # conftest's "highest" makes Mosaic refuse bf16 dots
+        with jax.default_matmul_precision("default"):
+            compiled = jax.jit(
+                latent_decode_attention, static_argnums=(0, 7)).lower(
+                DecodeAttention(PALLAS), arg((B, 32, 640)), arg(region),
+                arg((L, 1, B, 4, 640)), arg((), jnp.int32),
+                arg((B,), jnp.int32), arg((B,), jnp.int32), 512,
+                arg((B,), jnp.bool_)).compile()
+        text = compiled.as_text()
+        found = tpu_compile_check.region_copies(text, region)
+        records[f"latent_decode_{config}"] = {
+            "ok": True, "region_shard": list(region),
+            "mosaic_calls": text.count("tpu_custom_call"),
+            "region_copies": {"count": len(found),
+                              "shapes": sorted(set(found))},
+            "temp_bytes": compiled.memory_analysis().temp_size_in_bytes,
+            "region_bytes": 2 * math.prod(region)}
     return records
 
 
-@pytest.mark.parametrize("program", MOVERS + ("latent_decode",))
+@pytest.mark.parametrize(
+    "program", MOVERS + tuple(f"latent_decode_{c}" for c in LATENT_REGIONS))
 def test_latent_movers_and_decode_copy_no_region_on_v5e(latent_records,
                                                         program):
     """A new region shape is a new chance for XLA:TPU to relayout it (a
     576-wide row did, PR 31): ring -> region, region -> pool, both in one
-    jit, and the decode attention's chunk reads leave the 2.5 GB region
-    where it is."""
+    jit, and the decode attention's kernel, which takes the region whole
+    and un-blocked (``memory_space=ANY``), leave the 2.5 GB (1.7 GB)
+    region where it is: no copy of its size, temporaries under 5 % of
+    it."""
     rec = latent_records[program]
     assert rec["ok"], rec
-    assert rec["region_shard"] == [7, 1, 17, 16384, 640]
+    config = program.removeprefix("latent_decode_")
+    L, B, S = LATENT_REGIONS.get(config, LATENT_REGIONS["xing4-mhc-d7"])
+    assert rec["region_shard"] == [L, 1, B + 1, S, 640]
+    if config in LATENT_REGIONS:
+        assert rec["mosaic_calls"] == 1      # the kernel, not the XLA loop
     assert rec["region_copies"] == {"count": 0, "shapes": []}, rec
     assert rec["temp_bytes"] < 0.05 * rec["region_bytes"], rec
 
@@ -404,12 +426,16 @@ def test_hybrid_cell_keeps_four_prefill_programs():
 # test itself; a PR that MEANS to change one of these programs records the
 # new digest here and says so (equal digests are what let a chip check of
 # the cell be predicted ``unchanged``, PERF.md section 6, PR 35).
+# PR 42 MEANT to move ``round_seal_n4_w64`` of the routed-expert chat cell
+# (9c08905c1b04a832 on its parent): its decode attention reads the region
+# through ops/latent_decode.py's kernel. The other five of that cell, which
+# hold no decode step, and the dense prefills kept the digests below.
 UNMOVED = {
     ("mla-moe-joyai-d5", 0): {
         "flush_ctx": "aa9a25ef5ee32101",
         "seal_blocks_w64": "60d93eac534dbceb",
         "flush_seal_w64": "0cf53d0f869aa38b",
-        "round_seal_n4_w64": "9c08905c1b04a832",
+        "round_seal_n4_w64": "9eb8bd24fab037be",
         "load_ctx_pages_n64": "217cccff59be759b",
         "batch_prefill_K2_T128": "459e2c202d3ac337",
     },
