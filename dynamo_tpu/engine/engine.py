@@ -113,11 +113,9 @@ _FIRST_TOKEN_KEY_TAG = 0x46697273  # distinct PRNG stream for first tokens
 # per-request trace spans shipped back in the finishing annotation are
 # capped (a 10k-token generation must not grow a 10k-entry span list);
 # the total decode-round count still travels in the timing annotation
-_MAX_ROUND_SPANS = 24
-# requests flagged "trace_detail" by the frontend (forensics candidates —
-# every request, since breach status is only known at finish) keep a much
-# deeper round-span ring so a late promotion yields a complete dossier
-_MAX_ROUND_SPANS_DETAIL = 256
+# and in the ``decode`` span. Deep enough that a request promoted to a
+# forensics dossier at finish (a breach is only known then) is whole.
+_MAX_ROUND_SPANS = 256
 
 
 def _span_dict(name: str, t0_monotonic: float, **attrs) -> dict:
@@ -193,6 +191,12 @@ class _Request:
     prefill_chunks: int = 0
     rounds_at_dispatch: int = 0
     decode_rounds: int = 0
+    # the ``decode`` phase (_note_emit): the rounds of them dispatched
+    # behind a prefill program, this request's own gaps at those rounds,
+    # and the padded prompt positions that stood ahead of them
+    rounds_behind_prefill: int = 0
+    behind_prefill_s: float = 0.0
+    prefill_tokens_ahead: int = 0
     # speculative decoding (spec/): a speculating slot's device lane
     # stays PARKED (dest=scratch) — its real state lives here on the
     # host and in the ctx region, driven by verify dispatches instead of
@@ -220,10 +224,6 @@ class _Request:
     # snapshot still steps this lane (the clear patch lands after them
     # in program order; their tokens are real and must be mirrored)
     spec_rearm_wait: int = 0
-    # forensics: frontend marks candidates with a "trace_detail"
-    # annotation — lifts the round-span cap so late (finish-time) trace
-    # promotion still sees the full decode path
-    trace_detail: bool = False
     # tenancy plane: SFQ virtual finish-time stamp minted at enqueue
     # (tenant virtual clock + prompt cost / weight) — orders
     # same-priority waiting entries so a storming tenant self-paces
@@ -285,6 +285,9 @@ class _Entry:
     aux: Any = None
     # telemetry: dispatch time, for dynamo_engine_round_seconds
     t_dispatch: float = 0.0
+    # round: (ordinal, prefill programs, their padded tokens) dispatched
+    # since the round before: what stood ahead of it on the device
+    ahead: tuple = (0, 0, 0)
     # spec verify: (draft_s, verify_s) host dispatch walls — become the
     # spec_draft / spec_verify child spans under the round span
     spec_host: Any = None
@@ -532,6 +535,13 @@ class TpuEngine:
         self._h_round = self.telemetry.get(tmetrics.ROUND[0])
         self._h_first_token = self.telemetry.get(tmetrics.FIRST_TOKEN[0])
         self._h_frontend = self.telemetry.get(tmetrics.FRONTEND[0])
+        self._h_tpot = self.telemetry.get(tmetrics.TPOT[0])
+        self._h_step_gap = self.telemetry.get(tmetrics.STEP_GAP[0])
+        self._h_step_gap_clean = self.telemetry.get(
+            tmetrics.STEP_GAP_CLEAN[0])
+        self._h_pf_ahead = self.telemetry.get(
+            tmetrics.ROUND_PREFILL_AHEAD[0])
+        self._h_dry = self.telemetry.get(tmetrics.DISPATCH_DRY[0])
         self._h_pf_tokens = self.telemetry.get(tmetrics.PREFILL_TOKENS[0])
         self._h_pf_padded = self.telemetry.get(tmetrics.PREFILL_PADDED[0])
         self._h_pf_matched = self.telemetry.get(tmetrics.PREFILL_MATCHED[0])
@@ -700,6 +710,14 @@ class TpuEngine:
         self.waiting_preemptions = 0  # waiting entries evicted by priority
         self.preempt_migrations = 0   # running streams force-migrated
         self._entries: list[_Entry] = []
+        # the time between tokens, from inside: prefill programs and
+        # their padded tokens dispatched since the last fused round (what
+        # stands ahead of the next one), an output of the newest model
+        # program dispatched (the device runs one in-order queue: when it
+        # is ready the device stands dry), the last round's consume time
+        self._ahead = [0, 0]
+        self._newest: Any = None
+        self._t_round_consumed = 0.0
         # sealed blocks awaiting the batched ctx->pool copy:
         # (slot, start_pos, pool_page)
         self._seal_queue: list[tuple[int, int, int]] = []
@@ -1164,7 +1182,6 @@ class TpuEngine:
             out=asyncio.Queue(),
             loop=asyncio.get_running_loop(),
             tokens=list(request.token_ids),
-            trace_detail="trace_detail" in (request.annotations or []),
         )
         if request.received_unix is not None:
             # everything before the engine, on the unix clock the stamp
@@ -2004,8 +2021,6 @@ class TpuEngine:
                 and not self._prefilling and self._intake.empty()
                 and all(s is None for s in self._slots)):
             self._drained_evt.set()
-        if not did_work:
-            prof.mark_fed()  # nothing live: idle, not starved
         prof.end_round(record=did_work)
         return did_work
 
@@ -2375,7 +2390,13 @@ class TpuEngine:
         # is garbage by contract) — one compiled variant per engine, not
         # one per seal-width plus a plain variant, which is what keeps
         # the fusion free at compile time too.
+        self._poll_dry()
         t_disp = time.monotonic()
+        counts = self.dispatch_counts
+        ahead = (counts["round"] + counts["round_seal"], *self._ahead)
+        self._ahead = [0, 0]
+        self.prof.mark_round(dispatched=ahead[0], programs_ahead=ahead[1],
+                             padded_tokens_ahead=ahead[2])
         if seal is not None:
             self.dispatch_counts["round_seal"] += 1
             seal_dev = (jnp.asarray(seal[0]), jnp.asarray(seal[1]),
@@ -2447,6 +2468,7 @@ class TpuEngine:
             _Entry(
                 kind="round",
                 t_dispatch=t_disp,
+                ahead=ahead,
                 handle=stacked,
                 # snapshot EXCLUDES speculating slots: their device lanes
                 # are parked, so their columns in this round's stacked
@@ -2583,6 +2605,7 @@ class TpuEngine:
                 penalties[1][j] = so.frequency_penalty or 0.0
                 penalties[2][j] = so.presence_penalty or 0.0
                 penalties[3][j] = so.repetition_penalty or 1.0
+        self._poll_dry()
         t_disp = time.monotonic()
         drafted = None
         if self.spec.draft is not None:
@@ -2722,6 +2745,7 @@ class TpuEngine:
                 penalties[1][j] = so.frequency_penalty or 0.0
                 penalties[2][j] = so.presence_penalty or 0.0
                 penalties[3][j] = so.repetition_penalty or 1.0
+        self._poll_dry()
         t_disp = time.monotonic()
         drafted = None
         if draft_mode:
@@ -2969,19 +2993,26 @@ class TpuEngine:
     ) -> None:
         """Telemetry for one round's emitted batch: per-token gaps into
         the ITL histogram (the batch arrives together — its gap is the
-        round wall split over the tokens) and a capped round span."""
+        round wall split over the tokens), the request's ``decode`` phase
+        (which of its rounds stood behind a prefill, and what they cost
+        it) and a capped round span."""
         now = time.monotonic()
+        _, programs, padded = entry.ahead
         if r.t_last_emit is not None:
-            gap = (now - r.t_last_emit) / n_tokens
+            wait = now - r.t_last_emit
+            gap = wait / n_tokens
             self._h_itl.observe(gap, n_tokens,
                                 exemplar_id=r.req.request_id or None)
             if len(r.itl_gaps) < 4096:
                 r.itl_gaps.append((gap, n_tokens))
+            if programs:
+                r.behind_prefill_s += wait
+        if programs:
+            r.rounds_behind_prefill += 1
+            r.prefill_tokens_ahead += padded
         r.t_last_emit = now
         r.decode_rounds += 1
-        cap = (_MAX_ROUND_SPANS_DETAIL if r.trace_detail
-               else _MAX_ROUND_SPANS)
-        if (len(r.trace_spans) + len(r.round_spans) < cap
+        if (len(r.trace_spans) + len(r.round_spans) < _MAX_ROUND_SPANS
                 and entry.t_dispatch):
             # annotate diet: the hot loop records one raw tuple; the
             # span dicts (and spec draft/verify children) are built
@@ -3028,32 +3059,8 @@ class TpuEngine:
             if v is not None:
                 timing[key] = round(v, 6)
         ann["timing"] = timing
-        if r.round_spans:
-            # materialize the lazily-accumulated round spans (same wire
-            # form _span_dict produced per round before the diet: the
-            # unix start is anchored off the shared monotonic clock)
-            wall_now = time.time()
-            mono_now = time.monotonic()
-            for kind, t0, dur, n_toks, spec_host in r.round_spans:
-                start = wall_now - (mono_now - t0)
-                sp: dict[str, Any] = {
-                    "name": kind, "start_s": round(start, 6),
-                    "duration_s": round(dur, 6),
-                    "attrs": {"tokens": n_toks},
-                }
-                if spec_host is not None:
-                    # spec rounds carry draft/verify child spans so the
-                    # speculation cost shows up inside timelines, not
-                    # just as one opaque round span
-                    draft_s, verify_s = spec_host
-                    t0_w = sp["start_s"]
-                    sp["children"] = [
-                        Span("spec_draft", t0_w, draft_s).to_dict(),
-                        Span("spec_verify", t0_w + draft_s,
-                             verify_s).to_dict(),
-                    ]
-                r.trace_spans.append(sp)
-            r.round_spans = []
+        if r.decode_rounds and r.first_token_time is not None:
+            r.trace_spans.append(self._decode_span(r, timing))
         if r.trace_spans:
             ann["trace"] = {"spans": list(r.trace_spans)}
             rid = r.req.request_id
@@ -3069,6 +3076,55 @@ class TpuEngine:
                     trace_spans=r.trace_spans,
                 )
         return ann
+
+    def _decode_span(self, r: _Request, timing: dict) -> dict:
+        """The ``decode`` phase: ``first_token`` ended with the first
+        token on the host, this one ends with the last emitted batch, so
+        engine E2E = queue + first_token + decode + the finishing
+        bookkeeping. The per-round spans become its children, as
+        ``prefill`` became ``first_token``'s."""
+        rid = r.req.request_id or None
+        dur = r.t_last_emit - r.first_token_time
+        timing["decode_s"] = round(dur, 6)
+        if r.produced > 1:
+            tpot = dur / (r.produced - 1)
+            timing["tpot_s"] = round(tpot, 6)
+            self._h_tpot.observe(tpot, exemplar_id=rid)
+        wall_now = time.time()
+        mono_now = time.monotonic()
+        sp = Span(
+            "decode", wall_now - (mono_now - r.first_token_time), dur,
+            attrs=dict(
+                request_id=rid, tokens=r.produced - 1,
+                rounds=r.decode_rounds,
+                rounds_behind_prefill=r.rounds_behind_prefill,
+                behind_prefill_s=round(r.behind_prefill_s, 6),
+                prefill_tokens_ahead=r.prefill_tokens_ahead,
+            )).to_dict()
+        # materialize the lazily-accumulated round spans (the unix start
+        # is anchored off the shared monotonic clock)
+        rounds = sp["children"] = []
+        for kind, t0, round_s, n_toks, spec_host in r.round_spans:
+            rs: dict[str, Any] = {
+                "name": kind,
+                "start_s": round(wall_now - (mono_now - t0), 6),
+                "duration_s": round(round_s, 6),
+                "attrs": {"tokens": n_toks},
+            }
+            if spec_host is not None:
+                # spec rounds carry draft/verify child spans so the
+                # speculation cost shows up inside timelines, not
+                # just as one opaque round span
+                draft_s, verify_s = spec_host
+                t0_w = rs["start_s"]
+                rs["children"] = [
+                    Span("spec_draft", t0_w, draft_s).to_dict(),
+                    Span("spec_verify", t0_w + draft_s,
+                         verify_s).to_dict(),
+                ]
+            rounds.append(rs)
+        r.round_spans = []
+        return sp
 
     def _spec_annotations(self, r: _Request) -> dict:
         """Per-request speculation stats for the finishing output — the
@@ -3623,14 +3679,14 @@ class TpuEngine:
             })
         t_disp = time.monotonic()
         self.dispatch_counts["prefill_batch"] += 1
-        self._h_pf_tokens.observe(sum(chunk_lens))
-        self._h_pf_padded.observe(K * width)
+        self._note_prefill_dispatch(sum(chunk_lens), K * width)
         self._observe_attn_pairs(width, q_starts, seq_lens, ctx_span)
         self.ctx, logits = llama.batch_prefill(
             self.config, self.params, self.ctx, jnp.asarray(toks),
             jnp.asarray(slots), jnp.asarray(q_starts),
             jnp.asarray(seq_lens), ctx_span, jnp.asarray(adapter_ids),
         )
+        self._newest = logits
         self.flight.record(
             "prefill_batch", slots=[r.slot for r in group], width=width,
             dispatch_ms=round((time.monotonic() - t_disp) * 1e3, 3),
@@ -3645,6 +3701,27 @@ class TpuEngine:
             if self._finish_prefill(r, logits[i], index=i) == "done":
                 done.append(r)
         return done
+
+    def _note_prefill_dispatch(self, real: int, padded: int) -> None:
+        """The books of one prefill program about to be dispatched: the
+        prompt tokens it computes and the positions it runs, which then
+        stand ahead of the next fused round on the device's one queue."""
+        self._poll_dry()
+        self._h_pf_tokens.observe(real)
+        self._h_pf_padded.observe(padded)
+        self._ahead[0] += 1
+        self._ahead[1] += padded
+
+    def _poll_dry(self) -> None:
+        """Before a model program is dispatched: has the newest one
+        dispatched before it already finished (or was there none)? Then
+        the device stood dry when this dispatch found it. The count is
+        exact; the time is not, for the device ran dry somewhere between
+        two polls (RoundProf.poll charges the whole stretch)."""
+        newest = self._newest
+        dry = newest is None or newest.is_ready()
+        self._h_dry.observe(float(dry))
+        self.prof.poll(dry)
 
     def _observe_attn_pairs(self, width, q_starts, seq_lens,
                             ctx_span) -> None:
@@ -3848,8 +3925,7 @@ class TpuEngine:
             })
         t_disp = time.monotonic()
         self.dispatch_counts["prefill"] += 1
-        self._h_pf_tokens.observe(len(chunk))
-        self._h_pf_padded.observe(pad_t)
+        self._note_prefill_dispatch(len(chunk), pad_t)
         self._observe_attn_pairs(
             pad_t, [start], [start + len(chunk)],
             e.max_context if start else 0)
@@ -3862,6 +3938,7 @@ class TpuEngine:
             embeds, embeds_mask, jnp.int32(r.adapter_id),
             fresh=start == 0,
         )
+        self._newest = logits
         self.flight.record(
             "prefill", slots=[r.slot], tokens=len(chunk), start=start,
             dispatch_ms=round((time.monotonic() - t_disp) * 1e3, 3),
@@ -3903,8 +3980,7 @@ class TpuEngine:
             })
         t_disp = time.monotonic()
         self.dispatch_counts["sp_prefill"] += 1
-        self._h_pf_tokens.observe(len(prompt))
-        self._h_pf_padded.observe(len(toks))
+        self._note_prefill_dispatch(len(prompt), len(toks))
         # the ring path scores every pair of its padded prompt
         n = len(prompt)
         self._h_pf_live.observe(n * (n + 1) // 2)
@@ -4039,9 +4115,10 @@ class TpuEngine:
     # ---- processing side (lagged results) ----
 
     def _track(self, entry: _Entry) -> None:
-        """Every in-flight fetch is tracked here: the device has work."""
+        """Every in-flight fetch is tracked here; its handle is an output
+        of the newest program dispatched."""
         self._entries.append(entry)
-        self.prof.mark_fed()
+        self._newest = entry.handle
 
     def _process_entries(self, block: bool = False) -> None:
         # first-token / offload entries are independent of round ordering
@@ -4061,16 +4138,17 @@ class TpuEngine:
         self._entries = remaining
         while self._entries:
             entry = self._entries[0]
-            if not block and not entry.handle.is_ready():
+            ready = entry.handle.is_ready()
+            if not ready and not block:
+                self.prof.poll(False)   # the device is busy right now
                 return
             self._entries.pop(0)
             self._consume_entry(entry)
+            if not ready:
+                # the host waited this program out: the device was busy
+                # up to here, whatever the next dispatch finds
+                self.prof.poll(False)
             block = False  # only force at most one blocking wait
-        # the last tracked fetch is consumed: if requests are still live
-        # the device has nothing queued until the next dispatch
-        if (self._waiting or self._prefilling
-                or self._slot_active.any() or self._slot_spec.any()):
-            self.prof.mark_starved()
 
     def _unpack_lp(self, packed: np.ndarray):
         """Split one packed logprob row/stack [..., 1+2K] back into
@@ -4173,6 +4251,18 @@ class TpuEngine:
         per-token emits through the asyncio machinery are pure host
         overhead — on a 1-core box they, not the device, capped
         throughput)."""
+        t = time.monotonic()
+        wall = t - max(self._t_round_consumed, entry.t_dispatch)
+        self._t_round_consumed = t
+        gap = wall / entry.n_steps
+        ordinal, programs, padded = entry.ahead
+        self._h_step_gap.observe(gap)
+        if programs:
+            self._h_pf_ahead.observe(padded)
+        else:
+            self._h_step_gap_clean.observe(gap)
+        self.prof.mark_round(consumed=ordinal, wall_us=int(wall * 1e6),
+                             steps=entry.n_steps)
         lp_arrs = None
         if entry.lp_handle is not None:
             lp_arrs = self._unpack_lp(np.asarray(entry.lp_handle))
@@ -4403,6 +4493,6 @@ class TpuEngine:
         self._waiting = []
         self._prefilling = {}
         self._entries = []
-        self.prof.mark_fed()  # nothing live: idle, not starved
+        self._newest = None
         self._seal_queue = []
 

@@ -781,12 +781,6 @@ class HttpService:
                 "tokenize", t_tok,
                 model=req.model, prompt_tokens=len(pre.token_ids),
             ))
-            # ship the detail bit to the worker: an SLO breach is only
-            # detectable at finish, so the engine must retain the FULL
-            # round-span history until then for a late promotion to
-            # yield a complete dossier (the PR 4 shell-trace gap)
-            if "trace_detail" not in pre.annotations:
-                pre.annotations.append("trace_detail")
             # overload plane: header hints land on top of the nvext
             # fields the preprocessor already applied (headers win;
             # nvext is NOT re-applied — re-minting its deadline here

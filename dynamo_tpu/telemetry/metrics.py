@@ -397,6 +397,30 @@ FRONTEND = ("dynamo_request_frontend_seconds",
             "HTTP handler entry to engine intake: parse, templating, "
             "tokenizing, routing and transport (requests stamped "
             "with received_unix only)")
+# the time between tokens, from inside (engine._process_round,
+# _note_prefill_dispatch, _poll_dry, _decode_span). A consumed fused
+# round's GAP is the time from the later of its dispatch and the previous
+# round's consume to its own consume, per decode step: over a stretch in
+# which a round is always in flight the gaps x steps telescope to the wall
+TPOT = ("dynamo_request_tpot_seconds",
+        "per finished request with more than one token: (last emit - "
+        "first token) / (tokens - 1), the engine's side of the client's "
+        "time per output token")
+STEP_GAP = ("dynamo_engine_step_gap_seconds",
+            "per consumed decode round: consume time less the later of "
+            "its dispatch and the previous round's consume, per step")
+STEP_GAP_CLEAN = ("dynamo_engine_step_gap_clean_seconds",
+                  "the same gap, of the rounds with no prefill program "
+                  "dispatched since the round before them")
+ROUND_PREFILL_AHEAD = (
+    "dynamo_engine_round_prefill_tokens_ahead",
+    "padded prompt positions of the prefill programs dispatched since the "
+    "round before, observed only by rounds that had some (the count is "
+    "the rounds that stood behind a prefill)")
+DISPATCH_DRY = ("dynamo_engine_dispatch_found_dry",
+                "per model-program dispatch (round, prefill, spec verify): "
+                "1 if the newest program dispatched before it had already "
+                "finished or none was, so the device stood dry; else 0")
 # work and waste, one observation per dispatch / consumed round: the
 # sum is the quantity, the count the dispatches (or requests, rounds)
 PREFILL_TOKENS = ("dynamo_engine_prefill_tokens",
@@ -473,9 +497,12 @@ def request_histograms(
     for name, help_ in (TTFT, ITL, E2E):
         reg.histogram(name, help_)
     if engine:
-        for name, help_ in (QUEUE, ROUND, FIRST_TOKEN, FRONTEND):
+        for name, help_ in (QUEUE, ROUND, FIRST_TOKEN, FRONTEND, TPOT,
+                            STEP_GAP, STEP_GAP_CLEAN):
             reg.histogram(name, help_)
+        reg.histogram(*DISPATCH_DRY, (0.0, 1.0))
         for name, help_ in (PREFILL_TOKENS, PREFILL_PADDED, PREFILL_MATCHED,
+                            ROUND_PREFILL_AHEAD,
                             ROUND_LIVE_LANE_STEPS, ROUND_TOKENS,
                             MOE_TOUCHED, MOE_ROUTED, MOE_LOAD_MAX,
                             MOE_PICKS_ROUTED,

@@ -16,15 +16,17 @@ gauge, and the SLO burn-rate gauges on all three scrape surfaces (same
 pattern as the RESILIENCE / KV_TRANSFER plane registries). ``/debug/prof``
 serves the live top-segment summary.
 
-Two things ride the same switches. STARVED time: between
-``mark_starved()`` (the engine consumed its last tracked fetch while
-requests were live, so the device has nothing queued) and ``mark_fed()``
-(a fetch is tracked again), every slice is also charged to
-``starved[segment]`` -- which host segment ran while the device had
-nothing to do. And, while a ``jax.profiler`` session is on, each segment
-is a ``TraceAnnotation("host/<segment>")`` on the profiler's own clock,
-so a device trace's idle gaps can be labelled by the host segment that
-covers them (tools/trace_gaps.py).
+Two things ride the same switches. STARVED time: before every dispatch
+of a model program the engine asks whether the newest program it
+dispatched has already finished (``poll(dry)``); when it has, what each
+segment ran since the poll before is charged to ``starved[segment]`` --
+which host segments ran while the device had nothing queued. And, while a
+``jax.profiler`` session is on, each segment is a
+``TraceAnnotation("host/<segment>")`` on the profiler's own clock, so a
+device trace's idle gaps can be labelled by the host segment that covers
+them, and each fused round leaves an ``engine/round`` mark at its dispatch
+and at its consume (``mark_round``) that names it by ordinal
+(tools/trace_gaps.py).
 """
 from __future__ import annotations
 
@@ -62,6 +64,9 @@ _N_SEG = len(SEGMENTS)
 _OTHER = _SEG_INDEX["other"]
 ANNOTATION_PREFIX = "host/"
 _ANN_NAMES = tuple(ANNOTATION_PREFIX + s for s in SEGMENTS)
+# a fused round's two marks in a profiler trace (mark_round): no segment,
+# so outside the prefix that tools/trace_gaps.py labels idle gaps by
+ROUND_ANNOTATION = "engine/round"
 
 # host segments run at µs scale — DEFAULT_TIME_BUCKETS' 0.5 ms floor
 # would flatten the whole distribution into one bucket. Same ~1.6x step
@@ -98,25 +103,29 @@ class RoundProf:
     benchmark reads ``totals()`` in every cell, so there is no off mode
     to measure in.
 
-    Starved time is an estimate from the host's side, biased both ways
-    by a few hundred microseconds per event: patches and standalone seals
-    are dispatched without a tracked entry (device work the flag cannot
-    see: counts high), and a prefill is dispatched a little before its
-    ``first`` entry is tracked (the flag clears late: counts high), while
-    a tracked fetch whose program already finished still reads as fed
-    (counts low). Rounds that are not recorded (the idle spin) drop
-    their starved slice: an engine with no requests is idle, not starved.
-    Calibrated against the device trace's idle share in PERF.md.
+    Starved time is an UPPER bound of the time the device stood dry for
+    want of the host. The host learns that the device ran dry only when it
+    next asks (``poll``, before each dispatch of a model program), so a
+    dry poll charges everything the segments ran since the poll before it
+    -- after which a program was dispatched, so the device was fed then --
+    although the device ran for the first part of that stretch. (A fetch
+    that finds its program unfinished, or waits it out, polls too, not
+    dry: the host saw the device busy there.) Patches,
+    standalone seals and page movers are dispatched without a poll and
+    their device time counts as dry too. A stretch that holds an
+    unrecorded round (the idle spin) is dropped whole: an engine with no
+    request is idle, not starved. Calibrated against the device trace's
+    idle share in PERF.md.
     """
 
     RING = 256  # recent per-round records kept for /debug/prof + timeline
 
     def __init__(self):
         self._acc = [0.0] * _N_SEG     # current round, per segment
-        # the part of _acc that ran while the device was starved
-        self._starved_acc = [0.0] * _N_SEG
-        self._starved_dirty = False    # _starved_acc holds something
-        self._starved = False
+        # per-segment sums (total + the open round) at the last poll, and
+        # whether the engine spun idle since: what a dry poll charges
+        self._poll_mark = np.zeros(_N_SEG)
+        self._idle_since_poll = True
         self.starved_total = np.zeros(_N_SEG)
         # profiler annotations: a TraceMe takes its start time when it is
         # CONSTRUCTED, so one is made per switch, and only while a
@@ -152,9 +161,6 @@ class RoundProf:
     def begin_round(self) -> None:
         t = time.monotonic()
         self._acc = [0.0] * _N_SEG
-        if self._starved_dirty:
-            self._starved_acc = [0.0] * _N_SEG
-            self._starved_dirty = False
         self._seg = _OTHER
         self._t = t
         self._t_begin = t
@@ -164,11 +170,7 @@ class RoundProf:
     def _charge(self) -> None:
         """Charge time since the last switch to the current segment."""
         t = time.monotonic()
-        dt = t - self._t
-        self._acc[self._seg] += dt
-        if self._starved:
-            self._starved_acc[self._seg] += dt
-            self._starved_dirty = True
+        self._acc[self._seg] += t - self._t
         self._t = t
 
     def enter(self, seg: int) -> None:
@@ -184,22 +186,29 @@ class RoundProf:
         if self._tracing:
             self._ann_open = self._annotation(_ANN_NAMES[seg])
 
-    def mark_starved(self) -> None:
-        """The device has nothing queued although requests are live:
-        from here on slices are also charged to ``starved[segment]``."""
-        if self._starved:
-            return
+    def poll(self, dry: bool) -> None:
+        """The engine is about to dispatch a model program and found the
+        newest one it dispatched before finished (``dry``) or still
+        queued; or a fetch found its program unfinished (not dry). Dry:
+        what each segment ran since the last poll goes to
+        ``starved[segment]``, unless the engine spun idle in between."""
         if self._in_round:
             self._charge()
-        self._starved = True
+        sums = self.total + self._acc
+        if dry and not self._idle_since_poll:
+            self.starved_total += sums - self._poll_mark
+        self._poll_mark = sums
+        self._idle_since_poll = False
 
-    def mark_fed(self) -> None:
-        """A fetch is tracked again: the device has work."""
-        if not self._starved:
-            return
-        if self._in_round:
-            self._charge()
-        self._starved = False
+    def mark_round(self, **stats) -> None:
+        """While a profiler session is on: one ``engine/round`` mark on
+        the profiler's clock carrying ``stats`` (the round's ordinal and
+        what stood ahead of it at dispatch; its ordinal and gap at
+        consume), so that a kept trace sets the host's gaps beside the
+        device's (tools/trace_gaps.py --rounds)."""
+        if self._tracing:
+            self._annotation(ROUND_ANNOTATION, **stats).__exit__(
+                None, None, None)
 
     def push(self, seg: int) -> int:
         """Nested attribution (e.g. annotation build inside the fetch
@@ -217,9 +226,9 @@ class RoundProf:
             self._ann_open = None
         self._in_round = False
         if not record:
-            return  # idle spin — keep µs no-op rounds out of the stats
-        if self._starved_dirty:
-            self.starved_total += self._starved_acc
+            # idle spin — keep µs no-op rounds out of the stats
+            self._idle_since_poll = True
+            return
         wall = self._t - self._t_begin
         row = self._rec_n % self.RING
         self._ring_acc[row] = self._acc
@@ -318,8 +327,8 @@ class RoundProf:
                 r_wall / n_recent * 1e3, 4) if n_recent else 0.0,
             "coverage_ratio": round(self.coverage(), 4),
             "segments": rows,
-            # device-starved host time (live requests, nothing tracked
-            # in flight) by the segment that was running, hottest first
+            # host time up to a dispatch that found the device dry, by
+            # the segment that ran, hottest first (an upper bound)
             "starved": {
                 "total_s": round(totals["starved"]["total_s"], 6),
                 "share": round(totals["starved"]["total_s"] / wall, 4)
@@ -483,6 +492,7 @@ PROF = ProfRegistry()
 __all__ = [
     "SEGMENTS",
     "ANNOTATION_PREFIX",
+    "ROUND_ANNOTATION",
     "HOST_BUCKETS",
     "HOST_ROUND",
     "COVERAGE",

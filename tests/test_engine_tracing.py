@@ -1,7 +1,10 @@
-"""Engine tracing plane (PR 25): request phases closed where the work
-ends, device-starved time by host segment, work-and-waste counters at
-the dispatch, host segments as profiler annotations, and the per-layer
-readers of ``benchmarks/layer_metrics`` that turn them into metrics.
+"""Engine tracing plane (PR 25, PR 43): request phases closed where the
+work ends (queue, first_token, decode), the time between decode steps and
+what stood ahead of each round, dispatches that found the device dry and
+the host segments that ran up to them, work-and-waste counters at the
+dispatch, host segments and rounds as profiler annotations, and the
+per-layer readers of ``benchmarks/layer_metrics`` that turn them into
+metrics.
 
 One tiny engine serves one scenario per module (a cold wave of three
 prompts, then one of them again); the parametrised cases read what it
@@ -47,6 +50,12 @@ HCRES = "dynamo_hc_sinkhorn_residual"
 CONT = "dynamo_prefill_continued_tokens"
 ROWS_READ = "dynamo_decode_attn_rows_read"
 ROWS_LIVE = "dynamo_decode_attn_rows_live"
+GAP = "dynamo_engine_step_gap_seconds"
+GAP_CLEAN = "dynamo_engine_step_gap_clean_seconds"
+AHEAD = "dynamo_engine_round_prefill_tokens_ahead"
+TPOT = "dynamo_request_tpot_seconds"
+DRY = "dynamo_engine_dispatch_found_dry"
+E2E = "dynamo_request_e2e_seconds"
 
 
 def _engine(**kw) -> TpuEngine:
@@ -105,15 +114,21 @@ def served():
         eng.start()
         h0 = _hists(eng)
         t_sent = time.time()
+        t0 = time.monotonic()
         cold = await asyncio.gather(*[
-            _one(eng, p, received_unix=t_sent if i == 0 else None)
+            _one(eng, p, received_unix=t_sent if i == 0 else None,
+                 request_id=f"rid-{i}")
             for i, p in enumerate(prompts)])
         h1 = await _settled(eng)
         again = await _one(eng, prompts[1])
         h2 = await _settled(eng)
+        wall = time.monotonic() - t0
+        one = await _one(eng, prompts[0], osl=1)   # a one-token answer
+        h3 = await _settled(eng)
         starved = eng.prof.totals()["starved"]
         await eng.stop()
-        return {"cold": cold, "again": again, "h": (h0, h1, h2),
+        return {"cold": cold, "again": again, "one": one,
+                "h": (h0, h1, h2), "h3": h3, "wall": wall,
                 "starved": starved, "flush_every": eng.ecfg.flush_every}
 
     return asyncio.run(scenario())
@@ -212,10 +227,67 @@ async def test_prefill_dispatches_observe_attention_pairs():
     "per_request_queue_plus_first_token_is_ttft",
     "first_token_span_wraps_its_prefill_child",
     "frontend_observed_only_when_stamped",
+    "e2e_is_queue_plus_first_token_plus_decode_and_the_finishing",
+    "decode_span_owns_the_decode_round_spans",
+    "tpot_is_the_decode_phase_over_tokens_less_one",
+    "a_one_token_answer_has_no_decode_phase",
 ])
 def test_request_phases(served, case):
     h0, _, h2 = served["h"]
     anns = [a for _, a in served["cold"]] + [served["again"][1]]
+    if case == "e2e_is_queue_plus_first_token_plus_decode_and_the_finishing":
+        for ann in anns:
+            (ft,), (dec,) = _spans(ann, "first_token"), _spans(ann, "decode")
+            timing = ann["timing"]
+            assert dec["duration_s"] == timing["decode_s"]
+            phases = (timing["queue_s"] + ft["duration_s"]
+                      + dec["duration_s"])
+            # what is left is the finishing bookkeeping after the last
+            # emit, never negative (four values rounded to the microsecond)
+            assert phases <= timing["e2e_s"] + 4e-6
+            assert timing["ttft_s"] + timing["decode_s"] == pytest.approx(
+                phases, abs=4e-6)
+            # the phases follow one another on the wall clock too
+            assert dec["start_s"] == pytest.approx(
+                ft["start_s"] + ft["duration_s"], abs=5e-3)
+        return
+    if case == "decode_span_owns_the_decode_round_spans":
+        for i, ann in enumerate(anns):
+            (dec,) = _spans(ann, "decode")
+            assert not _spans(ann, "decode_round")   # moved under decode
+            rounds = dec["children"]
+            assert {c["name"] for c in rounds} == {"decode_round"}
+            at = dec["attrs"]
+            if i < 3:                   # the repeat drew an id of its own
+                assert at["request_id"] == f"rid-{i}"
+            assert at["request_id"] == _spans(
+                ann, "first_token")[0]["attrs"]["request_id"]
+            assert len(rounds) == at["rounds"] == (
+                ann["timing"]["decode_rounds"])
+            assert sum(c["attrs"]["tokens"] for c in rounds) == (
+                at["tokens"]) == OSL - 1
+            # a request's own prefill stands ahead of its first round
+            assert 1 <= at["rounds_behind_prefill"] <= at["rounds"]
+            assert at["prefill_tokens_ahead"] >= 32
+            assert 0.0 <= at["behind_prefill_s"] <= dec["duration_s"] + 1e-6
+        return
+    if case == "tpot_is_the_decode_phase_over_tokens_less_one":
+        for ann in anns:
+            (dec,) = _spans(ann, "decode")
+            assert ann["timing"]["tpot_s"] == pytest.approx(
+                dec["duration_s"] / (OSL - 1), abs=2e-6)
+        assert _delta(h0, h2, TPOT, "count") == len(anns)
+        assert _delta(h0, h2, TPOT) == pytest.approx(
+            sum(a["timing"]["tpot_s"] for a in anns), abs=1e-5)
+        return
+    if case == "a_one_token_answer_has_no_decode_phase":
+        toks, ann = served["one"]
+        assert len(toks) == 1
+        assert not _spans(ann, "decode") and "tpot_s" not in ann["timing"]
+        assert _spans(ann, "first_token")
+        assert _delta(h2, served["h3"], TPOT, "count") == 0
+        assert _delta(h2, served["h3"], E2E, "count") == 1
+        return
     if case == "histogram_sums_queue_plus_first_token_is_ttft":
         assert _delta(h0, h2, FIRST, "count") == _delta(h0, h2, TTFT, "count")
         assert _delta(h0, h2, Q) + _delta(h0, h2, FIRST) == pytest.approx(
@@ -253,44 +325,240 @@ def test_received_unix_survives_the_wire():
     assert PreprocessedRequest.from_dict(old).received_unix is None
 
 
-# ---- starved time -----------------------------------------------------
+# ---- the time between tokens -------------------------------------------
 
 
-async def test_sleep_between_fetch_and_dispatch_is_starved_admit():
-    """One round in flight at most and no early dispatch: every round's
-    fetch leaves nothing tracked while the slot is live, so admission
-    runs with the device starved and its time is booked to ``admit``.
-    Order and counts, not durations: what a hook ahead of admission sees
-    of the profile's state, round by round."""
+@pytest.mark.parametrize("case", [
+    "clean_plus_behind_is_every_consumed_round",
+    "every_padded_token_stands_ahead_of_exactly_one_round",
+    "the_gaps_fit_inside_the_wall",
+    "every_dispatch_polled_and_the_first_found_the_device_dry",
+])
+def test_token_gaps_of_a_served_wave(served, case):
+    h0, h1, h2 = served["h"]
+    if case == "clean_plus_behind_is_every_consumed_round":
+        rounds = _delta(h0, h2, RTOK, "count")
+        assert rounds > 0 and _delta(h0, h2, GAP, "count") == rounds
+        assert (_delta(h0, h2, GAP_CLEAN, "count")
+                + _delta(h0, h2, AHEAD, "count")) == rounds
+        assert _delta(h0, h2, GAP_CLEAN) <= _delta(h0, h2, GAP)
+    elif case == "every_padded_token_stands_ahead_of_exactly_one_round":
+        # every request decodes, so every prefill has a round after it
+        assert _delta(h0, h2, AHEAD) == _delta(h0, h2, PAD)
+        assert 1 <= _delta(h0, h2, AHEAD, "count") <= _delta(
+            h0, h2, PAD, "count")
+    elif case == "the_gaps_fit_inside_the_wall":
+        assert 0.0 < _delta(h0, h2, GAP) * served["flush_every"] <= (
+            served["wall"])
+    else:
+        # one poll a fused round and a prefill program (no speculation)
+        assert _delta(h0, h2, DRY, "count") == (
+            _delta(h0, h2, LIVE, "count") + _delta(h0, h2, PF, "count"))
+        assert 1 <= _delta(h0, h1, DRY) <= _delta(h0, h1, DRY, "count")
+
+
+class _Clock:
+    """The engine module's ``time`` on a script: every reading inside one
+    scripted action is the same instant."""
+
+    def __init__(self):
+        self.t = 0.0
+
+    def monotonic(self):
+        return self.t
+
+    def time(self):
+        return 1_000_000.0 + self.t
+
+
+class _Handle:
+    def __init__(self, ready):
+        self.ready = ready
+
+    def is_ready(self):
+        return self.ready
+
+
+@pytest.fixture(scope="module")
+def scripted():
+    """An engine that was never started, driven by hand on a scripted
+    clock: three fused rounds (no lane holds a request), two prefill
+    dispatches booked between the first and the second, the second
+    dispatched while the first is in flight, the third after a stretch
+    with none in flight."""
+    from dynamo_tpu.engine import engine as engine_mod
+
+    eng = _engine()
+    clock = _Clock()
+    real, engine_mod.time = engine_mod.time, clock
+    try:
+        h0 = _hists(eng)
+        consumed = []
+
+        def dispatch(t, newest=None):
+            clock.t = t
+            if newest is not None:
+                eng._newest = newest
+            eng._dispatch_round([0], False, False)
+            return eng._entries[-1]
+
+        def consume(t):
+            clock.t = t
+            eng._consume_entry(eng._entries.pop(0))
+            consumed.append(_hists(eng))
+
+        first = dispatch(10.0)                    # nothing tracked: dry
+        eng._newest = _Handle(False)
+        eng._note_prefill_dispatch(20, 32)        # device busy: not dry
+        eng._note_prefill_dispatch(40, 64)
+        second = dispatch(11.0, _Handle(False))   # pipelined: not dry
+        consume(12.0)
+        consume(15.0)
+        third = dispatch(20.0, _Handle(True))     # ran dry meanwhile
+        consume(21.0)
+        return {"h0": h0, "consumed": consumed, "n": eng.ecfg.flush_every,
+                "ahead": [e.ahead for e in (first, second, third)]}
+    finally:
+        engine_mod.time = real
+
+
+@pytest.mark.parametrize("case", [
+    "the_gaps_telescope_to_the_wall",
+    "a_prefill_marks_the_next_dispatched_round_and_no_other",
+    "clean_plus_behind_is_all",
+    "dry_into_an_idle_engine_and_not_in_a_pipelined_steady_state",
+])
+def test_token_gaps_on_a_scripted_run(scripted, case):
+    h0, (c1, c2, c3), n = scripted["h0"], scripted["consumed"], scripted["n"]
+    if case == "the_gaps_telescope_to_the_wall":
+        # walls 12 - 10, 15 - 12 (dispatched at 11, before the first was
+        # consumed) and 21 - 20 (none in flight from 15 to 20)
+        assert _delta(h0, c1, GAP) * n == pytest.approx(2.0, abs=1e-12)
+        assert _delta(c1, c2, GAP) * n == pytest.approx(3.0, abs=1e-12)
+        assert _delta(c2, c3, GAP) * n == pytest.approx(1.0, abs=1e-12)
+        assert _delta(h0, c3, GAP) * n == pytest.approx(
+            (21.0 - 10.0) - (20.0 - 15.0), abs=1e-12)
+    elif case == "a_prefill_marks_the_next_dispatched_round_and_no_other":
+        assert scripted["ahead"] == [(0, 0, 0), (1, 2, 96), (2, 0, 0)]
+        assert _delta(h0, c1, AHEAD, "count") == 0
+        assert (_delta(c1, c2, AHEAD, "count"), _delta(c1, c2, AHEAD)) == (
+            1, 96)
+        assert _delta(c2, c3, AHEAD, "count") == 0
+    elif case == "clean_plus_behind_is_all":
+        assert _delta(h0, c3, GAP, "count") == 3
+        assert _delta(h0, c3, GAP_CLEAN, "count") == 2
+        assert _delta(h0, c3, GAP_CLEAN) * n == pytest.approx(2.0 + 1.0)
+    else:
+        # five polls: round (nothing tracked: dry), two prefills and the
+        # pipelined round (the newest handle not ready), the round after
+        # the engine ran dry
+        assert _delta(h0, c3, DRY, "count") == 5
+        assert _delta(h0, c1, DRY) == 1.0       # all before the first consume
+        assert _delta(c1, c2, DRY) == 0.0
+        assert _delta(c2, c3, DRY) == 1.0
+
+
+def test_e2e_is_the_phases_on_a_scripted_clock():
+    """queue 0.5 + first_token 0.5 + decode 2.0 + 0.25 of finishing
+    bookkeeping = 3.25 s of engine E2E; TPOT = 2.0 / (5 - 1)."""
+    from dynamo_tpu.engine import engine as engine_mod
+    from dynamo_tpu.engine.engine import _Entry, _Request
+
+    eng = _engine()
+    r = _Request(req=PreprocessedRequest(token_ids=[1, 2], request_id="r1"),
+                 seq=None, out=None, loop=None, tokens=[1, 2],
+                 enqueue_time=100.0)
+    clock = _Clock()
+    real, engine_mod.time = engine_mod.time, clock
+    try:
+        h0 = _hists(eng)
+        r.t_prefill_start = 100.5
+        clock.t = 101.0
+        r.first_token_time = r.t_last_emit = clock.t
+        eng._note_first_token(r)
+        r.produced = 5
+        clock.t = 102.0
+        eng._note_emit(r, 2, _Entry("round", None, t_dispatch=101.5,
+                                    ahead=(7, 1, 64)), "decode_round")
+        clock.t = 103.0
+        eng._note_emit(r, 2, _Entry("round", None, t_dispatch=102.5),
+                       "decode_round")
+        clock.t = 103.25
+        ann = eng._final_annotations(r)
+        h1 = _hists(eng)
+    finally:
+        engine_mod.time = real
+    timing = ann["timing"]
+    (ft,), (dec,) = _spans(ann, "first_token"), _spans(ann, "decode")
+    assert timing["e2e_s"] == 3.25 == (
+        timing["queue_s"] + ft["duration_s"] + dec["duration_s"] + 0.25)
+    assert (timing["queue_s"], ft["duration_s"], dec["duration_s"]) == (
+        0.5, 0.5, 2.0)
+    assert dec["start_s"] == ft["start_s"] + ft["duration_s"]
+    assert timing["tpot_s"] == 0.5
+    assert (_delta(h0, h1, TPOT, "count"), _delta(h0, h1, TPOT)) == (1, 0.5)
+    assert dec["attrs"] == {
+        "request_id": "r1", "tokens": 4, "rounds": 2,
+        "rounds_behind_prefill": 1, "behind_prefill_s": 1.0,
+        "prefill_tokens_ahead": 64}
+    assert [(c["name"], c["duration_s"], c["attrs"]["tokens"])
+            for c in dec["children"]] == [("decode_round", 0.5, 2)] * 2
+
+
+# ---- dispatches that found the device dry, and starved time -------------
+
+
+async def test_one_round_in_flight_finds_the_device_dry_at_every_round():
+    """One round in flight at most and no early dispatch: every round is
+    dispatched after the only round in flight was consumed, so each finds
+    the device dry, and what ran since the dispatch before it -- the
+    blocking fetch, admission -- is booked as starved by segment."""
     eng = _engine(max_inflight_rounds=0, round_pipeline=False)
-    admit = eng._admit
-    ADMIT = SEGMENTS.index("admit")
-    seen = []            # (device starved?, open segment, decode slot live?)
-
-    def watched_admit():
-        seen.append((eng.prof._starved, eng.prof._seg,
-                     bool(eng._slot_active.any())))
-        admit()
-
-    eng._admit = watched_admit
     eng.start()
+    h0 = _hists(eng)
     toks, _ = await _one(eng, list(range(1, 30)), osl=17)
+    h1 = await _settled(eng)
     await eng.stop()
     t = eng.prof.totals()
     rounds = -(-(17 - 1) // eng.ecfg.flush_every)
-    assert len(toks) == 17
-    # admission always runs inside its own segment
-    assert all(seg == ADMIT for _, seg, _ in seen)
-    # with a decode slot live, the blocking fetch ahead of admission has
-    # consumed the only round in flight: starved every time, and at
-    # least once for each round but the last (whose fetch ends the slot)
-    live = [starved for starved, _, slot_live in seen if slot_live]
-    assert all(live) and len(live) >= rounds - 1
+    assert len(toks) == 17 and _delta(h0, h1, LIVE, "count") == rounds
+    # the prefill (into an idle engine) and every round but perhaps the
+    # first, which may catch the prefill still running
+    assert _delta(h0, h1, DRY, "count") == rounds + 1
+    assert _delta(h0, h1, DRY) >= rounds
     starved = t["starved"]["segments"]
-    assert starved["admit"] > 0.0
+    assert starved["fetch"] > 0.0
     assert t["starved"]["total_s"] == pytest.approx(sum(starved.values()))
     # a subset of the segment's own time, never more
     assert all(starved[s] <= t["segments"][s] + 1e-9 for s in SEGMENTS)
+
+
+def test_a_fetch_that_finds_its_program_unfinished_moves_the_mark():
+    """The host saw the device busy: a dry dispatch after it charges only
+    what ran since, not the stretch back to the dispatch before."""
+    from dynamo_tpu.engine.engine import _Entry
+
+    eng = _engine()
+    clock = _Clock()
+    real, tprof.time = tprof.time, clock
+    try:
+        p = eng.prof
+        p.begin_round()
+        p.enter(SEGMENTS.index("fetch"))
+        p.poll(False)                          # a dispatch, at t = 0
+        clock.t = 5.0
+        eng._entries = [_Entry("round", _Handle(False))]
+        eng._process_entries()                 # not ready: busy at t = 5
+        assert len(eng._entries) == 1          # and left in flight
+        clock.t = 6.0
+        eng._newest = _Handle(True)
+        eng._poll_dry()                        # dry at t = 6
+        p.end_round()
+    finally:
+        tprof.time = real
+    t = p.totals()
+    assert t["starved"]["segments"]["fetch"] == 1.0
+    assert t["segments"]["fetch"] == 6.0
 
 
 async def test_idle_engine_records_no_starved_time(served):
@@ -299,32 +567,50 @@ async def test_idle_engine_records_no_starved_time(served):
     await asyncio.sleep(0.15)             # spins idle, nothing to serve
     await eng.stop()
     assert eng.prof.totals()["starved"]["total_s"] == 0.0
+    assert _hists(eng)[DRY]["count"] == 0     # nothing was dispatched
     # the pipelined scenario kept a round in flight: starved stays a
     # small part of the wall it was measured over
     assert served["starved"]["total_s"] >= 0.0
 
 
-@pytest.mark.parametrize("record", [True, False])
-def test_roundprof_starved_slices(record):
-    p = RoundProf()
-    i, j = SEGMENTS.index("fetch"), SEGMENTS.index("admit")
-    p.begin_round()
-    p.enter(i)
-    time.sleep(0.002)
-    p.mark_starved()                      # splits the open fetch slice
-    time.sleep(0.002)
-    p.enter(j)
-    time.sleep(0.003)
-    p.mark_fed()
-    time.sleep(0.002)
-    p.end_round(record=record)
+@pytest.mark.parametrize("case", [
+    "a_dry_poll_charges_the_stretch_since_the_poll_before",
+    "a_stretch_with_an_idle_spin_in_it_is_dropped",
+])
+def test_roundprof_poll_on_a_scripted_clock(case):
+    clock = _Clock()
+    real, tprof.time = tprof.time, clock
+    try:
+        p = RoundProf()
+        i, j = SEGMENTS.index("fetch"), SEGMENTS.index("admit")
+        p.begin_round()
+        p.enter(i)
+        clock.t = 2.0
+        p.poll(False)                     # still fed: only moves the mark
+        clock.t = 3.0
+        p.enter(j)
+        clock.t = 7.0
+        p.poll(True)                      # dry: fetch 1 + admit 4
+        clock.t = 8.0
+        p.poll(True)                      # dry again: admit 1, no more
+        clock.t = 9.0
+        p.end_round()
+        if case == "a_stretch_with_an_idle_spin_in_it_is_dropped":
+            p.begin_round()
+            clock.t = 15.0
+            p.end_round(record=False)     # nothing live: the idle spin
+            p.begin_round()
+            p.enter(j)
+            clock.t = 25.0
+            p.poll(True)                  # dry for want of work
+            p.end_round()
+    finally:
+        tprof.time = real
     t = p.totals()
-    if not record:
-        assert t["starved"]["total_s"] == 0.0 and t["rounds"] == 0
-        return
     s = t["starved"]["segments"]
-    assert 0.002 <= s["fetch"] <= t["segments"]["fetch"] - 0.002
-    assert 0.003 <= s["admit"] <= t["segments"]["admit"] - 0.002
+    assert (s["fetch"], s["admit"]) == (1.0, 5.0)
+    assert t["starved"]["total_s"] == 6.0
+    assert t["segments"]["fetch"] == 3.0
     assert p.summary()["starved"]["segments"].keys() == {"fetch", "admit"}
 
 
@@ -335,9 +621,9 @@ class _StubAnnotation:
     log: list = []
     enabled = True
 
-    def __init__(self, name):
+    def __init__(self, name, **stats):
         self.name = name
-        _StubAnnotation.log.append(("open", name))
+        _StubAnnotation.log.append(("open", name, *sorted(stats.items())))
 
     def __exit__(self, *exc):
         _StubAnnotation.log.append(("close", self.name))
@@ -358,20 +644,42 @@ def test_annotations_balanced_across_enter_push_end(session):
         p.enter(SEGMENTS.index("fetch"))
         prev = p.push(SEGMENTS.index("annotate"))
         p.enter(prev)
-        p.mark_starved()
+        p.poll(True)
         p.enter(SEGMENTS.index("admit"))
-        p.mark_fed()
+        p.poll(False)
         p.end_round(record=record)
     log = _StubAnnotation.log
     if not session:
         assert log == []
         return
-    opens = [n for k, n in log if k == "open"]
+    opens = [ev[1] for ev in log if ev[0] == "open"]
     assert opens == ["host/fetch", "host/annotate", "host/fetch",
                      "host/admit"] * 2
     # strictly alternating: never two segments open at once, none left
-    assert [k for k, _ in log] == ["open", "close"] * len(opens)
+    assert [ev[0] for ev in log] == ["open", "close"] * len(opens)
     assert all(log[i][1] == log[i + 1][1] for i in range(0, len(log), 2))
+
+
+@pytest.mark.parametrize("session", [True, False])
+def test_round_marks_carry_their_stats_only_in_a_session(session):
+    _StubAnnotation.log = []
+    _StubAnnotation.enabled = session
+    p = RoundProf()
+    p._annotation = _StubAnnotation
+    p.begin_round()
+    p.enter(SEGMENTS.index("dispatch"))
+    p.mark_round(dispatched=7, programs_ahead=1, padded_tokens_ahead=64)
+    p.end_round()
+    marks = [ev for ev in _StubAnnotation.log
+             if ev[1] == tprof.ROUND_ANNOTATION]
+    if not session:
+        assert _StubAnnotation.log == []
+        return
+    # opened and closed at once, inside the open segment
+    assert marks == [
+        ("open", "engine/round", ("dispatched", 7),
+         ("padded_tokens_ahead", 64), ("programs_ahead", 1)),
+        ("close", "engine/round")]
 
 
 def test_segments_land_on_the_profilers_host_plane(tmp_path):
@@ -392,9 +700,19 @@ def test_segments_land_on_the_profilers_host_plane(tmp_path):
         p.enter(SEGMENTS.index("admit"))
         time.sleep(0.002)
         p.enter(SEGMENTS.index("dispatch"))
+        p.mark_round(dispatched=7, programs_ahead=1, padded_tokens_ahead=64)
+        p.mark_round(consumed=7, wall_us=1234, steps=4)
         p.end_round()
     finally:
         jax.profiler.stop_trace()
+    # the round marks come back with their stats, keyed by ordinal
+    gaps = _trace_gaps()
+    modules, dispatched, consumed = gaps.read_rounds(
+        find_xplane(str(tmp_path)))
+    assert modules == []                  # no chip here
+    assert {o: v[1:] for o, v in dispatched.items()} == {7: (1, 64)}
+    assert {o: v[1:] for o, v in consumed.items()} == {7: (1_234_000, 4)}
+    assert dispatched[7][0] <= consumed[7][0]
     data = ProfileData.from_file(find_xplane(str(tmp_path)))
     found = {ev.name: ev.duration_ns
              for plane in data.planes if plane.name.startswith("/host:")
@@ -429,7 +747,8 @@ def _sources():
         RTOK: (300.0, 20), ALIVE: (1e6, 4), ASCORED: (4e6, 4),
         TOUCHED: (1000.0, 20), ROUTED: (3000.0, 20), LOADMAX: (100.0, 20),
         HCRES: (2e-6, 20), CONT: (500.0, 4), ROWS_READ: (1e6, 20),
-        ROWS_LIVE: (4e5, 20)},
+        ROWS_LIVE: (4e5, 20), GAP: (0.5, 20), GAP_CLEAN: (0.2, 15),
+        AHEAD: (10000.0, 5), TPOT: (0.3, 10), DRY: (1.0, 24)},
         1.0)
     after = snap(150.0, {
         FRONT: (1.5, 60), FIRST: (30.0, 60), PF: (17000.0, 54),
@@ -437,7 +756,9 @@ def _sources():
         RTOK: (2700.0, 120), ALIVE: (7e6, 54), ASCORED: (19e6, 54),
         TOUCHED: (205800.0, 120), ROUTED: (617400.0, 120),
         LOADMAX: (700.0, 120), HCRES: (3.2e-5, 120), CONT: (6900.0, 54),
-        ROWS_READ: (9e6, 120), ROWS_LIVE: (2.4e6, 120)}, 3.5)
+        ROWS_READ: (9e6, 120), ROWS_LIVE: (2.4e6, 120),
+        GAP: (3.0, 120), GAP_CLEAN: (1.0, 95), AHEAD: (110000.0, 25),
+        TPOT: (1.8, 60), DRY: (4.0, 174)}, 3.5)
     return {"before": before, "after": after,
             "engine_up": {"flush_every": 4},
             "config": {"engine": {"max_decode_slots": 8},
@@ -463,6 +784,16 @@ READERS = {
     "hc.sinkhorn_residual_max": (3e-5 / 100, [HCRES]),
     "step.prefill_continued_share": (6400 / 16000 * 100, [CONT]),
     "step.decode_attn_live_share": (2e6 / 8e6 * 100, [ROWS_READ]),
+    # PR 43: 100 rounds consumed, 80 of them clean at 10 ms a step and
+    # 20 behind 5000 padded tokens each, 2.5 s of gaps in all
+    "sched.step_gap_ms_mean": (2.5 / 100 * 1e3, [GAP]),
+    "sched.step_gap_clean_ms_mean": (0.8 / 80 * 1e3, [GAP_CLEAN]),
+    "sched.gap_behind_prefill_share": ((2.5 - 100 * 0.01) / 2.5 * 100,
+                                       [GAP_CLEAN]),
+    "sched.rounds_behind_prefill_share": (20 / 100 * 100, [AHEAD]),
+    "sched.prefill_ktok_ahead_mean": (100000 / 20 / 1e3, [AHEAD]),
+    "sched.tpot_engine_ms_mean": (1.5 / 50 * 1e3, [TPOT]),
+    "sched.dispatch_dry_share": (3.0 / 150 * 100, [DRY]),
 }
 
 
@@ -659,11 +990,62 @@ def test_benchmark_json_names_every_new_reader():
 # ---- tools/trace_gaps.py ---------------------------------------------
 
 
-def test_trace_gaps_labels_by_the_covering_segment():
+def _trace_gaps():
     spec = importlib.util.spec_from_file_location(
         "trace_gaps", os.path.join(REPO, "tools", "trace_gaps.py"))
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
+    return mod
+
+
+def test_trace_gaps_sets_the_hosts_round_walls_beside_the_devices():
+    """Four fused rounds on the first chip of which the trace's marks
+    name the last three (ordinals 41-43; the first ran a round dispatched
+    before the session began), a prefill between the second and the third.
+    One offset pairs them: at any other a round would start before its
+    dispatch mark or end after its consume mark."""
+    mod = _trace_gaps()
+    ms = 1_000_000
+    R, P = mod.ROUND_MODULE, "jit_prefill_impl"
+    modules = [(R, 0, 20 * ms), (R, 20 * ms, 40 * ms),
+               (P, 40 * ms, 70 * ms), ("jit_patch", 70 * ms, 71 * ms),
+               (R, 72 * ms, 92 * ms), (R, 92 * ms, 112 * ms)]
+    dispatched = {41: (5 * ms, 0, 0), 42: (38 * ms, 1, 2048),
+                  43: (60 * ms, 0, 0)}
+    consumed = {41: (41 * ms, 21 * ms, 4), 42: (93 * ms, 52 * ms, 4),
+                43: (113 * ms, 20 * ms, 4)}
+    out = mod.rounds_report(modules, dispatched, consumed)
+    assert (out["offset"], out["marks_contradicted"]) == (1, 0)
+    by = {r["ordinal"]: r for r in out["rounds"]}
+    assert sorted(by) == [41, 42, 43]
+    behind = by[42]
+    assert behind["device_s"] == pytest.approx(0.052)
+    assert behind["between_s"] == {P: pytest.approx(0.030),
+                                   "jit_patch": pytest.approx(0.001)}
+    assert behind["idle_s"] == pytest.approx(0.001)
+    assert behind["round_s"] == pytest.approx(0.020)
+    assert behind["host_s"] == pytest.approx(0.052)
+    means = out["means"]
+    assert means["clean"]["rounds"] == 2 and (
+        means["behind_prefill"]["rounds"]) == 1
+    assert means["clean"]["device_step_s"] == pytest.approx(0.005)
+    assert means["clean"]["abs_diff_s"] == pytest.approx(0.0005)
+    assert means["all"]["host_s"] == pytest.approx(0.031)
+    # two rounds always in flight: every round starts after the NEXT one's
+    # dispatch mark, so an offset one too low contradicts nothing either;
+    # the last offset that contradicts nothing is the one
+    deep = [(R, i * 20 * ms, (i + 1) * 20 * ms) for i in range(6)]
+    d2 = {o: ((o - 10) * 20 * ms - 30 * ms, 0, 0) for o in range(10, 15)}
+    c2 = {o: ((o - 10) * 20 * ms + 61 * ms, 20 * ms, 4)
+          for o in range(10, 15)}
+    assert mod.pair_rounds(deep, d2, c2) == (2, 0)
+    # no marks, or no round: nothing paired and nothing raised
+    assert mod.rounds_report(modules, {}, {})["rounds"] == []
+    assert mod.rounds_report([], dispatched, consumed)["rounds"] == []
+
+
+def test_trace_gaps_labels_by_the_covering_segment():
+    mod = _trace_gaps()
     ms = 1_000_000
     ops = {"/device:TPU:0": [(0, 4 * ms), (6 * ms, 10 * ms), (13 * ms, 20 * ms)],
            # this chip's trace starts 3 ms late and stops 1 ms early

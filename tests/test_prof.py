@@ -283,9 +283,9 @@ def _best_us(fn, calls: int = 200, repeats: int = 30) -> float:
 async def test_attribution_overhead_within_5pct():
     """The always-on claim, per call: one host-loop round of the
     attribution plane (begin, 15 segment switches, end; the annotation
-    branch and the starved accounting included) costs <= 20 µs, and what
-    a dispatch adds (mark_fed + the two prefill token observes, the most
-    any dispatch site makes) <= 5 µs. A wall-clock A/B of two engines
+    branch included) costs <= 20 µs, and what a dispatch adds (the dry
+    poll with its observe + the two prefill token observes, the most
+    any dispatch site makes) <= 8 µs. A wall-clock A/B of two engines
     under parallel test workers is not a measurement; this is, and the
     absolute steady-decode pin on a real engine stays."""
     from dynamo_tpu.telemetry import TelemetryRegistry, request_histograms
@@ -299,21 +299,22 @@ async def test_attribution_overhead_within_5pct():
             p.enter(i % n_seg)
         p.end_round()
 
-    fed = _best_us(one_round)
-    p.mark_starved()                      # the dearer branch of _charge
-    starved = _best_us(one_round)
-    assert max(fed, starved) <= 20.0, (fed, starved)
+    assert _best_us(one_round) <= 20.0
 
     reg = request_histograms(TelemetryRegistry(), engine=True)
     real = reg.get("dynamo_engine_prefill_tokens")
     padded = reg.get("dynamo_engine_prefill_padded_tokens")
+    dry = reg.get("dynamo_engine_dispatch_found_dry")
+    p.begin_round()
 
     def one_dispatch():
         real.observe(319)
         padded.observe(512)
-        p.mark_fed()
+        dry.observe(1.0)
+        p.poll(True)                      # the dearer branch: it charges
 
-    assert _best_us(one_dispatch) <= 5.0
+    assert _best_us(one_dispatch) <= 8.0
+    p.end_round()
 
     # steady-decode host budget pin: the generous tiny-harness ceiling
     # (typical ~1-5 ms/round on CPU; regressions land well above)

@@ -114,6 +114,11 @@ def test_registry_render_and_snapshot():
         "dynamo_decode_attn_rows_read", "dynamo_decode_attn_rows_live",
         "dynamo_moe_picks_routed",
         "dynamo_ssm_state_bytes",
+        "dynamo_request_tpot_seconds",
+        "dynamo_engine_step_gap_seconds",
+        "dynamo_engine_step_gap_clean_seconds",
+        "dynamo_engine_round_prefill_tokens_ahead",
+        "dynamo_engine_dispatch_found_dry",
     }
     reg.get("dynamo_request_ttft_seconds").observe(0.2)
     text = reg.render()
@@ -388,8 +393,11 @@ async def test_frontend_span_tree_and_histograms(tiny_routed_manager):
         assert tree["trace_id"] == rid and tree["finished"]
         names = [s["name"] for s in tree["spans"]]
         for expected in ("tokenize", "route", "queue", "first_token",
-                         "decode_round"):
+                         "decode"):
             assert expected in names, (expected, names)
+        # the per-round spans hang under the decode phase
+        dec = next(s for s in tree["spans"] if s["name"] == "decode")
+        assert {c["name"] for c in dec["children"]} == {"decode_round"}
         # the dispatch-only prefill span hangs under first_token
         ft = next(s for s in tree["spans"] if s["name"] == "first_token")
         assert [c["name"] for c in ft["children"]] == ["prefill"]
