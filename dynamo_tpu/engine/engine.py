@@ -3679,7 +3679,9 @@ class TpuEngine:
             })
         t_disp = time.monotonic()
         self.dispatch_counts["prefill_batch"] += 1
-        self._note_prefill_dispatch(sum(chunk_lens), K * width)
+        self._note_prefill_dispatch(
+            sum(chunk_lens), llama.prefill_positions_run(
+                self.config, width, q_starts, seq_lens))
         self._observe_attn_pairs(width, q_starts, seq_lens, ctx_span)
         self.ctx, logits = llama.batch_prefill(
             self.config, self.params, self.ctx, jnp.asarray(toks),
@@ -3704,8 +3706,10 @@ class TpuEngine:
 
     def _note_prefill_dispatch(self, real: int, padded: int) -> None:
         """The books of one prefill program about to be dispatched: the
-        prompt tokens it computes and the positions it runs, which then
-        stand ahead of the next fused round on the device's one queue."""
+        prompt tokens it computes and the positions it runs (a dense
+        chunk's, from ``llama.prefill_positions_run``: its live row
+        blocks where the program loops over them), which then stand
+        ahead of the next fused round on the device's one queue."""
         self._poll_dry()
         self._h_pf_tokens.observe(real)
         self._h_pf_padded.observe(padded)
@@ -3925,7 +3929,9 @@ class TpuEngine:
             })
         t_disp = time.monotonic()
         self.dispatch_counts["prefill"] += 1
-        self._note_prefill_dispatch(len(chunk), pad_t)
+        self._note_prefill_dispatch(
+            len(chunk), llama.prefill_positions_run(
+                self.config, pad_t, [start], [start + len(chunk)]))
         self._observe_attn_pairs(
             pad_t, [start], [start + len(chunk)],
             e.max_context if start else 0)

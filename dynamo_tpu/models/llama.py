@@ -32,6 +32,10 @@ Design notes (TPU-first, round-4 layout):
   - Prefill is B=1 over a padded token bucket (positions q_start..q_start+T);
     decode is a fixed-slot batch, one token per slot. Both are jittable with
     static shapes; the engine buckets prompt lengths to bound recompiles.
+    Inside a wide bucket the work follows the live rows, not the bucket:
+    the attention and the two row-wise halves of a layer each loop over
+    the row blocks that hold a prompt token (prefill_attention,
+    _live_rows), with trip counts that are values of the one program.
 
 THE SEAM (ROADMAP D2). These names are what the engine and the
 benchmark's launcher call. Three blocks hang on them (``block_of``): a
@@ -710,6 +714,107 @@ def _layer_body(c: ModelConfig, lp, h, cos, sin, write_kv, attend,
     return _layer_out(c, lp, h, attn, ffn_valid, ad), new_cache
 
 
+# Height R of the row blocks a dense prefill chunk's two row-wise halves
+# (_layer_qkv, _layer_out) loop over; chosen on the chip
+# (tools/prefill_rows_bench.py, PERF.md section 6, PR 44).
+LIVE_ROW_BLOCK = 512
+
+
+def live_row_block(c: ModelConfig, T: int, tree: bool = False) -> int:
+    """Rows a block of ``_live_rows`` for a prefill chunk of bucket width
+    ``T``, or 0 where the halves run straight-line over all T rows: a
+    bucket of one or two blocks (nothing worth a loop), a packed token
+    tree (live nodes are no prefix of the chunk), the capacity-bounded
+    expert layer (rows compete for capacity, so no row block stands
+    alone) and the block models, which have their own halves. Decided by
+    the shape and by what the program is given, never by a model's
+    name."""
+    R = LIVE_ROW_BLOCK
+    if tree or c.moe is not None or block_of(c) is not None:
+        return 0
+    return R if T % R == 0 and T // R > 2 else 0
+
+
+def live_row_trips(q_starts, seq_lens, T: int, R: int):
+    """Row blocks of each lane that hold a live row: ``ceil(n / R)`` of
+    its ``n = seq_len - q_start`` live rows, none for a dummy lane.
+    numpy in (the engine's mirror), numpy out; traced in, traced out."""
+    return ((seq_lens - q_starts).clip(0, T) + (R - 1)) // R
+
+
+def prefill_positions_run(c: ModelConfig, T: int, q_starts,
+                          seq_lens) -> int:
+    """Token positions one dense prefill dispatch of ``len(q_starts)``
+    lanes at bucket width ``T`` runs its matmuls over — the host's mirror
+    of ``_live_rows``' trip count: live row blocks x block height where
+    the program loops over them, lanes x width where it does not."""
+    R = live_row_block(c, T)
+    if not R:
+        return len(q_starts) * T
+    trips = live_row_trips(np.asarray(q_starts, np.int64),
+                           np.asarray(seq_lens, np.int64), T, R)
+    return int(trips.sum()) * R
+
+
+@functools.partial(jax.jit, static_argnames=("half", "c", "R"))
+def _live_rows(half, c: ModelConfig, layers, l, ad, trips, rows, R: int):
+    """One row-wise half of decoder layer ``l`` (``half`` = _layer_qkv or
+    _layer_out: no row of its output depends on another row) over the
+    row blocks that hold a live row, instead of over all K x T bucket
+    rows. ``rows`` are the half's per-row operands, each [K, T, ...];
+    ``layers`` the STACKED weights of every layer and ``l`` the layer as
+    a value; ``ad`` the layer's adapter factors with a leading lane axis
+    ({site: (a [K, d, r], b [K, r, o])}) or None; ``trips`` [K] =
+    live_row_trips. Returns the half's outputs as a tuple, each
+    [K, T, ...]; rows of blocks that never ran are 0 (the attention, the
+    KV scales, the logits and every reader of the region stop at the
+    live length).
+
+    ONE rolled loop whose trip count is a traced value, over the flat
+    work list of (lane, row block) pairs — the list prefill_attention
+    builds for its query blocks. The layer is a value and the weights
+    are arguments, so the layers of a program, unrolled in the caller,
+    share one traced and lowered loop body, and the lowered program has
+    the same size at every bucket width and lane count.
+
+    The body slices its layer's weights out of the stack ITSELF, by an
+    index the compiler cannot see is the same at every trip
+    (optimization_barrier): a slice that is loop-invariant is hoisted,
+    and XLA:TPU then copies the layer's weights (218 MB of int8 at
+    Mistral-7B's widths) in front of every loop. Sliced inside, the
+    slice and the int8 -> bf16 convert fuse into the matmul, as in the
+    straight-line code (compile-only, PERF.md section 6, PR 44)."""
+    i32 = jnp.int32
+    trips = trips.astype(i32)
+    ends = jnp.cumsum(trips)
+
+    def half_of_block(lane, r0):
+        blk = [jax.lax.dynamic_slice(
+            x, (lane, r0) + (0,) * (x.ndim - 2), (1, R) + x.shape[2:])[0]
+            for x in rows]
+        ad_lane = None if ad is None else jax.tree.map(
+            lambda x: jax.lax.dynamic_index_in_dim(x, lane, keepdims=False),
+            ad)
+        lb, _ = jax.lax.optimization_barrier((l, r0))
+        lp = jax.tree.map(
+            lambda x: jax.lax.dynamic_index_in_dim(x, lb, keepdims=False),
+            layers)
+        return jax.tree.leaves(half(c, lp, *blk, ad=ad_lane))
+
+    def run(w, outs):
+        lane = jnp.sum(w >= ends).astype(i32)
+        r0 = (w - (ends[lane] - trips[lane])) * R
+        return tuple(
+            jax.lax.dynamic_update_slice(
+                o, y[None], (lane, r0) + (0,) * (y.ndim - 1))
+            for o, y in zip(outs, half_of_block(lane, r0)))
+
+    K, T = rows[0].shape[:2]
+    shapes = jax.eval_shape(half_of_block, i32(0), i32(0))
+    outs = tuple(jnp.zeros((K, T) + s.shape[1:], s.dtype) for s in shapes)
+    return jax.lax.fori_loop(0, ends[-1], run, outs)
+
+
 def _logits(config: ModelConfig, params: Params, h: jnp.ndarray) -> jnp.ndarray:
     h = rms_norm(h, params["norm_f"], config.rms_norm_eps)
     w = params["embed"] if config.tie_word_embeddings else params["lm_head"]
@@ -784,29 +889,39 @@ def prefill_impl(
     # the donated update chain aliases in place (interleaved write/read of
     # the GB-scale buffer would force XLA to materialize copies of it).
     ag = _gather_adapters(params.get("adapters"), adapter_id)
+    one = lambda x: jnp.asarray(x)[None]  # noqa: E731 — K = 1
+    # a wide bucket runs the two row-wise halves of a layer over its live
+    # row blocks only, as the one lane of _live_rows
+    R = live_row_block(c, T)
+    trips = live_row_trips(one(q_start), one(seq_len), T, R) if R else None
+
+    def live(half, l, *rows):
+        out = _live_rows(
+            half, c, params["layers"], jnp.int32(l),
+            jax.tree.map(one, _adapter_layer(ag, l, per_row=False)), trips,
+            tuple(x[None] for x in rows), R)
+        return tuple(x[0] for x in out)
+
     new_ks: list[jnp.ndarray] = []
     new_vs: list[jnp.ndarray] = []
     for l in range(c.num_layers):
-        lp = jax.tree.map(lambda x: x[l], params["layers"])
-
-        def write_kv(k, v):
-            new_ks.append(k)
-            new_vs.append(v)
-            return (k, v)
-
-        def attend(q, kv, l=l):
-            k_new, v_new = kv
-            one = lambda x: jnp.asarray(x)[None]  # noqa: E731 — K = 1
-            return prefill_attention(
-                q[None], k_new[None], v_new[None], one(q_start),
-                one(seq_len),
-                None if fresh else _prior_context(ctx_kv, l, one(slot)),
-            )[0]
-
-        # padding tokens must not claim MoE expert capacity
-        h, _ = _layer_body(c, lp, h, cos, sin, write_kv, attend,
-                           ffn_valid=positions < seq_len,
-                           ad=_adapter_layer(ag, l, per_row=False))
+        if R:
+            q, k, v = live(_layer_qkv, l, h, cos, sin)
+        else:
+            lp = jax.tree.map(lambda x: x[l], params["layers"])
+            ad = _adapter_layer(ag, l, per_row=False)
+            q, k, v = _layer_qkv(c, lp, h, cos, sin, ad)
+        new_ks.append(k)
+        new_vs.append(v)
+        attn = prefill_attention(
+            q[None], k[None], v[None], one(q_start), one(seq_len),
+            None if fresh else _prior_context(ctx_kv, l, one(slot)),
+        )[0]
+        if R:
+            h, = live(_layer_out, l, h, attn)
+        else:
+            # padding tokens must not claim MoE expert capacity
+            h = _layer_out(c, lp, h, attn, positions < seq_len, ad)
 
     # tail: one contiguous span write per buffer (all reads are done)
     upd_k = jnp.stack(new_ks).transpose(0, 2, 1, 3)  # [L, kvh, T, hd]
@@ -866,7 +981,11 @@ def _batch_forward(
     so a tp-sharded layer keeps two all-reduces over [K, T, hidden] —
     and between them ONE prefill_attention call takes all lanes with
     their q_starts and seq_lens, so a dummy lane or a short prompt costs
-    no attention. ``ctx_span`` 0 compiles no read of the region.
+    no attention. ``ctx_span`` 0 compiles no read of the region. A
+    bucket of more than two row blocks (live_row_block) runs the halves
+    over its live (lane, row block) pairs instead (_live_rows: the
+    all-reduces then sit in the loop, one a block), so a dummy lane or
+    a short prompt costs no matmul either.
 
     Tree mode (``depths``/``chunk_masks`` given, always together): the
     chunk is a packed token TREE, not a linear run — node t's RoPE
@@ -896,18 +1015,29 @@ def _batch_forward(
         node_valid = (positions < seq_lens[:, None]) & (depths >= 0)
     cos, sin = jax.vmap(lambda p: rope_cos_sin(p, inv_freq))(positions)
     h = jax.vmap(lambda t: _embed_rows(params, t, cdt))(tokens)
+    # wide linear chunks run each half over their live row blocks only
+    R = live_row_block(c, T, tree=depths is not None)
+    trips = live_row_trips(q_starts, seq_lens, T, R) if R else None
+
+    def live(half, l, *rows):
+        return _live_rows(half, c, params["layers"], jnp.int32(l),
+                          _adapter_layer(ag, l, per_row=True), trips, rows, R)
+
     new_ks: list[jnp.ndarray] = []
     new_vs: list[jnp.ndarray] = []
     for l in range(c.num_layers):
-        lp = jax.tree.map(lambda x: x[l], params["layers"])
+        if R:
+            q, k, v = live(_layer_qkv, l, h, cos, sin)
+        else:
+            lp = jax.tree.map(lambda x: x[l], params["layers"])
 
-        def ad(ag_row, l=l):
-            return _adapter_layer(ag_row, l, per_row=False)
+            def ad(ag_row, l=l):
+                return _adapter_layer(ag_row, l, per_row=False)
 
-        q, k, v = jax.vmap(
-            lambda h, cos, sin, ag_row: _layer_qkv(
-                c, lp, h, cos, sin, ad(ag_row))
-        )(h, cos, sin, ag)
+            q, k, v = jax.vmap(
+                lambda h, cos, sin, ag_row: _layer_qkv(
+                    c, lp, h, cos, sin, ad(ag_row))
+            )(h, cos, sin, ag)
         new_ks.append(k)
         new_vs.append(v)
         attn = prefill_attention(
@@ -915,10 +1045,13 @@ def _batch_forward(
             _prior_context(ctx_kv, l, slots) if ctx_span > 0 else None,
             chunk_masks, ctx_span=ctx_span,
         )
-        h = jax.vmap(
-            lambda h, attn, valid, ag_row: _layer_out(
-                c, lp, h, attn, valid, ad(ag_row))
-        )(h, attn, node_valid, ag)
+        if R:
+            h, = live(_layer_out, l, h, attn)
+        else:
+            h = jax.vmap(
+                lambda h, attn, valid, ag_row: _layer_out(
+                    c, lp, h, attn, valid, ad(ag_row))
+            )(h, attn, node_valid, ag)
     return (
         jnp.stack(new_ks, axis=1).astype(cdt),
         jnp.stack(new_vs, axis=1).astype(cdt),

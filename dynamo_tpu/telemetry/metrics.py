@@ -426,8 +426,10 @@ DISPATCH_DRY = ("dynamo_engine_dispatch_found_dry",
 PREFILL_TOKENS = ("dynamo_engine_prefill_tokens",
                   "real prompt tokens computed per prefill dispatch")
 PREFILL_PADDED = ("dynamo_engine_prefill_padded_tokens",
-                  "token positions a prefill dispatch ran: lanes of the "
-                  "compiled group (dummies included) x bucket width")
+                  "token positions a prefill dispatch ran: live row "
+                  "blocks x block height where the program loops over "
+                  "them, lanes of the compiled group (dummies included) "
+                  "x bucket width otherwise")
 PREFILL_MATCHED = ("dynamo_engine_prefill_matched_tokens",
                    "prompt tokens served from a prefix match (HBM or "
                    "host tier) per request starting its prefill")

@@ -219,6 +219,32 @@ async def test_prefill_dispatches_observe_attention_pairs():
         await eng.stop()
 
 
+async def test_a_wide_dense_bucket_observes_its_live_row_blocks():
+    """A 2100-token prompt runs the 4096 bucket, whose row-wise halves
+    loop over the row blocks that hold a live row (``llama._live_rows``):
+    the padded-positions counter reads the 5 x 512 positions the program
+    ran, not the bucket, and so does what stands ahead of the next round.
+    A bucket of one or two blocks reads whole, as before."""
+    from dynamo_tpu.models import llama
+
+    assert llama.LIVE_ROW_BLOCK == 512
+    eng = _engine(page_size=64, max_pages_per_seq=64, num_pages=80,
+                  max_decode_slots=2, prefill_buckets=(1024, 4096))
+    eng.start()
+    try:
+        h0 = _hists(eng)
+        await _one(eng, [1 + j % 250 for j in range(2100)], osl=2)
+        h1 = await _settled(eng)
+        assert _delta(h0, h1, PF) == 2100
+        assert _delta(h0, h1, PAD) == 2560
+        assert _delta(h0, h1, AHEAD) == 2560
+        await _one(eng, [3 + j % 250 for j in range(300)], osl=2)
+        h2 = await _settled(eng)
+        assert _delta(h1, h2, PAD) == 1024
+    finally:
+        await eng.stop()
+
+
 # ---- request phases ---------------------------------------------------
 
 

@@ -470,6 +470,50 @@ def test_programs_beside_the_continuing_latent_chunk_keep_their_lowering(
     assert unmoved_digests[key][program] == UNMOVED[key][program]
 
 
+# the wide dense prefill programs loop their row-wise halves over the live
+# row blocks (llama._live_rows, PR 44). Temporaries of the 2-layer
+# ``[2, 4096]`` programs, compiled for the v5e, PR 44: 0.247 GB fresh and
+# 0.550 continuing at Mistral-7B's int8 widths (the straight-line parent
+# 0.407 / 0.776); 0.160 / 0.286 a chip at Nemo-12B's under tp = 4
+# (0.264 / 0.383). A loop whose weights are sliced where XLA can hoist
+# the slice copies every layer's weights in front of it and reads 0.64 /
+# 0.95 GB: the ceilings sit between.
+LOOPED_TEMP_CEILING = {
+    ("mistral7b-w8", "batch_prefill"): 0.30e9,
+    ("mistral7b-w8", "batch_prefill_cont"): 0.62e9,
+    ("nemo12b-tp4", "batch_prefill"): 0.20e9,
+    ("nemo12b-tp4", "batch_prefill_cont"): 0.33e9,
+}
+
+
+@pytest.fixture(scope="module", params=["mistral7b-w8", "nemo12b-tp4"])
+def looped_records(request):
+    _v5e_or_skip()
+    with jax.default_matmul_precision("default"):
+        records = tpu_compile_check.compile_programs(
+            config=request.param, layers=2, prefill_width=4096,
+            programs=("batch_prefill", "batch_prefill_cont"),
+            keep_text=True)
+    return request.param, dict(zip(("batch_prefill", "batch_prefill_cont"),
+                                   records))
+
+
+@pytest.mark.parametrize("program", ["batch_prefill", "batch_prefill_cont"])
+def test_looped_dense_prefill_copies_no_weights_on_v5e(looped_records,
+                                                       program):
+    """The loop's body slices its layer's weights out of the stack itself
+    and the slice fuses into the matmul: a block's products read
+    ``[512, ...]`` and the temporaries stay under the ceiling (a copy of
+    each layer's weights in front of the loops passes it by 2x)."""
+    config, records = looped_records
+    rec = records[program]
+    assert rec["ok"], rec.get("error")
+    assert rec["temp_bytes"] < LOOPED_TEMP_CEILING[config, program], (
+        rec["temp_gb"])
+    ffn = 14336 if config == "mistral7b-w8" else 3584
+    assert f"bf16[512,{ffn}]" in rec["text"]      # a block's gate / up
+
+
 def test_region_copies_reads_copy_and_copy_start():
     shard = (2, 2, 17, 4096, 128)
     text = """
