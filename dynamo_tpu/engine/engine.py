@@ -72,7 +72,7 @@ from dynamo_tpu.kv_router.protocols import (
 )
 from dynamo_tpu.models import llama, mla_moe, ssm_moe
 from dynamo_tpu.models.config import ModelConfig
-from dynamo_tpu.ops import latent_decode
+from dynamo_tpu.ops import latent_decode, sparse_attention
 from dynamo_tpu.ops.attention import (
     decode_attention_for,
     prefill_attention_pairs,
@@ -352,6 +352,15 @@ class TpuEngine:
         # the module of a block that is not the dense decoder: its own
         # decode step, which also returns the routing counters
         self._block = llama.block_of(model_config)
+        # does a round's counter row carry routing counters? (a block
+        # whose feed-forward part is one dense MLP routes nothing)
+        self._routes = (self._block is not None
+                        and self._block.routes(model_config))
+        # a block-sparse attention's geometry (ops/sparse_attention.py),
+        # for the host's mirrors of what its layers read
+        self._sparse, self._sparse_layers = (
+            ssm_moe.sparse_layers(model_config)
+            if self._block is ssm_moe else (None, 0))
         if self._block is not None:
             self._refuse_row_only_planes(self.ecfg, on_dispatch,
                                          draft_config)
@@ -566,6 +575,14 @@ class TpuEngine:
             tmetrics.DECODE_ATTN_ROWS_LIVE[0])
         self._h_moe_picks_routed = self.telemetry.get(
             tmetrics.MOE_PICKS_ROUTED[0])
+        self._h_sparse_read = self.telemetry.get(
+            tmetrics.SPARSE_ATTN_ROWS_READ[0])
+        self._h_sparse_live = self.telemetry.get(
+            tmetrics.SPARSE_ATTN_ROWS_LIVE[0])
+        self._h_sparse_scored = self.telemetry.get(
+            tmetrics.SPARSE_PREFILL_SCORED[0])
+        self._h_sparse_selected = self.telemetry.get(
+            tmetrics.SPARSE_PREFILL_SELECTED[0])
         # bytes a token holds in the ctx region, and bytes a lane holds
         # in recurrent state whatever its context: observed once, here
         recurrent = llama.state_kinds(self.ctx)
@@ -822,9 +839,11 @@ class TpuEngine:
             toks_out = jnp.zeros((n_steps + int(routed), B), jnp.int32)
             moe_stats = (block or mla_moe).stats_zero(c)
             # recurrent leaves (models/ssm_moe.py) are updated by every
-            # step, where the region's rows are read-only until the
+            # step, and compressed-key rows by the step that completes
+            # one, where the region's other rows are read-only until the
             # flush: they ride the loop's carry ({} for the other blocks)
-            recurrent = {n: ctx_kv[n] for n in llama.state_kinds(ctx_kv)}
+            recurrent = ({n: ctx_kv[n] for n in block.stepped_kinds(ctx_kv)}
+                         if block is ssm_moe else {})
             lp_out = (
                 jnp.zeros((n_steps, B, 1 + 2 * max_logprobs), jnp.float32)
                 if want_lp else None
@@ -1228,6 +1247,14 @@ class TpuEngine:
         reinterprets the row, and none snapshots a recurrent state."""
         what = ("a latent (MLA) cache row" if self.config.mla is not None
                 else "a recurrent (state-space) state")
+        if self._sparse is not None:
+            what = ("a recurrent (linear-attention) state and "
+                    "compressed-key rows (kc)")
+            if e.page_size % self._sparse.block:
+                raise ValueError(
+                    f"page_size={e.page_size} is no multiple of the sparse "
+                    f"attention's block ({self._sparse.block}): a prefill "
+                    "chunk starts on a page and has to start on a block")
         planes = {
             "kv_quant=int8 (the int8 KV plane)": e.kv_quant != "none",
             "host/disk offload tiers and their kv_integrity frames "
@@ -1263,7 +1290,9 @@ class TpuEngine:
         if self.config.hybrid is not None:
             raise ValueError(
                 "kv_transfer / disaggregation cannot carry a recurrent "
-                "(state-space) state yet: pages move K and V rows, and a "
+                "(state-space or linear-attention) state"
+                + (" or compressed-key rows (kc)" if self._sparse else "")
+                + " yet: pages move K and V rows, and a "
                 "prompt cannot resume from rows without the state at "
                 "their boundary")
 
@@ -2440,6 +2469,8 @@ class TpuEngine:
         )
         if self.config.mla is not None:
             self._observe_decode_attn_rows(active, n)
+        if self._sparse is not None:
+            self._observe_sparse_rows(active, n)
         # only dispatched lanes advance (spec slots track their own
         # lengths through verify processing)
         self._ctx_disp[active] = np.minimum(
@@ -3736,10 +3767,34 @@ class TpuEngine:
             width, q_starts, seq_lens, ctx_span)
         self._h_pf_live.observe(live)
         self._h_pf_scored.observe(scored)
+        if self._sparse is not None:
+            # sparse layers score that whole causal context under the
+            # selection's mask: what a gathering prefill would score
+            self._h_sparse_scored.observe(self._sparse_layers * scored)
+            self._h_sparse_selected.observe(
+                self._sparse_layers * sparse_attention.prefill_pairs(
+                    self._sparse, q_starts, seq_lens, width))
         # and the prompt positions it computed in CONTINUING chunks
         self._h_pf_continued.observe(sum(
             min(max(int(n) - int(q), 0), width)
             for q, n in zip(q_starts, seq_lens) if int(q) > 0))
+
+    def _observe_sparse_rows(self, active, n_steps: int) -> None:
+        """One observation per dispatched round of the rows its sparse
+        attention layers read and the rows a dense read of the live lanes
+        would take, all such layers and steps (the host's mirror,
+        ``sparse_attention.decode_rows``; the lanes' lengths move on by
+        one a step)."""
+        live = np.zeros(self._B, bool)
+        live[active] = True
+        read = rows = 0
+        for s in range(n_steps):
+            a, b = sparse_attention.decode_rows(
+                self._sparse, self._ctx_disp + s, live,
+                self.ecfg.max_context, self.ecfg.flush_every)
+            read, rows = read + a, rows + b
+        self._h_sparse_read.observe(self._sparse_layers * read)
+        self._h_sparse_live.observe(self._sparse_layers * rows)
 
     def _observe_decode_attn_rows(self, active, n_steps: int) -> None:
         """One observation per dispatched round of the region rows its
@@ -4272,7 +4327,7 @@ class TpuEngine:
         lp_arrs = None
         if entry.lp_handle is not None:
             lp_arrs = self._unpack_lp(np.asarray(entry.lp_handle))
-        if self._block is not None:
+        if self._routes:
             touched, routed, load_max = toks[entry.n_steps, :3]
             self._h_moe_touched.observe(int(touched))
             self._h_moe_routed.observe(int(routed))

@@ -19,6 +19,10 @@ from typing import Any, Optional
 _DENSE_TYPES = frozenset({"llama", "mistral", "qwen2"})
 _MLA_MOE_TYPES = frozenset({"deepseek_v3", "joyai_llm_flash", "xing4_0"})
 _SSM_MOE_TYPES = frozenset({"granitemoehybrid"})
+# linear-attention layers with a matrix state beside block-sparse NoPE GQA
+# layers, one dense MLP a layer: the same hybrid stack (models/ssm_moe.py)
+# with other mixers
+_LINEAR_SPARSE_TYPES = frozenset({"minicpm_sala"})
 # keys that mean "not a dense Llama": a config carrying one is refused
 # rather than read with its extra structure dropped
 _FOREIGN_KEYS = ("kv_lora_rank", "q_lora_rank", "n_routed_experts",
@@ -50,6 +54,42 @@ _HYBRID_KEYS = _SSM_KEYS + (
     "residual_multiplier", "logits_scaling", "shared_intermediate_size",
     "num_local_experts", "num_experts_per_tok", "intermediate_size")
 _LAYER_KINDS = frozenset({"mamba", "attention"})
+# the linear-attention + sparse-attention stack as its config.json
+# parameterises it: every key must be there
+_LINEAR_SPARSE_KEYS = (
+    "mixer_types", "lightning_nh", "lightning_nkv", "lightning_head_dim",
+    "lightning_scale", "lightning_use_rope", "attn_use_rope", "qk_norm",
+    "use_output_gate", "use_output_norm", "attn_use_output_gate",
+    "scale_emb", "scale_depth", "dim_model_base", "sparse_config")
+# the published names of its mixers -> the kinds models/ssm_moe.py builds
+_MIXER_KINDS = {"lightning-attn": "linear_attention",
+                "minicpm4": "sparse_attention"}
+# the selection's geometry (InfLLM-V2's ``sparse_config``): compressed keys of ``kernel_size`` positions every
+# ``kernel_stride``, blocks of ``block_size`` positions, ``topk`` blocks
+# a query in all, of which ``init_blocks`` leading ones and those over the
+# last ``window_size`` positions are forced; queries below ``dense_len``
+# attend everything
+_SPARSE_KEYS = ("kernel_size", "kernel_stride", "block_size", "topk",
+                "init_blocks", "window_size", "dense_len")
+_TINY_LINEAR_SPARSE = {
+    "model_type": "minicpm_sala", "vocab_size": 256, "hidden_size": 64,
+    "intermediate_size": 128, "num_hidden_layers": 6,
+    "mixer_types": ["lightning-attn", "minicpm4", "minicpm4",
+                    "lightning-attn", "lightning-attn", "minicpm4"],
+    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+    "lightning_nh": 4, "lightning_nkv": 4, "lightning_head_dim": 16,
+    "lightning_scale": "1/sqrt(d)", "lightning_use_rope": True,
+    "attn_use_rope": False, "qk_norm": True, "use_output_gate": True,
+    "use_output_norm": True, "attn_use_output_gate": True,
+    "scale_emb": 12, "scale_depth": 1.4, "dim_model_base": 16,
+    "depth_scale_layers": 8,
+    "sparse_config": {"kernel_size": 4, "kernel_stride": 2, "block_size": 8,
+                      "topk": 6, "init_blocks": 1, "window_size": 16,
+                      "dense_len": 64},
+    "attention_bias": False, "hidden_act": "silu", "rms_norm_eps": 1e-6,
+    "rope_theta": 10000, "max_position_embeddings": 512,
+    "tie_word_embeddings": False,
+}
 _TINY_SSM_MOE = {
     "model_type": "granitemoehybrid", "vocab_size": 256, "hidden_size": 64,
     "intermediate_size": 32, "shared_intermediate_size": 48,
@@ -142,6 +182,10 @@ class ModelConfig:
     # the attention layers and, beside them, a recurrent state a lane for
     # each state-space layer. `num_layers` counts both kinds;
     # `num_heads`/`num_kv_heads`/`head_dim` are the attention layers'.
+    # The same stack with other mixers (_from_hf_linear_sparse): linear
+    # attention with a matrix state, block-sparse attention with
+    # compressed-key rows, one dense MLP a layer; what a layer does
+    # follows from `layer_types`.
     hybrid: Optional[tuple[tuple[str, Any], ...]] = None
 
     @property
@@ -183,13 +227,17 @@ class ModelConfig:
             return cls._from_hf_mla_moe(d)
         if model_type in _SSM_MOE_TYPES:
             return cls._from_hf_ssm_moe(d)
+        if model_type in _LINEAR_SPARSE_TYPES:
+            return cls._from_hf_linear_sparse(d)
         if model_type not in _DENSE_TYPES:
             raise ValueError(
                 f"model_type {model_type!r} is no block this program "
                 f"builds (dense: {sorted(_DENSE_TYPES)}; latent attention "
                 f"+ routed experts: {sorted(_MLA_MOE_TYPES)}; state-space "
                 f"+ attention layers with routed experts: "
-                f"{sorted(_SSM_MOE_TYPES)})")
+                f"{sorted(_SSM_MOE_TYPES)}; linear-attention + "
+                f"block-sparse attention layers: "
+                f"{sorted(_LINEAR_SPARSE_TYPES)})")
         unknown = sorted(k for k in _FOREIGN_KEYS if d.get(k))
         if unknown:
             raise ValueError(
@@ -369,6 +417,110 @@ class ModelConfig:
             model_type=d["model_type"],
             hybrid=tuple(sorted(hybrid.items())),
         )
+
+    @classmethod
+    def _from_hf_linear_sparse(cls, d: dict[str, Any]) -> "ModelConfig":
+        """Linear-attention layers with a decayed matrix state beside
+        block-sparse NoPE GQA layers, one dense SwiGLU a layer, muP
+        multipliers on the embedding, the residual and the logits. Every key the equations need must be there with
+        a value this program builds; anything else is refused by name.
+
+        ``sparse_config`` (the selection's geometry, ``_SPARSE_KEYS``) is
+        the family's convention and a key of its own in a configuration
+        file, as is ``depth_scale_layers``: the depth that
+        ``scale_depth / sqrt(depth)`` is taken at, the PUBLISHED one where
+        a file holds a cut of the layers (default: num_hidden_layers)."""
+        missing = sorted(k for k in _LINEAR_SPARSE_KEYS if k not in d)
+        if missing:
+            raise ValueError("linear + sparse attention block: keys "
+                             f"{missing} are missing from the config")
+        published = tuple(d["mixer_types"])
+        kinds = tuple(_MIXER_KINDS.get(k, k) for k in published)
+        sparse = d["sparse_config"]
+        if not isinstance(sparse, dict) or set(sparse) != set(_SPARSE_KEYS):
+            raise ValueError(
+                "linear + sparse attention block: sparse_config needs "
+                f"exactly {sorted(_SPARSE_KEYS)} (it has "
+                f"{sorted(sparse) if isinstance(sparse, dict) else sparse})")
+        sp = {k: int(sparse[k]) for k in _SPARSE_KEYS}
+        heads = d["num_attention_heads"]
+        hd = d.get("head_dim") or d["hidden_size"] // heads
+        forced = sp["init_blocks"] + -(-sp["window_size"]
+                                       // max(sp["block_size"], 1)) + 1
+        refused = {
+            f"mixer_types other than {sorted(_MIXER_KINDS)}":
+                not set(published) <= set(_MIXER_KINDS),
+            "mixer_types whose length is not num_hidden_layers":
+                len(kinds) != d["num_hidden_layers"],
+            "qk_norm false": not d["qk_norm"],
+            "use_output_gate false": not d["use_output_gate"],
+            "use_output_norm false": not d["use_output_norm"],
+            "attn_use_output_gate false": not d["attn_use_output_gate"],
+            "attn_use_rope true (the sparse layers are built without "
+            "rotary)": bool(d["attn_use_rope"]),
+            "lightning_use_rope false": not d["lightning_use_rope"],
+            f"lightning_scale {d['lightning_scale']!r} (only '1/sqrt(d)')":
+                d["lightning_scale"] != "1/sqrt(d)",
+            "lightning_nkv != lightning_nh (no grouped linear attention)":
+                d["lightning_nkv"] != d["lightning_nh"],
+            "rope_scaling": d.get("rope_scaling") is not None,
+            "attention_bias": bool(d.get("attention_bias")),
+            f"hidden_act {d.get('hidden_act')!r}":
+                d.get("hidden_act", "silu") != "silu",
+            "tie_word_embeddings true (the head is a matrix of its own)":
+                bool(d.get("tie_word_embeddings")),
+            "sparse_config: kernel_size is not 2 x kernel_stride, or "
+            "block_size no multiple of kernel_stride":
+                sp["kernel_size"] != 2 * sp["kernel_stride"]
+                or sp["kernel_stride"] < 1
+                or sp["block_size"] % max(sp["kernel_stride"], 1) != 0,
+            f"sparse_config: topk {sp['topk']} below the {forced} blocks "
+            "that are forced (init_blocks + those over window_size)":
+                sp["topk"] < forced,
+            "sparse_config: dense_len below topk x block_size (a query "
+            "past it must have topk blocks to choose from)":
+                sp["dense_len"] < sp["topk"] * sp["block_size"],
+            "num_attention_heads no multiple of num_key_value_heads":
+                heads % d.get("num_key_value_heads", heads) != 0,
+        }
+        bad = sorted(k for k, v in refused.items() if v)
+        if bad:
+            raise ValueError(
+                f"linear + sparse attention block: {bad} are values this "
+                "program does not build")
+        depth = int(d.get("depth_scale_layers", d["num_hidden_layers"]))
+        hybrid = dict(
+            layer_types=kinds,
+            lightning_heads=int(d["lightning_nh"]),
+            lightning_head_dim=int(d["lightning_head_dim"]),
+            sparse=tuple(sorted(sp.items())),
+            embedding_multiplier=d["scale_emb"],
+            residual_multiplier=d["scale_depth"] / depth ** 0.5,
+            logits_scaling=d["hidden_size"] / d["dim_model_base"])
+        return cls(
+            vocab_size=d["vocab_size"],
+            hidden_size=d["hidden_size"],
+            intermediate_size=d["intermediate_size"],
+            num_layers=d["num_hidden_layers"],
+            num_heads=heads,
+            num_kv_heads=d.get("num_key_value_heads", heads),
+            head_dim=hd,
+            rope_theta=float(d.get("rope_theta", 10000.0)),
+            rms_norm_eps=d.get("rms_norm_eps", 1e-6),
+            max_position_embeddings=d.get("max_position_embeddings", 8192),
+            tie_word_embeddings=False,
+            model_type=d["model_type"],
+            hybrid=tuple(sorted(hybrid.items())),
+        )
+
+    @classmethod
+    def tiny_linear_sparse(cls, **kw) -> "ModelConfig":
+        """Toy linear + sparse attention stack for CPU tests: six layers
+        of both kinds with two sparse ones adjacent, blocks of 8, top 6,
+        a switch to the selection at position 64."""
+        d = dict(_TINY_LINEAR_SPARSE)
+        d.update(kw)
+        return cls.from_hf_dict(d)
 
     @classmethod
     def tiny_ssm_moe(cls, **kw) -> "ModelConfig":

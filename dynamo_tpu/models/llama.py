@@ -115,6 +115,15 @@ def _any_row(state: Cache) -> jnp.ndarray:
     return state[row_kinds(state)[0]]
 
 
+def _row_stride(state: Cache, name: str) -> int:
+    """Positions a row of kind ``name`` stands for. A region's (or a
+    pool's) row kinds cover one span of positions, so a kind with fewer
+    rows than the longest holds one row every so many positions (a page
+    of it is ``page_size / stride`` rows)."""
+    return (max(state[n].shape[3] for n in row_kinds(state))
+            // state[name].shape[3])
+
+
 def _dense_only(config_or_state, plane: str) -> None:
     """Refuse a latent-row or recurrent-state model (or its state) in a
     plane that knows one row geometry only, naming the plane."""
@@ -460,7 +469,7 @@ def init_ring(
 
 def ring_shardings(config: ModelConfig, mesh: Mesh) -> Cache:
     if block_of(config) is not None:
-        return block_of(config).row_shardings(config, mesh)
+        return block_of(config).ring_shardings(config, mesh)
     s = NamedSharding(mesh, P(None, "tp", None, None, None))
     return {"k": s, "v": s}
 
@@ -1622,8 +1631,8 @@ def load_ctx_pages_impl(
             s = cache[name + "_scale"][:, page_ids]       # [L, usable]
             pages = (pages.astype(jnp.float32)
                      * s[:, None, :, None, None])
-        L, kvh, _, _, hd = pages.shape
-        span = pages.reshape(L, kvh, usable * ps, hd)
+        L, kvh, _, rows, hd = pages.shape     # ps rows a page, or ps /
+        span = pages.reshape(L, kvh, usable * rows, hd)  # stride of them
         out[name] = jax.lax.dynamic_update_slice(
             ctx_kv[name], span[:, :, None].astype(ctx_kv[name].dtype),
             (0, 0, slot, 0, 0),
@@ -1698,15 +1707,21 @@ def seal_blocks_impl(
     pool_q = cache_is_quantized(cache)
     ctx_q = ctx_is_quantized(ctx_kv)
     if not (pool_q or ctx_q):
-        def block(pool, src, i):
+        def block(pool, src, i, stride):
+            # a kind with one row every ``stride`` positions moves its
+            # ``ps / stride`` rows of the block
+            lane, start = slots[i], starts[i]
+            if stride != 1:
+                start = start // stride
             rows = jax.lax.dynamic_slice(
-                src, (0, 0, slots[i], starts[i], 0),
-                src.shape[:2] + (1, ps) + src.shape[4:])
+                src, (0, 0, lane, start, 0),
+                src.shape[:2] + (1, ps // stride) + src.shape[4:])
             return jax.lax.dynamic_update_slice(
                 pool, rows.astype(pool.dtype), (0, 0, pages[i], 0, 0))
 
         def one(i, pools):
-            return {n: block(pool, ctx_kv[n], i) for n, pool in pools.items()}
+            return {n: block(pool, ctx_kv[n], i, _row_stride(ctx_kv, n))
+                    for n, pool in pools.items()}
 
         return jax.lax.fori_loop(
             0, slots.shape[0], one, {n: cache[n] for n in row_kinds(ctx_kv)})

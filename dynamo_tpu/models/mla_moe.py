@@ -239,6 +239,7 @@ def row_shardings(config: ModelConfig, mesh: Mesh,
 
 
 ctx_shardings = row_shardings   # the region holds rows only
+ring_shardings = row_shardings  # and the ring the same kind
 
 
 # ---------------------------------------------------------------------------
@@ -338,6 +339,10 @@ def _unabsorb_o(c: ModelConfig, lp, o_lat):
 
 def _mlp(x, wg, wu, wd):
     return (jax.nn.silu(x @ wg) * (x @ wu)) @ wd
+
+
+def routes(c: ModelConfig) -> bool:
+    return True   # every round's counter row carries routing counters
 
 
 def route(c: ModelConfig, ep, x):

@@ -40,6 +40,32 @@ engine meets it:
     scale = ``attention_multiplier``, the logits / ``logits_scaling``;
     the head is the embedding (tied).
 
+THE SAME STACK WITH OTHER MIXERS (a config whose ``layer_types`` name
+them; nothing here names a model). What a layer does follows from its
+kind, what a region holds from its leaves:
+
+  - ``linear_attention`` (ops/lightning.py): q, k, v of [heads, D], an
+    RMSNorm over D on q and k, rotary on both, then ``S_t = lam_h S_{t-1}
+    + k_t^T v_t``, ``o_t = q_t S_t / sqrt(D)``; an RMSNorm over the
+    concatenated heads, a sigmoid gate from the layer's input, W_o. Its
+    state is a ``lin_state`` leaf [lanes + 1, heads, D, D] float32 a
+    layer, stepped and written as the SSM state is (a lane that is not
+    live: decay 1, key 0).
+  - ``sparse_attention`` (ops/sparse_attention.py): NoPE GQA with an
+    RMSNorm on q and k and a sigmoid gate, whose queries at or past
+    ``dense_len`` attend a SELECTION of blocks scored on COMPRESSED keys.
+    Those are a row kind of their own, ``kc`` [L_sparse, kvh, lanes, S /
+    stride, hd]: one row every ``stride`` positions, written where K rows
+    are written (by a prefill chunk's tail; by the decode STEP that
+    completes one, so ``kc`` rides the round's carry beside the recurrent
+    leaves: ``stepped_kinds``). Decode gathers the chosen blocks'
+    rows from the region; a lane below ``dense_len`` takes the dense
+    read. Prefill scores the whole causal context under the selection's
+    mask.
+  - the feed-forward part is experts + a shared MLP where the config has
+    experts, else one dense SwiGLU; the head is the embedding where the
+    config ties them, else a matrix of its own.
+
 Every function here is reached through the ``llama`` names
 (``llama.block_of``), as models/mla_moe.py is.
 """
@@ -55,52 +81,100 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models.mla_moe import _mlp, _rms   # the same norm and SwiGLU
 from dynamo_tpu.models.moe import grouped_experts
-from dynamo_tpu.ops import mamba2
+from dynamo_tpu.ops import lightning, mamba2, sparse_attention
 from dynamo_tpu.ops.attention import (
     DecodeAttention,
     PriorContext,
     ctx_decode_attention,
     prefill_attention,
 )
+from dynamo_tpu.ops.rope import apply_rope, rope_cos_sin, rope_inv_freq
 
 Params = dict[str, Any]
 Cache = dict[str, Any]
 
 SSM, CONV = "ssm_state", "conv_state"   # the recurrent leaves of a ctx
+LIN = "lin_state"     # a linear-attention layer's matrix state
+KC = "kc"             # the sparse layers' compressed-key rows
+ROW_LAYERS = ("attention", "sparse_attention")   # kinds that keep K/V rows
 
 
 def dims(c: ModelConfig) -> dict[str, Any]:
     k = c.hybrid_dict
-    inner = k["mamba_n_heads"] * k["mamba_d_head"]
     kinds = k["layer_types"]
-    return {
+    d = {
         "kinds": kinds,
         "n_ssm": sum(t == "mamba" for t in kinds),
-        "n_attn": sum(t == "attention" for t in kinds),
-        "nh": k["mamba_n_heads"], "P": k["mamba_d_head"],
-        "N": k["mamba_d_state"], "W": k["mamba_d_conv"],
-        "inner": inner, "conv": inner + 2 * k["mamba_d_state"],
-        "chunk": k["mamba_chunk_size"],
-        "E": k["published_experts"], "held": k["num_local_experts"],
-        "K": k["num_experts_per_tok"], "I_e": k["intermediate_size"],
-        "I_s": k["shared_intermediate_size"],
-        # the first expert held here; None: all of them, and the grouped
-        # product traces as it does without a share
-        "first": (k["share_index"] * k["num_local_experts"]
-                  if k["share_of"] > 1 else None),
+        "n_lin": sum(t == "linear_attention" for t in kinds),
+        "n_attn": sum(t in ROW_LAYERS for t in kinds),
+        "n_sparse": sum(t == "sparse_attention" for t in kinds),
+        "experts": "num_local_experts" in k,
     }
+    if "mamba_n_heads" in k:
+        inner = k["mamba_n_heads"] * k["mamba_d_head"]
+        d.update({
+            "nh": k["mamba_n_heads"], "P": k["mamba_d_head"],
+            "N": k["mamba_d_state"], "W": k["mamba_d_conv"],
+            "inner": inner, "conv": inner + 2 * k["mamba_d_state"],
+            "chunk": k["mamba_chunk_size"],
+        })
+    if d["experts"]:
+        d.update({
+            "E": k["published_experts"], "held": k["num_local_experts"],
+            "K": k["num_experts_per_tok"], "I_e": k["intermediate_size"],
+            "I_s": k["shared_intermediate_size"],
+            # the first expert held here; None: all of them, and the
+            # grouped product traces as it does without a share
+            "first": (k["share_index"] * k["num_local_experts"]
+                      if k["share_of"] > 1 else None),
+        })
+    if "lightning_heads" in k:
+        d.update({
+            "lin_heads": k["lightning_heads"],
+            "lin_dim": k["lightning_head_dim"],
+            "sparse": sparse_attention.Geometry.of(dict(k["sparse"])),
+        })
+    return d
 
 
-def kv_row_bytes(c: ModelConfig, itemsize: int) -> int:
-    """Bytes one token holds in the ctx region (attention layers only)."""
-    return dims(c)["n_attn"] * 2 * c.kv_dim * itemsize
+def routes(c: ModelConfig) -> bool:
+    """Whether a round's counter row carries routing counters: a
+    feed-forward part that is one dense MLP routes nothing."""
+    return dims(c)["experts"]
+
+
+def sparse_layers(c: ModelConfig):
+    """(the block-sparse attention's geometry or None, how many layers
+    run it): what the host's mirrors of their reads are computed from."""
+    d = dims(c)
+    return d.get("sparse") if d["n_sparse"] else None, d["n_sparse"]
+
+
+LIN_CHUNK = 256   # positions a chunk of the linear attention's prefill
+SPARSE_QK_GAIN = 2.0   # init_params: the sparse layers' q / k norm gains
+
+
+def kv_row_bytes(c: ModelConfig, itemsize: int) -> float:
+    """Bytes one token holds in the ctx region: K and V in the layers
+    that keep rows, and its share of a compressed key in the sparse
+    ones."""
+    d = dims(c)
+    rows = d["n_attn"] * 2 * c.kv_dim * itemsize
+    if d["n_sparse"]:
+        rows += d["n_sparse"] * c.kv_dim * itemsize / d["sparse"].stride
+    return rows
 
 
 def state_bytes(c: ModelConfig, itemsize: int) -> int:
     """Bytes one lane holds in recurrent state, whatever its context."""
     d = dims(c)
-    return d["n_ssm"] * (d["nh"] * d["P"] * d["N"] * 4
-                         + (d["W"] - 1) * d["conv"] * itemsize)
+    total = 0
+    if d["n_ssm"]:
+        total += d["n_ssm"] * (d["nh"] * d["P"] * d["N"] * 4
+                               + (d["W"] - 1) * d["conv"] * itemsize)
+    if d["n_lin"]:
+        total += d["n_lin"] * d["lin_heads"] * d["lin_dim"] ** 2 * 4
+    return total
 
 
 # ---------------------------------------------------------------------------
@@ -128,19 +202,39 @@ def init_params(config: ModelConfig, rng: jax.Array | int = 0) -> Params:
     H = c.hidden_size
 
     def layer(kind):
-        lp = {
-            "ln1": jnp.ones((H,), dtype), "ln2": jnp.ones((H,), dtype),
-            "wr": rnd(H, d["E"]),
-            # the published fused input matrix [H, 2 I] as its two halves
-            "we_g": rnd(d["held"], H, d["I_e"]),
-            "we_u": rnd(d["held"], H, d["I_e"]),
-            "we_d": rnd(d["held"], d["I_e"], H),
-            "ws_g": rnd(H, d["I_s"]), "ws_u": rnd(H, d["I_s"]),
-            "ws_d": rnd(d["I_s"], H),
-        }
+        lp = {"ln1": jnp.ones((H,), dtype), "ln2": jnp.ones((H,), dtype)}
+        if d["experts"]:
+            lp.update(
+                wr=rnd(H, d["E"]),
+                # the published fused input matrix [H, 2 I] as its two halves
+                we_g=rnd(d["held"], H, d["I_e"]),
+                we_u=rnd(d["held"], H, d["I_e"]),
+                we_d=rnd(d["held"], d["I_e"], H),
+                ws_g=rnd(H, d["I_s"]), ws_u=rnd(H, d["I_s"]),
+                ws_d=rnd(d["I_s"], H))
+        else:
+            I = c.intermediate_size
+            lp.update(w_g=rnd(H, I), w_u=rnd(H, I), w_d=rnd(I, H))
         if kind == "attention":
             lp.update(wq=rnd(H, c.q_dim), wk=rnd(H, c.kv_dim),
                       wv=rnd(H, c.kv_dim), wo=rnd(c.q_dim, H))
+            return lp
+        if kind == "sparse_attention":
+            # q and k gains of 2: a softmax row's logits have a spread of
+            # ~4, so its mass sits on few keys and WHICH blocks are read
+            # changes the output (gains of 1 give near-flat rows, and a
+            # dropped selection changes nothing a check can see)
+            gain = jnp.full((c.head_dim,), SPARSE_QK_GAIN, dtype)
+            lp.update(wq=rnd(H, c.q_dim), wk=rnd(H, c.kv_dim),
+                      wv=rnd(H, c.kv_dim), wo=rnd(c.q_dim, H),
+                      wz=rnd(H, c.q_dim), q_norm=gain, k_norm=gain)
+            return lp
+        if kind == "linear_attention":
+            inner = d["lin_heads"] * d["lin_dim"]
+            one = jnp.ones((d["lin_dim"],), dtype)
+            lp.update(wq=rnd(H, inner), wk=rnd(H, inner), wv=rnd(H, inner),
+                      wo=rnd(inner, H), wz=rnd(H, inner), q_norm=one,
+                      k_norm=one, o_norm=jnp.ones((inner,), dtype))
             return lp
         u = lambda lo, hi: jax.random.uniform(  # noqa: E731
             next(keys), (d["nh"],), jnp.float32, lo, hi)
@@ -157,13 +251,16 @@ def init_params(config: ModelConfig, rng: jax.Array | int = 0) -> Params:
         )
         return lp
 
-    return {
+    params = {
         "embed": rnd(c.vocab_size, H, scale=1.0 / np.sqrt(H)),
         "norm_f": jnp.ones((H,), dtype),
         # one entry a layer, NOT stacked: the kinds differ, and a
         # kernel's operand sliced out of a stack is a copy of it
         "layers": [layer(kind) for kind in d["kinds"]],
     }
+    if not c.tie_word_embeddings:
+        params["head"] = rnd(H, c.vocab_size)
+    return params
 
 
 def param_shardings(config: ModelConfig, mesh: Mesh) -> Params:
@@ -184,9 +281,17 @@ def param_shardings(config: ModelConfig, mesh: Mesh) -> Params:
 # ---------------------------------------------------------------------------
 # Cache spec: rows for the attention layers, a state for the others
 
-def _rows(c: ModelConfig, lanes: int, length: int, dtype) -> Cache:
-    shape = (dims(c)["n_attn"], c.num_kv_heads, lanes, length, c.head_dim)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+def _rows(c: ModelConfig, lanes: int, length: int, dtype,
+          compressed: bool = True) -> Cache:
+    d = dims(c)
+    shape = (d["n_attn"], c.num_kv_heads, lanes, length, c.head_dim)
+    rows = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    if d["n_sparse"] and compressed:
+        # one compressed key every ``stride`` positions of a sparse layer
+        rows[KC] = jnp.zeros(
+            (d["n_sparse"], c.num_kv_heads, lanes,
+             length // d["sparse"].stride, c.head_dim), dtype)
+    return rows
 
 
 def _refuse_quant(kv_quant: str) -> None:
@@ -208,30 +313,60 @@ def init_ctx(config, batch, ctx_len, dtype=None, kv_quant="none",
     d = dims(config)
     dtype = dtype or jnp.dtype(config.dtype)
     ctx = _rows(config, batch + 1, ctx_len, dtype)
-    ctx[SSM] = [jnp.zeros((batch + 1, d["nh"], d["P"], d["N"]), jnp.float32)
-                for _ in range(d["n_ssm"])]
-    ctx[CONV] = [jnp.zeros((batch + 1, d["W"] - 1, d["conv"]), dtype)
-                 for _ in range(d["n_ssm"])]
+    if d["n_ssm"]:
+        ctx[SSM] = [
+            jnp.zeros((batch + 1, d["nh"], d["P"], d["N"]), jnp.float32)
+            for _ in range(d["n_ssm"])]
+        ctx[CONV] = [jnp.zeros((batch + 1, d["W"] - 1, d["conv"]), dtype)
+                     for _ in range(d["n_ssm"])]
+    if d["n_lin"]:
+        ctx[LIN] = [
+            jnp.zeros((batch + 1, d["lin_heads"], d["lin_dim"],
+                       d["lin_dim"]), jnp.float32)
+            for _ in range(d["n_lin"])]
     return ctx
 
 
 def init_ring(config, batch, ring_len, dtype=None):
-    return _rows(config, batch, ring_len, dtype or jnp.dtype(config.dtype))
+    # a step that completes a compressed key writes it into the region's
+    # own leaf (it rides the round's carry): the ring holds K and V only
+    return _rows(config, batch, ring_len, dtype or jnp.dtype(config.dtype),
+                 compressed=False)
 
 
 def row_shardings(config: ModelConfig, mesh: Mesh,
                   kv_quant: str = "none") -> Cache:
     _refuse_quant(kv_quant)
     s = NamedSharding(mesh, P(None, None, None, None, None))
-    return {"k": s, "v": s}
+    out = {"k": s, "v": s}
+    if dims(config)["n_sparse"]:
+        out[KC] = s
+    return out
+
+
+def ring_shardings(config: ModelConfig, mesh: Mesh) -> Cache:
+    rows = row_shardings(config, mesh)
+    return {n: rows[n] for n in ("k", "v")}   # init_ring's kinds
+
+
+def stepped_kinds(state: Cache) -> tuple[str, ...]:
+    """A region's leaves that a decode STEP writes, where K and V are
+    read-only until the round's flush: the recurrent leaves, and the
+    compressed-key rows (a step that completes one writes it where it
+    belongs). They ride the round's carry; the ring holds none of them."""
+    return tuple(sorted(n for n in state
+                        if n.endswith("_state") or n == KC))
 
 
 def ctx_shardings(config: ModelConfig, mesh: Mesh,
                   kv_quant: str = "none") -> Cache:
     out = row_shardings(config, mesh, kv_quant)
-    n = dims(config)["n_ssm"]
-    out[SSM] = [NamedSharding(mesh, P(None, None, None, None))] * n
-    out[CONV] = [NamedSharding(mesh, P(None, None, None))] * n
+    d = dims(config)
+    if d["n_ssm"]:
+        out[SSM] = [NamedSharding(mesh, P(None, None, None, None))] * d["n_ssm"]
+        out[CONV] = [NamedSharding(mesh, P(None, None, None))] * d["n_ssm"]
+    if d["n_lin"]:
+        out[LIN] = [NamedSharding(mesh, P(None, None, None, None))] * d["n_lin"]
     return out
 
 
@@ -276,7 +411,12 @@ def _ffn(c: ModelConfig, lp, x, valid, stats):
 def _layer_out(c: ModelConfig, lp, h, mix, valid, stats):
     r = jnp.asarray(c.hybrid_dict["residual_multiplier"], h.dtype)
     h = h + r * mix
-    y, stats = _ffn(c, lp, _rms(h, lp["ln2"], c.rms_norm_eps), valid, stats)
+    x = _rms(h, lp["ln2"], c.rms_norm_eps)
+    if dims(c)["experts"]:
+        y, stats = _ffn(c, lp, x, valid, stats)
+    else:
+        with jax.named_scope("mlp"):
+            y = _mlp(x, lp["w_g"], lp["w_u"], lp["w_d"])
     return h + r * y, stats
 
 
@@ -287,7 +427,8 @@ def _embed(c: ModelConfig, params, tokens, dtype):
 
 def _logits(c: ModelConfig, params, h):
     h = _rms(h, params["norm_f"], c.rms_norm_eps)
-    y = jnp.matmul(h, params["embed"].T, preferred_element_type=jnp.float32)
+    head = params["head"] if "head" in params else params["embed"].T
+    y = jnp.matmul(h, head, preferred_element_type=jnp.float32)
     return y / c.hybrid_dict["logits_scaling"]
 
 
@@ -328,6 +469,132 @@ def _split_xbc(c: ModelConfig, xbc):
     d = dims(c)
     xs, B, C = jnp.split(xbc, [d["inner"], d["inner"] + d["N"]], -1)
     return xs.reshape(*xs.shape[:-1], d["nh"], d["P"]), B, C
+
+
+def _gated(o, z):
+    """``o * sigmoid(z)``, the product in float32 and rounded once."""
+    return (o.astype(jnp.float32)
+            * jax.nn.sigmoid(z.astype(jnp.float32))).astype(z.dtype)
+
+
+def _lin_in(c: ModelConfig, lp, x, positions):
+    """[N, H] -> q (scaled), k, v [N, heads, D] and the gate's input z
+    [N, heads x D]: an RMSNorm over D on q and k, then rotary (rotate-
+    half over the whole head) at ``positions`` [N]."""
+    d = dims(c)
+    N, nh, D = x.shape[0], d["lin_heads"], d["lin_dim"]
+    with jax.named_scope("lin_attn_proj"):
+        q, k, v = ((x @ lp[w]).reshape(N, nh, D) for w in ("wq", "wk", "wv"))
+        z = x @ lp["wz"]
+        q = _rms(q, lp["q_norm"], c.rms_norm_eps)
+        k = _rms(k, lp["k_norm"], c.rms_norm_eps)
+        cos, sin = rope_cos_sin(positions, rope_inv_freq(D, c.rope_theta))
+        k = apply_rope(k, cos, sin)
+        q = (apply_rope(q, cos, sin).astype(jnp.float32)
+             / np.sqrt(D)).astype(x.dtype)
+    return q, k, v, z
+
+
+def _lin_out(c: ModelConfig, lp, o, z):
+    """``o`` [N, heads, D] float32 from the recurrence -> the mixer's
+    output: the norm over all heads' values, the gate, W_o."""
+    with jax.named_scope("lin_attn_out"):
+        o = o.reshape(o.shape[0], -1)
+        var = jnp.mean(o * o, axis=-1, keepdims=True)
+        o = o * jax.lax.rsqrt(var + c.rms_norm_eps) * lp["o_norm"].astype(
+            jnp.float32)
+        return _gated(o, z) @ lp["wo"]
+
+
+def _sparse_in(c: ModelConfig, lp, x):
+    """[N, H] -> q [N, heads, hd], k, v [N, kvh, hd], z [N, heads x hd]:
+    an RMSNorm over hd on q and k, no rotary."""
+    N = x.shape[0]
+    q = (x @ lp["wq"]).reshape(N, c.num_heads, c.head_dim)
+    k = (x @ lp["wk"]).reshape(N, c.num_kv_heads, c.head_dim)
+    v = (x @ lp["wv"]).reshape(N, c.num_kv_heads, c.head_dim)
+    return (_rms(q, lp["q_norm"], c.rms_norm_eps),
+            _rms(k, lp["k_norm"], c.rms_norm_eps), v, x @ lp["wz"])
+
+
+def _sparse_prefill(c: ModelConfig, lp, x, ctx_kv, row: int, sp: int, slots,
+                    q_starts, seq_lens, span: int, K: int, T: int):
+    """One sparse layer over K chunks: (the mixer's output [K T, H], k, v
+    [K, T, kvh, hd], the lanes' compressed keys with this chunk's written
+    in [K, kvh, S / stride, hd]). ``row`` / ``sp``: the layer's ordinal
+    among the layers that keep rows / among the sparse ones."""
+    g = dims(c)["sparse"]
+    kvh, hd = c.num_kv_heads, c.head_dim
+    with jax.named_scope("nope_attn"):
+        q, k, v, z = _sparse_in(c, lp, x)
+        q, k, v = (a.reshape(K, T, *a.shape[1:]) for a in (q, k, v))
+        Sc = ctx_kv[KC].shape[3]
+        masks, kcs = [], []
+        for i in range(K):
+            with jax.named_scope("sparse_compress"):
+                if span:
+                    at = jnp.maximum(q_starts[i] - g.stride, 0)
+                    tail = jax.lax.dynamic_slice(
+                        ctx_kv["k"], (row, 0, slots[i], at, 0),
+                        (1, kvh, 1, g.stride, hd))[0, :, 0].transpose(1, 0, 2)
+                    kc_lane = jax.lax.dynamic_slice(
+                        ctx_kv[KC], (sp, 0, slots[i], 0, 0),
+                        (1, kvh, 1, Sc, hd))[0, :, 0]
+                else:
+                    tail = jnp.zeros((g.stride, kvh, hd), k.dtype)
+                    kc_lane = jnp.zeros((kvh, Sc, hd), ctx_kv[KC].dtype)
+                kc_lane = sparse_attention.overlay(
+                    kc_lane, sparse_attention.compress_chunk(g, k[i], tail),
+                    q_starts[i] // g.stride - 1)
+            with jax.named_scope("sparse_select"):
+                masks.append(sparse_attention.prefill_block_mask(
+                    g, q[i].reshape(T, kvh, -1, hd), kc_lane.astype(q.dtype),
+                    q_starts[i], seq_lens[i] - q_starts[i]))
+            kcs.append(kc_lane)
+        with jax.named_scope("sparse_attn"):
+            o = prefill_attention(
+                q, k, v, q_starts, seq_lens,
+                _prior_rows(ctx_kv, row, slots, span), ctx_span=span,
+                block_masks=jnp.stack(masks), mask_block=g.block)
+        mix = _gated(o.reshape(K * T, c.q_dim), z) @ lp["wo"]
+    return mix, k, v, jnp.stack(kcs)
+
+
+def _sparse_decode(c: ModelConfig, lp, x, ctx_kv, ring, kc, row: int,
+                   sp: int, ctx_lens, ring_base, ring_pos, live,
+                   attn: DecodeAttention):
+    """One sparse layer, one token a lane: (the mixer's output [B, H],
+    the ring with the new rows, the compressed keys with the one this
+    step completed). A lane at or past ``dense_len`` reads its selected
+    blocks; one below it takes the dense read."""
+    g = dims(c)["sparse"]
+    B = x.shape[0]
+    t = ctx_lens - 1
+    with jax.named_scope("nope_attn"):
+        q, k, v, z = _sparse_in(c, lp, x)
+        for name, new in (("k", k), ("v", v)):
+            ring[name] = jax.lax.dynamic_update_slice(
+                ring[name],
+                new.transpose(1, 0, 2)[None, :, :, None, :].astype(
+                    ring[name].dtype), (row, 0, 0, ring_pos, 0))
+        with jax.named_scope("sparse_compress"):
+            kc = sparse_attention.compress_step(
+                g, ctx_kv["k"], ring["k"], kc, row, sp, t, ring_base, live)
+        selects = live & (t >= g.dense_len)
+        o_sel, _ = sparse_attention.decode_attention(
+            g, q, ctx_kv["k"], ctx_kv["v"], kc, ring["k"], ring["v"],
+            row, sp, ctx_lens, ring_base, selects)
+        # the dense read (a Mosaic call with a grid over every lane's
+        # chunks) only when some live lane stands below the switch
+        o = jax.lax.cond(
+            jnp.any(live & ~selects),
+            lambda: ctx_decode_attention(
+                attn, q, ctx_kv["k"], ctx_kv["v"], ring["k"], ring["v"],
+                jnp.int32(row), jnp.where(selects, 0, ctx_lens), ring_base),
+            lambda: jnp.zeros_like(o_sel))
+        o = jnp.where(selects[:, None, None], o_sel, o)
+        mix = _gated(o.reshape(B, c.q_dim), z) @ lp["wo"]
+    return mix, ring, kc
 
 
 def _prior_rows(ctx_kv, layer: int, slots, span: int):
@@ -382,7 +649,7 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
     continuing = q_starts > 0
     h = _embed(c, params, tokens.reshape(K * T), cdt)
     stats = stats_zero(c)
-    ks, vs, ssm_out, conv_out = [], [], [], []
+    ks, vs, kcs, ssm_out, conv_out, lin_out = [], [], [], [], [], []
     A = lambda lp: -jnp.exp(lp["A_log"])  # noqa: E731
     for kind, lp in zip(d["kinds"], params["layers"]):
         x = _rms(h, lp["ln1"], c.rms_norm_eps)
@@ -396,6 +663,31 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
                 o = prefill_attention(q, k, v, q_starts, seq_lens, prior,
                                       ctx_span=span)
                 mix = o.reshape(K * T, c.q_dim) @ lp["wo"]
+        elif kind == "sparse_attention":
+            mix, k, v, kc = _sparse_prefill(
+                c, lp, x, ctx_kv, len(ks), len(kcs), slots, q_starts,
+                seq_lens, span, K, T)
+            ks.append(k)
+            vs.append(v)
+            kcs.append(kc)
+        elif kind == "linear_attention":
+            j = len(lin_out)
+            q, k, v, z = _lin_in(c, lp, x, positions.reshape(K * T))
+            if span:
+                S0 = jnp.where(continuing[:, None, None, None],
+                               ctx_kv[LIN][j][slots], 0.0)
+            else:
+                S0 = jnp.zeros((K, d["lin_heads"], d["lin_dim"],
+                                d["lin_dim"]), jnp.float32)
+            decay = jnp.asarray(lightning.log_decays(d["lin_heads"]))
+            with jax.named_scope("lin_attn_scan"):
+                o, S = jax.vmap(
+                    lambda q, k, v, real, S0: lightning.chunk_scan(
+                        q, k, v, decay, real, S0, LIN_CHUNK)
+                )(*(a.reshape(K, T, *a.shape[1:]) for a in (q, k, v)),
+                  real, S0)
+            lin_out.append(S)
+            mix = _lin_out(c, lp, o.reshape(K * T, *o.shape[2:]), z)
         else:
             j = len(ssm_out)
             z, xbc, dt = _ssm_in(c, lp, x)
@@ -425,9 +717,12 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
                            xs.reshape(K * T, d["nh"], d["P"]), z)
         h, stats = _layer_out(c, lp, h, mix, valid, stats)
 
-    # tail: every read is done. Rows as spans, states as whole lanes
+    # tail: every read is done. Rows as spans, compressed keys and states
+    # as whole lanes
     rows = {"k": jnp.stack(ks, 1).astype(cdt),      # [K, L_attn, T, kvh, hd]
             "v": jnp.stack(vs, 1).astype(cdt)}
+    states = [(name, new) for name, new in (
+        (SSM, ssm_out), (CONV, conv_out), (LIN, lin_out)) if new]
 
     def write_lane(i, out):
         out = dict(out)
@@ -436,7 +731,13 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
             out[name] = jax.lax.dynamic_update_slice(
                 out[name], r.transpose(0, 2, 1, 3)[:, :, None],
                 (0, 0, slots[i], q_starts[i], 0))
-        for name, new in ((SSM, ssm_out), (CONV, conv_out)):
+        if kcs:
+            kc = jax.lax.dynamic_index_in_dim(
+                jnp.stack(kcs, 1), i, keepdims=False)  # [L_sparse, kvh, Sc, hd]
+            out[KC] = jax.lax.dynamic_update_slice(
+                out[KC], kc[:, :, None].astype(out[KC].dtype),
+                (0, 0, slots[i], 0, 0))
+        for name, new in states:
             out[name] = [
                 jax.lax.dynamic_update_slice(
                     buf, jax.lax.dynamic_index_in_dim(s, i, keepdims=True),
@@ -473,19 +774,22 @@ def decode_step_impl(config, params, ctx_kv, ring, state, tokens, ctx_lens,
     """One decode step for all slots: (ring, state, logits [B, vocab],
     stats). The attention layers' new rows land in ring slot
     ``ring_pos`` and the region is read-only, as in the dense decoder;
-    ``state`` (``{SSM: [...], CONV: [...]}``, lanes + 1 wide) comes back
-    moved on by one position for the lanes that are ``live`` and bit for
-    bit as it was for the others."""
+    ``state`` (the region's leaves a step writes, ``stepped_kinds``:
+    ``{SSM: [...], CONV: [...]}`` or ``{LIN: [...], KC: rows}``, lanes + 1
+    wide) comes back moved on by one position for the lanes that are
+    ``live`` and bit for bit as it was for the others."""
     c, d = config, dims(config)
     _refuse_adapters(params)
     B = tokens.shape[0]
     h = _embed(c, params, tokens, ctx_kv["k"].dtype)
     stats = stats_zero(c)
     ring = dict(ring)
-    ssm, conv = list(state[SSM]), list(state[CONV])
+    state = {n: (list(v) if isinstance(v, (list, tuple)) else v)
+             for n, v in state.items()}
+    ssm, conv = state.get(SSM), state.get(CONV)
     # the scratch lane rides along as one more row that never moves
     pad = lambda a: jnp.pad(a, ((0, 1),) + ((0, 0),) * (a.ndim - 1))  # noqa: E731
-    a = j = 0
+    a = j = n = sp = 0
     for kind, lp in zip(d["kinds"], params["layers"]):
         x = _rms(h, lp["ln1"], c.rms_norm_eps)
         if kind == "attention":
@@ -501,6 +805,24 @@ def decode_step_impl(config, params, ctx_kv, ring, state, tokens, ctx_lens,
                     jnp.int32(a), ctx_lens, ring_base)
                 mix = o.reshape(B, c.q_dim) @ lp["wo"]
             a += 1
+        elif kind == "sparse_attention":
+            mix, ring, state[KC] = _sparse_decode(
+                c, lp, x, ctx_kv, ring, state[KC], a, sp, ctx_lens,
+                ring_base, ring_pos, live, attn)
+            a += 1
+            sp += 1
+        elif kind == "linear_attention":
+            q, k, v, z = _lin_in(c, lp, x, ctx_lens - 1)
+            decay = jnp.asarray(lightning.log_decays(d["lin_heads"]))
+            with jax.named_scope("lin_attn_scan"):
+                # a lane that is not live: decay exp(0), key 0, the state
+                # as it was
+                o, state[LIN][n] = lightning.step(
+                    pad(q), pad(jnp.where(live[:, None, None], k, 0)),
+                    pad(v), pad(jnp.where(live[:, None], decay[None], 0.0)),
+                    state[LIN][n])
+            mix = _lin_out(c, lp, o[:B], z)
+            n += 1
         else:
             z, xbc, dt = _ssm_in(c, lp, x)
             with jax.named_scope("ssm_conv"):
@@ -519,4 +841,4 @@ def decode_step_impl(config, params, ctx_kv, ring, state, tokens, ctx_lens,
             mix = _ssm_out(c, lp, y[:B], xs, z)
             j += 1
         h, stats = _layer_out(c, lp, h, mix, live, stats)
-    return ring, {SSM: ssm, CONV: conv}, _logits(c, params, h), stats
+    return ring, state, _logits(c, params, h), stats

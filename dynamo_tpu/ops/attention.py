@@ -176,7 +176,8 @@ class PriorContext(NamedTuple):
     v_scale: Optional[jnp.ndarray] = None  # region is int8
 
 
-@functools.partial(jax.jit, static_argnames=("block", "ctx_span"))
+@functools.partial(jax.jit,
+                   static_argnames=("block", "ctx_span", "mask_block"))
 def prefill_attention(
     q: jnp.ndarray,          # [K, T, n_heads, hd] — K chunks of T new tokens
     k_new: jnp.ndarray,      # [K, T, kvh, hd] — the chunks' own keys
@@ -190,6 +191,12 @@ def prefill_attention(
     block: int = PREFILL_BLOCK,
     ctx_span: int = 0,       # STATIC bound on the region rows read (0 =
                              # the whole region)
+    block_masks: Optional[jnp.ndarray] = None,  # [K, kvh, T, NB] bool: may
+                             # query row i of K/V group g read the keys at
+                             # ABSOLUTE positions [b mask_block, (b + 1)
+                             # mask_block)? (a block-sparse SELECTION, ops/
+                             # sparse_attention.py); None = every block
+    mask_block: int = 0,
 ) -> jnp.ndarray:
     """The one prefill attention: blocked, running-softmax, causal, in
     pure XLA, scoring only (query block, key block) pairs that can hold
@@ -220,6 +227,11 @@ def prefill_attention(
     dtype before the PV product. A width that is no multiple of the
     block slides its last block back (start = width - block) and masks
     the rows it has already seen, instead of padding the source.
+
+    With ``block_masks`` every pair is still SCORED (the loops' bounds
+    are causality's) and the selection masks the scores: the same
+    mathematics as an attention that reads only the chosen blocks, at the
+    cost of the whole causal context.
     """
     K, T, n_heads, hd = q.shape
     kvh, hd_v = k_new.shape[2], v_new.shape[3]
@@ -240,9 +252,26 @@ def prefill_attention(
     kt = k_new.transpose(0, 2, 1, 3).astype(q.dtype)  # [K, kvh, T, hd]
     vt = v_new.transpose(0, 2, 1, 3).astype(q.dtype)
 
+    if block_masks is not None:
+        # one spare block, so that a slice that starts in the last one
+        # never slides back
+        block_masks = jnp.pad(block_masks, ((0, 0),) * 3 + ((0, 1),))
+
+    def selected(lane, q0, k0, n):
+        """The selection for query rows q0.. of ``lane`` against the n
+        keys at absolute positions k0..: [kvh, 1, blk, n]."""
+        nb = -(-n // mask_block) + 1
+        m = jax.lax.dynamic_slice(
+            block_masks, (lane, 0, q0, k0 // mask_block),
+            (1, kvh, blk, nb))[0]
+        m = jnp.repeat(m, mask_block, axis=-1)
+        return jax.lax.dynamic_slice(
+            m, (0, 0, k0 % mask_block), (kvh, blk, n))[:, None]
+
     def score(carry, q_blk, k_blk, v_blk, ok):
         """One running-softmax step: q_blk [kvh, rep, blk, hd] against
-        k_blk/v_blk [kvh, n, hd] under ok [blk, n]."""
+        k_blk/v_blk [kvh, n, hd] under ok [blk, n] (or [kvh, 1, blk, n]
+        under a selection)."""
         m, l, acc = carry
         s = jnp.einsum("grqh,gkh->grqk", q_blk, k_blk,
                        preferred_element_type=jnp.float32) * scale
@@ -291,9 +320,11 @@ def prefill_attention(
 
                     k_blk = dequant(k_blk, ctx.k_scale)
                     v_blk = dequant(v_blk, ctx.v_scale)
-                ok = (kp >= j * cb) & (kp < below)
+                ok = ((kp >= j * cb) & (kp < below))[None, :]
+                if block_masks is not None:
+                    ok = ok & selected(lane, q0, k0, cb)
                 return score(carry, q_blk, k_blk.astype(q.dtype),
-                             v_blk.astype(q.dtype), ok[None, :])
+                             v_blk.astype(q.dtype), ok)
 
             carry = jax.lax.fori_loop(
                 0, (below + cb - 1) // cb, ctx_block, carry)
@@ -308,6 +339,8 @@ def prefill_attention(
             else:
                 ok = ok & jax.lax.dynamic_slice(
                     chunk_masks, (lane, q0, k0), (1, blk, blk))[0]
+            if block_masks is not None:
+                ok = ok & selected(lane, q0, q_start + k0, blk)
             return score(
                 carry, q_blk,
                 jax.lax.dynamic_slice(kt, at, (1, kvh, blk, hd))[0],

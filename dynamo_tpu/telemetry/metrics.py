@@ -458,9 +458,31 @@ MOE_PICKS_ROUTED = ("dynamo_moe_picks_routed",
                     "pick) pairs the router made for live lanes in a "
                     "consumed decode round, held here or elsewhere")
 SSM_STATE_BYTES = ("dynamo_ssm_state_bytes",
-                   "bytes one lane holds in recurrent (state-space) state, "
-                   "all layers, whatever its context (observed once, at "
-                   "engine start)")
+                   "bytes one lane holds in recurrent state (a state-space "
+                   "layer's SSM state and convolution window, a "
+                   "linear-attention layer's matrix state), all layers, "
+                   "whatever its context (observed once, at engine start)")
+SPARSE_ATTN_ROWS_READ = (
+    "dynamo_sparse_attn_rows_read",
+    "block-sparse attention models: rows a dispatched decode round's "
+    "sparse layers read, all such layers and steps: the selected blocks' "
+    "rows and the ring of a lane past the switch to the selection, its "
+    "own rows for one below it, and the compressed keys the selection "
+    "scored (every lane's whole compressed region, in their own rows)")
+SPARSE_ATTN_ROWS_LIVE = (
+    "dynamo_sparse_attn_rows_live",
+    "block-sparse attention models: rows a dense read of that round's "
+    "live lanes would take, all sparse layers and steps")
+SPARSE_PREFILL_SCORED = (
+    "dynamo_sparse_prefill_pairs_scored",
+    "block-sparse attention models: (query, key) pairs a prefill "
+    "dispatch's sparse layers computed a score for (the whole causal "
+    "context in whole blocks, masked to the selection afterwards)")
+SPARSE_PREFILL_SELECTED = (
+    "dynamo_sparse_prefill_pairs_selected",
+    "block-sparse attention models: (query, key) pairs the selection "
+    "admits for that dispatch's real rows, all sparse layers: what a "
+    "prefill that gathered the chosen blocks would score")
 HC_SINKHORN_RESIDUAL = (
     "dynamo_hc_sinkhorn_residual",
     "hyper-connection models: max over a consumed decode round's tokens "
@@ -518,6 +540,10 @@ def request_histograms(
         reg.histogram(*KV_ROW_BYTES, tuple(float(4 ** i) for i in range(3, 12)))
         reg.histogram(*SSM_STATE_BYTES,
                       tuple(float(4 ** i) for i in range(6, 15)))
-        for name, help_ in (PREFILL_ATTN_LIVE, PREFILL_ATTN_SCORED):
+        for name, help_ in (PREFILL_ATTN_LIVE, PREFILL_ATTN_SCORED,
+                            SPARSE_PREFILL_SCORED, SPARSE_PREFILL_SELECTED):
             reg.histogram(name, help_, PAIR_BUCKETS)
+        for name, help_ in (SPARSE_ATTN_ROWS_READ, SPARSE_ATTN_ROWS_LIVE):
+            reg.histogram(name, help_,
+                          tuple(float(4 ** i) for i in range(3, 13)))
     return reg

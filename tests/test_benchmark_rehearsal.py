@@ -170,7 +170,11 @@ def test_carried_tok_s_reads_the_way_its_entry_says(monkeypatch, cell, entry,
      None),
     ("granite4h-ep2-d10.ragdoc", "4100410041",
      "benchmarks/references/ssm_moe.py", 1.0),
-], ids=["chat-decode", "longdoc", "longprompt", "ragdoc"])
+    # prompts of 8k-28k tokens: the CPU needs ~3 s a 4096-token chunk of
+    # the tiny model, so one request in the pre-roll and one in the window
+    ("minicpm-sala-d16.longctx", "4500450045",
+     "benchmarks/references/sala.py", 0.17),
+], ids=["chat-decode", "longdoc", "longprompt", "ragdoc", "longctx"])
 def test_the_new_cell_rehearses_on_the_cpu(tmp_path, cell, seed, reference,
                                            rate_rps):
     """A cell's files end to end at the dry-run widths: configuration,
@@ -179,7 +183,9 @@ def test_the_new_cell_rehearses_on_the_cpu(tmp_path, cell, seed, reference,
     can. The long-document cell's check and traffic run a fresh
     4096-token chunk, continuing chunks and decode over a 16384-token
     region here too, and its warm-up set has to leave the window nothing
-    to compile."""
+    to compile. The long-context cell's check crosses the toy model's
+    switch to the block selection (position 1024) in prefill and in
+    decode, over a 32768-token region with its compressed-key rows."""
     root = REPO if rate_rps is None else checkout_at_rate(
         tmp_path, cell, rate_rps)
     r = run([sys.executable, "benchmarks/run.py", "--workload", cell,
@@ -212,8 +218,13 @@ def _sweep_line(tok_s, offered, p50, p90, failed=0):
     (_sweep_line(120.4, 138.7, (3937, 6568), (5662, 7393)), False),
     (_sweep_line(151.7, 152.0, (690, 516), (1246, 1202), failed=1), False),
     ({"failed": 0, "tok_s": 1.0, "offered_tok_s": 1.0}, False),
+    # the long-context cell over 150 s (PERF.md section 6, PR 45): 0.6
+    # req/s and, one step up, a queue by both TTFT arms
+    (_sweep_line(98.8, 109.3, (1642, 2156), (2764, 3388)), True),
+    (_sweep_line(100.7, 119.0, (1608, 3958), (3851, 8184)), False),
 ], ids=["keeps-up", "level-at-0.90", "tail-grows", "first-half-slower",
-        "median-grows", "a-failure", "an-empty-half"])
+        "median-grows", "a-failure", "an-empty-half", "longctx-at-0.6",
+        "longctx-at-0.65"])
 def test_the_knee_rule_reads_the_recorded_sweeps(line, bounded):
     """ONE rule says whether a rate's backlog stayed bounded, for every
     open-loop cell (``tools/knee_sweep.py: bounded``)."""

@@ -114,6 +114,9 @@ def test_registry_render_and_snapshot():
         "dynamo_decode_attn_rows_read", "dynamo_decode_attn_rows_live",
         "dynamo_moe_picks_routed",
         "dynamo_ssm_state_bytes",
+        "dynamo_sparse_attn_rows_read", "dynamo_sparse_attn_rows_live",
+        "dynamo_sparse_prefill_pairs_scored",
+        "dynamo_sparse_prefill_pairs_selected",
         "dynamo_request_tpot_seconds",
         "dynamo_engine_step_gap_seconds",
         "dynamo_engine_step_gap_clean_seconds",

@@ -417,6 +417,70 @@ def test_hybrid_cell_keeps_four_prefill_programs():
                                 (4096, 1, False), (4096, 1, True)]
 
 
+# the linear + sparse attention cell's programs (compiled, PR 45: the round
+# 0.107 GB, a 4096-token chunk 0.364 GB fresh and 0.444 continuing) with a
+# little room. 12.86 GB of weights, rows, compressed keys and state leave
+# the chip ~3 GB
+SPARSE_TEMP_CEILING = {"round_seal": 0.2e9, "batch_prefill": 0.5e9,
+                       "batch_prefill_cont": 0.6e9}
+
+
+@pytest.fixture(scope="module", params=sorted(SPARSE_TEMP_CEILING))
+def sparse_record(request):
+    """The fused round and the ``[1, 4096]`` prefills of the linear +
+    sparse attention cell at its published widths (16 layers, region ``[4,
+    2, 17, 32768, 128]``, compressed keys ``[4, 2, 17, 2048, 128]``, twelve
+    ``[17, 32, 128, 128]`` float32 states), compiled by XLA:TPU and Mosaic
+    for a compile-only v5e (~10-20 s)."""
+    _v5e_or_skip()
+    with jax.default_matmul_precision("default"):
+        (rec,) = tpu_compile_check.compile_programs(
+            config="minicpm-sala-d16", programs=(request.param,),
+            prefill_width=4096, keep_text=True)
+    return request.param, rec
+
+
+def test_sparse_programs_hold_no_copy_of_the_region(sparse_record):
+    """The decode step reads the region where it lies: the chosen blocks
+    by a gather, the keys a step's compressed key averages by one slice a
+    lane (a gather over lanes, or a rolled loop that carries the region,
+    made XLA:TPU relayout all 1.14 GB of K in every step: PR 45), the
+    dense read by the flash kernel (16 query heads a K/V head). XLA's
+    temporaries say so: a materialised copy of K or V alone is 1.14 GB.
+    (``region_copies`` also counts a layout change FUSED into the slices
+    that read it and the state's asynchronous write-backs, which hold no
+    buffer of their own: the ceiling on temporaries is the test.)"""
+    name, rec = sparse_record
+    assert rec["ok"], rec.get("error")
+    assert rec["region_shard"] == [4, 2, 17, 32768, 128]
+    assert rec["temp_bytes"] < SPARSE_TEMP_CEILING[name], rec["temp_gb"]
+    assert 12.8 < rec["argument_gb"] < 12.95
+    if name == "round_seal":
+        # the dense read of a lane below the switch: one Mosaic call a
+        # sparse layer
+        assert rec["mosaic_calls"] >= 4
+
+
+def test_sparse_cell_keeps_four_prefill_programs():
+    """As the other long-prompt cells: 2 buckets x 1 lane x {fresh,
+    continuing} whole-model prefill programs beside the round's two."""
+    import json
+
+    from dynamo_tpu.engine.config import EngineConfig
+
+    with open(os.path.join(os.path.dirname(tpu_compile_check.__file__), "..",
+                           "benchmarks", "configs",
+                           "minicpm-sala-d16.json")) as f:
+        e = EngineConfig(**json.load(f)["engine"])
+    assert (e.max_decode_slots, e.max_context) == (16, 32768)
+    programs = {(T, e.prefill_lanes(T, group), continuing)
+                for T in e.prefill_buckets
+                for group in range(1, e.prefill_chunks_per_round + 1)
+                for continuing in (False, True)}
+    assert sorted(programs) == [(2048, 1, False), (2048, 1, True),
+                                (4096, 1, False), (4096, 1, True)]
+
+
 # ``lowered_sha256`` (tools/tpu_compile_check.py: the StableHLO text before
 # the compiler, Mosaic bodies masked) of the programs that share code with
 # the continuing latent chunk and must NOT move with it: every program

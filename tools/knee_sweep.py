@@ -9,8 +9,9 @@ once, and reads the request log it leaves. One JSON line per rate with
 TTFT percentiles of the window's first and second half (a backlog that
 grows through the window shows as a second half far above the first),
 the tokens completed per second against the tokens the schedule asked
-for, the end-to-end metrics, and ``bounded``: the one rule by which a
-cell's knee is read (``bounded`` below; PERF.md section 4). No JAX here.
+for, the mean number of streams decoding, the end-to-end metrics, and
+``bounded``: the one rule by which a cell's knee is read (``bounded``
+below; PERF.md section 4). No JAX here.
 
   chiprun --timeout 3000 -- python3 tools/knee_sweep.py \
       --workload mla-moe-joyai-d5.chat-decode --rates 4 6 8 10
@@ -96,9 +97,16 @@ def main() -> int:
             gen = stats.reduce_log(log, args.seconds)
             asked = sum(q["asked"] for q in log
                         if stats.of_window(q, args.seconds))
+            # streams between their first and last chunk, averaged over
+            # the window (pre-roll's included): the decode lanes live
+            decoding = sum(
+                max(0.0, min(q["chunks"][-1], args.seconds)
+                    - max(q["chunks"][0], 0.0))
+                for q in log if q["ok"] and q["chunks"]) / args.seconds
             rec.update(
                 correct=line["correct"], attempted=line["attempted"],
                 failed=line["failed"], offered_tok_s=asked / args.seconds,
+                decoding_streams_mean=round(decoding, 2),
                 tok_s=gen.get("tok_s"), tpot_ms_p50=gen.get("tpot_ms_p50"),
                 tpot_ms_p90=gen.get("tpot_ms_p90"),
                 ttft_ms_p90=gen.get("ttft_ms_p90"),
