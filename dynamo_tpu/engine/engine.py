@@ -575,6 +575,10 @@ class TpuEngine:
             tmetrics.DECODE_ATTN_ROWS_LIVE[0])
         self._h_moe_picks_routed = self.telemetry.get(
             tmetrics.MOE_PICKS_ROUTED[0])
+        self._h_moe_pf_sorted = self.telemetry.get(
+            tmetrics.MOE_PREFILL_ROWS_SORTED[0])
+        self._h_moe_pf_moved = self.telemetry.get(
+            tmetrics.MOE_PREFILL_ROWS_MOVED[0])
         self._h_sparse_read = self.telemetry.get(
             tmetrics.SPARSE_ATTN_ROWS_READ[0])
         self._h_sparse_live = self.telemetry.get(
@@ -734,6 +738,9 @@ class TpuEngine:
         # is ready the device stands dry), the last round's consume time
         self._ahead = [0, 0]
         self._newest: Any = None
+        # (rows moved on the device, rows sorted) of the prefill programs
+        # in flight whose expert layers move rows in the looped form
+        self._moe_rows: deque = deque()
         self._t_round_consumed = 0.0
         # sealed blocks awaiting the batched ctx->pool copy:
         # (slot, start_pos, pool_page)
@@ -3714,12 +3721,15 @@ class TpuEngine:
             sum(chunk_lens), llama.prefill_positions_run(
                 self.config, width, q_starts, seq_lens))
         self._observe_attn_pairs(width, q_starts, seq_lens, ctx_span)
-        self.ctx, logits = llama.batch_prefill(
+        sorted_rows = llama.moe_prefill_rows_sorted(self.config, K * width)
+        self.ctx, logits, *moved = llama.batch_prefill(
             self.config, self.params, self.ctx, jnp.asarray(toks),
             jnp.asarray(slots), jnp.asarray(q_starts),
             jnp.asarray(seq_lens), ctx_span, jnp.asarray(adapter_ids),
+            counted=sorted_rows > 0,
         )
         self._newest = logits
+        self._note_moe_rows(moved, sorted_rows)
         self.flight.record(
             "prefill_batch", slots=[r.slot for r in group], width=width,
             dispatch_ms=round((time.monotonic() - t_disp) * 1e3, 3),
@@ -3752,11 +3762,31 @@ class TpuEngine:
         dispatched before it already finished (or was there none)? Then
         the device stood dry when this dispatch found it. The count is
         exact; the time is not, for the device ran dry somewhere between
-        two polls (RoundProf.poll charges the whole stretch)."""
+        two polls (RoundProf.poll charges the whole stretch). The same
+        moment reads what finished prefill programs counted."""
         newest = self._newest
         dry = newest is None or newest.is_ready()
         self._h_dry.observe(float(dry))
         self.prof.poll(dry)
+        self._observe_moe_rows()
+
+    def _note_moe_rows(self, moved: list, sorted_rows: int) -> None:
+        """A prefill program whose expert layers loop over their live row
+        blocks was dispatched: its count of the rows they moved starts
+        for the host now and is read once it has landed
+        (``_observe_moe_rows``), never waited for."""
+        if moved:
+            moved[0].copy_to_host_async()
+            self._moe_rows.append((moved[0], sorted_rows))
+
+    def _observe_moe_rows(self) -> None:
+        """The counts of the prefill programs that have finished, in
+        dispatch order (the device's): rows its expert layers sorted,
+        rows their gathers ran."""
+        while self._moe_rows and self._moe_rows[0][0].is_ready():
+            moved, sorted_rows = self._moe_rows.popleft()
+            self._h_moe_pf_sorted.observe(sorted_rows)
+            self._h_moe_pf_moved.observe(int(moved))
 
     def _observe_attn_pairs(self, width, q_starts, seq_lens,
                             ctx_span) -> None:
@@ -3991,15 +4021,17 @@ class TpuEngine:
             pad_t, [start], [start + len(chunk)],
             e.max_context if start else 0)
         r.prefill_chunks += 1
+        sorted_rows = llama.moe_prefill_rows_sorted(self.config, pad_t)
         # a fresh prompt runs the program with no read of the region
-        self.ctx, logits = llama.prefill(
+        self.ctx, logits, *moved = llama.prefill(
             self.config, self.params, self.ctx,
             jnp.asarray(toks), jnp.int32(r.slot),
             jnp.int32(start), jnp.int32(start + len(chunk)),
             embeds, embeds_mask, jnp.int32(r.adapter_id),
-            fresh=start == 0,
+            fresh=start == 0, counted=sorted_rows > 0,
         )
         self._newest = logits
+        self._note_moe_rows(moved, sorted_rows)
         self.flight.record(
             "prefill", slots=[r.slot], tokens=len(chunk), start=start,
             dispatch_ms=round((time.monotonic() - t_disp) * 1e3, 3),
@@ -4555,5 +4587,6 @@ class TpuEngine:
         self._prefilling = {}
         self._entries = []
         self._newest = None
+        self._moe_rows.clear()
         self._seal_queue = []
 

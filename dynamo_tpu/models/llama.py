@@ -765,6 +765,17 @@ def prefill_positions_run(c: ModelConfig, T: int, q_starts,
     return int(trips.sum()) * R
 
 
+def moe_prefill_rows_sorted(c: ModelConfig, n_tokens: int) -> int:
+    """(token, pick) rows the expert layers of one prefill program sort
+    over ``n_tokens`` positions (lanes x bucket width), where they move
+    those rows in the looped form — the host's mirror of
+    ``moe.move_block``, the program's own rule; else 0. Only a block that
+    holds a share of its experts loops, and only the hybrid block holds
+    one."""
+    return ssm_moe.prefill_rows_sorted(c, n_tokens) if block_of(
+        c) is ssm_moe else 0
+
+
 @functools.partial(jax.jit, static_argnames=("half", "c", "R"))
 def _live_rows(half, c: ModelConfig, layers, l, ad, trips, rows, R: int):
     """One row-wise half of decoder layer ``l`` (``half`` = _layer_qkv or
@@ -859,6 +870,11 @@ def prefill_impl(
                               # ignored when params carry no bank
     fresh: bool = False,      # STATIC: the caller knows q_start == 0 —
                               # no read of the region is compiled at all
+    counted: bool = False,    # STATIC: the hybrid block's third output
+                              # too, the rows its expert layers' looped
+                              # gathers ran (scalar i32; the engine asks
+                              # where moe_prefill_rows_sorted says they
+                              # loop)
 ) -> tuple[Cache, jnp.ndarray]:
     """Run T new tokens through the model, writing their KV into the
     slot's contiguous context region at [q_start, q_start+T).
@@ -875,9 +891,10 @@ def prefill_impl(
     """
     c = config
     if block_of(c) is not None:
-        return block_of(c).prefill_impl(
+        out = block_of(c).prefill_impl(
             c, params, ctx_kv, tokens, slot, q_start, seq_len, embeds,
             embeds_mask, adapter_id, fresh)
+        return out if counted else out[:2]
     T = tokens.shape[0]
     inv_freq = jnp.asarray(
         rope_inv_freq(c.head_dim, c.rope_theta, c.rope_scaling_dict)
@@ -960,7 +977,7 @@ def prefill_impl(
 
 
 prefill = jax.jit(
-    prefill_impl, static_argnums=(0,), static_argnames=("fresh",),
+    prefill_impl, static_argnums=(0,), static_argnames=("fresh", "counted"),
     donate_argnums=(2,),
 )
 
@@ -1123,6 +1140,7 @@ def batch_prefill_impl(
                             # no context read compiled at all)
     adapter_ids: Optional[jnp.ndarray] = None,  # [K] i32 — resident LoRA
                             # bank rows (0 = identity; padding lanes 0)
+    counted: bool = False,  # STATIC: a third output, as prefill_impl's
 ) -> tuple[Cache, jnp.ndarray]:
     """Batched multi-request prefill: K chunks through the model in ONE
     program — the TTFT lever for concurrent arrivals (reference analogue:
@@ -1143,9 +1161,10 @@ def batch_prefill_impl(
     tokens out of MoE routing and their region writes hit scratch.
     """
     if block_of(config) is not None:
-        return block_of(config).batch_prefill_impl(
+        out = block_of(config).batch_prefill_impl(
             config, params, ctx_kv, tokens, slots, q_starts, seq_lens,
             ctx_span, adapter_ids)
+        return out if counted else out[:2]
     ks, vs, h = _batch_forward(
         config, params, ctx_kv, tokens, slots, q_starts, seq_lens, ctx_span,
         adapter_ids,
@@ -1158,7 +1177,8 @@ def batch_prefill_impl(
 
 
 batch_prefill = jax.jit(
-    batch_prefill_impl, static_argnums=(0, 7), donate_argnums=(2,)
+    batch_prefill_impl, static_argnums=(0, 7), static_argnames=("counted",),
+    donate_argnums=(2,),
 )
 
 

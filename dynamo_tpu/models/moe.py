@@ -194,6 +194,15 @@ GMM_ROWS = 128   # rows per tile of the TPU kernel (tools/moe_gmm_bench.py)
 # output tiles. 2048 x 768 (3 MiB) fits whole; 3584 x 1024 (7 MiB) does
 # not ("Ran out of memory in memory space vmem", compile-only, PR 37)
 GMM_TILE_BYTES = 4 * 2 ** 20
+# The two row movements of a call that holds a SHARE of the experts (the
+# gather into sorted order, the un-sort) loop over blocks of MOVE_ROWS
+# rows, of which only those with a live row run, where the call sorts more
+# than MOVE_STRAIGHT_ROWS rows. Both chosen on the chip
+# (tools/moe_rows_bench.py, PERF.md section 6, PR 46): 256 rows beat 128
+# and 512 at 20480 and 40960 sorted rows; with a share the loops tie
+# straight-line at 2560 rows and win from 5120 up.
+MOVE_ROWS = 256
+MOVE_STRAIGHT_ROWS = 4096
 
 
 def gmm_tile_n(k: int, n: int, itemsize: int) -> int:
@@ -268,7 +277,13 @@ def grouped_experts(
     order = jnp.argsort(flat, stable=True)
     expert_of_row = jnp.minimum(flat[order], E - 1)
     group_sizes = jnp.zeros(E + 1, jnp.int32).at[flat].add(1)[:E]
-    xs = x[order // K]                                   # [T*K, H]
+    held = first is not None
+    R = move_block(T, K, held)
+    with jax.named_scope("moe_gather"):
+        if R:
+            xs = _gather_live(x, order, K, group_sizes.sum(), R)
+        else:
+            xs = x[order // K]                           # [T*K, H]
     with jax.named_scope("moe_experts"):
         g = _grouped(xs, wg, group_sizes, expert_of_row)
         u = _grouped(xs, wu, group_sizes, expert_of_row)
@@ -276,20 +291,96 @@ def grouped_experts(
     # the inverse permutation: the sorted row that holds pick (t, k)
     back = jnp.zeros_like(order).at[order].set(
         jnp.arange(T * K, dtype=order.dtype))
-    y = y[back].reshape(T, K, -1)
+    with jax.named_scope("moe_combine"):
+        if R:
+            out = _combine_live(y, back, weights, flat, valid, E, held, R)
+        else:
+            out = _combine(y[back], weights, flat, valid, E, held)
+    return out, group_sizes
+
+
+def _combine(y, weights, flat, valid, E: int, held: bool):
+    """sum_k weights[t, k] * y[t, k], summed in float32, for a run of n
+    tokens: y [n * K, H] their picks' rows un-sorted, flat [n * K] the
+    picks' group ids. In y's dtype."""
+    n, K = weights.shape
+    dtype, y = y.dtype, y.reshape(n, K, -1)
     w = weights.astype(jnp.float32)
-    if first is not None:
+    if held:
         # picks held elsewhere, and unrouted rows, hold whatever the
         # product left there
-        here = (flat < E).reshape(T, K)
+        here = (flat < E).reshape(n, K)
         y = jnp.where(here[..., None], y, 0)
         w = jnp.where(here, w, 0.0)
     elif valid is not None:
         # unrouted rows hold whatever the product left there
         y = jnp.where(valid[:, None, None], y, 0)
         w = jnp.where(valid[:, None], w, 0.0)
-    out = jnp.einsum("tk,tkh->th", w, y.astype(jnp.float32))
-    return out.astype(x.dtype), group_sizes
+    return jnp.einsum("tk,tkh->th", w, y.astype(jnp.float32)).astype(dtype)
+
+
+def move_block(n_tokens: int, top_k: int, held: bool) -> int:
+    """Rows a block of the looped row movements for a call that routes
+    ``n_tokens`` tokens to ``top_k`` experts each, or 0 where both run
+    straight-line over all n_tokens x top_k rows. Decided by what the call
+    can see, and the host's mirror (the engine's counters) calls it too:
+    its shape, and whether it holds a share (``held``: about half its rows
+    are dead by construction, whatever the padding). A call that holds
+    every expert has only its padding dead, and there the loops lose
+    (PERF.md section 6, PR 46)."""
+    R = MOVE_ROWS
+    if not held or n_tokens % R or n_tokens * top_k <= MOVE_STRAIGHT_ROWS:
+        return 0
+    return R
+
+
+def rows_moved(total, R: int):
+    """Rows the gather loop runs for ``total`` live sorted rows: whole
+    blocks. numpy or int in, the same out; traced in, traced out."""
+    return (total + R - 1) // R * R
+
+
+def _gather_live(x, order, K: int, total, R: int):
+    """``x[order // K]`` for the sorted rows below ``total`` (the groups'
+    total: held and valid picks; whatever has id E sorts behind them), in
+    blocks of R rows. Rows of blocks that never ran are 0: no group holds
+    them, so the grouped products never visit them, and nothing reads
+    their results unmasked."""
+    def block(i, xs):
+        rows = jax.lax.dynamic_slice(order, (i * R,), (R,)) // K
+        return jax.lax.dynamic_update_slice(xs, x[rows], (i * R, 0))
+
+    return jax.lax.fori_loop(
+        0, rows_moved(total, R) // R, block,
+        jnp.zeros((order.shape[0], x.shape[1]), x.dtype))
+
+
+def _combine_live(y, back, weights, flat, valid, E: int, held: bool, R: int):
+    """``_combine`` over the blocks of R tokens that hold a valid one (all
+    of them without ``valid``); the rows of a block that never ran are 0,
+    as ``_combine`` leaves an unrouted row. A block's sums are
+    ``_combine``'s own, in its order."""
+    T, K = weights.shape
+    if valid is None:
+        n, blocks = T // R, jnp.arange(T // R, dtype=jnp.int32)
+    else:
+        live = valid.reshape(T // R, R).any(axis=1)
+        n, blocks = live.sum(), jnp.argsort(~live, stable=True)
+
+    def block(i, out):
+        t0 = blocks[i].astype(jnp.int32) * R
+
+        def cut(a, per=1):   # the block's tokens' part of a
+            return jax.lax.dynamic_slice(
+                a, (t0 * per,) + (0,) * (a.ndim - 1),
+                (R * per,) + a.shape[1:])
+
+        part = _combine(y[cut(back, K)], cut(weights), cut(flat, K),
+                        None if valid is None else cut(valid), E, held)
+        return jax.lax.dynamic_update_slice(out, part, (t0, 0))
+
+    return jax.lax.fori_loop(
+        0, n, block, jnp.zeros((T, y.shape[1]), y.dtype))
 
 
 def moe_reference(h, params, cfg: MoEConfig) -> jnp.ndarray:

@@ -80,7 +80,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models.mla_moe import _mlp, _rms   # the same norm and SwiGLU
-from dynamo_tpu.models.moe import grouped_experts
+from dynamo_tpu.models.moe import grouped_experts, move_block, rows_moved
 from dynamo_tpu.ops import lightning, mamba2, sparse_attention
 from dynamo_tpu.ops.attention import (
     DecodeAttention,
@@ -394,6 +394,22 @@ def merge_stats(a, b):
                       a[3] + b[3]])
 
 
+def _move_block(d, n_tokens: int) -> int:
+    """``moe.move_block`` for the expert layers of a program over
+    ``n_tokens`` positions; 0 without expert layers."""
+    if not d["experts"]:
+        return 0
+    return move_block(n_tokens, d["K"], d["first"] is not None)
+
+
+def prefill_rows_sorted(c: ModelConfig, n_tokens: int) -> int:
+    """(token, pick) rows the expert layers of one prefill program over
+    ``n_tokens`` positions sort, where they move rows in the looped form;
+    0 where they do not, or there are none."""
+    d = dims(c)
+    return n_tokens * d["K"] * c.num_layers if _move_block(d, n_tokens) else 0
+
+
 def _ffn(c: ModelConfig, lp, x, valid, stats):
     """Routed experts (this chip's share) + the shared MLP, ungated."""
     d = dims(c)
@@ -649,6 +665,8 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
     continuing = q_starts > 0
     h = _embed(c, params, tokens.reshape(K * T), cdt)
     stats = stats_zero(c)
+    R = _move_block(d, K * T)
+    moved = jnp.int32(0)
     ks, vs, kcs, ssm_out, conv_out, lin_out = [], [], [], [], [], []
     A = lambda lp: -jnp.exp(lp["A_log"])  # noqa: E731
     for kind, lp in zip(d["kinds"], params["layers"]):
@@ -715,7 +733,10 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
             conv_out.append(win)
             mix = _ssm_out(c, lp, y.reshape(K * T, d["nh"], d["P"]),
                            xs.reshape(K * T, d["nh"], d["P"]), z)
+        before = stats
         h, stats = _layer_out(c, lp, h, mix, valid, stats)
+        if R:   # the layer's held picks are its groups' total
+            moved = moved + rows_moved(stats[1] - before[1], R)
 
     # tail: every read is done. Rows as spans, compressed keys and states
     # as whole lanes
@@ -749,7 +770,7 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
     last = jnp.maximum(seq_lens - q_starts - 1, 0)
     h_last = jnp.take_along_axis(
         h.reshape(K, T, -1), last[:, None, None], axis=1)[:, 0]
-    return out_ctx, _logits(c, params, h_last)
+    return out_ctx, _logits(c, params, h_last), moved
 
 
 def prefill_impl(config, params, ctx_kv, tokens, slot, q_start, seq_len,
@@ -760,10 +781,10 @@ def prefill_impl(config, params, ctx_kv, tokens, slot, q_start, seq_len,
         raise ValueError("the state-space hybrid block takes no embedding "
                          "overrides (multimodal)")
     one = lambda x: jnp.asarray(x, jnp.int32)[None]  # noqa: E731
-    ctx_kv, logits = batch_prefill_impl(
+    ctx_kv, logits, moved = batch_prefill_impl(
         config, params, ctx_kv, tokens[None], one(slot), one(q_start),
         one(seq_len), 0 if fresh else ctx_kv["k"].shape[3])
-    return ctx_kv, logits[0]
+    return ctx_kv, logits[0], moved
 
 
 # ---------------------------------------------------------------------------

@@ -457,6 +457,17 @@ MOE_PICKS_ROUTED = ("dynamo_moe_picks_routed",
                     "models that hold a share of the experts: all (token, "
                     "pick) pairs the router made for live lanes in a "
                     "consumed decode round, held here or elsewhere")
+MOE_PREFILL_ROWS_SORTED = (
+    "dynamo_moe_prefill_rows_sorted",
+    "(token, pick) rows the expert layers of a finished prefill program "
+    "sorted, all its expert layers, where they move rows in the looped "
+    "form (models/moe.py: move_block): positions x picks x expert layers")
+MOE_PREFILL_ROWS_MOVED = (
+    "dynamo_moe_prefill_rows_moved",
+    "rows the looped gathers of that program's expert layers ran: the "
+    "row blocks (moe.MOVE_ROWS high) that hold a pick computed here "
+    "(routed, on an expert held here) x their height, counted by the "
+    "program itself")
 SSM_STATE_BYTES = ("dynamo_ssm_state_bytes",
                    "bytes one lane holds in recurrent state (a state-space "
                    "layer's SSM state and convolution window, a "
@@ -534,7 +545,8 @@ def request_histograms(
             reg.histogram(name, help_, TOKEN_BUCKETS)
         reg.histogram(*HC_SINKHORN_RESIDUAL,
                       tuple(10.0 ** i for i in range(-9, 1)))
-        for name, help_ in (DECODE_ATTN_ROWS_READ, DECODE_ATTN_ROWS_LIVE):
+        for name, help_ in (DECODE_ATTN_ROWS_READ, DECODE_ATTN_ROWS_LIVE,
+                            MOE_PREFILL_ROWS_SORTED, MOE_PREFILL_ROWS_MOVED):
             reg.histogram(name, help_,
                           tuple(float(4 ** i) for i in range(3, 13)))
         reg.histogram(*KV_ROW_BYTES, tuple(float(4 ** i) for i in range(3, 12)))

@@ -24,7 +24,7 @@ import pytest
 
 from dynamo_tpu.engine.config import EngineConfig
 from dynamo_tpu.engine.engine import TpuEngine
-from dynamo_tpu.models import llama, ssm_moe
+from dynamo_tpu.models import llama, moe, ssm_moe
 from dynamo_tpu.models.config import _TINY_SSM_MOE, ModelConfig
 from dynamo_tpu.ops import mamba2
 from dynamo_tpu.ops.attention import REFERENCE
@@ -140,6 +140,41 @@ async def test_served_path_equals_the_reference(setup, case):
     await eng.stop()
 
 
+async def test_looped_expert_rows_serve_the_reference_and_are_counted(
+        monkeypatch, setup):
+    """The served path with the expert layers' row movements looped over
+    toy blocks of 8 (a config of its own, so that no cached program of
+    another test is met): a fresh 32-token chunk and a ragged continuing
+    one still equal the reference, and each prefill program's count of
+    the rows it moved lands in telemetry beside the rows it sorted,
+    without a fetch the host waits for."""
+    _, _, ref = setup
+    monkeypatch.setattr(moe, "MOVE_ROWS", 8)
+    monkeypatch.setattr(moe, "MOVE_STRAIGHT_ROWS", 0)
+    hf = dict(HF, rms_norm_eps=1.5e-5)
+    cfg = ModelConfig.tiny_ssm_moe(dtype="float32", rms_norm_eps=1.5e-5)
+    params = llama.init_params(cfg, 3)
+    eng = engine(cfg, params)
+    prompt = prompt_of(45, 10)
+    toks, tops = await serve(eng, prompt, 12)
+    assert distance(ref, params, prompt, toks, tops, hf=hf) < TOL
+    assert eng.dispatch_counts["prefill"] == 2 and not eng._moe_rows
+    snap = eng.telemetry.snapshot()
+    rows_sorted = snap["dynamo_moe_prefill_rows_sorted"]
+    rows_moved = snap["dynamo_moe_prefill_rows_moved"]
+    assert rows_sorted["count"] == rows_moved["count"] == 2
+    # 32 + 16 positions x 2 picks x 6 expert layers
+    assert rows_sorted["sum"] == ssm_moe.prefill_rows_sorted(
+        cfg, 32) + ssm_moe.prefill_rows_sorted(cfg, 16) == 48 * 2 * 6
+    # about half of 45 tokens' picks are held here, in blocks of 8
+    assert 0 < rows_moved["sum"] < rows_sorted["sum"]
+    assert rows_moved["sum"] % 8 == 0
+    await eng.stop()
+    # under the real rule these programs run straight-line, uncounted
+    monkeypatch.undo()
+    assert ssm_moe.prefill_rows_sorted(cfg, 32) == 0
+
+
 async def test_chunks_interleave_with_other_lanes_decode_and_lanes_are_reused(
         setup):
     """A prompt prefilled in three chunks WHILE another lane decodes
@@ -220,16 +255,24 @@ def test_chunked_scan_equals_the_recurrence_and_masks_its_padding():
                                atol=1e-4)
 
 
-def test_the_shares_and_the_shared_mlp_once_add_up_to_the_uncut_layer(setup):
+@pytest.mark.parametrize("tokens,block", [(13, 0), (16, 4)],
+                         ids=["straight-line", "looped"])
+def test_the_shares_and_the_shared_mlp_once_add_up_to_the_uncut_layer(
+        monkeypatch, setup, tokens, block):
     """One test ties the share to the model: the program's expert layer
     as share 0 of 2 and as share 1 of 2, the shared MLP counted once, is
-    the reference's UNCUT layer (all 8 experts held)."""
+    the reference's UNCUT layer (all 8 experts held) — with the layer's
+    row movements straight-line, and looped over blocks of 4 rows."""
     cfg, _, ref = setup
+    if block:
+        monkeypatch.setattr(moe, "MOVE_ROWS", block)
+        monkeypatch.setattr(moe, "MOVE_STRAIGHT_ROWS", 0)
+    assert moe.move_block(tokens, 2, True) == block
     uncut_hf = dict(_TINY_SSM_MOE, num_local_experts=8, expert_share=None)
     whole = llama.init_params(
         ModelConfig.from_hf_dict(dict(uncut_hf, dtype="float32")), 5)
     lp = jax.tree.map(lambda a: a.astype(jnp.float32), whole["layers"][0])
-    x = jnp.asarray(np.random.RandomState(7).randn(13, cfg.hidden_size),
+    x = jnp.asarray(np.random.RandomState(7).randn(tokens, cfg.hidden_size),
                     jnp.float32)
     hp = ref.hyper(uncut_hf)
     want = ref.routed(hp, lp, x) + ref.shared(lp, x)
@@ -245,13 +288,13 @@ def test_the_shares_and_the_shared_mlp_once_add_up_to_the_uncut_layer(setup):
                                 ssm_moe.stats_zero(share))
         total = total + y
         picks += int(stats[1])
-        assert int(stats[3]) == 13 * 2
+        assert int(stats[3]) == tokens * 2
         # and the reference given the same share computes the same part
         part = ref.routed(ref.hyper(dict(_TINY_SSM_MOE, expert_share={
             "published_experts": 8, "of": 2, "index": index})), mine, x)
         np.testing.assert_allclose(y - ref.shared(lp, x), part, rtol=1e-4,
                                    atol=1e-4)
-    assert picks == 13 * 2                # every pick landed on one share
+    assert picks == tokens * 2               # every pick landed on one share
     np.testing.assert_allclose(total, want, rtol=1e-4, atol=1e-4)
 
 

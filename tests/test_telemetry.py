@@ -113,6 +113,8 @@ def test_registry_render_and_snapshot():
         "dynamo_hc_sinkhorn_residual", "dynamo_prefill_continued_tokens",
         "dynamo_decode_attn_rows_read", "dynamo_decode_attn_rows_live",
         "dynamo_moe_picks_routed",
+        "dynamo_moe_prefill_rows_sorted",
+        "dynamo_moe_prefill_rows_moved",
         "dynamo_ssm_state_bytes",
         "dynamo_sparse_attn_rows_read", "dynamo_sparse_attn_rows_live",
         "dynamo_sparse_prefill_pairs_scored",

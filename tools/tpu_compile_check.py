@@ -161,6 +161,7 @@ def compile_programs(model_config: str = "llama3_1b", tp: int = 1,
     if T not in e.prefill_buckets:
         raise ValueError(f"{T} is no prefill bucket of {e.prefill_buckets}")
     K = e.prefill_lanes(T, 2)
+    counted = llama.moe_prefill_rows_sorted(c, K * T) > 0
 
     def largest_shard(state):
         a = max(jax.tree.leaves(state), key=lambda a: math.prod(a.shape))
@@ -231,11 +232,15 @@ def compile_programs(model_config: str = "llama3_1b", tp: int = 1,
                                         page_scales)
             if quant else
             llama.scatter_pages.trace(pool, i32(n_pages), page_data)),
+        # counted as the engine dispatches it: where the expert layers
+        # move rows in the looped form the program also returns its count
         "batch_prefill": lambda: llama.batch_prefill.trace(
             c, params, ctx, i32(K, T), i32(K), i32(K), i32(K), 0, i32(K),
+            counted=counted,
         ),
         "batch_prefill_cont": lambda: llama.batch_prefill.trace(
             c, params, ctx, i32(K, T), i32(K), i32(K), i32(K), S, i32(K),
+            counted=counted,
         ),
     }
     if llama.block_of(c) is not None:
