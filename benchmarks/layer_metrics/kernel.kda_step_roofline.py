@@ -1,0 +1,42 @@
+"""The delta-rule decode step kernel's share of its roofline
+(ops/kda.py: ``step_pallas``, the Pallas call named ``kda_step``: one a
+KDA layer a decode step, every lane's [heads, D, D] float32 state read
+and written once, in place). Bound: HBM bandwidth (a state element is
+read, scaled, updated by one outer-product term and written: ~6
+operations for 8 bytes).
+
+Bytes: the per-lane states the program's counter says the window's rounds
+stepped (``dynamo_kda_state_rows_stepped``), as a mean a round, x 2 x one
+state (``benchmarks/bytes/<name>.py: kda_step_bytes``), x the rounds the
+traced span holds (executions of ``jit_engine_round_seal``). Time: the
+seconds of every custom call whose label starts with ``kda_step`` in the
+traced span (``sources["trace"]["kernels"]``). A program without the
+kernel or the counter, or a byte count without ``kda_step_bytes``:
+nothing to read."""
+import os
+
+_HERE = os.path.dirname(os.path.abspath(__file__))
+_BYTES = os.path.join(os.path.dirname(_HERE), "bytes")
+MODULE = "jit_engine_round_seal"
+KERNEL = "kda_step"
+
+
+def read(sources):
+    trace, cfg = sources.get("trace"), sources["config"]
+    if not trace or "bytes" not in cfg or MODULE not in trace.get(
+            "modules", {}):
+        return None
+    mod = sources["byname"].module_with(_BYTES, cfg["bytes"],
+                                        "decode_bytes_per_step")
+    if not hasattr(mod, "kda_step_bytes"):
+        return None
+    stepped = mod.kda_states_stepped(sources)
+    seconds = sum(s for label, s in trace.get("kernels", {}).items()
+                  if label.split(" ")[0] == KERNEL)
+    if stepped is None or seconds <= 0:
+        return None
+    states, rounds = stepped
+    nbytes = (mod.kda_step_bytes(states / rounds)(cfg)
+              * trace["modules"][MODULE]["count"])
+    _, bw = sources["peaks"].peaks_for(sources["engine_up"]["device_kind"])
+    return nbytes / bw / seconds * 100.0

@@ -361,6 +361,11 @@ class TpuEngine:
         self._sparse, self._sparse_layers = (
             ssm_moe.sparse_layers(model_config)
             if self._block is ssm_moe else (None, 0))
+        # the stats row's width says what rides it (the block's
+        # stats_zero): a fifth counter is the grouped router's
+        self._stats_width = (
+            self._block.stats_zero(model_config).shape[0]
+            if self._block is not None else 0)
         if self._block is not None:
             self._refuse_row_only_planes(self.ecfg, on_dispatch,
                                          draft_config)
@@ -575,6 +580,10 @@ class TpuEngine:
             tmetrics.DECODE_ATTN_ROWS_LIVE[0])
         self._h_moe_picks_routed = self.telemetry.get(
             tmetrics.MOE_PICKS_ROUTED[0])
+        self._h_moe_groups_kept = self.telemetry.get(
+            tmetrics.MOE_GROUPS_KEPT_HERE[0])
+        self._h_kda_rows = self.telemetry.get(
+            tmetrics.KDA_STATE_ROWS_STEPPED[0])
         self._h_moe_pf_sorted = self.telemetry.get(
             tmetrics.MOE_PREFILL_ROWS_SORTED[0])
         self._h_moe_pf_moved = self.telemetry.get(
@@ -590,6 +599,9 @@ class TpuEngine:
         # bytes a token holds in the ctx region, and bytes a lane holds
         # in recurrent state whatever its context: observed once, here
         recurrent = llama.state_kinds(self.ctx)
+        # delta-rule layers: every one rewrites every lane's matrix state
+        # every step (what _h_kda_rows counts a round)
+        self._kda_layers = len(self.ctx.get(ssm_moe.KDA, ()))
         self.telemetry.get(tmetrics.KV_ROW_BYTES[0]).observe(sum(
             x.nbytes for n, leaf in self.ctx.items() if n not in recurrent
             for x in jax.tree.leaves(leaf)
@@ -1252,8 +1264,10 @@ class TpuEngine:
         head_dim], addressable by position) refuse a latent-row model and
         a model with recurrent layers at start-up, by name: none
         reinterprets the row, and none snapshots a recurrent state."""
-        what = ("a latent (MLA) cache row" if self.config.mla is not None
-                else "a recurrent (state-space) state")
+        what = " beside ".join(
+            ["a latent (MLA) cache row"] * (self.config.mla is not None)
+            + ["a recurrent (state-space) state"]
+            * (self.config.hybrid is not None))
         if self._sparse is not None:
             what = ("a recurrent (linear-attention) state and "
                     "compressed-key rows (kc)")
@@ -1279,7 +1293,7 @@ class TpuEngine:
                 raise ValueError(
                     f"{plane} cannot carry {what} yet; "
                     "turn it off for this model")
-        need = self._block.stats_zero(self.config).shape[0]
+        need = self._stats_width
         if e.max_decode_slots < need:
             # the round's counters (the block's stats_zero) ride home in
             # one more row of the stacked-token fetch, max_decode_slots
@@ -2478,6 +2492,8 @@ class TpuEngine:
             self._observe_decode_attn_rows(active, n)
         if self._sparse is not None:
             self._observe_sparse_rows(active, n)
+        if self._kda_layers:
+            self._h_kda_rows.observe(n * self._B * self._kda_layers)
         # only dispatched lanes advance (spec slots track their own
         # lengths through verify processing)
         self._ctx_disp[active] = np.minimum(
@@ -4369,6 +4385,9 @@ class TpuEngine:
                 # picks that landed on it, this all the picks the router made
                 self._h_moe_picks_routed.observe(
                     int(toks[entry.n_steps, 3]))
+                if self._stats_width > 4:
+                    self._h_moe_groups_kept.observe(
+                        int(toks[entry.n_steps, 4]))
             if self.config.hc is not None:
                 # the fourth counter is a float32's bits
                 self._h_hc_residual.observe(float(

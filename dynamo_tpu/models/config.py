@@ -23,6 +23,11 @@ _SSM_MOE_TYPES = frozenset({"granitemoehybrid"})
 # layers, one dense MLP a layer: the same hybrid stack (models/ssm_moe.py)
 # with other mixers
 _LINEAR_SPARSE_TYPES = frozenset({"minicpm_sala"})
+# delta-rule linear attention (KDA) layers beside latent (MLA) layers, a
+# leading run of dense layers, then sigmoid-routed experts picked within
+# groups: the same hybrid stack again (_from_hf_kda_latent). Tested BEFORE
+# the latent block's ``"kv_lora_rank" in d`` arm: such a file has the key
+_KDA_LATENT_TYPES = frozenset({"ling3_flash", "bailing_hybrid"})
 # keys that mean "not a dense Llama": a config carrying one is refused
 # rather than read with its extra structure dropped
 _FOREIGN_KEYS = ("kv_lora_rank", "q_lora_rank", "n_routed_experts",
@@ -71,6 +76,60 @@ _MIXER_KINDS = {"lightning-attn": "linear_attention",
 # attend everything
 _SPARSE_KEYS = ("kernel_size", "kernel_stride", "block_size", "topk",
                 "init_blocks", "window_size", "dense_len")
+# the KDA + latent stack as its config.json parameterises it: every key
+# the equations read, each with the one value this program builds (None:
+# any value, checked by the reader)
+_KDA_LATENT_KEYS = {
+    "hidden_size": None, "intermediate_size": None,
+    "num_hidden_layers": None, "num_attention_heads": None,
+    "head_dim": None, "vocab_size": None, "first_k_dense_replace": None,
+    "moe_intermediate_size": None,
+    "moe_shared_expert_intermediate_size": None, "num_experts": None,
+    "num_experts_per_tok": None, "n_group": None, "topk_group": None,
+    "routed_scaling_factor": None, "kv_lora_rank": None,
+    "q_lora_rank": None, "qk_nope_head_dim": None,
+    "qk_rope_head_dim": None, "v_head_dim": None, "rope_theta": None,
+    "rms_norm_eps": None, "layer_group_size": None,
+    "short_conv_kernel_size": None, "kda_lower_bound": None,
+    "rotary_dim": None, "partial_rotary_factor": None,
+    "num_kv_heads_for_linear_attn": None,
+    "expert_swiglu_limit_list": None,
+    "share_expert_swiglu_limit_list": None,
+    "score_function": "sigmoid", "norm_topk_prob": True,
+    "moe_router_enable_expert_bias": True, "kda_safe_gate": True,
+    "linear_silu": True, "use_qk_norm": True, "group_norm_size": 1,
+    "gated_attention_proj_granularity_type": "head_wise",
+    "no_kda_lora": True, "use_kda_lora": False, "use_nGPT": False,
+    "value_norm": False, "up_proj_norm": False,
+    "scale_router_input": False, "mtp_use_kda": False,
+    "use_mla_nope": False,
+}
+_TINY_KDA_LATENT = {
+    "model_type": "ling3_flash", "vocab_size": 256, "hidden_size": 64,
+    "intermediate_size": 128, "num_hidden_layers": 7,
+    "first_k_dense_replace": 1, "layer_group_size": 3,
+    "num_attention_heads": 4, "num_key_value_heads": 4, "head_dim": 16,
+    "num_kv_heads_for_linear_attn": 0, "short_conv_kernel_size": 4,
+    "kda_safe_gate": True, "kda_lower_bound": -5, "linear_silu": True,
+    "use_qk_norm": True, "group_norm_size": 1,
+    "gated_attention_proj_granularity_type": "head_wise",
+    "no_kda_lora": True, "use_kda_lora": False, "mtp_use_kda": False,
+    "use_nGPT": False, "value_norm": False, "up_proj_norm": False,
+    "scale_router_input": False, "use_mla_nope": False,
+    "q_lora_rank": None, "kv_lora_rank": 24, "qk_nope_head_dim": 16,
+    "qk_rope_head_dim": 8, "v_head_dim": 16, "rotary_dim": 8,
+    "partial_rotary_factor": 0.5, "rope_theta": 10000.0,
+    "num_experts": 16, "num_experts_per_tok": 4, "n_group": 4,
+    "topk_group": 2, "routed_scaling_factor": 2.5, "norm_topk_prob": True,
+    "score_function": "sigmoid", "moe_router_enable_expert_bias": True,
+    "moe_intermediate_size": 32,
+    "moe_shared_expert_intermediate_size": 32,
+    "num_local_experts": 4,
+    "expert_share": {"published_experts": 16, "of": 4, "index": 0},
+    "expert_swiglu_limit_list": [0] * 7,
+    "share_expert_swiglu_limit_list": [0] * 7,
+    "rms_norm_eps": 1e-6, "max_position_embeddings": 512,
+}
 _TINY_LINEAR_SPARSE = {
     "model_type": "minicpm_sala", "vocab_size": 256, "hidden_size": 64,
     "intermediate_size": 128, "num_hidden_layers": 6,
@@ -184,8 +243,13 @@ class ModelConfig:
     # `num_heads`/`num_kv_heads`/`head_dim` are the attention layers'.
     # The same stack with other mixers (_from_hf_linear_sparse): linear
     # attention with a matrix state, block-sparse attention with
-    # compressed-key rows, one dense MLP a layer; what a layer does
-    # follows from `layer_types`.
+    # compressed-key rows, one dense MLP a layer; and
+    # (_from_hf_kda_latent, which sets `mla` beside `hybrid`) delta-rule
+    # linear attention with a matrix state and three convolution windows,
+    # latent attention with the `kv` row of the latent block, a leading
+    # run of dense layers, grouped sigmoid routing. What a layer does
+    # follows from `layer_types`: mamba | attention | linear_attention |
+    # sparse_attention | kda | latent_attention.
     hybrid: Optional[tuple[tuple[str, Any], ...]] = None
 
     @property
@@ -223,6 +287,8 @@ class ModelConfig:
     @classmethod
     def from_hf_dict(cls, d: dict[str, Any]) -> "ModelConfig":
         model_type = d.get("model_type", "llama")
+        if model_type in _KDA_LATENT_TYPES:
+            return cls._from_hf_kda_latent(d)
         if model_type in _MLA_MOE_TYPES or "kv_lora_rank" in d:
             return cls._from_hf_mla_moe(d)
         if model_type in _SSM_MOE_TYPES:
@@ -237,7 +303,10 @@ class ModelConfig:
                 f"+ attention layers with routed experts: "
                 f"{sorted(_SSM_MOE_TYPES)}; linear-attention + "
                 f"block-sparse attention layers: "
-                f"{sorted(_LINEAR_SPARSE_TYPES)})")
+                f"{sorted(_LINEAR_SPARSE_TYPES)}; delta-rule linear "
+                f"attention + latent attention layers with grouped "
+                f"sigmoid experts (_from_hf_kda_latent): "
+                f"{sorted(_KDA_LATENT_TYPES)})")
         unknown = sorted(k for k in _FOREIGN_KEYS if d.get(k))
         if unknown:
             raise ValueError(
@@ -512,6 +581,152 @@ class ModelConfig:
             model_type=d["model_type"],
             hybrid=tuple(sorted(hybrid.items())),
         )
+
+    @classmethod
+    def _from_hf_kda_latent(cls, d: dict[str, Any]) -> "ModelConfig":
+        """Delta-rule linear attention (KDA) layers with a per-channel
+        gate and short convolutions, one latent (MLA) layer closing every
+        ``layer_group_size``, ``first_k_dense_replace`` dense layers and
+        then sigmoid-routed experts picked within groups (top
+        ``topk_group`` of ``n_group`` groups by the sum of their two best
+        scores + bias) with one shared expert. Every key the equations
+        read must be there with a value this program builds; anything
+        else is refused by name.
+
+        The experts held HERE are ``num_local_experts`` (default: all
+        ``num_experts``); a file that holds a share states the deployment
+        under ``expert_share`` as the state-space hybrid does
+        (``published_experts``, ``of``, ``index``), and ``of`` has to
+        divide ``n_group`` or be a multiple of it, so that a share is
+        whole groups or a whole part of one."""
+        missing = sorted(k for k in _KDA_LATENT_KEYS if k not in d)
+        if missing:
+            raise ValueError("delta-rule + latent attention block: keys "
+                             f"{missing} are missing from the config")
+        E = int(d["num_experts"])
+        held = int(d.get("num_local_experts", E))
+        share = d.get("expert_share") or {
+            "published_experts": held, "of": 1, "index": 0}
+        if set(share) != {"published_experts", "of", "index"}:
+            raise ValueError(
+                "delta-rule + latent attention block: expert_share needs "
+                f"exactly published_experts, of and index (it has "
+                f"{sorted(share)})")
+        of, index = int(share["of"]), int(share["index"])
+        L, period = int(d["num_hidden_layers"]), int(d["layer_group_size"])
+        heads, hd = int(d["num_attention_heads"]), int(d["head_dim"])
+        n_group, rope = int(d["n_group"]), int(d["qk_rope_head_dim"])
+        clamps = [d["expert_swiglu_limit_list"],
+                  d["share_expert_swiglu_limit_list"]]
+        refused = {
+            f"{k} {d[k]!r} (only {want!r})": d[k] != want
+            for k, want in _KDA_LATENT_KEYS.items() if want is not None}
+        refused.update({
+            "q_lora_rank (the latent layers' query has no low-rank "
+            "factor here)": d["q_lora_rank"] is not None,
+            "num_kv_heads_for_linear_attn other than 0 or "
+            "num_attention_heads": d["num_kv_heads_for_linear_attn"]
+                not in (0, heads),
+            "a non-zero entry of expert_swiglu_limit_list / "
+            "share_expert_swiglu_limit_list (the clamp's form is not "
+            "published: none is built)":
+                any(x != 0 for lst in clamps for x in lst),
+            "a clamp list whose length is not num_hidden_layers":
+                any(len(lst) != L for lst in clamps),
+            "layer_group_size < 2, or above num_hidden_layers (a stack "
+            "without a latent layer keeps no rows)": not 2 <= period <= L,
+            "short_conv_kernel_size < 2": d["short_conv_kernel_size"] < 2,
+            "kda_lower_bound not below 0": not d["kda_lower_bound"] < 0,
+            "rotary_dim != qk_rope_head_dim (the latent layers rotate "
+            "their rope part, the KDA layers nothing)":
+                d["rotary_dim"] != rope
+                or d["partial_rotary_factor"] * hd != d["rotary_dim"],
+            "qk_nope_head_dim / v_head_dim != head_dim":
+                (d["qk_nope_head_dim"], d["v_head_dim"]) != (hd, hd),
+            "rope_scaling": d.get("rope_scaling") is not None,
+            "rope_interleave false": not d.get("rope_interleave", True),
+            "tie_word_embeddings": bool(d.get("tie_word_embeddings")),
+            f"hidden_act {d.get('hidden_act')!r}":
+                d.get("hidden_act", "silu") != "silu",
+            "use_bias / use_qkv_bias":
+                bool(d.get("use_bias")) or bool(d.get("use_qkv_bias")),
+            "num_shared_experts other than 1":
+                d.get("num_shared_experts", 1) != 1,
+            f"topk_method {d.get('topk_method')!r}":
+                d.get("topk_method", "noaux_tc") != "noaux_tc",
+            "scoring_func other than score_function":
+                d.get("scoring_func", "sigmoid") != "sigmoid",
+            f"n_group {n_group} does not divide num_experts {E}, or "
+            "topk_group outside 1..n_group":
+                n_group < 1 or E % max(n_group, 1) != 0
+                or not 1 <= d["topk_group"] <= n_group,
+            "num_experts_per_tok above what topk_group groups hold":
+                d["num_experts_per_tok"]
+                > d["topk_group"] * (E // max(n_group, 1)),
+            "a group of fewer than 2 experts (its score is the sum of "
+            "its two best)": E // max(n_group, 1) < 2,
+            f"expert_share: {held} held x {of} chips is not the "
+            f"published {E} experts, or published_experts is not "
+            "num_experts": of < 1 or held * of != E
+                or int(share["published_experts"]) != E,
+            f"expert_share: of {of} neither divides n_group {n_group} "
+            "nor is a multiple of it":
+                of >= 1 and n_group % of != 0 and of % n_group != 0,
+            f"expert_share index {index} outside 0..{of - 1}":
+                not 0 <= index < max(of, 1),
+            "first_k_dense_replace above num_hidden_layers":
+                not 0 <= d["first_k_dense_replace"] <= L,
+        })
+        bad = sorted(k for k, v in refused.items() if v)
+        if bad:
+            raise ValueError(
+                f"delta-rule + latent attention block: {bad} are values "
+                "this program does not build")
+        kinds = tuple("latent_attention" if (l + 1) % period == 0 else "kda"
+                      for l in range(L))
+        hybrid = dict(
+            layer_types=kinds, kda_heads=heads, kda_head_dim=hd,
+            kda_conv=int(d["short_conv_kernel_size"]),
+            kda_lower_bound=float(d["kda_lower_bound"]),
+            n_dense=int(d["first_k_dense_replace"]),
+            router="sigmoid_groups", n_group=n_group,
+            topk_group=int(d["topk_group"]),
+            routed_scaling_factor=float(d["routed_scaling_factor"]),
+            num_local_experts=held,
+            num_experts_per_tok=int(d["num_experts_per_tok"]),
+            intermediate_size=int(d["moe_intermediate_size"]),
+            shared_intermediate_size=int(
+                d["moe_shared_expert_intermediate_size"]),
+            published_experts=E, share_of=of, share_index=index,
+            embedding_multiplier=1.0, residual_multiplier=1.0,
+            logits_scaling=1.0)
+        mla = {k: d[k] for k in _MLA_KEYS}
+        return cls(
+            vocab_size=d["vocab_size"],
+            hidden_size=d["hidden_size"],
+            intermediate_size=d["intermediate_size"],
+            num_layers=L,
+            num_heads=heads,
+            # the latent layers' cached row, as the latent block states it
+            num_kv_heads=1,
+            head_dim=d["kv_lora_rank"] + rope,
+            rope_theta=float(d["rope_theta"]),
+            rms_norm_eps=d["rms_norm_eps"],
+            max_position_embeddings=d.get("max_position_embeddings", 8192),
+            tie_word_embeddings=False,
+            model_type=d.get("model_type", "ling3_flash"),
+            mla=tuple(sorted(mla.items())),
+            hybrid=tuple(sorted(hybrid.items())),
+        )
+
+    @classmethod
+    def tiny_kda_latent(cls, **kw) -> "ModelConfig":
+        """Toy delta-rule + latent stack for CPU tests: one dense layer,
+        two periods of (kda, kda, latent), 16 experts in 4 groups of which
+        2 are kept, top 4, share 0 of 4 holds one group."""
+        d = dict(_TINY_KDA_LATENT)
+        d.update(kw)
+        return cls.from_hf_dict(d)
 
     @classmethod
     def tiny_linear_sparse(cls, **kw) -> "ModelConfig":

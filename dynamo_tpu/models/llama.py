@@ -91,10 +91,10 @@ def block_of(config: ModelConfig):
     """The module that builds ``config``'s block where it is not the
     dense decoder of this file (None): every name below hands over to
     it."""
+    if config.hybrid is not None:   # which may borrow the latent block's
+        return ssm_moe              # attention (``mla`` set beside it)
     if config.mla is not None:
         return mla_moe
-    if config.hybrid is not None:
-        return ssm_moe
     return None
 
 

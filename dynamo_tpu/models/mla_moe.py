@@ -49,6 +49,13 @@ What differs from models/llama.py, and how the engine meets it:
 Every function here is reached through the ``llama`` names the engine and
 the benchmark's launcher call (``llama.init_params``, ``init_ctx``,
 ``prefill``, ``batch_prefill`` ...), which dispatch on ``config.mla``.
+
+The hybrid stack (models/ssm_moe.py) runs this block's ATTENTION as its
+``latent_attention`` layer kind, beside recurrent layers: it imports
+``_attn_in`` (whose ``q_lora_rank`` None arm is its model's), ``_wkvb``,
+``_expand_kv``, ``_absorb_q``, ``_unabsorb_o``, ``_expand_prior`` and
+``ROW``, with a config that sets ``mla`` beside ``hybrid`` and no
+``routed`` (``dims`` then gives the attention's sizes only).
 """
 from __future__ import annotations
 
@@ -79,8 +86,11 @@ ROW = "kv"   # the one row kind of this block's cache
 
 
 def dims(c: ModelConfig) -> dict[str, int]:
+    """The attention's sizes, and where the config routes experts as this
+    block does (``c.routed``; the hybrid stack, which borrows the
+    attention alone, has its own) the expert layers'."""
     m, r = c.mla_dict, c.routed_dict
-    return {
+    d = {
         "nh": c.num_heads, "q_rank": m["q_lora_rank"],
         "kv_rank": m["kv_lora_rank"], "nope": m["qk_nope_head_dim"],
         "rope": m["qk_rope_head_dim"], "v": m["v_head_dim"],
@@ -92,13 +102,17 @@ def dims(c: ModelConfig) -> dict[str, int]:
         # every row-wise read and write into a relayout of the region
         "stored": -(-(m["kv_lora_rank"] + m["qk_rope_head_dim"]) // 128)
         * 128,
-        "E": r["n_routed_experts"], "K": r["num_experts_per_tok"],
-        "I_e": r["moe_intermediate_size"],
-        "I_s": r["moe_intermediate_size"] * r["n_shared_experts"],
-        "n_dense": min(r["first_k_dense_replace"], c.num_layers),
         # residual streams (1: the plain residual)
         "n": c.hc_dict["hc_mult"] if c.hc else 1,
     }
+    if r is not None:
+        d.update({
+            "E": r["n_routed_experts"], "K": r["num_experts_per_tok"],
+            "I_e": r["moe_intermediate_size"],
+            "I_s": r["moe_intermediate_size"] * r["n_shared_experts"],
+            "n_dense": min(r["first_k_dense_replace"], c.num_layers),
+        })
+    return d
 
 
 def kv_row_bytes(c: ModelConfig, itemsize: int) -> int:
@@ -281,14 +295,19 @@ def _rope_pairs(x, positions, inv_freq, times: float = 1.0):
 def _attn_in(c: ModelConfig, lp, h, positions):
     """Norm, the two low-rank projections, RoPE. ``h`` [N, H] ->
     q_nope [N, nh, nope], q_rope [N, nh, rope], and the row to cache
-    [N, stored] = [c_kv | k_rope | 0...]."""
+    [N, stored] = [c_kv | k_rope | 0...]. A config whose ``q_lora_rank``
+    is None projects the query in one step (``wq``), with no query
+    norm."""
     d = dims(c)
     N = h.shape[0]
     inv_freq, times, _ = _rotary(c)
     inv_freq = jnp.asarray(inv_freq)
     x = _rms(h, lp["ln1"], c.rms_norm_eps)
-    cq = _rms(x @ lp["wqa"], lp["q_norm"], c.rms_norm_eps)
-    q = (cq @ lp["wqb"]).reshape(N, d["nh"], d["nope"] + d["rope"])
+    if d["q_rank"] is None:
+        q = x @ lp["wq"]
+    else:
+        q = _rms(x @ lp["wqa"], lp["q_norm"], c.rms_norm_eps) @ lp["wqb"]
+    q = q.reshape(N, d["nh"], d["nope"] + d["rope"])
     q_nope, q_rope = q[..., :d["nope"]], q[..., d["nope"]:]
     kv = x @ lp["wkva"]
     c_kv = _rms(kv[:, :d["kv_rank"]], lp["kv_norm"], c.rms_norm_eps)

@@ -62,9 +62,37 @@ kind, what a region holds from its leaves:
     rows from the region; a lane below ``dense_len`` takes the dense
     read. Prefill scores the whole causal context under the selection's
     mask.
+  - ``kda`` (ops/kda.py): delta-rule linear attention with a gate per
+    CHANNEL. q, k, v each pass a short causal depthwise convolution (one
+    ``kda_conv_state`` leaf [lanes + 1, W - 1, 3 x heads x D] a layer: the
+    three streams' windows side by side) and SiLU; q and k are
+    L2-normalised over D, q scaled by D^-1/2; the gate ``g = bound x
+    sigmoid(exp(A_log) (x W_f + dt_bias))`` in (bound, 0) a channel and the
+    step ``b = sigmoid(x W_b)`` a head, both float32; ``S' = Diag(e^g) S``,
+    ``S = S' + b k (v - S'^T k)^T``, ``o = S^T q`` on a ``kda_state`` leaf
+    [lanes + 1, heads, D, D] float32 a layer (a lane that is not live: g
+    0, b 0, k 0); an RMSNorm over D a head, a sigmoid gate a HEAD from the
+    layer's input, W_o. No rotary. On TPU devices the decode step is one
+    Pallas kernel a layer that rewrites the state in place.
+  - ``latent_attention``: models/mla_moe.py's attention (imported, not
+    copied) on ONE ``kv`` row leaf [L_latent, 1, lanes, S, stored] in
+    place of K and V: prefill expands K and V per head (a continuing
+    chunk's prior rows through ``_expand_prior``), decode absorbs
+    (ops/latent_decode.py) at the latent layers' ordinal. Latent rows and
+    a recurrent state share one region: the movers carry the row kinds a
+    region holds, the round's carry the ``*_state`` leaves.
   - the feed-forward part is experts + a shared MLP where the config has
-    experts, else one dense SwiGLU; the head is the embedding where the
+    experts (after ``n_dense`` leading layers of one dense SwiGLU, if it
+    says so), else one dense SwiGLU; the head is the embedding where the
     config ties them, else a matrix of its own.
+  - A SECOND ROUTER (``router: sigmoid_groups``): float32 ``s = sigmoid(x
+    W_r)`` over the published experts, ``c = s + bias``; the experts lie
+    in ``n_group`` contiguous groups whose score is the sum of their two
+    best ``c``; the ``topk_group`` best groups stay, the top k of ``c``
+    among them are picked, weighted by ``s`` (no bias) over their sum x
+    ``routed_scaling_factor``. The share is told to the grouped product
+    as for the softmax router; a fifth counter says how many tokens kept
+    the group(s) held here.
 
 Every function here is reached through the ``llama`` names
 (``llama.block_of``), as models/mla_moe.py is.
@@ -79,15 +107,19 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dynamo_tpu.models.config import ModelConfig
+from dynamo_tpu.models import mla_moe
 from dynamo_tpu.models.mla_moe import _mlp, _rms   # the same norm and SwiGLU
 from dynamo_tpu.models.moe import grouped_experts, move_block, rows_moved
-from dynamo_tpu.ops import lightning, mamba2, sparse_attention
+from dynamo_tpu.ops import kda, lightning, mamba2, sparse_attention
 from dynamo_tpu.ops.attention import (
+    PALLAS_INTERPRET,
+    REFERENCE_IMPL,
     DecodeAttention,
     PriorContext,
     ctx_decode_attention,
     prefill_attention,
 )
+from dynamo_tpu.ops.latent_decode import latent_decode_attention
 from dynamo_tpu.ops.rope import apply_rope, rope_cos_sin, rope_inv_freq
 
 Params = dict[str, Any]
@@ -96,6 +128,9 @@ Cache = dict[str, Any]
 SSM, CONV = "ssm_state", "conv_state"   # the recurrent leaves of a ctx
 LIN = "lin_state"     # a linear-attention layer's matrix state
 KC = "kc"             # the sparse layers' compressed-key rows
+KDA, KDA_CONV = "kda_state", "kda_conv_state"   # a delta-rule layer's
+                      # matrix state and its three convolution windows
+KV = mla_moe.ROW      # the latent layers' one row kind
 ROW_LAYERS = ("attention", "sparse_attention")   # kinds that keep K/V rows
 
 
@@ -108,7 +143,11 @@ def dims(c: ModelConfig) -> dict[str, Any]:
         "n_lin": sum(t == "linear_attention" for t in kinds),
         "n_attn": sum(t in ROW_LAYERS for t in kinds),
         "n_sparse": sum(t == "sparse_attention" for t in kinds),
+        "n_kda": sum(t == "kda" for t in kinds),
+        "n_latent": sum(t == "latent_attention" for t in kinds),
         "experts": "num_local_experts" in k,
+        # leading layers whose feed-forward part is one dense MLP
+        "n_dense": k.get("n_dense", 0),
     }
     if "mamba_n_heads" in k:
         inner = k["mamba_n_heads"] * k["mamba_d_head"]
@@ -128,6 +167,13 @@ def dims(c: ModelConfig) -> dict[str, Any]:
             "first": (k["share_index"] * k["num_local_experts"]
                       if k["share_of"] > 1 else None),
         })
+    if d["experts"] and k.get("router") == "sigmoid_groups":
+        d.update({"groups": k["n_group"], "kept": k["topk_group"],
+                  "scale": k["routed_scaling_factor"]})
+    if d["n_kda"]:
+        d.update({"kda_heads": k["kda_heads"], "kda_dim": k["kda_head_dim"],
+                  "kda_inner": k["kda_heads"] * k["kda_head_dim"],
+                  "kda_W": k["kda_conv"], "kda_bound": k["kda_lower_bound"]})
     if "lightning_heads" in k:
         d.update({
             "lin_heads": k["lightning_heads"],
@@ -160,6 +206,8 @@ def kv_row_bytes(c: ModelConfig, itemsize: int) -> float:
     ones."""
     d = dims(c)
     rows = d["n_attn"] * 2 * c.kv_dim * itemsize
+    if d["n_latent"]:
+        rows += d["n_latent"] * mla_moe.dims(c)["stored"] * itemsize
     if d["n_sparse"]:
         rows += d["n_sparse"] * c.kv_dim * itemsize / d["sparse"].stride
     return rows
@@ -174,6 +222,10 @@ def state_bytes(c: ModelConfig, itemsize: int) -> int:
                                + (d["W"] - 1) * d["conv"] * itemsize)
     if d["n_lin"]:
         total += d["n_lin"] * d["lin_heads"] * d["lin_dim"] ** 2 * 4
+    if d["n_kda"]:
+        total += d["n_kda"] * (
+            d["kda_heads"] * d["kda_dim"] ** 2 * 4
+            + (d["kda_W"] - 1) * 3 * d["kda_inner"] * itemsize)
     return total
 
 
@@ -185,7 +237,11 @@ def init_params(config: ModelConfig, rng: jax.Array | int = 0) -> Params:
     initialisation of the recurrence: ``A`` uniform in 1..16, ``dt``
     log-uniform in 0.001..0.1 (``dt_bias`` its inverse softplus), ``D``
     1. Heads then remember over tens to thousands of positions, so a
-    state dropped at a chunk boundary changes the logits."""
+    state dropped at a chunk boundary changes the logits. A delta-rule
+    layer's gate is drawn to the same end: ``dt_bias`` uniform in -8..-4.5
+    a channel under a gate matrix of half the usual scale, so that a
+    channel's decay ``a`` lies in ~0.91..0.998, and ``W_b`` at the usual
+    scale, so that the step ``b`` spans ~0.1..0.9."""
     if isinstance(rng, int):
         rng = jax.random.PRNGKey(rng)
     c, d = config, dims(config)
@@ -201,9 +257,18 @@ def init_params(config: ModelConfig, rng: jax.Array | int = 0) -> Params:
 
     H = c.hidden_size
 
-    def layer(kind):
+    u = lambda lo, hi, *shape: jax.random.uniform(  # noqa: E731
+        next(keys), shape, jnp.float32, lo, hi)
+
+    def layer(i, kind):
         lp = {"ln1": jnp.ones((H,), dtype), "ln2": jnp.ones((H,), dtype)}
-        if d["experts"]:
+        if d["experts"] and i >= d["n_dense"]:
+            if "groups" in d:
+                # in SCORE units, as the latent block draws it (the 8th
+                # and 9th best of hundreds of sigmoid scores lie ~0.005
+                # apart)
+                lp["bias"] = 0.01 * jax.random.normal(
+                    next(keys), (d["E"],), jnp.float32)
             lp.update(
                 wr=rnd(H, d["E"]),
                 # the published fused input matrix [H, 2 I] as its two halves
@@ -229,6 +294,30 @@ def init_params(config: ModelConfig, rng: jax.Array | int = 0) -> Params:
                       wv=rnd(H, c.kv_dim), wo=rnd(c.q_dim, H),
                       wz=rnd(H, c.q_dim), q_norm=gain, k_norm=gain)
             return lp
+        if kind == "kda":
+            inner, nh = d["kda_inner"], d["kda_heads"]
+            lp.update(
+                # q | k | v, and their three convolutions, side by side
+                w_qkv=rnd(H, 3 * inner),
+                conv_w=rnd(d["kda_W"], 3 * inner,
+                           scale=1.0 / np.sqrt(d["kda_W"])),
+                w_f=rnd(H, inner, scale=0.5 / np.sqrt(H)),
+                A_log=jnp.log(u(0.8, 1.25, nh)),
+                dt_bias=u(-8.0, -4.5, inner),
+                # the step b | the output gate, a scalar a head each
+                w_bg=rnd(H, 2 * nh),
+                o_norm=jnp.ones((d["kda_dim"],), dtype),
+                wo=rnd(inner, H))
+            return lp
+        if kind == "latent_attention":
+            m = mla_moe.dims(c)
+            lp.update(
+                wq=rnd(H, m["nh"] * (m["nope"] + m["rope"])),
+                wkva=rnd(H, m["row"]),
+                kv_norm=jnp.ones((m["kv_rank"],), dtype),
+                wkvb=rnd(m["kv_rank"], m["nh"] * (m["nope"] + m["v"])),
+                wo=rnd(m["nh"] * m["v"], H))
+            return lp
         if kind == "linear_attention":
             inner = d["lin_heads"] * d["lin_dim"]
             one = jnp.ones((d["lin_dim"],), dtype)
@@ -236,14 +325,12 @@ def init_params(config: ModelConfig, rng: jax.Array | int = 0) -> Params:
                       wo=rnd(inner, H), wz=rnd(H, inner), q_norm=one,
                       k_norm=one, o_norm=jnp.ones((inner,), dtype))
             return lp
-        u = lambda lo, hi: jax.random.uniform(  # noqa: E731
-            next(keys), (d["nh"],), jnp.float32, lo, hi)
-        dt = jnp.exp(u(np.log(1e-3), np.log(1e-1)))
+        dt = jnp.exp(u(np.log(1e-3), np.log(1e-1), d["nh"]))
         lp.update(
             w_in=rnd(H, 2 * d["inner"] + 2 * d["N"] + d["nh"]),
             conv_w=rnd(d["W"], d["conv"], scale=1.0 / np.sqrt(d["W"])),
             conv_b=rnd(d["conv"], scale=0.1),
-            A_log=jnp.log(u(1.0, 16.0)),
+            A_log=jnp.log(u(1.0, 16.0, d["nh"])),
             dt_bias=dt + jnp.log(-jnp.expm1(-dt)),
             D=jnp.ones((d["nh"],), jnp.float32),
             norm=jnp.ones((d["inner"],), dtype),
@@ -256,7 +343,7 @@ def init_params(config: ModelConfig, rng: jax.Array | int = 0) -> Params:
         "norm_f": jnp.ones((H,), dtype),
         # one entry a layer, NOT stacked: the kinds differ, and a
         # kernel's operand sliced out of a stack is a copy of it
-        "layers": [layer(kind) for kind in d["kinds"]],
+        "layers": [layer(i, kind) for i, kind in enumerate(d["kinds"])],
     }
     if not c.tie_word_embeddings:
         params["head"] = rnd(H, c.vocab_size)
@@ -284,6 +371,10 @@ def param_shardings(config: ModelConfig, mesh: Mesh) -> Params:
 def _rows(c: ModelConfig, lanes: int, length: int, dtype,
           compressed: bool = True) -> Cache:
     d = dims(c)
+    if d["n_latent"]:
+        # the latent layers' one row kind, in place of K and V
+        return {KV: jnp.zeros((d["n_latent"], 1, lanes, length,
+                               mla_moe.dims(c)["stored"]), dtype)}
     shape = (d["n_attn"], c.num_kv_heads, lanes, length, c.head_dim)
     rows = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
     if d["n_sparse"] and compressed:
@@ -324,6 +415,14 @@ def init_ctx(config, batch, ctx_len, dtype=None, kv_quant="none",
             jnp.zeros((batch + 1, d["lin_heads"], d["lin_dim"],
                        d["lin_dim"]), jnp.float32)
             for _ in range(d["n_lin"])]
+    if d["n_kda"]:
+        ctx[KDA] = [
+            jnp.zeros((batch + 1, d["kda_heads"], d["kda_dim"],
+                       d["kda_dim"]), jnp.float32)
+            for _ in range(d["n_kda"])]
+        ctx[KDA_CONV] = [
+            jnp.zeros((batch + 1, d["kda_W"] - 1, 3 * d["kda_inner"]), dtype)
+            for _ in range(d["n_kda"])]
     return ctx
 
 
@@ -338,6 +437,8 @@ def row_shardings(config: ModelConfig, mesh: Mesh,
                   kv_quant: str = "none") -> Cache:
     _refuse_quant(kv_quant)
     s = NamedSharding(mesh, P(None, None, None, None, None))
+    if dims(config)["n_latent"]:
+        return {KV: s}
     out = {"k": s, "v": s}
     if dims(config)["n_sparse"]:
         out[KC] = s
@@ -346,7 +447,7 @@ def row_shardings(config: ModelConfig, mesh: Mesh,
 
 def ring_shardings(config: ModelConfig, mesh: Mesh) -> Cache:
     rows = row_shardings(config, mesh)
-    return {n: rows[n] for n in ("k", "v")}   # init_ring's kinds
+    return {n: s for n, s in rows.items() if n != KC}   # init_ring's kinds
 
 
 def stepped_kinds(state: Cache) -> tuple[str, ...]:
@@ -367,6 +468,9 @@ def ctx_shardings(config: ModelConfig, mesh: Mesh,
         out[CONV] = [NamedSharding(mesh, P(None, None, None))] * d["n_ssm"]
     if d["n_lin"]:
         out[LIN] = [NamedSharding(mesh, P(None, None, None, None))] * d["n_lin"]
+    if d["n_kda"]:
+        out[KDA] = [NamedSharding(mesh, P(None, None, None, None))] * d["n_kda"]
+        out[KDA_CONV] = [NamedSharding(mesh, P(None, None, None))] * d["n_kda"]
     return out
 
 
@@ -374,24 +478,56 @@ def ctx_shardings(config: ModelConfig, mesh: Mesh,
 # Forward pieces
 
 def route(c: ModelConfig, lp, x):
-    """The published router: float32 logits over ALL the deployment's
-    experts, the top k logits picked, softmax over the picked."""
+    """The published router, float32 over ALL the deployment's experts:
+    (picks [N, K], combine weights [N, K], and for the grouped router
+    whether each token kept a group held here [N] bool, else None).
+
+    Softmax form: the top k logits picked, softmax over the picked.
+    ``sigmoid_groups``: scores ``s = sigmoid(logits)``, ``c = s + bias``;
+    a group's score is the sum of its two best ``c``; the ``kept`` best
+    groups stay and the rest are masked out; the top k of ``c`` among what
+    stays; weights ``s`` at the picks over their sum, x ``scale``."""
+    d = dims(c)
     with jax.named_scope("moe_route"):
         logits = jnp.matmul(x, lp["wr"], preferred_element_type=jnp.float32)
-        top, sel = jax.lax.top_k(logits, dims(c)["K"])
-        return sel, jax.nn.softmax(top, axis=-1)
+        if "groups" not in d:
+            top, sel = jax.lax.top_k(logits, d["K"])
+            return sel, jax.nn.softmax(top, axis=-1), None
+        s = jax.nn.sigmoid(logits)
+        biased = s + lp["bias"]
+        N, G = s.shape[0], d["groups"]
+        size = d["E"] // G
+        # a group's two best as two maxima (top_k of 2 lowers to a sort of
+        # every group on the TPU: 2.6 % of a decode step's device time)
+        per = biased.reshape(N, G, size)
+        at = jnp.argmax(per, axis=-1, keepdims=True)
+        second = jnp.max(jnp.where(
+            jnp.arange(size) == at, -jnp.inf, per), axis=-1)
+        _, keep = jax.lax.top_k(per.max(-1) + second, d["kept"])   # [N, kept]
+        kept = jnp.zeros((N, G), bool).at[
+            jnp.arange(N)[:, None], keep].set(True)
+        masked = jnp.where(jnp.repeat(kept, size, axis=1), biased, -jnp.inf)
+        _, sel = jax.lax.top_k(masked, d["K"])
+        w = jnp.take_along_axis(s, sel, axis=-1)
+        w = w / (w.sum(-1, keepdims=True) + 1e-20) * d["scale"]
+        # the groups that hold this chip's experts (one, a part of one,
+        # or several: config.py's rule on the share)
+        first = d["first"] or 0
+        here = kept[:, first // size:(first + d["held"] - 1) // size + 1]
+        return sel, w, here.any(axis=1)
 
 
 def stats_zero(c: ModelConfig):
     """A step's counters before any layer: [held experts touched, picks
     that landed on a held expert, most tokens on one held expert, all
-    picks of routed tokens]."""
-    return jnp.zeros(4, jnp.int32)
+    picks of routed tokens], and under the grouped router a fifth: routed
+    tokens that kept a group held here."""
+    return jnp.zeros(5 if "groups" in dims(c) else 4, jnp.int32)
 
 
 def merge_stats(a, b):
-    return jnp.stack([a[0] + b[0], a[1] + b[1], jnp.maximum(a[2], b[2]),
-                      a[3] + b[3]])
+    return jnp.stack([a[0] + b[0], a[1] + b[1], jnp.maximum(a[2], b[2])]
+                     + [a[i] + b[i] for i in range(3, a.shape[0])])
 
 
 def _move_block(d, n_tokens: int) -> int:
@@ -407,28 +543,31 @@ def prefill_rows_sorted(c: ModelConfig, n_tokens: int) -> int:
     ``n_tokens`` positions sort, where they move rows in the looped form;
     0 where they do not, or there are none."""
     d = dims(c)
-    return n_tokens * d["K"] * c.num_layers if _move_block(d, n_tokens) else 0
+    layers = c.num_layers - d["n_dense"]
+    return n_tokens * d["K"] * layers if _move_block(d, n_tokens) else 0
 
 
 def _ffn(c: ModelConfig, lp, x, valid, stats):
     """Routed experts (this chip's share) + the shared MLP, ungated."""
     d = dims(c)
-    sel, w = route(c, lp, x)
+    sel, w, here = route(c, lp, x)
     y, load = grouped_experts(x, sel, w, lp["we_g"], lp["we_u"], lp["we_d"],
                               valid, first=d["first"])
     with jax.named_scope("moe_shared"):
         y = y + _mlp(x, lp["ws_g"], lp["ws_u"], lp["ws_d"])
     picks = (x.shape[0] if valid is None else valid.sum()) * d["K"]
-    return y, merge_stats(stats, jnp.stack([
-        jnp.sum(load > 0), load.sum(), load.max(),
-        jnp.asarray(picks, jnp.int32)]).astype(jnp.int32))
+    seen = [jnp.sum(load > 0), load.sum(), load.max(),
+            jnp.asarray(picks, jnp.int32)]
+    if here is not None:
+        seen.append(jnp.sum(here if valid is None else here & valid))
+    return y, merge_stats(stats, jnp.stack(seen).astype(jnp.int32))
 
 
 def _layer_out(c: ModelConfig, lp, h, mix, valid, stats):
     r = jnp.asarray(c.hybrid_dict["residual_multiplier"], h.dtype)
     h = h + r * mix
     x = _rms(h, lp["ln2"], c.rms_norm_eps)
-    if dims(c)["experts"]:
+    if "wr" in lp:   # the layer routes: a leading dense layer does not
         y, stats = _ffn(c, lp, x, valid, stats)
     else:
         with jax.named_scope("mlp"):
@@ -520,6 +659,60 @@ def _lin_out(c: ModelConfig, lp, o, z):
         o = o * jax.lax.rsqrt(var + c.rms_norm_eps) * lp["o_norm"].astype(
             jnp.float32)
         return _gated(o, z) @ lp["wo"]
+
+
+def _l2(x):
+    """``x`` / its L2 norm over the last axis, in float32."""
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+
+def _kda_in(c: ModelConfig, lp, x):
+    """[N, H] -> the three convolutions' input [N, 3 x inner] (q | k |
+    v), the log of the per-channel decay g [N, heads, D] float32 in
+    (bound, 0), the step b [N, heads] float32 in (0, 1), and the output
+    gate's input z [N, heads] float32."""
+    d = dims(c)
+    N, nh = x.shape[0], d["kda_heads"]
+    with jax.named_scope("kda_proj"):
+        qkv = x @ lp["w_qkv"]
+    with jax.named_scope("kda_gate"):
+        f = jnp.matmul(x, lp["w_f"], preferred_element_type=jnp.float32)
+        f = (f + lp["dt_bias"]).reshape(N, nh, -1)
+        g = d["kda_bound"] * jax.nn.sigmoid(
+            jnp.exp(lp["A_log"])[:, None] * f)
+        bz = jnp.matmul(x, lp["w_bg"], preferred_element_type=jnp.float32)
+    return qkv, g, jax.nn.sigmoid(bz[:, :nh]), bz[:, nh:]
+
+
+def _kda_qkv(c: ModelConfig, qkv):
+    """The convolved streams [..., 3 x inner] -> q (L2-normalised, x
+    D^-1/2), k (L2-normalised) float32 and v, each [..., heads, D]."""
+    d = dims(c)
+    q, k, v = (a.reshape(*a.shape[:-1], d["kda_heads"], d["kda_dim"])
+               for a in jnp.split(qkv, 3, axis=-1))
+    return _l2(q) / np.sqrt(d["kda_dim"]), _l2(k), v
+
+
+def _kda_out(c: ModelConfig, lp, o, z, dtype):
+    """``o`` [N, heads, D] float32 from the recurrence -> the mixer's
+    output: an RMSNorm over D a head, the gate a head, W_o."""
+    with jax.named_scope("kda_out"):
+        var = jnp.mean(o * o, axis=-1, keepdims=True)
+        o = (o * jax.lax.rsqrt(var + c.rms_norm_eps)
+             * lp["o_norm"].astype(jnp.float32)
+             * jax.nn.sigmoid(z)[..., None])
+        return o.reshape(o.shape[0], -1).astype(dtype) @ lp["wo"]
+
+
+def _no_bias(lp):
+    return jnp.zeros((lp["conv_w"].shape[1],), jnp.float32)
+
+
+def _rows_of(ctx_kv):
+    """A region's (or a ring's) K rows, or its latent rows where it keeps
+    those instead: what gives its dtype and its length."""
+    return ctx_kv["k"] if "k" in ctx_kv else ctx_kv[KV]
 
 
 def _sparse_in(c: ModelConfig, lp, x):
@@ -656,18 +849,27 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
     c, d = config, dims(config)
     _refuse_adapters(params)
     K, T = tokens.shape
-    cdt = ctx_kv["k"].dtype
+    cdt = _rows_of(ctx_kv).dtype
     positions = q_starts[:, None] + jnp.arange(T, dtype=jnp.int32)
     real = positions < seq_lens[:, None]                      # [K, T]
     valid = real.reshape(K * T)
     n_real = jnp.clip(seq_lens - q_starts, 0, T)
-    span = min(ctx_span, ctx_kv["k"].shape[3])
+    span = min(ctx_span, _rows_of(ctx_kv).shape[3])
     continuing = q_starts > 0
+    if span and d["n_latent"]:
+        # continuing chunks' prior latent rows, expanded per head into a
+        # workspace every latent layer rewrites (mla_moe._expand_prior)
+        m = mla_moe.dims(c)
+        below = jnp.minimum(jnp.minimum(q_starts, seq_lens), span)
+        work = tuple(jnp.zeros((1, m["nh"], K, span, w), cdt)
+                     for w in (m["nope"] + m["rope"], m["v"]))
     h = _embed(c, params, tokens.reshape(K * T), cdt)
     stats = stats_zero(c)
     R = _move_block(d, K * T)
     moved = jnp.int32(0)
     ks, vs, kcs, ssm_out, conv_out, lin_out = [], [], [], [], [], []
+    lat, kda_out, kda_conv_out = [], [], []
+    lanes = lambda a: a.reshape(K, T, *a.shape[1:])  # noqa: E731
     A = lambda lp: -jnp.exp(lp["A_log"])  # noqa: E731
     for kind, lp in zip(d["kinds"], params["layers"]):
         x = _rms(h, lp["ln1"], c.rms_norm_eps)
@@ -706,6 +908,45 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
                   real, S0)
             lin_out.append(S)
             mix = _lin_out(c, lp, o.reshape(K * T, *o.shape[2:]), z)
+        elif kind == "kda":
+            j = len(kda_out)
+            qkv, g, b, z = _kda_in(c, lp, x)
+            if span:
+                keep = continuing[:, None, None]
+                win0 = jnp.where(keep, ctx_kv[KDA_CONV][j][slots], 0)
+                S0 = jnp.where(keep[..., None], ctx_kv[KDA][j][slots], 0.0)
+            else:
+                win0 = jnp.zeros((K, d["kda_W"] - 1, 3 * d["kda_inner"]), cdt)
+                S0 = jnp.zeros((K, d["kda_heads"], d["kda_dim"],
+                                d["kda_dim"]), jnp.float32)
+            with jax.named_scope("kda_conv"):
+                qkv, win = jax.vmap(
+                    lambda a, w0, n: mamba2.causal_conv(
+                        a, w0, lp["conv_w"], _no_bias(lp), n)
+                )(qkv.reshape(K, T, -1), win0, n_real)
+            with jax.named_scope("kda_scan"):
+                o, S = jax.vmap(kda.chunk_scan)(
+                    *_kda_qkv(c, qkv), lanes(g), lanes(b), real, S0)
+            kda_out.append(S)
+            kda_conv_out.append(win)
+            mix = _kda_out(c, lp, o.reshape(K * T, *o.shape[2:]), z, cdt)
+        elif kind == "latent_attention":
+            with jax.named_scope("mla_attn"):
+                q_nope, q_rope, row = mla_moe._attn_in(
+                    c, lp, h, positions.reshape(K * T))
+                k, v = mla_moe._expand_kv(c, lp, row)
+                prior = None
+                if span:
+                    work = mla_moe._expand_prior(
+                        c, work, ctx_kv[KV], lp["wkvb"], jnp.int32(len(lat)),
+                        slots, below)
+                    prior = PriorContext(*work, jnp.int32(0),
+                                         jnp.arange(K, dtype=jnp.int32))
+                lat.append(row.reshape(K, T, 1, -1))
+                o = prefill_attention(
+                    lanes(jnp.concatenate([q_nope, q_rope], -1)), lanes(k),
+                    lanes(v), q_starts, seq_lens, prior, ctx_span=span)
+                mix = o.reshape(K * T, -1) @ lp["wo"]
         else:
             j = len(ssm_out)
             z, xbc, dt = _ssm_in(c, lp, x)
@@ -740,10 +981,14 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
 
     # tail: every read is done. Rows as spans, compressed keys and states
     # as whole lanes
-    rows = {"k": jnp.stack(ks, 1).astype(cdt),      # [K, L_attn, T, kvh, hd]
-            "v": jnp.stack(vs, 1).astype(cdt)}
+    if lat:
+        rows = {KV: jnp.stack(lat, 1).astype(cdt)}  # [K, L_latent, T, 1, row]
+    else:
+        rows = {"k": jnp.stack(ks, 1).astype(cdt),  # [K, L_attn, T, kvh, hd]
+                "v": jnp.stack(vs, 1).astype(cdt)}
     states = [(name, new) for name, new in (
-        (SSM, ssm_out), (CONV, conv_out), (LIN, lin_out)) if new]
+        (SSM, ssm_out), (CONV, conv_out), (LIN, lin_out), (KDA, kda_out),
+        (KDA_CONV, kda_conv_out)) if new]
 
     def write_lane(i, out):
         out = dict(out)
@@ -783,7 +1028,7 @@ def prefill_impl(config, params, ctx_kv, tokens, slot, q_start, seq_len,
     one = lambda x: jnp.asarray(x, jnp.int32)[None]  # noqa: E731
     ctx_kv, logits, moved = batch_prefill_impl(
         config, params, ctx_kv, tokens[None], one(slot), one(q_start),
-        one(seq_len), 0 if fresh else ctx_kv["k"].shape[3])
+        one(seq_len), 0 if fresh else _rows_of(ctx_kv).shape[3])
     return ctx_kv, logits[0], moved
 
 
@@ -796,13 +1041,16 @@ def decode_step_impl(config, params, ctx_kv, ring, state, tokens, ctx_lens,
     stats). The attention layers' new rows land in ring slot
     ``ring_pos`` and the region is read-only, as in the dense decoder;
     ``state`` (the region's leaves a step writes, ``stepped_kinds``:
-    ``{SSM: [...], CONV: [...]}`` or ``{LIN: [...], KC: rows}``, lanes + 1
-    wide) comes back moved on by one position for the lanes that are
-    ``live`` and bit for bit as it was for the others."""
+    ``{SSM: [...], CONV: [...]}``, ``{LIN: [...], KC: rows}`` or ``{KDA:
+    [...], KDA_CONV: [...]}``, lanes + 1 wide) comes back moved on by one
+    position for the lanes that are ``live`` and as it was for the others.
+    ``attn`` also says which delta-rule step runs: the Pallas kernel where
+    the decode attention is one, the XLA form beside the reference."""
     c, d = config, dims(config)
     _refuse_adapters(params)
     B = tokens.shape[0]
-    h = _embed(c, params, tokens, ctx_kv["k"].dtype)
+    cdt = _rows_of(ctx_kv).dtype
+    h = _embed(c, params, tokens, cdt)
     stats = stats_zero(c)
     ring = dict(ring)
     state = {n: (list(v) if isinstance(v, (list, tuple)) else v)
@@ -844,6 +1092,42 @@ def decode_step_impl(config, params, ctx_kv, ring, state, tokens, ctx_lens,
                     state[LIN][n])
             mix = _lin_out(c, lp, o[:B], z)
             n += 1
+        elif kind == "kda":
+            qkv, g, b, z = _kda_in(c, lp, x)
+            with jax.named_scope("kda_conv"):
+                qkv, win = mamba2.conv_step(
+                    pad(qkv), state[KDA_CONV][n], lp["conv_w"], _no_bias(lp))
+                state[KDA_CONV][n] = jnp.where(
+                    pad(live)[:, None, None], win, state[KDA_CONV][n])
+            q, k, v = _kda_qkv(c, qkv[:B])
+            # a lane that is not live: decay exp(0), step 0, key 0
+            k = jnp.where(live[:, None, None], k, 0.0)
+            g = jnp.where(live[:, None, None], g, 0.0)
+            b = jnp.where(live[:, None], b, 0.0)
+            with jax.named_scope("kda_step"):
+                if attn.impl == REFERENCE_IMPL:
+                    o, state[KDA][n] = kda.step(
+                        pad(q), pad(k), pad(v), pad(g), pad(b),
+                        state[KDA][n])
+                else:   # the lanes' rows in place, the scratch lane as is
+                    o, state[KDA][n] = kda.step_pallas(
+                        q, k, v, g, b, state[KDA][n],
+                        interpret=attn.impl == PALLAS_INTERPRET)
+            mix = _kda_out(c, lp, o[:B], z, cdt)
+            n += 1
+        elif kind == "latent_attention":
+            with jax.named_scope("mla_attn"):
+                q_nope, q_rope, row = mla_moe._attn_in(
+                    c, lp, h, jnp.maximum(ctx_lens - 1, 0))
+                ring[KV] = jax.lax.dynamic_update_slice(
+                    ring[KV], row.astype(ring[KV].dtype)[None, None, :, None],
+                    (a, 0, 0, ring_pos, 0))
+                o_lat = latent_decode_attention(
+                    attn, mla_moe._absorb_q(c, lp, q_nope, q_rope),
+                    ctx_kv[KV], ring[KV], jnp.int32(a), ctx_lens, ring_base,
+                    mla_moe.dims(c)["kv_rank"], live)
+                mix = mla_moe._unabsorb_o(c, lp, o_lat) @ lp["wo"]
+            a += 1
         else:
             z, xbc, dt = _ssm_in(c, lp, x)
             with jax.named_scope("ssm_conv"):

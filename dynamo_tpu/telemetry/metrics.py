@@ -457,6 +457,17 @@ MOE_PICKS_ROUTED = ("dynamo_moe_picks_routed",
                     "models that hold a share of the experts: all (token, "
                     "pick) pairs the router made for live lanes in a "
                     "consumed decode round, held here or elsewhere")
+MOE_GROUPS_KEPT_HERE = (
+    "dynamo_moe_groups_kept_here",
+    "models whose router picks within groups and that hold a share of the "
+    "experts: routed tokens of live lanes in a consumed decode round, "
+    "summed over its steps and expert layers, whose kept groups include "
+    "one held here (the others can land no pick on this chip)")
+KDA_STATE_ROWS_STEPPED = (
+    "dynamo_kda_state_rows_stepped",
+    "delta-rule (KDA) models: per-lane matrix states a dispatched decode "
+    "round's steps rewrote: its steps x the engine's lanes (live or not) "
+    "x the delta-rule layers")
 MOE_PREFILL_ROWS_SORTED = (
     "dynamo_moe_prefill_rows_sorted",
     "(token, pick) rows the expert layers of a finished prefill program "
@@ -471,7 +482,9 @@ MOE_PREFILL_ROWS_MOVED = (
 SSM_STATE_BYTES = ("dynamo_ssm_state_bytes",
                    "bytes one lane holds in recurrent state (a state-space "
                    "layer's SSM state and convolution window, a "
-                   "linear-attention layer's matrix state), all layers, "
+                   "linear-attention layer's matrix state, a delta-rule "
+                   "layer's matrix state and three convolution windows), "
+                   "all layers, "
                    "whatever its context (observed once, at engine start)")
 SPARSE_ATTN_ROWS_READ = (
     "dynamo_sparse_attn_rows_read",
@@ -540,13 +553,14 @@ def request_histograms(
                             ROUND_PREFILL_AHEAD,
                             ROUND_LIVE_LANE_STEPS, ROUND_TOKENS,
                             MOE_TOUCHED, MOE_ROUTED, MOE_LOAD_MAX,
-                            MOE_PICKS_ROUTED,
+                            MOE_PICKS_ROUTED, MOE_GROUPS_KEPT_HERE,
                             PREFILL_CONTINUED):
             reg.histogram(name, help_, TOKEN_BUCKETS)
         reg.histogram(*HC_SINKHORN_RESIDUAL,
                       tuple(10.0 ** i for i in range(-9, 1)))
         for name, help_ in (DECODE_ATTN_ROWS_READ, DECODE_ATTN_ROWS_LIVE,
-                            MOE_PREFILL_ROWS_SORTED, MOE_PREFILL_ROWS_MOVED):
+                            MOE_PREFILL_ROWS_SORTED, MOE_PREFILL_ROWS_MOVED,
+                            KDA_STATE_ROWS_STEPPED):
             reg.histogram(name, help_,
                           tuple(float(4 ** i) for i in range(3, 13)))
         reg.histogram(*KV_ROW_BYTES, tuple(float(4 ** i) for i in range(3, 12)))

@@ -120,7 +120,10 @@ def test_the_benchmarks_own_checks_pass(cmd, limit_s):
      False),
     ("granite4h-ep2-d10.ragdoc", "gen.carried_tok_s.ragdoc-open", "higher",
      True),
-], ids=["chat", "longprompt", "chat-decode", "longdoc", "ragdoc"])
+    ("ling3-flash-ep8-d12.reasoning", "gen.carried_tok_s.reasoning-open",
+     "higher", True),
+], ids=["chat", "longprompt", "chat-decode", "longdoc", "ragdoc",
+        "reasoning"])
 def test_carried_tok_s_reads_the_way_its_entry_says(monkeypatch, cell, entry,
                                                      shown, registered_agrees):
     """``benchmarks/test_contract.py``'s two servers (cell 1's pace before
@@ -149,6 +152,10 @@ def test_carried_tok_s_reads_the_way_its_entry_says(monkeypatch, cell, entry,
             gen["tok_s"] - contract.offered_tok_s(log))
     if shown == "lower":
         assert 0 < carried["fast"] < carried["slow"]
+    elif cell == "ling3-flash-ep8-d12.reasoning":
+        # a 20 s pre-roll of answers that outlast it carries IN as well as
+        # out: the faster server still reads the higher, on either side of 0
+        assert carried["slow"] < carried["fast"] and carried["slow"] < 0
     else:
         assert carried["slow"] < carried["fast"] < 0
     assert (contract.PER_LAYER[entry]["better"] == shown) is registered_agrees
@@ -174,7 +181,12 @@ def test_carried_tok_s_reads_the_way_its_entry_says(monkeypatch, cell, entry,
     # the tiny model, so one request in the pre-roll and one in the window
     ("minicpm-sala-d16.longctx", "4500450045",
      "benchmarks/references/sala.py", 0.17),
-], ids=["chat-decode", "longdoc", "longprompt", "ragdoc", "longctx"])
+    # answers of 384-2048 tokens from up to 48 lanes: six requests in the
+    # 20 s pre-roll and two in the window are what the CPU drains in time
+    ("ling3-flash-ep8-d12.reasoning", "4700470047",
+     "benchmarks/references/kda_mla_moe.py", 0.3),
+], ids=["chat-decode", "longdoc", "longprompt", "ragdoc", "longctx",
+        "reasoning"])
 def test_the_new_cell_rehearses_on_the_cpu(tmp_path, cell, seed, reference,
                                            rate_rps):
     """A cell's files end to end at the dry-run widths: configuration,
@@ -185,7 +197,11 @@ def test_the_new_cell_rehearses_on_the_cpu(tmp_path, cell, seed, reference,
     region here too, and its warm-up set has to leave the window nothing
     to compile. The long-context cell's check crosses the toy model's
     switch to the block selection (position 1024) in prefill and in
-    decode, over a 32768-token region with its compressed-key rows."""
+    decode, over a 32768-token region with its compressed-key rows. The
+    reasoning cell's check carries the toy model's delta-rule state, its
+    convolution windows and its latent rows over a chunk boundary at 4096
+    and through 72 decode steps, under grouped routing with a share of
+    eight."""
     root = REPO if rate_rps is None else checkout_at_rate(
         tmp_path, cell, rate_rps)
     r = run([sys.executable, "benchmarks/run.py", "--workload", cell,
@@ -290,3 +306,124 @@ def test_the_four_stream_checks_limits_stand_between_its_readings(
     part of the mathematics, and the lower precision, fails."""
     tol_max, tol_mean = _stated_limits("benchmarks/references/mla_moe_mhc.py")
     assert (worst <= tol_max and mean <= tol_mean) is passes, reading
+
+
+def _reader_sources(hists_after, kernels):
+    """What ``run.py`` hands a reader, as far as the delta-rule cell's own
+    readers look: the published configuration, counters that stood at zero
+    when the window opened, a reduced trace of ten rounds."""
+    sys.path.insert(0, os.path.join(REPO, "benchmarks"))
+    import byname
+    import peaks
+
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "ling3-flash-ep8-d12.json")) as f:
+        cfg = json.load(f)
+    zero = {name: {"sum": 0.0, "count": 0} for name in hists_after}
+    return {
+        "config": cfg, "byname": byname, "peaks": peaks,
+        "before": {"histograms": zero}, "after": {"histograms": hists_after},
+        "engine_up": {"flush_every": 4, "device_kind": "TPU v5 lite"},
+        "trace": {"modules": {"jit_engine_round_seal": {"count": 10}},
+                  "kernels": kernels},
+    }
+
+
+@pytest.mark.parametrize("kernels,stepped,share", [
+    # 100 rounds of 4 steps x 48 lanes x 10 layers; the span holds ten of
+    # them: 10 x 1920 states x 2 x 2 MiB over 0.5 s of the kernel
+    ({"kda_step (f32[48,32,128], f32[49,32,128,128])": 0.5,
+      "gmm bf16[384,768]": 9.0}, {"sum": 192000.0, "count": 100},
+     10 * 1920 * 2 * 2097152 / 819e9 / 0.5 * 100),
+    # a program without the kernel (the XLA step), or without the counter
+    # (the parent): nothing to read, and no reader raises
+    ({"gmm bf16[384,768]": 9.0}, {"sum": 192000.0, "count": 100}, None),
+    ({"kda_step f32[48,32,128]": 0.5}, None, None),
+], ids=["kernel-and-counter", "no-kernel", "no-counter"])
+def test_the_step_kernels_roofline_reads_states_stepped_over_its_seconds(
+        kernels, stepped, share):
+    hists = {} if stepped is None else {
+        "dynamo_kda_state_rows_stepped": stepped}
+    sources = _reader_sources(hists, kernels)
+    read = sources["byname"].module_with(
+        os.path.join(REPO, "benchmarks", "layer_metrics"),
+        "kernel.kda_step_roofline", "read").read
+    got = read(sources)
+    if share is None:
+        assert got is None
+    else:
+        assert got == pytest.approx(share) and 0 < got < 100
+
+
+def test_the_reasoning_cells_counter_readers_count_expert_layers_only():
+    """Ten of the twelve held layers route: the held experts touched a
+    step are read over 10 x 64, and the byte count's parts say what a step
+    of 40 live lanes at 2000 rows moves."""
+    hists = {"dynamo_moe_experts_touched": {"sum": 100 * 4 * 320.0,
+                                            "count": 100},
+             "dynamo_moe_tokens_routed": {"sum": 100 * 4 * 400.0,
+                                          "count": 100},
+             "dynamo_moe_picks_routed": {"sum": 100 * 4 * 3200.0,
+                                         "count": 100}}
+    sources = _reader_sources(hists, {})
+    metrics = os.path.join(REPO, "benchmarks", "layer_metrics")
+    reader = lambda name: sources["byname"].module_with(  # noqa: E731
+        metrics, name, "read").read
+    assert reader("moe.experts_touched_share.reasoning-open")(
+        sources) == pytest.approx(320 / 640 * 100)
+    assert reader("moe.held_pick_share.reasoning-open")(
+        sources) == pytest.approx(12.5)
+    count = sources["byname"].module_with(
+        os.path.join(REPO, "benchmarks", "bytes"), "kda_mla_moe",
+        "decode_parts")
+    parts = count.decode_parts(sources, [2000.0] * 40)
+    assert parts["state"] == 2 * 40 * 10 * (2097152 + 73728)
+    assert parts["rows"] == 40 * 2000 * 640 * 2 * 2
+    assert parts["experts"] == 320 * 3 * 2560 * 768 * 2
+    # 10 x 52.6 M + 2 x 31.9 M + 2 x 47.2 M + 10 x 7.2 M + 50.3 M
+    assert parts["weights"] == pytest.approx(2 * 807.0e6, rel=0.01)
+    nbytes, ops, labels = count.gmm_decode(sources)
+    assert labels == ("gmm bf16[384,768]", "gmm bf16[384,2560]")
+    assert nbytes == parts["experts"] and ops == 400 * 3 * 2 * 2560 * 768
+
+
+_ENTRY_KEYS = {
+    "configs": {"name", "source", "file", "reduced", "why"},
+    "workloads": {"name", "config", "traffic", "chips", "why"},
+    "end_to_end": {"name", "unit", "better", "bound", "source", "workloads"},
+    "per_layer": {"name", "unit", "better", "source", "layer", "moves",
+                  "workloads"},
+}
+
+
+@pytest.mark.parametrize("section", sorted(_ENTRY_KEYS))
+def test_benchmark_json_keeps_the_form_the_driver_refuses_without(section):
+    """What the driver checks of ``BENCHMARK.json`` before any run, from
+    the file alone (PR 47 was refused once for a configuration's ``why`` of
+    220 characters): a name is at most 64 of ``[A-Za-z0-9_.-]``, a ``why``,
+    ``layer`` or ``source`` 1 to 200 printable characters on one line, a
+    unit 1 to 16 characters without a space, no key beyond the entry's own,
+    no name twice, the whole file under 64 KiB."""
+    import re
+
+    path = os.path.join(REPO, "BENCHMARK.json")
+    assert os.path.getsize(path) <= 64 * 1024
+    with open(path) as f:
+        entries = json.load(f)[section]
+    name = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}")
+    names = [e["name"] for e in entries]
+    assert len(set(names)) == len(names)
+    for e in entries:
+        assert not set(e) - _ENTRY_KEYS[section], e["name"]
+        words = [e["name"], *e.get("reduced", []), *e.get("workloads", []),
+                 *(e[k] for k in ("config", "traffic", "moves") if k in e)]
+        assert all(name.fullmatch(w) for w in words), e["name"]
+        assert len(e.get("reduced", [])) <= 16, e["name"]
+        for key in ("why", "layer", "source"):
+            if key in e:
+                text = e[key]
+                assert 1 <= len(text) <= 200 and text.isprintable(), (
+                    e["name"], key, len(text))
+        if "unit" in e:
+            assert re.fullmatch(r"[A-Za-z0-9_/%.\-]{1,16}", e["unit"]), e
+            assert e["better"] in ("lower", "higher"), e["name"]

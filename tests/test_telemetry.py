@@ -119,6 +119,7 @@ def test_registry_render_and_snapshot():
         "dynamo_sparse_attn_rows_read", "dynamo_sparse_attn_rows_live",
         "dynamo_sparse_prefill_pairs_scored",
         "dynamo_sparse_prefill_pairs_selected",
+        "dynamo_moe_groups_kept_here", "dynamo_kda_state_rows_stepped",
         "dynamo_request_tpot_seconds",
         "dynamo_engine_step_gap_seconds",
         "dynamo_engine_step_gap_clean_seconds",

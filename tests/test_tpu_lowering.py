@@ -503,6 +503,70 @@ def test_sparse_cell_keeps_four_prefill_programs():
                                 (4096, 1, False), (4096, 1, True)]
 
 
+# the delta-rule + latent attention cell's programs (compiled, PR 47: the
+# round 0.083 GB; a 4096-token chunk 0.74 GB fresh and 2.37 GB continuing,
+# of which 0.42 GB is the workspace of the prior latent rows expanded per
+# head over a 20480-row span). 12.9 GB of weights, latent rows and state
+# leave the chip ~4 GB: the continuing chunk is what has to fit
+KDA_TEMP_CEILING = {"round_seal": 0.15e9, "batch_prefill_cont": 2.6e9}
+
+
+@pytest.fixture(scope="module", params=sorted(KDA_TEMP_CEILING))
+def kda_record(request):
+    """The fused round and the continuing ``[1, 4096]`` prefill of the
+    delta-rule + latent attention cell at its published widths (12 layers,
+    latent rows ``[2, 1, 49, 20480, 640]``, ten ``[49, 32, 128, 128]``
+    float32 states and ``[49, 3, 12288]`` windows, 64 held experts a
+    layer), compiled by XLA:TPU and Mosaic for a compile-only v5e (~15-40
+    s)."""
+    _v5e_or_skip()
+    with jax.default_matmul_precision("default"):
+        (rec,) = tpu_compile_check.compile_programs(
+            config="ling3-flash-ep8-d12", programs=(request.param,),
+            prefill_width=4096)
+    return request.param, rec
+
+
+def test_delta_rule_programs_copy_neither_the_state_nor_the_region(
+        kda_record):
+    """Every decode step rewrites ten 100 MB state leaves in place (the
+    step kernel aliases its state operand; the leaves ride the round's
+    carry) and reads the latent rows where they lie; a continuing chunk
+    reads its lane's state and rows and writes them in one tail pass: no
+    ``copy`` the size of the latent region (2.57 GB) or of a state leaf,
+    temporaries under their ceiling, and the step kernel is there (ten
+    KDA layers' and two latent layers' Mosaic calls beside the grouped
+    products')."""
+    name, rec = kda_record
+    assert rec["ok"], rec.get("error")
+    assert rec["region_shard"] == [2, 1, 49, 20480, 640]
+    assert rec["region_copies"] == {"count": 0, "shapes": []}, rec
+    assert rec["temp_bytes"] < KDA_TEMP_CEILING[name], rec["temp_gb"]
+    assert 12.85 < rec["argument_gb"] < 12.95
+    if name == "round_seal":
+        assert rec["mosaic_calls"] >= 10 + 2 + 3 * 10
+
+
+def test_delta_rule_cell_keeps_four_prefill_programs():
+    """As the other long-prompt cells: 2 buckets x 1 lane x {fresh,
+    continuing} whole-model prefill programs beside the round's two."""
+    import json
+
+    from dynamo_tpu.engine.config import EngineConfig
+
+    with open(os.path.join(os.path.dirname(tpu_compile_check.__file__), "..",
+                           "benchmarks", "configs",
+                           "ling3-flash-ep8-d12.json")) as f:
+        e = EngineConfig(**json.load(f)["engine"])
+    assert (e.max_decode_slots, e.max_context) == (48, 20480)
+    programs = {(T, e.prefill_lanes(T, group), continuing)
+                for T in e.prefill_buckets
+                for group in range(1, e.prefill_chunks_per_round + 1)
+                for continuing in (False, True)}
+    assert sorted(programs) == [(1024, 1, False), (1024, 1, True),
+                                (4096, 1, False), (4096, 1, True)]
+
+
 # ``lowered_sha256`` (tools/tpu_compile_check.py: the StableHLO text before
 # the compiler, Mosaic bodies masked) of the programs that share code with
 # the continuing latent chunk and must NOT move with it: every program
