@@ -361,11 +361,16 @@ class TpuEngine:
         self._sparse, self._sparse_layers = (
             ssm_moe.sparse_layers(model_config)
             if self._block is ssm_moe else (None, 0))
-        # the stats row's width says what rides it (the block's
-        # stats_zero): a fifth counter is the grouped router's
+        # how wide the round's stats row is (the block's stats_zero), and
+        # where the counters only some stacks have ride it (ssm_moe.
+        # stats_layout): the grouped router's, the delta-rule layers'
         self._stats_width = (
             self._block.stats_zero(model_config).shape[0]
             if self._block is not None else 0)
+        layout = (ssm_moe.stats_layout(model_config)
+                  if self._block is ssm_moe else ())
+        self._stats_at = {name: layout.index(name) for name in
+                          ("groups_kept", "kda_stepped") if name in layout}
         if self._block is not None:
             self._refuse_row_only_planes(self.ecfg, on_dispatch,
                                          draft_config)
@@ -599,9 +604,6 @@ class TpuEngine:
         # bytes a token holds in the ctx region, and bytes a lane holds
         # in recurrent state whatever its context: observed once, here
         recurrent = llama.state_kinds(self.ctx)
-        # delta-rule layers: every one rewrites every lane's matrix state
-        # every step (what _h_kda_rows counts a round)
-        self._kda_layers = len(self.ctx.get(ssm_moe.KDA, ()))
         self.telemetry.get(tmetrics.KV_ROW_BYTES[0]).observe(sum(
             x.nbytes for n, leaf in self.ctx.items() if n not in recurrent
             for x in jax.tree.leaves(leaf)
@@ -2492,8 +2494,6 @@ class TpuEngine:
             self._observe_decode_attn_rows(active, n)
         if self._sparse is not None:
             self._observe_sparse_rows(active, n)
-        if self._kda_layers:
-            self._h_kda_rows.observe(n * self._B * self._kda_layers)
         # only dispatched lanes advance (spec slots track their own
         # lengths through verify processing)
         self._ctx_disp[active] = np.minimum(
@@ -4385,14 +4385,18 @@ class TpuEngine:
                 # picks that landed on it, this all the picks the router made
                 self._h_moe_picks_routed.observe(
                     int(toks[entry.n_steps, 3]))
-                if self._stats_width > 4:
-                    self._h_moe_groups_kept.observe(
-                        int(toks[entry.n_steps, 4]))
+                if "groups_kept" in self._stats_at:
+                    self._h_moe_groups_kept.observe(int(
+                        toks[entry.n_steps, self._stats_at["groups_kept"]]))
             if self.config.hc is not None:
                 # the fourth counter is a float32's bits
                 self._h_hc_residual.observe(float(
                     toks[entry.n_steps, 3:4].astype(np.int32).view(
                         np.float32)[0]))
+        if "kda_stepped" in self._stats_at:
+            # counted by the program: the states its work lists held
+            self._h_kda_rows.observe(int(
+                toks[entry.n_steps, self._stats_at["kda_stepped"]]))
         delivered = 0
         for slot, r in enumerate(entry.slots):
             # identity check doubles as the epoch: a recycled slot holds

@@ -70,10 +70,11 @@ kind, what a region holds from its leaves:
     sigmoid(exp(A_log) (x W_f + dt_bias))`` in (bound, 0) a channel and the
     step ``b = sigmoid(x W_b)`` a head, both float32; ``S' = Diag(e^g) S``,
     ``S = S' + b k (v - S'^T k)^T``, ``o = S^T q`` on a ``kda_state`` leaf
-    [lanes + 1, heads, D, D] float32 a layer (a lane that is not live: g
-    0, b 0, k 0); an RMSNorm over D a head, a sigmoid gate a HEAD from the
-    layer's input, W_o. No rotary. On TPU devices the decode step is one
-    Pallas kernel a layer that rewrites the state in place.
+    [lanes + 1, heads, D, D] float32 a layer; an RMSNorm over D a head, a
+    sigmoid gate a HEAD from the layer's input, W_o. No rotary. On TPU
+    devices the decode step is one Pallas kernel a layer that follows a
+    work list of the LIVE lanes and rewrites their states in place (the
+    XLA form steps every lane, one that is not live with g 0, b 0, k 0).
   - ``latent_attention``: models/mla_moe.py's attention (imported, not
     copied) on ONE ``kv`` row leaf [L_latent, 1, lanes, S, stored] in
     place of K and V: prefill expands K and V per head (a continuing
@@ -517,12 +518,21 @@ def route(c: ModelConfig, lp, x):
         return sel, w, here.any(axis=1)
 
 
-def stats_zero(c: ModelConfig):
-    """A step's counters before any layer: [held experts touched, picks
+def stats_layout(c: ModelConfig) -> tuple:
+    """What a step's counters hold, in order: held experts touched, picks
     that landed on a held expert, most tokens on one held expert, all
-    picks of routed tokens], and under the grouped router a fifth: routed
-    tokens that kept a group held here."""
-    return jnp.zeros(5 if "groups" in dims(c) else 4, jnp.int32)
+    picks of routed tokens; under the grouped router, routed tokens that
+    kept a group held here; with delta-rule layers, the per-lane matrix
+    states their steps moved on (the live lanes', a layer)."""
+    d = dims(c)
+    return (("touched", "held", "load_max", "picks")
+            + (("groups_kept",) if "groups" in d else ())
+            + (("kda_stepped",) if d["n_kda"] else ()))
+
+
+def stats_zero(c: ModelConfig):
+    """A step's counters (``stats_layout``) before any layer."""
+    return jnp.zeros(len(stats_layout(c)), jnp.int32)
 
 
 def merge_stats(a, b):
@@ -560,6 +570,7 @@ def _ffn(c: ModelConfig, lp, x, valid, stats):
             jnp.asarray(picks, jnp.int32)]
     if here is not None:
         seen.append(jnp.sum(here if valid is None else here & valid))
+    seen += [0] * (stats.shape[0] - len(seen))   # counters not a router's
     return y, merge_stats(stats, jnp.stack(seen).astype(jnp.int32))
 
 
@@ -1059,6 +1070,12 @@ def decode_step_impl(config, params, ctx_kv, ring, state, tokens, ctx_lens,
     # the scratch lane rides along as one more row that never moves
     pad = lambda a: jnp.pad(a, ((0, 1),) + ((0, 0),) * (a.ndim - 1))  # noqa: E731
     a = j = n = sp = 0
+    if d["n_kda"]:
+        # the delta-rule layers' work list, the same for all of them, and
+        # what they step of it: the live lanes' states, a layer
+        work = kda.work_list(live)
+        stats = stats.at[stats_layout(c).index("kda_stepped")].add(
+            work[1][0] * d["n_kda"])
     for kind, lp in zip(d["kinds"], params["layers"]):
         x = _rms(h, lp["ln1"], c.rms_norm_eps)
         if kind == "attention":
@@ -1100,18 +1117,18 @@ def decode_step_impl(config, params, ctx_kv, ring, state, tokens, ctx_lens,
                 state[KDA_CONV][n] = jnp.where(
                     pad(live)[:, None, None], win, state[KDA_CONV][n])
             q, k, v = _kda_qkv(c, qkv[:B])
-            # a lane that is not live: decay exp(0), step 0, key 0
-            k = jnp.where(live[:, None, None], k, 0.0)
-            g = jnp.where(live[:, None, None], g, 0.0)
-            b = jnp.where(live[:, None], b, 0.0)
             with jax.named_scope("kda_step"):
                 if attn.impl == REFERENCE_IMPL:
+                    # every lane steps; one that is not live: decay
+                    # exp(0), step 0, key 0
                     o, state[KDA][n] = kda.step(
-                        pad(q), pad(k), pad(v), pad(g), pad(b),
+                        pad(q), pad(jnp.where(live[:, None, None], k, 0.0)),
+                        pad(v), pad(jnp.where(live[:, None, None], g, 0.0)),
+                        pad(jnp.where(live[:, None], b, 0.0)),
                         state[KDA][n])
-                else:   # the lanes' rows in place, the scratch lane as is
+                else:   # the live lanes' states in place, no other touched
                     o, state[KDA][n] = kda.step_pallas(
-                        q, k, v, g, b, state[KDA][n],
+                        q, k, v, g, b, state[KDA][n], *work,
                         interpret=attn.impl == PALLAS_INTERPRET)
             mix = _kda_out(c, lp, o[:B], z, cdt)
             n += 1

@@ -51,9 +51,12 @@ precision.
 DECODE (``step``) is the recurrence as written, one position a lane, in
 float32; a lane with ``g`` = 0, ``b`` = 0 and ``k`` = 0 gets its state
 back as it was. ``step_pallas`` is the same step as one kernel a layer
-that reads and writes each lane's [H, D, D] state once, IN PLACE (the
+that reads and writes a lane's [H, D, D] state once, IN PLACE (the
 XLA form is a scale, a mat-vec, an outer product and a second mat-vec over
-a 2.1 MB operand a lane: two passes over the state). The kernel wants
+a 2.1 MB operand a lane: two passes over the state), and only the lanes
+of a WORK LIST (``work_list``: the lanes that hold a request, scalar
+prefetch): a lane the list does not hold is neither read nor written, so
+a step costs what its live lanes' state costs. The kernel wants
 ``a``, ``k`` and ``q`` along the state's KEY axis, i.e. as columns: each
 rides in as one [heads, D] tile a grid step that the kernel pads to [D, D]
 and transposes once, head h in column h.
@@ -211,11 +214,13 @@ def step(q, k, v, g, b, state):
     return jnp.sum(S * q[..., None], axis=-2), S
 
 
-def _step_kernel(a_ref, k_ref, q_ref, v_ref, b_ref, s_ref, o_ref, out_ref):
-    """A lane's ``hb`` heads: ``a`` (the decay, exp g), ``k``, ``q``,
-    ``v`` and ``b`` (broadcast over D) as [1, hb, D] tiles, ``s_ref`` /
-    ``out_ref`` [1, hb, D, D] the state before and after (one buffer),
-    ``o_ref`` [1, hb, D] the outputs."""
+def _step_kernel(lanes_ref, n_ref, a_ref, k_ref, q_ref, v_ref, b_ref, s_ref,
+                 o_ref, out_ref):
+    """Work item ``i`` of the grid's first axis: ``hb`` heads of lane
+    ``lanes[i]``. ``a`` (the decay, exp g), ``k``, ``q``, ``v`` and ``b``
+    (broadcast over D) as [1, hb, D] tiles, ``s_ref`` / ``out_ref`` [1,
+    hb, D, D] the state before and after (one buffer), ``o_ref`` [1, hb,
+    D] the outputs."""
     hb, D = s_ref.shape[1], s_ref.shape[2]
 
     def columns(ref):
@@ -225,40 +230,69 @@ def _step_kernel(a_ref, k_ref, q_ref, v_ref, b_ref, s_ref, o_ref, out_ref):
         return jnp.concatenate(
             [x, jnp.zeros((D - hb, D), x.dtype)], axis=0).T
 
-    a_c, k_c, q_c = columns(a_ref), columns(k_ref), columns(q_ref)
-    for h in range(hb):
-        a, k, q = (c[:, h:h + 1] for c in (a_c, k_c, q_c))     # [D, 1]
-        v, b = v_ref[0, h:h + 1, :], b_ref[0, h:h + 1, :]      # [1, D]
-        S = a * s_ref[0, h]
-        u = b * (v - jnp.sum(S * k, axis=0, keepdims=True))
-        S = S + k * u
-        out_ref[0, h] = S
-        o_ref[0, h:h + 1, :] = jnp.sum(S * q, axis=0, keepdims=True)
+    @pl.when(n_ref[0] > 0)
+    def _():
+        a_c, k_c, q_c = columns(a_ref), columns(k_ref), columns(q_ref)
+        for h in range(hb):
+            a, k, q = (c[:, h:h + 1] for c in (a_c, k_c, q_c))     # [D, 1]
+            v, b = v_ref[0, h:h + 1, :], b_ref[0, h:h + 1, :]      # [1, D]
+            S = a * s_ref[0, h]
+            u = b * (v - jnp.sum(S * k, axis=0, keepdims=True))
+            S = S + k * u
+            out_ref[0, h] = S
+            o_ref[0, h:h + 1, :] = jnp.sum(S * q, axis=0, keepdims=True)
+
+    @pl.when(n_ref[0] == 0)
+    def _():
+        # an empty list: the grid's one lane (lane 0) goes back as it came
+        out_ref[...] = s_ref[...]
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def work_list(live):
+    """The lanes a step visits, from ``live`` [B] bool: (``lanes`` [B]
+    int32, the live lanes' indices first and 0 after them, ``n_live`` [1]
+    int32)."""
+    lanes, = jnp.nonzero(live, size=live.shape[0], fill_value=0)
+    return lanes.astype(jnp.int32), jnp.sum(live, dtype=jnp.int32)[None]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def step_pallas(q, k, v, g, b, state, interpret: bool = False):
-    """``step`` as one kernel: ``q``, ``k``, ``v``, ``g`` [B, H, D], ``b``
-    [B, H]; ``state`` [L >= B, H, D, D] float32, of which lanes 0..B-1
-    are stepped IN PLACE and the others are not touched."""
+def step_pallas(q, k, v, g, b, state, lanes, n_live, interpret: bool = False):
+    """``step`` as one kernel over the lanes of a work list
+    (``work_list``): ``q``, ``k``, ``v``, ``g`` [B, H, D], ``b`` [B, H];
+    ``state`` [L >= B, H, D, D] float32; ``lanes`` [B] and ``n_live`` [1]
+    int32 ride in as scalar prefetch, and ``n_live`` is the grid's bound:
+    a lane past the list costs nothing, not even an empty grid step. The
+    lanes ``lanes[:n_live]`` are stepped IN PLACE; no other lane's state is
+    read or written, and its ``o`` comes back 0."""
     B, H, D = q.shape
     hb = next(n for n in (HEADS_PER_STEP, 4, 2, 1) if H % n == 0)
     vectors = (jnp.exp(g.astype(_F32)), k.astype(_F32), q.astype(_F32),
                v.astype(_F32),
                jnp.broadcast_to(b.astype(_F32)[..., None], (B, H, D)))
-    tile = pl.BlockSpec((1, hb, D), lambda i, j: (i, j, 0))
-    lane = pl.BlockSpec((1, hb, D, D), lambda i, j: (i, j, 0, 0))
+    tile = pl.BlockSpec((1, hb, D), lambda i, j, lanes, n: (lanes[i], j, 0))
+    lane = pl.BlockSpec((1, hb, D, D),
+                        lambda i, j, lanes, n: (lanes[i], j, 0, 0))
     o, state = pl.pallas_call(
         _step_kernel,
-        grid=(B, H // hb),
-        in_specs=[tile] * 5 + [lane],
-        out_specs=[tile, lane],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            # never an empty grid: what a pipeline with no step writes
+            # back is nobody's promise
+            grid=(jnp.maximum(n_live[0], 1), H // hb),
+            in_specs=[tile] * 5 + [lane],
+            out_specs=[tile, lane]),
         out_shape=[jax.ShapeDtypeStruct((B, H, D), _F32),
                    jax.ShapeDtypeStruct(state.shape, _F32)],
-        input_output_aliases={5: 1},
+        # operand indices count the two prefetched scalars
+        input_output_aliases={7: 1},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="kda_step",
-    )(*vectors, state)
-    return o, state
+    )(lanes, n_live, *vectors, state)
+    # a lane no item visited: its block of ``o`` was never written
+    visited = jnp.any((lanes[None, :] == jnp.arange(B)[:, None])
+                      & (jnp.arange(B)[None, :] < n_live[0]), axis=1)
+    return jnp.where(visited[:, None, None], o, 0.0), state

@@ -465,9 +465,10 @@ MOE_GROUPS_KEPT_HERE = (
     "one held here (the others can land no pick on this chip)")
 KDA_STATE_ROWS_STEPPED = (
     "dynamo_kda_state_rows_stepped",
-    "delta-rule (KDA) models: per-lane matrix states a dispatched decode "
-    "round's steps rewrote: its steps x the engine's lanes (live or not) "
-    "x the delta-rule layers")
+    "delta-rule (KDA) models: per-lane matrix states the steps of a "
+    "consumed decode round moved on, counted by the program: the lanes "
+    "its step kernel's work list held (the live ones), summed over the "
+    "round's steps x the delta-rule layers")
 MOE_PREFILL_ROWS_SORTED = (
     "dynamo_moe_prefill_rows_sorted",
     "(token, pick) rows the expert layers of a finished prefill program "

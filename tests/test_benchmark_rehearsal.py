@@ -355,6 +355,30 @@ def test_the_step_kernels_roofline_reads_states_stepped_over_its_seconds(
         assert got == pytest.approx(share) and 0 < got < 100
 
 
+@pytest.mark.parametrize("stepped,live,ratio", [
+    # 100 rounds of 4 steps at 29 of 48 lanes live, ten delta-rule layers:
+    # a program that steps every lane, then one that follows its work list
+    (100 * 4 * 48 * 10.0, 100 * 4 * 29.0, 48 / 29),
+    (100 * 4 * 29 * 10.0, 100 * 4 * 29.0, 1.0),
+    # a program without the counter, a window without a round: nothing
+    (None, 100 * 4 * 29.0, None),
+    (100 * 4 * 29 * 10.0, None, None),
+], ids=["every-lane", "the-work-list", "no-counter", "no-rounds"])
+def test_the_states_stepped_are_read_over_the_live_lanes(stepped, live,
+                                                         ratio):
+    hists = {name: {"sum": total, "count": 100} for name, total in (
+        ("dynamo_kda_state_rows_stepped", stepped),
+        ("dynamo_engine_round_live_lane_steps", live)) if total is not None}
+    sources = _reader_sources(hists, {})
+    got = sources["byname"].module_with(
+        os.path.join(REPO, "benchmarks", "layer_metrics"),
+        "kda.states_stepped_over_live", "read").read(sources)
+    if ratio is None:
+        assert got is None
+    else:
+        assert got == pytest.approx(ratio)   # ~1.65, and 1.00
+
+
 def test_the_reasoning_cells_counter_readers_count_expert_layers_only():
     """Ten of the twelve held layers route: the held experts touched a
     step are read over 10 x 64, and the byte count's parts say what a step

@@ -47,6 +47,7 @@ TOL = 2e-4
 PS = 8
 BUCKETS = (32, 64)
 TOP = 5
+LANES = 6   # a round's six counters ride home in a row this wide
 HF = dict(_TINY_KDA_LATENT, engine={"prefill_buckets": list(BUCKETS)})
 
 
@@ -73,7 +74,7 @@ def setup():
 def engine(cfg, params, **kw):
     ecfg = EngineConfig(**{**dict(
         num_pages=16, page_size=PS, max_pages_per_seq=32,
-        max_decode_slots=5, prefill_buckets=BUCKETS, flush_every=4,
+        max_decode_slots=LANES, prefill_buckets=BUCKETS, flush_every=4,
         cache_dtype="float32", max_logprobs=TOP), **kw})
     return TpuEngine(cfg, ecfg, params=params, mesh_config=MeshConfig(tp=1))
 
@@ -175,18 +176,29 @@ def test_gates_at_the_bound_for_a_whole_chunk_stay_finite(same_key):
     np.testing.assert_allclose(S_end, S_want, atol=5e-5)
 
 
+@pytest.mark.parametrize("live", [(True, False, True, False), (True,) * 4,
+                                  (False,) * 4],
+                         ids=["holes", "all-live", "none-live"])
 @pytest.mark.parametrize("H, D", [(4, 16), (8, 128)],
                          ids=["toy", "a-tile-of-published-heads"])
-def test_the_step_kernel_equals_the_step(H, D):
-    """The Pallas kernel (interpreted) against ``kda.step``; the lanes
-    past the ones it is given keep their state."""
-    q, k, v, g, b, _ = kda_inputs(3, H, D, seed=3)
-    S = jax.random.normal(jax.random.PRNGKey(9), (4, H, D, D))
-    o, S_new = kda.step(q, k, v, g, b, S[:3])
-    o2, S2 = kda.step_pallas(q, k, v, g, b, S, interpret=True)
-    np.testing.assert_allclose(o2, o, atol=1e-5)
-    np.testing.assert_allclose(S2[:3], S_new, atol=1e-5)
-    np.testing.assert_array_equal(S2[3], S[3])
+def test_the_step_kernel_equals_the_step(H, D, live):
+    """The Pallas kernel (interpreted) over a work list against
+    ``kda.step`` on the lanes the list holds; every other lane and the
+    scratch lane keep their state bit for bit, and their ``o`` is 0."""
+    q, k, v, g, b, _ = kda_inputs(4, H, D, seed=3)
+    S = jax.random.normal(jax.random.PRNGKey(9), (5, H, D, D))
+    o, S_new = kda.step(q, k, v, g, b, S[:4])
+    lanes, n_live = kda.work_list(jnp.asarray(live))
+    assert int(n_live[0]) == sum(live)
+    o2, S2 = kda.step_pallas(q, k, v, g, b, S, lanes, n_live, interpret=True)
+    for lane, on in enumerate(live):
+        if on:
+            np.testing.assert_allclose(o2[lane], o[lane], atol=1e-5)
+            np.testing.assert_allclose(S2[lane], S_new[lane], atol=1e-5)
+        else:
+            np.testing.assert_array_equal(S2[lane], S[lane])
+            assert not np.asarray(o2[lane]).any()
+    np.testing.assert_array_equal(S2[4], S[4])
 
 
 # ---------------------------------------------------------------------------
@@ -246,9 +258,13 @@ async def test_chunks_interleave_with_other_lanes_decode_and_lanes_are_reused(
         cfg, 4) == 5 * (4 * 16 * 16 * 4 + 3 * 3 * 64 * 4)
     assert snap["dynamo_kv_row_bytes"]["sum"] == ssm_moe.kv_row_bytes(
         cfg, 4) == 2 * 128 * 4
+    # the program's own count: the live lanes' states a step, five layers
     rounds = snap["dynamo_kda_state_rows_stepped"]
-    assert rounds["count"] > 0
-    assert rounds["sum"] == rounds["count"] * 4 * 5 * 5   # steps, lanes, layers
+    lane_steps = snap["dynamo_engine_round_live_lane_steps"]
+    assert rounds["count"] == lane_steps["count"] > 0
+    assert rounds["sum"] == lane_steps["sum"] * 5
+    # a lane stood empty: fewer than steps x lanes x layers
+    assert rounds["sum"] < rounds["count"] * 4 * LANES * 5
     picks = snap["dynamo_moe_picks_routed"]["sum"]
     held = snap["dynamo_moe_tokens_routed"]["sum"]
     kept = snap["dynamo_moe_groups_kept_here"]["sum"]

@@ -536,7 +536,10 @@ def test_delta_rule_programs_copy_neither_the_state_nor_the_region(
     ``copy`` the size of the latent region (2.57 GB) or of a state leaf,
     temporaries under their ceiling, and the step kernel is there (ten
     KDA layers' and two latent layers' Mosaic calls beside the grouped
-    products')."""
+    products'). Since PR 48 the step kernel follows a scalar-prefetched
+    list of the live lanes under a grid bound that is traced (0.068 GB
+    of temporaries for the round): all of the above must hold of that
+    form too, the state still aliased in place."""
     name, rec = kda_record
     assert rec["ok"], rec.get("error")
     assert rec["region_shard"] == [2, 1, 49, 20480, 640]
