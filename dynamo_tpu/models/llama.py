@@ -75,6 +75,7 @@ from dynamo_tpu.kv_quant import (
 )
 from dynamo_tpu.models import mla_moe, ssm_moe
 from dynamo_tpu.models.config import ModelConfig
+from dynamo_tpu.models.live_rows import live_row_trips, over_live_blocks
 from dynamo_tpu.ops.attention import (
     DecodeAttention,
     PriorContext,
@@ -730,33 +731,30 @@ LIVE_ROW_BLOCK = 512
 
 
 def live_row_block(c: ModelConfig, T: int, tree: bool = False) -> int:
-    """Rows a block of ``_live_rows`` for a prefill chunk of bucket width
-    ``T``, or 0 where the halves run straight-line over all T rows: a
-    bucket of one or two blocks (nothing worth a loop), a packed token
-    tree (live nodes are no prefix of the chunk), the capacity-bounded
-    expert layer (rows compete for capacity, so no row block stands
-    alone) and the block models, which have their own halves. Decided by
-    the shape and by what the program is given, never by a model's
-    name."""
-    R = LIVE_ROW_BLOCK
-    if tree or c.moe is not None or block_of(c) is not None:
+    """Rows a block of the live-row loops for a prefill chunk of bucket
+    width ``T``, or 0 where a chunk's per-row work runs straight-line over
+    all T rows: a bucket of one or two blocks (nothing worth a loop), a
+    packed token tree (live nodes are no prefix of the chunk), the
+    capacity-bounded expert layer (rows compete for capacity, so no row
+    block stands alone) and the latent block, whose halves are its own.
+    The hybrid block has its own rule for its own halves and scans
+    (ssm_moe.live_row_block). Decided by the shape and by what the
+    program is given, never by a model's name."""
+    if tree or c.moe is not None or block_of(c) is mla_moe:
         return 0
+    if block_of(c) is ssm_moe:
+        return ssm_moe.live_row_block(c, T)
+    R = LIVE_ROW_BLOCK
     return R if T % R == 0 and T // R > 2 else 0
-
-
-def live_row_trips(q_starts, seq_lens, T: int, R: int):
-    """Row blocks of each lane that hold a live row: ``ceil(n / R)`` of
-    its ``n = seq_len - q_start`` live rows, none for a dummy lane.
-    numpy in (the engine's mirror), numpy out; traced in, traced out."""
-    return ((seq_lens - q_starts).clip(0, T) + (R - 1)) // R
 
 
 def prefill_positions_run(c: ModelConfig, T: int, q_starts,
                           seq_lens) -> int:
-    """Token positions one dense prefill dispatch of ``len(q_starts)``
-    lanes at bucket width ``T`` runs its matmuls over — the host's mirror
-    of ``_live_rows``' trip count: live row blocks x block height where
-    the program loops over them, lanes x width where it does not."""
+    """Token positions one prefill dispatch of ``len(q_starts)`` lanes at
+    bucket width ``T`` runs its per-row work over: the host's mirror of
+    the live-row loops' trip count (``_live_rows`` here, the hybrid
+    block's halves and scans): live row blocks x block height where the
+    program loops over them, lanes x width where it does not."""
     R = live_row_block(c, T)
     if not R:
         return len(q_starts) * T
@@ -804,14 +802,7 @@ def _live_rows(half, c: ModelConfig, layers, l, ad, trips, rows, R: int):
     Mistral-7B's widths) in front of every loop. Sliced inside, the
     slice and the int8 -> bf16 convert fuse into the matmul, as in the
     straight-line code (compile-only, PERF.md section 6, PR 44)."""
-    i32 = jnp.int32
-    trips = trips.astype(i32)
-    ends = jnp.cumsum(trips)
-
-    def half_of_block(lane, r0):
-        blk = [jax.lax.dynamic_slice(
-            x, (lane, r0) + (0,) * (x.ndim - 2), (1, R) + x.shape[2:])[0]
-            for x in rows]
+    def block(lane, r0, blk):
         ad_lane = None if ad is None else jax.tree.map(
             lambda x: jax.lax.dynamic_index_in_dim(x, lane, keepdims=False),
             ad)
@@ -821,18 +812,7 @@ def _live_rows(half, c: ModelConfig, layers, l, ad, trips, rows, R: int):
             layers)
         return jax.tree.leaves(half(c, lp, *blk, ad=ad_lane))
 
-    def run(w, outs):
-        lane = jnp.sum(w >= ends).astype(i32)
-        r0 = (w - (ends[lane] - trips[lane])) * R
-        return tuple(
-            jax.lax.dynamic_update_slice(
-                o, y[None], (lane, r0) + (0,) * (y.ndim - 1))
-            for o, y in zip(outs, half_of_block(lane, r0)))
-
-    K, T = rows[0].shape[:2]
-    shapes = jax.eval_shape(half_of_block, i32(0), i32(0))
-    outs = tuple(jnp.zeros((K, T) + s.shape[1:], s.dtype) for s in shapes)
-    return jax.lax.fori_loop(0, ends[-1], run, outs)
+    return over_live_blocks(block, trips, rows, R)
 
 
 def _logits(config: ModelConfig, params: Params, h: jnp.ndarray) -> jnp.ndarray:

@@ -26,7 +26,11 @@ engine meets it:
   - PREFILL reads a continuing chunk's state from its lane (zeros when
     the chunk is fresh) and writes the state after the chunk's last REAL
     position: padding runs with ``dt`` 0 and the convolution's window is
-    gathered at the real length.
+    gathered at the real length. In a bucket of two row blocks or more a
+    chunk's per-row work (both row-wise halves of every layer kind and the
+    chunked scans) runs the blocks that hold a real row only
+    (``live_row_block``, models/live_rows.py); the masks then cover the
+    tail of the last live block.
   - NO PREFIX REUSE: a page of K/V rows without the recurrent state at
     its boundary cannot resume a prompt, so the engine turns its prefix
     cache off for this block, and the planes that move rows only refuse
@@ -100,6 +104,7 @@ Every function here is reached through the ``llama`` names
 """
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
@@ -109,6 +114,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models import mla_moe
+from dynamo_tpu.models.live_rows import live_row_trips, over_live_blocks
 from dynamo_tpu.models.mla_moe import _mlp, _rms   # the same norm and SwiGLU
 from dynamo_tpu.models.moe import grouped_experts, move_block, rows_moved
 from dynamo_tpu.ops import kda, lightning, mamba2, sparse_attention
@@ -557,21 +563,29 @@ def prefill_rows_sorted(c: ModelConfig, n_tokens: int) -> int:
     return n_tokens * d["K"] * layers if _move_block(d, n_tokens) else 0
 
 
-def _ffn(c: ModelConfig, lp, x, valid, stats):
-    """Routed experts (this chip's share) + the shared MLP, ungated."""
-    d = dims(c)
-    sel, w, here = route(c, lp, x)
-    y, load = grouped_experts(x, sel, w, lp["we_g"], lp["we_u"], lp["we_d"],
-                              valid, first=d["first"])
-    with jax.named_scope("moe_shared"):
-        y = y + _mlp(x, lp["ws_g"], lp["ws_u"], lp["ws_d"])
-    picks = (x.shape[0] if valid is None else valid.sum()) * d["K"]
+def _seen(c: ModelConfig, load, here, valid, n_tokens: int, stats):
+    """``stats`` with one expert layer's counters merged in: ``load`` the
+    tokens each held expert received, ``here`` the router's third value."""
+    picks = (n_tokens if valid is None else valid.sum()) * dims(c)["K"]
     seen = [jnp.sum(load > 0), load.sum(), load.max(),
             jnp.asarray(picks, jnp.int32)]
     if here is not None:
         seen.append(jnp.sum(here if valid is None else here & valid))
     seen += [0] * (stats.shape[0] - len(seen))   # counters not a router's
-    return y, merge_stats(stats, jnp.stack(seen).astype(jnp.int32))
+    return merge_stats(stats, jnp.stack(seen).astype(jnp.int32))
+
+
+def _shared(lp, x):
+    with jax.named_scope("moe_shared"):
+        return _mlp(x, lp["ws_g"], lp["ws_u"], lp["ws_d"])
+
+
+def _ffn(c: ModelConfig, lp, x, valid, stats):
+    """Routed experts (this chip's share) + the shared MLP, ungated."""
+    sel, w, here = route(c, lp, x)
+    y, load = grouped_experts(x, sel, w, lp["we_g"], lp["we_u"], lp["we_d"],
+                              valid, first=dims(c)["first"])
+    return y + _shared(lp, x), _seen(c, load, here, valid, x.shape[0], stats)
 
 
 def _layer_out(c: ModelConfig, lp, h, mix, valid, stats):
@@ -780,6 +794,48 @@ def _sparse_prefill(c: ModelConfig, lp, x, ctx_kv, row: int, sp: int, slots,
     return mix, k, v, jnp.stack(kcs)
 
 
+def _sparse_attend(c: ModelConfig, q, k, v, ctx_kv, row: int, sp: int,
+                    slots, q_starts, seq_lens, span: int):
+    """One sparse layer's attention over K chunks, from its in-projection's
+    q [K, T, heads, hd], k and v [K, T, kvh, hd]: (o [K, T, heads, hd],
+    the lanes' compressed keys with this chunk's written in [K, kvh, S /
+    stride, hd]). ``row`` / ``sp``: the layer's ordinal among the layers
+    that keep rows / among the sparse ones."""
+    g = dims(c)["sparse"]
+    kvh, hd = c.num_kv_heads, c.head_dim
+    K, T = q.shape[:2]
+    with jax.named_scope("nope_attn"):
+        Sc = ctx_kv[KC].shape[3]
+        masks, kcs = [], []
+        for i in range(K):
+            with jax.named_scope("sparse_compress"):
+                if span:
+                    at = jnp.maximum(q_starts[i] - g.stride, 0)
+                    tail = jax.lax.dynamic_slice(
+                        ctx_kv["k"], (row, 0, slots[i], at, 0),
+                        (1, kvh, 1, g.stride, hd))[0, :, 0].transpose(1, 0, 2)
+                    kc_lane = jax.lax.dynamic_slice(
+                        ctx_kv[KC], (sp, 0, slots[i], 0, 0),
+                        (1, kvh, 1, Sc, hd))[0, :, 0]
+                else:
+                    tail = jnp.zeros((g.stride, kvh, hd), k.dtype)
+                    kc_lane = jnp.zeros((kvh, Sc, hd), ctx_kv[KC].dtype)
+                kc_lane = sparse_attention.overlay(
+                    kc_lane, sparse_attention.compress_chunk(g, k[i], tail),
+                    q_starts[i] // g.stride - 1)
+            with jax.named_scope("sparse_select"):
+                masks.append(sparse_attention.prefill_block_mask(
+                    g, q[i].reshape(T, kvh, -1, hd), kc_lane.astype(q.dtype),
+                    q_starts[i], seq_lens[i] - q_starts[i]))
+            kcs.append(kc_lane)
+        with jax.named_scope("sparse_attn"):
+            o = prefill_attention(
+                q, k, v, q_starts, seq_lens,
+                _prior_rows(ctx_kv, row, slots, span), ctx_span=span,
+                block_masks=jnp.stack(masks), mask_block=g.block)
+    return o, jnp.stack(kcs)
+
+
 def _sparse_decode(c: ModelConfig, lp, x, ctx_kv, ring, kc, row: int,
                    sp: int, ctx_lens, ring_base, ring_pos, live,
                    attn: DecodeAttention):
@@ -848,6 +904,189 @@ def _refuse_adapters(params):
 # ---------------------------------------------------------------------------
 # Prefill
 
+# Heights of the row blocks a prefill chunk's per-row work loops over: the
+# two row-wise halves of every layer kind (_mix_in, _mix_out) in blocks of
+# LIVE_ROW_BLOCK, the chunked scans of the recurrent kinds (_scan_block) in
+# blocks of SCAN_ROW_BLOCK. Both chosen on the chip
+# (tools/hybrid_rows_bench.py, PERF.md section 6, PR 49): the halves'
+# matmuls want 512 rows a trip, a scan wants ONE 256-row chunk a trip (a
+# trip of two is a loop in a loop: 1.25 x the straight-line scan at full
+# length where one chunk a trip is 0.87 x).
+LIVE_ROW_BLOCK = 512
+SCAN_ROW_BLOCK = 256
+# The layer kinds whose stacks loop. A stack of lightning and block-sparse
+# layers stays straight-line: its looped layers are 2-7 % faster a row, but
+# served whole (full chunks, so little to skip) it read no gain end to end
+# and its check's distance rose by a sixth: the looped program rounds what
+# crosses a half's boundary to the cache dtype as written, which the
+# straight-line program's fusions skip, and the sparse layers' block
+# selection flips on such roundings (PERF.md section 6, PR 49).
+LIVE_ROW_KINDS = ("mamba", "attention", "kda", "latent_attention")
+
+
+def live_row_block(c: ModelConfig, T: int) -> int:
+    """Rows a block of the hybrid block's live-row loops for a prefill
+    chunk of bucket width ``T`` (the halves' height; the scans' is
+    ``SCAN_ROW_BLOCK``), or 0 where its halves and scans run straight-line
+    over all T rows: a bucket of one block or of no whole number of them,
+    scan blocks that are no whole part of a block or no whole number of a
+    scan's chunks (a block's scan must cut its rows where the whole
+    chunk's would), and a stack with a layer kind outside
+    ``LIVE_ROW_KINDS``. Decided by the shapes and the kinds, one decision
+    a program; the host's mirror (``llama.prefill_positions_run``) calls
+    it too."""
+    R, Rs, d = LIVE_ROW_BLOCK, SCAN_ROW_BLOCK, dims(c)
+    chunks = [q for n, q in ((d["n_ssm"], d.get("chunk")),
+                             (d["n_kda"], kda.CHUNK), (d["n_lin"], LIN_CHUNK))
+              if n]
+    if (T % R or T // R < 2 or R % Rs or any(Rs % q for q in chunks)
+            or any(kind not in LIVE_ROW_KINDS for kind in d["kinds"])):
+        return 0
+    return R
+
+
+def _mix_in(c: ModelConfig, kind: str, lp, h, pos):
+    """The row-wise FIRST half of a layer of ``kind``: the norm and the
+    mixer's in-projection. ``h`` [N, H], ``pos`` [N] the rows' positions
+    -> what the kind's sequence operation takes, a tuple of [N, ...]."""
+    if kind == "latent_attention":
+        q_nope, q_rope, row = mla_moe._attn_in(c, lp, h, pos)
+        k, v = mla_moe._expand_kv(c, lp, row)
+        return jnp.concatenate([q_nope, q_rope], -1), k, v, row
+    x = _rms(h, lp["ln1"], c.rms_norm_eps)
+    if kind == "attention":
+        return _qkv(c, lp, x)
+    if kind == "sparse_attention":
+        return _sparse_in(c, lp, x)
+    if kind == "linear_attention":
+        return _lin_in(c, lp, x, pos)
+    if kind == "kda":
+        return _kda_in(c, lp, x)
+    return _ssm_in(c, lp, x)
+
+
+def _scan_block(c: ModelConfig, kind: str, lp, S, *blk):
+    """A run of one lane's rows through a recurrent layer's chunked scan,
+    from the state ``S`` the rows before left: ([o], the state after the
+    run's last real row). The run is a whole chunk of T rows, or one block
+    of them (a whole number of the scan's chunks). Padding is masked here:
+    it neither decays nor feeds the state."""
+    d = dims(c)
+    if kind == "linear_attention":
+        q, k, v, real = blk
+        with jax.named_scope("lin_attn_scan"):
+            o, S = lightning.chunk_scan(
+                q, k, v, jnp.asarray(lightning.log_decays(d["lin_heads"])),
+                real, S, LIN_CHUNK)
+    elif kind == "kda":
+        qkv, g, b, real = blk
+        with jax.named_scope("kda_scan"):
+            o, S = kda.chunk_scan(*_kda_qkv(c, qkv), g, b, real, S)
+    else:
+        xbc, dt, real = blk
+        xs, Bm, Cm = _split_xbc(c, xbc)
+        with jax.named_scope("ssm_scan"):
+            o, S = mamba2.chunk_scan(
+                xs, jnp.where(real[:, None], dt, 0.0), -jnp.exp(lp["A_log"]),
+                Bm, Cm, S, d["chunk"])
+    return [o], S
+
+
+def _mix_out(c: ModelConfig, kind: str, lp, h, *seq):
+    """The row-wise SECOND half of a layer of ``kind``, up to the expert
+    sort: the mixer's out-norm, gate and out-projection from what its
+    sequence operation returned (``seq``, [N, ...] each), the residual,
+    ``ln2`` and, where the layer routes, the router and the shared MLP:
+    (h after the mixer, x = ln2 of it, picks, combine weights, the shared
+    MLP of x[, whether a token kept a group held here]); where it does
+    not, the dense MLP and its residual too: (h after the layer,)."""
+    if kind in ("attention", "latent_attention"):
+        o, = seq
+        mix = o.reshape(o.shape[0], -1) @ lp["wo"]
+    elif kind == "sparse_attention":
+        o, z = seq
+        mix = _gated(o.reshape(o.shape[0], c.q_dim), z) @ lp["wo"]
+    elif kind == "linear_attention":
+        mix = _lin_out(c, lp, *seq)
+    elif kind == "kda":
+        mix = _kda_out(c, lp, *seq, h.dtype)
+    else:
+        y, xbc, z = seq
+        mix = _ssm_out(c, lp, y, _split_xbc(c, xbc)[0], z)
+    r = jnp.asarray(c.hybrid_dict["residual_multiplier"], h.dtype)
+    h = h + r * mix
+    x = _rms(h, lp["ln2"], c.rms_norm_eps)
+    if "wr" not in lp:   # a leading dense layer
+        with jax.named_scope("mlp"):
+            return (h + r * _mlp(x, lp["w_g"], lp["w_u"], lp["w_d"]),)
+    sel, w, here = route(c, lp, x)
+    return (h, x, sel, w, _shared(lp, x)) + (() if here is None else (here,))
+
+
+@functools.partial(jax.jit, static_argnames=("half", "kind", "c", "R"))
+def _live_half(half, kind: str, c: ModelConfig, lp, trips, rows, R: int):
+    """``half`` (_mix_in or _mix_out: no row of its output depends on
+    another row) of a layer of ``kind`` over the row blocks that hold a
+    live row (live_rows.over_live_blocks): ``rows`` its per-row operands
+    [K, T, ...], ``trips`` [K] the lanes' live blocks. A tuple of [K, T,
+    ...], rows of blocks that never ran 0. The layer's weights are
+    arguments, so the same-kind layers of a program, unrolled in the
+    caller, share ONE traced and lowered loop body."""
+    return over_live_blocks(
+        lambda lane, r0, blk: list(half(c, kind, lp, *blk)), trips, rows, R)
+
+
+@functools.partial(jax.jit, static_argnames=("kind", "c", "R"))
+def _live_scan(kind: str, c: ModelConfig, lp, trips, rows, state, R: int):
+    """A recurrent layer's chunked scan over the row blocks that hold a
+    live row: each trip runs ``_scan_block`` on its block from the lane's
+    carried float32 state. (o [K, T, ...] with the rows of blocks that
+    never ran 0, the states [K, ...] after each lane's last live block:
+    what masking the padding of the whole chunk gives too.)"""
+    (o,), state = over_live_blocks(
+        lambda lane, r0, blk, S: _scan_block(c, kind, lp, S, *blk),
+        trips, rows, R, state)
+    return o, state
+
+
+def _write_chunks(c, params, ctx_kv, rows, kcs, states, slots, q_starts,
+                  seq_lens, h):
+    """A prefill program's tail, after every read: the K chunks' rows
+    (``rows``: {kind: [K, L, T, heads, width]}) as spans, their compressed
+    keys (``kcs``: [K, kvh, Sc, hd] a sparse layer) and recurrent leaves
+    (``states``: (name, [K, ...] a layer) pairs) as whole lanes, and the
+    logits of each chunk's last real position from ``h`` [K, T, H] (or
+    [K T, H])."""
+    K = slots.shape[0]
+
+    def write_lane(i, out):
+        out = dict(out)
+        for name, r in rows.items():
+            r = jax.lax.dynamic_index_in_dim(r, i, keepdims=False)
+            out[name] = jax.lax.dynamic_update_slice(
+                out[name], r.transpose(0, 2, 1, 3)[:, :, None],
+                (0, 0, slots[i], q_starts[i], 0))
+        if kcs:
+            kc = jax.lax.dynamic_index_in_dim(
+                jnp.stack(kcs, 1), i, keepdims=False)  # [L_sparse, kvh, Sc, hd]
+            out[KC] = jax.lax.dynamic_update_slice(
+                out[KC], kc[:, :, None].astype(out[KC].dtype),
+                (0, 0, slots[i], 0, 0))
+        for name, new in states:
+            out[name] = [
+                jax.lax.dynamic_update_slice(
+                    buf, jax.lax.dynamic_index_in_dim(s, i, keepdims=True),
+                    (slots[i],) + (0,) * (buf.ndim - 1))
+                for buf, s in zip(out[name], new)]
+        return out
+
+    out_ctx = jax.lax.fori_loop(0, K, write_lane, dict(ctx_kv))
+    last = jnp.maximum(seq_lens - q_starts - 1, 0)
+    h_last = jnp.take_along_axis(
+        h.reshape(K, -1, h.shape[-1]), last[:, None, None], axis=1)[:, 0]
+    return out_ctx, _logits(c, params, h_last)
+
+
 def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
                        seq_lens, ctx_span=0, adapter_ids=None):
     """K chunks [K, T] through the model in one program. The attention
@@ -856,7 +1095,18 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
     read. ``ctx_span`` 0: every chunk is fresh, and neither the region
     nor a lane's state is read. Else a chunk with q_start > 0 starts
     from its lane's state as it attends its lane's rows; one with
-    q_start 0 starts from zeros."""
+    q_start 0 starts from zeros.
+
+    Where ``live_row_block`` gives a block height, the program is
+    ``_live_prefill``'s instead: the same layers with their per-row work
+    over the lanes' LIVE row blocks. Here every layer runs all K x T
+    bucket rows, padding masked: the form every narrow bucket and a stack
+    outside ``LIVE_ROW_KINDS`` keep, and one whose lowered text must not
+    move with the looped form's (a block-sparse stack's served tail latency
+    follows its chunks' compiled schedule: PERF.md section 6, PR 49)."""
+    if live_row_block(config, tokens.shape[1]):
+        return _live_prefill(config, params, ctx_kv, tokens, slots, q_starts,
+                             seq_lens, ctx_span)
     c, d = config, dims(config)
     _refuse_adapters(params)
     K, T = tokens.shape
@@ -1001,32 +1251,160 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
         (SSM, ssm_out), (CONV, conv_out), (LIN, lin_out), (KDA, kda_out),
         (KDA_CONV, kda_conv_out)) if new]
 
-    def write_lane(i, out):
-        out = dict(out)
-        for name, r in rows.items():
-            r = jax.lax.dynamic_index_in_dim(r, i, keepdims=False)
-            out[name] = jax.lax.dynamic_update_slice(
-                out[name], r.transpose(0, 2, 1, 3)[:, :, None],
-                (0, 0, slots[i], q_starts[i], 0))
-        if kcs:
-            kc = jax.lax.dynamic_index_in_dim(
-                jnp.stack(kcs, 1), i, keepdims=False)  # [L_sparse, kvh, Sc, hd]
-            out[KC] = jax.lax.dynamic_update_slice(
-                out[KC], kc[:, :, None].astype(out[KC].dtype),
-                (0, 0, slots[i], 0, 0))
-        for name, new in states:
-            out[name] = [
-                jax.lax.dynamic_update_slice(
-                    buf, jax.lax.dynamic_index_in_dim(s, i, keepdims=True),
-                    (slots[i],) + (0,) * (buf.ndim - 1))
-                for buf, s in zip(out[name], new)]
-        return out
+    out_ctx, logits = _write_chunks(c, params, ctx_kv, rows, kcs, states, slots,
+                                    q_starts, seq_lens, h)
+    return out_ctx, logits, moved
 
-    out_ctx = jax.lax.fori_loop(0, K, write_lane, dict(ctx_kv))
-    last = jnp.maximum(seq_lens - q_starts - 1, 0)
-    h_last = jnp.take_along_axis(
-        h.reshape(K, T, -1), last[:, None, None], axis=1)[:, 0]
-    return out_ctx, _logits(c, params, h_last), moved
+
+def _live_prefill(config, params, ctx_kv, tokens, slots, q_starts, seq_lens,
+                  ctx_span):
+    """``batch_prefill_impl`` where ``live_row_block`` gives a block height
+    R: everything a layer does per row follows the lanes' LIVE rows (a
+    prefix of the chunk) in blocks: both row-wise halves (``_live_half``,
+    R rows a block) and the recurrent kinds' chunked scans
+    (``_live_scan``, ``SCAN_ROW_BLOCK`` rows a block, within the halves'
+    blocks), as the attention's query blocks and the expert path's row
+    movements do; rows of blocks that never ran are 0 from the first
+    layer's second half on, and no reader passes a lane's live length.
+    Left over all T rows: the short convolutions (elementwise, a 3-row
+    halo) and a routing layer's last residual."""
+    c, d = config, dims(config)
+    _refuse_adapters(params)
+    K, T = tokens.shape
+    cdt = _rows_of(ctx_kv).dtype
+    positions = q_starts[:, None] + jnp.arange(T, dtype=jnp.int32)
+    real = positions < seq_lens[:, None]                      # [K, T]
+    valid = real.reshape(K * T)
+    n_real = jnp.clip(seq_lens - q_starts, 0, T)
+    span = min(ctx_span, _rows_of(ctx_kv).shape[3])
+    continuing = q_starts > 0
+    if span and d["n_latent"]:
+        # continuing chunks' prior latent rows, expanded per head into a
+        # workspace every latent layer rewrites (mla_moe._expand_prior)
+        m = mla_moe.dims(c)
+        below = jnp.minimum(jnp.minimum(q_starts, seq_lens), span)
+        work = tuple(jnp.zeros((1, m["nh"], K, span, w), cdt)
+                     for w in (m["nope"] + m["rope"], m["v"]))
+    h = _embed(c, params, tokens, cdt)                        # [K, T, H]
+    stats = stats_zero(c)
+    # the lanes' live blocks, of the halves' and of the scans' height
+    R, Rs = live_row_block(c, T), SCAN_ROW_BLOCK
+    trips, scan_trips = (live_row_trips(q_starts, seq_lens, T, height)
+                         for height in (R, Rs))
+    Rm = _move_block(d, K * T)
+    moved = jnp.int32(0)
+    r = jnp.asarray(c.hybrid_dict["residual_multiplier"], cdt)
+    ks, vs, kcs, lat = [], [], [], []
+    new = {name: [] for name in (SSM, CONV, LIN, KDA, KDA_CONV)}
+    flat = lambda a: a.reshape(K * T, *a.shape[2:])  # noqa: E731
+
+    def rowwise(half, kind, lp, *rows):
+        return _live_half(half, kind, c, lp, trips, rows, R)
+
+    def scan(kind, lp, S0, *rows):
+        return _live_scan(kind, c, lp, scan_trips, rows + (real,), S0, Rs)
+
+    def before(names, shapes):
+        """The lanes' recurrent leaves a chunk starts from: their own
+        where it continues, zeros where it is fresh."""
+        if not span:
+            return [jnp.zeros((K,) + shape, dtype) for shape, dtype in shapes]
+        j = len(new[names[0]])
+        return [jnp.where(continuing.reshape((K,) + (1,) * len(shape)),
+                          ctx_kv[name][j][slots], 0)
+                for name, (shape, _) in zip(names, shapes)]
+
+    def conv(lp, a, win0, bias):
+        return jax.vmap(lambda a, w0, n: mamba2.causal_conv(
+            a, w0, lp["conv_w"], bias, n))(a, win0, n_real)
+
+    for kind, lp in zip(d["kinds"], params["layers"]):
+        ins = rowwise(_mix_in, kind, lp, h, positions)
+        if kind == "attention":
+            q, k, v = ins
+            with jax.named_scope("nope_attn"):
+                seq = (prefill_attention(
+                    q, k, v, q_starts, seq_lens,
+                    _prior_rows(ctx_kv, len(ks), slots, span),
+                    ctx_span=span),)
+            ks.append(k)
+            vs.append(v)
+        elif kind == "sparse_attention":
+            q, k, v, z = ins
+            o, kc = _sparse_attend(c, q, k, v, ctx_kv, len(ks), len(kcs),
+                                   slots, q_starts, seq_lens, span)
+            ks.append(k)
+            vs.append(v)
+            kcs.append(kc)
+            seq = (o, z)
+        elif kind == "linear_attention":
+            q, k, v, z = ins
+            S0, = before((LIN,), [((d["lin_heads"], d["lin_dim"],
+                                    d["lin_dim"]), jnp.float32)])
+            o, S = scan(kind, lp, S0, q, k, v)
+            new[LIN].append(S)
+            seq = (o, z)
+        elif kind == "kda":
+            qkv, g, b, z = ins
+            S0, win0 = before((KDA, KDA_CONV), [
+                ((d["kda_heads"], d["kda_dim"], d["kda_dim"]), jnp.float32),
+                ((d["kda_W"] - 1, 3 * d["kda_inner"]), cdt)])
+            with jax.named_scope("kda_conv"):
+                qkv, win = conv(lp, qkv, win0, _no_bias(lp))
+            o, S = scan(kind, lp, S0, qkv, g, b)
+            new[KDA].append(S)
+            new[KDA_CONV].append(win)
+            seq = (o, z)
+        elif kind == "latent_attention":
+            qq, k, v, row = ins
+            with jax.named_scope("mla_attn"):
+                prior = None
+                if span:
+                    work = mla_moe._expand_prior(
+                        c, work, ctx_kv[KV], lp["wkvb"], jnp.int32(len(lat)),
+                        slots, below)
+                    prior = PriorContext(*work, jnp.int32(0),
+                                         jnp.arange(K, dtype=jnp.int32))
+                seq = (prefill_attention(qq, k, v, q_starts, seq_lens, prior,
+                                         ctx_span=span),)
+            lat.append(row[:, :, None])
+        else:
+            z, xbc, dt = ins
+            S0, win0 = before((SSM, CONV), [
+                ((d["nh"], d["P"], d["N"]), jnp.float32),
+                ((d["W"] - 1, d["conv"]), cdt)])
+            with jax.named_scope("ssm_conv"):
+                xbc, win = conv(lp, xbc, win0, lp["conv_b"])
+            y, S = scan(kind, lp, S0, xbc, dt)
+            new[SSM].append(S)
+            new[CONV].append(win)
+            seq = (y, xbc, z)
+        out = rowwise(_mix_out, kind, lp, h, *seq)
+        if "wr" not in lp:
+            h, = out
+            continue
+        h, x, sel, w, shared, *here = out
+        y, load = grouped_experts(
+            flat(x), flat(sel), flat(w), lp["we_g"], lp["we_u"], lp["we_d"],
+            valid, first=d["first"])
+        h = h + r * (y.reshape(h.shape) + shared)
+        stats = _seen(c, load, flat(here[0]) if here else None, valid,
+                      K * T, stats)
+        if Rm:   # the layer's held picks are its groups' total
+            moved = moved + rows_moved(load.sum(), Rm)
+
+    # tail: every read is done. Rows as spans, compressed keys and states
+    # as whole lanes
+    if lat:
+        rows = {KV: jnp.stack(lat, 1).astype(cdt)}  # [K, L_latent, T, 1, row]
+    else:
+        rows = {"k": jnp.stack(ks, 1).astype(cdt),  # [K, L_attn, T, kvh, hd]
+                "v": jnp.stack(vs, 1).astype(cdt)}
+    states = [(name, leaves) for name, leaves in new.items() if leaves]
+
+    out_ctx, logits = _write_chunks(c, params, ctx_kv, rows, kcs, states, slots,
+                                    q_starts, seq_lens, h)
+    return out_ctx, logits, moved
 
 
 def prefill_impl(config, params, ctx_kv, tokens, slot, q_start, seq_len,

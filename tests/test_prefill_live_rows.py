@@ -233,16 +233,22 @@ def test_the_shape_alone_decides(T_, blocks):
                                  "latent_block", "hybrid_block"])
 def test_straight_line_where_rows_do_not_stand_alone(why):
     """Tree chunks (live nodes are no prefix), an expert layer whose rows
-    compete for capacity, and the block models with their own halves."""
+    compete for capacity, and the latent block with its own halves. The
+    hybrid block is the POSITIVE case since PR 49: its own halves and
+    scans loop over the live row blocks by its own rule
+    (``ssm_moe.live_row_block``; tests/test_hybrid_live_rows.py), which
+    ``llama.live_row_block`` and the host's mirror hand over to."""
     c = {"tree": _config(),
          "capacity_moe": _config(moe=(("capacity_factor", 1.25),
                                       ("num_experts", 4), ("top_k", 2))),
          "dropless_moe": ModelConfig.tiny_moe(dtype="float32"),
          "latent_block": ModelConfig.tiny_mla_moe(dtype="float32"),
          "hybrid_block": ModelConfig.tiny_ssm_moe(dtype="float32")}[why]
-    assert llama.live_row_block(c, 4096, tree=why == "tree") == 0
+    looped = why == "hybrid_block"
+    assert llama.live_row_block(c, 4096, tree=why == "tree") == (
+        512 if looped else 0)
     assert llama.prefill_positions_run(c, 4096, [0, 0], [2100, 0]) == (
-        2 * 4096 if why != "tree" else 2560)
+        2 * 4096 if why not in ("tree", "hybrid_block") else 2560)
     if why == "tree":
         params, ctx = _abstract(c)
         K, N = 2, 2048
@@ -255,11 +261,12 @@ def test_straight_line_where_rows_do_not_stand_alone(why):
             jax.jit(llama.batch_score_impl, static_argnums=(0, 7)),
             c, params, ctx, _i32(K, N), _i32(K), _i32(K), _i32(K), 4096)
         assert "_live_rows" in linear       # a linear chunk may take it
-    elif "moe" in why:
+    elif "moe" in why or looped:
         params, ctx = _abstract(c)
-        assert "_live_rows" not in _lowered(
-            llama.prefill, c, params, ctx, _i32(4096), _i32(), _i32(),
-            _i32(), fresh=True)
+        text = _lowered(llama.prefill, c, params, ctx, _i32(4096), _i32(),
+                        _i32(), _i32(), fresh=True)
+        assert "_live_rows" not in text       # the dense decoder's loop
+        assert ("_live_half" in text) == looped
 
 
 # ---- program guards ----------------------------------------------------
@@ -346,6 +353,9 @@ def test_host_mirror_is_the_programs_trip_count(case):
 # (c13f2fc) by this test itself, under the conftest's matmul precision:
 # their programs do not move with the dense
 # path's halves. A PR that MEANS to change one records the new digest here.
+# Since PR 49 the hybrid block's wide buckets take another program
+# (``ssm_moe._live_prefill``); its straight-line one, which this 64-row
+# bucket runs, kept the parent's text.
 BLOCK_MODEL_DIGESTS = {
     "tiny_mla_moe": "c728cb0a7a7fceae",
     "tiny_mla_moe_mhc": "23f9246b56275a3d",

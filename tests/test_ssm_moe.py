@@ -175,6 +175,35 @@ async def test_looped_expert_rows_serve_the_reference_and_are_counted(
     assert ssm_moe.prefill_rows_sorted(cfg, 32) == 0
 
 
+async def test_live_row_blocks_serve_the_reference_and_are_counted(
+        monkeypatch, setup):
+    """The served path with a chunk's halves and scans looped over toy row
+    blocks of 8 (a config of its own, so that no cached program of another
+    test is met): a 37-token prompt runs a full 32-token chunk (four live
+    blocks) and a continuing 5-token one in the 16 bucket (one live block
+    of two), still equals the reference, and the padded-positions counter
+    reads the 40 positions the programs ran, not the 48 of the buckets."""
+    _, _, ref = setup
+    monkeypatch.setattr(ssm_moe, "LIVE_ROW_BLOCK", 8)
+    monkeypatch.setattr(ssm_moe, "SCAN_ROW_BLOCK", 8)
+    hf = dict(HF, rms_norm_eps=1.7e-5)
+    cfg = ModelConfig.tiny_ssm_moe(dtype="float32", rms_norm_eps=1.7e-5)
+    assert [ssm_moe.live_row_block(cfg, T) for T in BUCKETS] == [8, 8]
+    params = llama.init_params(cfg, 4)
+    eng = engine(cfg, params)
+    prompt = prompt_of(37, 11)
+    toks, tops = await serve(eng, prompt, 12)
+    assert distance(ref, params, prompt, toks, tops, hf=hf) < TOL
+    assert eng.dispatch_counts["prefill"] == 2
+    snap = eng.telemetry.snapshot()
+    assert snap["dynamo_engine_prefill_tokens"]["sum"] == 37
+    assert snap["dynamo_engine_prefill_padded_tokens"]["sum"] == 32 + 8
+    await eng.stop()
+    # under the real rule these buckets run straight-line
+    monkeypatch.undo()
+    assert llama.prefill_positions_run(cfg, 16, [32], [37]) == 16
+
+
 async def test_chunks_interleave_with_other_lanes_decode_and_lanes_are_reused(
         setup):
     """A prompt prefilled in three chunks WHILE another lane decodes

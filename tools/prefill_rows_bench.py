@@ -12,7 +12,9 @@ does the loop cost at full length, and which ``R``?  The attention between
 the halves is left out (it already stops at the live length). Before
 them, per widths, the WHOLE solo prefill program of a 4-layer cut, looped
 against straight-line: do logits and region rows agree on this device? One
-JSON line per (widths, T, live, form).
+JSON line per (widths, T, live, form). The hybrid block's halves and scans
+(models/ssm_moe.py) have a tool of their own beside this one:
+tools/hybrid_rows_bench.py.
 
   python tools/prefill_rows_bench.py            # on the chip (chiprun)
   python tools/prefill_rows_bench.py --dry-run  # toy widths, here
