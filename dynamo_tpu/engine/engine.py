@@ -70,9 +70,8 @@ from dynamo_tpu.kv_router.protocols import (
     KvStats,
     WorkerStats,
 )
-from dynamo_tpu.models import llama, mla_moe, ssm_moe
+from dynamo_tpu.models import llama
 from dynamo_tpu.models.config import ModelConfig
-from dynamo_tpu.ops import latent_decode, sparse_attention
 from dynamo_tpu.ops.attention import (
     decode_attention_for,
     prefill_attention_pairs,
@@ -349,31 +348,28 @@ class TpuEngine:
         # compiled Pallas kernel on TPU devices, the jnp reference on the
         # CPU test meshes — decided here, once, from the mesh's devices
         self.decode_attn = decode_attention_for(self.mesh)
-        # the module of a block that is not the dense decoder: its own
-        # decode step, which also returns the routing counters
-        self._block = llama.block_of(model_config)
-        # does a round's counter row carry routing counters? (a block
-        # whose feed-forward part is one dense MLP routes nothing)
-        self._routes = (self._block is not None
-                        and self._block.routes(model_config))
-        # a block-sparse attention's geometry (ops/sparse_attention.py),
-        # for the host's mirrors of what its layers read
-        self._sparse, self._sparse_layers = (
-            ssm_moe.sparse_layers(model_config)
-            if self._block is ssm_moe else (None, 0))
-        # how wide the round's stats row is (the block's stats_zero), and
-        # where the counters only some stacks have ride it (ssm_moe.
-        # stats_layout): the grouped router's, the delta-rule layers'
-        self._stats_width = (
-            self._block.stats_zero(model_config).shape[0]
-            if self._block is not None else 0)
-        layout = (ssm_moe.stats_layout(model_config)
-                  if self._block is ssm_moe else ())
-        self._stats_at = {name: layout.index(name) for name in
-                          ("groups_kept", "kda_stepped") if name in layout}
-        if self._block is not None:
+        # what the model's block says of itself (models/llama.py: the
+        # block protocol), asked once: the counter row its round brings
+        # home, and what of its state the row-only planes cannot carry
+        self._stats_layout = llama.stats_layout(model_config)
+        multiple = llama.page_multiple(model_config)
+        if self.ecfg.page_size % multiple:
+            raise ValueError(
+                f"page_size={self.ecfg.page_size} is no multiple of the "
+                f"sparse attention's block ({multiple}): a prefill "
+                "chunk starts on a page and has to start on a block")
+        what = llama.state_called(model_config)
+        if what is not None:
             self._refuse_row_only_planes(self.ecfg, on_dispatch,
-                                         draft_config)
+                                         draft_config, what)
+        if self.ecfg.max_decode_slots < len(self._stats_layout):
+            # the round's counters ride home in one more row of the
+            # stacked-token fetch, max_decode_slots wide
+            raise ValueError(
+                f"max_decode_slots={self.ecfg.max_decode_slots}: this "
+                f"routed-expert model needs at least "
+                f"{len(self._stats_layout)} (its counters ride the "
+                "round's token fetch in a row that wide)")
         dev0 = self.mesh.devices.flat[0]
         log.info(
             "engine devices: platform=%s device_kind=%s mesh=%s "
@@ -473,7 +469,7 @@ class TpuEngine:
         # cannot resume a prompt: a model with recurrent layers takes no
         # prefix match and seals nothing (a preempted or migrated stream
         # recomputes from position 0)
-        prefix_caching = e.enable_prefix_caching and c.hybrid is None
+        prefix_caching = e.enable_prefix_caching and llama.pages_resume(c)
         if e.enable_prefix_caching and not prefix_caching:
             log.info("prefix cache bypassed: this model's layers carry a "
                      "recurrent state that pages of K/V rows do not hold")
@@ -570,37 +566,25 @@ class TpuEngine:
         self._h_live_steps = self.telemetry.get(
             tmetrics.ROUND_LIVE_LANE_STEPS[0])
         self._h_round_tokens = self.telemetry.get(tmetrics.ROUND_TOKENS[0])
-        # routed-expert counters, one observation per consumed decode
-        # round; they ride the round's stacked-token fetch (an extra row)
-        self._h_moe_touched = self.telemetry.get(tmetrics.MOE_TOUCHED[0])
-        self._h_moe_routed = self.telemetry.get(tmetrics.MOE_ROUTED[0])
-        self._h_moe_load_max = self.telemetry.get(tmetrics.MOE_LOAD_MAX[0])
-        self._h_hc_residual = self.telemetry.get(
-            tmetrics.HC_SINKHORN_RESIDUAL[0])
         self._h_pf_continued = self.telemetry.get(
             tmetrics.PREFILL_CONTINUED[0])
-        self._h_attn_rows_read = self.telemetry.get(
-            tmetrics.DECODE_ATTN_ROWS_READ[0])
-        self._h_attn_rows_live = self.telemetry.get(
-            tmetrics.DECODE_ATTN_ROWS_LIVE[0])
-        self._h_moe_picks_routed = self.telemetry.get(
-            tmetrics.MOE_PICKS_ROUTED[0])
-        self._h_moe_groups_kept = self.telemetry.get(
-            tmetrics.MOE_GROUPS_KEPT_HERE[0])
-        self._h_kda_rows = self.telemetry.get(
-            tmetrics.KDA_STATE_ROWS_STEPPED[0])
         self._h_moe_pf_sorted = self.telemetry.get(
             tmetrics.MOE_PREFILL_ROWS_SORTED[0])
         self._h_moe_pf_moved = self.telemetry.get(
             tmetrics.MOE_PREFILL_ROWS_MOVED[0])
-        self._h_sparse_read = self.telemetry.get(
-            tmetrics.SPARSE_ATTN_ROWS_READ[0])
-        self._h_sparse_live = self.telemetry.get(
-            tmetrics.SPARSE_ATTN_ROWS_LIVE[0])
-        self._h_sparse_scored = self.telemetry.get(
-            tmetrics.SPARSE_PREFILL_SCORED[0])
-        self._h_sparse_selected = self.telemetry.get(
-            tmetrics.SPARSE_PREFILL_SELECTED[0])
+        # the block's counters, one observation per consumed decode
+        # round; they ride the round's stacked-token fetch (an extra
+        # row): (column, histogram, is a float32's bits) for every column
+        # of the declared layout that feeds one
+        self._stats_table = [
+            (col, self.telemetry.get(counter.metric), counter.f32_bits)
+            for col, counter in enumerate(self._stats_layout)
+            if counter.metric is not None]
+        # the host's mirrors of what the block's attention reads in a
+        # dispatched round and a prefill dispatch (None: it has none)
+        self._decode_mirror = llama.decode_mirror(
+            c, e.max_context, e.flush_every, self.decode_attn)
+        self._prefill_mirror = llama.prefill_mirror(c)
         # bytes a token holds in the ctx region, and bytes a lane holds
         # in recurrent state whatever its context: observed once, here
         recurrent = llama.state_kinds(self.ctx)
@@ -853,18 +837,16 @@ class TpuEngine:
             bare argmax instead of top-k over the vocab."""
             B = dev["tokens"].shape[0]
             ring_base = jnp.maximum(dev["ctx"] - 1, 0)
-            # routed-expert models: one more row carries the round's
-            # routing counters home in the same fetch
-            block = llama.block_of(c)
-            routed = block is not None
-            toks_out = jnp.zeros((n_steps + int(routed), B), jnp.int32)
-            moe_stats = (block or mla_moe).stats_zero(c)
-            # recurrent leaves (models/ssm_moe.py) are updated by every
-            # step, and compressed-key rows by the step that completes
-            # one, where the region's other rows are read-only until the
-            # flush: they ride the loop's carry ({} for the other blocks)
-            recurrent = ({n: ctx_kv[n] for n in block.stepped_kinds(ctx_kv)}
-                         if block is ssm_moe else {})
+            # a block that counts: one more row carries the round's
+            # counters (llama.stats_layout) home in the same fetch
+            rides = bool(llama.stats_layout(c))
+            toks_out = jnp.zeros((n_steps + int(rides), B), jnp.int32)
+            stats = llama.stats_zero(c)
+            # the region's leaves a decode STEP writes (recurrent state,
+            # rows a step completes) where its other rows are read-only
+            # until the flush: they ride the loop's carry ({} for a block
+            # whose step writes the ring only)
+            stepped = {n: ctx_kv[n] for n in llama.stepped_kinds(c, ctx_kv)}
             lp_out = (
                 jnp.zeros((n_steps, B, 1 + 2 * max_logprobs), jnp.float32)
                 if want_lp else None
@@ -878,29 +860,15 @@ class TpuEngine:
             # MoE models: freed/garbage lanes must not claim expert
             # capacity (and masking keeps outputs batch-independent)
             live = ((dev["dest"] != B)
-                    if c.moe is not None or routed else None)
+                    if c.moe is not None or rides else None)
 
             def body(s, carry):
-                ring, dev, toks_out, lp_out, moe_stats, recurrent = carry
-                if block is ssm_moe:
-                    ring, recurrent, logits, st = block.decode_step_impl(
-                        c, params, ctx_kv, ring, recurrent, dev["tokens"],
-                        dev["ctx"], ring_base, s, live,
-                        attn=self.decode_attn,
-                    )
-                    moe_stats = block.merge_stats(moe_stats, st)
-                elif routed:
-                    ring, logits, st = mla_moe.decode_step_impl(
-                        c, params, ctx_kv, ring, dev["tokens"], dev["ctx"],
-                        ring_base, s, live, attn=self.decode_attn,
-                    )
-                    moe_stats = mla_moe.merge_stats(moe_stats, st)
-                else:
-                    ring, logits = llama.decode_step_impl(
-                        c, params, ctx_kv, ring, dev["tokens"], dev["ctx"],
-                        ring_base, s, live, dev["adapter"],
-                        attn=self.decode_attn,
-                    )
+                ring, dev, toks_out, lp_out, stats, stepped = carry
+                ring, stepped, logits, stats = llama.round_step(
+                    c, params, ctx_kv, ring, stepped, dev["tokens"],
+                    dev["ctx"], ring_base, s, live, dev["adapter"], stats,
+                    attn=self.decode_attn,
+                )
                 if want_sample:
                     toks, st = sampling.sample_step_impl(
                         logits,
@@ -928,24 +896,22 @@ class TpuEngine:
                     keys=keys,
                     counts=counts,
                 )
-                return ring, dev, toks_out, lp_out, moe_stats, recurrent
+                return ring, dev, toks_out, lp_out, stats, stepped
 
-            (ring, dev, toks_out, lp_out, moe_stats,
-             recurrent) = jax.lax.fori_loop(
+            ring, dev, toks_out, lp_out, stats, stepped = jax.lax.fori_loop(
                 0, n_steps, body,
-                (ring, dev, toks_out, lp_out, moe_stats, recurrent)
+                (ring, dev, toks_out, lp_out, stats, stepped)
             )
-            if routed:
-                toks_out = toks_out.at[
-                    n_steps, :moe_stats.shape[0]].set(moe_stats)
+            if rides:
+                toks_out = toks_out.at[n_steps, :stats.shape[0]].set(stats)
             # round boundary: the ring goes into the ctx region, one
             # in-place span a lane, after every read (llama.flush_ctx_impl)
             valid = jnp.minimum(jnp.int32(n_steps), max_context - ring_base)
             ctx_kv = llama.flush_ctx_impl(
                 ctx_kv, ring, dev["dest"], ring_base, valid
             )
-            if recurrent:
-                ctx_kv = dict(ctx_kv, **recurrent)
+            if stepped:
+                ctx_kv = dict(ctx_kv, **stepped)
             return ctx_kv, ring, dev, toks_out, lp_out
 
         engine_round = functools.partial(
@@ -1261,23 +1227,12 @@ class TpuEngine:
     # page 0 (garbage by contract)
 
     def _refuse_row_only_planes(self, e: EngineConfig, on_dispatch,
-                                draft_config) -> None:
+                                draft_config, what: str) -> None:
         """Planes that know one row geometry (a K and a V of [kv_heads,
-        head_dim], addressable by position) refuse a latent-row model and
-        a model with recurrent layers at start-up, by name: none
-        reinterprets the row, and none snapshots a recurrent state."""
-        what = " beside ".join(
-            ["a latent (MLA) cache row"] * (self.config.mla is not None)
-            + ["a recurrent (state-space) state"]
-            * (self.config.hybrid is not None))
-        if self._sparse is not None:
-            what = ("a recurrent (linear-attention) state and "
-                    "compressed-key rows (kc)")
-            if e.page_size % self._sparse.block:
-                raise ValueError(
-                    f"page_size={e.page_size} is no multiple of the sparse "
-                    f"attention's block ({self._sparse.block}): a prefill "
-                    "chunk starts on a page and has to start on a block")
+        head_dim], addressable by position) refuse a model whose lanes
+        hold ``what`` (the block's own name for it: a latent row, a
+        recurrent state) at start-up, by name: none reinterprets the row,
+        and none snapshots a recurrent state."""
         planes = {
             "kv_quant=int8 (the int8 KV plane)": e.kv_quant != "none",
             "host/disk offload tiers and their kv_integrity frames "
@@ -1295,29 +1250,11 @@ class TpuEngine:
                 raise ValueError(
                     f"{plane} cannot carry {what} yet; "
                     "turn it off for this model")
-        need = self._stats_width
-        if e.max_decode_slots < need:
-            # the round's counters (the block's stats_zero) ride home in
-            # one more row of the stacked-token fetch, max_decode_slots
-            # wide
-            raise ValueError(
-                f"max_decode_slots={e.max_decode_slots}: this routed-expert "
-                f"model needs at least {need} (its counters ride the "
-                "round's token fetch in a row that wide)")
 
     def _refuse_latent_transfer(self) -> None:
-        if self.config.mla is not None:
-            raise ValueError(
-                "kv_transfer / disaggregation cannot carry a latent (MLA) "
-                "cache row yet: pages move as a K and a V")
-        if self.config.hybrid is not None:
-            raise ValueError(
-                "kv_transfer / disaggregation cannot carry a recurrent "
-                "(state-space or linear-attention) state"
-                + (" or compressed-key rows (kc)" if self._sparse else "")
-                + " yet: pages move K and V rows, and a "
-                "prompt cannot resume from rows without the state at "
-                "their boundary")
+        why = llama.transfer_refusal(self.config)
+        if why is not None:
+            raise ValueError(why)
 
     def _gather_padded(self, pages: list[int]):
         """Device gather of whole pages; returns DEVICE arrays
@@ -2490,10 +2427,10 @@ class TpuEngine:
             spec_slots=np.flatnonzero(self._slot_spec).tolist(),
             dispatch_ms=round((time.monotonic() - t_disp) * 1e3, 3),
         )
-        if self.config.mla is not None:
-            self._observe_decode_attn_rows(active, n)
-        if self._sparse is not None:
-            self._observe_sparse_rows(active, n)
+        if self._decode_mirror is not None:
+            live = np.zeros(self._B, bool)
+            live[active] = True
+            self._observe(self._decode_mirror(self._ctx_disp, live, n))
         # only dispatched lanes advance (spec slots track their own
         # lengths through verify processing)
         self._ctx_disp[active] = np.minimum(
@@ -3813,52 +3750,20 @@ class TpuEngine:
             width, q_starts, seq_lens, ctx_span)
         self._h_pf_live.observe(live)
         self._h_pf_scored.observe(scored)
-        if self._sparse is not None:
-            # sparse layers score that whole causal context under the
-            # selection's mask: what a gathering prefill would score
-            self._h_sparse_scored.observe(self._sparse_layers * scored)
-            self._h_sparse_selected.observe(
-                self._sparse_layers * sparse_attention.prefill_pairs(
-                    self._sparse, q_starts, seq_lens, width))
+        if self._prefill_mirror is not None:
+            self._observe(self._prefill_mirror(
+                width, q_starts, seq_lens, scored))
         # and the prompt positions it computed in CONTINUING chunks
         self._h_pf_continued.observe(sum(
             min(max(int(n) - int(q), 0), width)
             for q, n in zip(q_starts, seq_lens) if int(q) > 0))
 
-    def _observe_sparse_rows(self, active, n_steps: int) -> None:
-        """One observation per dispatched round of the rows its sparse
-        attention layers read and the rows a dense read of the live lanes
-        would take, all such layers and steps (the host's mirror,
-        ``sparse_attention.decode_rows``; the lanes' lengths move on by
-        one a step)."""
-        live = np.zeros(self._B, bool)
-        live[active] = True
-        read = rows = 0
-        for s in range(n_steps):
-            a, b = sparse_attention.decode_rows(
-                self._sparse, self._ctx_disp + s, live,
-                self.ecfg.max_context, self.ecfg.flush_every)
-            read, rows = read + a, rows + b
-        self._h_sparse_read.observe(self._sparse_layers * read)
-        self._h_sparse_live.observe(self._sparse_layers * rows)
-
-    def _observe_decode_attn_rows(self, active, n_steps: int) -> None:
-        """One observation per dispatched round of the region rows its
-        latent decode attention read and the rows that were some lane's
-        own — the host's mirror of latent_decode_attention's trip counts
-        (``latent_decode.region_trips``, which its wrapper calls too):
-        each dispatched lane's rows in whole chunks under the kernel,
-        every lane to the longest of them under the XLA loop (the
-        region's rows lie below the round's ring base, ctx - 1)."""
-        base = np.maximum(self._ctx_disp - 1, 0)
-        live = np.zeros(self._B, bool)
-        live[active] = True
-        attn = self.decode_attn
-        cb = latent_decode.chunk_rows(self.ecfg.max_context, attn.chunk)
-        trips = latent_decode.region_trips(base, live, cb)
-        self._h_attn_rows_read.observe(
-            n_steps * latent_decode.region_rows_read(attn.impl, trips, cb))
-        self._h_attn_rows_live.observe(n_steps * int(base[active].sum()))
+    def _observe(self, pairs) -> None:
+        """What a block's host mirror returned, (metric, value) pairs,
+        each into the histogram it names (llama.decode_mirror,
+        llama.prefill_mirror)."""
+        for metric, value in pairs:
+            self.telemetry.get(metric).observe(value)
 
     def _free_slot(self) -> Optional[int]:
         for i, s in enumerate(self._slots):
@@ -4375,28 +4280,12 @@ class TpuEngine:
         lp_arrs = None
         if entry.lp_handle is not None:
             lp_arrs = self._unpack_lp(np.asarray(entry.lp_handle))
-        if self._routes:
-            touched, routed, load_max = toks[entry.n_steps, :3]
-            self._h_moe_touched.observe(int(touched))
-            self._h_moe_routed.observe(int(routed))
-            self._h_moe_load_max.observe(int(load_max))
-            if self.config.hybrid is not None:
-                # a share of the experts is held: ``routed`` counted the
-                # picks that landed on it, this all the picks the router made
-                self._h_moe_picks_routed.observe(
-                    int(toks[entry.n_steps, 3]))
-                if "groups_kept" in self._stats_at:
-                    self._h_moe_groups_kept.observe(int(
-                        toks[entry.n_steps, self._stats_at["groups_kept"]]))
-            if self.config.hc is not None:
-                # the fourth counter is a float32's bits
-                self._h_hc_residual.observe(float(
-                    toks[entry.n_steps, 3:4].astype(np.int32).view(
-                        np.float32)[0]))
-        if "kda_stepped" in self._stats_at:
-            # counted by the program: the states its work lists held
-            self._h_kda_rows.observe(int(
-                toks[entry.n_steps, self._stats_at["kda_stepped"]]))
+        for col, hist, f32_bits in self._stats_table:
+            # the block's declared counter row (llama.stats_layout): one
+            # more row of the fetch, behind the steps' tokens
+            v = toks[entry.n_steps, col:col + 1]
+            hist.observe(float(v.astype(np.int32).view(np.float32)[0])
+                         if f32_bits else int(v[0]))
         delivered = 0
         for slot, r in enumerate(entry.slots):
             # identity check doubles as the epoch: a recycled slot holds

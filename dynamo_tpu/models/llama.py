@@ -37,23 +37,83 @@ Design notes (TPU-first, round-4 layout):
     the row blocks that hold a prompt token (prefill_attention,
     _live_rows), with trip counts that are values of the one program.
 
-THE SEAM (ROADMAP D2). These names are what the engine and the
-benchmark's launcher call. Three blocks hang on them (``block_of``): a
-``ModelConfig`` with ``mla`` set is the latent-attention + routed-expert
-block of models/mla_moe.py, one with ``hybrid`` set the state-space +
-attention hybrid of models/ssm_moe.py, and the parameter, state and
-prefill functions below hand over to that module.
-The movers (flush_ctx, seal_blocks, load_ctx_pages) carry whatever ROW
-KINDS a region holds (``row_kinds``: ``k`` and ``v`` of [kvh, hd] here
-and in the hybrid, one ``kv`` row in the latent block) and pass over a
-region's RECURRENT leaves (``state_kinds``: the hybrid's per-lane SSM
-state and convolution window, which are no rows). The decode step
-has ONE entry per block: ``decode_step_impl`` here, and each module's
-own ``decode_step_impl`` (which also returns the routing counters; the
-hybrid's takes and returns the recurrent state); the engine's round
-picks. Functions of planes that cannot carry a latent row or a recurrent
-state (speculation, sequence-parallel prefill, embeddings, page
-transfer) refuse it by name (``_dense_only``).
+THE SEAM (ROADMAP D2): ONE FRONT DOOR, AND WHAT A BLOCK IS. The engine,
+``spec/``, ``engine/multihost.py`` and the benchmark's launcher call the
+names of THIS module and no other of ``models/``. A BLOCK builds one
+family of decoder: the dense one of this file, the latent-attention +
+routed-expert block (models/mla_moe.py, a ``ModelConfig`` with ``mla``
+set), the state-space / linear-attention hybrid (models/ssm_moe.py,
+``hybrid`` set). ``block_of(config)`` names the module (None: the dense
+decoder), and every name marked ``@_hands_over`` below runs the block's
+function of the SAME name and signature; the dense decoder's is the
+marked function's own body. That is the whole mechanism, and the list
+of marked names is the whole protocol (``PROTOCOL``, held by
+tests/test_model_seam.py). A new block is a module with these names and
+one line in ``block_of``; no line of engine/engine.py.
+
+  parameters and state (``c`` a ModelConfig, first everywhere)
+    init_params(c, rng=0)                          -> params
+    param_shardings(c, mesh)                       -> shardings of params
+    init_cache(c, num_pages, page_size, dtype=None, kv_quant="none")
+    cache_shardings(c, mesh, kv_quant="none")      the pool: ROW leaves only
+    init_ctx(c, batch, ctx_len, dtype=None, kv_quant="none", group=128)
+    ctx_shardings(c, mesh, kv_quant="none")        the region: rows + state
+    init_ring(c, batch, ring_len, dtype=None)
+    ring_shardings(c, mesh)
+    stepped_kinds(c, state) -> names               the region's leaves a
+        decode STEP writes (recurrent state, rows a step completes): they
+        ride the round's carry, the other leaves are read-only until the
+        round's flush. () where a step writes the ring only.
+  programs
+    prefill_impl(c, params, ctx_kv, tokens, slot, q_start, seq_len,
+                 embeds=None, embeds_mask=None, adapter_id=None,
+                 fresh=False)           -> (ctx_kv, logits[, rows_moved])
+    batch_prefill_impl(c, params, ctx_kv, tokens, slots, q_starts,
+                       seq_lens, ctx_span=0, adapter_ids=None)
+                                        -> (ctx_kv, logits[, rows_moved])
+        (the front door's ``prefill_impl`` / ``batch_prefill_impl`` add
+        ``counted=`` and drop the third result unless asked)
+    round_step(c, params, ctx_kv, ring, stepped, tokens, ctx_lens,
+               ring_base, s, live, adapter_ids, stats, *, attn)
+                                        -> (ring, stepped, logits, stats)
+        ONE decode step of the engine's round, one signature for every
+        block: ``stepped`` = {name: ctx_kv[name] for stepped_kinds},
+        moved on by one position; ``stats`` the round's counter row so
+        far, returned with this step's counters merged in.
+  the round's counter row
+    stats_layout(c) -> (Counter, ...)   the row's columns in order, each
+        by the telemetry/metrics.py histogram it feeds (None: a column
+        the program carries and nothing reads) and whether the column is
+        an int or a float32's bits. () = the block counts nothing and no
+        row rides the round's token fetch.
+    stats_zero(c) -> int32 [len(stats_layout(c))]  the row before a step
+        (the dense decoder's is three wide under an empty layout: the
+        dead carry its round programs have always held, kept because a
+        new shape would move the dense cells' program text).
+  what the state can and cannot do (asked once, at engine start)
+    state_called(c) -> str | None       what a plane that moves K and V
+        rows only is told it cannot carry; None: it can carry all of it.
+    transfer_refusal(c) -> str | None   why pages of this state cannot
+        move between workers; None: they can.
+    page_multiple(c) -> int             positions a page must be a
+        multiple of (a prefill chunk starts on a page).
+    pages_resume(c) -> bool             whether sealed pages of rows are
+        all a prompt needs to resume (the prefix cache's premise).
+  the host's mirrors of what the kernels read (None: the block has none)
+    decode_mirror(c, max_context, ring_len, attn)
+        -> f(ctx_lens, live, n_steps) -> ((metric, value), ...)
+    prefill_mirror(c)
+        -> f(width, q_starts, seq_lens, scored) -> ((metric, value), ...)
+  and two live-row rules that are the front door's own
+  (``live_row_block``, ``moe_prefill_rows_sorted``).
+
+The movers (flush_ctx, seal_blocks, load_ctx_pages) are no part of it:
+they carry whatever ROW KINDS a region holds (``row_kinds``: ``k`` and
+``v`` of [kvh, hd] here and in the hybrid, one ``kv`` row in the latent
+block) and pass over a region's RECURRENT leaves (``state_kinds``).
+Functions of planes that cannot carry a latent row or a recurrent state
+(speculation, sequence-parallel prefill, embeddings, page transfer, the
+dense ``decode_step``) refuse it by name (``_dense_only``).
 
 Parity: this is the TPU engine the reference delegates to vLLM for
 (launch/dynamo-run subprocess engines; SURVEY.md §2.1 L3).
@@ -61,7 +121,8 @@ Parity: this is the TPU engine the reference delegates to vLLM for
 from __future__ import annotations
 
 import functools
-from typing import Any, Optional
+import inspect
+from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -83,6 +144,7 @@ from dynamo_tpu.ops.attention import (
     prefill_attention,
 )
 from dynamo_tpu.ops.rope import apply_rope, rope_cos_sin, rope_inv_freq
+from dynamo_tpu.telemetry.metrics import Counter
 
 Params = dict[str, Any]
 Cache = dict[str, jnp.ndarray]
@@ -90,13 +152,36 @@ Cache = dict[str, jnp.ndarray]
 
 def block_of(config: ModelConfig):
     """The module that builds ``config``'s block where it is not the
-    dense decoder of this file (None): every name below hands over to
-    it."""
+    dense decoder of this file (None): every ``@_hands_over`` name below
+    hands over to it."""
     if config.hybrid is not None:   # which may borrow the latent block's
         return ssm_moe              # attention (``mla`` set beside it)
     if config.mla is not None:
         return mla_moe
     return None
+
+
+# the block protocol: name -> the front door's signature (the module doc
+# has it in words); filled by ``_hands_over``
+PROTOCOL: dict[str, inspect.Signature] = {}
+
+
+def _hands_over(door: Callable) -> Callable:
+    """Mark ``door`` a name of the block protocol: called with a config
+    another block builds, it is that module's function of the same name,
+    with the arguments as given; else its own body, the dense decoder's.
+    A leading underscore is no part of the name (a front-door function
+    that adds to the protocol's hands over through a private twin)."""
+    name = door.__name__.lstrip("_")
+    PROTOCOL[name] = inspect.signature(door)
+
+    @functools.wraps(door)
+    def front(config, *args, **kwargs):
+        block = block_of(config)
+        if block is None:
+            return door(config, *args, **kwargs)
+        return getattr(block, name)(config, *args, **kwargs)
+    return front
 
 
 def row_kinds(state: Cache) -> tuple[str, ...]:
@@ -145,8 +230,71 @@ def _dense_only(config_or_state, plane: str) -> None:
 
 
 # ---------------------------------------------------------------------------
+# What the engine asks of a block's state and counters (the block protocol)
+
+@_hands_over
+def stats_layout(config: ModelConfig) -> tuple[Counter, ...]:
+    """The columns of a round's counter row, in order. The dense decoder
+    counts nothing: no row rides its round's token fetch."""
+    return ()
+
+
+@_hands_over
+def stats_zero(config: ModelConfig) -> jnp.ndarray:
+    """A round's counter row before any step. The dense decoder's is the
+    three-wide zero its round has always carried and never written (a
+    dead carry of the loop: another shape would move the program text of
+    every dense round)."""
+    return jnp.zeros(3, jnp.int32)
+
+
+@_hands_over
+def state_called(config: ModelConfig) -> Optional[str]:
+    """What a plane that moves a K and a V row is told it cannot carry,
+    or None where K and V rows are all a lane holds (here)."""
+    return None
+
+
+@_hands_over
+def transfer_refusal(config: ModelConfig) -> Optional[str]:
+    """Why pages of this block's state cannot move between workers, or
+    None where they can (here: pages move as a K and a V)."""
+    return None
+
+
+@_hands_over
+def page_multiple(config: ModelConfig) -> int:
+    """Positions a page has to be a multiple of."""
+    return 1
+
+
+@_hands_over
+def pages_resume(config: ModelConfig) -> bool:
+    """Whether sealed pages of rows are all a prompt needs to resume."""
+    return True
+
+
+@_hands_over
+def decode_mirror(config: ModelConfig, max_context: int, ring_len: int,
+                  attn: DecodeAttention) -> Optional[Callable]:
+    """The host's mirror of what a dispatched round's decode attention
+    reads: ``f(ctx_lens [B], live [B] bool, n_steps) -> ((metric, value),
+    ...)`` to observe, or None where nothing is mirrored (here)."""
+    return None
+
+
+@_hands_over
+def prefill_mirror(config: ModelConfig) -> Optional[Callable]:
+    """The host's mirror of what a prefill dispatch's attention layers
+    score beyond ``prefill_attention_pairs``: ``f(width, q_starts,
+    seq_lens, scored) -> ((metric, value), ...)``, or None (here)."""
+    return None
+
+
+# ---------------------------------------------------------------------------
 # Parameters
 
+@_hands_over
 def init_params(config: ModelConfig, rng: jax.Array | int = 0) -> Params:
     """Random-init parameters (bf16, or w8a16 when config.quant="int8").
     Weight values only matter for quality, not performance, so benchmarks
@@ -156,8 +304,6 @@ def init_params(config: ModelConfig, rng: jax.Array | int = 0) -> Params:
     constant per-channel scale matched to the dense init's std) — an 8B's
     dense weights can never be materialized on a 16 GB chip, so there is
     no dense-then-quantize step here."""
-    if block_of(config) is not None:
-        return block_of(config).init_params(config, rng)
     if isinstance(rng, int):
         rng = jax.random.PRNGKey(rng)
     c = config
@@ -208,13 +354,12 @@ def init_params(config: ModelConfig, rng: jax.Array | int = 0) -> Params:
     return params
 
 
+@_hands_over
 def param_shardings(config: ModelConfig, mesh: Mesh) -> Params:
     """NamedSharding pytree: Megatron-style TP over the `tp` mesh axis.
     qkv/gate/up shard the output (head/hidden) dim; o/down shard the input
     dim; embedding + lm_head shard the vocab dim. Quantized leaves get the
     weight's spec on "q" and the spec minus the reduced axis on "s"."""
-    if block_of(config) is not None:
-        return block_of(config).param_shardings(config, mesh)
     quant8 = config.quant == "int8"
 
     def ns(*spec):
@@ -262,6 +407,7 @@ def param_shardings(config: ModelConfig, mesh: Mesh) -> Params:
 # ---------------------------------------------------------------------------
 # KV cache
 
+@_hands_over
 def init_cache(
     config: ModelConfig, num_pages: int, page_size: int, dtype=None,
     kv_quant: str = "none",
@@ -276,9 +422,6 @@ def init_cache(
     untouched: quantize fuses into seal_blocks (ctx->pool), dequantize
     into load_ctx_pages (pool->ctx)."""
     c = config
-    if block_of(c) is not None:
-        return block_of(c).init_cache(c, num_pages, page_size, dtype,
-                                      kv_quant)
     shape = (c.num_layers, c.num_kv_heads, num_pages, page_size, c.head_dim)
     if kv_quant == "int8":
         return {
@@ -291,11 +434,10 @@ def init_cache(
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
+@_hands_over
 def cache_shardings(
     config: ModelConfig, mesh: Mesh, kv_quant: str = "none"
 ) -> Cache:
-    if block_of(config) is not None:
-        return block_of(config).row_shardings(config, mesh, kv_quant)
     s = NamedSharding(mesh, P(None, "tp", None, None, None))
     out = {"k": s, "v": s}
     if kv_quant == "int8":
@@ -310,6 +452,7 @@ def cache_is_quantized(cache: Cache) -> bool:
     return "k_scale" in cache
 
 
+@_hands_over
 def init_ctx(
     config: ModelConfig, batch: int, ctx_len: int, dtype=None,
     kv_quant: str = "none", group: int = 128,
@@ -327,9 +470,6 @@ def init_ctx(
     S is padded up to a multiple of it (the engine's max_context is
     already page-aligned, so no padding in practice)."""
     c = config
-    if block_of(c) is not None:
-        return block_of(c).init_ctx(c, batch, ctx_len, dtype, kv_quant,
-                                    group)
     shape = (c.num_layers, c.num_kv_heads, batch + 1, ctx_len, c.head_dim)
     if kv_quant == "int8":
         S = -(-ctx_len // group) * group
@@ -346,10 +486,9 @@ def init_ctx(
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
+@_hands_over
 def ctx_shardings(config: ModelConfig, mesh: Mesh,
                   kv_quant: str = "none") -> Cache:
-    if block_of(config) is not None:
-        return block_of(config).ctx_shardings(config, mesh, kv_quant)
     s = NamedSharding(mesh, P(None, "tp", None, None, None))
     out = {"k": s, "v": s}
     if kv_quant == "int8":
@@ -447,6 +586,7 @@ def _quant_store_span(
     return flat.reshape(L, kvh, lanes, S, hd), scale
 
 
+@_hands_over
 def init_ring(
     config: ModelConfig, batch: int, ring_len: int, dtype=None
 ) -> Cache:
@@ -461,18 +601,22 @@ def init_ring(
     holds the token at position ``ring_base[b] + r``.
     """
     c = config
-    if block_of(c) is not None:
-        return block_of(c).init_ring(c, batch, ring_len, dtype)
     dtype = dtype or jnp.dtype(c.dtype)
     shape = (c.num_layers, c.num_kv_heads, batch, ring_len, c.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
+@_hands_over
 def ring_shardings(config: ModelConfig, mesh: Mesh) -> Cache:
-    if block_of(config) is not None:
-        return block_of(config).ring_shardings(config, mesh)
     s = NamedSharding(mesh, P(None, "tp", None, None, None))
     return {"k": s, "v": s}
+
+
+@_hands_over
+def stepped_kinds(config: ModelConfig, state: Cache) -> tuple[str, ...]:
+    """A region's leaves a decode STEP writes (they ride the round's
+    carry). The dense step writes the ring only."""
+    return ()
 
 
 # ---------------------------------------------------------------------------
@@ -869,12 +1013,18 @@ def prefill_impl(
     dynamic_update_slice silently clamps under jit): q_start+T must fit the
     region.
     """
+    out = _prefill_impl(config, params, ctx_kv, tokens, slot, q_start,
+                        seq_len, embeds, embeds_mask, adapter_id, fresh)
+    return out if counted else out[:2]
+
+
+@_hands_over
+def _prefill_impl(config, params, ctx_kv, tokens, slot, q_start, seq_len,
+                  embeds=None, embeds_mask=None, adapter_id=None,
+                  fresh=False):
+    """``prefill_impl`` as the block protocol has it: the dense decoder's
+    chunk, (ctx_kv, logits)."""
     c = config
-    if block_of(c) is not None:
-        out = block_of(c).prefill_impl(
-            c, params, ctx_kv, tokens, slot, q_start, seq_len, embeds,
-            embeds_mask, adapter_id, fresh)
-        return out if counted else out[:2]
     T = tokens.shape[0]
     inv_freq = jnp.asarray(
         rope_inv_freq(c.head_dim, c.rope_theta, c.rope_scaling_dict)
@@ -1140,11 +1290,16 @@ def batch_prefill_impl(
     scratch lane (batch index B) with seq_len=0 — ffn_valid masks their
     tokens out of MoE routing and their region writes hit scratch.
     """
-    if block_of(config) is not None:
-        out = block_of(config).batch_prefill_impl(
-            config, params, ctx_kv, tokens, slots, q_starts, seq_lens,
-            ctx_span, adapter_ids)
-        return out if counted else out[:2]
+    out = _batch_prefill_impl(config, params, ctx_kv, tokens, slots,
+                              q_starts, seq_lens, ctx_span, adapter_ids)
+    return out if counted else out[:2]
+
+
+@_hands_over
+def _batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
+                        seq_lens, ctx_span=0, adapter_ids=None):
+    """``batch_prefill_impl`` as the block protocol has it: the dense
+    decoder's K chunks, (ctx_kv, logits)."""
     ks, vs, h = _batch_forward(
         config, params, ctx_kv, tokens, slots, q_starts, seq_lens, ctx_span,
         adapter_ids,
@@ -1435,6 +1590,20 @@ decode_step = jax.jit(
     decode_step_impl, static_argnums=(0,), static_argnames=("attn",),
     donate_argnums=(3,),
 )
+
+
+@_hands_over
+def round_step(config, params, ctx_kv, ring, stepped, tokens, ctx_lens,
+               ring_base, s, live, adapter_ids, stats, *,
+               attn: DecodeAttention):
+    """One decode step of the engine's round, under the one signature
+    every block has (the module doc): (ring, stepped, logits, stats). The
+    dense decoder steps no leaf of the region and counts nothing:
+    ``stepped`` and ``stats`` come back as they were given."""
+    ring, logits = decode_step_impl(
+        config, params, ctx_kv, ring, tokens, ctx_lens, ring_base, s, live,
+        adapter_ids, attn=attn)
+    return ring, stepped, logits, stats
 
 
 def flush_ctx_impl(

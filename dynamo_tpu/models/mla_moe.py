@@ -76,8 +76,18 @@ from dynamo_tpu.ops.attention import (
     PriorContext,
     prefill_attention,
 )
+from dynamo_tpu.ops import latent_decode
 from dynamo_tpu.ops.latent_decode import latent_decode_attention
 from dynamo_tpu.ops.rope import rope_inv_freq, yarn_inv_freq, yarn_mscale
+from dynamo_tpu.telemetry.metrics import (
+    DECODE_ATTN_ROWS_LIVE,
+    DECODE_ATTN_ROWS_READ,
+    HC_SINKHORN_RESIDUAL,
+    MOE_LOAD_MAX,
+    MOE_ROUTED,
+    MOE_TOUCHED,
+    Counter,
+)
 
 Params = dict[str, Any]
 Cache = dict[str, jnp.ndarray]
@@ -246,14 +256,56 @@ def init_ring(config, batch, ring_len, dtype=None):
     return _rows(config, batch, ring_len, dtype)
 
 
-def row_shardings(config: ModelConfig, mesh: Mesh,
-                  kv_quant: str = "none") -> Cache:
+def cache_shardings(config: ModelConfig, mesh: Mesh,
+                    kv_quant: str = "none") -> Cache:
     _refuse_quant(kv_quant)
     return {ROW: NamedSharding(mesh, P(None, None, None, None, None))}
 
 
-ctx_shardings = row_shardings   # the region holds rows only
-ring_shardings = row_shardings  # and the ring the same kind
+ctx_shardings = cache_shardings   # the region holds rows only
+
+
+def ring_shardings(config: ModelConfig, mesh: Mesh) -> Cache:
+    return cache_shardings(config, mesh)   # and the ring the same kind
+
+
+def stepped_kinds(config: ModelConfig, state: Cache) -> tuple[str, ...]:
+    return ()   # a decode step writes the ring only
+
+
+# What the engine is told of this state (llama.py: the block protocol)
+
+def state_called(config: ModelConfig) -> str:
+    return "a latent (MLA) cache row"
+
+
+def transfer_refusal(config: ModelConfig) -> str:
+    return ("kv_transfer / disaggregation cannot carry a latent (MLA) "
+            "cache row yet: pages move as a K and a V")
+
+
+def page_multiple(config: ModelConfig) -> int:
+    return 1
+
+
+def pages_resume(config: ModelConfig) -> bool:
+    return True   # a page of latent rows is all a prompt's prefix holds
+
+
+def decode_mirror(config: ModelConfig, max_context: int, ring_len: int,
+                  attn: DecodeAttention):
+    """The host's mirror of the latent decode attention's reads, a layer
+    (``latent_decode.round_rows``), by the two histograms they feed."""
+    def mirror(ctx_lens, live, n_steps: int):
+        read, own = latent_decode.round_rows(attn, ctx_lens, live, n_steps,
+                                             max_context)
+        return ((DECODE_ATTN_ROWS_READ[0], read),
+                (DECODE_ATTN_ROWS_LIVE[0], own))
+    return mirror
+
+
+def prefill_mirror(config: ModelConfig):
+    return None   # prefill_attention_pairs is all its prefill scores
 
 
 # ---------------------------------------------------------------------------
@@ -360,10 +412,6 @@ def _mlp(x, wg, wu, wd):
     return (jax.nn.silu(x @ wg) * (x @ wu)) @ wd
 
 
-def routes(c: ModelConfig) -> bool:
-    return True   # every round's counter row carries routing counters
-
-
 def route(c: ModelConfig, ep, x):
     """The published router: sigmoid scores in float32, top k of scores +
     bias, weights from the scores alone, normalised and scaled."""
@@ -389,12 +437,20 @@ def expert_ffn(c: ModelConfig, ep, x, valid=None):
     return y, load
 
 
+def stats_layout(c: ModelConfig) -> tuple[Counter, ...]:
+    """A step's counters, in order: experts touched, tokens routed, most
+    tokens on one expert, and with hyper-connections a fourth, the last
+    layer's Sinkhorn residual (hc.row_sum_residual) as the bits of a
+    float32 (not negative, so the bits order as it does)."""
+    return ((Counter(MOE_TOUCHED[0]), Counter(MOE_ROUTED[0]),
+             Counter(MOE_LOAD_MAX[0]))
+            + ((Counter(HC_SINKHORN_RESIDUAL[0], f32_bits=True),)
+               if c.hc is not None else ()))
+
+
 def stats_zero(c: ModelConfig):
-    """A step's counters before any layer: [experts touched, tokens
-    routed, most tokens on one expert], and with hyper-connections a
-    fourth, the last layer's Sinkhorn residual (hc.row_sum_residual) as
-    the bits of a float32 (not negative, so the bits order as it does)."""
-    return jnp.zeros(3 if c.hc is None else 4, jnp.int32)
+    """A step's counters (``stats_layout``) before any layer."""
+    return jnp.zeros(len(stats_layout(c)), jnp.int32)
 
 
 def merge_stats(a, b):
@@ -663,3 +719,15 @@ def decode_step_impl(config, params, ctx_kv, ring, tokens, ctx_lens,
             o = _unabsorb_o(c, lp, o_lat)
         h, stats = _layer_out(c, params, lp, l, h, o, mix, live, stats)
     return {ROW: buf}, _logits(c, params, h), stats
+
+
+def round_step(config, params, ctx_kv, ring, stepped, tokens, ctx_lens,
+               ring_base, s, live, adapter_ids, stats, *,
+               attn: DecodeAttention):
+    """``decode_step_impl`` under the round's one signature (llama.py: the
+    block protocol): no leaf of the region is stepped, and the step's
+    counters come back merged into the round's."""
+    ring, logits, st = decode_step_impl(
+        config, params, ctx_kv, ring, tokens, ctx_lens, ring_base, s, live,
+        attn=attn)
+    return ring, stepped, logits, merge_stats(stats, st)

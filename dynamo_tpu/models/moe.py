@@ -188,7 +188,7 @@ def _build_moe(mesh: Mesh, axis: str, cfg: MoEConfig, n: int, Tl: int):
 # function, bias, scale) is the caller's: this takes the picks and their
 # combine weights.
 
-GMM_ROWS = 128   # rows per tile of the TPU kernel (tools/moe_gmm_bench.py)
+GMM_ROWS = 128   # rows per tile of the TPU kernel
 # the most one weight tile may hold: the kernel double-buffers it inside
 # the 16 MiB of VMEM a Mosaic call gets by default, beside the row and
 # output tiles. 2048 x 768 (3 MiB) fits whole; 3584 x 1024 (7 MiB) does

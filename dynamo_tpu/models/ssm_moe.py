@@ -128,6 +128,19 @@ from dynamo_tpu.ops.attention import (
 )
 from dynamo_tpu.ops.latent_decode import latent_decode_attention
 from dynamo_tpu.ops.rope import apply_rope, rope_cos_sin, rope_inv_freq
+from dynamo_tpu.telemetry.metrics import (
+    KDA_STATE_ROWS_STEPPED,
+    MOE_GROUPS_KEPT_HERE,
+    MOE_LOAD_MAX,
+    MOE_PICKS_ROUTED,
+    MOE_ROUTED,
+    MOE_TOUCHED,
+    SPARSE_ATTN_ROWS_LIVE,
+    SPARSE_ATTN_ROWS_READ,
+    SPARSE_PREFILL_SCORED,
+    SPARSE_PREFILL_SELECTED,
+    Counter,
+)
 
 Params = dict[str, Any]
 Cache = dict[str, Any]
@@ -440,8 +453,8 @@ def init_ring(config, batch, ring_len, dtype=None):
                  compressed=False)
 
 
-def row_shardings(config: ModelConfig, mesh: Mesh,
-                  kv_quant: str = "none") -> Cache:
+def cache_shardings(config: ModelConfig, mesh: Mesh,
+                    kv_quant: str = "none") -> Cache:
     _refuse_quant(kv_quant)
     s = NamedSharding(mesh, P(None, None, None, None, None))
     if dims(config)["n_latent"]:
@@ -453,11 +466,11 @@ def row_shardings(config: ModelConfig, mesh: Mesh,
 
 
 def ring_shardings(config: ModelConfig, mesh: Mesh) -> Cache:
-    rows = row_shardings(config, mesh)
+    rows = cache_shardings(config, mesh)
     return {n: s for n, s in rows.items() if n != KC}   # init_ring's kinds
 
 
-def stepped_kinds(state: Cache) -> tuple[str, ...]:
+def stepped_kinds(config: ModelConfig, state: Cache) -> tuple[str, ...]:
     """A region's leaves that a decode STEP writes, where K and V are
     read-only until the round's flush: the recurrent leaves, and the
     compressed-key rows (a step that completes one writes it where it
@@ -468,7 +481,7 @@ def stepped_kinds(state: Cache) -> tuple[str, ...]:
 
 def ctx_shardings(config: ModelConfig, mesh: Mesh,
                   kv_quant: str = "none") -> Cache:
-    out = row_shardings(config, mesh, kv_quant)
+    out = cache_shardings(config, mesh, kv_quant)
     d = dims(config)
     if d["n_ssm"]:
         out[SSM] = [NamedSharding(mesh, P(None, None, None, None))] * d["n_ssm"]
@@ -524,16 +537,23 @@ def route(c: ModelConfig, lp, x):
         return sel, w, here.any(axis=1)
 
 
-def stats_layout(c: ModelConfig) -> tuple:
+KDA_STEPPED = Counter(KDA_STATE_ROWS_STEPPED[0])
+
+
+def stats_layout(c: ModelConfig) -> tuple[Counter, ...]:
     """What a step's counters hold, in order: held experts touched, picks
     that landed on a held expert, most tokens on one held expert, all
-    picks of routed tokens; under the grouped router, routed tokens that
-    kept a group held here; with delta-rule layers, the per-lane matrix
-    states their steps moved on (the live lanes', a layer)."""
+    picks of routed tokens (four columns every stack's row has; a stack
+    that routes nothing carries them at 0 and feeds no histogram); under
+    the grouped router, routed tokens that kept a group held here; with
+    delta-rule layers, the per-lane matrix states their steps moved on
+    (the live lanes', a layer)."""
     d = dims(c)
-    return (("touched", "held", "load_max", "picks")
-            + (("groups_kept",) if "groups" in d else ())
-            + (("kda_stepped",) if d["n_kda"] else ()))
+    router = [m[0] if routes(c) else None for m in (
+        MOE_TOUCHED, MOE_ROUTED, MOE_LOAD_MAX, MOE_PICKS_ROUTED)]
+    return (tuple(Counter(m) for m in router)
+            + ((Counter(MOE_GROUPS_KEPT_HERE[0]),) if "groups" in d else ())
+            + ((KDA_STEPPED,) if d["n_kda"] else ()))
 
 
 def stats_zero(c: ModelConfig):
@@ -1452,7 +1472,7 @@ def decode_step_impl(config, params, ctx_kv, ring, state, tokens, ctx_lens,
         # the delta-rule layers' work list, the same for all of them, and
         # what they step of it: the live lanes' states, a layer
         work = kda.work_list(live)
-        stats = stats.at[stats_layout(c).index("kda_stepped")].add(
+        stats = stats.at[stats_layout(c).index(KDA_STEPPED)].add(
             work[1][0] * d["n_kda"])
     for kind, lp in zip(d["kinds"], params["layers"]):
         x = _rms(h, lp["ln1"], c.rms_norm_eps)
@@ -1542,3 +1562,86 @@ def decode_step_impl(config, params, ctx_kv, ring, state, tokens, ctx_lens,
             j += 1
         h, stats = _layer_out(c, lp, h, mix, live, stats)
     return ring, state, _logits(c, params, h), stats
+
+
+def round_step(config, params, ctx_kv, ring, stepped, tokens, ctx_lens,
+               ring_base, s, live, adapter_ids, stats, *,
+               attn: DecodeAttention):
+    """``decode_step_impl`` under the round's one signature (llama.py: the
+    block protocol): the step's counters come back merged into the
+    round's."""
+    ring, stepped, logits, st = decode_step_impl(
+        config, params, ctx_kv, ring, stepped, tokens, ctx_lens, ring_base,
+        s, live, attn=attn)
+    return ring, stepped, logits, merge_stats(stats, st)
+
+
+# ---------------------------------------------------------------------------
+# What the engine is told of this state (llama.py: the block protocol)
+
+def state_called(config: ModelConfig) -> str:
+    if sparse_layers(config)[0] is not None:
+        return ("a recurrent (linear-attention) state and "
+                "compressed-key rows (kc)")
+    return " beside ".join(
+        ([mla_moe.state_called(config)] if config.mla is not None else [])
+        + ["a recurrent (state-space) state"])
+
+
+def transfer_refusal(config: ModelConfig) -> str:
+    if config.mla is not None:
+        return mla_moe.transfer_refusal(config)
+    return ("kv_transfer / disaggregation cannot carry a recurrent "
+            "(state-space or linear-attention) state"
+            + (" or compressed-key rows (kc)"
+               if sparse_layers(config)[0] is not None else "")
+            + " yet: pages move K and V rows, and a "
+            "prompt cannot resume from rows without the state at "
+            "their boundary")
+
+
+def page_multiple(config: ModelConfig) -> int:
+    """A prefill chunk starts on a page and has to start on a block of
+    the sparse attention's selection."""
+    sparse, _ = sparse_layers(config)
+    return sparse.block if sparse is not None else 1
+
+
+def pages_resume(config: ModelConfig) -> bool:
+    return False   # not without the recurrent state at their boundary
+
+
+def decode_mirror(config: ModelConfig, max_context: int, ring_len: int,
+                  attn: DecodeAttention):
+    """The host's mirrors of what a dispatched round's attention layers
+    read: the latent layers' (mla_moe's, a layer) or the sparse layers'
+    (``sparse_attention.round_rows``, all such layers); None for a stack
+    with neither."""
+    if dims(config)["n_latent"]:
+        return mla_moe.decode_mirror(config, max_context, ring_len, attn)
+    sparse, layers = sparse_layers(config)
+    if sparse is None:
+        return None
+
+    def mirror(ctx_lens, live, n_steps: int):
+        read, rows = sparse_attention.round_rows(
+            sparse, ctx_lens, live, n_steps, max_context, ring_len)
+        return ((SPARSE_ATTN_ROWS_READ[0], layers * read),
+                (SPARSE_ATTN_ROWS_LIVE[0], layers * rows))
+    return mirror
+
+
+def prefill_mirror(config: ModelConfig):
+    """The sparse layers score a chunk's whole causal context under the
+    selection's mask (``scored``, what ``prefill_attention_pairs`` counts
+    a layer): beside it, what a gathering prefill would score."""
+    sparse, layers = sparse_layers(config)
+    if sparse is None:
+        return None
+
+    def mirror(width: int, q_starts, seq_lens, scored: int):
+        return ((SPARSE_PREFILL_SCORED[0], layers * scored),
+                (SPARSE_PREFILL_SELECTED[0],
+                 layers * sparse_attention.prefill_pairs(
+                     sparse, q_starts, seq_lens, width)))
+    return mirror

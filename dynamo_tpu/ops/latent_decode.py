@@ -39,6 +39,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -75,6 +76,23 @@ def region_rows_read(impl: str, trips, cb: int) -> int:
     if impl == REFERENCE_IMPL:
         return len(trips) * int(trips.max(initial=0)) * cb
     return int(trips.sum()) * cb
+
+
+def round_rows(attn: DecodeAttention, ctx_lens, live, n_steps: int,
+               max_context: int) -> tuple[int, int]:
+    """The host's mirror of one dispatched round, a layer: (region rows
+    its steps' attention read, rows that were some live lane's own).
+    ``ctx_lens`` [B] the lanes' lengths at dispatch (the region's rows lie
+    below the round's ring base, ctx - 1), ``live`` [B] bool the lanes
+    dispatched: each one's rows in whole chunks under the kernel, every
+    lane to the longest of them under the XLA loop (``region_trips``,
+    which the attention's wrapper calls too)."""
+    base = np.maximum(np.asarray(ctx_lens) - 1, 0)
+    live = np.asarray(live, bool)
+    cb = chunk_rows(max_context, attn.chunk)
+    trips = region_trips(base, live, cb)
+    return (n_steps * region_rows_read(attn.impl, trips, cb),
+            n_steps * int(base[live].sum()))
 
 
 def _score(carry, q, rows, ok, v_width):

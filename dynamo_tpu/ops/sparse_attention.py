@@ -334,3 +334,14 @@ def decode_rows(g: Geometry, ctx_lens, live, region_rows: int,
         read += (region_rows // g.stride + g.topk * g.block + ring_rows
                  if n - 1 >= g.dense_len else n)
     return read, live_rows
+
+
+def round_rows(g: Geometry, ctx_lens, live, n_steps: int, region_rows: int,
+               ring_rows: int) -> tuple[int, int]:
+    """``decode_rows`` over the ``n_steps`` of one dispatched round, one
+    sparse layer: the lanes' lengths move on by one a step."""
+    read = rows = 0
+    for s in range(n_steps):
+        a, b = decode_rows(g, ctx_lens + s, live, region_rows, ring_rows)
+        read, rows = read + a, rows + b
+    return read, rows

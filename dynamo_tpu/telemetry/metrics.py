@@ -15,7 +15,7 @@ from __future__ import annotations
 import math
 import threading
 import time
-from typing import Any, Optional
+from typing import Any, NamedTuple, Optional
 
 import numpy as np
 
@@ -530,6 +530,15 @@ DECODE_ATTN_ROWS_LIVE = (
 KV_ROW_BYTES = ("dynamo_kv_row_bytes",
                 "bytes one token holds in the ctx region, all layers "
                 "(observed once, at engine start)")
+
+
+class Counter(NamedTuple):
+    """One column of the counter row a fused decode round brings home
+    (models/llama.py: ``stats_layout``): what a block's program counts,
+    tied to the histogram that takes it."""
+    metric: Optional[str]    # a name above; None: nothing reads the column
+    f32_bits: bool = False   # the int32 column holds a float32's bits
+
 
 # token-count series: powers of two up to a full 32k-position dispatch
 TOKEN_BUCKETS = tuple(float(2 ** i) for i in range(16))

@@ -4,44 +4,19 @@ ran its contract tests, its arithmetic self-tests or a rehearsal of a
 cell unless a builder did by hand. Each runs as the command a builder
 would type, in a child process (the rehearsal's server child takes the
 CPU "chip"; nothing here imports JAX), under a time limit of its own.
+
+The rehearsals of whole cells (``test_the_new_cell_rehearses_on_the_cpu``,
+one case a cell) live in ``tests/test_rehearsal_*.py``, two cells a file,
+over ``tests/rehearsal.py``: the driver's run gives a file to one worker,
+and six rehearsals in this file were 835 of its 863 s (PR 50).
 """
 import json
 import os
-import subprocess
 import sys
 
 import pytest
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-NEW_CELL = "mla-moe-joyai-d5.chat-decode"
-
-
-def run(cmd, limit_s, cwd=REPO):
-    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=cwd)
-    env.pop("XLA_FLAGS", None)   # the rehearsal sets its own device count
-    return subprocess.run(cmd, cwd=cwd, env=env, capture_output=True,
-                          text=True, timeout=limit_s)
-
-
-def checkout_at_rate(tmp_path, cell, rate_rps):
-    """A copy of what the benchmark reads (``BENCHMARK.json``,
-    ``benchmarks/``; the program linked, not copied) whose cell offers
-    ``rate_rps``: what ``tools/knee_sweep.py`` does to the chip machine's
-    copy. The CPU computes a 4096-token continuing chunk of the TINY model
-    in two seconds, so at a rate sized for the chip every stream of the
-    rehearsal would outlast the drain grace."""
-    import shutil
-
-    root = str(tmp_path / "checkout")
-    shutil.copytree(os.path.join(REPO, "benchmarks"),
-                    os.path.join(root, "benchmarks"))
-    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), root)
-    os.symlink(os.path.join(REPO, "dynamo_tpu"),
-               os.path.join(root, "dynamo_tpu"))
-    with open(os.path.join(root, "benchmarks", "cells", cell + ".json"),
-              "w") as f:
-        json.dump({"rate_rps": rate_rps}, f)
-    return root
+from tests.rehearsal import REPO, run
 
 
 def _benchmark_tests():
@@ -162,58 +137,6 @@ def test_carried_tok_s_reads_the_way_its_entry_says(monkeypatch, cell, entry,
     if cell == "mistral7b-w8.chat":
         contract.test_open_loop_under_the_knee_the_faster_server_reads_the_lower_tok_s()
         contract.test_carried_tok_s_reader_is_tok_s_less_the_windows_own_tokens()
-
-
-@pytest.mark.parametrize("cell,seed,reference,rate_rps", [
-    # 14.4 req/s is sized for the chip: ~170 streams of up to 512 tokens
-    # in 12 s. Alone the CPU holds that; under the six workers of the
-    # driver's run it did not (PR 40's run of the standing tree: streams
-    # outlasted the drain grace, ``failed`` > 0). A third of it, as the
-    # other rehearsals run at a rate the CPU holds
-    (NEW_CELL, "3100310031", "benchmarks/references/mla_moe.py", 4.0),
-    ("xing4-mhc-d7.longdoc", "3700370037",
-     "benchmarks/references/mla_moe_mhc.py", 0.34),
-    ("mistral7b-w8.longprompt", "3700370038", "benchmarks/reference.py",
-     None),
-    ("granite4h-ep2-d10.ragdoc", "4100410041",
-     "benchmarks/references/ssm_moe.py", 1.0),
-    # prompts of 8k-28k tokens: the CPU needs ~3 s a 4096-token chunk of
-    # the tiny model, so one request in the pre-roll and one in the window
-    ("minicpm-sala-d16.longctx", "4500450045",
-     "benchmarks/references/sala.py", 0.17),
-    # answers of 384-2048 tokens from up to 48 lanes: six requests in the
-    # 20 s pre-roll and two in the window are what the CPU drains in time
-    ("ling3-flash-ep8-d12.reasoning", "4700470047",
-     "benchmarks/references/kda_mla_moe.py", 0.3),
-], ids=["chat-decode", "longdoc", "longprompt", "ragdoc", "longctx",
-        "reasoning"])
-def test_the_new_cell_rehearses_on_the_cpu(tmp_path, cell, seed, reference,
-                                           rate_rps):
-    """A cell's files end to end at the dry-run widths: configuration,
-    reference by name, traffic mix, warm-up, window, result line; at the
-    cell's own rate, or where the CPU cannot hold that (above) at one it
-    can. The long-document cell's check and traffic run a fresh
-    4096-token chunk, continuing chunks and decode over a 16384-token
-    region here too, and its warm-up set has to leave the window nothing
-    to compile. The long-context cell's check crosses the toy model's
-    switch to the block selection (position 1024) in prefill and in
-    decode, over a 32768-token region with its compressed-key rows. The
-    reasoning cell's check carries the toy model's delta-rule state, its
-    convolution windows and its latent rows over a chunk boundary at 4096
-    and through 72 decode steps, under grouped routing with a share of
-    eight."""
-    root = REPO if rate_rps is None else checkout_at_rate(
-        tmp_path, cell, rate_rps)
-    r = run([sys.executable, "benchmarks/run.py", "--workload", cell,
-             "--seed", seed, "--seconds", "6", "--cpu-dry-run"], 900, root)
-    assert r.returncode == 0, (r.stdout + r.stderr)[-3000:]
-    line = json.loads(r.stdout.strip().splitlines()[-1])
-    assert line["dry_run"] is True and line["device"]["platform"] == "cpu"
-    assert line["correct"] is True and line["failed"] == 0, r.stdout[-3000:]
-    assert {"tpot_ms_p90", "setup_s"} <= set(line["metrics"])
-    notes = next(json.loads(l[len("notes: "):])
-                 for l in r.stdout.splitlines() if l.startswith("notes: "))
-    assert notes["check"]["reference"] == reference
 
 
 def _sweep_line(tok_s, offered, p50, p90, failed=0):

@@ -916,20 +916,21 @@ def test_decode_attn_rows_mirror_by_hand(impl, read):
     four lanes of a 2048-row region, two dispatched at 1300 and 512
     region rows (3 chunks and 1 of 512), four steps. The kernel reads the
     dispatched lanes' own chunks; the XLA loop every lane to the longest."""
+    from dynamo_tpu.models import llama
     from dynamo_tpu.ops.attention import DecodeAttention
 
-    class Seen(list):
-        observe = list.append
-
-    eng = TpuEngine.__new__(TpuEngine)
-    eng._B, eng.decode_attn = 4, DecodeAttention(impl)
-    eng.ecfg = EngineConfig(page_size=64, max_pages_per_seq=32)
-    assert latent_decode.CHUNK == 512 and eng.ecfg.max_context == 2048
-    eng._ctx_disp = np.array([1301, 1, 513, 2000], np.int32)
-    eng._h_attn_rows_read, eng._h_attn_rows_live = Seen(), Seen()
-    eng._observe_decode_attn_rows(np.array([0, 2]), 4)
-    assert eng._h_attn_rows_read == [read]
-    assert eng._h_attn_rows_live == [4 * (1300 + 512)]
+    ecfg = EngineConfig(page_size=64, max_pages_per_seq=32)
+    assert latent_decode.CHUNK == 512 and ecfg.max_context == 2048
+    # where the mirror lives now: the latent block's, through the front
+    # door (the engine asks once, at start-up, and observes the pairs)
+    mirror = llama.decode_mirror(ModelConfig.tiny_mla_moe(),
+                                 ecfg.max_context, ecfg.flush_every,
+                                 DecodeAttention(impl))
+    live = np.zeros(4, bool)
+    live[[0, 2]] = True
+    seen = dict(mirror(np.array([1301, 1, 513, 2000], np.int32), live, 4))
+    assert seen == {ROWS_READ: read,
+                    ROWS_LIVE: 4 * (1300 + 512)}
 
 
 def test_hc_scopes_are_in_the_lowered_step():

@@ -328,9 +328,9 @@ def test_a_lane_that_is_not_live_keeps_its_state_and_windows(setup):
     rng = np.random.RandomState(0)
     ctx = jax.tree.map(lambda a: jnp.asarray(rng.randn(*a.shape), a.dtype),
                        llama.init_ctx(cfg, B, 128, jnp.float32))
-    assert ssm_moe.stepped_kinds(ctx) == ("kda_conv_state", "kda_state")
+    assert ssm_moe.stepped_kinds(cfg, ctx) == ("kda_conv_state", "kda_state")
     assert llama.row_kinds(ctx) == ("kv",)
-    state = {n: ctx[n] for n in ssm_moe.stepped_kinds(ctx)}
+    state = {n: ctx[n] for n in ssm_moe.stepped_kinds(cfg, ctx)}
     live = jnp.asarray([True, False, True])
     i32 = lambda *v: jnp.asarray(v, jnp.int32)  # noqa: E731
     for attn in (REFERENCE, DecodeAttention(PALLAS_INTERPRET)):
@@ -354,7 +354,7 @@ def test_the_kernel_step_and_the_xla_step_give_one_decode(setup):
     ctx = jax.tree.map(
         lambda a: jnp.asarray(0.1 * rng.randn(*a.shape), a.dtype),
         llama.init_ctx(cfg, B, 64, jnp.float32))
-    state = {n: ctx[n] for n in ssm_moe.stepped_kinds(ctx)}
+    state = {n: ctx[n] for n in ssm_moe.stepped_kinds(cfg, ctx)}
     i32 = lambda *v: jnp.asarray(v, jnp.int32)  # noqa: E731
     out = [ssm_moe.decode_step_impl(
         cfg, params, ctx, llama.init_ring(cfg, B, 2, jnp.float32), state,

@@ -1,0 +1,194 @@
+"""The engine <-> model seam (ROADMAP D2): the engine, the multihost replay
+and spec/ reach the model layer through ``models/llama.py`` and nothing
+else of ``models/``, and the three blocks (the dense decoder of that file,
+models/mla_moe.py, models/ssm_moe.py) each meet the block protocol written
+at its head. CPU, no engine start: imports by ``ast``, signatures by
+``inspect``, the round's step by ``jax.eval_shape``.
+"""
+from __future__ import annotations
+
+import ast
+import glob
+import inspect
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from dynamo_tpu.models import llama
+from dynamo_tpu.models.config import ModelConfig
+from dynamo_tpu.ops.attention import REFERENCE
+from dynamo_tpu.telemetry import metrics as tmetrics
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ENGINE = "dynamo_tpu/engine/engine.py"
+CALLERS = [ENGINE, "dynamo_tpu/engine/multihost.py"] + sorted(
+    os.path.relpath(p, REPO)
+    for p in glob.glob(os.path.join(REPO, "dynamo_tpu/spec/*.py")))
+
+# one tiny configuration a block and mixer family
+FAMILIES = {
+    "dense": ModelConfig.tiny,
+    "latent": ModelConfig.tiny_mla_moe,
+    "latent_mhc": ModelConfig.tiny_mla_moe_mhc,
+    "mamba2": ModelConfig.tiny_ssm_moe,
+    "lightning_sparse": ModelConfig.tiny_linear_sparse,
+    "kda_latent": ModelConfig.tiny_kda_latent,
+}
+
+
+def _imported(path: str, package: str) -> set[str]:
+    """The submodules of ``package`` a file imports, at any depth of the
+    file (a function-level import counts)."""
+    with open(os.path.join(REPO, path)) as f:
+        tree = ast.parse(f.read())
+    found = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.ImportFrom) and node.module:
+            if node.module == package:
+                found.update(a.name for a in node.names)
+            elif node.module.startswith(package + "."):
+                found.add(node.module[len(package) + 1:].split(".")[0])
+        elif isinstance(node, ast.Import):
+            for a in node.names:
+                if a.name.startswith(package + "."):
+                    found.add(a.name[len(package) + 1:].split(".")[0])
+    return found
+
+
+@pytest.mark.parametrize("path", CALLERS)
+def test_callers_import_the_front_door_only(path):
+    assert _imported(path, "dynamo_tpu.models") <= {"llama", "config"}
+
+
+def test_the_engine_knows_no_block():
+    """No op of a block (its attention, its mirrors), no field of
+    ``ModelConfig`` that names one. ``ring_attention`` is the
+    sequence-parallel prefill plane's sharding helper, a plane of the
+    dense decoder and no block's op."""
+    assert _imported(ENGINE, "dynamo_tpu.ops") <= {"attention",
+                                                   "ring_attention"}
+    with open(os.path.join(REPO, ENGINE)) as f:
+        text = f.read()
+    read = {n.attr for n in ast.walk(ast.parse(text))
+            if isinstance(n, ast.Attribute)}
+    assert not read & {"mla", "hybrid", "hc", "_stats_at"}
+    for word in ("mla_moe", "ssm_moe", "latent_decode", "sparse_attention"):
+        assert word not in text, word
+
+
+def _arity(sig: inspect.Signature):
+    """(positional parameters, those of them without a default, the
+    keyword-only names) of a signature."""
+    ps = list(sig.parameters.values())
+    pos = [p for p in ps if p.kind in (p.POSITIONAL_ONLY,
+                                       p.POSITIONAL_OR_KEYWORD)]
+    return (len(pos), sum(p.default is p.empty for p in pos),
+            sorted(p.name for p in ps if p.kind == p.KEYWORD_ONLY))
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_the_block_meets_the_written_protocol(family):
+    c = FAMILIES[family]()
+    block = llama.block_of(c)
+    assert len(llama.PROTOCOL) == 20
+    for name, sig in llama.PROTOCOL.items():
+        # written once, in words, at the head of the front door
+        assert f"    {name}(c" in llama.__doc__, name
+        assert callable(getattr(llama, name))
+        if block is not None:   # (the dense decoder's is the door's body)
+            assert _arity(inspect.signature(getattr(block, name))) == \
+                _arity(sig), name
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_the_counter_row_is_declared(family):
+    c = FAMILIES[family]()
+    layout = llama.stats_layout(c)
+    zero = jax.eval_shape(lambda: llama.stats_zero(c))
+    assert zero.dtype == jnp.int32 and zero.ndim == 1
+    if family == "dense":
+        # counts nothing; its round's dead three-wide carry is the one
+        # exception to "as wide as the layout" (moving it moves the text
+        # of every dense round program)
+        assert layout == () and zero.shape == (3,)
+    else:
+        assert layout and zero.shape == (len(layout),)
+    registered = tmetrics.request_histograms(
+        tmetrics.TelemetryRegistry(), engine=True)
+    for counter in layout:
+        assert isinstance(counter, tmetrics.Counter)
+        if counter.metric is not None:
+            assert registered.get(counter.metric) is not None, counter
+    fed = [k.metric for k in layout if k.metric is not None]
+    assert len(fed) == len(set(fed))
+    assert fed == {
+        "dense": [],
+        "latent": [tmetrics.MOE_TOUCHED[0], tmetrics.MOE_ROUTED[0],
+                   tmetrics.MOE_LOAD_MAX[0]],
+        "latent_mhc": [tmetrics.MOE_TOUCHED[0], tmetrics.MOE_ROUTED[0],
+                       tmetrics.MOE_LOAD_MAX[0],
+                       tmetrics.HC_SINKHORN_RESIDUAL[0]],
+        "mamba2": [tmetrics.MOE_TOUCHED[0], tmetrics.MOE_ROUTED[0],
+                   tmetrics.MOE_LOAD_MAX[0], tmetrics.MOE_PICKS_ROUTED[0]],
+        "lightning_sparse": [],   # one dense MLP a layer: routes nothing
+        "kda_latent": [tmetrics.MOE_TOUCHED[0], tmetrics.MOE_ROUTED[0],
+                       tmetrics.MOE_LOAD_MAX[0],
+                       tmetrics.MOE_PICKS_ROUTED[0],
+                       tmetrics.MOE_GROUPS_KEPT_HERE[0],
+                       tmetrics.KDA_STATE_ROWS_STEPPED[0]],
+    }[family]
+    assert [k.f32_bits for k in layout if k.metric ==
+            tmetrics.HC_SINKHORN_RESIDUAL[0]] == [True] * (
+                family == "latent_mhc")
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_what_the_state_says_of_itself(family):
+    """The four questions the engine asks at start-up, and which mirrors
+    a configuration has."""
+    c = FAMILIES[family]()
+    called, why = llama.state_called(c), llama.transfer_refusal(c)
+    assert (called is None) == (why is None) == (family == "dense")
+    assert llama.pages_resume(c) == (
+        family in ("dense", "latent", "latent_mhc"))
+    assert llama.page_multiple(c) == (8 if family == "lightning_sparse"
+                                      else 1)
+    decode = llama.decode_mirror(c, 128, 4, REFERENCE)
+    prefill = llama.prefill_mirror(c)
+    assert (decode is not None) == (family in (
+        "latent", "latent_mhc", "lightning_sparse", "kda_latent"))
+    assert (prefill is not None) == (family == "lightning_sparse")
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_the_rounds_step_through_the_front_door(family):
+    """ONE signature for every block: (ring, stepped, logits, stats), the
+    stepped leaves and the counter row shaped as they went in."""
+    c = FAMILIES[family](dtype="float32")
+    B, R = 6, 4
+    params = jax.eval_shape(lambda: llama.init_params(c, 0))
+    ctx = jax.eval_shape(lambda: llama.init_ctx(c, B, 64, jnp.float32))
+    ring = jax.eval_shape(lambda: llama.init_ring(c, B, R, jnp.float32))
+    kinds = llama.stepped_kinds(c, ctx)
+    assert set(kinds) <= set(ctx) and not set(kinds) & set(ring)
+    assert bool(kinds) == (family in ("mamba2", "lightning_sparse",
+                                      "kda_latent"))
+    stepped = {n: ctx[n] for n in kinds}
+    stats = jax.eval_shape(lambda: llama.stats_zero(c))
+    i32 = jax.ShapeDtypeStruct((B,), jnp.int32)
+    out = jax.eval_shape(
+        lambda *a: llama.round_step(c, *a, attn=REFERENCE),
+        params, ctx, ring, stepped, i32, i32, i32,
+        jax.ShapeDtypeStruct((), jnp.int32),
+        jax.ShapeDtypeStruct((B,), jnp.bool_), i32, stats)
+    assert len(out) == 4
+    new_ring, new_stepped, logits, new_stats = out
+    same = lambda a, b: jax.tree.map(   # noqa: E731
+        lambda x, y: (x.shape, x.dtype) == (y.shape, y.dtype), a, b)
+    assert all(jax.tree.leaves(same(new_ring, ring)))
+    assert jax.tree.structure(new_stepped) == jax.tree.structure(stepped)
+    assert all(jax.tree.leaves(same(new_stepped, stepped)))
+    assert logits.shape == (B, c.vocab_size)
+    assert (new_stats.shape, new_stats.dtype) == (stats.shape, stats.dtype)

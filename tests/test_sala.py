@@ -188,8 +188,8 @@ def test_a_lane_that_is_not_live_keeps_its_state_and_keys_bit_for_bit(setup):
     rng = np.random.RandomState(0)
     ctx = jax.tree.map(lambda a: jnp.asarray(rng.randn(*a.shape), a.dtype),
                        llama.init_ctx(cfg, B, 128, jnp.float32))
-    assert ssm_moe.stepped_kinds(ctx) == ("kc", "lin_state")
-    state = {n: ctx[n] for n in ssm_moe.stepped_kinds(ctx)}
+    assert ssm_moe.stepped_kinds(cfg, ctx) == ("kc", "lin_state")
+    state = {n: ctx[n] for n in ssm_moe.stepped_kinds(cfg, ctx)}
     live = jnp.asarray([True, False, True])
     i32 = lambda *v: jnp.asarray(v, jnp.int32)  # noqa: E731
     # position 3 ends a compressed key (kernel 4, stride 2): lanes 0 and
