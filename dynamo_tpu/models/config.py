@@ -28,6 +28,14 @@ _LINEAR_SPARSE_TYPES = frozenset({"minicpm_sala"})
 # groups: the same hybrid stack again (_from_hf_kda_latent). Tested BEFORE
 # the latent block's ``"kv_lora_rank" in d`` arm: such a file has the key
 _KDA_LATENT_TYPES = frozenset({"ling3_flash", "bailing_hybrid"})
+# Mamba-1 (selective scan) layers beside NoPE attention layers, one dense
+# SwiGLU a layer, the head tied: the same hybrid stack (_from_hf_jamba)
+_JAMBA_TYPES = frozenset({"jamba"})
+_JAMBA_KEYS = ("attn_layer_offset", "attn_layer_period", "mamba_d_conv",
+               "mamba_d_state", "mamba_dt_rank", "mamba_expand",
+               "mamba_conv_bias", "mamba_proj_bias", "num_experts",
+               "intermediate_size", "num_attention_heads",
+               "num_key_value_heads", "num_hidden_layers")
 # keys that mean "not a dense Llama": a config carrying one is refused
 # rather than read with its extra structure dropped
 _FOREIGN_KEYS = ("kv_lora_rank", "q_lora_rank", "n_routed_experts",
@@ -129,6 +137,18 @@ _TINY_KDA_LATENT = {
     "expert_swiglu_limit_list": [0] * 7,
     "share_expert_swiglu_limit_list": [0] * 7,
     "rms_norm_eps": 1e-6, "max_position_embeddings": 512,
+}
+_TINY_JAMBA = {
+    "model_type": "jamba", "vocab_size": 256, "hidden_size": 64,
+    "intermediate_size": 128, "num_hidden_layers": 6,
+    "attn_layer_offset": 1, "attn_layer_period": 3,
+    "expert_layer_offset": 1, "expert_layer_period": 2,
+    "num_experts": 1, "num_experts_per_tok": 1,
+    "num_attention_heads": 4, "num_key_value_heads": 1,
+    "mamba_d_conv": 4, "mamba_d_state": 16, "mamba_dt_rank": 8,
+    "mamba_expand": 2, "mamba_conv_bias": True, "mamba_proj_bias": False,
+    "hidden_act": "silu", "rms_norm_eps": 1e-6, "sliding_window": None,
+    "max_position_embeddings": 512, "tie_word_embeddings": True,
 }
 _TINY_LINEAR_SPARSE = {
     "model_type": "minicpm_sala", "vocab_size": 256, "hidden_size": 64,
@@ -249,7 +269,9 @@ class ModelConfig:
     # latent attention with the `kv` row of the latent block, a leading
     # run of dense layers, grouped sigmoid routing. What a layer does
     # follows from `layer_types`: mamba | attention | linear_attention |
-    # sparse_attention | kda | latent_attention.
+    # sparse_attention | kda | latent_attention | mamba1 (_from_hf_jamba:
+    # the selective scan with a decay per channel and state column, one
+    # dense MLP a layer, the head tied).
     hybrid: Optional[tuple[tuple[str, Any], ...]] = None
 
     @property
@@ -293,6 +315,8 @@ class ModelConfig:
             return cls._from_hf_mla_moe(d)
         if model_type in _SSM_MOE_TYPES:
             return cls._from_hf_ssm_moe(d)
+        if model_type in _JAMBA_TYPES:
+            return cls._from_hf_jamba(d)
         if model_type in _LINEAR_SPARSE_TYPES:
             return cls._from_hf_linear_sparse(d)
         if model_type not in _DENSE_TYPES:
@@ -583,6 +607,72 @@ class ModelConfig:
         )
 
     @classmethod
+    def _from_hf_jamba(cls, d: dict[str, Any]) -> "ModelConfig":
+        """Mamba-1 mixers (a decay per channel and state column, RMSNorms
+        on dt / B / C between ``x_proj`` and ``dt_proj``) with a NoPE
+        attention layer where ``i % attn_layer_period ==
+        attn_layer_offset``, one dense SwiGLU a layer, the head tied, no
+        multipliers: ``model_type: jamba`` with ``num_experts`` 1. Every
+        key the equations read must be there with a value this program
+        builds; anything else is refused by name. ``head_dim`` is not a
+        key of the family: hidden_size / num_attention_heads."""
+        missing = sorted(k for k in _JAMBA_KEYS if k not in d)
+        if missing:
+            raise ValueError("Mamba-1 + attention block: keys "
+                             f"{missing} are missing from the config")
+        heads, kvh = d["num_attention_heads"], d["num_key_value_heads"]
+        refused = {
+            f"num_experts {d['num_experts']} (routed experts on this "
+            "stack are not built: only the dense feed-forward part, "
+            "num_experts 1)": d["num_experts"] != 1,
+            "mamba_proj_bias": bool(d["mamba_proj_bias"]),
+            "mamba_conv_bias false": not d["mamba_conv_bias"],
+            "sliding_window": d.get("sliding_window") is not None,
+            f"hidden_act {d.get('hidden_act')!r}":
+                d.get("hidden_act", "silu") != "silu",
+            "tie_word_embeddings false (the head is the embedding)":
+                not d.get("tie_word_embeddings", True),
+            "hidden_size no multiple of num_attention_heads":
+                d["hidden_size"] % heads != 0,
+            "num_attention_heads no multiple of num_key_value_heads":
+                heads % kvh != 0,
+            "attn_layer_offset outside its period":
+                not 0 <= d["attn_layer_offset"] < d["attn_layer_period"],
+        }
+        bad = sorted(k for k, v in refused.items() if v)
+        if bad:
+            raise ValueError(f"Mamba-1 + attention block: {bad} are values "
+                             "this program does not build")
+        hd = d["hidden_size"] // heads
+        kinds = tuple(
+            "attention" if i % d["attn_layer_period"]
+            == d["attn_layer_offset"] else "mamba1"
+            for i in range(d["num_hidden_layers"]))
+        hybrid = dict(
+            layer_types=kinds,
+            m1_inner=int(d["mamba_expand"] * d["hidden_size"]),
+            m1_state=int(d["mamba_d_state"]),
+            m1_dt_rank=int(d["mamba_dt_rank"]),
+            m1_conv=int(d["mamba_d_conv"]),
+            # the family has none of the four multipliers
+            embedding_multiplier=1.0, residual_multiplier=1.0,
+            attention_multiplier=hd ** -0.5, logits_scaling=1.0)
+        return cls(
+            vocab_size=d["vocab_size"],
+            hidden_size=d["hidden_size"],
+            intermediate_size=d["intermediate_size"],
+            num_layers=d["num_hidden_layers"],
+            num_heads=heads,
+            num_kv_heads=kvh,
+            head_dim=hd,
+            rms_norm_eps=d.get("rms_norm_eps", 1e-6),
+            max_position_embeddings=d.get("max_position_embeddings", 8192),
+            tie_word_embeddings=True,
+            model_type=d["model_type"],
+            hybrid=tuple(sorted(hybrid.items())),
+        )
+
+    @classmethod
     def _from_hf_kda_latent(cls, d: dict[str, Any]) -> "ModelConfig":
         """Delta-rule linear attention (KDA) layers with a per-channel
         gate and short convolutions, one latent (MLA) layer closing every
@@ -725,6 +815,15 @@ class ModelConfig:
         two periods of (kda, kda, latent), 16 experts in 4 groups of which
         2 are kept, top 4, share 0 of 4 holds one group."""
         d = dict(_TINY_KDA_LATENT)
+        d.update(kw)
+        return cls.from_hf_dict(d)
+
+    @classmethod
+    def tiny_jamba(cls, **kw) -> "ModelConfig":
+        """Toy Mamba-1 + attention stack for CPU tests: two periods of
+        (mamba1, attention, mamba1), four query heads on ONE K/V head, an
+        inner width of 128 with 16 state columns and a dt rank of 8."""
+        d = dict(_TINY_JAMBA)
         d.update(kw)
         return cls.from_hf_dict(d)
 

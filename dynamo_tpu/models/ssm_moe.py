@@ -79,6 +79,18 @@ kind, what a region holds from its leaves:
     devices the decode step is one Pallas kernel a layer that follows a
     work list of the LIVE lanes and rewrites their states in place (the
     XLA form steps every lane, one that is not live with g 0, b 0, k 0).
+  - ``mamba1`` (ops/mamba1.py): the selective scan with a decay per
+    channel AND state column. ``[x | z] = u W_in``; x through the short
+    causal depthwise convolution (bias, SiLU; an ``m1_conv_state`` leaf
+    [lanes + 1, W - 1, inner] a layer); ``[dt_r | B | C] = x W_x``, EACH
+    through an RMSNorm with a gain; ``dt = softplus(dt_r W_dt +
+    dt_bias)``, ``A = -exp(A_log)``; ``h = exp(dt A) h + (dt x) outer B``,
+    ``y = h C + D x`` on an ``m1_state`` leaf [lanes + 1, d_state, inner]
+    float32 a layer (channels MINOR: the published [inner, d_state] has a
+    minor dimension of 16, which the chip tiles to 128); ``y silu(z)
+    W_out``, no out-norm. Prefill is one Pallas kernel a scan block on TPU
+    devices (a ``lax.scan`` elsewhere), decode one kernel a layer over the
+    live lanes' states in place, as the delta-rule step.
   - ``latent_attention``: models/mla_moe.py's attention (imported, not
     copied) on ONE ``kv`` row leaf [L_latent, 1, lanes, S, stored] in
     place of K and V: prefill expands K and V per head (a continuing
@@ -117,7 +129,7 @@ from dynamo_tpu.models import mla_moe
 from dynamo_tpu.models.live_rows import live_row_trips, over_live_blocks
 from dynamo_tpu.models.mla_moe import _mlp, _rms   # the same norm and SwiGLU
 from dynamo_tpu.models.moe import grouped_experts, move_block, rows_moved
-from dynamo_tpu.ops import kda, lightning, mamba2, sparse_attention
+from dynamo_tpu.ops import kda, lightning, mamba1, mamba2, sparse_attention
 from dynamo_tpu.ops.attention import (
     PALLAS_INTERPRET,
     REFERENCE_IMPL,
@@ -139,6 +151,8 @@ from dynamo_tpu.telemetry.metrics import (
     SPARSE_ATTN_ROWS_READ,
     SPARSE_PREFILL_SCORED,
     SPARSE_PREFILL_SELECTED,
+    SSM_SCAN_POSITIONS,
+    SSM_STATE_ROWS_STEPPED,
     Counter,
 )
 
@@ -150,6 +164,8 @@ LIN = "lin_state"     # a linear-attention layer's matrix state
 KC = "kc"             # the sparse layers' compressed-key rows
 KDA, KDA_CONV = "kda_state", "kda_conv_state"   # a delta-rule layer's
                       # matrix state and its three convolution windows
+M1, M1_CONV = "m1_state", "m1_conv_state"   # a Mamba-1 layer's [N, inner]
+                      # state (channels minor) and its convolution window
 KV = mla_moe.ROW      # the latent layers' one row kind
 ROW_LAYERS = ("attention", "sparse_attention")   # kinds that keep K/V rows
 
@@ -164,6 +180,7 @@ def dims(c: ModelConfig) -> dict[str, Any]:
         "n_attn": sum(t in ROW_LAYERS for t in kinds),
         "n_sparse": sum(t == "sparse_attention" for t in kinds),
         "n_kda": sum(t == "kda" for t in kinds),
+        "n_m1": sum(t == "mamba1" for t in kinds),
         "n_latent": sum(t == "latent_attention" for t in kinds),
         "experts": "num_local_experts" in k,
         # leading layers whose feed-forward part is one dense MLP
@@ -194,6 +211,9 @@ def dims(c: ModelConfig) -> dict[str, Any]:
         d.update({"kda_heads": k["kda_heads"], "kda_dim": k["kda_head_dim"],
                   "kda_inner": k["kda_heads"] * k["kda_head_dim"],
                   "kda_W": k["kda_conv"], "kda_bound": k["kda_lower_bound"]})
+    if d["n_m1"]:
+        d.update({"m1_inner": k["m1_inner"], "m1_N": k["m1_state"],
+                  "m1_rank": k["m1_dt_rank"], "m1_W": k["m1_conv"]})
     if "lightning_heads" in k:
         d.update({
             "lin_heads": k["lightning_heads"],
@@ -246,6 +266,9 @@ def state_bytes(c: ModelConfig, itemsize: int) -> int:
         total += d["n_kda"] * (
             d["kda_heads"] * d["kda_dim"] ** 2 * 4
             + (d["kda_W"] - 1) * 3 * d["kda_inner"] * itemsize)
+    if d["n_m1"]:
+        total += d["n_m1"] * d["m1_inner"] * (
+            d["m1_N"] * 4 + (d["m1_W"] - 1) * itemsize)
     return total
 
 
@@ -261,7 +284,10 @@ def init_params(config: ModelConfig, rng: jax.Array | int = 0) -> Params:
     layer's gate is drawn to the same end: ``dt_bias`` uniform in -8..-4.5
     a channel under a gate matrix of half the usual scale, so that a
     channel's decay ``a`` lies in ~0.91..0.998, and ``W_b`` at the usual
-    scale, so that the step ``b`` spans ~0.1..0.9."""
+    scale, so that the step ``b`` spans ~0.1..0.9. A Mamba-1 layer takes
+    its family's: ``A_log`` = log(1..N) down every channel's state column,
+    ``dt`` log-uniform in 0.001..0.1 a channel, ``D`` 1, the three inner
+    norms' gains 1."""
     if isinstance(rng, int):
         rng = jax.random.PRNGKey(rng)
     c, d = config, dims(config)
@@ -328,6 +354,24 @@ def init_params(config: ModelConfig, rng: jax.Array | int = 0) -> Params:
                 w_bg=rnd(H, 2 * nh),
                 o_norm=jnp.ones((d["kda_dim"],), dtype),
                 wo=rnd(inner, H))
+            return lp
+        if kind == "mamba1":
+            I, N, R = d["m1_inner"], d["m1_N"], d["m1_rank"]
+            dt = jnp.exp(u(np.log(1e-3), np.log(1e-1), I))
+            lp.update(
+                w_in=rnd(H, 2 * I),                   # x | the gate z
+                conv_w=rnd(d["m1_W"], I, scale=1.0 / np.sqrt(d["m1_W"])),
+                conv_b=rnd(I, scale=0.1),
+                w_x=rnd(I, R + 2 * N),                # dt_r | B | C
+                dt_norm=jnp.ones((R,), dtype), b_norm=jnp.ones((N,), dtype),
+                c_norm=jnp.ones((N,), dtype),
+                w_dt=rnd(R, I),
+                dt_bias=dt + jnp.log(-jnp.expm1(-dt)),
+                # channels minor, as the state is held
+                A_log=jnp.broadcast_to(jnp.log(jnp.arange(
+                    1, N + 1, dtype=jnp.float32))[:, None], (N, I)),
+                D=jnp.ones((I,), jnp.float32),
+                w_out=rnd(I, H))
             return lp
         if kind == "latent_attention":
             m = mla_moe.dims(c)
@@ -443,6 +487,14 @@ def init_ctx(config, batch, ctx_len, dtype=None, kv_quant="none",
         ctx[KDA_CONV] = [
             jnp.zeros((batch + 1, d["kda_W"] - 1, 3 * d["kda_inner"]), dtype)
             for _ in range(d["n_kda"])]
+    if d["n_m1"]:
+        # [N, inner], NOT the published [inner, N]: a minor dimension of
+        # 16 is tiled to 128 on the chip (8 x the bytes, a relayout a step)
+        ctx[M1] = [jnp.zeros((batch + 1, d["m1_N"], d["m1_inner"]),
+                             jnp.float32) for _ in range(d["n_m1"])]
+        ctx[M1_CONV] = [
+            jnp.zeros((batch + 1, d["m1_W"] - 1, d["m1_inner"]), dtype)
+            for _ in range(d["n_m1"])]
     return ctx
 
 
@@ -491,6 +543,9 @@ def ctx_shardings(config: ModelConfig, mesh: Mesh,
     if d["n_kda"]:
         out[KDA] = [NamedSharding(mesh, P(None, None, None, None))] * d["n_kda"]
         out[KDA_CONV] = [NamedSharding(mesh, P(None, None, None))] * d["n_kda"]
+    if d["n_m1"]:
+        for name in (M1, M1_CONV):
+            out[name] = [NamedSharding(mesh, P(None, None, None))] * d["n_m1"]
     return out
 
 
@@ -538,6 +593,7 @@ def route(c: ModelConfig, lp, x):
 
 
 KDA_STEPPED = Counter(KDA_STATE_ROWS_STEPPED[0])
+M1_STEPPED = Counter(SSM_STATE_ROWS_STEPPED[0])
 
 
 def stats_layout(c: ModelConfig) -> tuple[Counter, ...]:
@@ -547,13 +603,14 @@ def stats_layout(c: ModelConfig) -> tuple[Counter, ...]:
     that routes nothing carries them at 0 and feeds no histogram); under
     the grouped router, routed tokens that kept a group held here; with
     delta-rule layers, the per-lane matrix states their steps moved on
-    (the live lanes', a layer)."""
+    (the live lanes', a layer); with Mamba-1 layers, the same of theirs."""
     d = dims(c)
     router = [m[0] if routes(c) else None for m in (
         MOE_TOUCHED, MOE_ROUTED, MOE_LOAD_MAX, MOE_PICKS_ROUTED)]
     return (tuple(Counter(m) for m in router)
             + ((Counter(MOE_GROUPS_KEPT_HERE[0]),) if "groups" in d else ())
-            + ((KDA_STEPPED,) if d["n_kda"] else ()))
+            + ((KDA_STEPPED,) if d["n_kda"] else ())
+            + ((M1_STEPPED,) if d["n_m1"] else ()))
 
 
 def stats_zero(c: ModelConfig):
@@ -750,6 +807,36 @@ def _kda_out(c: ModelConfig, lp, o, z, dtype):
         return o.reshape(o.shape[0], -1).astype(dtype) @ lp["wo"]
 
 
+def _m1_in(c: ModelConfig, lp, x):
+    """[N, H] -> the gate's input z and the convolution's input, each
+    [N, inner] (the published in-projection gives x first, then z)."""
+    with jax.named_scope("m1_in_proj"):
+        xs, z = jnp.split(x @ lp["w_in"], 2, axis=-1)
+    return z, xs
+
+
+def _m1_ssm(c: ModelConfig, lp, xs):
+    """The convolved ``xs`` [N, inner] -> dt [N, inner] float32 (after
+    its projection, bias and softplus), B and C [N, d_state]: ``x_proj``,
+    then an RMSNorm with a gain on each of its three parts."""
+    d = dims(c)
+    R, N = d["m1_rank"], d["m1_N"]
+    with jax.named_scope("m1_xproj"):
+        dt, B, C = jnp.split(xs @ lp["w_x"], [R, R + N], axis=-1)
+        dt, B, C = (_rms(a, lp[g], c.rms_norm_eps) for a, g in (
+            (dt, "dt_norm"), (B, "b_norm"), (C, "c_norm")))
+        dt = jnp.matmul(dt, lp["w_dt"], preferred_element_type=jnp.float32)
+        return jax.nn.softplus(dt + lp["dt_bias"]), B, C
+
+
+def _m1_out(lp, y, z):
+    """``y`` [N, inner] float32 from the scan (D x in it) -> the mixer's
+    output: the gate, W_out; no norm."""
+    with jax.named_scope("m1_out"):
+        return (y * jax.nn.silu(z.astype(jnp.float32))).astype(
+            z.dtype) @ lp["w_out"]
+
+
 def _no_bias(lp):
     return jnp.zeros((lp["conv_w"].shape[1],), jnp.float32)
 
@@ -941,7 +1028,7 @@ SCAN_ROW_BLOCK = 256
 # crosses a half's boundary to the cache dtype as written, which the
 # straight-line program's fusions skip, and the sparse layers' block
 # selection flips on such roundings (PERF.md section 6, PR 49).
-LIVE_ROW_KINDS = ("mamba", "attention", "kda", "latent_attention")
+LIVE_ROW_KINDS = ("mamba", "attention", "kda", "latent_attention", "mamba1")
 
 
 def live_row_block(c: ModelConfig, T: int) -> int:
@@ -957,7 +1044,8 @@ def live_row_block(c: ModelConfig, T: int) -> int:
     it too."""
     R, Rs, d = LIVE_ROW_BLOCK, SCAN_ROW_BLOCK, dims(c)
     chunks = [q for n, q in ((d["n_ssm"], d.get("chunk")),
-                             (d["n_kda"], kda.CHUNK), (d["n_lin"], LIN_CHUNK))
+                             (d["n_kda"], kda.CHUNK), (d["n_lin"], LIN_CHUNK),
+                             (d["n_m1"], mamba1.GROUP))
               if n]
     if (T % R or T // R < 2 or R % Rs or any(Rs % q for q in chunks)
             or any(kind not in LIVE_ROW_KINDS for kind in d["kinds"])):
@@ -982,6 +1070,8 @@ def _mix_in(c: ModelConfig, kind: str, lp, h, pos):
         return _lin_in(c, lp, x, pos)
     if kind == "kda":
         return _kda_in(c, lp, x)
+    if kind == "mamba1":
+        return _m1_in(c, lp, x)
     return _ssm_in(c, lp, x)
 
 
@@ -1002,6 +1092,13 @@ def _scan_block(c: ModelConfig, kind: str, lp, S, *blk):
         qkv, g, b, real = blk
         with jax.named_scope("kda_scan"):
             o, S = kda.chunk_scan(*_kda_qkv(c, qkv), g, b, real, S)
+    elif kind == "mamba1":
+        xs, real = blk
+        dt, Bm, Cm = _m1_ssm(c, lp, xs)
+        with jax.named_scope("m1_scan"):
+            o, S = mamba1.chunk_scan(
+                xs, dt, Bm, Cm, -jnp.exp(lp["A_log"]), lp["D"], S,
+                jnp.sum(real, dtype=jnp.int32))
     else:
         xbc, dt, real = blk
         xs, Bm, Cm = _split_xbc(c, xbc)
@@ -1030,6 +1127,8 @@ def _mix_out(c: ModelConfig, kind: str, lp, h, *seq):
         mix = _lin_out(c, lp, *seq)
     elif kind == "kda":
         mix = _kda_out(c, lp, *seq, h.dtype)
+    elif kind == "mamba1":
+        mix = _m1_out(lp, *seq)
     else:
         y, xbc, z = seq
         mix = _ssm_out(c, lp, y, _split_xbc(c, xbc)[0], z)
@@ -1149,7 +1248,7 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
     R = _move_block(d, K * T)
     moved = jnp.int32(0)
     ks, vs, kcs, ssm_out, conv_out, lin_out = [], [], [], [], [], []
-    lat, kda_out, kda_conv_out = [], [], []
+    lat, kda_out, kda_conv_out, m1_out, m1_conv_out = [], [], [], [], []
     lanes = lambda a: a.reshape(K, T, *a.shape[1:])  # noqa: E731
     A = lambda lp: -jnp.exp(lp["A_log"])  # noqa: E731
     for kind, lp in zip(d["kinds"], params["layers"]):
@@ -1211,6 +1310,30 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
             kda_out.append(S)
             kda_conv_out.append(win)
             mix = _kda_out(c, lp, o.reshape(K * T, *o.shape[2:]), z, cdt)
+        elif kind == "mamba1":
+            j = len(m1_out)
+            z, xs = _m1_in(c, lp, x)
+            if span:
+                keep = continuing[:, None, None]
+                win0 = jnp.where(keep, ctx_kv[M1_CONV][j][slots], 0)
+                S0 = jnp.where(keep, ctx_kv[M1][j][slots], 0.0)
+            else:
+                win0 = jnp.zeros((K, d["m1_W"] - 1, d["m1_inner"]), cdt)
+                S0 = jnp.zeros((K, d["m1_N"], d["m1_inner"]), jnp.float32)
+            with jax.named_scope("m1_conv"):
+                xs, win = jax.vmap(
+                    lambda a, w0, n: mamba2.causal_conv(
+                        a, w0, lp["conv_w"], lp["conv_b"], n)
+                )(xs.reshape(K, T, -1), win0, n_real)
+            dt, Bm, Cm = _m1_ssm(c, lp, xs.reshape(K * T, -1))
+            with jax.named_scope("m1_scan"):
+                # a lane a kernel call: K is the few chunks of one dispatch
+                ys, Ss = zip(*(mamba1.chunk_scan(
+                    xs[i], lanes(dt)[i], lanes(Bm)[i], lanes(Cm)[i], A(lp),
+                    lp["D"], S0[i], n_real[i]) for i in range(K)))
+            m1_out.append(jnp.stack(Ss))
+            m1_conv_out.append(win)
+            mix = _m1_out(lp, jnp.concatenate(ys), z)
         elif kind == "latent_attention":
             with jax.named_scope("mla_attn"):
                 q_nope, q_rope, row = mla_moe._attn_in(
@@ -1269,7 +1392,8 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
                 "v": jnp.stack(vs, 1).astype(cdt)}
     states = [(name, new) for name, new in (
         (SSM, ssm_out), (CONV, conv_out), (LIN, lin_out), (KDA, kda_out),
-        (KDA_CONV, kda_conv_out)) if new]
+        (KDA_CONV, kda_conv_out), (M1, m1_out), (M1_CONV, m1_conv_out))
+        if new]
 
     out_ctx, logits = _write_chunks(c, params, ctx_kv, rows, kcs, states, slots,
                                     q_starts, seq_lens, h)
@@ -1315,7 +1439,7 @@ def _live_prefill(config, params, ctx_kv, tokens, slots, q_starts, seq_lens,
     moved = jnp.int32(0)
     r = jnp.asarray(c.hybrid_dict["residual_multiplier"], cdt)
     ks, vs, kcs, lat = [], [], [], []
-    new = {name: [] for name in (SSM, CONV, LIN, KDA, KDA_CONV)}
+    new = {name: [] for name in (SSM, CONV, LIN, KDA, KDA_CONV, M1, M1_CONV)}
     flat = lambda a: a.reshape(K * T, *a.shape[2:])  # noqa: E731
 
     def rowwise(half, kind, lp, *rows):
@@ -1375,6 +1499,17 @@ def _live_prefill(config, params, ctx_kv, tokens, slots, q_starts, seq_lens,
             new[KDA].append(S)
             new[KDA_CONV].append(win)
             seq = (o, z)
+        elif kind == "mamba1":
+            z, xs = ins
+            S0, win0 = before((M1, M1_CONV), [
+                ((d["m1_N"], d["m1_inner"]), jnp.float32),
+                ((d["m1_W"] - 1, d["m1_inner"]), cdt)])
+            with jax.named_scope("m1_conv"):
+                xs, win = conv(lp, xs, win0, lp["conv_b"])
+            y, S = scan(kind, lp, S0, xs)
+            new[M1].append(S)
+            new[M1_CONV].append(win)
+            seq = (y, z)
         elif kind == "latent_attention":
             qq, k, v, row = ins
             with jax.named_scope("mla_attn"):
@@ -1450,8 +1585,9 @@ def decode_step_impl(config, params, ctx_kv, ring, state, tokens, ctx_lens,
     stats). The attention layers' new rows land in ring slot
     ``ring_pos`` and the region is read-only, as in the dense decoder;
     ``state`` (the region's leaves a step writes, ``stepped_kinds``:
-    ``{SSM: [...], CONV: [...]}``, ``{LIN: [...], KC: rows}`` or ``{KDA:
-    [...], KDA_CONV: [...]}``, lanes + 1 wide) comes back moved on by one
+    ``{SSM: [...], CONV: [...]}``, ``{LIN: [...], KC: rows}``, ``{KDA:
+    [...], KDA_CONV: [...]}`` or ``{M1: [...], M1_CONV: [...]}``, lanes + 1
+    wide) comes back moved on by one
     position for the lanes that are ``live`` and as it was for the others.
     ``attn`` also says which delta-rule step runs: the Pallas kernel where
     the decode attention is one, the XLA form beside the reference."""
@@ -1468,12 +1604,13 @@ def decode_step_impl(config, params, ctx_kv, ring, state, tokens, ctx_lens,
     # the scratch lane rides along as one more row that never moves
     pad = lambda a: jnp.pad(a, ((0, 1),) + ((0, 0),) * (a.ndim - 1))  # noqa: E731
     a = j = n = sp = 0
-    if d["n_kda"]:
-        # the delta-rule layers' work list, the same for all of them, and
-        # what they step of it: the live lanes' states, a layer
-        work = kda.work_list(live)
-        stats = stats.at[stats_layout(c).index(KDA_STEPPED)].add(
-            work[1][0] * d["n_kda"])
+    for layers, stepped in ((d["n_kda"], KDA_STEPPED), (d["n_m1"], M1_STEPPED)):
+        if layers:
+            # the step kernels' work list, the same for every such layer,
+            # and what they step of it: the live lanes' states, a layer
+            work = kda.work_list(live)
+            stats = stats.at[stats_layout(c).index(stepped)].add(
+                work[1][0] * layers)
     for kind, lp in zip(d["kinds"], params["layers"]):
         x = _rms(h, lp["ln1"], c.rms_norm_eps)
         if kind == "attention":
@@ -1529,6 +1666,30 @@ def decode_step_impl(config, params, ctx_kv, ring, state, tokens, ctx_lens,
                         q, k, v, g, b, state[KDA][n], *work,
                         interpret=attn.impl == PALLAS_INTERPRET)
             mix = _kda_out(c, lp, o[:B], z, cdt)
+            n += 1
+        elif kind == "mamba1":
+            z, xs = _m1_in(c, lp, x)
+            with jax.named_scope("m1_conv"):
+                xs, win = mamba2.conv_step(
+                    pad(xs), state[M1_CONV][n], lp["conv_w"], lp["conv_b"])
+                xs = xs[:B]
+                state[M1_CONV][n] = jnp.where(
+                    pad(live)[:, None, None], win, state[M1_CONV][n])
+            dt, Bm, Cm = _m1_ssm(c, lp, xs)
+            A = -jnp.exp(lp["A_log"])
+            with jax.named_scope("m1_step"):
+                if attn.impl == REFERENCE_IMPL:
+                    # every lane steps; one that is not live with dt 0:
+                    # exp(0) h + 0, the state as it was
+                    y, state[M1][n] = mamba1.scan_step(
+                        pad(xs), pad(jnp.where(live[:, None], dt, 0.0)),
+                        pad(Bm), pad(Cm), A, lp["D"], state[M1][n])
+                    y = y[:B]
+                else:   # the live lanes' states in place, no other touched
+                    y, state[M1][n] = mamba1.scan_step_pallas(
+                        xs, dt, Bm, Cm, A, lp["D"], state[M1][n], *work,
+                        interpret=attn.impl == PALLAS_INTERPRET)
+            mix = _m1_out(lp, y, z)
             n += 1
         elif kind == "latent_attention":
             with jax.named_scope("mla_attn"):
@@ -1634,7 +1795,22 @@ def decode_mirror(config: ModelConfig, max_context: int, ring_len: int,
 def prefill_mirror(config: ModelConfig):
     """The sparse layers score a chunk's whole causal context under the
     selection's mask (``scored``, what ``prefill_attention_pairs`` counts
-    a layer): beside it, what a gathering prefill would score."""
+    a layer): beside it, what a gathering prefill would score. A
+    stack with Mamba-1 layers mirrors the positions their prefill scans
+    run instead (it has no sparse layer)."""
+    n_m1 = dims(config)["n_m1"]
+    if n_m1:
+        # the positions the Mamba-1 layers' prefill scans run: a lane's
+        # live scan blocks where the program loops, every bucket row else
+        def scanned(width: int, q_starts, seq_lens, scored: int):
+            rows = len(q_starts) * width
+            if live_row_block(config, width):
+                rows = SCAN_ROW_BLOCK * int(live_row_trips(
+                    np.asarray(q_starts, np.int64),
+                    np.asarray(seq_lens, np.int64), width,
+                    SCAN_ROW_BLOCK).sum())
+            return ((SSM_SCAN_POSITIONS[0], n_m1 * rows),)
+        return scanned
     sparse, layers = sparse_layers(config)
     if sparse is None:
         return None

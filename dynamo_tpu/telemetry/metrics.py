@@ -469,6 +469,18 @@ KDA_STATE_ROWS_STEPPED = (
     "consumed decode round moved on, counted by the program: the lanes "
     "its step kernel's work list held (the live ones), summed over the "
     "round's steps x the delta-rule layers")
+SSM_STATE_ROWS_STEPPED = (
+    "dynamo_ssm_state_rows_stepped",
+    "Mamba-1 (selective scan) models: per-lane [d_state, inner] states "
+    "the steps of a consumed decode round moved on, counted by the "
+    "program: the lanes its step kernel's work list held (the live ones), "
+    "summed over the round's steps x the Mamba-1 layers")
+SSM_SCAN_POSITIONS = (
+    "dynamo_ssm_scan_positions",
+    "Mamba-1 (selective scan) models: positions the prefill scans of a "
+    "dispatch ran, all such layers: the lanes' live scan blocks x their "
+    "height where the program loops over them, lanes x bucket width "
+    "where it does not (the host's mirror, ssm_moe.prefill_mirror)")
 MOE_PREFILL_ROWS_SORTED = (
     "dynamo_moe_prefill_rows_sorted",
     "(token, pick) rows the expert layers of a finished prefill program "
@@ -484,7 +496,8 @@ SSM_STATE_BYTES = ("dynamo_ssm_state_bytes",
                    "bytes one lane holds in recurrent state (a state-space "
                    "layer's SSM state and convolution window, a "
                    "linear-attention layer's matrix state, a delta-rule "
-                   "layer's matrix state and three convolution windows), "
+                   "layer's matrix state and three convolution windows, a "
+                   "Mamba-1 layer's state and convolution window), "
                    "all layers, "
                    "whatever its context (observed once, at engine start)")
 SPARSE_ATTN_ROWS_READ = (
@@ -570,7 +583,8 @@ def request_histograms(
                       tuple(10.0 ** i for i in range(-9, 1)))
         for name, help_ in (DECODE_ATTN_ROWS_READ, DECODE_ATTN_ROWS_LIVE,
                             MOE_PREFILL_ROWS_SORTED, MOE_PREFILL_ROWS_MOVED,
-                            KDA_STATE_ROWS_STEPPED):
+                            KDA_STATE_ROWS_STEPPED, SSM_STATE_ROWS_STEPPED,
+                            SSM_SCAN_POSITIONS):
             reg.histogram(name, help_,
                           tuple(float(4 ** i) for i in range(3, 13)))
         reg.histogram(*KV_ROW_BYTES, tuple(float(4 ** i) for i in range(3, 12)))

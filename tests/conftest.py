@@ -38,8 +38,8 @@ def pytest_configure(config):
     )
 
 
-# The twelve files whose cases take minutes (160-690 s each in the builder's
-# run of PR 50's tree), in two waves. The driver's tier-1 run (`-n 6 --dist
+# The files whose cases take minutes (160-690 s each in the builder's run
+# of PR 50's tree; fourteen since PR 51), in two waves. The driver's tier-1 run (`-n 6 --dist
 # loadfile`) hands FILES to its six workers in collection order, two to a
 # worker at the start and one more whenever a worker finishes one. In the
 # alphabet's order a long file that sorts late was the run's clock alone
@@ -59,6 +59,7 @@ LONG_FILES = (
     "test_spec.py", "test_rehearsal_longdoc_reasoning.py", "test_sala.py",
     "test_spec_tree.py", "test_prefill_live_rows.py",
     "test_rehearsal_ragdoc_longprompt.py",
+    "test_mamba1.py", "test_rehearsal_chat_rate.py",   # PR 51: ~3 min each
 )
 
 

@@ -71,6 +71,10 @@ CELLS = {
     # 20 s pre-roll and two in the window are what the CPU drains in time
     "reasoning": ("ling3-flash-ep8-d12.reasoning", "4700470047",
                   "benchmarks/references/kda_mla_moe.py", 0.3),
+    # a rate sized for the chip's 96 lanes; the CPU drains ~20 requests of
+    # 32-768 tokens out in the 10 s pre-roll and the window
+    "chat-rate": ("jamba2-3b.chat-rate", "5100510051",
+                  "benchmarks/references/jamba.py", 1.2),
 }
 
 
@@ -95,7 +99,10 @@ def rehearse(tmp_path, cell, seed, reference, rate_rps):
     reasoning cell's check carries the toy model's delta-rule state, its
     convolution windows and its latent rows over a chunk boundary at 4096
     and through 72 decode steps, under grouped routing with a share of
-    eight."""
+    eight. The chat-rate cell's check carries the toy model's Mamba-1
+    state, window and K/V rows over a chunk boundary at 2048, through a
+    looped 1024 bucket and a padded 256 one, and its warm-up fills all 96
+    lanes once."""
     root = REPO if rate_rps is None else checkout_at_rate(
         tmp_path, cell, rate_rps)
     r = run([sys.executable, "benchmarks/run.py", "--workload", cell,

@@ -97,8 +97,13 @@ def test_the_benchmarks_own_checks_pass(cmd, limit_s):
      True),
     ("ling3-flash-ep8-d12.reasoning", "gen.carried_tok_s.reasoning-open",
      "higher", True),
+    # answers of up to 768 tokens behind a 10 s pre-roll: what is carried
+    # OUT decides (-134.3 slow / +8.6 fast at the cell's 12.8 req/s: the fast
+    # server drains the pre-roll's backlog inside the window)
+    ("jamba2-3b.chat-rate", "gen.carried_tok_s.chat-rate-open", "higher",
+     True),
 ], ids=["chat", "longprompt", "chat-decode", "longdoc", "ragdoc",
-        "reasoning"])
+        "reasoning", "chat-rate"])
 def test_carried_tok_s_reads_the_way_its_entry_says(monkeypatch, cell, entry,
                                                      shown, registered_agrees):
     """``benchmarks/test_contract.py``'s two servers (cell 1's pace before
@@ -127,9 +132,9 @@ def test_carried_tok_s_reads_the_way_its_entry_says(monkeypatch, cell, entry,
             gen["tok_s"] - contract.offered_tok_s(log))
     if shown == "lower":
         assert 0 < carried["fast"] < carried["slow"]
-    elif cell == "ling3-flash-ep8-d12.reasoning":
-        # a 20 s pre-roll of answers that outlast it carries IN as well as
-        # out: the faster server still reads the higher, on either side of 0
+    elif cell in ("ling3-flash-ep8-d12.reasoning", "jamba2-3b.chat-rate"):
+        # a pre-roll of answers that outlast it carries IN as well as out:
+        # the faster server still reads the higher, on either side of 0
         assert carried["slow"] < carried["fast"] and carried["slow"] < 0
     else:
         assert carried["slow"] < carried["fast"] < 0

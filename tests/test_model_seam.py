@@ -35,6 +35,7 @@ FAMILIES = {
     "mamba2": ModelConfig.tiny_ssm_moe,
     "lightning_sparse": ModelConfig.tiny_linear_sparse,
     "kda_latent": ModelConfig.tiny_kda_latent,
+    "mamba1": ModelConfig.tiny_jamba,
 }
 
 
@@ -138,6 +139,8 @@ def test_the_counter_row_is_declared(family):
                        tmetrics.MOE_PICKS_ROUTED[0],
                        tmetrics.MOE_GROUPS_KEPT_HERE[0],
                        tmetrics.KDA_STATE_ROWS_STEPPED[0]],
+        # one dense MLP a layer: routes nothing; its step kernel counts
+        "mamba1": [tmetrics.SSM_STATE_ROWS_STEPPED[0]],
     }[family]
     assert [k.f32_bits for k in layout if k.metric ==
             tmetrics.HC_SINKHORN_RESIDUAL[0]] == [True] * (
@@ -159,7 +162,8 @@ def test_what_the_state_says_of_itself(family):
     prefill = llama.prefill_mirror(c)
     assert (decode is not None) == (family in (
         "latent", "latent_mhc", "lightning_sparse", "kda_latent"))
-    assert (prefill is not None) == (family == "lightning_sparse")
+    assert (prefill is not None) == (family in ("lightning_sparse",
+                                                "mamba1"))
 
 
 @pytest.mark.parametrize("family", FAMILIES)
@@ -174,7 +178,7 @@ def test_the_rounds_step_through_the_front_door(family):
     kinds = llama.stepped_kinds(c, ctx)
     assert set(kinds) <= set(ctx) and not set(kinds) & set(ring)
     assert bool(kinds) == (family in ("mamba2", "lightning_sparse",
-                                      "kda_latent"))
+                                      "kda_latent", "mamba1"))
     stepped = {n: ctx[n] for n in kinds}
     stats = jax.eval_shape(lambda: llama.stats_zero(c))
     i32 = jax.ShapeDtypeStruct((B,), jnp.int32)
