@@ -593,7 +593,7 @@ def route(c: ModelConfig, lp, x):
 
 
 KDA_STEPPED = Counter(KDA_STATE_ROWS_STEPPED[0])
-M1_STEPPED = Counter(SSM_STATE_ROWS_STEPPED[0])
+SSM_STEPPED = Counter(SSM_STATE_ROWS_STEPPED[0])   # Mamba-1 or Mamba-2
 
 
 def stats_layout(c: ModelConfig) -> tuple[Counter, ...]:
@@ -603,14 +603,15 @@ def stats_layout(c: ModelConfig) -> tuple[Counter, ...]:
     that routes nothing carries them at 0 and feeds no histogram); under
     the grouped router, routed tokens that kept a group held here; with
     delta-rule layers, the per-lane matrix states their steps moved on
-    (the live lanes', a layer); with Mamba-1 layers, the same of theirs."""
+    (the live lanes', a layer); with Mamba-1 or Mamba-2 layers (a stack
+    has one of the two kinds), the same of theirs."""
     d = dims(c)
     router = [m[0] if routes(c) else None for m in (
         MOE_TOUCHED, MOE_ROUTED, MOE_LOAD_MAX, MOE_PICKS_ROUTED)]
     return (tuple(Counter(m) for m in router)
             + ((Counter(MOE_GROUPS_KEPT_HERE[0]),) if "groups" in d else ())
             + ((KDA_STEPPED,) if d["n_kda"] else ())
-            + ((M1_STEPPED,) if d["n_m1"] else ()))
+            + ((SSM_STEPPED,) if d["n_m1"] or d["n_ssm"] else ()))
 
 
 def stats_zero(c: ModelConfig):
@@ -1589,8 +1590,9 @@ def decode_step_impl(config, params, ctx_kv, ring, state, tokens, ctx_lens,
     [...], KDA_CONV: [...]}`` or ``{M1: [...], M1_CONV: [...]}``, lanes + 1
     wide) comes back moved on by one
     position for the lanes that are ``live`` and as it was for the others.
-    ``attn`` also says which delta-rule step runs: the Pallas kernel where
-    the decode attention is one, the XLA form beside the reference."""
+    ``attn`` also says which delta-rule, Mamba-1 or Mamba-2 step runs: the
+    Pallas kernel over the live lanes where the decode attention is one,
+    the XLA form over every lane beside the reference."""
     c, d = config, dims(config)
     _refuse_adapters(params)
     B = tokens.shape[0]
@@ -1604,7 +1606,8 @@ def decode_step_impl(config, params, ctx_kv, ring, state, tokens, ctx_lens,
     # the scratch lane rides along as one more row that never moves
     pad = lambda a: jnp.pad(a, ((0, 1),) + ((0, 0),) * (a.ndim - 1))  # noqa: E731
     a = j = n = sp = 0
-    for layers, stepped in ((d["n_kda"], KDA_STEPPED), (d["n_m1"], M1_STEPPED)):
+    for layers, stepped in ((d["n_kda"], KDA_STEPPED),
+                            (d["n_m1"] + d["n_ssm"], SSM_STEPPED)):
         if layers:
             # the step kernels' work list, the same for every such layer,
             # and what they step of it: the live lanes' states, a layer
@@ -1714,12 +1717,20 @@ def decode_step_impl(config, params, ctx_kv, ring, state, tokens, ctx_lens,
                 xbc = xbc[:B]
                 conv[j] = jnp.where(pad(live)[:, None, None], win, conv[j])
             xs, Bm, Cm = _split_xbc(c, xbc)
+            A = -jnp.exp(lp["A_log"])
             with jax.named_scope("ssm_scan"):
-                # dt 0: exp(0) S + 0, the state as it was
-                y, ssm[j] = mamba2.scan_step(
-                    pad(xs), pad(jnp.where(live[:, None], dt, 0.0)),
-                    -jnp.exp(lp["A_log"]), pad(Bm), pad(Cm), ssm[j])
-            mix = _ssm_out(c, lp, y[:B], xs, z)
+                if attn.impl == REFERENCE_IMPL:
+                    # every lane steps; one that is not live with dt 0:
+                    # exp(0) S + 0, the state as it was
+                    y, ssm[j] = mamba2.scan_step(
+                        pad(xs), pad(jnp.where(live[:, None], dt, 0.0)), A,
+                        pad(Bm), pad(Cm), ssm[j])
+                    y = y[:B]
+                else:   # the live lanes' states in place, no other touched
+                    y, ssm[j] = mamba2.scan_step_pallas(
+                        xs, dt, A, Bm, Cm, ssm[j], *work,
+                        interpret=attn.impl == PALLAS_INTERPRET)
+            mix = _ssm_out(c, lp, y, xs, z)
             j += 1
         h, stats = _layer_out(c, lp, h, mix, live, stats)
     return ring, state, _logits(c, params, h), stats

@@ -257,6 +257,14 @@ def work_list(live):
     return lanes.astype(jnp.int32), jnp.sum(live, dtype=jnp.int32)[None]
 
 
+def visited(lanes, n_live):
+    """[B] bool: the lanes a step kernel's items visited, ``lanes[:n_live]``
+    of a work list. The output rows of the others were never written."""
+    B = lanes.shape[0]
+    return jnp.any((lanes[None, :] == jnp.arange(B)[:, None])
+                   & (jnp.arange(B)[None, :] < n_live[0]), axis=1)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def step_pallas(q, k, v, g, b, state, lanes, n_live, interpret: bool = False):
     """``step`` as one kernel over the lanes of a work list
@@ -292,7 +300,4 @@ def step_pallas(q, k, v, g, b, state, lanes, n_live, interpret: bool = False):
         interpret=interpret,
         name="kda_step",
     )(lanes, n_live, *vectors, state)
-    # a lane no item visited: its block of ``o`` was never written
-    visited = jnp.any((lanes[None, :] == jnp.arange(B)[:, None])
-                      & (jnp.arange(B)[None, :] < n_live[0]), axis=1)
-    return jnp.where(visited[:, None, None], o, 0.0), state
+    return jnp.where(visited(lanes, n_live)[:, None, None], o, 0.0), state

@@ -55,6 +55,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dynamo_tpu.ops import kda
+
 TILE = 512    # channels a grid step of the prefill kernel: an [N, TILE]
               # float32 state is 8 vector registers at N = 16
 ROWS = 256    # positions a block of the prefill kernel
@@ -253,7 +255,4 @@ def scan_step_pallas(x, dt, B, C, A, D, state, lanes, n_live,
     )(lanes, n_live, x.astype(_F32), dt.astype(_F32),
       B.astype(_F32)[:, :, None], C.astype(_F32)[:, :, None],
       A.astype(_F32), D.astype(_F32)[None], state)
-    # a lane no item visited: its row of ``y`` was never written
-    visited = jnp.any((lanes[None, :] == jnp.arange(nB)[:, None])
-                      & (jnp.arange(nB)[None, :] < n_live[0]), axis=1)
-    return jnp.where(visited[:, None], y, 0.0), state
+    return jnp.where(kda.visited(lanes, n_live)[:, None], y, 0.0), state

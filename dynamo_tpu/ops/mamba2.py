@@ -27,12 +27,28 @@ matmuls).
 
 DECODE (``scan_step``) is the recurrence as written, one position a
 lane: multiply, add, and a reduction over N, fused by XLA into one pass
-that reads the lane's state once and writes it once.
+that reads the lane's state once and writes it once; a lane with ``dt``
+= 0 gets its state back bit for bit. ``scan_step_pallas`` is the same
+step as one kernel a layer over the lanes of a WORK LIST
+(``kda.work_list``: the lanes that hold a request, scalar prefetch),
+their [H, P, N] states rewritten IN PLACE: a lane the list does not hold
+is neither read nor written.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from dynamo_tpu.ops import kda
+
+HEADS_PER_STEP = 32   # heads a grid step of the decode kernel: a [32, 64,
+                      # 128] float32 state block is 1 MB
+
+_F32 = jnp.float32
 
 
 def causal_conv(xbc, window, w, b, n_real):
@@ -115,3 +131,100 @@ def scan_step(x, dt, A, B, C, state):
         :, None, None, :]
     state = decay * state + fed
     return jnp.sum(state * C.astype(f32)[:, None, None, :], axis=-1), state
+
+
+def _step_kernel(lanes_ref, n_ref, decay_ref, fed_ref, b_ref, c_ref, s_ref,
+                 y_ref, out_ref):
+    """Work item ``(i, j)``: the ``hb`` heads from ``j * hb`` of lane
+    ``lanes[i]``, in blocks of ``per`` heads = R rows of the state.
+    ``decay_ref`` [B, H] (exp(dt A)) sits in SMEM: a head's decay is a
+    scalar. ``fed_ref`` (dt x) and ``y_ref`` [B, H / per, R], ``b_ref`` /
+    ``c_ref`` [B, N] hold every lane's rows, resident for the whole call:
+    the item reads and writes its own. ``s_ref`` / ``out_ref`` [1, hb, P,
+    N] the state before and after (one buffer).
+
+    ``dt x`` is wanted down the state's P axis and ``y`` comes out down
+    it, while both live ALONG the lanes of their rows: the item's [blocks,
+    R] tile of ``fed`` is transposed once (block k in column k), and a
+    block's [R, N] products ``S C`` are transposed once and summed over
+    the sublanes, which gives its R values of ``y`` as a row. (Reducing
+    every register over its 128 lanes instead, and broadcasting a column
+    of decays beside the column of ``dt x``, held this kernel to 55 % of
+    the HBM's pace on the v5e; this form runs at the pace of its DMAs.)"""
+    hb, P, N = s_ref.shape[1:]
+    R = fed_ref.shape[2]
+    per, blocks = R // P, hb * P // R
+    lane, j = lanes_ref[pl.program_id(0)], pl.program_id(1)
+    row = pl.ds(lane, 1)
+    tile = pl.ds(pl.multiple_of(j * blocks, blocks), blocks)
+    side = max(blocks, R)
+
+    @pl.when(n_ref[0] > 0)
+    def _():
+        b, c = b_ref[row, :], c_ref[row, :]                        # [1, N]
+        fed = jnp.pad(fed_ref[row, tile, :][0],
+                      ((0, side - blocks), (0, side - R))).T       # [R.., k..]
+        ys = []
+        for k in range(blocks):
+            products = []
+            for i in range(per):
+                h = k * per + i
+                S = (decay_ref[lane, j * hb + h] * s_ref[0, h]
+                     + fed[i * P:(i + 1) * P, k:k + 1] * b)
+                out_ref[0, h] = S
+                products.append(S * c)
+            t = jnp.concatenate(products, axis=0)                  # [R, N]
+            ys.append(jnp.sum(t.T, axis=0, keepdims=True))
+        y_ref[row, tile, :] = jnp.concatenate(ys, axis=0)[None]
+
+    @pl.when(n_ref[0] == 0)
+    def _():
+        # an empty list: the grid's one lane (lane 0) goes back as it came
+        out_ref[...] = s_ref[...]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def scan_step_pallas(x, dt, A, B, C, state, lanes, n_live,
+                     interpret: bool = False):
+    """``scan_step`` as one kernel over the lanes of a work list
+    (``kda.work_list``): ``x`` [B, H, P], ``dt`` [B, H] float32, ``A``
+    [H], ``B`` and ``C`` [B, N]; ``state`` [L >= B, H, P, N] float32;
+    ``lanes`` [B] and ``n_live`` [1] int32 ride in as scalar prefetch, and
+    ``n_live`` is the grid's bound. The lanes ``lanes[:n_live]`` are
+    stepped IN PLACE; no other lane's state is read or written, and its
+    ``y`` comes back 0. A state element is computed as ``scan_step``
+    computes it; ``y`` sums the same products in another order."""
+    nB, H, P = x.shape
+    N = B.shape[1]
+    hb = next(n for n in (HEADS_PER_STEP, 16, 8, 4, 2, 1) if H % n == 0)
+    # heads a block: as many as fill a register's 128 lanes with their P
+    per = next(n for n in range(min(hb, max(1, 128 // P)), 0, -1)
+               if hb % n == 0)
+    G, R = H // per, per * P
+    dt = dt.astype(_F32)
+    fed = (dt[:, :, None] * x.astype(_F32)).reshape(nB, G, R)
+    whole = lambda *s: pl.BlockSpec(s, lambda i, j, lanes, n: (0,) * len(s))  # noqa: E731
+    lane = pl.BlockSpec((1, hb, P, N),
+                        lambda i, j, lanes, n: (lanes[i], j, 0, 0))
+    y, state = pl.pallas_call(
+        _step_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            # never an empty grid: what a pipeline with no step writes
+            # back is nobody's promise
+            grid=(jnp.maximum(n_live[0], 1), H // hb),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                      whole(nB, G, R), whole(nB, N), whole(nB, N), lane],
+            out_specs=[whole(nB, G, R), lane]),
+        out_shape=[jax.ShapeDtypeStruct((nB, G, R), _F32),
+                   jax.ShapeDtypeStruct(state.shape, _F32)],
+        # operand indices count the two prefetched scalars
+        input_output_aliases={6: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="m2_step",
+    )(lanes, n_live, jnp.exp(dt * A), fed, B.astype(_F32), C.astype(_F32),
+      state)
+    return jnp.where(kda.visited(lanes, n_live)[:, None, None],
+                     y.reshape(nB, H, P), 0.0), state

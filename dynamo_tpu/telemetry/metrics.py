@@ -471,10 +471,11 @@ KDA_STATE_ROWS_STEPPED = (
     "round's steps x the delta-rule layers")
 SSM_STATE_ROWS_STEPPED = (
     "dynamo_ssm_state_rows_stepped",
-    "Mamba-1 (selective scan) models: per-lane [d_state, inner] states "
-    "the steps of a consumed decode round moved on, counted by the "
-    "program: the lanes its step kernel's work list held (the live ones), "
-    "summed over the round's steps x the Mamba-1 layers")
+    "state-space models (Mamba-1 selective scan or Mamba-2): per-lane "
+    "states ([d_state, inner], or [heads, head, d_state]) the steps of a "
+    "consumed decode round moved on, counted by the program: the lanes "
+    "its step kernel's work list held (the live ones), summed over the "
+    "round's steps x the state-space layers")
 SSM_SCAN_POSITIONS = (
     "dynamo_ssm_scan_positions",
     "Mamba-1 (selective scan) models: positions the prefill scans of a "

@@ -236,16 +236,17 @@ def test_the_four_stream_checks_limits_stand_between_its_readings(
     assert (worst <= tol_max and mean <= tol_mean) is passes, reading
 
 
-def _reader_sources(hists_after, kernels):
+def _reader_sources(hists_after, kernels, config="ling3-flash-ep8-d12"):
     """What ``run.py`` hands a reader, as far as the delta-rule cell's own
-    readers look: the published configuration, counters that stood at zero
-    when the window opened, a reduced trace of ten rounds."""
+    readers look (or another cell's, by its ``config``): the published
+    configuration, counters that stood at zero when the window opened, a
+    reduced trace of ten rounds."""
     sys.path.insert(0, os.path.join(REPO, "benchmarks"))
     import byname
     import peaks
 
     with open(os.path.join(REPO, "benchmarks", "configs",
-                           "ling3-flash-ep8-d12.json")) as f:
+                           config + ".json")) as f:
         cfg = json.load(f)
     zero = {name: {"sum": 0.0, "count": 0} for name in hists_after}
     return {
@@ -305,6 +306,34 @@ def test_the_states_stepped_are_read_over_the_live_lanes(stepped, live,
         assert got is None
     else:
         assert got == pytest.approx(ratio)   # ~1.65, and 1.00
+
+
+@pytest.mark.parametrize("config,stepped,live,ratio", [
+    # 100 rounds of 4 steps at 10.3 of 32 lanes live, nine Mamba-2 layers:
+    # a program that steps every lane (had it counted), then one that
+    # follows its work list
+    ("granite4h-ep2-d10", 100 * 4 * 32 * 9.0, 100 * 4 * 10.3, 32 / 10.3),
+    ("granite4h-ep2-d10", 100 * 4 * 10.3 * 9, 100 * 4 * 10.3, 1.0),
+    # a program without the counter (the parent), a window without a
+    # round, a byte count without ``n_ssm`` (another stack's): nothing
+    ("granite4h-ep2-d10", None, 100 * 4 * 10.3, None),
+    ("granite4h-ep2-d10", 100 * 4 * 10.3 * 9, None, None),
+    ("ling3-flash-ep8-d12", 100 * 4 * 29 * 10.0, 100 * 4 * 29.0, None),
+], ids=["every-lane", "the-work-list", "no-counter", "no-rounds",
+        "no-mamba2-layers"])
+def test_the_mamba2_states_stepped_are_read_over_the_live_lanes(
+        config, stepped, live, ratio):
+    hists = {name: {"sum": total, "count": 100} for name, total in (
+        ("dynamo_ssm_state_rows_stepped", stepped),
+        ("dynamo_engine_round_live_lane_steps", live)) if total is not None}
+    sources = _reader_sources(hists, {}, config)
+    got = sources["byname"].module_with(
+        os.path.join(REPO, "benchmarks", "layer_metrics"),
+        "ssm.states_stepped_over_live.ragdoc-open", "read").read(sources)
+    if ratio is None:
+        assert got is None
+    else:
+        assert got == pytest.approx(ratio)   # ~3.1, and 1.00
 
 
 def test_the_reasoning_cells_counter_readers_count_expert_layers_only():

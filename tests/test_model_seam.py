@@ -132,7 +132,8 @@ def test_the_counter_row_is_declared(family):
                        tmetrics.MOE_LOAD_MAX[0],
                        tmetrics.HC_SINKHORN_RESIDUAL[0]],
         "mamba2": [tmetrics.MOE_TOUCHED[0], tmetrics.MOE_ROUTED[0],
-                   tmetrics.MOE_LOAD_MAX[0], tmetrics.MOE_PICKS_ROUTED[0]],
+                   tmetrics.MOE_LOAD_MAX[0], tmetrics.MOE_PICKS_ROUTED[0],
+                   tmetrics.SSM_STATE_ROWS_STEPPED[0]],
         "lightning_sparse": [],   # one dense MLP a layer: routes nothing
         "kda_latent": [tmetrics.MOE_TOUCHED[0], tmetrics.MOE_ROUTED[0],
                        tmetrics.MOE_LOAD_MAX[0],

@@ -26,8 +26,12 @@ from dynamo_tpu.engine.config import EngineConfig
 from dynamo_tpu.engine.engine import TpuEngine
 from dynamo_tpu.models import llama, moe, ssm_moe
 from dynamo_tpu.models.config import _TINY_SSM_MOE, ModelConfig
-from dynamo_tpu.ops import mamba2
-from dynamo_tpu.ops.attention import REFERENCE
+from dynamo_tpu.ops import kda, mamba2
+from dynamo_tpu.ops.attention import (
+    PALLAS_INTERPRET,
+    REFERENCE,
+    DecodeAttention,
+)
 from dynamo_tpu.parallel.mesh import MeshConfig
 from dynamo_tpu.protocols.common import (
     OutputOptions,
@@ -41,6 +45,7 @@ TOL = 1e-4
 PS = 8
 BUCKETS = (16, 32)
 TOP = 5
+LANES = 6   # a round's five counters ride home in a row this wide
 
 
 def load_reference():
@@ -63,7 +68,7 @@ def setup():
 def engine(cfg, params, **kw):
     ecfg = EngineConfig(**{**dict(
         num_pages=16, page_size=PS, max_pages_per_seq=24,
-        max_decode_slots=4, prefill_buckets=BUCKETS, flush_every=8,
+        max_decode_slots=LANES, prefill_buckets=BUCKETS, flush_every=8,
         cache_dtype="float32", max_logprobs=TOP), **kw})
     return TpuEngine(cfg, ecfg, params=params, mesh_config=MeshConfig(tp=1))
 
@@ -235,22 +240,37 @@ async def test_chunks_interleave_with_other_lanes_decode_and_lanes_are_reused(
     # the routing counters count the HELD experts' picks, beside all picks
     assert 0 < snap["dynamo_moe_tokens_routed"]["sum"] < snap[
         "dynamo_moe_picks_routed"]["sum"]
+    # the program's own count: the live lanes' states a step, four
+    # Mamba-2 layers, a row a consumed round
+    stepped = snap["dynamo_ssm_state_rows_stepped"]
+    lane_steps = snap["dynamo_engine_round_live_lane_steps"]
+    assert stepped["count"] == lane_steps["count"] > 0
+    assert stepped["sum"] == lane_steps["sum"] * 4
+    assert stepped["sum"] < stepped["count"] * 8 * LANES * 4   # steps, lanes
+    assert snap["dynamo_kda_state_rows_stepped"]["count"] == 0
     await eng.stop()
 
 
-def test_a_lane_that_is_not_live_keeps_its_state_bit_for_bit(setup):
-    cfg, params, _ = setup
-    B = 3
-    rng = np.random.RandomState(0)
+def step_inputs(cfg, B, seed):
+    rng = np.random.RandomState(seed)
     ctx = llama.init_ctx(cfg, B, 32, jnp.float32)
     state = {n: [jnp.asarray(rng.randn(*a.shape), a.dtype) for a in ctx[n]]
              for n in llama.state_kinds(ctx)}
+    return ctx, state, llama.init_ring(cfg, B, 2, jnp.float32)
+
+
+@pytest.mark.parametrize("attn", [REFERENCE, DecodeAttention(PALLAS_INTERPRET)],
+                         ids=["xla-step", "step-kernel"])
+def test_a_lane_that_is_not_live_keeps_its_state_bit_for_bit(setup, attn):
+    cfg, params, _ = setup
+    B = 3
+    ctx, state, ring = step_inputs(cfg, B, 0)
     live = jnp.asarray([True, False, True])
     i32 = lambda *v: jnp.asarray(v, jnp.int32)  # noqa: E731
     _, new, logits, stats = ssm_moe.decode_step_impl(
-        cfg, params, ctx, llama.init_ring(cfg, B, 2, jnp.float32), state,
+        cfg, params, ctx, ring, state,
         i32(5, 6, 7), i32(3, 3, 3), i32(2, 2, 2), jnp.int32(0), live,
-        attn=REFERENCE)
+        attn=attn)
     for name in state:
         for old, upd in zip(state[name], new[name]):
             np.testing.assert_array_equal(upd[1], old[1])   # not live
@@ -259,7 +279,63 @@ def test_a_lane_that_is_not_live_keeps_its_state_bit_for_bit(setup):
     d = ssm_moe.dims(cfg)
     assert int(stats[3]) == 2 * d["K"] * cfg.num_layers
     assert 0 < int(stats[1]) <= int(stats[3])
+    # two live lanes x four Mamba-2 layers, counted by the program
+    assert int(stats[-1]) == 2 * d["n_ssm"] == 8
     assert np.isfinite(np.asarray(logits)).all()
+
+
+def test_the_kernel_step_and_the_xla_step_give_one_decode(setup):
+    """Decode under the Mamba-2 step kernel (interpreted) over the work
+    list against the XLA step over every lane."""
+    cfg, params, _ = setup
+    ctx, state, ring = step_inputs(cfg, 3, 1)
+    i32 = lambda *v: jnp.asarray(v, jnp.int32)  # noqa: E731
+    out = [ssm_moe.decode_step_impl(
+        cfg, params, ctx, ring, state, i32(5, 6, 7), i32(9, 30, 4),
+        i32(8, 29, 3), jnp.int32(0), jnp.asarray([True, True, False]),
+        attn=attn)
+        for attn in (REFERENCE, DecodeAttention(PALLAS_INTERPRET))]
+    np.testing.assert_allclose(out[0][2][:2], out[1][2][:2], atol=1e-5)
+    for a, b in zip(out[0][1][ssm_moe.SSM], out[1][1][ssm_moe.SSM]):
+        np.testing.assert_allclose(a, b, atol=1e-5)
+    np.testing.assert_array_equal(out[0][3], out[1][3])
+
+
+STEP_LIVE = {"holes": [True, False, True, True], "all-live": [True] * 4,
+             "none-live": [False] * 4, "the-last-lane": [False] * 3 + [True]}
+
+
+@pytest.mark.parametrize("live", sorted(STEP_LIVE))
+@pytest.mark.parametrize("H,P,N", [(4, 8, 16), (6, 8, 16), (32, 64, 128)],
+                         ids=["toy", "toy-heads-of-two", "a-published-tile"])
+def test_the_step_kernel_equals_the_step(H, P, N, live):
+    """The Pallas kernel (interpreted) over a work list against
+    ``mamba2.scan_step`` on the lanes the list holds; every other lane and
+    the scratch lane keep their state bit for bit, and their ``y`` is 0.
+    ``[32, 64, 128]`` is one grid step's state block at the published
+    widths (sixteen blocks of two heads); the toys hold one block of four
+    heads, and three grid steps of one block of two."""
+    live = STEP_LIVE[live]
+    rng = np.random.RandomState(7)
+    f32 = lambda *s: jnp.asarray(rng.randn(*s), jnp.float32)  # noqa: E731
+    x, Bm, Cm, S = f32(4, H, P), f32(4, N), f32(4, N), f32(5, H, P, N)
+    dt = jnp.abs(f32(4, H)) * 0.3
+    A = -jnp.abs(f32(H)) - 0.5
+    y, S_new = mamba2.scan_step(x, dt, A, Bm, Cm, S[:4])
+    lanes, n_live = kda.work_list(jnp.asarray(live))
+    assert int(n_live[0]) == sum(live)
+    y2, S2 = mamba2.scan_step_pallas(x, dt, A, Bm, Cm, S, lanes, n_live,
+                                     interpret=True)
+    for lane, on in enumerate(live):
+        if on:
+            np.testing.assert_allclose(y2[lane], y[lane], rtol=1e-5,
+                                       atol=1e-5)
+            np.testing.assert_allclose(S2[lane], S_new[lane], rtol=1e-6,
+                                       atol=1e-6)
+        else:
+            np.testing.assert_array_equal(S2[lane], S[lane])
+            assert not np.asarray(y2[lane]).any()
+    np.testing.assert_array_equal(S2[4], S[4])
 
 
 def test_chunked_scan_equals_the_recurrence_and_masks_its_padding():
@@ -413,12 +489,12 @@ def test_the_ssm_state_is_float32_whatever_the_cache_dtype(cache_dtype):
     ("LoRA", {"lora_adapters": 2}),
     ("sequence-parallel", {"sp_prefill_threshold": 64}),
     # no plane: the routing counters' row is max_decode_slots wide
-    ("fewer than 4 slots", {"max_decode_slots": 3}),
+    ("fewer than 5 slots", {"max_decode_slots": 4}),
 ])
 def test_a_plane_that_cannot_carry_a_recurrent_state_refuses_at_start(
         setup, plane, kw):
     cfg, params, _ = setup
-    with pytest.raises(ValueError, match="recurrent|at least 4"):
+    with pytest.raises(ValueError, match="recurrent|at least 5"):
         engine(cfg, params, **kw)
 
 

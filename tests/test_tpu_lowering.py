@@ -426,6 +426,43 @@ def test_hybrid_programs_copy_neither_the_state_nor_the_region(hybrid_record):
         # a block of the looped first half: the mixers' in-projection over
         # 512 rows, where the parent ran the bucket's ``[T, 16768]``
         assert "bf16[512,16768]" in rec["text"]
+        return
+    # the round: a step's nine Mamba-2 layers step the live lanes' states
+    # through ``m2_step`` (PR 52: 31 Mosaic calls -> 40), in place; no
+    # every-lane recurrence is left, and neither a step operand (``dt x``
+    # and ``y`` as ``[32, 64, 128]`` rows, ``exp(dt A)`` ``[32, 128]``)
+    # nor a state leaf is relaid by a ``copy``
+    assert rec["mosaic_calls"] == 31 + 9
+    assert rec["text"].count("m2_step") >= 9
+    assert "f32[33,128,64]" not in rec["text"]
+    relayouts = [l for l in rec["text"].splitlines() if re.search(
+        r"= f32\[(32,64,128|32,128,64|32,128|33,128,64,128)\]\S* copy\(", l)]
+    assert not relayouts, relayouts[:3]
+
+
+def test_mamba2_step_kernel_compiles_at_the_published_widths():
+    """``ops/mamba2.py: scan_step_pallas`` alone, through Mosaic for the
+    v5e (~3 s): a decode step over 32 lanes' ``[128, 64, 128]`` float32
+    states in place, 1 MB state blocks of 32 heads."""
+    from jax.sharding import SingleDeviceSharding
+
+    from dynamo_tpu.ops import mamba2
+
+    one = SingleDeviceSharding(_v5e_or_skip().devices[0])
+    sd = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one)
+    L, H, P, N = 32, 128, 64, 128
+    bf = jnp.bfloat16
+    with jax.default_matmul_precision("default"):
+        step = jax.jit(mamba2.scan_step_pallas, donate_argnums=(5,)).lower(
+            sd((L, H, P), bf), sd((L, H)), sd((H,)), sd((L, N), bf),
+            sd((L, N), bf), sd((L + 1, H, P, N)), sd((L,), jnp.int32),
+            sd((1,), jnp.int32)).compile()
+    text = step.as_text()
+    assert "m2_step" in text
+    # the state goes in and comes out in one buffer: no copy of it
+    assert not re.search(r"= f32\[33,128,64,128\]\S* copy\(", text)
+    assert step.memory_analysis().temp_size_in_bytes < 8e6
 
 
 def test_hybrid_cell_keeps_four_prefill_programs():
@@ -704,6 +741,12 @@ def test_chat_rate_cell_keeps_nine_prefill_programs():
 # rows: the ROUND of each routed-expert cell (320 / 512 / 64 sorted rows a
 # step) and every program of the two latent cells, which hold every expert,
 # stay straight-line, recorded on its parent (9362de9).
+# PR 52 MEANT to move ``round_seal_n4_w32`` of the state-space hybrid cell
+# (1f3088abd615d9a7 on its parent, 75f42ae), ONCE: its nine Mamba-2 layers
+# step the live lanes' states through ``mamba2.scan_step_pallas`` where the
+# every-lane XLA recurrence stood. The cell's four prefill programs, its
+# flush, seal and load, and every program of the eight other
+# configurations kept the parent's digests (CHANGES.md, PR 52).
 UNMOVED = {
     ("mla-moe-joyai-d5", 0): {
         "flush_ctx": "aa9a25ef5ee32101",
@@ -718,7 +761,7 @@ UNMOVED = {
         "batch_prefill_cont_K2_T128_S4096": "2c9a09ec6d798ada",
     },
     ("xing4-mhc-d7", 0): {"round_seal_n4_w16": "4a70f5eac1edcf3e"},
-    ("granite4h-ep2-d10", 0): {"round_seal_n4_w32": "1f3088abd615d9a7"},
+    ("granite4h-ep2-d10", 0): {"round_seal_n4_w32": "45ec7a29f1e8de72"},
 }
 _UNMOVED_NAMES = {"mla-moe-joyai-d5": MOVERS + ("round_seal", "load_ctx_pages",
                                                 "batch_prefill"),
