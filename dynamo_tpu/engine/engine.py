@@ -857,10 +857,12 @@ class TpuEngine:
                 repetition_penalty=dev["rep"],
             )
 
-            # MoE models: freed/garbage lanes must not claim expert
-            # capacity (and masking keeps outputs batch-independent)
-            live = ((dev["dest"] != B)
-                    if c.moe is not None or rides else None)
+            # the lanes that hold a request: a freed lane's device length
+            # keeps counting up, so every block's decode attention and
+            # step kernels walk a work list of these lanes only, and
+            # freed / garbage lanes claim no expert capacity (masking
+            # keeps outputs batch-independent)
+            live = dev["dest"] != B
 
             def body(s, carry):
                 ring, dev, toks_out, lp_out, stats, stepped = carry

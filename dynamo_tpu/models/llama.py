@@ -102,6 +102,8 @@ one line in ``block_of``; no line of engine/engine.py.
   the host's mirrors of what the kernels read (None: the block has none)
     decode_mirror(c, max_context, ring_len, attn)
         -> f(ctx_lens, live, n_steps) -> ((metric, value), ...)
+        (the dense decoder's: the region rows its attention's work list
+        reads a layer, beside the live lanes' own)
     prefill_mirror(c)
         -> f(width, q_starts, seq_lens, scored) -> ((metric, value), ...)
   and two live-row rules that are the front door's own
@@ -141,6 +143,7 @@ from dynamo_tpu.ops.attention import (
     DecodeAttention,
     PriorContext,
     ctx_decode_attention,
+    dense_round_rows,
     prefill_attention,
 )
 from dynamo_tpu.ops.rope import apply_rope, rope_cos_sin, rope_inv_freq
@@ -279,8 +282,10 @@ def decode_mirror(config: ModelConfig, max_context: int, ring_len: int,
                   attn: DecodeAttention) -> Optional[Callable]:
     """The host's mirror of what a dispatched round's decode attention
     reads: ``f(ctx_lens [B], live [B] bool, n_steps) -> ((metric, value),
-    ...)`` to observe, or None where nothing is mirrored (here)."""
-    return None
+    ...)`` to observe, or None where nothing is mirrored. Here: the region
+    rows the flash kernel's work list reads a layer, in whole chunks a
+    live lane, beside the live lanes' own rows."""
+    return mla_moe.rows_mirror(dense_round_rows, attn, max_context)
 
 
 @_hands_over
@@ -1576,6 +1581,7 @@ def decode_step_impl(
                 ctx_lens, ring_base,
                 ctx_k_scale=ctx_kv["k_scale"] if quant else None,
                 ctx_v_scale=ctx_kv["v_scale"] if quant else None,
+                live=live,
             )
 
         h, ring = _layer_body(c, lp, h, cos, sin, write_kv, attend,

@@ -292,16 +292,25 @@ def pages_resume(config: ModelConfig) -> bool:
     return True   # a page of latent rows is all a prompt's prefix holds
 
 
-def decode_mirror(config: ModelConfig, max_context: int, ring_len: int,
-                  attn: DecodeAttention):
-    """The host's mirror of the latent decode attention's reads, a layer
-    (``latent_decode.round_rows``), by the two histograms they feed."""
+def rows_mirror(round_rows, attn: DecodeAttention, max_context: int):
+    """A decode mirror over ``round_rows(attn, ctx_lens, live, n_steps,
+    max_context)`` -> (region rows the round's attention read a layer,
+    rows that were a live lane's own): ``latent_decode.round_rows`` here,
+    ``attention.dense_round_rows`` for K and V rows (the dense decoder and
+    the hybrid block's ``attention`` kind), by the two histograms both
+    feed."""
     def mirror(ctx_lens, live, n_steps: int):
-        read, own = latent_decode.round_rows(attn, ctx_lens, live, n_steps,
-                                             max_context)
+        read, own = round_rows(attn, ctx_lens, live, n_steps, max_context)
         return ((DECODE_ATTN_ROWS_READ[0], read),
                 (DECODE_ATTN_ROWS_LIVE[0], own))
     return mirror
+
+
+def decode_mirror(config: ModelConfig, max_context: int, ring_len: int,
+                  attn: DecodeAttention):
+    """The host's mirror of the latent decode attention's reads, a layer
+    (``latent_decode.round_rows``)."""
+    return rows_mirror(latent_decode.round_rows, attn, max_context)
 
 
 def prefill_mirror(config: ModelConfig):

@@ -136,6 +136,7 @@ from dynamo_tpu.ops.attention import (
     DecodeAttention,
     PriorContext,
     ctx_decode_attention,
+    dense_round_rows,
     prefill_attention,
 )
 from dynamo_tpu.ops.latent_decode import latent_decode_attention
@@ -968,13 +969,14 @@ def _sparse_decode(c: ModelConfig, lp, x, ctx_kv, ring, kc, row: int,
         o_sel, _ = sparse_attention.decode_attention(
             g, q, ctx_kv["k"], ctx_kv["v"], kc, ring["k"], ring["v"],
             row, sp, ctx_lens, ring_base, selects)
-        # the dense read (a Mosaic call with a grid over every lane's
-        # chunks) only when some live lane stands below the switch
+        # the dense read (a Mosaic call over the work list of the live
+        # lanes below the switch) only when there is such a lane
+        dense = live & ~selects
         o = jax.lax.cond(
-            jnp.any(live & ~selects),
+            jnp.any(dense),
             lambda: ctx_decode_attention(
                 attn, q, ctx_kv["k"], ctx_kv["v"], ring["k"], ring["v"],
-                jnp.int32(row), jnp.where(selects, 0, ctx_lens), ring_base),
+                jnp.int32(row), ctx_lens, ring_base, live=dense),
             lambda: jnp.zeros_like(o_sel))
         o = jnp.where(selects[:, None, None], o_sel, o)
         mix = _gated(o.reshape(B, c.q_dim), z) @ lp["wo"]
@@ -1626,7 +1628,7 @@ def decode_step_impl(config, params, ctx_kv, ring, state, tokens, ctx_lens,
                             ring[name].dtype), (a, 0, 0, ring_pos, 0))
                 o = ctx_decode_attention(
                     attn, q, ctx_kv["k"], ctx_kv["v"], ring["k"], ring["v"],
-                    jnp.int32(a), ctx_lens, ring_base)
+                    jnp.int32(a), ctx_lens, ring_base, live=live)
                 mix = o.reshape(B, c.q_dim) @ lp["wo"]
             a += 1
         elif kind == "sparse_attention":
@@ -1786,14 +1788,19 @@ def pages_resume(config: ModelConfig) -> bool:
 def decode_mirror(config: ModelConfig, max_context: int, ring_len: int,
                   attn: DecodeAttention):
     """The host's mirrors of what a dispatched round's attention layers
-    read: the latent layers' (mla_moe's, a layer) or the sparse layers'
-    (``sparse_attention.round_rows``, all such layers); None for a stack
-    with neither."""
-    if dims(config)["n_latent"]:
+    read: the latent layers' (mla_moe's, a layer), the sparse layers'
+    (``sparse_attention.round_rows``, all such layers), or the
+    ``attention`` kind's dense read (a layer, into the latent layers'
+    two histograms: a stack has one or the other); None for a stack with
+    none of them."""
+    d = dims(config)
+    if d["n_latent"]:
         return mla_moe.decode_mirror(config, max_context, ring_len, attn)
     sparse, layers = sparse_layers(config)
     if sparse is None:
-        return None
+        if "attention" not in d["kinds"]:
+            return None
+        return mla_moe.rows_mirror(dense_round_rows, attn, max_context)
 
     def mirror(ctx_lens, live, n_steps: int):
         read, rows = sparse_attention.round_rows(

@@ -15,7 +15,10 @@ no gathers, no page tables:
     (ops/latent_decode.py: ``latent_decode_attention``, a work list of
     each lane's own chunks). Nothing here looks at the process's default
     backend, and there is no fall-through from one to the other: a kernel
-    that fails to compile fails the program;
+    that fails to compile fails the program. Both kernels walk a flat
+    WORK LIST of the live lanes' chunks in one invocation a layer, and
+    this module is the one place that says how many chunks a lane reads
+    (``region_trips``) and how the list is laid out (``flat_items``);
   - prefill: ONE blocked running-softmax attention in pure XLA
     (``prefill_attention``) for every prefill-family program, solo and
     batched: two rolled loops whose trip counts follow the live rows, so
@@ -36,9 +39,11 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dynamo_tpu.ops.flash_decode import (
+    chunk_rows as dense_chunk_rows,
     flash_decode_attention,
     flash_decode_attention_reference,
 )
@@ -63,11 +68,9 @@ class DecodeAttention:
     # layers on every device and refuses tp / ep > 1: its kernel is called
     # bare, as its grouped expert product is.
     mesh: Optional[Mesh] = None
-    # kernel tiling; 0 = the kernel's defaults (tools sweep these).
-    # ``chunk`` is region rows a step of either decode attention, XLA
-    # loop included; ``slot_block`` is the flash kernel's alone
+    # region rows a work item of either decode attention holds (a step
+    # of the latent XLA loop too); 0 = the kernel's default (tools sweep)
     chunk: int = 0
-    slot_block: int = 0
 
     def __post_init__(self):
         if self.impl not in (PALLAS, PALLAS_INTERPRET, REFERENCE_IMPL):
@@ -93,6 +96,61 @@ def decode_attention_for(mesh: Mesh) -> DecodeAttention:
     )
 
 
+def region_trips(below, live, cb: int):
+    """Chunks of the region each lane's decode attention reads: ``below``
+    [B] rows under the lane's ring base, ``live`` [B] whether the lane
+    holds a request, ``cb`` rows a chunk. numpy in (the engine's mirror),
+    numpy out; traced in, traced out."""
+    return (below + (cb - 1)) // cb * live
+
+
+def flat_items(counts, width: int):
+    """The flat work list a decode kernel walks, from ``counts`` [B] items
+    a lane: (``lane_of`` [width] int32, item -> lane; ``item_of`` [width],
+    item -> which of its lane's items, ascending; ``ends`` [B], the
+    running sum of ``counts``: ``ends[-1]`` items are on the list). Lane
+    b's items 0..counts[b]-1, lane after lane; what lies past the list's
+    end is never read. ``width`` is a static bound on the sum of
+    ``counts`` (the same list serves every layer of a step: XLA keeps
+    one)."""
+    B = counts.shape[0]
+    i32 = jnp.int32
+    ends = jnp.cumsum(counts)
+    w = jnp.arange(width, dtype=i32)
+    lane_of = jnp.minimum(
+        jnp.sum(w[:, None] >= ends[None, :], axis=1), B - 1).astype(i32)
+    item_of = w - (ends - counts)[lane_of]
+    return lane_of, item_of.astype(i32), ends
+
+
+def round_rows(ctx_lens, live, n_steps: int, cb: int,
+               rows_read) -> tuple[int, int]:
+    """The host's mirror of one dispatched round, a layer: (region rows
+    its steps' attention read, rows that were some live lane's own).
+    ``ctx_lens`` [B] the lanes' lengths at dispatch (the region's rows lie
+    below the round's ring base, ctx - 1), ``live`` [B] bool the lanes
+    dispatched, ``cb`` rows a chunk, ``rows_read(trips)`` the rows a step
+    of the traced implementation reads given the lanes' ``region_trips``
+    (which the attention's wrapper calls too): a kernel reads each live
+    lane's own chunks, ``trips.sum() * cb``."""
+    base = np.maximum(np.asarray(ctx_lens) - 1, 0)
+    live = np.asarray(live, bool)
+    trips = region_trips(base, live, cb)
+    return n_steps * int(rows_read(trips)), n_steps * int(base[live].sum())
+
+
+def dense_round_rows(attn: DecodeAttention, ctx_lens, live, n_steps: int,
+                     max_context: int) -> tuple[int, int]:
+    """``round_rows`` of ``ctx_decode_attention``: each live lane's rows
+    in whole chunks under the kernel; the jnp reference of the CPU meshes
+    scores every lane's whole region."""
+    cb = dense_chunk_rows(max_context, attn.chunk)
+    return round_rows(
+        ctx_lens, live, n_steps, cb,
+        (lambda trips: len(trips) * max_context)
+        if attn.impl == REFERENCE_IMPL else (lambda trips: trips.sum() * cb))
+
+
 def ctx_decode_attention(
     attn: DecodeAttention,
     q: jnp.ndarray,          # [B, n_heads, hd] — one new token per slot
@@ -105,25 +163,50 @@ def ctx_decode_attention(
     ring_base: jnp.ndarray,  # [B] i32 — position held by ring slot 0
     ctx_k_scale: Optional[jnp.ndarray] = None,  # f32 [L, B(+1), S//g]
     ctx_v_scale: Optional[jnp.ndarray] = None,  # when ctx is int8
+    live: Optional[jnp.ndarray] = None,  # [B] bool — lanes that hold a
+                             # request; None = all. The others read
+                             # nothing and come back 0
 ) -> jnp.ndarray:
     """Decode attention over the two-tier context (ctx region below
     ring_base + ring above). The current token's KV must already be in the
     ring. Returns [B, n_heads, hd]. When the ctx region is int8
     (scales given), each KV chunk dequantizes in VMEM right after the
-    DMA — the HBM stream is the int8 bytes."""
+    DMA — the HBM stream is the int8 bytes.
+
+    The kernel walks a work list: each live lane's ``region_trips`` chunks
+    of the region, ascending, then its ring. A freed lane's device length
+    keeps counting up (engine.py's round body); it is not on the list."""
     scales = () if ctx_k_scale is None else (ctx_k_scale, ctx_v_scale)
     if attn.impl == REFERENCE_IMPL:
         return flash_decode_attention_reference(
             q, ctx_k, ctx_v, ring_k, ring_v, layer, ctx_lens, ring_base,
-            *scales,
+            *scales, live=live,
         )
+    B, S = q.shape[0], ctx_k.shape[3]
+    i32 = jnp.int32
+    if live is None:
+        live = jnp.ones(B, bool)
+    cb = dense_chunk_rows(
+        S, attn.chunk, S // ctx_k_scale.shape[2] if scales else 1)
+    n_chunks = S // cb
+    trips = region_trips(
+        jnp.minimum(ring_base, ctx_lens).astype(i32), live, cb).astype(i32)
+    # a live lane's items: its chunks, then (item ``trips``) its ring,
+    # which the kernel knows as chunk ``n_chunks`` and whose K / V blocks
+    # are the chunk's before it once more (a block index cannot be "none")
+    lane_of, item_of, ends = flat_items(
+        (trips + 1) * live, B * (n_chunks + 1))
+    last = trips[lane_of]
+    work = (lane_of, jnp.where(item_of == last, n_chunks, item_of),
+            jnp.minimum(item_of, jnp.maximum(last - 1, 0)),
+            ends[-1:].astype(i32))
 
     def kernel(q, ctx_k, ctx_v, ring_k, ring_v, layer, ctx_lens, ring_base,
-               *scales):
+               lane_of, chunk_of, fetch_of, total, *scales):
         k_scale, v_scale = scales or (None, None)
         return flash_decode_attention(
             q, ctx_k, ctx_v, ring_k, ring_v, layer, ctx_lens, ring_base,
-            chunk=attn.chunk, slot_block=attn.slot_block,
+            (lane_of, chunk_of, fetch_of, total), chunk=cb,
             interpret=attn.impl == PALLAS_INTERPRET,
             ctx_k_scale=k_scale, ctx_v_scale=v_scale,
         )
@@ -132,7 +215,8 @@ def ctx_decode_attention(
         # heads are independent: q/out shard on heads, ctx/ring on kv
         # heads (the layouts llama.param/ctx/ring_shardings already give
         # them), each shard runs the kernel on its own heads — no
-        # collective. Scales carry no head axis: replicated.
+        # collective. The work list and the scales carry no head axis:
+        # replicated, every shard walks the same list over its own heads.
         tp = attn.mesh.shape[AXIS_TENSOR]
         if ctx_k.shape[1] % tp:
             raise ValueError(
@@ -143,16 +227,18 @@ def ctx_decode_attention(
         kernel = jax.shard_map(
             kernel, mesh=attn.mesh,
             in_specs=(heads, kv, kv, kv, kv, P(), P(), P())
-            + (P(),) * len(scales),
+            + (P(),) * (len(work) + len(scales)),
             out_specs=heads,
             # pallas_call has no replication rule; the other mesh axes
             # see replicated operands and produce replicated outputs
             check_vma=False,
         )
-    return kernel(
+    out = kernel(
         q, ctx_k, ctx_v, ring_k, ring_v, jnp.asarray(layer, jnp.int32),
-        ctx_lens, ring_base, *scales,
+        ctx_lens, ring_base, *work, *scales,
     )
+    # no item wrote the row of a lane that is not on the list
+    return jnp.where(live[:, None, None], out, 0)
 
 
 PREFILL_BLOCK = 256  # query/key rows per block of prefill_attention

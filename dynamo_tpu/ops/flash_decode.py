@@ -1,51 +1,71 @@
 """Pallas TPU kernel: flash decode attention over CONTIGUOUS per-slot KV
-plus a small per-round write ring.
+plus a small per-round write ring, for the lanes of a WORK LIST.
 
-Round-4 redesign of the decode hot path. Two lessons drive the design
-(from the round-3/4 development runs; not re-measured on the v5e host
-since — PERF.md):
+What the file is. Decode context lives in a contiguous per-slot region
+``ctx_kv [L, kvh, B+1, S, hd]`` (the paged pool is prefix-cache
+*storage*; the engine copies pages in and out at admission and seal), and
+a step writes its K and V to a tiny per-slot RING ``[L, kvh, B, R, hd]``
+that the engine flushes into the region once a round, AFTER all reads,
+where the update aliases in place (a scatter into the multi-GB region
+beside the kernel's reads made XLA copy it: 119 ms a step, round 4).
+Attention therefore streams big dense blocks and never gathers.
 
-  1. The round-3 kernel walked the paged pool with grid (slot, kv-head,
-     page): 36k kernel invocations per step at ~0.4 µs each — 15.9 ms/step
-     of pure grid overhead. Fix: decode context lives in a contiguous
-     per-slot region ``ctx_kv [L, kvh, B+1, S, hd]`` (the paged pool
-     remains prefix-cache *storage*; engine copies pages in/out at
-     admission/seal), so attention streams big dense blocks:
-     grid (B, S/CHUNK + 1) — ~32-130 invocations per layer.
-  2. Writing the multi-GB ctx buffer per layer (scatter) while custom
-     calls read it forces XLA to materialize copies (~7 GB temps,
-     119 ms/step). Fix: steps write a tiny per-slot RING
-     ``[L, kvh, B, R, hd]`` instead; the engine flushes ring->ctx once
-     per round, AFTER all reads, where the update aliases in place.
+ONE call a layer (PR 53) walks a flat list of work items built by the
+caller (``ops/attention.py: ctx_decode_attention``): for every lane that
+holds a request, its region chunks of ``DEFAULT_CHUNK`` rows, ascending,
+then its ring, lane after lane. The list rides in as scalar prefetch and
+its LENGTH, a traced value, is the grid's bound (the pattern of
+``ops/kda.py: step_pallas``): a grid step is one item, every block's index
+map reads the item's lane and chunk, so Mosaic's own pipeline fetches the
+next item's ``[kvh, chunk, hd]`` K and V blocks, of this lane or the next
+one, while this one is scored. The running max / denominator /
+accumulator are the lane's being walked; its ring item (which names the
+chunk before it again, so nothing is fetched) normalises and writes the
+lane's output block. A lane that is not on the list costs nothing, not
+even an empty grid step, and its output row is never written (the caller
+makes it 0; a freed lane's device length keeps counting up: the kernel
+never sees it). The cost follows the live lanes' own chunks, not lanes x
+the region's capacity.
 
-Round-5 knob: ``slot_block`` processes SB slots per grid invocation
-(grid (B/SB, chunks)) — measured per-invocation cost is dominated by
-fixed overhead (grid sequencing + DMA setup + Mosaic's serialization of
-small batched dots), so fewer, fatter invocations close the gap to the
-bandwidth roofline. The DMA-skip index then clamps to the LONGEST live
-context in the slot group (short slots ride along). ``chunk`` and
-``slot_block`` are static arguments (``ops.attention.DecodeAttention``
-carries them for sweeps); nothing is read from the environment.
+Why (chip numbers: PERF.md section 6, PR 53). Until PR 53 the grid was
+``(B, S / chunk + 1)``: 72 steps a layer at 8 lanes of 4096 rows, 864 at
+96, whatever was live, each ~0.2 us when it did nothing, and every lane's
+first chunk was DMA'd because a block index cannot be "none".
+On the v5e (``tools/latent_decode_bench.py --kind dense``, a layer-call,
+old -> this): 8 lanes x 8 K/V heads with 2 live at 300 rows 44 -> 10 us
+(0 live: 5; all 8 live: 34), at 3000 rows 153 -> 38 (all live 158 ->
+157: never slower); a 2-head shard of 16 lanes, 13 live, 47 -> 23; 96
+lanes x 1 head, 48 live, 249 -> 87; 16 lanes of 32768 rows, 5 live at
+6000, 348 -> 67. About 2 us an item at 1-2 MB and 5 us fixed. In the
+chat cell the kernel fell from 0.40 to 0.10 s of a 3 s span and the
+decode step from 11.77 to 10.46 ms. A live lane's output differs from the
+old grid's by at most one last bit of bfloat16 where a shard holds more
+than one K/V head (the same ``accumulate``; Mosaic schedules it anew).
+A first form of the list kernel left K and V in HBM (``memory_space=ANY``)
+and double-buffered the blocks by hand, as ``ops/latent_decode.py`` does
+for 640-wide rows: Mosaic slices no minor dim below its 128-wide tiling
+in a hand-made DMA, so a head of 64 could not compile. Blocks fetched by
+the pipeline can hold any head size.
 
 Partitioning: GSPMD cannot split a Mosaic call, so under a mesh the
 caller maps this function per shard over ``tp`` (``ops/attention.py``):
-heads are independent, each shard sees its own kv heads and needs no
-collective.
+heads are independent, each shard walks the same (replicated) list over
+its own kv heads and needs no collective.
 
 Int8 ctx (scales given): the int8 payload widens exactly to the compute
 dtype in VMEM and the per-group f32 scales multiply the score /
 probability COLUMNS (q.(s k) == s (q.k)), never the [chunk, hd] tiles —
 Mosaic refuses the sublane-splitting reshape a tile-wise dequant needs
-("infer-vector-layout: unsupported shape cast"). Scale blocks are
-[.., 1, chunk/group]: a block's last two dims must be (8, 128)-divisible
-or equal to the array's.
+("infer-vector-layout: unsupported shape cast"). The layer's scales ride
+in as one VMEM block ``[1, lanes, n_chunks, chunk/group]`` (a block's last
+two dims must be (8, 128)-divisible or equal to the array's, and a DMA
+cannot slice a minor dim below its tiling, so an item cannot fetch its
+own 32 bytes); an item reads its lane's and chunk's row of it.
 
 Position semantics: ctx_kv[l, :, b, p] holds position p of slot b, valid
 while p < ring_base[b]; ring[l, :, b, r] holds position ring_base[b]+r,
 valid while < ctx_lens[b] (the current token INCLUDED — the decode step
-writes its KV to the ring before attending). Chunks beyond a slot
-group's live context repeat the previous block index, so their DMA is
-elided — cost tracks the LIVE context, not the padded capacity.
+writes its KV to the ring before attending).
 
 This replaces what vLLM's paged-attention CUDA kernel does for the
 reference (SURVEY.md §7 "Paged attention on TPU" hard part); paging moved
@@ -55,7 +75,6 @@ from __future__ import annotations
 
 import functools
 import logging
-import math
 
 import jax
 import jax.numpy as jnp
@@ -66,11 +85,12 @@ logger = logging.getLogger(__name__)
 
 NEG_INF = -1e30
 
+# region rows a work item holds. benchmarks/peaks.py counts a live lane's
+# context in whole chunks of this many rows
 DEFAULT_CHUNK = 512
-DEFAULT_SLOT_BLOCK = 1
 
-# chunk floor for the divisor fallback: below this the grid degenerates
-# into the per-invocation-overhead regime the kernel exists to avoid
+# chunk floor for the divisor fallback: below this an item's fixed cost
+# (its DMAs' latency, the loop's bookkeeping) outweighs its rows
 CHUNK_FLOOR = 128
 
 _chunk_warned: set = set()
@@ -82,9 +102,8 @@ def _pick_chunk(S: int, want: int, step: int = 1) -> int:
     group), else the largest divisor of S ≤ want that is a multiple of
     step, promoted to the smallest divisor ≥ CHUNK_FLOOR if the best
     candidate falls below it. The old ``gcd(want, S)`` fallback could
-    silently pick a tiny divisor (S=520 → chunk 8 → 66 grid invocations
-    per layer — the round-3 overhead cliff); log once per config when
-    the request is adjusted."""
+    silently pick a tiny divisor (S=520 → chunk 8 → 66 items a lane);
+    log once per config when the request is adjusted."""
     want = max(1, min(want, S))
     if S % want == 0 and want % step == 0:
         return want
@@ -106,48 +125,62 @@ def _pick_chunk(S: int, want: int, step: int = 1) -> int:
     return best
 
 
+def chunk_rows(S: int, chunk: int = 0, group: int = 1) -> int:
+    """Region rows one work item holds: ``DEFAULT_CHUNK`` (or the one a
+    ``DecodeAttention`` names), fitted to a region of S rows and to whole
+    scale groups of an int8 region (``_pick_chunk``)."""
+    return _pick_chunk(S, chunk if chunk > 0 else DEFAULT_CHUNK, group)
+
+
 def _kernel(
     # scalar prefetch
     layer_ref,   # [1] i32
+    total_ref,   # [1] i32 — work items: every live lane's chunks + 1
+    lane_ref,    # [W] i32 — work item -> lane
+    chunk_ref,   # [W] i32 — work item -> chunk of that lane; n_chunks =
+                 #           the lane's ring, its last item
+    fetch_ref,   # [W] i32 — work item -> the chunk its K / V blocks hold
     ctx_sm,      # [B] i32
     base_sm,     # [B] i32 — ring base positions
-    # blocks
-    q_ref,       # [SB, nkv, G, HD]
-    k_ref,       # [1, nkv, SB, CHUNK, HD] — int8 when quantized
+    # blocks, the item's
+    q_ref,       # [1, nkv, G, HD]
+    k_ref,       # [1, nkv, 1, CHUNK, HD] — int8 when quantized
     v_ref,
-    # quantized only: ksc_ref/vsc_ref [1, SB, 1, 1, CHUNK//group] f32
-    # then:
-    # rk_ref,    # [1, nkv, SB, R, HD]   ring lanes (compute dtype)
+    # quantized only: ksc_ref/vsc_ref [1, B(+1), n_chunks, CHUNK//group]
+    # f32, the layer's; then:
+    # rk_ref,    # [1, nkv, 1, R, HD]   the lane's ring (compute dtype)
     # rv_ref,
-    # o_ref,     # [SB, nkv, G, HD]
+    # o_ref,     # [1, nkv, G, HD]
     # scratch:
-    # m_ref,     # [SB, nkv, G, 128] f32 running max
-    # l_ref,     # [SB, nkv, G, 128] f32 running denom
-    # acc_ref,   # [SB, nkv, G, HD] f32 running numerator
+    # m_ref,     # [nkv, G, 128] f32 running max of the lane being walked
+    # l_ref,     # [nkv, G, 128] f32 running denom
+    # acc_ref,   # [nkv, G, HD] f32 running numerator
     *refs,
     scale: float,
     chunk: int,
-    sb: int,
+    n_chunks: int,
     quantized: bool,
 ):
     if quantized:
         ksc_ref, vsc_ref = refs[:2]
-        rk_ref, rv_ref, o_ref, m_ref, l_ref, acc_ref = refs[2:]
-    else:
-        ksc_ref = vsc_ref = None
-        rk_ref, rv_ref, o_ref, m_ref, l_ref, acc_ref = refs
-    s_idx = pl.program_id(0)
-    i = pl.program_id(1)
-    n_chunks = pl.num_programs(1)  # ctx chunks + 1 ring chunk
-    is_ring = i == n_chunks - 1
+        refs = refs[2:]
+    rk_ref, rv_ref, o_ref, m_ref, l_ref, acc_ref = refs
+    w = pl.program_id(0)
+    lane, j = lane_ref[w], chunk_ref[w]
+    ctx, base = ctx_sm[lane], base_sm[lane]
+    # an empty list still runs one grid step: it does nothing
+    listed = total_ref[0] > 0
 
-    @pl.when(i == 0)
+    def reset():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(w == 0)
     def _():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        reset()
 
-    def accumulate(j, k, v, start, limit, length, k_sc=None, v_sc=None):
+    def accumulate(k, v, start, limit, length, k_sc=None, v_sc=None):
         # k/v [nkv, length, HD]; positions start + iota valid below limit.
         # k_sc/v_sc [1, length] f32: per-position dequant scales of an
         # int8 chunk, applied to the score / probability COLUMNS —
@@ -158,7 +191,7 @@ def _kernel(
         pos = start + jax.lax.broadcasted_iota(
             jnp.int32, (1, 1, length), 2)
         valid = pos < limit
-        q = q_ref[j]                                       # [nkv, G, HD]
+        q = q_ref[0]                                       # [nkv, G, HD]
         s = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
@@ -166,21 +199,21 @@ def _kernel(
         if k_sc is not None:
             s = s * k_sc[None]
         s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_ref[j, :, :, :1]
+        m_prev = m_ref[:, :, :1]
         row_max = jnp.max(s, axis=2, keepdims=True)
         m_new = jnp.maximum(m_prev, row_max)
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_new = l_ref[j, :, :, :1] * alpha + jnp.sum(
+        l_new = l_ref[:, :, :1] * alpha + jnp.sum(
             p, axis=2, keepdims=True)
         if v_sc is not None:
             p = p * v_sc[None]
-        acc_ref[j] = acc_ref[j] * alpha + jax.lax.dot_general(
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )
-        m_ref[j] = jnp.broadcast_to(m_new, m_ref.shape[1:])
-        l_ref[j] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
     def per_position(sc):
         # [1, chunk//grp] group scales -> [1, chunk] along the lane axis:
@@ -193,47 +226,35 @@ def _kernel(
             out = jnp.where(pos >= g * grp, sc[:, g:g + 1], out)
         return out
 
-    for j in range(sb):
-        b = s_idx * sb + j
-        ctx = ctx_sm[b]
-        base = base_sm[b]
-
-        # ctx chunk: positions [i*chunk, +chunk), valid below ring_base
-        @pl.when(jnp.logical_and(
-            jnp.logical_not(is_ring), i * chunk < base))
-        def _(j=j, ctx=ctx, base=base):
-            k = k_ref[0, :, j]                  # [nkv, chunk, HD]
-            v = v_ref[0, :, j]
-            k_sc = v_sc = None
-            if quantized:
-                # dequantize in VMEM, right after the DMA: the HBM
-                # stream was the int8 bytes. The payload widens to the
-                # compute dtype exactly (|int8| <= 127 fits bf16's
-                # mantissa); the f32 scales ride the score columns
-                k = k.astype(jnp.float32).astype(q_ref.dtype)
-                v = v.astype(jnp.float32).astype(q_ref.dtype)
-                k_sc = per_position(ksc_ref[0, j, 0])
-                v_sc = per_position(vsc_ref[0, j, 0])
-            accumulate(
-                j, k, v, i * chunk, jnp.minimum(base, ctx), chunk,
-                k_sc, v_sc,
-            )
-
-        # ring chunk: slot r holds position base + r, valid below ctx
-        @pl.when(is_ring)
-        def _(j=j, ctx=ctx, base=base):
-            accumulate(j, rk_ref[0, :, j], rv_ref[0, :, j], base, ctx,
-                       rk_ref.shape[3])
-
-    @pl.when(i == n_chunks - 1)
+    # ctx chunk: positions [j*chunk, +chunk), valid below ring_base
+    @pl.when(jnp.logical_and(listed, j < n_chunks))
     def _():
-        denom = jnp.maximum(l_ref[:, :, :, :1], 1e-30)
-        o_ref[:] = (acc_ref[:] / denom).astype(o_ref.dtype)
+        k, v = k_ref[0, :, 0], v_ref[0, :, 0]      # [nkv, chunk, HD]
+        k_sc = v_sc = None
+        if quantized:
+            # dequantize in VMEM, right after the DMA: the HBM stream
+            # was the int8 bytes. The payload widens to the compute
+            # dtype exactly (|int8| <= 127 fits bf16's mantissa); the
+            # f32 scales ride the score columns
+            k = k.astype(jnp.float32).astype(q_ref.dtype)
+            v = v.astype(jnp.float32).astype(q_ref.dtype)
+            k_sc = per_position(ksc_ref[0, lane, pl.ds(j, 1), :])
+            v_sc = per_position(vsc_ref[0, lane, pl.ds(j, 1), :])
+        accumulate(k, v, j * chunk, jnp.minimum(base, ctx), chunk,
+                   k_sc, v_sc)
+
+    # the lane's last item, its ring: slot r holds position base + r,
+    # valid below ctx
+    @pl.when(jnp.logical_and(listed, j == n_chunks))
+    def _():
+        accumulate(rk_ref[0, :, 0], rv_ref[0, :, 0], base, ctx,
+                   rk_ref.shape[3])
+        denom = jnp.maximum(l_ref[:, :, :1], 1e-30)
+        o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+        reset()
 
 
-@functools.partial(
-    jax.jit, static_argnames=("chunk", "interpret", "slot_block")
-)
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def flash_decode_attention(
     q: jnp.ndarray,          # [B, n_heads, HD]
     ctx_k: jnp.ndarray,      # [L, kvh, B(+1), S, HD] contiguous per-slot KV
@@ -243,108 +264,103 @@ def flash_decode_attention(
     layer: jnp.ndarray,      # scalar i32
     ctx_lens: jnp.ndarray,   # [B] i32 — context length INCL. current token
     ring_base: jnp.ndarray,  # [B] i32 — position held by ring slot 0
+    work: tuple,             # the work list: (lane_of, chunk_of, fetch_of,
+                             # total)
     chunk: int = 0,
     interpret: bool = False,
-    slot_block: int = 0,
     ctx_k_scale: jnp.ndarray | None = None,  # f32 [L, B(+1), S//group]
     ctx_v_scale: jnp.ndarray | None = None,  # (int8 ctx_k/ctx_v)
 ) -> jnp.ndarray:
-    """Flash decode attention over contiguous KV + ring. Returns
-    [B, n_heads, HD]. The current token's KV must already be in the ring
+    """Flash decode attention over contiguous KV + ring, for the lanes of
+    a work list. Returns [B, n_heads, HD]: a listed lane's attention; the
+    row of a lane that is not on the list is NEVER WRITTEN (the caller,
+    ``ops/attention.py: ctx_decode_attention``, which also builds the
+    list, makes it 0). The current token's KV must already be in the ring
     (position ctx-1 == ring_base + r for the step's ring slot r).
-    chunk/slot_block of 0 pick the defaults. With ctx scales given,
-    ctx_k/ctx_v are int8 and each chunk dequantizes in VMEM after its
-    DMA (half the live-context HBM bytes)."""
+    ``chunk`` is the chunk the list was built for. With ctx scales given,
+    ctx_k/ctx_v are int8 and each chunk dequantizes in VMEM after its DMA
+    (half the live-context HBM bytes)."""
     B, n_heads, hd = q.shape
     L, nkv, _, S, _ = ctx_k.shape
     R = ring_k.shape[3]
     g = n_heads // nkv
     quantized = ctx_k_scale is not None
-    if chunk <= 0:
-        chunk = DEFAULT_CHUNK
-    if slot_block <= 0:
-        slot_block = DEFAULT_SLOT_BLOCK
     # chunk must tile S exactly (and whole scale groups when quantized)
     group = S // ctx_k_scale.shape[2] if quantized else 1
-    chunk = _pick_chunk(S, chunk, group)
-    sb = math.gcd(slot_block, B)
-    scale = float(1.0 / (hd ** 0.5))
-    qg = q.reshape(B, nkv, g, hd)
+    chunk = chunk_rows(S, chunk, group)
     n_chunks = S // chunk
-    ctx_i32 = ctx_lens.astype(jnp.int32)
-    base_i32 = ring_base.astype(jnp.int32)
+    lane_of, chunk_of, fetch_of, total = work
+    if lane_of.shape[0] != B * (n_chunks + 1):
+        raise ValueError(
+            f"a work list of {lane_of.shape[0]} items was not built for "
+            f"{B} lanes of {n_chunks} chunks and a ring")
+    scale = float(1.0 / (hd ** 0.5))
+    i32 = jnp.int32
 
-    def q_map(s, i, layer, ctx, base):
-        return (s, 0, 0, 0)
+    def of_lane(w, layer, total, lane_of, *_):
+        return (lane_of[w], 0, 0, 0)
 
-    def _grp_live(s, base):
-        # chunks beyond the slot GROUP's longest live context repeat the
-        # previous index so the pipeline skips the (unused) DMA
-        # scalar loads only in index maps (SMEM): unrolled group max
-        grp_max = base[s * sb]
-        for j in range(1, sb):
-            grp_max = jnp.maximum(grp_max, base[s * sb + j])
-        return jnp.maximum((grp_max + chunk - 1) // chunk - 1, 0)
+    def kv_map(w, layer, total, lane_of, chunk_of, fetch_of, *_):
+        # a ring item names the chunk before it again: no DMA
+        return (layer[0], 0, lane_of[w], fetch_of[w], 0)
 
-    def kv_map(s, i, layer, ctx, base):
-        return (layer[0], 0, s, jnp.minimum(i, _grp_live(s, base)), 0)
+    def sc_map(w, layer, *_):
+        return (layer[0], 0, 0, 0)
 
-    def sc_map(s, i, layer, ctx, base):
-        return (layer[0], s, jnp.minimum(i, _grp_live(s, base)), 0, 0)
-
-    def ring_map(s, i, layer, ctx, base):
-        return (layer[0], 0, s, 0, 0)
+    def ring_map(w, layer, total, lane_of, *_):
+        return (layer[0], 0, lane_of[w], 0, 0)
 
     in_specs = [
-        pl.BlockSpec((sb, nkv, g, hd), q_map),
-        pl.BlockSpec((1, nkv, sb, chunk, hd), kv_map),
-        pl.BlockSpec((1, nkv, sb, chunk, hd), kv_map),
+        pl.BlockSpec((1, nkv, g, hd), of_lane),
+        pl.BlockSpec((1, nkv, 1, chunk, hd), kv_map),
+        pl.BlockSpec((1, nkv, 1, chunk, hd), kv_map),
     ]
-    inputs = [qg, ctx_k, ctx_v]
+    inputs = [q.reshape(B, nkv, g, hd), ctx_k, ctx_v]
     if quantized:
-        # Mosaic wants a block's last two dims (8, 128)-divisible or equal
-        # to the array's: view the scale row as [n_chunks, 1, chunk/group]
-        # so one chunk's scales are a whole (1, chunk/group) minor tile
+        # a block's last two dims must be (8, 128)-divisible or equal to
+        # the array's: the layer's scales, viewed [lanes, n_chunks,
+        # chunk/group], ride in once a call and an item reads its lane's
+        # and chunk's row
         ngc = chunk // group
-        sc_shape = (L, ctx_k_scale.shape[1], n_chunks, 1, ngc)
-        in_specs += [
-            pl.BlockSpec((1, sb, 1, 1, ngc), sc_map),
-            pl.BlockSpec((1, sb, 1, 1, ngc), sc_map),
-        ]
-        inputs += [ctx_k_scale.reshape(sc_shape),
-                   ctx_v_scale.reshape(sc_shape)]
-    in_specs += [
-        pl.BlockSpec((1, nkv, sb, R, hd), ring_map),
-        pl.BlockSpec((1, nkv, sb, R, hd), ring_map),
-    ]
+        lanes = ctx_k_scale.shape[1]
+        in_specs += [pl.BlockSpec((1, lanes, n_chunks, ngc), sc_map)] * 2
+        inputs += [ctx_k_scale.reshape(L, lanes, n_chunks, ngc),
+                   ctx_v_scale.reshape(L, lanes, n_chunks, ngc)]
+    in_specs += [pl.BlockSpec((1, nkv, 1, R, hd), ring_map)] * 2
     inputs += [ring_k, ring_v]
 
     out = pl.pallas_call(
         functools.partial(
-            _kernel, scale=scale, chunk=chunk, sb=sb, quantized=quantized
+            _kernel, scale=scale, chunk=chunk, n_chunks=n_chunks,
+            quantized=quantized,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(B // sb, n_chunks + 1),
+            num_scalar_prefetch=7,
+            # the list's length, a traced value, is the grid's bound: an
+            # item past the list costs nothing, not even an empty step
+            # (never an empty grid: what a pipeline with no step writes
+            # back is nobody's promise)
+            grid=(jnp.maximum(total[0], 1),),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((sb, nkv, g, hd), q_map),
+            out_specs=pl.BlockSpec((1, nkv, g, hd), of_lane),
             scratch_shapes=[
-                pltpu.VMEM((sb, nkv, g, 128), jnp.float32),
-                pltpu.VMEM((sb, nkv, g, 128), jnp.float32),
-                pltpu.VMEM((sb, nkv, g, hd), jnp.float32),
+                pltpu.VMEM((nkv, g, 128), jnp.float32),
+                pltpu.VMEM((nkv, g, 128), jnp.float32),
+                pltpu.VMEM((nkv, g, hd), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, nkv, g, hd), q.dtype),
-        # generous scoped-vmem budget for the chunked block pipeline
         compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            # generous scoped-vmem budget for the chunked block pipeline
             vmem_limit_bytes=64 * 1024 * 1024,
         ),
         interpret=interpret,
+        name="flash_decode_attention",
     )(
-        jnp.asarray(layer, jnp.int32).reshape(1),
-        ctx_i32,
-        base_i32,
-        *inputs,
+        jnp.asarray(layer, i32).reshape(1), total.astype(i32).reshape(1),
+        lane_of.astype(i32), chunk_of.astype(i32), fetch_of.astype(i32),
+        ctx_lens.astype(i32), ring_base.astype(i32), *inputs,
     )
     return out.reshape(B, n_heads, hd)
 
@@ -360,11 +376,14 @@ def flash_decode_attention_reference(
     ring_base: jnp.ndarray,
     ctx_k_scale: jnp.ndarray | None = None,
     ctx_v_scale: jnp.ndarray | None = None,
+    live: jnp.ndarray | None = None,   # [B] bool; None = every lane
 ) -> jnp.ndarray:
     """Pure-jnp equivalent (CPU tests / kernel parity checks). With ctx
     scales given, ctx_k/ctx_v are int8 per-group quantized — dequantize
     them to the query dtype first (matching the kernel's in-VMEM
-    dequant, so parity tests cover the quantized math too)."""
+    dequant, so parity tests cover the quantized math too). A lane that
+    is not ``live`` comes back 0, as from the kernel; every lane's whole
+    region is scored all the same."""
     B, n_heads, hd = q.shape
     L, nkv, _, S, _ = ctx_k.shape
     R = ring_k.shape[3]
@@ -398,4 +417,6 @@ def flash_decode_attention_reference(
         "bns,nbsh->bnh", probs.astype(v.dtype), v,
         preferred_element_type=jnp.float32,
     )
+    if live is not None:
+        out = jnp.where(live[:, None, None], out, 0.0)
     return out.astype(q.dtype)

@@ -8,10 +8,11 @@ write ring (models/llama.py: init_ring).
 
 A running softmax over chunks of the region, then over the ring. The
 region part has two implementations behind one ``DecodeAttention``
-(ops/attention.py), and ``region_trips`` is the one place that says how
-many chunks a lane reads in either: ``ceil(rows below its ring base /
-chunk)`` for a lane that holds a request, none for one that does not (a
-freed lane's device length keeps counting up, engine.py's round body).
+(ops/attention.py), and that module's ``region_trips`` is the one place
+that says how many chunks a lane reads in either, as in the dense
+kernel: ``ceil(rows below its ring base / chunk)`` for a lane that holds
+a request, none for one that does not (a freed lane's device length
+keeps counting up, engine.py's round body).
 
   - the Pallas TPU kernel (``_region_kernel``): ONE invocation a layer
     walks a flat work list of (lane, chunk) pairs, so its cost follows
@@ -23,8 +24,9 @@ freed lane's device length keeps counting up, engine.py's round body).
     on the MXU against the lane's ``[heads, row]`` queries, and the
     running max / denominator / accumulator of every lane live in the
     kernel's VMEM outputs. A grid of lanes x chunks would pay ~0.4 us a
-    dead step (ops/flash_decode.py), 1024 steps a layer at 16 lanes of
-    16384 rows;
+    dead step, 1024 steps a layer at 16 lanes of 16384 rows (the dense
+    kernel of ops/flash_decode.py had such a grid until PR 53, and walks
+    a list like this one since);
   - the pure-XLA loop (``_region_reference``): what the kernel is tested
     against and what the CPU test meshes run. Its trip count is a traced
     value too, but ONE for the batch: every lane is read to the longest
@@ -39,7 +41,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -47,6 +48,9 @@ from dynamo_tpu.ops.attention import (
     PALLAS_INTERPRET,
     REFERENCE_IMPL,
     DecodeAttention,
+    flat_items,
+    region_trips,
+    round_rows as _round_rows,
 )
 
 NEG_INF = -1e30
@@ -61,14 +65,6 @@ def chunk_rows(S: int, chunk: int = 0) -> int:
     return min(chunk or CHUNK, S)
 
 
-def region_trips(below, live, cb: int):
-    """Chunks of the region each lane's decode attention reads: ``below``
-    [B] rows under the lane's ring base, ``live`` [B] whether the lane
-    holds a request, ``cb`` rows a chunk. numpy in (the engine's mirror),
-    numpy out; traced in, traced out."""
-    return (below + (cb - 1)) // cb * live
-
-
 def region_rows_read(impl: str, trips, cb: int) -> int:
     """Region rows one decode step's attention reads, from the host's
     ``region_trips``: the kernel reads each lane's own chunks, the XLA
@@ -80,19 +76,13 @@ def region_rows_read(impl: str, trips, cb: int) -> int:
 
 def round_rows(attn: DecodeAttention, ctx_lens, live, n_steps: int,
                max_context: int) -> tuple[int, int]:
-    """The host's mirror of one dispatched round, a layer: (region rows
-    its steps' attention read, rows that were some live lane's own).
-    ``ctx_lens`` [B] the lanes' lengths at dispatch (the region's rows lie
-    below the round's ring base, ctx - 1), ``live`` [B] bool the lanes
-    dispatched: each one's rows in whole chunks under the kernel, every
-    lane to the longest of them under the XLA loop (``region_trips``,
-    which the attention's wrapper calls too)."""
-    base = np.maximum(np.asarray(ctx_lens) - 1, 0)
-    live = np.asarray(live, bool)
+    """The host's mirror of one dispatched round, a layer
+    (``attention.round_rows``): each live lane's rows in whole chunks
+    under the kernel, every lane to the longest of them under the XLA
+    loop."""
     cb = chunk_rows(max_context, attn.chunk)
-    trips = region_trips(base, live, cb)
-    return (n_steps * region_rows_read(attn.impl, trips, cb),
-            n_steps * int(base[live].sum()))
+    return _round_rows(ctx_lens, live, n_steps, cb,
+                       lambda trips: region_rows_read(attn.impl, trips, cb))
 
 
 def _score(carry, q, rows, ok, v_width):
@@ -220,12 +210,7 @@ def _region_pallas(q, ctx, layer, below, trips, v_width, cb, interpret):
     S = ctx.shape[3]
     i32 = jnp.int32
     # the flat work list: lane b's chunks 0..trips[b]-1, lane after lane
-    # (the same for every layer of a step: XLA keeps one)
-    ends = jnp.cumsum(trips)
-    w = jnp.arange(B * -(-S // cb), dtype=i32)
-    lane_of = jnp.minimum(
-        jnp.sum(w[:, None] >= ends[None, :], axis=1), B - 1).astype(i32)
-    chunk_of = w - (ends - trips)[lane_of]
+    lane_of, chunk_of, ends = flat_items(trips, B * -(-S // cb))
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     stat = jax.ShapeDtypeStruct((B, nh, 128), jnp.float32)
@@ -241,8 +226,8 @@ def _region_pallas(q, ctx, layer, below, trips, v_width, cb, interpret):
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
         name="latent_decode_region",
-    )(layer.reshape(1), ends[-1:].astype(i32), lane_of, chunk_of.astype(i32),
-      below, q, ctx)
+    )(layer.reshape(1), ends[-1:].astype(i32), lane_of, chunk_of, below, q,
+      ctx)
     return m[..., 0], l[..., 0], acc
 
 

@@ -533,14 +533,16 @@ PREFILL_CONTINUED = (
     "context already in the region (q_start > 0)")
 DECODE_ATTN_ROWS_READ = (
     "dynamo_decode_attn_rows_read",
-    "latent-attention models: region rows a dispatched decode round's "
-    "attention read a layer: steps x the dispatched lanes' own rows in "
-    "whole chunks under the TPU kernel; steps x lanes x the longest "
-    "dispatched lane under the XLA loop of the CPU meshes")
+    "region rows a dispatched decode round's attention read a layer "
+    "(latent rows, or K and V rows of the dense decoder and the hybrid "
+    "block's attention layers): steps x the dispatched lanes' own rows "
+    "in whole chunks under a TPU kernel's work list; on the CPU meshes "
+    "steps x lanes x the longest dispatched lane (the latent XLA loop) "
+    "or x the whole region (the dense jnp reference)")
 DECODE_ATTN_ROWS_LIVE = (
     "dynamo_decode_attn_rows_live",
-    "latent-attention models: region rows of that round that were some "
-    "live lane's own context: steps x the sum of the lanes' lengths")
+    "region rows of that round that were some live lane's own context: "
+    "steps x the sum of the lanes' lengths")
 KV_ROW_BYTES = ("dynamo_kv_row_bytes",
                 "bytes one token holds in the ctx region, all layers "
                 "(observed once, at engine start)")

@@ -336,6 +336,31 @@ def test_the_mamba2_states_stepped_are_read_over_the_live_lanes(
         assert got == pytest.approx(ratio)   # ~3.1, and 1.00
 
 
+@pytest.mark.parametrize("read,own,share", [
+    # 100 rounds of 4 steps, 1.9 of 8 lanes live at ~330 rows each: a grid
+    # over every lane's chunks (had it counted) reads every lane's first
+    # 512-row chunk, the work list the live lanes' alone
+    (100 * 4 * 8 * 512.0, 100 * 4 * 1.9 * 330, 1.9 * 330 / (8 * 512) * 100),
+    (100 * 4 * 1.9 * 512, 100 * 4 * 1.9 * 330, 330 / 512 * 100),
+    # a program without the counters (the parent), a window without a round
+    (None, None, None),
+    (0.0, 0.0, None),
+], ids=["every-lane", "the-work-list", "no-counter", "no-rounds"])
+def test_the_chat_cells_decode_attention_rows_are_read_over_the_live_lanes(
+        read, own, share):
+    hists = {name: {"sum": total, "count": 100} for name, total in (
+        ("dynamo_decode_attn_rows_read", read),
+        ("dynamo_decode_attn_rows_live", own)) if total is not None}
+    sources = _reader_sources(hists, {}, "mistral7b-w8")
+    got = sources["byname"].module_with(
+        os.path.join(REPO, "benchmarks", "layer_metrics"),
+        "step.decode_attn_live_share.chat-open", "read").read(sources)
+    if share is None:
+        assert got is None
+    else:
+        assert got == pytest.approx(share)   # ~15 %, and 64 %
+
+
 def test_the_reasoning_cells_counter_readers_count_expert_layers_only():
     """Ten of the twelve held layers route: the held experts touched a
     step are read over 10 x 64, and the byte count's parts say what a step

@@ -909,6 +909,37 @@ def test_counters_of_the_four_stream_block(served_streams, case):
         assert (dev_lens[2:] == 1 + rounds * e.flush_every).all()
 
 
+def test_the_dense_decoder_counts_its_attention_rows_too():
+    """PR 53: the dense decoder mirrors what its decode attention reads,
+    a layer, into the two histograms the latent block feeds: one
+    observation a dispatched round; under the jnp reference of the CPU
+    meshes every lane's whole region, the live lanes' own rows beside."""
+    from dynamo_tpu.models import llama
+
+    cfg = ModelConfig.tiny()
+    params = llama.init_params(cfg, 3)
+
+    async def scenario():
+        eng = TpuEngine(cfg, EngineConfig(
+            num_pages=64, page_size=PS, max_pages_per_seq=8,
+            max_decode_slots=4, prefill_buckets=(32,),
+            cache_dtype="float32"), params=params,
+            mesh_config=MeshConfig(tp=1))
+        eng.start()
+        h0 = _hists(eng)
+        await _one(eng, [7 + i for i in range(20)], osl=9)
+        h1 = await _settled(eng)
+        await eng.stop()
+        return h0, h1, eng.ecfg
+    h0, h1, e = asyncio.run(scenario())
+    read, live = _delta(h0, h1, ROWS_READ), _delta(h0, h1, ROWS_LIVE)
+    rounds = _delta(h0, h1, ROWS_READ, "count")
+    assert rounds == _delta(h0, h1, LIVE, "count") > 0
+    assert read == rounds * e.flush_every * e.max_decode_slots * e.max_context
+    # one lane, 20 rows at the first round and flush_every more a round
+    assert 20 * e.flush_every * rounds <= live < read
+
+
 @pytest.mark.parametrize("impl,read", [("pallas", 4 * (3 + 1) * 512),
                                        ("reference", 4 * 4 * 3 * 512)])
 def test_decode_attn_rows_mirror_by_hand(impl, read):

@@ -10,6 +10,14 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from dynamo_tpu.ops.attention import (
+    PALLAS_INTERPRET,
+    REFERENCE,
+    DecodeAttention,
+    ctx_decode_attention,
+    dense_round_rows,
+    flat_items,
+)
 from dynamo_tpu.ops.flash_decode import (
     _pick_chunk,
     flash_decode_attention,
@@ -31,14 +39,19 @@ def data():
     return q, ck, cv, rk, rv
 
 
-def both(data, ctx, base, chunk, layer=0):
+def kernel(q, ck, cv, rk, rv, layer, ctx, base, *scales, chunk, live=None):
+    """The kernel, interpreted, as every caller reaches it: through the
+    wrapper that builds its work list."""
+    return ctx_decode_attention(
+        DecodeAttention(PALLAS_INTERPRET, chunk=chunk), q, ck, cv, rk, rv,
+        jnp.int32(layer), ctx, base, *scales, live=live)
+
+
+def both(data, ctx, base, chunk, layer=0, live=None):
     q, ck, cv, rk, rv = data
-    got = flash_decode_attention(
-        q, ck, cv, rk, rv, jnp.int32(layer), ctx, base,
-        chunk=chunk, interpret=True,
-    )
+    got = kernel(q, ck, cv, rk, rv, layer, ctx, base, chunk=chunk, live=live)
     want = flash_decode_attention_reference(
-        q, ck, cv, rk, rv, jnp.int32(layer), ctx, base
+        q, ck, cv, rk, rv, jnp.int32(layer), ctx, base, live=live
     )
     return np.asarray(got), np.asarray(want)
 
@@ -68,10 +81,7 @@ def test_single_token_context_is_v_row(data):
     q, ck, cv, rk, rv = data
     base = jnp.zeros(B, jnp.int32)
     ctx = jnp.ones(B, jnp.int32)
-    got = flash_decode_attention(
-        q, ck, cv, rk, rv, jnp.int32(1), ctx, base,
-        chunk=32, interpret=True,
-    )
+    got = kernel(q, ck, cv, rk, rv, 1, ctx, base, chunk=32)
     for b in range(B):
         for n in range(NH):
             h = n // (NH // NKV)
@@ -126,11 +136,8 @@ def test_int8_kernel_matches_reference(data, group, chunk):
         base = jnp.asarray(bases, jnp.int32)
         ctx = base + 2
         for layer in (0, L - 1):
-            got = flash_decode_attention(
-                q, ck_q, cv_q, rk, rv, jnp.int32(layer), ctx, base,
-                chunk=chunk, interpret=True,
-                ctx_k_scale=ks, ctx_v_scale=vs,
-            )
+            got = kernel(q, ck_q, cv_q, rk, rv, layer, ctx, base, ks, vs,
+                         chunk=chunk)
             want = flash_decode_attention_reference(
                 q, ck_q, cv_q, rk, rv, jnp.int32(layer), ctx, base,
                 ctx_k_scale=ks, ctx_v_scale=vs,
@@ -139,24 +146,75 @@ def test_int8_kernel_matches_reference(data, group, chunk):
                 np.asarray(got), np.asarray(want), atol=1e-2, rtol=0)
 
 
-@pytest.mark.parametrize("sb", [2, 4])
-def test_int8_kernel_slot_blocked(data, sb):
-    """slot_block > 1 groups lanes per grid invocation; the quantized
-    DMA-skip/scale index math must clamp identically."""
-    q, ck_q, cv_q, rk, rv, ks, vs = _quant_args(data, 16)
-    base = jnp.asarray([3, 17, 31, 59], jnp.int32)
-    ctx = base + 2
-    got = flash_decode_attention(
-        q, ck_q, cv_q, rk, rv, jnp.int32(1), ctx, base,
-        chunk=16, slot_block=sb, interpret=True,
-        ctx_k_scale=ks, ctx_v_scale=vs,
-    )
-    want = flash_decode_attention_reference(
-        q, ck_q, cv_q, rk, rv, jnp.int32(1), ctx, base,
-        ctx_k_scale=ks, ctx_v_scale=vs,
-    )
-    np.testing.assert_allclose(
-        np.asarray(got), np.asarray(want), atol=1e-2, rtol=0)
+# --- the work list (PR 53) --------------------------------------------
+# contexts at 0 / 1 / chunk - 1 / chunk / chunk + 1 / S rows of the region
+# (chunk 16, S 64) and what is live of the four lanes. A lane that is not
+# live holds a stale length, as a freed lane of the engine does.
+_C = 16
+WORK_LISTS = {
+    "no_lane_live": ([40, 63, 17, 5], [0, 0, 0, 0]),
+    "one_live_among_stale_lengths": ([60, 64, 3, 64], [0, 0, 1, 0]),
+    "every_lane_live": ([1, 15, 31, 60], [1, 1, 1, 1]),
+    "rows_0_1_chunk-1_chunk": ([0, 1, _C - 1, _C], [1, 1, 1, 1]),
+    "rows_chunk+1_S_and_holes": ([_C + 1, S, 2 * _C, S], [1, 1, 0, 1]),
+    "ring_only_contexts": ([0, 0, 0, 0], [1, 0, 1, 1]),
+    "the_last_lane_alone": ([64, 64, 64, 33], [0, 0, 0, 1]),
+    "a_whole_region_every_lane": ([S, S, S, S], [1, 1, 1, 1]),
+}
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["dense", "int8"])
+@pytest.mark.parametrize("case", sorted(WORK_LISTS))
+def test_the_kernel_follows_its_work_list(data, case, quant):
+    """One invocation a layer walks the LIVE lanes' chunks, ascending,
+    then each one's ring: a live lane reads what the reference reads, a
+    lane that is not on the list comes back exactly 0 whatever its stale
+    length, and an empty list starts nothing and returns zeros."""
+    below, live = (np.asarray(x) for x in WORK_LISTS[case])
+    live = live.astype(bool)
+    base = jnp.asarray(below, jnp.int32)
+    ctx = base + jnp.asarray([1, 2, 3, 4], jnp.int32)   # ring occupancy
+    args, tol = data, dict(rtol=5e-3, atol=5e-3)
+    if quant:
+        args, tol = _quant_args(data, 16), dict(atol=1e-2, rtol=0)
+    got = np.asarray(kernel(*args[:5], 1, ctx, base, *args[5:], chunk=_C,
+                            live=jnp.asarray(live)))
+    want = np.asarray(flash_decode_attention_reference(
+        *args[:5], jnp.int32(1), ctx, base, *args[5:]))
+    np.testing.assert_allclose(got[live], want[live], **tol)
+    assert not got[~live].any()
+    if live.all():
+        # None = every lane (the kernel tests' form)
+        np.testing.assert_array_equal(
+            got, np.asarray(kernel(*args[:5], 1, ctx, base, *args[5:],
+                                   chunk=_C)))
+
+
+def test_the_work_list_by_hand():
+    """``flat_items``: lane after lane, a lane's items ascending; the
+    kernel's wrapper marks a live lane's last item (its ring) with the
+    region's chunk count. And the host's mirror of what the list reads:
+    whole chunks a live lane under the kernel, every lane's whole region
+    under the jnp reference."""
+    lane_of, item_of, ends = flat_items(jnp.asarray([2, 0, 3, 1]), 12)
+    assert np.asarray(ends).tolist() == [2, 2, 5, 6]    # 6 items in all
+    assert np.asarray(lane_of)[:6].tolist() == [0, 0, 2, 2, 2, 3]
+    assert np.asarray(item_of)[:6].tolist() == [0, 1, 0, 1, 2, 0]
+    assert not np.asarray(flat_items(jnp.zeros(4, jnp.int32), 12)[2]).any()
+    with pytest.raises(ValueError, match="work list of 7 items"):
+        flash_decode_attention(
+            jnp.zeros((B, NH, HD)), jnp.zeros((L, NKV, B + 1, S, HD)),
+            jnp.zeros((L, NKV, B + 1, S, HD)), jnp.zeros((L, NKV, B, R, HD)),
+            jnp.zeros((L, NKV, B, R, HD)), jnp.int32(0),
+            jnp.ones(B, jnp.int32), jnp.zeros(B, jnp.int32),
+            (jnp.zeros(7, jnp.int32),) * 3 + (jnp.zeros(1, jnp.int32),),
+            chunk=_C, interpret=True)
+    lens = np.array([1301, 1, 513, 2000], np.int32)
+    live = np.array([True, False, True, False])
+    assert dense_round_rows(DecodeAttention(PALLAS_INTERPRET), lens, live, 4,
+                            2048) == (4 * (3 + 1) * 512, 4 * (1300 + 512))
+    assert dense_round_rows(REFERENCE, lens, live, 4, 2048) == (
+        4 * 4 * 2048, 4 * (1300 + 512))
 
 
 def test_int8_dequant_error_bound(data):

@@ -14,6 +14,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from dynamo_tpu.models import llama
@@ -161,8 +162,15 @@ def test_what_the_state_says_of_itself(family):
                                       else 1)
     decode = llama.decode_mirror(c, 128, 4, REFERENCE)
     prefill = llama.prefill_mirror(c)
-    assert (decode is not None) == (family in (
-        "latent", "latent_mhc", "lightning_sparse", "kda_latent"))
+    # every family with an attention layer mirrors its region reads: the
+    # latent rows', the sparse layers', or (PR 53) the dense K and V rows'
+    assert decode is not None
+    live = np.array([True, False, True, False, False, False])
+    seen = dict(decode(np.array([41, 9, 17, 300, 1, 1], np.int32), live, 4))
+    if family in ("dense", "mamba2", "mamba1"):
+        # the jnp reference scores every lane's whole 128-row region
+        assert seen == {tmetrics.DECODE_ATTN_ROWS_READ[0]: 4 * 6 * 128,
+                        tmetrics.DECODE_ATTN_ROWS_LIVE[0]: 4 * (40 + 16)}
     assert (prefill is not None) == (family in ("lightning_sparse",
                                                 "mamba1"))
 

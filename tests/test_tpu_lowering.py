@@ -35,7 +35,6 @@ from dynamo_tpu.ops.attention import (
     ctx_decode_attention,
     decode_attention_for,
 )
-from dynamo_tpu.ops.flash_decode import flash_decode_attention
 from dynamo_tpu.parallel.mesh import MeshConfig, make_mesh
 
 # the CLI's default engine sizes (EngineConfig): 8 slots, 4096 context,
@@ -55,20 +54,22 @@ def _kernel_args(c, quant, layers=2):
         sds(ring, jnp.bfloat16), sds(ring, jnp.bfloat16),
         sds((), jnp.int32), sds((B,), jnp.int32), sds((B,), jnp.int32),
     ]
-    scales = {}
+    scales = {"live": sds((B,), jnp.bool_)}
     if quant:
         sc = sds((layers, B + 1, S // GROUP), jnp.float32)
-        scales = {"ctx_k_scale": sc, "ctx_v_scale": sc}
+        scales.update(ctx_k_scale=sc, ctx_v_scale=sc)
     return args, scales
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
 @pytest.mark.parametrize("model", ["llama3_1b", "llama3_8b"])
 def test_flash_decode_lowers_for_tpu(model, quant):
-    """hd 64 / g 4 and hd 128 serving shapes, dense and int8 ctx."""
+    """hd 64 / g 4 and hd 128 serving shapes, dense and int8 ctx, with
+    the work list its wrapper builds from ``live``."""
     c = getattr(ModelConfig, model)()
     args, scales = _kernel_args(c, quant)
-    lowered = flash_decode_attention.trace(*args, **scales).lower(
+    lowered = jax.jit(ctx_decode_attention, static_argnums=0).trace(
+        DecodeAttention(PALLAS), *args, **scales).lower(
         lowering_platforms=("tpu",)
     )
     assert "tpu_custom_call" in lowered.as_text()
@@ -151,14 +152,19 @@ def test_shard_mapped_kernel_matches_reference(small, tp, quant):
     mesh = make_mesh(MeshConfig(tp=tp), jax.devices()[:tp])
     attn = DecodeAttention(PALLAS_INTERPRET, mesh, chunk=16)
     args = (d["q"], ck, cv, d["rk"], d["rv"], jnp.int32(1), ctx_lens,
-            d["base"]) + scales
-    got = jax.jit(ctx_decode_attention, static_argnums=0)(attn, *args)
-    want = ctx_decode_attention(REFERENCE, *args)
-    # interpret mode emulates the MXU's bf16 passes (test_flash_decode)
-    np.testing.assert_allclose(
-        np.asarray(got), np.asarray(want), rtol=5e-3, atol=5e-3)
-    # and the output really is head-sharded over the mesh
-    assert len({s.index for s in got.addressable_shards}) == tp
+            d["base"]) + (scales or (None, None))
+    # every lane, then a work list of lane 1 alone (replicated: each shard
+    # walks it over its own heads): lane 0 comes back 0
+    for live in (None, jnp.asarray([False, True])):
+        got = jax.jit(ctx_decode_attention, static_argnums=0)(
+            attn, *args, live)
+        want = ctx_decode_attention(REFERENCE, *args, live)
+        # interpret mode emulates the MXU's bf16 passes (test_flash_decode)
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=5e-3, atol=5e-3)
+        assert live is None or not np.asarray(got)[0].any()
+        # and the output really is head-sharded over the mesh
+        assert len({s.index for s in got.addressable_shards}) == tp
 
 
 def test_kv_heads_must_divide_tp(small):
@@ -196,6 +202,47 @@ def _v5e_or_skip():
             platform="tpu", topology_name=tpu_compile_check.TOPOLOGY)
     except Exception as exc:  # noqa: BLE001 — no libtpu, or it is held
         pytest.skip(f"no compile-only v5e topology here: {exc!r:.200}")
+
+
+# the dense kernel's serving shapes: (layers, K/V heads, lanes, region rows,
+# query heads, head size) of cell 1, a tp = 4 shard of cell 2, cell 9, and
+# the smoke's llama3_1b, whose head of 64 a hand-made DMA cannot slice
+DENSE_KERNEL_SHAPES = {"mistral7b-w8": (32, 8, 8, 4096, 32, 128),
+                       "nemo12b-tp4_shard": (40, 2, 16, 4096, 8, 128),
+                       "jamba2-3b": (2, 1, 96, 4096, 20, 128),
+                       "llama3_1b": (16, 8, 8, 4096, 32, 64)}
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("shape", sorted(DENSE_KERNEL_SHAPES))
+def test_the_work_list_kernel_compiles_for_the_v5e(shape, quant):
+    """``flash_decode_attention`` through Mosaic for a compile-only v5e
+    (~0.5 s each): a grid whose bound is the list's traced length, 512-row
+    K and V blocks by the item's lane and chunk, the int8 region's scales
+    as the layer's block. One Mosaic call, and (at a head of 128: XLA
+    holds a narrower head's region rows-minor and relays it for ANY Mosaic
+    call, the parent's too) no copy of the region in front of it."""
+    one = jax.sharding.SingleDeviceSharding(_v5e_or_skip().devices[0])
+    L, nkv, B, S, nh, hd = DENSE_KERNEL_SHAPES[shape]
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    region = (L, nkv, B + 1, S, hd)
+    kv = arg(region, jnp.int8 if quant else jnp.bfloat16)
+    ring = arg((L, nkv, B, R, hd))
+    scale = arg((L, B + 1, S // GROUP), jnp.float32) if quant else None
+    # conftest's "highest" makes Mosaic refuse bf16 dots
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(ctx_decode_attention, static_argnums=0).lower(
+            DecodeAttention(PALLAS), arg((B, nh, hd)), kv, kv, ring, ring,
+            arg((), jnp.int32), arg((B,), jnp.int32), arg((B,), jnp.int32),
+            scale, scale, arg((B,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%flash_decode_attention\S* = ", text)) == 1
+    if hd == 128:
+        assert not tpu_compile_check.region_copies(text, region)
+        assert compiled.memory_analysis().temp_size_in_bytes < 4e6
 
 
 @pytest.fixture(scope="module", params=["mistral7b-w8", "nemo12b-tp4"],
@@ -527,6 +574,7 @@ def test_sparse_programs_hold_no_copy_of_the_region(sparse_record):
         # the dense read of a lane below the switch: one Mosaic call a
         # sparse layer
         assert rec["mosaic_calls"] >= 4
+        assert rec["lowered_sha256"] == ROUND_LOWERING["minicpm-sala-d16"]
 
 
 def test_sparse_cell_keeps_four_prefill_programs():
@@ -599,6 +647,7 @@ def test_delta_rule_programs_copy_neither_the_state_nor_the_region(
     assert 12.85 < rec["argument_gb"] < 12.95
     if name == "round_seal":
         assert rec["mosaic_calls"] >= 10 + 2 + 3 * 10
+        assert rec["lowered_sha256"] == ROUND_LOWERING["ling3-flash-ep8-d12"]
     else:
         # a block of the looped first half: q | k | v of 512 rows
         assert "bf16[512,12288]" in rec["text"]
@@ -671,6 +720,7 @@ def test_selective_scan_programs_relayout_neither_the_state_nor_a(m1_record):
     assert 7.3 < rec["argument_gb"] < 7.5
     if name == "round_seal":
         assert rec["mosaic_calls"] == 26 + 2
+        assert rec["lowered_sha256"] == ROUND_LOWERING["jamba2-3b"]
         # x_proj's 192 columns (dt rank 160 + 2 x 16) for 96 lanes
         assert "bf16[96,192]" in rec["text"]
     else:
@@ -747,6 +797,17 @@ def test_chat_rate_cell_keeps_nine_prefill_programs():
 # every-lane XLA recurrence stood. The cell's four prefill programs, its
 # flush, seal and load, and every program of the eight other
 # configurations kept the parent's digests (CHANGES.md, PR 52).
+# PR 53 MEANT to move the round of every configuration with a dense
+# attention layer, ONCE: ``flash_decode_attention`` became one invocation a
+# layer over a work list built from ``live`` (the 2-layer dense round
+# 38264b5231907f4c on its parent, 308d9b1, pinned here since; the granite
+# round 45ec7a29f1e8de72; at full depth ``ROUND_LOWERING`` below: jamba2-3b
+# 02daac48e3b8de20, minicpm-sala-d16 c835954f482bb9f2). Every program of
+# the three latent configurations (cells 3, 4, 8: their kernel's list is
+# built by the same ops through ``attention.flat_items``) and every
+# prefill, flush, seal and load program of all nine kept the parent's
+# digests: 54 of 62 programs equal, the 6 rounds and the two dense
+# ``decode_step`` programs moved (CHANGES.md, PR 53).
 UNMOVED = {
     ("mla-moe-joyai-d5", 0): {
         "flush_ctx": "aa9a25ef5ee32101",
@@ -757,15 +818,22 @@ UNMOVED = {
         "batch_prefill_K2_T128": "459e2c202d3ac337",
     },
     ("mistral7b-w8", 2): {
+        "round_seal_n4_w8": "4fc6864b36262415",
         "batch_prefill_K2_T128": "587de9cf00cdecb3",
         "batch_prefill_cont_K2_T128_S4096": "2c9a09ec6d798ada",
     },
     ("xing4-mhc-d7", 0): {"round_seal_n4_w16": "4a70f5eac1edcf3e"},
-    ("granite4h-ep2-d10", 0): {"round_seal_n4_w32": "45ec7a29f1e8de72"},
+    ("granite4h-ep2-d10", 0): {"round_seal_n4_w32": "5aace7fa74a39250"},
 }
+# the full-depth rounds the records above already compile: the latent
+# cell 8's as its parents left it, the two PR 53 moved
+ROUND_LOWERING = {"ling3-flash-ep8-d12": "301254f0fe8b14b6",
+                  "jamba2-3b": "85ce2cc7f19b7d4e",
+                  "minicpm-sala-d16": "ebe6ccdc309a8d57"}
 _UNMOVED_NAMES = {"mla-moe-joyai-d5": MOVERS + ("round_seal", "load_ctx_pages",
                                                 "batch_prefill"),
-                  "mistral7b-w8": ("batch_prefill", "batch_prefill_cont"),
+                  "mistral7b-w8": ("round_seal", "batch_prefill",
+                                   "batch_prefill_cont"),
                   "xing4-mhc-d7": ("round_seal",),
                   "granite4h-ep2-d10": ("round_seal",)}
 
