@@ -36,6 +36,13 @@ _JAMBA_KEYS = ("attn_layer_offset", "attn_layer_period", "mamba_d_conv",
                "mamba_conv_bias", "mamba_proj_bias", "num_experts",
                "intermediate_size", "num_attention_heads",
                "num_key_value_heads", "num_hidden_layers")
+# Mamba-1 layers, window / full / cross DIFFERENTIAL attention layers and
+# gated memory units, LayerNorm, one dense SwiGLU a layer, the head tied:
+# the same hybrid stack (_from_hf_phi4flash)
+_PHI4FLASH_TYPES = frozenset({"phi4flash"})
+_PHI4FLASH_KEYS = ("mb_per_layer", "sliding_window", "layer_norm_eps",
+                   "intermediate_size", "num_attention_heads",
+                   "num_key_value_heads", "num_hidden_layers")
 # keys that mean "not a dense Llama": a config carrying one is refused
 # rather than read with its extra structure dropped
 _FOREIGN_KEYS = ("kv_lora_rank", "q_lora_rank", "n_routed_experts",
@@ -148,6 +155,14 @@ _TINY_JAMBA = {
     "mamba_d_conv": 4, "mamba_d_state": 16, "mamba_dt_rank": 8,
     "mamba_expand": 2, "mamba_conv_bias": True, "mamba_proj_bias": False,
     "hidden_act": "silu", "rms_norm_eps": 1e-6, "sliding_window": None,
+    "max_position_embeddings": 512, "tie_word_embeddings": True,
+}
+_TINY_PHI4FLASH = {
+    "model_type": "phi4flash", "vocab_size": 256, "hidden_size": 64,
+    "intermediate_size": 128, "num_hidden_layers": 8, "mb_per_layer": 2,
+    "num_attention_heads": 4, "num_key_value_heads": 2,
+    "sliding_window": 8, "layer_norm_eps": 1e-5, "hidden_act": "silu",
+    "mamba_dt_rank": 8, "mlp_bias": False, "lm_head_bias": False,
     "max_position_embeddings": 512, "tie_word_embeddings": True,
 }
 _TINY_LINEAR_SPARSE = {
@@ -271,7 +286,10 @@ class ModelConfig:
     # follows from `layer_types`: mamba | attention | linear_attention |
     # sparse_attention | kda | latent_attention | mamba1 (_from_hf_jamba:
     # the selective scan with a decay per channel and state column, one
-    # dense MLP a layer, the head tied).
+    # dense MLP a layer, the head tied) | window_attention |
+    # cross_attention | gmu (_from_hf_phi4flash: differential attention
+    # behind a window, over another layer's rows, and a gated memory unit
+    # over another layer's scan; LayerNorm).
     hybrid: Optional[tuple[tuple[str, Any], ...]] = None
 
     @property
@@ -317,6 +335,8 @@ class ModelConfig:
             return cls._from_hf_ssm_moe(d)
         if model_type in _JAMBA_TYPES:
             return cls._from_hf_jamba(d)
+        if model_type in _PHI4FLASH_TYPES:
+            return cls._from_hf_phi4flash(d)
         if model_type in _LINEAR_SPARSE_TYPES:
             return cls._from_hf_linear_sparse(d)
         if model_type not in _DENSE_TYPES:
@@ -330,7 +350,9 @@ class ModelConfig:
                 f"{sorted(_LINEAR_SPARSE_TYPES)}; delta-rule linear "
                 f"attention + latent attention layers with grouped "
                 f"sigmoid experts (_from_hf_kda_latent): "
-                f"{sorted(_KDA_LATENT_TYPES)})")
+                f"{sorted(_KDA_LATENT_TYPES)}; Mamba-1 + differential "
+                f"window / full / cross attention + gated memory units "
+                f"(_from_hf_phi4flash): {sorted(_PHI4FLASH_TYPES)})")
         unknown = sorted(k for k in _FOREIGN_KEYS if d.get(k))
         if unknown:
             raise ValueError(
@@ -673,6 +695,105 @@ class ModelConfig:
         )
 
     @classmethod
+    def _from_hf_phi4flash(cls, d: dict[str, Any]) -> "ModelConfig":
+        """The decoder-hybrid-decoder stack of ``model_type: phi4flash``:
+        with ``mb_per_layer`` 2 every even layer is a Mamba-side mixer and
+        every odd one an attention-side mixer, and the second half of the
+        stack is the cross-decoder. Of L layers (a multiple of 4): even l
+        <= L/2 Mamba-1 (no inner norms), odd l < L/2 DIFFERENTIAL attention
+        behind a window of ``sliding_window``, l = L/2 + 1 full differential
+        attention (the only full K/V rows), and above it even l gated
+        memory units over layer L/2's scan output and odd l cross
+        differential attention over layer L/2 + 1's rows. LayerNorm (gain
+        and bias), projection biases on the attention, one dense SwiGLU a
+        layer, the head tied, no rotary, no multipliers. Adjacent heads
+        pair, so a K/V row is held pair-wide (2 x head_dim). The Mamba
+        sizes are the family's where the file has no key for them (state
+        16, conv 4, expand 2, dt rank ceil(hidden / 16)). Anything this
+        program does not build is refused by name."""
+        missing = sorted(k for k in _PHI4FLASH_KEYS if k not in d)
+        if missing:
+            raise ValueError("differential-attention hybrid block: keys "
+                             f"{missing} are missing from the config")
+        L, H = d["num_hidden_layers"], d["hidden_size"]
+        heads, kvh = d["num_attention_heads"], d["num_key_value_heads"]
+        half = L // 2
+        window = d["sliding_window"]
+        if isinstance(window, (list, tuple)):
+            # one entry a layer: the window on the window layers, none on
+            # the others
+            at = [w for l, w in enumerate(window) if l % 2 and l < half]
+            roles_ok = (len(window) == L and len(set(at)) == 1
+                        and all(w is None for l, w in enumerate(window)
+                                if not (l % 2 and l < half)))
+            window = at[0] if roles_ok and at else None
+        rank = d.get("mamba_dt_rank", "auto")
+        refused = {
+            f"mb_per_layer {d['mb_per_layer']} (only 2: Mamba-side and "
+            "attention-side mixers alternate)": d["mb_per_layer"] != 2,
+            f"num_hidden_layers {L} (no multiple of 4: the two decoders "
+            "are halves of whole (Mamba, attention) pairs)": L % 4 != 0,
+            "sliding_window (no one window on the odd layers of the first "
+            "half and none elsewhere)":
+                not isinstance(window, int) or isinstance(window, bool)
+                or window < 2,
+            f"num_key_value_heads {kvh} (odd: adjacent K/V heads pair)":
+                kvh % 2 != 0,
+            "num_attention_heads no multiple of num_key_value_heads":
+                heads % kvh != 0,
+            "hidden_size no multiple of num_attention_heads":
+                H % heads != 0,
+            "tie_word_embeddings false (the head is the embedding)":
+                not d.get("tie_word_embeddings", True),
+            "mlp_bias": bool(d.get("mlp_bias", False)),
+            "lm_head_bias": bool(d.get("lm_head_bias", False)),
+            f"hidden_act {d.get('hidden_act')!r}":
+                d.get("hidden_act", "silu") != "silu",
+            "mamba_proj_bias": bool(d.get("mamba_proj_bias", False)),
+            "mamba_conv_bias false": not d.get("mamba_conv_bias", True),
+        }
+        bad = sorted(k for k, v in refused.items() if v)
+        if bad:
+            raise ValueError("differential-attention hybrid block: "
+                             f"{bad} are values this program does not build")
+        kinds = tuple(
+            ("mamba1" if l % 2 == 0 else "window_attention") if l <= half
+            else "attention" if l == half + 1
+            else "gmu" if l % 2 == 0 else "cross_attention"
+            for l in range(L))
+        hybrid = dict(
+            layer_types=kinds,
+            m1_inner=int(d.get("mamba_expand", 2) * H),
+            m1_state=int(d.get("mamba_d_state", 16)),
+            m1_dt_rank=-(-H // 16) if rank == "auto" else int(rank),
+            m1_conv=int(d.get("mamba_d_conv", 4)),
+            # the differential form on every attention layer; its window
+            # and the rows a window layer's lane buffer holds (a power of
+            # two, position p in slot p mod it)
+            differential=True, layer_norm=True, m1_inner_norms=False,
+            window=int(window),
+            window_rows=1 << (int(window) - 1).bit_length(),
+            # the layer whose rows the cross layers read, and the layer
+            # whose scan output the gated memory units read
+            rows_from=half + 1, scan_from=half,
+            embedding_multiplier=1.0, residual_multiplier=1.0,
+            attention_multiplier=(H // heads) ** -0.5, logits_scaling=1.0)
+        return cls(
+            vocab_size=d["vocab_size"],
+            hidden_size=H,
+            intermediate_size=d["intermediate_size"],
+            num_layers=L,
+            num_heads=heads,
+            num_kv_heads=kvh,
+            head_dim=H // heads,
+            rms_norm_eps=d["layer_norm_eps"],
+            max_position_embeddings=d.get("max_position_embeddings", 8192),
+            tie_word_embeddings=True,
+            model_type=d["model_type"],
+            hybrid=tuple(sorted(hybrid.items())),
+        )
+
+    @classmethod
     def _from_hf_kda_latent(cls, d: dict[str, Any]) -> "ModelConfig":
         """Delta-rule linear attention (KDA) layers with a per-channel
         gate and short convolutions, one latent (MLA) layer closing every
@@ -824,6 +945,16 @@ class ModelConfig:
         (mamba1, attention, mamba1), four query heads on ONE K/V head, an
         inner width of 128 with 16 state columns and a dt rank of 8."""
         d = dict(_TINY_JAMBA)
+        d.update(kw)
+        return cls.from_hf_dict(d)
+
+    @classmethod
+    def tiny_phi4flash(cls, **kw) -> "ModelConfig":
+        """Toy decoder-hybrid-decoder stack for CPU tests: eight layers
+        (Mamba-1, window, Mamba-1, window, Mamba-1, full, gated memory
+        unit, cross), four query heads on two K/V heads of 16 (one K/V
+        pair of 32), a window of 8."""
+        d = dict(_TINY_PHI4FLASH)
         d.update(kw)
         return cls.from_hf_dict(d)
 

@@ -1631,7 +1631,11 @@ def flush_ctx_impl(
     valid entries, write the span back, in a loop rolled over the lanes.
     A lane's R entries are contiguous in the region whatever the number of
     heads, so nothing else is touched (a scatter over (lane, position)
-    would relayout the whole region: module header).
+    would relayout the whole region: module header). A kind whose region
+    rows are FEWER than the longest kind's is a MODULAR buffer a lane (the
+    window layers' rows, models/ssm_moe.py: position p lives in slot p mod
+    its length): its entries go in as two such spans, one where the ring's
+    first position falls and one at slot 0 for what wraps.
 
     Quantized regions (ctx_is_quantized) instead requantize the minimal
     group-aligned WINDOW around each lane's ring span: gather old int8
@@ -1643,7 +1647,7 @@ def flush_ctx_impl(
     if ctx_is_quantized(ctx_kv):
         return _flush_ctx_quant(ctx_kv, ring, dest, ring_base, valid_len)
     B, R = _any_row(ring).shape[2:4]
-    S = _any_row(ctx_kv).shape[3]
+    S = max(ctx_kv[n].shape[3] for n in row_kinds(ring))
     i = jnp.arange(R, dtype=jnp.int32)
 
     def span(buf, src, b):
@@ -1661,11 +1665,40 @@ def flush_ctx_impl(
                         new.astype(buf.dtype), old)
         return jax.lax.dynamic_update_slice(buf, new, at)
 
+    def wrapped(t, bufs):
+        # a modular buffer, two trips a lane (ONE read-modify-write a trip:
+        # two of a buffer in one loop body make XLA:TPU relayout the whole
+        # leaf around the loop): slot s takes ring entry (s - ring_base)
+        # mod its length where that is a valid entry, first in the span
+        # where the ring's first position falls, then in the one at slot 0
+        # for what wraps
+        b = t % B
+        out = {}
+        for n, buf in bufs.items():
+            W = buf.shape[3]
+            size = ring[n].shape[:2] + (1, R) + ring[n].shape[4:]
+            new = jax.lax.dynamic_slice(ring[n], (0, 0, b, 0, 0), size)
+            start = jnp.where(t < B, jnp.minimum(ring_base[b] % W, W - R), 0)
+            at = (0, 0, dest[b], start, 0)
+            old = jax.lax.dynamic_slice(buf, at, size)
+            entry = (start + i - ring_base[b]) % W
+            rows = jnp.take(new, jnp.clip(entry, 0, R - 1), axis=3)
+            out[n] = jax.lax.dynamic_update_slice(
+                buf, jnp.where(
+                    (entry < valid_len[b])[None, None, None, :, None],
+                    rows.astype(buf.dtype), old), at)
+        return out
+
     def lane(b, bufs):
         return {n: span(buf, ring[n], b) for n, buf in bufs.items()}
 
-    return jax.lax.fori_loop(
-        0, B, lane, {n: ctx_kv[n] for n in row_kinds(ring)})
+    kinds = row_kinds(ring)
+    out = jax.lax.fori_loop(
+        0, B, lane, {n: ctx_kv[n] for n in kinds if ctx_kv[n].shape[3] == S})
+    modular = {n: ctx_kv[n] for n in kinds if ctx_kv[n].shape[3] != S}
+    if modular:
+        out.update(jax.lax.fori_loop(0, 2 * B, wrapped, modular))
+    return out
 
 
 def _flush_ctx_quant(
@@ -1796,9 +1829,11 @@ def load_ctx_pages_impl(
                 ctx_kv[name + "_scale"], s[:, None], (0, slot, 0)
             )
         return out
-    # (a region's recurrent leaves are no rows: they pass through)
-    out = {n: ctx_kv[n] for n in state_kinds(ctx_kv)}
-    for name in row_kinds(ctx_kv):
+    # (a region's recurrent leaves are no rows, and a window layer's
+    # modular buffer is no kind the pool holds: they pass through)
+    held = row_kinds(cache)
+    out = {n: ctx_kv[n] for n in ctx_kv if n not in held}
+    for name in held:
         pages = cache[name][:, :, page_ids]      # [L, kvh, usable, ps, hd]
         if pool_q:
             # fused dequant: int8 pages * per-(layer, page) scale, in the
@@ -1899,7 +1934,7 @@ def seal_blocks_impl(
                     for n, pool in pools.items()}
 
         return jax.lax.fori_loop(
-            0, slots.shape[0], one, {n: cache[n] for n in row_kinds(ctx_kv)})
+            0, slots.shape[0], one, {n: cache[n] for n in row_kinds(cache)})
     if ctx_q:
         g = ctx_group_size(ctx_kv)
         assert g == ps, (

@@ -83,14 +83,53 @@ kind, what a region holds from its leaves:
     channel AND state column. ``[x | z] = u W_in``; x through the short
     causal depthwise convolution (bias, SiLU; an ``m1_conv_state`` leaf
     [lanes + 1, W - 1, inner] a layer); ``[dt_r | B | C] = x W_x``, EACH
-    through an RMSNorm with a gain; ``dt = softplus(dt_r W_dt +
-    dt_bias)``, ``A = -exp(A_log)``; ``h = exp(dt A) h + (dt x) outer B``,
-    ``y = h C + D x`` on an ``m1_state`` leaf [lanes + 1, d_state, inner]
-    float32 a layer (channels MINOR: the published [inner, d_state] has a
-    minor dimension of 16, which the chip tiles to 128); ``y silu(z)
+    through an RMSNorm with a gain (where the parameters hold the gains:
+    a family without the inner norms has none); ``dt = softplus(dt_r W_dt
+    + dt_bias)``, ``A = -exp(A_log)``; ``h = exp(dt A) h + (dt x) outer
+    B``, ``y = h C + D x`` on an ``m1_state`` leaf [lanes + 1, d_state,
+    inner] float32 a layer (channels MINOR: the published [inner, d_state]
+    has a minor dimension of 16, which the chip tiles to 128); ``y silu(z)
     W_out``, no out-norm. Prefill is one Pallas kernel a scan block on TPU
     devices (a ``lax.scan`` elsewhere), decode one kernel a layer over the
     live lanes' states in place, as the delta-rule step.
+  - THE DIFFERENTIAL FORM (a config with ``differential``) on the
+    ``attention`` kind and on the two kinds below: adjacent heads pair.
+    Query pair j = heads (2j, 2j + 1), K/V pair g = heads (2g, 2g + 1);
+    ``A_i = softmax(q_i k_i^T / sqrt(hd))``, ``o = (A_1 - lam A_2) [v_2g |
+    v_2g+1]``, an RMSNorm over the 2 hd values (one gain a layer) x (1 -
+    lam_init), W_o; ``lam = exp(lq1 . lk1) - exp(lq2 . lk2) + lam_init``,
+    ``lam_init = 0.8 - 0.6 exp(-0.3 l)``; projection biases. A K/V head
+    PAIR is held as ONE row of 2 hd (``row_heads``: the region is [L, kvh /
+    2, lanes, S, 2 hd], a free reshape of the projections' columns), and a
+    query head rides in zero-padded to 2 hd, its values in its own half:
+    the two score maps are then one pass of the attention ops as they are
+    (GQA group 4 over 128-wide rows), subtracted afterwards. With
+    ``layer_norm`` the stack's norms are LayerNorms (mean removed, gain and
+    bias).
+  - ``window_attention``: that form behind a window of ``window``
+    positions. Its rows are a row kind of their own, ``wk`` / ``wv``
+    [L_window, kvh / 2, lanes, window_rows, 2 hd]: ROWS OF A SECOND LENGTH
+    in the region, a lane's last ``window_rows`` (a power of two >= window)
+    as a modular buffer, position p in slot p mod that, the same size
+    whatever the context. A decode step writes the ring (which holds both
+    kinds); the round's flush writes the short kind modulo its length
+    (llama.flush_ctx_impl); a prefill chunk's tail rewrites the lane's
+    buffer whole, each slot with the chunk's last position on it; a
+    continuing chunk un-rotates the buffer into a workspace of its last
+    rows (``_window_prior``). Both attention ops take the window as a BOUND
+    (a lower loop bound over key blocks in prefill, the buffer's chunks
+    and a mask in decode), never a [T, S] map.
+  - ``cross_attention``: that form with ``w_q`` / ``w_o`` only, over the
+    rows of ANOTHER layer (``rows_from``, an ``attention`` layer below it):
+    it keeps no rows, and the rows it reads are indexed by that layer's
+    ordinal, not its own. ``gmu``: the gated memory unit ``(m * silu(x
+    W_1)) W_2`` over the scan output ``m`` (before its gate) of the
+    Mamba-1 layer ``scan_from`` at the same position: a transient of the
+    step or chunk, never cached. Where every layer above ``rows_from`` is
+    one of these two, nothing up there writes a row or a state, and a
+    PREFILL CHUNK'S ROWS STOP after that layer's K/V projection: only the
+    chunk's last real row climbs the rest (``_climb``), every chunk, final
+    or not.
   - ``latent_attention``: models/mla_moe.py's attention (imported, not
     copied) on ONE ``kv`` row leaf [L_latent, 1, lanes, S, stored] in
     place of K and V: prefill expands K and V per head (a continuing
@@ -131,23 +170,32 @@ from dynamo_tpu.models.mla_moe import _mlp, _rms   # the same norm and SwiGLU
 from dynamo_tpu.models.moe import grouped_experts, move_block, rows_moved
 from dynamo_tpu.ops import kda, lightning, mamba1, mamba2, sparse_attention
 from dynamo_tpu.ops.attention import (
+    NEG_INF,
     PALLAS_INTERPRET,
     REFERENCE_IMPL,
     DecodeAttention,
     PriorContext,
     ctx_decode_attention,
+    dense_chunk_rows,
     dense_round_rows,
     prefill_attention,
+    region_trips,
 )
 from dynamo_tpu.ops.latent_decode import latent_decode_attention
 from dynamo_tpu.ops.rope import apply_rope, rope_cos_sin, rope_inv_freq
 from dynamo_tpu.telemetry.metrics import (
+    ATTN_SHARED_ROWS_READ,
+    ATTN_WINDOW_ROWS_BOUND,
+    ATTN_WINDOW_ROWS_READ,
+    DECODE_ATTN_ROWS_READ,
     KDA_STATE_ROWS_STEPPED,
     MOE_GROUPS_KEPT_HERE,
     MOE_LOAD_MAX,
     MOE_PICKS_ROUTED,
     MOE_ROUTED,
     MOE_TOUCHED,
+    PREFILL_LAYER_ROWS,
+    PREFILL_LAYER_ROWS_SKIPPED,
     SPARSE_ATTN_ROWS_LIVE,
     SPARSE_ATTN_ROWS_READ,
     SPARSE_PREFILL_SCORED,
@@ -168,7 +216,16 @@ KDA, KDA_CONV = "kda_state", "kda_conv_state"   # a delta-rule layer's
 M1, M1_CONV = "m1_state", "m1_conv_state"   # a Mamba-1 layer's [N, inner]
                       # state (channels minor) and its convolution window
 KV = mla_moe.ROW      # the latent layers' one row kind
+WK, WV = "wk", "wv"   # the window layers' K/V rows: a modular buffer a lane
 ROW_LAYERS = ("attention", "sparse_attention")   # kinds that keep K/V rows
+                      # of the region's length
+# the kinds the differential form covers where the config states it
+DIFF_KINDS = ("attention", "window_attention", "cross_attention")
+DIFF_KERNEL = "diff_decode_attention"   # their decode kernel in a trace
+# prefill: where every layer above the one whose rows the cross layers read
+# writes neither rows nor state, only a chunk's last real row climbs them
+# (tests set it False: every row climbs, the logits must not move)
+SKIP_ROWS = True
 
 
 def dims(c: ModelConfig) -> dict[str, Any]:
@@ -182,6 +239,8 @@ def dims(c: ModelConfig) -> dict[str, Any]:
         "n_sparse": sum(t == "sparse_attention" for t in kinds),
         "n_kda": sum(t == "kda" for t in kinds),
         "n_m1": sum(t == "mamba1" for t in kinds),
+        "n_win": sum(t == "window_attention" for t in kinds),
+        "diff": bool(k.get("differential")),
         "n_latent": sum(t == "latent_attention" for t in kinds),
         "experts": "num_local_experts" in k,
         # leading layers whose feed-forward part is one dense MLP
@@ -215,6 +274,21 @@ def dims(c: ModelConfig) -> dict[str, Any]:
     if d["n_m1"]:
         d.update({"m1_inner": k["m1_inner"], "m1_N": k["m1_state"],
                   "m1_rank": k["m1_dt_rank"], "m1_W": k["m1_conv"]})
+    if d["n_win"]:
+        d.update({"window": k["window"], "window_rows": k["window_rows"]})
+    if "rows_from" in k:
+        # the layer whose rows the cross layers read (and its ordinal
+        # among the layers that keep full rows), the layer whose scan
+        # output the gated memory units read, and whether a prefill
+        # chunk's rows stop at the first: nothing above it keeps anything
+        above = kinds[k["rows_from"] + 1:]
+        d.update({
+            "rows_from": k["rows_from"], "scan_from": k["scan_from"],
+            "rows_row": sum(t in ROW_LAYERS
+                            for t in kinds[:k["rows_from"]]),
+            "climbs": all(t in ("gmu", "cross_attention") for t in above),
+            "n_cross": sum(t == "cross_attention" for t in kinds),
+        })
     if "lightning_heads" in k:
         d.update({
             "lin_heads": k["lightning_heads"],
@@ -222,6 +296,17 @@ def dims(c: ModelConfig) -> dict[str, Any]:
             "sparse": sparse_attention.Geometry.of(dict(k["sparse"])),
         })
     return d
+
+
+def row_heads(c: ModelConfig) -> tuple[int, int]:
+    """(heads, width) of a K or V row as the region holds it: under the
+    differential form adjacent K/V heads pair into ONE row of twice the
+    width (a free reshape of the projections' columns; the form reads the
+    pair anyway, and a minor dimension of 64 is tiled to 128 on the
+    chip)."""
+    if dims(c)["diff"]:
+        return c.num_kv_heads // 2, 2 * c.head_dim
+    return c.num_kv_heads, c.head_dim
 
 
 def routes(c: ModelConfig) -> bool:
@@ -252,6 +337,15 @@ def kv_row_bytes(c: ModelConfig, itemsize: int) -> float:
     if d["n_sparse"]:
         rows += d["n_sparse"] * c.kv_dim * itemsize / d["sparse"].stride
     return rows
+
+
+def window_bytes(c: ModelConfig, itemsize: int) -> int:
+    """Bytes one lane holds in window rows, whatever its context: the
+    window layers' modular buffers."""
+    d = dims(c)
+    if not d["n_win"]:
+        return 0
+    return d["n_win"] * d["window_rows"] * 2 * c.kv_dim * itemsize
 
 
 def state_bytes(c: ModelConfig, itemsize: int) -> int:
@@ -288,14 +382,19 @@ def init_params(config: ModelConfig, rng: jax.Array | int = 0) -> Params:
     scale, so that the step ``b`` spans ~0.1..0.9. A Mamba-1 layer takes
     its family's: ``A_log`` = log(1..N) down every channel's state column,
     ``dt`` log-uniform in 0.001..0.1 a channel, ``D`` 1, the three inner
-    norms' gains 1."""
+    norms' gains 1 (where the config has them). Under the differential
+    form an attention layer has projection biases (x 0.1), four ``lam``
+    vectors (x 0.1), the pair norm's gain 1 and ``lam_init`` = 0.8 - 0.6
+    exp(-0.3 l) of its depth l; a LayerNorm stack a bias (x 0.1) beside
+    each gain."""
     if isinstance(rng, int):
         rng = jax.random.PRNGKey(rng)
     c, d = config, dims(config)
     if c.quant is not None:
         raise ValueError("the state-space hybrid block has no int8 weights")
     dtype = jnp.dtype(c.dtype)
-    keys = iter(jax.random.split(rng, 4 + 16 * c.num_layers))
+    keys = iter(jax.random.split(
+        rng, 4 + (24 if d["diff"] else 16) * c.num_layers))
 
     def rnd(*shape, scale=None):
         scale = scale or 1.0 / np.sqrt(shape[-2])
@@ -307,8 +406,28 @@ def init_params(config: ModelConfig, rng: jax.Array | int = 0) -> Params:
     u = lambda lo, hi, *shape: jax.random.uniform(  # noqa: E731
         next(keys), shape, jnp.float32, lo, hi)
 
+    layer_norm = bool(c.hybrid_dict.get("layer_norm"))
+    bias = lambda n: rnd(n, scale=0.1)  # noqa: E731
+
+    def diff_attention(i, own_rows):
+        """The differential form's parameters: a cross layer has neither
+        ``wk`` nor ``wv`` (it reads another layer's rows)."""
+        hd = c.head_dim
+        lp = dict(wq=rnd(H, c.q_dim), bq=bias(c.q_dim),
+                  wo=rnd(c.q_dim, H), bo=bias(H),
+                  sub_norm=jnp.ones((2 * hd,), dtype),
+                  lam_init=jnp.float32(0.8 - 0.6 * np.exp(-0.3 * i)))
+        for name in ("lam_q1", "lam_k1", "lam_q2", "lam_k2"):
+            lp[name] = 0.1 * jax.random.normal(next(keys), (hd,), jnp.float32)
+        if own_rows:
+            lp.update(wk=rnd(H, c.kv_dim), bk=bias(c.kv_dim),
+                      wv=rnd(H, c.kv_dim), bv=bias(c.kv_dim))
+        return lp
+
     def layer(i, kind):
         lp = {"ln1": jnp.ones((H,), dtype), "ln2": jnp.ones((H,), dtype)}
+        if layer_norm:
+            lp.update(ln1_b=bias(H), ln2_b=bias(H))
         if d["experts"] and i >= d["n_dense"]:
             if "groups" in d:
                 # in SCORE units, as the latent block draws it (the 8th
@@ -327,6 +446,12 @@ def init_params(config: ModelConfig, rng: jax.Array | int = 0) -> Params:
         else:
             I = c.intermediate_size
             lp.update(w_g=rnd(H, I), w_u=rnd(H, I), w_d=rnd(I, H))
+        if d["diff"] and kind in DIFF_KINDS:
+            lp.update(diff_attention(i, kind != "cross_attention"))
+            return lp
+        if kind == "gmu":
+            lp.update(w1=rnd(H, d["m1_inner"]), w2=rnd(d["m1_inner"], H))
+            return lp
         if kind == "attention":
             lp.update(wq=rnd(H, c.q_dim), wk=rnd(H, c.kv_dim),
                       wv=rnd(H, c.kv_dim), wo=rnd(c.q_dim, H))
@@ -364,8 +489,6 @@ def init_params(config: ModelConfig, rng: jax.Array | int = 0) -> Params:
                 conv_w=rnd(d["m1_W"], I, scale=1.0 / np.sqrt(d["m1_W"])),
                 conv_b=rnd(I, scale=0.1),
                 w_x=rnd(I, R + 2 * N),                # dt_r | B | C
-                dt_norm=jnp.ones((R,), dtype), b_norm=jnp.ones((N,), dtype),
-                c_norm=jnp.ones((N,), dtype),
                 w_dt=rnd(R, I),
                 dt_bias=dt + jnp.log(-jnp.expm1(-dt)),
                 # channels minor, as the state is held
@@ -373,6 +496,10 @@ def init_params(config: ModelConfig, rng: jax.Array | int = 0) -> Params:
                     1, N + 1, dtype=jnp.float32))[:, None], (N, I)),
                 D=jnp.ones((I,), jnp.float32),
                 w_out=rnd(I, H))
+            if c.hybrid_dict.get("m1_inner_norms", True):
+                lp.update(dt_norm=jnp.ones((R,), dtype),
+                          b_norm=jnp.ones((N,), dtype),
+                          c_norm=jnp.ones((N,), dtype))
             return lp
         if kind == "latent_attention":
             m = mla_moe.dims(c)
@@ -410,6 +537,8 @@ def init_params(config: ModelConfig, rng: jax.Array | int = 0) -> Params:
         # kernel's operand sliced out of a stack is a copy of it
         "layers": [layer(i, kind) for i, kind in enumerate(d["kinds"])],
     }
+    if layer_norm:
+        params["norm_f_b"] = bias(H)
     if not c.tie_word_embeddings:
         params["head"] = rnd(H, c.vocab_size)
     return params
@@ -434,14 +563,22 @@ def param_shardings(config: ModelConfig, mesh: Mesh) -> Params:
 # Cache spec: rows for the attention layers, a state for the others
 
 def _rows(c: ModelConfig, lanes: int, length: int, dtype,
-          compressed: bool = True) -> Cache:
+          compressed: bool = True, window_rows: int = 0) -> Cache:
+    """The row kinds of a region, a ring or the pool, ``length`` rows a
+    lane; the window layers' kinds where ``window_rows`` says how many
+    rows a lane holds of them (the pool holds none)."""
     d = dims(c)
     if d["n_latent"]:
         # the latent layers' one row kind, in place of K and V
         return {KV: jnp.zeros((d["n_latent"], 1, lanes, length,
                                mla_moe.dims(c)["stored"]), dtype)}
-    shape = (d["n_attn"], c.num_kv_heads, lanes, length, c.head_dim)
+    heads, width = row_heads(c)
+    shape = (d["n_attn"], heads, lanes, length, width)
     rows = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    if d["n_win"] and window_rows:
+        for name in (WK, WV):
+            rows[name] = jnp.zeros(
+                (d["n_win"], heads, lanes, window_rows, width), dtype)
     if d["n_sparse"] and compressed:
         # one compressed key every ``stride`` positions of a sparse layer
         rows[KC] = jnp.zeros(
@@ -468,7 +605,11 @@ def init_ctx(config, batch, ctx_len, dtype=None, kv_quant="none",
     _refuse_quant(kv_quant)
     d = dims(config)
     dtype = dtype or jnp.dtype(config.dtype)
-    ctx = _rows(config, batch + 1, ctx_len, dtype)
+    # a window layer keeps a lane's last ``window_rows`` rows, position p
+    # in slot p mod that, whatever the context: rows of two LENGTHS in one
+    # region (a round's rows wait in the ring, a chunk's in the program)
+    ctx = _rows(config, batch + 1, ctx_len, dtype,
+                window_rows=d.get("window_rows", 0))
     if d["n_ssm"]:
         ctx[SSM] = [
             jnp.zeros((batch + 1, d["nh"], d["P"], d["N"]), jnp.float32)
@@ -501,9 +642,15 @@ def init_ctx(config, batch, ctx_len, dtype=None, kv_quant="none",
 
 def init_ring(config, batch, ring_len, dtype=None):
     # a step that completes a compressed key writes it into the region's
-    # own leaf (it rides the round's carry): the ring holds K and V only
+    # own leaf (it rides the round's carry): the ring holds K and V only,
+    # the window layers' beside the full layers'
+    if ring_len > dims(config).get("window_rows", ring_len):
+        raise ValueError(
+            f"a ring of {ring_len} rows is flushed into a window layer's "
+            f"buffer of {dims(config)['window_rows']} rows a lane: "
+            "flush_every must not pass the window's rows")
     return _rows(config, batch, ring_len, dtype or jnp.dtype(config.dtype),
-                 compressed=False)
+                 compressed=False, window_rows=ring_len)
 
 
 def cache_shardings(config: ModelConfig, mesh: Mesh,
@@ -518,9 +665,17 @@ def cache_shardings(config: ModelConfig, mesh: Mesh,
     return out
 
 
+def _window_shardings(config: ModelConfig, mesh: Mesh) -> Cache:
+    if not dims(config)["n_win"]:
+        return {}
+    s = NamedSharding(mesh, P(None, None, None, None, None))
+    return {WK: s, WV: s}
+
+
 def ring_shardings(config: ModelConfig, mesh: Mesh) -> Cache:
     rows = cache_shardings(config, mesh)
-    return {n: s for n, s in rows.items() if n != KC}   # init_ring's kinds
+    return dict({n: s for n, s in rows.items() if n != KC},   # init_ring's
+                **_window_shardings(config, mesh))
 
 
 def stepped_kinds(config: ModelConfig, state: Cache) -> tuple[str, ...]:
@@ -534,7 +689,8 @@ def stepped_kinds(config: ModelConfig, state: Cache) -> tuple[str, ...]:
 
 def ctx_shardings(config: ModelConfig, mesh: Mesh,
                   kv_quant: str = "none") -> Cache:
-    out = cache_shardings(config, mesh, kv_quant)
+    out = dict(cache_shardings(config, mesh, kv_quant),
+               **_window_shardings(config, mesh))
     d = dims(config)
     if d["n_ssm"]:
         out[SSM] = [NamedSharding(mesh, P(None, None, None, None))] * d["n_ssm"]
@@ -654,6 +810,19 @@ def _seen(c: ModelConfig, load, here, valid, n_tokens: int, stats):
     return merge_stats(stats, jnp.stack(seen).astype(jnp.int32))
 
 
+def _norm(c: ModelConfig, lp, name: str, x):
+    """The stack's norm with gain ``lp[name]``: a LayerNorm (mean removed,
+    gain and bias) where the parameters hold its bias, else the
+    RMSNorm."""
+    if name + "_b" not in lp:
+        return _rms(x, lp[name], c.rms_norm_eps)
+    xf = x.astype(jnp.float32)
+    xf = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(xf * xf, axis=-1, keepdims=True)
+    return ((xf * jax.lax.rsqrt(var + c.rms_norm_eps)).astype(x.dtype)
+            * lp[name] + lp[name + "_b"])
+
+
 def _shared(lp, x):
     with jax.named_scope("moe_shared"):
         return _mlp(x, lp["ws_g"], lp["ws_u"], lp["ws_d"])
@@ -670,7 +839,7 @@ def _ffn(c: ModelConfig, lp, x, valid, stats):
 def _layer_out(c: ModelConfig, lp, h, mix, valid, stats):
     r = jnp.asarray(c.hybrid_dict["residual_multiplier"], h.dtype)
     h = h + r * mix
-    x = _rms(h, lp["ln2"], c.rms_norm_eps)
+    x = _norm(c, lp, "ln2", h)
     if "wr" in lp:   # the layer routes: a leading dense layer does not
         y, stats = _ffn(c, lp, x, valid, stats)
     else:
@@ -685,7 +854,7 @@ def _embed(c: ModelConfig, params, tokens, dtype):
 
 
 def _logits(c: ModelConfig, params, h):
-    h = _rms(h, params["norm_f"], c.rms_norm_eps)
+    h = _norm(c, params, "norm_f", h)
     head = params["head"] if "head" in params else params["embed"].T
     y = jnp.matmul(h, head, preferred_element_type=jnp.float32)
     return y / c.hybrid_dict["logits_scaling"]
@@ -820,13 +989,16 @@ def _m1_in(c: ModelConfig, lp, x):
 def _m1_ssm(c: ModelConfig, lp, xs):
     """The convolved ``xs`` [N, inner] -> dt [N, inner] float32 (after
     its projection, bias and softplus), B and C [N, d_state]: ``x_proj``,
-    then an RMSNorm with a gain on each of its three parts."""
+    then an RMSNorm with a gain on each of its three parts where the
+    parameters hold the gains (a family without the inner norms has
+    none)."""
     d = dims(c)
     R, N = d["m1_rank"], d["m1_N"]
     with jax.named_scope("m1_xproj"):
         dt, B, C = jnp.split(xs @ lp["w_x"], [R, R + N], axis=-1)
-        dt, B, C = (_rms(a, lp[g], c.rms_norm_eps) for a, g in (
-            (dt, "dt_norm"), (B, "b_norm"), (C, "c_norm")))
+        if "dt_norm" in lp:
+            dt, B, C = (_rms(a, lp[g], c.rms_norm_eps) for a, g in (
+                (dt, "dt_norm"), (B, "b_norm"), (C, "c_norm")))
         dt = jnp.matmul(dt, lp["w_dt"], preferred_element_type=jnp.float32)
         return jax.nn.softplus(dt + lp["dt_bias"]), B, C
 
@@ -837,6 +1009,113 @@ def _m1_out(lp, y, z):
     with jax.named_scope("m1_out"):
         return (y * jax.nn.silu(z.astype(jnp.float32))).astype(
             z.dtype) @ lp["w_out"]
+
+
+def _gmu(lp, x, m):
+    """The gated memory unit: ``(m * silu(x W_1)) W_2`` with ``m`` [N,
+    inner] float32 another layer's scan output (before ITS gate) at the
+    same positions; the product in float32 and rounded once."""
+    with jax.named_scope("gmu"):
+        g = jax.nn.silu((x @ lp["w1"]).astype(jnp.float32))
+        return (m * g).astype(x.dtype) @ lp["w2"]
+
+
+def _diff_q(c: ModelConfig, lp, x):
+    """[N, H] -> the differential form's queries against PAIR-WIDE rows,
+    [N, heads, 2 hd]: query pair j is heads (2j, 2j + 1); the first holds
+    its values in the row's first half and zeros in the second, the other
+    the reverse, so that against a K row ``[k1 | k2]`` each scores its own
+    key and both read the pair's one V row: two score maps in one pass of
+    the attention ops as they are (GQA group 4: pair j reads K/V pair j //
+    2). The softmax scale rides on q (x sqrt(2 hd), which the ops divide
+    out), the product in float32 and rounded once."""
+    N, hd = x.shape[0], c.head_dim
+    k = c.hybrid_dict["attention_multiplier"] * np.sqrt(2 * hd)
+    q = ((x @ lp["wq"] + lp["bq"]).astype(jnp.float32) * k).astype(x.dtype)
+    q = q.reshape(N, c.num_heads // 2, 2, hd)
+    zero = jnp.zeros_like(q[:, :, 0])
+    return jnp.stack(
+        [jnp.concatenate([q[:, :, 0], zero], -1),
+         jnp.concatenate([zero, q[:, :, 1]], -1)], 2
+    ).reshape(N, c.num_heads, 2 * hd)
+
+
+def _diff_kv(c: ModelConfig, lp, x):
+    """[N, H] -> K and V rows as the region holds them, [N, pairs, 2 hd]:
+    ``[k_2g | k_2g+1]`` and ``[v_2g | v_2g+1]``, a free reshape of the
+    projections' columns."""
+    N = x.shape[0]
+    return ((x @ lp["wk"] + lp["bk"]).reshape(N, *row_heads(c)),
+            (x @ lp["wv"] + lp["bv"]).reshape(N, *row_heads(c)))
+
+
+def _diff_out(c: ModelConfig, lp, o):
+    """``o`` [N, heads, 2 hd], what the two maps of every query pair read
+    of the pair-wide V -> the mixer's output: ``o_1 - lam o_2``, an
+    RMSNorm over the 2 hd values with the layer's one gain, x (1 -
+    lam_init), W_o and its bias. ``lam = exp(lq1 . lk1) - exp(lq2 . lk2) +
+    lam_init``, float32."""
+    N, dtype = o.shape[0], o.dtype
+    o = o.astype(jnp.float32).reshape(N, c.num_heads // 2, 2, -1)
+    lam = (jnp.exp(jnp.sum(lp["lam_q1"] * lp["lam_k1"]))
+           - jnp.exp(jnp.sum(lp["lam_q2"] * lp["lam_k2"])) + lp["lam_init"])
+    o = o[:, :, 0] - lam * o[:, :, 1]
+    var = jnp.mean(o * o, axis=-1, keepdims=True)
+    o = (o * jax.lax.rsqrt(var + c.rms_norm_eps)
+         * lp["sub_norm"].astype(jnp.float32) * (1.0 - lp["lam_init"]))
+    return o.reshape(N, -1).astype(dtype) @ lp["wo"] + lp["bo"]
+
+
+def _row_attention(q, k_new, v_new, last, prior, below):
+    """ONE query row a chunk over its whole causal context: q [K, heads,
+    w] of the chunk's row ``last`` [K] against the chunk's own rows up to
+    it (``k_new`` / ``v_new`` [K, T, kvh, w]) and, where ``prior`` is a
+    ``PriorContext`` workspace ([1, kvh, K, span, w] a kind), its first
+    ``below`` [K] rows. [K, heads, w]. The scores of one row are a vector:
+    no blocks, one softmax over both parts."""
+    K, nh, w = q.shape
+    T, kvh = k_new.shape[1:3]
+    f32 = jnp.float32
+    qg = q.reshape(K, kvh, nh // kvh, w)
+    scale = 1.0 / np.sqrt(w)
+    s = jnp.einsum("kgrh,ktgh->kgrt", qg, k_new.astype(q.dtype),
+                   preferred_element_type=f32) * scale
+    s = jnp.where(jnp.arange(T) <= last[:, None, None, None], s, NEG_INF)
+    if prior is not None:
+        span = prior.k.shape[3]
+        s0 = jnp.einsum("kgrh,gksh->kgrs", qg, prior.k[0].astype(q.dtype),
+                        preferred_element_type=f32) * scale
+        s0 = jnp.where(jnp.arange(span) < below[:, None, None, None],
+                       s0, NEG_INF)
+        s = jnp.concatenate([s0, s], -1)
+    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    o = jnp.einsum("kgrt,ktgh->kgrh", p[..., -T:], v_new.astype(q.dtype),
+                   preferred_element_type=f32)
+    if prior is not None:
+        o = o + jnp.einsum("kgrs,gksh->kgrh", p[..., :span],
+                           prior.v[0].astype(q.dtype),
+                           preferred_element_type=f32)
+    return o.reshape(K, nh, w).astype(q.dtype)
+
+
+def _window_prior(ctx_kv, layer: int, slots, q_starts):
+    """The prior context of K continuing chunks in window layer ``layer``:
+    each lane's modular buffer sliced out of the region and UN-ROTATED
+    into a workspace [1, kvh, K, W, w] a kind whose row i holds position
+    q_start - W + i (``prefill_attention`` under a window; rows of
+    positions below 0, and all of a fresh chunk's, are masked there)."""
+    K, W = slots.shape[0], ctx_kv[WK].shape[3]
+    at = (q_starts[:, None] + jnp.arange(W, dtype=jnp.int32)) % W  # [K, W]
+
+    def lanes(buf):
+        size = (1, buf.shape[1], 1, W, buf.shape[4])
+        return jnp.concatenate([
+            jnp.take(jax.lax.dynamic_slice(
+                buf, (layer, 0, slots[i], 0, 0), size), at[i], axis=3)
+            for i in range(K)], axis=2)
+
+    return PriorContext(lanes(ctx_kv[WK]), lanes(ctx_kv[WV]),
+                        jnp.int32(0), jnp.arange(K, dtype=jnp.int32))
 
 
 def _no_bias(lp):
@@ -1031,7 +1310,8 @@ SCAN_ROW_BLOCK = 256
 # crosses a half's boundary to the cache dtype as written, which the
 # straight-line program's fusions skip, and the sparse layers' block
 # selection flips on such roundings (PERF.md section 6, PR 49).
-LIVE_ROW_KINDS = ("mamba", "attention", "kda", "latent_attention", "mamba1")
+LIVE_ROW_KINDS = ("mamba", "attention", "kda", "latent_attention", "mamba1",
+                  "window_attention", "cross_attention", "gmu")
 
 
 def live_row_block(c: ModelConfig, T: int) -> int:
@@ -1064,7 +1344,12 @@ def _mix_in(c: ModelConfig, kind: str, lp, h, pos):
         q_nope, q_rope, row = mla_moe._attn_in(c, lp, h, pos)
         k, v = mla_moe._expand_kv(c, lp, row)
         return jnp.concatenate([q_nope, q_rope], -1), k, v, row
-    x = _rms(h, lp["ln1"], c.rms_norm_eps)
+    x = _norm(c, lp, "ln1", h)
+    if kind == "gmu":
+        return (x,)
+    if dims(c)["diff"] and kind in DIFF_KINDS:
+        return (_diff_q(c, lp, x),) + (
+            () if kind == "cross_attention" else _diff_kv(c, lp, x))
     if kind == "attention":
         return _qkv(c, lp, x)
     if kind == "sparse_attention":
@@ -1120,7 +1405,11 @@ def _mix_out(c: ModelConfig, kind: str, lp, h, *seq):
     (h after the mixer, x = ln2 of it, picks, combine weights, the shared
     MLP of x[, whether a token kept a group held here]); where it does
     not, the dense MLP and its residual too: (h after the layer,)."""
-    if kind in ("attention", "latent_attention"):
+    if kind == "gmu":
+        mix = _gmu(lp, *seq)
+    elif dims(c)["diff"] and kind in DIFF_KINDS:
+        mix = _diff_out(c, lp, *seq)
+    elif kind in ("attention", "latent_attention"):
         o, = seq
         mix = o.reshape(o.shape[0], -1) @ lp["wo"]
     elif kind == "sparse_attention":
@@ -1137,7 +1426,7 @@ def _mix_out(c: ModelConfig, kind: str, lp, h, *seq):
         mix = _ssm_out(c, lp, y, _split_xbc(c, xbc)[0], z)
     r = jnp.asarray(c.hybrid_dict["residual_multiplier"], h.dtype)
     h = h + r * mix
-    x = _rms(h, lp["ln2"], c.rms_norm_eps)
+    x = _norm(c, lp, "ln2", h)
     if "wr" not in lp:   # a leading dense layer
         with jax.named_scope("mlp"):
             return (h + r * _mlp(x, lp["w_g"], lp["w_u"], lp["w_d"]),)
@@ -1172,19 +1461,38 @@ def _live_scan(kind: str, c: ModelConfig, lp, trips, rows, state, R: int):
 
 
 def _write_chunks(c, params, ctx_kv, rows, kcs, states, slots, q_starts,
-                  seq_lens, h):
+                  seq_lens, h, logits=None):
     """A prefill program's tail, after every read: the K chunks' rows
-    (``rows``: {kind: [K, L, T, heads, width]}) as spans, their compressed
+    (``rows``: {kind: [K, L, T, heads, width]}) as spans (a window kind's
+    into its lane's modular buffer, whole), their compressed
     keys (``kcs``: [K, kvh, Sc, hd] a sparse layer) and recurrent leaves
     (``states``: (name, [K, ...] a layer) pairs) as whole lanes, and the
     logits of each chunk's last real position from ``h`` [K, T, H] (or
-    [K T, H])."""
+    [K T, H]) unless the caller has them (``logits``)."""
     K = slots.shape[0]
 
     def write_lane(i, out):
         out = dict(out)
         for name, r in rows.items():
             r = jax.lax.dynamic_index_in_dim(r, i, keepdims=False)
+            if name in (WK, WV):
+                # slot s takes the chunk's LAST real position that falls
+                # on it, or keeps what it holds (an earlier position the
+                # lane's next reader still sees)
+                T, W = r.shape[1], out[name].shape[3]
+                end = q_starts[i] + jnp.clip(seq_lens[i] - q_starts[i], 0, T)
+                p = end - 1 - (end - 1 - jnp.arange(W, dtype=jnp.int32)) % W
+                at = (0, 0, slots[i], 0, 0)
+                old = jax.lax.dynamic_slice(
+                    out[name], at, r.shape[:1] + (r.shape[2], 1, W)
+                    + r.shape[3:])
+                new = jnp.take(r, jnp.clip(p - q_starts[i], 0, T - 1),
+                               axis=1).transpose(0, 2, 1, 3)[:, :, None]
+                out[name] = jax.lax.dynamic_update_slice(
+                    out[name], jnp.where(
+                        (p >= q_starts[i])[None, None, None, :, None],
+                        new.astype(old.dtype), old), at)
+                continue
             out[name] = jax.lax.dynamic_update_slice(
                 out[name], r.transpose(0, 2, 1, 3)[:, :, None],
                 (0, 0, slots[i], q_starts[i], 0))
@@ -1203,10 +1511,84 @@ def _write_chunks(c, params, ctx_kv, rows, kcs, states, slots, q_starts,
         return out
 
     out_ctx = jax.lax.fori_loop(0, K, write_lane, dict(ctx_kv))
+    if logits is not None:
+        return out_ctx, logits
     last = jnp.maximum(seq_lens - q_starts - 1, 0)
     h_last = jnp.take_along_axis(
         h.reshape(K, -1, h.shape[-1]), last[:, None, None], axis=1)[:, 0]
     return out_ctx, _logits(c, params, h_last)
+
+
+DIFF_SCOPES = {"attention": "full_diff_attn",
+               "window_attention": "window_diff_attn",
+               "cross_attention": "cross_diff_attn"}
+
+
+def _diff_prefill(c, kind: str, ctx_kv, slots, q_starts, seq_lens, span: int,
+                  q, own, rows, window_rows):
+    """One differential attention layer over K chunks, from its
+    in-projection's q [K, T, heads, w] and ``own`` = (k, v) [K, T, kvh, w]
+    (() for a cross layer): o [K, T, heads, w]. ``rows`` / ``window_rows``
+    are the (ks, vs) lists of the layers that keep full / window rows so
+    far in the program: the layer's own rows are appended to its pair, a
+    cross layer reads the pair of the layer it names."""
+    d = dims(c)
+    window = kind == "window_attention"
+    held = window_rows if window else rows
+    row = d["rows_row"] if kind == "cross_attention" else len(held[0])
+    for kept, new in zip(held, own):
+        kept.append(new)
+    if window:
+        return prefill_attention(
+            q, *own, q_starts, seq_lens,
+            _window_prior(ctx_kv, row, slots, q_starts) if span else None,
+            ctx_span=ctx_kv[WK].shape[3] if span else 0, window=d["window"])
+    return prefill_attention(
+        q, held[0][row], held[1][row], q_starts, seq_lens,
+        _prior_rows(ctx_kv, row, slots, span), ctx_span=span)
+
+
+def _climbs_at(c, l: int) -> bool:
+    """Whether a prefill chunk's rows stop at layer ``l``: the layer whose
+    rows the cross layers read, in a stack where nothing above it keeps a
+    row or a state (and the skip is on)."""
+    d = dims(c)
+    return SKIP_ROWS and d.get("climbs", False) and l == d["rows_from"]
+
+
+def _climb(c, params, ctx_kv, slots, q_starts, seq_lens, span: int, h, q,
+           k, v, m):
+    """The cross-decoder's share of a prefill chunk where only the chunk's
+    last REAL row climbs it (``dims(c)["climbs"]``): the layers from the
+    one whose rows the cross layers read up. ``h`` [K, T, H] the rows
+    below that layer, ``q`` / ``k`` / ``v`` [K, T, ...] ITS in-projection
+    of them (its K and V go to the region for every row; the caller keeps
+    them), ``m`` [K, T, inner] the scan output the gated memory units
+    read. The logits [K, vocab] of the row that climbed. Exact: nothing
+    above that layer writes a row or a state, so no other row of the chunk
+    is ever read there."""
+    d = dims(c)
+    K, T = h.shape[:2]
+    last = jnp.clip(seq_lens - q_starts, 1, T) - 1
+    at = lambda a: jnp.take_along_axis(  # noqa: E731
+        a, last.reshape((K,) + (1,) * (a.ndim - 1)), axis=1)[:, 0]
+    h, q, m = at(h), at(q), at(m)
+    prior = _prior_rows(ctx_kv, d["rows_row"], slots, span)
+    below = jnp.minimum(jnp.minimum(q_starts, seq_lens), span)
+    stats = stats_zero(c)
+    for l in range(d["rows_from"], c.num_layers):
+        kind, lp = d["kinds"][l], params["layers"][l]
+        x = _norm(c, lp, "ln1", h)
+        if kind == "gmu":
+            mix = _gmu(lp, x, m)
+        else:
+            with jax.named_scope(DIFF_SCOPES[kind]):
+                if l > d["rows_from"]:
+                    q = _diff_q(c, lp, x)
+                mix = _diff_out(c, lp, _row_attention(q, k, v, last, prior,
+                                                      below))
+        h, stats = _layer_out(c, lp, h, mix, None, stats)
+    return _logits(c, params, h)
 
 
 def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
@@ -1254,8 +1636,31 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
     lat, kda_out, kda_conv_out, m1_out, m1_conv_out = [], [], [], [], []
     lanes = lambda a: a.reshape(K, T, *a.shape[1:])  # noqa: E731
     A = lambda lp: -jnp.exp(lp["A_log"])  # noqa: E731
-    for kind, lp in zip(d["kinds"], params["layers"]):
-        x = _rms(h, lp["ln1"], c.rms_norm_eps)
+    wks, wvs = [], []     # the window layers' rows
+    m = logits = None     # the scan output the gated memory units read
+    for l, (kind, lp) in enumerate(zip(d["kinds"], params["layers"])):
+        if kind == "gmu" or (d["diff"] and kind in DIFF_KINDS):
+            # the row-wise halves as the looped form has them, around the
+            # attention ops at pair-wide rows
+            ins = _mix_in(c, kind, lp, h, None)
+            if kind == "gmu":
+                h, = _mix_out(c, kind, lp, h, ins[0], m)
+                continue
+            q, *own = map(lanes, ins)
+            if _climbs_at(c, l):
+                # the rows stop here: the chunk's last real row climbs
+                # the rest of the stack alone
+                ks.append(own[0])
+                vs.append(own[1])
+                logits = _climb(c, params, ctx_kv, slots, q_starts,
+                                seq_lens, span, lanes(h), q, *own, lanes(m))
+                break
+            with jax.named_scope(DIFF_SCOPES[kind]):
+                o = _diff_prefill(c, kind, ctx_kv, slots, q_starts, seq_lens,
+                                  span, q, own, (ks, vs), (wks, wvs))
+            h, = _mix_out(c, kind, lp, h, o.reshape(K * T, *o.shape[2:]))
+            continue
+        x = _norm(c, lp, "ln1", h)
         if kind == "attention":
             with jax.named_scope("nope_attn"):
                 q, k, v = (a.reshape(K, T, *a.shape[1:])
@@ -1336,6 +1741,8 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
                     lp["D"], S0[i], n_real[i]) for i in range(K)))
             m1_out.append(jnp.stack(Ss))
             m1_conv_out.append(win)
+            if l == d.get("scan_from"):
+                m = jnp.concatenate(ys)   # before the gate
             mix = _m1_out(lp, jnp.concatenate(ys), z)
         elif kind == "latent_attention":
             with jax.named_scope("mla_attn"):
@@ -1393,13 +1800,16 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
     else:
         rows = {"k": jnp.stack(ks, 1).astype(cdt),  # [K, L_attn, T, kvh, hd]
                 "v": jnp.stack(vs, 1).astype(cdt)}
+    if wks:
+        rows.update({WK: jnp.stack(wks, 1).astype(cdt),
+                     WV: jnp.stack(wvs, 1).astype(cdt)})
     states = [(name, new) for name, new in (
         (SSM, ssm_out), (CONV, conv_out), (LIN, lin_out), (KDA, kda_out),
         (KDA_CONV, kda_conv_out), (M1, m1_out), (M1_CONV, m1_conv_out))
         if new]
 
     out_ctx, logits = _write_chunks(c, params, ctx_kv, rows, kcs, states, slots,
-                                    q_starts, seq_lens, h)
+                                    q_starts, seq_lens, h, logits)
     return out_ctx, logits, moved
 
 
@@ -1465,9 +1875,27 @@ def _live_prefill(config, params, ctx_kv, tokens, slots, q_starts, seq_lens,
         return jax.vmap(lambda a, w0, n: mamba2.causal_conv(
             a, w0, lp["conv_w"], bias, n))(a, win0, n_real)
 
-    for kind, lp in zip(d["kinds"], params["layers"]):
+    wks, wvs = [], []     # the window layers' rows
+    m = logits = None     # the scan output the gated memory units read
+    for l, (kind, lp) in enumerate(zip(d["kinds"], params["layers"])):
         ins = rowwise(_mix_in, kind, lp, h, positions)
-        if kind == "attention":
+        if kind == "gmu":
+            seq = (ins[0], m)
+        elif d["diff"] and kind in DIFF_KINDS:
+            q, *own = ins
+            if _climbs_at(c, l):
+                # the rows stop here: the chunk's last real row climbs
+                # the rest of the stack alone
+                ks.append(own[0])
+                vs.append(own[1])
+                logits = _climb(c, params, ctx_kv, slots, q_starts,
+                                seq_lens, span, h, q, *own, m)
+                break
+            with jax.named_scope(DIFF_SCOPES[kind]):
+                seq = (_diff_prefill(c, kind, ctx_kv, slots, q_starts,
+                                     seq_lens, span, q, own, (ks, vs),
+                                     (wks, wvs)),)
+        elif kind == "attention":
             q, k, v = ins
             with jax.named_scope("nope_attn"):
                 seq = (prefill_attention(
@@ -1512,6 +1940,8 @@ def _live_prefill(config, params, ctx_kv, tokens, slots, q_starts, seq_lens,
             y, S = scan(kind, lp, S0, xs)
             new[M1].append(S)
             new[M1_CONV].append(win)
+            if l == d.get("scan_from"):
+                m = y   # before the gate
             seq = (y, z)
         elif kind == "latent_attention":
             qq, k, v, row = ins
@@ -1558,10 +1988,13 @@ def _live_prefill(config, params, ctx_kv, tokens, slots, q_starts, seq_lens,
     else:
         rows = {"k": jnp.stack(ks, 1).astype(cdt),  # [K, L_attn, T, kvh, hd]
                 "v": jnp.stack(vs, 1).astype(cdt)}
+    if wks:
+        rows.update({WK: jnp.stack(wks, 1).astype(cdt),
+                     WV: jnp.stack(wvs, 1).astype(cdt)})
     states = [(name, leaves) for name, leaves in new.items() if leaves]
 
     out_ctx, logits = _write_chunks(c, params, ctx_kv, rows, kcs, states, slots,
-                                    q_starts, seq_lens, h)
+                                    q_starts, seq_lens, h, logits)
     return out_ctx, logits, moved
 
 
@@ -1616,9 +2049,35 @@ def decode_step_impl(config, params, ctx_kv, ring, state, tokens, ctx_lens,
             work = kda.work_list(live)
             stats = stats.at[stats_layout(c).index(stepped)].add(
                 work[1][0] * layers)
-    for kind, lp in zip(d["kinds"], params["layers"]):
-        x = _rms(h, lp["ln1"], c.rms_norm_eps)
-        if kind == "attention":
+    wl = 0        # a window layer's ordinal among its kind
+    m = None      # the scan output the gated memory units read, [B, inner]
+    for l, (kind, lp) in enumerate(zip(d["kinds"], params["layers"])):
+        x = _norm(c, lp, "ln1", h)
+        if kind == "gmu":
+            mix = _gmu(lp, x, m)
+        elif d["diff"] and kind in DIFF_KINDS:
+            # the full layer's rows at its ordinal (a cross layer reads
+            # the same rows, this step's in the ring already), a window
+            # layer's in its lane's modular buffer
+            win = kind == "window_attention"
+            names = (WK, WV) if win else ("k", "v")
+            row = wl if win else a if kind == "attention" else d["rows_row"]
+            with jax.named_scope(DIFF_SCOPES[kind]):
+                if kind != "cross_attention":
+                    for name, new in zip(names, _diff_kv(c, lp, x)):
+                        ring[name] = jax.lax.dynamic_update_slice(
+                            ring[name],
+                            new.transpose(1, 0, 2)[None, :, :, None, :].astype(
+                                ring[name].dtype), (row, 0, 0, ring_pos, 0))
+                o = ctx_decode_attention(
+                    attn, _diff_q(c, lp, x), ctx_kv[names[0]],
+                    ctx_kv[names[1]], ring[names[0]], ring[names[1]],
+                    jnp.int32(row), ctx_lens, ring_base, live=live,
+                    window=d["window"] if win else 0, name=DIFF_KERNEL)
+                mix = _diff_out(c, lp, o)
+            wl += win
+            a += kind == "attention"
+        elif kind == "attention":
             with jax.named_scope("nope_attn"):
                 q, k, v = _qkv(c, lp, x)
                 for name, new in (("k", k), ("v", v)):
@@ -1694,6 +2153,8 @@ def decode_step_impl(config, params, ctx_kv, ring, state, tokens, ctx_lens,
                     y, state[M1][n] = mamba1.scan_step_pallas(
                         xs, dt, Bm, Cm, A, lp["D"], state[M1][n], *work,
                         interpret=attn.impl == PALLAS_INTERPRET)
+            if l == d.get("scan_from"):
+                m = y   # before the gate; a transient of the step
             mix = _m1_out(lp, y, z)
             n += 1
         elif kind == "latent_attention":
@@ -1800,7 +2261,31 @@ def decode_mirror(config: ModelConfig, max_context: int, ring_len: int,
     if sparse is None:
         if "attention" not in d["kinds"]:
             return None
-        return mla_moe.rows_mirror(dense_round_rows, attn, max_context)
+        dense = mla_moe.rows_mirror(dense_round_rows, attn, max_context)
+        if not d["n_win"]:
+            return dense
+        # rows of two lengths: the full layer's rows are read by it AND by
+        # every cross layer; a window layer reads its lane's buffer in
+        # whole chunks, against the window's own min(n, window) rows
+        readers = 1 + d.get("n_cross", 0)
+        W, window = d["window_rows"], d["window"]
+        cb = dense_chunk_rows(W, attn.chunk)
+
+        def mirror(ctx_lens, live, n_steps: int):
+            (_, read), own = dense(ctx_lens, live, n_steps)
+            live = np.asarray(live, bool)
+            n = np.asarray(ctx_lens)[live].astype(np.int64)
+            steps = np.arange(n_steps)[:, None]
+            in_buffer = (np.minimum(np.maximum(n - 1, 0), W)
+                         if attn.impl != REFERENCE_IMPL
+                         else np.full(len(ctx_lens), W))
+            return ((DECODE_ATTN_ROWS_READ[0], read), own,
+                    (ATTN_SHARED_ROWS_READ[0], readers * read),
+                    (ATTN_WINDOW_ROWS_READ[0], d["n_win"] * n_steps * int(
+                        region_trips(in_buffer, 1, cb).sum() * cb)),
+                    (ATTN_WINDOW_ROWS_BOUND[0], d["n_win"] * int(
+                        np.minimum(n[None, :] + steps, window).sum())))
+        return mirror
 
     def mirror(ctx_lens, live, n_steps: int):
         read, rows = sparse_attention.round_rows(
@@ -1816,7 +2301,8 @@ def prefill_mirror(config: ModelConfig):
     a layer): beside it, what a gathering prefill would score. A
     stack with Mamba-1 layers mirrors the positions their prefill scans
     run instead (it has no sparse layer)."""
-    n_m1 = dims(config)["n_m1"]
+    d = dims(config)
+    n_m1 = d["n_m1"]
     if n_m1:
         # the positions the Mamba-1 layers' prefill scans run: a lane's
         # live scan blocks where the program loops, every bucket row else
@@ -1827,7 +2313,19 @@ def prefill_mirror(config: ModelConfig):
                     np.asarray(q_starts, np.int64),
                     np.asarray(seq_lens, np.int64), width,
                     SCAN_ROW_BLOCK).sum())
-            return ((SSM_SCAN_POSITIONS[0], n_m1 * rows),)
+            out = ((SSM_SCAN_POSITIONS[0], n_m1 * rows),)
+            if SKIP_ROWS and d.get("climbs"):
+                # real prompt rows x layers, and of them the rows x layers
+                # that never ran: all but one row a chunk in the layers
+                # from the one whose rows the cross layers read up
+                real = np.clip(np.asarray(seq_lens, np.int64)
+                               - np.asarray(q_starts, np.int64), 0, width)
+                above = config.num_layers - d["rows_from"]
+                out += ((PREFILL_LAYER_ROWS[0],
+                         int(real.sum()) * config.num_layers),
+                        (PREFILL_LAYER_ROWS_SKIPPED[0],
+                         int(np.maximum(real - 1, 0).sum()) * above))
+            return out
         return scanned
     sparse, layers = sparse_layers(config)
     if sparse is None:

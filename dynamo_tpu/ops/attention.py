@@ -166,6 +166,11 @@ def ctx_decode_attention(
     live: Optional[jnp.ndarray] = None,  # [B] bool — lanes that hold a
                              # request; None = all. The others read
                              # nothing and come back 0
+    window: int = 0,         # > 0: the ``window`` positions up to the
+                             # query's own are visible, and ``ctx_k`` /
+                             # ``ctx_v`` hold a MODULAR buffer a lane of
+                             # its last S rows (ops/flash_decode.py)
+    name: str = "flash_decode_attention",   # the kernel's, in a trace
 ) -> jnp.ndarray:
     """Decode attention over the two-tier context (ctx region below
     ring_base + ring above). The current token's KV must already be in the
@@ -180,7 +185,7 @@ def ctx_decode_attention(
     if attn.impl == REFERENCE_IMPL:
         return flash_decode_attention_reference(
             q, ctx_k, ctx_v, ring_k, ring_v, layer, ctx_lens, ring_base,
-            *scales, live=live,
+            *scales, live=live, window=window,
         )
     B, S = q.shape[0], ctx_k.shape[3]
     i32 = jnp.int32
@@ -189,8 +194,10 @@ def ctx_decode_attention(
     cb = dense_chunk_rows(
         S, attn.chunk, S // ctx_k_scale.shape[2] if scales else 1)
     n_chunks = S // cb
-    trips = region_trips(
-        jnp.minimum(ring_base, ctx_lens).astype(i32), live, cb).astype(i32)
+    below = jnp.minimum(ring_base, ctx_lens).astype(i32)
+    if window:   # a lane's buffer holds its last S rows and no more
+        below = jnp.minimum(below, S)
+    trips = region_trips(below, live, cb).astype(i32)
     # a live lane's items: its chunks, then (item ``trips``) its ring,
     # which the kernel knows as chunk ``n_chunks`` and whose K / V blocks
     # are the chunk's before it once more (a block index cannot be "none")
@@ -208,7 +215,8 @@ def ctx_decode_attention(
             q, ctx_k, ctx_v, ring_k, ring_v, layer, ctx_lens, ring_base,
             (lane_of, chunk_of, fetch_of, total), chunk=cb,
             interpret=attn.impl == PALLAS_INTERPRET,
-            ctx_k_scale=k_scale, ctx_v_scale=v_scale,
+            ctx_k_scale=k_scale, ctx_v_scale=v_scale, window=window,
+            name=name,
         )
 
     if attn.mesh is not None:
@@ -263,7 +271,8 @@ class PriorContext(NamedTuple):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("block", "ctx_span", "mask_block"))
+                   static_argnames=("block", "ctx_span", "mask_block",
+                                    "window"))
 def prefill_attention(
     q: jnp.ndarray,          # [K, T, n_heads, hd] — K chunks of T new tokens
     k_new: jnp.ndarray,      # [K, T, kvh, hd] — the chunks' own keys
@@ -283,6 +292,10 @@ def prefill_attention(
                              # mask_block)? (a block-sparse SELECTION, ops/
                              # sparse_attention.py); None = every block
     mask_block: int = 0,
+    window: int = 0,         # > 0: a query reads the ``window`` positions
+                             # up to its own only, and ``ctx`` holds each
+                             # chunk's LAST ``ctx_span`` prior positions
+                             # (row i = position q_start - ctx_span + i)
 ) -> jnp.ndarray:
     """The one prefill attention: blocked, running-softmax, causal, in
     pure XLA, scoring only (query block, key block) pairs that can hold
@@ -313,6 +326,12 @@ def prefill_attention(
     dtype before the PV product. A width that is no multiple of the
     block slides its last block back (start = width - block) and masks
     the rows it has already seen, instead of padding the source.
+
+    Under a ``window`` the bound is a LOWER loop bound too: the key blocks
+    wholly below a query block's window are never scored, in the chunk and
+    in the prior context, which is then a workspace of the lane's last
+    ``ctx_span`` prior rows (a window layer keeps no more), not a region
+    from position 0.
 
     With ``block_masks`` every pair is still SCORED (the loops' bounds
     are causality's) and the selection masks the scores: the same
@@ -392,6 +411,8 @@ def prefill_attention(
             def ctx_block(j, carry):
                 k0 = jnp.minimum(j * cb, span - cb)
                 kp = k0 + jnp.arange(cb, dtype=i32)  # absolute position
+                if window:
+                    return windowed_ctx_block(j, k0, kp, carry)
                 at = (layer, 0, slot, k0, 0)
                 k_blk = jax.lax.dynamic_slice(
                     ctx.k, at, (1, kvh, 1, cb, hd))[0, :, 0]
@@ -412,8 +433,30 @@ def prefill_attention(
                 return score(carry, q_blk, k_blk.astype(q.dtype),
                              v_blk.astype(q.dtype), ok)
 
-            carry = jax.lax.fori_loop(
-                0, (below + cb - 1) // cb, ctx_block, carry)
+            def windowed_ctx_block(j, k0, kp, carry):
+                # row ``kp`` of the workspace holds position q_start -
+                # span + kp
+                at = (layer, 0, slot, k0, 0)
+                k_blk = jax.lax.dynamic_slice(
+                    ctx.k, at, (1, kvh, 1, cb, hd))[0, :, 0]
+                v_blk = jax.lax.dynamic_slice(
+                    ctx.v, at, (1, kvh, 1, cb, hd_v))[0, :, 0]
+                pos = q_start - span + kp
+                ok = ((kp >= j * cb) & (pos >= 0))[None, :] & (
+                    pos[None, :] > (q_start + rows - window)[:, None])
+                return score(carry, q_blk, k_blk.astype(q.dtype),
+                             v_blk.astype(q.dtype), ok)
+
+            if window:
+                # the first workspace row the block's first query sees,
+                # and the first that holds a position at all
+                first = jnp.maximum(q0 - window + 1, -q_start) + span
+                carry = jax.lax.fori_loop(
+                    jnp.clip(first, 0, span) // cb, (span + cb - 1) // cb,
+                    ctx_block, carry)
+            else:
+                carry = jax.lax.fori_loop(
+                    0, (below + cb - 1) // cb, ctx_block, carry)
 
         def chunk_block(j, carry):
             k0 = jnp.minimum(j * blk, T - blk)
@@ -422,6 +465,8 @@ def prefill_attention(
             ok = ((kp >= j * blk) & (kp < live))[None, :]
             if chunk_masks is None:
                 ok = ok & (kp[None, :] <= rows[:, None])
+                if window:
+                    ok = ok & (kp[None, :] > rows[:, None] - window)
             else:
                 ok = ok & jax.lax.dynamic_slice(
                     chunk_masks, (lane, q0, k0), (1, blk, blk))[0]
@@ -435,7 +480,9 @@ def prefill_attention(
         n_keys = nblk_live[lane]
         if chunk_masks is None:
             n_keys = jnp.minimum(qb + 1, n_keys)     # the causal diagonal
-        m, l, acc = jax.lax.fori_loop(0, n_keys, chunk_block, carry)
+        m, l, acc = jax.lax.fori_loop(
+            jnp.maximum(q0 - window + 1, 0) // blk if window else 0,
+            n_keys, chunk_block, carry)
         # a row that met no unmasked score holds p = exp(0) per masked
         # key (NEG_INF is finite): gate on the running max, emit zeros
         o = acc / jnp.maximum(l, 1e-30)[..., None]

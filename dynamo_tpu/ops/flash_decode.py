@@ -160,6 +160,7 @@ def _kernel(
     chunk: int,
     n_chunks: int,
     quantized: bool,
+    window: int = 0,
 ):
     if quantized:
         ksc_ref, vsc_ref = refs[:2]
@@ -180,8 +181,11 @@ def _kernel(
     def _():
         reset()
 
-    def accumulate(k, v, start, limit, length, k_sc=None, v_sc=None):
-        # k/v [nkv, length, HD]; positions start + iota valid below limit.
+    def accumulate(k, v, start, limit, length, k_sc=None, v_sc=None,
+                   wrap=None, lower=None):
+        # k/v [nkv, length, HD]; positions start + iota valid below limit
+        # (under a window: at or above ``lower`` too, and a region row's
+        # position is the last one below ``wrap`` + 1 that its slot holds).
         # k_sc/v_sc [1, length] f32: per-position dequant scales of an
         # int8 chunk, applied to the score / probability COLUMNS —
         # q.(s_c k_c) == s_c (q.k_c) and sum_c p_c (s_c v_c) ==
@@ -190,7 +194,11 @@ def _kernel(
         # inference refuses the sublane-splitting cast that needs)
         pos = start + jax.lax.broadcasted_iota(
             jnp.int32, (1, 1, length), 2)
+        if wrap is not None:
+            pos = wrap - ((wrap - pos) & (n_chunks * chunk - 1))
         valid = pos < limit
+        if lower is not None:
+            valid = valid & (pos >= lower)
         q = q_ref[0]                                       # [nkv, G, HD]
         s = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
@@ -240,21 +248,31 @@ def _kernel(
             v = v.astype(jnp.float32).astype(q_ref.dtype)
             k_sc = per_position(ksc_ref[0, lane, pl.ds(j, 1), :])
             v_sc = per_position(vsc_ref[0, lane, pl.ds(j, 1), :])
-        accumulate(k, v, j * chunk, jnp.minimum(base, ctx), chunk,
-                   k_sc, v_sc)
+        if window:
+            # the region is a lane's MODULAR buffer of its last rows
+            # (position p in slot p mod its length): a slot holds the last
+            # position below the ring base that falls on it, read where
+            # that is inside the window
+            accumulate(k, v, j * chunk, jnp.minimum(base, ctx), chunk,
+                       wrap=base - 1, lower=jnp.maximum(ctx - window, 0))
+        else:
+            accumulate(k, v, j * chunk, jnp.minimum(base, ctx), chunk,
+                       k_sc, v_sc)
 
     # the lane's last item, its ring: slot r holds position base + r,
     # valid below ctx
     @pl.when(jnp.logical_and(listed, j == n_chunks))
     def _():
         accumulate(rk_ref[0, :, 0], rv_ref[0, :, 0], base, ctx,
-                   rk_ref.shape[3])
+                   rk_ref.shape[3],
+                   lower=ctx - window if window else None)
         denom = jnp.maximum(l_ref[:, :, :1], 1e-30)
         o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
         reset()
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret",
+                                             "window", "name"))
 def flash_decode_attention(
     q: jnp.ndarray,          # [B, n_heads, HD]
     ctx_k: jnp.ndarray,      # [L, kvh, B(+1), S, HD] contiguous per-slot KV
@@ -270,6 +288,11 @@ def flash_decode_attention(
     interpret: bool = False,
     ctx_k_scale: jnp.ndarray | None = None,  # f32 [L, B(+1), S//group]
     ctx_v_scale: jnp.ndarray | None = None,  # (int8 ctx_k/ctx_v)
+    window: int = 0,         # > 0: a query reads the ``window`` positions
+                             # up to its own, and the region is a MODULAR
+                             # buffer a lane (S a power of two >= window -
+                             # 1: position p in slot p mod S)
+    name: str = "flash_decode_attention",   # the Mosaic call's, in a trace
 ) -> jnp.ndarray:
     """Flash decode attention over contiguous KV + ring, for the lanes of
     a work list. Returns [B, n_heads, HD]: a listed lane's attention; the
@@ -285,6 +308,11 @@ def flash_decode_attention(
     R = ring_k.shape[3]
     g = n_heads // nkv
     quantized = ctx_k_scale is not None
+    if window and (quantized or S & (S - 1) or S < window - 1):
+        raise ValueError(
+            f"a window of {window} over a buffer of {S} rows a lane: the "
+            "buffer holds a power of two of rows, the window less one at "
+            "least, unquantised")
     # chunk must tile S exactly (and whole scale groups when quantized)
     group = S // ctx_k_scale.shape[2] if quantized else 1
     chunk = chunk_rows(S, chunk, group)
@@ -332,7 +360,7 @@ def flash_decode_attention(
     out = pl.pallas_call(
         functools.partial(
             _kernel, scale=scale, chunk=chunk, n_chunks=n_chunks,
-            quantized=quantized,
+            quantized=quantized, window=window,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=7,
@@ -356,7 +384,7 @@ def flash_decode_attention(
             vmem_limit_bytes=64 * 1024 * 1024,
         ),
         interpret=interpret,
-        name="flash_decode_attention",
+        name=name,
     )(
         jnp.asarray(layer, i32).reshape(1), total.astype(i32).reshape(1),
         lane_of.astype(i32), chunk_of.astype(i32), fetch_of.astype(i32),
@@ -377,6 +405,7 @@ def flash_decode_attention_reference(
     ctx_k_scale: jnp.ndarray | None = None,
     ctx_v_scale: jnp.ndarray | None = None,
     live: jnp.ndarray | None = None,   # [B] bool; None = every lane
+    window: int = 0,                   # as the kernel's
 ) -> jnp.ndarray:
     """Pure-jnp equivalent (CPU tests / kernel parity checks). With ctx
     scales given, ctx_k/ctx_v are int8 per-group quantized — dequantize
@@ -407,9 +436,16 @@ def flash_decode_attention_reference(
         "bnh,nbsh->bns", q, k, preferred_element_type=jnp.float32
     ) / (hd ** 0.5)
     ctx_pos = jnp.arange(S)[None, :]                    # [1, S]
+    if window:   # a slot's position: the last below the ring base on it
+        last = ring_base[:, None] - 1
+        ctx_pos = last - ((last - ctx_pos) & (S - 1))
     ctx_ok = ctx_pos < jnp.minimum(ring_base, ctx_lens)[:, None]
     ring_pos = ring_base[:, None] + jnp.arange(R)[None, :]
     ring_ok = ring_pos < ctx_lens[:, None]
+    if window:
+        lower = (ctx_lens - window)[:, None]
+        ctx_ok = ctx_ok & (ctx_pos >= jnp.maximum(lower, 0))
+        ring_ok = ring_ok & (ring_pos >= lower)
     mask = jnp.concatenate([ctx_ok, ring_ok], axis=1)   # [B, S+R]
     scores = jnp.where(mask[:, None, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)

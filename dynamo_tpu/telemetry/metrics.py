@@ -543,6 +543,32 @@ DECODE_ATTN_ROWS_LIVE = (
     "dynamo_decode_attn_rows_live",
     "region rows of that round that were some live lane's own context: "
     "steps x the sum of the lanes' lengths")
+ATTN_SHARED_ROWS_READ = (
+    "dynamo_attn_shared_rows_read",
+    "models whose cross-attention layers read ANOTHER layer's K/V rows: "
+    "region rows of that one layer a dispatched decode round read, by the "
+    "layer itself and by every cross layer (dynamo_decode_attn_rows_read "
+    "x the readers): stored once, read once a reader")
+ATTN_WINDOW_ROWS_READ = (
+    "dynamo_attn_window_rows_read",
+    "window-attention models: rows of the lanes' window buffers a "
+    "dispatched decode round's window layers read, all such layers and "
+    "steps: the dispatched lanes' buffered rows in whole chunks under a "
+    "TPU kernel's work list (the whole buffer of every lane under the "
+    "jnp reference of the CPU meshes)")
+ATTN_WINDOW_ROWS_BOUND = (
+    "dynamo_attn_window_rows_bound",
+    "window-attention models: rows the window admits in that round, "
+    "min(context, window) a live lane a step, all window layers")
+PREFILL_LAYER_ROWS = (
+    "dynamo_prefill_layer_rows",
+    "models whose prefill stops a chunk's rows part-way up the stack: real "
+    "prompt rows x layers of a prefill dispatch")
+PREFILL_LAYER_ROWS_SKIPPED = (
+    "dynamo_prefill_layer_rows_not_climbed",
+    "of those, the rows x layers the program never ran: every row but "
+    "each chunk's last real one, in the layers above the last that "
+    "writes rows or state")
 KV_ROW_BYTES = ("dynamo_kv_row_bytes",
                 "bytes one token holds in the ctx region, all layers "
                 "(observed once, at engine start)")
@@ -587,7 +613,9 @@ def request_histograms(
         for name, help_ in (DECODE_ATTN_ROWS_READ, DECODE_ATTN_ROWS_LIVE,
                             MOE_PREFILL_ROWS_SORTED, MOE_PREFILL_ROWS_MOVED,
                             KDA_STATE_ROWS_STEPPED, SSM_STATE_ROWS_STEPPED,
-                            SSM_SCAN_POSITIONS):
+                            SSM_SCAN_POSITIONS, ATTN_SHARED_ROWS_READ,
+                            ATTN_WINDOW_ROWS_READ, ATTN_WINDOW_ROWS_BOUND,
+                            PREFILL_LAYER_ROWS, PREFILL_LAYER_ROWS_SKIPPED):
             reg.histogram(name, help_,
                           tuple(float(4 ** i) for i in range(3, 13)))
         reg.histogram(*KV_ROW_BYTES, tuple(float(4 ** i) for i in range(3, 12)))

@@ -60,6 +60,7 @@ LONG_FILES = (
     "test_spec_tree.py", "test_prefill_live_rows.py",
     "test_rehearsal_ragdoc_longprompt.py",
     "test_mamba1.py", "test_rehearsal_chat_rate.py",   # PR 51: ~3 min each
+    "test_sambay.py",                                  # PR 54: ~2.5 min
 )
 
 

@@ -75,6 +75,11 @@ CELLS = {
     # 32-768 tokens out in the 10 s pre-roll and the window
     "chat-rate": ("jamba2-3b.chat-rate", "5100510051",
                   "benchmarks/references/jamba.py", 1.2),
+    # answers of 256-1536 tokens after prompts of 128-16384 (one to four
+    # chunks of the tiny model): five requests in the 20 s pre-roll and one
+    # or two in the window are what the CPU drains in time
+    "thinklong": ("phi4-mini-flash.thinklong", "5400540054",
+                  "benchmarks/references/sambay.py", 0.25),
 }
 
 
@@ -102,7 +107,11 @@ def rehearse(tmp_path, cell, seed, reference, rate_rps):
     eight. The chat-rate cell's check carries the toy model's Mamba-1
     state, window and K/V rows over a chunk boundary at 2048, through a
     looped 1024 bucket and a padded 256 one, and its warm-up fills all 96
-    lanes once."""
+    lanes once. The long-thought cell's check carries the toy model's
+    Mamba-1 state, its window buffers (8 rows a lane: wrapped a thousand
+    times), layer 5's rows and the gated memory unit's m over two chunk
+    boundaries at 4096, with only each chunk's last row above layer 5, and
+    its decode crosses multiples of the buffer's length in every round."""
     root = REPO if rate_rps is None else checkout_at_rate(
         tmp_path, cell, rate_rps)
     r = run([sys.executable, "benchmarks/run.py", "--workload", cell,

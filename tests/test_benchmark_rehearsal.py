@@ -102,8 +102,12 @@ def test_the_benchmarks_own_checks_pass(cmd, limit_s):
     # server drains the pre-roll's backlog inside the window)
     ("jamba2-3b.chat-rate", "gen.carried_tok_s.chat-rate-open", "higher",
      True),
+    # answers of 256-1536 tokens behind a 20 s pre-roll, as the reasoning
+    # cell's: carried in as well as out
+    ("phi4-mini-flash.thinklong", "gen.carried_tok_s.thinklong-open",
+     "higher", True),
 ], ids=["chat", "longprompt", "chat-decode", "longdoc", "ragdoc",
-        "reasoning", "chat-rate"])
+        "reasoning", "chat-rate", "thinklong"])
 def test_carried_tok_s_reads_the_way_its_entry_says(monkeypatch, cell, entry,
                                                      shown, registered_agrees):
     """``benchmarks/test_contract.py``'s two servers (cell 1's pace before
@@ -132,7 +136,8 @@ def test_carried_tok_s_reads_the_way_its_entry_says(monkeypatch, cell, entry,
             gen["tok_s"] - contract.offered_tok_s(log))
     if shown == "lower":
         assert 0 < carried["fast"] < carried["slow"]
-    elif cell in ("ling3-flash-ep8-d12.reasoning", "jamba2-3b.chat-rate"):
+    elif cell in ("ling3-flash-ep8-d12.reasoning", "jamba2-3b.chat-rate",
+                  "phi4-mini-flash.thinklong"):
         # a pre-roll of answers that outlast it carries IN as well as out:
         # the faster server still reads the higher, on either side of 0
         assert carried["slow"] < carried["fast"] and carried["slow"] < 0

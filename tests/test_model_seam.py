@@ -37,6 +37,7 @@ FAMILIES = {
     "lightning_sparse": ModelConfig.tiny_linear_sparse,
     "kda_latent": ModelConfig.tiny_kda_latent,
     "mamba1": ModelConfig.tiny_jamba,
+    "differential": ModelConfig.tiny_phi4flash,
 }
 
 
@@ -143,6 +144,7 @@ def test_the_counter_row_is_declared(family):
                        tmetrics.KDA_STATE_ROWS_STEPPED[0]],
         # one dense MLP a layer: routes nothing; its step kernel counts
         "mamba1": [tmetrics.SSM_STATE_ROWS_STEPPED[0]],
+        "differential": [tmetrics.SSM_STATE_ROWS_STEPPED[0]],
     }[family]
     assert [k.f32_bits for k in layout if k.metric ==
             tmetrics.HC_SINKHORN_RESIDUAL[0]] == [True] * (
@@ -171,8 +173,18 @@ def test_what_the_state_says_of_itself(family):
         # the jnp reference scores every lane's whole 128-row region
         assert seen == {tmetrics.DECODE_ATTN_ROWS_READ[0]: 4 * 6 * 128,
                         tmetrics.DECODE_ATTN_ROWS_LIVE[0]: 4 * (40 + 16)}
-    assert (prefill is not None) == (family in ("lightning_sparse",
-                                                "mamba1"))
+    if family == "differential":
+        # rows of two lengths: the full layer's rows beside what its ONE
+        # cross layer doubles, and the two window layers' 8-row buffers
+        # (every lane's, whole, under the jnp reference) against min(n, 8)
+        assert seen == {
+            tmetrics.DECODE_ATTN_ROWS_READ[0]: 4 * 6 * 128,
+            tmetrics.DECODE_ATTN_ROWS_LIVE[0]: 4 * (40 + 16),
+            tmetrics.ATTN_SHARED_ROWS_READ[0]: 2 * 4 * 6 * 128,
+            tmetrics.ATTN_WINDOW_ROWS_READ[0]: 2 * 4 * 6 * 8,
+            tmetrics.ATTN_WINDOW_ROWS_BOUND[0]: 2 * 4 * 2 * 8}
+    assert (prefill is not None) == (family in (
+        "lightning_sparse", "mamba1", "differential"))
 
 
 @pytest.mark.parametrize("family", FAMILIES)
@@ -187,7 +199,8 @@ def test_the_rounds_step_through_the_front_door(family):
     kinds = llama.stepped_kinds(c, ctx)
     assert set(kinds) <= set(ctx) and not set(kinds) & set(ring)
     assert bool(kinds) == (family in ("mamba2", "lightning_sparse",
-                                      "kda_latent", "mamba1"))
+                                      "kda_latent", "mamba1",
+                                      "differential"))
     stepped = {n: ctx[n] for n in kinds}
     stats = jax.eval_shape(lambda: llama.stats_zero(c))
     i32 = jax.ShapeDtypeStruct((B,), jnp.int32)

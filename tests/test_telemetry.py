@@ -121,6 +121,9 @@ def test_registry_render_and_snapshot():
         "dynamo_sparse_prefill_pairs_selected",
         "dynamo_moe_groups_kept_here", "dynamo_kda_state_rows_stepped",
         "dynamo_ssm_state_rows_stepped", "dynamo_ssm_scan_positions",
+        "dynamo_attn_shared_rows_read", "dynamo_attn_window_rows_read",
+        "dynamo_attn_window_rows_bound", "dynamo_prefill_layer_rows",
+        "dynamo_prefill_layer_rows_not_climbed",
         "dynamo_request_tpot_seconds",
         "dynamo_engine_step_gap_seconds",
         "dynamo_engine_step_gap_clean_seconds",

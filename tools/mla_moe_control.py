@@ -107,6 +107,13 @@ async def main(args) -> int:
     stated = reference["logprobs"].__globals__
     required = tuple(stated.get("CONTROLS_REQUIRED", REQUIRED))
     named = tuple(stated.get("CONTROLS_NAMED", NAMED))
+    if args.only:   # a part of them (a control is minutes of reference)
+        unknown = sorted(set(args.only) - set(required + named))
+        if unknown:
+            raise SystemExit(f"--only {unknown}: the reference states "
+                             f"{required + named}")
+        required, named = (tuple(c for c in held if c in args.only)
+                           for held in (required, named))
     engine = server.build_engine(cfg, args.seeds[0], args.dry_run)
     draw = jax.jit(
         lambda key: llama.init_params(engine.config, key),
@@ -166,5 +173,7 @@ if __name__ == "__main__":
     ap.add_argument("--seeds", "--seed", type=int, nargs="+", required=True)
     ap.add_argument("--controls-on", type=int, default=1,
                     help="how many of the first seeds also run the controls")
+    ap.add_argument("--only", nargs="*", default=[],
+                    help="run these of the reference's controls only")
     ap.add_argument("--dry-run", action="store_true")
     sys.exit(asyncio.run(main(ap.parse_args())))
