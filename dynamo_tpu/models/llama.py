@@ -843,9 +843,29 @@ def _layer_qkv(c: ModelConfig, lp, h, cos, sin, ad=None):
     N = h.shape[0]
     ad = ad or {}
     x = rms_norm(h, lp["ln1"], c.rms_norm_eps)
-    q = _mm_ad(x, lp["wq"], ad.get("wq")).reshape(N, c.num_heads, c.head_dim)
-    k = _mm_ad(x, lp["wk"], ad.get("wk")).reshape(N, c.num_kv_heads, c.head_dim)
-    v = _mm_ad(x, lp["wv"], ad.get("wv")).reshape(N, c.num_kv_heads, c.head_dim)
+
+    def heads(name, n):
+        y = _mm_ad(x, lp[name], ad.get(name))
+        if not _is_quant(lp[name]):
+            # The product ends HERE, as an [N, out] array. Left to itself
+            # XLA:TPU folds the reshape to heads (and what the rope and
+            # the attention do to the heads after it) into a bf16 product,
+            # which then wants its weight head-major with the contraction
+            # minor, and writes every layer's wq / wk / wv shard out
+            # twice, a slice of the stack and a transposed copy, in front
+            # of every product of every call: each decode step and each
+            # prefill (tools/tpu_compile_check.py ``weight_copies``;
+            # PERF.md section 6, PR 55). Behind the barrier the product
+            # reads the shard where it lies in the stack, as wo and the
+            # MLP's do, and what is laid out anew is the [N, out]
+            # activation. An int8 weight's dequantising product already
+            # reads the stack in place: its programs stay as they are.
+            y = jax.lax.optimization_barrier(y)
+        return y.reshape(N, n, c.head_dim)
+
+    q = heads("wq", c.num_heads)
+    k = heads("wk", c.num_kv_heads)
+    v = heads("wv", c.num_kv_heads)
     return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
 
 

@@ -918,6 +918,65 @@ def test_region_copies_reads_copy_and_copy_start():
         "bf16[2,2,69632,128]"]
 
 
+def test_weight_copies_reads_copies_and_materialised_slices():
+    """Canned text of the parent's 2-layer nemo12b-tp4 prefill (PR 55): a
+    slice of the wq stack written out a layer, its transposed copy, an
+    async copy of a wk shard; not the dot's own fusion, not what a
+    fusion's computation reads, not an activation of as many elements."""
+    shards = ((5120, 1024), (5120, 256), (5120, 3584))
+    text = """
+%fused_computation.97 (param_0.1: bf16[2,5120,1024]) -> (bf16[1024,5120], bf16[1024,5120]) {
+  %copy.1 = bf16[1024,5120]{1,0:T(8,128)(2,1)} copy(%param_0.1)
+  ROOT %t = (bf16[1024,5120]{0,1}, bf16[1024,5120]{0,1}) tuple(%copy.1, %copy.1)
+}
+
+ENTRY %main.7_spmd (param.16: bf16[2,5120,1024]) -> bf16[2,256,5120] {
+  %slice_bitcast_fusion = (bf16[1024,5120]{0,1:T(8,128)(2,1)S(1)}, bf16[1024,5120]{0,1:T(8,128)(2,1)S(1)}) fusion(%custom-call.4), kind=kLoop, calls=%fused_computation.97, metadata={op_name="jit(batch_prefill_impl)/vmap()/dot_general"}
+  %copy.33 = bf16[1024,5120]{1,0:T(8,128)(2,1)S(1)} copy(%get-tuple-element.545), metadata={op_name="jit(batch_prefill_impl)/vmap()/dot_general"}
+  %copy-start.2 = (bf16[1,5120,256]{1,2,0:T(8,128)(2,1)S(1)}, bf16[1,5120,256]{2,1,0}, u32[]{:S(2)}) copy-start(%slice.2)
+  %copy.24 = bf16[2,2560,2,128]{3,1,2,0:T(8,128)(2,1)S(1)} copy(%bitcast.290)
+  %fusion.52 = (f32[2,256]{1,0}, bf16[2,256,5120]{2,1,0}) fusion(%all-reduce, %all-reduce.1), kind=kLoop, calls=%fused_computation.86
+  %fusion.68 = bf16[2,256,8,128]{1,3,2,0:T(8,128)(2,1)S(1)} fusion(%bitcast.276, %get-tuple-element.538), kind=kOutput, calls=%fused_computation.104
+  ROOT %fusion.60 = bf16[2,256,5120]{2,1,0} fusion(%bitcast.285, %param.21), kind=kOutput, calls=%fused_computation.94
+}
+"""
+    assert tpu_compile_check.weight_copies(text, *shards) == [
+        "bf16[1024,5120]", "bf16[1,5120,256]",
+        "bf16[1024,5120]", "bf16[1024,5120]"]
+    # the count alone takes the [2,2560,2,128] activation for a wk shard
+    assert "bf16[2,2560,2,128]" in tpu_compile_check.region_copies(
+        text, (5120, 256))
+
+
+@pytest.fixture(scope="module", params=[
+    (config, width) for config in ("nemo12b-tp4", "mistral7b-w8")
+    for width in (256, 1024)], ids=lambda p: f"{p[0]}-T{p[1]}")
+def narrow_prefill_records(request):
+    _v5e_or_skip()
+    config, width = request.param
+    with jax.default_matmul_precision("default"):
+        records = tpu_compile_check.compile_programs(
+            config=config, layers=2, prefill_width=width,
+            programs=("batch_prefill", "batch_prefill_cont"))
+    return dict(zip(("batch_prefill", "batch_prefill_cont"), records))
+
+
+@pytest.mark.parametrize("program", ["batch_prefill", "batch_prefill_cont"])
+def test_dense_prefill_relayouts_no_projection_weight_on_v5e(
+        narrow_prefill_records, program):
+    """Every product of a dense prefill reads its weight where it lies in
+    the layer stack. Until PR 55 the bf16 programs wrote each layer's wq
+    and wk shard out twice, a slice of the stack and its transpose, in
+    front of the product (four ``bf16[1024,5120]`` and four
+    ``bf16[256,5120]`` in each of the four nemo12b-tp4 programs at 2
+    layers; none in mistral7b-w8's, whose int8 weights dequantise in
+    place): ``llama._layer_qkv`` ends the product ahead of the reshape
+    to heads."""
+    rec = narrow_prefill_records[program]
+    assert rec["ok"], rec.get("error")
+    assert rec["weight_copies"] == [], rec["weight_copies"]
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("tp,kv_quant", [(1, "none"), (4, "int8")])
 def test_v5e_topology_compile(tp, kv_quant):

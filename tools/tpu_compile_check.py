@@ -35,8 +35,13 @@ prefill at the lanes the engine gives a group of two: fresh
 (``batch_prefill_cont``), at the smallest bucket (``--prefill-width``
 names another: the long-context cell's continuing ``[1, 4096]`` program
 is held by tests/test_tpu_lowering.py).
-Prints one JSON line per program with XLA's memory analysis and the
-region-shaped copies in the compiled text; exits 1 if any program fails
+Prints one JSON line per program with XLA's memory analysis, the
+region-shaped copies in the compiled text and what it writes out in the
+shape of one layer's shard of a weight (``weight_copies``: a weight laid
+out anew in front of its product, every call; a seventh of the four-chip
+cell's device time until PR 55; read it at FULL depth before believing a
+2-layer text: the compiler hoists or prefetches at depth 2 what it
+re-does per layer per step at depth 40); exits 1 if any program fails
 to compile. libtpu warns about ``TPU_ACCELERATOR_TYPE`` / worker hostnames
 on a box with no TPU — harmless here.
 """
@@ -66,6 +71,18 @@ _COPY = re.compile(
     r"^\s*(?:ROOT\s+)?%?[\w.\-]+ = \(?(\w+)\[([\d,]*)\]"
     r"(?:[^\n]*?[)}])? copy(?:-start)?\(", re.M)
 _MOSAIC_BODY = re.compile(r'\\22body\\22: \\22[^\\]*\\22')
+# `%f.3 = (bf16[256,5120]{0,1:T(8,128)(2,1)S(1)}, bf16[256,5120]{...})
+# fusion(%custom-call.5), kind=kLoop, calls=%fused_computation.129`: a
+# fusion that writes a tuple (a dot's fusion is kOutput and writes one array)
+_TUPLE_FUSION = re.compile(
+    r"^\s*(?:ROOT\s+)?%?[\w.\-]+ = \(([^\n]*?)\) fusion\([^\n]*?"
+    r"kind=(\w+), calls=", re.M)
+_SHAPE = re.compile(r"(\w+)\[([\d,]+)\]")
+# a computation of the module, `%name (params) -> result {` ... `}`; a
+# fusion names its own by `calls=%name`
+_COMPUTATION = re.compile(
+    r"^(?:ENTRY\s+)?%?([\w.\-]+) \([^\n]*\) -> [^\n]*\{\n(.*?)^\}", re.M | re.S)
+_FUSED = re.compile(r" fusion\([^\n]*?calls=%?([\w.\-]+)")
 
 
 def region_copies(hlo_text: str, *shards: tuple[int, ...]) -> list[str]:
@@ -81,6 +98,37 @@ def region_copies(hlo_text: str, *shards: tuple[int, ...]) -> list[str]:
     return found
 
 
+def weight_copies(hlo_text: str, *shards: tuple[int, ...]) -> list[str]:
+    """What a compiled module writes out in the shape of one layer's shard
+    of a projection or MLP weight: the results of ``copy`` / ``copy-start``
+    and the elements of a tuple that a fusion other than a dot's writes (a
+    slice of the layer stack laid out anew, one output a layer:
+    ``slice_bitcast_fusion``). "The shape of": the shard's dimensions in
+    any order, ones dropped (``[1024,5120]`` and ``[1,5120,1024]`` for a
+    ``[5120,1024]`` shard). The element count alone, ``region_copies``'
+    test, takes activations for weights here (at Mistral-7B's widths the
+    rope halves of a ``[2, 1024]`` chunk's q, ``bf16[2,1024,32,64]``,
+    count as many as a ``wk``). Instructions INSIDE a fusion's computation
+    are that fusion's reads, nothing written, and do not count.
+    ``["bf16[1024,5120]", ...]``"""
+    def dims(shape):
+        return tuple(sorted(int(d) for d in shape if int(d) != 1))
+
+    wanted = {dims(s) for s in shards}
+    fused = set(_FUSED.findall(hlo_text))
+    found = []
+    for name, body in _COMPUTATION.findall(hlo_text):
+        if name in fused:
+            continue
+        written = [m.groups() for m in _COPY.finditer(body)]
+        for m in _TUPLE_FUSION.finditer(body):
+            if m.group(2) != "kOutput":
+                written += _SHAPE.findall(m.group(1))
+        found += [f"{dtype}[{shape}]" for dtype, shape in written
+                  if shape and dims(shape.split(",")) in wanted]
+    return found
+
+
 def compile_programs(model_config: str = "llama3_1b", tp: int = 1,
                      kv_quant: str = "none", layers: int = 0, *,
                      config: str = "", programs: tuple[str, ...] = (),
@@ -88,11 +136,12 @@ def compile_programs(model_config: str = "llama3_1b", tp: int = 1,
                      keep_text: bool = False) -> list[dict]:
     """Compile the serving programs for a tp-wide mesh of compile-only
     v5e devices. Returns one record per program: {"program", "ok",
-    "seconds", "error" | memory fields, "region_copies"}. ``config`` names
-    a file of benchmarks/configs and overrides ``model_config`` and
-    ``tp``; ``programs`` keeps the named ones only; ``prefill_width``
-    picks the prefill programs' bucket (0: the first); ``keep_text`` adds
-    the compiled module's text to the record (``"text"``)."""
+    "seconds", "error" | memory fields, "region_copies", "weight_copies"}.
+    ``config`` names a file of benchmarks/configs and overrides
+    ``model_config`` and ``tp``; ``programs`` keeps the named ones only;
+    ``prefill_width`` picks the prefill programs' bucket (0: the first);
+    ``keep_text`` adds the compiled module's text to the record
+    (``"text"``)."""
     import dataclasses
 
     import jax
@@ -174,6 +223,18 @@ def compile_programs(model_config: str = "llama3_1b", tp: int = 1,
     # convolution windows beside it are left out)
     state_shards = [shard for shard, width in (
         largest_shard(ctx[n]) for n in llama.state_kinds(ctx)) if width == 4]
+
+    # one layer's shard of each projection, MLP and expert weight (the
+    # leaves under a key that starts with "w"; the int8 tensor of a
+    # quantised one; an expert stack whole and one expert of it): what no
+    # program should lay out anew per call. The dense and latent blocks
+    # stack their layers along a leading axis, the hybrid ones list them
+    stacked = isinstance(params["layers"], dict)
+    weight_shards = set()
+    for path, w in jax.tree_util.tree_flatten_with_path(params["layers"])[0]:
+        if any(str(getattr(k, "key", "")).startswith("w") for k in path):
+            shard = w.sharding.shard_shape(w.shape)[int(stacked):]
+            weight_shards |= {shard[i:] for i in range(len(shard) - 1)}
 
     # the round AS THE ENGINE BUILDS IT: its jits close over these three
     # attributes and nothing else of an engine
@@ -293,6 +354,7 @@ def compile_programs(model_config: str = "llama3_1b", tp: int = 1,
                 region_bytes=math.prod(region_shard) * itemsize,
                 region_copies={"count": len(copies),
                                "shapes": sorted(set(copies))},
+                weight_copies=sorted(weight_copies(text, *weight_shards)),
                 mosaic_calls=text.count("tpu_custom_call"),
             )
             if keep_text:
