@@ -133,6 +133,9 @@ _SEG_RELEASES = tprof.SEGMENTS.index("releases")
 _SEG_TRANSFER = tprof.SEGMENTS.index("transfer")
 _SEG_OFFLOAD = tprof.SEGMENTS.index("offload")
 _SEG_ADMIT = tprof.SEGMENTS.index("admit")
+_SEG_ADMIT_PACK = tprof.SEGMENTS.index("admit_pack")
+_SEG_ADMIT_LAUNCH = tprof.SEGMENTS.index("admit_launch")
+_SEG_ADMIT_FIRST = tprof.SEGMENTS.index("admit_first")
 _SEG_SEAL_ASM = tprof.SEGMENTS.index("seal_assembly")
 _SEG_DISPATCH = tprof.SEGMENTS.index("dispatch")
 _SEG_SPEC = tprof.SEGMENTS.index("spec_dispatch")
@@ -196,6 +199,9 @@ class _Request:
     rounds_behind_prefill: int = 0
     behind_prefill_s: float = 0.0
     prefill_tokens_ahead: int = 0
+    # its LATE rounds (RoundProf.judge_round) and their excess by cause
+    late_rounds: int = 0
+    late_s: dict = field(default_factory=dict)
     # speculative decoding (spec/): a speculating slot's device lane
     # stays PARKED (dest=scratch) — its real state lives here on the
     # host and in the ctx region, driven by verify dispatches instead of
@@ -287,6 +293,8 @@ class _Entry:
     # round: (ordinal, prefill programs, their padded tokens) dispatched
     # since the round before: what stood ahead of it on the device
     ahead: tuple = (0, 0, 0)
+    # round, once consumed LATE: its excess by cause (judge_round)
+    late: Optional[dict] = None
     # spec verify: (draft_s, verify_s) host dispatch walls — become the
     # spec_draft / spec_verify child spans under the round span
     spec_host: Any = None
@@ -1821,6 +1829,15 @@ class TpuEngine:
     # engine loop
 
     def _run_loop(self) -> None:
+        prof = self.prof
+        prof.register_thread()
+        try:
+            self._loop(prof)
+        finally:
+            prof.unregister_thread()
+        self._drain_xfer_queue()
+
+    def _loop(self, prof: tprof.RoundProf) -> None:
         last_idle_beat = 0.0
         while not self._stop.is_set():
             try:
@@ -1838,6 +1855,10 @@ class TpuEngine:
                     log.exception("fail_all cleanup itself failed")
                 did_work = False
             if not did_work:
+                # the engine is EMPTY: the heartbeat and the doorbell wait
+                # are booked as idle, so recorded passes + idle = this
+                # thread's life (prof.totals()["loop_coverage"])
+                prof.idle_enter()
                 # idle heartbeat: busy rounds publish metrics themselves;
                 # an IDLE engine must keep heartbeating too, or the
                 # health plane's soft leases (resilience/health.py
@@ -1860,7 +1881,7 @@ class TpuEngine:
                     self._waiting.append(self._intake.get_nowait())
                 except queue_mod.Empty:
                     pass
-        self._drain_xfer_queue()
+                prof.idle_exit()
 
     def _drain_xfer_queue(self) -> None:
         """Abandon queued transfer ops with an error, not a long stall.
@@ -2012,7 +2033,13 @@ class TpuEngine:
                 and not self._prefilling and self._intake.empty()
                 and all(s is None for s in self._slots)):
             self._drained_evt.set()
-        prof.end_round(record=did_work)
+        stall = prof.end_round(record=did_work)
+        if stall is not None:
+            # the pass ran 0.1 s outside fetch: what it ran, the
+            # collector's part, and the round that waited on it
+            self.flight.record("stall", round=next(
+                (en.ahead[0] for en in self._entries if en.kind == "round"),
+                None), **stall)
         return did_work
 
     def _pipeline_clear(self) -> bool:
@@ -3003,6 +3030,10 @@ class TpuEngine:
         if programs:
             r.rounds_behind_prefill += 1
             r.prefill_tokens_ahead += padded
+        if entry.late is not None:
+            r.late_rounds += 1
+            for cause, s in entry.late.items():
+                r.late_s[cause] = r.late_s.get(cause, 0.0) + s
         r.t_last_emit = now
         r.decode_rounds += 1
         if (len(r.trace_spans) + len(r.round_spans) < _MAX_ROUND_SPANS
@@ -3079,6 +3110,11 @@ class TpuEngine:
         rid = r.req.request_id or None
         dur = r.t_last_emit - r.first_token_time
         timing["decode_s"] = round(dur, 6)
+        # its late rounds and what they cost it by cause, beside the
+        # behind-prefill split: a p90 request's own account
+        late_s = {c: round(v, 6) for c, v in r.late_s.items()}
+        timing["late_rounds"] = r.late_rounds
+        timing["late_s"] = late_s
         if r.produced > 1:
             tpot = dur / (r.produced - 1)
             timing["tpot_s"] = round(tpot, 6)
@@ -3093,6 +3129,7 @@ class TpuEngine:
                 rounds_behind_prefill=r.rounds_behind_prefill,
                 behind_prefill_s=round(r.behind_prefill_s, 6),
                 prefill_tokens_ahead=r.prefill_tokens_ahead,
+                late_rounds=r.late_rounds, late_s=late_s,
             )).to_dict()
         # materialize the lazily-accumulated round spans (the unix start
         # is anchored off the shared monotonic clock)
@@ -3566,6 +3603,8 @@ class TpuEngine:
                 budget -= len(group)
                 for r in self._batch_prefill_group(group, width):
                     self._waiting.remove(r)
+            # a dispatch site leaves in admit_pack / _launch / _first
+            self.prof.enter(_SEG_ADMIT)
 
     def _needs_solo_prefill(self, r: _Request) -> bool:
         """Paths the batched program doesn't carry: multimodal embedding
@@ -3641,6 +3680,7 @@ class TpuEngine:
         capped it): a group of two runs two lanes, and only a group of 3
         or 5-7 pads with scratch-lane dummies."""
         e = self.ecfg
+        self.prof.enter(_SEG_ADMIT_PACK)
         K = e.prefill_lanes(width, len(group))
         toks = np.zeros((K, width), np.int32)
         slots = np.full(K, self._B, np.int32)   # dummies -> scratch lane
@@ -3677,6 +3717,7 @@ class TpuEngine:
                 self.config, width, q_starts, seq_lens))
         self._observe_attn_pairs(width, q_starts, seq_lens, ctx_span)
         sorted_rows = llama.moe_prefill_rows_sorted(self.config, K * width)
+        self.prof.enter(_SEG_ADMIT_LAUNCH)
         self.ctx, logits, *moved = llama.batch_prefill(
             self.config, self.params, self.ctx, jnp.asarray(toks),
             jnp.asarray(slots), jnp.asarray(q_starts),
@@ -3689,6 +3730,7 @@ class TpuEngine:
             "prefill_batch", slots=[r.slot for r in group], width=width,
             dispatch_ms=round((time.monotonic() - t_disp) * 1e3, 3),
         )
+        self.prof.enter(_SEG_ADMIT_FIRST)
         done: list[_Request] = []
         for i, r in enumerate(group):
             r.prefill_chunks += 1
@@ -3898,6 +3940,7 @@ class TpuEngine:
 
         # one page-aligned continuation chunk (q_start advances); only the
         # final chunk's logits matter
+        self.prof.enter(_SEG_ADMIT_PACK)
         max_chunk = ((e.prefill_buckets[-1] + ps - 1) // ps) * ps
         start = r.prefill_pos
         chunk = prompt[start : start + max_chunk]
@@ -3945,6 +3988,7 @@ class TpuEngine:
             e.max_context if start else 0)
         r.prefill_chunks += 1
         sorted_rows = llama.moe_prefill_rows_sorted(self.config, pad_t)
+        self.prof.enter(_SEG_ADMIT_LAUNCH)
         # a fresh prompt runs the program with no read of the region
         self.ctx, logits, *moved = llama.prefill(
             self.config, self.params, self.ctx,
@@ -3959,6 +4003,7 @@ class TpuEngine:
             "prefill", slots=[r.slot], tokens=len(chunk), start=start,
             dispatch_ms=round((time.monotonic() - t_disp) * 1e3, 3),
         )
+        self.prof.enter(_SEG_ADMIT_FIRST)
         r.prefill_pos = start + len(chunk)
         if r.prefill_pos < len(prompt):
             # commit the chunk's complete blocks now (prefix-hittable /
@@ -3986,6 +4031,7 @@ class TpuEngine:
         r.slot = slot
         self._prefilling[slot] = r
         self._note_queue_wait(r)
+        self.prof.enter(_SEG_ADMIT_PACK)
         sp_n = self.mesh.shape["sp"]
         pad = -len(prompt) % sp_n
         toks = np.zeros(len(prompt) + pad, np.int32)
@@ -4002,6 +4048,7 @@ class TpuEngine:
         self._h_pf_live.observe(n * (n + 1) // 2)
         self._h_pf_scored.observe(len(toks) ** 2)
         r.prefill_chunks += 1
+        self.prof.enter(_SEG_ADMIT_LAUNCH)
         kv, logits = llama.sp_prefill(
             self.config, self.params,
             sp_shard(jnp.asarray(toks), self.mesh),
@@ -4012,6 +4059,7 @@ class TpuEngine:
             dispatch_ms=round((time.monotonic() - t_disp) * 1e3, 3),
         )
         self.ctx = llama.write_ctx_span(self.ctx, jnp.int32(slot), kv)
+        self.prof.enter(_SEG_ADMIT_FIRST)
         r.prefill_pos = len(prompt)
         r.matched_blocks = 0
         self.sp_prefills += 1
@@ -4277,8 +4325,22 @@ class TpuEngine:
             self._h_pf_ahead.observe(padded)
         else:
             self._h_step_gap_clean.observe(gap)
-        self.prof.mark_round(consumed=ordinal, wall_us=int(wall * 1e6),
-                             steps=entry.n_steps)
+        mark = dict(consumed=ordinal, wall_us=int(wall * 1e6),
+                    steps=entry.n_steps)
+        # against the last clean rounds' mean wall: did it come late, and
+        # where did its excess go (RoundProf.judge_round)
+        late = self.prof.judge_round(wall, entry.n_steps, bool(programs))
+        if late is not None:
+            cause, entry.late, lead, expected = late
+            mark["late"] = cause
+            self.flight.record(
+                "late_round", round=ordinal, wall_ms=round(wall * 1e3, 3),
+                expected_ms=round(expected * 1e3, 3), cause=cause,
+                excess_ms={c: round(v * 1e3, 3)
+                           for c, v in entry.late.items()},
+                host_segment=lead,
+            )
+        self.prof.mark_round(**mark)
         lp_arrs = None
         if entry.lp_handle is not None:
             lp_arrs = self._unpack_lp(np.asarray(entry.lp_handle))

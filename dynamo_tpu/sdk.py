@@ -67,6 +67,10 @@ class RequestStats:
     itl_p95_s: Optional[float] = None
     e2e_s: Optional[float] = None
     queue_s: Optional[float] = None
+    # its decode rounds that took twice a clean round's wall, and their
+    # excess seconds by cause (gc / behind_prefill / host / other)
+    late_rounds: int = 0
+    late_s: dict[str, float] = field(default_factory=dict)
 
     @property
     def spec_acceptance_rate(self) -> Optional[float]:
@@ -101,6 +105,8 @@ def request_stats(outputs: Iterable[Any]) -> RequestStats:
                         "queue_s"):
                 if timing.get(key) is not None:
                     setattr(st, key, float(timing[key]))
+            st.late_rounds = int(timing.get("late_rounds", 0))
+            st.late_s = dict(timing.get("late_s") or {})
     return st
 
 

@@ -27,11 +27,25 @@ device trace's idle gaps can be labelled by the host segment that covers
 them, and each fused round leaves an ``engine/round`` mark at its dispatch
 and at its consume (``mark_round``) that names it by ordinal
 (tools/trace_gaps.py).
+
+The loop's clock closes (PR 56). Beside the recorded passes the engine
+thread books its EMPTY time (``idle_enter`` / ``idle_exit`` around the
+doorbell wait, and the passes ``end_round(record=False)`` drops), so
+recorded wall + idle is the thread's life (``loop_coverage``). One
+module-level ``gc.callbacks`` hook times the interpreter's cyclic
+collector by generation, by thread and by the segment the engine thread
+stood in when the collection ended: an overlay on that segment, not a
+segment of its own, so the segment sums still equal the wall. A pass whose
+wall outside ``fetch`` reaches ``STALL_S`` is a stall, and a consumed
+fused round that took twice the last clean rounds' mean names where its excess
+went (``judge_round``).
 """
 from __future__ import annotations
 
+import gc
 import threading
 import time
+import weakref
 from typing import Any, Optional
 
 import numpy as np
@@ -51,7 +65,10 @@ SEGMENTS = (
     "releases",       # _apply_releases: freed-lane patches
     "transfer",       # _process_transfers + export-stream servicing
     "offload",        # _dispatch_offloads + _drain_host_ingest
-    "admit",          # _admit: prefill dispatch + admission patches
+    "admit",          # _admit: waiting scan, shedding, lane + prefix match
+    "admit_pack",     # a prefill dispatch's host assembly: rows, mirrors
+    "admit_launch",   # its jnp.asarray uploads + the prefill program call
+    "admit_first",    # _finish_prefill: first-token sample, patch, seals
     "seal_assembly",  # _take_seal_batch: seal-batch packing
     "dispatch",       # _dispatch_round: fused-round program launch
     "spec_dispatch",  # _dispatch_spec: draft + verify launches
@@ -62,8 +79,17 @@ SEGMENTS = (
 _SEG_INDEX = {s: i for i, s in enumerate(SEGMENTS)}
 _N_SEG = len(SEGMENTS)
 _OTHER = _SEG_INDEX["other"]
+_FETCH = _SEG_INDEX["fetch"]
 ANNOTATION_PREFIX = "host/"
 _ANN_NAMES = tuple(ANNOTATION_PREFIX + s for s in SEGMENTS)
+# the engine thread outside a pass: the doorbell wait of an empty engine.
+# No segment (it is not host work), but a label of the same plane: the
+# collector's seconds by segment and tools/trace_gaps.py both use it
+IDLE = "idle"
+IDLE_ANNOTATION = ANNOTATION_PREFIX + IDLE
+# pauses of the whole interpreter, on the profiler's clock beside host/*
+PAUSE_PREFIX = "pause/"
+GC_ANNOTATION = PAUSE_PREFIX + "gc"
 # a fused round's two marks in a profiler trace (mark_round): no segment,
 # so outside the prefix that tools/trace_gaps.py labels idle gaps by
 ROUND_ANNOTATION = "engine/round"
@@ -71,13 +97,23 @@ ROUND_ANNOTATION = "engine/round"
 # host segments run at µs scale — DEFAULT_TIME_BUCKETS' 0.5 ms floor
 # would flatten the whole distribution into one bucket. Same ~1.6x step
 # ladder, shifted three decades down, topping out at 0.1 s (a host slice
-# beyond that is a bug the +Inf bucket makes visible).
+# beyond that is a bug the +Inf bucket makes visible, and a pass that
+# long outside `fetch` is booked as a stall: STALL_S).
 HOST_BUCKETS = (
     0.000002, 0.000005, 0.00001, 0.00002, 0.000035, 0.00005, 0.000075,
     0.0001, 0.0002, 0.00035, 0.0005, 0.00075,
     0.001, 0.002, 0.0035, 0.005, 0.0075,
     0.01, 0.02, 0.035, 0.05, 0.1,
 )
+
+# a pass whose wall outside `fetch` reaches the top edge is a stall
+STALL_S = HOST_BUCKETS[-1]
+# a consumed fused round is judged against the mean wall of the last this
+# many clean rounds (none is judged before that many were seen), and is
+# late past this multiple of it
+LATE_MIN_CLEAN = 16
+LATE_FACTOR = 2.0
+LATE_CAUSES = ("gc", "behind_prefill", "host", "other")
 
 HOST_ROUND = ("dynamo_host_round_seconds",
               "host wall time per engine round by attribution segment")
@@ -92,6 +128,65 @@ SLO_ITL_BURN = ("dynamo_slo_itl_burn_rate",
                 "ITL SLO burn rate: fraction of token gaps over the "
                 "target divided by the error budget (1-objective); "
                 ">1 burns budget")
+
+
+# ---- the interpreter's cyclic collector, timed -------------------------
+#
+# ONE hook for the process, installed with the first RoundProf whose
+# engine thread registers and removed with the last (tier-1 builds
+# hundreds of engines in one process); the RoundProfs are held weakly. A
+# collection runs start -> stop on one thread with the GIL held and never
+# nests, so its start time is one module-level float.
+
+_gc_profs: list = []            # weakref.ref(RoundProf), registered threads
+_gc_lock = threading.RLock()    # re-entrant: a ref's callback may run
+#                                 inside a collection that register began
+_gc_t0 = 0.0
+_gc_ann = None
+
+
+def _gc_hook(phase: str, info: dict) -> None:
+    global _gc_t0, _gc_ann
+    if phase == "start":
+        for ref in tuple(_gc_profs):
+            p = ref()
+            if p is not None and p._tracing:
+                _gc_ann = p._annotation(
+                    GC_ANNOTATION, generation=info["generation"])
+                break
+        _gc_t0 = time.monotonic()
+        return
+    dt = time.monotonic() - _gc_t0
+    if _gc_ann is not None:
+        _gc_ann.__exit__(None, None, None)
+        _gc_ann = None
+    tid = threading.get_ident()
+    for ref in tuple(_gc_profs):
+        p = ref()
+        if p is not None:
+            p._note_gc(info["generation"], dt, tid)
+
+
+def _gc_forget(ref) -> None:
+    with _gc_lock:
+        if ref in _gc_profs:
+            _gc_profs.remove(ref)
+        if not _gc_profs and _gc_hook in gc.callbacks:
+            gc.callbacks.remove(_gc_hook)
+
+
+def _gc_register(prof: "RoundProf") -> None:
+    with _gc_lock:
+        if not any(ref() is prof for ref in _gc_profs):
+            _gc_profs.append(weakref.ref(prof, _gc_forget))
+        if _gc_hook not in gc.callbacks:
+            gc.callbacks.append(_gc_hook)
+
+
+def _gc_unregister(prof: "RoundProf") -> None:
+    for ref in tuple(_gc_profs):
+        if ref() is prof:
+            _gc_forget(ref)
 
 
 class RoundProf:
@@ -116,6 +211,12 @@ class RoundProf:
     unrecorded round (the idle spin) is dropped whole: an engine with no
     request is idle, not starved. Calibrated against the device trace's
     idle share in PERF.md.
+
+    The empty engine's own time is ``idle``: the unrecorded passes and
+    the loop's doorbell waits between them, so that recorded wall + idle
+    is the engine thread's life. The collector's pauses, stalls and late
+    rounds are accounts BESIDE the segments (module docstring): none of
+    them takes a second out of a segment.
     """
 
     RING = 256  # recent per-round records kept for /debug/prof + timeline
@@ -155,8 +256,50 @@ class RoundProf:
         self._ring_acc = np.zeros((self.RING, _N_SEG))
         self._rec_n = 0
         self._fold_mark = 0
+        # the empty engine: the doorbell waits and the dropped passes
+        self.idle_total = 0.0
+        self.idle_waits = 0
+        # the engine thread, once its loop runs (register_thread): its
+        # ident, when it started and what was booked before it did
+        self._thread: Optional[int] = None
+        self._t_loop0 = 0.0
+        self._booked0 = 0.0
+        # the collector (_note_gc, from the module's hook): by generation,
+        # on the engine thread, and by the segment the engine thread stood
+        # in when the collection ended (last index: idle)
+        self.gc_collections = [0, 0, 0]
+        self.gc_pause_s = [0.0, 0.0, 0.0]
+        self.gc_on_loop_s = 0.0
+        self.gc_by_segment = [0.0] * (_N_SEG + 1)
+        self.gc_total_s = 0.0
+        self._gc_pass_mark = 0.0       # gc_total_s at begin_round
+        # stalls: passes whose wall outside fetch reached STALL_S
+        self.stall_count = 0
+        self.stall_total = 0.0
+        self.stall_by_segment = [0.0] * _N_SEG
+        # late rounds (judge_round), and the marks of the previous consume
+        self.late_rounds = 0
+        self.late_judged = 0
+        self.late_excess = dict.fromkeys(LATE_CAUSES, 0.0)
+        # the last LATE_MIN_CLEAN clean rounds' walls a step: the yardstick
+        self._clean_gaps = [0.0] * LATE_MIN_CLEAN
+        self._clean_n = 0
+        self._consume_rec = 0
+        self._consume_gc = 0.0
 
     # -- engine-thread hot path ----------------------------------------
+
+    def register_thread(self) -> None:
+        """The engine thread, as its loop starts: from here recorded wall
+        + idle is this thread's life, and the collector is timed."""
+        self._thread = threading.get_ident()
+        self._t_loop0 = self._t = time.monotonic()
+        self._booked0 = self.wall_total + self.idle_total
+        _gc_register(self)
+
+    def unregister_thread(self) -> None:
+        """The loop has ended: the hook goes with its last RoundProf."""
+        _gc_unregister(self)
 
     def begin_round(self) -> None:
         t = time.monotonic()
@@ -165,6 +308,7 @@ class RoundProf:
         self._t = t
         self._t_begin = t
         self._in_round = True
+        self._gc_pass_mark = self.gc_total_s
         self._tracing = self._annotation.is_enabled()
 
     def _charge(self) -> None:
@@ -180,9 +324,7 @@ class RoundProf:
             return
         self._charge()
         self._seg = seg
-        if self._ann_open is not None:
-            self._ann_open.__exit__(None, None, None)
-            self._ann_open = None
+        self._close_annotation()
         if self._tracing:
             self._ann_open = self._annotation(_ANN_NAMES[seg])
 
@@ -217,27 +359,136 @@ class RoundProf:
         self.enter(seg)
         return prev
 
-    def end_round(self, record: bool = True) -> None:
-        if not self._in_round:
-            return
-        self._charge()  # close the open segment
+    def _close_annotation(self) -> None:
         if self._ann_open is not None:
             self._ann_open.__exit__(None, None, None)
             self._ann_open = None
+
+    def end_round(self, record: bool = True) -> Optional[dict]:
+        """Close the pass. ``record=False``: nothing was live, the pass is
+        the empty engine's and its wall goes to ``idle``. Returns the
+        stall's account when the pass was one (its wall outside ``fetch``
+        reached ``STALL_S``), for the caller's flight recorder."""
+        if not self._in_round:
+            return None
+        self._charge()  # close the open segment
+        self._close_annotation()
         self._in_round = False
-        if not record:
+        wall = self._t - self._t_begin
+        if record:
+            row = self._rec_n % self.RING
+            self._ring_acc[row] = self._acc
+            self._ring_wall[row] = wall
+            self._ring_ts[row] = time.time()
+            self._rec_n += 1
+            self.total += self._ring_acc[row]
+            self.rounds += 1
+            self.wall_total += wall
+        else:
             # idle spin — keep µs no-op rounds out of the stats
             self._idle_since_poll = True
-            return
-        wall = self._t - self._t_begin
-        row = self._rec_n % self.RING
-        self._ring_acc[row] = self._acc
-        self._ring_wall[row] = wall
-        self._ring_ts[row] = time.time()
-        self._rec_n += 1
-        self.total += self._ring_acc[row]
-        self.rounds += 1
-        self.wall_total += wall
+            self.idle_total += wall
+        host = wall - self._acc[_FETCH]
+        return self._book_stall(host) if host >= STALL_S else None
+
+    def _book_stall(self, host: float) -> dict:
+        acc = self._acc
+        lead = max((i for i in range(_N_SEG) if i != _FETCH),
+                   key=acc.__getitem__)
+        self.stall_count += 1
+        self.stall_total += host
+        self.stall_by_segment[lead] += host
+        return {
+            "host_ms": round(host * 1e3, 3),
+            "segment": SEGMENTS[lead],
+            "gc_ms": round((self.gc_total_s - self._gc_pass_mark) * 1e3, 3),
+            "segments_ms": {SEGMENTS[i]: round(v * 1e3, 3)
+                            for i, v in enumerate(acc) if v > 0.0},
+        }
+
+    def idle_enter(self) -> None:
+        """The pass found nothing to do and the loop is about to wait on
+        its doorbell: while a session is on the wait is ``host/idle``."""
+        if self._tracing:
+            self._ann_open = self._annotation(IDLE_ANNOTATION)
+
+    def idle_exit(self) -> None:
+        """The wait is over: everything since the dropped pass closed is
+        the empty engine's."""
+        t = time.monotonic()
+        self.idle_total += t - self._t
+        self._t = t
+        self.idle_waits += 1
+        self._close_annotation()
+
+    def _note_gc(self, generation: int, dt: float, thread: int) -> None:
+        """One collection ended (any thread: it held the GIL). Booked to
+        the segment this engine's thread stands in NOW: one that ends
+        while it waits in ``fetch`` on the device cost it nothing and
+        reads so. An overlay: the segment keeps the seconds too."""
+        self.gc_collections[generation] += 1
+        self.gc_pause_s[generation] += dt
+        self.gc_total_s += dt
+        if thread == self._thread:
+            self.gc_on_loop_s += dt
+        self.gc_by_segment[self._seg if self._in_round else _N_SEG] += dt
+
+    def judge_round(self, wall: float, steps: int,
+                    behind: bool) -> Optional[tuple]:
+        """A fused round of ``steps`` steps was consumed after ``wall``
+        seconds, ``behind`` prefill programs or clean. The yardstick comes
+        from the run: the mean wall a step of the LAST ``LATE_MIN_CLEAN``
+        clean rounds (none is judged before that many were seen; a running
+        mean since the engine started would carry warm-up's compiling
+        rounds into the window: 95-108 ms against 44 on the chip, PERF.md
+        section 6, PR 56). Past ``LATE_FACTOR`` x expected the round is
+        LATE and its excess is booked, in this order: to ``gc`` up to the
+        collector's seconds since the previous consume; then to
+        ``behind_prefill`` if ``behind``; else to ``host`` if the segments
+        other than ``fetch`` ran, beside the collector, at least half of
+        what is left since the previous consume (the passes the ring holds
+        since then, taken whole); else to ``other``: the device's own.
+        Returns (cause, {cause: s}, leading host segment, expected) for a
+        late round: the cause is where most of the excess went. Every
+        other round pays a sum of sixteen, the comparison and a store."""
+        late = None
+        n = self._clean_n
+        if n >= LATE_MIN_CLEAN:
+            self.late_judged += 1
+            expected = sum(self._clean_gaps) / LATE_MIN_CLEAN * steps
+            if wall > LATE_FACTOR * expected:
+                late = self._book_late(wall - expected, behind) + (expected,)
+        if not behind:
+            self._clean_gaps[n % LATE_MIN_CLEAN] = wall / steps
+            self._clean_n = n + 1
+        self._consume_rec = self._rec_n
+        self._consume_gc = self.gc_total_s
+        return late
+
+    def _book_late(self, excess: float, behind: bool) -> tuple:
+        n = min(self._rec_n - self._consume_rec, self.RING)
+        host = self._ring_acc[self._rows(n)].sum(axis=0)
+        if self._in_round:
+            host += self._acc
+        host[_FETCH] = 0.0
+        lead = SEGMENTS[int(host.argmax())]
+        parts = {}
+        gc_s = min(excess, self.gc_total_s - self._consume_gc)
+        if gc_s > 0.0:
+            parts["gc"] = gc_s
+        left = excess - gc_s
+        if left > 0.0:
+            if behind:
+                cause = "behind_prefill"
+            elif float(host.sum()) - gc_s >= 0.5 * left:
+                cause = "host"
+            else:
+                cause = "other"
+            parts[cause] = left
+        self.late_rounds += 1
+        for cause, s in parts.items():
+            self.late_excess[cause] += s
+        return max(parts, key=parts.get), parts, lead
 
     # -- fold / read side ----------------------------------------------
 
@@ -253,19 +504,6 @@ class RoundProf:
         if n <= 0:
             return None
         return self._ring_acc[self._rows(n)]
-
-    def drain(self) -> list[tuple]:
-        """Unfolded rounds as (end_unix_s, wall_s, (per-seg s, ...))
-        tuples — the legacy wire form (tests, ad-hoc tooling); the hot
-        fold path uses drain_arrays() and never builds these."""
-        n = min(self._rec_n - self._fold_mark, self.RING)
-        rows = self._rows(n)
-        self._fold_mark = self._rec_n
-        return [
-            (float(self._ring_ts[r]), float(self._ring_wall[r]),
-             tuple(self._ring_acc[r]))
-            for r in rows
-        ]
 
     def recent(self, n: int = 64) -> list[tuple]:
         n = min(n, self._rec_n, self.RING)
@@ -290,11 +528,49 @@ class RoundProf:
                     for i, s in enumerate(SEGMENTS)
                 },
             },
+            # the collector, an overlay on the segments (any thread's
+            # collection holds the GIL); by the engine thread's segment
+            # when each ended, `idle` for the empty engine
+            "gc": {
+                "collections": list(self.gc_collections),
+                "pause_s": list(self.gc_pause_s),
+                "on_loop_s": self.gc_on_loop_s,
+                "by_segment_s": {
+                    s: v for s, v in zip(SEGMENTS + (IDLE,),
+                                         self.gc_by_segment) if v > 0.0},
+            },
+            # the empty engine: wall_s + idle.total_s is the thread's life
+            "idle": {"total_s": self.idle_total, "waits": self.idle_waits},
+            "loop_coverage": self.loop_coverage(),
+            "stalls": {
+                "count": self.stall_count,
+                "total_s": self.stall_total,
+                "by_segment_s": {
+                    s: v for s, v in zip(SEGMENTS, self.stall_by_segment)
+                    if v > 0.0},
+            },
+            "late": {
+                "rounds": self.late_rounds,
+                "judged": self.late_judged,
+                "excess_s": dict(self.late_excess),
+            },
         }
 
     def coverage(self) -> float:
         return (float(self.total.sum()) / self.wall_total
                 if self.wall_total > 0 else 1.0)
+
+    def loop_coverage(self) -> float:
+        """(recorded wall + idle) / the engine thread's life up to the
+        books' own last reading of the clock; what is missing is the few
+        statements between two passes. 1.0 before a thread registers."""
+        life = self._t - self._t_loop0
+        if self._thread is None or life <= 0.0:
+            return 1.0
+        booked = self.wall_total + self.idle_total - self._booked0
+        if self._in_round:
+            booked += sum(self._acc)
+        return booked / life
 
     def summary(self, top: int = 0) -> dict[str, Any]:
         """The /debug/prof payload: cumulative per-segment share plus a
@@ -339,7 +615,20 @@ class RoundProf:
                         key=lambda kv: -kv[1]) if v > 0.0
                 },
             },
+            # the loop's clock closed (totals() has each to the full)
+            "loop_coverage": round(totals["loop_coverage"], 4),
+            **{k: _rounded(totals[k])
+               for k in ("gc", "idle", "stalls", "late")},
         }
+
+
+def _rounded(v: Any) -> Any:
+    """A totals() subtree with its seconds rounded for display."""
+    if isinstance(v, dict):
+        return {k: _rounded(x) for k, x in v.items()}
+    if isinstance(v, list):
+        return [_rounded(x) for x in v]
+    return round(v, 6) if isinstance(v, float) else v
 
 
 class ProfRegistry:
@@ -492,7 +781,15 @@ PROF = ProfRegistry()
 __all__ = [
     "SEGMENTS",
     "ANNOTATION_PREFIX",
+    "PAUSE_PREFIX",
+    "GC_ANNOTATION",
+    "IDLE",
+    "IDLE_ANNOTATION",
     "ROUND_ANNOTATION",
+    "STALL_S",
+    "LATE_MIN_CLEAN",
+    "LATE_FACTOR",
+    "LATE_CAUSES",
     "HOST_BUCKETS",
     "HOST_ROUND",
     "COVERAGE",

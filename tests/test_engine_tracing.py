@@ -111,6 +111,18 @@ def served():
 
     async def scenario():
         eng = _engine()
+        # what ONE segment around _admit would read, for the four it is
+        # split into
+        admit_wall, inner = [0.0], eng._admit
+
+        def timed_admit():
+            t = time.perf_counter()
+            try:
+                inner()
+            finally:
+                admit_wall[0] += time.perf_counter() - t
+
+        eng._admit = timed_admit
         eng.start()
         h0 = _hists(eng)
         t_sent = time.time()
@@ -129,7 +141,10 @@ def served():
         await eng.stop()
         return {"cold": cold, "again": again, "one": one,
                 "h": (h0, h1, h2), "h3": h3, "wall": wall,
-                "starved": starved, "flush_every": eng.ecfg.flush_every}
+                "starved": starved, "flush_every": eng.ecfg.flush_every,
+                "prof": eng.prof.totals(), "admit_wall": admit_wall[0],
+                "dispatch_counts": dict(eng.dispatch_counts),
+                "flight": eng.flight.snapshot()}
 
     return asyncio.run(scenario())
 
@@ -507,7 +522,8 @@ def test_e2e_is_the_phases_on_a_scripted_clock():
         eng._note_emit(r, 2, _Entry("round", None, t_dispatch=101.5,
                                     ahead=(7, 1, 64)), "decode_round")
         clock.t = 103.0
-        eng._note_emit(r, 2, _Entry("round", None, t_dispatch=102.5),
+        eng._note_emit(r, 2, _Entry("round", None, t_dispatch=102.5,
+                                    late={"gc": 0.25, "host": 0.5}),
                        "decode_round")
         clock.t = 103.25
         ann = eng._final_annotations(r)
@@ -526,7 +542,16 @@ def test_e2e_is_the_phases_on_a_scripted_clock():
     assert dec["attrs"] == {
         "request_id": "r1", "tokens": 4, "rounds": 2,
         "rounds_behind_prefill": 1, "behind_prefill_s": 1.0,
-        "prefill_tokens_ahead": 64}
+        "prefill_tokens_ahead": 64,
+        # the second round was consumed LATE: its excess by cause
+        "late_rounds": 1, "late_s": {"gc": 0.25, "host": 0.5}}
+    assert (timing["late_rounds"], timing["late_s"]) == (
+        1, {"gc": 0.25, "host": 0.5})
+    from dynamo_tpu.protocols.common import FinishReason, LLMEngineOutput
+    from dynamo_tpu.sdk import request_stats
+    st = request_stats([LLMEngineOutput(
+        token_ids=[], finish_reason=FinishReason.LENGTH, annotations=ann)])
+    assert (st.late_rounds, st.late_s) == (1, {"gc": 0.25, "host": 0.5})
     assert [(c["name"], c["duration_s"], c["attrs"]["tokens"])
             for c in dec["children"]] == [("decode_round", 0.5, 2)] * 2
 
@@ -708,9 +733,184 @@ def test_round_marks_carry_their_stats_only_in_a_session(session):
         ("close", "engine/round")]
 
 
+# ---- a late round names its cause, the loop's clock closes --------------
+
+
+@pytest.fixture(scope="module")
+def late():
+    """An engine that was never started, its fused rounds dispatched and
+    consumed by hand on ONE scripted clock (the engine module's and the
+    prof module's), a profiler session stubbed on. Fifteen clean rounds of
+    1 s; one of 10 s behind a prefill and a sixteenth clean one, which
+    nothing judges yet; then one round a cause, each read off the books
+    before and after it, beside the mean of the sixteen clean walls
+    before it."""
+    from dynamo_tpu.engine import engine as engine_mod
+
+    eng = _engine()
+    clock = _Clock()
+    real = engine_mod.time, tprof.time
+    engine_mod.time = tprof.time = clock
+    _StubAnnotation.log = []
+    _StubAnnotation.enabled = True
+    p = eng.prof
+    p._annotation, p._tracing = _StubAnnotation, True
+    p.register_thread()
+    out = {"n": eng.ecfg.flush_every}
+    clean_walls = []
+
+    def run_round(wall, during=None, behind=False, name=None):
+        last = clean_walls[-tprof.LATE_MIN_CLEAN:]
+        t0, before = clock.t, p.totals()["late"]
+        if behind:
+            eng._newest = _Handle(False)
+            eng._note_prefill_dispatch(40, 64)
+        eng._dispatch_round([0], False, False)
+        if during is not None:
+            during()
+        clock.t = t0 + wall
+        eng._consume_entry(eng._entries.pop(0))
+        clock.t += 1.0                    # none in flight for a second
+        if not behind:
+            clean_walls.append(wall)
+        if name is not None:
+            out[name] = {
+                "expected": sum(last) / len(last),
+                "before": before, "after": p.totals()["late"],
+                "flight": [ev for ev in eng.flight.snapshot()
+                           if ev["kind"] == "late_round"][-1:]}
+
+    def collection():
+        clock.t += 0.5
+        tprof._gc_hook("start", {"generation": 2})
+        clock.t += 3.0
+        tprof._gc_hook("stop", {"generation": 2})
+
+    def host_pass():
+        p.begin_round()
+        p.enter(SEGMENTS.index("admit_launch"))
+        clock.t += 3.0
+        p.enter(SEGMENTS.index("fetch"))
+        clock.t += 0.5
+        p.end_round()
+        p._tracing = True                 # the stub session stays on
+
+    try:
+        for _ in range(15):
+            run_round(1.0)
+        run_round(10.0, behind=True, name="too_few_behind")
+        run_round(10.0, name="too_few_clean")
+        # 16 clean rounds now, the last of 10 s: from here every round is
+        # judged against the mean wall of the sixteen clean ones before it
+        run_round(1.5, name="on_time")
+        run_round(6.0, during=collection, name="gc")
+        run_round(9.0, behind=True, name="behind_prefill")
+        run_round(7.0, during=host_pass, name="host")
+        run_round(5.0, name="other")
+        out["marks"] = [ev for ev in _StubAnnotation.log
+                        if ev[:2] == ("open", tprof.ROUND_ANNOTATION)
+                        and any(k == "consumed" for k, _ in ev[2:])]
+        out["totals"] = p.totals()
+    finally:
+        p.unregister_thread()
+        engine_mod.time, tprof.time = real
+    return out
+
+
+@pytest.mark.parametrize("case", [
+    "too_few_behind", "too_few_clean", "on_time",
+    "gc", "behind_prefill", "host", "other", "the_marks_name_the_cause",
+])
+def test_a_late_round_names_its_cause(late, case):
+    if case == "the_marks_name_the_cause":
+        # every consume leaves its mark; only the late ones say so
+        causes = [dict(ev[2:]).get("late") for ev in late["marks"]]
+        assert causes == [None] * 18 + ["gc", "behind_prefill", "host",
+                                        "other"]
+        assert late["totals"]["late"]["rounds"] == 4
+        assert late["totals"]["late"]["judged"] == 5
+        return
+    got = late[case]
+    before, after = got["before"], got["after"]
+    booked = {c: after["excess_s"][c] - before["excess_s"][c]
+              for c in tprof.LATE_CAUSES}
+    if case.startswith("too_few"):
+        # 15 clean rounds seen, then 15 again (the round behind a prefill
+        # is no clean one): a wall of ten times theirs is judged by nothing
+        assert got["expected"] == 1.0
+        assert after == before and after["judged"] == 0
+        assert got["flight"] == []
+        return
+    # (15 x 1 s + 10 s) / 16, then the window of sixteen moves on
+    exp = got["expected"]
+    assert after["judged"] == before["judged"] + 1
+    if case == "on_time":
+        assert exp == 25.0 / 16 and 1.5 <= 2 * exp
+        assert after["rounds"] == before["rounds"] and got["flight"] == []
+        return
+    assert after["rounds"] == before["rounds"] + 1
+    wall = {"gc": 6.0, "behind_prefill": 9.0, "host": 7.0, "other": 5.0}[case]
+    assert wall > 2 * exp
+    want = dict.fromkeys(tprof.LATE_CAUSES, 0.0)
+    if case == "gc":
+        # the collector's 3 s first; the host ran nothing: the rest is
+        # the device's own
+        want.update(gc=3.0, other=wall - exp - 3.0)
+    else:
+        want[case] = wall - exp
+    assert booked == pytest.approx(want)
+    (ev,) = got["flight"]
+    assert (ev["cause"], ev["wall_ms"]) == (case, wall * 1e3)
+    assert ev["expected_ms"] == pytest.approx(exp * 1e3, abs=1e-3)
+    assert ev["excess_ms"] == pytest.approx(
+        {c: v * 1e3 for c, v in want.items() if v}, abs=1e-3)
+    # the host segment that ran most since the consume before
+    assert ev["host_segment"] == (
+        "admit_launch" if case == "host" else SEGMENTS[0])
+
+
+@pytest.mark.parametrize("case", [
+    "wall_plus_idle_is_the_threads_life",
+    "the_four_admit_segments_are_what_one_read",
+    "the_collector_was_timed",
+])
+def test_the_loops_clock_closes_on_a_served_engine(served, case):
+    prof = served["prof"]
+    seg = prof["segments"]
+    if case == "wall_plus_idle_is_the_threads_life":
+        assert sum(seg.values()) == pytest.approx(prof["wall_s"])
+        assert prof["idle"]["waits"] >= 1 and prof["idle"]["total_s"] > 0.0
+        assert 0.98 <= prof["loop_coverage"] <= 1.0 + 1e-9
+    elif case == "the_four_admit_segments_are_what_one_read":
+        parts = [seg[s] for s in ("admit", "admit_pack", "admit_launch",
+                                  "admit_first")]
+        assert all(v > 0.0 for v in parts)
+        # all the segment switches inside _admit cost less than this
+        assert sum(parts) == pytest.approx(served["admit_wall"], abs=5e-3)
+        n = sum(served["dispatch_counts"][k]
+                for k in ("prefill", "prefill_batch", "sp_prefill"))
+        assert n == len(PROMPT_LENS) + 2
+    else:
+        gc_t = prof["gc"]
+        assert sum(gc_t["collections"]) >= 1
+        assert sum(gc_t["by_segment_s"].values()) == pytest.approx(
+            sum(gc_t["pause_s"]))
+        assert gc_t["on_loop_s"] <= sum(gc_t["pause_s"]) + 1e-12
+        # on this box a stall is compile time in admit_launch, and each
+        # left its event
+        stalls = [ev for ev in served["flight"] if ev["kind"] == "stall"]
+        assert prof["stalls"]["count"] >= len(stalls)
+        assert prof["stalls"]["total_s"] == pytest.approx(
+            sum(prof["stalls"]["by_segment_s"].values()))
+
+
 def test_segments_land_on_the_profilers_host_plane(tmp_path):
     """A real profiler session at the benchmark's tracer levels: the
-    segments are events of the /host:CPU plane, named host/<segment>."""
+    segments are events of the /host:CPU plane, named host/<segment>; the
+    empty engine's wait is host/idle and a collection pause/gc beside
+    them."""
+    import gc
+
     import jax
     from jax.profiler import ProfileData
 
@@ -729,7 +929,15 @@ def test_segments_land_on_the_profilers_host_plane(tmp_path):
         p.mark_round(dispatched=7, programs_ahead=1, padded_tokens_ahead=64)
         p.mark_round(consumed=7, wall_us=1234, steps=4)
         p.end_round()
+        p.register_thread()
+        p.begin_round()                   # an empty engine's pass
+        gc.collect()
+        p.end_round(record=False)
+        p.idle_enter()
+        time.sleep(0.002)
+        p.idle_exit()
     finally:
+        p.unregister_thread()
         jax.profiler.stop_trace()
     # the round marks come back with their stats, keyed by ordinal
     gaps = _trace_gaps()
@@ -737,15 +945,24 @@ def test_segments_land_on_the_profilers_host_plane(tmp_path):
         find_xplane(str(tmp_path)))
     assert modules == []                  # no chip here
     assert {o: v[1:] for o, v in dispatched.items()} == {7: (1, 64)}
-    assert {o: v[1:] for o, v in consumed.items()} == {7: (1_234_000, 4)}
+    assert {o: v[1:] for o, v in consumed.items()} == {
+        7: (1_234_000, 4, None)}
     assert dispatched[7][0] <= consumed[7][0]
     data = ProfileData.from_file(find_xplane(str(tmp_path)))
     found = {ev.name: ev.duration_ns
              for plane in data.planes if plane.name.startswith("/host:")
              for line in plane.lines for ev in line.events
-             if ev.name.startswith(tprof.ANNOTATION_PREFIX)}
-    assert {"host/admit", "host/dispatch"} <= set(found)
+             if ev.name.startswith((tprof.ANNOTATION_PREFIX,
+                                    tprof.PAUSE_PREFIX))}
+    assert {"host/admit", "host/dispatch", "host/idle",
+            "pause/gc"} <= set(found)
     assert found["host/admit"] >= 2_000_000
+    assert found["host/idle"] >= 2_000_000
+    # and tools/trace_gaps.py reads the three kinds apart
+    _, segments, pauses = gaps.read_planes(find_xplane(str(tmp_path)))
+    assert {"admit", "dispatch", "idle"} <= set(segments)
+    assert list(pauses) == ["gc"]
+    assert p.totals()["gc"]["collections"][2] >= 1
 
 
 # ---- the per-layer readers -------------------------------------------
@@ -760,12 +977,28 @@ def _reader(name):
 
 
 def _sources():
-    def snap(t, hists, starved):
+    def snap(t, hists, starved, admit, prefills, gc_s, idle, stall, late):
+        rounds, judged, by_gc, by_host = late
         return {"t_wall": t,
                 "histograms": {k: {"sum": s, "count": c}
                                for k, (s, c) in hists.items()},
-                "prof": {"rounds": 0, "wall_s": 0.0, "segments": {},
-                         "starved": {"total_s": starved, "segments": {}}}}
+                "dispatch_counts": dict(zip(
+                    ("prefill", "prefill_batch", "sp_prefill", "round"),
+                    prefills)),
+                "prof": {"rounds": 0, "wall_s": 0.0,
+                         "segments": dict(zip(
+                             ("admit", "admit_pack", "admit_launch",
+                              "admit_first", "fetch"), admit)),
+                         "starved": {"total_s": starved, "segments": {}},
+                         "gc": {"collections": [9, 9, 9], "pause_s": gc_s,
+                                "on_loop_s": 0.0, "by_segment_s": {}},
+                         "idle": {"total_s": idle, "waits": 7},
+                         "stalls": {"count": 1, "total_s": stall,
+                                    "by_segment_s": {}},
+                         "late": {"rounds": rounds, "judged": judged,
+                                  "excess_s": {
+                                      "gc": by_gc, "behind_prefill": 9.0,
+                                      "host": by_host, "other": 9.0}}}}
 
     before = snap(100.0, {
         FRONT: (1.0, 10), FIRST: (5.0, 10), PF: (1000.0, 4),
@@ -775,7 +1008,8 @@ def _sources():
         HCRES: (2e-6, 20), CONT: (500.0, 4), ROWS_READ: (1e6, 20),
         ROWS_LIVE: (4e5, 20), GAP: (0.5, 20), GAP_CLEAN: (0.2, 15),
         AHEAD: (10000.0, 5), TPOT: (0.3, 10), DRY: (1.0, 24)},
-        1.0)
+        1.0, (1.0, 0.5, 2.0, 0.5, 50.0), (10, 5, 0, 999),
+        [0.25, 0.5, 1.0], 2.0, 0.125, (3, 100, 0.5, 0.25))
     after = snap(150.0, {
         FRONT: (1.5, 60), FIRST: (30.0, 60), PF: (17000.0, 54),
         PAD: (26000.0, 54), MATCH: (4000.0, 54), LIVE: (3600.0, 120),
@@ -784,7 +1018,9 @@ def _sources():
         LOADMAX: (700.0, 120), HCRES: (3.2e-5, 120), CONT: (6900.0, 54),
         ROWS_READ: (9e6, 120), ROWS_LIVE: (2.4e6, 120),
         GAP: (3.0, 120), GAP_CLEAN: (1.0, 95), AHEAD: (110000.0, 25),
-        TPOT: (1.8, 60), DRY: (4.0, 174)}, 3.5)
+        TPOT: (1.8, 60), DRY: (4.0, 174)}, 3.5,
+        (2.0, 1.0, 5.0, 1.0, 90.0), (40, 24, 1, 9999),
+        [0.5, 0.75, 1.375], 14.5, 0.375, (11, 300, 0.625, 0.5))
     return {"before": before, "after": after,
             "engine_up": {"flush_every": 4},
             "config": {"engine": {"max_decode_slots": 8},
@@ -820,7 +1056,25 @@ READERS = {
     "sched.prefill_ktok_ahead_mean": (100000 / 20 / 1e3, [AHEAD]),
     "sched.tpot_engine_ms_mean": (1.5 / 50 * 1e3, [TPOT]),
     "sched.dispatch_dry_share": (3.0 / 150 * 100, [DRY]),
+    # PR 56, all of prof.totals(): 12.5 of the 50 s empty; 0.25 + 0.25 +
+    # 0.375 s inside collections, 375 ms of them full ones; 250 ms of
+    # stalled passes; 8 of 200 judged rounds late, 125 + 250 ms of their
+    # excess the host's; the four admit segments 1 + 0.5 + 3 + 0.5 = 5 s
+    # over 30 + 19 + 1 prefill dispatches
+    "sched.empty_share": (12.5 / 50 * 100, ["idle"]),
+    "sched.gc_pause_share": (0.875 / 50 * 100, ["gc"]),
+    "sched.gc_full_pause_ms": (375.0, ["gc"]),
+    "sched.stall_ms": (250.0, ["stalls"]),
+    "sched.late_round_share": (8 / 200 * 100, ["late"]),
+    "sched.late_host_ms": (375.0, ["late"]),
+    "sched.admit_ms_per_prefill": (5.0 / 50 * 1e3, ["segments"]),
+    "sched.admit_launch_share": (3.0 / 5.0 * 100, ["segments"]),
 }
+# where the key is there and nothing happened in the window: 0.0, not None
+QUIET = ("sched.empty_share", "sched.gc_pause_share",
+         "sched.gc_full_pause_ms", "sched.stall_ms",
+         "sched.late_round_share", "sched.late_host_ms",
+         "sched.admit_ms_per_prefill", "sched.admit_launch_share")
 
 
 @pytest.mark.parametrize("name", sorted(READERS))
@@ -840,6 +1094,13 @@ def test_reader_gives_none_on_a_program_without_the_counter(name):
             snap["histograms"].pop(key, None)
             snap["prof"].pop(key, None)
     assert _reader(name)(src) is None
+
+
+@pytest.mark.parametrize("name", QUIET)
+def test_reader_gives_zero_where_nothing_happened(name):
+    src = _sources()
+    src["after"] = dict(src["before"], t_wall=150.0)
+    assert _reader(name)(src) == 0.0
 
 
 @pytest.fixture(scope="module")
@@ -1121,3 +1382,42 @@ def test_trace_gaps_labels_by_the_covering_segment():
     assert c1["idle_s"] == 0.0 and c1["edge_s"] == pytest.approx(0.004)
     assert c1["attributed_share"] == 1.0
     assert mod.label_gaps({}, segments) == {"window_s": 0.0, "chips": {}}
+
+
+def test_trace_gaps_labels_the_empty_engine_and_the_collector():
+    """Three gaps on one chip: 10 ms while the engine waits on its doorbell
+    (host/idle, a dropped pass's slot_scan beside it), 8 ms of `releases`
+    with 6 ms of a full collection inside, and 4 ms of `admit_launch` that
+    a 1 ms collection of the young generation only touches."""
+    mod = _trace_gaps()
+    ms = 1_000_000
+    ops = {"/device:TPU:0": [(0, 10 * ms), (20 * ms, 30 * ms),
+                             (38 * ms, 40 * ms), (44 * ms, 50 * ms)]}
+    segments = {"idle": [(10 * ms, 14 * ms), (15 * ms, 20 * ms)],
+                "slot_scan": [(14 * ms, 15 * ms)],
+                "releases": [(30 * ms, 38 * ms)],
+                "admit_launch": [(40 * ms, 44 * ms)],
+                "fetch": [(0, 10 * ms), (20 * ms, 30 * ms)]}
+    pauses = {"gc": [(31 * ms, 37 * ms), (41 * ms, 42 * ms)]}
+    out = mod.label_gaps(ops, segments, pauses)
+    chip = out["chips"]["/device:TPU:0"]
+    assert chip["idle_by_segment_s"] == {
+        "idle": pytest.approx(0.010), "releases+gc": pytest.approx(0.008),
+        "admit_launch": pytest.approx(0.004)}
+    assert [seg for seg, _ in chip["longest_gaps"]] == [
+        "idle", "releases+gc", "admit_launch"]
+    assert out["pauses"] == {"gc": [2, pytest.approx(0.007)]}
+    # without the pauses the labels are the segments', as before PR 56
+    plain = mod.label_gaps(ops, segments)["chips"]["/device:TPU:0"]
+    assert set(plain["idle_by_segment_s"]) == {
+        "idle", "releases", "admit_launch"}
+    # a consume mark's `late` reaches the round's row
+    R = mod.ROUND_MODULE
+    rows = mod.rounds_report(
+        [(R, 0, 20 * ms), (R, 20 * ms, 40 * ms), (R, 40 * ms, 110 * ms)],
+        {5: (5 * ms, 0, 0), 6: (25 * ms, 0, 0)},
+        {5: (41 * ms, 20 * ms, 4, None), 6: (111 * ms, 70 * ms, 4, "gc")},
+    )["rounds"]
+    assert [(r["ordinal"], r["late"]) for r in rows] == [
+        (5, None), (6, "gc")]
+

@@ -285,6 +285,19 @@ def test_tenant_families_on_all_three_surfaces():
         TENANT.reset()
 
 
+def test_readme_row_names_every_host_segment():
+    """`dynamo_host_round_seconds{segment=...}`: the README's row lists
+    the enum (telemetry/prof.py SEGMENTS) member for member."""
+    import re
+
+    from dynamo_tpu.telemetry.prof import SEGMENTS
+
+    row = next(line for line in _readme_text().splitlines()
+               if line.startswith("| `dynamo_host_round_seconds`"))
+    listed = re.findall(r"`([a-z_]+)`", row.split("`segment=`", 1)[1])
+    assert listed == list(SEGMENTS)
+
+
 def test_prof_families_on_all_three_surfaces():
     """The attribution plane's families render — with HELP/TYPE and the
     per-segment label — on every scrape surface."""

@@ -13,7 +13,12 @@ the ``/host:CPU`` plane carries the segments on the same clock as the
 of the union of its ``XLA Ops`` intervals inside the traced window (the
 same arithmetic as ``benchmarks/trace_reduce.py``, whose pure interval
 functions are imported), and each gap goes to the segment whose
-annotations overlap it most, or to ``unattributed`` when none does.
+annotations overlap it most, or to ``unattributed`` when none does. The
+empty engine's doorbell wait is ``host/idle`` on the same plane (PR 56),
+so a gap for want of work reads ``idle``; and a pause of the whole
+interpreter is ``pause/<kind>`` (``pause/gc``: one collection of the cyclic
+collector, its generation as a stat): a gap more than half under one reads
+``<segment>+<kind>``.
 
 The window is the one ``trace_reduce`` uses, first op on ANY chip to the
 last. The chips' traces do not start and stop together, so on several
@@ -26,7 +31,8 @@ no op ran.
 ``engine/round`` marks a fused round (telemetry/prof.py ``mark_round``): at
 its dispatch (ordinal, prefill programs and padded tokens dispatched since
 the round before) and at its consume (ordinal, the wall the host booked to
-it: ``dynamo_engine_step_gap_seconds`` x steps). The first chip's
+it: ``dynamo_engine_step_gap_seconds`` x steps; ``late=<cause>`` when the
+round took twice a clean round's wall, RoundProf.judge_round). The first chip's
 ``jit_engine_round_seal`` modules run in dispatch order, so one offset
 pairs them with the ordinals: the one at which every round starts after
 its dispatch mark and ends before its consume mark. For each paired round:
@@ -50,19 +56,21 @@ from benchmarks.trace_reduce import (  # noqa: E402
     DEVICE_PLANE, MODULES_LINE, OPS_LINE, find_xplane, module_base, overlap,
     total, union)
 from dynamo_tpu.telemetry.prof import (  # noqa: E402
-    ANNOTATION_PREFIX, ROUND_ANNOTATION)
+    ANNOTATION_PREFIX, PAUSE_PREFIX, ROUND_ANNOTATION)
 
 UNATTRIBUTED = "unattributed"
 ROUND_MODULE = "jit_engine_round_seal"
 
 
 def read_planes(path: str):
-    """({chip: [(start, end)] of its ops}, {segment: [(start, end)]})."""
+    """({chip: [(start, end)] of its ops}, {segment: [(start, end)]},
+    {pause kind: [(start, end)]})."""
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(path)
     ops: dict[str, list] = {}
     segments: dict[str, list] = {}
+    pauses: dict[str, list] = {}
     for plane in data.planes:
         device = DEVICE_PLANE.match(plane.name)
         if not device and not plane.name.startswith("/host:"):
@@ -77,17 +85,24 @@ def read_planes(path: str):
                 elif ev.name.startswith(ANNOTATION_PREFIX):
                     segments.setdefault(
                         ev.name[len(ANNOTATION_PREFIX):], []).append(span)
-    return ops, segments
+                elif ev.name.startswith(PAUSE_PREFIX):
+                    pauses.setdefault(
+                        ev.name[len(PAUSE_PREFIX):], []).append(span)
+    return ops, segments, pauses
 
 
-def label_gaps(ops: dict[str, list], segments: dict[str, list]) -> dict:
-    """Per chip: idle seconds by the segment covering most of each gap."""
+def label_gaps(ops: dict[str, list], segments: dict[str, list],
+               pauses: dict[str, list] | None = None) -> dict:
+    """Per chip: idle seconds by the segment covering most of each gap
+    (``idle``: the engine was empty), ``+<kind>`` behind it where a pause
+    of that kind covers more than half the gap."""
     every = [s for spans in ops.values() for s in spans]
     if not every:
         return {"window_s": 0.0, "chips": {}}
     w0 = min(s for s, _ in every)
     w1 = max(e for _, e in every)
     covers = {seg: union(spans) for seg, spans in segments.items()}
+    paused = {kind: union(spans) for kind, spans in (pauses or {}).items()}
     chips = {}
     for chip, spans in sorted(ops.items()):
         busy = union(spans)
@@ -100,6 +115,9 @@ def label_gaps(ops: dict[str, list], segments: dict[str, list]) -> dict:
                 ns = overlap([(g0, g1)], cover)
                 if ns > best_ns:
                     best, best_ns = seg, ns
+            for kind, cover in paused.items():
+                if 2 * overlap([(g0, g1)], cover) > g1 - g0:
+                    best += "+" + kind
             gaps.append((g1 - g0, best))
         by_seg: dict[str, int] = {}
         for ns, seg in gaps:
@@ -119,13 +137,15 @@ def label_gaps(ops: dict[str, list], segments: dict[str, list]) -> dict:
         }
     return {"window_s": (w1 - w0) / 1e9, "chips": chips,
             "annotations": {seg: len(spans)
-                            for seg, spans in sorted(segments.items())}}
+                            for seg, spans in sorted(segments.items())},
+            "pauses": {kind: [len(spans), total(union(spans)) / 1e9]
+                       for kind, spans in sorted((pauses or {}).items())}}
 
 
 def read_rounds(path: str):
     """(the first chip's modules [(base name, start, end)], the dispatch
     marks {ordinal: (t, programs ahead, padded tokens ahead)}, the consume
-    marks {ordinal: (t, wall_ns, steps)})."""
+    marks {ordinal: (t, wall_ns, steps, late cause or None)})."""
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(path)
@@ -155,7 +175,7 @@ def read_rounds(path: str):
                     elif "consumed" in st:
                         consumed[int(st["consumed"])] = (
                             int(ev.start_ns), int(st["wall_us"]) * 1000,
-                            int(st["steps"]))
+                            int(st["steps"]), st.get("late"))
     return sorted(modules, key=lambda m: m[1]), dispatched, consumed
 
 
@@ -202,10 +222,11 @@ def rounds_report(modules: list, dispatched: dict, consumed: dict) -> dict:
             if prev_end <= s < r0:
                 between[name] = between.get(name, 0) + (e - s)
         _, programs, padded = dispatched[o]
-        _, wall_ns, steps = consumed[o]
+        _, wall_ns, steps, *late = consumed[o]
         rows.append({
             "ordinal": o, "programs_ahead": programs,
             "padded_tokens_ahead": padded, "steps": steps,
+            "late": late[0] if late else None,
             "device_s": (r1 - prev_end) / 1e9, "round_s": (r1 - r0) / 1e9,
             "between_s": {n: ns / 1e9 for n, ns in sorted(between.items())},
             "idle_s": (r0 - prev_end - sum(between.values())) / 1e9,
@@ -260,6 +281,12 @@ def print_rounds(result: dict) -> None:
               f"{g['abs_diff_s'] * 1e3:.3f} ms; a step: device "
               f"{g['device_step_s'] * 1e3:.3f}, host "
               f"{g['host_step_s'] * 1e3:.3f} ms")
+    for r in result["rounds"]:
+        if r["late"]:
+            print(f"late: round {r['ordinal']} ({r['late']}): host "
+                  f"{r['host_s'] * 1e3:.3f} ms, device "
+                  f"{r['device_s'] * 1e3:.3f} (round "
+                  f"{r['round_s'] * 1e3:.3f}, idle {r['idle_s'] * 1e3:.3f})")
 
 
 def main() -> int:
@@ -280,14 +307,16 @@ def main() -> int:
         print_rounds(result)
         return 0
     print(f"window {result['window_s']:.6f} s; host annotations: "
-          f"{sum(result.get('annotations', {}).values())}")
+          f"{sum(result.get('annotations', {}).values())}; pauses: "
+          + (", ".join(f"{n} {kind} {s * 1e3:.3f} ms" for kind, (n, s)
+                       in result.get("pauses", {}).items()) or "none"))
     for chip, c in result["chips"].items():
         print(f"{chip}: busy {c['busy_s']:.6f} s, untraced edges "
               f"{c['edge_s']:.6f} s, idle {c['idle_s']:.6f} s, "
               f"{c['attributed_share'] * 100:.1f} % of it under a named "
               "segment")
         for seg, s in c["idle_by_segment_s"].items():
-            print(f"    {seg:<14} {s:.6f} s")
+            print(f"    {seg:<18} {s:.6f} s")
         print("    longest gaps: " + ", ".join(
             f"{seg} {s * 1e3:.3f} ms" for seg, s in c["longest_gaps"]))
     return 0
