@@ -425,7 +425,8 @@ class TpuEngine:
         p_sh = llama.param_shardings(c, self.mesh)
         if params is None:
             params = llama.init_params(c, rng_seed)
-        self.params = jax.tree.map(lambda x, s: jax.device_put(x, s), params, p_sh)
+        self.params = llama.serving_params(c, jax.tree.map(
+            lambda x, s: jax.device_put(x, s), params, p_sh))
         # resident LoRA adapter bank (tenancy plane): rides INSIDE the
         # params pytree so every jitted program carries it with zero
         # signature churn — the model fns look it up via

@@ -54,6 +54,9 @@ one line in ``block_of``; no line of engine/engine.py.
   parameters and state (``c`` a ModelConfig, first everywhere)
     init_params(c, rng=0)                          -> params
     param_shardings(c, mesh)                       -> shardings of params
+    serving_params(c, params)                      -> params + the leaves
+        a block makes from them ONCE where the engine takes them; what
+        the programs below are handed
     init_cache(c, num_pages, page_size, dtype=None, kv_quant="none")
     cache_shardings(c, mesh, kv_quant="none")      the pool: ROW leaves only
     init_ctx(c, batch, ctx_len, dtype=None, kv_quant="none", group=128)
@@ -407,6 +410,18 @@ def param_shardings(config: ModelConfig, mesh: Mesh) -> Params:
     if not config.tie_word_embeddings:
         out["lm_head"] = w("lm_head", None, "tp")
     return out
+
+
+@_hands_over
+def serving_params(config: ModelConfig, params: Params) -> Params:
+    """``params`` (of ``init_params``' tree, or a checkpoint's, already on
+    their devices) as the PROGRAMS read them: the same leaves, plus what
+    a block lays out anew once at engine start so that no program does
+    it every call (the latent attention's W_kvb by head,
+    ``mla_moe.serving_params``). The dense decoder reads its published
+    leaves where they lie. Every program of this module takes the result;
+    ``param_shardings`` describes ``init_params``' tree."""
+    return params
 
 
 # ---------------------------------------------------------------------------

@@ -52,7 +52,7 @@ the benchmark's launcher call (``llama.init_params``, ``init_ctx``,
 
 The hybrid stack (models/ssm_moe.py) runs this block's ATTENTION as its
 ``latent_attention`` layer kind, beside recurrent layers: it imports
-``_attn_in`` (whose ``q_lora_rank`` None arm is its model's), ``_wkvb``,
+``_attn_in`` (whose ``q_lora_rank`` None arm is its model's), ``by_head``,
 ``_expand_kv``, ``_absorb_q``, ``_unabsorb_o``, ``_expand_prior`` and
 ``ROW``, with a config that sets ``mla`` beside ``hybrid`` and no
 ``routed`` (``dims`` then gives the attention's sizes only).
@@ -368,6 +368,15 @@ def _attn_in(c: ModelConfig, lp, h, positions):
         q = x @ lp["wq"]
     else:
         q = _rms(x @ lp["wqa"], lp["q_norm"], c.rms_norm_eps) @ lp["wqb"]
+    # The product ends HERE, as an [N, nh * (nope + rope)] array, as the
+    # dense decoder's do (llama._layer_qkv; PERF.md section 6, PR 55, 57).
+    # Left to fold the reshape to heads into it, XLA:TPU wants the weight
+    # head-major with the contraction minor: it transposed the whole wqb
+    # stack once a round, wrote every layer's slice of that out again
+    # every decode step, and laid each layer's shard out anew in front of
+    # every prefill call (tools/tpu_compile_check.py ``weight_copies``).
+    # Behind the barrier the product reads the shard where it lies.
+    q = jax.lax.optimization_barrier(q)
     q = q.reshape(N, d["nh"], d["nope"] + d["rope"])
     q_nope, q_rope = q[..., :d["nope"]], q[..., d["nope"]:]
     kv = x @ lp["wkva"]
@@ -378,12 +387,31 @@ def _attn_in(c: ModelConfig, lp, h, positions):
     return q_nope, q_rope, jnp.concatenate([c_kv, k_rope, pad], axis=-1)
 
 
-def _wkvb(c: ModelConfig, lp):
-    """W_kvb split by head: keys' part [kv_rank, nh, nope] and values'
-    part [kv_rank, nh, v]."""
+@functools.partial(jax.jit, static_argnames=("c",))
+def by_head(c: ModelConfig, wkvb):
+    """``wkvb`` [..., kv_rank, nh * (nope + v)], as published, -> the two
+    weights the programs read (``serving_params``): the keys' part
+    ``wkb`` [..., nh, nope, kv_rank] and the values' part ``wvb`` [...,
+    nh, v, kv_rank], head-major with the latent minor."""
     d = dims(c)
-    w = lp["wkvb"].reshape(d["kv_rank"], d["nh"], d["nope"] + d["v"])
-    return w[..., :d["nope"]], w[..., d["nope"]:]
+    w = wkvb.reshape(*wkvb.shape[:-1], d["nh"], d["nope"] + d["v"])
+    w = jnp.moveaxis(w, -3, -1)
+    return w[..., :d["nope"], :], w[..., d["nope"]:, :]
+
+
+def serving_params(config: ModelConfig, params: Params) -> Params:
+    """The published parameters plus ``wkb`` and ``wvb`` (``by_head``),
+    made ONCE, where the engine takes its parameters. Every product over
+    W_kvb is batched over the head (``_absorb_q``, ``_unabsorb_o``,
+    ``_expand_kv``), so XLA:TPU wants the head major and the latent minor,
+    and what it cannot read in place is a PART of a leaf that has a head
+    dimension: fed the published ``wkvb`` (or one head-major leaf split in
+    the program) it transposed the whole stack every round and wrote every
+    layer's slice of it out every decode step (PERF.md section 6, PR 57).
+    ``wkvb`` stays: the benchmark's references read it."""
+    layers = params["layers"]
+    wkb, wvb = by_head(config, layers["wkvb"])
+    return dict(params, layers=dict(layers, wkb=wkb, wvb=wvb))
 
 
 def _absorb_q(c: ModelConfig, lp, q_nope, q_rope):
@@ -392,8 +420,7 @@ def _absorb_q(c: ModelConfig, lp, q_nope, q_rope):
     rotary rule's factor on the softmax scale."""
     d = dims(c)
     times = _rotary(c)[2]
-    wk, _ = _wkvb(c, lp)
-    q_lat = jnp.einsum("nhd,chd->nhc", q_nope, wk)
+    q_lat = jnp.einsum("nhd,hdc->nhc", q_nope, lp["wkb"])
     pad = jnp.zeros(q_rope.shape[:2] + (d["stored"] - d["row"],),
                     q_rope.dtype)
     q = jnp.concatenate([q_lat, q_rope, pad], axis=-1)
@@ -412,8 +439,7 @@ def _scaled(c: ModelConfig, q, k: float):
 
 def _unabsorb_o(c: ModelConfig, lp, o_lat):
     """[N, nh, kv_rank] weighted sums of latents -> [N, nh * v]."""
-    _, wv = _wkvb(c, lp)
-    o = jnp.einsum("nhc,chd->nhd", o_lat, wv)
+    o = jnp.einsum("nhc,hdc->nhd", o_lat, lp["wvb"])
     return o.reshape(o.shape[0], -1)
 
 
@@ -550,18 +576,18 @@ def _expand_kv(c: ModelConfig, lp, row):
     rope] = [c_kv W_kvb^K | k_rope, the same for every head] and V [N,
     nh, v] = c_kv W_kvb^V, in the rows' dtype."""
     d = dims(c)
-    wk, wv = _wkvb(c, lp)
     c_kv = row[:, :d["kv_rank"]]
     k_rope = row[:, d["kv_rank"]:d["row"]]
     k = jnp.concatenate([
-        jnp.einsum("nc,chd->nhd", c_kv, wk),
+        jnp.einsum("nc,hdc->nhd", c_kv, lp["wkb"]),
         jnp.broadcast_to(k_rope[:, None],
                          (row.shape[0], d["nh"], d["rope"]))], -1)
-    return k, jnp.einsum("nc,chd->nhd", c_kv, wv)
+    return k, jnp.einsum("nc,hdc->nhd", c_kv, lp["wvb"])
 
 
 @functools.partial(jax.jit, static_argnames=("c",))
-def _expand_prior(c: ModelConfig, work, region, wkvb, layer, slots, below):
+def _expand_prior(c: ModelConfig, work, region, wkb, wvb, layer, slots,
+                  below):
     """The prior rows of K continuing chunks, expanded per head ONCE a
     dispatch: rows [0, below[i]) of lane ``slots[i]`` of the region's
     layer ``layer`` -> ``work`` = (K [1, nh, K, span, nope + rope], V [1,
@@ -572,7 +598,12 @@ def _expand_prior(c: ModelConfig, work, region, wkvb, layer, slots, below):
     16384-row span a quarter of it. Rows at or past ``below`` keep what
     ``work`` held, finite values the attention masks. Jitted with the
     layer a VALUE, so that a program's layers share one traced
-    expansion."""
+    expansion. ``wkb`` / ``wvb`` are one layer's (the hybrid stack's
+    leaves), or the STACK of every layer's, which the loop's body slices
+    at ``layer`` itself by an index the compiler cannot see is the same
+    at every trip: a layer's slice handed to the loop is written out in
+    front of it (``weight_copies``: ten ``bf16[32,128,512]`` a call), and
+    so is one the compiler can hoist (llama._live_rows; PR 44)."""
     span = work[0].shape[3]
     cb = min(PREFILL_BLOCK, span)
     i32 = jnp.int32
@@ -595,10 +626,15 @@ def _expand_prior(c: ModelConfig, work, region, wkvb, layer, slots, below):
             region, (layer, 0, slots[lane].astype(i32), k0, 0),
             (1, 1, 1, cb, region.shape[4]))[0, 0, 0]
         at = (0, 0, lane, k0, 0)
+        lp = {"wkb": wkb, "wvb": wvb}
+        if wkb.ndim == 4:
+            lb, _ = jax.lax.optimization_barrier((layer, w))
+            lp = jax.tree.map(lambda x: jax.lax.dynamic_index_in_dim(
+                x, lb, keepdims=False), lp)
         return tuple(
             jax.lax.dynamic_update_slice(
                 buf, x.transpose(1, 0, 2)[None, :, None], at)
-            for buf, x in zip(work, _expand_kv(c, {"wkvb": wkvb}, rows)))
+            for buf, x in zip(work, _expand_kv(c, lp, rows)))
 
     return jax.lax.fori_loop(0, ends[-1], expand, tuple(work))
 
@@ -653,8 +689,9 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
                 v = jnp.pad(v, ((0, 0), (0, 0),
                                 (0, k.shape[-1] - d["v"])))
             else:
-                work = _expand_prior(c, work, ctx_kv[ROW], lp["wkvb"],
-                                     jnp.int32(l), slots, below)
+                work = _expand_prior(
+                    c, work, ctx_kv[ROW], params["layers"]["wkb"],
+                    params["layers"]["wvb"], jnp.int32(l), slots, below)
                 prior = PriorContext(*work, jnp.int32(0),
                                      jnp.arange(K, dtype=jnp.int32))
             q = jnp.concatenate([q_nope, q_rope], -1)

@@ -559,6 +559,20 @@ def param_shardings(config: ModelConfig, mesh: Mesh) -> Params:
         lambda x: NamedSharding(mesh, P(*([None] * x.ndim))), shapes)
 
 
+def serving_params(config: ModelConfig, params: Params) -> Params:
+    """The published parameters, each ``latent_attention`` layer's beside
+    its ``wkvb`` by head (``mla_moe.by_head``: ``wkb``, ``wvb``), made
+    once where the engine takes them; every other kind reads its
+    published leaves."""
+    def served(lp):
+        if "wkvb" not in lp:
+            return lp
+        wkb, wvb = mla_moe.by_head(config, lp["wkvb"])
+        return dict(lp, wkb=wkb, wvb=wvb)
+
+    return dict(params, layers=[served(lp) for lp in params["layers"]])
+
+
 # ---------------------------------------------------------------------------
 # Cache spec: rows for the attention layers, a state for the others
 
@@ -1752,8 +1766,8 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
                 prior = None
                 if span:
                     work = mla_moe._expand_prior(
-                        c, work, ctx_kv[KV], lp["wkvb"], jnp.int32(len(lat)),
-                        slots, below)
+                        c, work, ctx_kv[KV], lp["wkb"], lp["wvb"],
+                        jnp.int32(len(lat)), slots, below)
                     prior = PriorContext(*work, jnp.int32(0),
                                          jnp.arange(K, dtype=jnp.int32))
                 lat.append(row.reshape(K, T, 1, -1))
@@ -1949,8 +1963,8 @@ def _live_prefill(config, params, ctx_kv, tokens, slots, q_starts, seq_lens,
                 prior = None
                 if span:
                     work = mla_moe._expand_prior(
-                        c, work, ctx_kv[KV], lp["wkvb"], jnp.int32(len(lat)),
-                        slots, below)
+                        c, work, ctx_kv[KV], lp["wkb"], lp["wvb"],
+                        jnp.int32(len(lat)), slots, below)
                     prior = PriorContext(*work, jnp.int32(0),
                                          jnp.arange(K, dtype=jnp.int32))
                 seq = (prefill_attention(qq, k, v, q_starts, seq_lens, prior,

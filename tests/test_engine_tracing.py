@@ -1235,7 +1235,9 @@ def test_hc_scopes_are_in_the_lowered_step():
     from dynamo_tpu.ops.attention import REFERENCE
 
     cfg = ModelConfig.tiny_mla_moe_mhc()
-    params = jax.eval_shape(lambda: llama.init_params(cfg, 0))
+    shapes = lambda c: jax.eval_shape(   # noqa: E731
+        lambda: llama.serving_params(c, llama.init_params(c, 0)))
+    params = shapes(cfg)
     ctx = jax.eval_shape(lambda: llama.init_ctx(cfg, 4, 64, jnp.float32))
     ring = jax.eval_shape(lambda: llama.init_ring(cfg, 4, 4, jnp.float32))
     i32 = jax.ShapeDtypeStruct((4,), jnp.int32)
@@ -1249,8 +1251,7 @@ def test_hc_scopes_are_in_the_lowered_step():
                   "moe_experts", "moe_shared"):
         assert f"/{scope}/" in text or f"{scope}/" in text, scope
     plain = step.lower(
-        ModelConfig.tiny_mla_moe(), jax.eval_shape(
-            lambda: llama.init_params(ModelConfig.tiny_mla_moe(), 0)),
+        ModelConfig.tiny_mla_moe(), shapes(ModelConfig.tiny_mla_moe()),
         ctx, ring, i32, i32, i32,
         jax.ShapeDtypeStruct((), jnp.int32),
         attn=REFERENCE).as_text(debug_info=True)

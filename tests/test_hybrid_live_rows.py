@@ -37,7 +37,7 @@ CONFIGS = {"mamba_attention": "tiny_ssm_moe",
 @functools.lru_cache(maxsize=None)
 def _model(name):
     c = getattr(ModelConfig, CONFIGS[name])(dtype="float32")
-    return c, llama.init_params(c, 0)
+    return c, llama.serving_params(c, llama.init_params(c, 0))
 
 
 @pytest.fixture(autouse=True)
@@ -348,7 +348,8 @@ def test_same_kind_layers_share_one_lowered_loop_body(name, monkeypatch):
     monkeypatch.setattr(ssm_moe, "LIN_CHUNK", 256)   # the real heights
     monkeypatch.setattr(ssm_moe, "SCAN_ROW_BLOCK", 256)   # and chunks
     c, _ = _model(name)
-    params = jax.eval_shape(lambda: llama.init_params(c, 0))
+    params = jax.eval_shape(
+        lambda: llama.serving_params(c, llama.init_params(c, 0)))
     ctx = jax.eval_shape(lambda: llama.init_ctx(c, 2, 8192, jnp.float32))
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
     texts = [llama.prefill.lower(c, params, ctx, i32(T_), i32(), i32(),

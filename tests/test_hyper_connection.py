@@ -124,7 +124,8 @@ def test_fixed_coefficients_reproduce_the_one_stream_block():
     rope = dict(_TINY_MHC)["rope_scaling"]
     one = ModelConfig.tiny_mla_moe(rope_scaling=rope)
     four = ModelConfig.tiny_mla_moe_mhc()
-    p4 = fixed_coefficients(llama.init_params(four, 5))
+    p4 = fixed_coefficients(
+        llama.serving_params(four, llama.init_params(four, 5)))
     p1 = dict(p4, layers={k: v for k, v in p4["layers"].items()
                           if not k.startswith("hc_")})
     toks = jnp.asarray(

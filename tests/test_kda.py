@@ -335,7 +335,8 @@ def test_a_lane_that_is_not_live_keeps_its_state_and_windows(setup):
     i32 = lambda *v: jnp.asarray(v, jnp.int32)  # noqa: E731
     for attn in (REFERENCE, DecodeAttention(PALLAS_INTERPRET)):
         _, new, logits, _ = ssm_moe.decode_step_impl(
-            cfg, params, ctx, llama.init_ring(cfg, B, 2, jnp.float32),
+            cfg, llama.serving_params(cfg, params), ctx,
+            llama.init_ring(cfg, B, 2, jnp.float32),
             state, i32(5, 6, 7), i32(4, 4, 5), i32(3, 3, 4), jnp.int32(0),
             live, attn=attn)
         assert bool(jnp.isfinite(logits).all())
@@ -357,7 +358,8 @@ def test_the_kernel_step_and_the_xla_step_give_one_decode(setup):
     state = {n: ctx[n] for n in ssm_moe.stepped_kinds(cfg, ctx)}
     i32 = lambda *v: jnp.asarray(v, jnp.int32)  # noqa: E731
     out = [ssm_moe.decode_step_impl(
-        cfg, params, ctx, llama.init_ring(cfg, B, 2, jnp.float32), state,
+        cfg, llama.serving_params(cfg, params), ctx,
+        llama.init_ring(cfg, B, 2, jnp.float32), state,
         i32(5, 6), i32(9, 30), i32(8, 29), jnp.int32(0),
         jnp.asarray([True, True]), attn=attn)
         for attn in (REFERENCE, DecodeAttention(PALLAS_INTERPRET))]

@@ -48,11 +48,17 @@ def load_reference(name="mla_moe"):
     return mod
 
 
+def served(cfg, seed=3):
+    """Parameters as the engine hands them to the block's programs (the
+    tests below call those directly): the published leaves, W_kvb by head
+    beside them."""
+    return llama.serving_params(cfg, llama.init_params(cfg, seed))
+
+
 @pytest.fixture(scope="module")
 def setup():
     cfg = ModelConfig.tiny_mla_moe(dtype="float32")
-    params = llama.init_params(cfg, 3)
-    return cfg, params, load_reference()
+    return cfg, served(cfg), load_reference()
 
 
 @pytest.fixture(scope="module", params=["one-stream", "mhc"])
@@ -66,8 +72,7 @@ def block(request, setup):
         return cfg, params, ref, dict(_TINY_MLA_MOE), 21
     cfg = ModelConfig.tiny_mla_moe_mhc()
     hf = dict(_TINY_MLA_MOE, **_TINY_MHC)
-    return cfg, llama.init_params(cfg, 3), load_reference("mla_moe_mhc"), \
-        hf, 75
+    return cfg, served(cfg), load_reference("mla_moe_mhc"), hf, 75
 
 
 def padded(prompt, width):
@@ -594,12 +599,13 @@ def test_a_plane_that_cannot_carry_a_latent_row_refuses_at_start(
         setup, plane, kw):
     """(f) named refusals at engine start-up; nothing reinterprets the
     row."""
-    cfg, params, _ = setup
+    cfg, _, _ = setup
     ecfg = EngineConfig(**{**dict(
         num_pages=16, page_size=PS, max_pages_per_seq=4, max_decode_slots=4,
         prefill_buckets=(32,), cache_dtype="float32"), **kw})
     with pytest.raises(ValueError, match="latent|at least 3"):
-        TpuEngine(cfg, ecfg, params=params, mesh_config=MeshConfig(tp=1))
+        TpuEngine(cfg, ecfg, params=llama.init_params(cfg, 3),
+                  mesh_config=MeshConfig(tp=1))
 
 
 def test_model_functions_of_other_planes_refuse_a_latent_row(setup):
@@ -687,7 +693,10 @@ async def test_engine_serves_the_block_and_counts_its_routing(setup):
     ecfg = EngineConfig(num_pages=32, page_size=PS, max_pages_per_seq=8,
                         max_decode_slots=4, prefill_buckets=(32, 64),
                         cache_dtype="float32")
-    eng = TpuEngine(cfg, ecfg, params=params, mesh_config=MeshConfig(tp=1))
+    # the engine takes the PUBLISHED leaves and makes the others itself
+    eng = TpuEngine(cfg, ecfg, params=llama.init_params(cfg, 3),
+                    mesh_config=MeshConfig(tp=1))
+    assert set(eng.params["layers"]) == set(params["layers"])
     prompt = list(range(1, 41))
 
     async def collect():

@@ -95,7 +95,7 @@ def _arity(sig: inspect.Signature):
 def test_the_block_meets_the_written_protocol(family):
     c = FAMILIES[family]()
     block = llama.block_of(c)
-    assert len(llama.PROTOCOL) == 20
+    assert len(llama.PROTOCOL) == 21
     for name, sig in llama.PROTOCOL.items():
         # written once, in words, at the head of the front door
         assert f"    {name}(c" in llama.__doc__, name
@@ -188,12 +188,42 @@ def test_what_the_state_says_of_itself(family):
 
 
 @pytest.mark.parametrize("family", FAMILIES)
+def test_serving_params_add_leaves_and_change_none(family):
+    """What the engine hands its programs: every published leaf as it
+    was (the benchmark's references read ``wkvb`` and ``wqb`` from
+    ``engine.params``), and beside each ``wkvb`` its two parts by head,
+    head-major with the latent minor, value for value; nothing else."""
+    c = FAMILIES[family](dtype="float32")
+    params = llama.init_params(c, 2)
+    served = llama.serving_params(c, params)
+    was = dict(jax.tree_util.tree_flatten_with_path(params)[0])
+    now = dict(jax.tree_util.tree_flatten_with_path(served)[0])
+    assert all(now[path] is leaf for path, leaf in was.items())
+    added = sorted(set(now) - set(was), key=str)
+    sources = [path for path in was if path[-1].key == "wkvb"]
+    assert len(added) == 2 * len(sources)
+    assert bool(sources) == (family in ("latent", "latent_mhc", "kda_latent"))
+    m = c.mla_dict or {}
+    for path in sources:
+        w = np.asarray(was[path]).reshape(
+            *was[path].shape[:-1], c.num_heads,
+            m["qk_nope_head_dim"] + m["v_head_dim"])
+        w = np.moveaxis(w, -3, -1)   # [..., nh, nope + v, kv_rank]
+        key = jax.tree_util.DictKey
+        np.testing.assert_array_equal(
+            now[path[:-1] + (key("wkb"),)], w[..., :m["qk_nope_head_dim"], :])
+        np.testing.assert_array_equal(
+            now[path[:-1] + (key("wvb"),)], w[..., m["qk_nope_head_dim"]:, :])
+
+
+@pytest.mark.parametrize("family", FAMILIES)
 def test_the_rounds_step_through_the_front_door(family):
     """ONE signature for every block: (ring, stepped, logits, stats), the
     stepped leaves and the counter row shaped as they went in."""
     c = FAMILIES[family](dtype="float32")
     B, R = 6, 4
-    params = jax.eval_shape(lambda: llama.init_params(c, 0))
+    params = jax.eval_shape(
+        lambda: llama.serving_params(c, llama.init_params(c, 0)))
     ctx = jax.eval_shape(lambda: llama.init_ctx(c, B, 64, jnp.float32))
     ring = jax.eval_shape(lambda: llama.init_ring(c, B, R, jnp.float32))
     kinds = llama.stepped_kinds(c, ctx)

@@ -212,7 +212,7 @@ def _lowered(fn, *args, **kw):
 
 def _abstract(c, lanes=2, rows=4096):
     shapes = jax.eval_shape
-    return (shapes(lambda: llama.init_params(c, 0)),
+    return (shapes(lambda: llama.serving_params(c, llama.init_params(c, 0))),
             shapes(lambda: llama.init_ctx(c, lanes, rows, jnp.float32)))
 
 
@@ -355,10 +355,13 @@ def test_host_mirror_is_the_programs_trip_count(case):
 # path's halves. A PR that MEANS to change one records the new digest here.
 # Since PR 49 the hybrid block's wide buckets take another program
 # (``ssm_moe._live_prefill``); its straight-line one, which this 64-row
-# bucket runs, kept the parent's text.
+# bucket runs, kept the parent's text. PR 57 MEANT to move the two latent
+# ones (c728cb0a7a7fceae and 23f9246b56275a3d on its parent, d39232c): the
+# query product ends ahead of the reshape to heads and the products over
+# W_kvb read ``wkb`` / ``wvb`` (``llama.serving_params``).
 BLOCK_MODEL_DIGESTS = {
-    "tiny_mla_moe": "c728cb0a7a7fceae",
-    "tiny_mla_moe_mhc": "23f9246b56275a3d",
+    "tiny_mla_moe": "8788c3406236fb77",
+    "tiny_mla_moe_mhc": "f840bfcf1391f976",
     "tiny_ssm_moe": "6d845cc44ce2c3d6",
 }
 
@@ -366,7 +369,8 @@ BLOCK_MODEL_DIGESTS = {
 @pytest.mark.parametrize("name", sorted(BLOCK_MODEL_DIGESTS))
 def test_block_models_keep_their_lowered_prefill(name):
     c = getattr(ModelConfig, name)(dtype="float32")
-    params = jax.eval_shape(lambda: llama.init_params(c, 0))
+    params = jax.eval_shape(
+        lambda: llama.serving_params(c, llama.init_params(c, 0)))
     ctx = jax.eval_shape(lambda: llama.init_ctx(c, 2, 256, jnp.float32))
     text = _lowered(llama.prefill, c, params, ctx, _i32(64), _i32(), _i32(),
                     _i32(), fresh=True)

@@ -366,7 +366,10 @@ _HEADS_BY_STORED_ROW = re.compile(r"\w+\[(?:\d+,)*32,(?:\d+,)*640\]")
 # their expert layers hold every expert, so the looped row movements of
 # models/moe.py pass them by (8192 / 16384 sorted rows, padding alone dead:
 # the loops lose there, PERF.md section 6, PR 46)
-CONTINUING_LOWERING = {2048: "8f681471b1b3b42f", 4096: "c4c1c5a74bec99f4"}
+# PR 57 MEANT to move them (8f681471b1b3b42f / c4c1c5a74bec99f4 until then):
+# the products over W_kvb read ``wkb`` / ``wvb``, the stack sliced inside
+# ``_expand_prior``'s loop; temporaries 0.822 / 1.068 -> 0.762 / 1.011 GB
+CONTINUING_LOWERING = {2048: "4b19bd970156cc91", 4096: "843c34a9da1c84ae"}
 
 
 @pytest.mark.parametrize("what", ["no_region_copy", "no_op_at_the_rows_width",
@@ -648,6 +651,11 @@ def test_delta_rule_programs_copy_neither_the_state_nor_the_region(
     if name == "round_seal":
         assert rec["mosaic_calls"] >= 10 + 2 + 3 * 10
         assert rec["lowered_sha256"] == ROUND_LOWERING["ling3-flash-ep8-d12"]
+        # its two latent layers read W_kvb where it lies (one transposed
+        # ``bf16[512,8192]`` a layer a round until PR 57); what is left
+        # are same-layout prefetches of the delta-rule layers' ``w_bg``
+        assert {w.split(" ", 1)[1] for w in rec["weight_copies"]} <= {
+            "prefetch loop", "prefetch entry"}, rec["weight_copies"]
     else:
         # a block of the looped first half: q | k | v of 512 rows
         assert "bf16[512,12288]" in rec["text"]
@@ -808,26 +816,37 @@ def test_chat_rate_cell_keeps_nine_prefill_programs():
 # prefill, flush, seal and load program of all nine kept the parent's
 # digests: 54 of 62 programs equal, the 6 rounds and the two dense
 # ``decode_step`` programs moved (CHANGES.md, PR 53).
+# PR 57 MEANT to move the round and every prefill program of the three
+# latent configurations, ONCE (joyai ``round_seal_n4_w64`` 9eb8bd24fab037be
+# and ``batch_prefill_K2_T128`` 459e2c202d3ac337, xing4 ``round_seal_n4_w16``
+# 4a70f5eac1edcf3e, ling3's round 301254f0fe8b14b6, xing4's continuing
+# ``[1, 2048]`` / ``[1, 4096]`` 8f681471b1b3b42f / c4c1c5a74bec99f4 on its
+# parent, d39232c): the query product ends ahead of its reshape to heads
+# and the products over W_kvb read ``wkb`` / ``wvb``
+# (``llama.serving_params``). Their flush, seal and load programs and
+# every program of the six configurations with no latent layer kept the
+# parent's digests (CHANGES.md, PR 57).
 UNMOVED = {
     ("mla-moe-joyai-d5", 0): {
         "flush_ctx": "aa9a25ef5ee32101",
         "seal_blocks_w64": "60d93eac534dbceb",
         "flush_seal_w64": "0cf53d0f869aa38b",
-        "round_seal_n4_w64": "9eb8bd24fab037be",
+        "round_seal_n4_w64": "b5cdb9902b515b77",
         "load_ctx_pages_n64": "217cccff59be759b",
-        "batch_prefill_K2_T128": "459e2c202d3ac337",
+        "batch_prefill_K2_T128": "23c0aebdc3c5113f",
     },
     ("mistral7b-w8", 2): {
         "round_seal_n4_w8": "4fc6864b36262415",
         "batch_prefill_K2_T128": "587de9cf00cdecb3",
         "batch_prefill_cont_K2_T128_S4096": "2c9a09ec6d798ada",
     },
-    ("xing4-mhc-d7", 0): {"round_seal_n4_w16": "4a70f5eac1edcf3e"},
+    ("xing4-mhc-d7", 0): {"round_seal_n4_w16": "f57339fb4108d2f4"},
     ("granite4h-ep2-d10", 0): {"round_seal_n4_w32": "5aace7fa74a39250"},
 }
 # the full-depth rounds the records above already compile: the latent
-# cell 8's as its parents left it, the two PR 53 moved
-ROUND_LOWERING = {"ling3-flash-ep8-d12": "301254f0fe8b14b6",
+# cell 8's as PR 57 moved it (its two latent layers; 301254f0fe8b14b6 until
+# then), the two PR 53 moved
+ROUND_LOWERING = {"ling3-flash-ep8-d12": "2faf5c54da78563f",
                   "jamba2-3b": "85ce2cc7f19b7d4e",
                   "minicpm-sala-d16": "ebe6ccdc309a8d57"}
 _UNMOVED_NAMES = {"mla-moe-joyai-d5": MOVERS + ("round_seal", "load_ctx_pages",
@@ -839,13 +858,12 @@ _UNMOVED_NAMES = {"mla-moe-joyai-d5": MOVERS + ("round_seal", "load_ctx_pages",
 
 
 @pytest.fixture(scope="module")
-def unmoved_digests():
+def unmoved_records():
     _v5e_or_skip()
     with jax.default_matmul_precision("default"):
         return {
             (config, layers): {
-                r["program"]: r.get("lowered_sha256", r.get("error"))
-                for r in tpu_compile_check.compile_programs(
+                r["program"]: r for r in tpu_compile_check.compile_programs(
                     config=config, layers=layers,
                     programs=_UNMOVED_NAMES[config])}
             for config, layers in UNMOVED}
@@ -855,8 +873,41 @@ def unmoved_digests():
     (key, program) for key in sorted(UNMOVED) for program in UNMOVED[key]],
     ids=lambda v: v if isinstance(v, str) else v[0])
 def test_programs_beside_the_continuing_latent_chunk_keep_their_lowering(
-        unmoved_digests, key, program):
-    assert unmoved_digests[key][program] == UNMOVED[key][program]
+        unmoved_records, key, program):
+    rec = unmoved_records[key][program]
+    assert rec.get("lowered_sha256", rec.get("error")) == UNMOVED[key][program]
+
+
+# the latent rounds' temporaries, compiled for the v5e at each cell's OWN
+# depth (a 2-layer text hoists what depth 5 re-does a step): 0.232 / 0.294
+# GB on the parent of PR 57, most of it the wqb and wkvb stacks transposed
+# whole once a round and their layers' slices written out a step; 0.013 /
+# 0.045 GB since. The ceiling stands between.
+LATENT_ROUND_TEMP_CEILING = 0.05e9
+
+
+@pytest.mark.parametrize("config,program", [
+    ("mla-moe-joyai-d5", "round_seal_n4_w64"),
+    ("mla-moe-joyai-d5", "batch_prefill_K2_T128"),
+    ("xing4-mhc-d7", "round_seal_n4_w16")])
+def test_latent_programs_relayout_neither_wqb_nor_wkvb_on_v5e(
+        unmoved_records, config, program):
+    """The latent block's round and prefill read ``wqb`` and W_kvb where
+    they lie. Until PR 57 the round transposed both stacks whole in ENTRY
+    (``bf16[5,1536,6144]``, ``bf16[5,512,8192]``), wrote every layer's
+    slice of each out again every decode step (five ``bf16[1,1536,6144]``
+    and five ``bf16[1,512,8192]`` from one fusion a stack in the step
+    loop) and moved them a third time into fast memory, and a prefill
+    call wrote each layer's shard out twice. ``mla_moe._attn_in`` ends the
+    query product ahead of the reshape to heads; the products over W_kvb
+    read two head-major leaves made once at engine start
+    (``llama.serving_params``), which the reader knows by their names."""
+    rec = unmoved_records[config, 0][program]
+    assert rec["ok"], rec.get("error")
+    assert rec["layers"] == (5 if config == "mla-moe-joyai-d5" else 7)
+    assert rec["weight_copies"] == [], rec["weight_copies"]
+    if program.startswith("round_seal"):
+        assert rec["temp_bytes"] < LATENT_ROUND_TEMP_CEILING, rec["temp_gb"]
 
 
 # the wide dense prefill programs loop their row-wise halves over the live
@@ -922,7 +973,9 @@ def test_weight_copies_reads_copies_and_materialised_slices():
     """Canned text of the parent's 2-layer nemo12b-tp4 prefill (PR 55): a
     slice of the wq stack written out a layer, its transposed copy, an
     async copy of a wk shard; not the dot's own fusion, not what a
-    fusion's computation reads, not an activation of as many elements."""
+    fusion's computation reads, not an activation of as many elements.
+    Each with what it is (the two sides' orders differ: a relayout; the
+    operand's order not in the text: a copy) and where it stands."""
     shards = ((5120, 1024), (5120, 256), (5120, 3584))
     text = """
 %fused_computation.97 (param_0.1: bf16[2,5120,1024]) -> (bf16[1024,5120], bf16[1024,5120]) {
@@ -932,8 +985,10 @@ def test_weight_copies_reads_copies_and_materialised_slices():
 
 ENTRY %main.7_spmd (param.16: bf16[2,5120,1024]) -> bf16[2,256,5120] {
   %slice_bitcast_fusion = (bf16[1024,5120]{0,1:T(8,128)(2,1)S(1)}, bf16[1024,5120]{0,1:T(8,128)(2,1)S(1)}) fusion(%custom-call.4), kind=kLoop, calls=%fused_computation.97, metadata={op_name="jit(batch_prefill_impl)/vmap()/dot_general"}
+  %get-tuple-element.545 = bf16[1024,5120]{0,1:T(8,128)(2,1)S(1)} get-tuple-element(%slice_bitcast_fusion), index=0
   %copy.33 = bf16[1024,5120]{1,0:T(8,128)(2,1)S(1)} copy(%get-tuple-element.545), metadata={op_name="jit(batch_prefill_impl)/vmap()/dot_general"}
   %copy-start.2 = (bf16[1,5120,256]{1,2,0:T(8,128)(2,1)S(1)}, bf16[1,5120,256]{2,1,0}, u32[]{:S(2)}) copy-start(%slice.2)
+  %copy.34 = bf16[5120,3584]{0,1:T(8,128)(2,1)} copy(%somewhere.else)
   %copy.24 = bf16[2,2560,2,128]{3,1,2,0:T(8,128)(2,1)S(1)} copy(%bitcast.290)
   %fusion.52 = (f32[2,256]{1,0}, bf16[2,256,5120]{2,1,0}) fusion(%all-reduce, %all-reduce.1), kind=kLoop, calls=%fused_computation.86
   %fusion.68 = bf16[2,256,8,128]{1,3,2,0:T(8,128)(2,1)S(1)} fusion(%bitcast.276, %get-tuple-element.538), kind=kOutput, calls=%fused_computation.104
@@ -941,11 +996,60 @@ ENTRY %main.7_spmd (param.16: bf16[2,5120,1024]) -> bf16[2,256,5120] {
 }
 """
     assert tpu_compile_check.weight_copies(text, *shards) == [
-        "bf16[1024,5120]", "bf16[1,5120,256]",
-        "bf16[1024,5120]", "bf16[1024,5120]"]
+        "bf16[1024,5120] relayout entry", "bf16[1,5120,256] relayout entry",
+        "bf16[5120,3584] copy entry",
+        "bf16[1024,5120] slice entry", "bf16[1024,5120] slice entry"]
     # the count alone takes the [2,2560,2,128] activation for a wk shard
     assert "bf16[2,2560,2,128]" in tpu_compile_check.region_copies(
         text, (5120, 256))
+
+
+def test_weight_copies_tells_a_prefetch_from_a_relayout_and_a_step_from_a_call():
+    """Canned from the parents of PR 57. The latent round (joyai, depth 5):
+    ENTRY transposes the whole wqb stack once a round; the step loop's
+    body, and what it calls, writes a layer's slice of the transposed
+    stack out again and moves it into fast memory as it lies. The delta-
+    rule round (ling3, depth 12): ``w_bg`` [2560, 64] goes into memory
+    space 1 in the order it has, a prefetch the step needs, which
+    ROADMAP S6(f) took for a relayout while the reader was silent on
+    both questions."""
+    text = """
+%fused_computation.1143 (param_0.9: bf16[5,1536,6144]) -> (bf16[1,1536,6144], bf16[1,1536,6144]) {
+  %copy.7 = bf16[1,1536,6144]{1,2,0:T(8,128)(2,1)} copy(%param_0.9)
+  ROOT %t = (bf16[1,1536,6144]{1,2,0}, bf16[1,1536,6144]{1,2,0}) tuple(%copy.7, %copy.7)
+}
+
+%called_by_the_body.3 (p.1: bf16[2560,64]) -> bf16[2560,64] {
+  %p.1 = bf16[2560,64]{0,1:T(8,128)(2,1)} parameter(0)
+  %copy-start.66 = (bf16[2560,64]{0,1:T(8,128)(2,1)S(1)}, bf16[2560,64]{0,1:T(8,128)(2,1)}, u32[]{:S(2)}) copy-start(%p.1)
+  ROOT %copy-done.66 = bf16[2560,64]{0,1:T(8,128)(2,1)S(1)} copy-done(%copy-start.66)
+}
+
+%region_27.sunk (arg: (s32[], bf16[5,1536,6144])) -> (s32[], bf16[5,1536,6144]) {
+  %fusion.1161 = (bf16[1,1536,6144]{1,2,0:T(8,128)(2,1)}, bf16[1,1536,6144]{1,2,0:T(8,128)(2,1)S(1)}) fusion(%get-tuple-element.4250), kind=kLoop, calls=%fused_computation.1143, metadata={op_name="jit(engine_round_seal)/while/body/closed_call/slice"}
+  %get-tuple-element.3796 = bf16[1,1536,6144]{1,2,0:T(8,128)(2,1)} get-tuple-element(%fusion.1161), index=0
+  %copy-start.5 = (bf16[1,1536,6144]{1,2,0:T(8,128)(2,1)S(1)}, bf16[1,1536,6144]{1,2,0:T(8,128)(2,1)}, u32[]{:S(2)}) copy-start(%get-tuple-element.3796)
+  %call.2 = bf16[2560,64]{0,1:T(8,128)(2,1)S(1)} call(%w_bg), to_apply=%called_by_the_body.3
+}
+
+%region_28 (arg: (s32[], bf16[5,1536,6144])) -> pred[] {
+  ROOT %lt = pred[] compare(%i, %n), direction=LT
+}
+
+ENTRY %main.9 (params__layers____wqb__.1: bf16[5,1536,6144]) -> bf16[64,129280] {
+  %params__layers____wqb__.1 = bf16[5,1536,6144]{2,1,0:T(8,128)(2,1)} parameter(0)
+  %copy.491 = bf16[5,1536,6144]{1,2,0:T(8,128)(2,1)} copy(%params__layers____wqb__.1)
+  %copy.492 = bf16[2560,64]{0,1:T(8,128)(2,1)S(1)} copy(%w_bg_as_it_lies)
+  %w_bg_as_it_lies = bf16[2560,64]{0,1:T(8,128)(2,1)} parameter(1)
+  %while.108 = (s32[], bf16[5,1536,6144]{1,2,0:T(8,128)(2,1)}) while(%tuple.678), condition=%region_28, body=%region_27.sunk
+}
+"""
+    assert tpu_compile_check.weight_copies(
+        text, (5, 1536, 6144), (1536, 6144), (2560, 64)) == [
+        "bf16[2560,64] prefetch loop",
+        "bf16[1,1536,6144] prefetch loop",
+        "bf16[1,1536,6144] slice loop", "bf16[1,1536,6144] slice loop",
+        "bf16[5,1536,6144] relayout entry", "bf16[2560,64] prefetch entry"]
 
 
 @pytest.fixture(scope="module", params=[

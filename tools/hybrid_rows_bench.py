@@ -179,7 +179,8 @@ def main(argv=None) -> int:
     for name, c in widths(args.dry_run).items():
         if args.configs and name not in args.configs.split(","):
             continue
-        layers = llama.init_params(c, 0)["layers"]
+        layers = llama.serving_params(
+            c, llama.init_params(c, 0))["layers"]
         seen = set()
         for kind, lp in zip(ssm_moe.dims(c)["kinds"], layers):
             if (kind, "wr" in lp) in seen:

@@ -128,7 +128,8 @@ async def main(args) -> int:
             # nothing may still hold the old weights when the new are drawn
             engine.params = replay = None
             gc.collect()
-            engine.params = draw(jax.random.PRNGKey(seed % (2 ** 31)))
+            engine.params = llama.serving_params(
+                engine.config, draw(jax.random.PRNGKey(seed % (2 ** 31))))
         replay = Replay(engine)
         controls = (None,) + (required + named if n < args.controls_on
                               else ())

@@ -67,9 +67,14 @@ TOPOLOGY = "v5e:2x2"  # the four-chip host; tp=1 uses its first device
 
 # `%copy.3 = bf16[2,8,9,4096,128]{4,1,3,2,0:T(8,128)(2,1)} copy(%p)`, and
 # `copy-start`, whose result is a tuple that leads with the copy's shape
+# and holds its operand's next: (dtype, dimensions, the result's
+# minor-to-major order, the operand's where the tuple gives it, the
+# operand's name)
 _COPY = re.compile(
-    r"^\s*(?:ROOT\s+)?%?[\w.\-]+ = \(?(\w+)\[([\d,]*)\]"
-    r"(?:[^\n]*?[)}])? copy(?:-start)?\(", re.M)
+    r"^\s*(?:ROOT\s+)?%?[\w.\-]+ = \(?(\w+)\[([\d,]*)\](?:\{([\d,]*)[^\n]*?[)}])?"
+    r" copy(?:-start)?\(%?([\w.\-]+)", re.M)
+_COPY_START_SOURCE = re.compile(
+    r"= \(\w+\[[\d,]*\]\{[^}]*\}, \w+\[[\d,]*\]\{([\d,]*)")
 _MOSAIC_BODY = re.compile(r'\\22body\\22: \\22[^\\]*\\22')
 # `%f.3 = (bf16[256,5120]{0,1:T(8,128)(2,1)S(1)}, bf16[256,5120]{...})
 # fusion(%custom-call.5), kind=kLoop, calls=%fused_computation.129`: a
@@ -83,6 +88,13 @@ _SHAPE = re.compile(r"(\w+)\[([\d,]+)\]")
 _COMPUTATION = re.compile(
     r"^(?:ENTRY\s+)?%?([\w.\-]+) \([^\n]*\) -> [^\n]*\{\n(.*?)^\}", re.M | re.S)
 _FUSED = re.compile(r" fusion\([^\n]*?calls=%?([\w.\-]+)")
+# an array-valued instruction's own minor-to-major order, by its name
+_LAID_OUT = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = \w+\[[\d,]*\]\{([\d,]*)", re.M)
+_LOOP_BODY = re.compile(r" while\([^\n]*?body=%?([\w.\-]+)")
+_CALLED = re.compile(
+    r"(?:calls|to_apply|body|condition|\w+_computation)=%?([\w.\-]+)"
+    r"|branch_computations=\{([^}]*)\}")
 
 
 def region_copies(hlo_text: str, *shards: tuple[int, ...]) -> list[str]:
@@ -98,33 +110,73 @@ def region_copies(hlo_text: str, *shards: tuple[int, ...]) -> list[str]:
     return found
 
 
+def _in_loops(bodies: dict[str, str]) -> set[str]:
+    """The computations a ``while`` runs: its body and whatever that
+    calls, by name."""
+    seen, todo = set(), [
+        n for body in bodies.values() for n in _LOOP_BODY.findall(body)]
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in bodies:
+            continue
+        seen.add(name)
+        for one, several in _CALLED.findall(bodies[name]):
+            todo += [one] if one else [
+                n.strip().lstrip("%") for n in several.split(",")]
+    return seen
+
+
 def weight_copies(hlo_text: str, *shards: tuple[int, ...]) -> list[str]:
     """What a compiled module writes out in the shape of one layer's shard
-    of a projection or MLP weight: the results of ``copy`` / ``copy-start``
-    and the elements of a tuple that a fusion other than a dot's writes (a
-    slice of the layer stack laid out anew, one output a layer:
-    ``slice_bitcast_fusion``). "The shape of": the shard's dimensions in
-    any order, ones dropped (``[1024,5120]`` and ``[1,5120,1024]`` for a
-    ``[5120,1024]`` shard). The element count alone, ``region_copies``'
-    test, takes activations for weights here (at Mistral-7B's widths the
-    rope halves of a ``[2, 1024]`` chunk's q, ``bf16[2,1024,32,64]``,
-    count as many as a ``wk``). Instructions INSIDE a fusion's computation
-    are that fusion's reads, nothing written, and do not count.
-    ``["bf16[1024,5120]", ...]``"""
+    of a projection or MLP weight, each with WHAT it is and WHERE it
+    stands: ``"bf16[1,5120,256] relayout entry"``.
+
+    What: the result of a ``copy`` / ``copy-start`` whose two sides differ
+    in their minor-to-major order is a ``relayout`` (the weight laid out
+    anew in front of its product, every call: a seventh of the four-chip
+    cell's device time until PR 55); with one order on both sides it is a
+    ``prefetch`` (the weight moved as it lies into the memory the product
+    reads, which a step has to do anyway: ``bf16[2560,64]`` of cell 8,
+    taken for a relayout until PR 57); ``copy`` where the operand's order
+    is not in the text. An element of a tuple that a fusion other than a
+    dot's writes is a ``slice`` (a slice of the layer stack written out,
+    one output a layer: ``slice_bitcast_fusion``). Where: ``loop`` in a
+    computation some ``while`` runs (a round's steps: every STEP), else
+    ``entry`` (once a call).
+
+    "The shape of": the shard's dimensions in any order, ones dropped
+    (``[1024,5120]`` and ``[1,5120,1024]`` for a ``[5120,1024]`` shard).
+    The element count alone, ``region_copies``' test, takes activations
+    for weights here (at Mistral-7B's widths the rope halves of a ``[2,
+    1024]`` chunk's q, ``bf16[2,1024,32,64]``, count as many as a ``wk``).
+    Instructions INSIDE a fusion's computation are that fusion's reads,
+    nothing written, and do not count."""
     def dims(shape):
         return tuple(sorted(int(d) for d in shape if int(d) != 1))
 
     wanted = {dims(s) for s in shards}
     fused = set(_FUSED.findall(hlo_text))
+    bodies = dict(_COMPUTATION.findall(hlo_text))
+    looped = _in_loops(bodies)
     found = []
-    for name, body in _COMPUTATION.findall(hlo_text):
+    for name, body in bodies.items():
         if name in fused:
             continue
-        written = [m.groups() for m in _COPY.finditer(body)]
+        where = "loop" if name in looped else "entry"
+        orders = dict(_LAID_OUT.findall(body))
+        written = []
+        for m in _COPY.finditer(body):
+            dtype, shape, order, operand = m.groups()
+            source = _COPY_START_SOURCE.search(m.group(0))
+            source = source.group(1) if source else orders.get(operand)
+            written.append((dtype, shape, "copy" if source is None else
+                            "prefetch" if source == order else "relayout"))
         for m in _TUPLE_FUSION.finditer(body):
             if m.group(2) != "kOutput":
-                written += _SHAPE.findall(m.group(1))
-        found += [f"{dtype}[{shape}]" for dtype, shape in written
+                written += [(dtype, shape, "slice") for dtype, shape
+                            in _SHAPE.findall(m.group(1))]
+        found += [f"{dtype}[{shape}] {what} {where}"
+                  for dtype, shape, what in written
                   if shape and dims(shape.split(",")) in wanted]
     return found
 
@@ -192,6 +244,14 @@ def compile_programs(model_config: str = "llama3_1b", tp: int = 1,
 
     params = abstract(lambda: llama.init_params(c, 0),
                       llama.param_shardings(c, mesh))
+    # as the engine hands them to its programs: the leaves a block makes
+    # once at start (llama.serving_params) beside the published ones,
+    # replicated as every block that makes any holds its weights
+    published = dict(jax.tree_util.tree_flatten_with_path(params)[0])
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, x: published[path] if path in published
+        else jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=rep),
+        jax.eval_shape(lambda p: llama.serving_params(c, p), params))
     ctx = abstract(
         lambda: llama.init_ctx(c, B, S, dtype, kv_quant=kv_quant,
                                group=e.page_size),
@@ -229,6 +289,10 @@ def compile_programs(model_config: str = "llama3_1b", tp: int = 1,
     # quantised one; an expert stack whole and one expert of it): what no
     # program should lay out anew per call. The dense and latent blocks
     # stack their layers along a leading axis, the hybrid ones list them
+    # (a stack laid out anew WHOLE is not looked for by shape: at the 2
+    # layers and 2 lanes tier-1 compiles, a chunk's ``[2, 256, 5120]``
+    # activations have a ``wk`` stack's dimensions; ``temp_gb`` holds it,
+    # as it held the latent rounds' two until PR 57)
     stacked = isinstance(params["layers"], dict)
     weight_shards = set()
     for path, w in jax.tree_util.tree_flatten_with_path(params["layers"])[0]:
