@@ -1,0 +1,13 @@
+"""Time to first token, p90 (due time -> first streamed chunk with text),
+in the open-loop coding-turn mix, from the generator's clock in the traced
+run: the long tasks' four chunks of 4096 behind other prompts' chunks and
+the decoding lanes' rounds. Recorded, not judged. The arithmetic is the
+chat-decode mix's reader's (the generator's reduction is one)."""
+import os
+
+_HERE = os.path.dirname(os.path.abspath(__file__))
+
+
+def read(sources):
+    return sources["byname"].module_with(
+        _HERE, "ttft_ms_p90.chat-decode-open", "read").read(sources)

@@ -43,6 +43,26 @@ _PHI4FLASH_TYPES = frozenset({"phi4flash"})
 _PHI4FLASH_KEYS = ("mb_per_layer", "sliding_window", "layer_norm_eps",
                    "intermediate_size", "num_attention_heads",
                    "num_key_value_heads", "num_hidden_layers")
+# window and full rotary GQA layers whose query-head counts differ by layer
+# over one K/V geometry, a per-head sigmoid gate on the attention output,
+# a rotary rule a layer kind, one leading dense SwiGLU and then sigmoid-
+# routed experts with a shared one, the head untied: the same hybrid stack
+# (_from_hf_laguna)
+_LAGUNA_TYPES = frozenset({"laguna"})
+_LAGUNA_KEYS = ("hidden_size", "intermediate_size", "num_hidden_layers",
+                "num_attention_heads", "num_key_value_heads", "head_dim",
+                "vocab_size", "rms_norm_eps", "num_experts",
+                "num_experts_per_tok", "moe_intermediate_size",
+                "shared_expert_intermediate_size", "norm_topk_prob",
+                "mlp_only_layers", "gating", "sliding_window",
+                "rope_parameters", "layer_types", "mlp_layer_types",
+                "gating_types", "moe_routed_scaling_factor",
+                "num_attention_heads_per_layer")
+# the published names of its layer kinds -> the kinds models/ssm_moe.py builds
+_LAGUNA_KINDS = {"full_attention": "attention",
+                 "sliding_attention": "window_attention"}
+_YARN_RULE_KEYS = ("factor", "original_max_position_embeddings",
+                   "beta_fast", "beta_slow", "attention_factor")
 # keys that mean "not a dense Llama": a config carrying one is refused
 # rather than read with its extra structure dropped
 _FOREIGN_KEYS = ("kv_lora_rank", "q_lora_rank", "n_routed_experts",
@@ -164,6 +184,33 @@ _TINY_PHI4FLASH = {
     "sliding_window": 8, "layer_norm_eps": 1e-5, "hidden_act": "silu",
     "mamba_dt_rank": 8, "mlp_bias": False, "lm_head_bias": False,
     "max_position_embeddings": 512, "tie_word_embeddings": True,
+}
+_TINY_LAGUNA = {
+    "model_type": "laguna", "vocab_size": 256, "hidden_size": 64,
+    "intermediate_size": 128, "num_hidden_layers": 6,
+    "num_attention_heads": 12, "num_key_value_heads": 2, "head_dim": 16,
+    "attention_bias": False, "rms_norm_eps": 1e-6,
+    "num_experts": 4, "num_experts_per_tok": 4,
+    "expert_share": {"published_experts": 16, "of": 4, "index": 0},
+    "moe_intermediate_size": 32, "shared_expert_intermediate_size": 32,
+    "norm_topk_prob": True, "decoder_sparse_step": 1,
+    "mlp_only_layers": [0], "gating": "per-head", "sliding_window": 8,
+    "rope_parameters": {
+        "full_attention": {
+            "rope_theta": 500000, "rope_type": "yarn", "factor": 4,
+            "original_max_position_embeddings": 32, "beta_slow": 1,
+            "beta_fast": 32, "attention_factor": 1.1386294361119891,
+            "partial_rotary_factor": 0.5},
+        "sliding_attention": {"rope_type": "default", "rope_theta": 10000,
+                              "partial_rotary_factor": 1}},
+    "layer_types": ["full_attention"] + ["sliding_attention"] * 3
+                   + ["full_attention", "sliding_attention"],
+    "mlp_layer_types": ["dense"] + ["sparse"] * 5,
+    "gating_types": ["per_head"] * 6,
+    "num_attention_heads_per_layer": [12, 18, 18, 18, 12, 18],
+    "moe_apply_router_weight_on_input": False,
+    "moe_routed_scaling_factor": 2.5, "moe_router_logit_softcapping": 0,
+    "max_position_embeddings": 512, "tie_word_embeddings": False,
 }
 _TINY_LINEAR_SPARSE = {
     "model_type": "minicpm_sala", "vocab_size": 256, "hidden_size": 64,
@@ -289,7 +336,11 @@ class ModelConfig:
     # dense MLP a layer, the head tied) | window_attention |
     # cross_attention | gmu (_from_hf_phi4flash: differential attention
     # behind a window, over another layer's rows, and a gated memory unit
-    # over another layer's scan; LayerNorm).
+    # over another layer's scan; LayerNorm); and (_from_hf_laguna) the
+    # attention and window_attention kinds in the ROTARY GQA form: a
+    # rotary rule a kind (`rope`), every layer's own number of query heads
+    # (`heads_by_layer`; `num_heads` is then the published default only), a
+    # sigmoid gate a head, the one-group sigmoid router with a share.
     hybrid: Optional[tuple[tuple[str, Any], ...]] = None
 
     @property
@@ -339,6 +390,8 @@ class ModelConfig:
             return cls._from_hf_phi4flash(d)
         if model_type in _LINEAR_SPARSE_TYPES:
             return cls._from_hf_linear_sparse(d)
+        if model_type in _LAGUNA_TYPES:
+            return cls._from_hf_laguna(d)
         if model_type not in _DENSE_TYPES:
             raise ValueError(
                 f"model_type {model_type!r} is no block this program "
@@ -352,7 +405,10 @@ class ModelConfig:
                 f"sigmoid experts (_from_hf_kda_latent): "
                 f"{sorted(_KDA_LATENT_TYPES)}; Mamba-1 + differential "
                 f"window / full / cross attention + gated memory units "
-                f"(_from_hf_phi4flash): {sorted(_PHI4FLASH_TYPES)})")
+                f"(_from_hf_phi4flash): {sorted(_PHI4FLASH_TYPES)}; window "
+                f"+ full rotary GQA layers with head counts by layer, a "
+                f"head gate and sigmoid experts (_from_hf_laguna): "
+                f"{sorted(_LAGUNA_TYPES)})")
         unknown = sorted(k for k in _FOREIGN_KEYS if d.get(k))
         if unknown:
             raise ValueError(
@@ -794,6 +850,158 @@ class ModelConfig:
         )
 
     @classmethod
+    def _from_hf_laguna(cls, d: dict[str, Any]) -> "ModelConfig":
+        """Window and full GQA layers of ``model_type: laguna``: every
+        layer is softmax attention over ``num_key_value_heads`` K/V heads
+        of ``head_dim``, ``full_attention`` over the whole context and
+        ``sliding_attention`` over the last ``sliding_window`` positions
+        (the query's own among them), with ITS OWN number of query heads
+        (``num_attention_heads_per_layer``), a sigmoid gate a head on the
+        attention output before W_o (``gating`` ``per-head``) and a rotary
+        rule a kind (``rope_parameters``: theta, the share of the head
+        that rotates, rotate-half, and YaRN with its factor on cos and
+        sin). The layers of ``mlp_only_layers`` (a leading run) carry one
+        dense SwiGLU of ``intermediate_size``, the others ``num_experts``
+        routed experts of ``moe_intermediate_size`` (top
+        ``num_experts_per_tok`` of sigmoid scores with a selection-only
+        bias, weights normalised x ``moe_routed_scaling_factor``: the
+        grouped sigmoid router with ONE group) plus a shared expert,
+        ungated; the head is untied. Anything this program does not build
+        is refused by name.
+
+        The experts held HERE are ``num_experts``; a file that holds a
+        share states the deployment under ``expert_share``
+        (``published_experts``, ``of``, ``index``) as the other expert
+        stacks do, and the router keeps the published width."""
+        missing = sorted(k for k in _LAGUNA_KEYS if k not in d)
+        if missing:
+            raise ValueError("window + full GQA block: keys "
+                             f"{missing} are missing from the config")
+        L, hd = int(d["num_hidden_layers"]), int(d["head_dim"])
+        kvh = int(d["num_key_value_heads"])
+        held = int(d["num_experts"])
+        share = d.get("expert_share") or {
+            "published_experts": held, "of": 1, "index": 0}
+        if set(share) != {"published_experts", "of", "index"}:
+            raise ValueError(
+                "window + full GQA block: expert_share needs exactly "
+                f"published_experts, of and index (it has {sorted(share)})")
+        E, of, index = (int(share[k]) for k in
+                        ("published_experts", "of", "index"))
+        lists = {k: list(d[k]) for k in (
+            "layer_types", "mlp_layer_types", "gating_types",
+            "num_attention_heads_per_layer")}
+        short = sorted(k for k, v in lists.items() if len(v) != L)
+        if short:
+            raise ValueError(
+                f"window + full GQA block: {short} hold no entry a layer "
+                f"(num_hidden_layers {L})")
+        kinds = tuple(_LAGUNA_KINDS.get(t, t) for t in lists["layer_types"])
+        heads = tuple(int(n) for n in lists["num_attention_heads_per_layer"])
+        dense = sorted(int(l) for l in d["mlp_only_layers"])
+        window = d["sliding_window"]
+        rules = d["rope_parameters"]
+        rope, bad_rules = {}, []
+        for name, kind in _LAGUNA_KINDS.items():
+            r = rules.get(name) if isinstance(rules, dict) else None
+            if kind not in kinds:
+                continue
+            rot = (hd * r.get("partial_rotary_factor", 1)
+                   if isinstance(r, dict) else 0)
+            yarn = isinstance(r, dict) and r.get("rope_type") == "yarn"
+            if (not isinstance(r, dict) or "rope_theta" not in r
+                    or r.get("rope_type") not in ("default", "yarn")
+                    or rot != int(rot) or int(rot) % 2 or not 0 < rot <= hd
+                    or (yarn and any(k not in r for k in _YARN_RULE_KEYS))):
+                bad_rules.append(name)
+                continue
+            rule = {"type": r["rope_type"], "theta": float(r["rope_theta"]),
+                    "rot": int(rot)}
+            if yarn:
+                rule.update({k: float(r[k]) for k in _YARN_RULE_KEYS})
+            rope[kind] = tuple(sorted(rule.items()))
+        refused = {
+            f"gating {d['gating']!r} (only 'per-head': one sigmoid gate a "
+            "query head)": d["gating"] != "per-head",
+            "gating_types other than per_head":
+                set(lists["gating_types"]) != {"per_head"},
+            f"layer_types other than {sorted(_LAGUNA_KINDS)}":
+                not set(lists["layer_types"]) <= set(_LAGUNA_KINDS),
+            "a stack without a full_attention layer (its region would "
+            "hold no rows of the context's length)":
+                "attention" not in kinds,
+            f"rope_parameters of {bad_rules} (a rule a layer kind: "
+            "rope_theta, rope_type default | yarn with its five keys, an "
+            "even partial_rotary_factor x head_dim)": bool(bad_rules),
+            "moe_router_logit_softcapping other than 0":
+                d.get("moe_router_logit_softcapping", 0) != 0,
+            "moe_apply_router_weight_on_input":
+                bool(d.get("moe_apply_router_weight_on_input", False)),
+            f"decoder_sparse_step {d.get('decoder_sparse_step', 1)} (only "
+            "1)": d.get("decoder_sparse_step", 1) != 1,
+            "norm_topk_prob false": not d["norm_topk_prob"],
+            "attention_bias": bool(d.get("attention_bias", False)),
+            "tie_word_embeddings": bool(d.get("tie_word_embeddings", False)),
+            f"hidden_act {d.get('hidden_act')!r}":
+                d.get("hidden_act", "silu") != "silu",
+            "mlp_only_layers that are no leading run, or mlp_layer_types "
+            "that disagree with them":
+                dense != list(range(len(dense)))
+                or lists["mlp_layer_types"] != (
+                    ["dense"] * len(dense) + ["sparse"] * (L - len(dense))),
+            "sliding_window (no one window of 2 positions or more)":
+                not isinstance(window, int) or isinstance(window, bool)
+                or window < 2,
+            "num_attention_heads_per_layer: a count that is no multiple "
+            "of num_key_value_heads": any(n < kvh or n % kvh for n in heads),
+            f"expert_share: {held} held x {of} chips is not the published "
+            f"{E} experts (a share is a whole-number split)":
+                of < 1 or held * of != E,
+            f"expert_share index {index} outside 0..{of - 1}":
+                not 0 <= index < max(of, 1),
+            "num_experts_per_tok above the published experts, or fewer "
+            "than 2 of them": d["num_experts_per_tok"] > E or E < 2,
+        }
+        bad = sorted(k for k, v in refused.items() if v)
+        if bad:
+            raise ValueError(f"window + full GQA block: {bad} are values "
+                             "this program does not build")
+        hybrid = dict(
+            layer_types=kinds, heads_by_layer=heads, gate="head",
+            rope=tuple(sorted(rope.items())),
+            window=int(window),
+            window_rows=1 << (int(window) - 1).bit_length(),
+            n_dense=len(dense),
+            # the grouped sigmoid router with ONE group: every expert
+            # stays in the running
+            router="sigmoid_groups", n_group=1, topk_group=1,
+            routed_scaling_factor=float(d["moe_routed_scaling_factor"]),
+            num_local_experts=held,
+            num_experts_per_tok=int(d["num_experts_per_tok"]),
+            intermediate_size=int(d["moe_intermediate_size"]),
+            shared_intermediate_size=int(
+                d["shared_expert_intermediate_size"]),
+            published_experts=E, share_of=of, share_index=index,
+            embedding_multiplier=1.0, residual_multiplier=1.0,
+            attention_multiplier=hd ** -0.5, logits_scaling=1.0)
+        if "window_attention" not in kinds:
+            del hybrid["window"], hybrid["window_rows"]
+        return cls(
+            vocab_size=d["vocab_size"],
+            hidden_size=d["hidden_size"],
+            intermediate_size=d["intermediate_size"],
+            num_layers=L,
+            num_heads=int(d["num_attention_heads"]),
+            num_kv_heads=kvh,
+            head_dim=hd,
+            rms_norm_eps=d["rms_norm_eps"],
+            max_position_embeddings=d.get("max_position_embeddings", 8192),
+            tie_word_embeddings=False,
+            model_type=d["model_type"],
+            hybrid=tuple(sorted(hybrid.items())),
+        )
+
+    @classmethod
     def _from_hf_kda_latent(cls, d: dict[str, Any]) -> "ModelConfig":
         """Delta-rule linear attention (KDA) layers with a per-channel
         gate and short convolutions, one latent (MLA) layer closing every
@@ -955,6 +1163,17 @@ class ModelConfig:
         unit, cross), four query heads on two K/V heads of 16 (one K/V
         pair of 32), a window of 8."""
         d = dict(_TINY_PHI4FLASH)
+        d.update(kw)
+        return cls.from_hf_dict(d)
+
+    @classmethod
+    def tiny_laguna(cls, **kw) -> "ModelConfig":
+        """Toy window + full GQA stack for CPU tests: six layers (full,
+        window x 3, full, window), 18 / 12 query heads (groups of 9 and 6)
+        over two K/V heads of 16, a window of 8, YaRN over half the head
+        on the full layers, one dense layer and then 16 experts top 4 of
+        which share 0 of 4 holds 4."""
+        d = dict(_TINY_LAGUNA)
         d.update(kw)
         return cls.from_hf_dict(d)
 

@@ -119,6 +119,25 @@ kind, what a region holds from its leaves:
     rows (``_window_prior``). Both attention ops take the window as a BOUND
     (a lower loop bound over key blocks in prefill, the buffer's chunks
     and a mask in decode), never a [T, S] map.
+  - THE ROTARY GQA FORM (a config with a rotary rule a layer kind,
+    ``rope``) on the ``attention`` and ``window_attention`` kinds, where
+    the differential form is not stated: plain softmax GQA over the
+    stack's ONE K/V geometry (``row_heads``: the region, the ring and the
+    movers are as above, the window kind's rows in the same ``wk`` / ``wv``
+    modular buffers), with EVERY LAYER'S OWN NUMBER OF QUERY HEADS
+    (``heads_by_layer``: ``wq`` [H, heads_l hd], ``wo`` [heads_l hd, H]; a
+    query group of heads_l / kvh rows a K/V head, whatever it is a layer).
+    q and k are rotated BY THE KIND'S RULE (ops/rope.py: ``kind_rotary``:
+    theta a kind, rotate-half over the first ``rot`` dimensions of the
+    head with the rest passed through, YaRN's inverse frequencies over the
+    rotated dimensions with its factor on cos and sin, which is no factor
+    on the softmax scale once part of the head passes through); K rows are
+    stored rotated. A sigmoid GATE A HEAD multiplies the attention output
+    before W_o: ``z = x W_g`` [H, heads_l] float32 from the layer's normed
+    input, ``sigmoid(z_h) o_h``. The window and full paths are the
+    differential form's (``_rows_prefill``, the decode step's one branch):
+    the two forms differ in what happens to q, k and v before and to o
+    after. Each kind's decode call has its own name in a trace.
   - ``cross_attention``: that form with ``w_q`` / ``w_o`` only, over the
     rows of ANOTHER layer (``rows_from``, an ``attention`` layer below it):
     it keeps no rows, and the rows it reads are indexed by that layer's
@@ -148,7 +167,8 @@ kind, what a region holds from its leaves:
     among them are picked, weighted by ``s`` (no bias) over their sum x
     ``routed_scaling_factor``. The share is told to the grouped product
     as for the softmax router; a fifth counter says how many tokens kept
-    the group(s) held here.
+    the group(s) held here. With ONE group every expert stays in the
+    running: the top k of ``c``, no group scored.
 
 Every function here is reached through the ``llama`` names
 (``llama.block_of``), as models/mla_moe.py is.
@@ -182,11 +202,19 @@ from dynamo_tpu.ops.attention import (
     region_trips,
 )
 from dynamo_tpu.ops.latent_decode import latent_decode_attention
-from dynamo_tpu.ops.rope import apply_rope, rope_cos_sin, rope_inv_freq
+from dynamo_tpu.ops.rope import (
+    apply_rope,
+    apply_rope_leading,
+    kind_rotary,
+    rope_cos_sin,
+    rope_inv_freq,
+)
 from dynamo_tpu.telemetry.metrics import (
     ATTN_SHARED_ROWS_READ,
     ATTN_WINDOW_ROWS_BOUND,
     ATTN_WINDOW_ROWS_READ,
+    DECODE_ATTN_Q_ROWS_FULL,
+    DECODE_ATTN_Q_ROWS_WINDOW,
     DECODE_ATTN_ROWS_READ,
     KDA_STATE_ROWS_STEPPED,
     MOE_GROUPS_KEPT_HERE,
@@ -222,6 +250,14 @@ ROW_LAYERS = ("attention", "sparse_attention")   # kinds that keep K/V rows
 # the kinds the differential form covers where the config states it
 DIFF_KINDS = ("attention", "window_attention", "cross_attention")
 DIFF_KERNEL = "diff_decode_attention"   # their decode kernel in a trace
+# the kinds of the ROTARY GQA form (a config with a rotary rule a kind:
+# ``rope``), their scopes and their decode kernels' names in a trace
+GQA_KINDS = ("attention", "window_attention")
+GQA_SCOPES = {"attention": "full_gqa_attn",
+              "window_attention": "window_gqa_attn"}
+GQA_KERNELS = {"attention": "full_gqa_decode_attention",
+               "window_attention": "window_gqa_decode_attention"}
+ROPE_SCOPES = {"attention": "rope_full", "window_attention": "rope_window"}
 # prefill: where every layer above the one whose rows the cross layers read
 # writes neither rows nor state, only a chunk's last real row climbs them
 # (tests set it False: every row climbs, the logits must not move)
@@ -241,6 +277,12 @@ def dims(c: ModelConfig) -> dict[str, Any]:
         "n_m1": sum(t == "mamba1" for t in kinds),
         "n_win": sum(t == "window_attention" for t in kinds),
         "diff": bool(k.get("differential")),
+        # a rotary rule a layer kind ({} = the attention kinds are NoPE),
+        # and every layer's own number of query heads over the stack's one
+        # K/V geometry
+        "rotary": {kind: dict(rule) for kind, rule in k.get("rope", ())},
+        "heads": tuple(k.get("heads_by_layer")
+                       or (c.num_heads,) * len(kinds)),
         "n_latent": sum(t == "latent_attention" for t in kinds),
         "experts": "num_local_experts" in k,
         # leading layers whose feed-forward part is one dense MLP
@@ -296,6 +338,18 @@ def dims(c: ModelConfig) -> dict[str, Any]:
             "sparse": sparse_attention.Geometry.of(dict(k["sparse"])),
         })
     return d
+
+
+def _form(d, kind: str):
+    """Which softmax-attention form a layer of ``kind`` runs on the window
+    / full row paths they share (``_rows_prefill``, the decode step's one
+    branch): ``"diff"`` the differential form, ``"gqa"`` the rotary GQA
+    form with a gate a head; None: the kind's own path."""
+    if d["diff"] and kind in DIFF_KINDS:
+        return "diff"
+    if d["rotary"] and kind in GQA_KINDS:
+        return "gqa"
+    return None
 
 
 def row_heads(c: ModelConfig) -> tuple[int, int]:
@@ -386,7 +440,8 @@ def init_params(config: ModelConfig, rng: jax.Array | int = 0) -> Params:
     form an attention layer has projection biases (x 0.1), four ``lam``
     vectors (x 0.1), the pair norm's gain 1 and ``lam_init`` = 0.8 - 0.6
     exp(-0.3 l) of its depth l; a LayerNorm stack a bias (x 0.1) beside
-    each gain."""
+    each gain. A layer of the rotary GQA form has ``wq`` / ``wo`` at ITS
+    number of query heads and ``wg`` [H, heads], the gate's."""
     if isinstance(rng, int):
         rng = jax.random.PRNGKey(rng)
     c, d = config, dims(config)
@@ -446,11 +501,17 @@ def init_params(config: ModelConfig, rng: jax.Array | int = 0) -> Params:
         else:
             I = c.intermediate_size
             lp.update(w_g=rnd(H, I), w_u=rnd(H, I), w_d=rnd(I, H))
-        if d["diff"] and kind in DIFF_KINDS:
+        if _form(d, kind) == "diff":
             lp.update(diff_attention(i, kind != "cross_attention"))
             return lp
         if kind == "gmu":
             lp.update(w1=rnd(H, d["m1_inner"]), w2=rnd(d["m1_inner"], H))
+            return lp
+        if _form(d, kind) == "gqa":
+            q_dim = d["heads"][i] * c.head_dim
+            lp.update(wq=rnd(H, q_dim), wk=rnd(H, c.kv_dim),
+                      wv=rnd(H, c.kv_dim), wg=rnd(H, d["heads"][i]),
+                      wo=rnd(q_dim, H))
             return lp
         if kind == "attention":
             lp.update(wq=rnd(H, c.q_dim), wk=rnd(H, c.kv_dim),
@@ -743,19 +804,25 @@ def route(c: ModelConfig, lp, x):
         biased = s + lp["bias"]
         N, G = s.shape[0], d["groups"]
         size = d["E"] // G
-        # a group's two best as two maxima (top_k of 2 lowers to a sort of
-        # every group on the TPU: 2.6 % of a decode step's device time)
-        per = biased.reshape(N, G, size)
-        at = jnp.argmax(per, axis=-1, keepdims=True)
-        second = jnp.max(jnp.where(
-            jnp.arange(size) == at, -jnp.inf, per), axis=-1)
-        _, keep = jax.lax.top_k(per.max(-1) + second, d["kept"])   # [N, kept]
-        kept = jnp.zeros((N, G), bool).at[
-            jnp.arange(N)[:, None], keep].set(True)
-        masked = jnp.where(jnp.repeat(kept, size, axis=1), biased, -jnp.inf)
+        masked = biased   # one group: every expert stays in the running
+        if G > 1:
+            # a group's two best as two maxima (top_k of 2 lowers to a sort
+            # of every group on the TPU: 2.6 % of a decode step's device
+            # time)
+            per = biased.reshape(N, G, size)
+            at = jnp.argmax(per, axis=-1, keepdims=True)
+            second = jnp.max(jnp.where(
+                jnp.arange(size) == at, -jnp.inf, per), axis=-1)
+            _, keep = jax.lax.top_k(per.max(-1) + second, d["kept"])  # [N, kept]
+            kept = jnp.zeros((N, G), bool).at[
+                jnp.arange(N)[:, None], keep].set(True)
+            masked = jnp.where(jnp.repeat(kept, size, axis=1), biased,
+                               -jnp.inf)
         _, sel = jax.lax.top_k(masked, d["K"])
         w = jnp.take_along_axis(s, sel, axis=-1)
         w = w / (w.sum(-1, keepdims=True) + 1e-20) * d["scale"]
+        if G == 1:
+            return sel, w, jnp.ones((N,), bool)
         # the groups that hold this chip's experts (one, a part of one,
         # or several: config.py's rule on the share)
         first = d["first"] or 0
@@ -884,6 +951,53 @@ def _qkv(c: ModelConfig, lp, x):
     return (q.reshape(N, c.num_heads, c.head_dim),
             (x @ lp["wk"]).reshape(N, c.num_kv_heads, c.head_dim),
             (x @ lp["wv"]).reshape(N, c.num_kv_heads, c.head_dim))
+
+
+def _gqa_in(c: ModelConfig, kind: str, lp, x, pos):
+    """The rotary GQA form's in-projection: [N, H] at positions ``pos`` [N]
+    -> q [N, heads, hd] at the LAYER'S OWN number of query heads (its
+    ``wq``'s columns), k and v [N, kvh, hd], and the gate's input z [N,
+    heads] float32, one scalar a query head. q and k are rotated by the
+    kind's rule (ops/rope.py: ``kind_rotary``: theta, the leading
+    dimensions that rotate, YaRN's factor on cos and sin), so K rows are
+    stored rotated; the softmax scale is the attention ops' own
+    1 / sqrt(hd)."""
+    N, hd = x.shape[0], c.head_dim
+    # each product ends as an [N, out] array: left to fold the reshape to
+    # heads (and the rotary after it) into the product, XLA:TPU lays every
+    # layer's wq / wk / wv out anew in front of it, every call
+    # (tools/tpu_compile_check.py ``weight_copies``; PERF.md section 6,
+    # PR 55)
+    q, k, v = (jax.lax.optimization_barrier(x @ lp[w]).reshape(N, -1, hd)
+               for w in ("wq", "wk", "wv"))
+    with jax.named_scope("head_gate"):
+        z = jnp.matmul(x, lp["wg"], preferred_element_type=jnp.float32)
+    with jax.named_scope(ROPE_SCOPES[kind]):
+        inv_freq, factor = kind_rotary(hd, dims(c)["rotary"][kind])
+        cos, sin = rope_cos_sin(pos, inv_freq)
+        q = apply_rope_leading(q, cos, sin, factor)
+        k = apply_rope_leading(k, cos, sin, factor)
+    return q, k, v, z
+
+
+def _gqa_out(lp, o, z):
+    """``o`` [N, heads, hd] from the attention, ``z`` [N, heads] float32
+    -> the mixer's output: ``sigmoid(z_h) o_h`` a head (the product in
+    float32, rounded once), W_o."""
+    with jax.named_scope("head_gate"):
+        o = (o.astype(jnp.float32)
+             * jax.nn.sigmoid(z)[..., None]).astype(o.dtype)
+    return o.reshape(o.shape[0], -1) @ lp["wo"]
+
+
+def _ring_write(ring, names, row: int, new, ring_pos):
+    """A decode step's K and V rows ``new`` ([B, heads, w] each) into slot
+    ``ring_pos`` of the ring's leaves ``names`` at layer ordinal ``row``."""
+    for name, rows in zip(names, new):
+        ring[name] = jax.lax.dynamic_update_slice(
+            ring[name],
+            rows.transpose(1, 0, 2)[None, :, :, None, :].astype(
+                ring[name].dtype), (row, 0, 0, ring_pos, 0))
 
 
 def _ssm_in(c: ModelConfig, lp, x):
@@ -1250,11 +1364,7 @@ def _sparse_decode(c: ModelConfig, lp, x, ctx_kv, ring, kc, row: int,
     t = ctx_lens - 1
     with jax.named_scope("nope_attn"):
         q, k, v, z = _sparse_in(c, lp, x)
-        for name, new in (("k", k), ("v", v)):
-            ring[name] = jax.lax.dynamic_update_slice(
-                ring[name],
-                new.transpose(1, 0, 2)[None, :, :, None, :].astype(
-                    ring[name].dtype), (row, 0, 0, ring_pos, 0))
+        _ring_write(ring, ("k", "v"), row, (k, v), ring_pos)
         with jax.named_scope("sparse_compress"):
             kc = sparse_attention.compress_step(
                 g, ctx_kv["k"], ring["k"], kc, row, sp, t, ring_base, live)
@@ -1361,9 +1471,12 @@ def _mix_in(c: ModelConfig, kind: str, lp, h, pos):
     x = _norm(c, lp, "ln1", h)
     if kind == "gmu":
         return (x,)
-    if dims(c)["diff"] and kind in DIFF_KINDS:
+    form = _form(dims(c), kind)
+    if form == "diff":
         return (_diff_q(c, lp, x),) + (
             () if kind == "cross_attention" else _diff_kv(c, lp, x))
+    if form == "gqa":
+        return _gqa_in(c, kind, lp, x, pos)
     if kind == "attention":
         return _qkv(c, lp, x)
     if kind == "sparse_attention":
@@ -1419,10 +1532,13 @@ def _mix_out(c: ModelConfig, kind: str, lp, h, *seq):
     (h after the mixer, x = ln2 of it, picks, combine weights, the shared
     MLP of x[, whether a token kept a group held here]); where it does
     not, the dense MLP and its residual too: (h after the layer,)."""
+    form = _form(dims(c), kind)
     if kind == "gmu":
         mix = _gmu(lp, *seq)
-    elif dims(c)["diff"] and kind in DIFF_KINDS:
+    elif form == "diff":
         mix = _diff_out(c, lp, *seq)
+    elif form == "gqa":
+        mix = _gqa_out(lp, *seq)
     elif kind in ("attention", "latent_attention"):
         o, = seq
         mix = o.reshape(o.shape[0], -1) @ lp["wo"]
@@ -1538,9 +1654,11 @@ DIFF_SCOPES = {"attention": "full_diff_attn",
                "cross_attention": "cross_diff_attn"}
 
 
-def _diff_prefill(c, kind: str, ctx_kv, slots, q_starts, seq_lens, span: int,
+def _rows_prefill(c, kind: str, ctx_kv, slots, q_starts, seq_lens, span: int,
                   q, own, rows, window_rows):
-    """One differential attention layer over K chunks, from its
+    """One window, full or cross attention layer over K chunks, in the
+    differential form or the rotary GQA form (they differ in what happens
+    to q, k and v before and to o after, not here), from its
     in-projection's q [K, T, heads, w] and ``own`` = (k, v) [K, T, kvh, w]
     (() for a cross layer): o [K, T, heads, w]. ``rows`` / ``window_rows``
     are the (ks, vs) lists of the layers that keep full / window rows so
@@ -1653,7 +1771,7 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
     wks, wvs = [], []     # the window layers' rows
     m = logits = None     # the scan output the gated memory units read
     for l, (kind, lp) in enumerate(zip(d["kinds"], params["layers"])):
-        if kind == "gmu" or (d["diff"] and kind in DIFF_KINDS):
+        if kind == "gmu" or _form(d, kind) == "diff":
             # the row-wise halves as the looped form has them, around the
             # attention ops at pair-wide rows
             ins = _mix_in(c, kind, lp, h, None)
@@ -1670,12 +1788,20 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
                                 seq_lens, span, lanes(h), q, *own, lanes(m))
                 break
             with jax.named_scope(DIFF_SCOPES[kind]):
-                o = _diff_prefill(c, kind, ctx_kv, slots, q_starts, seq_lens,
+                o = _rows_prefill(c, kind, ctx_kv, slots, q_starts, seq_lens,
                                   span, q, own, (ks, vs), (wks, wvs))
             h, = _mix_out(c, kind, lp, h, o.reshape(K * T, *o.shape[2:]))
             continue
         x = _norm(c, lp, "ln1", h)
-        if kind == "attention":
+        if _form(d, kind) == "gqa":
+            with jax.named_scope(GQA_SCOPES[kind]):
+                q, k, v, z = _gqa_in(c, kind, lp, x,
+                                     positions.reshape(K * T))
+                o = _rows_prefill(c, kind, ctx_kv, slots, q_starts, seq_lens,
+                                  span, lanes(q), [lanes(k), lanes(v)],
+                                  (ks, vs), (wks, wvs))
+                mix = _gqa_out(lp, o.reshape(K * T, *o.shape[2:]), z)
+        elif kind == "attention":
             with jax.named_scope("nope_attn"):
                 q, k, v = (a.reshape(K, T, *a.shape[1:])
                            for a in _qkv(c, lp, x))
@@ -1895,7 +2021,7 @@ def _live_prefill(config, params, ctx_kv, tokens, slots, q_starts, seq_lens,
         ins = rowwise(_mix_in, kind, lp, h, positions)
         if kind == "gmu":
             seq = (ins[0], m)
-        elif d["diff"] and kind in DIFF_KINDS:
+        elif _form(d, kind) == "diff":
             q, *own = ins
             if _climbs_at(c, l):
                 # the rows stop here: the chunk's last real row climbs
@@ -1906,9 +2032,15 @@ def _live_prefill(config, params, ctx_kv, tokens, slots, q_starts, seq_lens,
                                 seq_lens, span, h, q, *own, m)
                 break
             with jax.named_scope(DIFF_SCOPES[kind]):
-                seq = (_diff_prefill(c, kind, ctx_kv, slots, q_starts,
+                seq = (_rows_prefill(c, kind, ctx_kv, slots, q_starts,
                                      seq_lens, span, q, own, (ks, vs),
                                      (wks, wvs)),)
+        elif _form(d, kind) == "gqa":
+            q, k, v, z = ins
+            with jax.named_scope(GQA_SCOPES[kind]):
+                seq = (_rows_prefill(c, kind, ctx_kv, slots, q_starts,
+                                     seq_lens, span, q, [k, v], (ks, vs),
+                                     (wks, wvs)), z)
         elif kind == "attention":
             q, k, v = ins
             with jax.named_scope("nope_attn"):
@@ -2069,36 +2201,38 @@ def decode_step_impl(config, params, ctx_kv, ring, state, tokens, ctx_lens,
         x = _norm(c, lp, "ln1", h)
         if kind == "gmu":
             mix = _gmu(lp, x, m)
-        elif d["diff"] and kind in DIFF_KINDS:
+        elif _form(d, kind):
             # the full layer's rows at its ordinal (a cross layer reads
             # the same rows, this step's in the ring already), a window
-            # layer's in its lane's modular buffer
+            # layer's in its lane's modular buffer; the differential and
+            # the rotary GQA form differ before and after the one read
+            diff = _form(d, kind) == "diff"
             win = kind == "window_attention"
             names = (WK, WV) if win else ("k", "v")
             row = wl if win else a if kind == "attention" else d["rows_row"]
-            with jax.named_scope(DIFF_SCOPES[kind]):
-                if kind != "cross_attention":
-                    for name, new in zip(names, _diff_kv(c, lp, x)):
-                        ring[name] = jax.lax.dynamic_update_slice(
-                            ring[name],
-                            new.transpose(1, 0, 2)[None, :, :, None, :].astype(
-                                ring[name].dtype), (row, 0, 0, ring_pos, 0))
+            with jax.named_scope((DIFF_SCOPES if diff else GQA_SCOPES)[kind]):
+                if diff:
+                    own = () if kind == "cross_attention" else _diff_kv(
+                        c, lp, x)
+                else:
+                    q, *own, z = _gqa_in(c, kind, lp, x,
+                                         jnp.maximum(ctx_lens - 1, 0))
+                _ring_write(ring, names, row, own, ring_pos)
+                if diff:   # after the write, as the traced text has it
+                    q = _diff_q(c, lp, x)
                 o = ctx_decode_attention(
-                    attn, _diff_q(c, lp, x), ctx_kv[names[0]],
+                    attn, q, ctx_kv[names[0]],
                     ctx_kv[names[1]], ring[names[0]], ring[names[1]],
                     jnp.int32(row), ctx_lens, ring_base, live=live,
-                    window=d["window"] if win else 0, name=DIFF_KERNEL)
-                mix = _diff_out(c, lp, o)
+                    window=d["window"] if win else 0,
+                    name=DIFF_KERNEL if diff else GQA_KERNELS[kind])
+                mix = _diff_out(c, lp, o) if diff else _gqa_out(lp, o, z)
             wl += win
             a += kind == "attention"
         elif kind == "attention":
             with jax.named_scope("nope_attn"):
                 q, k, v = _qkv(c, lp, x)
-                for name, new in (("k", k), ("v", v)):
-                    ring[name] = jax.lax.dynamic_update_slice(
-                        ring[name],
-                        new.transpose(1, 0, 2)[None, :, :, None, :].astype(
-                            ring[name].dtype), (a, 0, 0, ring_pos, 0))
+                _ring_write(ring, ("k", "v"), a, (k, v), ring_pos)
                 o = ctx_decode_attention(
                     attn, q, ctx_kv["k"], ctx_kv["v"], ring["k"], ring["v"],
                     jnp.int32(a), ctx_lens, ring_base, live=live)
@@ -2228,10 +2362,19 @@ def round_step(config, params, ctx_kv, ring, stepped, tokens, ctx_lens,
 # ---------------------------------------------------------------------------
 # What the engine is told of this state (llama.py: the block protocol)
 
+def _rows_only(config: ModelConfig) -> bool:
+    """A stack of full and window attention layers alone: no recurrent
+    leaf, but rows of a second length that no page of the pool holds."""
+    return set(dims(config)["kinds"]) <= set(GQA_KINDS) and bool(
+        dims(config)["n_win"])
+
+
 def state_called(config: ModelConfig) -> str:
     if sparse_layers(config)[0] is not None:
         return ("a recurrent (linear-attention) state and "
                 "compressed-key rows (kc)")
+    if _rows_only(config):
+        return "window rows (a modular buffer a lane, outside the pool)"
     return " beside ".join(
         ([mla_moe.state_called(config)] if config.mla is not None else [])
         + ["a recurrent (state-space) state"])
@@ -2240,6 +2383,10 @@ def state_called(config: ModelConfig) -> str:
 def transfer_refusal(config: ModelConfig) -> str:
     if config.mla is not None:
         return mla_moe.transfer_refusal(config)
+    if _rows_only(config):
+        return ("kv_transfer / disaggregation cannot carry window rows "
+                "yet: pages move K and V rows of the context's length, and "
+                "a prompt cannot resume without its lane's window buffers")
     return ("kv_transfer / disaggregation cannot carry a recurrent "
             "(state-space or linear-attention) state"
             + (" or compressed-key rows (kc)"
@@ -2257,7 +2404,9 @@ def page_multiple(config: ModelConfig) -> int:
 
 
 def pages_resume(config: ModelConfig) -> bool:
-    return False   # not without the recurrent state at their boundary
+    # not without the recurrent state (or the window buffers) at their
+    # boundary
+    return False
 
 
 def decode_mirror(config: ModelConfig, max_context: int, ring_len: int,
@@ -2284,6 +2433,10 @@ def decode_mirror(config: ModelConfig, max_context: int, ring_len: int,
         readers = 1 + d.get("n_cross", 0)
         W, window = d["window_rows"], d["window"]
         cb = dense_chunk_rows(W, attn.chunk)
+        # the query heads of all layers of a kind, where they are a layer's
+        # own (the rotary GQA form): what the score work follows
+        q_heads = {kind: sum(n for n, t in zip(d["heads"], d["kinds"])
+                             if t == kind) for kind in GQA_KINDS}
 
         def mirror(ctx_lens, live, n_steps: int):
             (_, read), own = dense(ctx_lens, live, n_steps)
@@ -2293,12 +2446,20 @@ def decode_mirror(config: ModelConfig, max_context: int, ring_len: int,
             in_buffer = (np.minimum(np.maximum(n - 1, 0), W)
                          if attn.impl != REFERENCE_IMPL
                          else np.full(len(ctx_lens), W))
-            return ((DECODE_ATTN_ROWS_READ[0], read), own,
-                    (ATTN_SHARED_ROWS_READ[0], readers * read),
-                    (ATTN_WINDOW_ROWS_READ[0], d["n_win"] * n_steps * int(
-                        region_trips(in_buffer, 1, cb).sum() * cb)),
-                    (ATTN_WINDOW_ROWS_BOUND[0], d["n_win"] * int(
-                        np.minimum(n[None, :] + steps, window).sum())))
+            out = ((DECODE_ATTN_ROWS_READ[0], read), own,
+                   (ATTN_WINDOW_ROWS_READ[0], d["n_win"] * n_steps * int(
+                       region_trips(in_buffer, 1, cb).sum() * cb)),
+                   (ATTN_WINDOW_ROWS_BOUND[0], d["n_win"] * int(
+                       np.minimum(n[None, :] + steps, window).sum())))
+            if "rows_from" in d:   # another layer's rows, read once a reader
+                out += ((ATTN_SHARED_ROWS_READ[0], readers * read),)
+            if d["rotary"]:
+                lane_steps = int(live.sum()) * n_steps
+                out += ((DECODE_ATTN_Q_ROWS_FULL[0],
+                         lane_steps * q_heads["attention"]),
+                        (DECODE_ATTN_Q_ROWS_WINDOW[0],
+                         lane_steps * q_heads["window_attention"]))
+            return out
         return mirror
 
     def mirror(ctx_lens, live, n_steps: int):

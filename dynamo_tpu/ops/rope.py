@@ -1,6 +1,8 @@
 """Rotary position embeddings (HF llama "rotate-half" convention, incl.
 llama3 frequency scaling), and YaRN as DeepSeek-V3's config.json
-parameterises it (the latent-attention block, models/mla_moe.py)."""
+parameterises it (the latent-attention block, models/mla_moe.py); and a
+rule a layer KIND for a stack whose kinds rotate differently
+(``kind_rotary``, ``apply_rope_leading``: models/ssm_moe.py)."""
 from __future__ import annotations
 
 import math
@@ -79,3 +81,36 @@ def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarra
     x1, x2 = x[..., :half], x[..., half:]
     rotated = jnp.concatenate([-x2, x1], axis=-1)
     return (x.astype(jnp.float32) * c + rotated.astype(jnp.float32) * s).astype(x.dtype)
+
+
+def kind_rotary(head_dim: int, rule: dict[str, Any]):
+    """One layer KIND's rotary rule (a stack whose kinds rotate
+    differently: models/ssm_moe.py) -> (inverse frequencies [rot / 2], the
+    factor on cos and sin). ``rule``: ``theta``, ``rot`` (the leading
+    dimensions of the head that rotate, rotate-half among themselves; the
+    rest pass through) and ``type``: ``default``, or ``yarn`` with
+    ``factor``, ``original_max_position_embeddings``, ``beta_fast``,
+    ``beta_slow`` and ``attention_factor`` (YaRN over the ROTATED
+    dimensions; its factor rides on cos and sin, so it scales the rotated
+    part of q and of k and not the part that passes through: with part of
+    the head rotated that is no factor on the softmax scale)."""
+    rot, theta = int(rule["rot"]), float(rule["theta"])
+    if rot % 2 or not 0 < rot <= head_dim:
+        raise ValueError(f"{rot} rotated dimensions of a head of {head_dim}")
+    if rule["type"] == "yarn":
+        return yarn_inv_freq(rot, theta, rule), float(rule["attention_factor"])
+    return rope_inv_freq(rot, theta), 1.0
+
+
+def apply_rope_leading(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray,
+                       factor: float = 1.0) -> jnp.ndarray:
+    """``apply_rope`` over the first ``cos.shape[-1]`` dimensions of the
+    head, cos and sin x ``factor``; the dimensions past them pass through.
+    x: [..., n_heads, head_dim]; cos / sin: [..., rot]."""
+    rot = cos.shape[-1]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
+    if rot == x.shape[-1]:
+        return apply_rope(x, cos, sin)
+    return jnp.concatenate(
+        [apply_rope(x[..., :rot], cos, sin), x[..., rot:]], axis=-1)

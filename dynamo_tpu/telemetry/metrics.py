@@ -560,6 +560,16 @@ ATTN_WINDOW_ROWS_BOUND = (
     "dynamo_attn_window_rows_bound",
     "window-attention models: rows the window admits in that round, "
     "min(context, window) a live lane a step, all window layers")
+DECODE_ATTN_Q_ROWS_FULL = (
+    "dynamo_decode_attn_q_rows_full",
+    "models whose layers differ in their number of query heads: "
+    "query-head rows the FULL attention layers' decode scored in a "
+    "dispatched round: live lanes x steps x the query heads of every such "
+    "layer (each row scores its lane's whole context)")
+DECODE_ATTN_Q_ROWS_WINDOW = (
+    "dynamo_decode_attn_q_rows_window",
+    "the same of the WINDOW attention layers (each row scores "
+    "min(context, window) keys)")
 PREFILL_LAYER_ROWS = (
     "dynamo_prefill_layer_rows",
     "models whose prefill stops a chunk's rows part-way up the stack: real "
@@ -615,6 +625,7 @@ def request_histograms(
                             KDA_STATE_ROWS_STEPPED, SSM_STATE_ROWS_STEPPED,
                             SSM_SCAN_POSITIONS, ATTN_SHARED_ROWS_READ,
                             ATTN_WINDOW_ROWS_READ, ATTN_WINDOW_ROWS_BOUND,
+                            DECODE_ATTN_Q_ROWS_FULL, DECODE_ATTN_Q_ROWS_WINDOW,
                             PREFILL_LAYER_ROWS, PREFILL_LAYER_ROWS_SKIPPED):
             reg.histogram(name, help_,
                           tuple(float(4 ** i) for i in range(3, 13)))

@@ -61,6 +61,7 @@ LONG_FILES = (
     "test_rehearsal_ragdoc_longprompt.py",
     "test_mamba1.py", "test_rehearsal_chat_rate.py",   # PR 51: ~3 min each
     "test_sambay.py",                                  # PR 54: ~2.5 min
+    "test_window_gqa_moe.py",                          # PR 58: ~75 s
 )
 
 

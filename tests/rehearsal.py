@@ -80,6 +80,11 @@ CELLS = {
     # or two in the window are what the CPU drains in time
     "thinklong": ("phi4-mini-flash.thinklong", "5400540054",
                   "benchmarks/references/sambay.py", 0.25),
+    # prompts of 512-16384 (one to four 4096-token chunks of the tiny
+    # model, ~2 s each on the CPU): two requests in the 10 s pre-roll and
+    # one in the window
+    "codeturn": ("laguna-s-ep8-d12.codeturn", "5800580058",
+                 "benchmarks/references/window_gqa_moe.py", 0.2),
 }
 
 
@@ -111,7 +116,11 @@ def rehearse(tmp_path, cell, seed, reference, rate_rps):
     Mamba-1 state, its window buffers (8 rows a lane: wrapped a thousand
     times), layer 5's rows and the gated memory unit's m over two chunk
     boundaries at 4096, with only each chunk's last row above layer 5, and
-    its decode crosses multiples of the buffer's length in every round."""
+    its decode crosses multiples of the buffer's length in every round. The
+    coding-turn cell's check carries the toy model's window buffers (8 rows
+    a lane) and its three full layers' rows over two chunk boundaries at
+    4096 at 18 and 12 query heads over two K/V heads, rotary by kind, under
+    the one-group router with a share of eight."""
     root = REPO if rate_rps is None else checkout_at_rate(
         tmp_path, cell, rate_rps)
     r = run([sys.executable, "benchmarks/run.py", "--workload", cell,

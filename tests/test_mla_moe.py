@@ -570,7 +570,8 @@ def test_from_hf_dict_reads_the_published_keys_and_refuses_the_unknown():
         "intermediate_size": 8, "num_hidden_layers": 1,
         "num_attention_heads": 2, "sliding_window": None}).mla is None
     with pytest.raises(ValueError, match="no block this program builds"):
-        ModelConfig.from_hf_dict({"model_type": "laguna", "vocab_size": 8})
+        ModelConfig.from_hf_dict({"model_type": "no_such_block",
+                                  "vocab_size": 8})
     with pytest.raises(ValueError, match="refusing to read it as a Llama"):
         ModelConfig.from_hf_dict({
             "model_type": "llama", "vocab_size": 8, "hidden_size": 8,

@@ -38,6 +38,7 @@ FAMILIES = {
     "kda_latent": ModelConfig.tiny_kda_latent,
     "mamba1": ModelConfig.tiny_jamba,
     "differential": ModelConfig.tiny_phi4flash,
+    "rotary_gqa": ModelConfig.tiny_laguna,
 }
 
 
@@ -145,6 +146,11 @@ def test_the_counter_row_is_declared(family):
         # one dense MLP a layer: routes nothing; its step kernel counts
         "mamba1": [tmetrics.SSM_STATE_ROWS_STEPPED[0]],
         "differential": [tmetrics.SSM_STATE_ROWS_STEPPED[0]],
+        # the one-group sigmoid router with a share; no recurrent leaf
+        "rotary_gqa": [tmetrics.MOE_TOUCHED[0], tmetrics.MOE_ROUTED[0],
+                       tmetrics.MOE_LOAD_MAX[0],
+                       tmetrics.MOE_PICKS_ROUTED[0],
+                       tmetrics.MOE_GROUPS_KEPT_HERE[0]],
     }[family]
     assert [k.f32_bits for k in layout if k.metric ==
             tmetrics.HC_SINKHORN_RESIDUAL[0]] == [True] * (
@@ -183,6 +189,18 @@ def test_what_the_state_says_of_itself(family):
             tmetrics.ATTN_SHARED_ROWS_READ[0]: 2 * 4 * 6 * 128,
             tmetrics.ATTN_WINDOW_ROWS_READ[0]: 2 * 4 * 6 * 8,
             tmetrics.ATTN_WINDOW_ROWS_BOUND[0]: 2 * 4 * 2 * 8}
+    if family == "rotary_gqa":
+        # its TWO full layers' rows a layer, the four window layers' 8-row
+        # buffers, no rows shared with a cross layer, and the query-head
+        # rows of each kind: 2 live lanes x 4 steps x (12 + 12 | 4 x 18)
+        assert called.startswith("window rows") and "window rows" in why
+        assert seen == {
+            tmetrics.DECODE_ATTN_ROWS_READ[0]: 4 * 6 * 128,
+            tmetrics.DECODE_ATTN_ROWS_LIVE[0]: 4 * (40 + 16),
+            tmetrics.ATTN_WINDOW_ROWS_READ[0]: 4 * 4 * 6 * 8,
+            tmetrics.ATTN_WINDOW_ROWS_BOUND[0]: 4 * 4 * 2 * 8,
+            tmetrics.DECODE_ATTN_Q_ROWS_FULL[0]: 2 * 4 * 24,
+            tmetrics.DECODE_ATTN_Q_ROWS_WINDOW[0]: 2 * 4 * 72}
     assert (prefill is not None) == (family in (
         "lightning_sparse", "mamba1", "differential"))
 

@@ -123,6 +123,7 @@ def test_registry_render_and_snapshot():
         "dynamo_ssm_state_rows_stepped", "dynamo_ssm_scan_positions",
         "dynamo_attn_shared_rows_read", "dynamo_attn_window_rows_read",
         "dynamo_attn_window_rows_bound", "dynamo_prefill_layer_rows",
+        "dynamo_decode_attn_q_rows_full", "dynamo_decode_attn_q_rows_window",
         "dynamo_prefill_layer_rows_not_climbed",
         "dynamo_request_tpot_seconds",
         "dynamo_engine_step_gap_seconds",
