@@ -109,6 +109,13 @@ log = logging.getLogger(__name__)
 
 _FIRST_TOKEN_KEY_TAG = 0x46697273  # distinct PRNG stream for first tokens
 
+# columns of the packed uint32 row a lane of ``admit_first`` (floats ride
+# as their bits; the two keys take two columns each)
+(_ROW_SLOT, _ROW_CTX, _ROW_ADAPTER, _ROW_TOP_K, _ROW_TEMP, _ROW_TOP_P,
+ _ROW_FREQ, _ROW_PRES, _ROW_REP, _ROW_KEY, _ROW_STEP_KEY) = (
+    0, 1, 2, 3, 4, 5, 6, 7, 8, 9, 11)
+_ROW_W = 13
+
 # per-request trace spans shipped back in the finishing annotation are
 # capped (a 10k-token generation must not grow a 10k-entry span list);
 # the total decode-round count still travels in the timing annotation
@@ -273,13 +280,15 @@ class _Entry:
     # round:
     slots: list[Optional[_Request]] = field(default_factory=list)  # snapshot
     n_steps: int = 0
-    # first:
+    # first: the request, and its row of the dispatch's shared handles
+    # (admit_first returns one token and one packed logprob row a lane)
     request: Optional[_Request] = None
+    row: int = 0
     # offload: hashes/parents aligned with the gathered pages
     hashes: list[int] = field(default_factory=list)
     parents: list[int] = field(default_factory=list)
     # logprobs: ONE packed f32 handle — [F, B, 1+2K] for rounds,
-    # [1, 1+2K] for "first" entries (chosen | top ids as f32 | top lps;
+    # [K, 1+2N] for "first" entries (chosen | top ids as f32 | top lps;
     # see _build_jits.pack_lp / _unpack_lp)
     lp_handle: Optional[Any] = None
     # spec verify: (slot, request, history-length-at-dispatch) per live
@@ -639,7 +648,7 @@ class TpuEngine:
         self._slots: list[Optional[_Request]] = [None] * B
         # slots reserved by an in-progress (multi-chunk) prefill: occupied
         # but NOT decoding — their dev lane stays parked on scratch until
-        # the admission patch
+        # admit_first admits them
         self._prefilling: dict[int, _Request] = {}
         # host mirror of dispatch-time context lengths
         self._ctx_disp = np.ones(B, np.int32)
@@ -771,7 +780,7 @@ class TpuEngine:
         self.dispatch_counts: dict[str, int] = {
             "round": 0, "round_seal": 0, "seal": 0, "patch": 0,
             "prefill": 0, "prefill_batch": 0, "sp_prefill": 0,
-            "load_ctx": 0, "sample_first": 0, "fetch": 0, "encode": 0,
+            "load_ctx": 0, "admit_first": 0, "fetch": 0, "encode": 0,
             "offload_gather": 0, "xfer_gather": 0, "xfer_scatter": 0,
             # speculative path: the fused batch-draft and verify
             # programs
@@ -954,12 +963,14 @@ class TpuEngine:
         @functools.partial(jax.jit, donate_argnums=(0,))
         def patch(dev, clear_mask, admit_meta, admit_tok, admit_keys,
                   admit_counts):
-            """State patch (releases + one admission). ``admit_meta`` is
-            ONE packed f32[9] row — [slot, ctx, temp, top_k, top_p, freq,
-            pres, rep, adapter] — instead of eleven scalar device_puts
-            per admission (every int here is exact in f32; ctx and
-            adapter ids < 2^24). slot == B is the no-admission sentinel:
-            every .at[] update is dropped."""
+            """State patch: releases, and the one admission that brings
+            its own token and histogram (a slot despeculating back to the
+            fused round; a prompt's admission rides ``admit_first``).
+            ``admit_meta`` is ONE packed f32[9] row — [slot, ctx, temp,
+            top_k, top_p, freq, pres, rep, adapter] — instead of eleven
+            scalar device_puts per admission (every int here is exact in
+            f32; ctx and adapter ids < 2^24). slot == B is the
+            no-admission sentinel: every .at[] update is dropped."""
             B = dev["tokens"].shape[0]
             dev = dict(dev)
             dev["ctx"] = jnp.where(clear_mask, 1, dev["ctx"])
@@ -993,26 +1004,67 @@ class TpuEngine:
             )
             return dev
 
-        @functools.partial(jax.jit, static_argnums=(5, 6))
-        def sample_first(logits, key, temp, top_k, top_p, vocab, want_lp):
+        @functools.partial(jax.jit, donate_argnums=(0,),
+                           static_argnums=(3,))
+        def admit_first(dev, logits, rows, want_lp):
+            """The first token of every request that finished its prompt
+            with ONE prefill dispatch, and their admissions: the
+            prefill's logits as it returned them (``[K, V]``, or ``[V]``
+            from a solo chunk) and ONE packed ``uint32[K, _ROW_W]``
+            upload, a row a lane (``_first_row``). Each row samples with
+            its own key (zero counts, no penalties on a first token: what
+            a fused step does to a fresh histogram) and is admitted as
+            ``patch`` admits: token, ctx, dest, keys, a zero counts row,
+            the six sampling scalars, adapter. A row whose slot is B (a
+            lane that continues, a dummy, a speculative admission, which
+            stays parked) samples and admits nothing: every .at[] update
+            is dropped."""
+            K = rows.shape[0]
+            logits = logits.reshape(K, -1)
+
+            def i32(col):
+                return jax.lax.bitcast_convert_type(rows[:, col], jnp.int32)
+
+            def f32(col):
+                return jax.lax.bitcast_convert_type(rows[:, col], jnp.float32)
+
+            temp, top_k, top_p = f32(_ROW_TEMP), i32(_ROW_TOP_K), f32(_ROW_TOP_P)
             st = sampling.SamplerState(
-                keys=key[None], counts=jnp.zeros((1, vocab), jnp.int32)
+                keys=rows[:, _ROW_KEY:_ROW_KEY + 2],
+                counts=jnp.zeros(logits.shape, jnp.int32),
             )
             sp = sampling.SamplingParams(
-                temperature=temp[None], top_k=top_k[None], top_p=top_p[None],
-                frequency_penalty=jnp.zeros(1), presence_penalty=jnp.zeros(1),
-                repetition_penalty=jnp.ones(1),
+                temperature=temp, top_k=top_k, top_p=top_p,
+                frequency_penalty=jnp.zeros(K), presence_penalty=jnp.zeros(K),
+                repetition_penalty=jnp.ones(K),
             )
-            toks, _ = sampling.sample_step_impl(logits[None], st, sp, max_top_k)
+            toks, _ = sampling.sample_step_impl(logits, st, sp, max_top_k)
             lp = (pack_lp(*sampling.compute_logprobs(
-                      logits[None], toks, max_logprobs))
+                      logits, toks, max_logprobs))
                   if want_lp else None)
-            return toks, lp  # [1] i32, optional packed [1, 1+2K] f32
+            s = i32(_ROW_SLOT)
+            dev = dict(
+                dev,
+                tokens=dev["tokens"].at[s].set(toks),
+                ctx=dev["ctx"].at[s].set(i32(_ROW_CTX)),
+                dest=dev["dest"].at[s].set(s),
+                keys=dev["keys"].at[s].set(
+                    rows[:, _ROW_STEP_KEY:_ROW_STEP_KEY + 2]),
+                counts=dev["counts"].at[s].set(0),
+                temp=dev["temp"].at[s].set(temp),
+                top_k=dev["top_k"].at[s].set(top_k),
+                top_p=dev["top_p"].at[s].set(top_p),
+                freq=dev["freq"].at[s].set(f32(_ROW_FREQ)),
+                pres=dev["pres"].at[s].set(f32(_ROW_PRES)),
+                rep=dev["rep"].at[s].set(f32(_ROW_REP)),
+                adapter=dev["adapter"].at[s].set(i32(_ROW_ADAPTER)),
+            )
+            return dev, toks, lp  # [K] i32, optional packed [K, 1+2N] f32
 
         self._engine_round = engine_round
         self._engine_round_seal = engine_round_seal
         self._patch = patch
-        self._sample_first = sample_first
+        self._admit_first = admit_first
         # reusable zero counts row for ordinary admissions (no per-patch
         # [V]-sized H2D upload) + the no-admission token placeholder
         self._zero_counts = jnp.zeros(c.vocab_size, jnp.int32)
@@ -2512,14 +2564,10 @@ class TpuEngine:
         admit: Optional[dict[str, Any]] = None,
     ) -> None:
         if self.on_dispatch is not None:
-            a = dict(admit or {})
-            a.pop("tok", None)  # followers use their own sample_first result
-            a.pop("counts", None)  # spec-only (spec is rejected multihost)
-            if "keys" in a:
-                a["keys"] = np.asarray(a["keys"]).tolist()
-            self.on_dispatch("patch", {
-                "clear_slots": list(clear_slots), "admit": a,
-            })
+            # a follower replays releases only: the one admission left
+            # here is a despeculating slot's, and spec is rejected
+            # multihost
+            self.on_dispatch("patch", {"clear_slots": list(clear_slots)})
         B = self._B
         clear = np.zeros(B, bool)
         for s in clear_slots:
@@ -3732,16 +3780,17 @@ class TpuEngine:
             dispatch_ms=round((time.monotonic() - t_disp) * 1e3, 3),
         )
         self.prof.enter(_SEG_ADMIT_FIRST)
-        done: list[_Request] = []
+        done: list[tuple[int, _Request]] = []
         for i, r in enumerate(group):
             r.prefill_chunks += 1
             r.prefill_pos = int(q_starts[i]) + chunk_lens[i]
             if r.prefill_pos < len(r.tokens):
                 self._seal_prefilled(r)  # mid-prompt blocks seal per chunk
                 continue  # multi-chunk: next chunk in a later round
-            if self._finish_prefill(r, logits[i], index=i) == "done":
-                done.append(r)
-        return done
+            done.append((i, r))
+        if done:
+            self._finish_prefill(logits, done, K)
+        return [r for _, r in done]
 
     def _note_prefill_dispatch(self, real: int, padded: int) -> None:
         """The books of one prefill program about to be dispatched: the
@@ -4012,7 +4061,7 @@ class TpuEngine:
             self._seal_prefilled(r)
             return "progress"  # decode rounds run before the next chunk
 
-        return self._finish_prefill(r, logits)
+        return self._finish_prefill(logits, [(0, r)])
 
     def _sp_prefill_full(self, r: _Request) -> str:
         """Whole-prompt sequence-parallel ring prefill (ops/
@@ -4064,13 +4113,51 @@ class TpuEngine:
         r.prefill_pos = len(prompt)
         r.matched_blocks = 0
         self.sp_prefills += 1
-        return self._finish_prefill(r, logits)
+        return self._finish_prefill(logits, [(0, r)])
 
-    def _finish_prefill(self, r: _Request, logits, index: int = None) -> str:
-        """Shared prefill tail: commit prompt blocks, sample the first
-        token on device, activate the slot. `index` is the request's row
-        when `logits` was sliced from a batched prefill — broadcast so
-        followers slice their own replayed [K, V] logits identically.
+    def _finish_prefill(self, logits, lanes: list[tuple[int, _Request]],
+                        K: int = 1) -> str:
+        """Shared prefill tail of ONE prefill dispatch: `lanes` are the
+        (row of `logits`, request) pairs whose prompts it completed, `K`
+        the dispatch's lanes. Commits their prompt blocks, activates
+        their slots, then ONE program and ONE upload sample every first
+        token and admit every slot (``admit_first``); a follower replays
+        the one event on its own logits (same keys, same tokens)."""
+        rows = np.zeros((K, _ROW_W), np.uint32)
+        rows[:, _ROW_SLOT] = self._B  # not finishing here: nothing admitted
+        for i, r in lanes:
+            rows[i] = self._first_row(r)
+        want_lp = any(r.req.output_options.logprobs is not None
+                      for _, r in lanes)
+        if self.on_dispatch is not None:
+            self.on_dispatch("admit_first", {
+                "rows": rows.tolist(), "want_lp": want_lp,
+            })
+        self.dispatch_counts["admit_first"] += 1
+        self._dev, first_toks, first_lps = self._admit_first(
+            self._dev, logits, jnp.asarray(rows), want_lp)
+        # first tokens reach their clients via the async fetch pipeline:
+        # one fetch an output a dispatch, shared by the lanes' entries
+        first_toks.copy_to_host_async()
+        self.dispatch_counts["fetch"] += 1
+        if want_lp:
+            first_lps.copy_to_host_async()  # packed: one fetch
+            self.dispatch_counts["fetch"] += 1
+        for i, r in lanes:
+            self._track(_Entry(
+                kind="first", handle=first_toks, request=r, row=i,
+                lp_handle=(first_lps
+                           if r.req.output_options.logprobs is not None
+                           else None),
+            ))
+        return "done"
+
+    def _first_row(self, r: _Request) -> np.ndarray:
+        """Everything the HOST does for a request whose prompt is
+        complete (commit its blocks, draw its keys, activate its slot)
+        and its row of ``admit_first``'s upload. A speculative admission's
+        row names slot B: its first token is sampled, its lane stays
+        parked.
 
         The ``prefill`` span recorded here ends when the last prefill
         program was DISPATCHED: it is host dispatch time, not device
@@ -4103,26 +4190,6 @@ class TpuEngine:
                 [_FIRST_TOKEN_KEY_TAG ^ int(nonce[0]), int(nonce[1])], np.uint32
             )
             step_keys = nonce
-        want_lp = r.req.output_options.logprobs is not None
-        if self.on_dispatch is not None:
-            self.on_dispatch("sample_first", {
-                "key": first_key.tolist(),
-                "temp": float(so.temperature or 0.0),
-                "top_k": int(so.top_k or 0),
-                "top_p": float(so.top_p if so.top_p is not None else 1.0),
-                "want_lp": want_lp,
-                "index": index,
-            })
-        self.dispatch_counts["sample_first"] += 1
-        first_tok, first_lp = self._sample_first(
-            logits,
-            jnp.asarray(first_key),
-            jnp.float32(so.temperature or 0.0),
-            jnp.int32(so.top_k or 0),
-            jnp.float32(so.top_p if so.top_p is not None else 1.0),
-            self.config.vocab_size,
-            want_lp,
-        )
 
         slot = r.slot
         del self._prefilling[slot]
@@ -4149,33 +4216,25 @@ class TpuEngine:
                 r.spec_counts = np.zeros(
                     self.config.vocab_size, np.int32
                 )
+            slot = self._B
         else:
             self._slot_on(slot, r)
-            self._dispatch_patch(
-                admit=dict(
-                    slot=slot,
-                    ctx=len(prompt) + 1,
-                    tok=first_tok,
-                    keys=step_keys,
-                    temp=so.temperature or 0.0,
-                    top_k=so.top_k or 0,
-                    top_p=so.top_p if so.top_p is not None else 1.0,
-                    freq=so.frequency_penalty or 0.0,
-                    pres=so.presence_penalty or 0.0,
-                    rep=so.repetition_penalty or 1.0,
-                    adapter=r.adapter_id,
-                ),
-            )
-        # first token reaches the client via the async fetch pipeline
-        first_tok.copy_to_host_async()
-        self.dispatch_counts["fetch"] += 1
-        if first_lp is not None:
-            first_lp.copy_to_host_async()  # packed: one fetch
-            self.dispatch_counts["fetch"] += 1
-        self._track(_Entry(
-            kind="first", handle=first_tok, request=r, lp_handle=first_lp
-        ))
-        return "done"
+        row = np.empty(_ROW_W, np.uint32)
+        # ints and floats as their bits: no scalar is converted by a
+        # device program, admit_first bitcasts them back
+        row[_ROW_SLOT:_ROW_TOP_K + 1] = np.array([
+            slot, len(prompt) + 1, r.adapter_id, so.top_k or 0,
+        ], np.int32).view(np.uint32)
+        row[_ROW_TEMP:_ROW_REP + 1] = np.array([
+            so.temperature or 0.0,
+            so.top_p if so.top_p is not None else 1.0,
+            so.frequency_penalty or 0.0,
+            so.presence_penalty or 0.0,
+            so.repetition_penalty or 1.0,
+        ], np.float32).view(np.uint32)
+        row[_ROW_KEY:_ROW_KEY + 2] = first_key
+        row[_ROW_STEP_KEY:_ROW_STEP_KEY + 2] = step_keys
+        return row
 
     # ---- processing side (lagged results) ----
 
@@ -4230,10 +4289,10 @@ class TpuEngine:
             lp = None
             if entry.lp_handle is not None:
                 chosen, ids, lps = self._unpack_lp(
-                    np.asarray(entry.lp_handle)[0]
+                    np.asarray(entry.lp_handle)[entry.row]
                 )
                 lp = (float(chosen), ids, lps)
-            self._process_first(entry.request, int(data[0]), lp)
+            self._process_first(entry.request, int(data[entry.row]), lp)
         elif entry.kind == "offload":
             scales = (
                 np.asarray(entry.aux)[:, :, : entry.n_steps]

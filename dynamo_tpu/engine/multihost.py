@@ -232,7 +232,7 @@ class Follower:
     # round/round_seal below and "patch" counts inside _dispatch_patch)
     _OP_BUCKETS = {
         "prefill": "prefill", "prefill_batch": "prefill_batch",
-        "sample_first": "sample_first", "sp_prefill": "sp_prefill",
+        "admit_first": "admit_first", "sp_prefill": "sp_prefill",
         "load_ctx": "load_ctx", "seal": "seal",
     }
 
@@ -265,16 +265,7 @@ class Follower:
                 )
                 eng.ctx, eng.ring, eng._dev = out[0], out[1], out[2]
         elif op == "patch":
-            admit = dict(cmd.get("admit") or {})
-            if admit:
-                # the admitted first token is this host's own sample_first
-                # replay result (same program + key -> same token)
-                admit["tok"] = eng._mh_last_first_tok
-                admit["keys"] = np.asarray(admit["keys"], np.uint32)
-            eng._dispatch_patch(
-                clear_slots=cmd.get("clear_slots") or [],
-                admit=admit or None,
-            )
+            eng._dispatch_patch(clear_slots=cmd.get("clear_slots") or [])
         elif op == "prefill":
             from dynamo_tpu.models import llama
 
@@ -300,20 +291,15 @@ class Follower:
                 jnp.asarray(np.asarray(
                     cmd.get("adapter_ids", [0] * k), np.int32)),
             )
-        elif op == "sample_first":
-            logits = eng._mh_last_logits
-            if cmd.get("index") is not None:
-                logits = logits[cmd["index"]]
-            toks, _lp = eng._sample_first(
-                logits,
-                jnp.asarray(np.asarray(cmd["key"], np.uint32)),
-                jnp.float32(cmd["temp"]),
-                jnp.int32(cmd["top_k"]),
-                jnp.float32(cmd["top_p"]),
-                eng.config.vocab_size,
+        elif op == "admit_first":
+            # the leader's one program a prefill dispatch, on this host's
+            # own replayed logits: same rows (keys among them) -> the same
+            # first tokens admitted
+            eng._dev, _toks, _lps = eng._admit_first(
+                eng._dev, eng._mh_last_logits,
+                jnp.asarray(np.asarray(cmd["rows"], np.uint32)),
                 cmd["want_lp"],
             )
-            eng._mh_last_first_tok = toks
         elif op == "sp_prefill":
             from dynamo_tpu.models import llama
             from dynamo_tpu.ops.ring_attention import sp_shard

@@ -68,7 +68,7 @@ SEGMENTS = (
     "admit",          # _admit: waiting scan, shedding, lane + prefix match
     "admit_pack",     # a prefill dispatch's host assembly: rows, mirrors
     "admit_launch",   # its jnp.asarray uploads + the prefill program call
-    "admit_first",    # _finish_prefill: first-token sample, patch, seals
+    "admit_first",    # _finish_prefill: seals + ONE admit_first program a dispatch
     "seal_assembly",  # _take_seal_batch: seal-batch packing
     "dispatch",       # _dispatch_round: fused-round program launch
     "spec_dispatch",  # _dispatch_spec: draft + verify launches

@@ -23,6 +23,7 @@ from dynamo_tpu.protocols.common import (
     PreprocessedRequest,
     StopConditions,
 )
+from tests.test_admit_first import _Held
 
 PS = 16
 
@@ -87,7 +88,7 @@ async def _steady_window_budget(adapter_ids=None, setup=None, **kw):
     assert delta["seal"] == 0, delta          # seals fused, not standalone
     assert delta["patch"] == 0, delta         # no admissions/releases
     assert delta["prefill"] == 0 and delta["prefill_batch"] == 0, delta
-    assert delta["load_ctx"] == 0 and delta["sample_first"] == 0, delta
+    assert delta["load_ctx"] == 0 and delta["admit_first"] == 0, delta
     total = sum(delta.values())
     # 1 program + 1 fetch per round; the snapshot can land between a
     # round's program and fetch increments, so allow one straggler
@@ -293,3 +294,161 @@ async def test_fused_seal_round_matches_standalone_pin():
     # engine's pool-reading gathers forced standalone flushes is
     # timing-dependent; token identity above is the invariant)
     assert outs["fused"][1]["round_seal"] >= 1
+
+
+# ---------------------------------------------------------------------------
+# the first-token budget: ONE program and ONE upload a prefill dispatch
+
+class _FirstTokenLedger:
+    """Wraps ``eng._finish_prefill``: for every call, the buckets of
+    ``dispatch_counts`` it moved, the host arrays it uploaded
+    (``jnp.asarray`` of a numpy array) and the first tokens it carried;
+    ``dest`` is the device's after the last call."""
+
+    def __init__(self, eng, monkeypatch):
+        import jax.numpy as jnp
+
+        self.calls = []
+        self._inside = False
+        self._uploads = 0
+        real_asarray = jnp.asarray
+        real_finish = eng._finish_prefill
+
+        def asarray(a, *args, **kw):
+            if self._inside and isinstance(a, np.ndarray):
+                self._uploads += 1
+            return real_asarray(a, *args, **kw)
+
+        def finish(logits, lanes, *args, **kw):
+            before = dict(eng.dispatch_counts)
+            self._inside, self._uploads = True, 0
+            try:
+                return real_finish(logits, lanes, *args, **kw)
+            finally:
+                self._inside = False
+                moved = {k: v - before[k]
+                         for k, v in eng.dispatch_counts.items()
+                         if v != before[k]}
+                self.calls.append((moved, self._uploads, len(lanes)))
+                # on the engine's thread: nothing donates dev meanwhile
+                self.dest = np.asarray(eng._dev["dest"])
+
+        monkeypatch.setattr(jnp, "asarray", asarray)
+        eng._finish_prefill = finish
+
+
+async def _first_token_window(monkeypatch, prompt_lens, spec=False, **kw):
+    """Serve one held prompt, then ``prompt_lens`` arriving together;
+    returns (the ledger's calls for that wave, the dispatch_counts delta
+    from its submission to every request's first token, the engine, the
+    device's ``dest`` after the last such dispatch)."""
+    sink = _Held()
+    base = dict(
+        num_pages=128, page_size=PS, max_pages_per_seq=16,
+        max_decode_slots=8, prefill_buckets=(64,),
+        prefill_chunks_per_round=8, cache_dtype="float32",
+    )
+    base.update(kw)
+    eng = TpuEngine(ModelConfig.tiny(dtype="float32"), EngineConfig(**base),
+                    mesh_config=MeshConfig(tp=1),
+                    on_dispatch=None if spec else sink)
+    rng = np.random.RandomState(7)
+    pat = rng.randint(1, 256, 8).tolist()
+    progress = {}
+
+    def req(n):
+        # a repetitive prompt is what the n-gram proposer speculates on
+        toks = (pat * 16)[:n] if spec else rng.randint(1, 256, n).tolist()
+        return PreprocessedRequest(
+            token_ids=toks, model=f"m:{len(progress)}:{n}",
+            stop_conditions=StopConditions(max_tokens=48, ignore_eos=True))
+
+    async def one(key, n):
+        progress[key] = 0
+        async for out in eng.generate(req(n)):
+            progress[key] += len(out.token_ids)
+
+    try:
+        # a first wave of the same shapes compiles every program the
+        # measured wave runs (a trace would upload constants of its own)
+        await asyncio.gather(*[one(("warm", i), n)
+                               for i, n in enumerate(prompt_lens)])
+        ledger = _FirstTokenLedger(eng, monkeypatch)
+        if spec:
+            # the warm wave's release patches land a round after its end
+            d0 = None
+            while d0 != dict(eng.dispatch_counts):
+                d0 = dict(eng.dispatch_counts)
+                await asyncio.sleep(0.1)
+            tasks = [asyncio.ensure_future(one(("w", 0), prompt_lens[0]))]
+        else:
+            sink.armed = True
+            held = asyncio.ensure_future(one(("held", 0), 20))
+            while not sink.entered.is_set():
+                await asyncio.sleep(0.005)
+            d0 = dict(eng.dispatch_counts)
+            tasks = [asyncio.ensure_future(one(("w", i), n))
+                     for i, n in enumerate(prompt_lens)]
+            while eng._intake.qsize() < len(prompt_lens):
+                await asyncio.sleep(0.005)
+            sink.release.set()
+            tasks.append(held)
+        while not all(progress.get(("w", i), 0) >= 1
+                      for i in range(len(prompt_lens))):
+            await asyncio.sleep(0.005)
+        d1 = dict(eng.dispatch_counts)
+        await asyncio.gather(*tasks)
+    finally:
+        sink.release.set()
+        await eng.stop()
+    delta = {k: d1[k] - d0[k] for k in d1}
+    # the held prompt's own dispatch stands first in the ledger
+    calls = ledger.calls if spec else ledger.calls[1:]
+    return calls, delta, eng, ledger.dest
+
+
+@pytest.mark.parametrize("name,prompt_lens,dispatches", [
+    # one prompt, one chunk: its own prefill, then ONE admit_first
+    ("solo", (40,), [1]),
+    # 100 tokens in chunks of 64: the first chunk finishes nothing and
+    # launches nothing; the LAST chunk's dispatch carries the first token
+    ("chunked_last_chunk", (100,), [1]),
+    # four prompts that finish with one [4, 64] dispatch: ONE program
+    ("group_of_4", (40, 41, 42, 43), [4]),
+    # a group of two in which one lane continues: the finishing lane's
+    # first token rides the group's dispatch (the other row names slot
+    # B), the continuing lane's rides its own last chunk's
+    ("group_one_lane_continues", (40, 100), [1, 1]),
+])
+async def test_first_token_costs_one_program_and_one_upload(
+        monkeypatch, name, prompt_lens, dispatches):
+    """Beyond the prefill's own launch, every request that completes its
+    prompt with a prefill dispatch is sampled AND admitted by ONE program
+    fed by ONE upload, with one token fetch a dispatch: launches a first
+    token = (admit_first + patch) / first tokens <= 1, 1 / K for a
+    group."""
+    calls, delta, eng, _ = await _first_token_window(
+        monkeypatch, prompt_lens)
+    assert [n for _, _, n in calls] == dispatches, calls
+    for moved, uploads, _ in calls:
+        assert moved == {"admit_first": 1, "fetch": 1}, calls
+        assert uploads == 1, calls
+    first_tokens = len(prompt_lens) + 1     # the held prompt's too
+    assert delta["patch"] == 0, delta       # no admission patches
+    assert delta["admit_first"] == len(dispatches) + 1, delta
+    assert (delta["admit_first"] + delta["patch"]) <= first_tokens, delta
+    assert "sample_first" not in eng.dispatch_counts
+
+
+async def test_speculative_admission_rides_admit_first_and_stays_parked(
+        monkeypatch):
+    """A speculative admission's first token is sampled by the same one
+    program; its row names the no-admission slot, so the device lane
+    stays parked on the scratch lane and no patch is launched."""
+    calls, delta, eng, dest = await _first_token_window(
+        monkeypatch, (32,), spec=True,
+        speculative="ngram", num_speculative_tokens=4, spec_adaptive=False)
+    assert calls == [({"admit_first": 1, "fetch": 1}, 1, 1)], calls
+    assert delta["admit_first"] == 1 and delta["patch"] == 0, delta
+    assert delta["spec_verify"] >= 1, delta   # it did speculate
+    assert (dest == eng._B).all(), dest     # every lane parked

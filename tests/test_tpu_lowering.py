@@ -269,6 +269,24 @@ def test_kv_movers_copy_no_region_on_v5e(mover_records, program):
     assert rec["temp_bytes"] < 0.05 * rec["region_bytes"], rec
 
 
+@pytest.mark.parametrize("config", ["nemo12b-tp4", "jamba2-3b"])
+def test_admit_first_compiles_for_the_v5e_in_place(config):
+    """The one program a prefill dispatch (every first token sampled, every
+    slot admitted: ``TpuEngine._build_jits.admit_first``) at a cell's own
+    vocabulary and slots, the logits' vocabulary over tp = 4 in the
+    four-chip cell: XLA:TPU takes it, the donated ``dev`` is updated in
+    place (the [B, V] histogram is not copied) and its temporaries stay
+    about the K rows of logits it samples."""
+    _v5e_or_skip()
+    (rec,) = tpu_compile_check.compile_programs(
+        config=config, programs=("admit_first",))
+    assert rec["ok"], rec.get("error")
+    assert rec["program"] == "admit_first_K2"
+    assert rec["mosaic_calls"] == 0
+    assert rec["alias_gb"] == rec["output_gb"] > 0, rec
+    assert rec["temp_bytes"] < 4e6, rec
+
+
 # the two latent cells' regions: layers, lanes, context (rows stored at 640)
 LATENT_REGIONS = {"xing4-mhc-d7": (7, 16, 16384),
                   "mla-moe-joyai-d5": (5, 64, 4096)}

@@ -34,7 +34,8 @@ prefill at the lanes the engine gives a group of two: fresh
 (``batch_prefill``) and continuing contexts in the region
 (``batch_prefill_cont``), at the smallest bucket (``--prefill-width``
 names another: the long-context cell's continuing ``[1, 4096]`` program
-is held by tests/test_tpu_lowering.py).
+is held by tests/test_tpu_lowering.py), and the one program that samples
+a prefill dispatch's first tokens and admits its slots (``admit_first``).
 Prints one JSON line per program with XLA's memory analysis, the
 region-shaped copies in the compiled text and what it writes out in the
 shape of one layer's shard of a weight (``weight_copies``: a weight laid
@@ -200,6 +201,7 @@ def compile_programs(model_config: str = "llama3_1b", tp: int = 1,
     import jax.numpy as jnp
     from jax.experimental import topologies
 
+    from dynamo_tpu.engine import engine as engine_mod
     from dynamo_tpu.engine.config import EngineConfig
     from dynamo_tpu.engine.engine import TpuEngine
     from dynamo_tpu.models import llama
@@ -367,6 +369,19 @@ def compile_programs(model_config: str = "llama3_1b", tp: int = 1,
             c, params, ctx, i32(K, T), i32(K), i32(K), i32(K), S, i32(K),
             counted=counted,
         ),
+        # the one program a prefill dispatch that samples every first
+        # token of the group and admits its slots, on the logits as the
+        # prefill leaves them (the vocabulary over tp) and ONE packed row
+        # a lane
+        "admit_first": lambda: eng._admit_first.trace(
+            dev, jax.ShapeDtypeStruct(
+                (K, c.vocab_size), jnp.float32,
+                sharding=jax.sharding.NamedSharding(
+                    mesh, jax.sharding.PartitionSpec(None, "tp"))),
+            jax.ShapeDtypeStruct((K, engine_mod._ROW_W), jnp.uint32,
+                                 sharding=rep),
+            False,
+        ),
     }
     if llama.block_of(c) is not None:
         # its step is in the round; no page transfer
@@ -380,6 +395,7 @@ def compile_programs(model_config: str = "llama3_1b", tp: int = 1,
               **{n: f"{n}_n{n_pages}" for n in
                  ("load_ctx_pages", "gather_pages", "scatter_pages")},
               "round_seal": f"round_seal_n{R}_w{W}",
+              "admit_first": f"admit_first_K{K}",
               "batch_prefill": f"batch_prefill_K{K}_T{T}",
               "batch_prefill_cont": f"batch_prefill_cont_K{K}_T{T}_S{S}"}
     out = []
@@ -444,7 +460,7 @@ def main(argv: list[str] | None = None) -> int:
                     help="comma-separated subset: decode_step, flush_ctx, "
                          "seal_blocks, flush_seal, round_seal, "
                          "load_ctx_pages, gather_pages, scatter_pages, "
-                         "batch_prefill, batch_prefill_cont")
+                         "batch_prefill, batch_prefill_cont, admit_first")
     ap.add_argument("--seal-width", type=int, default=0,
                     help="entries of the standalone seal (0 = the fused "
                          "round's width)")
