@@ -177,6 +177,44 @@ _TINY_JAMBA = {
     "hidden_act": "silu", "rms_norm_eps": 1e-6, "sliding_window": None,
     "max_position_embeddings": 512, "tie_word_embeddings": True,
 }
+# layers of ONE part each (``hybrid_override_pattern``: a Mamba-2 mixer with
+# B/C groups, a NoPE GQA mixer, an expert layer of UNGATED relu^2 experts,
+# or a dense relu^2 MLP; never two), sigmoid-routed experts in one group
+# with a shared one, the head untied: the same hybrid stack
+# (_from_hf_nemotron_h)
+_NEMOTRON_H_TYPES = frozenset({"nemotron_h"})
+_NEMOTRON_H_KEYS = (
+    "hidden_size", "num_hidden_layers", "hybrid_override_pattern",
+    "vocab_size", "layer_norm_epsilon", "tie_word_embeddings",
+    "num_attention_heads", "num_key_value_heads", "head_dim",
+    "mamba_num_heads", "mamba_head_dim", "n_groups", "ssm_state_size",
+    "conv_kernel", "chunk_size", "mamba_hidden_act", "use_conv_bias",
+    "mlp_hidden_act", "intermediate_size", "n_routed_experts",
+    "num_experts_per_tok", "moe_intermediate_size", "n_shared_experts",
+    "moe_shared_expert_intermediate_size", "n_group", "topk_group",
+    "norm_topk_prob", "routed_scaling_factor")
+# the pattern's letters -> the kinds models/ssm_moe.py builds
+_NEMOTRON_H_KINDS = {"M": "mamba", "*": "attention", "E": "experts",
+                     "-": "mlp"}
+_TINY_NEMOTRON_H = {
+    "model_type": "nemotron_h", "vocab_size": 256, "hidden_size": 64,
+    "num_hidden_layers": 13, "hybrid_override_pattern": "MEMEM*EMEMEM*",
+    "intermediate_size": 40, "layer_norm_epsilon": 1e-5, "norm_eps": 1e-5,
+    "num_attention_heads": 8, "num_key_value_heads": 2, "head_dim": 16,
+    "attention_bias": False, "mlp_bias": False, "use_bias": False,
+    "mamba_num_heads": 8, "mamba_head_dim": 12, "n_groups": 2,
+    "ssm_state_size": 16, "conv_kernel": 4, "chunk_size": 8, "expand": 2,
+    "mamba_hidden_act": "silu", "mamba_proj_bias": False,
+    "use_conv_bias": True, "mlp_hidden_act": "relu2",
+    "n_routed_experts": 4, "num_experts_per_tok": 6,
+    "expert_share": {"published_experts": 32, "of": 8, "index": 0},
+    "moe_intermediate_size": 40, "moe_shared_expert_intermediate_size": 80,
+    "n_shared_experts": 1, "n_group": 1, "topk_group": 1,
+    "norm_topk_prob": True, "routed_scaling_factor": 2.5,
+    "sliding_window": None, "residual_in_fp32": False,
+    "rope_theta": 10000, "partial_rotary_factor": 1,
+    "max_position_embeddings": 512, "tie_word_embeddings": False,
+}
 _TINY_PHI4FLASH = {
     "model_type": "phi4flash", "vocab_size": 256, "hidden_size": 64,
     "intermediate_size": 128, "num_hidden_layers": 8, "mb_per_layer": 2,
@@ -340,7 +378,11 @@ class ModelConfig:
     # attention and window_attention kinds in the ROTARY GQA form: a
     # rotary rule a kind (`rope`), every layer's own number of query heads
     # (`heads_by_layer`; `num_heads` is then the published default only), a
-    # sigmoid gate a head, the one-group sigmoid router with a share.
+    # sigmoid gate a head, the one-group sigmoid router with a share; and
+    # (_from_hf_nemotron_h) layers of ONE part (`one_part`: a mixer kind,
+    # or `experts` | `mlp`, a feed-forward kind of its own), Mamba-2 with
+    # B/C groups (`mamba_n_groups`), experts without a gate matrix
+    # (`expert_act` relu2).
     hybrid: Optional[tuple[tuple[str, Any], ...]] = None
 
     @property
@@ -392,6 +434,8 @@ class ModelConfig:
             return cls._from_hf_linear_sparse(d)
         if model_type in _LAGUNA_TYPES:
             return cls._from_hf_laguna(d)
+        if model_type in _NEMOTRON_H_TYPES:
+            return cls._from_hf_nemotron_h(d)
         if model_type not in _DENSE_TYPES:
             raise ValueError(
                 f"model_type {model_type!r} is no block this program "
@@ -539,8 +583,9 @@ class ModelConfig:
                 d.get("position_embedding_type") != "nope",
             "rope_scaling (there is no rotary to scale)":
                 d.get("rope_scaling") is not None,
-            f"mamba_n_groups {d['mamba_n_groups']} (the scan shares one B "
-            "and C over all heads)": d["mamba_n_groups"] != 1,
+            f"mamba_n_groups {d['mamba_n_groups']} (no whole number of "
+            f"the {nh} heads reads one group's B and C)":
+                d["mamba_n_groups"] < 1 or nh % d["mamba_n_groups"] != 0,
             "attention_bias": bool(d.get("attention_bias")),
             "mamba_proj_bias": bool(d["mamba_proj_bias"]),
             "mamba_conv_bias false": not d["mamba_conv_bias"],
@@ -554,8 +599,6 @@ class ModelConfig:
                 not set(kinds) <= _LAYER_KINDS,
             "layer_types whose length is not num_hidden_layers":
                 len(kinds) != d["num_hidden_layers"],
-            "mamba_n_heads x mamba_d_head != mamba_expand x hidden_size":
-                nh * hd != d["mamba_expand"] * d["hidden_size"],
             "mamba_d_conv < 2": d["mamba_d_conv"] < 2,
             f"expert_share: {held} held x {of} chips is not the published "
             f"{E} experts (a share is a whole-number split)":
@@ -1002,6 +1045,139 @@ class ModelConfig:
         )
 
     @classmethod
+    def _from_hf_nemotron_h(cls, d: dict[str, Any]) -> "ModelConfig":
+        """Layers of ONE part each, as a ``model_type: nemotron_h``
+        config.json parameterises them: ``x = x + part(RMSNorm(x))`` with
+        the part named by the layer's letter of ``hybrid_override_pattern``:
+        ``M`` a Mamba-2 mixer (``mamba_num_heads`` heads of
+        ``mamba_head_dim``, inner = their product whatever ``expand`` says,
+        B and C in ``n_groups`` groups of ``ssm_state_size``, a gated
+        RMSNorm over each group's channels, a convolution of
+        ``conv_kernel`` with a bias), ``*`` a GQA mixer with no rotary
+        (the family's attention is position-free: ``rope_theta`` and
+        ``partial_rotary_factor`` are in the file and unread), ``E``
+        ``n_routed_experts`` UNGATED experts ``W_d relu(x W_u)^2`` of
+        ``moe_intermediate_size`` (top ``num_experts_per_tok`` of sigmoid
+        scores with a selection-only bias, weights normalised x
+        ``routed_scaling_factor``: the grouped sigmoid router with ONE
+        group) plus one shared expert of
+        ``moe_shared_expert_intermediate_size``, ``-`` one dense relu^2
+        MLP of ``intermediate_size``. No multipliers; the head is tied or
+        not by what the file says. Anything this program does not build is
+        refused by name.
+
+        The experts held HERE are ``n_routed_experts``; a file that holds
+        a share states the deployment under ``expert_share``
+        (``published_experts``, ``of``, ``index``) as the other expert
+        stacks do, and the router keeps the published width."""
+        name = "one-part hybrid block"
+        missing = sorted(k for k in _NEMOTRON_H_KEYS if k not in d)
+        if missing:
+            raise ValueError(f"{name}: keys {missing} are missing from the "
+                             "config")
+        L, pattern = int(d["num_hidden_layers"]), str(
+            d["hybrid_override_pattern"])
+        kinds = tuple(_NEMOTRON_H_KINDS.get(t, t) for t in pattern)
+        held = int(d["n_routed_experts"])
+        share = d.get("expert_share") or {
+            "published_experts": held, "of": 1, "index": 0}
+        if set(share) != {"published_experts", "of", "index"}:
+            raise ValueError(
+                f"{name}: expert_share needs exactly published_experts, of "
+                f"and index (it has {sorted(share)})")
+        E, of, index = (int(share[k]) for k in
+                        ("published_experts", "of", "index"))
+        nh, groups = int(d["mamba_num_heads"]), int(d["n_groups"])
+        heads, kvh = (int(d[k]) for k in ("num_attention_heads",
+                                          "num_key_value_heads"))
+        hd = int(d["head_dim"])
+        refused = {
+            f"hybrid_override_pattern letters other than "
+            f"{sorted(_NEMOTRON_H_KINDS)}":
+                not set(pattern) <= set(_NEMOTRON_H_KINDS),
+            "hybrid_override_pattern whose length is not "
+            "num_hidden_layers": len(pattern) != L,
+            "a pattern without an attention layer (its region would hold "
+            "no rows of the context's length)": "*" not in pattern,
+            f"mlp_hidden_act {d['mlp_hidden_act']!r} (only 'relu2')":
+                d["mlp_hidden_act"] != "relu2",
+            f"mamba_hidden_act {d['mamba_hidden_act']!r} (only 'silu')":
+                d["mamba_hidden_act"] != "silu",
+            **{f"{flag} true": bool(d.get(flag, False)) for flag in (
+                "attention_bias", "mlp_bias", "use_bias", "mamba_proj_bias")},
+            "use_conv_bias false": not d["use_conv_bias"],
+            "residual_in_fp32": bool(d.get("residual_in_fp32", False)),
+            f"n_group {d['n_group']} / topk_group {d['topk_group']} (only "
+            "one group of experts, kept)":
+                d["n_group"] != 1 or d["topk_group"] != 1,
+            "norm_topk_prob false": not d["norm_topk_prob"],
+            f"n_shared_experts {d['n_shared_experts']} (only 1)":
+                d["n_shared_experts"] != 1,
+            f"n_groups {groups} (no whole number of the {nh} Mamba heads "
+            "reads one group's B and C)": groups < 1 or nh % groups != 0,
+            "conv_kernel < 2": d["conv_kernel"] < 2,
+            "num_attention_heads that is no multiple of "
+            "num_key_value_heads": kvh < 1 or heads % kvh != 0,
+            f"sliding_window {d.get('sliding_window')!r} (no window is "
+            "built on this block's attention)":
+                d.get("sliding_window") is not None,
+            f"norm_eps {d.get('norm_eps')!r} beside layer_norm_epsilon "
+            f"{d['layer_norm_epsilon']!r} (one epsilon)":
+                d.get("norm_eps", d["layer_norm_epsilon"])
+                != d["layer_norm_epsilon"],
+            f"expert_share: {held} held x {of} chips is not the published "
+            f"{E} experts (a share is a whole-number split)":
+                of < 1 or held * of != E,
+            f"expert_share index {index} outside 0..{of - 1}":
+                not 0 <= index < max(of, 1),
+            "num_experts_per_tok above the published experts, or fewer "
+            "than 2 of them": d["num_experts_per_tok"] > E or E < 2,
+        }
+        bad = sorted(k for k, v in refused.items() if v)
+        if bad:
+            raise ValueError(f"{name}: {bad} are values this program does "
+                             "not build")
+        hybrid = dict(
+            layer_types=kinds, one_part=True,
+            mamba_n_heads=nh, mamba_d_head=int(d["mamba_head_dim"]),
+            mamba_n_groups=groups, mamba_d_state=int(d["ssm_state_size"]),
+            mamba_d_conv=int(d["conv_kernel"]),
+            mamba_chunk_size=int(d["chunk_size"]),
+            # the grouped sigmoid router with ONE group: every expert
+            # stays in the running
+            router="sigmoid_groups", n_group=1, topk_group=1,
+            routed_scaling_factor=float(d["routed_scaling_factor"]),
+            expert_act="relu2",
+            num_local_experts=held,
+            num_experts_per_tok=int(d["num_experts_per_tok"]),
+            intermediate_size=int(d["moe_intermediate_size"]),
+            shared_intermediate_size=int(
+                d["moe_shared_expert_intermediate_size"]),
+            published_experts=E, share_of=of, share_index=index,
+            embedding_multiplier=1.0, residual_multiplier=1.0,
+            attention_multiplier=hd ** -0.5, logits_scaling=1.0)
+        if "E" not in pattern:   # nothing routes
+            for key in ("router", "n_group", "topk_group",
+                        "routed_scaling_factor", "num_local_experts",
+                        "num_experts_per_tok", "shared_intermediate_size",
+                        "published_experts", "share_of", "share_index"):
+                del hybrid[key]
+        return cls(
+            vocab_size=d["vocab_size"],
+            hidden_size=d["hidden_size"],
+            intermediate_size=d["intermediate_size"],
+            num_layers=L,
+            num_heads=heads,
+            num_kv_heads=kvh,
+            head_dim=hd,
+            rms_norm_eps=d["layer_norm_epsilon"],
+            max_position_embeddings=d.get("max_position_embeddings", 8192),
+            tie_word_embeddings=bool(d["tie_word_embeddings"]),
+            model_type=d["model_type"],
+            hybrid=tuple(sorted(hybrid.items())),
+        )
+
+    @classmethod
     def _from_hf_kda_latent(cls, d: dict[str, Any]) -> "ModelConfig":
         """Delta-rule linear attention (KDA) layers with a per-channel
         gate and short convolutions, one latent (MLA) layer closing every
@@ -1174,6 +1350,18 @@ class ModelConfig:
         on the full layers, one dense layer and then 16 experts top 4 of
         which share 0 of 4 holds 4."""
         d = dict(_TINY_LAGUNA)
+        d.update(kw)
+        return cls.from_hf_dict(d)
+
+    @classmethod
+    def tiny_nemotron_h(cls, **kw) -> "ModelConfig":
+        """Toy one-part hybrid stack for CPU tests: thirteen layers
+        ``MEMEM*EMEMEM*`` (six Mamba-2 mixers of 8 heads x 12 in 2 B/C
+        groups, inner 96 against hidden 64; five expert layers of 32
+        ungated relu^2 experts top 6 of which share 0 of 8 holds 4, width
+        40, plus a shared one of 80; two NoPE GQA layers of 8 heads over
+        2), a scan chunk of 8, the head untied."""
+        d = dict(_TINY_NEMOTRON_H)
         d.update(kw)
         return cls.from_hf_dict(d)
 

@@ -208,12 +208,33 @@ MOVE_STRAIGHT_ROWS = 4096
 def gmm_tile_n(k: int, n: int, itemsize: int) -> int:
     """Output columns of a weight tile [k, tn]: the whole matrix where it
     fits GMM_TILE_BYTES, else n halved (whole 128-column lanes) until it
-    does. The contraction is never split: a tile then needs no second
-    visit to finish its sums."""
+    does; where halving ends on an ODD number of 128-lane columns and the
+    tile is still too large (2688 = 21 columns; 1920 = 15), the columns are
+    dealt evenly over the fewest tiles that fit (21 -> 3 x 7, 15 -> 3 x
+    5; the last tile of a width that is no whole number of tiles or of
+    columns is short, which the kernel takes). The contraction is never
+    split: a tile then needs no second visit to finish its sums."""
     tn = n
     while k * tn * itemsize > GMM_TILE_BYTES and tn % 256 == 0:
         tn //= 2
-    return tn
+    if k * tn * itemsize <= GMM_TILE_BYTES:
+        return tn
+    fit = max(GMM_TILE_BYTES // (k * itemsize * 128), 1)   # columns a tile
+    tiles = -(-tn // (fit * 128))
+    return -(-tn // (tiles * 128)) * 128
+
+
+def stored_width(n: int) -> int:
+    """The width an expert's matrices are STORED at for the grouped
+    product: ``n`` itself where it is a whole number of 128-lane columns
+    (or at most one), else the next whole number, the columns of W_u and
+    the rows of W_d beyond ``n`` ZERO (exact under any activation that
+    maps 0 to 0 with no gate beside it: relu(0)^2 = 0 meets a zero row).
+    A tile whose last 64 columns hang over the matrix's edge streams at a
+    third of the pace of a whole one on the v5e (1856 against 1920 under
+    hidden 2688: 205 against 589 GB/s of PUBLISHED bytes a decode-shaped
+    call; PERF.md section 6, PR 60)."""
+    return n if n <= 128 or n % 128 == 0 else -(-n // 128) * 128
 
 
 def _gmm_tpu(x, w, group_sizes):
@@ -233,6 +254,11 @@ def _gmm_tpu(x, w, group_sizes):
                tiling=(tm, k, gmm_tile_n(k, w.shape[2], w.dtype.itemsize)))
 
 
+def relu2(u):
+    """``relu(u)^2``, squared in float32 and rounded once."""
+    return jnp.square(jax.nn.relu(u).astype(jnp.float32)).astype(u.dtype)
+
+
 def _grouped(x, w, group_sizes, expert_of_row):
     """Rows of x (sorted by expert) times their expert's matrix; dense
     or int8 ``{"q", "s"}`` experts (scale per [E, out])."""
@@ -247,14 +273,18 @@ def grouped_experts(
     x: jnp.ndarray,        # [T, H]
     sel: jnp.ndarray,      # [T, K] i32 — the experts each token picked
     weights: jnp.ndarray,  # [T, K] f32 — their combine weights
-    wg, wu, wd,            # [E, H, I], [E, H, I], [E, I, H]
+    wg, wu, wd,            # [E, H, I], [E, H, I], [E, I, H]; wg None
+                           # under ``act`` "relu2" (no gate matrix)
     valid=None,            # [T] bool — False: routed NOWHERE (padding,
                            # garbage decode lanes); its output row is 0
     first: int | None = None,  # a SHARE of the experts is held: wg/wu/wd
                            # are experts first .. first + E of the ones
                            # ``sel`` names; None: all of them
+    act: str = "swiglu",   # an expert: "swiglu" W_d (silu(x W_g) * x W_u),
+                           # three grouped products; "relu2" W_d relu(x
+                           # W_u)^2, two (``wg`` is None)
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """sum_k weights[t, k] * SwiGLU_{sel[t, k]}(x[t]) for every token, and
+    """sum_k weights[t, k] * expert_{sel[t, k]}(x[t]) for every token, and
     the tokens each expert received ([E] i32: what the counters read).
 
     With ``first`` the layer holds one chip's share of an expert-parallel
@@ -264,7 +294,7 @@ def grouped_experts(
     layer's sum (what the other chips would add arrives by an exchange
     that one chip does not run). The counts are of the held experts."""
     T, K = sel.shape
-    E = (wg["q"] if isinstance(wg, dict) else wg).shape[0]
+    E = (wu["q"] if isinstance(wu, dict) else wu).shape[0]
     flat = sel.reshape(T * K).astype(jnp.int32)
     if first is not None:
         # the same idiom as ``valid`` below: id E belongs to no group
@@ -285,9 +315,13 @@ def grouped_experts(
         else:
             xs = x[order // K]                           # [T*K, H]
     with jax.named_scope("moe_experts"):
-        g = _grouped(xs, wg, group_sizes, expert_of_row)
-        u = _grouped(xs, wu, group_sizes, expert_of_row)
-        y = _grouped(jax.nn.silu(g) * u, wd, group_sizes, expert_of_row)
+        if act == "relu2":
+            a = relu2(_grouped(xs, wu, group_sizes, expert_of_row))
+        else:
+            g = _grouped(xs, wg, group_sizes, expert_of_row)
+            u = _grouped(xs, wu, group_sizes, expert_of_row)
+            a = jax.nn.silu(g) * u
+        y = _grouped(a, wd, group_sizes, expert_of_row)
     # the inverse permutation: the sorted row that holds pick (t, k)
     back = jnp.zeros_like(order).at[order].set(
         jnp.arange(T * K, dtype=order.dtype))

@@ -170,6 +170,30 @@ kind, what a region holds from its leaves:
     the group(s) held here. With ONE group every expert stays in the
     running: the top k of ``c``, no group scored.
 
+  - LAYERS OF ONE PART (a config with ``one_part``): a layer is ONE norm
+    and ONE part, ``x = x + part(norm(x))``: a mixer kind's mixer and no
+    feed-forward part, or a kind of its own whose part IS the feed-forward
+    part (``experts``: routed experts + a shared one; ``mlp``: one dense
+    MLP) and no mixer. ``_parts`` says which of the two parts a layer of a
+    kind has, in either stack; every program asks there (``_layer_out``
+    for the decode step, the straight-line prefill and the climb,
+    ``_mix_out`` for the looped prefill's second half). A one-part layer's
+    parameters are its one norm and its part's leaves; what counts expert
+    layers counts the layers that ROUTE (``dims(c)["routes"]``).
+  - MAMBA-2 WITH B/C GROUPS (``mamba_n_groups`` G > 1): the convolution
+    runs over inner + 2 G N channels, B and C are [G, N] a position and
+    head h reads group h // (heads / G) (ops/mamba2.py: the chunked scan
+    takes the [Q, Q] map a group, the step and the ``m2_step`` kernel a
+    group's row for their heads); the gated RMSNorm is over EACH GROUP's
+    inner / G channels, gate first, one gain of inner. inner is heads x
+    head, whatever ``expand`` says. One group traces as it always did.
+  - EXPERTS WITHOUT A GATE MATRIX (``expert_act: relu2``): an expert is
+    ``W_d relu(x W_u)^2``, two matrices and two grouped products, and so
+    are the shared expert and a dense MLP of that stack; no gate leaf
+    exists. An expert stack whose width is no whole number of 128-lane
+    columns is STORED at the next one (``moe.stored_width``: zero columns
+    of W_u, zero rows of W_d: exact), so that every weight tile is whole.
+
 Every function here is reached through the ``llama`` names
 (``llama.block_of``), as models/mla_moe.py is.
 """
@@ -187,7 +211,13 @@ from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models import mla_moe
 from dynamo_tpu.models.live_rows import live_row_trips, over_live_blocks
 from dynamo_tpu.models.mla_moe import _mlp, _rms   # the same norm and SwiGLU
-from dynamo_tpu.models.moe import grouped_experts, move_block, rows_moved
+from dynamo_tpu.models.moe import (
+    grouped_experts,
+    move_block,
+    relu2,
+    rows_moved,
+    stored_width,
+)
 from dynamo_tpu.ops import kda, lightning, mamba1, mamba2, sparse_attention
 from dynamo_tpu.ops.attention import (
     NEG_INF,
@@ -217,6 +247,7 @@ from dynamo_tpu.telemetry.metrics import (
     DECODE_ATTN_Q_ROWS_WINDOW,
     DECODE_ATTN_ROWS_READ,
     KDA_STATE_ROWS_STEPPED,
+    LAYER_PARTS_RUN,
     MOE_GROUPS_KEPT_HERE,
     MOE_LOAD_MAX,
     MOE_PICKS_ROUTED,
@@ -247,6 +278,11 @@ KV = mla_moe.ROW      # the latent layers' one row kind
 WK, WV = "wk", "wv"   # the window layers' K/V rows: a modular buffer a lane
 ROW_LAYERS = ("attention", "sparse_attention")   # kinds that keep K/V rows
                       # of the region's length
+# the kinds of a stack of ONE-PART layers whose one part is the feed-forward
+# part (routed experts + a shared one | one dense MLP): no mixer, no state
+FFN_KINDS = ("experts", "mlp")
+# kinds whose mixer is a recurrence over a per-lane state
+STATE_KINDS = ("mamba", "mamba1", "kda", "linear_attention")
 # the kinds the differential form covers where the config states it
 DIFF_KINDS = ("attention", "window_attention", "cross_attention")
 DIFF_KERNEL = "diff_decode_attention"   # their decode kernel in a trace
@@ -287,19 +323,37 @@ def dims(c: ModelConfig) -> dict[str, Any]:
         "experts": "num_local_experts" in k,
         # leading layers whose feed-forward part is one dense MLP
         "n_dense": k.get("n_dense", 0),
+        # layers of ONE part (a mixer kind, or a kind of FFN_KINDS) where
+        # the config says so; else every layer = mixer + feed-forward part
+        "one_part": bool(k.get("one_part")),
+        # an expert (and the shared and dense MLPs beside them): "swiglu"
+        # W_d (silu(x W_g) * x W_u) or "relu2" W_d relu(x W_u)^2, which
+        # has no gate matrix
+        "act": k.get("expert_act", "swiglu"),
     }
+    # which layers ROUTE: in a one-part stack the ``experts`` kind, else
+    # every layer past the leading dense ones of a config with experts
+    d["routes"] = tuple(
+        (t == "experts") if d["one_part"] else
+        (d["experts"] and i >= d["n_dense"]) for i, t in enumerate(kinds))
     if "mamba_n_heads" in k:
         inner = k["mamba_n_heads"] * k["mamba_d_head"]
+        G = k.get("mamba_n_groups", 1)
         d.update({
             "nh": k["mamba_n_heads"], "P": k["mamba_d_head"],
             "N": k["mamba_d_state"], "W": k["mamba_d_conv"],
-            "inner": inner, "conv": inner + 2 * k["mamba_d_state"],
+            # B and C in G groups of N: head h reads group h // (nh / G)
+            "G": G,
+            "inner": inner, "conv": inner + 2 * G * k["mamba_d_state"],
             "chunk": k["mamba_chunk_size"],
         })
     if d["experts"]:
         d.update({
             "E": k["published_experts"], "held": k["num_local_experts"],
             "K": k["num_experts_per_tok"], "I_e": k["intermediate_size"],
+            # the width the held experts' matrices are stored at (zeros
+            # beyond the published I_e: moe.stored_width)
+            "I_st": stored_width(k["intermediate_size"]),
             "I_s": k["shared_intermediate_size"],
             # the first expert held here; None: all of them, and the
             # grouped product traces as it does without a share
@@ -441,7 +495,11 @@ def init_params(config: ModelConfig, rng: jax.Array | int = 0) -> Params:
     vectors (x 0.1), the pair norm's gain 1 and ``lam_init`` = 0.8 - 0.6
     exp(-0.3 l) of its depth l; a LayerNorm stack a bias (x 0.1) beside
     each gain. A layer of the rotary GQA form has ``wq`` / ``wo`` at ITS
-    number of query heads and ``wg`` [H, heads], the gate's."""
+    number of query heads and ``wg`` [H, heads], the gate's. A layer of a
+    ONE-PART stack holds one norm and its part's leaves only; experts
+    without a gate matrix hold two matrices each (``we_u``, ``we_d``), and
+    every expert stack is drawn at the published width and STORED at
+    ``moe.stored_width`` of it (zero columns of W_u, zero rows of W_d)."""
     if isinstance(rng, int):
         rng = jax.random.PRNGKey(rng)
     c, d = config, dims(config)
@@ -479,28 +537,51 @@ def init_params(config: ModelConfig, rng: jax.Array | int = 0) -> Params:
                       wv=rnd(H, c.kv_dim), bv=bias(c.kv_dim))
         return lp
 
+    gated = d["act"] == "swiglu"
+
+    def stored(a, axis):
+        """An expert stack drawn at the published width, at the width it
+        is stored at: zeros beyond ``I_e`` along ``axis``."""
+        if d["I_st"] == d["I_e"]:
+            return a
+        return jnp.pad(a, [(0, d["I_st"] - d["I_e"] if ax == axis else 0)
+                           for ax in range(a.ndim)])
+
     def layer(i, kind):
-        lp = {"ln1": jnp.ones((H,), dtype), "ln2": jnp.ones((H,), dtype)}
+        lp = {"ln1": jnp.ones((H,), dtype)}
+        if not d["one_part"]:
+            lp["ln2"] = jnp.ones((H,), dtype)
         if layer_norm:
             lp.update(ln1_b=bias(H), ln2_b=bias(H))
-        if d["experts"] and i >= d["n_dense"]:
+        if d["routes"][i]:
             if "groups" in d:
                 # in SCORE units, as the latent block draws it (the 8th
                 # and 9th best of hundreds of sigmoid scores lie ~0.005
                 # apart)
                 lp["bias"] = 0.01 * jax.random.normal(
                     next(keys), (d["E"],), jnp.float32)
-            lp.update(
-                wr=rnd(H, d["E"]),
+            lp["wr"] = rnd(H, d["E"])
+            if gated:
                 # the published fused input matrix [H, 2 I] as its two halves
-                we_g=rnd(d["held"], H, d["I_e"]),
-                we_u=rnd(d["held"], H, d["I_e"]),
-                we_d=rnd(d["held"], d["I_e"], H),
-                ws_g=rnd(H, d["I_s"]), ws_u=rnd(H, d["I_s"]),
-                ws_d=rnd(d["I_s"], H))
-        else:
+                lp.update(
+                    we_g=stored(rnd(d["held"], H, d["I_e"]), 2),
+                    we_u=stored(rnd(d["held"], H, d["I_e"]), 2),
+                    we_d=stored(rnd(d["held"], d["I_e"], H), 1),
+                    ws_g=rnd(H, d["I_s"]), ws_u=rnd(H, d["I_s"]),
+                    ws_d=rnd(d["I_s"], H))
+            else:   # two matrices an expert: no gate
+                lp.update(
+                    we_u=stored(rnd(d["held"], H, d["I_e"]), 2),
+                    we_d=stored(rnd(d["held"], d["I_e"], H), 1),
+                    ws_u=rnd(H, d["I_s"]), ws_d=rnd(d["I_s"], H))
+        elif not d["one_part"] or kind == "mlp":
             I = c.intermediate_size
-            lp.update(w_g=rnd(H, I), w_u=rnd(H, I), w_d=rnd(I, H))
+            if gated:
+                lp.update(w_g=rnd(H, I), w_u=rnd(H, I), w_d=rnd(I, H))
+            else:
+                lp.update(w_u=rnd(H, I), w_d=rnd(I, H))
+        if kind in FFN_KINDS:
+            return lp
         if _form(d, kind) == "diff":
             lp.update(diff_attention(i, kind != "cross_attention"))
             return lp
@@ -580,7 +661,7 @@ def init_params(config: ModelConfig, rng: jax.Array | int = 0) -> Params:
             return lp
         dt = jnp.exp(u(np.log(1e-3), np.log(1e-1), d["nh"]))
         lp.update(
-            w_in=rnd(H, 2 * d["inner"] + 2 * d["N"] + d["nh"]),
+            w_in=rnd(H, d["inner"] + d["conv"] + d["nh"]),
             conv_w=rnd(d["W"], d["conv"], scale=1.0 / np.sqrt(d["W"])),
             conv_b=rnd(d["conv"], scale=0.1),
             A_log=jnp.log(u(1.0, 16.0, d["nh"])),
@@ -875,8 +956,8 @@ def prefill_rows_sorted(c: ModelConfig, n_tokens: int) -> int:
     ``n_tokens`` positions sort, where they move rows in the looped form;
     0 where they do not, or there are none."""
     d = dims(c)
-    layers = c.num_layers - d["n_dense"]
-    return n_tokens * d["K"] * layers if _move_block(d, n_tokens) else 0
+    return (n_tokens * d["K"] * sum(d["routes"])
+            if _move_block(d, n_tokens) else 0)
 
 
 def _seen(c: ModelConfig, load, here, valid, n_tokens: int, stats):
@@ -904,28 +985,64 @@ def _norm(c: ModelConfig, lp, name: str, x):
             * lp[name] + lp[name + "_b"])
 
 
+def _dense(lp, x, g: str, u: str, d: str):
+    """One MLP over the leaves named: SwiGLU where the layer holds a gate
+    matrix, else ``W_d relu(x W_u)^2``."""
+    if g in lp:
+        return _mlp(x, lp[g], lp[u], lp[d])
+    return relu2(x @ lp[u]) @ lp[d]
+
+
 def _shared(lp, x):
     with jax.named_scope("moe_shared"):
-        return _mlp(x, lp["ws_g"], lp["ws_u"], lp["ws_d"])
+        return _dense(lp, x, "ws_g", "ws_u", "ws_d")
+
+
+def _experts(c: ModelConfig, lp, x, sel, w, valid):
+    """The grouped product over this chip's share of the experts, by the
+    stack's activation: (sum over the held picks [N, H], tokens a held
+    expert received)."""
+    d = dims(c)
+    return grouped_experts(x, sel, w, lp.get("we_g"), lp["we_u"], lp["we_d"],
+                           valid, first=d["first"], act=d["act"])
 
 
 def _ffn(c: ModelConfig, lp, x, valid, stats):
     """Routed experts (this chip's share) + the shared MLP, ungated."""
     sel, w, here = route(c, lp, x)
-    y, load = grouped_experts(x, sel, w, lp["we_g"], lp["we_u"], lp["we_d"],
-                              valid, first=dims(c)["first"])
+    y, load = _experts(c, lp, x, sel, w, valid)
     return y + _shared(lp, x), _seen(c, load, here, valid, x.shape[0], stats)
 
 
-def _layer_out(c: ModelConfig, lp, h, mix, valid, stats):
+def _parts(c: ModelConfig, kind: str):
+    """What a layer of ``kind`` is made of: (whether it runs a MIXER, the
+    norm in front of its FEED-FORWARD part or None where it has none). A
+    layer of two parts is both, ``ln2`` between them. In a stack of
+    one-part layers (``one_part``) a layer is one norm and one part: a
+    mixer kind's mixer, or the feed-forward part of a kind of
+    ``FFN_KINDS`` behind ``ln1``, the layer's only norm. The ONE place
+    that says so: every program below asks here."""
+    if not dims(c)["one_part"]:
+        return True, "ln2"
+    return (False, "ln1") if kind in FFN_KINDS else (True, None)
+
+
+def _layer_out(c: ModelConfig, kind: str, lp, h, mix, valid, stats):
+    """A layer of ``kind`` from its mixer's output ``mix`` on (None where
+    the layer has no mixer): the residual, then the feed-forward part
+    behind its norm, where the layer has one (``_parts``)."""
+    mixes, norm = _parts(c, kind)
     r = jnp.asarray(c.hybrid_dict["residual_multiplier"], h.dtype)
-    h = h + r * mix
-    x = _norm(c, lp, "ln2", h)
+    if mixes:
+        h = h + r * mix
+    if norm is None:
+        return h, stats
+    x = _norm(c, lp, norm, h)
     if "wr" in lp:   # the layer routes: a leading dense layer does not
         y, stats = _ffn(c, lp, x, valid, stats)
     else:
         with jax.named_scope("mlp"):
-            y = _mlp(x, lp["w_g"], lp["w_u"], lp["w_d"])
+            y = _dense(lp, x, "w_g", "w_u", "w_d")
     return h + r * y, stats
 
 
@@ -1012,18 +1129,30 @@ def _ssm_in(c: ModelConfig, lp, x):
 
 def _ssm_out(c: ModelConfig, lp, y, xs, z):
     """``y`` [N, heads, P] float32 from the scan -> the mixer's output:
-    + D x, the gate FIRST, then the norm over all heads, then W_out."""
+    + D x, the gate FIRST, then the norm (over all heads' channels, or
+    over EACH GROUP's where B and C come in groups; one gain of inner),
+    then W_out."""
+    G = dims(c)["G"]
     with jax.named_scope("ssm_out"):
         y = y + lp["D"][:, None] * xs.astype(jnp.float32)
         y = y.reshape(y.shape[0], -1) * jax.nn.silu(z.astype(jnp.float32))
+        if G > 1:
+            y = y.reshape(y.shape[0], G, -1)
         var = jnp.mean(y * y, axis=-1, keepdims=True)
         y = (y * jax.lax.rsqrt(var + c.rms_norm_eps)).astype(z.dtype)
+        if G > 1:
+            y = y.reshape(y.shape[0], -1)
         return (y * lp["norm"]) @ lp["w_out"]
 
 
 def _split_xbc(c: ModelConfig, xbc):
+    """The convolved [.., conv] -> x [.., heads, P], B and C [.., N] (one
+    group) or [.., G, N] (head h reads group h // (heads / G))."""
     d = dims(c)
-    xs, B, C = jnp.split(xbc, [d["inner"], d["inner"] + d["N"]], -1)
+    GN = d["G"] * d["N"]
+    xs, B, C = jnp.split(xbc, [d["inner"], d["inner"] + GN], -1)
+    if d["G"] > 1:
+        B, C = (a.reshape(*a.shape[:-1], d["G"], d["N"]) for a in (B, C))
     return xs.reshape(*xs.shape[:-1], d["nh"], d["P"]), B, C
 
 
@@ -1435,7 +1564,7 @@ SCAN_ROW_BLOCK = 256
 # straight-line program's fusions skip, and the sparse layers' block
 # selection flips on such roundings (PERF.md section 6, PR 49).
 LIVE_ROW_KINDS = ("mamba", "attention", "kda", "latent_attention", "mamba1",
-                  "window_attention", "cross_attention", "gmu")
+                  "window_attention", "cross_attention", "gmu") + FFN_KINDS
 
 
 def live_row_block(c: ModelConfig, T: int) -> int:
@@ -1528,12 +1657,19 @@ def _mix_out(c: ModelConfig, kind: str, lp, h, *seq):
     """The row-wise SECOND half of a layer of ``kind``, up to the expert
     sort: the mixer's out-norm, gate and out-projection from what its
     sequence operation returned (``seq``, [N, ...] each), the residual,
-    ``ln2`` and, where the layer routes, the router and the shared MLP:
-    (h after the mixer, x = ln2 of it, picks, combine weights, the shared
-    MLP of x[, whether a token kept a group held here]); where it does
-    not, the dense MLP and its residual too: (h after the layer,)."""
+    the norm in front of the feed-forward part and, where the layer
+    routes, the router and the shared MLP: (h after the mixer, x = that
+    norm of it, picks, combine weights, the shared MLP of x[, whether a
+    token kept a group held here]); where it does not, the dense MLP and
+    its residual too: (h after the layer,). Which of the two parts a
+    layer has is ``_parts``': a layer with no mixer takes no ``seq`` and
+    runs its feed-forward part on ``h``; one with no feed-forward part
+    ends at the mixer's residual."""
+    mixes, norm = _parts(c, kind)
     form = _form(dims(c), kind)
-    if kind == "gmu":
+    if not mixes:
+        pass
+    elif kind == "gmu":
         mix = _gmu(lp, *seq)
     elif form == "diff":
         mix = _diff_out(c, lp, *seq)
@@ -1555,11 +1691,14 @@ def _mix_out(c: ModelConfig, kind: str, lp, h, *seq):
         y, xbc, z = seq
         mix = _ssm_out(c, lp, y, _split_xbc(c, xbc)[0], z)
     r = jnp.asarray(c.hybrid_dict["residual_multiplier"], h.dtype)
-    h = h + r * mix
-    x = _norm(c, lp, "ln2", h)
+    if mixes:
+        h = h + r * mix
+    if norm is None:
+        return (h,)
+    x = _norm(c, lp, norm, h)
     if "wr" not in lp:   # a leading dense layer
         with jax.named_scope("mlp"):
-            return (h + r * _mlp(x, lp["w_g"], lp["w_u"], lp["w_d"]),)
+            return (h + r * _dense(lp, x, "w_g", "w_u", "w_d"),)
     sel, w, here = route(c, lp, x)
     return (h, x, sel, w, _shared(lp, x)) + (() if here is None else (here,))
 
@@ -1719,7 +1858,7 @@ def _climb(c, params, ctx_kv, slots, q_starts, seq_lens, span: int, h, q,
                     q = _diff_q(c, lp, x)
                 mix = _diff_out(c, lp, _row_attention(q, k, v, last, prior,
                                                       below))
-        h, stats = _layer_out(c, lp, h, mix, None, stats)
+        h, stats = _layer_out(c, kind, lp, h, mix, None, stats)
     return _logits(c, params, h)
 
 
@@ -1792,8 +1931,11 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
                                   span, q, own, (ks, vs), (wks, wvs))
             h, = _mix_out(c, kind, lp, h, o.reshape(K * T, *o.shape[2:]))
             continue
-        x = _norm(c, lp, "ln1", h)
-        if _form(d, kind) == "gqa":
+        mixes, _ = _parts(c, kind)
+        x = _norm(c, lp, "ln1", h) if mixes else None
+        if not mixes:
+            mix = None   # the layer is its feed-forward part
+        elif _form(d, kind) == "gqa":
             with jax.named_scope(GQA_SCOPES[kind]):
                 q, k, v, z = _gqa_in(c, kind, lp, x,
                                      positions.reshape(K * T))
@@ -1929,7 +2071,7 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
             mix = _ssm_out(c, lp, y.reshape(K * T, d["nh"], d["P"]),
                            xs.reshape(K * T, d["nh"], d["P"]), z)
         before = stats
-        h, stats = _layer_out(c, lp, h, mix, valid, stats)
+        h, stats = _layer_out(c, kind, lp, h, mix, valid, stats)
         if R:   # the layer's held picks are its groups' total
             moved = moved + rows_moved(stats[1] - before[1], R)
 
@@ -2018,8 +2160,11 @@ def _live_prefill(config, params, ctx_kv, tokens, slots, q_starts, seq_lens,
     wks, wvs = [], []     # the window layers' rows
     m = logits = None     # the scan output the gated memory units read
     for l, (kind, lp) in enumerate(zip(d["kinds"], params["layers"])):
-        ins = rowwise(_mix_in, kind, lp, h, positions)
-        if kind == "gmu":
+        mixes, _ = _parts(c, kind)
+        ins = rowwise(_mix_in, kind, lp, h, positions) if mixes else ()
+        if not mixes:
+            seq = ()   # the second half is the whole layer, from h
+        elif kind == "gmu":
             seq = (ins[0], m)
         elif _form(d, kind) == "diff":
             q, *own = ins
@@ -2118,9 +2263,7 @@ def _live_prefill(config, params, ctx_kv, tokens, slots, q_starts, seq_lens,
             h, = out
             continue
         h, x, sel, w, shared, *here = out
-        y, load = grouped_experts(
-            flat(x), flat(sel), flat(w), lp["we_g"], lp["we_u"], lp["we_d"],
-            valid, first=d["first"])
+        y, load = _experts(c, lp, flat(x), flat(sel), flat(w), valid)
         h = h + r * (y.reshape(h.shape) + shared)
         stats = _seen(c, load, flat(here[0]) if here else None, valid,
                       K * T, stats)
@@ -2198,8 +2341,11 @@ def decode_step_impl(config, params, ctx_kv, ring, state, tokens, ctx_lens,
     wl = 0        # a window layer's ordinal among its kind
     m = None      # the scan output the gated memory units read, [B, inner]
     for l, (kind, lp) in enumerate(zip(d["kinds"], params["layers"])):
-        x = _norm(c, lp, "ln1", h)
-        if kind == "gmu":
+        mixes, _ = _parts(c, kind)
+        x = _norm(c, lp, "ln1", h) if mixes else None
+        if not mixes:
+            mix = None   # the layer is its feed-forward part
+        elif kind == "gmu":
             mix = _gmu(lp, x, m)
         elif _form(d, kind):
             # the full layer's rows at its ordinal (a cross layer reads
@@ -2343,7 +2489,7 @@ def decode_step_impl(config, params, ctx_kv, ring, state, tokens, ctx_lens,
                         interpret=attn.impl == PALLAS_INTERPRET)
             mix = _ssm_out(c, lp, y, xs, z)
             j += 1
-        h, stats = _layer_out(c, lp, h, mix, live, stats)
+        h, stats = _layer_out(c, kind, lp, h, mix, live, stats)
     return ring, state, _logits(c, params, h), stats
 
 
@@ -2409,8 +2555,37 @@ def pages_resume(config: ModelConfig) -> bool:
     return False
 
 
+def layer_parts(config: ModelConfig) -> dict[str, int]:
+    """The parts ONE decode step runs, by what they are: recurrent mixers,
+    other mixers, expert parts (the layers that route), dense MLPs. A
+    stack of two-part layers runs a mixer AND a feed-forward part a layer,
+    one of one-part layers one of the four."""
+    d = dims(config)
+    mixers = [t for t in d["kinds"] if _parts(config, t)[0]]
+    ffn = [routes for t, routes in zip(d["kinds"], d["routes"])
+           if _parts(config, t)[1] is not None]
+    return {"mixer_ssm": sum(t in STATE_KINDS for t in mixers),
+            "mixer_attn": sum(t not in STATE_KINDS for t in mixers),
+            "experts": sum(ffn), "mlp": len(ffn) - sum(ffn)}
+
+
 def decode_mirror(config: ModelConfig, max_context: int, ring_len: int,
                   attn: DecodeAttention):
+    """The host's mirrors of a dispatched round: the parts its steps ran
+    (``layer_parts`` x the steps, one histogram a part), and what its
+    attention layers read (``_rows_mirror``), where the stack has any."""
+    parts = [(LAYER_PARTS_RUN[part][0], n)
+             for part, n in layer_parts(config).items()]
+    rows = _rows_mirror(config, max_context, ring_len, attn)
+
+    def mirror(ctx_lens, live, n_steps: int):
+        ran = tuple((name, n * n_steps) for name, n in parts)
+        return ran + (rows(ctx_lens, live, n_steps) if rows else ())
+    return mirror
+
+
+def _rows_mirror(config: ModelConfig, max_context: int, ring_len: int,
+                 attn: DecodeAttention):
     """The host's mirrors of what a dispatched round's attention layers
     read: the latent layers' (mla_moe's, a layer), the sparse layers'
     (``sparse_attention.round_rows``, all such layers), or the
@@ -2474,12 +2649,12 @@ def prefill_mirror(config: ModelConfig):
     """The sparse layers score a chunk's whole causal context under the
     selection's mask (``scored``, what ``prefill_attention_pairs`` counts
     a layer): beside it, what a gathering prefill would score. A
-    stack with Mamba-1 layers mirrors the positions their prefill scans
-    run instead (it has no sparse layer)."""
+    stack with Mamba-1 or Mamba-2 layers mirrors the positions their
+    prefill scans run instead (it has no sparse layer)."""
     d = dims(config)
-    n_m1 = d["n_m1"]
-    if n_m1:
-        # the positions the Mamba-1 layers' prefill scans run: a lane's
+    n_scan = d["n_m1"] or d["n_ssm"]
+    if n_scan:
+        # the positions the state-space layers' prefill scans run: a lane's
         # live scan blocks where the program loops, every bucket row else
         def scanned(width: int, q_starts, seq_lens, scored: int):
             rows = len(q_starts) * width
@@ -2488,7 +2663,7 @@ def prefill_mirror(config: ModelConfig):
                     np.asarray(q_starts, np.int64),
                     np.asarray(seq_lens, np.int64), width,
                     SCAN_ROW_BLOCK).sum())
-            out = ((SSM_SCAN_POSITIONS[0], n_m1 * rows),)
+            out = ((SSM_SCAN_POSITIONS[0], n_scan * rows),)
             if SKIP_ROWS and d.get("climbs"):
                 # real prompt rows x layers, and of them the rows x layers
                 # that never ran: all but one row a chunk in the layers

@@ -6,7 +6,10 @@ Per head h (P values wide) with a state ``S`` [P, N], over time::
     S_t = exp(dt_t A) S_{t-1} + dt_t (x_t outer B_t)      y_t = S_t C_t
 
 ``A`` < 0 a head, ``dt`` > 0 a head and position, ``B`` and ``C`` [N]
-shared by all heads (one group). ``D x_t``, the gate and the norm are
+shared by all heads (one group: ``B`` / ``C`` of [.., N]) or by the heads
+of a GROUP (``B`` / ``C`` of [.., G, N]: head h reads group h // (H / G);
+every function here takes either, and one group given as [.., N] traces
+as it did before groups were built). ``D x_t``, the gate and the norm are
 the model's (models/ssm_moe.py); so is the short causal convolution's
 weight, applied here (``causal_conv`` / ``conv_step``) because its
 window is the other half of a lane's recurrent state.
@@ -14,7 +17,8 @@ window is the other half of a lane's recurrent state.
 PREFILL (``chunk_scan``) is the chunked form of the same recurrence
 ("SSD"): the T positions are cut into chunks of Q; inside a chunk the
 outputs are one masked [Q, Q] product a head (every pair (t, s <= t)
-weighted by the decay between them), the state crosses chunks through a
+weighted by the decay between them; ``C_t . B_s`` is one [Q, Q] map a
+GROUP, which each head of the group reads), the state crosses chunks through a
 ``lax.scan`` whose carry is the state itself, so a chunk's temporaries
 ([Q, Q, heads] float32: 33.5 MB at Q 256, 128 heads) exist once, not T / Q
 times. ``dt`` 0 at a position makes it neither decay nor feed the state:
@@ -80,13 +84,20 @@ def conv_step(xbc, window, w, b):
     return out, full[:, 1:].astype(window.dtype)
 
 
+def _heads_of(a, H: int):
+    """A group's row for each of its heads: ``a`` [.., G, N] -> [.., H, N],
+    head h reading group h // (H / G)."""
+    return jnp.repeat(a, H // a.shape[-2], axis=-2)
+
+
 def chunk_scan(x, dt, A, B, C, state, chunk: int):
     """One lane's T positions from ``state`` to the state after them.
 
     ``x`` [T, H, P], ``dt`` [T, H] float32 (0 on padding), ``A`` [H]
-    float32 (negative), ``B`` and ``C`` [T, N], ``state`` [H, P, N]
-    float32. Returns (y [T, H, P] float32, the final state). T that is
-    no multiple of ``chunk`` is padded here with ``dt`` 0."""
+    float32 (negative), ``B`` and ``C`` [T, N] (one group) or [T, G, N]
+    (head h reads group h // (H / G)), ``state`` [H, P, N] float32.
+    Returns (y [T, H, P] float32, the final state). T that is no multiple
+    of ``chunk`` is padded here with ``dt`` 0."""
     T, H, P = x.shape
     Q = min(chunk, T)
     pad = -T % Q
@@ -95,9 +106,26 @@ def chunk_scan(x, dt, A, B, C, state, chunk: int):
                        for a in (x, dt, B, C))
     n = (T + pad) // Q
     f32 = jnp.float32
+    grouped = B.ndim == 3
     xs = (x.reshape(n, Q, H, P), dt.reshape(n, Q, H).astype(f32),
-          B.reshape(n, Q, -1), C.reshape(n, Q, -1))
+          B.reshape(n, Q, *B.shape[1:-1], -1),
+          C.reshape(n, Q, *C.shape[1:-1], -1))
     causal = jnp.tril(jnp.ones((Q, Q), bool))
+    if grouped:
+        G = B.shape[1]
+        per = H // G
+        # the [Q, Q] map ``C_t . B_s`` a GROUP, read by each of its heads;
+        # the state in and out through its group's rows
+        pairs = lambda Cc, Bc: jnp.repeat(  # noqa: E731
+            jnp.einsum("tgn,sgn->tsg", Cc, Bc), per, axis=2)
+        carried = lambda Cc, S: jnp.einsum(  # noqa: E731
+            "tgn,gipn->tgip", Cc, S.reshape(G, per, P, -1)).reshape(Q, H, P)
+        fed = lambda xw, Bc: jnp.einsum(  # noqa: E731
+            "sgip,sgn->gipn", xw.reshape(Q, G, per, P), Bc).reshape(H, P, -1)
+    else:
+        pairs = lambda Cc, Bc: (Cc @ Bc.T)[:, :, None]  # noqa: E731
+        carried = lambda Cc, S: jnp.einsum("tn,hpn->thp", Cc, S)  # noqa: E731
+        fed = lambda xw, Bc: jnp.einsum("shp,sn->hpn", xw, Bc)  # noqa: E731
 
     def one(S, c):
         xc, dtc, Bc, Cc = c
@@ -108,13 +136,13 @@ def chunk_scan(x, dt, A, B, C, state, chunk: int):
         # growth, an overflow)
         between = jnp.where(causal[:, :, None],
                             cum[:, None, :] - cum[None, :, :], -jnp.inf)
-        M = (Cc @ Bc.T)[:, :, None] * jnp.exp(between) * dtc[None, :, :]
+        M = pairs(Cc, Bc) * jnp.exp(between) * dtc[None, :, :]
         y = jnp.einsum("tsh,shp->thp", M, xc)
         # what the state carried into the chunk adds at t
-        y = y + jnp.einsum("tn,hpn->thp", Cc, S) * jnp.exp(cum)[:, :, None]
+        y = y + carried(Cc, S) * jnp.exp(cum)[:, :, None]
         to_end = jnp.exp(cum[-1][None, :] - cum) * dtc          # [Q, H]
         S = (jnp.exp(cum[-1])[:, None, None] * S
-             + jnp.einsum("shp,sn->hpn", xc * to_end[:, :, None], Bc))
+             + fed(xc * to_end[:, :, None], Bc))
         return S, y
 
     state, y = jax.lax.scan(one, state.astype(f32), xs)
@@ -123,25 +151,33 @@ def chunk_scan(x, dt, A, B, C, state, chunk: int):
 
 def scan_step(x, dt, A, B, C, state):
     """One position a lane, the recurrence as written. ``x`` [L, H, P],
-    ``dt`` [L, H] float32, ``A`` [H], ``B`` and ``C`` [L, N], ``state``
-    [L, H, P, N] float32 -> (y [L, H, P] float32, the new state)."""
+    ``dt`` [L, H] float32, ``A`` [H], ``B`` and ``C`` [L, N] or [L, G, N],
+    ``state`` [L, H, P, N] float32 -> (y [L, H, P] float32, the new
+    state)."""
     f32 = jnp.float32
+    if B.ndim == 3:   # a group's row for each of its heads
+        Bh, Ch = (_heads_of(a.astype(f32), x.shape[1])[:, :, None, :]
+                  for a in (B, C))
+    else:
+        Bh, Ch = (a.astype(f32)[:, None, None, :] for a in (B, C))
     decay = jnp.exp(dt * A)[:, :, None, None]
-    fed = (dt[:, :, None] * x.astype(f32))[..., None] * B.astype(f32)[
-        :, None, None, :]
+    fed = (dt[:, :, None] * x.astype(f32))[..., None] * Bh
     state = decay * state + fed
-    return jnp.sum(state * C.astype(f32)[:, None, None, :], axis=-1), state
+    return jnp.sum(state * Ch, axis=-1), state
 
 
 def _step_kernel(lanes_ref, n_ref, decay_ref, fed_ref, b_ref, c_ref, s_ref,
-                 y_ref, out_ref):
+                 y_ref, out_ref, heads_a_group: int = 0):
     """Work item ``(i, j)``: the ``hb`` heads from ``j * hb`` of lane
     ``lanes[i]``, in blocks of ``per`` heads = R rows of the state.
     ``decay_ref`` [B, H] (exp(dt A)) sits in SMEM: a head's decay is a
     scalar. ``fed_ref`` (dt x) and ``y_ref`` [B, H / per, R], ``b_ref`` /
     ``c_ref`` [B, N] hold every lane's rows, resident for the whole call:
     the item reads and writes its own. ``s_ref`` / ``out_ref`` [1, hb, P,
-    N] the state before and after (one buffer).
+    N] the state before and after (one buffer). With GROUPS
+    (``heads_a_group`` > 0) ``b_ref`` / ``c_ref`` are [B, G, N] and a block
+    reads the row of ITS group (a block of ``per`` heads never straddles
+    two: ``per`` divides a group's heads).
 
     ``dt x`` is wanted down the state's P axis and ``y`` comes out down
     it, while both live ALONG the lanes of their rows: the item's [blocks,
@@ -161,11 +197,15 @@ def _step_kernel(lanes_ref, n_ref, decay_ref, fed_ref, b_ref, c_ref, s_ref,
 
     @pl.when(n_ref[0] > 0)
     def _():
-        b, c = b_ref[row, :], c_ref[row, :]                        # [1, N]
+        if not heads_a_group:
+            b, c = b_ref[row, :], c_ref[row, :]                    # [1, N]
         fed = jnp.pad(fed_ref[row, tile, :][0],
                       ((0, side - blocks), (0, side - R))).T       # [R.., k..]
         ys = []
         for k in range(blocks):
+            if heads_a_group:
+                g = pl.ds((j * hb + k * per) // heads_a_group, 1)
+                b, c = b_ref[row, g, :][0], c_ref[row, g, :][0]    # [1, N]
             products = []
             for i in range(per):
                 h = k * per + i
@@ -188,33 +228,40 @@ def scan_step_pallas(x, dt, A, B, C, state, lanes, n_live,
                      interpret: bool = False):
     """``scan_step`` as one kernel over the lanes of a work list
     (``kda.work_list``): ``x`` [B, H, P], ``dt`` [B, H] float32, ``A``
-    [H], ``B`` and ``C`` [B, N]; ``state`` [L >= B, H, P, N] float32;
+    [H], ``B`` and ``C`` [B, N] or, by group, [B, G, N]; ``state`` [L >= B,
+    H, P, N] float32;
     ``lanes`` [B] and ``n_live`` [1] int32 ride in as scalar prefetch, and
     ``n_live`` is the grid's bound. The lanes ``lanes[:n_live]`` are
     stepped IN PLACE; no other lane's state is read or written, and its
     ``y`` comes back 0. A state element is computed as ``scan_step``
     computes it; ``y`` sums the same products in another order."""
     nB, H, P = x.shape
-    N = B.shape[1]
+    N = B.shape[-1]
     hb = next(n for n in (HEADS_PER_STEP, 16, 8, 4, 2, 1) if H % n == 0)
+    # with groups, [B, G, N]: the heads that read one row of B and C
+    group = H // B.shape[1] if B.ndim == 3 else 0
     # heads a block: as many as fill a register's 128 lanes with their P
+    # (and lie in ONE group: a block reads one row of B and of C)
     per = next(n for n in range(min(hb, max(1, 128 // P)), 0, -1)
-               if hb % n == 0)
+               if hb % n == 0 and not group % n)
     G, R = H // per, per * P
+    kernel = (functools.partial(_step_kernel, heads_a_group=group)
+              if group else _step_kernel)
     dt = dt.astype(_F32)
     fed = (dt[:, :, None] * x.astype(_F32)).reshape(nB, G, R)
     whole = lambda *s: pl.BlockSpec(s, lambda i, j, lanes, n: (0,) * len(s))  # noqa: E731
     lane = pl.BlockSpec((1, hb, P, N),
                         lambda i, j, lanes, n: (lanes[i], j, 0, 0))
     y, state = pl.pallas_call(
-        _step_kernel,
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             # never an empty grid: what a pipeline with no step writes
             # back is nobody's promise
             grid=(jnp.maximum(n_live[0], 1), H // hb),
             in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                      whole(nB, G, R), whole(nB, N), whole(nB, N), lane],
+                      whole(nB, G, R), whole(*B.shape), whole(*C.shape),
+                      lane],
             out_specs=[whole(nB, G, R), lane]),
         out_shape=[jax.ShapeDtypeStruct((nB, G, R), _F32),
                    jax.ShapeDtypeStruct(state.shape, _F32)],

@@ -478,10 +478,24 @@ SSM_STATE_ROWS_STEPPED = (
     "round's steps x the state-space layers")
 SSM_SCAN_POSITIONS = (
     "dynamo_ssm_scan_positions",
-    "Mamba-1 (selective scan) models: positions the prefill scans of a "
+    "state-space models (Mamba-1 or Mamba-2): positions the prefill scans of a "
     "dispatch ran, all such layers: the lanes' live scan blocks x their "
     "height where the program loops over them, lanes x bucket width "
     "where it does not (the host's mirror, ssm_moe.prefill_mirror)")
+# ``dynamo_layer_parts_run{part}``: histograms carry no labels here, so the
+# label is the end of the name
+LAYER_PARTS_RUN = {
+    part: (f"dynamo_layer_parts_run_{part}",
+           f"hybrid stacks: {what} the steps of a dispatched decode round "
+           "ran (steps x layers of the part; the host's mirror, "
+           "ssm_moe.decode_mirror): a stack of two-part layers runs a "
+           "mixer and a feed-forward part a layer, one of one-part layers "
+           "one part a layer")
+    for part, what in (
+        ("mixer_ssm", "recurrent (state-space, delta-rule, linear) mixers"),
+        ("mixer_attn", "attention and other stateless mixers"),
+        ("experts", "routed-expert parts (the layers that route)"),
+        ("mlp", "dense MLP parts"))}
 MOE_PREFILL_ROWS_SORTED = (
     "dynamo_moe_prefill_rows_sorted",
     "(token, pick) rows the expert layers of a finished prefill program "
@@ -626,7 +640,8 @@ def request_histograms(
                             SSM_SCAN_POSITIONS, ATTN_SHARED_ROWS_READ,
                             ATTN_WINDOW_ROWS_READ, ATTN_WINDOW_ROWS_BOUND,
                             DECODE_ATTN_Q_ROWS_FULL, DECODE_ATTN_Q_ROWS_WINDOW,
-                            PREFILL_LAYER_ROWS, PREFILL_LAYER_ROWS_SKIPPED):
+                            PREFILL_LAYER_ROWS, PREFILL_LAYER_ROWS_SKIPPED,
+                            *LAYER_PARTS_RUN.values()):
             reg.histogram(name, help_,
                           tuple(float(4 ** i) for i in range(3, 13)))
         reg.histogram(*KV_ROW_BYTES, tuple(float(4 ** i) for i in range(3, 12)))

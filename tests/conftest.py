@@ -62,6 +62,7 @@ LONG_FILES = (
     "test_mamba1.py", "test_rehearsal_chat_rate.py",   # PR 51: ~3 min each
     "test_sambay.py",                                  # PR 54: ~2.5 min
     "test_window_gqa_moe.py",                          # PR 58: ~75 s
+    "test_ssm_groups_moe.py",                          # PR 60: ~80 s
 )
 
 

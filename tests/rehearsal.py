@@ -85,6 +85,11 @@ CELLS = {
     # one in the window
     "codeturn": ("laguna-s-ep8-d12.codeturn", "5800580058",
                  "benchmarks/references/window_gqa_moe.py", 0.2),
+    # answers of 96-1024 tokens after prompts of 128-8192 (one or two
+    # chunks of the tiny model's 13 one-part layers): three requests in the
+    # 10 s pre-roll and one or two in the window
+    "agentthink": ("nemotron3-nano-ep8.agentthink", "6000600060",
+                   "benchmarks/references/ssm_groups_moe.py", 0.3),
 }
 
 
@@ -120,7 +125,10 @@ def rehearse(tmp_path, cell, seed, reference, rate_rps):
     coding-turn cell's check carries the toy model's window buffers (8 rows
     a lane) and its three full layers' rows over two chunk boundaries at
     4096 at 18 and 12 query heads over two K/V heads, rotary by kind, under
-    the one-group router with a share of eight."""
+    the one-group router with a share of eight. The agent-turn cell's
+    check carries the toy model's six grouped Mamba-2 states and windows
+    and its two attention layers' rows over a chunk boundary at 4096
+    through one-part layers, with experts stored wider than published."""
     root = REPO if rate_rps is None else checkout_at_rate(
         tmp_path, cell, rate_rps)
     r = run([sys.executable, "benchmarks/run.py", "--workload", cell,

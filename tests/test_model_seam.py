@@ -39,6 +39,7 @@ FAMILIES = {
     "mamba1": ModelConfig.tiny_jamba,
     "differential": ModelConfig.tiny_phi4flash,
     "rotary_gqa": ModelConfig.tiny_laguna,
+    "one_part": ModelConfig.tiny_nemotron_h,
 }
 
 
@@ -151,6 +152,11 @@ def test_the_counter_row_is_declared(family):
                        tmetrics.MOE_LOAD_MAX[0],
                        tmetrics.MOE_PICKS_ROUTED[0],
                        tmetrics.MOE_GROUPS_KEPT_HERE[0]],
+        # the same router, and the Mamba-2 states its steps moved on
+        "one_part": [tmetrics.MOE_TOUCHED[0], tmetrics.MOE_ROUTED[0],
+                     tmetrics.MOE_LOAD_MAX[0], tmetrics.MOE_PICKS_ROUTED[0],
+                     tmetrics.MOE_GROUPS_KEPT_HERE[0],
+                     tmetrics.SSM_STATE_ROWS_STEPPED[0]],
     }[family]
     assert [k.f32_bits for k in layout if k.metric ==
             tmetrics.HC_SINKHORN_RESIDUAL[0]] == [True] * (
@@ -175,7 +181,15 @@ def test_what_the_state_says_of_itself(family):
     assert decode is not None
     live = np.array([True, False, True, False, False, False])
     seen = dict(decode(np.array([41, 9, 17, 300, 1, 1], np.int32), live, 4))
-    if family in ("dense", "mamba2", "mamba1"):
+    # the hybrid stacks also say which PARTS the round's steps ran: a mixer
+    # and a feed-forward part a layer, or ONE part a layer (PR 60)
+    parts = {name: seen.pop(name) for name in list(seen)
+             if name.startswith("dynamo_layer_parts_run_")}
+    assert bool(parts) == (family not in ("dense", "latent", "latent_mhc"))
+    if parts:
+        assert sum(parts.values()) == 4 * c.num_layers * (
+            1 if family == "one_part" else 2)
+    if family in ("dense", "mamba2", "mamba1", "one_part"):
         # the jnp reference scores every lane's whole 128-row region
         assert seen == {tmetrics.DECODE_ATTN_ROWS_READ[0]: 4 * 6 * 128,
                         tmetrics.DECODE_ATTN_ROWS_LIVE[0]: 4 * (40 + 16)}
@@ -202,7 +216,7 @@ def test_what_the_state_says_of_itself(family):
             tmetrics.DECODE_ATTN_Q_ROWS_FULL[0]: 2 * 4 * 24,
             tmetrics.DECODE_ATTN_Q_ROWS_WINDOW[0]: 2 * 4 * 72}
     assert (prefill is not None) == (family in (
-        "lightning_sparse", "mamba1", "differential"))
+        "lightning_sparse", "mamba1", "differential", "mamba2", "one_part"))
 
 
 @pytest.mark.parametrize("family", FAMILIES)
@@ -248,7 +262,7 @@ def test_the_rounds_step_through_the_front_door(family):
     assert set(kinds) <= set(ctx) and not set(kinds) & set(ring)
     assert bool(kinds) == (family in ("mamba2", "lightning_sparse",
                                       "kda_latent", "mamba1",
-                                      "differential"))
+                                      "differential", "one_part"))
     stepped = {n: ctx[n] for n in kinds}
     stats = jax.eval_shape(lambda: llama.stats_zero(c))
     i32 = jax.ShapeDtypeStruct((B,), jnp.int32)

@@ -439,14 +439,13 @@ def test_from_hf_dict_reads_the_published_keys_and_refuses_the_unbuilt():
                                              + 3 * 8448 * 2)
     assert ssm_moe.kv_row_bytes(c, 2) == 4096
     for key, value in (
-            ("position_embedding_type", "rope"), ("mamba_n_groups", 8),
+            ("position_embedding_type", "rope"), ("mamba_n_groups", 7),
             ("attention_bias", True), ("mamba_proj_bias", True),
             ("hidden_act", "gelu"), ("normalization_function", "layernorm"),
             ("rope_scaling", {"type": "yarn", "factor": 4}),
             ("tie_word_embeddings", False), ("mamba_conv_bias", False),
             ("layer_types", ["mamba"] * 9 + ["window"]),
             ("layer_types", ["mamba"] * 9),
-            ("mamba_n_heads", 64),
             # a share is a whole-number split of the published experts
             ("num_local_experts", 35),
             ("expert_share", {"published_experts": 72, "of": 3, "index": 0}),

@@ -130,6 +130,8 @@ def test_registry_render_and_snapshot():
         "dynamo_engine_step_gap_clean_seconds",
         "dynamo_engine_round_prefill_tokens_ahead",
         "dynamo_engine_dispatch_found_dry",
+        "dynamo_layer_parts_run_mixer_ssm", "dynamo_layer_parts_run_mixer_attn",
+        "dynamo_layer_parts_run_experts", "dynamo_layer_parts_run_mlp",
     }
     reg.get("dynamo_request_ttft_seconds").observe(0.2)
     text = reg.render()
