@@ -602,7 +602,7 @@ class TpuEngine:
         # dispatched round and a prefill dispatch (None: it has none)
         self._decode_mirror = llama.decode_mirror(
             c, e.max_context, e.flush_every, self.decode_attn)
-        self._prefill_mirror = llama.prefill_mirror(c)
+        self._prefill_mirror = llama.prefill_mirror(c, self.decode_attn)
         # bytes a token holds in the ctx region, and bytes a lane holds
         # in recurrent state whatever its context: observed once, here
         recurrent = llama.state_kinds(self.ctx)
@@ -3844,9 +3844,8 @@ class TpuEngine:
             width, q_starts, seq_lens, ctx_span)
         self._h_pf_live.observe(live)
         self._h_pf_scored.observe(scored)
-        if self._prefill_mirror is not None:
-            self._observe(self._prefill_mirror(
-                width, q_starts, seq_lens, scored))
+        self._observe(self._prefill_mirror(
+            width, q_starts, seq_lens, scored, ctx_span))
         # and the prompt positions it computed in CONTINUING chunks
         self._h_pf_continued.observe(sum(
             min(max(int(n) - int(q), 0), width)

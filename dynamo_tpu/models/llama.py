@@ -102,13 +102,17 @@ one line in ``block_of``; no line of engine/engine.py.
         multiple of (a prefill chunk starts on a page).
     pages_resume(c) -> bool             whether sealed pages of rows are
         all a prompt needs to resume (the prefix cache's premise).
-  the host's mirrors of what the kernels read (None: the block has none)
+  the host's mirrors of what the kernels read (``decode_mirror`` None: the
+  block has none)
     decode_mirror(c, max_context, ring_len, attn)
         -> f(ctx_lens, live, n_steps) -> ((metric, value), ...)
         (the dense decoder's: the region rows its attention's work list
         reads a layer, beside the live lanes' own)
-    prefill_mirror(c)
-        -> f(width, q_starts, seq_lens, scored) -> ((metric, value), ...)
+    prefill_mirror(c, attn)
+        -> f(width, q_starts, seq_lens, scored, ctx_span)
+           -> ((metric, value), ...)
+        (every block's: the query blocks its attention layers ran, and
+        those that ran through the fused prefill kernel)
   and two live-row rules that are the front door's own
   (``live_row_block``, ``moe_prefill_rows_sorted``).
 
@@ -292,11 +296,14 @@ def decode_mirror(config: ModelConfig, max_context: int, ring_len: int,
 
 
 @_hands_over
-def prefill_mirror(config: ModelConfig) -> Optional[Callable]:
+def prefill_mirror(config: ModelConfig, attn: DecodeAttention) -> Callable:
     """The host's mirror of what a prefill dispatch's attention layers
-    score beyond ``prefill_attention_pairs``: ``f(width, q_starts,
-    seq_lens, scored) -> ((metric, value), ...)``, or None (here)."""
-    return None
+    ran beyond ``prefill_attention_pairs``: ``f(width, q_starts,
+    seq_lens, scored, ctx_span) -> ((metric, value), ...)``. Here: the
+    query blocks of every layer's attention, none of them through the
+    fused kernel (K and V are shared by a group of heads; ``attn`` says
+    what the engine's programs are traced for)."""
+    return mla_moe.blocks_mirror(attn, config.num_layers)
 
 
 # ---------------------------------------------------------------------------

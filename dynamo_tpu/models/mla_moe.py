@@ -72,9 +72,12 @@ from dynamo_tpu.models.moe import grouped_experts
 from dynamo_tpu.ops import hyper_connection as hc
 from dynamo_tpu.ops.attention import (
     PREFILL_BLOCK,
+    REFERENCE_IMPL,
     DecodeAttention,
     PriorContext,
-    prefill_attention,
+    fused_prefill_attention,
+    prefill_fuses,
+    prefill_query_blocks,
 )
 from dynamo_tpu.ops import latent_decode
 from dynamo_tpu.ops.latent_decode import latent_decode_attention
@@ -86,6 +89,8 @@ from dynamo_tpu.telemetry.metrics import (
     MOE_LOAD_MAX,
     MOE_ROUTED,
     MOE_TOUCHED,
+    PREFILL_ATTN_BLOCKS,
+    PREFILL_ATTN_FUSED_BLOCKS,
     Counter,
 )
 
@@ -313,8 +318,29 @@ def decode_mirror(config: ModelConfig, max_context: int, ring_len: int,
     return rows_mirror(latent_decode.round_rows, attn, max_context)
 
 
-def prefill_mirror(config: ModelConfig):
-    return None   # prefill_attention_pairs is all its prefill scores
+def blocks_mirror(attn: DecodeAttention, layers: int, fused_layers: int = 0,
+                  n_heads: int = 0):
+    """A block's ``prefill_mirror`` of the query blocks its ``layers``
+    prefill attentions ran a dispatch, and those of them that ran through
+    the fused kernel: ``fused_layers`` expanded latent layers of
+    ``n_heads`` heads, where the programs are traced for TPU devices
+    (``attn`` names a kernel: ``fused_prefill_attention`` lowers by
+    platform, as ``decode_attention_for`` chooses) at a geometry the
+    kernel takes (``prefill_fuses``, which the call site asks too)."""
+    def mirror(width: int, q_starts, seq_lens, scored: int, ctx_span: int):
+        blocks = prefill_query_blocks(width, q_starts, seq_lens)
+        fused = attn.impl != REFERENCE_IMPL and prefill_fuses(
+            width, n_heads, n_heads, ctx_span)
+        return ((PREFILL_ATTN_BLOCKS[0], layers * blocks),
+                (PREFILL_ATTN_FUSED_BLOCKS[0], fused_layers * blocks * fused))
+    return mirror
+
+
+def prefill_mirror(config: ModelConfig, attn: DecodeAttention):
+    """Every layer's prefill attention is the expanded latent one."""
+    return blocks_mirror(attn, config.num_layers,
+                         fused_layers=config.num_layers,
+                         n_heads=dims(config)["nh"])
 
 
 # ---------------------------------------------------------------------------
@@ -680,15 +706,7 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
             rows_out.append(row)
             lanes = lambda a: a.reshape(K, T, *a.shape[1:])  # noqa: E731
             k, v = _expand_kv(c, lp, row)
-            if not span:
-                # in the fresh programs V rides zero-padded to the key's
-                # width and the pad is cut from the result: the lowering
-                # the routed-expert chat cell's programs are pinned to
-                # (tests/test_tpu_lowering.py); the attention would take
-                # V at its own width, as the continuing programs pass it
-                v = jnp.pad(v, ((0, 0), (0, 0),
-                                (0, k.shape[-1] - d["v"])))
-            else:
+            if span:
                 work = _expand_prior(
                     c, work, ctx_kv[ROW], params["layers"]["wkb"],
                     params["layers"]["wvb"], jnp.int32(l), slots, below)
@@ -697,9 +715,10 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
             q = jnp.concatenate([q_nope, q_rope], -1)
             if scale_times != 1.0:
                 q = _scaled(c, q, scale_times)
-            o = prefill_attention(lanes(q), lanes(k), lanes(v), q_starts,
-                                  seq_lens, prior, ctx_span=span)
-            attn = o[..., :d["v"]].reshape(K * T, -1)
+            o = fused_prefill_attention(lanes(q), lanes(k), lanes(v),
+                                        q_starts, seq_lens, prior,
+                                        ctx_span=span)
+            attn = o.reshape(K * T, -1)
         h, stats = _layer_out(c, params, lp, l, h, attn, mix, valid, stats)
 
     rows = jnp.stack(rows_out).reshape(

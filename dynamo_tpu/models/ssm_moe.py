@@ -228,6 +228,7 @@ from dynamo_tpu.ops.attention import (
     ctx_decode_attention,
     dense_chunk_rows,
     dense_round_rows,
+    fused_prefill_attention,
     prefill_attention,
     region_trips,
 )
@@ -278,6 +279,11 @@ KV = mla_moe.ROW      # the latent layers' one row kind
 WK, WV = "wk", "wv"   # the window layers' K/V rows: a modular buffer a lane
 ROW_LAYERS = ("attention", "sparse_attention")   # kinds that keep K/V rows
                       # of the region's length
+# kinds whose prefill runs ``prefill_attention`` (or, the latent kind, its
+# fused form)
+PREFILL_ATTENTION_KINDS = ("attention", "sparse_attention",
+                           "window_attention", "cross_attention",
+                           "latent_attention")
 # the kinds of a stack of ONE-PART layers whose one part is the feed-forward
 # part (routed experts + a shared one | one dense MLP): no mixer, no state
 FFN_KINDS = ("experts", "mlp")
@@ -2039,7 +2045,7 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
                     prior = PriorContext(*work, jnp.int32(0),
                                          jnp.arange(K, dtype=jnp.int32))
                 lat.append(row.reshape(K, T, 1, -1))
-                o = prefill_attention(
+                o = fused_prefill_attention(
                     lanes(jnp.concatenate([q_nope, q_rope], -1)), lanes(k),
                     lanes(v), q_starts, seq_lens, prior, ctx_span=span)
                 mix = o.reshape(K * T, -1) @ lp["wo"]
@@ -2244,8 +2250,8 @@ def _live_prefill(config, params, ctx_kv, tokens, slots, q_starts, seq_lens,
                         jnp.int32(len(lat)), slots, below)
                     prior = PriorContext(*work, jnp.int32(0),
                                          jnp.arange(K, dtype=jnp.int32))
-                seq = (prefill_attention(qq, k, v, q_starts, seq_lens, prior,
-                                         ctx_span=span),)
+                seq = (fused_prefill_attention(
+                    qq, k, v, q_starts, seq_lens, prior, ctx_span=span),)
             lat.append(row[:, :, None])
         else:
             z, xbc, dt = ins
@@ -2645,7 +2651,26 @@ def _rows_mirror(config: ModelConfig, max_context: int, ring_len: int,
     return mirror
 
 
-def prefill_mirror(config: ModelConfig):
+def prefill_mirror(config: ModelConfig, attn: DecodeAttention):
+    """The query blocks the stack's attention layers of every kind ran,
+    and those of its ``latent_attention`` layers that ran through the
+    fused kernel (``mla_moe.blocks_mirror``); beside them, what the
+    stack's own layers add (``_prefill_mirror_of_kinds``)."""
+    d = dims(config)
+    blocks = mla_moe.blocks_mirror(
+        attn, sum(t in PREFILL_ATTENTION_KINDS for t in d["kinds"]),
+        fused_layers=d["n_latent"], n_heads=config.num_heads)
+    kinds = _prefill_mirror_of_kinds(config)
+
+    def mirror(width: int, q_starts, seq_lens, scored: int, ctx_span: int):
+        out = blocks(width, q_starts, seq_lens, scored, ctx_span)
+        if kinds is not None:
+            out += kinds(width, q_starts, seq_lens, scored)
+        return out
+    return mirror
+
+
+def _prefill_mirror_of_kinds(config: ModelConfig):
     """The sparse layers score a chunk's whole causal context under the
     selection's mask (``scored``, what ``prefill_attention_pairs`` counts
     a layer): beside it, what a gathering prefill would score. A

@@ -47,6 +47,7 @@ from dynamo_tpu.ops.flash_decode import (
     flash_decode_attention,
     flash_decode_attention_reference,
 )
+from dynamo_tpu.ops.flash_prefill import flash_prefill_attention
 from dynamo_tpu.parallel.mesh import AXIS_TENSOR
 
 NEG_INF = -1e30
@@ -494,6 +495,125 @@ def prefill_attention(
         0, ends[-1], query_block,
         jnp.zeros(qt.shape[:-1] + (hd_v,), qt.dtype))
     return out.transpose(0, 3, 1, 2, 4).reshape(K, T, n_heads, hd_v)
+
+
+def prefill_fuses(width: int, n_heads: int, kv_heads: int,
+                  ctx_span: int = 0, block: int = PREFILL_BLOCK) -> bool:
+    """The SHAPE RULE of ``fused_prefill_attention``: does a call of this
+    geometry run the fused kernel (ops/flash_prefill.py) on a TPU? K and V
+    per head (an expanded latent chunk) in whole blocks: the kernel's
+    tiles never slide back over rows they have seen."""
+    blk = min(block, width)
+    return (n_heads == kv_heads and width % blk == 0
+            and ctx_span % min(block, ctx_span or block) == 0)
+
+
+def prefill_query_blocks(width: int, q_starts, seq_lens,
+                         block: int = PREFILL_BLOCK) -> int:
+    """The (lane, query block) pairs with a live row of one prefill
+    dispatch, a layer: the items of ``prefill_attention``'s work list and
+    of the fused kernel's (the host's count, beside
+    ``prefill_attention_pairs``)."""
+    blk = min(block, width)
+    return sum(-(-min(max(int(n) - int(q), 0), width) // blk)
+               for q, n in zip(q_starts, seq_lens))
+
+
+def prefill_steps(n_live, below, width: int, ctx_span: int, block: int):
+    """The flat list of STEPS the fused kernel walks, from the lanes'
+    live chunk rows ``n_live`` [K] and prior rows ``below`` [K]: for every
+    (lane, query block) pair with a live row, lane after lane and block
+    after block, the lane's prior blocks, then the chunk's blocks up to
+    the causal diagonal and the live length: what ``prefill_attention``'s
+    loops run, trip for trip, and ``prefill_attention_pairs`` counts.
+    Returns ((lane_of, qb_of, j_of, last_of, total), block_live [K, nq],
+    pblk [K])."""
+    K = n_live.shape[0]
+    blk = min(block, width)
+    nq = width // blk
+    i32 = jnp.int32
+    cb = min(block, ctx_span) if ctx_span else blk
+    pblk = (below.astype(i32) + cb - 1) // cb
+    nblk_live = (n_live.astype(i32) + blk - 1) // blk
+    qbs = jnp.arange(nq, dtype=i32)[None, :]
+    block_live = qbs < nblk_live[:, None]
+    counts = jnp.where(
+        block_live, pblk[:, None] + jnp.minimum(qbs + 1, nblk_live[:, None]),
+        0).reshape(K * nq)
+    item_of, j_of, ends = flat_items(
+        counts, K * (nq * (ctx_span // cb) + nq * (nq + 1) // 2))
+    return ((item_of // nq, item_of % nq, j_of,
+             j_of == counts[item_of] - 1, ends[-1]), block_live, pblk)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("block", "ctx_span", "interpret",
+                                    "heads"))
+def fused_prefill_attention(
+    q: jnp.ndarray,          # [K, T, n_heads, hd]
+    k_new: jnp.ndarray,      # [K, T, n_heads, hd] — K and V per head
+    v_new: jnp.ndarray,      # [K, T, n_heads, hd_v]
+    q_starts: jnp.ndarray,   # [K] i32
+    seq_lens: jnp.ndarray,   # [K] i32
+    ctx: Optional[PriorContext] = None,   # no int8 region
+    block: int = PREFILL_BLOCK,
+    ctx_span: int = 0,
+    interpret: Optional[bool] = None,   # tests, tools: the kernel itself,
+                             # interpreted or not, whatever the platform
+    heads: int = 0,          # heads a grid step (tools sweep); 0: the
+                             # kernel's own
+) -> jnp.ndarray:
+    """``prefill_attention`` for the calls that hold no tree mask, no
+    selection, no window and no int8 region, with K and V per head: an
+    EXPANDED latent chunk (models/mla_moe.py, the ``latent_attention``
+    kind of models/ssm_moe.py). On a TPU it is ONE Mosaic call a layer
+    (ops/flash_prefill.py) that walks the same (lane, query block) work
+    list over the same key blocks, scores, probabilities and accumulator
+    in VMEM; elsewhere (the CPU test meshes), and at a geometry outside
+    ``prefill_fuses``, the XLA loops themselves. Same results to float32
+    rounding: rows of a query block with no live row and rows that see no
+    key are 0. Jitted with the layer a VALUE, like the loops: a program's
+    layers share one lowered body."""
+    K, T, n_heads, _ = q.shape
+    span = (ctx_span or ctx.k.shape[3]) if ctx is not None else 0
+
+    def loops(q, k_new, v_new, q_starts, seq_lens, ctx):
+        return prefill_attention(q, k_new, v_new, q_starts, seq_lens, ctx,
+                                 block=block, ctx_span=ctx_span)
+
+    if not prefill_fuses(T, n_heads, k_new.shape[2], span, block) or (
+            ctx is not None and ctx.k_scale is not None):
+        return loops(q, k_new, v_new, q_starts, seq_lens, ctx)
+
+    def kernel(q, k_new, v_new, q_starts, seq_lens, ctx, interpret=False):
+        i32 = jnp.int32
+        q_starts, seq_lens = q_starts.astype(i32), seq_lens.astype(i32)
+        n_live = jnp.clip(seq_lens - q_starts, 0, T)
+        below = jnp.minimum(jnp.minimum(q_starts, seq_lens), span)
+        steps, block_live, pblk = prefill_steps(
+            n_live, below, T, span, block)
+        # keys as COLUMNS, the chunk's and the region's: [.., hd, rows]
+        # is the layout XLA:TPU gives a [.., rows, 192] array by itself
+        # (192 is no whole number of 128-lane tiles), so the transposed
+        # view of the workspace is the buffer ``_expand_prior`` wrote,
+        # where the row-major one was a copy of it a layer
+        region = None if ctx is None else (
+            ctx.k.swapaxes(3, 4), ctx.v, ctx.layer, ctx.slots, pblk, below)
+        out = flash_prefill_attention(
+            q.transpose(0, 2, 1, 3),
+            k_new.transpose(0, 2, 3, 1).astype(q.dtype),
+            v_new.transpose(0, 2, 1, 3).astype(q.dtype),
+            steps, n_live, region, block=min(block, T),
+            ctx_block=min(block, span), heads=heads, interpret=interpret)
+        # no step wrote the tile of a query block with no live row
+        rows = jnp.repeat(block_live, min(block, T), axis=1)
+        return jnp.where(rows[:, :, None], out, 0).reshape(
+            K, T, n_heads, v_new.shape[3])
+
+    if interpret is not None:
+        return kernel(q, k_new, v_new, q_starts, seq_lens, ctx, interpret)
+    return jax.lax.platform_dependent(
+        q, k_new, v_new, q_starts, seq_lens, ctx, tpu=kernel, default=loops)
 
 
 def prefill_attention_pairs(

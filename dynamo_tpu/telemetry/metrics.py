@@ -439,6 +439,16 @@ PREFILL_ATTN_LIVE = ("dynamo_engine_prefill_attn_live_pairs",
 PREFILL_ATTN_SCORED = ("dynamo_engine_prefill_attn_scored_pairs",
                        "(query, key) pairs a prefill dispatch computes a "
                        "score for: whole blocks of its live rows")
+PREFILL_ATTN_BLOCKS = (
+    "dynamo_engine_prefill_attn_blocks",
+    "(lane, query block) pairs with a live row that the attention layers "
+    "of a prefill dispatch ran: the work list's items x the layers with a "
+    "prefill attention (the host's mirror, llama.prefill_mirror)")
+PREFILL_ATTN_FUSED_BLOCKS = (
+    "dynamo_engine_prefill_attn_fused_blocks",
+    "of dynamo_engine_prefill_attn_blocks, the pairs that ran through the "
+    "fused kernel (ops/flash_prefill.py): the expanded latent layers' on "
+    "TPU devices at a geometry inside attention.prefill_fuses, 0 elsewhere")
 ROUND_LIVE_LANE_STEPS = ("dynamo_engine_round_live_lane_steps",
                          "lanes live at dispatch x steps per fused "
                          "decode round")
@@ -630,7 +640,8 @@ def request_histograms(
                             ROUND_LIVE_LANE_STEPS, ROUND_TOKENS,
                             MOE_TOUCHED, MOE_ROUTED, MOE_LOAD_MAX,
                             MOE_PICKS_ROUTED, MOE_GROUPS_KEPT_HERE,
-                            PREFILL_CONTINUED):
+                            PREFILL_CONTINUED, PREFILL_ATTN_BLOCKS,
+                            PREFILL_ATTN_FUSED_BLOCKS):
             reg.histogram(name, help_, TOKEN_BUCKETS)
         reg.histogram(*HC_SINKHORN_RESIDUAL,
                       tuple(10.0 ** i for i in range(-9, 1)))

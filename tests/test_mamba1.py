@@ -195,8 +195,9 @@ def test_the_host_mirror_counts_the_scans_live_blocks(monkeypatch):
     """``ssm_moe.prefill_mirror`` at the published depth (26 Mamba-1
     layers): a lane's live 256-row scan blocks where the bucket loops,
     every bucket row where it does not."""
-    mirror = llama.prefill_mirror(ModelConfig.from_hf_dict(published()))
-    count = lambda *a: dict(mirror(*a, 0))[  # noqa: E731
+    mirror = llama.prefill_mirror(ModelConfig.from_hf_dict(published()),
+                                  REFERENCE)
+    count = lambda *a: dict(mirror(*a, 0, 0))[  # noqa: E731
         "dynamo_ssm_scan_positions"]
     # three lanes of the 1024 bucket: 700 rows = 3 blocks of 256, 90 = 1,
     # a dummy lane none

@@ -218,6 +218,8 @@ def test_readme_documents_canonical_series():
         # prefill attention work and waste (engine dispatch sites, PR 29)
         "dynamo_engine_prefill_attn_live_pairs",
         "dynamo_engine_prefill_attn_scored_pairs",
+        "dynamo_engine_prefill_attn_blocks",
+        "dynamo_engine_prefill_attn_fused_blocks",
     ):
         assert name in readme, f"{name} missing from README"
     for endpoint in ("/debug/trace", "/debug/flight", "/debug/prof",

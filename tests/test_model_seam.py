@@ -19,7 +19,7 @@ import pytest
 
 from dynamo_tpu.models import llama
 from dynamo_tpu.models.config import ModelConfig
-from dynamo_tpu.ops.attention import REFERENCE
+from dynamo_tpu.ops.attention import REFERENCE, DecodeAttention
 from dynamo_tpu.telemetry import metrics as tmetrics
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -175,7 +175,7 @@ def test_what_the_state_says_of_itself(family):
     assert llama.page_multiple(c) == (8 if family == "lightning_sparse"
                                       else 1)
     decode = llama.decode_mirror(c, 128, 4, REFERENCE)
-    prefill = llama.prefill_mirror(c)
+    prefill = llama.prefill_mirror(c, REFERENCE)
     # every family with an attention layer mirrors its region reads: the
     # latent rows', the sparse layers', or (PR 53) the dense K and V rows'
     assert decode is not None
@@ -215,8 +215,34 @@ def test_what_the_state_says_of_itself(family):
             tmetrics.ATTN_WINDOW_ROWS_BOUND[0]: 4 * 4 * 2 * 8,
             tmetrics.DECODE_ATTN_Q_ROWS_FULL[0]: 2 * 4 * 24,
             tmetrics.DECODE_ATTN_Q_ROWS_WINDOW[0]: 2 * 4 * 72}
-    assert (prefill is not None) == (family in (
-        "lightning_sparse", "mamba1", "differential", "mamba2", "one_part"))
+    # every family mirrors the query blocks its prefill attentions ran (a
+    # 300-row chunk over 512 prior rows and a dummy lane in the 1024
+    # bucket: two blocks a layer), and which of them the fused kernel of
+    # the expanded latent layers took: none under the jnp reference (the
+    # CPU meshes), every latent layer's where the programs are traced for
+    # a kernel, at a geometry inside its shape rule
+    lanes = (1024, [512, 0], [812, 0])
+    ran = dict(prefill(*lanes, 0, 4096))
+    latent = c.num_layers if family in ("latent", "latent_mhc") else sum(
+        t == "latent_attention"
+        for t in (c.hybrid_dict or {}).get("layer_types", ()))
+    assert bool(latent) == (family in ("latent", "latent_mhc", "kda_latent"))
+    assert ran[tmetrics.PREFILL_ATTN_FUSED_BLOCKS[0]] == 0
+    assert ran[tmetrics.PREFILL_ATTN_BLOCKS[0]] % 2 == 0
+    assert (ran[tmetrics.PREFILL_ATTN_BLOCKS[0]]
+            >= ran[tmetrics.PREFILL_ATTN_FUSED_BLOCKS[0]])
+    if family in ("dense", "latent", "latent_mhc"):
+        assert ran[tmetrics.PREFILL_ATTN_BLOCKS[0]] == 2 * c.num_layers
+    kernel = llama.prefill_mirror(c, DecodeAttention("pallas"))
+    assert dict(kernel(*lanes, 0, 4096))[
+        tmetrics.PREFILL_ATTN_FUSED_BLOCKS[0]] == 2 * latent
+    # a region that is no whole number of blocks: the loops run
+    assert dict(kernel(*lanes, 0, 4000))[
+        tmetrics.PREFILL_ATTN_FUSED_BLOCKS[0]] == 0
+    assert (set(ran) > {tmetrics.PREFILL_ATTN_BLOCKS[0],
+                        tmetrics.PREFILL_ATTN_FUSED_BLOCKS[0]}) == (
+        family in ("lightning_sparse", "mamba1", "differential", "mamba2",
+                   "one_part"))
 
 
 @pytest.mark.parametrize("family", FAMILIES)

@@ -18,9 +18,13 @@ from dynamo_tpu.models import llama
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.ops.attention import (
     PriorContext,
+    fused_prefill_attention,
     prefill_attention,
     prefill_attention_pairs,
+    prefill_fuses,
+    prefill_steps,
 )
+from dynamo_tpu.ops.flash_prefill import flash_prefill_attention
 
 HD = 16
 S = 48          # region rows per lane
@@ -36,7 +40,7 @@ def dense_reference(q, k_new, v_new, q_starts, seq_lens, region=None,
     q, k_new, v_new = (np.asarray(x, np.float32) for x in (q, k_new, v_new))
     K, T, nh, hd = q.shape
     kvh = k_new.shape[2]
-    out = np.zeros((K, T, nh, hd), np.float32)
+    out = np.zeros((K, T, nh, v_new.shape[3]), np.float32)
     for lane in range(K):
         qs, sl = int(q_starts[lane]), int(seq_lens[lane])
         n_ctx = min(qs, sl) if region is not None else 0
@@ -231,6 +235,148 @@ def test_int8_region_is_dequantized_per_block():
                      scale[0], scale[1]),
         block=BLOCK)
     check(out, ref, live_rows(T, q_starts, seq_lens), jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# the fused kernel of the expanded latent chunks (ops/flash_prefill.py),
+# interpreted, against the loops: K and V per head, V narrower than K
+
+FUSED_T, FUSED_SPAN = 32, 48
+# (q_starts, live rows of each chunk, with a region)
+FUSED_CASES = [
+    pytest.param([0, 0, 0], [32, 32, 32], False, id="fresh"),
+    pytest.param([0, 0, 0], [32, 19, 3], False,
+                 id="fresh-last-block-partly-dead"),
+    pytest.param([13, 16, 40], [32, 9, 5], True,
+                 id="continuing-ragged-starts-and-lengths"),
+    pytest.param([24, 0, 7], [32, 0, 32], True, id="dummy-lane"),
+    pytest.param([0, 48, 0], [20, 32, 0], True,
+                 id="fresh-lane-in-a-continuing-program"),
+    pytest.param([0, 0, 0], [0, 0, 0], True, id="nothing-live"),
+]
+
+
+def fused_case(seed, dtype=jnp.float32, K=3, nh=4, hd=24, hd_v=16):
+    rng = np.random.default_rng(seed)
+
+    def draw(*shape):
+        return jnp.asarray(rng.standard_normal(shape), dtype)
+
+    return (draw(K, FUSED_T, nh, hd), draw(K, FUSED_T, nh, hd),
+            draw(K, FUSED_T, nh, hd_v),
+            (draw(1, nh, 5, FUSED_SPAN, hd), draw(1, nh, 5, FUSED_SPAN, hd_v)),
+            jnp.asarray(rng.permutation(5)[:K], jnp.int32))
+
+
+@pytest.mark.parametrize("heads", [1, 4], ids=["a-head-a-step", "all-heads"])
+@pytest.mark.parametrize("q_starts,n_live,with_ctx", FUSED_CASES)
+def test_fused_kernel_equals_the_loops(q_starts, n_live, with_ctx, heads):
+    """Every row, live or not, to float32-rounding distance: the same
+    masks over the same blocks in the same order; dead blocks and dummy
+    lanes 0 in both."""
+    q, k, v, region, slots = fused_case(sum(n_live) + heads)
+    qs = jnp.asarray(q_starts, jnp.int32)
+    sl = qs + jnp.asarray(n_live, jnp.int32)
+    ctx = PriorContext(*region, 0, slots) if with_ctx else None
+    assert prefill_fuses(FUSED_T, 4, 4, FUSED_SPAN * with_ctx, BLOCK)
+    want = np.asarray(prefill_attention(q, k, v, qs, sl, ctx, block=BLOCK))
+    got = np.asarray(fused_prefill_attention(
+        q, k, v, qs, sl, ctx, block=BLOCK, interpret=True, heads=heads))
+    assert got.shape == want.shape == (3, FUSED_T, 4, 16)
+    np.testing.assert_allclose(got, want, atol=2e-6, rtol=2e-6)
+    dead = ~np.repeat(live_rows(FUSED_T, q_starts, np.asarray(sl))[
+        :, ::BLOCK], BLOCK, axis=1)
+    assert not got[dead].any()
+    ref = dense_reference(q, k, v, q_starts, np.asarray(sl),
+                          region if with_ctx else None, np.asarray(slots))
+    check(got, ref, live_rows(FUSED_T, q_starts, np.asarray(sl)),
+          jnp.float32)
+
+
+@pytest.mark.parametrize("q_starts,n_live,with_ctx", FUSED_CASES)
+def test_pair_count_is_what_the_fused_kernel_walks(q_starts, n_live,
+                                                   with_ctx):
+    """``prefill_attention_pairs`` still mirrors the device: the list the
+    kernel's grid walks holds one step a scored (query block, key block)
+    pair, each item's steps ascending and its last one marked."""
+    span = FUSED_SPAN * with_ctx
+    sl = np.asarray(q_starts) + np.asarray(n_live)
+    below = np.minimum(np.minimum(q_starts, sl), span)
+    (lane_of, qb_of, j_of, last_of, total), block_live, pblk = prefill_steps(
+        jnp.asarray(n_live), jnp.asarray(below), FUSED_T, span, BLOCK)
+    total = int(total)
+    assert total * BLOCK * BLOCK == prefill_attention_pairs(
+        FUSED_T, q_starts, sl, span, block=BLOCK)[1]
+    items = list(zip(np.asarray(lane_of)[:total], np.asarray(qb_of)[:total]))
+    want = [(lane, qb) for lane, n in enumerate(n_live)
+            for qb in range(-(-n // BLOCK))
+            for _ in range(-(-int(below[lane]) // BLOCK) + qb + 1)]
+    assert items == want
+    assert int(np.asarray(last_of)[:total].sum()) == len(set(want)) == int(
+        block_live.sum())
+    j_of = np.asarray(j_of)[:total]
+    assert all(j_of[i] == (0 if i == 0 or items[i] != items[i - 1]
+                           else j_of[i - 1] + 1) for i in range(total))
+
+
+def test_fused_kernel_at_whole_lanes():
+    """At the served widths (blocks of whole 128-lane tiles, V 128 wide)
+    the running max rides replicated over a register's lanes and the
+    running sum as lane-wise partial sums: the form the chip runs, here
+    interpreted at one block of 128."""
+    rng = np.random.default_rng(3)
+
+    def draw(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    q, k, v = draw(2, 256, 2, 192), draw(2, 256, 2, 192), draw(2, 256, 2, 128)
+    ctx = PriorContext(draw(1, 2, 3, 256, 192), draw(1, 2, 3, 256, 128), 0,
+                       jnp.asarray([2, 0], jnp.int32))
+    qs, sl = jnp.asarray([130, 256]), jnp.asarray([130 + 256, 256 + 9])
+    want = np.asarray(prefill_attention(q, k, v, qs, sl, ctx, block=128))
+    got = np.asarray(fused_prefill_attention(q, k, v, qs, sl, ctx, block=128,
+                                             interpret=True, heads=2))
+    np.testing.assert_allclose(got, want, atol=2e-6, rtol=2e-6)
+    assert not got[1, 128:].any() and got[1, :9].any()
+
+
+def test_fused_kernel_row_that_sees_no_key_emits_zero():
+    """The kernel's own gate (no caller's mask reaches it under plain
+    causality: every listed row sees key 0): a lane on the list whose
+    live length the kernel is told is 0 scores only masked keys, holds
+    p = exp(0) a key, and must emit 0, not their mean."""
+    q, k, v, _, _ = fused_case(5, K=1)
+    steps, _, _ = prefill_steps(jnp.asarray([FUSED_T]), jnp.asarray([0]),
+                                FUSED_T, 0, BLOCK)
+    out = flash_prefill_attention(
+        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 3, 1),
+        v.transpose(0, 2, 1, 3), steps, jnp.asarray([0]), block=BLOCK,
+        heads=2, interpret=True)
+    assert out.shape == (1, FUSED_T, 4 * 16) and not np.asarray(out).any()
+
+
+@pytest.mark.parametrize("width,heads,kv_heads,span,fuses", [
+    (4096, 32, 32, 16384, True),
+    (128, 32, 32, 0, True),
+    (1024, 32, 8, 0, False),     # K and V shared by a group of heads
+    (300, 32, 32, 0, False),     # the last block would slide back
+    (256, 32, 32, 1000, False),  # and so would the region's
+])
+def test_fused_kernel_shape_rule(width, heads, kv_heads, span, fuses):
+    assert prefill_fuses(width, heads, kv_heads, span) is fuses
+
+
+def test_outside_the_shape_rule_the_loops_run():
+    """A width that is no whole number of blocks: the one call site gets
+    the loops' own result (bit for bit), kernel asked for or not."""
+    q, k, v, region, slots = fused_case(9)
+    q, k, v = q[:, :20], k[:, :20], v[:, :20]
+    qs, sl = jnp.asarray([0, 9, 0]), jnp.asarray([20, 26, 0])
+    ctx = PriorContext(*region, 0, slots)
+    np.testing.assert_array_equal(
+        np.asarray(fused_prefill_attention(q, k, v, qs, sl, ctx, block=BLOCK,
+                                           interpret=True)),
+        np.asarray(prefill_attention(q, k, v, qs, sl, ctx, block=BLOCK)))
 
 
 # ---------------------------------------------------------------------------
@@ -469,3 +615,24 @@ def test_loops_are_rolled_and_layers_share_one_attention(shapes):
                               text)) == 1
         assert len(re.findall(r"call @prefill_attention", text)) == \
             CFG.num_layers
+
+
+@pytest.mark.parametrize("span", [0, 1024], ids=["fresh", "ctx"])
+def test_latent_layers_share_one_fused_attention(span):
+    """The expanded latent chunks' call site is jitted like the loops, the
+    layer a value: a program's layers call ONE lowered body (here, on the
+    CPU, the loops inside it: the kernel is a TPU lowering)."""
+    cfg = ModelConfig.tiny_mla_moe(dtype="float32")
+    params = abstract(jax.eval_shape(
+        lambda: llama.serving_params(cfg, llama.init_params(cfg, 0))))
+    ctx = abstract(jax.eval_shape(
+        lambda: llama.init_ctx(cfg, 4, 1024, jnp.float32)))
+    text = llama.batch_prefill.lower(
+        cfg, params, ctx, i32(2, 256), i32(2), i32(2), i32(2), span, i32(2),
+    ).as_text()
+    for name in ("fused_prefill_attention", "prefill_attention"):
+        assert len(re.findall(rf"func\.func private @{name}\b", text)) == 1
+    assert len(re.findall(r"call @fused_prefill_attention\b", text)) == \
+        cfg.num_layers
+    assert len(re.findall(r"call @prefill_attention\b", text)) == 1
+    assert "tpu_custom_call" not in text

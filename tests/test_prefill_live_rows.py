@@ -358,10 +358,14 @@ def test_host_mirror_is_the_programs_trip_count(case):
 # bucket runs, kept the parent's text. PR 57 MEANT to move the two latent
 # ones (c728cb0a7a7fceae and 23f9246b56275a3d on its parent, d39232c): the
 # query product ends ahead of the reshape to heads and the products over
-# W_kvb read ``wkb`` / ``wvb`` (``llama.serving_params``).
+# W_kvb read ``wkb`` / ``wvb`` (``llama.serving_params``). PR 61 MEANT to
+# move them again (8788c3406236fb77 and f840bfcf1391f976 on its parent,
+# 12cb44a): their attention is ``fused_prefill_attention`` (on the CPU the
+# same loops, called through one more jit) and V rides at its own width in
+# the fresh programs too. The hybrid toy (no latent layer) kept its text.
 BLOCK_MODEL_DIGESTS = {
-    "tiny_mla_moe": "8788c3406236fb77",
-    "tiny_mla_moe_mhc": "f840bfcf1391f976",
+    "tiny_mla_moe": "b55f9e38e5a838ec",
+    "tiny_mla_moe_mhc": "ae22457b9c68c707",
     "tiny_ssm_moe": "6d845cc44ce2c3d6",
 }
 

@@ -106,6 +106,8 @@ def test_registry_render_and_snapshot():
         "dynamo_engine_prefill_matched_tokens",
         "dynamo_engine_prefill_attn_live_pairs",
         "dynamo_engine_prefill_attn_scored_pairs",
+        "dynamo_engine_prefill_attn_blocks",
+        "dynamo_engine_prefill_attn_fused_blocks",
         "dynamo_engine_round_live_lane_steps",
         "dynamo_engine_round_tokens",
         "dynamo_moe_experts_touched", "dynamo_moe_tokens_routed",

@@ -387,11 +387,22 @@ _HEADS_BY_STORED_ROW = re.compile(r"\w+\[(?:\d+,)*32,(?:\d+,)*640\]")
 # PR 57 MEANT to move them (8f681471b1b3b42f / c4c1c5a74bec99f4 until then):
 # the products over W_kvb read ``wkb`` / ``wvb``, the stack sliced inside
 # ``_expand_prior``'s loop; temporaries 0.822 / 1.068 -> 0.762 / 1.011 GB
-CONTINUING_LOWERING = {2048: "4b19bd970156cc91", 4096: "843c34a9da1c84ae"}
+# PR 61 MEANT to move them (4b19bd970156cc91 / 843c34a9da1c84ae until
+# then): the attention of every layer is ``fused_prefill_attention``, ONE
+# Mosaic call (ops/flash_prefill.py) over the loops' own work list, reading
+# the workspace where ``_expand_prior`` wrote it; temporaries 0.762 / 1.011
+# -> 0.731 / 1.016 GB
+CONTINUING_LOWERING = {2048: "70c281862d799f5e", 4096: "2138dfd01f25012d"}
+
+
+# `%copy.116 = bf16[1,32,1,16384,192]{4,3,2,1,0:...} copy(%get-tuple-...)`:
+# the workspace's keys laid out anew in front of the kernel
+_WORKSPACE_COPY = re.compile(
+    r"= bf16\[1,32,1,16384,(?:192|128)\]\S* copy(?:-start)?\(")
 
 
 @pytest.mark.parametrize("what", ["no_region_copy", "no_op_at_the_rows_width",
-                                  "temporaries", "lowering"])
+                                  "temporaries", "lowering", "fused_kernel"])
 def test_latent_continuing_prefill_scores_at_the_heads_width(
         continuing_prefill_record, what):
     """A continuing chunk over latent rows expands its prior rows into a
@@ -411,6 +422,18 @@ def test_latent_continuing_prefill_scores_at_the_heads_width(
         assert "bf16[1,32,1,16384,192]" in rec["text"]   # the workspace
     elif what == "temporaries":
         assert rec["temp_bytes"] < CONTINUING_TEMP_CEILING[T], rec["temp_gb"]
+    elif what == "fused_kernel":
+        # PR 61: every layer's attention is the one Mosaic call, and it
+        # reads the workspace where ``_expand_prior`` left it. XLA:TPU lays
+        # a [.., rows, 192] buffer out rows-minor by itself (192 is no
+        # whole number of 128-lane tiles); a kernel that asked for the
+        # keys row-major got a 201 MB copy of them a layer (compiled here,
+        # PR 61), so it takes them as COLUMNS (``swapaxes``: a bitcast)
+        assert rec["mosaic_calls"] == 15 + 7   # the experts' products, + one
+        assert "flash_prefill_attention" in rec["text"]
+        assert not _WORKSPACE_COPY.findall(rec["text"])
+        assert rec["weight_copies"] == [] or T == 4096   # [4096, 3584] is
+        # an activation's shape at that bucket, and the parent's list
     else:
         assert rec["lowered_sha256"] == CONTINUING_LOWERING[T]
 
@@ -1048,6 +1071,14 @@ def test_agentthink_cell_keeps_four_prefill_programs():
 # (``llama.serving_params``). Their flush, seal and load programs and
 # every program of the six configurations with no latent layer kept the
 # parent's digests (CHANGES.md, PR 57).
+# PR 61 MEANT to move every PREFILL program of the three latent
+# configurations, ONCE, and none of their rounds (joyai
+# ``batch_prefill_K2_T128`` 23c0aebdc3c5113f on its parent, 12cb44a; xing4's
+# continuing pair above): the expanded latent chunk's attention is
+# ``attention.fused_prefill_attention``, V at its own width in the fresh
+# programs too. Their rounds, flush, seal, load and ``admit_first`` programs
+# and every program of the eight configurations with no latent layer kept
+# the parent's digests (CHANGES.md, PR 61).
 UNMOVED = {
     ("mla-moe-joyai-d5", 0): {
         "flush_ctx": "aa9a25ef5ee32101",
@@ -1055,7 +1086,7 @@ UNMOVED = {
         "flush_seal_w64": "0cf53d0f869aa38b",
         "round_seal_n4_w64": "b5cdb9902b515b77",
         "load_ctx_pages_n64": "217cccff59be759b",
-        "batch_prefill_K2_T128": "23c0aebdc3c5113f",
+        "batch_prefill_K2_T128": "49affdac97dd46ae",
     },
     ("mistral7b-w8", 2): {
         "round_seal_n4_w8": "4fc6864b36262415",

@@ -22,9 +22,34 @@ the live lanes' outputs are compared BIT FOR BIT. One JSON line a case:
 ``new_us`` / ``old_us`` a layer-call, ``items`` on the work list,
 ``live_bit_equal``, ``max_abs_diff``, ``dead_rows_zero``.
 
+``--kind prefill`` (PR 61): ONE layer's expanded latent PREFILL attention
+as the latent programs hand it over (``[K, T, 32, 192]`` q / k, ``[K, T,
+32, 128]`` v; a continuing chunk over a workspace of its prior rows
+expanded per head), at the long-context cell's buckets (T 2048 / 4096,
+fresh and continuing over 2048 / 4096 / 8192 prior rows of a 16384-row
+workspace, ragged live lengths) and the chat cell's (``[1..2, 128..1024]``
+over a 4096-row one): the XLA loops (``prefill_attention``; fresh chunks
+with V zero-padded to the key's width, as the parent's fresh programs
+passed it) against the fused kernel (``fused_prefill_attention``) at
+``--heads`` heads a grid step. One JSON line a (case, form): ``ms`` a
+layer-call, ``steps`` (query block, key block) pairs scored, ``us_per_step``,
+``mxu_share`` of benchmarks/peaks.py's 197 TFLOP/s that the scored pairs'
+two products make, and the kernel's distance from the loops on live rows.
+
   python tools/latent_decode_bench.py            # on the chip (chiprun)
   python tools/latent_decode_bench.py --dry-run  # tiny, interpreted, here
   python tools/latent_decode_bench.py --kind dense --old-tree .scratch/parent
+  python tools/latent_decode_bench.py --kind prefill --heads 4,8,16
+  python tools/latent_decode_bench.py --kind prefill --schedule --heads 4
+
+``--schedule`` (HERE, no chip, ~20 s): the fused prefill kernel compiled
+for a described v5e with libtpu's own dump of its FINAL instruction
+bundles (``--xla_jf_dump_to``; a bundle issues in a cycle), reduced to one
+JSON line a straight-line stretch of the kernel: bundles, and operations by
+unit. The two long stretches are a step's prior-block and chunk-block
+branches: bundles / ``--heads`` against the 384 a head that its MXU
+passes take alone is what the chip then shows (PR 61: 658 -> 402 bundles a
+head was 13.6 -> 10.7 us a 32-head step).
 """
 from __future__ import annotations
 
@@ -46,7 +71,12 @@ from dynamo_tpu.ops.attention import (  # noqa: E402
     PALLAS_INTERPRET,
     REFERENCE_IMPL,
     DecodeAttention,
+    PriorContext,
     ctx_decode_attention,
+    fused_prefill_attention,
+    prefill_attention,
+    prefill_attention_pairs,
+    prefill_steps,
     region_trips,
 )
 from dynamo_tpu.ops.flash_decode import DEFAULT_CHUNK  # noqa: E402
@@ -190,18 +220,221 @@ def dense_main(args, dev) -> int:
     return 0
 
 
+# (name, lanes K, bucket T, workspace rows (0: a fresh program), q_starts,
+# live rows of each chunk)
+PREFILL_CASES = [
+    ("longdoc_fresh_2048", 1, 2048, 0, [0], [2048]),
+    ("longdoc_fresh_4096", 1, 4096, 0, [0], [4096]),
+    ("longdoc_fresh_4096_ragged", 1, 4096, 0, [0], [3000]),
+    ("longdoc_cont_2048_over_4096", 1, 2048, 16384, [4096], [2048]),
+    ("longdoc_cont_4096_over_2048", 1, 4096, 16384, [2048], [4096]),
+    ("longdoc_cont_4096_over_4096", 1, 4096, 16384, [4096], [4096]),
+    ("longdoc_cont_4096_over_8192", 1, 4096, 16384, [8192], [4096]),
+    ("longdoc_cont_4096_over_8192_ragged", 1, 4096, 16384, [8192], [1700]),
+    ("chat_fresh_1x128", 1, 128, 0, [0], [100]),
+    ("chat_fresh_2x256", 2, 256, 0, [0, 0], [256, 150]),
+    ("chat_fresh_2x512", 2, 512, 0, [0, 0], [512, 300]),
+    ("chat_fresh_2x1024", 2, 1024, 0, [0, 0], [1024, 600]),
+    ("chat_fresh_1x1024", 1, 1024, 0, [0], [1024]),
+    ("chat_cont_2x256_over_1024", 2, 256, 4096, [1024, 512], [256, 100]),
+    ("chat_cont_1x1024_over_1024", 1, 1024, 4096, [1024], [1024]),
+]
+PREFILL_TOY = [
+    ("toy_fresh", 2, 32, 0, [0, 0], [32, 19]),
+    ("toy_cont", 2, 32, 64, [24, 0], [32, 9]),
+]
+
+
+def prefill_main(args, dev) -> int:
+    """``--kind prefill``: the module doc."""
+    cases, nh, hd, hd_v, dtype, block = (PREFILL_CASES, NH, 192, 128,
+                                         jnp.bfloat16, 256)
+    iters, interpret = args.iters, False
+    if args.dry_run:
+        cases, nh, hd, hd_v, dtype, block = (PREFILL_TOY, 4, 24, 16,
+                                             jnp.float32, 8)
+        iters, interpret = 1, True
+    heads = [int(h) for h in args.heads.split(",")]
+    for name, K, T, span, q_starts, n_live in cases:
+        if args.shapes and not any(s in name for s in args.shapes.split(",")):
+            continue
+        ks = jax.random.split(jax.random.PRNGKey(1), 5)
+        q, k = (jax.random.normal(x, (K, T, nh, hd), dtype) for x in ks[:2])
+        v = jax.random.normal(ks[2], (K, T, nh, hd_v), dtype)
+        work = () if not span else (
+            jax.random.normal(ks[3], (1, nh, K, span, hd), dtype),
+            jax.random.normal(ks[4], (1, nh, K, span, hd_v), dtype))
+        qs = jnp.asarray(q_starts, jnp.int32)
+        sl = qs + jnp.asarray(n_live, jnp.int32)
+        live = np.arange(T)[None, :] < np.asarray(n_live)[:, None]
+        _, scored = prefill_attention_pairs(T, q_starts, np.asarray(sl),
+                                            span, block=block)
+        steps = int(prefill_steps(
+            jnp.asarray(n_live), jnp.minimum(qs, span), T, span,
+            block)[0][4])
+
+        def loops(q, k, v, qs, sl, *work):
+            ctx = PriorContext(*work, jnp.int32(0), jnp.arange(
+                K, dtype=jnp.int32)) if work else None
+            if not work:   # the parent's fresh programs: V at K's width
+                v = jnp.pad(v, ((0, 0),) * 3 + ((0, hd - hd_v),))
+            return prefill_attention(q, k, v, qs, sl, ctx, block=block,
+                                     ctx_span=span)[..., :hd_v]
+
+        def fused(h):
+            def call(q, k, v, qs, sl, *work):
+                ctx = PriorContext(*work, jnp.int32(0), jnp.arange(
+                    K, dtype=jnp.int32)) if work else None
+                return fused_prefill_attention(
+                    q, k, v, qs, sl, ctx, block=block, ctx_span=span,
+                    interpret=interpret, heads=h)
+            return call
+
+        def run(call, n):
+            # the next call's lengths hang on this one's result (by a
+            # zero): nothing of the loop is the same at every trip
+            @jax.jit
+            def f(q, k, v, qs, sl, *work):
+                def body(_, c):
+                    qs, _ = c
+                    o = call(q, k, v, qs, sl, *work)
+                    return qs + (o[0, 0, 0, 0] > 1e30).astype(jnp.int32), o
+                return jax.lax.fori_loop(
+                    0, n, body, (qs, jnp.zeros((K, T, nh, hd_v), dtype)))[1]
+            operands = (q, k, v, qs, sl, *work)
+            jax.block_until_ready(f(*operands))            # compiles
+            t0 = time.perf_counter()
+            out = jax.block_until_ready(f(*operands))
+            return (time.perf_counter() - t0) / n, np.asarray(
+                out, np.float32)
+
+        want = None
+        for label, call in [("xla", loops)] + [
+                (f"kernel_h{h}", fused(h)) for h in heads]:
+            sec, out = run(call, iters)
+            if want is None:
+                want = out
+            flop = scored * nh * 2 * (hd + hd_v)
+            print(json.dumps({
+                "device": dev.device_kind, "case": name, "form": label,
+                "K": K, "T": T, "span": span, "q_starts": q_starts,
+                "live": n_live, "steps": steps,
+                "ms": round(sec * 1e3, 4),
+                "us_per_step": round(sec * 1e6 / max(steps, 1), 2),
+                "mxu_share": round(flop / sec / 197e12, 4),
+                "max_abs_diff_vs_xla": float(
+                    np.abs(out - want)[live].max(initial=0.0)),
+                "dead_blocks_zero": not out[
+                    ~(np.repeat(live[:, ::min(block, T)], min(block, T),
+                                axis=1))].any(),
+            }), flush=True)
+    return 0
+
+
+def _unit(op: str) -> str:
+    """The unit of a bundle that an LLO operation occupies."""
+    if op.startswith(("vmatmul", "vmatpush", "vpop.f32.mrf")):
+        return "mxu_" + op.split(".")[0]
+    if "xlane" in op or op.startswith(("vrot", "vxpose", "vperm")):
+        return "xlu"
+    if op.startswith(("vpow2", "vrcp", "vpop.eup")):
+        return "eup"
+    if op.startswith(("vld", "vst")):
+        return op[:3]
+    return "valu" if op.startswith("v") else "scalar"
+
+
+def schedule_main(args) -> int:
+    """``--schedule``: the module doc. The compile runs in a child: the
+    dumper ends the process (a report template the wheel does not ship)
+    AFTER the bundles are on disk."""
+    import collections
+    import glob
+    import re
+    import subprocess
+    import tempfile
+
+    heads = int(args.heads.split(",")[0])
+    with tempfile.TemporaryDirectory() as out:
+        child = (
+            "import jax, jax.numpy as jnp\n"
+            "from jax.experimental import topologies\n"
+            "from jax.sharding import SingleDeviceSharding\n"
+            "from dynamo_tpu.ops.attention import (PriorContext,\n"
+            "    fused_prefill_attention)\n"
+            "dev = SingleDeviceSharding(topologies.get_topology_desc(\n"
+            "    platform='tpu', topology_name='v5e:2x2').devices[0])\n"
+            "S = lambda *s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(\n"
+            "    s, dt, sharding=dev)\n"
+            "def f(q, k, v, qs, sl, pk, pv):\n"
+            "    ctx = PriorContext(pk, pv, jnp.int32(0),\n"
+            "                       jnp.zeros(1, jnp.int32))\n"
+            "    return fused_prefill_attention(\n"
+            "        q, k, v, qs, sl, ctx, ctx_span=16384, interpret=False,\n"
+            f"        heads={heads})\n"
+            "jax.jit(f).lower(S(1, 4096, 32, 192), S(1, 4096, 32, 192),\n"
+            "    S(1, 4096, 32, 128), S(1, dt=jnp.int32), S(1, dt=jnp.int32),\n"
+            "    S(1, 32, 1, 16384, 192), S(1, 32, 1, 16384, 128)).compile()\n")
+        ran = subprocess.run(
+            [sys.executable, "-c", child], capture_output=True, text=True,
+            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            env=dict(os.environ, JAX_PLATFORMS="cpu", TPU_LOG_DIR="disabled",
+                     LIBTPU_INIT_ARGS=f"--xla_jf_dump_to={out} "
+                                      "--xla_jf_dump_llo_text=true"))
+        found = glob.glob(os.path.join(
+            out, "*flash_prefill_attention*-final_bundles.txt"))
+        if not found:
+            print("the compiler left no bundles (one process at a time "
+                  "holds libtpu); the child's last words:\n"
+                  + ran.stderr[-1500:], file=sys.stderr)
+            return 1
+        with open(found[0]) as f:
+            text = f.read()
+    stretch, stretches = [], []
+    for line in text.splitlines():
+        m = re.match(r"\s*0x[0-9a-f]+\s+(?:[A-Z]+)?\s*:?\s*>?\s*\{(.*)\}", line)
+        if not m:
+            continue
+        if "PF:" in line[:24] or "sbr.rel" in line:   # a branch ends it
+            stretches.append(stretch)
+            stretch = []
+            continue
+        stretch.append([
+            (re.search(r"=\s*([a-z_0-9.]+)", op) or re.match(
+                r"\s*%?\w*\s*([a-z_0-9.]+)", op)).group(1)
+            for op in m.group(1).split(";;") if op.strip()])
+    for bundles in stretches + [stretch]:
+        if len(bundles) >= 100:
+            print(json.dumps({
+                "heads": heads, "bundles": len(bundles),
+                "bundles_per_head": round(len(bundles) / heads, 1),
+                **collections.Counter(
+                    _unit(op) for ops in bundles for op in ops)}))
+    return 0
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--kind", choices=("latent", "dense"), default="latent")
+    ap.add_argument("--kind", choices=("latent", "dense", "prefill"),
+                    default="latent")
     ap.add_argument("--old-tree", default="",
                     help="dense: a checkout whose flash kernel to time "
                          "and compare beside this tree's")
     ap.add_argument("--shapes", default="",
-                    help="dense: the shapes to run (all by default)")
+                    help="dense, prefill: the shapes to run (all by "
+                         "default; prefill: substrings of the cases' names)")
+    ap.add_argument("--heads", default="16",
+                    help="prefill: heads a grid step of the fused kernel, "
+                         "one run each")
     ap.add_argument("--dry-run", action="store_true")
     ap.add_argument("--chunks", default="256,512,1024")
     ap.add_argument("--iters", type=int, default=50)
+    ap.add_argument("--schedule", action="store_true",
+                    help="prefill: the kernel's compiled bundles by unit, "
+                         "here, without the chip")
     args = ap.parse_args(argv)
+    if args.schedule:
+        return schedule_main(args)
     chunks = [int(c) for c in args.chunks.split(",")]
     dev = jax.devices()[0]
     if not args.dry_run and dev.platform != "tpu":
@@ -210,6 +443,8 @@ def main(argv=None) -> int:
         return 2
     if args.kind == "dense":
         return dense_main(args, dev)
+    if args.kind == "prefill":
+        return prefill_main(args, dev)
     shapes = {"longdoc": (7, 16, 16384), "chat-decode": (5, 64, 4096)}
     dtype, kernel, iters = jnp.bfloat16, PALLAS, args.iters
     if args.dry_run:
