@@ -17,16 +17,61 @@ if "xla_force_host_platform_device_count" not in flags:
 
 import asyncio  # noqa: E402
 import inspect  # noqa: E402
+import json  # noqa: E402
+import shutil  # noqa: E402
+import tempfile  # noqa: E402
 
 import jax  # noqa: E402
 import pytest  # noqa: E402
+
+from dynamo_tpu import compile_cache  # noqa: E402
 
 # XLA CPU dispatches f32 matmuls to reduced-precision paths by default;
 # golden tests against torch need exact f32 accumulation.
 jax.config.update("jax_default_matmul_precision", "highest")
 
 
+_CACHE_DIR = pytest.StashKey[str]()
+
+
+@pytest.hookimpl(optionalhook=True)
+def pytest_configure_node(node):
+    """xdist's controller, for each worker it starts: the run's directory."""
+    node.workerinput["compile_cache"] = node.config.stash.get(_CACHE_DIR, "")
+
+
+def pytest_unconfigure(config):
+    if _CACHE_DIR in config.stash:
+        shutil.rmtree(config.stash[_CACHE_DIR], ignore_errors=True)
+
+
 def pytest_configure(config):
+    # the order below is the scheduler's only if it is left alone: xdist
+    # (3.8: ``--loadscope-reorder`` is its default) sorts files by their
+    # NUMBER of cases, which runs the one-case rehearsals last of all,
+    # three queued on one worker while five stand idle (a replay of PR 62's
+    # run: 1246 s of cases on the longest worker, 966 as collected, 964
+    # perfect)
+    if hasattr(config.option, "loadscopereorder"):
+        config.option.loadscopereorder = False
+    # ONE persistent compilation cache a run: the toy engines and models
+    # (``ModelConfig.tiny*``, ``llama3_1b(num_layers=...)``) are built in
+    # dozens of files by six processes, and a fresh engine a case compiles
+    # the programs the case before it compiled (a whole run with it: 5782 s
+    # of cases; without: 6820, no case's result another; my runs, PR 62).
+    # Made new by the run (its controller, or the one process of a run
+    # without xdist) and removed at its end, so no entry ever answers for
+    # another tree's code. Where the machine places JAX's cache itself
+    # (dynamo_tpu/compile_cache.py's rule), no path is set in code
+    if not os.environ.get(compile_cache.ENV_VAR):
+        if hasattr(config, "workerinput"):
+            path = config.workerinput["compile_cache"]
+        else:
+            path = config.stash[_CACHE_DIR] = tempfile.mkdtemp(
+                prefix="tier1-jax-")
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     config.addinivalue_line("markers", "asyncio: async test")
     config.addinivalue_line(
         "markers", "asyncio_timeout(seconds): override the 120s default"
@@ -38,45 +83,23 @@ def pytest_configure(config):
     )
 
 
-# The files whose cases take minutes (160-690 s each in the builder's run
-# of PR 50's tree; fourteen since PR 51), in two waves. The driver's tier-1 run (`-n 6 --dist
-# loadfile`) hands FILES to its six workers in collection order, two to a
-# worker at the start and one more whenever a worker finishes one. In the
-# alphabet's order a long file that sorts late was the run's clock alone
-# (test_tpu_lowering.py began ~320 s in and ran ~540 s more), and twelve
-# long files in a row pair the longest two on one worker. So: the first
-# wave of six, then six short files (each worker's second), then the second
-# wave, then the rest in the alphabet's order: the long files start early
-# and the many short ones fill in behind them (a simulation of the
-# scheduler over the measured seconds ends within 5 % of the sum / 6; the
-# alphabet's order, 55 % over). A stable sort: nothing moves inside a file.
-# One rehearsal of whole cells a wave's worker, not three at once. A NEW
-# LONG FILE joins the second wave.
-LONG_FILES = (
-    "test_tpu_lowering.py", "test_kda.py", "test_hybrid_live_rows.py",
-    "test_rehearsal_chat_decode_longctx.py", "test_mla_moe.py",
-    "test_ssm_moe.py",
-    "test_spec.py", "test_rehearsal_longdoc_reasoning.py", "test_sala.py",
-    "test_spec_tree.py", "test_prefill_live_rows.py",
-    "test_rehearsal_ragdoc_longprompt.py",
-    "test_mamba1.py", "test_rehearsal_chat_rate.py",   # PR 51: ~3 min each
-    "test_sambay.py",                                  # PR 54: ~2.5 min
-    "test_window_gqa_moe.py",                          # PR 58: ~75 s
-    "test_ssm_groups_moe.py",                          # PR 60: ~80 s
-)
+# The seconds each file took in a whole run by the driver's command
+# (``tools/test_seconds.py`` writes the table from that run's junit file).
+with open(os.path.join(os.path.dirname(__file__), "seconds.json")) as _f:
+    SECONDS = json.load(_f)
 
 
 def pytest_collection_modifyitems(items):
+    """The driver's run (``-n 6 --dist loadfile``) hands FILES to its six
+    workers in collection order, so the order is the longest file first:
+    the wall is then the work over six, whatever files there are. A file
+    the table does not know (a new one) starts first of all; the sort is
+    stable, so nothing moves inside a file."""
     for item in items:
         if inspect.iscoroutinefunction(getattr(item, "function", None)):
             item.add_marker(pytest.mark.asyncio)
-    file_of = lambda item: os.path.basename(str(item.fspath))  # noqa: E731
-    seen = list(dict.fromkeys(map(file_of, items)))
-    long = [name for name in LONG_FILES if name in seen]
-    rest = [name for name in seen if name not in LONG_FILES]
-    order = long[:6] + rest[:6] + long[6:] + rest[6:]
-    rank = {name: i for i, name in enumerate(order)}
-    items.sort(key=lambda item: rank[file_of(item)])
+    items.sort(key=lambda item: -SECONDS.get(
+        os.path.basename(str(item.fspath)), float("inf")))
 
 
 @pytest.hookimpl(tryfirst=True)

@@ -3,13 +3,10 @@ runner, a copied checkout at a rate the CPU holds, and the rehearsal of
 one whole cell (``rehearse``), with every cell's case in ``CELLS``.
 
 The driver's tier-1 run hands a FILE to one of its six workers
-(``--dist loadfile``), so the cases of one file run one after another:
-the six rehearsals in one file were 835 s of a run of 863 (PR 50). They
-are spread over ``tests/test_rehearsal_*.py``, two cells a file, a long
-one with a short one. A NEW CELL'S REHEARSAL joins the file that is
-shortest then (the times stand in each file's head), or opens a new file
-once every file holds two: add its case to ``CELLS`` here and its id to
-that file's ``cells(...)``.
+(``--dist loadfile``) and a worker draws its next file while two CASES of
+this one are pending, so a rehearsal is one file of one case: a new cell
+adds its line to ``CELLS`` here and ``tests/test_rehearsal_<id>.py``, the
+three lines every such file is.
 """
 import json
 import os
@@ -34,8 +31,10 @@ def checkout_at_rate(tmp_path, cell, rate_rps):
     ``benchmarks/``; the program linked, not copied) whose cell offers
     ``rate_rps``: what ``tools/knee_sweep.py`` does to the chip machine's
     copy. The CPU computes a 4096-token continuing chunk of the TINY model
-    in two seconds, so at a rate sized for the chip every stream of the
-    rehearsal would outlast the drain grace."""
+    in two seconds and, under the run's six workers, a decode step in
+    ~100 ms, so at a rate and a drain grace sized for the chip the streams
+    of the rehearsal would outlast the grace (1006 tokens in 96 s,
+    ``thinklong``): the copy's mixes wait three times as long."""
     root = str(tmp_path / "checkout")
     shutil.copytree(os.path.join(REPO, "benchmarks"),
                     os.path.join(root, "benchmarks"))
@@ -45,57 +44,72 @@ def checkout_at_rate(tmp_path, cell, rate_rps):
     with open(os.path.join(root, "benchmarks", "cells", cell + ".json"),
               "w") as f:
         json.dump({"rate_rps": rate_rps}, f)
+    traffic = os.path.join(root, "benchmarks", "traffic")
+    for name in os.listdir(traffic):
+        with open(os.path.join(traffic, name)) as f:
+            mix = json.load(f)
+        mix["drain_grace_s"] *= 3
+        with open(os.path.join(traffic, name), "w") as f:
+            json.dump(mix, f)
     return root
 
 
-# id -> (cell, seed, reference, rate_rps; None: the cell's own rate)
+# id -> (cell, seed, reference, rate_rps), a line a cell with what its check
+# carries at the dry-run widths. The rates are the CPU's, not the cells': a
+# rate sized for the chip queues tens of streams that the tiny model drains
+# at ~90 ms a token under the run's six workers, past the drain grace (PR
+# 40, PR 61's run); what is asserted (``correct``, ``failed == 0``, the
+# reference by name, the metrics' keys) needs ONE request in the window, so
+# each rate leaves one or two there and as many in the pre-roll
 CELLS = {
-    # 14.4 req/s is sized for the chip: ~170 streams of up to 512 tokens
-    # in 12 s. Alone the CPU holds that; under the six workers of the
-    # driver's run it did not (PR 40's run of the standing tree: streams
-    # outlasted the drain grace, ``failed`` > 0). A third of it, as the
-    # other rehearsals run at a rate the CPU holds
+    # decode through the latent kernel's XLA loop from 64 lanes
     "chat-decode": ("mla-moe-joyai-d5.chat-decode", "3100310031",
-                    "benchmarks/references/mla_moe.py", 4.0),
+                    "benchmarks/references/mla_moe.py", 0.5),
+    # a fresh 4096-token chunk, continuing chunks and decode over a
+    # 16384-token region; the warm-up set leaves the window nothing to
+    # compile
     "longdoc": ("xing4-mhc-d7.longdoc", "3700370037",
-                "benchmarks/references/mla_moe_mhc.py", 0.34),
+                "benchmarks/references/mla_moe_mhc.py", 0.17),
+    # the dense looped prefill at 2048 / 4096
     "longprompt": ("mistral7b-w8.longprompt", "3700370038",
-                   "benchmarks/reference.py", None),
+                   "benchmarks/reference.py", 0.34),
+    # Mamba-2 states and K/V rows over chunk boundaries, experts by halves
     "ragdoc": ("granite4h-ep2-d10.ragdoc", "4100410041",
-               "benchmarks/references/ssm_moe.py", 1.0),
-    # prompts of 8k-28k tokens: the CPU needs ~3 s a 4096-token chunk of
-    # the tiny model, so one request in the pre-roll and one in the window
+               "benchmarks/references/ssm_moe.py", 0.17),
+    # the switch to the block selection (position 1024) crossed in prefill
+    # and in decode, over a 32768-token region with its compressed-key rows
     "longctx": ("minicpm-sala-d16.longctx", "4500450045",
                 "benchmarks/references/sala.py", 0.17),
-    # answers of 384-2048 tokens from up to 48 lanes: six requests in the
-    # 20 s pre-roll and two in the window are what the CPU drains in time
+    # delta-rule state, convolution windows and latent rows over a chunk
+    # boundary at 4096 and through 72 decode steps, grouped routing with a
+    # share of eight; 0.06 req/s would leave the window empty
     "reasoning": ("ling3-flash-ep8-d12.reasoning", "4700470047",
-                  "benchmarks/references/kda_mla_moe.py", 0.3),
-    # a rate sized for the chip's 96 lanes; the CPU drains ~20 requests of
-    # 32-768 tokens out in the 10 s pre-roll and the window
+                  "benchmarks/references/kda_mla_moe.py", 0.1),
+    # Mamba-1 state, window and K/V rows over a chunk boundary at 2048,
+    # through a looped 1024 bucket and a padded 256 one; the warm-up fills
+    # all 96 lanes once
     "chat-rate": ("jamba2-3b.chat-rate", "5100510051",
-                  "benchmarks/references/jamba.py", 1.2),
-    # answers of 256-1536 tokens after prompts of 128-16384 (one to four
-    # chunks of the tiny model): five requests in the 20 s pre-roll and one
-    # or two in the window are what the CPU drains in time
+                  "benchmarks/references/jamba.py", 0.1),
+    # Mamba-1 state, window buffers of 8 rows wrapped a thousand times,
+    # layer 5's rows and the gated memory unit's m over two chunk
+    # boundaries at 4096; 0.06 req/s would leave the window empty
     "thinklong": ("phi4-mini-flash.thinklong", "5400540054",
-                  "benchmarks/references/sambay.py", 0.25),
-    # prompts of 512-16384 (one to four 4096-token chunks of the tiny
-    # model, ~2 s each on the CPU): two requests in the 10 s pre-roll and
-    # one in the window
+                  "benchmarks/references/sambay.py", 0.1),
+    # window buffers and three full layers' rows over two chunk boundaries
+    # at 4096, at 18 and 12 query heads over two K/V heads, rotary by kind
     "codeturn": ("laguna-s-ep8-d12.codeturn", "5800580058",
-                 "benchmarks/references/window_gqa_moe.py", 0.2),
-    # answers of 96-1024 tokens after prompts of 128-8192 (one or two
-    # chunks of the tiny model's 13 one-part layers): three requests in the
-    # 10 s pre-roll and one or two in the window
+                 "benchmarks/references/window_gqa_moe.py", 0.1),
+    # six grouped Mamba-2 states and two attention layers' rows over a
+    # chunk boundary at 4096 through one-part layers, experts stored wider
+    # than published
     "agentthink": ("nemotron3-nano-ep8.agentthink", "6000600060",
-                   "benchmarks/references/ssm_groups_moe.py", 0.3),
+                   "benchmarks/references/ssm_groups_moe.py", 0.1),
 }
 
 
 def cells(*ids):
     """The parametrisation of ``test_the_new_cell_rehearses_on_the_cpu``
-    for the cells a file holds, under the ids the one file gave them."""
+    for the cell a file holds, under its id."""
     return pytest.mark.parametrize(
         "cell,seed,reference,rate_rps", [CELLS[i] for i in ids],
         ids=list(ids))
@@ -103,34 +117,8 @@ def cells(*ids):
 
 def rehearse(tmp_path, cell, seed, reference, rate_rps):
     """A cell's files end to end at the dry-run widths: configuration,
-    reference by name, traffic mix, warm-up, window, result line; at the
-    cell's own rate, or where the CPU cannot hold that (above) at one it
-    can. The long-document cell's check and traffic run a fresh
-    4096-token chunk, continuing chunks and decode over a 16384-token
-    region here too, and its warm-up set has to leave the window nothing
-    to compile. The long-context cell's check crosses the toy model's
-    switch to the block selection (position 1024) in prefill and in
-    decode, over a 32768-token region with its compressed-key rows. The
-    reasoning cell's check carries the toy model's delta-rule state, its
-    convolution windows and its latent rows over a chunk boundary at 4096
-    and through 72 decode steps, under grouped routing with a share of
-    eight. The chat-rate cell's check carries the toy model's Mamba-1
-    state, window and K/V rows over a chunk boundary at 2048, through a
-    looped 1024 bucket and a padded 256 one, and its warm-up fills all 96
-    lanes once. The long-thought cell's check carries the toy model's
-    Mamba-1 state, its window buffers (8 rows a lane: wrapped a thousand
-    times), layer 5's rows and the gated memory unit's m over two chunk
-    boundaries at 4096, with only each chunk's last row above layer 5, and
-    its decode crosses multiples of the buffer's length in every round. The
-    coding-turn cell's check carries the toy model's window buffers (8 rows
-    a lane) and its three full layers' rows over two chunk boundaries at
-    4096 at 18 and 12 query heads over two K/V heads, rotary by kind, under
-    the one-group router with a share of eight. The agent-turn cell's
-    check carries the toy model's six grouped Mamba-2 states and windows
-    and its two attention layers' rows over a chunk boundary at 4096
-    through one-part layers, with experts stored wider than published."""
-    root = REPO if rate_rps is None else checkout_at_rate(
-        tmp_path, cell, rate_rps)
+    reference by name, traffic mix, warm-up, window, result line."""
+    root = checkout_at_rate(tmp_path, cell, rate_rps)
     r = run([sys.executable, "benchmarks/run.py", "--workload", cell,
              "--seed", seed, "--seconds", "6", "--cpu-dry-run"], 900, root)
     assert r.returncode == 0, (r.stdout + r.stderr)[-3000:]

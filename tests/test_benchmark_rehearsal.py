@@ -6,9 +6,9 @@ would type, in a child process (the rehearsal's server child takes the
 CPU "chip"; nothing here imports JAX), under a time limit of its own.
 
 The rehearsals of whole cells (``test_the_new_cell_rehearses_on_the_cpu``,
-one case a cell) live in ``tests/test_rehearsal_*.py``, two cells a file,
-over ``tests/rehearsal.py``: the driver's run gives a file to one worker,
-and six rehearsals in this file were 835 of its 863 s (PR 50).
+one case a cell) live in ``tests/test_rehearsal_<id>.py``, one cell a
+file, over ``tests/rehearsal.py``: the driver's run gives a file to one
+worker, and six rehearsals in this file were 835 of its 863 s (PR 50).
 """
 import json
 import os

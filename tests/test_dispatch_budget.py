@@ -398,10 +398,14 @@ async def _first_token_window(monkeypatch, prompt_lens, spec=False, **kw):
             await asyncio.sleep(0.005)
         d1 = dict(eng.dispatch_counts)
         await asyncio.gather(*tasks)
+        verifies = eng.dispatch_counts.get("spec_verify", 0)
     finally:
         sink.release.set()
         await eng.stop()
     delta = {k: d1[k] - d0[k] for k in d1}
+    # over the wave's WHOLE life: whether the first verify is dispatched
+    # before this thread sees the first token is the two threads' race
+    delta["spec_verify_to_the_end"] = verifies - d0.get("spec_verify", 0)
     # the held prompt's own dispatch stands first in the ledger
     calls = ledger.calls if spec else ledger.calls[1:]
     return calls, delta, eng, ledger.dest
@@ -450,5 +454,5 @@ async def test_speculative_admission_rides_admit_first_and_stays_parked(
         speculative="ngram", num_speculative_tokens=4, spec_adaptive=False)
     assert calls == [({"admit_first": 1, "fetch": 1}, 1, 1)], calls
     assert delta["admit_first"] == 1 and delta["patch"] == 0, delta
-    assert delta["spec_verify"] >= 1, delta   # it did speculate
+    assert delta["spec_verify_to_the_end"] >= 1, delta   # it did speculate
     assert (dest == eng._B).all(), dest     # every lane parked

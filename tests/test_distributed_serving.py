@@ -229,7 +229,10 @@ async def test_tpu_engine_through_distributed_stack():
     )
     entry = ModelEntry(name="tpum", namespace="tt", component="backend",
                        block_size=4, router_mode="kv")
-    served = await register_llm(rt, eng, entry, lease_ttl_s=0.5)
+    # nothing here waits for the lease to lapse, and the engine's first
+    # compile can hold the keep-alive off a loaded CPU for longer than a
+    # short one lives (lost once under the six workers at 0.5 s)
+    served = await register_llm(rt, eng, entry, lease_ttl_s=30.0)
 
     frontend_rt = await DistributedRuntime.connect(port=port)
     manager = ModelManager()
@@ -294,7 +297,7 @@ async def test_kv_events_claimed_per_model_with_race_buffer():
             rt, eng,
             ModelEntry(name=name, namespace="cm", component="backend",
                        block_size=BS, router_mode="kv"),
-            lease_ttl_s=0.5,
+            lease_ttl_s=30.0,   # no case here waits for it to lapse
         )
         workers.append((rt, eng, served))
     try:

@@ -4,7 +4,7 @@ held to a plain numpy statement of what they move, byte for byte.
 
 Both move unquantised rows as in-place span writes in a loop rolled over
 lanes / entries (PR 34; the scatter and flat-gather forms made XLA:TPU
-copy the whole region, tests/test_tpu_lowering.py guards that). One pair
+copy the whole region, tests/test_lowering_*.py guards that). One pair
 serves every row kind: ``k`` and ``v`` of [kvh, hd] (dense models, kvh
 sharded over ``tp``) and the latent block's one ``kv`` row (kvh == 1).
 The scratch lane (index B) and scratch page 0 hold garbage by contract

@@ -2,18 +2,19 @@
 
 Pins the tentpole invariants: the flat switch model attributes host
 round time with self-coverage ~1.0 by construction; the always-on
-instrumentation costs <= 20 µs a host-loop round and <= 5 µs a dispatch
-(a per-call microbench, not a wall-clock A/B of two engines); the SLO
+instrumentation costs a host-loop round one clock read a switch and a
+dispatch one (counted on a scripted clock and by the calls made: what
+this CPU's clock says under six test workers is no measurement); the SLO
 burn-rate math interpolates histogram CDFs correctly; and the timeline
 exporter turns a real disagg request
 (span tree + host rounds + kv_transfer stream events) into parseable
 Chrome Trace Event Format JSON.
 """
 import asyncio
+import contextlib
 import importlib.util
 import json
 import os
-import time
 
 import numpy as np
 import pytest
@@ -55,13 +56,46 @@ def _engine(**kw) -> TpuEngine:
 # ---- RoundProf: the flat switch model --------------------------------
 
 
-def test_roundprof_segment_sums_equal_wall():
+class _Clock:
+    """The prof module's ``time`` on a script; ``reads`` counts what the
+    plane asked of it."""
+
+    def __init__(self):
+        self.t = 0.0
+        self.reads = 0
+
+    def monotonic(self):
+        self.reads += 1
+        return self.t
+
+    def time(self):
+        self.reads += 1
+        return 1_000_000.0 + self.t
+
+
+@contextlib.contextmanager
+def _scripted_clock():
+    c = _Clock()
+    real, tprof.time = tprof.time, c
+    try:
+        yield c
+    finally:
+        tprof.time = real
+
+
+@pytest.fixture
+def clock():
+    with _scripted_clock() as c:
+        yield c
+
+
+def test_roundprof_segment_sums_equal_wall(clock):
     p = RoundProf()
     p.begin_round()
     p.enter(SEGMENTS.index("intake"))
-    time.sleep(0.002)
+    clock.t += 0.002
     p.enter(SEGMENTS.index("dispatch"))
-    time.sleep(0.003)
+    clock.t += 0.003
     p.end_round()
     assert p.rounds == 1
     t = p.totals()
@@ -69,24 +103,25 @@ def test_roundprof_segment_sums_equal_wall():
     # charged to exactly one segment
     assert sum(t["segments"].values()) == pytest.approx(t["wall_s"])
     assert p.coverage() == pytest.approx(1.0)
-    assert t["segments"]["intake"] >= 0.002
-    assert t["segments"]["dispatch"] >= 0.003
+    assert t["wall_s"] == pytest.approx(0.005)
+    assert t["segments"]["intake"] == pytest.approx(0.002)
+    assert t["segments"]["dispatch"] == pytest.approx(0.003)
     assert set(t["segments"]) == set(SEGMENTS)
 
 
-def test_roundprof_push_restores_nested_segment():
+def test_roundprof_push_restores_nested_segment(clock):
     p = RoundProf()
     p.begin_round()
     p.enter(SEGMENTS.index("fetch"))
-    time.sleep(0.001)
+    clock.t += 0.001
     prev = p.push(SEGMENTS.index("annotate"))
-    time.sleep(0.002)
+    clock.t += 0.002
     p.enter(prev)
-    time.sleep(0.001)
+    clock.t += 0.001
     p.end_round()
     t = p.totals()["segments"]
-    assert t["annotate"] >= 0.002
-    assert t["fetch"] >= 0.002  # both slices around the nested push
+    assert t["annotate"] == pytest.approx(0.002)
+    assert t["fetch"] == pytest.approx(0.002)  # both slices around the push
 
 
 def test_roundprof_idle_rounds_not_recorded():
@@ -117,29 +152,6 @@ def test_roundprof_ring_and_drain_bounded():
 # ---- the loop's clock closes: collector, empty engine, stalls --------
 
 
-class _Clock:
-    """The prof module's ``time`` on a script."""
-
-    def __init__(self):
-        self.t = 0.0
-
-    def monotonic(self):
-        return self.t
-
-    def time(self):
-        return 1_000_000.0 + self.t
-
-
-@pytest.fixture
-def clock():
-    c = _Clock()
-    real, tprof.time = tprof.time, c
-    try:
-        yield c
-    finally:
-        tprof.time = real
-
-
 def _collect(clock, generation, seconds):
     """One collection of the module's hook, ``seconds`` long."""
     tprof._gc_hook("start", {"generation": generation})
@@ -156,9 +168,13 @@ def test_collections_on_a_scripted_clock(clock, case):
     stands in `admit`, 2 s of generation 2 in `fetch`, both on the engine's
     thread, and 0.5 s of generation 2 on ANOTHER thread while the engine
     waits on its doorbell."""
+    import gc
     import threading
 
     p = RoundProf()
+    # the REAL collector would book its own collection through the hook
+    # (once, under six workers, PR 60's run): the script's three are all
+    gc.disable()
     p.register_thread()
     try:
         p.begin_round()
@@ -176,6 +192,7 @@ def test_collections_on_a_scripted_clock(clock, case):
         p.idle_exit()
     finally:
         p.unregister_thread()
+        gc.enable()
     t = p.totals()
     gc_t = t["gc"]
     if case == "by_generation":
@@ -332,17 +349,17 @@ def test_burn_rate_gauges_fold_and_render():
         assert f'segment="{s}"' in text
 
 
-def test_registry_fold_observes_per_segment():
+def test_registry_fold_observes_per_segment(clock):
     reg = ProfRegistry()
     p = RoundProf()
     p.begin_round()
     p.enter(SEGMENTS.index("dispatch"))
-    time.sleep(0.001)
+    clock.t += 0.001
     p.end_round()
     reg.fold(p)
     snap = reg.snapshot()
     assert snap["dispatch"]["count"] == 1
-    assert snap["dispatch"]["sum"] >= 0.001
+    assert snap["dispatch"]["sum"] == pytest.approx(0.001)
     assert snap["intake"]["count"] == 0
     assert reg.coverage_ratio() == pytest.approx(1.0)
     reg.fold(p)  # second fold: nothing new to drain
@@ -367,8 +384,8 @@ async def _run_wave(eng, prompts, osl):
 async def test_engine_attribution_coverage_and_host_budget():
     """Tier-1 pins: a served workload attributes its host time across
     the real segments with self-coverage >= 0.9, folds into the global
-    PROF registry at the publish cadence, and the steady-decode host
-    budget stays under a (generous, tiny-harness) per-round ceiling."""
+    PROF registry at the publish cadence, and the host loop makes a few
+    passes a program it dispatches, not a spin."""
     PROF.reset()
     eng = _engine()
     eng.start()
@@ -381,20 +398,22 @@ async def test_engine_attribution_coverage_and_host_budget():
 
     t1 = eng.prof.totals()
     rounds = t1["rounds"] - t0["rounds"]
-    wall = t1["wall_s"] - t0["wall_s"]
     assert rounds >= 10
     assert eng.prof.coverage() >= 0.9
     seg = {s: t1["segments"][s] - t0["segments"][s] for s in SEGMENTS}
     # the hot segments of a decode-heavy workload actually got charged
     for s in ("dispatch", "fetch", "admit", "slot_scan"):
         assert seg[s] > 0.0, seg
-    # whole-run host tripwire: on the CPU harness the admit segments
-    # (admit_launch) carry the blocking prefill compute, so exclude them
-    # (the steady-decode budget is pinned in the A/B test below);
-    # 50 ms/round is the "something pathological landed in the host
-    # loop" ceiling, not a perf target
-    admit = sum(seg[s] for s in SEGMENTS if s.startswith("admit"))
-    assert (wall - admit) / rounds <= 0.050, (wall, rounds, seg)
+    # whole-run host tripwire, in passes: a recorded pass of the loop is
+    # one that dispatched, fetched or held work in flight, and the loop
+    # blocks on the head fetch once ``max_inflight_rounds`` stand behind
+    # it, so the second wave's passes stay under the programs the engine
+    # dispatched (17 passes, 59 programs over both waves here, my run,
+    # PR 62). A loop that spins on work it cannot advance records
+    # thousands: "something pathological landed in the host loop",
+    # whatever this CPU's speed
+    programs = sum(eng.dispatch_counts.values())
+    assert rounds <= 2 * programs, (rounds, dict(eng.dispatch_counts), seg)
     # /debug/prof payload shape
     s = eng.prof.summary(top=3)
     assert len(s["segments"]) == 3
@@ -407,102 +426,119 @@ async def test_engine_attribution_coverage_and_host_budget():
     PROF.reset()
 
 
-async def _steady_round_wall_ms(eng, repeats=2) -> float:
-    """Min per-round wall (ms) over ``repeats`` steady-decode windows,
+async def _steady_passes_per_round(eng) -> float:
+    """Recorded host passes a fused round over a steady-decode window,
     same window mechanics as tests/test_dispatch_budget.py."""
     rng = np.random.RandomState(0)
     n_req, osl = 4, 64
     prompts = [rng.randint(1, 256, 48).tolist() for _ in range(n_req)]
     await _run_wave(eng, prompts, 8)  # warmup: compiles
-    best = None
-    for _ in range(repeats):
-        progress = [0] * n_req
+    progress = [0] * n_req
 
-        async def one(i):
-            async for out in eng.generate(PreprocessedRequest(
-                token_ids=list(prompts[i]),
-                stop_conditions=StopConditions(max_tokens=osl,
-                                               ignore_eos=True),
-            )):
-                progress[i] += len(out.token_ids)
+    async def one(i):
+        async for out in eng.generate(PreprocessedRequest(
+            token_ids=list(prompts[i]),
+            stop_conditions=StopConditions(max_tokens=osl,
+                                           ignore_eos=True),
+        )):
+            progress[i] += len(out.token_ids)
 
-        tasks = [asyncio.ensure_future(one(i)) for i in range(n_req)]
-        while not all(p >= 4 for p in progress):
-            await asyncio.sleep(0.005)
-        d0 = dict(eng.dispatch_counts)
-        t0 = time.monotonic()
-        while not any(p >= osl - 20 for p in progress):
-            await asyncio.sleep(0.005)
-        dt = time.monotonic() - t0
-        d1 = dict(eng.dispatch_counts)
-        await asyncio.gather(*tasks)
-        rounds = (d1.get("round", 0) + d1.get("round_seal", 0)
-                  - d0.get("round", 0) - d0.get("round_seal", 0))
-        if rounds > 0:
-            w = dt / rounds * 1e3
-            best = w if best is None else min(best, w)
-    return best
+    def books():
+        d = eng.dispatch_counts
+        return eng.prof.rounds, d.get("round", 0) + d.get("round_seal", 0)
+
+    tasks = [asyncio.ensure_future(one(i)) for i in range(n_req)]
+    while not all(p >= 4 for p in progress):
+        await asyncio.sleep(0.005)
+    p0, r0 = books()
+    while not any(p >= osl - 20 for p in progress):
+        await asyncio.sleep(0.005)
+    p1, r1 = books()
+    await asyncio.gather(*tasks)
+    assert r1 - r0 >= 4, (r0, r1)
+    return (p1 - p0) / (r1 - r0)
 
 
-def _best_us(fn, calls: int = 200, repeats: int = 30) -> float:
-    """Best mean cost (µs) of ``fn`` over ``repeats`` batches: the least
-    disturbed batch is the measurement, whatever else the box runs."""
-    best = float("inf")
-    for _ in range(repeats):
-        t0 = time.perf_counter()
-        for _ in range(calls):
-            fn()
-        best = min(best, (time.perf_counter() - t0) / calls)
-    return best * 1e6
+def _calls_made(fn) -> int:
+    """The calls (Python and C) ``fn()`` makes, itself included."""
+    import sys
+    made = [0]
+
+    def hook(frame, event, arg):
+        made[0] += event in ("call", "c_call")
+
+    sys.setprofile(hook)
+    try:
+        fn()
+    finally:
+        sys.setprofile(None)
+    return made[0] - 1                    # the setprofile(None) of the end
 
 
 async def test_attribution_overhead_within_5pct():
-    """The always-on claim, per call: one host-loop round of the
-    attribution plane (begin, 15 segment switches, end; the annotation
-    branch included) costs <= 20 µs, and what a dispatch adds (the dry
-    poll with its observe + the two prefill token observes, the most
-    any dispatch site makes) <= 8 µs. A wall-clock A/B of two engines
-    under parallel test workers is not a measurement; this is, and the
-    absolute steady-decode pin on a real engine stays."""
+    """The always-on claim, per call, in what the plane DOES: one
+    host-loop round of the attribution plane (begin, 15 segment switches,
+    end; the annotation branch included) reads the clock once a switch,
+    once at each end and once for the ring's stamp, and makes at most 100
+    calls (68 here, my run, PR 62: a switch is ``enter`` -> ``_charge``
+    -> the clock -> ``_close_annotation``); judging a consumed round
+    reads no clock (2 calls); what a dispatch adds (the dry poll with its
+    observe + the two prefill token observes, the most any dispatch site
+    makes) is ONE clock read and at most 32 calls (16 here). A
+    microsecond pin on a shared CPU under six test workers is not a
+    measurement; this is, and the steady host-loop pin on a real engine
+    stays, in passes a round."""
     from dynamo_tpu.telemetry import TelemetryRegistry, request_histograms
 
-    p = RoundProf()
-    p.register_thread()                   # the collector's hook installed
-    n_seg = len(SEGMENTS) - 1
+    with _scripted_clock() as clock:
+        p = RoundProf()
+        p.register_thread()                   # the collector's hook installed
+        n_seg = len(SEGMENTS) - 1
 
-    def one_round():
+        def one_round():
+            p.begin_round()
+            for i in range(15):
+                p.enter(i % n_seg)
+            p.end_round()
+
+        def reads_of(fn):
+            r0 = clock.reads
+            fn()
+            return clock.reads - r0
+
+        one_round()
+        assert reads_of(one_round) == 1 + 15 + 1 + 1
+        assert _calls_made(one_round) <= 100
+        # what a consumed fused round adds: the late rule's comparison
+        assert reads_of(lambda: p.judge_round(0.05, 4, False)) == 0
+        assert _calls_made(lambda: p.judge_round(0.05, 4, False)) <= 5
+
+        reg = request_histograms(TelemetryRegistry(), engine=True)
+        real = reg.get("dynamo_engine_prefill_tokens")
+        padded = reg.get("dynamo_engine_prefill_padded_tokens")
+        dry = reg.get("dynamo_engine_dispatch_found_dry")
         p.begin_round()
-        for i in range(15):
-            p.enter(i % n_seg)
+
+        def one_dispatch():
+            real.observe(319)
+            padded.observe(512)
+            dry.observe(1.0)
+            p.poll(True)                      # the dearer branch: it charges
+
+        one_dispatch()
+        assert reads_of(one_dispatch) == 1
+        assert _calls_made(one_dispatch) <= 32
         p.end_round()
+        p.unregister_thread()
 
-    assert _best_us(one_round) <= 20.0
-    # what a consumed fused round adds: the late rule's comparison
-    assert _best_us(lambda: p.judge_round(0.05, 4, False)) <= 2.0
-
-    reg = request_histograms(TelemetryRegistry(), engine=True)
-    real = reg.get("dynamo_engine_prefill_tokens")
-    padded = reg.get("dynamo_engine_prefill_padded_tokens")
-    dry = reg.get("dynamo_engine_dispatch_found_dry")
-    p.begin_round()
-
-    def one_dispatch():
-        real.observe(319)
-        padded.observe(512)
-        dry.observe(1.0)
-        p.poll(True)                      # the dearer branch: it charges
-
-    assert _best_us(one_dispatch) <= 8.0
-    p.end_round()
-    p.unregister_thread()
-
-    # steady-decode host budget pin: the generous tiny-harness ceiling
-    # (typical ~1-5 ms/round on CPU; regressions land well above)
+    # steady-decode host pin: each fused round costs the loop ONE pass
+    # here (the pass that dispatches it early also consumes the one
+    # before); a loop that polls its fetches without blocking makes tens
     eng = _engine()
     eng.start()
-    wall = await _steady_round_wall_ms(eng)
+    passes = await _steady_passes_per_round(eng)
     await eng.stop()
-    assert wall is not None and wall <= 50.0, wall
+    assert passes <= 4.0, passes
 
 
 # ---- timeline export: disagg request -> Chrome trace JSON ------------

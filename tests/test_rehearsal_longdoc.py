@@ -1,8 +1,8 @@
-"""The ``chat-rate`` cell's CPU rehearsal (tests/rehearsal.py)."""
+"""The ``longdoc`` cell's CPU rehearsal (tests/rehearsal.py)."""
 from tests.rehearsal import cells, rehearse
 
 
-@cells("chat-rate")
+@cells("longdoc")
 def test_the_new_cell_rehearses_on_the_cpu(tmp_path, cell, seed, reference,
                                            rate_rps):
     rehearse(tmp_path, cell, seed, reference, rate_rps)

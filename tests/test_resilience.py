@@ -8,6 +8,7 @@ sequence of an uninterrupted run — no drops, no duplicates — while
 ``dynamo_migration_total`` increments.
 """
 import asyncio
+import random
 
 import numpy as np
 import pytest
@@ -304,8 +305,24 @@ class DeadEngine:
         yield  # pragma: no cover — makes this an async generator
 
 
-def make_push(engines: dict, **kw) -> KvPushRouter:
+class Prefers:
+    """A tie-break that is no draw: the named worker wherever it ties
+    (at temperature 0 the selector asks its ``rng`` for ``choice`` alone)."""
+
+    def __init__(self, worker):
+        self.worker = worker
+
+    def choice(self, best):
+        return self.worker if self.worker in best else best[0]
+
+
+def make_push(engines: dict, rng=None, **kw) -> KvPushRouter:
     router = KvRouter(BS, KvRouterConfig(router_temperature=0.0))
+    # equal workers tie, and the selector breaks a tie by its ``rng``
+    # (unseeded: the module's own, so a case that counts a worker's turns
+    # fails one run in some): one seeded sequence of draws a router, or
+    # the case's own script
+    router.scheduler.selector.rng = rng or random.Random(0)
     return KvPushRouter(router, dict(engines), **kw)
 
 
@@ -509,10 +526,13 @@ async def test_breaker_excludes_failing_worker_from_routing():
 
     bad = DiesEveryTime()
     ok = LcgEngine()
-    push = make_push({"bad": bad, "ok": ok}, health=health)
-    # route several requests; "bad" fails mid-stream whenever chosen and
-    # migration recovers onto "ok". After 2 failures the breaker trips
-    # and "bad" stops receiving traffic entirely.
+    push = make_push({"bad": bad, "ok": ok}, health=health,
+                     rng=Prefers("bad"))
+    # route several requests; "bad" fails mid-stream whenever chosen (and
+    # it is, wherever the two tie: two unseeded draws chose it fewer than
+    # twice in eight, 9 runs in 256) and migration recovers onto "ok".
+    # After 2 failures the breaker trips and "bad" stops receiving
+    # traffic entirely.
     for i in range(8):
         prompt = list(range(i * 7 + 1, i * 7 + 9))
         toks, _ = await _drive(push, _req(prompt, max_tokens=4))

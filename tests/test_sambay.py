@@ -617,49 +617,3 @@ def test_the_differential_decode_kernels_roofline(kernels, share):
     assert got is None if share is None else abs(got - share) < 1e-6
     assert read(dict(src, trace=None)) is None
     assert read(dict(src, trace_span=None)) is None
-
-
-# ---------------------------------------------------------------------------
-# the published widths, compiled for the chip here (no chip: XLA:TPU and
-# Mosaic for a described v5e; tools/tpu_compile_check.py)
-
-@pytest.fixture(scope="module")
-def compile_check():
-    """The compile tool, and a skip where no v5e can be described here
-    (inside a fixture, never at import: one process at a time may load
-    libtpu)."""
-    import sys
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    import tpu_compile_check
-    from jax.experimental import topologies
-    try:
-        topologies.get_topology_desc(
-            platform="tpu", topology_name=tpu_compile_check.TOPOLOGY)
-    except Exception as exc:  # noqa: BLE001 — no libtpu, or it is held
-        pytest.skip(f"no compile-only v5e topology here: {exc!r:.200}")
-    return tpu_compile_check
-
-
-def test_the_round_relayouts_neither_row_kind_at_the_published_widths(
-        compile_check):
-    """The flush alone and the fused round as the engine builds it, at 40
-    lanes of 18432: no synchronous copy of the full rows ([1, 10, 41,
-    18432, 128]) nor of a window leaf ([8, 10, 41, 512, 128]: two
-    read-modify-writes of one buffer in ONE loop body made XLA:TPU relayout
-    it around the loop, 0.43 GB of temporaries a kind), the temporaries
-    small, and the kernels there: sixteen differential decode calls (eight
-    window layers, layer 17, seven cross layers) beside nine Mamba-1
-    steps."""
-    import re
-    with jax.default_matmul_precision("default"):
-        flush, round_ = compile_check.compile_programs(
-            config="phi4-mini-flash", programs=("flush_ctx", "round_seal"),
-            keep_text=True)
-    for rec in (flush, round_):
-        assert rec["ok"], rec.get("error")
-        assert rec["region_shard"] == [1, 10, 41, 18432, 128]
-        assert not [l for l in rec["text"].splitlines() if re.search(
-            r"= bf16\[(8,10,41,512|1,10,41,18432),128\]\S* copy\(", l)]
-        assert rec["temp_bytes"] < 0.15e9, rec["temp_gb"]
-    assert round_["mosaic_calls"] == 16 + 9
-    assert round_["text"].count("diff_decode_attention") >= 16

@@ -34,7 +34,7 @@ prefill at the lanes the engine gives a group of two: fresh
 (``batch_prefill``) and continuing contexts in the region
 (``batch_prefill_cont``), at the smallest bucket (``--prefill-width``
 names another: the long-context cell's continuing ``[1, 4096]`` program
-is held by tests/test_tpu_lowering.py), and the one program that samples
+is held by tests/test_lowering_*.py), and the one program that samples
 a prefill dispatch's first tokens and admits its slots (``admit_first``).
 Prints one JSON line per program with XLA's memory analysis, the
 region-shaped copies in the compiled text and what it writes out in the
