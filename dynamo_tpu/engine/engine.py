@@ -602,7 +602,8 @@ class TpuEngine:
         # dispatched round and a prefill dispatch (None: it has none)
         self._decode_mirror = llama.decode_mirror(
             c, e.max_context, e.flush_every, self.decode_attn)
-        self._prefill_mirror = llama.prefill_mirror(c, self.decode_attn)
+        self._prefill_mirror = llama.prefill_mirror(
+            c, self.decode_attn, e.kv_quant)
         # bytes a token holds in the ctx region, and bytes a lane holds
         # in recurrent state whatever its context: observed once, here
         recurrent = llama.state_kinds(self.ctx)
@@ -3771,7 +3772,7 @@ class TpuEngine:
             self.config, self.params, self.ctx, jnp.asarray(toks),
             jnp.asarray(slots), jnp.asarray(q_starts),
             jnp.asarray(seq_lens), ctx_span, jnp.asarray(adapter_ids),
-            counted=sorted_rows > 0,
+            counted=sorted_rows > 0, attn=self.decode_attn,
         )
         self._newest = logits
         self._note_moe_rows(moved, sorted_rows)
@@ -4045,6 +4046,7 @@ class TpuEngine:
             jnp.int32(start), jnp.int32(start + len(chunk)),
             embeds, embeds_mask, jnp.int32(r.adapter_id),
             fresh=start == 0, counted=sorted_rows > 0,
+            attn=self.decode_attn,
         )
         self._newest = logits
         self._note_moe_rows(moved, sorted_rows)

@@ -275,7 +275,7 @@ class Follower:
                 jnp.int32(cmd["slot"]),
                 jnp.int32(cmd["start"]), jnp.int32(cmd["end"]),
                 None, None, jnp.int32(cmd.get("adapter", 0)),
-                fresh=int(cmd["start"]) == 0,
+                fresh=int(cmd["start"]) == 0, attn=eng.decode_attn,
             )
         elif op == "prefill_batch":
             from dynamo_tpu.models import llama
@@ -290,6 +290,7 @@ class Follower:
                 int(cmd["ctx_span"]),
                 jnp.asarray(np.asarray(
                     cmd.get("adapter_ids", [0] * k), np.int32)),
+                attn=eng.decode_attn,
             )
         elif op == "admit_first":
             # the leader's one program a prefill dispatch, on this host's

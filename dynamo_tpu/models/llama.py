@@ -70,12 +70,16 @@ one line in ``block_of``; no line of engine/engine.py.
   programs
     prefill_impl(c, params, ctx_kv, tokens, slot, q_start, seq_len,
                  embeds=None, embeds_mask=None, adapter_id=None,
-                 fresh=False)           -> (ctx_kv, logits[, rows_moved])
-    batch_prefill_impl(c, params, ctx_kv, tokens, slots, q_starts,
-                       seq_lens, ctx_span=0, adapter_ids=None)
+                 fresh=False, *, attn=None)
                                         -> (ctx_kv, logits[, rows_moved])
+    batch_prefill_impl(c, params, ctx_kv, tokens, slots, q_starts,
+                       seq_lens, ctx_span=0, adapter_ids=None, *,
+                       attn=None)       -> (ctx_kv, logits[, rows_moved])
         (the front door's ``prefill_impl`` / ``batch_prefill_impl`` add
-        ``counted=`` and drop the third result unless asked)
+        ``counted=`` and drop the third result unless asked; ``attn`` is
+        the round's: the engine's ``DecodeAttention``, whose kernel and
+        mesh the dense decoder's prefill attention takes; None: a caller
+        that hands none over runs the XLA loops)
     round_step(c, params, ctx_kv, ring, stepped, tokens, ctx_lens,
                ring_base, s, live, adapter_ids, stats, *, attn)
                                         -> (ring, stepped, logits, stats)
@@ -108,7 +112,7 @@ one line in ``block_of``; no line of engine/engine.py.
         -> f(ctx_lens, live, n_steps) -> ((metric, value), ...)
         (the dense decoder's: the region rows its attention's work list
         reads a layer, beside the live lanes' own)
-    prefill_mirror(c, attn)
+    prefill_mirror(c, attn, kv_quant="none")
         -> f(width, q_starts, seq_lens, scored, ctx_span)
            -> ((metric, value), ...)
         (every block's: the query blocks its attention layers ran, and
@@ -150,8 +154,9 @@ from dynamo_tpu.ops.attention import (
     DecodeAttention,
     PriorContext,
     ctx_decode_attention,
+    dense_head_fuses,
+    dense_prefill_attention,
     dense_round_rows,
-    prefill_attention,
 )
 from dynamo_tpu.ops.rope import apply_rope, rope_cos_sin, rope_inv_freq
 from dynamo_tpu.telemetry.metrics import Counter
@@ -296,14 +301,23 @@ def decode_mirror(config: ModelConfig, max_context: int, ring_len: int,
 
 
 @_hands_over
-def prefill_mirror(config: ModelConfig, attn: DecodeAttention) -> Callable:
+def prefill_mirror(config: ModelConfig, attn: DecodeAttention,
+                   kv_quant: str = "none") -> Callable:
     """The host's mirror of what a prefill dispatch's attention layers
     ran beyond ``prefill_attention_pairs``: ``f(width, q_starts,
     seq_lens, scored, ctx_span) -> ((metric, value), ...)``. Here: the
-    query blocks of every layer's attention, none of them through the
-    fused kernel (K and V are shared by a group of heads; ``attn`` says
-    what the engine's programs are traced for)."""
-    return mla_moe.blocks_mirror(attn, config.num_layers)
+    query blocks of every layer's attention, and those that ran through
+    the fused kernel: every layer's, a group of query heads a K/V head,
+    where ``attn`` (what the engine's programs are traced for, which
+    the prefill programs are handed too) names a kernel, at a geometry
+    inside its shape rule and a head of whole 128-lane tiles; a
+    continuing chunk over an int8 region (``kv_quant``) keeps the
+    loops."""
+    return mla_moe.blocks_mirror(
+        attn, config.num_layers,
+        fused_layers=config.num_layers * dense_head_fuses(config.head_dim),
+        n_heads=config.num_heads, kv_heads=config.num_kv_heads,
+        int8_region=kv_quant == "int8")
 
 
 # ---------------------------------------------------------------------------
@@ -1046,6 +1060,12 @@ def prefill_impl(
                               # gathers ran (scalar i32; the engine asks
                               # where moe_prefill_rows_sorted says they
                               # loop)
+    attn: Optional[DecodeAttention] = None,   # STATIC: what the engine's
+                              # programs are traced for, as the round is
+                              # handed it (the dense decoder's prefill
+                              # attention is a kernel where it names one,
+                              # mapped over its mesh's tp axis; None: the
+                              # XLA loops)
 ) -> tuple[Cache, jnp.ndarray]:
     """Run T new tokens through the model, writing their KV into the
     slot's contiguous context region at [q_start, q_start+T).
@@ -1061,14 +1081,15 @@ def prefill_impl(
     region.
     """
     out = _prefill_impl(config, params, ctx_kv, tokens, slot, q_start,
-                        seq_len, embeds, embeds_mask, adapter_id, fresh)
+                        seq_len, embeds, embeds_mask, adapter_id, fresh,
+                        attn=attn)
     return out if counted else out[:2]
 
 
 @_hands_over
 def _prefill_impl(config, params, ctx_kv, tokens, slot, q_start, seq_len,
                   embeds=None, embeds_mask=None, adapter_id=None,
-                  fresh=False):
+                  fresh=False, *, attn=None):
     """``prefill_impl`` as the block protocol has it: the dense decoder's
     chunk, (ctx_kv, logits)."""
     c = config
@@ -1086,7 +1107,7 @@ def _prefill_impl(config, params, ctx_kv, tokens, slot, q_start, seq_len,
 
     # Layers are UNROLLED (python loop, static layer index). The region is
     # READ-ONLY during the layer stack: each layer's chunk KV is carried in
-    # values and attention takes it directly (prefill_attention, which
+    # values and attention takes it directly (dense_prefill_attention, which
     # reads the region only in blocks below q_start, and not at all when
     # `fresh`); ALL writes land in one tail pass after the last read, so
     # the donated update chain aliases in place (interleaved write/read of
@@ -1116,15 +1137,15 @@ def _prefill_impl(config, params, ctx_kv, tokens, slot, q_start, seq_len,
             q, k, v = _layer_qkv(c, lp, h, cos, sin, ad)
         new_ks.append(k)
         new_vs.append(v)
-        attn = prefill_attention(
-            q[None], k[None], v[None], one(q_start), one(seq_len),
+        o = dense_prefill_attention(
+            attn, q[None], k[None], v[None], one(q_start), one(seq_len),
             None if fresh else _prior_context(ctx_kv, l, one(slot)),
         )[0]
         if R:
-            h, = live(_layer_out, l, h, attn)
+            h, = live(_layer_out, l, h, o)
         else:
             # padding tokens must not claim MoE expert capacity
-            h = _layer_out(c, lp, h, attn, positions < seq_len, ad)
+            h = _layer_out(c, lp, h, o, positions < seq_len, ad)
 
     # tail: one contiguous span write per buffer (all reads are done)
     upd_k = jnp.stack(new_ks).transpose(0, 2, 1, 3)  # [L, kvh, T, hd]
@@ -1154,8 +1175,8 @@ def _prefill_impl(config, params, ctx_kv, tokens, slot, q_start, seq_len,
 
 
 prefill = jax.jit(
-    prefill_impl, static_argnums=(0,), static_argnames=("fresh", "counted"),
-    donate_argnums=(2,),
+    prefill_impl, static_argnums=(0,),
+    static_argnames=("fresh", "counted", "attn"), donate_argnums=(2,),
 )
 
 
@@ -1173,6 +1194,8 @@ def _batch_forward(
                             # (RoPE position = q_start + depth; -1 pad)
     chunk_masks: Optional[jnp.ndarray] = None,  # [K, T, T] bool tree-
                             # causal in-chunk visibility (spec tree)
+    attn: Optional[DecodeAttention] = None,  # as prefill_impl's (spec's
+                            # planes hand none over: the XLA loops)
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Read-only layer stack shared by batch_prefill and batch_score: K
     chunks through the model in one program. Returns (ks, vs, h) —
@@ -1182,7 +1205,7 @@ def _batch_forward(
     Each layer is lane-batched end to end: its two halves (_layer_qkv,
     _layer_out) are vmapped over the K lanes — one [K, T, H] pipeline,
     so a tp-sharded layer keeps two all-reduces over [K, T, hidden] —
-    and between them ONE prefill_attention call takes all lanes with
+    and between them ONE dense_prefill_attention call takes all lanes with
     their q_starts and seq_lens, so a dummy lane or a short prompt costs
     no attention. ``ctx_span`` 0 compiles no read of the region. A
     bucket of more than two row blocks (live_row_block) runs the halves
@@ -1243,18 +1266,18 @@ def _batch_forward(
             )(h, cos, sin, ag)
         new_ks.append(k)
         new_vs.append(v)
-        attn = prefill_attention(
-            q, k, v, q_starts, seq_lens,
+        o = dense_prefill_attention(
+            attn, q, k, v, q_starts, seq_lens,
             _prior_context(ctx_kv, l, slots) if ctx_span > 0 else None,
             chunk_masks, ctx_span=ctx_span,
         )
         if R:
-            h, = live(_layer_out, l, h, attn)
+            h, = live(_layer_out, l, h, o)
         else:
             h = jax.vmap(
-                lambda h, attn, valid, ag_row: _layer_out(
-                    c, lp, h, attn, valid, ad(ag_row))
-            )(h, attn, node_valid, ag)
+                lambda h, o, valid, ag_row: _layer_out(
+                    c, lp, h, o, valid, ad(ag_row))
+            )(h, o, node_valid, ag)
     return (
         jnp.stack(new_ks, axis=1).astype(cdt),
         jnp.stack(new_vs, axis=1).astype(cdt),
@@ -1318,6 +1341,7 @@ def batch_prefill_impl(
     adapter_ids: Optional[jnp.ndarray] = None,  # [K] i32 — resident LoRA
                             # bank rows (0 = identity; padding lanes 0)
     counted: bool = False,  # STATIC: a third output, as prefill_impl's
+    attn: Optional[DecodeAttention] = None,   # STATIC, as prefill_impl's
 ) -> tuple[Cache, jnp.ndarray]:
     """Batched multi-request prefill: K chunks through the model in ONE
     program — the TTFT lever for concurrent arrivals (reference analogue:
@@ -1338,18 +1362,20 @@ def batch_prefill_impl(
     tokens out of MoE routing and their region writes hit scratch.
     """
     out = _batch_prefill_impl(config, params, ctx_kv, tokens, slots,
-                              q_starts, seq_lens, ctx_span, adapter_ids)
+                              q_starts, seq_lens, ctx_span, adapter_ids,
+                              attn=attn)
     return out if counted else out[:2]
 
 
 @_hands_over
 def _batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
-                        seq_lens, ctx_span=0, adapter_ids=None):
+                        seq_lens, ctx_span=0, adapter_ids=None, *,
+                        attn=None):
     """``batch_prefill_impl`` as the block protocol has it: the dense
     decoder's K chunks, (ctx_kv, logits)."""
     ks, vs, h = _batch_forward(
         config, params, ctx_kv, tokens, slots, q_starts, seq_lens, ctx_span,
-        adapter_ids,
+        adapter_ids, attn=attn,
     )
     ctx_kv = _write_chunks(ctx_kv, ks, vs, slots, q_starts, seq_lens)
     last = jnp.maximum(seq_lens - q_starts - 1, 0)
@@ -1359,8 +1385,8 @@ def _batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
 
 
 batch_prefill = jax.jit(
-    batch_prefill_impl, static_argnums=(0, 7), static_argnames=("counted",),
-    donate_argnums=(2,),
+    batch_prefill_impl, static_argnums=(0, 7),
+    static_argnames=("counted", "attn"), donate_argnums=(2,),
 )
 
 

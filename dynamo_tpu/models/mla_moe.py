@@ -319,24 +319,30 @@ def decode_mirror(config: ModelConfig, max_context: int, ring_len: int,
 
 
 def blocks_mirror(attn: DecodeAttention, layers: int, fused_layers: int = 0,
-                  n_heads: int = 0):
+                  n_heads: int = 0, kv_heads: int = 0,
+                  int8_region: bool = False):
     """A block's ``prefill_mirror`` of the query blocks its ``layers``
     prefill attentions ran a dispatch, and those of them that ran through
-    the fused kernel: ``fused_layers`` expanded latent layers of
-    ``n_heads`` heads, where the programs are traced for TPU devices
-    (``attn`` names a kernel: ``fused_prefill_attention`` lowers by
-    platform, as ``decode_attention_for`` chooses) at a geometry the
-    kernel takes (``prefill_fuses``, which the call site asks too)."""
+    the fused kernel: ``fused_layers`` layers of ``n_heads`` query heads
+    over ``kv_heads`` K/V heads (0: one each, an expanded latent layer),
+    where the programs are traced for TPU devices (``attn`` names a
+    kernel: ``fused_prefill_attention`` lowers by platform, as
+    ``decode_attention_for`` chooses) at a geometry the kernel takes
+    (``prefill_fuses``, which the call site asks too) and the region a
+    continuing chunk reads is not int8 (``int8_region``: the call keeps
+    the loops)."""
     def mirror(width: int, q_starts, seq_lens, scored: int, ctx_span: int):
         blocks = prefill_query_blocks(width, q_starts, seq_lens)
         fused = attn.impl != REFERENCE_IMPL and prefill_fuses(
-            width, n_heads, n_heads, ctx_span)
+            width, n_heads, kv_heads or n_heads, ctx_span) and not (
+            int8_region and ctx_span)
         return ((PREFILL_ATTN_BLOCKS[0], layers * blocks),
                 (PREFILL_ATTN_FUSED_BLOCKS[0], fused_layers * blocks * fused))
     return mirror
 
 
-def prefill_mirror(config: ModelConfig, attn: DecodeAttention):
+def prefill_mirror(config: ModelConfig, attn: DecodeAttention,
+                   kv_quant: str = "none"):
     """Every layer's prefill attention is the expanded latent one."""
     return blocks_mirror(attn, config.num_layers,
                          fused_layers=config.num_layers,
@@ -666,7 +672,8 @@ def _expand_prior(c: ModelConfig, work, region, wkb, wvb, layer, slots,
 
 
 def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
-                       seq_lens, ctx_span=0, adapter_ids=None):
+                       seq_lens, ctx_span=0, adapter_ids=None, *,
+                       attn=None):
     """K chunks [K, T] through the model in one program; their rows land
     in each lane's region at [q_start, q_start + T) in one tail pass after
     every read. Attention is EXPANDED in both programs (K and V per head
@@ -681,7 +688,10 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
     bfloat16, allocated once a program and rewritten by every layer), and
     the attention reads that workspace where a dense model's reads its
     region. A fresh lane (q_start 0) in a continuing program expands and
-    reads nothing. Only decode absorbs (``decode_step_impl``)."""
+    reads nothing. Only decode absorbs (``decode_step_impl``). ``attn``
+    (the protocol's: what the engine's programs are traced for) is not
+    asked: the block holds whole layers on every device and its fused
+    attention lowers by platform."""
     c, d = config, dims(config)
     _refuse_adapters(params)
     K, T = tokens.shape
@@ -740,7 +750,7 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
 
 def prefill_impl(config, params, ctx_kv, tokens, slot, q_start, seq_len,
                  embeds=None, embeds_mask=None, adapter_id=None,
-                 fresh=False):
+                 fresh=False, *, attn=None):
     """One chunk: the K = 1 case of the batched program."""
     if embeds is not None:
         raise ValueError("the latent-attention block takes no embedding "

@@ -1869,7 +1869,8 @@ def _climb(c, params, ctx_kv, slots, q_starts, seq_lens, span: int, h, q,
 
 
 def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
-                       seq_lens, ctx_span=0, adapter_ids=None):
+                       seq_lens, ctx_span=0, adapter_ids=None, *,
+                       attn=None):
     """K chunks [K, T] through the model in one program. The attention
     layers' rows land in each lane's region at [q_start, q_start + T) and
     the Mamba layers' states in the lane, in one tail pass after every
@@ -1884,7 +1885,10 @@ def batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
     bucket rows, padding masked: the form every narrow bucket and a stack
     outside ``LIVE_ROW_KINDS`` keep, and one whose lowered text must not
     move with the looped form's (a block-sparse stack's served tail latency
-    follows its chunks' compiled schedule: PERF.md section 6, PR 49)."""
+    follows its chunks' compiled schedule: PERF.md section 6, PR 49).
+    ``attn`` (the protocol's: what the engine's programs are traced for)
+    is not asked: every kind's prefill attention keeps the XLA loops or
+    lowers by platform."""
     if live_row_block(config, tokens.shape[1]):
         return _live_prefill(config, params, ctx_kv, tokens, slots, q_starts,
                              seq_lens, ctx_span)
@@ -2295,7 +2299,7 @@ def _live_prefill(config, params, ctx_kv, tokens, slots, q_starts, seq_lens,
 
 def prefill_impl(config, params, ctx_kv, tokens, slot, q_start, seq_len,
                  embeds=None, embeds_mask=None, adapter_id=None,
-                 fresh=False):
+                 fresh=False, *, attn=None):
     """One chunk: the K = 1 case of the batched program."""
     if embeds is not None:
         raise ValueError("the state-space hybrid block takes no embedding "
@@ -2651,7 +2655,8 @@ def _rows_mirror(config: ModelConfig, max_context: int, ring_len: int,
     return mirror
 
 
-def prefill_mirror(config: ModelConfig, attn: DecodeAttention):
+def prefill_mirror(config: ModelConfig, attn: DecodeAttention,
+                   kv_quant: str = "none"):
     """The query blocks the stack's attention layers of every kind ran,
     and those of its ``latent_attention`` layers that ran through the
     fused kernel (``mla_moe.blocks_mirror``); beside them, what the

@@ -24,7 +24,12 @@ no gathers, no page tables:
     batched: two rolled loops whose trip counts follow the live rows, so
     padding rows, dummy lanes, blocks above the causal diagonal and — for
     a fresh prompt — the whole region are never scored, and the lowered
-    program has the same size at every bucket width and lane count.
+    program has the same size at every bucket width and lane count. The
+    calls that hold no tree mask, selection, window or int8 region run
+    the same work list as ONE Mosaic call a layer on a TPU
+    (``fused_prefill_attention``, ops/flash_prefill.py): the expanded
+    latent chunks and, through ``dense_prefill_attention``, the dense
+    decoder's GQA layers, mapped per shard over the mesh's ``tp`` axis.
 
 This replaces the round-3 paged-attention kernel whose (slot, head, page)
 grid cost 15.9 ms/step in pure invocation overhead (SURVEY.md §7 "Paged
@@ -500,11 +505,13 @@ def prefill_attention(
 def prefill_fuses(width: int, n_heads: int, kv_heads: int,
                   ctx_span: int = 0, block: int = PREFILL_BLOCK) -> bool:
     """The SHAPE RULE of ``fused_prefill_attention``: does a call of this
-    geometry run the fused kernel (ops/flash_prefill.py) on a TPU? K and V
-    per head (an expanded latent chunk) in whole blocks: the kernel's
-    tiles never slide back over rows they have seen."""
+    geometry run the fused kernel (ops/flash_prefill.py) on a TPU? Whole
+    groups of query heads a K/V head (one each: an expanded latent chunk;
+    more: a dense GQA layer, the group sharing its K/V tile) in whole
+    blocks: the kernel's tiles never slide back over rows they have
+    seen."""
     blk = min(block, width)
-    return (n_heads == kv_heads and width % blk == 0
+    return (n_heads % kv_heads == 0 and width % blk == 0
             and ctx_span % min(block, ctx_span or block) == 0)
 
 
@@ -548,32 +555,42 @@ def prefill_steps(n_live, below, width: int, ctx_span: int, block: int):
 
 @functools.partial(jax.jit,
                    static_argnames=("block", "ctx_span", "interpret",
-                                    "heads"))
+                                    "heads", "key_rows", "mesh"))
 def fused_prefill_attention(
     q: jnp.ndarray,          # [K, T, n_heads, hd]
-    k_new: jnp.ndarray,      # [K, T, n_heads, hd] — K and V per head
-    v_new: jnp.ndarray,      # [K, T, n_heads, hd_v]
+    k_new: jnp.ndarray,      # [K, T, kvh, hd] — a K and a V head a group
+    v_new: jnp.ndarray,      # [K, T, kvh, hd_v]   of n_heads / kvh heads
     q_starts: jnp.ndarray,   # [K] i32
     seq_lens: jnp.ndarray,   # [K] i32
-    ctx: Optional[PriorContext] = None,   # no int8 region
+    ctx: Optional[PriorContext] = None,   # an int8 region: the loops
     block: int = PREFILL_BLOCK,
     ctx_span: int = 0,
     interpret: Optional[bool] = None,   # tests, tools: the kernel itself,
                              # interpreted or not, whatever the platform
-    heads: int = 0,          # heads a grid step (tools sweep); 0: the
-                             # kernel's own
+    heads: int = 0,          # query heads a grid step (tools sweep); 0:
+                             # the kernel's own
+    key_rows: bool = False,  # the kernel reads keys as ROWS [.., rows,
+                             # hd]: the engine's own region, in place (a
+                             # head of whole 128-lane tiles lies row-major
+                             # on the chip). False: as COLUMNS, a latent
+                             # model's workspace as XLA lays it out
+    mesh: Optional[Mesh] = None,   # the kernel is mapped over this mesh's
+                             # ``tp`` axis, a shard's K/V heads and their
+                             # groups a device (heads are independent: no
+                             # collective); None: unsharded operands
 ) -> jnp.ndarray:
     """``prefill_attention`` for the calls that hold no tree mask, no
-    selection, no window and no int8 region, with K and V per head: an
-    EXPANDED latent chunk (models/mla_moe.py, the ``latent_attention``
-    kind of models/ssm_moe.py). On a TPU it is ONE Mosaic call a layer
-    (ops/flash_prefill.py) that walks the same (lane, query block) work
-    list over the same key blocks, scores, probabilities and accumulator
-    in VMEM; elsewhere (the CPU test meshes), and at a geometry outside
-    ``prefill_fuses``, the XLA loops themselves. Same results to float32
-    rounding: rows of a query block with no live row and rows that see no
-    key are 0. Jitted with the layer a VALUE, like the loops: a program's
-    layers share one lowered body."""
+    selection, no window and no int8 region: an EXPANDED latent chunk, K
+    and V per head (models/mla_moe.py, the ``latent_attention`` kind of
+    models/ssm_moe.py), and the dense decoder's GQA layers, a group of
+    query heads a K/V head (models/llama.py). On a TPU it is ONE Mosaic
+    call a layer (ops/flash_prefill.py) that walks the same (lane, query
+    block) work list over the same key blocks, scores, probabilities and
+    accumulator in VMEM; elsewhere (the CPU test meshes), and at a
+    geometry outside ``prefill_fuses``, the XLA loops themselves. Same
+    results to float32 rounding: rows of a query block with no live row
+    and rows that see no key are 0. Jitted with the layer a VALUE, like
+    the loops: a program's layers share one lowered body."""
     K, T, n_heads, _ = q.shape
     span = (ctx_span or ctx.k.shape[3]) if ctx is not None else 0
 
@@ -585,8 +602,9 @@ def fused_prefill_attention(
             ctx is not None and ctx.k_scale is not None):
         return loops(q, k_new, v_new, q_starts, seq_lens, ctx)
 
-    def kernel(q, k_new, v_new, q_starts, seq_lens, ctx, interpret=False):
+    def kernel(interpret, q, k_new, v_new, q_starts, seq_lens, ctx):
         i32 = jnp.int32
+        blk, (nh, hd), kvh = min(block, T), q.shape[2:], k_new.shape[2]
         q_starts, seq_lens = q_starts.astype(i32), seq_lens.astype(i32)
         n_live = jnp.clip(seq_lens - q_starts, 0, T)
         below = jnp.minimum(jnp.minimum(q_starts, seq_lens), span)
@@ -596,24 +614,95 @@ def fused_prefill_attention(
         # is the layout XLA:TPU gives a [.., rows, 192] array by itself
         # (192 is no whole number of 128-lane tiles), so the transposed
         # view of the workspace is the buffer ``_expand_prior`` wrote,
-        # where the row-major one was a copy of it a layer
+        # where the row-major one was a copy of it a layer. The engine's
+        # own region, rows of 128, lies row-major: ``key_rows`` reads it
+        # as it lies, where the transposed view would be the copy
         region = None if ctx is None else (
-            ctx.k.swapaxes(3, 4), ctx.v, ctx.layer, ctx.slots, pblk, below)
+            ctx.k if key_rows else ctx.k.swapaxes(3, 4), ctx.v, ctx.layer,
+            ctx.slots, pblk, below)
+        if nh == kvh:
+            qt = q.transpose(0, 2, 1, 3)
+        else:
+            # a query block's group of heads one under the other: the
+            # kernel's one [rep x blk, hd] operand a K/V head
+            qt = q.reshape(K, T // blk, blk, kvh, nh // kvh, hd).transpose(
+                0, 3, 1, 4, 2, 5).reshape(K, kvh, T * (nh // kvh), hd)
         out = flash_prefill_attention(
-            q.transpose(0, 2, 1, 3),
-            k_new.transpose(0, 2, 3, 1).astype(q.dtype),
+            qt,
+            k_new.transpose((0, 2, 1, 3) if key_rows
+                            else (0, 2, 3, 1)).astype(q.dtype),
             v_new.transpose(0, 2, 1, 3).astype(q.dtype),
-            steps, n_live, region, block=min(block, T),
-            ctx_block=min(block, span), heads=heads, interpret=interpret)
+            steps, n_live, region, block=blk,
+            ctx_block=min(block, span), heads=heads, key_rows=key_rows,
+            interpret=interpret)
         # no step wrote the tile of a query block with no live row
-        rows = jnp.repeat(block_live, min(block, T), axis=1)
+        rows = jnp.repeat(block_live, blk, axis=1)
         return jnp.where(rows[:, :, None], out, 0).reshape(
-            K, T, n_heads, v_new.shape[3])
+            K, T, nh, v_new.shape[3])
 
+    kernel = functools.partial(kernel, bool(interpret))
+    if mesh is not None:
+        # q / out shard on heads, the chunks' K and V and the region on
+        # K/V heads (the layouts llama.param / ctx_shardings already give
+        # them); the step list is built from replicated values: every
+        # shard walks the same list over its own heads
+        by_head, kv = P(None, None, AXIS_TENSOR, None), P(
+            None, AXIS_TENSOR, None, None, None)
+        kernel = jax.shard_map(
+            kernel, mesh=mesh,
+            in_specs=(by_head, by_head, by_head, P(), P(),
+                      None if ctx is None else PriorContext(
+                          kv, kv, P(), P())),
+            # pallas_call has no replication rule (ctx_decode_attention)
+            out_specs=by_head, check_vma=False)
     if interpret is not None:
-        return kernel(q, k_new, v_new, q_starts, seq_lens, ctx, interpret)
+        return kernel(q, k_new, v_new, q_starts, seq_lens, ctx)
     return jax.lax.platform_dependent(
         q, k_new, v_new, q_starts, seq_lens, ctx, tpu=kernel, default=loops)
+
+
+def dense_head_fuses(head_dim: int) -> bool:
+    """Whether a dense layer's head lets ``dense_prefill_attention`` read
+    the region's key rows in place: whole 128-lane tiles (the call site's
+    rule and the host mirror's, ``llama.prefill_mirror``)."""
+    return head_dim % 128 == 0
+
+
+def dense_prefill_attention(
+    attn: Optional[DecodeAttention],   # what the caller's programs are
+                             # traced for, handed over as the round's is;
+                             # None: a caller that says nothing of where
+                             # its arrays live
+    q: jnp.ndarray,          # [K, T, n_heads, hd]
+    k_new: jnp.ndarray,      # [K, T, kvh, hd]
+    v_new: jnp.ndarray,
+    q_starts: jnp.ndarray,
+    seq_lens: jnp.ndarray,
+    ctx: Optional[PriorContext] = None,   # the engine's own region
+    chunk_masks: Optional[jnp.ndarray] = None,
+    ctx_span: int = 0,
+) -> jnp.ndarray:
+    """The prefill attention of a layer whose region holds a K and a V
+    row a K/V head (the dense decoder's two call sites), by what the call
+    holds and nothing else: the fused kernel where ``attn`` names one,
+    reading the region's keys as the rows they are and mapped over the
+    ``tp`` axis of ``attn.mesh`` where that shards the heads; the XLA
+    loops for a tree mask (the speculative verifier), for the jnp
+    reference of the CPU meshes, and for a caller that hands no ``attn``
+    over (GSPMD partitions the loops wherever the arrays live; a bare
+    Mosaic call it cannot), and for a head that is no whole 128-lane
+    tiles (XLA holds such a region rows-minor: rows read in place are
+    whole lanes). An int8 region and a width of no whole blocks keep the
+    loops inside ``fused_prefill_attention``."""
+    if (attn is None or attn.impl == REFERENCE_IMPL
+            or chunk_masks is not None or not dense_head_fuses(q.shape[3])):
+        return prefill_attention(q, k_new, v_new, q_starts, seq_lens, ctx,
+                                 chunk_masks, ctx_span=ctx_span)
+    sharded = attn.mesh is not None and attn.mesh.shape[AXIS_TENSOR] > 1
+    return fused_prefill_attention(
+        q, k_new, v_new, q_starts, seq_lens, ctx, ctx_span=ctx_span,
+        interpret=True if attn.impl == PALLAS_INTERPRET else None,
+        key_rows=True, mesh=attn.mesh if sharded else None)
 
 
 def prefill_attention_pairs(

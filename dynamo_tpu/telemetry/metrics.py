@@ -447,8 +447,9 @@ PREFILL_ATTN_BLOCKS = (
 PREFILL_ATTN_FUSED_BLOCKS = (
     "dynamo_engine_prefill_attn_fused_blocks",
     "of dynamo_engine_prefill_attn_blocks, the pairs that ran through the "
-    "fused kernel (ops/flash_prefill.py): the expanded latent layers' on "
-    "TPU devices at a geometry inside attention.prefill_fuses, 0 elsewhere")
+    "fused kernel (ops/flash_prefill.py): the expanded latent layers' and "
+    "the dense decoder's GQA layers' on TPU devices at a geometry inside "
+    "attention.prefill_fuses (no int8 region read), 0 elsewhere")
 ROUND_LIVE_LANE_STEPS = ("dynamo_engine_round_live_lane_steps",
                          "lanes live at dispatch x steps per fused "
                          "decode round")

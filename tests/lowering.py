@@ -72,7 +72,7 @@ def record(config, program, width=None, layers=None):
     text (``rec["text"]``): ``program`` as the tool's table names it,
     ``width`` the prefill bucket (None: the cell's first), ``layers`` a cut
     depth (None: the cell's own). Compiled once a process whoever asks."""
-    if not program.startswith(("batch_prefill", "admit_first")):
+    if not program.startswith(("prefill", "batch_prefill", "admit_first")):
         width = None          # no other program's shapes follow the bucket
     return _record(config, program, width or 0, layers or 0)
 

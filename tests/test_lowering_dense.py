@@ -30,8 +30,11 @@ from dynamo_tpu.ops.attention import (
     PALLAS_INTERPRET,
     REFERENCE,
     DecodeAttention,
+    PriorContext,
     ctx_decode_attention,
     decode_attention_for,
+    dense_prefill_attention,
+    prefill_attention,
 )
 from dynamo_tpu.parallel.mesh import MeshConfig, make_mesh
 from tests.lowering import (
@@ -174,6 +177,36 @@ def test_shard_mapped_kernel_matches_reference(small, tp, quant):
         assert len({s.index for s in got.addressable_shards}) == tp
 
 
+@pytest.mark.parametrize("with_ctx", [False, True], ids=["fresh", "ctx"])
+@pytest.mark.parametrize("tp", [2, 4])
+def test_shard_mapped_prefill_kernel_matches_the_loops(tp, with_ctx):
+    """The fused prefill kernel mapped per shard over tp (interpret mode,
+    virtual devices; 4 K/V heads and their 8 query heads over tp) against
+    the unsharded XLA loops: heads are independent, the step list is built
+    from replicated values, every shard walks it over its own heads, a
+    dummy lane comes back 0, and the result is sharded over the heads."""
+    rng = np.random.RandomState(tp)
+
+    def f32(*shape):
+        return jnp.asarray(rng.randn(*shape) * 0.5, jnp.float32)
+
+    K, T, hd, lanes, span = 3, 32, 128, 4, 64
+    q, k, v = f32(K, T, NH, hd), f32(K, T, NKV, hd), f32(K, T, NKV, hd)
+    ctx = PriorContext(
+        f32(1, NKV, lanes, span, hd), f32(1, NKV, lanes, span, hd),
+        jnp.int32(0), jnp.asarray([2, 0, 3], jnp.int32)) if with_ctx else None
+    qs = jnp.asarray([24, 0, 7] if with_ctx else [0, 0, 0], jnp.int32)
+    sl = qs + jnp.asarray([32, 0, 19], jnp.int32)
+    mesh = make_mesh(MeshConfig(tp=tp), jax.devices()[:tp])
+    got = jax.jit(dense_prefill_attention, static_argnums=0)(
+        DecodeAttention(PALLAS_INTERPRET, mesh), q, k, v, qs, sl, ctx)
+    want = prefill_attention(q, k, v, qs, sl, ctx)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=5e-3, atol=5e-3)
+    assert not np.asarray(got)[1].any() and np.asarray(got)[2, :19].any()
+    assert len({s.index for s in got.addressable_shards}) == tp
+
+
 def test_kv_heads_must_divide_tp(small):
     d = small
     mesh = make_mesh(MeshConfig(tp=8), jax.devices()[:8])
@@ -266,14 +299,16 @@ def test_admit_first_compiles_for_the_v5e_in_place(config):
     assert rec["temp_bytes"] < 4e6, rec
 
 
-# the programs that share code with the continuing latent chunk
-# (``prefill_attention``) and must NOT move with it: the dense round and
-# the dense prefill, fresh and continuing, at 2 layers
+# the dense round, which shares code with the prefill attentions
+# (``ops/attention.py``) and must NOT move with them, and the dense prefill,
+# fresh and continuing, at 2 layers: MOVED by PR 63 on purpose (the fused
+# kernel a layer where the XLA loops were; 587de9cf00cdecb3 /
+# 2c9a09ec6d798ada until then) and pinned again as it left them
 UNMOVED = {
     ("mistral7b-w8", 2): {
         "round_seal_n4_w8": "4fc6864b36262415",
-        "batch_prefill_K2_T128": "587de9cf00cdecb3",
-        "batch_prefill_cont_K2_T128_S4096": "2c9a09ec6d798ada",
+        "batch_prefill_K2_T128": "d255deb9b8fe3dac",
+        "batch_prefill_cont_K2_T128_S4096": "fe6c7b8eb0fa194e",
     },
 }
 
@@ -282,6 +317,46 @@ UNMOVED = {
 def test_programs_beside_the_continuing_latent_chunk_keep_their_lowering(
         key, program):
     assert_pinned(UNMOVED, key, program)
+
+
+# `%copy.7 = bf16[2,8,9,4096,128]{...} copy(`, `bf16[1,2,1,128,4096]`: the
+# region, a layer or a lane of it, written out again or transposed in
+# front of the kernel (no chunk of the 1024 bucket has 4096 rows)
+_REGION_RELAYOUT = re.compile(
+    r"= \(?bf16\[(?:\d+,)*(?:4096,128|128,4096)\]\S* (?:copy(?:-start)?|"
+    r"transpose)\(")
+
+
+@pytest.mark.parametrize("program", ["prefill", "prefill_cont",
+                                     "batch_prefill", "batch_prefill_cont"])
+@pytest.mark.parametrize("config", ["mistral7b-w8", "nemo12b-tp4"])
+def test_dense_prefill_attention_is_the_fused_kernel_on_v5e(config, program):
+    """PR 63: the solo, the continuing and the batched dense prefill
+    programs, compiled for the v5e at 2 layers, hold ONE Mosaic call a
+    layer (``flash_prefill_attention``: a group of query heads shares its
+    K/V tile, scores and probabilities in VMEM) that reads the engine's
+    region where it lies: nothing the region's size is copied and no
+    ``[.., 4096, 128]`` slab of it is written out or transposed in front
+    of the kernel (keys are read as the rows they are). Under ``tp = 4``
+    the call is mapped over the tensor axis (a bare Mosaic call GSPMD
+    refuses: "cannot be automatically partitioned"), 2 K/V heads and
+    their 8 query heads a shard, and the attention adds no collective:
+    the layers' all-reduces and the logits' are all there is."""
+    rec = record(config, program, width=1024, layers=2)
+    assert rec["ok"], rec.get("error")
+    assert rec["decode_attention"] == PALLAS
+    assert rec["mosaic_calls"] == 2
+    assert len(re.findall(r"%flash_prefill_attention\S* = ",
+                          rec["text"])) == 2
+    assert rec["region_shard"] in ([2, 8, 9, 4096, 128],
+                                   [2, 2, 17, 4096, 128])
+    assert rec["region_copies"] == {"count": 0, "shapes": []}, rec
+    assert not _REGION_RELAYOUT.findall(rec["text"])
+    for collective in (" all-gather(", " all-to-all(",
+                       " collective-permute("):
+        assert collective not in rec["text"], collective
+    assert rec["text"].count(" all-reduce(") == (
+        5 if config == "nemo12b-tp4" else 0)
 
 
 # the wide dense prefill programs loop their row-wise halves over the live
@@ -293,7 +368,7 @@ def test_programs_beside_the_continuing_latent_chunk_keep_their_lowering(
 # the slice copies every layer's weights in front of it and reads 0.64 /
 # 0.95 GB: the ceilings sit between.
 LOOPED_TEMP_CEILING = {
-    ("mistral7b-w8", "batch_prefill"): 0.30e9,
+    ("mistral7b-w8", "batch_prefill"): 0.36e9,
     ("mistral7b-w8", "batch_prefill_cont"): 0.62e9,
     ("nemo12b-tp4", "batch_prefill"): 0.20e9,
     ("nemo12b-tp4", "batch_prefill_cont"): 0.33e9,

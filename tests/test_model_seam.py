@@ -8,6 +8,7 @@ at its head. CPU, no engine start: imports by ``ast``, signatures by
 from __future__ import annotations
 
 import ast
+import dataclasses
 import glob
 import inspect
 import os
@@ -234,8 +235,23 @@ def test_what_the_state_says_of_itself(family):
     if family in ("dense", "latent", "latent_mhc"):
         assert ran[tmetrics.PREFILL_ATTN_BLOCKS[0]] == 2 * c.num_layers
     kernel = llama.prefill_mirror(c, DecodeAttention("pallas"))
+    # (PR 63) and every layer of the dense decoder, a group of query heads
+    # a K/V head, unless the region a continuing chunk reads is int8
+    # where its head is whole 128-lane tiles (the toy's 16 is not),
+    # unless the region a continuing chunk reads is int8
     assert dict(kernel(*lanes, 0, 4096))[
         tmetrics.PREFILL_ATTN_FUSED_BLOCKS[0]] == 2 * latent
+    if family == "dense":
+        wide = dataclasses.replace(c, head_dim=128)
+        for kv_quant, continuing, fresh in (("none", 2, 2), ("int8", 0, 2)):
+            mirror = llama.prefill_mirror(
+                wide, DecodeAttention("pallas"), kv_quant)
+            assert dict(mirror(*lanes, 0, 4096))[
+                tmetrics.PREFILL_ATTN_FUSED_BLOCKS[0]] == (
+                continuing * c.num_layers)
+            assert dict(mirror(1024, [0, 0], [300, 0], 0, 0))[
+                tmetrics.PREFILL_ATTN_FUSED_BLOCKS[0]] == (
+                fresh * c.num_layers)
     # a region that is no whole number of blocks: the loops run
     assert dict(kernel(*lanes, 0, 4000))[
         tmetrics.PREFILL_ATTN_FUSED_BLOCKS[0]] == 0

@@ -17,7 +17,11 @@ import pytest
 from dynamo_tpu.models import llama
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.ops.attention import (
+    PALLAS,
+    PALLAS_INTERPRET,
+    DecodeAttention,
     PriorContext,
+    dense_prefill_attention,
     fused_prefill_attention,
     prefill_attention,
     prefill_attention_pairs,
@@ -238,8 +242,10 @@ def test_int8_region_is_dequantized_per_block():
 
 
 # ---------------------------------------------------------------------------
-# the fused kernel of the expanded latent chunks (ops/flash_prefill.py),
-# interpreted, against the loops: K and V per head, V narrower than K
+# the fused kernel (ops/flash_prefill.py), interpreted, against the loops:
+# the expanded latent chunks' K and V per head, V narrower than K, keys as
+# columns; and the dense decoder's groups of ``rep`` query heads a K/V
+# head, keys as the rows the engine's region holds (PR 63)
 
 FUSED_T, FUSED_SPAN = 32, 48
 # (q_starts, live rows of each chunk, with a region)
@@ -256,33 +262,50 @@ FUSED_CASES = [
 ]
 
 
-def fused_case(seed, dtype=jnp.float32, K=3, nh=4, hd=24, hd_v=16):
+def fused_case(seed, dtype=jnp.float32, K=3, nh=4, hd=24, hd_v=16, kvh=0):
+    """``kvh`` 0: K and V per head; else ``kvh`` K/V heads, chunk and
+    region, under the ``nh`` query heads."""
     rng = np.random.default_rng(seed)
+    kvh = kvh or nh
 
     def draw(*shape):
         return jnp.asarray(rng.standard_normal(shape), dtype)
 
-    return (draw(K, FUSED_T, nh, hd), draw(K, FUSED_T, nh, hd),
-            draw(K, FUSED_T, nh, hd_v),
-            (draw(1, nh, 5, FUSED_SPAN, hd), draw(1, nh, 5, FUSED_SPAN, hd_v)),
+    return (draw(K, FUSED_T, nh, hd), draw(K, FUSED_T, kvh, hd),
+            draw(K, FUSED_T, kvh, hd_v),
+            (draw(1, kvh, 5, FUSED_SPAN, hd),
+             draw(1, kvh, 5, FUSED_SPAN, hd_v)),
             jnp.asarray(rng.permutation(5)[:K], jnp.int32))
 
 
-@pytest.mark.parametrize("heads", [1, 4], ids=["a-head-a-step", "all-heads"])
+# (query heads a grid step, query heads, K/V heads): the latent form, a
+# head and all four a step; groups of 2 and of 4 query heads, a group and
+# every group a step, the region's keys read as rows in place
+FUSED_HEADS = [
+    pytest.param(1, 4, 4, id="a-head-a-step"),
+    pytest.param(4, 4, 4, id="all-heads"),
+    pytest.param(2, 4, 2, id="rep2-a-group-a-step"),
+    pytest.param(8, 8, 2, id="rep4-all-groups"),
+]
+
+
+@pytest.mark.parametrize("heads,nh,kvh", FUSED_HEADS)
 @pytest.mark.parametrize("q_starts,n_live,with_ctx", FUSED_CASES)
-def test_fused_kernel_equals_the_loops(q_starts, n_live, with_ctx, heads):
+def test_fused_kernel_equals_the_loops(q_starts, n_live, with_ctx, heads,
+                                       nh, kvh):
     """Every row, live or not, to float32-rounding distance: the same
     masks over the same blocks in the same order; dead blocks and dummy
     lanes 0 in both."""
-    q, k, v, region, slots = fused_case(sum(n_live) + heads)
+    q, k, v, region, slots = fused_case(sum(n_live) + heads, nh=nh, kvh=kvh)
     qs = jnp.asarray(q_starts, jnp.int32)
     sl = qs + jnp.asarray(n_live, jnp.int32)
     ctx = PriorContext(*region, 0, slots) if with_ctx else None
-    assert prefill_fuses(FUSED_T, 4, 4, FUSED_SPAN * with_ctx, BLOCK)
+    assert prefill_fuses(FUSED_T, nh, kvh, FUSED_SPAN * with_ctx, BLOCK)
     want = np.asarray(prefill_attention(q, k, v, qs, sl, ctx, block=BLOCK))
     got = np.asarray(fused_prefill_attention(
-        q, k, v, qs, sl, ctx, block=BLOCK, interpret=True, heads=heads))
-    assert got.shape == want.shape == (3, FUSED_T, 4, 16)
+        q, k, v, qs, sl, ctx, block=BLOCK, interpret=True, heads=heads,
+        key_rows=nh > kvh))
+    assert got.shape == want.shape == (3, FUSED_T, nh, 16)
     np.testing.assert_allclose(got, want, atol=2e-6, rtol=2e-6)
     dead = ~np.repeat(live_rows(FUSED_T, q_starts, np.asarray(sl))[
         :, ::BLOCK], BLOCK, axis=1)
@@ -293,13 +316,18 @@ def test_fused_kernel_equals_the_loops(q_starts, n_live, with_ctx, heads):
           jnp.float32)
 
 
+@pytest.mark.parametrize("rep", [1, 4], ids=["rep1", "rep4"])
 @pytest.mark.parametrize("q_starts,n_live,with_ctx", FUSED_CASES)
 def test_pair_count_is_what_the_fused_kernel_walks(q_starts, n_live,
-                                                   with_ctx):
+                                                   with_ctx, rep):
     """``prefill_attention_pairs`` still mirrors the device: the list the
     kernel's grid walks holds one step a scored (query block, key block)
-    pair, each item's steps ascending and its last one marked."""
+    pair, each item's steps ascending and its last one marked; ONE list
+    whatever the group (a step is a K/V head's tile against its ``rep``
+    query heads' blocks: the grid walks the list once a block of K/V
+    heads)."""
     span = FUSED_SPAN * with_ctx
+    assert prefill_fuses(FUSED_T, 2 * rep, 2, span, BLOCK)
     sl = np.asarray(q_starts) + np.asarray(n_live)
     below = np.minimum(np.minimum(q_starts, sl), span)
     (lane_of, qb_of, j_of, last_of, total), block_live, pblk = prefill_steps(
@@ -319,51 +347,126 @@ def test_pair_count_is_what_the_fused_kernel_walks(q_starts, n_live,
                            else j_of[i - 1] + 1) for i in range(total))
 
 
-def test_fused_kernel_at_whole_lanes():
+@pytest.mark.parametrize("nh,kvh,hd", [(2, 2, 192), (4, 1, 128)],
+                         ids=["latent-192", "rep4-rows-of-128"])
+def test_fused_kernel_at_whole_lanes(nh, kvh, hd):
     """At the served widths (blocks of whole 128-lane tiles, V 128 wide)
     the running max rides replicated over a register's lanes and the
     running sum as lane-wise partial sums: the form the chip runs, here
-    interpreted at one block of 128."""
+    interpreted at one block of 128; a group of four heads over ONE K/V
+    head of 128 with a partial last block and a prior region read in
+    place."""
     rng = np.random.default_rng(3)
 
     def draw(*shape):
         return jnp.asarray(rng.standard_normal(shape), jnp.float32)
 
-    q, k, v = draw(2, 256, 2, 192), draw(2, 256, 2, 192), draw(2, 256, 2, 128)
-    ctx = PriorContext(draw(1, 2, 3, 256, 192), draw(1, 2, 3, 256, 128), 0,
+    q, k, v = (draw(2, 256, nh, hd), draw(2, 256, kvh, hd),
+               draw(2, 256, kvh, 128))
+    ctx = PriorContext(draw(1, kvh, 3, 256, hd), draw(1, kvh, 3, 256, 128), 0,
                        jnp.asarray([2, 0], jnp.int32))
     qs, sl = jnp.asarray([130, 256]), jnp.asarray([130 + 256, 256 + 9])
     want = np.asarray(prefill_attention(q, k, v, qs, sl, ctx, block=128))
     got = np.asarray(fused_prefill_attention(q, k, v, qs, sl, ctx, block=128,
-                                             interpret=True, heads=2))
+                                             interpret=True, heads=nh,
+                                             key_rows=nh > kvh))
     np.testing.assert_allclose(got, want, atol=2e-6, rtol=2e-6)
     assert not got[1, 128:].any() and got[1, :9].any()
 
 
-def test_fused_kernel_row_that_sees_no_key_emits_zero():
+@pytest.mark.parametrize("kvh", [4, 2], ids=["rep1", "rep2-key-rows"])
+def test_fused_kernel_row_that_sees_no_key_emits_zero(kvh):
     """The kernel's own gate (no caller's mask reaches it under plain
     causality: every listed row sees key 0): a lane on the list whose
     live length the kernel is told is 0 scores only masked keys, holds
     p = exp(0) a key, and must emit 0, not their mean."""
-    q, k, v, _, _ = fused_case(5, K=1)
+    q, k, v, _, _ = fused_case(5, K=1, kvh=kvh)
     steps, _, _ = prefill_steps(jnp.asarray([FUSED_T]), jnp.asarray([0]),
                                 FUSED_T, 0, BLOCK)
+    rep = 4 // kvh
+    # the kernel's operands as ``fused_prefill_attention`` lays them out
+    qt = q.reshape(1, FUSED_T // BLOCK, BLOCK, kvh, rep, 24).transpose(
+        0, 3, 1, 4, 2, 5).reshape(1, kvh, FUSED_T * rep, 24)
     out = flash_prefill_attention(
-        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 3, 1),
+        qt, k.transpose((0, 2, 1, 3) if rep > 1 else (0, 2, 3, 1)),
         v.transpose(0, 2, 1, 3), steps, jnp.asarray([0]), block=BLOCK,
-        heads=2, interpret=True)
+        heads=2, key_rows=rep > 1, interpret=True)
     assert out.shape == (1, FUSED_T, 4 * 16) and not np.asarray(out).any()
 
 
 @pytest.mark.parametrize("width,heads,kv_heads,span,fuses", [
     (4096, 32, 32, 16384, True),
     (128, 32, 32, 0, True),
-    (1024, 32, 8, 0, False),     # K and V shared by a group of heads
+    (1024, 32, 8, 0, True),      # a group of four heads a K/V head
+    (4096, 32, 8, 4096, True),   # (Mistral-7B; a shard of Nemo-12B)
+    (256, 8, 2, 4096, True),
+    (128, 20, 1, 0, True),       # every head on ONE K/V head
+    (1024, 32, 12, 0, False),    # no whole groups
     (300, 32, 32, 0, False),     # the last block would slide back
+    (300, 32, 8, 0, False),
     (256, 32, 32, 1000, False),  # and so would the region's
+    (256, 32, 8, 1000, False),
 ])
 def test_fused_kernel_shape_rule(width, heads, kv_heads, span, fuses):
     assert prefill_fuses(width, heads, kv_heads, span) is fuses
+
+
+@pytest.mark.parametrize("holds", ["tree-mask", "int8-region", "no-attn",
+                                   "reference", "head-of-64"])
+def test_what_a_dense_call_holds_keeps_the_loops(holds):
+    """The dense decoder's call site (``dense_prefill_attention``) by what
+    the call holds: a tree mask (the speculative verifier) and an int8
+    region keep the XLA loops though the engine's programs are traced for
+    the kernel, and so does a caller that hands no ``attn`` over or the
+    jnp reference's, and a head that is no whole 128-lane tiles (the
+    smoke's llama3_1b: XLA holds its region rows-minor): the loops' own
+    result, and no fused body in the text. A window and a selection are
+    no arguments of the call: their callers (models/ssm_moe.py) call
+    ``prefill_attention``."""
+    q, k, v, region, slots = fused_case(
+        11, hd=64 if holds == "head-of-64" else 128, kvh=2)
+    qs, sl = jnp.asarray([13, 16, 0]), jnp.asarray([45, 25, 0])
+    ctx = PriorContext(*region, 0, slots)
+    attn, masks = DecodeAttention(PALLAS_INTERPRET), None
+    if holds == "tree-mask":
+        masks = jnp.asarray(np.tril(np.ones((FUSED_T, FUSED_T), bool))[
+            None].repeat(3, 0))
+    elif holds == "int8-region":
+        scale = jnp.full((1, 5, FUSED_SPAN // 16), 0.05, jnp.float32)
+        ctx = PriorContext(*(jnp.clip(jnp.round(x / 0.05), -127, 127).astype(
+            jnp.int8) for x in region), 0, slots, scale, scale)
+    elif holds == "no-attn":
+        attn = None
+    elif holds == "reference":
+        attn = DecodeAttention("reference")
+
+    @jax.jit
+    def both(q, k, v, qs, sl, ctx, masks):
+        return (dense_prefill_attention(attn, q, k, v, qs, sl, ctx, masks),
+                prefill_attention(q, k, v, qs, sl, ctx, masks))
+
+    text = both.lower(q, k, v, qs, sl, ctx, masks).as_text()
+    assert "fused_prefill_attention" not in text or holds == "int8-region"
+    got, want = both(q, k, v, qs, sl, ctx, masks)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_a_plain_dense_call_runs_the_kernel():
+    """... and the call that holds none of those runs the kernel (here
+    interpreted), equal to the loops to float32 rounding; traced for the
+    compiled kernel it lowers, on the CPU, to the loops inside the one
+    ``fused_prefill_attention`` body."""
+    q, k, v, region, slots = fused_case(11, hd=128, kvh=2)
+    qs, sl = jnp.asarray([13, 16, 0]), jnp.asarray([45, 25, 0])
+    ctx = PriorContext(*region, 0, slots)
+    want = np.asarray(prefill_attention(q, k, v, qs, sl, ctx))
+    for impl in (PALLAS_INTERPRET, PALLAS):
+        got = np.asarray(dense_prefill_attention(
+            DecodeAttention(impl), q, k, v, qs, sl, ctx))
+        np.testing.assert_allclose(got, want, atol=2e-6, rtol=2e-6)
+    jaxpr = str(jax.make_jaxpr(lambda *a: dense_prefill_attention(
+        DecodeAttention(PALLAS_INTERPRET), *a))(q, k, v, qs, sl, ctx))
+    assert "pallas_call" in jaxpr
 
 
 def test_outside_the_shape_rule_the_loops_run():
@@ -527,6 +630,37 @@ def test_batched_prefill_matches_solo_with_dummy_lanes(tiny):
                     atol=2e-4, rtol=2e-4)
 
 
+def test_dense_programs_agree_with_the_kernel_in_them():
+    """The dense decoder's solo, continuing and batched programs handed a
+    kernel's ``attn`` (here interpreted; a head of 128) against the same
+    programs on the loops: the same logits to float32 rounding and the
+    same rows in the region, a dummy lane included."""
+    cfg = ModelConfig.tiny(num_layers=2, head_dim=128, dtype="float32")
+    params = llama.init_params(cfg, 0)
+    kernel = DecodeAttention(PALLAS_INTERPRET)
+    toks = jnp.asarray(np.random.default_rng(0).integers(1, 250, 40),
+                       jnp.int32)
+    got = {}
+    for attn in (None, kernel):
+        ctx = llama.init_ctx(cfg, 4, 64, jnp.float32)
+        ctx, first = llama.prefill(
+            cfg, params, ctx, toks[:16], jnp.int32(1), jnp.int32(0),
+            jnp.int32(16), None, None, jnp.int32(0), fresh=True, attn=attn)
+        ctx, then = llama.prefill(
+            cfg, params, ctx, toks[16:32], jnp.int32(1), jnp.int32(16),
+            jnp.int32(27), None, None, jnp.int32(0), attn=attn)
+        ctx, both = llama.batch_prefill(
+            cfg, params, ctx,
+            jnp.stack([toks[24:40], toks[:16], toks[:16]]),
+            jnp.asarray([2, 3, 4]), jnp.asarray([0, 8, 0]),
+            jnp.asarray([16, 19, 0]), 64, jnp.zeros(3, jnp.int32), attn=attn)
+        got[attn] = (first, then, both[:2], ctx["k"][:, :, 1:4, :32],
+                     ctx["v"][:, :, 1:4, :32])
+    for a, b in zip(got[None], got[kernel]):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=2e-4, rtol=2e-4)
+
+
 # ---------------------------------------------------------------------------
 # program size: the guard for what set-up pays
 
@@ -615,6 +749,40 @@ def test_loops_are_rolled_and_layers_share_one_attention(shapes):
                               text)) == 1
         assert len(re.findall(r"call @prefill_attention", text)) == \
             CFG.num_layers
+
+
+@pytest.mark.parametrize("program", ["solo-fresh", "solo-ctx",
+                                     "batch-fresh", "batch-ctx"])
+def test_dense_layers_share_one_fused_attention(program):
+    """Handed the engine's ``attn`` (a kernel's), the dense decoder's
+    prefill programs call ONE lowered ``fused_prefill_attention`` body
+    from every layer, the layer a value (on the CPU, the loops inside
+    it: the kernel is a TPU lowering); handed none, the loops directly,
+    and no fused body is in the text. (A head of whole 128-lane tiles,
+    as the served dense models have: a narrower head keeps the loops.)"""
+    cfg = ModelConfig.tiny(num_layers=2, head_dim=128)
+    params = abstract(jax.eval_shape(lambda: llama.init_params(cfg, 0)))
+    ctx = abstract(jax.eval_shape(
+        lambda: llama.init_ctx(cfg, 8, 4096, jnp.bfloat16)))
+    for attn, fused in ((DecodeAttention(PALLAS), cfg.num_layers), (None, 0)):
+        if program.startswith("solo"):
+            text = llama.prefill.lower(
+                cfg, params, ctx, i32(1024), i32(), i32(), i32(), None, None,
+                i32(), fresh=program == "solo-fresh", attn=attn).as_text()
+        else:
+            text = llama.batch_prefill.lower(
+                cfg, params, ctx, i32(2, 1024), i32(2), i32(2), i32(2),
+                0 if program == "batch-fresh" else 4096, i32(2),
+                attn=attn).as_text()
+        assert len(re.findall(r"func\.func private @fused_prefill_attention\b",
+                              text)) == (1 if fused else 0)
+        assert len(re.findall(r"call @fused_prefill_attention\b",
+                              text)) == fused
+        assert len(re.findall(r"func\.func private @prefill_attention\b",
+                              text)) == 1
+        assert len(re.findall(r"call @prefill_attention\b", text)) == (
+            1 if fused else cfg.num_layers)
+        assert "tpu_custom_call" not in text
 
 
 @pytest.mark.parametrize("span", [0, 1024], ids=["fresh", "ctx"])

@@ -35,11 +35,20 @@ passed it) against the fused kernel (``fused_prefill_attention``) at
 layer-call, ``steps`` (query block, key block) pairs scored, ``us_per_step``,
 ``mxu_share`` of benchmarks/peaks.py's 197 TFLOP/s that the scored pairs'
 two products make, and the kernel's distance from the loops on live rows.
+With ``--kv-heads`` (PR 63) the DENSE decoder's GQA layer instead:
+``--q-heads`` query heads over ``--kv-heads`` K/V heads of 128 (32 / 8: a
+Mistral-7B layer; 8 / 2: a tp = 4 shard of Nemo-12B's), the prior context
+read from a region ``[1, kvh, lanes, 4096, 128]`` in place as the engine
+holds it (keys as rows), at the dense cells' buckets (``PREFILL_GQA_CASES``:
+a fresh 4096 with 2560 live rows, a fresh 2048, the chat buckets, a 2048-row
+chunk over 2048 prior rows).
 
   python tools/latent_decode_bench.py            # on the chip (chiprun)
   python tools/latent_decode_bench.py --dry-run  # tiny, interpreted, here
   python tools/latent_decode_bench.py --kind dense --old-tree .scratch/parent
   python tools/latent_decode_bench.py --kind prefill --heads 4,8,16
+  python tools/latent_decode_bench.py --kind prefill --kv-heads 8 --heads 16,32
+  python tools/latent_decode_bench.py --kind prefill --q-heads 8 --kv-heads 2
   python tools/latent_decode_bench.py --kind prefill --schedule --heads 4
 
 ``--schedule`` (HERE, no chip, ~20 s): the fused prefill kernel compiled
@@ -239,6 +248,19 @@ PREFILL_CASES = [
     ("chat_cont_2x256_over_1024", 2, 256, 4096, [1024, 512], [256, 100]),
     ("chat_cont_1x1024_over_1024", 1, 1024, 4096, [1024], [1024]),
 ]
+# the dense cells' (1, 2 and 5): 4096-row regions, prompts of ~2.5 k rows in
+# the 4096 bucket or chunked at 2048, and the chat buckets
+PREFILL_GQA_CASES = [
+    ("longprompt_fresh_4096_2560_live", 1, 4096, 0, [0], [2560]),
+    ("longprompt_fresh_2048", 1, 2048, 0, [0], [2048]),
+    ("longprompt_cont_2048_over_2048", 1, 2048, 4096, [2048], [2048]),
+    ("longprompt_cont_2048_over_2048_512_live", 1, 2048, 4096, [2048],
+     [512]),
+    ("chat_fresh_2x256", 2, 256, 0, [0, 0], [256, 150]),
+    ("chat_fresh_2x512", 2, 512, 0, [0, 0], [512, 300]),
+    ("chat_fresh_1x1024", 1, 1024, 0, [0], [1024]),
+    ("chat_cont_2x256_over_1024", 2, 256, 4096, [1024, 512], [256, 100]),
+]
 PREFILL_TOY = [
     ("toy_fresh", 2, 32, 0, [0, 0], [32, 19]),
     ("toy_cont", 2, 32, 64, [24, 0], [32, 9]),
@@ -249,21 +271,26 @@ def prefill_main(args, dev) -> int:
     """``--kind prefill``: the module doc."""
     cases, nh, hd, hd_v, dtype, block = (PREFILL_CASES, NH, 192, 128,
                                          jnp.bfloat16, 256)
+    kvh = args.kv_heads       # 0: K and V per head, the latent form
+    if kvh:
+        cases, nh, hd = PREFILL_GQA_CASES, args.q_heads, 128
     iters, interpret = args.iters, False
     if args.dry_run:
         cases, nh, hd, hd_v, dtype, block = (PREFILL_TOY, 4, 24, 16,
                                              jnp.float32, 8)
+        kvh, hd = (2, 16) if kvh else (0, hd)
         iters, interpret = 1, True
     heads = [int(h) for h in args.heads.split(",")]
     for name, K, T, span, q_starts, n_live in cases:
         if args.shapes and not any(s in name for s in args.shapes.split(",")):
             continue
         ks = jax.random.split(jax.random.PRNGKey(1), 5)
-        q, k = (jax.random.normal(x, (K, T, nh, hd), dtype) for x in ks[:2])
-        v = jax.random.normal(ks[2], (K, T, nh, hd_v), dtype)
+        q = jax.random.normal(ks[0], (K, T, nh, hd), dtype)
+        k = jax.random.normal(ks[1], (K, T, kvh or nh, hd), dtype)
+        v = jax.random.normal(ks[2], (K, T, kvh or nh, hd_v), dtype)
         work = () if not span else (
-            jax.random.normal(ks[3], (1, nh, K, span, hd), dtype),
-            jax.random.normal(ks[4], (1, nh, K, span, hd_v), dtype))
+            jax.random.normal(ks[3], (1, kvh or nh, K, span, hd), dtype),
+            jax.random.normal(ks[4], (1, kvh or nh, K, span, hd_v), dtype))
         qs = jnp.asarray(q_starts, jnp.int32)
         sl = qs + jnp.asarray(n_live, jnp.int32)
         live = np.arange(T)[None, :] < np.asarray(n_live)[:, None]
@@ -276,7 +303,8 @@ def prefill_main(args, dev) -> int:
         def loops(q, k, v, qs, sl, *work):
             ctx = PriorContext(*work, jnp.int32(0), jnp.arange(
                 K, dtype=jnp.int32)) if work else None
-            if not work:   # the parent's fresh programs: V at K's width
+            if not work and not kvh:   # the latent parent's fresh
+                # programs: V at K's width
                 v = jnp.pad(v, ((0, 0),) * 3 + ((0, hd - hd_v),))
             return prefill_attention(q, k, v, qs, sl, ctx, block=block,
                                      ctx_span=span)[..., :hd_v]
@@ -287,7 +315,7 @@ def prefill_main(args, dev) -> int:
                     K, dtype=jnp.int32)) if work else None
                 return fused_prefill_attention(
                     q, k, v, qs, sl, ctx, block=block, ctx_span=span,
-                    interpret=interpret, heads=h)
+                    interpret=interpret, heads=h, key_rows=bool(kvh))
             return call
 
         def run(call, n):
@@ -317,6 +345,7 @@ def prefill_main(args, dev) -> int:
             flop = scored * nh * 2 * (hd + hd_v)
             print(json.dumps({
                 "device": dev.device_kind, "case": name, "form": label,
+                "q_heads": nh, "kv_heads": kvh or nh,
                 "K": K, "T": T, "span": span, "q_starts": q_starts,
                 "live": n_live, "steps": steps,
                 "ms": round(sec * 1e3, 4),
@@ -355,6 +384,11 @@ def schedule_main(args) -> int:
     import tempfile
 
     heads = int(args.heads.split(",")[0])
+    # the latent layer's 4096-row chunk over a 16384-row workspace, or
+    # (--kv-heads) the dense GQA layer's over the engine's 4096-row region
+    nh, kvh, hd, span, rows = (
+        (args.q_heads, args.kv_heads, 128, 4096, True) if args.kv_heads
+        else (NH, NH, 192, 16384, False))
     with tempfile.TemporaryDirectory() as out:
         child = (
             "import jax, jax.numpy as jnp\n"
@@ -370,11 +404,13 @@ def schedule_main(args) -> int:
             "    ctx = PriorContext(pk, pv, jnp.int32(0),\n"
             "                       jnp.zeros(1, jnp.int32))\n"
             "    return fused_prefill_attention(\n"
-            "        q, k, v, qs, sl, ctx, ctx_span=16384, interpret=False,\n"
-            f"        heads={heads})\n"
-            "jax.jit(f).lower(S(1, 4096, 32, 192), S(1, 4096, 32, 192),\n"
-            "    S(1, 4096, 32, 128), S(1, dt=jnp.int32), S(1, dt=jnp.int32),\n"
-            "    S(1, 32, 1, 16384, 192), S(1, 32, 1, 16384, 128)).compile()\n")
+            f"        q, k, v, qs, sl, ctx, ctx_span={span},\n"
+            f"        interpret=False, heads={heads}, key_rows={rows})\n"
+            f"i = S(1, dt=jnp.int32)\n"
+            f"jax.jit(f).lower(S(1, 4096, {nh}, {hd}),\n"
+            f"    S(1, 4096, {kvh}, {hd}), S(1, 4096, {kvh}, 128), i, i,\n"
+            f"    S(1, {kvh}, 1, {span}, {hd}), S(1, {kvh}, 1, {span}, 128)"
+            ").compile()\n")
         ran = subprocess.run(
             [sys.executable, "-c", child], capture_output=True, text=True,
             cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
@@ -424,8 +460,14 @@ def main(argv=None) -> int:
                     help="dense, prefill: the shapes to run (all by "
                          "default; prefill: substrings of the cases' names)")
     ap.add_argument("--heads", default="16",
-                    help="prefill: heads a grid step of the fused kernel, "
-                         "one run each")
+                    help="prefill: query heads a grid step of the fused "
+                         "kernel, one run each")
+    ap.add_argument("--kv-heads", type=int, default=0,
+                    help="prefill: the dense GQA layer's cases, so many "
+                         "K/V heads of 128 (0: the expanded latent "
+                         "layer's, K and V per head)")
+    ap.add_argument("--q-heads", type=int, default=NH,
+                    help="prefill, with --kv-heads: the query heads")
     ap.add_argument("--dry-run", action="store_true")
     ap.add_argument("--chunks", default="256,512,1024")
     ap.add_argument("--iters", type=int, default=50)

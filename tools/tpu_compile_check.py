@@ -29,8 +29,9 @@ Mosaic call; not for the latent block, whose step is in the round), the
 ring->ctx flush, the standalone ctx->pool seal at ``--seal-width``
 entries, both in one jit, the fused round as the engine builds it
 (``flush_every`` decode steps, flush, seal), the pool -> region load and
-the pool's page gather / scatter at a long prompt's pages, and a batched
-prefill at the lanes the engine gives a group of two: fresh
+the pool's page gather / scatter at a long prompt's pages, one chunk's
+prefill alone (``prefill``, ``prefill_cont``) and a batched prefill at the
+lanes the engine gives a group of two: fresh
 (``batch_prefill``) and continuing contexts in the region
 (``batch_prefill_cont``), at the smallest bucket (``--prefill-width``
 names another: the long-context cell's continuing ``[1, 4096]`` program
@@ -273,6 +274,7 @@ def compile_programs(model_config: str = "llama3_1b", tp: int = 1,
         raise ValueError(f"{T} is no prefill bucket of {e.prefill_buckets}")
     K = e.prefill_lanes(T, 2)
     counted = llama.moe_prefill_rows_sorted(c, K * T) > 0
+    solo_counted = llama.moe_prefill_rows_sorted(c, T) > 0
 
     def largest_shard(state):
         a = max(jax.tree.leaves(state), key=lambda a: math.prod(a.shape))
@@ -359,15 +361,25 @@ def compile_programs(model_config: str = "llama3_1b", tp: int = 1,
                                         page_scales)
             if quant else
             llama.scatter_pages.trace(pool, i32(n_pages), page_data)),
+        # one chunk alone (the engine's program for a group of one at a
+        # bucket the batched form does not take), fresh and continuing
+        "prefill": lambda: llama.prefill.trace(
+            c, params, ctx, i32(T), i32(), i32(), i32(), None, None, i32(),
+            fresh=True, counted=solo_counted, attn=attn,
+        ),
+        "prefill_cont": lambda: llama.prefill.trace(
+            c, params, ctx, i32(T), i32(), i32(), i32(), None, None, i32(),
+            fresh=False, counted=solo_counted, attn=attn,
+        ),
         # counted as the engine dispatches it: where the expert layers
         # move rows in the looped form the program also returns its count
         "batch_prefill": lambda: llama.batch_prefill.trace(
             c, params, ctx, i32(K, T), i32(K), i32(K), i32(K), 0, i32(K),
-            counted=counted,
+            counted=counted, attn=attn,
         ),
         "batch_prefill_cont": lambda: llama.batch_prefill.trace(
             c, params, ctx, i32(K, T), i32(K), i32(K), i32(K), S, i32(K),
-            counted=counted,
+            counted=counted, attn=attn,
         ),
         # the one program a prefill dispatch that samples every first
         # token of the group and admits its slots, on the logits as the
@@ -396,6 +408,8 @@ def compile_programs(model_config: str = "llama3_1b", tp: int = 1,
                  ("load_ctx_pages", "gather_pages", "scatter_pages")},
               "round_seal": f"round_seal_n{R}_w{W}",
               "admit_first": f"admit_first_K{K}",
+              "prefill": f"prefill_T{T}",
+              "prefill_cont": f"prefill_cont_T{T}_S{S}",
               "batch_prefill": f"batch_prefill_K{K}_T{T}",
               "batch_prefill_cont": f"batch_prefill_cont_K{K}_T{T}_S{S}"}
     out = []
@@ -460,7 +474,8 @@ def main(argv: list[str] | None = None) -> int:
                     help="comma-separated subset: decode_step, flush_ctx, "
                          "seal_blocks, flush_seal, round_seal, "
                          "load_ctx_pages, gather_pages, scatter_pages, "
-                         "batch_prefill, batch_prefill_cont, admit_first")
+                         "prefill, prefill_cont, batch_prefill, "
+                         "batch_prefill_cont, admit_first")
     ap.add_argument("--seal-width", type=int, default=0,
                     help="entries of the standalone seal (0 = the fused "
                          "round's width)")
