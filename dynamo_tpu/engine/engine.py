@@ -379,14 +379,6 @@ class TpuEngine:
         if what is not None:
             self._refuse_row_only_planes(self.ecfg, on_dispatch,
                                          draft_config, what)
-        if self.ecfg.max_decode_slots < len(self._stats_layout):
-            # the round's counters ride home in one more row of the
-            # stacked-token fetch, max_decode_slots wide
-            raise ValueError(
-                f"max_decode_slots={self.ecfg.max_decode_slots}: this "
-                f"routed-expert model needs at least "
-                f"{len(self._stats_layout)} (its counters ride the "
-                "round's token fetch in a row that wide)")
         dev0 = self.mesh.devices.flat[0]
         log.info(
             "engine devices: platform=%s device_kind=%s mesh=%s "
@@ -521,13 +513,13 @@ class TpuEngine:
             )
 
             page_shape = (
-                2, c.num_layers, c.num_kv_heads, e.page_size, c.head_dim
+                2, c.cache_planes, c.num_kv_heads, e.page_size, c.head_dim
             )
             # tiers store what the pool stores: int8 pages + per-page
             # scale sidecars under kv_quant, so G2/G3 hold ~2x the
             # blocks per byte too
             tier_dtype = np.int8 if self.kv_quant else cache_dtype
-            scale_shape = (2, c.num_layers) if self.kv_quant else ()
+            scale_shape = (2, c.cache_planes) if self.kv_quant else ()
             spill = None
             if e.disk_offload_pages > 0:
                 spill = DiskOffloadTier(
@@ -604,13 +596,20 @@ class TpuEngine:
             c, e.max_context, e.flush_every, self.decode_attn)
         self._prefill_mirror = llama.prefill_mirror(
             c, self.decode_attn, e.kv_quant)
-        # bytes a token holds in the ctx region, and bytes a lane holds
-        # in recurrent state whatever its context: observed once, here
+        # bytes a token holds in the ctx region over how many planes of
+        # rows, and bytes a lane holds in recurrent state whatever its
+        # context: observed once, here
         recurrent = llama.state_kinds(self.ctx)
-        self.telemetry.get(tmetrics.KV_ROW_BYTES[0]).observe(sum(
+        kv_row_bytes = sum(
             x.nbytes for n, leaf in self.ctx.items() if n not in recurrent
             for x in jax.tree.leaves(leaf)
-        ) / ((e.max_decode_slots + 1) * e.max_context))
+        ) / ((e.max_decode_slots + 1) * e.max_context)
+        planes = max(self.ctx[n].shape[0] for n in llama.row_kinds(self.ctx))
+        self.telemetry.get(tmetrics.KV_ROW_BYTES[0]).observe(kv_row_bytes)
+        self.telemetry.get(tmetrics.KV_CACHE_PLANES[0]).observe(planes)
+        log.info("engine state: kv_row_bytes=%d cache_planes=%d lanes=%d "
+                 "max_context=%d", kv_row_bytes, planes, e.max_decode_slots,
+                 e.max_context)
         if recurrent:
             self.telemetry.get(tmetrics.SSM_STATE_BYTES[0]).observe(sum(
                 x.nbytes for n in recurrent
@@ -856,10 +855,11 @@ class TpuEngine:
             bare argmax instead of top-k over the vocab."""
             B = dev["tokens"].shape[0]
             ring_base = jnp.maximum(dev["ctx"] - 1, 0)
-            # a block that counts: one more row carries the round's
-            # counters (llama.stats_layout) home in the same fetch
-            rides = bool(llama.stats_layout(c))
-            toks_out = jnp.zeros((n_steps + int(rides), B), jnp.int32)
+            # a block that counts: one more row (more where its columns
+            # outnumber the lanes) carries the round's counters
+            # (llama.stats_layout) home in the same fetch
+            rides = -(-len(llama.stats_layout(c)) // B)
+            toks_out = jnp.zeros((n_steps + rides, B), jnp.int32)
             stats = llama.stats_zero(c)
             # the region's leaves a decode STEP writes (recurrent state,
             # rows a step completes) where its other rows are read-only
@@ -923,8 +923,12 @@ class TpuEngine:
                 0, n_steps, body,
                 (ring, dev, toks_out, lp_out, stats, stepped)
             )
-            if rides:
+            if rides == 1:   # as it always was: those programs' text stays
                 toks_out = toks_out.at[n_steps, :stats.shape[0]].set(stats)
+            elif rides:
+                toks_out = toks_out.at[n_steps:].set(jnp.pad(
+                    stats, (0, rides * B - stats.shape[0])
+                ).reshape(rides, B))
             # round boundary: the ring goes into the ctx region, one
             # in-place span a lane, after every read (llama.flush_ctx_impl)
             valid = jnp.minimum(jnp.int32(n_steps), max_context - ring_base)
@@ -4408,7 +4412,7 @@ class TpuEngine:
         for col, hist, f32_bits in self._stats_table:
             # the block's declared counter row (llama.stats_layout): one
             # more row of the fetch, behind the steps' tokens
-            v = toks[entry.n_steps, col:col + 1]
+            v = toks[entry.n_steps:].reshape(-1)[col:col + 1]
             hist.observe(float(v.astype(np.int32).view(np.float32)[0])
                          if f32_bits else int(v[0]))
         delivered = 0

@@ -178,7 +178,9 @@ def kvmeta_key(namespace: str, worker_id: str) -> str:
 
 @dataclass
 class KvCacheLayout:
-    """Block geometry; both sides must agree before pages move."""
+    """Block geometry; both sides must agree before pages move.
+    ``num_layers`` is the pool's leading axis, the model's
+    ``cache_planes``: a looped stack holds a plane a (step, layer)."""
 
     num_layers: int
     num_kv_heads: int

@@ -1007,7 +1007,9 @@ async def _attach_data_plane(args, rt, engine, worker_id: str):
     await publish_descriptor(rt.kv, args.namespace, BlocksetDescriptor(
         worker_id=worker_id, host=host, port=port,
         layout=KvCacheLayout(
-            num_layers=cfg.num_layers, num_kv_heads=cfg.num_kv_heads,
+            # the wire's layer axis is the pool's: a plane a layer, a
+            # plane a (step, layer) of a looped stack
+            num_layers=cfg.cache_planes, num_kv_heads=cfg.num_kv_heads,
             page_size=ecfg.page_size, head_dim=cfg.head_dim,
             # what moves on the wire: int8 payloads (+ header scales)
             # for a quantized pool

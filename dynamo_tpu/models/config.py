@@ -16,7 +16,12 @@ from typing import Any, Optional
 # model_type values served by the dense decoder (models/llama.py) and by
 # the latent-attention + routed-expert block (models/mla_moe.py); the
 # state-space + attention hybrid with routed experts is models/ssm_moe.py
-_DENSE_TYPES = frozenset({"llama", "mistral", "qwen2"})
+_DENSE_TYPES = frozenset({"llama", "mistral", "qwen2", "ouro"})
+# the dense decoder run ``total_ut_steps`` times over the SAME weights, a
+# K/V plane a (step, layer), four norms a layer, the final norm and an
+# exit gate after every step (_ouro_loop reads what the dense reader does
+# not; models/llama.py builds it as static branches of the dense decoder)
+_LOOPED_TYPES = frozenset({"ouro"})
 _MLA_MOE_TYPES = frozenset({"deepseek_v3", "joyai_llm_flash", "xing4_0"})
 _SSM_MOE_TYPES = frozenset({"granitemoehybrid"})
 # linear-attention layers with a matrix state beside block-sparse NoPE GQA
@@ -384,6 +389,32 @@ class ModelConfig:
     # B/C groups (`mamba_n_groups`), experts without a gate matrix
     # (`expert_act` relu2).
     hybrid: Optional[tuple[tuple[str, Any], ...]] = None
+    # The looped dense decoder (_ouro_loop): the stack of ``num_layers``
+    # WEIGHT layers runs ``loop_steps`` times over the same weights, the
+    # final norm after every step (its output feeds the next step and,
+    # after the last, the head). Attention at step t, layer l reads and
+    # writes CACHE PLANE t * num_layers + l (``cache_planes`` of them):
+    # whatever sizes a cache asks ``cache_planes``, whatever indexes a
+    # weight asks ``num_layers``. ``sandwich_norms``: a second norm on
+    # each half's output, ahead of the residual add (gains ln1b, ln2b).
+    # ``exit_gate``: a Linear(hidden, 1) + sigmoid on every step's
+    # output; the exit CDF is evaluated and counted, and at the served
+    # threshold of 1 only the last step reaches it.
+    loop_steps: int = 1
+    sandwich_norms: bool = False
+    exit_gate: bool = False
+
+    @property
+    def looped(self) -> bool:
+        """Whether the dense decoder takes any of its loop branches; a
+        config that takes none lowers to the plain dense programs."""
+        return self.loop_steps > 1 or self.sandwich_norms or self.exit_gate
+
+    @property
+    def cache_planes(self) -> int:
+        """Planes of K/V a cache (region, ring, pool page, transfer
+        frame) holds a token: one a weight layer a loop step."""
+        return self.num_layers * self.loop_steps
 
     @property
     def hybrid_dict(self) -> Optional[dict[str, Any]]:
@@ -453,7 +484,9 @@ class ModelConfig:
                 f"+ full rotary GQA layers with head counts by layer, a "
                 f"head gate and sigmoid experts (_from_hf_laguna): "
                 f"{sorted(_LAGUNA_TYPES)})")
-        unknown = sorted(k for k in _FOREIGN_KEYS if d.get(k))
+        loop = cls._ouro_loop(d) if model_type in _LOOPED_TYPES else {}
+        unknown = sorted(k for k in _FOREIGN_KEYS
+                         if d.get(k) and not (loop and k == "layer_types"))
         if unknown:
             raise ValueError(
                 f"config keys {unknown} belong to a block the dense "
@@ -479,7 +512,39 @@ class ModelConfig:
             max_position_embeddings=d.get("max_position_embeddings", 8192),
             tie_word_embeddings=d.get("tie_word_embeddings", False),
             model_type=d.get("model_type", "llama"),
+            **loop,
         )
+
+    @staticmethod
+    def _ouro_loop(d: dict[str, Any]) -> dict[str, Any]:
+        """The loop's fields of an ``ouro`` file (LoopLM, arXiv:2510.25741):
+        ``total_ut_steps`` passes of the stack, sandwich norms and the
+        exit gate always. The Qwen2-style window keys the family's files
+        carry dead are read as what they say, no window
+        (``max_window_layers`` then means nothing); a window, a layer
+        that is not full attention, or a threshold under 1 is refused."""
+        steps = int(d.get("total_ut_steps", 1))
+        if steps < 1:
+            raise ValueError(f"total_ut_steps={steps}: at least one pass")
+        if d.get("use_sliding_window") or d.get("sliding_window"):
+            raise ValueError(
+                "an ouro file with a sliding window (use_sliding_window="
+                f"{d.get('use_sliding_window')!r}, sliding_window="
+                f"{d.get('sliding_window')!r}): the looped dense decoder "
+                "has full attention only")
+        kinds = set(d.get("layer_types") or ()) - {"full_attention"}
+        if kinds:
+            raise ValueError(
+                f"ouro layer_types {sorted(kinds)}: the looped dense "
+                "decoder has full_attention layers only")
+        threshold = float(d.get("early_exit_threshold", 1.0))
+        if threshold < 1.0:
+            raise ValueError(
+                f"early_exit_threshold={threshold:g} < 1 (per-token early "
+                "exit: a step that no longer runs for every lane, K/V "
+                "planes of skipped steps to fill) is not served yet; the "
+                "published threshold of 1 runs every step for every token")
+        return dict(loop_steps=steps, sandwich_norms=True, exit_gate=True)
 
     @classmethod
     def _from_hf_mla_moe(cls, d: dict[str, Any]) -> "ModelConfig":
@@ -1424,6 +1489,16 @@ class ModelConfig:
         return cls(**base)
 
     @classmethod
+    def tiny_looped(cls, **kw) -> "ModelConfig":
+        """The toy model as a looped stack (the ``ouro`` block's shape):
+        3 weight layers run 4 times, 12 cache planes, sandwich norms, the
+        exit gate."""
+        base = dict(num_layers=3, loop_steps=4, sandwich_norms=True,
+                    exit_gate=True, model_type="ouro")
+        base.update(kw)
+        return cls.tiny(**base)
+
+    @classmethod
     def tiny_wide(cls, **kw) -> "ModelConfig":
         """Toy model with 4 kv heads — shardable to tp=4 (multi-host CPU
         tests / the cross-host CLI path)."""
@@ -1505,12 +1580,14 @@ class ModelConfig:
         )
 
     def num_params(self) -> int:
-        """Approximate parameter count (for memory planning)."""
+        """Approximate parameter count (for memory planning). A looped
+        stack's weights are counted ONCE: they are held once."""
         h, i, v = self.hidden_size, self.intermediate_size, self.vocab_size
         per_layer = (
             h * self.q_dim + 2 * h * self.kv_dim + self.q_dim * h  # attn
             + 3 * h * i  # mlp
-            + 2 * h  # norms
+            + (4 if self.sandwich_norms else 2) * h  # norms
         )
         embed = v * h * (1 if self.tie_word_embeddings else 2)
-        return self.num_layers * per_layer + embed + h
+        gate = h + 1 if self.exit_gate else 0
+        return self.num_layers * per_layer + embed + h + gate

@@ -128,6 +128,21 @@ Functions of planes that cannot carry a latent row or a recurrent state
 (speculation, sequence-parallel prefill, embeddings, page transfer, the
 dense ``decode_step``) refuse it by name (``_dense_only``).
 
+THE LOOP (``ModelConfig.looped``: ``model_type`` ``ouro``). The dense
+decoder of this file run ``loop_steps`` times over the SAME weights, as
+static branches: a config that takes none of them traces the plain dense
+programs, to the letter (tests/test_lowering_looped.py). WEIGHTS are
+indexed by layer ``l`` (``num_layers`` of them, a LoRA factor a weight
+layer); K/V by PLANE ``t * num_layers + l`` (``cache_planes``: the leading
+axis of region, ring and pool, a page of the offload tiers and the wire),
+the rows THIS pass of layer l wrote. Sandwich norms (``ln1b``, ``ln2b``)
+norm each half's output ahead of its add; ``_step_end`` closes every pass
+with the ONE final norm (``_logits`` then adds none) and, in the round,
+the exit gate, whose CDFs ``round_step`` counts. ``_over_passes`` runs the
+passes; a prefill's pass stores its own planes as it ends. Planes that run
+each layer once refuse a looped stack by name (``_no_loop``, and
+``state_called`` at engine start).
+
 Parity: this is the TPU engine the reference delegates to vLLM for
 (launch/dynamo-run subprocess engines; SURVEY.md §2.1 L3).
 """
@@ -159,7 +174,11 @@ from dynamo_tpu.ops.attention import (
     dense_round_rows,
 )
 from dynamo_tpu.ops.rope import apply_rope, rope_cos_sin, rope_inv_freq
-from dynamo_tpu.telemetry.metrics import Counter
+from dynamo_tpu.telemetry.metrics import (
+    LOOP_EXIT_CDF,
+    LOOP_STEPS_RUN,
+    Counter,
+)
 
 Params = dict[str, Any]
 Cache = dict[str, jnp.ndarray]
@@ -244,14 +263,34 @@ def _dense_only(config_or_state, plane: str) -> None:
             "moves K and V rows that are addressable by position")
 
 
+def _no_loop(config: ModelConfig, plane: str) -> None:
+    """Refuse a looped stack (``config.looped``: the layers run several
+    times, a K/V plane a step a layer, sandwich norms, the step's norm
+    and gate) in a plane that runs the layers once, naming the plane."""
+    if config.looped:
+        raise ValueError(
+            f"{plane}: this plane cannot carry a looped layer stack yet "
+            f"({config.loop_steps} passes over {config.num_layers} layers, "
+            f"{config.cache_planes} K/V planes a token); it runs each "
+            "layer once")
+
+
 # ---------------------------------------------------------------------------
 # What the engine asks of a block's state and counters (the block protocol)
 
 @_hands_over
 def stats_layout(config: ModelConfig) -> tuple[Counter, ...]:
     """The columns of a round's counter row, in order. The dense decoder
-    counts nothing: no row rides its round's token fetch."""
-    return ()
+    counts nothing: no row rides its round's token fetch. A looped stack
+    counts the passes a decoded token ran and, with the exit gate, the
+    live lanes' mean exit CDF after each step before the last (as many
+    steps as the registry has histograms for)."""
+    if not config.looped:
+        return ()
+    steps = min(config.loop_steps - 1, len(LOOP_EXIT_CDF))
+    return (Counter(LOOP_STEPS_RUN[0]),) + tuple(
+        Counter(LOOP_EXIT_CDF[t][0], f32_bits=True)
+        for t in range(steps if config.exit_gate else 0))
 
 
 @_hands_over
@@ -259,14 +298,20 @@ def stats_zero(config: ModelConfig) -> jnp.ndarray:
     """A round's counter row before any step. The dense decoder's is the
     three-wide zero its round has always carried and never written (a
     dead carry of the loop: another shape would move the program text of
-    every dense round)."""
-    return jnp.zeros(3, jnp.int32)
+    every dense round). A looped stack's is its layout's."""
+    return jnp.zeros(len(stats_layout(config)) or 3, jnp.int32)
 
 
 @_hands_over
 def state_called(config: ModelConfig) -> Optional[str]:
     """What a plane that moves a K and a V row is told it cannot carry,
-    or None where K and V rows are all a lane holds (here)."""
+    or None where K and V rows are all a lane holds (here), a plane a
+    layer: a looped stack's lanes hold a plane a (step, layer), which the
+    planes that size or index a cache by the weight layers cannot carry."""
+    if config.looped:
+        return (f"a looped layer stack ({config.loop_steps} passes over "
+                f"{config.num_layers} layers, {config.cache_planes} K/V "
+                "planes a token)")
     return None
 
 
@@ -308,14 +353,15 @@ def prefill_mirror(config: ModelConfig, attn: DecodeAttention,
     seq_lens, scored, ctx_span) -> ((metric, value), ...)``. Here: the
     query blocks of every layer's attention, and those that ran through
     the fused kernel: every layer's, a group of query heads a K/V head,
+    (every pass of every layer of a looped stack: ``cache_planes``)
     where ``attn`` (what the engine's programs are traced for, which
     the prefill programs are handed too) names a kernel, at a geometry
     inside its shape rule and a head of whole 128-lane tiles; a
     continuing chunk over an int8 region (``kv_quant``) keeps the
     loops."""
     return mla_moe.blocks_mirror(
-        attn, config.num_layers,
-        fused_layers=config.num_layers * dense_head_fuses(config.head_dim),
+        attn, config.cache_planes,
+        fused_layers=config.cache_planes * dense_head_fuses(config.head_dim),
         n_heads=config.num_heads, kv_heads=config.num_kv_heads,
         int8_region=kv_quant == "int8")
 
@@ -359,6 +405,10 @@ def init_params(config: ModelConfig, rng: jax.Array | int = 0) -> Params:
         "wv": rnd(keys[3], L, H, c.kv_dim),
         "wo": rnd(keys[4], L, c.q_dim, H),
     }
+    if c.sandwich_norms:
+        # the gains on each half's OUTPUT, ahead of the residual add
+        layers.update(ln1b=jnp.ones((L, H), dtype),
+                      ln2b=jnp.ones((L, H), dtype))
     if c.moe is not None:
         E = c.moe_dict["num_experts"]
         layers.update(
@@ -380,6 +430,12 @@ def init_params(config: ModelConfig, rng: jax.Array | int = 0) -> Params:
     }
     if not c.tie_word_embeddings:
         params["lm_head"] = rnd(keys[8], H, V, scale=0.02)
+    if c.exit_gate:
+        # Linear(H, 1) on every step's output, drawn a tenth of a
+        # projection's scale: exit probabilities near a half
+        params["gate_w"] = rnd(keys[10], H, scale=0.1 / np.sqrt(H),
+                               qaxis=None)
+        params["gate_b"] = jnp.zeros((), dtype)
     return params
 
 
@@ -409,6 +465,8 @@ def param_shardings(config: ModelConfig, mesh: Mesh) -> Params:
         "wv": w("wv", None, None, "tp"),
         "wo": w("wo", None, "tp", None),
     }
+    if config.sandwich_norms:
+        layers.update(ln1b=ns(None, None), ln2b=ns(None, None))
     if config.moe is not None:
         # experts over ep, expert hidden over tp (wide-EP shape §2.5)
         layers.update(
@@ -430,6 +488,8 @@ def param_shardings(config: ModelConfig, mesh: Mesh) -> Params:
     }
     if not config.tie_word_embeddings:
         out["lm_head"] = w("lm_head", None, "tp")
+    if config.exit_gate:
+        out.update(gate_w=ns(None), gate_b=ns())
     return out
 
 
@@ -463,13 +523,13 @@ def init_cache(
     untouched: quantize fuses into seal_blocks (ctx->pool), dequantize
     into load_ctx_pages (pool->ctx)."""
     c = config
-    shape = (c.num_layers, c.num_kv_heads, num_pages, page_size, c.head_dim)
+    shape = (c.cache_planes, c.num_kv_heads, num_pages, page_size, c.head_dim)
     if kv_quant == "int8":
         return {
             "k": jnp.zeros(shape, jnp.int8),
             "v": jnp.zeros(shape, jnp.int8),
-            "k_scale": jnp.zeros((c.num_layers, num_pages), jnp.float32),
-            "v_scale": jnp.zeros((c.num_layers, num_pages), jnp.float32),
+            "k_scale": jnp.zeros((c.cache_planes, num_pages), jnp.float32),
+            "v_scale": jnp.zeros((c.cache_planes, num_pages), jnp.float32),
         }
     dtype = dtype or jnp.dtype(c.dtype)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
@@ -498,7 +558,9 @@ def init_ctx(
     config: ModelConfig, batch: int, ctx_len: int, dtype=None,
     kv_quant: str = "none", group: int = 128,
 ) -> Cache:
-    """Contiguous per-slot serving context ``[L, kvh, batch+1, S, hd]``.
+    """Contiguous per-slot serving context ``[L, kvh, batch+1, S, hd]``
+    (L the config's ``cache_planes`` here, in the pool and in the ring:
+    a plane a layer, a plane a (step, layer) of a looped stack).
     Lane `batch` is the scratch lane for freed slots' in-flight garbage
     steps (see module doc / engine dest redirection).
 
@@ -511,7 +573,7 @@ def init_ctx(
     S is padded up to a multiple of it (the engine's max_context is
     already page-aligned, so no padding in practice)."""
     c = config
-    shape = (c.num_layers, c.num_kv_heads, batch + 1, ctx_len, c.head_dim)
+    shape = (c.cache_planes, c.num_kv_heads, batch + 1, ctx_len, c.head_dim)
     if kv_quant == "int8":
         S = -(-ctx_len // group) * group
         shape = shape[:3] + (S,) + shape[4:]
@@ -519,9 +581,9 @@ def init_ctx(
             "k": jnp.zeros(shape, jnp.int8),
             "v": jnp.zeros(shape, jnp.int8),
             "k_scale": jnp.zeros(
-                (c.num_layers, batch + 1, S // group), jnp.float32),
+                (c.cache_planes, batch + 1, S // group), jnp.float32),
             "v_scale": jnp.zeros(
-                (c.num_layers, batch + 1, S // group), jnp.float32),
+                (c.cache_planes, batch + 1, S // group), jnp.float32),
         }
     dtype = dtype or jnp.dtype(c.dtype)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
@@ -643,7 +705,7 @@ def init_ring(
     """
     c = config
     dtype = dtype or jnp.dtype(c.dtype)
-    shape = (c.num_layers, c.num_kv_heads, batch, ring_len, c.head_dim)
+    shape = (c.cache_planes, c.num_kv_heads, batch, ring_len, c.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
@@ -906,11 +968,18 @@ def _layer_qkv(c: ModelConfig, lp, h, cos, sin, ad=None):
 
 
 def _layer_out(c: ModelConfig, lp, h, attn, ffn_valid=None, ad=None):
-    """Second half: output projection, residual, norm, FFN."""
+    """Second half: output projection, residual, norm, FFN; with
+    sandwich norms each half's output is normed ahead of its add."""
     ad = ad or {}
-    h = h + _mm_ad(attn.reshape(h.shape[0], c.q_dim), lp["wo"], ad.get("wo"))
+    a = _mm_ad(attn.reshape(h.shape[0], c.q_dim), lp["wo"], ad.get("wo"))
+    if c.sandwich_norms:
+        a = rms_norm(a, lp["ln1b"], c.rms_norm_eps)
+    h = h + a
     x2 = rms_norm(h, lp["ln2"], c.rms_norm_eps)
-    return h + _ffn(c, lp, x2, ffn_valid, ad)
+    f = _ffn(c, lp, x2, ffn_valid, ad)
+    if c.sandwich_norms:
+        f = rms_norm(f, lp["ln2b"], c.rms_norm_eps)
+    return h + f
 
 
 def _layer_body(c: ModelConfig, lp, h, cos, sin, write_kv, attend,
@@ -1020,8 +1089,50 @@ def _live_rows(half, c: ModelConfig, layers, l, ad, trips, rows, R: int):
     return over_live_blocks(block, trips, rows, R)
 
 
+def _over_passes(c: ModelConfig, one_pass: Callable, carry):
+    """``carry = one_pass(t, carry)`` for every loop step ``t`` of the
+    stack: ONE pass straight through for the plain dense decoder (t = 0,
+    a plane a layer: its programs' text does not move), ``loop_steps``
+    passes for a looped one, each under the scope ``loop_step_<t>`` (a
+    looped stack's device ops carry their step in a trace). ``one_pass``
+    runs every layer l with the layer's weights and the cache plane
+    ``t * num_layers + l``. The steps are UNROLLED like the layers, the
+    step a static index: as a ``fori_loop`` of ``loop_steps`` trips
+    round the unrolled layers the round and the batched prefills compile
+    for the v5e in a third to a half of the time and copy nothing, but
+    the solo fresh prefill of a 1024 bucket then copies the region it
+    carries (7.5 GB more than the chip has; compile-only, PERF.md
+    section 6, PR 64)."""
+    if not c.looped:
+        return one_pass(0, carry)
+    for t in range(c.loop_steps):
+        with jax.named_scope(f"loop_step_{t}"):
+            carry = one_pass(t, carry)
+    return carry
+
+
+def _step_end(c: ModelConfig, params: Params, h: jnp.ndarray, stay=None):
+    """What closes a pass of a looped stack over ``h`` [..., H]: the ONE
+    final norm (its output feeds the next pass, and the head after the
+    last: ``_logits`` adds none) and, where ``stay`` is given (the round
+    of a config with the gate), the exit gate on the normed rows.
+    ``stay`` [...] f32 is the probability that a row has not left before
+    this step (1 ahead of the first); returns (h, stay after this step):
+    1 - stay is the exit CDF."""
+    with jax.named_scope("loop_norm_gate"):
+        h = rms_norm(h, params["norm_f"], c.rms_norm_eps)
+        if stay is not None:
+            lam = jax.nn.sigmoid(
+                jnp.einsum("...h,h->...", h.astype(jnp.float32),
+                           params["gate_w"].astype(jnp.float32))
+                + params["gate_b"].astype(jnp.float32))
+            stay = stay * (1.0 - lam)
+    return h, stay
+
+
 def _logits(config: ModelConfig, params: Params, h: jnp.ndarray) -> jnp.ndarray:
-    h = rms_norm(h, params["norm_f"], config.rms_norm_eps)
+    if not config.looped:    # a looped stack's last pass has normed it
+        h = rms_norm(h, params["norm_f"], config.rms_norm_eps)
     w = params["embed"] if config.tie_word_embeddings else params["lm_head"]
     if _is_quant(w):
         q = w["q"].T if config.tie_word_embeddings else w["q"]  # [H, V]
@@ -1126,48 +1237,80 @@ def _prefill_impl(config, params, ctx_kv, tokens, slot, q_start, seq_len,
             tuple(x[None] for x in rows), R)
         return tuple(x[0] for x in out)
 
-    new_ks: list[jnp.ndarray] = []
-    new_vs: list[jnp.ndarray] = []
-    for l in range(c.num_layers):
-        if R:
-            q, k, v = live(_layer_qkv, l, h, cos, sin)
-        else:
-            lp = jax.tree.map(lambda x: x[l], params["layers"])
-            ad = _adapter_layer(ag, l, per_row=False)
-            q, k, v = _layer_qkv(c, lp, h, cos, sin, ad)
-        new_ks.append(k)
-        new_vs.append(v)
-        o = dense_prefill_attention(
-            attn, q[None], k[None], v[None], one(q_start), one(seq_len),
-            None if fresh else _prior_context(ctx_kv, l, one(slot)),
-        )[0]
-        if R:
-            h, = live(_layer_out, l, h, o)
-        else:
-            # padding tokens must not claim MoE expert capacity
-            h = _layer_out(c, lp, h, o, positions < seq_len, ad)
-
-    # tail: one contiguous span write per buffer (all reads are done)
-    upd_k = jnp.stack(new_ks).transpose(0, 2, 1, 3)  # [L, kvh, T, hd]
-    upd_v = jnp.stack(new_vs).transpose(0, 2, 1, 3)
-    if ctx_is_quantized(ctx_kv):
-        g = ctx_group_size(ctx_kv)
-        ck, ksc = _quant_store_span(
-            ctx_kv["k"], ctx_kv["k_scale"], slot, q_start, upd_k, g,
-            valid_t=seq_len - q_start)
-        cv, vsc = _quant_store_span(
-            ctx_kv["v"], ctx_kv["v_scale"], slot, q_start, upd_v, g,
-            valid_t=seq_len - q_start)
-        out_ctx = {"k": ck, "v": cv, "k_scale": ksc, "v_scale": vsc}
-    else:
+    # A looped stack runs the layers ``loop_steps`` times over the same
+    # weights (weights by layer l, K/V by plane t * L + l: the rows THIS
+    # step's pass of layer l wrote), the final norm closing every pass,
+    # and its chunk rows go into the region a PASS at a time, planes
+    # [t * L, (t + 1) * L) after the pass's last read: carried to one tail
+    # they are cache_planes x 2 rows a token of temporaries (1.5 MB a
+    # token at Ouro-2.6B's widths, 3 GB a 1024-token chunk with its stack)
+    # beside a region sized to fill the chip. No pass reads what an
+    # earlier one wrote (other planes, and rows at and above q_start).
+    def store(ctx_kv, new_ks, new_vs, plane0):
+        # one contiguous span write per buffer
+        upd_k = jnp.stack(new_ks).transpose(0, 2, 1, 3)  # [L, kvh, T, hd]
+        upd_v = jnp.stack(new_vs).transpose(0, 2, 1, 3)
+        if ctx_is_quantized(ctx_kv):
+            _no_loop(c, "kv_quant=int8 (the int8 KV plane)")
+            g = ctx_group_size(ctx_kv)
+            ck, ksc = _quant_store_span(
+                ctx_kv["k"], ctx_kv["k_scale"], slot, q_start, upd_k, g,
+                valid_t=seq_len - q_start)
+            cv, vsc = _quant_store_span(
+                ctx_kv["v"], ctx_kv["v_scale"], slot, q_start, upd_v, g,
+                valid_t=seq_len - q_start)
+            return {"k": ck, "v": cv, "k_scale": ksc, "v_scale": vsc}
         ck, cv = ctx_kv["k"], ctx_kv["v"]
         ck = jax.lax.dynamic_update_slice(
-            ck, upd_k[:, :, None].astype(ck.dtype), (0, 0, slot, q_start, 0)
+            ck, upd_k[:, :, None].astype(ck.dtype),
+            (plane0, 0, slot, q_start, 0)
         )
         cv = jax.lax.dynamic_update_slice(
-            cv, upd_v[:, :, None].astype(cv.dtype), (0, 0, slot, q_start, 0)
+            cv, upd_v[:, :, None].astype(cv.dtype),
+            (plane0, 0, slot, q_start, 0)
         )
-        out_ctx = {"k": ck, "v": cv}
+        return {"k": ck, "v": cv}
+
+    new_ks: list[jnp.ndarray] = []
+    new_vs: list[jnp.ndarray] = []
+
+    def one_pass(t, carry):
+        h, ctx_kv = carry
+        plane0 = t * c.num_layers
+        for l in range(c.num_layers):
+            if R:
+                q, k, v = live(_layer_qkv, l, h, cos, sin)
+            else:
+                lp = jax.tree.map(lambda x: x[l], params["layers"])
+                ad = _adapter_layer(ag, l, per_row=False)
+                q, k, v = _layer_qkv(c, lp, h, cos, sin, ad)
+            new_ks.append(k)
+            new_vs.append(v)
+            o = dense_prefill_attention(
+                attn, q[None], k[None], v[None], one(q_start), one(seq_len),
+                None if fresh else _prior_context(ctx_kv, plane0 + l,
+                                                  one(slot)),
+            )[0]
+            if R:
+                h, = live(_layer_out, l, h, o)
+            else:
+                # padding tokens must not claim MoE expert capacity
+                h = _layer_out(c, lp, h, o, positions < seq_len, ad)
+        if c.looped:
+            h, _ = _step_end(c, params, h)
+            ctx_kv = store(ctx_kv, new_ks, new_vs, plane0)
+            new_ks.clear()
+            new_vs.clear()
+            # the next pass starts behind this one's store: left free, the
+            # scheduler of a FRESH program (no read orders the stores)
+            # holds every pass's rows to the end (6.2 GB of temporaries
+            # at two lanes of 1024, compile-only, PERF.md section 6, PR 64)
+            h, ctx_kv = jax.lax.optimization_barrier((h, ctx_kv))
+        return h, ctx_kv
+
+    h, ctx_kv = _over_passes(c, one_pass, (h, ctx_kv))
+    # tail (all reads are done); a looped stack's passes have stored theirs
+    out_ctx = ctx_kv if c.looped else store(ctx_kv, new_ks, new_vs, 0)
 
     last = seq_len - q_start - 1  # index of last valid token within T
     logits = _logits(c, params, h[last])
@@ -1196,11 +1339,14 @@ def _batch_forward(
                             # causal in-chunk visibility (spec tree)
     attn: Optional[DecodeAttention] = None,  # as prefill_impl's (spec's
                             # planes hand none over: the XLA loops)
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, Cache]:
     """Read-only layer stack shared by batch_prefill and batch_score: K
-    chunks through the model in one program. Returns (ks, vs, h) —
-    stacked per-layer KV [K, L, T, kvh, hd] and final hidden states
-    [K, T, H]; region writes happen after the stack, in the caller.
+    chunks through the model in one program. Returns (ks, vs, h, ctx_kv)
+    — stacked per-layer KV [K, L, T, kvh, hd] and final hidden states
+    [K, T, H]; region writes happen after the stack, in the caller, and
+    ``ctx_kv`` comes back as given. A looped stack's passes write their
+    own planes as each ends (``_prefill_impl`` has why): ks and vs come
+    back None beside the written region.
 
     Each layer is lane-batched end to end: its two halves (_layer_qkv,
     _layer_out) are vmapped over the K lanes — one [K, T, H] pipeline,
@@ -1221,6 +1367,8 @@ def _batch_forward(
     confined to the base model)."""
     c = config
     _dense_only(c, "speculation (spec/: scoring, drafting)")
+    if depths is not None:
+        _no_loop(c, "speculation (spec/: tree scoring)")
     K, T = tokens.shape
     inv_freq = jnp.asarray(
         rope_inv_freq(c.head_dim, c.rope_theta, c.rope_scaling_dict)
@@ -1251,37 +1399,57 @@ def _batch_forward(
 
     new_ks: list[jnp.ndarray] = []
     new_vs: list[jnp.ndarray] = []
-    for l in range(c.num_layers):
-        if R:
-            q, k, v = live(_layer_qkv, l, h, cos, sin)
-        else:
-            lp = jax.tree.map(lambda x: x[l], params["layers"])
 
-            def ad(ag_row, l=l):
-                return _adapter_layer(ag_row, l, per_row=False)
+    def one_pass(t, carry):     # as _prefill_impl's
+        h, ctx_kv = carry
+        plane0 = t * c.num_layers
+        for l in range(c.num_layers):
+            if R:
+                q, k, v = live(_layer_qkv, l, h, cos, sin)
+            else:
+                lp = jax.tree.map(lambda x: x[l], params["layers"])
 
-            q, k, v = jax.vmap(
-                lambda h, cos, sin, ag_row: _layer_qkv(
-                    c, lp, h, cos, sin, ad(ag_row))
-            )(h, cos, sin, ag)
-        new_ks.append(k)
-        new_vs.append(v)
-        o = dense_prefill_attention(
-            attn, q, k, v, q_starts, seq_lens,
-            _prior_context(ctx_kv, l, slots) if ctx_span > 0 else None,
-            chunk_masks, ctx_span=ctx_span,
-        )
-        if R:
-            h, = live(_layer_out, l, h, o)
-        else:
-            h = jax.vmap(
-                lambda h, o, valid, ag_row: _layer_out(
-                    c, lp, h, o, valid, ad(ag_row))
-            )(h, o, node_valid, ag)
+                def ad(ag_row, l=l):
+                    return _adapter_layer(ag_row, l, per_row=False)
+
+                q, k, v = jax.vmap(
+                    lambda h, cos, sin, ag_row: _layer_qkv(
+                        c, lp, h, cos, sin, ad(ag_row))
+                )(h, cos, sin, ag)
+            new_ks.append(k)
+            new_vs.append(v)
+            o = dense_prefill_attention(
+                attn, q, k, v, q_starts, seq_lens,
+                _prior_context(ctx_kv, plane0 + l, slots) if ctx_span > 0
+                else None,
+                chunk_masks, ctx_span=ctx_span,
+            )
+            if R:
+                h, = live(_layer_out, l, h, o)
+            else:
+                h = jax.vmap(
+                    lambda h, o, valid, ag_row: _layer_out(
+                        c, lp, h, o, valid, ad(ag_row))
+                )(h, o, node_valid, ag)
+        if c.looped:
+            h, _ = _step_end(c, params, h)
+            ctx_kv = _write_chunks(
+                ctx_kv, jnp.stack(new_ks, axis=1).astype(cdt),
+                jnp.stack(new_vs, axis=1).astype(cdt), slots, q_starts,
+                seq_lens, plane0=plane0)
+            new_ks.clear()
+            new_vs.clear()
+            h, ctx_kv = jax.lax.optimization_barrier((h, ctx_kv))
+        return h, ctx_kv
+
+    h, ctx_kv = _over_passes(c, one_pass, (h, ctx_kv))
+    if c.looped:
+        return None, None, h, ctx_kv
     return (
         jnp.stack(new_ks, axis=1).astype(cdt),
         jnp.stack(new_vs, axis=1).astype(cdt),
         h,
+        ctx_kv,
     )
 
 
@@ -1293,6 +1461,8 @@ def _write_chunks(
     q_starts: jnp.ndarray,
     seq_lens: Optional[jnp.ndarray] = None,  # [K] i32 — bounds the rows
                             # feeding int8 scales (padding excluded)
+    plane0: int = 0,        # STATIC: the region's plane ks[:, 0] goes to
+                            # (a looped stack's pass writes its own L)
 ) -> Cache:
     """Tail pass: K span writes per buffer, after every read — one
     rolled loop over the lanes whose carried buffers update in place, so
@@ -1301,6 +1471,7 @@ def _write_chunks(
     group-requantize window (_quant_store_span) instead of a raw DUS."""
     K = ks.shape[0]
     quant = ctx_is_quantized(ctx_kv)
+    assert not (quant and plane0), "an int8 region takes whole stacks"
     g = ctx_group_size(ctx_kv) if quant else 0
 
     def write_lane(i, ctx_kv):
@@ -1321,7 +1492,7 @@ def _write_chunks(
             else:
                 out[name] = jax.lax.dynamic_update_slice(
                     ctx_kv[name], span[:, :, None],
-                    (0, 0, slots[i], q_starts[i], 0))
+                    (plane0, 0, slots[i], q_starts[i], 0))
         return out
 
     return jax.lax.fori_loop(0, K, write_lane, dict(ctx_kv))
@@ -1373,11 +1544,12 @@ def _batch_prefill_impl(config, params, ctx_kv, tokens, slots, q_starts,
                         attn=None):
     """``batch_prefill_impl`` as the block protocol has it: the dense
     decoder's K chunks, (ctx_kv, logits)."""
-    ks, vs, h = _batch_forward(
+    ks, vs, h, ctx_kv = _batch_forward(
         config, params, ctx_kv, tokens, slots, q_starts, seq_lens, ctx_span,
         adapter_ids, attn=attn,
     )
-    ctx_kv = _write_chunks(ctx_kv, ks, vs, slots, q_starts, seq_lens)
+    if ks is not None:      # a looped stack's passes have written theirs
+        ctx_kv = _write_chunks(ctx_kv, ks, vs, slots, q_starts, seq_lens)
     last = jnp.maximum(seq_lens - q_starts - 1, 0)
     h_last = jnp.take_along_axis(h, last[:, None, None], axis=1)[:, 0]
     logits = _logits(config, params, h_last)
@@ -1410,7 +1582,8 @@ def batch_score_impl(
     attention masks by seq_len and the next write over the lane
     overwrites them, so rollback is pointer truncation, not a device op.
     """
-    ks, vs, h = _batch_forward(
+    _no_loop(config, "speculation (spec/: scoring)")
+    ks, vs, h, _ = _batch_forward(
         config, params, ctx_kv, tokens, slots, q_starts, seq_lens, ctx_span
     )
     ctx_kv = _write_chunks(ctx_kv, ks, vs, slots, q_starts, seq_lens)
@@ -1441,7 +1614,7 @@ def batch_score_tree_impl(
     must own. The caller runs acceptance on device, gathers exactly the
     accepted path's rows out of the returned (ks, vs), and commits them
     via commit_tree_path — rollback stays pointer-shaped."""
-    ks, vs, h = _batch_forward(
+    ks, vs, h, _ = _batch_forward(
         config, params, ctx_kv, tokens, slots, q_starts, seq_lens,
         ctx_span, None, depths, chunk_masks,
     )
@@ -1508,8 +1681,9 @@ def batch_draft_impl(
     (level s occupies columns [s*m, s*m + m), column s*m = the spine);
     spec/proposer.py comb_parents gives the matching parent pointers.
     """
+    _no_loop(config, "speculation (spec/: drafting)")
     B, T = tokens.shape
-    ks, vs, h = _batch_forward(
+    ks, vs, h, _ = _batch_forward(
         config, params, ctx_kv, tokens, slots, q_starts, seq_lens, ctx_span
     )
     ctx_kv = _write_chunks(ctx_kv, ks, vs, slots, q_starts, seq_lens)
@@ -1534,7 +1708,7 @@ def batch_draft_impl(
             toks_s = jax.lax.dynamic_slice_in_dim(drafted, s * m, 1, axis=1)
             pos = jnp.where(live, seq_lens + s, 0)
             sl = jnp.where(live, pos + 1, 0)
-            ks, vs, h = _batch_forward(
+            ks, vs, h, _ = _batch_forward(
                 config, params, ctx_kv, toks_s, slots, pos, sl, ctx_span
             )
             ctx_kv = _write_chunks(ctx_kv, ks, vs, slots, pos, sl)
@@ -1561,7 +1735,7 @@ def batch_draft_impl(
         # writes target scratch row 0 and attention masks them entirely
         pos = jnp.where(live, seq_lens + s, 0)
         sl = jnp.where(live, pos + 1, 0)
-        ks, vs, h = _batch_forward(
+        ks, vs, h, _ = _batch_forward(
             config, params, ctx_kv, toks_s, slots, pos, sl, ctx_span
         )
         ctx_kv = _write_chunks(ctx_kv, ks, vs, slots, pos, sl)
@@ -1613,6 +1787,15 @@ def decode_step_impl(
     calls — the write/read interleave on the GB-scale buffer is what
     forces XLA copies (see init_ring).
     """
+    return _decode_step(config, params, ctx_kv, ring, tokens, ctx_lens,
+                        ring_base, ring_pos, live, adapter_ids, attn=attn)[:2]
+
+
+def _decode_step(config, params, ctx_kv, ring, tokens, ctx_lens, ring_base,
+                 ring_pos, live=None, adapter_ids=None, *, attn):
+    """``decode_step_impl`` and, third, a looped stack's exit CDFs: f32
+    [loop_steps - 1, B], after each pass before the last, what
+    ``round_step`` counts (None without the gate)."""
     c = config
     _dense_only(c, "llama.decode_step (the latent block's decode entry "
                 "is mla_moe.decode_step_impl)")
@@ -1628,36 +1811,49 @@ def decode_step_impl(
     # gather out of the fori_loop wrapping this step in the fused round
     ag = _gather_adapters(params.get("adapters"), adapter_ids)
 
-    # unrolled layers — see prefill_impl for why not lax.scan
-    for l in range(c.num_layers):
-        lp = jax.tree.map(lambda x: x[l], params["layers"])
+    # unrolled layers — see prefill_impl for why not lax.scan; a looped
+    # stack's passes (_over_passes): weights by layer, the ring's and the
+    # region's K/V by plane
+    cdfs: list[jnp.ndarray] = []
 
-        def write_kv(k, v, l=l):
-            # one DUS per layer: [B, kvh, hd] -> ring[l, :, :, ring_pos, :]
-            def put(r, x):
-                upd = x.transpose(1, 0, 2)[None, :, :, None, :]
-                return jax.lax.dynamic_update_slice(
-                    r, upd.astype(r.dtype), (l, 0, 0, ring_pos, 0)
+    def one_pass(t, carry):
+        h, ring, stay = carry
+        for l in range(c.num_layers):
+            lp = jax.tree.map(lambda x: x[l], params["layers"])
+
+            def write_kv(k, v, l=t * c.num_layers + l):
+                # one DUS per layer: [B, kvh, hd] -> ring[l, :, :, ring_pos]
+                def put(r, x):
+                    upd = x.transpose(1, 0, 2)[None, :, :, None, :]
+                    return jax.lax.dynamic_update_slice(
+                        r, upd.astype(r.dtype), (l, 0, 0, ring_pos, 0)
+                    )
+
+                return {"k": put(ring["k"], k), "v": put(ring["v"], v)}
+
+            def attend(q, new_ring, l=t * c.num_layers + l):
+                return ctx_decode_attention(
+                    attn, q, ctx_kv["k"], ctx_kv["v"],
+                    new_ring["k"], new_ring["v"], jnp.int32(l),
+                    ctx_lens, ring_base,
+                    ctx_k_scale=ctx_kv["k_scale"] if quant else None,
+                    ctx_v_scale=ctx_kv["v_scale"] if quant else None,
+                    live=live,
                 )
 
-            return {"k": put(ring["k"], k), "v": put(ring["v"], v)}
+            h, ring = _layer_body(c, lp, h, cos, sin, write_kv, attend,
+                                  ffn_valid=live,
+                                  ad=_adapter_layer(ag, l, per_row=True))
+        if c.looped:
+            h, stay = _step_end(c, params, h, stay)
+            if stay is not None and t < c.loop_steps - 1:
+                cdfs.append(1.0 - stay)     # the last pass's is 1
+        return h, ring, stay
 
-        def attend(q, new_ring, l=l):
-            return ctx_decode_attention(
-                attn, q, ctx_kv["k"], ctx_kv["v"],
-                new_ring["k"], new_ring["v"], jnp.int32(l),
-                ctx_lens, ring_base,
-                ctx_k_scale=ctx_kv["k_scale"] if quant else None,
-                ctx_v_scale=ctx_kv["v_scale"] if quant else None,
-                live=live,
-            )
-
-        h, ring = _layer_body(c, lp, h, cos, sin, write_kv, attend,
-                              ffn_valid=live,
-                              ad=_adapter_layer(ag, l, per_row=True))
-
+    stay = jnp.ones(h.shape[0], jnp.float32) if c.exit_gate else None
+    h, ring, _ = _over_passes(c, one_pass, (h, ring, stay))
     logits = _logits(c, params, h)
-    return ring, logits
+    return ring, logits, jnp.stack(cdfs) if cdfs else None
 
 
 decode_step = jax.jit(
@@ -1673,10 +1869,20 @@ def round_step(config, params, ctx_kv, ring, stepped, tokens, ctx_lens,
     """One decode step of the engine's round, under the one signature
     every block has (the module doc): (ring, stepped, logits, stats). The
     dense decoder steps no leaf of the region and counts nothing:
-    ``stepped`` and ``stats`` come back as they were given."""
-    ring, logits = decode_step_impl(
+    ``stepped`` and ``stats`` come back as they were given. A looped
+    stack's row (``stats_layout``) is this step's: the passes a token ran
+    and, a column a step before the last, the live lanes' mean exit CDF."""
+    ring, logits, cdfs = _decode_step(
         config, params, ctx_kv, ring, tokens, ctx_lens, ring_base, s, live,
         adapter_ids, attn=attn)
+    if config.looped:
+        row = [jnp.int32(config.loop_steps)]
+        if cdfs is not None:
+            lanes = live.astype(jnp.float32)
+            mean = (cdfs * lanes).sum(-1) / jnp.maximum(lanes.sum(), 1.0)
+            row += list(jax.lax.bitcast_convert_type(
+                mean[:stats.shape[0] - 1], jnp.int32))
+        stats = jnp.stack(row)
     return ring, stepped, logits, stats
 
 
@@ -2080,6 +2286,7 @@ def sp_prefill(
 
     c = config
     _dense_only(c, "sequence-parallel prefill (sp_prefill)")
+    _no_loop(c, "sequence-parallel prefill (sp_prefill)")
     T = int(tokens.shape[0])
     inv_freq = jnp.asarray(
         rope_inv_freq(c.head_dim, c.rope_theta, c.rope_scaling_dict)
@@ -2131,6 +2338,7 @@ def encode_impl(
     tokens. Cache-free causal attention (prompt-sized, one shot)."""
     c = config
     _dense_only(c, "embeddings (encode)")
+    _no_loop(c, "embeddings (encode)")
     T = tokens.shape[0]
     inv_freq = jnp.asarray(
         rope_inv_freq(c.head_dim, c.rope_theta, c.rope_scaling_dict)

@@ -609,6 +609,24 @@ KV_ROW_BYTES = ("dynamo_kv_row_bytes",
                 "(observed once, at engine start)")
 
 
+KV_CACHE_PLANES = ("dynamo_kv_cache_planes",
+                   "planes of K/V rows a token holds in the ctx region: "
+                   "one a layer, or one a (loop step, layer) of a looped "
+                   "stack (observed once, at engine start)")
+LOOP_STEPS_RUN = ("dynamo_loop_steps_run",
+                  "passes of the layer stack a decoded token ran in a "
+                  "consumed round of a looped model (every step today; "
+                  "what a per-token early exit will lower)")
+# a histogram a loop step before the last, as many as a counter row is
+# given columns for (models/llama.py: stats_layout)
+LOOP_EXIT_CDF = tuple(
+    (f"dynamo_loop_exit_cdf_at_step_{t}",
+     f"mean over a consumed round's live lanes of the exit gate's CDF "
+     f"after loop step {t} (its last decode step): the share of exit mass "
+     f"a threshold below 1 would have let leave by then")
+    for t in range(3))
+
+
 class Counter(NamedTuple):
     """One column of the counter row a fused decode round brings home
     (models/llama.py: ``stats_layout``): what a block's program counts,
@@ -657,6 +675,12 @@ def request_histograms(
             reg.histogram(name, help_,
                           tuple(float(4 ** i) for i in range(3, 13)))
         reg.histogram(*KV_ROW_BYTES, tuple(float(4 ** i) for i in range(3, 12)))
+        reg.histogram(*KV_CACHE_PLANES,
+                      tuple(float(2 ** i) for i in range(11)))
+        reg.histogram(*LOOP_STEPS_RUN, tuple(float(i) for i in range(1, 9)))
+        for name, help_ in LOOP_EXIT_CDF:
+            reg.histogram(name, help_,
+                          tuple(i / 10 for i in range(1, 11)))
         reg.histogram(*SSM_STATE_BYTES,
                       tuple(float(4 ** i) for i in range(6, 15)))
         for name, help_ in (PREFILL_ATTN_LIVE, PREFILL_ATTN_SCORED,

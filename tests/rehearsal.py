@@ -104,6 +104,10 @@ CELLS = {
     # than published
     "agentthink": ("nemotron3-nano-ep8.agentthink", "6000600060",
                    "benchmarks/references/ssm_groups_moe.py", 0.1),
+    # twelve cache planes (3 layers x 4 passes) through ring, region and a
+    # four-page pool, the counter row in two rows of three lanes
+    "chat-looped": ("ouro-2p6b-ut4.chat", "6400640064",
+                    "benchmarks/references/looped.py", 0.3),
 }
 
 

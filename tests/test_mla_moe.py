@@ -11,6 +11,7 @@ logits of magnitude ~1 agree to ~1e-5 and the tolerances are 2e-4. A
 dropped term, a wrong rope pairing, a bias leaking into the weights or a
 mis-scaled expert moves logits by 1e-2 and more.
 """
+import asyncio
 import dataclasses
 import functools
 import importlib.util
@@ -32,8 +33,10 @@ from dynamo_tpu.ops.latent_decode import latent_decode_attention
 from dynamo_tpu.parallel.mesh import MeshConfig
 from dynamo_tpu.protocols.common import (
     PreprocessedRequest,
+    SamplingOptions,
     StopConditions,
 )
+from dynamo_tpu.telemetry import metrics as tmetrics
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TOL = dict(rtol=2e-4, atol=2e-4)
@@ -595,8 +598,6 @@ def test_from_hf_dict_reads_the_published_keys_and_refuses_the_unknown():
     ("spec/", {"speculative": "ngram"}),
     ("LoRA", {"lora_adapters": 2}),
     ("sequence-parallel", {"sp_prefill_threshold": 64}),
-    # no plane: the routing counters' row is max_decode_slots wide
-    ("fewer than 3 slots", {"max_decode_slots": 2}),
 ])
 def test_a_plane_that_cannot_carry_a_latent_row_refuses_at_start(
         setup, plane, kw):
@@ -606,9 +607,35 @@ def test_a_plane_that_cannot_carry_a_latent_row_refuses_at_start(
     ecfg = EngineConfig(**{**dict(
         num_pages=16, page_size=PS, max_pages_per_seq=4, max_decode_slots=4,
         prefill_buckets=(32,), cache_dtype="float32"), **kw})
-    with pytest.raises(ValueError, match="latent|at least 3"):
+    with pytest.raises(ValueError, match="latent"):
         TpuEngine(cfg, ecfg, params=llama.init_params(cfg, 3),
                   mesh_config=MeshConfig(tp=1))
+
+
+def test_fewer_lanes_than_counters_ride_more_rows(setup):
+    """No plane: the routing counters ride home behind the round's tokens
+    in rows ``max_decode_slots`` wide, as many rows as the columns fill
+    (until PR 64 an engine of fewer lanes than counters was refused)."""
+    cfg, _, _ = setup
+    assert len(llama.stats_layout(cfg)) == 3
+    eng = TpuEngine(cfg, EngineConfig(
+        num_pages=16, page_size=PS, max_pages_per_seq=4, max_decode_slots=2,
+        prefill_buckets=(32,), cache_dtype="float32"),
+        params=llama.init_params(cfg, 3), mesh_config=MeshConfig(tp=1))
+
+    async def serve():
+        req = PreprocessedRequest(
+            token_ids=list(range(5, 17)), model="t",
+            stop_conditions=StopConditions(max_tokens=6, ignore_eos=True),
+            sampling_options=SamplingOptions(temperature=0.0))
+        toks = [t async for out in eng.generate(req) for t in out.token_ids]
+        await eng.stop()
+        return toks
+
+    assert len(asyncio.run(serve())) == 6
+    snap = eng.telemetry.snapshot()
+    assert snap[tmetrics.MOE_ROUTED[0]]["count"] > 0
+    assert snap[tmetrics.MOE_LOAD_MAX[0]]["sum"] > 0      # the third column
 
 
 def test_model_functions_of_other_planes_refuse_a_latent_row(setup):

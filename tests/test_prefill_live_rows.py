@@ -335,7 +335,7 @@ def test_host_mirror_is_the_programs_trip_count(case):
     K = len(q_starts)
     ctx = llama.init_ctx(c, K, 8192, jnp.float32)
     toks = _tokens(np.random.RandomState(1), K, T_)
-    _, _, h = jax.jit(llama._batch_forward, static_argnums=(0, 7))(
+    _, _, h, _ = jax.jit(llama._batch_forward, static_argnums=(0, 7))(
         c, params, ctx, jnp.asarray(toks), jnp.arange(K, dtype=jnp.int32),
         jnp.asarray(q_starts, jnp.int32), jnp.asarray(seq_lens, jnp.int32),
         8192 if max(q_starts) else 0)

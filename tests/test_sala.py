@@ -483,7 +483,6 @@ def test_the_matrix_state_is_float32_whatever_the_cache_dtype(cache_dtype):
     ("spec/", {"speculative": "ngram"}),
     ("LoRA", {"lora_adapters": 2}),
     ("sequence-parallel", {"sp_prefill_threshold": 64}),
-    ("fewer than 4 slots", {"max_decode_slots": 3}),
     # a chunk starts on a page and has to start on a block
     ("pages of half a block", {"page_size": 4, "max_pages_per_seq": 64}),
 ])
@@ -492,8 +491,16 @@ def test_a_plane_that_cannot_carry_the_new_leaves_refuses_at_start(
     cfg, params, _ = setup
     with pytest.raises(
             ValueError,
-            match="compressed-key rows|recurrent|at least 4|no multiple"):
+            match="compressed-key rows|recurrent|no multiple"):
         engine(cfg, params, **kw)
+
+
+def test_an_engine_of_fewer_lanes_than_counters_starts(setup):
+    """No plane: the counter row takes as many rows behind the round's
+    tokens as its columns fill (until PR 64 three lanes were refused)."""
+    cfg, params, _ = setup
+    assert len(llama.stats_layout(cfg)) > 3
+    assert engine(cfg, params, max_decode_slots=3).ecfg.max_decode_slots == 3
 
 
 def test_the_other_planes_and_meshes_refuse_the_new_leaves(setup):

@@ -112,6 +112,7 @@ def test_registry_render_and_snapshot():
         "dynamo_engine_round_tokens",
         "dynamo_moe_experts_touched", "dynamo_moe_tokens_routed",
         "dynamo_moe_expert_load_max", "dynamo_kv_row_bytes",
+        "dynamo_kv_cache_planes", "dynamo_loop_steps_run",
         "dynamo_hc_sinkhorn_residual", "dynamo_prefill_continued_tokens",
         "dynamo_decode_attn_rows_read", "dynamo_decode_attn_rows_live",
         "dynamo_moe_picks_routed",
@@ -134,7 +135,7 @@ def test_registry_render_and_snapshot():
         "dynamo_engine_dispatch_found_dry",
         "dynamo_layer_parts_run_mixer_ssm", "dynamo_layer_parts_run_mixer_attn",
         "dynamo_layer_parts_run_experts", "dynamo_layer_parts_run_mlp",
-    }
+    } | {f"dynamo_loop_exit_cdf_at_step_{t}" for t in range(3)}
     reg.get("dynamo_request_ttft_seconds").observe(0.2)
     text = reg.render()
     assert "# TYPE dynamo_request_ttft_seconds histogram" in text

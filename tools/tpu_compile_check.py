@@ -322,11 +322,11 @@ def compile_programs(model_config: str = "llama3_1b", tp: int = 1,
     n_pages = min(e.max_pages_per_seq, 64)
     quant = kv_quant == "int8"
     page_data = jax.ShapeDtypeStruct(
-        (2, c.num_layers, c.num_kv_heads, n_pages, e.page_size, c.head_dim),
+        (2, c.cache_planes, c.num_kv_heads, n_pages, e.page_size, c.head_dim),
         jnp.int8 if quant else dtype, sharding=jax.sharding.NamedSharding(
             mesh, jax.sharding.PartitionSpec(None, None, "tp")))
     page_scales = jax.ShapeDtypeStruct(
-        (2, c.num_layers, n_pages), jnp.float32, sharding=rep)
+        (2, c.cache_planes, n_pages), jnp.float32, sharding=rep)
 
     table = {
         "decode_step": lambda: llama.decode_step.trace(
@@ -445,6 +445,9 @@ def compile_programs(model_config: str = "llama3_1b", tp: int = 1,
                 output_gb=round(mem.output_size_in_bytes / 1e9, 3),
                 alias_gb=round(mem.alias_size_in_bytes / 1e9, 3),
                 temp_bytes=mem.temp_size_in_bytes,
+                # the executable's own bytes, which a loaded program
+                # keeps on the device (a cache entry is a fifth of it)
+                code_mb=round(mem.generated_code_size_in_bytes / 1e6, 1),
                 region_bytes=math.prod(region_shard) * itemsize,
                 region_copies={"count": len(copies),
                                "shapes": sorted(set(copies))},
